@@ -20,9 +20,9 @@ and prints ONE JSON line::
 program grid (0 without ``--aot``), ``ttft_s`` the time from submitting
 the first request until its first decode chunk has run — with ``--aot``
 this is pure execution, without it the JIT pauses land here.  The JAX
-persistent compilation cache is DISABLED by default (it would make every
-start warm); pass ``--compile_cache DIR`` to measure cache-assisted
-restarts instead.  ``--out`` appends to a JSONL file
+persistent compilation cache is turned OFF here
+(``jax_enable_compilation_cache``): the uncached start is what this
+measures, and a warm cache would make every start warm.  ``--out`` appends to a JSONL file
 (``benchmarks/coldstart.jsonl`` by convention).
 """
 
@@ -36,15 +36,11 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from progen_tpu.core.cache import honor_env_platforms
-
-honor_env_platforms()
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from progen_tpu.observe.platform import probe_backend, stamp_record
+from progen_tpu.observe.platform import stamp_record
 
 
 def main() -> None:
@@ -64,19 +60,10 @@ def main() -> None:
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--out", metavar="FILE", default=None,
                     help="also append the record to this JSONL file")
-    ap.add_argument("--compile_cache", metavar="DIR", default=None,
-                    help="JAX persistent compilation cache dir (DEFAULT "
-                         "DISABLED here — a warm cache is not a cold "
-                         "start)")
     args = ap.parse_args()
 
-    from progen_tpu.core.cache import enable_compilation_cache
-
-    os.environ["PROGEN_COMPILE_CACHE"] = args.compile_cache or "0"
-    enable_compilation_cache()
-
-    if not probe_backend(metric="coldstart"):
-        return
+    # a warm cache is not a cold start: the uncached arm is the measurement
+    jax.config.update("jax_enable_compilation_cache", False)
 
     from progen_tpu.core.precision import make_policy
     from progen_tpu.decode import Request, ServingEngine

@@ -43,13 +43,9 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
 
-from progen_tpu.core.cache import honor_env_platforms
-
-honor_env_platforms()
-
 import numpy as np  # noqa: E402
 
-from progen_tpu.observe.platform import probe_backend, stamp_record  # noqa: E402
+from progen_tpu.observe.platform import stamp_record  # noqa: E402
 from progen_tpu.observe import slo as _slo  # noqa: E402
 
 
@@ -100,17 +96,11 @@ def main() -> None:
                          "completion against the max-size fixed fleet, "
                          "and across the swap's generation boundary")
     ap.add_argument("--out", metavar="FILE", default=None)
-    ap.add_argument("--compile_cache", metavar="DIR", default=None)
     args = ap.parse_args()
 
     from progen_tpu.core.cache import enable_compilation_cache
 
-    if args.compile_cache is not None:
-        os.environ["PROGEN_COMPILE_CACHE"] = args.compile_cache
     enable_compilation_cache()
-
-    if not probe_backend(metric="serving_elastic"):
-        return
 
     import jax
 

@@ -40,10 +40,6 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
 
-from progen_tpu.core.cache import honor_env_platforms
-
-honor_env_platforms()
-
 import numpy as np  # noqa: E402
 
 from progen_tpu.observe.platform import stamp_record  # noqa: E402

@@ -90,10 +90,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from progen_tpu.core.cache import honor_env_platforms
-
-honor_env_platforms()
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -101,7 +97,7 @@ import numpy as np
 from progen_tpu.observe import slo as _slo
 from progen_tpu.observe.meter import profile_trace
 from progen_tpu.observe.metrics import latency_percentiles
-from progen_tpu.observe.platform import probe_backend, stamp_record
+from progen_tpu.observe.platform import stamp_record
 from progen_tpu.observe.trace import (
     configure_tracing,
     get_tracer,
@@ -300,19 +296,11 @@ def main() -> None:
     ap.add_argument("--xprof-dir", metavar="DIR", default=None,
                     help="record an xprof/TensorBoard profile of the "
                          "measured drive into this directory")
-    ap.add_argument("--compile_cache", metavar="DIR", default=None,
-                    help="JAX persistent compilation cache dir ('0' "
-                         "disables); overrides PROGEN_COMPILE_CACHE")
     args = ap.parse_args()
 
     from progen_tpu.core.cache import enable_compilation_cache
 
-    if args.compile_cache is not None:
-        os.environ["PROGEN_COMPILE_CACHE"] = args.compile_cache
     enable_compilation_cache()
-
-    if not probe_backend(metric="serving"):
-        return
 
     if args.trace:
         os.makedirs(args.trace_out, exist_ok=True)

@@ -6,16 +6,15 @@ chaining outputs into inputs, interleaved reps, medians) but emits ONE
 JSON LINE per (n, pass) so driver runs can ingest the sweep directly::
 
     {"bench": "sgu", "n": 1024, "d": 2048, "pass": "fwd", "xla_ms": ...,
-     "pallas_ms": ..., "speedup": ..., "block": 64,
-     "blocks_executed": 136, "blocks_dense": 256, "flop_ratio": 0.53125}
+     "pallas_ms": ..., "speedup": ..., "block": 128,
+     "blocks_executed": 36, "blocks_dense": 64, "flop_ratio": 0.5625}
 
 The static block-skip fields come from
 :func:`progen_tpu.ops.pallas_sgu.sgu_block_flops` — on a CPU-only host
 the timings measure the INTERPRETER (meaningless for kernel speed; the
 block-skip counts are the honest artifact there), so the record carries
-a ``"platform"`` stamp.  Backend-init failures reuse ``bench.py``'s
-retried subprocess probe and emit its parseable JSON error record
-instead of a traceback.
+a ``"platform"`` stamp.  A run that raises exits non-zero with its
+traceback.
 
 Usage::
 
@@ -106,11 +105,6 @@ def main() -> None:
     ap.add_argument("--iters", type=int, default=50)
     ap.add_argument("--reps", type=int, default=5)
     args = ap.parse_args()
-
-    from progen_tpu.observe.platform import probe_backend
-
-    if not probe_backend():
-        return
 
     from progen_tpu.ops.pallas_sgu import sgu_block_flops
 

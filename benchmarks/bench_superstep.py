@@ -141,11 +141,6 @@ def main() -> None:
     if bad:
         ap.error(f"--steps {args.steps} not divisible by K in {bad}")
 
-    from progen_tpu.observe.platform import probe_backend
-
-    if not probe_backend():
-        return
-
     cfg, fns = build(args.config, args.batch, args.accum)
     platform = jax.default_backend()
     results = {}

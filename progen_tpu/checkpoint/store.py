@@ -42,7 +42,7 @@ class CheckpointStore:
         self._keep_last_n = keep_last_n
         self._mgr: ocp.CheckpointManager | None = None
         # every storage-touching operation goes through this policy: GCS
-        # 503s/429s and tunnel drops are routine at pod scale, and one
+        # 503s/429s and dropped connections are routine at pod scale, and one
         # failed periodic save must not kill a run that has a perfectly
         # good retry budget (env-tunable: PROGEN_CKPT_RETRY_*)
         self._retry = retry_policy or RetryPolicy.from_env("PROGEN_CKPT_RETRY")
@@ -138,21 +138,14 @@ class CheckpointStore:
         def _issue() -> bool:
             faults.inject("ckpt.save")
             # a still-finalizing previous async save makes orbax reject a
-            # new one (AssertionError on its finalize thread); saves are
-            # issued off the training critical path, so waiting here is
-            # free and removes the race.  orbax only CLEARS the finalize
-            # handle when the wait comes from the thread that issued that
-            # save — the trainer issues each background save from a fresh
-            # thread, so drop the joined-but-stale handle ourselves
-            # (guarded: only when its thread is provably finished).
+            # new one (AssertionError while its finalize thread is
+            # alive); saves are issued off the training critical path, so
+            # joining it here is free and removes the race.  The join
+            # works from any thread — the trainer issues each background
+            # save from a fresh one — and orbax (0.11) only refuses while
+            # the previous finalize thread is still ALIVE, so no handle
+            # needs clearing afterwards.
             mgr.wait_until_finished()
-            stale = getattr(mgr, "_finalize_thread", None)
-            if stale is not None and not stale.is_alive():
-                lock = getattr(mgr, "_finalize_thread_lock", None)
-                if lock is not None:
-                    with lock:
-                        if mgr._finalize_thread is stale:
-                            mgr._finalize_thread = None
             # membership, not latest_step(): re-converting a reference
             # pickle into a store that has trained past step 0 collides
             # with a step that exists but is no longer the newest

@@ -2216,7 +2216,12 @@ class ServingEngine:
     def _dispatch_chunk(self) -> None:
         """Run one guarded decode chunk.  A fatal fault on the paged
         Pallas kernel degrades to the bit-identical XLA fallback and
-        retries; a fatal fault anywhere else sheds the in-flight batch
+        retries — but only once the chunk program has run: until then
+        what it raises comes from tracing, lowering or compiling the
+        kernel, and a kernel the chip's compiler refuses is raised to the
+        caller, not hidden behind one ``fallback_activations`` count
+        (injected faults fire before the program and still degrade).  A
+        fatal fault anywhere else sheds the in-flight batch
         (``_fail_inflight``) and the engine keeps serving; transient
         exhaustion escapes as :class:`RetryError` (restart-and-replay).
         """
@@ -2250,6 +2255,11 @@ class ServingEngine:
                 return
             except (_ContainedFault, RetryError) as e:
                 if self.paged and self.paged_impl == "pallas":
+                    cause = e.__cause__
+                    if (("chunk",) not in self._compiled_keys
+                            and cause is not None and not isinstance(
+                                cause, faults.InjectedFault)):
+                        raise cause
                     self._activate_xla_fallback()
                     continue  # bit-identical retry on the degraded path
                 if isinstance(e, RetryError):
@@ -2585,7 +2595,7 @@ class ServingEngine:
         fresh (or restarted) process pays zero first-request compiles —
         the cold-start TTFT story (``benchmarks/bench_coldstart.py``).
         Composes with the persistent compilation cache
-        (``--compile_cache``), which turns these compiles into disk hits.
+        (``core/cache.py``), which turns these compiles into disk hits.
         """
         t0 = time.perf_counter()
         as_shape = partial(jax.tree.map,

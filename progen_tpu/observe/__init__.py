@@ -19,7 +19,7 @@ from progen_tpu.observe.metrics import (
     merge_snapshots,
     split_labeled,
 )
-from progen_tpu.observe.platform import emit_error_record, probe_backend
+from progen_tpu.observe.platform import require_tpu, stamp_record
 from progen_tpu.observe.robustness import RobustnessCounters
 from progen_tpu.observe.slo import BurnRateTracker, SLOSpec
 from progen_tpu.observe.statusz import StatuszServer, render_prometheus
@@ -37,9 +37,9 @@ from progen_tpu.observe.tracker import Tracker
 __all__ = [
     "PEAK_BF16_TFLOPS",
     "RobustnessCounters",
-    "emit_error_record",
     "git_sha",
-    "probe_backend",
+    "require_tpu",
+    "stamp_record",
     "mfu",
     "model_flops_per_token",
     "peak_flops_per_chip",

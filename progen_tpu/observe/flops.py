@@ -11,14 +11,17 @@ from __future__ import annotations
 
 import jax
 
-# bf16 peak by jax device_kind; extend as new generations appear.
+# THE one table of peak dense bf16 TFLOP/s per chip, keyed by jax
+# device_kind.  A TPU whose kind is not here is an error, not a default
+# (peak_flops_per_chip).  Sources: Google Cloud TPU documentation, the
+# "System architecture" page of each generation.
 PEAK_BF16_TFLOPS = {
-    "TPU v4": 275.0,
-    "TPU v5 lite": 197.0,
-    "TPU v5e": 197.0,
-    "TPU v5p": 459.0,
-    "TPU v6 lite": 918.0,
-    "TPU v6e": 918.0,
+    "TPU v4": 275.0,       # cloud.google.com/tpu/docs/v4
+    "TPU v5 lite": 197.0,  # cloud.google.com/tpu/docs/v5e (kind as JAX reports a v5e)
+    "TPU v5e": 197.0,      # same chip, alternative spelling
+    "TPU v5p": 459.0,      # cloud.google.com/tpu/docs/v5p
+    "TPU v6 lite": 918.0,  # cloud.google.com/tpu/docs/v6e (Trillium)
+    "TPU v6e": 918.0,      # same chip, alternative spelling
 }
 
 
@@ -49,11 +52,19 @@ def model_flops_per_token(cfg, num_params: int,
 
 
 def peak_flops_per_chip(device=None) -> float | None:
-    """Peak bf16 FLOP/s of the local accelerator, or None off-TPU /
-    unknown kind (callers skip MFU then)."""
+    """Peak bf16 FLOP/s of the local accelerator.  None off-TPU (there is
+    no device rate to normalize; callers skip MFU then); a TPU whose
+    ``device_kind`` is missing from :data:`PEAK_BF16_TFLOPS` raises —
+    add the kind with its source rather than assume a peak."""
     device = device or jax.devices()[0]
-    tflops = PEAK_BF16_TFLOPS.get(device.device_kind)
-    return None if tflops is None else tflops * 1e12
+    if device.platform != "tpu":
+        return None
+    if device.device_kind not in PEAK_BF16_TFLOPS:
+        raise KeyError(
+            f"no peak FLOP/s known for device_kind {device.device_kind!r}; "
+            f"add it to progen_tpu.observe.flops.PEAK_BF16_TFLOPS with "
+            f"its source (known: {sorted(PEAK_BF16_TFLOPS)})")
+    return PEAK_BF16_TFLOPS[device.device_kind] * 1e12
 
 
 def mfu(tokens_per_sec_per_chip: float, flops_per_token: float,

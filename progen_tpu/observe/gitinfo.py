@@ -9,6 +9,7 @@ account of provenance.
 from __future__ import annotations
 
 import functools
+import os
 import subprocess
 from pathlib import Path
 
@@ -27,6 +28,10 @@ def git_sha(short: bool = False) -> str | None:
             text=True,
             timeout=5,
             check=False,
+            # never look above the checkout: a copy that is not a git
+            # repository has no sha, whatever it is nested in
+            env={**os.environ,
+                 "GIT_CEILING_DIRECTORIES": str(_REPO_ROOT.parent)},
         )
     except (OSError, subprocess.TimeoutExpired):
         return None

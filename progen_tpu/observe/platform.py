@@ -1,31 +1,16 @@
-"""Platform capability probing shared by every benchmark entrypoint.
+"""The stamp every benchmark record carries, and the TPU requirement.
 
-Four benchmark drivers (``bench.py``, ``benchmarks/bench_serving.py``,
-``benchmarks/bench_sgu.py``, ``benchmarks/bench_superstep.py``) need the
-same two things before touching an accelerator:
-
-* :func:`probe_backend` — verify the backend actually comes up, in a
-  SUBPROCESS: TPU runtime init can fail transiently (libtpu UNAVAILABLE
-  when another process briefly holds the chips) or HANG outright in its
-  metadata fetches while holding the GIL, so an in-process thread
-  timeout can never fire.  Attempts retry via the resilience layer
-  (``PROGEN_BENCH_RETRY_*`` env knobs).
-* :func:`emit_error_record` — when the backend (or the run itself) is
-  beyond saving, print ONE parseable JSON error line with a platform
-  stamp and keep rc 0, so the capture driver ingests a structured record
-  instead of a raw traceback.
-
-Historically these lived in ``bench.py`` and the other drivers imported
-the root script — a working-directory trap and a circular layering smell.
-This module is the shared home (ROADMAP item 4's cleanup).
+* :func:`stamp_record` — the one door every benchmark JSON record leaves
+  through (``git_sha`` + ``wall_time``).
+* :func:`require_tpu` — for entry points whose output only means
+  something on the device (``bench.py``'s rate, ``chip_smoke.py``): raise
+  unless the process runs on a TPU.  JAX is initialized in
+  the calling process and nowhere else — a chip belongs to one process at
+  a time, so no child is started to look first — and a run that raises
+  exits non-zero with its traceback; nothing is turned into a record.
 """
 
 from __future__ import annotations
-
-import dataclasses
-import json
-import os
-import sys
 
 from progen_tpu.observe.gitinfo import git_sha
 
@@ -65,60 +50,16 @@ def stamp_record(record: dict | None = None, **extra) -> dict:
     return out
 
 
-def emit_error_record(e: BaseException, **extra) -> None:
-    """One parseable JSON error line (stdout, rc stays 0) with a platform
-    stamp — the driver ingests this instead of a traceback.  ``extra``
-    keys are merged into the record (e.g. the benchmark's knob values)."""
-    import platform
-
+def require_tpu():
+    """The first device, which must be a TPU; raise naming the platform
+    found otherwise.  Initializes JAX in THIS process."""
     import jax
 
-    print(json.dumps(stamp_record({
-        "error": f"{type(e).__name__}: {e}",
-        "metric": None,
-        "jax_platforms": os.environ.get("JAX_PLATFORMS", ""),
-        "jax_version": jax.__version__,
-        "python": platform.python_version(),
-    }, **extra)), flush=True)
-
-
-def probe_backend(**extra) -> bool:
-    """Check the accelerator backend comes up, retrying transient failures.
-
-    Runs ``jax.devices()`` in a subprocess per attempt (see module
-    docstring for why), retried under ``PROGEN_BENCH_RETRY_*``.  On
-    definitive failure, emits the structured error record (merging
-    ``extra``) and returns False — callers ``return`` without touching
-    the backend.
-    """
-    import subprocess
-
-    from progen_tpu.resilience.retry import (
-        AttemptTimeout, RetryPolicy, retry_call,
-    )
-
-    policy = RetryPolicy.from_env("PROGEN_BENCH_RETRY")
-    per_try = policy.attempt_timeout or 60.0
-    # the subprocess enforces the per-attempt bound itself — don't stack
-    # the thread-based attempt timeout on top
-    policy = dataclasses.replace(policy, attempt_timeout=None)
-
-    def probe():
-        try:
-            proc = subprocess.run(
-                [sys.executable, "-c", "import jax; jax.devices()"],
-                capture_output=True, text=True, timeout=per_try,
-            )
-        except subprocess.TimeoutExpired:
-            raise AttemptTimeout(
-                f"backend init exceeded {per_try:.0f}s") from None
-        if proc.returncode != 0:
-            tail = (proc.stderr or "").strip().splitlines()[-8:]
-            raise RuntimeError("backend init failed: " + " | ".join(tail))
-
-    try:
-        retry_call(probe, policy=policy, label="backend-init")
-        return True
-    except Exception as e:  # RetryError or fatal init error: report, don't raise
-        emit_error_record(e, **extra)
-        return False
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        raise RuntimeError(
+            f"this entry point reports on the device and needs a TPU; JAX "
+            f"found platform {device.platform!r} ({device.device_kind}, "
+            f"{len(jax.devices())} device(s)). A CPU run is never "
+            f"written under a device's name.")
+    return device

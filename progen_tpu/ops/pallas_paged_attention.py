@@ -19,17 +19,27 @@ row walks ONLY its own pages: the grid is ``(B, pages_per_row)``, the
 page axis is innermost (consecutive visits to the same output row, the
 accumulation contract from ``pallas_sgu.py``), and pages past the row's
 position are skipped entirely (``@pl.when`` — a short request touches
-``pos // ps + 1`` pages, not the table width).  Bit-for-bit discipline:
+``pos // ps + 1`` pages, not the table width).  Discipline:
 
 * the per-page partial products accumulate in an f32 VMEM scratch;
 * ``pos`` and the page table ride in as SCALAR-PREFETCH operands
-  (``pltpu.PrefetchScalarGridSpec``): the index maps that choose the
-  weight-row block (``pos_ref[b]``) and the pool page
-  (``table_ref[b, p]``) are integer lookups into prefetched SMEM —
-  no gather materialization, no float work on the scalar core;
+  (``pltpu.PrefetchScalarGridSpec``): the index map that chooses the pool
+  page (``table_ref[b, p]``) is an integer lookup into prefetched SMEM —
+  no gather materialization of the pool, no float work on the scalar
+  core;
+* every block is one the TPU compiler takes: the last two dimensions of a
+  block are multiples of (8, 128) or the whole of the array's, so every
+  size-1 axis sits in a LEADING block dimension.  The weight row of each
+  batch row is gathered, causally masked and (for int8 weights)
+  dequantized by XLA before the kernel — ``B`` rows of ``n`` f32, a few
+  KB against the pool's megabytes — and handed over as
+  ``(B, pages_per_row, 1, page_size)``; the bias is added after it.  The
+  kernel keeps what is large: the page walk and the contraction;
+* the contraction is a VPU multiply + sublane reduce (``(ps, 1)`` weight
+  column against the ``(ps, d)`` page), not an M=1 MXU matmul;
 * unowned table entries point at the all-zeros ``NULL_PAGE`` so reading
-  them is harmless, and the in-kernel causal mask zeroes columns past
-  ``pos`` so stale rows in reused pages contribute exact ±0.
+  them is harmless, and the causal mask zeroes columns past ``pos`` so
+  stale rows in reused pages contribute exact ±0.
 
 The XLA fallback (``impl="xla"``) is a gather + the SAME masked einsum
 the dense decode path uses, sliced to the dense row count — on CPU it is
@@ -51,8 +61,28 @@ from progen_tpu.decode.paging import DUMP_PAGE, NULL_PAGE
 from progen_tpu.ops.quant import quantize_rows
 
 
-def _mix_kernel(pos_ref, table_ref, w_ref, pool_ref, bias_ref, o_ref,
-                acc_ref, *, page_size, pages_per_row):
+def _column(row):
+    """``(1, k)`` row -> ``(k, 1)`` column without a transpose: broadcast
+    down the sublanes, keep the diagonal, reduce along the lanes (exact:
+    every sum has one non-zero term)."""
+    k = row.shape[1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (k, k), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (k, k), 1))
+    return jnp.sum(jnp.where(eye, jnp.broadcast_to(row, (k, k)), 0.0),
+                   axis=1, keepdims=True)
+
+
+def _mix_kernel(pos_ref, table_ref, w_ref, pool_ref, *rest, page_size,
+                pages_per_row, scaled):
+    """One (batch row, page) step.  ``w_ref`` is this page's slice of the
+    row's masked f32 weights; with ``scaled`` the int8 page is dequantized
+    here by folding its per-row scales (``pscale_ref``, indexed like the
+    page) into the weights, so nothing 8-bit of the pool ever round-trips
+    HBM at higher precision."""
+    if scaled:
+        pscale_ref, o_ref, acc_ref = rest
+    else:
+        o_ref, acc_ref = rest
     b = pl.program_id(0)
     p = pl.program_id(1)
 
@@ -60,157 +90,81 @@ def _mix_kernel(pos_ref, table_ref, w_ref, pool_ref, bias_ref, o_ref,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    pos = pos_ref[b]
     # pages strictly past the row's position hold no live rows: skip the
     # fetch-multiply entirely (ragged walk — work scales with pos, not
     # with the table width)
-    @pl.when(p <= pos // page_size)
+    @pl.when(p <= pos_ref[b] // page_size)
     def _accumulate():
-        col = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        w = jnp.where(col <= pos, w_ref[...].astype(jnp.float32), 0.0)
-        acc_ref[...] += jax.lax.dot_general(
-            w, pool_ref[0].astype(jnp.float32),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        w = w_ref[0, 0]  # (1, page_size)
+        if scaled:
+            w = w * pscale_ref[0]
+        acc_ref[...] += jnp.sum(
+            _column(w) * pool_ref[0].astype(jnp.float32),
+            axis=0, keepdims=True)
 
     @pl.when(p == pages_per_row - 1)
     def _epilogue():
-        o_ref[...] = acc_ref[...] + bias_ref[...].astype(jnp.float32)
+        o_ref[0] = acc_ref[...]
 
 
-def _mix_kernel_q8(pos_ref, table_ref, w_ref, pool_ref, bias_ref,
-                   wscale_ref, pscale_ref, o_ref, acc_ref, *,
-                   page_size, pages_per_row):
-    """Quantized variant of :func:`_mix_kernel`: dequant in the epilogue.
-
-    Int8 weight blocks and int8 pool pages are widened to f32 INSIDE the
-    kernel and multiplied by their scales — the per-weight-ROW scalar
-    (``wscale_ref``, indexed like the bias) and the per-pool-row scales
-    riding next to the page (``pscale_ref``, indexed like the page) — so
-    nothing 8-bit ever round-trips HBM at higher precision.  When one
-    side is full precision its scale pool is all ones and the multiply
-    is exact.
-    """
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-
-    @pl.when(p == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    pos = pos_ref[b]
-
-    @pl.when(p <= pos // page_size)
-    def _accumulate():
-        col = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        w = jnp.where(col <= pos, w_ref[...].astype(jnp.float32), 0.0)
-        w = w * wscale_ref[0, 0]
-        rows = pool_ref[0].astype(jnp.float32) * \
-            pscale_ref[...].reshape(page_size, 1)
-        acc_ref[...] += jax.lax.dot_general(
-            w, rows,
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-
-    @pl.when(p == pages_per_row - 1)
-    def _epilogue():
-        o_ref[...] = acc_ref[...] + bias_ref[...].astype(jnp.float32)
-
-
-def _pallas_mix(weights, biases, pool, table, pos, *, interpret):
-    batch, pages_per_row = table.shape
-    _, page_size, d = pool.shape
-    n = weights.shape[0]
-    span = pages_per_row * page_size
-    if span > n:
-        # the last page may run past the (n, n) weight square; pad the
-        # column axis so every (1, page_size) block is in-bounds (the
-        # causal mask kills the padded columns — and their pool rows are
-        # real page rows, so the product is exact zero, not garbage)
-        weights = jnp.pad(weights, ((0, 0), (0, span - n)))
-    # biases come in as (n, 1) column vectors (ops/sgu.py layout)
-    biases = biases.reshape(n, 1).T  # (1, n) -> block (1, 1) at [0, pos]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(batch, pages_per_row),
-        in_specs=[
-            # weight row pos_b, column block p  (integer-only index maps:
-            # scalar-prefetch refs indexed by grid coordinates)
-            pl.BlockSpec((1, page_size),
-                         lambda b, p, pos_ref, table_ref: (pos_ref[b], p)),
-            # the pool page this row's table names for block p
-            pl.BlockSpec((1, page_size, d),
-                         lambda b, p, pos_ref, table_ref:
-                         (table_ref[b, p], 0, 0)),
-            pl.BlockSpec((1, 1),
-                         lambda b, p, pos_ref, table_ref: (0, pos_ref[b])),
-        ],
-        out_specs=pl.BlockSpec((1, d),
-                               lambda b, p, pos_ref, table_ref: (b, 0)),
-        scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
-    )
-    kernel = functools.partial(_mix_kernel, page_size=page_size,
-                               pages_per_row=pages_per_row)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((batch, d), jnp.float32),
-        interpret=interpret,
-    )(pos.astype(jnp.int32), table.astype(jnp.int32), weights, pool, biases)
-
-
-def _pallas_mix_q8(weights, biases, pool, table, pos, w_scale, pool_scale,
-                   *, interpret):
-    """Quantized-path twin of :func:`_pallas_mix`: two extra scale
-    operands, same grid/ragged-walk structure, dequant in the kernel
-    epilogue (see :func:`_mix_kernel_q8`)."""
+def _pallas_mix(weights, biases, pool, table, pos, w_scale=None,
+                pool_scale=None, *, interpret):
+    """Launch the ragged page walk; ``w_scale`` (per weight row) and
+    ``pool_scale`` (per pool row) mark the int8 side(s).  Full precision
+    passes no scale operand at all: the bit-identity contract of the
+    default path must not depend on all-ones multiplies optimizing
+    away."""
     batch, pages_per_row = table.shape
     num_pages, page_size, d = pool.shape
     n = weights.shape[0]
     span = pages_per_row * page_size
+    pos = pos.astype(jnp.int32)
+    w_rows = weights[pos].astype(jnp.float32)  # (B, n)
+    if w_scale is not None:
+        w_rows = w_rows * w_scale.astype(jnp.float32)[pos][:, None]
+    w_rows = jnp.where(jnp.arange(n)[None, :] <= pos[:, None], w_rows, 0.0)
     if span > n:
-        weights = jnp.pad(weights, ((0, 0), (0, span - n)))
-    biases = biases.reshape(n, 1).T  # (1, n) -> block (1, 1) at [0, pos]
-    # missing scales mean that side is full precision: all-ones is exact
-    if w_scale is None:
-        w_scale = jnp.ones((n,), jnp.float32)
-    if pool_scale is None:
-        pool_scale = jnp.ones((num_pages, page_size), jnp.float32)
-    w_scale = w_scale.astype(jnp.float32).reshape(1, n)
+        # the last page may run past the (n, n) weight square: its
+        # columns get zero weights, and their pool rows are real page
+        # rows, so the product is exact zero, not garbage
+        w_rows = jnp.pad(w_rows, ((0, 0), (0, span - n)))
+    w_rows = w_rows[:, :span].reshape(batch, pages_per_row, 1, page_size)
 
+    in_specs = [
+        pl.BlockSpec((1, 1, 1, page_size),
+                     lambda b, p, pos_ref, table_ref: (b, p, 0, 0)),
+        # the pool page this row's table names for block p (integer-only
+        # index map: a scalar-prefetch ref indexed by grid coordinates)
+        pl.BlockSpec((1, page_size, d),
+                     lambda b, p, pos_ref, table_ref: (table_ref[b, p], 0, 0)),
+    ]
+    operands = [w_rows, pool]
+    if pool_scale is not None:
+        in_specs.append(
+            pl.BlockSpec((1, 1, page_size),
+                         lambda b, p, pos_ref, table_ref:
+                         (table_ref[b, p], 0, 0)))
+        operands.append(pool_scale.astype(jnp.float32).reshape(
+            num_pages, 1, page_size))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(batch, pages_per_row),
-        in_specs=[
-            pl.BlockSpec((1, page_size),
-                         lambda b, p, pos_ref, table_ref: (pos_ref[b], p)),
-            pl.BlockSpec((1, page_size, d),
-                         lambda b, p, pos_ref, table_ref:
-                         (table_ref[b, p], 0, 0)),
-            pl.BlockSpec((1, 1),
-                         lambda b, p, pos_ref, table_ref: (0, pos_ref[b])),
-            # the weight ROW's scale: scalar block, indexed like the bias
-            pl.BlockSpec((1, 1),
-                         lambda b, p, pos_ref, table_ref: (0, pos_ref[b])),
-            # the pool page's per-row scales: indexed like the page
-            pl.BlockSpec((1, page_size),
-                         lambda b, p, pos_ref, table_ref:
-                         (table_ref[b, p], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, d),
-                               lambda b, p, pos_ref, table_ref: (b, 0)),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, 1, d),
+                               lambda b, p, pos_ref, table_ref: (b, 0, 0)),
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
     )
-    kernel = functools.partial(_mix_kernel_q8, page_size=page_size,
-                               pages_per_row=pages_per_row)
-    return pl.pallas_call(
+    kernel = functools.partial(_mix_kernel, page_size=page_size,
+                               pages_per_row=pages_per_row,
+                               scaled=pool_scale is not None)
+    mixed = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((batch, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((batch, 1, d), jnp.float32),
         interpret=interpret,
-    )(pos.astype(jnp.int32), table.astype(jnp.int32), weights, pool, biases,
-      w_scale, pool_scale)
+    )(pos, table.astype(jnp.int32), *operands)
+    # biases come in as (n, 1) column vectors (ops/sgu.py layout)
+    return mixed[:, 0] + biases.astype(jnp.float32).reshape(n, 1)[pos]
 
 
 def _xla_mix(weights, biases, pool, table, pos, *, n_rows,
@@ -278,14 +232,8 @@ def paged_gate_mix(weights, biases, pool, table, pos, *, n_rows,
         raise ValueError(f"unknown paged gate impl: {impl!r}")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if w_scale is None and pool_scale is None:
-        # full precision keeps the ORIGINAL kernel: the bit-identity
-        # contract of the default path must not depend on all-ones
-        # multiplies optimizing away
-        return _pallas_mix(weights, biases, pool, table, pos,
-                           interpret=interpret)
-    return _pallas_mix_q8(weights, biases, pool, table, pos,
-                          w_scale, pool_scale, interpret=interpret)
+    return _pallas_mix(weights, biases, pool, table, pos, w_scale,
+                       pool_scale, interpret=interpret)
 
 
 def write_gate_row(pool, table, pos, gate, write_ok, scale=None):

@@ -34,43 +34,11 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-               check_vma=True):
-    """``jax.shard_map`` compat: older jax only ships the experimental API,
-    which spells partial-manual as ``auto`` (the complement of
-    ``axis_names``) and ``check_vma`` as ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        kw = {"check_vma": check_vma}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    auto = (frozenset(mesh.axis_names) - frozenset(axis_names)
-            if axis_names is not None else frozenset())
-    mapped = _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                 check_rep=check_vma and not auto, auto=auto)
-    # the experimental impl rule rejects eager partial-manual calls
-    # (``if auto: raise NotImplementedError``); staging through jit lowers
-    # them via GSPMD exactly as the modern API does
-    return jax.jit(mapped) if auto else mapped
-
-
-def _axis_size(axis_name: str) -> int:
-    """Static mesh-axis size from inside shard_map; ``jax.lax.axis_size``
-    only exists on newer jax, but ``psum`` of a unit constant folds to the
-    same static int everywhere."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def _left_halo(t, axis_name: str):
     """Send each shard's LAST window right; receive the left neighbour's
     (zeros at the leftmost shard).  ``t``: (..., W_local, wsz, D) ->
     (..., 1, wsz, D) halo window."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     last = t[..., -1:, :, :]
     if n == 1:
         return jnp.zeros_like(last)
@@ -123,7 +91,7 @@ def cp_local_attention(
         return local_attention(q_loc, k2, v2, window_size=wsz, scale=scale)
 
     spec = P(None, None, seq_axis, None)
-    return _shard_map(
+    return jax.shard_map(
         inner, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         axis_names=frozenset({seq_axis}), check_vma=True,
     )(q, k, v)
@@ -169,7 +137,7 @@ def sharded_pallas_local_attention(
     # check_vma=False: pallas_call's out_shape carries no varying-mesh-axes
     # metadata, which the vma checker requires; this shard_map is full-manual
     # so there is nothing for the checker to catch anyway.
-    return _shard_map(
+    return jax.shard_map(
         inner, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)
@@ -215,7 +183,7 @@ def sharded_pallas_spatial_gate(
     spec = P(batch_axes, None, d_axis)
     # check_vma=False for the same reason as sharded_pallas_local_attention:
     # pallas_call outputs carry no varying-mesh-axes metadata.
-    return _shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(spec, spec, P(), P()),
         out_specs=spec,
@@ -255,7 +223,7 @@ def cp_spatial_gate(
                            preferred_element_type=jnp.float32)
         return (mixed + b_loc).astype(gate_loc.dtype)
 
-    return _shard_map(
+    return jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(P(None, seq_axis, None), P(seq_axis, None), P(seq_axis, None)),

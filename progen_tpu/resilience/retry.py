@@ -1,7 +1,7 @@
 """Generic retry with exponential backoff — the I/O fault boundary.
 
 At pod scale, transient failure is the steady state: GCS returns 503s,
-the TPU tunnel drops mid-save, the coordination service takes a few
+a connection drops mid-save, the coordination service takes a few
 seconds to come up before ``jax.distributed.initialize`` can connect
 (GSPMD-scale training treats preemption and flaky storage as routine,
 arXiv 2105.04663 / 2204.06514).  Every storage/init seam in this stack —

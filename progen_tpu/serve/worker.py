@@ -42,10 +42,6 @@ import sys
 import time
 from collections import deque
 
-from progen_tpu.core.cache import honor_env_platforms
-
-honor_env_platforms()
-
 
 def make_spec(config, *, mixed_precision: bool = True, init_seed: int = 0,
               checkpoint_path: str | None = None, draft: str = "identity",

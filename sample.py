@@ -6,10 +6,6 @@ the cached scan sampler instead of O(L) full forwards.
 
 import click
 
-from progen_tpu.core.cache import honor_env_platforms
-
-honor_env_platforms()
-
 
 @click.command()
 @click.option("--seed", default=42)
@@ -145,18 +141,13 @@ honor_env_platforms()
 @click.option("--xprof_dir", default=None, metavar="DIR",
               help="record an xprof/TensorBoard profile of the decode "
                    "into this directory (view with tensorboard)")
-@click.option("--compile_cache", default=None, metavar="DIR",
-              help="JAX persistent compilation cache directory ('0' "
-                   "disables); overrides PROGEN_COMPILE_CACHE, default "
-                   "~/.cache/progen_tpu/xla")
 def main(seed, checkpoint_path, prime, top_k, temperature, num_samples,
          seq_len, mesh_spec, strategies, serve, embed_mode, infill, slots,
          chunk, paged, page_size, quantize_mode, serve_attempts,
          snapshot_path, aot_warmup,
          spec, spec_k, disagg, serve_procs, prefill_procs, replicas,
          autoscale, min_prefill, max_prefill, min_replicas, max_replicas,
-         swap_at, watchdog_timeout, statusz, trace, trace_out, xprof_dir,
-         compile_cache):
+         swap_at, watchdog_timeout, statusz, trace, trace_out, xprof_dir):
     import os
 
     import jax
@@ -165,9 +156,7 @@ def main(seed, checkpoint_path, prime, top_k, temperature, num_samples,
 
     from progen_tpu.core.cache import enable_compilation_cache
 
-    if compile_cache is not None:
-        os.environ["PROGEN_COMPILE_CACHE"] = compile_cache
-    enable_compilation_cache()  # the decode scan is minutes of compile
+    enable_compilation_cache()  # a second invocation reads its programs
 
     from progen_tpu.checkpoint import CheckpointStore, abstract_params_like
     from progen_tpu.core.precision import make_policy

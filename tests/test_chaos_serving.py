@@ -345,6 +345,56 @@ def test_pallas_failure_degrades_to_xla_fallback(trained):
     assert all(s == "ok" for _, s in got.values())
 
 
+def test_compile_refusal_of_paged_kernel_reaches_caller(trained,
+                                                        monkeypatch):
+    """An error raised while the paged chunk program compiles (its first
+    dispatch) is the caller's to see: not contained, not answered with
+    the XLA fallback — on the chip that fallback would hide a kernel the
+    compiler refuses behind one ``fallback_activations`` count."""
+    _, params, policy = trained
+    eng = ServingEngine(CFG, params, policy=policy, num_slots=2,
+                        chunk_size=4, max_len=20, paged=True, page_size=4,
+                        paged_impl="pallas")
+
+    def refuse(*a, **k):
+        raise ValueError("Mosaic failed to compile TPU kernel: block "
+                         "shape (1, 16) not divisible by (8, 128)")
+
+    monkeypatch.setattr(eng, "_decode_chunk", refuse)
+    for r in _mk_requests(2):
+        eng.submit(r)
+    with pytest.raises(ValueError, match="Mosaic failed to compile"):
+        eng.run_until_idle(max_chunks=50)
+    assert eng.robust.fallback_activations == 0
+    assert eng.paged_impl == "pallas"
+
+
+def test_runtime_failure_of_paged_kernel_after_first_run_degrades(
+        trained, monkeypatch):
+    """The other side of that rule: once the chunk program has run, a
+    fatal fault of it that nobody injected still degrades to the XLA
+    fallback, and the tokens do not change."""
+    _, params, policy = trained
+    kw = dict(num_slots=2, chunk_size=4, max_len=20, paged=True,
+              page_size=4)
+    _, want = _run_engine(params, policy, _mk_requests(2), **kw)
+    eng = ServingEngine(CFG, params, policy=policy, paged_impl="pallas",
+                        **kw)
+    for r in _mk_requests(2):
+        eng.submit(r)
+    done = eng.step()  # the chunk program compiles and runs once
+    assert ("chunk",) in eng._compiled_keys
+
+    def halted(*a, **k):
+        raise ValueError("device halted")
+
+    monkeypatch.setattr(eng, "_decode_chunk", halted)
+    done += eng.run_until_idle(max_chunks=50)
+    assert eng.robust.fallback_activations == 1
+    assert eng.paged_impl == "xla"
+    assert {c.uid: (c.tokens.tolist(), c.status) for c in done} == want
+
+
 # ------------------------------------------------------- warmup / watchdog
 
 

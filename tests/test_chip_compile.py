@@ -1,0 +1,160 @@
+"""Compile the main path's Pallas kernels for a described TPU v5e chip.
+
+Every other test of these kernels runs them under the Pallas interpreter,
+which accepts block shapes and memory footprints the chip's compiler
+refuses.  The TPU compiler is installed in the CPU sandbox and compiles
+for a chip that is described, not attached — nothing runs, so this file
+says nothing about results or speed (``chip_smoke.py`` does, on the
+chip); it says that the compiler takes each kernel, ``interpret=False``,
+at ProGen-small and ProGen-base widths.
+
+The topology is described inside a module-scoped fixture of THIS file and
+nowhere else: only one process may load the TPU library, so the call must
+not happen while any module is imported, and these tests must stay in one
+file (a second file can land on another xdist worker, where its fixture
+would skip).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from progen_tpu.models.configs import BASE, SMALL
+
+# batch sizes are the ones the configs are run at on one chip; they do
+# not enter any block shape
+WIDTHS = {"small": (SMALL, 8), "base": (BASE, 2)}
+PAGE_SIZE = 16  # the engine's default
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def shape(topo):
+    """``shape(dims, dtype)`` -> abstract array placed on one described
+    chip (there is no device to hold a real one)."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    return make
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip; keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _assert_kernel_compiles(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "kernel did not lower to Mosaic"
+
+
+def _grad_of(fn, nargs):
+    return jax.grad(lambda *a: fn(*a).astype(jnp.float32).sum(),
+                    argnums=tuple(range(nargs)))
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("width", list(WIDTHS))
+def test_windowed_attention_compiles_for_v5e(shape, no_persistent_cache,
+                                             width, direction):
+    from progen_tpu.ops.pallas_attention import pallas_local_attention
+
+    cfg, batch = WIDTHS[width]
+    q = shape((batch, cfg.heads, cfg.seq_len, cfg.dim_head), jnp.bfloat16)
+
+    def fn(q, k, v):
+        return pallas_local_attention(q, k, v, cfg.window_size,
+                                      interpret=False)
+
+    _assert_kernel_compiles(fn if direction == "fwd" else _grad_of(fn, 3),
+                            q, q, q)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("width", list(WIDTHS))
+def test_blocked_sgu_compiles_for_v5e(shape, no_persistent_cache, width,
+                                      direction):
+    from progen_tpu.ops.pallas_sgu import pallas_spatial_gate
+
+    cfg, batch = WIDTHS[width]
+    n, d = cfg.seq_len, cfg.dim * cfg.ff_mult // 2
+    x = shape((batch, n, d), jnp.bfloat16)
+    w = shape((n, n), jnp.bfloat16)
+    b = shape((n, 1), jnp.bfloat16)
+
+    def fn(res, gate, w, b):
+        return pallas_spatial_gate(res, gate, w, b, interpret=False)
+
+    _assert_kernel_compiles(fn if direction == "fwd" else _grad_of(fn, 4),
+                            x, x, w, b)
+
+
+def test_short_sgu_pads_to_chip_tiles(shape, no_persistent_cache):
+    """A prefill shorter than two tiles still compiles: it pads up to the
+    128-wide tiles instead of taking one the compiler refuses."""
+    from progen_tpu.ops.pallas_sgu import pallas_spatial_gate
+
+    x = shape((2, 96, 2048), jnp.bfloat16)
+    _assert_kernel_compiles(
+        lambda r, g, w, b: pallas_spatial_gate(r, g, w, b, interpret=False),
+        x, x, shape((96, 96), jnp.bfloat16), shape((96, 1), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("gate_pages", ["bf16", "q8"])
+@pytest.mark.parametrize("width", list(WIDTHS))
+def test_paged_gate_mix_compiles_for_v5e(shape, no_persistent_cache, width,
+                                         gate_pages):
+    """The decode-side kernel has no backward.  ``q8`` is int8 weights +
+    int8 gate pages with their scale operands."""
+    from progen_tpu.ops.pallas_paged_attention import paged_gate_mix
+
+    cfg, _ = WIDTHS[width]
+    n, d, batch = cfg.seq_len, cfg.dim * cfg.ff_mult // 2, 8
+    pages_per_row = n // PAGE_SIZE
+    num_pages = 2 + batch * pages_per_row
+    table = shape((batch, pages_per_row), jnp.int32)
+    pos = shape((batch,), jnp.int32)
+    biases = shape((n, 1), jnp.float32)
+    if gate_pages == "bf16":
+        def fn(w, b, pool, table, pos):
+            return paged_gate_mix(w, b, pool, table, pos, n_rows=n,
+                                  impl="pallas", interpret=False)
+
+        args = (shape((n, n), jnp.float32), biases,
+                shape((num_pages, PAGE_SIZE, d), jnp.bfloat16), table, pos)
+    else:
+        def fn(w, b, pool, table, pos, w_scale, pool_scale):
+            return paged_gate_mix(w, b, pool, table, pos, n_rows=n,
+                                  impl="pallas", interpret=False,
+                                  w_scale=w_scale, pool_scale=pool_scale)
+
+        args = (shape((n, n), jnp.int8), biases,
+                shape((num_pages, PAGE_SIZE, d), jnp.int8), table, pos,
+                shape((n,), jnp.float32),
+                shape((num_pages, PAGE_SIZE), jnp.float32))
+    _assert_kernel_compiles(fn, *args)

@@ -1,7 +1,7 @@
 """Multi-host smoke tests: two real ``jax.distributed`` CPU processes run
 the actual Trainer and must agree with a single-process run.
 
-Verifies, end to end (VERDICT r1 item 7):
+Verifies, end to end:
 
 * ``jax.distributed.initialize`` + a mesh spanning both processes;
 * per-host data sharding (round-robin record split) feeds each host

@@ -651,3 +651,45 @@ def test_cluster_kill_prefill_worker_sheds_typed(tmp_path):
         if not c.ok:
             assert c.status == "failed_fault"
     assert stats["supervision"]["denied"] >= 1
+
+
+# ------------------------------------------------------ platform placement
+
+
+def test_worker_env_carries_no_platform_default(monkeypatch):
+    """A worker runs where its parent runs: the cluster hands down
+    ``JAX_PLATFORMS`` only when the caller's environment has it, and
+    never defaults it to the CPU (on the chip machine that default put
+    the workers on the CPU under the parent's "tpu" stamp)."""
+    c = _bare_cluster()
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    env = c._worker_env()
+    assert "JAX_PLATFORMS" not in env
+    # single-device runtime + repo on the path are still arranged
+    assert "--xla_force_host_platform_device_count=1" in env["XLA_FLAGS"]
+    assert env["PYTHONPATH"]
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert c._worker_env()["JAX_PLATFORMS"] == "cpu"
+
+
+def test_cluster_refuses_worker_processes_on_tpu(monkeypatch):
+    """On a TPU the parent that asked JAX for its devices holds every
+    chip, so no worker process can start: the cluster refuses up front
+    with the counts, instead of hanging in the spawn wait."""
+    import types
+
+    import jax
+
+    from progen_tpu.serve import cluster as cl
+
+    chips = [types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+             for _ in range(4)]
+    monkeypatch.setattr(jax, "local_devices", lambda: chips)
+    with pytest.raises(RuntimeError) as exc:
+        cl.ServeCluster({}, prefill_procs=2, replicas=3)
+    msg = str(exc.value)
+    assert "5 worker process(es)" in msg and "4 chip(s)" in msg
+    assert "0 workers can start" in msg
+    # on the CPU (what JAX gives this test process) nothing is refused
+    monkeypatch.undo()
+    cl._refuse_unplaceable_workers(5)

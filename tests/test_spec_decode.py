@@ -10,7 +10,6 @@ admission order changes, tokens must not.  Both compose with the fault
 plan / snapshot / replay machinery from the resilience work.
 """
 
-import json
 import pathlib
 import warnings
 
@@ -405,24 +404,23 @@ def test_chaos_prefill_worker_fault_sheds_batch(trained, clean):
 
 
 def test_bench_ladder_survives_backend_crash(monkeypatch, capsys):
-    """Regression: a backend that probes OK but dies at first in-process
-    use (TPU claimed between probe and use) inside the LADDER branch must
-    emit the structured error record and exit rc 0, not traceback."""
+    """Turned round (PR 21): a backend that dies at first in-process use
+    makes ``bench.main()`` RAISE — the caller sees the traceback and a
+    non-zero exit, and no record is printed under rc 0."""
     import bench
 
     def boom():
         raise RuntimeError("backend init failed: device busy")
 
-    monkeypatch.setattr(bench, "_probe_backend", lambda: True)
-    monkeypatch.setattr(bench.jax, "device_count", boom)
+    # the persistent cache stays off inside the 8-virtual-device pytest
+    # process (.claude/skills/verify/SKILL.md)
+    monkeypatch.setattr(bench, "enable_compilation_cache", lambda: None)
+    monkeypatch.setattr(bench.jax, "devices", boom)
     monkeypatch.setenv("PROGEN_BENCH_CONFIGS", "small,base")
     monkeypatch.setattr("sys.argv", ["bench.py"])
-    bench.main()  # must not raise
-    lines = [ln for ln in capsys.readouterr().out.splitlines() if ln]
-    rec = json.loads(lines[-1])
-    assert "backend init failed" in rec["error"]
-    assert rec["metric"] is None
-    assert "git_sha" in rec
+    with pytest.raises(RuntimeError, match="backend init failed"):
+        bench.main()
+    assert not capsys.readouterr().out.strip()
 
 
 def test_bench_records_carry_git_sha():

@@ -76,7 +76,7 @@ def test_train_checkpoint_resume_sample(data_dir, tmp_path):
 
 
 def test_ragged_corpus_through_sharded_trainer(tmp_path, devices8):
-    """VERDICT r1 missing #1 / next #6: a corpus with N % batch != 0 must
+    """A corpus with N % batch != 0 must
     stream through the MESH-SHARDED trainer across epoch boundaries with
     no shape retrace (which would be a hard divisibility crash under the
     ('data','fsdp')-sharded batch)."""
