@@ -43,7 +43,7 @@ class Zone:
 HOT_ZONES: tuple[Zone, ...] = (
     Zone(
         r"train/trainer\.py$",
-        r"Trainer\.(_run_loop|_run_loop_superstep|evaluate|_note_phase"
+        r"Trainer\.(_run_loop|_run_loop_superstep|evaluate|_phase"
         r"|_publish_train_health|_statusz_health|_statusz_status)$",
         frozenset({"meter", "tracker", "config", "model_config", "store",
                    "_recorder", "_tracer", "lr_schedule", "cfg",
@@ -61,7 +61,9 @@ HOT_ZONES: tuple[Zone, ...] = (
         r"|_dispatch_chunk|_fail_inflight|_activate_xla_fallback"
         r"|_drain_pending|robustness_counters|_prefill_round"
         r"|_admit_from_handoff|_prefill_worker_call|_merge_call"
-        r"|admit_handle|run_prefill_round|drain_sheds|_note_stage"
+        r"|admit_handle|run_prefill_round|drain_sheds|_span|_record_stage"
+        r"|_close_stages|_note_admitted"
+        r"|_build_dense_admission|_build_paged_admission"
         r"|submit_embed|_embed_round|run_embed_round|embed_pending"
         r"|_build_lmask|status|_maybe_preempt|_preempt_slot|qos_status"
         r"|_publish_qos_gauges|submit_fork|_release_forks|forget_ttft"
@@ -79,7 +81,10 @@ HOT_ZONES: tuple[Zone, ...] = (
                    "_spec_rounds", "remote_prefill", "stage_seconds",
                    "_tracer", "_stage_hist", "_embed_queue", "lora",
                    "qos_weights", "_qos_gauge_keys", "prefix_lookups",
-                   "fork_groups", "_fork_wait", "_ttft"}),
+                   "fork_groups", "_fork_wait", "_ttft", "_admitted",
+                   "_open_stages", "_step_no",
+                   "_step_wait", "_queue_wait_hist", "_ttft_hist",
+                   "_step_host_hist"}),
         # requests, admission rows and snapshots are host payloads by API
         # contract: numpy masks, python ints, JSON-safe dicts — never
         # device arrays

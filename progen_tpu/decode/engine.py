@@ -240,20 +240,33 @@ class Completion:
     # weight generation that primed the request — the serving control
     # plane bumps this on swap_weights; 0 for a never-swapped engine
     generation: int = 0
-    # instant the request's FIRST generated token existed (admission
-    # dispatch returned) — None for sheds and embed completions.  The
-    # cluster rewrites this onto the driver clock so ``ttft`` is
-    # end-to-end (queue + prefill + transport + merge) fleet-wide.
+    # instant the request's FIRST generated token was known to exist: the
+    # return of the first host fetch of the slot flags after its admission
+    # dispatch (a dispatch returns before the device has run) — None for
+    # sheds and embed completions.  The cluster rewrites this onto the
+    # driver clock so ``ttft`` is end-to-end (queue + prefill + transport
+    # + merge) fleet-wide.
     first_token_time: float | None = None
     # latency as measured on the WORKER's clock (submit→finish inside
     # the remote engine); 0.0 for local completions, where ``latency``
     # already is that number.  The difference vs ``latency`` is the
     # transport + merge overhead the fleet adds on top of the engine.
     worker_latency: float = 0.0
+    # instant the request left the queue (the start of the admission
+    # round that took it; the earliest across evict/replay) — None for a
+    # request shed before any admission
+    admit_time: float | None = None
 
     @property
     def latency(self) -> float:
         return self.finish_time - self.submit_time
+
+    @property
+    def queue_wait(self) -> float | None:
+        """Seconds between submission and leaving the queue."""
+        if self.admit_time is None:
+            return None
+        return self.admit_time - self.submit_time
 
     @property
     def ttft(self) -> float | None:
@@ -369,6 +382,8 @@ class ServingEngine:
         self._fork_wait: dict[Any, list[Request]] = {}
         self.fork_groups = 0
         self._ttft: dict[Any, float] = {}
+        # request timeline, host clock: when each uid left the queue
+        self._admitted: dict[Any, float] = {}
         # admission recency (slot -> monotone seq) across ALL modes: the
         # preemption and pool-starvation paths evict youngest-first
         self._admit_seq = 0
@@ -388,13 +403,16 @@ class ServingEngine:
         self._aot: dict[tuple, Any] = {}       # AOT-compiled executables
         self._compiled_keys: set[tuple] = set()
         self._defer_streak: dict[str, int] = {}
-        # dispatch wall per stage (perf_counter deltas around the guarded
-        # device calls) — multi-process bench records prove prefill wall
-        # LEAVES the decode process (its prefill_s stays 0.0)
+        # wall per stage, from the start of its dispatch to the return of
+        # the host fetch that shows its work done (docs/OBSERVABILITY.md
+        # lists the one stage that is dispatch-only) — multi-process bench
+        # records prove prefill wall LEAVES the decode process (its
+        # prefill_s stays 0.0)
         self.stage_seconds = {"prefill_s": 0.0, "merge_s": 0.0,
                               "decode_chunk_s": 0.0, "embed_s": 0.0}
-        # the same deltas feed the process tracer (no-op unless enabled)
-        # and the shared metrics registry's per-stage latency histograms
+        # the same durations feed the shared metrics registry's per-stage
+        # histograms; spans go through the process tracer (profiler
+        # annotation always, ring when enabled)
         self._tracer = _obs_trace.get_tracer()
         registry = _metrics.get_registry()
         self._stage_hist = {
@@ -403,6 +421,17 @@ class ServingEngine:
             "decode_chunk_s": registry.histogram("engine.decode_chunk_s"),
             "embed_s": registry.histogram("engine.embed_s"),
         }
+        self._queue_wait_hist = registry.histogram("engine.queue_wait_s")
+        self._ttft_hist = registry.histogram("engine.ttft_s")
+        self._step_host_hist = registry.histogram("engine.step_host_s")
+        # stages dispatched and not yet known done, ``(stage, kind, t0,
+        # requests)``: a dispatch returns before the device has run, so
+        # the next fetch of the slot flags closes them (_close_stages).
+        # ``kind`` is "admit" (the requests' first tokens exist once it
+        # has run) or "chunk"
+        self._open_stages: list[tuple] = []
+        self._step_no = 0       # step() calls so far: ``step=`` on spans
+        self._step_wait = 0.0   # seconds of this step() inside that fetch
 
         if params_shardings is not None:
             params = jax.device_put(params, {"params": params_shardings})
@@ -608,15 +637,44 @@ class ServingEngine:
 
     # ------------------------------------------------------ fault containment
 
-    def _note_stage(self, stage: str, span: str, t0: float, **args) -> None:
-        """Fold one guarded device dispatch into every observability
-        surface at once: ``stage_seconds`` (the legacy per-stage wall),
-        the shared metrics histogram, and the trace ring (a no-op span
-        unless tracing is enabled)."""
-        dt = time.perf_counter() - t0
+    def _span(self, name: str, **args):
+        """The one span call: profiler annotation and (when enabled) ring,
+        tagged with the ``step()`` it belongs to."""
+        return self._tracer.span(name, step=self._step_no, **args)
+
+    def _record_stage(self, stage: str, dt: float) -> None:
         self.stage_seconds[stage] += dt
         self._stage_hist[stage].observe(dt)
-        self._tracer.add(span, t0, dt, **args)
+
+    def _close_stages(self, now: float) -> None:
+        """The flags fetch returned at ``now``: every dispatched stage has
+        run.  Each gets the wall from its dispatch to the next stage's (the
+        device runs them in order), the last one to ``now``; the ring gets
+        ``serve.<kind>_work`` with those instants, and the requests of an
+        admission their first-token instant."""
+        stages, self._open_stages = self._open_stages, []
+        ends = [t0 for _, _, t0, _ in stages[1:]] + [now]
+        for (stage, kind, t0, batch), end in zip(stages, ends):
+            self._record_stage(stage, end - t0)
+            if self._tracer.enabled:
+                self._tracer.add(f"serve.{kind}_work", t0, end - t0,
+                                 step=self._step_no, stage=stage,
+                                 uids=[r.uid for r in batch])
+            if kind != "admit":
+                continue
+            for r in batch:
+                # the earliest stamp survives an evict/replay round trip
+                if r.uid not in self._ttft:
+                    self._ttft[r.uid] = now
+                    self._ttft_hist.observe(now - r.submit_time)
+
+    def _note_admitted(self, batch, now: float) -> None:
+        """``batch`` left the queue at ``now`` (earliest wins across
+        evict/replay, like ``_ttft``)."""
+        for r in batch:
+            if r.uid not in self._admitted:
+                self._admitted[r.uid] = now
+                self._queue_wait_hist.observe(now - r.submit_time)
 
     def _guard(self, point: str, fn: Callable | None = None, *args,
                key: tuple | None = None):
@@ -1409,11 +1467,12 @@ class ServingEngine:
         return [f.uid for f in forks]
 
     def forget_ttft(self, uids) -> None:
-        """Drop first-token stamps for requests that leave this engine
+        """Drop the timeline stamps of requests that leave this engine
         for another process (prefill workers hand off and never harvest
         locally), so the stamp map cannot grow without bound."""
         for u in uids:
             self._ttft.pop(u, None)
+            self._admitted.pop(u, None)
 
     def _release_forks(self) -> None:
         """Submit fork followers whose leader has left the queue (its
@@ -1479,7 +1538,8 @@ class ServingEngine:
             finish_reason=status, status=status,
             submit_time=r.submit_time, finish_time=time.perf_counter(),
             generation=self.generation,
-            first_token_time=self._ttft.pop(r.uid, None))
+            first_token_time=self._ttft.pop(r.uid, None),
+            admit_time=self._admitted.pop(r.uid, None))
         self.completions.append(comp)
         self._pending.append(comp)
         self._tracer.event("serve.shed", trace=r.uid, status=status)
@@ -1608,12 +1668,55 @@ class ServingEngine:
         return lmask
 
     def _admit_pending_dense(self) -> None:
+        # host work with the device idle: slots, host arrays, the mask
+        with self._span("serve.admit_build") as build:
+            built = self._build_dense_admission()
+            if built is None:
+                return
+            batch, args, p_pad = built
+            uids = [r.uid for _, r in batch]
+            build.note(uids=uids)
+
+        t0 = time.perf_counter()
+        try:
+            with self._span("serve.admit_prefill", uids=uids, p_pad=p_pad):
+                self.state = self._guard(
+                    "serve.prefill", self._admit_call, *args,
+                    key=("admit", p_pad))
+        except _ContainedFault:
+            # the batch's prefill never merged: undo the bookkeeping and
+            # shed exactly the requests whose work was lost
+            for slot, r in batch:
+                self._inflight.pop(slot, None)
+                self._shed(r, FAILED_FAULT)
+        except RetryError:
+            # escape for restart-and-replay, but leave the engine
+            # consistent: the un-prefilled batch goes back to the queue
+            # front in its original order
+            for slot, r in reversed(batch):
+                self._inflight.pop(slot, None)
+                self._queue.appendleft(r)
+            raise
+        else:
+            # the admit program samples each request's first token; that
+            # it has RUN is known at the next flags fetch, which stamps
+            # first-token time and closes the stage
+            self._open_stages.append(
+                ("prefill_s", "admit", t0, [r for _, r in batch]))
+
+    def _build_dense_admission(self):
+        """Take what fits out of the queue and fill the host arrays of one
+        dense admission round: ``(batch, arguments of the admit program
+        after params and state, prefill bucket)``, or None with nothing
+        to admit."""
+        t_build = time.perf_counter()
         free = [i for i in range(self.num_slots) if i not in self._inflight]
         if not free or not self._queue:
-            return
+            return None
         batch: list[tuple[int, Request]] = []
         while free and self._queue:
             batch.append((free.pop(0), self._queue.popleft()))
+        self._note_admitted([r for _, r in batch], t_build)
 
         s = self.num_slots
         longest = max(len(r.tokens) for _, r in batch)
@@ -1642,40 +1745,54 @@ class ServingEngine:
             self._admit_seq += 1
         lmask = self._build_lmask(batch)
         extra = (tenant,) if self.lora else ()
+        return batch, (tokens, lengths, stops, seeds, top_k, temp, mask,
+                       lmask, *extra), p_pad
+
+    def _admit_pending_paged(self) -> None:
+        with self._span("serve.admit_build") as build:
+            built = self._build_paged_admission()
+            if built is None:
+                return
+            batch, args, p_pad, pending_prefix = built
+            uids = [r.uid for _, r in batch]
+            build.note(uids=uids)
 
         t0 = time.perf_counter()
         try:
-            with jax.profiler.TraceAnnotation("serve.admit_prefill"):
+            with self._span("serve.admit_prefill", uids=uids, p_pad=p_pad):
                 self.state = self._guard(
-                    "serve.prefill", self._admit_call, tokens, lengths,
-                    stops, seeds, top_k, temp, mask, lmask, *extra,
+                    "serve.prefill", self._admit_call, *args,
                     key=("admit", p_pad))
-            self._note_stage("prefill_s", "serve.admit_prefill", t0,
-                             uids=[r.uid for _, r in batch], p_pad=p_pad)
         except _ContainedFault:
-            # the batch's prefill never merged: undo the bookkeeping and
-            # shed exactly the requests whose work was lost
+            # prefill never merged: the planned pages hold nothing — free
+            # them (no prefix registration was committed, so the index
+            # cannot serve a garbage page) and shed the batch
             for slot, r in batch:
                 self._inflight.pop(slot, None)
+                self._host_stop[slot] = 0
+                self._free_slot_pages(slot)
                 self._shed(r, FAILED_FAULT)
+            return
         except RetryError:
-            # escape for restart-and-replay, but leave the engine
-            # consistent: the un-prefilled batch goes back to the queue
-            # front in its original order
             for slot, r in reversed(batch):
                 self._inflight.pop(slot, None)
+                self._host_stop[slot] = 0
+                self._free_slot_pages(slot)
                 self._queue.appendleft(r)
             raise
-        else:
-            # the admit program samples each request's first token, so
-            # admission success IS first-token time; setdefault keeps the
-            # earliest stamp across evict/replay round-trips
-            now = time.perf_counter()
-            for _, r in batch:
-                self._ttft.setdefault(r.uid, now)
+        # prefill dispatched: NOW the freshly-filled full-prefix pages may
+        # be published for sharing
+        for key, pid in pending_prefix:
+            self._pool.register_prefix(key, pid)
+        self._open_stages.append(
+            ("prefill_s", "admit", t0, [r for _, r in batch]))
 
-    def _admit_pending_paged(self) -> None:
-        """FIFO admission gated by free slots AND free pages.
+    def _build_paged_admission(self):
+        """FIFO admission gated by free slots AND free pages: take what
+        fits, plan its pages and fill the host arrays.  Returns ``(batch,
+        arguments of the admit program after params and state, prefill
+        bucket, deferred prefix registrations)``, or None when nothing was
+        admitted (blocked head, or a contained planning fault).
 
         The head of the queue is admitted only if the pool can cover its
         whole prime plus the first sampled token WITHOUT prefix sharing
@@ -1689,6 +1806,7 @@ class ServingEngine:
         contract; pre-QoS FIFO deferral is the degenerate single-class
         case).
         """
+        t_build = time.perf_counter()
         free = [i for i in range(self.num_slots) if i not in self._inflight]
         batch: list[tuple[int, Request]] = []
         reserved = 0
@@ -1700,7 +1818,8 @@ class ServingEngine:
             reserved += need
             batch.append((free.pop(0), self._queue.popleft()))
         if not batch:
-            return
+            return None
+        self._note_admitted([r for _, r in batch], t_build)
 
         s = self.num_slots
         longest = max(len(r.tokens) for _, r in batch)
@@ -1755,7 +1874,7 @@ class ServingEngine:
             for r in reversed(innocents):
                 self._queue.appendleft(r)
             self._shed(batch[j][1], FAILED_FAULT)
-            return
+            return None
         except RetryError:
             j = len(planned)
             for slot, r in reversed(batch[: j + 1]):
@@ -1767,41 +1886,9 @@ class ServingEngine:
             raise
         lmask = self._build_lmask(batch)
         extra = (tenant,) if self.lora else ()
-
-        t0 = time.perf_counter()
-        try:
-            with jax.profiler.TraceAnnotation("serve.admit_prefill"):
-                self.state = self._guard(
-                    "serve.prefill", self._admit_call, tokens, lengths,
-                    stops, seeds, top_k, temp, mask, lmask,
-                    self._page_table.copy(), wtable, *extra,
-                    key=("admit", p_pad))
-            self._note_stage("prefill_s", "serve.admit_prefill", t0,
-                             uids=[r.uid for _, r in batch], p_pad=p_pad)
-        except _ContainedFault:
-            # prefill never merged: the planned pages hold nothing — free
-            # them (no prefix registration was committed, so the index
-            # cannot serve a garbage page) and shed the batch
-            for slot, r in batch:
-                self._inflight.pop(slot, None)
-                self._host_stop[slot] = 0
-                self._free_slot_pages(slot)
-                self._shed(r, FAILED_FAULT)
-            return
-        except RetryError:
-            for slot, r in reversed(batch):
-                self._inflight.pop(slot, None)
-                self._host_stop[slot] = 0
-                self._free_slot_pages(slot)
-                self._queue.appendleft(r)
-            raise
-        # prefill landed: NOW the freshly-filled full-prefix pages may be
-        # published for sharing
-        for key, pid in pending_prefix:
-            self._pool.register_prefix(key, pid)
-        now = time.perf_counter()
-        for _, r in batch:
-            self._ttft.setdefault(r.uid, now)
+        return batch, (tokens, lengths, stops, seeds, top_k, temp, mask,
+                       lmask, self._page_table.copy(), wtable,
+                       *extra), p_pad, pending_prefix
 
     # ---------------------------------------------------------- embeddings
 
@@ -1837,12 +1924,14 @@ class ServingEngine:
             lengths[row] = len(t)
         t0 = time.perf_counter()
         try:
-            with jax.profiler.TraceAnnotation("serve.embed"):
+            # the span and the stage end at the fetch of the vectors
+            with self._span("serve.embed", uids=[r.uid for r in batch],
+                            p_pad=p_pad):
                 vecs = self._guard(
                     "serve.embed", self._embed_call, tokens, lengths,
                     key=("embed", p_pad))
-            self._note_stage("embed_s", "serve.embed", t0,
-                             uids=[r.uid for r in batch], p_pad=p_pad)
+                vecs = np.asarray(jax.device_get(
+                    vecs))
         except _ContainedFault:
             for r in batch:
                 self._shed(r, FAILED_FAULT)
@@ -1851,9 +1940,8 @@ class ServingEngine:
             for r in reversed(batch):
                 self._embed_queue.appendleft(r)
             raise
-        vecs = np.asarray(jax.device_get(
-            vecs))
         now = time.perf_counter()
+        self._record_stage("embed_s", now - t0)
         for row, r in enumerate(batch):
             comp = Completion(
                 uid=r.uid, prime=np.asarray(r.tokens, np.int32),
@@ -1884,12 +1972,14 @@ class ServingEngine:
         cfg = self.config
         p_pad = pad_prime_length(len(self._queue[0].tokens),
                                  cfg.window_size, cfg.seq_len, bucket=True)
+        t_build = time.perf_counter()
         batch: list[Request] = []
         while (self._queue and len(batch) < self.prefill_batch
                and pad_prime_length(len(self._queue[0].tokens),
                                     cfg.window_size, cfg.seq_len,
                                     bucket=True) == p_pad):
             batch.append(self._queue.popleft())
+        self._note_admitted(batch, t_build)
 
         s = self.num_slots
         tokens = np.zeros((s, p_pad), np.int32)
@@ -1913,13 +2003,12 @@ class ServingEngine:
         extra = (tenant,) if self.lora else ()
         t0 = time.perf_counter()
         try:
-            with jax.profiler.TraceAnnotation("serve.prefill"):
+            with self._span("serve.prefill", uids=[r.uid for r in batch],
+                            p_pad=p_pad):
                 h = self._guard(
                     "serve.prefill", self._prefill_worker_call, tokens,
                     lengths, stops, seeds, top_k, temp, lmask, *extra,
                     key=("prefill", p_pad))
-            self._note_stage("prefill_s", "serve.prefill", t0,
-                             uids=[r.uid for r in batch], p_pad=p_pad)
         except _ContainedFault:
             for r in batch:
                 self._shed(r, FAILED_FAULT)
@@ -1928,12 +2017,13 @@ class ServingEngine:
             for r in reversed(batch):
                 self._queue.appendleft(r)
             raise
-        # the prefill worker samples each request's first token, so the
-        # handle landing IS first-token time (the decode-side merge only
-        # moves already-sampled state into slots)
-        now = time.perf_counter()
-        for r in batch:
-            self._ttft.setdefault(r.uid, now)
+        # DISPATCH-ONLY: nothing in this round fetches the handle (a
+        # prefill worker process serializes it on its transport thread),
+        # so this is the one stage time that does not end at a fetch.  The
+        # worker samples each request's first token; when it existed is
+        # known where the handle is next read: at the flags fetch after
+        # the decode-side merge here, on the driver's clock in a cluster
+        self._record_stage("prefill_s", time.perf_counter() - t0)
         self._handoff.put(Handle(requests=batch, state=h, p_pad=p_pad))
 
     def _admit_from_handoff(self) -> None:
@@ -1966,6 +2056,10 @@ class ServingEngine:
             # admission is committed — ownership continues in ``h``
             # graftcheck: disable=resource-leak
             self._handoff.get()
+            # a remote-prefill handle's requests were never in this
+            # engine's queue: they are admitted here (a local prefill
+            # round's earlier stamp stands)
+            self._note_admitted([r for _, r in live_rows], now)
             if live_rows:
                 src = np.zeros((self.num_slots,), np.int32)
                 mask = np.zeros((self.num_slots,), bool)
@@ -2005,13 +2099,11 @@ class ServingEngine:
                     # retry/requeue-safe because faults.inject raises
                     # BEFORE the jitted program dispatches — a contained
                     # or transient failure here has not consumed them
-                    with jax.profiler.TraceAnnotation("serve.merge"):
+                    with self._span("serve.merge",
+                                    uids=[r.uid for _, r in live_rows]):
                         self.state = self._guard(
                             "serve.handoff", self._merge_call, h.state,
                             src, mask, *extra, key=("merge",))
-                    self._note_stage(
-                        "merge_s", "serve.merge", t0,
-                        uids=[r.uid for _, r in live_rows])
                 except _ContainedFault:
                     for slot, r in placed:
                         self._inflight.pop(slot, None)
@@ -2032,13 +2124,10 @@ class ServingEngine:
                 else:
                     for key, pid in pending_prefix:
                         self._pool.register_prefix(key, pid)
-                    # remote-prefill handles never passed through this
-                    # engine's _prefill_round; their first token lands
-                    # here (setdefault keeps the local prefill stamp on
-                    # the inline disagg path)
-                    merged = time.perf_counter()
-                    for _, r in live_rows:
-                        self._ttft.setdefault(r.uid, merged)
+                    # the first tokens came with the handle; the next
+                    # flags fetch shows the merged state and stamps them
+                    self._open_stages.append(
+                        ("merge_s", "admit", t0, [r for _, r in live_rows]))
             for r in expired:
                 self._shed(r, SHED_DEADLINE)
 
@@ -2176,41 +2265,51 @@ class ServingEngine:
             self._defer("harvest", e)
             return []
         self._defer_streak.pop("harvest", None)
-        t0 = time.perf_counter()
         # two-phase fetch: one small transfer of the per-slot flags gates
         # the call (the common case is "nothing finished"); the big seq
-        # buffer only crosses the wire when some slot actually completed
-        done, active = _host_fetch(
-            (self.state["done"], self.state["active"]))
+        # buffer only crosses the wire when some slot actually completed.
+        # The flags fetch is the engine's one sync point: it returns when
+        # every program dispatched before it has run, so the open stages
+        # and the first-token stamps of their requests close here
+        after = self._open_stages[-1][1] if self._open_stages else "idle"
+        t0 = time.perf_counter()
+        with self._span("serve.device_wait", after=after):
+            done, active = _host_fetch(
+                (self.state["done"], self.state["active"]))
+        now = time.perf_counter()
+        self._step_wait += now - t0
+        self._close_stages(now)
         ready = [i for i in range(self.num_slots)
                  if done[i] and active[i] and i in self._inflight]
         if not ready:
             return []
-        seq, pos, start = _host_fetch(
-            (self.state["seq"], self.state["pos"], self.state["start"]))
-        out = []
-        now = time.perf_counter()
-        act = self.state["active"]
-        for i in ready:
-            r = self._inflight.pop(i)
-            if self.paged:
-                self._free_slot_pages(i)
-            toks = seq[i, start[i]: pos[i] + 1].copy()
-            reason = "eos" if (toks.size and toks[-1] == EOS_ID) else "length"
-            comp = Completion(
-                uid=r.uid, prime=np.asarray(r.tokens, np.int32),
-                tokens=toks, finish_reason=reason,
-                submit_time=r.submit_time, finish_time=now,
-                generation=self.generation,
-                first_token_time=self._ttft.pop(r.uid, None))
-            out.append(comp)
-            if r.on_complete is not None:
-                r.on_complete(comp)
-            act = act.at[i].set(False)
-        self.state = {**self.state, "active": act}
-        self.completions.extend(out)
-        self._tracer.add("serve.harvest", t0, time.perf_counter() - t0,
-                         uids=[c.uid for c in out])
+        with self._span("serve.harvest") as harvest:
+            seq, pos, start = _host_fetch(
+                (self.state["seq"], self.state["pos"], self.state["start"]))
+            out = []
+            now = time.perf_counter()
+            act = self.state["active"]
+            for i in ready:
+                r = self._inflight.pop(i)
+                if self.paged:
+                    self._free_slot_pages(i)
+                toks = seq[i, start[i]: pos[i] + 1].copy()
+                reason = ("eos" if (toks.size and toks[-1] == EOS_ID)
+                          else "length")
+                comp = Completion(
+                    uid=r.uid, prime=np.asarray(r.tokens, np.int32),
+                    tokens=toks, finish_reason=reason,
+                    submit_time=r.submit_time, finish_time=now,
+                    generation=self.generation,
+                    first_token_time=self._ttft.pop(r.uid, None),
+                    admit_time=self._admitted.pop(r.uid, None))
+                out.append(comp)
+                if r.on_complete is not None:
+                    r.on_complete(comp)
+                act = act.at[i].set(False)
+            self.state = {**self.state, "active": act}
+            self.completions.extend(out)
+            harvest.note(uids=[c.uid for c in out])
         return out
 
     def _dispatch_chunk(self) -> None:
@@ -2236,12 +2335,13 @@ class ServingEngine:
         while True:
             t0 = time.perf_counter()
             try:
-                with jax.profiler.TraceAnnotation("serve.decode_chunk"):
+                batch = list(self._inflight.values())
+                with self._span("serve.decode_chunk",
+                                uids=[r.uid for r in batch]):
                     out = self._guard(point, self._chunk_call, *args,
                                       key=("chunk",))
-                self._note_stage(
-                    "decode_chunk_s", "serve.decode_chunk", t0,
-                    uids=[r.uid for r in self._inflight.values()])
+                self._open_stages.append(
+                    ("decode_chunk_s", "chunk", t0, batch))
                 if self.spec:
                     out, stats = out
                     # lazy device-side accumulation — spec_counters()
@@ -2287,6 +2387,10 @@ class ServingEngine:
         into free slots, decode one chunk, harvest newly finished slots.
         The return includes typed SHED completions recorded since the
         last step (e.g. queue-full sheds from ``submit()``)."""
+        t_step = time.perf_counter()
+        self._step_no += 1
+        self._step_wait = 0.0
+        chunks_before = self.chunks_run
         completed = self._drain_pending()
         if self._watchdog is not None:
             self._watchdog.beat("serve.step")
@@ -2327,6 +2431,11 @@ class ServingEngine:
         self.qos_status()
         if self.paged:
             self._publish_cache_gauges()
+        if self.chunks_run > chunks_before:
+            # the host's self time in a step that ran a chunk: its wall
+            # less what it spent waiting for the device in the flags fetch
+            self._step_host_hist.observe(
+                time.perf_counter() - t_step - self._step_wait)
         return completed
 
     # ----------------------------------------- multi-process handoff API

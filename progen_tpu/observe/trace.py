@@ -15,11 +15,19 @@ Trace ids are the request uids: a span either carries ``trace=<uid>``
 (per-request work) or ``uids=[...]`` in its args (batch-level work such as
 a prefill round).  ``spans_for`` finds both.
 
-Disabled (the default) costs one attribute check per call: ``span()``
-returns a shared no-op context manager and ``add()``/``event()`` return
-before allocating the record.  This file is deliberately pure stdlib —
+One span call, two sinks: ``Tracer.span()`` opens the profiler's
+annotation of the same name (``Tracer.annotation``; the process tracer's
+is ``jax.profiler.TraceAnnotation``, so a device trace shows the program's
+spans on its own clock) whether or not the ring is enabled, and records
+the span in the ring when it is.  ``add()`` records an already-timed
+span in the ring alone: an annotation cannot be back-dated.
+
+Disabled (the default) the ring costs one attribute check per call: a
+tracer with no annotation returns a shared no-op context manager from
+``span()``, and ``add()``/``event()`` return before allocating the
+record.  This file imports nothing but the stdlib —
 ``resilience/watchdog.py`` dumps the ring on a trip and must not pull in
-jax to do it.
+jax to do it; the annotation is imported at the first ``span()``.
 """
 
 import json
@@ -55,14 +63,28 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def note(self, **args):
+        pass
+
 
 _NOOP_SPAN = _NoopSpan()
 
 
-class _Span:
-    """Live timing context: stamps perf_counter on enter, records on exit."""
+def _jax_annotation(name):
+    """The profiler's annotation (a TraceMe: nothing measurable when no
+    profile is running).  Imported here, at the first span of a process
+    that dispatches to a device, and not with this module."""
+    from jax.profiler import TraceAnnotation
 
-    __slots__ = ("_tracer", "_name", "_trace", "_args", "_t0")
+    return TraceAnnotation(name)
+
+
+class _Span:
+    """Live timing context: enters the annotation and stamps perf_counter
+    on enter; on exit records in the ring (when enabled) and leaves the
+    annotation, so the ring's extent lies inside the profiler's."""
+
+    __slots__ = ("_tracer", "_name", "_trace", "_args", "_t0", "_note")
 
     def __init__(self, tracer, name, trace, args):
         self._tracer = tracer
@@ -71,6 +93,10 @@ class _Span:
         self._args = args
 
     def __enter__(self):
+        annotation = self._tracer.annotation
+        self._note = annotation(self._name) if annotation else None
+        if self._note is not None:
+            self._note.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -78,7 +104,13 @@ class _Span:
         dur = time.perf_counter() - self._t0
         self._tracer.add(self._name, self._t0, dur, trace=self._trace,
                          **self._args)
+        if self._note is not None:
+            self._note.__exit__(*exc)
         return False
+
+    def note(self, **args):
+        """Fields known only inside the block (what a harvest found)."""
+        self._args.update(args)
 
 
 class Tracer:
@@ -90,27 +122,30 @@ class Tracer:
     keep only the recent window — exactly what a watchdog trip wants."""
 
     def __init__(self, *, enabled=False, capacity=DEFAULT_CAPACITY,
-                 process="main"):
+                 process="main", annotation=None):
         self.enabled = enabled
         self.capacity = capacity
         self.process = process
+        # name -> context manager on the profiler's clock, or None for a
+        # tracer that feeds its ring alone
+        self.annotation = annotation
         self._ring = deque(maxlen=capacity)
         self._meta = {}
 
     # -- recording ---------------------------------------------------------
 
     def span(self, name, trace=None, **args):
-        """Context manager timing a block; no-op singleton when disabled."""
-        if not self.enabled:
+        """Context manager timing a block into both sinks: the annotation
+        always, the ring when enabled.  With neither, the no-op singleton."""
+        if not self.enabled and self.annotation is None:
             return _NOOP_SPAN
         return _Span(self, name, trace, args)
 
     def add(self, name, t0, dur, trace=None, **args):
-        """Record an already-timed span (t0 from ``time.perf_counter()``).
-
-        This is the form used at the engine's existing stage-timing sites:
-        the ``t0 = time.perf_counter()`` deltas that feed ``stage_seconds``
-        become spans for free."""
+        """Record an already-timed span (t0 from ``time.perf_counter()``)
+        in the ring alone: the form for a span that opens in one method
+        and closes in another (the engine's ``serve.admit_work``, which
+        ends at the fetch that follows the dispatch)."""
         if not self.enabled:
             return
         rec = {"name": name, "ts": t0, "dur": dur}
@@ -160,7 +195,7 @@ class Tracer:
         return path
 
 
-_TRACER = Tracer()
+_TRACER = Tracer(annotation=_jax_annotation)
 
 
 def get_tracer():

@@ -116,6 +116,7 @@ def _forward_ext(q, k_ext, v_ext, window_size: int, scale: float,
             jax.ShapeDtypeStruct((bh, n, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="local_attn_fwd",
     )(qf, kf, kf, vf, vf)
     return out.reshape(b, h, n, d), lse.reshape(b, h, n)
 
@@ -221,6 +222,7 @@ def _backward_ext(q, k_ext, v_ext, o, lse, do, window_size: int,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, n, d), q.dtype),
         interpret=interpret,
+        name="local_attn_dq",
     )(qf, kf, kf, vf, vf, dof, lsef, ddf)
 
     # grid over the w+1 EXTENDED key windows
@@ -244,6 +246,7 @@ def _backward_ext(q, k_ext, v_ext, o, lse, do, window_size: int,
             jax.ShapeDtypeStruct((bh, n + wsz, d), v_ext.dtype),
         ],
         interpret=interpret,
+        name="local_attn_dkv",
     )(kf, vf, qf, qf, dof, dof, lsef, lsef, ddf, ddf)
 
     return (

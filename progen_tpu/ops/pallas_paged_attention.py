@@ -162,6 +162,7 @@ def _pallas_mix(weights, biases, pool, table, pos, w_scale=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, 1, d), jnp.float32),
         interpret=interpret,
+        name="paged_gate_mix",
     )(pos, table.astype(jnp.int32), *operands)
     # biases come in as (n, 1) column vectors (ops/sgu.py layout)
     return mixed[:, 0] + biases.astype(jnp.float32).reshape(n, 1)[pos]

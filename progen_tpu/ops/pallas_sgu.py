@@ -229,6 +229,7 @@ def _forward(res, gate, weights, biases, block: int, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((bsz, nbr * block, d), gate.dtype),
         scratch_shapes=[pltpu.VMEM((block, d), jnp.float32)],
         interpret=interpret,
+        name="sgu_fwd",
     )(w, g, r, b)
     return out[:, :n].reshape(*lead, n, d)
 
@@ -259,6 +260,7 @@ def _backward_dgate(weights, dout, res, block: int, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((bsz, nbr * block, d), dout.dtype),
         scratch_shapes=[pltpu.VMEM((block, d), jnp.float32)],
         interpret=interpret,
+        name="sgu_dgate",
     )(w, do_p, res_p)
     return dg[:, :n].reshape(*lead, n, d)
 
@@ -290,6 +292,7 @@ def _backward_dw(dout, res, gate, weights_dtype, n: int, block: int,
                                        weights_dtype),
         scratch_shapes=[pltpu.VMEM((block, block), jnp.float32)],
         interpret=interpret,
+        name="sgu_dw",
     )(do, r, g)
     # hard-zero the masked parameterization's dead region: tril also
     # clears the strictly-upper tiles the grid never visited

@@ -355,13 +355,19 @@ class Trainer:
     def _request_preempt_checkpoint(self, signum=None, frame=None) -> None:
         self._preempt_requested = True
 
-    def _note_phase(self, name: str, t0: float, **fields: Any) -> None:
-        """One loop phase -> a trace span AND a flight-recorder event, so
-        a watchdog trip shows the recent phase history whether or not the
-        process is tracing (the recorder is always on)."""
-        dur = time.perf_counter() - t0
-        self._tracer.add(name, t0, dur, **fields)
-        self._recorder.record(name, dur_s=round(dur, 6), **fields)
+    @contextlib.contextmanager
+    def _phase(self, name: str, **fields: Any):
+        """One loop phase -> the tracer's span (profiler annotation, and
+        the ring when enabled) AND a flight-recorder event, so a watchdog
+        trip shows the recent phase history whether or not the process is
+        tracing (the recorder is always on).  Yields ``fields``: the
+        block may add what it learns (a validation loss)."""
+        t0 = time.perf_counter()
+        with self._tracer.span(name) as span:
+            yield fields
+            span.note(**fields)
+        self._recorder.record(name, dur_s=round(time.perf_counter() - t0, 6),
+                              **fields)
 
     def _statusz_health(self) -> dict:
         events = self._recorder.snapshot()
@@ -768,17 +774,18 @@ class Trainer:
                         if watchdog is not None and epoch == 1 and i == 0
                         else contextlib.nullcontext()
                     )
-                    t0 = time.perf_counter()
-                    with grace:
+                    # dispatch time only (the step runs async on device);
+                    # a long span here means input starvation or a compile:
+                    # the feed's child span inside it says which
+                    with self._phase("train.step_dispatch",
+                                     step=global_step + 1), grace:
                         for _ in range(cfg.grad_accum_every):
-                            batch = (next(train_it) if prefetched
-                                     else self._to_device(next(train_it)))
+                            with self._tracer.span("train.feed_wait",
+                                                   step=global_step + 1):
+                                batch = (next(train_it) if prefetched else
+                                         self._to_device(next(train_it)))
                             state, metrics = self.fns.train_step(state, batch)
                     global_step += 1
-                    # dispatch time only (the step runs async on device);
-                    # a long span here means input starvation or a compile
-                    self._note_phase("train.step_dispatch", t0,
-                                     step=global_step)
                     # monotonic, never wrapped: the checkpointed cursor must
                     # identify the position in the multi-epoch STREAM
                     seq_cursor = seq_cursor + effective_batch
@@ -794,31 +801,31 @@ class Trainer:
                         # is executed — the only trustworthy sync point, so
                         # the meter ticks HERE with the tokens since the
                         # last sync (one device_get, not one per metric)
-                        t0 = time.perf_counter()
-                        host_metrics = jax.device_get(metrics)  # graftcheck: disable=host-sync
-                        last_loss = float(host_metrics["loss"])
-                        self.meter.tick(pending_tokens)
-                        pending_tokens = 0
-                        log = {
-                            "loss": last_loss,
-                            "grad_norm": float(host_metrics["grad_norm"]),
-                            # computed on device by the step itself: the
-                            # schedule value this update was actually
-                            # scaled with (no host-side reconstruction
-                            # from global_step)
-                            "lr": float(host_metrics["lr"]),
-                        }
-                        tps = self.meter.tokens_per_sec_per_chip
-                        if tps is not None:
-                            log["tokens_per_sec_per_chip"] = tps
-                            util = mfu(tps, flops_per_token, peak)
-                            if util is not None:
-                                log["mfu"] = util
-                        self.tracker.log(log, global_step)
-                        self._recorder.record("step", step=global_step, **log)
-                        # log span covers the device_get sync + metric
-                        # assembly — the loop's only blocking point
-                        self._note_phase("train.log", t0, step=global_step)
+                        with self._phase("train.log", step=global_step):
+                            # the span covers the device_get sync + metric
+                            # assembly — the loop's only blocking point
+                            host_metrics = jax.device_get(metrics)  # graftcheck: disable=host-sync
+                            last_loss = float(host_metrics["loss"])
+                            self.meter.tick(pending_tokens)
+                            pending_tokens = 0
+                            log = {
+                                "loss": last_loss,
+                                "grad_norm": float(host_metrics["grad_norm"]),
+                                # computed on device by the step itself: the
+                                # schedule value this update was actually
+                                # scaled with (no host-side reconstruction
+                                # from global_step)
+                                "lr": float(host_metrics["lr"]),
+                            }
+                            tps = self.meter.tokens_per_sec_per_chip
+                            if tps is not None:
+                                log["tokens_per_sec_per_chip"] = tps
+                                util = mfu(tps, flops_per_token, peak)
+                                if util is not None:
+                                    log["mfu"] = util
+                            self.tracker.log(log, global_step)
+                            self._recorder.record("step", step=global_step,
+                                                  **log)
                         self.meter.publish(get_registry())
                         self._publish_train_health(log, global_step)
                         if process_index == 0:
@@ -837,20 +844,20 @@ class Trainer:
 
                     hooks_ran = False
                     if global_step % cfg.checkpoint_every == 0:
-                        t0 = time.perf_counter()
-                        self._checkpoint(state, seq_cursor)
-                        self._note_phase("train.checkpoint", t0,
-                                         step=global_step)
+                        with self._phase("train.checkpoint",
+                                         step=global_step):
+                            self._checkpoint(state, seq_cursor)
                         hooks_ran = True
 
                     if global_step % cfg.validate_every == 0:
-                        t0 = time.perf_counter()
-                        vbatch = self._to_device(next(valid_it))
-                        vmetrics = self.fns.eval_step(state, vbatch)
-                        vloss = float(jax.device_get(vmetrics["loss"]))  # graftcheck: disable=host-sync
-                        self.tracker.log({"valid_loss": vloss}, global_step)
-                        self._note_phase("train.validate", t0,
-                                         step=global_step, loss=vloss)
+                        with self._phase("train.validate",
+                                         step=global_step) as fields:
+                            vbatch = self._to_device(next(valid_it))
+                            vmetrics = self.fns.eval_step(state, vbatch)
+                            vloss = float(jax.device_get(vmetrics["loss"]))  # graftcheck: disable=host-sync
+                            self.tracker.log({"valid_loss": vloss},
+                                             global_step)
+                            fields["loss"] = vloss
                         if process_index == 0:
                             print(f"valid_loss: {vloss:.4f}")
                         hooks_ran = True
@@ -946,15 +953,17 @@ class Trainer:
                         else contextlib.nullcontext()
                     )
                     compiled_ks.add(k)
-                    t0 = time.perf_counter()
-                    with grace:
+                    with self._phase("train.step_dispatch",
+                                     step=global_step + span,
+                                     span=span), grace:
                         for _ in range(span // k):
+                            with self._tracer.span("train.feed_wait",
+                                                   step=global_step + span):
+                                superbatch = stager.get(k)
                             state, metrics = self.fns.train_multi_step(
-                                state, stager.get(k))
+                                state, superbatch)
                     done += span
                     global_step += span
-                    self._note_phase("train.step_dispatch", t0,
-                                     step=global_step, span=span)
                     seq_cursor = seq_cursor + effective_batch * span
                     pending_tokens += effective_batch * seq_len * span
                     pending_steps += span
@@ -968,35 +977,35 @@ class Trainer:
                         # ONE batched transfer fetches the whole span's
                         # K-stacked metrics — the sync point the meter
                         # ticks at, now rating K steps per sync
-                        t0 = time.perf_counter()
-                        host_metrics = jax.device_get(metrics)  # graftcheck: disable=host-sync
-                        last_loss = float(host_metrics["loss"][-1, -1])
-                        self.meter.tick(pending_tokens, steps=pending_steps)
-                        pending_tokens = 0
-                        pending_steps = 0
-                        log = {
-                            "loss": last_loss,
-                            "grad_norm": float(
-                                host_metrics["grad_norm"][-1, -1]),
-                            # computed on device by the step itself: the
-                            # schedule value the final update in the span
-                            # was actually scaled with
-                            "lr": float(host_metrics["lr"][-1]),
-                        }
-                        tps = self.meter.tokens_per_sec_per_chip
-                        if tps is not None:
-                            log["tokens_per_sec_per_chip"] = tps
-                            util = mfu(tps, flops_per_token, peak)
-                            if util is not None:
-                                log["mfu"] = util
-                        sps = self.meter.steps_per_sec
-                        if sps is not None:
-                            log["steps_per_sec"] = sps
-                        self.tracker.log(log, global_step)
-                        self._recorder.record("step", step=global_step, **log)
-                        # log span covers the device_get sync + metric
-                        # assembly — the loop's only blocking point
-                        self._note_phase("train.log", t0, step=global_step)
+                        with self._phase("train.log", step=global_step):
+                            # the span covers the device_get sync + metric
+                            # assembly — the loop's only blocking point
+                            host_metrics = jax.device_get(metrics)  # graftcheck: disable=host-sync
+                            last_loss = float(host_metrics["loss"][-1, -1])
+                            self.meter.tick(pending_tokens, steps=pending_steps)
+                            pending_tokens = 0
+                            pending_steps = 0
+                            log = {
+                                "loss": last_loss,
+                                "grad_norm": float(
+                                    host_metrics["grad_norm"][-1, -1]),
+                                # computed on device by the step itself: the
+                                # schedule value the final update in the span
+                                # was actually scaled with
+                                "lr": float(host_metrics["lr"][-1]),
+                            }
+                            tps = self.meter.tokens_per_sec_per_chip
+                            if tps is not None:
+                                log["tokens_per_sec_per_chip"] = tps
+                                util = mfu(tps, flops_per_token, peak)
+                                if util is not None:
+                                    log["mfu"] = util
+                            sps = self.meter.steps_per_sec
+                            if sps is not None:
+                                log["steps_per_sec"] = sps
+                            self.tracker.log(log, global_step)
+                            self._recorder.record("step", step=global_step,
+                                                  **log)
                         self.meter.publish(get_registry())
                         self._publish_train_health(log, global_step)
                         if process_index == 0:
@@ -1013,20 +1022,20 @@ class Trainer:
 
                     hooks_ran = False
                     if global_step % cfg.checkpoint_every == 0:
-                        t0 = time.perf_counter()
-                        self._checkpoint(state, seq_cursor)
-                        self._note_phase("train.checkpoint", t0,
-                                         step=global_step)
+                        with self._phase("train.checkpoint",
+                                         step=global_step):
+                            self._checkpoint(state, seq_cursor)
                         hooks_ran = True
 
                     if global_step % cfg.validate_every == 0:
-                        t0 = time.perf_counter()
-                        vbatch = self._to_device(next(valid_it))
-                        vmetrics = self.fns.eval_step(state, vbatch)
-                        vloss = float(jax.device_get(vmetrics["loss"]))  # graftcheck: disable=host-sync
-                        self.tracker.log({"valid_loss": vloss}, global_step)
-                        self._note_phase("train.validate", t0,
-                                         step=global_step, loss=vloss)
+                        with self._phase("train.validate",
+                                         step=global_step) as fields:
+                            vbatch = self._to_device(next(valid_it))
+                            vmetrics = self.fns.eval_step(state, vbatch)
+                            vloss = float(jax.device_get(vmetrics["loss"]))  # graftcheck: disable=host-sync
+                            self.tracker.log({"valid_loss": vloss},
+                                             global_step)
+                            fields["loss"] = vloss
                         if process_index == 0:
                             print(f"valid_loss: {vloss:.4f}")
                         hooks_ran = True
