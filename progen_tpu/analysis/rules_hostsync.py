@@ -64,6 +64,7 @@ HOT_ZONES: tuple[Zone, ...] = (
         r"|admit_handle|run_prefill_round|drain_sheds|_span|_record_stage"
         r"|_close_stages|_note_admitted"
         r"|_build_dense_admission|_build_paged_admission"
+        r"|_prefill_args|_deactivate"
         r"|submit_embed|_embed_round|run_embed_round|embed_pending"
         r"|_build_lmask|status|_maybe_preempt|_preempt_slot|qos_status"
         r"|_publish_qos_gauges|submit_fork|_release_forks|forget_ttft"
@@ -84,7 +85,7 @@ HOT_ZONES: tuple[Zone, ...] = (
                    "fork_groups", "_fork_wait", "_ttft", "_admitted",
                    "_open_stages", "_step_no",
                    "_step_wait", "_queue_wait_hist", "_ttft_hist",
-                   "_step_host_hist"}),
+                   "_step_host_hist", "admit_rows", "_admit_rows_hist"}),
         # requests, admission rows and snapshots are host payloads by API
         # contract: numpy masks, python ints, JSON-safe dicts — never
         # device arrays

@@ -138,6 +138,21 @@ DRAIN_TIMEOUT = "drain_timeout"
 # the engine concludes the fault is permanent and gives up
 _MAX_DEFER_STREAK = 16
 
+# slots per row of the dense admission program: admission prefills the
+# requests it admits, ``num_slots // SLOTS_PER_ADMIT_ROW`` (at least one)
+# at a time, not every slot.  The group a step brings grows with the
+# slots (slots x chunk_size / generated length finish per chunk), a row of
+# padding costs a whole prime's prefill and another run of the program a
+# fixed pass over the state; PERF.md section 6 (PR 26) has the sweep on
+# the chip: 4 rows at 64 slots, 1 at 16
+SLOTS_PER_ADMIT_ROW = 16
+
+
+@jax.jit
+def _clear_rows(active, freed):
+    """The slots' ``active`` flags with the ``freed`` ones cleared."""
+    return active & ~freed
+
 
 def _host_fetch(tree):
     """Batched device→host fetch that also handles PROCESS-SPANNING
@@ -366,6 +381,7 @@ class ServingEngine:
         self.config = config
         self.policy = policy or make_policy()
         self.num_slots = num_slots
+        self.admit_rows = max(1, num_slots // SLOTS_PER_ADMIT_ROW)
         self.chunk_size = chunk_size
         self.max_len = min(max_len or config.seq_len, config.seq_len)
         self.mesh = mesh
@@ -421,6 +437,9 @@ class ServingEngine:
             "decode_chunk_s": registry.histogram("engine.decode_chunk_s"),
             "embed_s": registry.histogram("engine.embed_s"),
         }
+        # requests in each run of the dense admission program (a count,
+        # not a latency: mean fill = sum / count, of ``admit_rows``)
+        self._admit_rows_hist = registry.histogram("engine.admit_rows")
         self._queue_wait_hist = registry.histogram("engine.queue_wait_s")
         self._ttft_hist = registry.histogram("engine.ttft_s")
         self._step_host_hist = registry.histogram("engine.step_host_s")
@@ -728,11 +747,20 @@ class ServingEngine:
                 f"serve.{phase} failed {streak} consecutive rounds — "
                 f"fault is not transient and not shedding") from cause
 
-    def _admit_call(self, *args):
+    def _admit_call(self, p_pad, *args):
         """Dispatch the admission (prefill) program: the AOT executable
         for this prefill bucket when warmed, the jit wrapper otherwise."""
-        fn = self._aot.get(("admit", args[0].shape[1]), self._admit)
+        fn = self._aot.get(("admit", p_pad), self._admit)
         return fn(self._params, self.state, *args)
+
+    def _deactivate(self, slots) -> None:
+        """Clear the ``active`` flag of ``slots`` in one dispatch (their
+        requests finished, were shed, or went back to the queue)."""
+        freed = np.zeros((self.num_slots,), bool)
+        freed[list(slots)] = True
+        fn = self._aot.get(("release",), _clear_rows)
+        self.state = {**self.state,
+                      "active": fn(self.state["active"], freed)}
 
     def _chunk_call(self, *args):
         fn = self._aot.get(("chunk",), self._decode_chunk)
@@ -839,78 +867,17 @@ class ServingEngine:
                                     length=self.chunk_size)
         return state
 
-    def _admit_impl(self, params, state, tokens, lengths, stops, seeds,
-                    top_k, temp, mask, lmask, tenant=None):
-        """Prefill ``tokens (S, P_pad)`` in one parallel forward and merge
-        rows where ``mask`` into ``state`` (rows outside ``mask`` carry
-        dummy primes and are discarded).  ``lmask (S, L, V)`` is each
-        row's infill logit mask indexed by write position (all-true for
-        unconstrained requests); ``tenant (S,)`` rides only under LoRA."""
-        cfg = self.config
-        with self._trace_ctx():
-            logits, varz = self._prefill_model.apply(
-                self._target_params(params), tokens,
-                self._adapters(params), tenant, mutable=["cache"])
-            caches_new = harvest_caches(cfg, varz["cache"], lengths,
-                                        self.policy, self.max_len)
-            if self.mesh is not None:
-                caches_new = _constrain_caches(caches_new, self.mesh,
-                                               self.strategies)
-            if self.spec:
-                _, dvarz = self._draft_prefill_model.apply(
-                    params["draft"], tokens, mutable=["cache"])
-                draft_new = harvest_caches(
-                    self.draft_config, dvarz["cache"], lengths,
-                    self.policy, self.max_len)
-
-        last = jnp.take_along_axis(
-            logits, (lengths - 1)[:, None, None], axis=1
-        )[:, 0].astype(jnp.float32)
-        keys = jax.vmap(jax.random.key)(seeds.astype(jnp.uint32))
-        split = jax.vmap(jax.random.split)(keys)
-        # the first generated token writes at position ``lengths`` — its
-        # mask row applies here, not in the decode chunk
-        first_mrow = jnp.take_along_axis(
-            lmask, lengths[:, None, None], axis=1)[:, 0]
-        first = gumbel_topk_sample_batched(
-            split[:, 1], last, top_k, temp,
-            mask=first_mrow).astype(jnp.int32)
-
-        s, L = self.num_slots, self.max_len
-        p_pad = tokens.shape[1]
-        # p_pad is window-aligned and may overshoot L; real tokens never do
-        # (submit enforces prime + 1 <= max_len), so truncation drops pad only
-        tok_L = tokens[:, :L] if p_pad >= L else jnp.pad(
-            tokens, ((0, 0), (0, L - p_pad)))
-        seq = tok_L * (jnp.arange(L)[None, :] < lengths[:, None])
-        seq = seq.at[jnp.arange(s), lengths].set(first)
-        pos = lengths
-        done = (first == EOS_ID) | (pos + 1 >= stops)
-
-        def merge(new, old):
-            m = mask.reshape((-1,) + (1,) * (old.ndim - 1))
-            return jnp.where(m, new, old)
-
-        merged_caches = jax.tree.map(merge, caches_new, state["caches"])
-        out = {
-            "seq": merge(seq, state["seq"]),
-            "caches": merged_caches,
-            "pos": merge(pos, state["pos"]),
-            "start": merge(lengths, state["start"]),
-            "stop": merge(stops, state["stop"]),
-            "active": merge(jnp.ones((s,), bool), state["active"]),
-            "done": merge(done, state["done"]),
-            "keys": merge(jax.random.key_data(split[:, 0]), state["keys"]),
-            "top_k": merge(top_k, state["top_k"]),
-            "temp": merge(temp, state["temp"]),
-            "lmask": merge(lmask, state["lmask"]),
-        }
-        if self.lora:
-            out["tenant"] = merge(tenant, state["tenant"])
-        if self.spec:
-            out["draft_caches"] = jax.tree.map(
-                merge, draft_new, state["draft_caches"])
-        return out
+    def _admit_impl(self, params, state, src, mask, *prefill):
+        """One dense admission run: prefill only the rows being admitted
+        — ``prefill`` is ``_prefill_worker_impl``'s arguments over ``R =
+        self.admit_rows`` rows, ``tokens (R, P_pad)`` first — and gather
+        the R-row handle into the slots the host chose (``src (S,)`` slot
+        -> handle row, ``mask (S,)`` the slots admitted).  The composition
+        of the two halves disaggregated serving runs as separate
+        programs; unused handle rows carry a dummy one-token prime and
+        land nowhere."""
+        handle = self._prefill_worker_impl(params, *prefill)
+        return self._merge_impl(state, handle, {}, src, mask)
 
     # -------------------------------------------------------- paged decoding
 
@@ -1152,10 +1119,12 @@ class ServingEngine:
 
     def _prefill_worker_impl(self, params, tokens, lengths, stops, seeds,
                              top_k, temp, lmask, tenant=None):
-        """Prefill stage of disaggregated serving: same math as the admit
-        impls but with NO slot state in scope — the product is a handle
-        of ``(num_slots, ...)`` slabs the merge program later gathers
-        into slots.  Gate rows stay dense here even in paged mode (the
+        """The prefill half of admission, with NO slot state in scope:
+        one parallel forward over ``tokens (rows, P_pad)`` whose product
+        is a handle of ``(rows, ...)`` slabs that ``_merge_impl`` gathers
+        into slots — inside the same program for dense admission
+        (``_admit_impl``), as a program of its own under disaggregated
+        serving.  Gate rows stay dense here even in paged mode (the
         worker cannot know which pool pages the rows will land in; the
         merge scatters them through a row-indexed write table).
         ``tenant (S,)`` rides only under LoRA and travels in the handle
@@ -1182,18 +1151,22 @@ class ServingEngine:
         )[:, 0].astype(jnp.float32)
         keys = jax.vmap(jax.random.key)(seeds.astype(jnp.uint32))
         split = jax.vmap(jax.random.split)(keys)
+        # the first generated token writes at position ``lengths`` — its
+        # mask row applies here, not in the decode chunk
         first_mrow = jnp.take_along_axis(
             lmask, lengths[:, None, None], axis=1)[:, 0]
         first = gumbel_topk_sample_batched(
             split[:, 1], last, top_k, temp,
             mask=first_mrow).astype(jnp.int32)
 
-        s, L = self.num_slots, self.max_len
-        p_pad = tokens.shape[1]
+        L = self.max_len
+        rows, p_pad = tokens.shape
+        # p_pad is window-aligned and may overshoot L; real tokens never do
+        # (submit enforces prime + 1 <= max_len), so truncation drops pad only
         tok_L = tokens[:, :L] if p_pad >= L else jnp.pad(
             tokens, ((0, 0), (0, L - p_pad)))
         seq = tok_L * (jnp.arange(L)[None, :] < lengths[:, None])
-        seq = seq.at[jnp.arange(s), lengths].set(first)
+        seq = seq.at[jnp.arange(rows), lengths].set(first)
         out = {
             "seq": seq,
             "caches": caches,
@@ -1213,20 +1186,21 @@ class ServingEngine:
         return out
 
     def _merge_impl(self, state, hstate, gate_rows, src, mask, *extra):
-        """Decode-side half of the handoff: gather handle rows into slot
-        state.  ``src (S,)`` gives each slot its handle row (any value
-        where ``mask`` is False), ``mask (S,)`` the slots being admitted.
-        The handle is DONATED (``donate_argnums=(1,)``) — its buffers
+        """The merge half of admission: gather handle rows (as many as the
+        handle has) into slot state.  ``src (S,)`` gives each slot its
+        handle row (any value where ``mask`` is False), ``mask (S,)`` the
+        slots being admitted.  As the decode-side program of the handoff
+        the handle is DONATED (``donate_argnums=(1,)``) — its buffers
         alias the merged state outputs, so the caches move rather than
-        copy.  A gather (host-inverted mapping) rather than a scatter of
+        copy; inside ``_admit_impl`` it is that program's own
+        intermediate.  A gather (host-inverted mapping) rather than a scatter of
         handle rows: no duplicate-index hazard, and dead rows vanish for
         free.  In paged mode the handle's dense gate slabs ride in as
         ``gate_rows`` (NOT donated — they scatter into the pool, so they
         cannot alias anything) and ``extra[0]`` is ``row_wtable (S,
         ppr)``: a handle-ROW-indexed write table (DUMP for unused rows)
         feeding ``scatter_gate_rows``."""
-        s = self.num_slots
-        csrc = jnp.clip(src, 0, s - 1)
+        csrc = jnp.clip(src, 0, hstate["pos"].shape[0] - 1)
 
         def take(h, old):
             m = mask.reshape((-1,) + (1,) * (old.ndim - 1))
@@ -1571,7 +1545,6 @@ class ServingEngine:
         active, seq, pos, start = _host_fetch(
             (self.state["active"], self.state["seq"], self.state["pos"],
              self.state["start"]))
-        act = self.state["active"]
         for slot in slots:
             r = self._inflight.pop(slot)
             toks = (seq[slot, start[slot]: pos[slot] + 1].copy()
@@ -1580,8 +1553,7 @@ class ServingEngine:
                 self._host_stop[slot] = 0
                 self._free_slot_pages(slot)
             self._shed(r, SHED_DEADLINE, tokens=toks)
-            act = act.at[slot].set(False)
-        self.state = {**self.state, "active": act}
+        self._deactivate(slots)
 
     # ----------------------------------------------------------- admission
 
@@ -1627,8 +1599,7 @@ class ServingEngine:
             self._free_slot_pages(slot)
         else:
             self._admit_order.pop(slot, None)
-        self.state = {**self.state, "active":
-                      self.state["active"].at[slot].set(False)}
+        self._deactivate([slot])
         self._queue.append(r)
         self.robust.preemptions += 1
         self._tracer.event("serve.preempt", trace=r.uid, slot=slot)
@@ -1652,14 +1623,14 @@ class ServingEngine:
         else:
             self._admit_pending_dense()
 
-    def _build_lmask(self, rows: list) -> np.ndarray:
-        """``(S, max_len, V)`` write-position-indexed logit masks for the
-        rows being admitted (``rows`` pairs a slot/handle-row index with
-        its request).  Unconstrained rows stay all-True — bit-identical
-        to serving without masks at all.  Request row ``g`` constrains
-        the token written at absolute position ``len(prime) + g``."""
-        lmask = np.ones((self.num_slots, self.max_len,
-                         self.config.num_tokens), bool)
+    def _build_lmask(self, n_rows: int, rows: list) -> np.ndarray:
+        """``(n_rows, max_len, V)`` write-position-indexed logit masks for
+        the rows being admitted (``rows`` pairs a slot/handle-row index
+        with its request).  Unconstrained rows stay all-True —
+        bit-identical to serving without masks at all.  Request row ``g``
+        constrains the token written at absolute position
+        ``len(prime) + g``."""
+        lmask = np.ones((n_rows, self.max_len, self.config.num_tokens), bool)
         for idx, r in rows:
             if r.logit_mask is not None:
                 m = np.asarray(r.logit_mask, bool)
@@ -1667,86 +1638,118 @@ class ServingEngine:
                 lmask[idx, p: p + m.shape[0]] = m
         return lmask
 
-    def _admit_pending_dense(self) -> None:
-        # host work with the device idle: slots, host arrays, the mask
-        with self._span("serve.admit_build") as build:
-            built = self._build_dense_admission()
-            if built is None:
-                return
-            batch, args, p_pad = built
-            uids = [r.uid for _, r in batch]
-            build.note(uids=uids)
+    def _prefill_args(self, n_rows: int, rows: list, p_pad: int) -> tuple:
+        """Host arrays of one prefill over ``n_rows`` handle rows, request
+        ``rows[k]`` in row ``k``: the arguments of
+        ``_prefill_worker_impl`` after ``params``.  Unused rows carry a
+        dummy one-token prime."""
+        tokens = np.zeros((n_rows, p_pad), np.int32)
+        lengths = np.ones((n_rows,), np.int32)
+        stops = np.full((n_rows,), 2, np.int32)
+        seeds = np.zeros((n_rows,), np.uint32)
+        top_k = np.zeros((n_rows,), np.int32)
+        temp = np.ones((n_rows,), np.float32)
+        tenant = np.zeros((n_rows,), np.int32)
+        for row, r in enumerate(rows):
+            t = np.asarray(r.tokens, np.int32)
+            tokens[row, : len(t)] = t
+            lengths[row] = len(t)
+            stops[row] = min(len(t) + r.max_new_tokens, self.max_len)
+            seeds[row] = np.uint32(int(r.seed) & 0xFFFFFFFF)
+            top_k[row] = 0 if r.top_k is None else int(r.top_k)
+            temp[row] = float(r.temperature)
+            tenant[row] = int(r.tenant)
+        lmask = self._build_lmask(n_rows, list(enumerate(rows)))
+        extra = (tenant,) if self.lora else ()
+        return (tokens, lengths, stops, seeds, top_k, temp, lmask, *extra)
 
-        t0 = time.perf_counter()
-        try:
-            with self._span("serve.admit_prefill", uids=uids, p_pad=p_pad):
-                self.state = self._guard(
-                    "serve.prefill", self._admit_call, *args,
-                    key=("admit", p_pad))
-        except _ContainedFault:
-            # the batch's prefill never merged: undo the bookkeeping and
-            # shed exactly the requests whose work was lost
-            for slot, r in batch:
-                self._inflight.pop(slot, None)
-                self._shed(r, FAILED_FAULT)
-        except RetryError:
-            # escape for restart-and-replay, but leave the engine
-            # consistent: the un-prefilled batch goes back to the queue
-            # front in its original order
-            for slot, r in reversed(batch):
-                self._inflight.pop(slot, None)
-                self._queue.appendleft(r)
-            raise
-        else:
+    def _admit_pending_dense(self) -> None:
+        """Admit what fits, ``admit_rows`` requests per run of the
+        admission program and as many runs as the group needs, one in
+        flight at a time and with no fetch between them.  Bookkeeping and
+        fault handling are per run: a run whose prefill was lost sheds or
+        re-queues its own requests and leaves the earlier runs of the
+        group in their slots."""
+        stage = None
+        while self._queue and len(self._inflight) < self.num_slots:
+            # host work with the device idle (the first run) or busy with
+            # the run before: slots, host arrays, the mask
+            with self._span("serve.admit_build") as build:
+                batch, args, p_pad = self._build_dense_admission()
+                uids = [r.uid for _, r in batch]
+                build.note(uids=uids)
+            if stage is not None:
+                # the state is not donated, so every run in flight holds a
+                # copy of it: the run before has to be done (no transfer,
+                # the arrays above are built already) before this one is
+                # dispatched, and two copies live at once however large
+                # the group — what the chunk program needs anyway
+                t_wait = time.perf_counter()
+                with self._span("serve.device_wait", after="admit"):
+                    # graftcheck: disable=host-sync
+                    jax.block_until_ready(self.state["pos"])
+                self._step_wait += time.perf_counter() - t_wait
+
+            t0 = time.perf_counter()
+            try:
+                with self._span("serve.admit_prefill", uids=uids,
+                                p_pad=p_pad):
+                    self.state = self._guard(
+                        "serve.prefill", self._admit_call, p_pad, *args,
+                        key=("admit", p_pad))
+            except _ContainedFault:
+                # the run's prefill never merged: undo the bookkeeping and
+                # shed exactly the requests whose work was lost; what is
+                # still queued waits for the next step
+                for slot, r in batch:
+                    self._inflight.pop(slot, None)
+                    self._shed(r, FAILED_FAULT)
+                return
+            except RetryError:
+                # escape for restart-and-replay, but leave the engine
+                # consistent: the un-prefilled run goes back to the queue
+                # front in its original order
+                for slot, r in reversed(batch):
+                    self._inflight.pop(slot, None)
+                    self._queue.appendleft(r)
+                raise
+            self._admit_rows_hist.observe(len(batch))
             # the admit program samples each request's first token; that
             # it has RUN is known at the next flags fetch, which stamps
-            # first-token time and closes the stage
-            self._open_stages.append(
-                ("prefill_s", "admit", t0, [r for _, r in batch]))
+            # first-token time and closes the stage: ONE stage per
+            # admitting step, from the dispatch of the group's first run
+            if stage is None:
+                stage = []
+                self._open_stages.append(("prefill_s", "admit", t0, stage))
+            stage.extend(r for _, r in batch)
 
     def _build_dense_admission(self):
-        """Take what fits out of the queue and fill the host arrays of one
-        dense admission round: ``(batch, arguments of the admit program
-        after params and state, prefill bucket)``, or None with nothing
-        to admit."""
+        """Take up to ``admit_rows`` requests out of the queue into free
+        slots (the caller has seen one of each) and fill the host arrays
+        of one admission run: ``(batch, arguments of the admit program
+        after params and state, prefill bucket)``."""
         t_build = time.perf_counter()
         free = [i for i in range(self.num_slots) if i not in self._inflight]
-        if not free or not self._queue:
-            return None
-        batch: list[tuple[int, Request]] = []
-        while free and self._queue:
-            batch.append((free.pop(0), self._queue.popleft()))
-        self._note_admitted([r for _, r in batch], t_build)
+        requests: list[Request] = []
+        while self._queue and len(requests) < min(self.admit_rows,
+                                                  len(free)):
+            requests.append(self._queue.popleft())
+        batch = list(zip(free, requests))
+        self._note_admitted(requests, t_build)
 
-        s = self.num_slots
-        longest = max(len(r.tokens) for _, r in batch)
+        longest = max(len(r.tokens) for r in requests)
         p_pad = pad_prime_length(longest, self.config.window_size,
                                  self.config.seq_len, bucket=True)
-        tokens = np.zeros((s, p_pad), np.int32)
-        lengths = np.ones((s,), np.int32)  # dummy rows: 1-token prime
-        stops = np.full((s,), 2, np.int32)
-        seeds = np.zeros((s,), np.uint32)
-        top_k = np.zeros((s,), np.int32)
-        temp = np.ones((s,), np.float32)
-        mask = np.zeros((s,), bool)
-        tenant = np.zeros((s,), np.int32)
-        for slot, r in batch:
-            t = np.asarray(r.tokens, np.int32)
-            tokens[slot, : len(t)] = t
-            lengths[slot] = len(t)
-            stops[slot] = min(len(t) + r.max_new_tokens, self.max_len)
-            seeds[slot] = np.uint32(int(r.seed) & 0xFFFFFFFF)
-            top_k[slot] = 0 if r.top_k is None else int(r.top_k)
-            temp[slot] = float(r.temperature)
+        src = np.zeros((self.num_slots,), np.int32)
+        mask = np.zeros((self.num_slots,), bool)
+        for row, (slot, r) in enumerate(batch):
+            src[slot] = row
             mask[slot] = True
-            tenant[slot] = int(r.tenant)
             self._inflight[slot] = r
             self._admit_order[slot] = self._admit_seq
             self._admit_seq += 1
-        lmask = self._build_lmask(batch)
-        extra = (tenant,) if self.lora else ()
-        return batch, (tokens, lengths, stops, seeds, top_k, temp, mask,
-                       lmask, *extra), p_pad
+        prefill = self._prefill_args(self.admit_rows, requests, p_pad)
+        return batch, (src, mask, *prefill), p_pad
 
     def _admit_pending_paged(self) -> None:
         with self._span("serve.admit_build") as build:
@@ -1761,7 +1764,7 @@ class ServingEngine:
         try:
             with self._span("serve.admit_prefill", uids=uids, p_pad=p_pad):
                 self.state = self._guard(
-                    "serve.prefill", self._admit_call, *args,
+                    "serve.prefill", self._admit_call, p_pad, *args,
                     key=("admit", p_pad))
         except _ContainedFault:
             # prefill never merged: the planned pages hold nothing — free
@@ -1884,7 +1887,7 @@ class ServingEngine:
             for _, r in reversed(batch):
                 self._queue.appendleft(r)
             raise
-        lmask = self._build_lmask(batch)
+        lmask = self._build_lmask(s, batch)
         extra = (tenant,) if self.lora else ()
         return batch, (tokens, lengths, stops, seeds, top_k, temp, mask,
                        lmask, self._page_table.copy(), wtable,
@@ -1981,33 +1984,14 @@ class ServingEngine:
             batch.append(self._queue.popleft())
         self._note_admitted(batch, t_build)
 
-        s = self.num_slots
-        tokens = np.zeros((s, p_pad), np.int32)
-        lengths = np.ones((s,), np.int32)  # dummy rows: 1-token prime
-        stops = np.full((s,), 2, np.int32)
-        seeds = np.zeros((s,), np.uint32)
-        top_k = np.zeros((s,), np.int32)
-        temp = np.ones((s,), np.float32)
-        tenant = np.zeros((s,), np.int32)
-        for row, r in enumerate(batch):
-            t = np.asarray(r.tokens, np.int32)
-            tokens[row, : len(t)] = t
-            lengths[row] = len(t)
-            stops[row] = min(len(t) + r.max_new_tokens, self.max_len)
-            seeds[row] = np.uint32(int(r.seed) & 0xFFFFFFFF)
-            top_k[row] = 0 if r.top_k is None else int(r.top_k)
-            temp[row] = float(r.temperature)
-            tenant[row] = int(r.tenant)
-        # handle-ROW-indexed, like every other slab the worker produces
-        lmask = self._build_lmask(list(enumerate(batch)))
-        extra = (tenant,) if self.lora else ()
+        # handle-ROW-indexed, like every slab the worker produces
+        args = self._prefill_args(self.num_slots, batch, p_pad)
         t0 = time.perf_counter()
         try:
             with self._span("serve.prefill", uids=[r.uid for r in batch],
                             p_pad=p_pad):
                 h = self._guard(
-                    "serve.prefill", self._prefill_worker_call, tokens,
-                    lengths, stops, seeds, top_k, temp, lmask, *extra,
+                    "serve.prefill", self._prefill_worker_call, *args,
                     key=("prefill", p_pad))
         except _ContainedFault:
             for r in batch:
@@ -2189,8 +2173,7 @@ class ServingEngine:
         re-decode reproduces the identical token prefix."""
         r = self._inflight.pop(slot)
         self._free_slot_pages(slot)
-        self.state = {**self.state, "active":
-                      self.state["active"].at[slot].set(False)}
+        self._deactivate([slot])
         self._queue.appendleft(r)
         self.evictions += 1
 
@@ -2288,7 +2271,6 @@ class ServingEngine:
                 (self.state["seq"], self.state["pos"], self.state["start"]))
             out = []
             now = time.perf_counter()
-            act = self.state["active"]
             for i in ready:
                 r = self._inflight.pop(i)
                 if self.paged:
@@ -2306,8 +2288,7 @@ class ServingEngine:
                 out.append(comp)
                 if r.on_complete is not None:
                     r.on_complete(comp)
-                act = act.at[i].set(False)
-            self.state = {**self.state, "active": act}
+            self._deactivate(ready)
             self.completions.extend(out)
             harvest.note(uids=[c.uid for c in out])
         return out
@@ -2372,15 +2353,14 @@ class ServingEngine:
         decode fault: the batch's device state can no longer be trusted
         to advance, but queued requests are untouched — the engine keeps
         serving."""
-        act = self.state["active"]
-        for slot in sorted(self._inflight):
+        slots = sorted(self._inflight)
+        for slot in slots:
             r = self._inflight.pop(slot)
             if self.paged:
                 self._host_stop[slot] = 0
                 self._free_slot_pages(slot)
             self._shed(r, FAILED_FAULT)
-            act = act.at[slot].set(False)
-        self.state = {**self.state, "active": act}
+        self._deactivate(slots)
 
     def step(self) -> list[Completion]:
         """One engine iteration: shed expired requests, admit queued ones
@@ -2725,6 +2705,13 @@ class ServingEngine:
         f32 = partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
         b8 = partial(jax.ShapeDtypeStruct, dtype=jnp.bool_)
         L, V = self.max_len, self.config.num_tokens
+
+        def prefill_sd(rows, p_pad):
+            """``_prefill_worker_impl``'s arguments after ``params``."""
+            sd = [i32(rows, p_pad), i32(rows), i32(rows), u32((rows,)),
+                  i32(rows), f32((rows,)), b8((rows, L, V))]
+            return sd + [i32(rows)] if self.lora else sd
+
         for p_pad in buckets:
             if embed and ("embed", p_pad) not in self._aot:
                 tgt_sd = as_shape(self._target_params(self._params))
@@ -2736,37 +2723,34 @@ class ServingEngine:
                 key = ("prefill", p_pad)
                 if key in self._aot:
                     continue
-                pre_args = [params_sd, i32(s, p_pad), i32(s), i32(s),
-                            u32((s,)), i32(s), f32((s,)), b8((s, L, V))]
-                if self.lora:
-                    pre_args += [i32(s)]
-                self._aot[key] = (
-                    self._prefill_worker.lower(*pre_args).compile())
+                self._aot[key] = self._prefill_worker.lower(
+                    params_sd, *prefill_sd(s, p_pad)).compile()
                 self._compiled_keys.add(key)
                 programs += 1
                 continue
             key = ("admit", p_pad)
             if key in self._aot:
                 continue
-            admit_args = [params_sd, state_sd, i32(s, p_pad), i32(s),
-                          i32(s), u32((s,)), i32(s), f32((s,)), b8((s,)),
-                          b8((s, L, V))]
             if self.paged:
-                admit_args += [i32(s, self.pages_per_row),
-                               i32(s, self.pages_per_row)]
-            if self.lora:
-                admit_args += [i32(s)]
-            self._aot[key] = self._admit.lower(*admit_args).compile()
+                # the paged program still prefills every slot
+                admit_args = [i32(s, p_pad), i32(s), i32(s), u32((s,)),
+                              i32(s), f32((s,)), b8((s,)), b8((s, L, V)),
+                              i32(s, self.pages_per_row),
+                              i32(s, self.pages_per_row)]
+                if self.lora:
+                    admit_args += [i32(s)]
+            else:
+                admit_args = [i32(s), b8((s,)),
+                              *prefill_sd(self.admit_rows, p_pad)]
+            self._aot[key] = self._admit.lower(
+                params_sd, state_sd, *admit_args).compile()
             self._compiled_keys.add(key)
             programs += 1
         if self.disagg and ("merge",) not in self._aot:
             # the handle's shape is bucket-independent (everything is
             # harvested to max_len), so any bucket's worker sizes it
-            h_args = [params_sd, i32(s, buckets[0]), i32(s), i32(s),
-                      u32((s,)), i32(s), f32((s,)), b8((s, L, V))]
-            if self.lora:
-                h_args += [i32(s)]
-            h_sd = jax.eval_shape(self._prefill_worker_impl, *h_args)
+            h_sd = jax.eval_shape(self._prefill_worker_impl, params_sd,
+                                  *prefill_sd(s, buckets[0]))
             gate_sd: dict = {}
             if self.paged:
                 gate_sd = h_sd["caches"]["sgu_gate"]
@@ -2789,6 +2773,11 @@ class ServingEngine:
                 self._decode_chunk.lower(*chunk_args).compile())
             self._compiled_keys.add(("chunk",))
             programs += 1
+        if ("release",) not in self._aot:
+            # the one-operation program that clears finished slots' flags:
+            # not counted, but built here like everything step() runs
+            self._aot[("release",)] = _clear_rows.lower(
+                state_sd["active"], b8((s,))).compile()
         return {"programs": programs,
                 "seconds": time.perf_counter() - t0}
 

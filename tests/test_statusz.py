@@ -11,6 +11,7 @@ import json
 import math
 import os
 import re
+import time
 import urllib.error
 import urllib.request
 
@@ -453,6 +454,13 @@ def _drive(statusz):
                                    temperature=0.0, seed=i))
         done = cluster.drain(timeout=300.0)
         if statusz:
+            # the per-worker staleness gauges need one heartbeat from each
+            # worker, and workers beat once a second from their ready
+            # frame: a fleet that drains three requests inside that second
+            # has sent none yet
+            deadline = time.monotonic() + 10.0
+            while len(cluster._hb) < 2 and time.monotonic() < deadline:
+                cluster._pump(0.1)  # the driver's event loop
             ports = cluster.stats()["statusz_ports"]
             assert set(ports) == {"driver", "prefill:0", "decode:0"}
             for who, port in ports.items():
