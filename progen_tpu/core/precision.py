@@ -33,12 +33,15 @@ class Policy:
         return jnp.asarray(x, self.output_dtype)
 
 
-def make_policy(mixed_precision: bool = True) -> Policy:
+def make_policy(mixed_precision: bool = True, *,
+                param_dtype=jnp.float32) -> Policy:
     """``mixed_precision=False`` computes in f32 end to end (parity/test mode).
 
     Mirrors the reference's ``ProGen(mixed_precision=...)`` kwarg
     (``progen.py:235``) but defaults to bf16 compute, the TPU-native choice.
+    ``param_dtype=jnp.bfloat16`` stores the parameters as a checkpoint
+    published in bfloat16 holds them (``models/longcat.py``); the logits
+    stay float32.
     """
-    if mixed_precision:
-        return Policy(jnp.float32, jnp.bfloat16, jnp.float32)
-    return Policy(jnp.float32, jnp.float32, jnp.float32)
+    compute = jnp.bfloat16 if mixed_precision else jnp.float32
+    return Policy(param_dtype, compute, jnp.float32)

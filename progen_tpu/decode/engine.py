@@ -90,6 +90,7 @@ from progen_tpu.observe.robustness import RobustnessCounters
 from progen_tpu.resilience import faults
 from progen_tpu.resilience.retry import RetryError, default_classifier
 from progen_tpu.resilience.watchdog import Watchdog
+from progen_tpu.decode.family import UnsupportedFamilyMode, family_for
 from progen_tpu.decode.incremental import (
     ProGenDecodeStep,
     ProGenPagedDecodeStep,
@@ -112,9 +113,7 @@ from progen_tpu.decode.prefill import (
     _constrain_caches,
     harvest_caches,
     harvest_gate_pages,
-    make_embedder,
     pad_prime_length,
-    prime_buckets,
     scatter_gate_rows,
 )
 from progen_tpu.decode.sampler import (
@@ -202,7 +201,10 @@ class Request:
     Workload knobs: ``logit_mask`` is an optional ``(G, V)`` bool array
     (``G ≤ max_new_tokens``) constraining generated position ``g`` to
     its true entries (``workloads/infill.ScaffoldSpec`` builds these;
-    positions past ``G`` are unconstrained); ``tenant`` selects a row of
+    positions past ``G`` are unconstrained), or a ``(V,)`` array that
+    constrains EVERY generated position alike — the one form a family
+    whose slot state holds a mask row per slot, not per position, takes
+    (``decode/family.py``); ``tenant`` selects a row of
     the engine's LoRA adapter bank (0 = base model; nonzero requires the
     engine to hold a bank).
 
@@ -380,6 +382,19 @@ class ServingEngine:
                  quantize: str | None = None):
         self.config = config
         self.policy = policy or make_policy()
+        # the model behind the seam (decode/family.py): the plain dense
+        # path calls nothing else of it, and a mode the family does not
+        # state is refused here, by name and with no fallback
+        self._weights_mode = "int8" if quantize else "bf16"
+        self.family = family_for(config, self.policy, self._weights_mode)
+        asked = {"paged": paged, "spec": spec, "disagg": disagg,
+                 "lora_bank": lora_bank is not None,
+                 "quantize": quantize is not None, "mesh": mesh is not None}
+        for mode, on in asked.items():
+            if on and mode not in self.family.modes:
+                raise UnsupportedFamilyMode(
+                    f"the {self.family.name} family is served by the plain "
+                    f"dense path only: {mode} is not supported for it")
         self.num_slots = num_slots
         self.admit_rows = max(1, num_slots // SLOTS_PER_ADMIT_ROW)
         self.chunk_size = chunk_size
@@ -467,7 +482,6 @@ class ServingEngine:
             raise ValueError("quantize='weights+pages' requires paged=True "
                              "(the 8-bit gate format is a page format)")
         self.quantize = quantize
-        self._weights_mode = "int8" if quantize else "bf16"
         self.gate_dtype = "int8" if quantize == "weights+pages" else "bf16"
         if quantize:
             params = self._quantize_variables(params)
@@ -579,15 +593,15 @@ class ServingEngine:
                 else self._decode_chunk_paged_impl)
             self._admit = jax.jit(self._admit_paged_impl)
         else:
-            self._step_model = ProGenDecodeStep(config=config,
-                                                policy=self.policy,
-                                                weights=self._weights_mode)
             self._decode_chunk = jax.jit(
                 self._decode_chunk_spec_impl if spec
                 else self._decode_chunk_impl)
             self._admit = jax.jit(self._admit_impl)
-        self._prefill_model = ProGen(config=config, policy=self.policy,
-                                     weights=self._weights_mode)
+        self.model_stats: dict = {}     # the family's counters as last fetched
+        # the paged, speculative and disaggregated programs call the
+        # family's modules themselves (None where it has no such mode)
+        self._step_model = self.family.step_model
+        self._prefill_model = self.family.prefill_model
         if remote_prefill and not disagg:
             raise ValueError("remote_prefill requires disagg=True")
         self.remote_prefill = remote_prefill
@@ -606,9 +620,7 @@ class ServingEngine:
         # prime bucket, AOT-warmable like admission
         self._embed_queue: deque[Request] = deque()
         self.embed_batch = num_slots
-        self._embedder = make_embedder(config, self.policy, mesh=mesh,
-                                       strategies=self.strategies,
-                                       weights=self._weights_mode)
+        self._embedder = self.family.embedder(mesh, self.strategies)
         self.state = self._init_state()
 
     # ---------------------------------------------------------------- state
@@ -616,9 +628,9 @@ class ServingEngine:
     def _init_state(self) -> dict:
         s, L = self.num_slots, self.max_len
         with self._trace_ctx():
-            caches = init_caches(self.config, s, self.policy, decode_len=L,
-                                 with_sgu=not self.paged)
             if self.paged:
+                caches = init_caches(self.config, s, self.policy,
+                                     decode_len=L, with_sgu=False)
                 caches.pop("sgu_gate")
                 caches["sgu_pool"] = init_gate_pool(
                     self.config, self._pool.num_pages, self.page_size,
@@ -626,6 +638,8 @@ class ServingEngine:
                 if self.gate_dtype == "int8":
                     caches["sgu_pool_scale"] = init_gate_scale(
                         self.config, self._pool.num_pages, self.page_size)
+            else:
+                caches = self.family.init_caches(s, L)
             if self.mesh is not None:
                 caches = _constrain_caches(caches, self.mesh, self.strategies)
         keys = jax.vmap(jax.random.key)(jnp.zeros((s,), jnp.uint32))
@@ -642,9 +656,15 @@ class ServingEngine:
             "temp": jnp.ones((s,), jnp.float32),
             # per-slot per-position logit mask, indexed by WRITE position;
             # all-true rows are bit-identical to no masking at all, so the
-            # plain generate path pays only the (S, L, V)-bool gather
-            "lmask": jnp.ones((s, L, self.config.num_tokens), bool),
+            # plain generate path pays only the (S, L, V)-bool gather.  A
+            # family without position masks holds one (V,) row per slot
+            "lmask": jnp.ones(self._lmask_shape(s), bool),
         }
+        stats = self.family.init_stats()
+        if stats:
+            # the family's device-side counters: summed by its programs,
+            # read with the flags fetch the harvest makes anyway
+            state["stats"] = stats
         if self.lora:
             state["tenant"] = jnp.zeros((s,), jnp.int32)
         if self.spec:
@@ -653,6 +673,11 @@ class ServingEngine:
             state["draft_caches"] = init_caches(
                 self.draft_config, s, self.policy, decode_len=L)
         return state
+
+    def _lmask_shape(self, rows: int) -> tuple:
+        if self.family.position_masks:
+            return (rows, self.max_len, self.family.vocab)
+        return (rows, self.family.vocab)
 
     # ------------------------------------------------------ fault containment
 
@@ -837,15 +862,16 @@ class ServingEngine:
                 pos = st["pos"]
                 tok = jnp.take_along_axis(st["seq"], pos[:, None],
                                           axis=1)[:, 0]
-                logits, caches = self._step_model.apply(
+                logits, caches, stats = self.family.decode_step(
                     self._target_params(params), tok, pos, st["caches"],
-                    self._adapters(params), st.get("tenant"))
+                    live, self._adapters(params), st.get("tenant"))
                 kd, sub = split_keys_batched(st["keys"])
                 writepos = jnp.clip(pos + 1, 0, self.max_len - 1)
                 # the infill mask row for the position this step WRITES;
                 # all-pass rows leave sampling bit-identical
                 mrow = jnp.take_along_axis(
-                    st["lmask"], writepos[:, None, None], axis=1)[:, 0]
+                    st["lmask"], writepos[:, None, None], axis=1
+                )[:, 0] if self.family.position_masks else st["lmask"]
                 nxt = gumbel_topk_sample_batched(
                     sub, logits, st["top_k"], st["temp"],
                     mask=mrow).astype(jnp.int32)
@@ -860,8 +886,11 @@ class ServingEngine:
                 # a slot's key advances only on its own live steps, so a
                 # request's trajectory is independent of its neighbours
                 new_keys = jnp.where(live[:, None], kd, st["keys"])
-                return {**st, "seq": seq, "caches": caches, "pos": new_pos,
-                        "done": done, "keys": new_keys}, None
+                out = {**st, "seq": seq, "caches": caches, "pos": new_pos,
+                       "done": done, "keys": new_keys}
+                if stats:
+                    out["stats"] = jax.tree.map(jnp.add, st["stats"], stats)
+                return out, None
 
             state, _ = jax.lax.scan(body, state, None,
                                     length=self.chunk_size)
@@ -1129,13 +1158,10 @@ class ServingEngine:
         merge scatters them through a row-indexed write table).
         ``tenant (S,)`` rides only under LoRA and travels in the handle
         state so the decode side keeps gathering the right adapter."""
-        cfg = self.config
         with self._trace_ctx():
-            logits, varz = self._prefill_model.apply(
-                self._target_params(params), tokens,
-                self._adapters(params), tenant, mutable=["cache"])
-            caches = harvest_caches(cfg, varz["cache"], lengths,
-                                    self.policy, self.max_len)
+            last, caches, stats = self.family.prefill(
+                self._target_params(params), tokens, lengths, self.max_len,
+                self._adapters(params), tenant)
             if self.mesh is not None:
                 caches = _constrain_caches(caches, self.mesh,
                                            self.strategies)
@@ -1146,15 +1172,13 @@ class ServingEngine:
                     self.draft_config, dvarz["cache"], lengths,
                     self.policy, self.max_len)
 
-        last = jnp.take_along_axis(
-            logits, (lengths - 1)[:, None, None], axis=1
-        )[:, 0].astype(jnp.float32)
         keys = jax.vmap(jax.random.key)(seeds.astype(jnp.uint32))
         split = jax.vmap(jax.random.split)(keys)
         # the first generated token writes at position ``lengths`` — its
         # mask row applies here, not in the decode chunk
         first_mrow = jnp.take_along_axis(
-            lmask, lengths[:, None, None], axis=1)[:, 0]
+            lmask, lengths[:, None, None], axis=1
+        )[:, 0] if self.family.position_masks else lmask
         first = gumbel_topk_sample_batched(
             split[:, 1], last, top_k, temp,
             mask=first_mrow).astype(jnp.int32)
@@ -1183,6 +1207,8 @@ class ServingEngine:
             out["tenant"] = tenant
         if self.spec:
             out["draft_caches"] = draft_caches
+        if stats:
+            out["stats"] = stats
         return out
 
     def _merge_impl(self, state, hstate, gate_rows, src, mask, *extra):
@@ -1248,6 +1274,10 @@ class ServingEngine:
         if self.spec:
             out["draft_caches"] = jax.tree.map(
                 take, hstate["draft_caches"], state["draft_caches"])
+        if "stats" in state:
+            # counters are sums, not rows: the handle's join the state's
+            out["stats"] = jax.tree.map(jnp.add, state["stats"],
+                                        hstate["stats"])
         return out
 
     def _prefill_worker_call(self, *args):
@@ -1289,10 +1319,18 @@ class ServingEngine:
                 f"request {request.uid!r}: max_new_tokens must be >= 1")
         if request.logit_mask is not None:
             m = np.asarray(request.logit_mask, bool)
-            if m.ndim != 2 or m.shape[1] != self.config.num_tokens:
+            vocab = self.family.vocab
+            if m.ndim == 2 and not self.family.position_masks:
+                raise UnsupportedFamilyMode(
+                    f"request {request.uid!r}: the {self.family.name} "
+                    f"family takes a logit_mask that is the same at every "
+                    f"position, as one ({vocab},) row, not {m.shape}")
+            if m.ndim == 1 and m.shape[0] == vocab:
+                m = m[None, :]      # checked as one row, kept as (V,)
+            if m.ndim != 2 or m.shape[1] != vocab:
                 raise ValueError(
                     f"request {request.uid!r}: logit_mask must be "
-                    f"(G, {self.config.num_tokens}), got {m.shape}")
+                    f"(G, {vocab}) or ({vocab},), got {m.shape}")
             if m.shape[0] > request.max_new_tokens:
                 raise ValueError(
                     f"request {request.uid!r}: logit_mask has {m.shape[0]} "
@@ -1306,7 +1344,8 @@ class ServingEngine:
                     f"request {request.uid!r}: logit_mask has an all-False "
                     f"row — every constrained position needs >= 1 allowed "
                     f"token")
-            request.logit_mask = m
+            request.logit_mask = (
+                m[0] if np.ndim(request.logit_mask) == 1 else m)
         tenant = int(request.tenant)
         if tenant != 0 and not self.lora:
             raise ValueError(
@@ -1359,6 +1398,9 @@ class ServingEngine:
         pooled final hidden state, no decode slot consumed.  Same shed
         rules as :meth:`submit`; ``max_new_tokens``/``top_k``/``temp``/
         ``seed`` are ignored (nothing is sampled)."""
+        if self._embedder is None:
+            raise UnsupportedFamilyMode(
+                f"the {self.family.name} family has no embedding program")
         n = len(request.tokens)
         if n < 1:
             raise ValueError(f"request {request.uid!r}: empty prime")
@@ -1630,21 +1672,26 @@ class ServingEngine:
         bit-identical to serving without masks at all.  Request row ``g``
         constrains the token written at absolute position
         ``len(prime) + g``."""
-        lmask = np.ones((n_rows, self.max_len, self.config.num_tokens), bool)
+        lmask = np.ones(self._lmask_shape(n_rows), bool)
         for idx, r in rows:
             if r.logit_mask is not None:
                 m = np.asarray(r.logit_mask, bool)
                 p = len(r.tokens)
-                lmask[idx, p: p + m.shape[0]] = m
+                if not self.family.position_masks:
+                    lmask[idx] = m
+                elif m.ndim == 1:
+                    lmask[idx, p: p + r.max_new_tokens] = m
+                else:
+                    lmask[idx, p: p + m.shape[0]] = m
         return lmask
 
     def _prefill_args(self, n_rows: int, rows: list, p_pad: int) -> tuple:
         """Host arrays of one prefill over ``n_rows`` handle rows, request
         ``rows[k]`` in row ``k``: the arguments of
-        ``_prefill_worker_impl`` after ``params``.  Unused rows carry a
-        dummy one-token prime."""
+        ``_prefill_worker_impl`` after ``params``.  Unused rows carry the
+        family's ``idle_length``: a dummy one-token prime, or no token."""
         tokens = np.zeros((n_rows, p_pad), np.int32)
-        lengths = np.ones((n_rows,), np.int32)
+        lengths = np.full((n_rows,), self.family.idle_length, np.int32)
         stops = np.full((n_rows,), 2, np.int32)
         seeds = np.zeros((n_rows,), np.uint32)
         top_k = np.zeros((n_rows,), np.int32)
@@ -1738,8 +1785,7 @@ class ServingEngine:
         self._note_admitted(requests, t_build)
 
         longest = max(len(r.tokens) for r in requests)
-        p_pad = pad_prime_length(longest, self.config.window_size,
-                                 self.config.seq_len, bucket=True)
+        p_pad = self.family.bucket(longest, self.max_len)
         src = np.zeros((self.num_slots,), np.int32)
         mask = np.zeros((self.num_slots,), bool)
         for row, (slot, r) in enumerate(batch):
@@ -2257,11 +2303,14 @@ class ServingEngine:
         after = self._open_stages[-1][1] if self._open_stages else "idle"
         t0 = time.perf_counter()
         with self._span("serve.device_wait", after=after):
-            done, active = _host_fetch(
-                (self.state["done"], self.state["active"]))
+            done, active, stats = _host_fetch(
+                (self.state["done"], self.state["active"],
+                 self.state.get("stats")))
         now = time.perf_counter()
         self._step_wait += now - t0
         self._close_stages(now)
+        if stats:
+            self._publish_model_stats(stats)
         ready = [i for i in range(self.num_slots)
                  if done[i] and active[i] and i in self._inflight]
         if not ready:
@@ -2292,6 +2341,14 @@ class ServingEngine:
             self.completions.extend(out)
             harvest.note(uids=[c.uid for c in out])
         return out
+
+    def _publish_model_stats(self, stats: dict) -> None:
+        """The family's counters, fetched with the slot flags, as registry
+        gauges (cumulative since the engine was built)."""
+        self.model_stats = stats
+        registry = _metrics.get_registry()
+        for name, value in self.family.publish(stats).items():
+            registry.gauge(name).set(value)
 
     def _dispatch_chunk(self) -> None:
         """Run one guarded decode chunk.  A fatal fault on the paged
@@ -2561,7 +2618,10 @@ class ServingEngine:
         }
         if r.logit_mask is not None:
             from progen_tpu.workloads.infill import mask_to_wire
-            entry["logit_mask"] = mask_to_wire(r.logit_mask)
+            m = np.asarray(r.logit_mask, bool)
+            # a (V,) mask of every position travels as its allowed ids
+            entry["logit_mask"] = ({"every": np.flatnonzero(m).tolist()}
+                                   if m.ndim == 1 else mask_to_wire(m))
         if int(r.tenant) != 0:
             entry["tenant"] = int(r.tenant)
         if int(r.priority) != 0:
@@ -2594,8 +2654,12 @@ class ServingEngine:
             lmask = None
             if e.get("logit_mask") is not None:
                 from progen_tpu.workloads.infill import mask_from_wire
-                lmask = mask_from_wire(e["logit_mask"],
-                                       self.config.num_tokens)
+                wire = e["logit_mask"]
+                if isinstance(wire, dict):
+                    lmask = np.zeros((self.family.vocab,), bool)
+                    lmask[wire["every"]] = True
+                else:
+                    lmask = mask_from_wire(wire, self.family.vocab)
             r = Request(
                 uid=e["uid"], tokens=e["tokens"],
                 max_new_tokens=e["max_new_tokens"], top_k=e["top_k"],
@@ -2699,21 +2763,21 @@ class ServingEngine:
         params_sd, state_sd = as_shape(self._params), as_shape(self.state)
         programs = 0
         cap = min(max_prime or self.max_len - 1, self.max_len - 1)
-        buckets = prime_buckets(self.config.window_size,
-                                self.config.seq_len, cap)
+        buckets = self.family.buckets(cap, self.max_len)
         u32 = partial(jax.ShapeDtypeStruct, dtype=jnp.uint32)
         f32 = partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
         b8 = partial(jax.ShapeDtypeStruct, dtype=jnp.bool_)
-        L, V = self.max_len, self.config.num_tokens
+        L, V = self.max_len, self.family.vocab
 
         def prefill_sd(rows, p_pad):
             """``_prefill_worker_impl``'s arguments after ``params``."""
             sd = [i32(rows, p_pad), i32(rows), i32(rows), u32((rows,)),
-                  i32(rows), f32((rows,)), b8((rows, L, V))]
+                  i32(rows), f32((rows,)), b8(self._lmask_shape(rows))]
             return sd + [i32(rows)] if self.lora else sd
 
         for p_pad in buckets:
-            if embed and ("embed", p_pad) not in self._aot:
+            if (embed and self._embedder is not None
+                    and ("embed", p_pad) not in self._aot):
                 tgt_sd = as_shape(self._target_params(self._params))
                 self._aot[("embed", p_pad)] = self._embedder.lower(
                     tgt_sd, i32(s, p_pad), i32(s)).compile()

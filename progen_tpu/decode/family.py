@@ -1,0 +1,140 @@
+"""The model-step seam: what ``ServingEngine``'s plain dense path asks of a
+model family, and ProGen's answer.
+
+The engine owns slots, sampling, admission in runs of ``admit_rows``,
+harvest, clocks, fault containment and spans; a family owns what is in a
+cache and how a token moves through the model:
+
+``name``, ``vocab`` (the logits' width), ``seq_len`` (the longest sequence
+the model takes), ``position_masks`` (whether the slot state holds a logit
+mask per WRITE POSITION, ``(S, L, V)``, or one row per slot, ``(S, V)``),
+``idle_length`` (the ``lengths`` entry of an admission row that carries no
+request: 0 where the prefill takes an empty row, so that its counters count
+real prime tokens only; 1, a dummy one-token prime, where it does not)
+
+What a family can do beyond the plain dense path it states itself, and the
+engine tests nothing else (no family's name, no config's type):
+
+``modes``
+    the serving modes it has, of ``SERVING_MODES``; the engine refuses any
+    other at construction with :class:`UnsupportedFamilyMode`
+``step_model`` / ``prefill_model``
+    the modules those modes' programs call themselves (None for a family
+    with no such mode)
+``embedder(mesh, strategies)``
+    the embedding program, or None for a family without one
+
+``init_caches(slots, max_len)``
+    the decode caches of ``slots`` rows, a pytree whose every leaf has the
+    slot as its leading axis (the engine merges row-wise and knows no key)
+``init_stats()``
+    device-side counters carried in the slot state (``{}`` for none)
+``prefill(params, tokens (R, P), lengths (R,), max_len, adapters, tenant)``
+    ``(last-position logits (R, V) float32, cache rows of R, stats)``
+``decode_step(params, tok (S,), pos (S,), caches, live (S,), adapters, tenant)``
+    ``(logits (S, V), caches, stats)``
+``bucket(prime_len, max_len)`` / ``buckets(cap, max_len)``
+    the padded prefill length of a prime, and every such length up to
+    ``cap``: the admission programs ``aot_warmup`` compiles
+``publish(stats)``
+    registry gauge values from the fetched counters
+
+Paged, speculative, LoRA, disaggregated, quantized and mesh serving are
+ProGen's alone today (its ``modes``); there is no fallback.
+"""
+
+from __future__ import annotations
+
+import jax.numpy as jnp
+
+from progen_tpu.core.precision import Policy
+from progen_tpu.decode.incremental import ProGenDecodeStep, init_caches
+from progen_tpu.decode.prefill import (
+    harvest_caches,
+    make_embedder,
+    pad_prime_length,
+    prime_buckets,
+)
+from progen_tpu.models.progen import ProGen, ProGenConfig
+
+
+# every mode beyond the plain dense path, by ``ServingEngine``'s argument
+SERVING_MODES = frozenset(
+    {"paged", "spec", "disagg", "lora_bank", "quantize", "mesh"})
+
+
+class UnsupportedFamilyMode(ValueError):
+    """A serving mode was asked of a model family that does not have it."""
+
+
+class ProGenFamily:
+    """ProGen behind the seam: fixed k/v rings, token-shift carries and SGU
+    gate rows, prefill buckets ``window_size * 2^k``, a logit mask per
+    write position."""
+
+    name = "progen"
+    position_masks = True
+    idle_length = 1
+    modes = SERVING_MODES
+
+    def __init__(self, config: ProGenConfig, policy: Policy,
+                 weights: str = "bf16"):
+        self.config = config
+        self.policy = policy
+        self.weights = weights
+        self.vocab = config.num_tokens
+        self.seq_len = config.seq_len
+        self.step_model = ProGenDecodeStep(config=config, policy=policy,
+                                           weights=weights)
+        self.prefill_model = ProGen(config=config, policy=policy,
+                                    weights=weights)
+
+    def embedder(self, mesh=None, strategies=()):
+        return make_embedder(self.config, self.policy, mesh=mesh,
+                             strategies=strategies, weights=self.weights)
+
+    def init_caches(self, slots: int, max_len: int):
+        return init_caches(self.config, slots, self.policy,
+                           decode_len=max_len)
+
+    def init_stats(self) -> dict:
+        return {}
+
+    def bucket(self, prime_len: int, max_len: int) -> int:
+        return pad_prime_length(prime_len, self.config.window_size,
+                                self.config.seq_len, bucket=True)
+
+    def buckets(self, cap: int, max_len: int) -> list[int]:
+        return prime_buckets(self.config.window_size, self.config.seq_len,
+                             cap)
+
+    def prefill(self, params, tokens, lengths, max_len, adapters=None,
+                tenant=None):
+        logits, varz = self.prefill_model.apply(
+            params, tokens, adapters, tenant, mutable=["cache"])
+        caches = harvest_caches(self.config, varz["cache"], lengths,
+                                self.policy, max_len)
+        last = jnp.take_along_axis(
+            logits, (lengths - 1)[:, None, None], axis=1
+        )[:, 0].astype(jnp.float32)
+        return last, caches, {}
+
+    def decode_step(self, params, tok, pos, caches, live, adapters=None,
+                    tenant=None):
+        logits, caches = self.step_model.apply(params, tok, pos, caches,
+                                               adapters, tenant)
+        return logits, caches, {}
+
+    def publish(self, stats: dict) -> dict:
+        return {}
+
+
+def family_for(config, policy: Policy, weights: str = "bf16"):
+    """The family that serves ``config``."""
+    if isinstance(config, ProGenConfig):
+        return ProGenFamily(config, policy, weights)
+    from progen_tpu.models.longcat import LongCatConfig, LongCatFamily
+
+    if isinstance(config, LongCatConfig):
+        return LongCatFamily(config, policy)
+    raise TypeError(f"no model family serves a {type(config).__name__}")

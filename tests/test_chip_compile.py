@@ -158,3 +158,52 @@ def test_paged_gate_mix_compiles_for_v5e(shape, no_persistent_cache, width,
                 shape((n,), jnp.float32),
                 shape((num_pages, PAGE_SIZE), jnp.float32))
     _assert_kernel_compiles(fn, *args)
+
+
+# ---- LongCat-Flash's pieces at published widths (models/longcat.py) ----
+
+
+def _longcat_shapes(shape, fn, *args):
+    """``fn``'s abstract outputs placed on the described chip."""
+    return jax.tree.map(lambda a: shape(a.shape, a.dtype),
+                        jax.eval_shape(fn, *args))
+
+
+@pytest.mark.parametrize("tokens", [32, 8192], ids=["decode", "prefill"])
+def test_longcat_expert_share_compiles_for_the_chip(shape, tokens,
+                                                    no_persistent_cache):
+    """The grouped product over held experts (``jax.lax.ragged_dot`` in a
+    ``while`` over windows) at 16 experts of 6144 x 2048, for a decode
+    batch and for an admission run of 2 x 4096 tokens: the chip's compiler
+    takes it and lowers the ragged product to its own kernel."""
+    from progen_tpu.models import longcat
+
+    c = longcat.LongCatConfig(num_layers=1, vocab_size=16384,
+                              experts_held=16)
+    layer = _longcat_shapes(
+        shape, lambda k: longcat._init_layer(k, c, jnp.bfloat16),
+        jax.random.key(0))
+    u = shape((tokens, c.hidden_size), jnp.bfloat16)
+    live = shape((tokens,), jnp.bool_)
+    _assert_kernel_compiles(
+        lambda layer, u, live: longcat.moe_share(u, layer, c, live),
+        layer, u, live)
+
+
+def test_longcat_absorbed_decode_compiles_for_the_chip(shape,
+                                                       no_persistent_cache):
+    """One absorbed attention step of 32 rows over a 4096-row latent cache
+    of 576 numbers (not a multiple of the 128 lanes)."""
+    from progen_tpu.models import longcat
+
+    c = longcat.LongCatConfig(num_layers=1, vocab_size=16384,
+                              experts_held=16)
+    p = _longcat_shapes(
+        shape, lambda k: longcat._init_attn(k, c, jnp.bfloat16),
+        jax.random.key(0))
+    compiled = jax.jit(
+        lambda x, pos, cache, p: longcat.mla_decode(x, pos, cache, p, c)
+    ).lower(shape((32, c.hidden_size), jnp.bfloat16),
+            shape((32,), jnp.int32),
+            shape((32, 4096, c.latent_width), jnp.bfloat16), p).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024 ** 3
