@@ -21,8 +21,9 @@ compile on a miss) and counts cache hits and misses per phase.
 Phases of the default run (one chip):
 
 1. device    — fail unless JAX's first device is a TPU; print versions.
-2. kernels   — windowed attention, blocked SGU (forward and backward) and
-               the paged gate-mix (bf16, q8): compiled kernel
+2. kernels   — windowed attention, blocked SGU (forward and backward),
+               the paged gate-mix (bf16, q8) and the decode step's cache
+               write (rings, gate cache; bit for bit): compiled kernel
                (``tpu_custom_call`` asserted in the compiled text) against
                its XLA twin on the same seeded inputs.
 3. train     — ``train.py --model_name small --mixed_precision --attn_impl
@@ -306,6 +307,7 @@ def kernels_phase(cfg, seed: int) -> list[dict]:
     import jax.numpy as jnp
 
     from progen_tpu.decode.paging import NULL_PAGE
+    from progen_tpu.ops import row_write
     from progen_tpu.ops.local_attention import local_attention
     from progen_tpu.ops.pallas_attention import pallas_local_attention
     from progen_tpu.ops.pallas_paged_attention import paged_gate_mix
@@ -382,6 +384,29 @@ def kernels_phase(cfg, seed: int) -> list[dict]:
     results.append(_compare(
         "paged_gate_mix q8", mix_q8("pallas"), mix_q8("xla"),
         (qw, biases, pool_q, table, pos, w_scale, pool_scale), PAGED_TOL))
+
+    # the decode step's cache write against the scatter it replaces: a
+    # layer's k and v rings in one call, then the gate cache; a copy, so
+    # the tolerance is zero
+    ring = (BATCH, cfg.heads, 2 * cfg.window_size, cfg.dim_head)
+    k_ring, v_ring = (jax.random.normal(next(keys), ring, bf16)
+                      for _ in range(2))
+    k_row, v_row = (jax.random.normal(next(keys), ring[:2] + ring[3:], bf16)
+                    for _ in range(2))
+    slot = pos % ring[2]
+    results.append(_compare(
+        "row_write rings",
+        lambda k, v, a, b, i: row_write.pallas_write_rows(
+            (k, v), (a, b), i, interpret=False),
+        lambda k, v, a, b, i: (row_write._scatter_rows(k, a, i, 1),
+                               row_write._scatter_rows(v, b, i, 1)),
+        (k_ring, v_ring, k_row, v_row, slot), 0.0))
+    results.append(_compare(
+        "row_write gate cache",
+        lambda c, u, i: row_write.pallas_write_rows(
+            (c,), (u,), i, interpret=False),
+        lambda c, u, i: (row_write._scatter_rows(c, u, i, 0),),
+        (gate, gate[:, 0], pos), 0.0))
     return results
 
 

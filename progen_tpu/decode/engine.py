@@ -74,7 +74,7 @@ import json
 import os
 import time
 from collections import deque
-from functools import partial
+from functools import partial, wraps
 from typing import Any, Callable, Sequence
 
 import flax.linen as nn
@@ -122,6 +122,7 @@ from progen_tpu.decode.sampler import (
 )
 from progen_tpu.decode.spec import check_draft_config, spec_round
 from progen_tpu.models.progen import ProGen, ProGenConfig
+from progen_tpu.ops.row_write import record_paths, write_rows
 
 EOS_ID = 0
 
@@ -588,15 +589,16 @@ class ServingEngine:
                 config=config, n_rows=self.max_len, policy=self.policy,
                 impl=paged_impl, weights=self._weights_mode,
                 gate_dtype=self.gate_dtype)
-            self._decode_chunk = jax.jit(
+            self._decode_chunk = self._jit_chunk(
                 self._decode_chunk_spec_paged_impl if spec
                 else self._decode_chunk_paged_impl)
             self._admit = jax.jit(self._admit_paged_impl)
         else:
-            self._decode_chunk = jax.jit(
+            self._decode_chunk = self._jit_chunk(
                 self._decode_chunk_spec_impl if spec
                 else self._decode_chunk_impl)
             self._admit = jax.jit(self._admit_impl)
+        self.row_write: str | None = None
         self.model_stats: dict = {}     # the family's counters as last fetched
         # the paged, speculative and disaggregated programs call the
         # family's modules themselves (None where it has no such mode)
@@ -839,7 +841,7 @@ class ServingEngine:
             config=self.config, n_rows=self.max_len, policy=self.policy,
             impl="xla", weights=self._weights_mode,
             gate_dtype=self.gate_dtype)
-        self._decode_chunk = jax.jit(
+        self._decode_chunk = self._jit_chunk(
             self._decode_chunk_spec_paged_impl if self.spec
             else self._decode_chunk_paged_impl)
         self._aot.pop(("chunk",), None)
@@ -848,6 +850,21 @@ class ServingEngine:
               "bit-identical XLA fallback", flush=True)
 
     # ------------------------------------------------------------- decoding
+
+    def _jit_chunk(self, impl):
+        """``jax.jit(impl)`` for a decode-chunk program; tracing it notes
+        which lowering the step's cache writes took (``ops/row_write.py``:
+        ``"pallas"`` on a TPU, ``"scatter"`` elsewhere, both joined by
+        ``+`` where the shapes split them) for ``status()["row_write"]``."""
+
+        @wraps(impl)
+        def traced(*args):
+            with record_paths() as paths:
+                out = impl(*args)
+            self.row_write = "+".join(sorted(paths))
+            return out
+
+        return jax.jit(traced)
 
     def _decode_chunk_impl(self, params, state):
         cfg = self.config
@@ -878,8 +895,7 @@ class ServingEngine:
                 cur = jnp.take_along_axis(st["seq"], writepos[:, None],
                                           axis=1)[:, 0]
                 val = jnp.where(live, nxt, cur)
-                seq = st["seq"].at[
-                    jnp.arange(self.num_slots), writepos].set(val)
+                seq = write_rows(st["seq"], val, writepos, axis=0)
                 new_pos = jnp.where(live, pos + 1, pos)
                 done = st["done"] | (live & (
                     (val == EOS_ID) | (new_pos + 1 >= st["stop"])))
@@ -958,8 +974,7 @@ class ServingEngine:
                 cur = jnp.take_along_axis(st["seq"], writepos[:, None],
                                           axis=1)[:, 0]
                 val = jnp.where(live, nxt, cur)
-                seq = st["seq"].at[
-                    jnp.arange(self.num_slots), writepos].set(val)
+                seq = write_rows(st["seq"], val, writepos, axis=0)
                 new_pos = jnp.where(live, pos + 1, pos)
                 done = st["done"] | (live & (
                     (val == EOS_ID) | (new_pos + 1 >= st["stop"])))
@@ -2881,6 +2896,9 @@ class ServingEngine:
             "inflight_uids": sorted(r.uid for r in
                                     list(self._inflight.values())),
             "chunks_run": self.chunks_run,
+            # lowering of the chunk program's cache writes; None until the
+            # program has been traced
+            "row_write": self.row_write,
             "paged": self.paged,
             "disagg": self.disagg,
             "spec": self.spec,

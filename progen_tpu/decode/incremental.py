@@ -46,6 +46,7 @@ from progen_tpu.core.precision import Policy, make_policy
 from progen_tpu.models.progen import ProGenConfig, _dense, _norm, apply_lora
 from progen_tpu.ops.local_attention import ATTN_MASK_VALUE
 from progen_tpu.ops.rotary import fixed_pos_embedding, rotate_every_two
+from progen_tpu.ops.row_write import write_rows
 
 
 def _shift_with_carry(h, prev):
@@ -62,14 +63,6 @@ def _rotate_at(x, sin_row, cos_row):
     sin_row = sin_row[:, None, :]
     cos_row = cos_row[:, None, :]
     return x * cos_row + rotate_every_two(x) * sin_row
-
-
-def _update_rows(cache, update, idx, axis):
-    """Per-row ``dynamic_update_index_in_dim``: write ``update[b]`` into
-    ``cache[b]`` at row ``idx[b]`` along ``axis`` (of the per-row view)."""
-    return jax.vmap(
-        lambda c, u, i: jax.lax.dynamic_update_index_in_dim(c, u, i, axis)
-    )(cache, update, idx)
 
 
 def init_caches(config: ProGenConfig, batch_size: int,
@@ -184,8 +177,8 @@ class LocalAttentionDecode(nn.Module):
 
         # per-row ring slot (rows may sit at different positions — the
         # continuous-batching engine drives one step with a (B,) pos vector)
-        k_cache = _update_rows(k_cache, k, slot, axis=1)
-        v_cache = _update_rows(v_cache, v, slot, axis=1)
+        k_cache, v_cache = write_rows((k_cache, v_cache), (k, v), slot,
+                                      axis=1)
 
         sim = jnp.einsum("bhd,bhsd->bhs", q, k_cache,
                          preferred_element_type=jnp.float32) * (d ** -0.5)
@@ -242,7 +235,7 @@ class SGUDecode(nn.Module):
         # stays < n_cache for the whole decode.  ``pos`` is (B,): each row
         # reads its own weight row / bias and masks at its own position.
         n_cache = gate_cache.shape[1]
-        gate_cache = _update_rows(gate_cache, gate, pos, axis=0)
+        gate_cache = write_rows(gate_cache, gate, pos, axis=0)
         w_rows = weights.astype(jnp.float32)[pos][:, :n_cache]  # (B, n_cache)
         if w_scale is not None:
             # per-ROW scale: each batch row reads weight row pos[b]

@@ -44,6 +44,7 @@ from progen_tpu.decode.sampler import (
     split_keys_batched,
 )
 from progen_tpu.models.progen import ProGenConfig
+from progen_tpu.ops.row_write import write_rows
 
 
 def check_draft_config(target: ProGenConfig, draft: ProGenConfig) -> None:
@@ -163,7 +164,7 @@ def spec_round(state: dict, *, spec_k: int, max_len: int, eos_id: int,
         cur = jnp.take_along_axis(st["seq"], writepos[:, None],
                                   axis=1)[:, 0]
         val = jnp.where(live, nxt, cur)
-        seq = st["seq"].at[jnp.arange(s), writepos].set(val)
+        seq = write_rows(st["seq"], val, writepos, axis=0)
         new_pos = jnp.where(live, pos + 1, pos)
         done_now = live & ((val == eos_id) | (new_pos + 1 >= st["stop"]))
         new_keys = jnp.where(live[:, None], kd, st["keys"])

@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from progen_tpu.core.precision import Policy, make_policy
+from progen_tpu.ops.row_write import write_rows
 
 F32 = jnp.float32
 QUERY_BLOCK = 256  # prefill attention: query rows per score block
@@ -293,7 +294,7 @@ def mla_decode(x, pos, cache, p, c: LongCatConfig):
     rank = c.kv_lora_rank
     with jax.named_scope("mla.decode"):
         q_nope, q_rope, row = _mla_project(x[:, None], p, c, pos[:, None])
-        cache = cache.at[jnp.arange(s), pos].set(row[:, 0].astype(cache.dtype))
+        cache = write_rows(cache, row[:, 0].astype(cache.dtype), pos, axis=0)
         wk, wv = _wkvb(p, c, x.dtype)
         q_lat = jnp.einsum("shd,lhd->shl", q_nope[:, 0], wk)
         q_cat = jnp.concatenate([q_lat, q_rope[:, 0]], axis=-1)

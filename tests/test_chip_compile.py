@@ -17,6 +17,7 @@ would skip).
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -158,6 +159,41 @@ def test_paged_gate_mix_compiles_for_v5e(shape, no_persistent_cache, width,
                 shape((n,), jnp.float32),
                 shape((num_pages, PAGE_SIZE), jnp.float32))
     _assert_kernel_compiles(fn, *args)
+
+
+# (cache shape at the slots the benchmark's cells run, arrays in one call)
+ROW_WRITES = {
+    "small-rings": ((64, 8, 512, 128), 2),
+    "small-gate": ((64, 1024, 2048), 1),
+    "base-rings": ((16, 12, 1024, 128), 2),
+    "base-gate": ((16, 2048, 3072), 1),
+    "longcat-latent": ((32, 4096, 576), 1),
+}
+
+
+@pytest.mark.parametrize("case", list(ROW_WRITES))
+def test_row_write_compiles_for_v5e(shape, no_persistent_cache, case):
+    """The decode step's cache write (``ops/row_write.py``): a layer's k
+    and v rings in one call, the gate cache, and LongCat's latent cache of
+    576 lanes, each aliased to its output — no second cache in the
+    program's temporaries (at 576 lanes the entry layout the compiler
+    prefers differs from the kernel's, with the scatter as with the
+    kernel, so that case checks the aliasing alone)."""
+    from progen_tpu.ops.row_write import pallas_write_rows
+
+    dims, n = ROW_WRITES[case]
+    caches = (shape(dims, jnp.bfloat16),) * n
+    updates = (shape(dims[:-2] + dims[-1:], jnp.bfloat16),) * n
+    compiled = jax.jit(
+        lambda c, u, i: pallas_write_rows(c, u, i, interpret=False),
+        donate_argnums=(0,),
+    ).lower(caches, updates, shape((dims[0],), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "kernel did not lower to Mosaic"
+    assert "output_to_operand_aliasing" in text
+    if dims[-1] % 128 == 0:
+        one_cache = 2 * np.prod(dims)
+        assert compiled.memory_analysis().temp_size_in_bytes < one_cache
 
 
 # ---- LongCat-Flash's pieces at published widths (models/longcat.py) ----
