@@ -40,13 +40,16 @@ DET_ZONES: tuple[DetZone, ...] = (
             why="QoS ordering is replayed by the overload trace harness"),
     DetZone(r"progen_tpu/serve/router\.py$", r".*",
             why="placement must replay for exactly-once completion"),
+    DetZone(r"progen_tpu/decode/paging\.py$", r".*",
+            why="which pages a slot shares, takes or gives back is replayed "
+                "with the schedule"),
     DetZone(r"progen_tpu/decode/spec\.py$", r".*",
             why="spec accept/reject is part of token identity"),
     DetZone(
         r"progen_tpu/decode/engine\.py$",
         r"(?:.*\.)?(submit_fork|_release_forks|_maybe_preempt|_preempt_slot"
-        r"|_admit_pending\w*|_admit_from_handoff|_plan_slot_pages"
-        r"|_ensure_chunk_pages|_free_slot_pages|_harvest_done)$",
+        r"|_admit_pending|_take_requests|_place|_unplace|_vacate"
+        r"|_admit_from_handoff|_ensure_chunk_pages|_harvest_done)$",
         clocks=(r"time\.perf_counter(?:_ns)?",),
         why="engine scheduling; the monotonic clock is the sanctioned "
             "timebase that virtual time is derived from"),

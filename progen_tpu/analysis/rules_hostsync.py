@@ -55,25 +55,22 @@ HOT_ZONES: tuple[Zone, ...] = (
     Zone(
         r"decode/engine\.py$",
         r"ServingEngine\.(step|submit|run_until_idle|_admit_pending"
-        r"|_admit_pending_dense|_admit_pending_paged|_plan_slot_pages"
-        r"|_free_slot_pages|_evict_slot|_ensure_chunk_pages|_harvest_done"
+        r"|_admission_open|_take_requests|_place|_unplace|_vacate"
+        r"|_evict_slot|_ensure_chunk_pages|_harvest_done"
         r"|drain|snapshot|restore|has_work|_shed_expired|_shed|_guard"
         r"|_dispatch_chunk|_fail_inflight|_activate_xla_fallback"
         r"|_drain_pending|robustness_counters|_prefill_round"
         r"|_admit_from_handoff|_prefill_worker_call|_merge_call"
         r"|admit_handle|run_prefill_round|drain_sheds|_span|_record_stage"
-        r"|_close_stages|_note_admitted"
-        r"|_build_dense_admission|_build_paged_admission"
-        r"|_prefill_args|_deactivate"
+        r"|_close_stages|_note_admitted|_prefill_args|_deactivate"
         r"|submit_embed|_embed_round|run_embed_round|embed_pending"
         r"|_build_lmask|status|_maybe_preempt|_preempt_slot|qos_status"
         r"|_publish_qos_gauges|submit_fork|_release_forks|forget_ttft"
         r"|prefix_digest|cache_status|_publish_cache_gauges)$",
         frozenset({"_inflight", "_queue", "completions", "config",
                    "num_slots", "max_len", "chunks_run", "_pool",
-                   "_slot_pages", "_page_table", "_paused", "_host_stop",
-                   "_admit_order", "_admit_seq", "page_size",
-                   "pages_per_row", "paged", "chunk_size", "evictions",
+                   "_layout", "_admit_order", "_admit_seq", "page_size",
+                   "paged", "chunk_size", "evictions",
                    "pause_events", "prefix_hits", "robust", "_pending",
                    "_draining", "_aot", "_compiled_keys", "_defer_streak",
                    "fault_retries", "max_queue", "shed_policy",
@@ -91,9 +88,15 @@ HOT_ZONES: tuple[Zone, ...] = (
         # device arrays
         frozenset({"request", "rows", "snap"}),
     ),
-    # the page pool is pure host bookkeeping between dispatches: nothing
-    # in it may touch a device value, so every sync call is a finding
-    Zone(r"decode/paging\.py$", r"PagePool\..*$"),
+    # the page pool, and the host side of the paged cache layout above
+    # it, are pure host bookkeeping between dispatches: nothing in them
+    # may touch a device value, so every sync call is a finding
+    Zone(r"decode/paging\.py$",
+         r"(PagePool\..*|PagedGates\.(chunk_operands|covers|write_tables"
+         r"|plan|_plan_pages|free))$",
+         frozenset({"pool", "slot_pages", "table", "paused", "page_size",
+                    "pages_per_row"}),
+         frozenset({"request", "requests", "tokens"})),
     # the QoS scheduler runs between every admission decision: pure host
     # bookkeeping over Request metadata (priority/tenant/deadline are
     # python scalars by API contract), a sync here stalls every step.

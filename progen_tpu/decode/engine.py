@@ -18,11 +18,18 @@ style, PAPERS.md), with all device programs compiled once:
   slack per row;
 * **refill** — between chunks, finished slots are harvested (completion
   callbacks fire) and refilled from the queue via the one-pass parallel
-  prefill (``decode/prefill.py``): queued primes are padded into a
-  ``(S, P_pad)`` ragged batch (``P_pad`` bucketed to ``window ·
-  2^k`` so admission compiles O(log) programs, then cached), prefilled
-  in ONE forward, and scattered into the free slots while live slots'
-  state rides through untouched.
+  prefill (``decode/prefill.py``): queued primes are padded into
+  ``(R, P_pad)`` ragged batches of ``R = admit_rows`` rows (``P_pad``
+  bucketed to ``window · 2^k`` so admission compiles O(log) programs,
+  then cached), each prefilled in ONE forward and gathered into the
+  free slots while live slots' state rides through untouched.
+
+Three device programs serve every mode: the decode chunk, its
+speculative variant and admission (= prefill ∘ merge, the two halves
+disaggregated serving runs apart).  Where a slot's cache rows live —
+in the slot, or its gate rows in a page pool — is a CACHE LAYOUT
+(``decode/paging.py``) chosen at construction; the programs and the one
+place / undo pair of admission take what differs from it.
 
 Determinism: each request carries its own seed; a request's token
 trajectory depends only on (params, prime, seed, sampling knobs), never
@@ -68,7 +75,6 @@ seed-determinism replays in-flight requests token-identically
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import json
 import os
@@ -77,7 +83,6 @@ from collections import deque
 from functools import partial, wraps
 from typing import Any, Callable, Sequence
 
-import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -91,30 +96,14 @@ from progen_tpu.resilience import faults
 from progen_tpu.resilience.retry import RetryError, default_classifier
 from progen_tpu.resilience.watchdog import Watchdog
 from progen_tpu.decode.family import UnsupportedFamilyMode, family_for
-from progen_tpu.decode.incremental import (
-    ProGenDecodeStep,
-    ProGenPagedDecodeStep,
-    init_caches,
-    init_gate_pool,
-    init_gate_scale,
-)
-from progen_tpu.decode.paging import (
-    DUMP_PAGE,
-    NULL_PAGE,
-    RESERVED_PAGES,
-    PagePool,
-    SlotPages,
-    pages_for_span,
-    prefix_key,
-)
+from progen_tpu.decode.incremental import ProGenDecodeStep, init_caches
+from progen_tpu.decode.paging import PagedGates, SlotCaches, pages_for_span
 from progen_tpu.decode.handoff import Handle, HandoffQueue
 from progen_tpu.decode.qos import QoSQueue
 from progen_tpu.decode.prefill import (
     _constrain_caches,
     harvest_caches,
-    harvest_gate_pages,
-    pad_prime_length,
-    scatter_gate_rows,
+    mesh_trace_ctx,
 )
 from progen_tpu.decode.sampler import (
     gumbel_topk_sample_batched,
@@ -138,7 +127,7 @@ DRAIN_TIMEOUT = "drain_timeout"
 # the engine concludes the fault is permanent and gives up
 _MAX_DEFER_STREAK = 16
 
-# slots per row of the dense admission program: admission prefills the
+# slots per row of the admission program: admission prefills the
 # requests it admits, ``num_slots // SLOTS_PER_ADMIT_ROW`` (at least one)
 # at a time, not every slot.  The group a step brings grows with the
 # slots (slots x chunk_size / generated length finish per chunk), a row of
@@ -173,6 +162,18 @@ def _host_fetch(tree):
         return x
 
     return jax.device_get(jax.tree_util.tree_map(_one, tree))
+
+
+@dataclasses.dataclass
+class _Placement:
+    """Requests booked into slots for one admission dispatch, until it is
+    dispatched: what ``_unplace`` takes back if it never is."""
+
+    batch: list             # (slot, request), in booking order
+    src: np.ndarray         # (S,) slot -> handle row
+    mask: np.ndarray        # (S,) the slots booked
+    operands: tuple         # the layout's merge operands (write table)
+    prefixes: list          # prefix registrations deferred to the dispatch
 
 
 class _ContainedFault(Exception):
@@ -310,7 +311,9 @@ class ServingEngine:
     — moves into a global page pool (``decode/paging.py``): pages are
     allocated on demand as positions advance, freed (refcounted) at
     completion, and shared across requests with a common prompt prefix.
-    Admission is gated by free PAGES as well as free slots; when the pool
+    Admission is the same program as in the fixed-slot engine (the merge
+    scatters the admitted rows' gates into their pages), gated by free
+    PAGES as well as free slots; when the pool
     runs dry mid-decode, starved slots are PAUSED (their rows freeze —
     position, key and sequence do not advance, so the trajectory is
     delayed, never altered) and, if every live slot is starved, the most
@@ -453,7 +456,7 @@ class ServingEngine:
             "decode_chunk_s": registry.histogram("engine.decode_chunk_s"),
             "embed_s": registry.histogram("engine.embed_s"),
         }
-        # requests in each run of the dense admission program (a count,
+        # requests in each run of the admission program (a count,
         # not a latency: mean fill = sum / count, of ``admit_rows``)
         self._admit_rows_hist = registry.histogram("engine.admit_rows")
         self._queue_wait_hist = registry.histogram("engine.queue_wait_s")
@@ -552,58 +555,31 @@ class ServingEngine:
         # instead — a uid's generation is the one that PRIMED it)
         self.generation = 0
 
-        if mesh is not None:
-            from progen_tpu.parallel.sharding import logical_rules
+        self._trace_ctx = mesh_trace_ctx(mesh, self.strategies)
 
-            rules = logical_rules(self.strategies)
-
-            def trace_ctx():
-                stack = contextlib.ExitStack()
-                stack.enter_context(mesh)
-                stack.enter_context(nn.logical_axis_rules(rules))
-                return stack
-        else:
-            trace_ctx = contextlib.ExitStack
-        self._trace_ctx = trace_ctx
-
+        # where a slot's cache rows live (decode/paging.py), chosen once:
+        # the programs and the admission routines below ask the layout for
+        # whatever differs between slots and pages
         self.paged = paged
         self.paged_impl = paged_impl if paged else None
         if paged:
+            self._layout = PagedGates(
+                config, self.policy, num_slots=num_slots,
+                max_len=self.max_len, page_size=page_size,
+                num_pages=num_pages, impl=paged_impl,
+                weights=self._weights_mode, gate_dtype=self.gate_dtype,
+                prefix_caching=prefix_cache)
             self.page_size = page_size
-            self.pages_per_row = -(-self.max_len // page_size)
-            if num_pages is None:
-                num_pages = RESERVED_PAGES + num_slots * self.pages_per_row
-            self._pool = PagePool(num_pages, page_size,
-                                  prefix_caching=prefix_cache,
-                                  gate_dtype=self.gate_dtype)
-            self._slot_pages: dict[int, SlotPages] = {}
-            self._page_table = np.zeros((num_slots, self.pages_per_row),
-                                        np.int32)
-            self._paused = np.zeros((num_slots,), bool)
-            self._host_stop = np.zeros((num_slots,), np.int64)
             self.evictions = 0
             self.pause_events = 0
-            self.prefix_hits = 0
-            self.prefix_lookups = 0
-            self._paged_step_model = ProGenPagedDecodeStep(
-                config=config, n_rows=self.max_len, policy=self.policy,
-                impl=paged_impl, weights=self._weights_mode,
-                gate_dtype=self.gate_dtype)
-            self._decode_chunk = self._jit_chunk(
-                self._decode_chunk_spec_paged_impl if spec
-                else self._decode_chunk_paged_impl)
-            self._admit = jax.jit(self._admit_paged_impl)
         else:
-            self._decode_chunk = self._jit_chunk(
-                self._decode_chunk_spec_impl if spec
-                else self._decode_chunk_impl)
-            self._admit = jax.jit(self._admit_impl)
+            self._layout = SlotCaches(self.family)
+        self._pool = self._layout.pool
+        self._decode_chunk = self._jit_chunk(
+            self._decode_chunk_spec_impl if spec else self._decode_chunk_impl)
+        self._admit = jax.jit(self._admit_impl)
         self.row_write: str | None = None
         self.model_stats: dict = {}     # the family's counters as last fetched
-        # the paged, speculative and disaggregated programs call the
-        # family's modules themselves (None where it has no such mode)
-        self._step_model = self.family.step_model
-        self._prefill_model = self.family.prefill_model
         if remote_prefill and not disagg:
             raise ValueError("remote_prefill requires disagg=True")
         self.remote_prefill = remote_prefill
@@ -630,20 +606,8 @@ class ServingEngine:
     def _init_state(self) -> dict:
         s, L = self.num_slots, self.max_len
         with self._trace_ctx():
-            if self.paged:
-                caches = init_caches(self.config, s, self.policy,
-                                     decode_len=L, with_sgu=False)
-                caches.pop("sgu_gate")
-                caches["sgu_pool"] = init_gate_pool(
-                    self.config, self._pool.num_pages, self.page_size,
-                    self.policy, gate_dtype=self.gate_dtype)
-                if self.gate_dtype == "int8":
-                    caches["sgu_pool_scale"] = init_gate_scale(
-                        self.config, self._pool.num_pages, self.page_size)
-            else:
-                caches = self.family.init_caches(s, L)
-            if self.mesh is not None:
-                caches = _constrain_caches(caches, self.mesh, self.strategies)
+            caches = self._layout.init_caches(s, L)
+            caches = _constrain_caches(caches, self.mesh, self.strategies)
         keys = jax.vmap(jax.random.key)(jnp.zeros((s,), jnp.uint32))
         state = {
             "seq": jnp.zeros((s, L), jnp.int32),
@@ -837,13 +801,10 @@ class ServingEngine:
         """
         self.robust.fallback_activations += 1
         self.paged_impl = "xla"
-        self._paged_step_model = ProGenPagedDecodeStep(
-            config=self.config, n_rows=self.max_len, policy=self.policy,
-            impl="xla", weights=self._weights_mode,
-            gate_dtype=self.gate_dtype)
+        self._layout.use_impl("xla")
         self._decode_chunk = self._jit_chunk(
-            self._decode_chunk_spec_paged_impl if self.spec
-            else self._decode_chunk_paged_impl)
+            self._decode_chunk_spec_impl if self.spec
+            else self._decode_chunk_impl)
         self._aot.pop(("chunk",), None)
         self._compiled_keys.discard(("chunk",))
         print("serving: pallas paged kernel failed; degraded to the "
@@ -866,22 +827,27 @@ class ServingEngine:
 
         return jax.jit(traced)
 
-    def _decode_chunk_impl(self, params, state):
-        cfg = self.config
-
+    def _decode_chunk_impl(self, params, state, *operands):
+        """``chunk_size`` single-token steps of every slot.  ``operands``
+        are the cache layout's (the page table and the paused rows, for
+        paged gates).  A row that is not live runs the step fully masked:
+        sequence, position and key freeze, and its caches keep what the
+        layout says an idle row keeps."""
+        lay = self._layout
         with self._trace_ctx():
-            if self.mesh is not None:
-                state = {**state, "caches": _constrain_caches(
-                    state["caches"], self.mesh, self.strategies)}
+            state = {**state, "caches": _constrain_caches(
+                state["caches"], self.mesh, self.strategies)}
 
             def body(st, _):
-                live = st["active"] & ~st["done"]
+                live = lay.live(st, operands)
                 pos = st["pos"]
                 tok = jnp.take_along_axis(st["seq"], pos[:, None],
                                           axis=1)[:, 0]
-                logits, caches, stats = self.family.decode_step(
+                logits, caches, stats = lay.step(
                     self._target_params(params), tok, pos, st["caches"],
-                    live, self._adapters(params), st.get("tenant"))
+                    live, self._adapters(params), st.get("tenant"),
+                    operands)
+                caches = lay.idle_keeps(live, caches, st["caches"])
                 kd, sub = split_keys_batched(st["keys"])
                 writepos = jnp.clip(pos + 1, 0, self.max_len - 1)
                 # the infill mask row for the position this step WRITES;
@@ -901,6 +867,7 @@ class ServingEngine:
                     (val == EOS_ID) | (new_pos + 1 >= st["stop"])))
                 # a slot's key advances only on its own live steps, so a
                 # request's trajectory is independent of its neighbours
+                # (and pausing delays it, never alters it)
                 new_keys = jnp.where(live[:, None], kd, st["keys"])
                 out = {**st, "seq": seq, "caches": caches, "pos": new_pos,
                        "done": done, "keys": new_keys}
@@ -912,252 +879,56 @@ class ServingEngine:
                                     length=self.chunk_size)
         return state
 
-    def _admit_impl(self, params, state, src, mask, *prefill):
-        """One dense admission run: prefill only the rows being admitted
-        — ``prefill`` is ``_prefill_worker_impl``'s arguments over ``R =
-        self.admit_rows`` rows, ``tokens (R, P_pad)`` first — and gather
-        the R-row handle into the slots the host chose (``src (S,)`` slot
-        -> handle row, ``mask (S,)`` the slots admitted).  The composition
-        of the two halves disaggregated serving runs as separate
-        programs; unused handle rows carry a dummy one-token prime and
-        land nowhere."""
-        handle = self._prefill_worker_impl(params, *prefill)
-        return self._merge_impl(state, handle, {}, src, mask)
-
-    # -------------------------------------------------------- paged decoding
-
-    _RING_KEYS = ("attn_prev", "ff_prev", "k", "v")
-
-    def _decode_chunk_paged_impl(self, params, state, table, paused):
-        """Paged twin of ``_decode_chunk_impl``: the page ``table`` and
-        ``paused`` mask ride in as data (host-side allocation decisions
-        never retrace the program).  Paused rows run the step but are
-        fully masked — sequence/position/key freeze AND their ring/carry
-        writes are dropped (a paused row's carries still hold position
-        ``pos-1``'s activations; letting the discarded speculative step
-        overwrite them would corrupt the real step after unpausing).
-        Pool writes are masked inside the step via ``write_ok``."""
-        with self._trace_ctx():
-            if self.mesh is not None:
-                state = {**state, "caches": _constrain_caches(
-                    state["caches"], self.mesh, self.strategies)}
-
-            def body(st, _):
-                live = st["active"] & ~st["done"] & ~paused
-                pos = st["pos"]
-                tok = jnp.take_along_axis(st["seq"], pos[:, None],
-                                          axis=1)[:, 0]
-                logits, caches = self._paged_step_model.apply(
-                    self._target_params(params), tok, pos, st["caches"],
-                    table, live, self._adapters(params), st.get("tenant"))
-
-                def mrg(new, old):
-                    m = live.reshape((-1,) + (1,) * (old.ndim - 1))
-                    return jnp.where(m, new, old)
-
-                caches = {
-                    **{k: jax.tree.map(mrg, caches[k], st["caches"][k])
-                       for k in self._RING_KEYS},
-                    "sgu_pool": caches["sgu_pool"],
-                    # 8-bit gate pages carry a per-row scale pool whose
-                    # writes are masked inside the step, like the pool's
-                    **({"sgu_pool_scale": caches["sgu_pool_scale"]}
-                       if "sgu_pool_scale" in caches else {}),
-                }
-                kd, sub = split_keys_batched(st["keys"])
-                writepos = jnp.clip(pos + 1, 0, self.max_len - 1)
-                mrow = jnp.take_along_axis(
-                    st["lmask"], writepos[:, None, None], axis=1)[:, 0]
-                nxt = gumbel_topk_sample_batched(
-                    sub, logits, st["top_k"], st["temp"],
-                    mask=mrow).astype(jnp.int32)
-                cur = jnp.take_along_axis(st["seq"], writepos[:, None],
-                                          axis=1)[:, 0]
-                val = jnp.where(live, nxt, cur)
-                seq = write_rows(st["seq"], val, writepos, axis=0)
-                new_pos = jnp.where(live, pos + 1, pos)
-                done = st["done"] | (live & (
-                    (val == EOS_ID) | (new_pos + 1 >= st["stop"])))
-                # key advances only on the slot's own live steps (see the
-                # dense body) — pausing therefore delays, never alters
-                new_keys = jnp.where(live[:, None], kd, st["keys"])
-                return {**st, "seq": seq, "caches": caches, "pos": new_pos,
-                        "done": done, "keys": new_keys}, None
-
-            state, _ = jax.lax.scan(body, state, None,
-                                    length=self.chunk_size)
-        return state
-
-    def _admit_paged_impl(self, params, state, tokens, lengths, stops,
-                          seeds, top_k, temp, mask, lmask, table, wtable,
-                          tenant=None):
-        """Paged twin of ``_admit_impl``: rings/carries harvest and merge
-        as in the dense path, but gate rows scatter straight into the
-        page pool through the WRITE table (``wtable`` — private pages
-        only; prefix-shared and dummy rows dump)."""
-        cfg = self.config
-        with self._trace_ctx():
-            logits, varz = self._prefill_model.apply(
-                self._target_params(params), tokens,
-                self._adapters(params), tenant, mutable=["cache"])
-            caches_new = harvest_caches(cfg, varz["cache"], lengths,
-                                        self.policy, self.max_len,
-                                        with_sgu=False)
-            if self.gate_dtype == "int8":
-                pool_new, pscale_new = harvest_gate_pages(
-                    cfg, varz["cache"], lengths,
-                    state["caches"]["sgu_pool"], wtable, self.policy,
-                    pool_scale=state["caches"]["sgu_pool_scale"])
-            else:
-                pool_new = harvest_gate_pages(
-                    cfg, varz["cache"], lengths,
-                    state["caches"]["sgu_pool"], wtable, self.policy)
-            if self.mesh is not None:
-                caches_new = _constrain_caches(caches_new, self.mesh,
-                                               self.strategies)
-            if self.spec:
-                # draft caches stay dense even in paged mode — the draft
-                # is small enough that paging it would buy nothing
-                _, dvarz = self._draft_prefill_model.apply(
-                    params["draft"], tokens, mutable=["cache"])
-                draft_new = harvest_caches(
-                    self.draft_config, dvarz["cache"], lengths,
-                    self.policy, self.max_len)
-
-        last = jnp.take_along_axis(
-            logits, (lengths - 1)[:, None, None], axis=1
-        )[:, 0].astype(jnp.float32)
-        keys = jax.vmap(jax.random.key)(seeds.astype(jnp.uint32))
-        split = jax.vmap(jax.random.split)(keys)
-        first_mrow = jnp.take_along_axis(
-            lmask, lengths[:, None, None], axis=1)[:, 0]
-        first = gumbel_topk_sample_batched(
-            split[:, 1], last, top_k, temp,
-            mask=first_mrow).astype(jnp.int32)
-
-        s, L = self.num_slots, self.max_len
-        p_pad = tokens.shape[1]
-        tok_L = tokens[:, :L] if p_pad >= L else jnp.pad(
-            tokens, ((0, 0), (0, L - p_pad)))
-        seq = tok_L * (jnp.arange(L)[None, :] < lengths[:, None])
-        seq = seq.at[jnp.arange(s), lengths].set(first)
-        pos = lengths
-        done = (first == EOS_ID) | (pos + 1 >= stops)
-
-        def merge(new, old):
-            m = mask.reshape((-1,) + (1,) * (old.ndim - 1))
-            return jnp.where(m, new, old)
-
-        merged_caches = {
-            **{k: jax.tree.map(merge, caches_new[k], state["caches"][k])
-               for k in self._RING_KEYS},
-            "sgu_pool": pool_new,
-            **({"sgu_pool_scale": pscale_new}
-               if self.gate_dtype == "int8" else {}),
-        }
-        out = {
-            "seq": merge(seq, state["seq"]),
-            "caches": merged_caches,
-            "pos": merge(pos, state["pos"]),
-            "start": merge(lengths, state["start"]),
-            "stop": merge(stops, state["stop"]),
-            "active": merge(jnp.ones((s,), bool), state["active"]),
-            "done": merge(done, state["done"]),
-            "keys": merge(jax.random.key_data(split[:, 0]), state["keys"]),
-            "top_k": merge(top_k, state["top_k"]),
-            "temp": merge(temp, state["temp"]),
-            "lmask": merge(lmask, state["lmask"]),
-        }
-        if self.lora:
-            out["tenant"] = merge(tenant, state["tenant"])
-        if self.spec:
-            out["draft_caches"] = jax.tree.map(
-                merge, draft_new, state["draft_caches"])
-        return out
-
-    # --------------------------------------------------- speculative decoding
-
-    def _decode_chunk_spec_impl(self, params, state):
-        """Speculative twin of ``_decode_chunk_impl``: the chunk becomes
-        ``_spec_rounds`` propose/verify/commit rounds (``decode/spec.py``)
-        instead of ``chunk_size`` single-token target steps.  Returns
-        ``(state, stats)``; emitted-token and verify-round counts stay on
-        device (``spec_counters`` reads them off the hot path)."""
+    def _decode_chunk_spec_impl(self, params, state, *operands):
+        """The chunk under speculative decoding: ``_spec_rounds``
+        propose/verify/commit rounds (``decode/spec.py``) instead of
+        ``chunk_size`` single-token target steps.  A verify step is taken
+        by its ``live`` rows only and the layout rolls the others back (a
+        paged pool's writes are masked inside the step: a live verify step
+        consumes a token the round has already committed, so its write is
+        final).  Returns ``(state, stats)``; emitted-token and verify-round
+        counts stay on device (``spec_counters`` reads them off the hot
+        path)."""
+        lay = self._layout
         tgt, drf = params["target"], params["draft"]
         with self._trace_ctx():
-            if self.mesh is not None:
-                state = {**state, "caches": _constrain_caches(
-                    state["caches"], self.mesh, self.strategies)}
+            state = {**state, "caches": _constrain_caches(
+                state["caches"], self.mesh, self.strategies)}
 
             def target_step(tok, pos, caches, live):
-                del live  # dense writes roll back via merge_caches
-                return self._step_model.apply(tgt, tok, pos, caches)
+                return lay.step(tgt, tok, pos, caches, live, None, None,
+                                operands)[:2]
 
             def draft_step(tok, pos, dc):
                 return self._draft_step_model.apply(drf, tok, pos, dc)
 
-            def merge_caches(live, new, old):
-                def mrg(n, o):
-                    m = live.reshape((-1,) + (1,) * (o.ndim - 1))
-                    return jnp.where(m, n, o)
-                return jax.tree.map(mrg, new, old)
-
             emitted = jnp.zeros((), jnp.int32)
             rounds = jnp.zeros((), jnp.int32)
             for _ in range(self._spec_rounds):
-                live0 = state["active"] & ~state["done"]
+                live0 = lay.live(state, operands)
                 state, em = spec_round(
                     state, spec_k=self.spec_k, max_len=self.max_len,
                     eos_id=EOS_ID, target_step=target_step,
-                    draft_step=draft_step, merge_caches=merge_caches,
+                    draft_step=draft_step, merge_caches=lay.rollback,
                     live0=live0)
                 emitted = emitted + jnp.sum(em)
                 rounds = rounds + jnp.any(live0).astype(jnp.int32)
         return state, {"emitted": emitted, "rounds": rounds}
 
-    def _decode_chunk_spec_paged_impl(self, params, state, table, paused):
-        """Speculative + paged.  Pool writes are masked inside the step
-        via ``write_ok=live`` (a live verify step consumes a token the
-        round has already committed, so its pool write is final); only
-        ring/carry keys need the live-mask rollback, exactly as in the
-        plain paged chunk body."""
-        tgt, drf = params["target"], params["draft"]
-        with self._trace_ctx():
-            if self.mesh is not None:
-                state = {**state, "caches": _constrain_caches(
-                    state["caches"], self.mesh, self.strategies)}
-
-            def target_step(tok, pos, caches, live):
-                return self._paged_step_model.apply(
-                    tgt, tok, pos, caches, table, live)
-
-            def draft_step(tok, pos, dc):
-                return self._draft_step_model.apply(drf, tok, pos, dc)
-
-            def merge_caches(live, new, old):
-                def mrg(n, o):
-                    m = live.reshape((-1,) + (1,) * (o.ndim - 1))
-                    return jnp.where(m, n, o)
-                return {
-                    **{k: jax.tree.map(mrg, new[k], old[k])
-                       for k in self._RING_KEYS},
-                    "sgu_pool": new["sgu_pool"],
-                    **({"sgu_pool_scale": new["sgu_pool_scale"]}
-                       if "sgu_pool_scale" in new else {}),
-                }
-
-            emitted = jnp.zeros((), jnp.int32)
-            rounds = jnp.zeros((), jnp.int32)
-            for _ in range(self._spec_rounds):
-                live0 = state["active"] & ~state["done"] & ~paused
-                state, em = spec_round(
-                    state, spec_k=self.spec_k, max_len=self.max_len,
-                    eos_id=EOS_ID, target_step=target_step,
-                    draft_step=draft_step, merge_caches=merge_caches,
-                    live0=live0)
-                emitted = emitted + jnp.sum(em)
-                rounds = rounds + jnp.any(live0).astype(jnp.int32)
-        return state, {"emitted": emitted, "rounds": rounds}
+    def _admit_impl(self, params, state, src, mask, *args):
+        """One admission run: prefill only the rows being admitted —
+        ``args`` is ``_prefill_worker_impl``'s arguments over ``R =
+        self.admit_rows`` rows, ``tokens (R, P_pad)`` first, then the
+        layout's merge operands (none, or the R-row write table) — and
+        gather the R-row handle into the slots the host chose (``src
+        (S,)`` slot -> handle row, ``mask (S,)`` the slots admitted).  The
+        composition of the two halves disaggregated serving runs as
+        separate programs; unused handle rows carry a dummy one-token
+        prime and land nowhere."""
+        n = len(args) - self._layout.merge_operands
+        handle = self._prefill_worker_impl(params, *args[:n])
+        return self._merge_impl(state, *self._layout.split_handle(handle),
+                                src, mask, *args[n:])
 
     # ------------------------------------------------- disaggregated serving
 
@@ -1166,10 +937,10 @@ class ServingEngine:
         """The prefill half of admission, with NO slot state in scope:
         one parallel forward over ``tokens (rows, P_pad)`` whose product
         is a handle of ``(rows, ...)`` slabs that ``_merge_impl`` gathers
-        into slots — inside the same program for dense admission
+        into slots — inside the same program for inline admission
         (``_admit_impl``), as a program of its own under disaggregated
-        serving.  Gate rows stay dense here even in paged mode (the
-        worker cannot know which pool pages the rows will land in; the
+        serving.  Gate rows stay dense here whatever the cache layout (the
+        worker cannot know which pool pages the rows will land in; a paged
         merge scatters them through a row-indexed write table).
         ``tenant (S,)`` rides only under LoRA and travels in the handle
         state so the decode side keeps gathering the right adapter."""
@@ -1177,9 +948,7 @@ class ServingEngine:
             last, caches, stats = self.family.prefill(
                 self._target_params(params), tokens, lengths, self.max_len,
                 self._adapters(params), tenant)
-            if self.mesh is not None:
-                caches = _constrain_caches(caches, self.mesh,
-                                           self.strategies)
+            caches = _constrain_caches(caches, self.mesh, self.strategies)
             if self.spec:
                 _, dvarz = self._draft_prefill_model.apply(
                     params["draft"], tokens, mutable=["cache"])
@@ -1236,41 +1005,16 @@ class ServingEngine:
         copy; inside ``_admit_impl`` it is that program's own
         intermediate.  A gather (host-inverted mapping) rather than a scatter of
         handle rows: no duplicate-index hazard, and dead rows vanish for
-        free.  In paged mode the handle's dense gate slabs ride in as
-        ``gate_rows`` (NOT donated — they scatter into the pool, so they
-        cannot alias anything) and ``extra[0]`` is ``row_wtable (S,
-        ppr)``: a handle-ROW-indexed write table (DUMP for unused rows)
-        feeding ``scatter_gate_rows``."""
+        free.  ``gate_rows`` and ``extra`` are the cache layout's: what it
+        split out of the handle (NOT donated) and its merge operands."""
         csrc = jnp.clip(src, 0, hstate["pos"].shape[0] - 1)
 
         def take(h, old):
             m = mask.reshape((-1,) + (1,) * (old.ndim - 1))
             return jnp.where(m, jnp.take(h, csrc, axis=0), old)
 
-        if self.paged:
-            (row_wtable,) = extra
-            h_caches = hstate["caches"]
-            if self.gate_dtype == "int8":
-                # handle slabs arrive in compute dtype; they quantize
-                # here, at the page-pool boundary
-                pool, pscale = scatter_gate_rows(
-                    self.config, gate_rows, hstate["start"],
-                    state["caches"]["sgu_pool"], row_wtable,
-                    pool_scale=state["caches"]["sgu_pool_scale"])
-            else:
-                pool = scatter_gate_rows(
-                    self.config, gate_rows, hstate["start"],
-                    state["caches"]["sgu_pool"], row_wtable)
-            caches = {
-                **{k: jax.tree.map(take, h_caches[k], state["caches"][k])
-                   for k in self._RING_KEYS},
-                "sgu_pool": pool,
-                **({"sgu_pool_scale": pscale}
-                   if self.gate_dtype == "int8" else {}),
-            }
-        else:
-            caches = jax.tree.map(take, hstate["caches"],
-                                  state["caches"])
+        caches = self._layout.merge(take, state["caches"], hstate,
+                                    gate_rows, extra)
         out = {
             "seq": take(hstate["seq"], state["seq"]),
             "caches": caches,
@@ -1302,15 +1046,7 @@ class ServingEngine:
 
     def _merge_call(self, hstate, *args):
         fn = self._aot.get(("merge",), self._merge)
-        if self.paged:
-            # split the gate slabs out of the donated handle (they
-            # scatter, never alias; donating them only warns)
-            gate = hstate["caches"]["sgu_gate"]
-            hstate = {**hstate, "caches": {
-                k: v for k, v in hstate["caches"].items()
-                if k != "sgu_gate"}}
-            return fn(self.state, hstate, gate, *args)
-        return fn(self.state, hstate, {}, *args)
+        return fn(self.state, *self._layout.split_handle(hstate), *args)
 
     # ----------------------------------------------------------------- API
 
@@ -1525,6 +1261,14 @@ class ServingEngine:
                 + sum(len(v) for v in self._fork_wait.values()))
 
     @property
+    def prefix_hits(self) -> int:
+        return self._layout.prefix_hits
+
+    @property
+    def prefix_lookups(self) -> int:
+        return self._layout.prefix_lookups
+
+    @property
     def num_active(self) -> int:
         return len(self._inflight)
 
@@ -1603,13 +1347,9 @@ class ServingEngine:
             (self.state["active"], self.state["seq"], self.state["pos"],
              self.state["start"]))
         for slot in slots:
-            r = self._inflight.pop(slot)
             toks = (seq[slot, start[slot]: pos[slot] + 1].copy()
                     if active[slot] else None)
-            if self.paged:
-                self._host_stop[slot] = 0
-                self._free_slot_pages(slot)
-            self._shed(r, SHED_DEADLINE, tokens=toks)
+            self._shed(self._vacate(slot), SHED_DEADLINE, tokens=toks)
         self._deactivate(slots)
 
     # ----------------------------------------------------------- admission
@@ -1630,11 +1370,8 @@ class ServingEngine:
             return
         while self._queue and self._inflight:
             head = self._queue[0]
-            blocked = len(self._inflight) >= self.num_slots
-            if not blocked and self.paged:
-                need = pages_for_span(len(head.tokens), self.page_size)
-                blocked = not self._pool.can_allocate(need)
-            if not blocked:
+            if (len(self._inflight) < self.num_slots
+                    and self._layout.covers([head])):
                 return
             victim = min(
                 self._inflight,
@@ -1650,35 +1387,183 @@ class ServingEngine:
         seniority among same-class peers but waits behind the class that
         displaced it (contrast :meth:`_evict_slot`, whose front-of-queue
         requeue is the pool-starvation replay path)."""
-        r = self._inflight.pop(slot)
-        if self.paged:
-            self._host_stop[slot] = 0
-            self._free_slot_pages(slot)
-        else:
-            self._admit_order.pop(slot, None)
+        r = self._vacate(slot)
         self._deactivate([slot])
         self._queue.append(r)
         self.robust.preemptions += 1
         self._tracer.event("serve.preempt", trace=r.uid, slot=slot)
 
     def _admit_pending(self) -> None:
+        """Inline admission: admit what fits, ``admit_rows`` requests per
+        run of the admission program and as many runs as the group needs,
+        one in flight at a time and with no fetch between them.
+        Bookkeeping and fault handling are per run: a run whose prefill
+        was lost sheds or re-queues its own requests and leaves the
+        earlier runs of the group in their slots."""
         if not self._queue:
             return
         self._maybe_preempt()
-        if len(self._inflight) >= self.num_slots:
+        if (len(self._inflight) >= self.num_slots
+                or not self._admission_open(self._queue)):
             return
+        stage = None
+        while self._queue and len(self._inflight) < self.num_slots:
+            placed = self._new_placement(self.admit_rows)
+            requests: list[Request] = []
+            try:
+                # host work with the device idle (the first run) or busy
+                # with the run before: slots, pages, host arrays, the mask
+                with self._span("serve.admit_build") as build:
+                    t_build = time.perf_counter()
+                    requests = self._take_requests()
+                    if not requests:
+                        return      # the head waits for pages
+                    self._note_admitted(requests, t_build)
+                    uids = [r.uid for r in requests]
+                    build.note(uids=uids)
+                    p_pad = self.family.bucket(
+                        max(len(r.tokens) for r in requests), self.max_len)
+                    self._place(placed, list(enumerate(requests)), p_pad)
+                    args = (placed.src, placed.mask,
+                            *self._prefill_args(self.admit_rows, requests,
+                                                p_pad),
+                            *placed.operands)
+                if stage is not None:
+                    # the state is not donated, so every run in flight
+                    # holds a copy of it: the run before has to be done (no
+                    # transfer, the arrays above are built already) before
+                    # this one is dispatched, and two copies live at once
+                    # however large the group — what the chunk program
+                    # needs anyway
+                    t_wait = time.perf_counter()
+                    with self._span("serve.device_wait", after="admit"):
+                        # graftcheck: disable=host-sync
+                        jax.block_until_ready(self.state["pos"])
+                    self._step_wait += time.perf_counter() - t_wait
+
+                t0 = time.perf_counter()
+                with self._span("serve.admit_prefill", uids=uids,
+                                p_pad=p_pad):
+                    self.state = self._guard(
+                        "serve.prefill", self._admit_call, p_pad, *args,
+                        key=("admit", p_pad))
+            except _ContainedFault as e:
+                self._unplace(placed)
+                if e.point == "serve.page_alloc":
+                    # only the request whose planning faulted is shed; the
+                    # innocents (planned before it or never reached) go
+                    # back to the queue front in order
+                    lost = [placed.batch[-1][1]]
+                    for r in reversed(requests):
+                        if r is not lost[0]:
+                            self._queue.appendleft(r)
+                else:
+                    # the run's prefill never merged: shed exactly the
+                    # requests whose work was lost; what is still queued
+                    # waits for the next step
+                    lost = requests
+                for r in lost:
+                    self._shed(r, FAILED_FAULT)
+                return
+            except RetryError:
+                # escape for restart-and-replay, but leave the engine
+                # consistent: the un-prefilled run goes back to the queue
+                # front in its original order
+                self._unplace(placed)
+                for r in reversed(requests):
+                    self._queue.appendleft(r)
+                raise
+            self._publish_prefixes(placed)
+            self._admit_rows_hist.observe(len(requests))
+            # the admit program samples each request's first token; that
+            # it has RUN is known at the next flags fetch, which stamps
+            # first-token time and closes the stage: ONE stage per
+            # admitting step, from the dispatch of the group's first run
+            if stage is None:
+                stage = []
+                self._open_stages.append(("prefill_s", "admit", t0, stage))
+            stage.extend(requests)
+
+    def _admission_open(self, queue) -> bool:
+        """``serve.admit``, before a round takes from ``queue``.  False
+        when the admission machinery is poisoned for this round: the
+        queue's head is shed (livelock breaker — a permanently faulting
+        point must not starve the whole queue) and the rest waits."""
         try:
             self._guard("serve.admit")
         except _ContainedFault:
-            # the admission machinery is poisoned for this round: shed the
-            # queue head (livelock breaker — a permanently faulting point
-            # must not starve the whole queue) and defer the rest
-            self._shed(self._queue.popleft(), FAILED_FAULT)
-            return
-        if self.paged:
-            self._admit_pending_paged()
-        else:
-            self._admit_pending_dense()
+            self._shed(queue.popleft(), FAILED_FAULT)
+            return False
+        return True
+
+    def _take_requests(self) -> list[Request]:
+        """Up to ``admit_rows`` requests off the queue, as many as there
+        are free slots and — through the cache layout — free pages.
+
+        The head of the queue is taken only if the pool can cover its
+        whole prime plus the first sampled token WITHOUT prefix sharing
+        (a conservative bound — planning shares whatever it can, so the
+        allocation never exceeds the reservation); a blocked head DEFERS
+        everything behind it.  "Head" is whatever the QoS scheduler ranks
+        first RIGHT NOW (priority, then weighted-fair tenant share, then
+        EDF) — within one admission round the order is fixed, across
+        rounds a higher-priority arrival may overtake a deferred head
+        (that, plus :meth:`_maybe_preempt`, is the QoS contract; pre-QoS
+        FIFO deferral is the degenerate single-class case)."""
+        room = min(self.admit_rows, self.num_slots - len(self._inflight))
+        requests: list[Request] = []
+        while self._queue and len(requests) < room:
+            if not self._layout.covers(requests + [self._queue[0]]):
+                break  # head-of-line blocks: deferral, not reordering
+            requests.append(self._queue.popleft())
+        return requests
+
+    # ---- booking slots: shared by inline and handed-off admission
+
+    def _new_placement(self, n_rows: int) -> "_Placement":
+        s = self.num_slots
+        return _Placement(batch=[], src=np.zeros((s,), np.int32),
+                          mask=np.zeros((s,), bool),
+                          operands=self._layout.write_tables(n_rows),
+                          prefixes=[])
+
+    def _place(self, placed: "_Placement", rows: list, p_pad: int) -> None:
+        """Book each ``(handle row, request)`` of ``rows`` into a free
+        slot (the caller has counted them) and let the cache layout plan
+        its pages.  ``placed`` is filled as it goes, so that whatever
+        raises — a fault while planning, or the caller's dispatch later —
+        :meth:`_unplace` takes back exactly what was booked."""
+        free = [i for i in range(self.num_slots) if i not in self._inflight]
+        for slot, (row, r) in zip(free, rows):
+            placed.src[slot] = row
+            placed.mask[slot] = True
+            self._inflight[slot] = r
+            self._admit_order[slot] = self._admit_seq
+            self._admit_seq += 1
+            placed.batch.append((slot, r))
+            self._layout.plan(self._guard, slot, row, r, p_pad,
+                              placed.operands, placed.prefixes)
+
+    def _unplace(self, placed: "_Placement") -> None:
+        """The dispatch never ran: free the slots and the planned pages.
+        They hold nothing, and no prefix registration was committed (the
+        deferred ones die with ``placed``), so the index cannot serve a
+        garbage page."""
+        for slot, _ in reversed(placed.batch):
+            self._vacate(slot)
+
+    def _publish_prefixes(self, placed: "_Placement") -> None:
+        """The prefill is dispatched: NOW the freshly-filled full-prefix
+        pages may be published for sharing."""
+        for key, pid in placed.prefixes:
+            self._pool.register_prefix(key, pid)
+
+    def _vacate(self, slot: int) -> Request:
+        """Take ``slot``'s request out of it: the booking, and what the
+        cache layout holds for the slot on the host."""
+        self._admit_order.pop(slot, None)
+        self._layout.free(slot)
+        return self._inflight.pop(slot)
 
     def _build_lmask(self, n_rows: int, rows: list) -> np.ndarray:
         """``(n_rows, max_len, V)`` write-position-indexed logit masks for
@@ -1725,235 +1610,6 @@ class ServingEngine:
         extra = (tenant,) if self.lora else ()
         return (tokens, lengths, stops, seeds, top_k, temp, lmask, *extra)
 
-    def _admit_pending_dense(self) -> None:
-        """Admit what fits, ``admit_rows`` requests per run of the
-        admission program and as many runs as the group needs, one in
-        flight at a time and with no fetch between them.  Bookkeeping and
-        fault handling are per run: a run whose prefill was lost sheds or
-        re-queues its own requests and leaves the earlier runs of the
-        group in their slots."""
-        stage = None
-        while self._queue and len(self._inflight) < self.num_slots:
-            # host work with the device idle (the first run) or busy with
-            # the run before: slots, host arrays, the mask
-            with self._span("serve.admit_build") as build:
-                batch, args, p_pad = self._build_dense_admission()
-                uids = [r.uid for _, r in batch]
-                build.note(uids=uids)
-            if stage is not None:
-                # the state is not donated, so every run in flight holds a
-                # copy of it: the run before has to be done (no transfer,
-                # the arrays above are built already) before this one is
-                # dispatched, and two copies live at once however large
-                # the group — what the chunk program needs anyway
-                t_wait = time.perf_counter()
-                with self._span("serve.device_wait", after="admit"):
-                    # graftcheck: disable=host-sync
-                    jax.block_until_ready(self.state["pos"])
-                self._step_wait += time.perf_counter() - t_wait
-
-            t0 = time.perf_counter()
-            try:
-                with self._span("serve.admit_prefill", uids=uids,
-                                p_pad=p_pad):
-                    self.state = self._guard(
-                        "serve.prefill", self._admit_call, p_pad, *args,
-                        key=("admit", p_pad))
-            except _ContainedFault:
-                # the run's prefill never merged: undo the bookkeeping and
-                # shed exactly the requests whose work was lost; what is
-                # still queued waits for the next step
-                for slot, r in batch:
-                    self._inflight.pop(slot, None)
-                    self._shed(r, FAILED_FAULT)
-                return
-            except RetryError:
-                # escape for restart-and-replay, but leave the engine
-                # consistent: the un-prefilled run goes back to the queue
-                # front in its original order
-                for slot, r in reversed(batch):
-                    self._inflight.pop(slot, None)
-                    self._queue.appendleft(r)
-                raise
-            self._admit_rows_hist.observe(len(batch))
-            # the admit program samples each request's first token; that
-            # it has RUN is known at the next flags fetch, which stamps
-            # first-token time and closes the stage: ONE stage per
-            # admitting step, from the dispatch of the group's first run
-            if stage is None:
-                stage = []
-                self._open_stages.append(("prefill_s", "admit", t0, stage))
-            stage.extend(r for _, r in batch)
-
-    def _build_dense_admission(self):
-        """Take up to ``admit_rows`` requests out of the queue into free
-        slots (the caller has seen one of each) and fill the host arrays
-        of one admission run: ``(batch, arguments of the admit program
-        after params and state, prefill bucket)``."""
-        t_build = time.perf_counter()
-        free = [i for i in range(self.num_slots) if i not in self._inflight]
-        requests: list[Request] = []
-        while self._queue and len(requests) < min(self.admit_rows,
-                                                  len(free)):
-            requests.append(self._queue.popleft())
-        batch = list(zip(free, requests))
-        self._note_admitted(requests, t_build)
-
-        longest = max(len(r.tokens) for r in requests)
-        p_pad = self.family.bucket(longest, self.max_len)
-        src = np.zeros((self.num_slots,), np.int32)
-        mask = np.zeros((self.num_slots,), bool)
-        for row, (slot, r) in enumerate(batch):
-            src[slot] = row
-            mask[slot] = True
-            self._inflight[slot] = r
-            self._admit_order[slot] = self._admit_seq
-            self._admit_seq += 1
-        prefill = self._prefill_args(self.admit_rows, requests, p_pad)
-        return batch, (src, mask, *prefill), p_pad
-
-    def _admit_pending_paged(self) -> None:
-        with self._span("serve.admit_build") as build:
-            built = self._build_paged_admission()
-            if built is None:
-                return
-            batch, args, p_pad, pending_prefix = built
-            uids = [r.uid for _, r in batch]
-            build.note(uids=uids)
-
-        t0 = time.perf_counter()
-        try:
-            with self._span("serve.admit_prefill", uids=uids, p_pad=p_pad):
-                self.state = self._guard(
-                    "serve.prefill", self._admit_call, p_pad, *args,
-                    key=("admit", p_pad))
-        except _ContainedFault:
-            # prefill never merged: the planned pages hold nothing — free
-            # them (no prefix registration was committed, so the index
-            # cannot serve a garbage page) and shed the batch
-            for slot, r in batch:
-                self._inflight.pop(slot, None)
-                self._host_stop[slot] = 0
-                self._free_slot_pages(slot)
-                self._shed(r, FAILED_FAULT)
-            return
-        except RetryError:
-            for slot, r in reversed(batch):
-                self._inflight.pop(slot, None)
-                self._host_stop[slot] = 0
-                self._free_slot_pages(slot)
-                self._queue.appendleft(r)
-            raise
-        # prefill dispatched: NOW the freshly-filled full-prefix pages may
-        # be published for sharing
-        for key, pid in pending_prefix:
-            self._pool.register_prefix(key, pid)
-        self._open_stages.append(
-            ("prefill_s", "admit", t0, [r for _, r in batch]))
-
-    def _build_paged_admission(self):
-        """FIFO admission gated by free slots AND free pages: take what
-        fits, plan its pages and fill the host arrays.  Returns ``(batch,
-        arguments of the admit program after params and state, prefill
-        bucket, deferred prefix registrations)``, or None when nothing was
-        admitted (blocked head, or a contained planning fault).
-
-        The head of the queue is admitted only if the pool can cover its
-        whole prime plus the first sampled token WITHOUT prefix sharing
-        (a conservative bound — actual planning below shares whatever it
-        can, so the allocation never exceeds the reservation); a blocked
-        head DEFERS everything behind it.  "Head" is whatever the QoS
-        scheduler ranks first RIGHT NOW (priority, then weighted-fair
-        tenant share, then EDF) — within one admission round the order
-        is fixed, across rounds a higher-priority arrival may overtake
-        a deferred head (that, plus :meth:`_maybe_preempt`, is the QoS
-        contract; pre-QoS FIFO deferral is the degenerate single-class
-        case).
-        """
-        t_build = time.perf_counter()
-        free = [i for i in range(self.num_slots) if i not in self._inflight]
-        batch: list[tuple[int, Request]] = []
-        reserved = 0
-        while free and self._queue:
-            r = self._queue[0]
-            need = pages_for_span(len(r.tokens), self.page_size)
-            if not self._pool.can_allocate(reserved + need):
-                break  # head-of-line blocks: deferral, not reordering
-            reserved += need
-            batch.append((free.pop(0), self._queue.popleft()))
-        if not batch:
-            return None
-        self._note_admitted([r for _, r in batch], t_build)
-
-        s = self.num_slots
-        longest = max(len(r.tokens) for _, r in batch)
-        p_pad = pad_prime_length(longest, self.config.window_size,
-                                 self.config.seq_len, bucket=True)
-        tokens = np.zeros((s, p_pad), np.int32)
-        lengths = np.ones((s,), np.int32)  # dummy rows: 1-token prime
-        stops = np.full((s,), 2, np.int32)
-        seeds = np.zeros((s,), np.uint32)
-        top_k = np.zeros((s,), np.int32)
-        temp = np.ones((s,), np.float32)
-        mask = np.zeros((s,), bool)
-        tenant = np.zeros((s,), np.int32)
-        wtable = np.full((s, self.pages_per_row), DUMP_PAGE, np.int32)
-        pending_prefix: list[tuple[tuple, int]] = []
-        planned: list[tuple[int, Request]] = []
-        try:
-            for slot, r in batch:
-                t = np.asarray(r.tokens, np.int32)
-                tokens[slot, : len(t)] = t
-                lengths[slot] = len(t)
-                stops[slot] = min(len(t) + r.max_new_tokens, self.max_len)
-                seeds[slot] = np.uint32(int(r.seed) & 0xFFFFFFFF)
-                top_k[slot] = 0 if r.top_k is None else int(r.top_k)
-                temp[slot] = float(r.temperature)
-                mask[slot] = True
-                tenant[slot] = int(r.tenant)
-                self._inflight[slot] = r
-                self._host_stop[slot] = stops[slot]
-                self._admit_order[slot] = self._admit_seq
-                self._admit_seq += 1
-                self._paused[slot] = False
-                # planning allocates (and retains shared) pages — a
-                # faultable operation, guarded at the SAME point as the
-                # chunk-growth allocator.  A contained fault mid-batch
-                # rolls back every page planned so far AND the deferred
-                # registrations (pending_prefix dies with this frame) —
-                # the fork path leans on exactly this discipline
-                self._guard("serve.page_alloc", self._plan_slot_pages,
-                            slot, r, p_pad, wtable, pending_prefix)
-                planned.append((slot, r))
-        except _ContainedFault:
-            j = len(planned)
-            for slot, r in reversed(batch[: j + 1]):
-                self._inflight.pop(slot, None)
-                self._host_stop[slot] = 0
-                self._free_slot_pages(slot)
-            # innocents (planned before the fault or never reached) go
-            # back to the queue front in order; only the request whose
-            # planning faulted is shed
-            innocents = [r for _, r in batch[:j] + batch[j + 1:]]
-            for r in reversed(innocents):
-                self._queue.appendleft(r)
-            self._shed(batch[j][1], FAILED_FAULT)
-            return None
-        except RetryError:
-            j = len(planned)
-            for slot, r in reversed(batch[: j + 1]):
-                self._inflight.pop(slot, None)
-                self._host_stop[slot] = 0
-                self._free_slot_pages(slot)
-            for _, r in reversed(batch):
-                self._queue.appendleft(r)
-            raise
-        lmask = self._build_lmask(s, batch)
-        extra = (tenant,) if self.lora else ()
-        return batch, (tokens, lengths, stops, seeds, top_k, temp, mask,
-                       lmask, self._page_table.copy(), wtable,
-                       *extra), p_pad, pending_prefix
-
     # ---------------------------------------------------------- embeddings
 
     def _embed_round(self) -> None:
@@ -1962,21 +1618,15 @@ class ServingEngine:
         ``embed_batch`` rows, one pooled forward, completions with the
         ``(D,)`` vector attached.  No slot state is touched — embedding
         traffic composes with any decode configuration."""
-        if not self._embed_queue:
+        if not (self._embed_queue
+                and self._admission_open(self._embed_queue)):
             return
-        try:
-            self._guard("serve.admit")
-        except _ContainedFault:
-            self._shed(self._embed_queue.popleft(), FAILED_FAULT)
-            return
-        cfg = self.config
-        p_pad = pad_prime_length(len(self._embed_queue[0].tokens),
-                                 cfg.window_size, cfg.seq_len, bucket=True)
+        bucket = self.family.bucket
+        p_pad = bucket(len(self._embed_queue[0].tokens), self.max_len)
         batch: list[Request] = []
         while (self._embed_queue and len(batch) < self.embed_batch
-               and pad_prime_length(len(self._embed_queue[0].tokens),
-                                    cfg.window_size, cfg.seq_len,
-                                    bucket=True) == p_pad):
+               and bucket(len(self._embed_queue[0].tokens),
+                          self.max_len) == p_pad):
             batch.append(self._embed_queue.popleft())
 
         b = self.embed_batch
@@ -2025,23 +1675,16 @@ class ServingEngine:
         handle.  A full handoff queue skips the round entirely —
         backpressure: prefilled caches are the expensive thing to hold,
         so the wait is absorbed by the cheap token queue instead."""
-        if not self._queue or self._handoff.full():
+        if (not self._queue or self._handoff.full()
+                or not self._admission_open(self._queue)):
             return
-        try:
-            self._guard("serve.admit")
-        except _ContainedFault:
-            # same livelock breaker as inline admission: shed the head
-            self._shed(self._queue.popleft(), FAILED_FAULT)
-            return
-        cfg = self.config
-        p_pad = pad_prime_length(len(self._queue[0].tokens),
-                                 cfg.window_size, cfg.seq_len, bucket=True)
+        bucket = self.family.bucket
+        p_pad = bucket(len(self._queue[0].tokens), self.max_len)
         t_build = time.perf_counter()
         batch: list[Request] = []
         while (self._queue and len(batch) < self.prefill_batch
-               and pad_prime_length(len(self._queue[0].tokens),
-                                    cfg.window_size, cfg.seq_len,
-                                    bucket=True) == p_pad):
+               and bucket(len(self._queue[0].tokens),
+                          self.max_len) == p_pad):
             batch.append(self._queue.popleft())
         self._note_admitted(batch, t_build)
 
@@ -2075,7 +1718,7 @@ class ServingEngine:
         """Decode-side admission: move queued handles into free slots via
         the donating merge program.  The head handle DEFERS (never
         reorders) while slots or pages are short, exactly like inline
-        paged admission."""
+        admission."""
         while self._handoff:
             h = self._handoff.peek()
             now = time.perf_counter()
@@ -2087,15 +1730,10 @@ class ServingEngine:
                     expired.append(r)
                 else:
                     live_rows.append((row, r))
-            free = [i for i in range(self.num_slots)
-                    if i not in self._inflight]
-            if len(free) < len(live_rows):
+            admitted = [r for _, r in live_rows]
+            if (self.num_slots - len(self._inflight) < len(live_rows)
+                    or not self._layout.covers(admitted)):
                 return
-            if self.paged and live_rows:
-                need = sum(pages_for_span(len(r.tokens), self.page_size)
-                           for _, r in live_rows)
-                if not self._pool.can_allocate(need):
-                    return
             # peek-then-pop: ``h`` above came from front() without
             # consuming; this get() pops that same handle now that
             # admission is committed — ownership continues in ``h``
@@ -2104,136 +1742,49 @@ class ServingEngine:
             # a remote-prefill handle's requests were never in this
             # engine's queue: they are admitted here (a local prefill
             # round's earlier stamp stands)
-            self._note_admitted([r for _, r in live_rows], now)
+            self._note_admitted(admitted, now)
             if live_rows:
-                src = np.zeros((self.num_slots,), np.int32)
-                mask = np.zeros((self.num_slots,), bool)
-                extra: tuple = ()
-                pending_prefix: list[tuple[tuple, int]] = []
-                placed: list[tuple[int, Request]] = []
-                if self.paged:
-                    # the merge scatters the handle's dense gate slabs
-                    # through a handle-ROW-indexed write table; the page
-                    # plan is slot-indexed, so plan into a slot scratch
-                    # row and copy it across
-                    row_wtable = np.full(
-                        (self.num_slots, self.pages_per_row), DUMP_PAGE,
-                        np.int32)
-                    scratch = np.full(
-                        (self.num_slots, self.pages_per_row), DUMP_PAGE,
-                        np.int32)
-                for slot, (row, r) in zip(free, live_rows):
-                    src[slot] = row
-                    mask[slot] = True
-                    self._inflight[slot] = r
-                    placed.append((slot, r))
-                    if self.paged:
-                        self._host_stop[slot] = min(
-                            len(r.tokens) + r.max_new_tokens, self.max_len)
-                        self._admit_order[slot] = self._admit_seq
-                        self._admit_seq += 1
-                        self._paused[slot] = False
-                        self._plan_slot_pages(slot, r, h.p_pad, scratch,
-                                              pending_prefix)
-                        row_wtable[row] = scratch[slot]
-                if self.paged:
-                    extra = (row_wtable,)
-                t0 = time.perf_counter()
+                # the write table is handle-ROW-indexed, like every slab
+                # the worker produced
+                placed = self._new_placement(len(h.state["pos"]))
                 try:
+                    self._place(placed, live_rows, h.p_pad)
+                    t0 = time.perf_counter()
                     # the merge DONATES the handle's buffers; this stays
                     # retry/requeue-safe because faults.inject raises
                     # BEFORE the jitted program dispatches — a contained
                     # or transient failure here has not consumed them
                     with self._span("serve.merge",
-                                    uids=[r.uid for _, r in live_rows]):
+                                    uids=[r.uid for r in admitted]):
                         self.state = self._guard(
                             "serve.handoff", self._merge_call, h.state,
-                            src, mask, *extra, key=("merge",))
+                            placed.src, placed.mask, *placed.operands,
+                            key=("merge",))
                 except _ContainedFault:
-                    for slot, r in placed:
-                        self._inflight.pop(slot, None)
-                        if self.paged:
-                            self._host_stop[slot] = 0
-                            self._free_slot_pages(slot)
+                    self._unplace(placed)
+                    for r in admitted:
                         self._shed(r, FAILED_FAULT)
                 except RetryError:
-                    for slot, r in placed:
-                        self._inflight.pop(slot, None)
-                        if self.paged:
-                            self._host_stop[slot] = 0
-                            self._free_slot_pages(slot)
+                    self._unplace(placed)
                     # expired rows were NOT shed yet, so the requeued
                     # handle replays them all exactly once after restart
                     self._handoff.requeue(h)
                     raise
                 else:
-                    for key, pid in pending_prefix:
-                        self._pool.register_prefix(key, pid)
+                    self._publish_prefixes(placed)
                     # the first tokens came with the handle; the next
                     # flags fetch shows the merged state and stamps them
                     self._open_stages.append(
-                        ("merge_s", "admit", t0, [r for _, r in live_rows]))
+                        ("merge_s", "admit", t0, admitted))
             for r in expired:
                 self._shed(r, SHED_DEADLINE)
-
-    def _plan_slot_pages(self, slot: int, r: Request, p_pad: int,
-                         wtable: np.ndarray,
-                         pending_prefix: list[tuple[tuple, int]]) -> None:
-        """Build the slot's page list for rows ``[0, P]`` (prime + first
-        sampled token): longest run of prefix-cache hits first, fresh
-        private pages for the rest.  Fills the slot's ``_page_table`` row
-        and its ``wtable`` row (private pages only — shared pages were
-        filled by the request that first computed them and MUST stay
-        read-only: rewriting them from a different prefill batch shape
-        could perturb the sharer's bits).
-
-        Fresh full-prefix pages are NOT registered here: registrations
-        collect in ``pending_prefix`` and commit only after the guarded
-        prefill dispatch succeeds — a failed prefill must never leave the
-        index pointing at pages that were never filled."""
-        ps = self.page_size
-        p = len(r.tokens)
-        n_pages = p // ps + 1  # decode writes row P before any page grows
-        n_full = p // ps       # full pages strictly inside the prime
-        shared: list[int] = []
-        for j in range(n_full):
-            pid = self._pool.lookup_prefix(prefix_key(p_pad, r.tokens,
-                                                      (j + 1) * ps))
-            if pid is None:
-                break
-            shared.append(pid)
-        fresh = self._pool.allocate(n_pages - len(shared))
-        assert fresh is not None, "admission reserved pages conservatively"
-        for pid in shared:
-            self._pool.retain(pid)
-        self.prefix_hits += len(shared)
-        self.prefix_lookups += n_full
-        pages = shared + fresh
-        for j in range(len(shared), n_full):
-            pending_prefix.append(
-                (prefix_key(p_pad, r.tokens, (j + 1) * ps), pages[j]))
-        self._slot_pages[slot] = SlotPages(pages=pages, shared=len(shared))
-        self._page_table[slot, :] = NULL_PAGE
-        self._page_table[slot, : n_pages] = pages
-        wtable[slot, : n_pages] = [DUMP_PAGE] * len(shared) + fresh
-
-    def _free_slot_pages(self, slot: int) -> None:
-        sp = self._slot_pages.pop(slot, None)
-        if sp is None:
-            return
-        for pid in sp.pages:
-            self._pool.release(pid)
-        self._page_table[slot, :] = NULL_PAGE
-        self._paused[slot] = False
-        self._admit_order.pop(slot, None)
 
     def _evict_slot(self, slot: int) -> None:
         """Restart preemption: free the slot's pages and push its request
         back to the FRONT of the queue.  Replaying from scratch is safe —
         a trajectory depends only on (params, prime, seed, knobs), so the
         re-decode reproduces the identical token prefix."""
-        r = self._inflight.pop(slot)
-        self._free_slot_pages(slot)
+        r = self._vacate(slot)
         self._deactivate([slot])
         self._queue.appendleft(r)
         self.evictions += 1
@@ -2246,6 +1797,7 @@ class ServingEngine:
         live slot, the youngest is evicted until someone can run."""
         if not self._inflight:
             return
+        lay = self._layout
         try:
             self._guard("serve.page_alloc")
         except _ContainedFault as e:
@@ -2254,9 +1806,9 @@ class ServingEngine:
             # are delayed, never altered) and retry next round
             self._defer("page_alloc", e)
             for slot in self._inflight:
-                if not self._paused[slot]:
+                if not lay.paused[slot]:
                     self.pause_events += 1
-                self._paused[slot] = True
+                lay.paused[slot] = True
             return
         self._defer_streak.pop("page_alloc", None)
         pos = _host_fetch(
@@ -2270,32 +1822,33 @@ class ServingEngine:
                 # _max_advance == chunk_size except under speculation,
                 # where a chunk of fully-accepted rounds can advance
                 # rounds * (k + 1) positions
-                last = min(int(pos[slot]) + self._max_advance - 1,
-                           int(self._host_stop[slot]) - 2)
+                r = self._inflight[slot]
+                stop = min(len(r.tokens) + r.max_new_tokens, self.max_len)
+                last = min(int(pos[slot]) + self._max_advance - 1, stop - 2)
                 need = pages_for_span(last, self.page_size)
-                sp = self._slot_pages[slot]
-                delta = need - len(sp.pages)
+                pages = lay.slot_pages[slot]
+                delta = need - len(pages)
                 if delta <= 0:
-                    self._paused[slot] = False
+                    lay.paused[slot] = False
                     continue
-                fresh = self._pool.allocate(delta)
+                fresh = lay.pool.allocate(delta)
                 if fresh is None:
-                    if not self._paused[slot]:
+                    if not lay.paused[slot]:
                         self.pause_events += 1
-                    self._paused[slot] = True
+                    lay.paused[slot] = True
                     continue
-                base = len(sp.pages)
-                sp.pages.extend(fresh)
-                self._page_table[slot, base: base + delta] = fresh
-                self._paused[slot] = False
-            if any(not self._paused[s] for s in self._inflight):
+                base = len(pages)
+                pages.extend(fresh)
+                lay.table[slot, base: base + delta] = fresh
+                lay.paused[slot] = False
+            if any(not lay.paused[s] for s in self._inflight):
                 return
             # every live slot starved: evict the most recently admitted
             victim = max(self._inflight, key=self._admit_order.__getitem__)
             if len(self._inflight) == 1:
                 raise RuntimeError(
                     f"page pool too small for any progress: slot {victim} "
-                    f"needs pages beyond capacity {self._pool.capacity} "
+                    f"needs pages beyond capacity {lay.pool.capacity} "
                     f"with nothing left to evict")
             self._evict_slot(victim)
 
@@ -2336,9 +1889,8 @@ class ServingEngine:
             out = []
             now = time.perf_counter()
             for i in ready:
-                r = self._inflight.pop(i)
-                if self.paged:
-                    self._free_slot_pages(i)
+                r = self._inflight[i]
+                self._vacate(i)
                 toks = seq[i, start[i]: pos[i] + 1].copy()
                 reason = ("eos" if (toks.size and toks[-1] == EOS_ID)
                           else "length")
@@ -2381,9 +1933,7 @@ class ServingEngine:
             self._ensure_chunk_pages()
             if not self._inflight:
                 return  # everything got evicted back to the queue
-            args = (self._page_table.copy(), self._paused.copy())
-        else:
-            args = ()
+        args = self._layout.chunk_operands()
         point = "serve.verify" if self.spec else "serve.decode_chunk"
         while True:
             t0 = time.perf_counter()
@@ -2427,11 +1977,7 @@ class ServingEngine:
         serving."""
         slots = sorted(self._inflight)
         for slot in slots:
-            r = self._inflight.pop(slot)
-            if self.paged:
-                self._host_stop[slot] = 0
-                self._free_slot_pages(slot)
-            self._shed(r, FAILED_FAULT)
+            self._shed(self._vacate(slot), FAILED_FAULT)
         self._deactivate(slots)
 
     def step(self) -> list[Completion]:
@@ -2782,7 +2328,6 @@ class ServingEngine:
         u32 = partial(jax.ShapeDtypeStruct, dtype=jnp.uint32)
         f32 = partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
         b8 = partial(jax.ShapeDtypeStruct, dtype=jnp.bool_)
-        L, V = self.max_len, self.family.vocab
 
         def prefill_sd(rows, p_pad):
             """``_prefill_worker_impl``'s arguments after ``params``."""
@@ -2790,73 +2335,43 @@ class ServingEngine:
                   i32(rows), f32((rows,)), b8(self._lmask_shape(rows))]
             return sd + [i32(rows)] if self.lora else sd
 
-        for p_pad in buckets:
-            if (embed and self._embedder is not None
-                    and ("embed", p_pad) not in self._aot):
-                tgt_sd = as_shape(self._target_params(self._params))
-                self._aot[("embed", p_pad)] = self._embedder.lower(
-                    tgt_sd, i32(s, p_pad), i32(s)).compile()
-                self._compiled_keys.add(("embed", p_pad))
-                programs += 1
-            if self.disagg:
-                key = ("prefill", p_pad)
-                if key in self._aot:
-                    continue
-                self._aot[key] = self._prefill_worker.lower(
-                    params_sd, *prefill_sd(s, p_pad)).compile()
-                self._compiled_keys.add(key)
-                programs += 1
-                continue
-            key = ("admit", p_pad)
+        def build(key, program, *shapes) -> int:
+            """Compile ``program`` for ``shapes`` under ``key`` unless it
+            is there already; the number of programs built."""
             if key in self._aot:
-                continue
-            if self.paged:
-                # the paged program still prefills every slot
-                admit_args = [i32(s, p_pad), i32(s), i32(s), u32((s,)),
-                              i32(s), f32((s,)), b8((s,)), b8((s, L, V)),
-                              i32(s, self.pages_per_row),
-                              i32(s, self.pages_per_row)]
-                if self.lora:
-                    admit_args += [i32(s)]
-            else:
-                admit_args = [i32(s), b8((s,)),
-                              *prefill_sd(self.admit_rows, p_pad)]
-            self._aot[key] = self._admit.lower(
-                params_sd, state_sd, *admit_args).compile()
+                return 0
+            self._aot[key] = program.lower(*shapes).compile()
             self._compiled_keys.add(key)
-            programs += 1
+            return 1
+
+        rows, lay = self.admit_rows, self._layout
+        for p_pad in buckets:
+            if embed and self._embedder is not None:
+                programs += build(
+                    ("embed", p_pad), self._embedder,
+                    as_shape(self._target_params(self._params)),
+                    i32(s, p_pad), i32(s))
+            if self.disagg:
+                programs += build(("prefill", p_pad), self._prefill_worker,
+                                  params_sd, *prefill_sd(s, p_pad))
+            else:
+                programs += build(
+                    ("admit", p_pad), self._admit, params_sd, state_sd,
+                    i32(s), b8((s,)), *prefill_sd(rows, p_pad),
+                    *as_shape(lay.write_tables(rows)))
         if self.disagg and ("merge",) not in self._aot:
             # the handle's shape is bucket-independent (everything is
             # harvested to max_len), so any bucket's worker sizes it
             h_sd = jax.eval_shape(self._prefill_worker_impl, params_sd,
                                   *prefill_sd(s, buckets[0]))
-            gate_sd: dict = {}
-            if self.paged:
-                gate_sd = h_sd["caches"]["sgu_gate"]
-                h_sd = {**h_sd, "caches": {
-                    k: v for k, v in h_sd["caches"].items()
-                    if k != "sgu_gate"}}
-            merge_args = [state_sd, h_sd, gate_sd, i32(s), b8((s,))]
-            if self.paged:
-                merge_args += [i32(s, self.pages_per_row)]
-            self._aot[("merge",)] = (
-                self._merge.lower(*merge_args).compile())
-            self._compiled_keys.add(("merge",))
-            programs += 1
-        if ("chunk",) not in self._aot:
-            chunk_args = [params_sd, state_sd]
-            if self.paged:
-                chunk_args += [i32(s, self.pages_per_row),
-                               jax.ShapeDtypeStruct((s,), bool)]
-            self._aot[("chunk",)] = (
-                self._decode_chunk.lower(*chunk_args).compile())
-            self._compiled_keys.add(("chunk",))
-            programs += 1
-        if ("release",) not in self._aot:
-            # the one-operation program that clears finished slots' flags:
-            # not counted, but built here like everything step() runs
-            self._aot[("release",)] = _clear_rows.lower(
-                state_sd["active"], b8((s,))).compile()
+            programs += build(
+                ("merge",), self._merge, state_sd, *lay.split_handle(h_sd),
+                i32(s), b8((s,)), *as_shape(lay.write_tables(s)))
+        programs += build(("chunk",), self._decode_chunk, params_sd,
+                          state_sd, *as_shape(lay.chunk_operands()))
+        # the one-operation program that clears finished slots' flags: not
+        # counted, but built here like everything step() runs
+        build(("release",), _clear_rows, state_sd["active"], b8((s,)))
         return {"programs": programs,
                 "seconds": time.perf_counter() - t0}
 

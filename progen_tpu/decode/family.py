@@ -19,8 +19,8 @@ engine tests nothing else (no family's name, no config's type):
     the serving modes it has, of ``SERVING_MODES``; the engine refuses any
     other at construction with :class:`UnsupportedFamilyMode`
 ``step_model`` / ``prefill_model``
-    the modules those modes' programs call themselves (None for a family
-    with no such mode)
+    the flax modules behind ``decode_step`` / ``prefill`` (None for a family
+    that is plain functions)
 ``embedder(mesh, strategies)``
     the embedding program, or None for a family without one
 
@@ -40,7 +40,10 @@ engine tests nothing else (no family's name, no config's type):
     registry gauge values from the fetched counters
 
 Paged, speculative, LoRA, disaggregated, quantized and mesh serving are
-ProGen's alone today (its ``modes``); there is no fallback.
+ProGen's alone today (its ``modes``); there is no fallback.  Their programs
+are the plain path's own — one chunk body, one admission — reading the
+engine's cache layout (``decode/paging.py``): ``SlotCaches`` steps through
+this seam, ``PagedGates`` is ProGen's paged step.
 """
 
 from __future__ import annotations

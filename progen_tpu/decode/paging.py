@@ -16,11 +16,17 @@ token rows each) and a per-request PAGE TABLE mapping row index
   prompt's gate rows exist once in HBM no matter how many requests are
   decoding from it.
 
-This module is the HOST side only: free lists, refcounts and the prefix
+``PagePool`` is the HOST side: free lists, refcounts and the prefix
 index are plain Python (they make per-request decisions between device
 dispatches).  The device side — the pooled gate arrays, the page-table
 walk in the decode step, and the ragged paged mix kernel — lives in
 ``decode/incremental.py`` and ``ops/pallas_paged_attention.py``.
+
+The two CACHE LAYOUTS at the end of the module are what ``ServingEngine``
+knows of all this: ``SlotCaches`` (every cache row in its slot) and
+``PagedGates`` (ProGen's gate rows in the pool).  It picks one at
+construction, and its chunk body, its merge and its place / undo pair
+ask the layout for whatever differs between the two.
 
 Two pool pages are reserved:
 
@@ -35,10 +41,21 @@ Two pool pages are reserved:
 
 from __future__ import annotations
 
-import dataclasses
 import hashlib
 from collections import OrderedDict
-from typing import Sequence
+from typing import Callable, Sequence
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from progen_tpu.decode.incremental import (
+    ProGenPagedDecodeStep,
+    init_caches,
+    init_gate_pool,
+    init_gate_scale,
+)
+from progen_tpu.decode.prefill import scatter_gate_rows
 
 NULL_PAGE = 0
 DUMP_PAGE = 1
@@ -73,17 +90,6 @@ def prefix_key(p_pad: int, tokens: Sequence[int], upto: int) -> tuple:
     primes land in different prefill buckets recompute rather than share.
     """
     return (p_pad, upto, token_span_digest(tokens, upto))
-
-
-@dataclasses.dataclass
-class SlotPages:
-    """Pages owned by one in-flight request, in row order: ``pages[j]``
-    covers rows ``[j*page_size, (j+1)*page_size)``.  The first ``shared``
-    entries are prefix-cache hits (read-only; prefill/decode never write
-    them)."""
-
-    pages: list[int]
-    shared: int
 
 
 class PagePool:
@@ -213,18 +219,6 @@ class PagePool:
         self._key_of[pid] = key
         self._ref[pid] = self._ref.get(pid, 0) + 1
 
-    def unregister_prefix(self, pid: int) -> None:
-        """Withdraw ``pid`` from the prefix index (no-op when it was never
-        published).  Needed when the prefill that was going to FILL a
-        registered page fails after planning: the index must not serve a
-        page holding garbage.  The index's reference is dropped; any
-        in-flight sharer keeps theirs."""
-        key = self._key_of.pop(pid, None)
-        if key is None:
-            return
-        del self._prefix[key]
-        self._release_ref(pid)
-
     # ---------------------------------------------------------------- stats
 
     @property
@@ -262,3 +256,249 @@ class PagePool:
             "pages_in_use": self.capacity - self.free_pages,
             "gate_dtype": self.gate_dtype,
         }
+
+
+def _where_rows(live, new, old):
+    """``new`` in the ``live`` rows of a cache pytree, ``old`` elsewhere."""
+    def mrg(n, o):
+        return jnp.where(live.reshape((-1,) + (1,) * (o.ndim - 1)), n, o)
+    return jax.tree.map(mrg, new, old)
+
+
+class SlotCaches:
+    """Every cache row lives in its slot: the model family's own caches,
+    stepped by the family.  Nothing is allocated between dispatches, so
+    the host side of the layout is empty."""
+
+    pool = None
+    merge_operands = 0      # operands of the merge after ``src, mask``
+    prefix_hits = prefix_lookups = 0
+
+    def __init__(self, family):
+        self.family = family
+
+    # -- the device side: called while the engine's programs are traced
+
+    def init_caches(self, slots: int, max_len: int):
+        return self.family.init_caches(slots, max_len)
+
+    def live(self, state, operands):
+        return state["active"] & ~state["done"]
+
+    def step(self, params, tok, pos, caches, live, adapters, tenant,
+             operands):
+        return self.family.decode_step(params, tok, pos, caches, live,
+                                       adapters, tenant)
+
+    def idle_keeps(self, live, new, old):
+        """After a plain step: an idle slot is done or empty, and nothing
+        reads its caches before an admission overwrites them."""
+        return new
+
+    def rollback(self, live, new, old):
+        """After a speculative step: only the ``live`` rows took it."""
+        return _where_rows(live, new, old)
+
+    def split_handle(self, hstate):
+        """``(handle, gate rows)``: what of a handle the merge may donate
+        and what it scatters."""
+        return hstate, {}
+
+    def merge(self, take, caches, hstate, gate_rows, operands):
+        return jax.tree.map(take, hstate["caches"], caches)
+
+    # -- the host side: called between dispatches
+
+    def chunk_operands(self) -> tuple:
+        return ()
+
+    def covers(self, requests) -> bool:
+        return True
+
+    def write_tables(self, rows: int) -> tuple:
+        return ()
+
+    def plan(self, guard, slot, row, request, p_pad, tables,
+             pending_prefix) -> None:
+        pass
+
+    def free(self, slot: int) -> None:
+        pass
+
+
+class PagedGates:
+    """ProGen's caches with the SGU gate rows in a global page pool: the
+    rings and carries stay per slot, ``sgu_pool`` (and, for 8-bit pages,
+    ``sgu_pool_scale``) is shared, and the page ``table`` and the
+    ``paused`` rows ride into the chunk program as data, so the host's
+    allocation decisions never retrace it.
+
+    The host side is the pool's bookkeeping per slot: ``slot_pages``,
+    the page ``table`` and ``paused`` (rows the pool could not cover
+    this chunk)."""
+
+    merge_operands = 1      # the handle-ROW-indexed write table
+    _RING_KEYS = ("attn_prev", "ff_prev", "k", "v")
+
+    def __init__(self, config, policy, *, num_slots: int, max_len: int,
+                 page_size: int, num_pages: int | None, impl: str,
+                 weights: str, gate_dtype: str, prefix_caching: bool):
+        self.config, self.policy = config, policy
+        self.max_len, self.weights = max_len, weights
+        self.gate_dtype = gate_dtype
+        self.page_size = page_size
+        self.pages_per_row = -(-max_len // page_size)
+        if num_pages is None:
+            num_pages = RESERVED_PAGES + num_slots * self.pages_per_row
+        self.pool = PagePool(num_pages, page_size,
+                             prefix_caching=prefix_caching,
+                             gate_dtype=gate_dtype)
+        # slot -> its pages in row order: ``pages[j]`` covers rows
+        # ``[j * page_size, (j + 1) * page_size)``, prefix-cache hits first
+        self.slot_pages: dict[int, list[int]] = {}
+        self.table = np.zeros((num_slots, self.pages_per_row), np.int32)
+        self.paused = np.zeros((num_slots,), bool)
+        self.prefix_hits = 0
+        self.prefix_lookups = 0
+        self.use_impl(impl)
+
+    def use_impl(self, impl: str) -> None:
+        """The ragged kernel (``"pallas"``) or its bit-identical gather
+        fallback (``"xla"``) for the gate mix of the step."""
+        self.impl = impl
+        self.step_model = ProGenPagedDecodeStep(
+            config=self.config, n_rows=self.max_len, policy=self.policy,
+            impl=impl, weights=self.weights, gate_dtype=self.gate_dtype)
+
+    # -- the device side
+
+    def init_caches(self, slots: int, max_len: int):
+        caches = init_caches(self.config, slots, self.policy,
+                             decode_len=max_len, with_sgu=False)
+        caches.pop("sgu_gate")
+        caches["sgu_pool"] = init_gate_pool(
+            self.config, self.pool.num_pages, self.page_size, self.policy,
+            gate_dtype=self.gate_dtype)
+        if self.gate_dtype == "int8":
+            caches["sgu_pool_scale"] = init_gate_scale(
+                self.config, self.pool.num_pages, self.page_size)
+        return caches
+
+    def live(self, state, operands):
+        _, paused = operands
+        return state["active"] & ~state["done"] & ~paused
+
+    def step(self, params, tok, pos, caches, live, adapters, tenant,
+             operands):
+        table, _ = operands
+        logits, caches = self.step_model.apply(
+            params, tok, pos, caches, table, live, adapters, tenant)
+        return logits, caches, {}
+
+    def rollback(self, live, new, old):
+        """A row that did not take the step keeps its rings and carries;
+        the pool (and its scales) is written through, because its writes
+        are masked inside the step (``write_ok``)."""
+        return {**new, **{k: _where_rows(live, new[k], old[k])
+                          for k in self._RING_KEYS}}
+
+    # a paused row runs the step fully masked and resumes later: its
+    # carries still hold position ``pos - 1``'s activations, and the
+    # discarded step must not overwrite them
+    idle_keeps = rollback
+
+    def split_handle(self, hstate):
+        # the gate slabs scatter into the pool, so they can alias nothing:
+        # donating them with the handle would only warn
+        caches = dict(hstate["caches"])
+        gate = caches.pop("sgu_gate")
+        return {**hstate, "caches": caches}, gate
+
+    def merge(self, take, caches, hstate, gate_rows, operands):
+        """Rings and carries are gathered like any slot row; the handle's
+        dense gate rows (compute dtype: they quantize here, at the pool's
+        boundary) scatter through the handle-ROW-indexed write table
+        (DUMP for shared pages, unused rows and pad tails)."""
+        (row_wtable,) = operands
+        out = {k: jax.tree.map(take, hstate["caches"][k], caches[k])
+               for k in self._RING_KEYS}
+        pool = scatter_gate_rows(
+            self.config, gate_rows, hstate["start"], caches["sgu_pool"],
+            row_wtable, pool_scale=caches.get("sgu_pool_scale"))
+        if self.gate_dtype == "int8":
+            pool, out["sgu_pool_scale"] = pool
+        return {**out, "sgu_pool": pool}
+
+    # -- the host side
+
+    def chunk_operands(self) -> tuple:
+        return self.table.copy(), self.paused.copy()
+
+    def covers(self, requests) -> bool:
+        """Whether the pool can hold every prime of ``requests`` plus its
+        first sampled token WITHOUT prefix sharing: the conservative
+        reservation admission is gated by."""
+        return self.pool.can_allocate(sum(
+            pages_for_span(len(r.tokens), self.page_size)
+            for r in requests))
+
+    def write_tables(self, rows: int) -> tuple:
+        return (np.full((rows, self.pages_per_row), DUMP_PAGE, np.int32),)
+
+    def plan(self, guard: Callable, slot: int, row: int, request,
+             p_pad: int, tables: tuple, pending_prefix: list) -> None:
+        """Book ``slot`` for ``request``, handle row ``row``.  Planning
+        allocates (and retains shared) pages — a faultable operation,
+        guarded at the SAME point as the chunk-growth allocator."""
+        self.paused[slot] = False
+        guard("serve.page_alloc", self._plan_pages, slot, request.tokens,
+              p_pad, tables[0][row], pending_prefix)
+
+    def _plan_pages(self, slot: int, tokens, p_pad: int, wrow,
+                    pending_prefix: list) -> None:
+        """Build the slot's page list for rows ``[0, P]`` (prime + first
+        sampled token): longest run of prefix-cache hits first, fresh
+        private pages for the rest.  Fills the slot's ``table`` row and
+        the handle row's write-table row ``wrow`` (private pages only —
+        shared pages were filled by the request that first computed them
+        and MUST stay read-only: rewriting them from a different prefill
+        batch shape could perturb the sharer's bits).
+
+        Fresh full-prefix pages are NOT registered here: registrations
+        collect in ``pending_prefix`` and commit only after the guarded
+        prefill dispatch succeeds — a failed prefill must never leave the
+        index pointing at pages that were never filled."""
+        ps = self.page_size
+        p = len(tokens)
+        n_pages = p // ps + 1  # decode writes row P before any page grows
+        n_full = p // ps       # full pages strictly inside the prime
+        shared: list[int] = []
+        for j in range(n_full):
+            pid = self.pool.lookup_prefix(prefix_key(p_pad, tokens,
+                                                     (j + 1) * ps))
+            if pid is None:
+                break
+            shared.append(pid)
+        fresh = self.pool.allocate(n_pages - len(shared))
+        assert fresh is not None, "admission reserved pages conservatively"
+        for pid in shared:
+            self.pool.retain(pid)
+        self.prefix_hits += len(shared)
+        self.prefix_lookups += n_full
+        pages = shared + fresh
+        for j in range(len(shared), n_full):
+            pending_prefix.append(
+                (prefix_key(p_pad, tokens, (j + 1) * ps), pages[j]))
+        self.slot_pages[slot] = pages
+        self.table[slot, :] = NULL_PAGE
+        self.table[slot, : n_pages] = pages
+        wrow[: n_pages] = [DUMP_PAGE] * len(shared) + fresh
+
+    def free(self, slot: int) -> None:
+        pages = self.slot_pages.pop(slot, None)
+        if pages is None:
+            return
+        for pid in pages:
+            self.pool.release(pid)
+        self.table[slot, :] = NULL_PAGE
+        self.paused[slot] = False

@@ -87,8 +87,36 @@ def prime_buckets(window_size: int, seq_len: int,
     return out
 
 
-def _constrain_caches(caches, mesh: Mesh, strategies: Sequence[str]):
-    """Pin the decode caches' layouts over the mesh.
+def mesh_trace_ctx(mesh: Mesh | None, strategies: Sequence[str]):
+    """A factory of the context a model is TRACED under: the mesh and its
+    logical-axis rules (both must be active while flax traces, the same
+    pattern as ``train/step.py``'s ``apply_model``), or nothing without a
+    mesh."""
+    if mesh is None:
+        return contextlib.ExitStack
+    from progen_tpu.parallel.sharding import logical_rules
+
+    rules = logical_rules(strategies)
+
+    def trace_ctx():
+        stack = contextlib.ExitStack()
+        stack.enter_context(mesh)
+        stack.enter_context(nn.logical_axis_rules(rules))
+        return stack
+
+    return trace_ctx
+
+
+def _replicated_out(mesh: Mesh | None) -> dict:
+    """``jax.jit`` keywords that replicate a program's outputs over the
+    mesh."""
+    if mesh is None:
+        return {}
+    return {"out_shardings": NamedSharding(mesh, PartitionSpec())}
+
+
+def _constrain_caches(caches, mesh: Mesh | None, strategies: Sequence[str]):
+    """Pin the decode caches' layouts over the mesh (none: as they are).
 
     Only tensor parallelism shards real decode state: the k/v rings split
     on heads and the SGU gate cache on its channel half, matching the tp
@@ -98,7 +126,8 @@ def _constrain_caches(caches, mesh: Mesh, strategies: Sequence[str]):
     fsdp's win is the PARAMS staying sharded, which they do via
     ``params_shardings``.
     """
-    if "tp" not in strategies or mesh.shape.get("tensor", 1) <= 1:
+    if (mesh is None or "tp" not in strategies
+            or mesh.shape.get("tensor", 1) <= 1):
         return caches
     wsc = jax.lax.with_sharding_constraint
     kv = NamedSharding(mesh, PartitionSpec(None, "tensor", None, None))
@@ -125,14 +154,12 @@ def _take_row(x, idx):
 
 
 def harvest_caches(config: ProGenConfig, sown: dict, lengths, policy: Policy,
-                   decode_len: int, with_sgu: bool = True) -> dict:
+                   decode_len: int) -> dict:
     """Build decode caches from the parallel forward's sown "cache"
-    collection, per-row masked to ``lengths``.
-
-    ``with_sgu=False`` skips the dense per-slot gate cache (the paged
-    engine scatters gate rows straight into the global page pool via
-    :func:`harvest_gate_pages` instead — no ``(B, n_rows, half)`` slab is
-    ever materialized).
+    collection, per-row masked to ``lengths``.  The gate rows are dense
+    ``(B, n_rows, half)`` slabs whatever the engine's cache layout: a
+    paged engine scatters them into its page pool when it merges them
+    (:func:`scatter_gate_rows`).
     """
     c = config
     pol = policy
@@ -165,7 +192,7 @@ def harvest_caches(config: ProGenConfig, sown: dict, lengths, policy: Policy,
         caches["k"].append(jnp.where(m, k_ring, 0).astype(pol.compute_dtype))
         caches["v"].append(jnp.where(m, v_ring, 0).astype(pol.compute_dtype))
 
-        if c.layer_uses_gmlp(i) and with_sgu:
+        if c.layer_uses_gmlp(i):
             gate = ff["sgu"]["gate"][0]  # (B, P_pad, hidden/2) normed
             b, p_pad, half = gate.shape
             rows = jnp.zeros((b, n_rows, half), pol.compute_dtype)
@@ -177,78 +204,25 @@ def harvest_caches(config: ProGenConfig, sown: dict, lengths, policy: Policy,
     return caches
 
 
-def harvest_gate_pages(config: ProGenConfig, sown: dict, lengths, pool: dict,
-                       wtable, policy: Policy, pool_scale: dict | None = None):
-    """Scatter the prefill's sown gate rows straight into the page pool.
-
-    The paged engine's admission path: instead of building a contiguous
-    ``(B, n_rows, half)`` gate cache, each prime row ``i`` of request
-    ``b`` is scattered to pool page ``wtable[b, i // page_size]`` at
-    offset ``i % page_size``.  ``wtable`` is the WRITE table: it names the
-    request's freshly allocated private pages and holds ``DUMP_PAGE`` for
-    pages it must not write — prefix-cache hits (read-only, filled by the
-    first request that computed them) and unowned tail entries.  Pad rows
-    (``i >= lengths[b]``) are dumped too, so the scatter stays dense.
-
-    With ``pool_scale`` (the f32 twin of an int8 pool, see
-    ``init_gate_scale``) every gate row is quantized per-row before the
-    scatter and the call returns ``(new_pool, new_scale)``.
-    """
-    from progen_tpu.decode.paging import DUMP_PAGE
-    from progen_tpu.ops.quant import quantize_rows
-
-    c = config
-    new_pool = dict(pool)
-    new_scale = dict(pool_scale) if pool_scale is not None else None
-    for i in range(c.depth):
-        if not c.layer_uses_gmlp(i):
-            continue
-        gate = sown[f"ff{i}"]["sgu"]["gate"][0]  # (B, P_pad, half) normed
-        b, p_pad, half = gate.shape
-        layer_pool = pool[str(i)]  # (num_pages, page_size, half)
-        page_size = layer_pool.shape[1]
-        pages_per_row = wtable.shape[1]
-        rows = jnp.arange(p_pad)
-        # the window-aligned P_pad can overshoot the table span; clamp the
-        # page index — every overshooting row is >= lengths and dumped
-        page_idx = jnp.minimum(rows // page_size, pages_per_row - 1)
-        tgt = wtable[:, page_idx]  # (B, P_pad)
-        tgt = jnp.where(rows[None, :] < lengths[:, None], tgt, DUMP_PAGE)
-        off = jnp.broadcast_to((rows % page_size)[None, :], (b, p_pad))
-        if new_scale is None:
-            new_pool[str(i)] = layer_pool.at[
-                tgt.reshape(-1), off.reshape(-1)
-            ].set(gate.astype(layer_pool.dtype).reshape(-1, half))
-        else:
-            q, s = quantize_rows(gate)  # (B, P_pad, half) int8, (B, P_pad)
-            new_pool[str(i)] = layer_pool.at[
-                tgt.reshape(-1), off.reshape(-1)
-            ].set(q.reshape(-1, half))
-            new_scale[str(i)] = pool_scale[str(i)].at[
-                tgt.reshape(-1), off.reshape(-1)
-            ].set(s.reshape(-1))
-    if new_scale is not None:
-        return new_pool, new_scale
-    return new_pool
-
-
 def scatter_gate_rows(config: ProGenConfig, gate_rows: dict, lengths,
                       pool: dict, wtable, pool_scale: dict | None = None):
     """Scatter DENSE per-row gate slabs into the page pool.
 
-    The disaggregated admission path (``decode/handoff.py``): the
-    prefill worker hands off ``(B, n_rows, half)`` gate slabs per gMLP
-    layer (keyed ``str(i)`` like the dense cache), and the decode pool's
-    merge program scatters each handle row ``i < lengths[b]`` to page
-    ``wtable[b, i // page_size]`` at offset ``i % page_size`` — the same
-    contract as :func:`harvest_gate_pages`, with the slab (not the sown
-    prefill intermediates) as the source.  ``wtable`` rows for prefix-
-    shared pages, unadmitted handle rows and pad tails hold
-    ``DUMP_PAGE``.
+    How a paged engine admits, inline and disaggregated alike: the
+    prefill hands over ``(B, n_rows, half)`` gate slabs per gMLP layer
+    (keyed ``str(i)`` like the dense cache), and the merge scatters each
+    handle row ``i < lengths[b]`` to page ``wtable[b, i // page_size]`` at
+    offset ``i % page_size``.  ``wtable`` is the WRITE table: it names the
+    request's freshly allocated private pages and holds ``DUMP_PAGE`` for
+    what must not be written — prefix-cache hits (read-only, filled by
+    the first request that computed them), unadmitted handle rows and
+    unowned tail entries.  Pad rows (``i >= lengths[b]``) are dumped too,
+    so the scatter stays dense.
 
-    Handle slabs ride the handoff in the COMPUTE dtype regardless of the
-    pool's format (the prefill worker cannot know the decode pool's page
-    layout); with ``pool_scale`` the rows are quantized here, at the
+    Handle slabs come in the COMPUTE dtype regardless of the pool's
+    format (a prefill worker cannot know the decode pool's page layout);
+    with ``pool_scale`` (the f32 twin of an int8 pool, see
+    ``init_gate_scale``) the rows are quantized per row here, at the
     merge, and the call returns ``(new_pool, new_scale)``.
     """
     from progen_tpu.decode.paging import DUMP_PAGE
@@ -265,22 +239,19 @@ def scatter_gate_rows(config: ProGenConfig, gate_rows: dict, lengths,
         page_size = layer_pool.shape[1]
         pages_per_row = wtable.shape[1]
         rows = jnp.arange(n_rows)
+        # the slab can overshoot the table span; clamp the page index —
+        # every overshooting row is >= lengths and dumped
         page_idx = jnp.minimum(rows // page_size, pages_per_row - 1)
         tgt = wtable[:, page_idx]  # (B, n_rows)
         tgt = jnp.where(rows[None, :] < lengths[:, None], tgt, DUMP_PAGE)
         off = jnp.broadcast_to((rows % page_size)[None, :], (b, n_rows))
-        if new_scale is None:
-            new_pool[str(i)] = layer_pool.at[
-                tgt.reshape(-1), off.reshape(-1)
-            ].set(gate.astype(layer_pool.dtype).reshape(-1, half))
-        else:
-            q, s = quantize_rows(gate)
-            new_pool[str(i)] = layer_pool.at[
-                tgt.reshape(-1), off.reshape(-1)
-            ].set(q.reshape(-1, half))
-            new_scale[str(i)] = pool_scale[str(i)].at[
-                tgt.reshape(-1), off.reshape(-1)
-            ].set(s.reshape(-1))
+        at = (tgt.reshape(-1), off.reshape(-1))
+        if new_scale is not None:
+            gate, scale = quantize_rows(gate)   # (B, n_rows, half) int8
+            new_scale[str(i)] = pool_scale[str(i)].at[at].set(
+                scale.reshape(-1))
+        new_pool[str(i)] = layer_pool.at[at].set(
+            gate.astype(layer_pool.dtype).reshape(-1, half))
     if new_scale is not None:
         return new_pool, new_scale
     return new_pool
@@ -306,22 +277,9 @@ def make_embedder(config: ProGenConfig, policy: Policy | None = None,
     model = ProGen(config=config, policy=policy, mesh=None,
                    sow_final_hidden=True, weights=weights)
 
-    if mesh is not None:
-        from progen_tpu.parallel.sharding import logical_rules
+    trace_ctx = mesh_trace_ctx(mesh, strategies)
 
-        rules = logical_rules(strategies)
-        jit_kwargs = {"out_shardings": NamedSharding(mesh, PartitionSpec())}
-
-        def trace_ctx():
-            stack = contextlib.ExitStack()
-            stack.enter_context(mesh)
-            stack.enter_context(nn.logical_axis_rules(rules))
-            return stack
-    else:
-        jit_kwargs = {}
-        trace_ctx = contextlib.ExitStack
-
-    @partial(jax.jit, **jit_kwargs)
+    @partial(jax.jit, **_replicated_out(mesh))
     def embed(params, tokens, lengths):
         b, p_pad = tokens.shape
         if p_pad % config.window_size != 0 or p_pad > config.seq_len:
@@ -361,22 +319,10 @@ def make_prefiller(config: ProGenConfig, policy: Policy | None = None,
     policy = policy or make_policy()
     model = ProGen(config=config, policy=policy, mesh=None, weights=weights)
 
-    if mesh is not None:
-        from progen_tpu.parallel.sharding import logical_rules
+    trace_ctx = mesh_trace_ctx(mesh, strategies)
 
-        rules = logical_rules(strategies)
-        jit_kwargs = {"out_shardings": NamedSharding(mesh, PartitionSpec())}
-
-        def trace_ctx():
-            stack = contextlib.ExitStack()
-            stack.enter_context(mesh)
-            stack.enter_context(nn.logical_axis_rules(rules))
-            return stack
-    else:
-        jit_kwargs = {}
-        trace_ctx = contextlib.ExitStack
-
-    @partial(jax.jit, static_argnames=("decode_len",), **jit_kwargs)
+    @partial(jax.jit, static_argnames=("decode_len",),
+             **_replicated_out(mesh))
     def prefill(params, tokens, lengths, decode_len):
         b, p_pad = tokens.shape
         if p_pad % config.window_size != 0 or p_pad > config.seq_len:
@@ -390,8 +336,7 @@ def make_prefiller(config: ProGenConfig, policy: Policy | None = None,
             logits, varz = model.apply(params, tokens, mutable=["cache"])
             caches = harvest_caches(config, varz["cache"], lengths, policy,
                                     decode_len)
-            if mesh is not None:
-                caches = _constrain_caches(caches, mesh, strategies)
+            caches = _constrain_caches(caches, mesh, strategies)
         last_logits = _take_row(logits, lengths - 1).astype(jnp.float32)
         return last_logits, caches
 

@@ -19,20 +19,20 @@ BOS/pad counts as the first).  Structural differences, both conscious:
 
 from __future__ import annotations
 
-import contextlib
 from functools import partial
 from typing import Sequence
 
-import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import Mesh
 
 from progen_tpu.core.precision import Policy, make_policy
 from progen_tpu.decode.incremental import ProGenDecodeStep, init_caches
 from progen_tpu.decode.prefill import (
     _constrain_caches,
+    _replicated_out,
     make_prefiller,
+    mesh_trace_ctx,
     pad_prime_length,
 )
 from progen_tpu.models.progen import ProGenConfig
@@ -158,26 +158,11 @@ def make_sampler(config: ProGenConfig, policy: Policy | None = None,
     policy = policy or make_policy()
     step_model = ProGenDecodeStep(config=config, policy=policy)
 
-    if mesh is not None:
-        from progen_tpu.parallel.sharding import logical_rules
-
-        rules = logical_rules(strategies)
-        repl = NamedSharding(mesh, PartitionSpec())
-        # params shardings are applied via an explicit device_put in the
-        # wrapper below (a no-op when the caller's params already live
-        # there) — jit's in_shardings would reject the static kwargs
-        jit_kwargs = {"out_shardings": repl}
-
-        def trace_ctx():
-            # rules + mesh must be active while flax TRACES the decode
-            # step (same pattern as train/step.py's apply_model)
-            stack = contextlib.ExitStack()
-            stack.enter_context(mesh)
-            stack.enter_context(nn.logical_axis_rules(rules))
-            return stack
-    else:
-        jit_kwargs = {}
-        trace_ctx = contextlib.ExitStack
+    trace_ctx = mesh_trace_ctx(mesh, strategies)
+    # params shardings are applied via an explicit device_put in the
+    # wrapper below (a no-op when the caller's params already live
+    # there) — jit's in_shardings would reject the static kwargs
+    jit_kwargs = _replicated_out(mesh)
 
     @partial(jax.jit, static_argnames=("length", "top_k", "add_bos", "temperature"),
              **jit_kwargs)
@@ -203,8 +188,7 @@ def make_sampler(config: ProGenConfig, policy: Policy | None = None,
 
         with trace_ctx():
             caches = init_caches(config, b, policy, decode_len=length)
-            if mesh is not None:
-                caches = _constrain_caches(caches, mesh, strategies)
+            caches = _constrain_caches(caches, mesh, strategies)
 
             def body(carry, pos):
                 seq, caches, key = carry
@@ -267,18 +251,7 @@ def make_chunked_sampler(config: ProGenConfig, policy: Policy | None = None,
     step_model = ProGenDecodeStep(config=config, policy=policy)
     prefiller = make_prefiller(config, policy, mesh=mesh, strategies=strategies)
 
-    if mesh is not None:
-        from progen_tpu.parallel.sharding import logical_rules
-
-        rules = logical_rules(strategies)
-
-        def trace_ctx():
-            stack = contextlib.ExitStack()
-            stack.enter_context(mesh)
-            stack.enter_context(nn.logical_axis_rules(rules))
-            return stack
-    else:
-        trace_ctx = contextlib.ExitStack
+    trace_ctx = mesh_trace_ctx(mesh, strategies)
 
     @partial(jax.jit,
              static_argnames=("length", "start_pos", "top_k", "temperature"))
@@ -309,8 +282,7 @@ def make_chunked_sampler(config: ProGenConfig, policy: Policy | None = None,
     def decode_chunk(params, seq, caches, key, zcount, pos0, length,
                      start_pos, top_k, temperature, logit_mask=None):
         with trace_ctx():
-            if mesh is not None:
-                caches = _constrain_caches(caches, mesh, strategies)
+            caches = _constrain_caches(caches, mesh, strategies)
 
             def body(carry, i):
                 seq, caches, key, zcount = carry

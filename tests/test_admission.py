@@ -1,4 +1,4 @@
-"""Dense admission prefills only the rows it admits.
+"""Admission prefills only the rows it admits, whatever the cache layout.
 
 One admission program per prefill bucket takes ``R = engine.admit_rows``
 rows (``num_slots // SLOTS_PER_ADMIT_ROW``, at least one), prefills them
@@ -8,7 +8,9 @@ the engine to: the same tokens whatever the group size; no executable built
 after ``aot_warmup``; R-row inputs (no all-slots host mask); fault handling
 per run; one ``engine.admit_rows`` observation per run and one
 ``engine.prefill_s`` per admitting step; no host fetch between runs, and
-one run in flight at a time.
+one run in flight at a time.  The ``paged`` cases run the same program
+with the gate rows in a page pool (the merge scatters them through an
+R-row write table) and are held to the fixed-slot engine's tokens.
 """
 
 import dataclasses
@@ -40,6 +42,9 @@ ADMIT_ROWS = 3      # so that 1, R - 1, R, R + 1 and SLOTS all differ
 SLOTS = ADMIT_ROWS * SLOTS_PER_ADMIT_ROW
 ENGINE = dict(num_slots=SLOTS, chunk_size=4, max_len=24)
 GROUPS = sorted({1, ADMIT_ROWS - 1, ADMIT_ROWS, ADMIT_ROWS + 1, SLOTS})
+# the cache layouts, by the engine keywords that choose them
+LAYOUTS = {"slots": {}, "paged": dict(paged=True, page_size=4)}
+PAGES_PER_ROW = -(-ENGINE["max_len"] // LAYOUTS["paged"]["page_size"])
 
 
 @pytest.fixture(scope="module")
@@ -116,12 +121,16 @@ def alone(served):
         for sampled in (False, True) for masked in (False, True)}
 
 
-@pytest.mark.parametrize("masked", [False, True], ids=["free", "masked"])
-@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
-@pytest.mark.parametrize("n", GROUPS)
+@pytest.mark.parametrize("n,sampled,masked,layout", [
+    pytest.param(n, sampled, masked, layout, id="-".join((
+        str(n), "sampled" if sampled else "greedy",
+        "masked" if masked else "free", layout)))
+    for layout in LAYOUTS for n in GROUPS
+    for sampled in (False, True) for masked in (False, True)
+    if layout == "slots" or sampled == masked])
 def test_group_of_n_serves_the_tokens_of_requests_served_alone(
-        served, alone, n, sampled, masked):
-    eng = _engine(served)
+        served, alone, n, sampled, masked, layout):
+    eng = _engine(served, **LAYOUTS[layout])
     runs0, rows0 = _runs(eng)
     for r in _requests(n, sampled=sampled, masked=masked):
         eng.submit(r)
@@ -136,6 +145,9 @@ def test_group_of_n_serves_the_tokens_of_requests_served_alone(
     if masked:
         assert all(t % 2 == 0 and t for toks, _ in got.values()
                    for t in toks)
+    if layout == "paged":
+        assert eng._pool.free_pages + eng._pool.cached_pages == \
+            eng._pool.capacity
 
 
 @pytest.mark.parametrize("n", [ADMIT_ROWS + 1, SLOTS])
@@ -179,11 +191,12 @@ def test_slots_are_taken_in_queue_order(served):
 # ------------------------------------------ (b) nothing compiles once warm
 
 
-def test_no_compilation_after_aot_warmup(served):
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_no_compilation_after_aot_warmup(served, layout):
     """Groups of every size, slots finishing one and several at a time,
     chunks in between: every executable ``step()`` dispatches was built by
     ``aot_warmup``."""
-    eng = _engine(served)
+    eng = _engine(served, **LAYOUTS[layout])
     info = eng.aot_warmup()
     buckets = prime_buckets(CFG.window_size, CFG.seq_len, eng.max_len - 1)
     admits = [k for k in eng._aot if k[0] == "admit"]
@@ -235,8 +248,9 @@ def test_aot_state_round_trips_between_the_programs(served):
 # ---------------------------------------------- (c) R-row inputs, no S-row mask
 
 
-def test_admission_inputs_have_r_rows(served, monkeypatch):
-    eng = _engine(served)
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_admission_inputs_have_r_rows(served, monkeypatch, layout):
+    eng = _engine(served, **LAYOUTS[layout])
     seen, masks = [], []
     real_call, real_mask = eng._admit_call, eng._build_lmask
 
@@ -258,6 +272,8 @@ def test_admission_inputs_have_r_rows(served, monkeypatch):
     assert eng.admit_rows == R
     assert len(seen) == math.ceil(SLOTS / R)
     for p_pad, shapes in seen:
+        if layout == "paged":       # the write table: R rows, like the rest
+            assert shapes.pop() == (R, PAGES_PER_ROW)
         src, mask, tokens, *per_row, lmask = shapes
         assert src == mask == (SLOTS,)
         assert tokens == (R, p_pad)
@@ -284,9 +300,10 @@ def clean2r(served):
     return _alone(served, _requests(2 * ADMIT_ROWS))
 
 
+@pytest.mark.parametrize("layout", LAYOUTS)
 def test_fatal_fault_in_the_second_run_sheds_only_its_requests(
-        served, clean2r):
-    eng = _engine(served)
+        served, clean2r, layout):
+    eng = _engine(served, **LAYOUTS[layout])
     for r in _requests(2 * ADMIT_ROWS):
         eng.submit(r)
     faults.configure("serve.prefill:fatal:at=2", seed=0)
@@ -296,11 +313,15 @@ def test_fatal_fault_in_the_second_run_sheds_only_its_requests(
     assert eng.robust.failed_faults == ADMIT_ROWS
     for u in range(ADMIT_ROWS):
         assert got[u] == clean2r[u]
+    if layout == "paged":       # the lost run's pages came back
+        assert eng._pool.free_pages + eng._pool.cached_pages == \
+            eng._pool.capacity
 
 
+@pytest.mark.parametrize("layout", LAYOUTS)
 def test_transient_exhaustion_in_the_second_run_requeues_it_in_order(
-        served, clean2r):
-    eng = _engine(served, fault_retries=0)
+        served, clean2r, layout):
+    eng = _engine(served, fault_retries=0, **LAYOUTS[layout])
     for r in _requests(2 * ADMIT_ROWS):
         eng.submit(r)
     faults.configure("serve.prefill:unavailable:at=2", seed=0)
@@ -311,6 +332,8 @@ def test_transient_exhaustion_in_the_second_run_requeues_it_in_order(
     # front, in order, and no slot is booked for it
     assert sorted(r.uid for r in eng._inflight.values()) == list(
         range(ADMIT_ROWS))
+    if layout == "paged":       # and no page: only the first run holds any
+        assert sorted(eng._layout.slot_pages) == sorted(eng._inflight)
     assert [eng._queue.popleft().uid for _ in range(ADMIT_ROWS)] == list(
         range(ADMIT_ROWS, 2 * ADMIT_ROWS))
     assert not eng._queue

@@ -27,7 +27,7 @@ from progen_tpu.decode import (
     Request,
     ServingEngine,
     harvest_caches,
-    harvest_gate_pages,
+    scatter_gate_rows,
     init_gate_pool,
     pages_for_span,
     prefix_key,
@@ -112,9 +112,10 @@ def test_prefix_key_includes_pad_shape():
 # --------------------------------------------------------------- harvest
 
 
-def test_harvest_gate_pages_matches_contiguous(trained):
-    """Prefill gate rows scattered into pool pages, gathered back through
-    the page table, equal the dense contiguous harvest bit for bit."""
+def test_scatter_gate_rows_matches_contiguous(trained):
+    """A prefill's dense gate rows scattered into pool pages, gathered
+    back through the page table, equal the contiguous harvest bit for
+    bit (and rows past each prime stay out of the pages)."""
     model, params, policy = trained
     lengths = np.asarray([5, 8, 1])
     p_pad = 8
@@ -137,8 +138,8 @@ def test_harvest_gate_pages_matches_contiguous(trained):
         n = pages_for_span(int(p) - 1, ps)
         table[b, :n] = wtable[b, :n] = range(nxt, nxt + n)
         nxt += n
-    pool = harvest_gate_pages(CFG, varz["cache"], jnp.asarray(lengths),
-                              pool, jnp.asarray(wtable), policy)
+    pool = scatter_gate_rows(CFG, dense["sgu_gate"], jnp.asarray(lengths),
+                             pool, jnp.asarray(wtable))
 
     for i in range(CFG.depth):
         if not CFG.layer_uses_gmlp(i):
