@@ -111,7 +111,8 @@ from progen_tpu.decode.sampler import (
 )
 from progen_tpu.decode.spec import check_draft_config, spec_round
 from progen_tpu.models.progen import ProGen, ProGenConfig
-from progen_tpu.ops.row_write import record_paths, write_rows
+from progen_tpu.ops.lowering import record_lowerings
+from progen_tpu.ops.row_write import write_rows
 
 EOS_ID = 0
 
@@ -575,10 +576,11 @@ class ServingEngine:
         else:
             self._layout = SlotCaches(self.family)
         self._pool = self._layout.pool
-        self._decode_chunk = self._jit_chunk(
+        # op -> the lowering its calls took in the programs traced so far
+        self.lowerings: dict[str, str] = {}
+        self._decode_chunk = self._jit_noting(
             self._decode_chunk_spec_impl if spec else self._decode_chunk_impl)
-        self._admit = jax.jit(self._admit_impl)
-        self.row_write: str | None = None
+        self._admit = self._jit_noting(self._admit_impl)
         self.model_stats: dict = {}     # the family's counters as last fetched
         if remote_prefill and not disagg:
             raise ValueError("remote_prefill requires disagg=True")
@@ -802,7 +804,7 @@ class ServingEngine:
         self.robust.fallback_activations += 1
         self.paged_impl = "xla"
         self._layout.use_impl("xla")
-        self._decode_chunk = self._jit_chunk(
+        self._decode_chunk = self._jit_noting(
             self._decode_chunk_spec_impl if self.spec
             else self._decode_chunk_impl)
         self._aot.pop(("chunk",), None)
@@ -812,17 +814,21 @@ class ServingEngine:
 
     # ------------------------------------------------------------- decoding
 
-    def _jit_chunk(self, impl):
-        """``jax.jit(impl)`` for a decode-chunk program; tracing it notes
-        which lowering the step's cache writes took (``ops/row_write.py``:
-        ``"pallas"`` on a TPU, ``"scatter"`` elsewhere, both joined by
-        ``+`` where the shapes split them) for ``status()["row_write"]``."""
+    def _jit_noting(self, impl):
+        """``jax.jit(impl)`` for a decode-chunk or admission program;
+        tracing it notes which lowering each op that owns two took
+        (``ops/lowering.py``) for ``status()``: the step's cache writes
+        (``"row_write"``: ``"pallas"`` on a TPU, ``"scatter"`` elsewhere,
+        both joined by ``+`` where the shapes split them) and a latent
+        attention prefill's core (``"mla_prefill"``: ``"pallas"`` /
+        ``"xla"``)."""
 
         @wraps(impl)
         def traced(*args):
-            with record_paths() as paths:
+            with record_lowerings() as chosen:
                 out = impl(*args)
-            self.row_write = "+".join(sorted(paths))
+            for op, paths in chosen.items():
+                self.lowerings[op] = "+".join(sorted(paths))
             return out
 
         return jax.jit(traced)
@@ -2411,9 +2417,11 @@ class ServingEngine:
             "inflight_uids": sorted(r.uid for r in
                                     list(self._inflight.values())),
             "chunks_run": self.chunks_run,
-            # lowering of the chunk program's cache writes; None until the
-            # program has been traced
-            "row_write": self.row_write,
+            # lowering of the chunk program's cache writes and of a latent
+            # attention prefill's core; None until a program that holds the
+            # op has been traced
+            "row_write": self.lowerings.get("row_write"),
+            "mla_prefill": self.lowerings.get("mla_prefill"),
             "paged": self.paged,
             "disagg": self.disagg,
             "spec": self.spec,

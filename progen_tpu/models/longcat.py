@@ -49,10 +49,10 @@ import jax
 import jax.numpy as jnp
 
 from progen_tpu.core.precision import Policy, make_policy
+from progen_tpu.ops.mla_prefill import prefill_attention
 from progen_tpu.ops.row_write import write_rows
 
 F32 = jnp.float32
-QUERY_BLOCK = 256  # prefill attention: query rows per score block
 
 
 @dataclasses.dataclass(frozen=True)
@@ -253,14 +253,15 @@ def _wkvb(p, c: LongCatConfig, dtype):
     return w[..., : c.qk_nope_head_dim], w[..., c.qk_nope_head_dim:]
 
 
-def mla_prefill(x, p, c: LongCatConfig):
+def mla_prefill(x, p, c: LongCatConfig, lengths=None):
     """Full causal attention over ``x (R, P, h)`` in the NON-absorbed form
-    (keys and values expanded from the latent once), in blocks of
-    ``QUERY_BLOCK`` query rows against the keys they can see, so the score
-    tensor is ``(R, H, block, <= P)`` and the work is the causal half.
-    Returns ``(out (R, P, h), latent rows (R, P, latent))``."""
+    (keys and values expanded from the latent once); the core is
+    ``ops/mla_prefill.py``: a flash kernel on the chip at the published
+    head widths, blocks of query rows in XLA elsewhere.  ``lengths (R,)``:
+    the real leading positions of each row (default all); the output at a
+    pad position is finite and otherwise unspecified.  Returns ``(out (R,
+    P, h), latent rows (R, P, latent))``."""
     r, n, _ = x.shape
-    heads, nope, vd = c.num_attention_heads, c.qk_nope_head_dim, c.v_head_dim
     with jax.named_scope("mla.prefill"):
         positions = jnp.broadcast_to(jnp.arange(n), (r, n))
         q_nope, q_rope, latent = _mla_project(x, p, c, positions)
@@ -268,21 +269,7 @@ def mla_prefill(x, p, c: LongCatConfig):
         c_kv, k_r = latent[..., : c.kv_lora_rank], latent[..., c.kv_lora_rank:]
         k_nope = jnp.einsum("rnl,lhd->rhnd", c_kv, wk)
         v = jnp.einsum("rnl,lhd->rhnd", c_kv, wv)
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_r[:, None], (r, heads) + k_r.shape[1:])],
-            axis=-1)
-        q = jnp.concatenate([q_nope, q_rope], axis=-1).transpose(0, 2, 1, 3)
-        scale = 1.0 / math.sqrt(q.shape[-1])
-        outs = []
-        for s in range(0, n, QUERY_BLOCK):
-            e = min(s + QUERY_BLOCK, n)
-            logits = jnp.einsum("rhqd,rhkd->rhqk", q[:, :, s:e], k[:, :, :e],
-                                preferred_element_type=F32) * scale
-            causal = jnp.arange(e)[None, :] <= jnp.arange(s, e)[:, None]
-            probs = jax.nn.softmax(jnp.where(causal, logits, -jnp.inf), -1)
-            outs.append(jnp.einsum("rhqk,rhkd->rqhd", probs.astype(x.dtype),
-                                   v[:, :, :e]))
-        o = jnp.concatenate(outs, axis=1).reshape(r, n, heads * vd)
+        o = prefill_attention(q_nope, q_rope, k_nope, k_r, v, lengths)
         return _mm(o, p["wo"]), latent
 
 
@@ -453,9 +440,11 @@ def prefill(params, tokens, lengths, config: LongCatConfig,
     -> ``(logits (R, K, V) float32 at logit_positions (R, K)`` (default the
     last real position, K = 1), ``latent rows {block: (R, P, latent)},
     stats)``.  Padding, and the whole of a row of length 0 (an admission
-    row that carries no request), is computed by attention and the dense
-    FFNs (the shapes are static) but not by the experts, and is not
-    counted."""
+    row that carries no request), is computed by the dense FFNs (the shapes
+    are static) but not by the experts, and by attention only where the
+    blocked XLA form runs (``ops/mla_prefill.py``: the kernel visits no
+    tile past a row's length); it is not counted, and no real position's
+    output depends on what it holds."""
     c = config
     dt = (policy or bf16_policy()).compute_dtype
     r, n = tokens.shape
@@ -463,7 +452,7 @@ def prefill(params, tokens, lengths, config: LongCatConfig,
     rows = {}
 
     def attend(x, i, j, p):
-        out, latent = mla_prefill(x.reshape(r, n, -1), p, c)
+        out, latent = mla_prefill(x.reshape(r, n, -1), p, c, lengths)
         rows[f"l{i}a{j}"] = latent
         return out.reshape(r * n, -1)
 

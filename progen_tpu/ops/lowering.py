@@ -1,0 +1,51 @@
+"""What an op that owns two lowerings of one contract can observe, and a
+note of which one it took.
+
+``ops/row_write.py`` and ``ops/mla_prefill.py`` each keep a Pallas kernel
+and an XLA form behind one function and choose between them from the
+backend, the mesh in scope and the shapes, never from a knob.  The choice is
+made while a program is traced, so a caller that traces one
+(``ServingEngine`` around its chunk and admission programs) can collect it:
+:func:`record_lowerings` yields ``{op name: {lowering, ...}}`` for the ops
+traced inside the block, at no cost per step.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import contextvars
+
+import jax
+
+_recorder: contextvars.ContextVar = contextvars.ContextVar(
+    "op_lowerings", default=None)
+
+
+@contextlib.contextmanager
+def record_lowerings():
+    """Collect ``{op: {lowering, ...}}`` as :func:`note` reports them for
+    the ops traced inside the block."""
+    chosen: dict[str, set[str]] = {}
+    token = _recorder.set(chosen)
+    try:
+        yield chosen
+    finally:
+        _recorder.reset(token)
+
+
+def note(op: str, lowering: str) -> None:
+    """An op's report of the lowering the call being traced takes."""
+    chosen = _recorder.get()
+    if chosen is not None:
+        chosen.setdefault(op, set()).add(lowering)
+
+
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def mesh_in_scope() -> bool:
+    from jax._src import mesh as mesh_lib
+
+    return not (mesh_lib.thread_resources.env.physical_mesh.empty
+                and jax.sharding.get_abstract_mesh().empty)

@@ -37,14 +37,14 @@ which lowering its writes took (``ServingEngine.status()["row_write"]``).
 from __future__ import annotations
 
 import contextlib
-import contextvars
 import functools
 
 import jax
 import jax.numpy as jnp
 
-_recorder: contextvars.ContextVar = contextvars.ContextVar(
-    "row_write_paths", default=None)
+from progen_tpu.ops.lowering import mesh_in_scope as _mesh_in_scope
+from progen_tpu.ops.lowering import note, record_lowerings
+from progen_tpu.ops.lowering import on_tpu as _on_tpu
 
 
 @contextlib.contextmanager
@@ -52,23 +52,8 @@ def record_paths():
     """Collect the lowerings (``"pallas"`` / ``"scatter"``) that the
     :func:`write_rows` calls traced inside the block chose for caches of
     three or more dimensions."""
-    paths: set[str] = set()
-    token = _recorder.set(paths)
-    try:
-        yield paths
-    finally:
-        _recorder.reset(token)
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _mesh_in_scope() -> bool:
-    from jax._src import mesh as mesh_lib
-
-    return not (mesh_lib.thread_resources.env.physical_mesh.empty
-                and jax.sharding.get_abstract_mesh().empty)
+    with record_lowerings() as chosen:
+        yield chosen.setdefault("row_write", set())
 
 
 def sublane_tile(dtype) -> int:
@@ -162,9 +147,7 @@ def write_rows(cache, update, idx, axis):
                     for c, u in zip(caches, updates))
         return out if many else out[0]
     kernel = _on_tpu() and not _mesh_in_scope() and _kernel_takes(first, axis)
-    paths = _recorder.get()
-    if paths is not None:
-        paths.add("pallas" if kernel else "scatter")
+    note("row_write", "pallas" if kernel else "scatter")
     if kernel:
         out = pallas_write_rows(caches, updates, idx)
     else:

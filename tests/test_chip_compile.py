@@ -243,3 +243,23 @@ def test_longcat_absorbed_decode_compiles_for_the_chip(shape,
             shape((32,), jnp.int32),
             shape((32, 4096, c.latent_width), jnp.bfloat16), p).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024 ** 3
+
+
+@pytest.mark.parametrize("bucket", [512, 1024, 2048, 4096])
+def test_mla_prefill_kernel_compiles_for_v5e(shape, no_persistent_cache,
+                                             bucket):
+    """The prefill's flash kernel (``ops/mla_prefill.py``,
+    ``mla_prefill_fwd``) at an admission run of ``serve-longcat-backlog``:
+    2 rows, 64 heads of 128 + 64 (v 128), bfloat16, each prefill bucket the
+    cell warms, with the tiles the chip path takes."""
+    from progen_tpu.ops.mla_prefill import pallas_prefill_attention
+
+    r, heads, bf16 = 2, 64, jnp.bfloat16
+    _assert_kernel_compiles(
+        lambda *a: pallas_prefill_attention(*a, interpret=False),
+        shape((r, heads, bucket, 128), bf16),
+        shape((r, heads, bucket, 64), bf16),
+        shape((r, heads, bucket, 128), bf16),
+        shape((r, bucket, 64), bf16),
+        shape((r, heads, bucket, 128), bf16),
+        shape((r,), jnp.int32))
