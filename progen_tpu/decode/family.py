@@ -140,4 +140,11 @@ def family_for(config, policy: Policy, weights: str = "bf16"):
 
     if isinstance(config, LongCatConfig):
         return LongCatFamily(config, policy)
+    from progen_tpu.models.deepseek_v2 import (
+        DeepSeekV2Config,
+        DeepSeekV2Family,
+    )
+
+    if isinstance(config, DeepSeekV2Config):
+        return DeepSeekV2Family(config, policy)
     raise TypeError(f"no model family serves a {type(config).__name__}")

@@ -24,8 +24,8 @@ def as_dict(config) -> dict:
     return dataclasses.asdict(config)
 
 
-def make(config=TINY, mixed=False, seed=0):
+def make(config=TINY, mixed=False, seed=0, family=lc):
     """``(params, policy)``: float32 end to end, or bfloat16 parameters and
-    compute with the float32 islands."""
-    policy = lc.bf16_policy() if mixed else make_policy(False)
-    return lc.init_params(config, jax.random.key(seed), policy), policy
+    compute with the float32 islands.  ``family``: the model's module."""
+    policy = family.bf16_policy() if mixed else make_policy(False)
+    return family.init_params(config, jax.random.key(seed), policy), policy

@@ -168,6 +168,7 @@ ROW_WRITES = {
     "base-rings": ((16, 12, 1024, 128), 2),
     "base-gate": ((16, 2048, 3072), 1),
     "longcat-latent": ((32, 4096, 576), 1),
+    "dsv2-latent": ((64, 3072, 576), 1),
 }
 
 
@@ -243,6 +244,67 @@ def test_longcat_absorbed_decode_compiles_for_the_chip(shape,
             shape((32,), jnp.int32),
             shape((32, 4096, c.latent_width), jnp.bfloat16), p).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024 ** 3
+
+
+# ---- DeepSeek-V2's pieces at published widths (models/deepseek_v2.py) ----
+
+
+@pytest.mark.parametrize("tokens", [64, 4096], ids=["decode", "prefill"])
+def test_dsv2_expert_share_compiles_for_the_chip(shape, tokens,
+                                                 no_persistent_cache):
+    """The group-limited router and the grouped product over 40 held
+    experts of 5120 x 1536, for a decode batch of 64 rows and for an
+    admission run of 4 x 1024 tokens (windows of 256 and 12,288
+    assignments: 1.5 a token expected, twice that held)."""
+    from progen_tpu.models import deepseek_v2
+
+    c = deepseek_v2.DeepSeekV2Config(num_hidden_layers=2, vocab_size=25600,
+                                     experts_held=40)
+    layer = _longcat_shapes(
+        shape, lambda k: deepseek_v2._init_layer(k, c, jnp.bfloat16, False),
+        jax.random.key(0))
+    u = shape((tokens, c.hidden_size), jnp.bfloat16)
+    live = shape((tokens,), jnp.bool_)
+    _assert_kernel_compiles(
+        lambda layer, u, live: deepseek_v2.moe_share(u, layer, c, live),
+        layer, u, live)
+
+
+def test_dsv2_absorbed_decode_compiles_for_the_chip(shape,
+                                                    no_persistent_cache):
+    """One absorbed attention step of 64 rows and 128 heads over a 3072-row
+    latent cache, the YaRN table a constant of the program."""
+    from progen_tpu.models import deepseek_v2, latent
+
+    c = deepseek_v2.DeepSeekV2Config(num_hidden_layers=2, vocab_size=25600,
+                                     experts_held=40)
+    p = _longcat_shapes(
+        shape, lambda k: latent.init_attn(k, c, jnp.bfloat16),
+        jax.random.key(0))
+    compiled = jax.jit(
+        lambda x, pos, cache, p: latent.mla_decode(x, pos, cache, p, c)
+    ).lower(shape((64, c.hidden_size), jnp.bfloat16),
+            shape((64,), jnp.int32),
+            shape((64, 3072, c.latent_width), jnp.bfloat16), p).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024 ** 3
+
+
+@pytest.mark.parametrize("bucket", [512, 1024])
+def test_mla_prefill_kernel_compiles_for_v5e_at_128_heads(
+        shape, no_persistent_cache, bucket):
+    """``mla_prefill_fwd`` at an admission run of
+    ``serve-dsv2-decode-backlog``: 4 rows, 128 heads, both buckets."""
+    from progen_tpu.ops.mla_prefill import pallas_prefill_attention
+
+    r, heads, bf16 = 4, 128, jnp.bfloat16
+    _assert_kernel_compiles(
+        lambda *a: pallas_prefill_attention(*a, interpret=False),
+        shape((r, heads, bucket, 128), bf16),
+        shape((r, heads, bucket, 64), bf16),
+        shape((r, heads, bucket, 128), bf16),
+        shape((r, bucket, 64), bf16),
+        shape((r, heads, bucket, 128), bf16),
+        shape((r,), jnp.int32))
 
 
 @pytest.mark.parametrize("bucket", [512, 1024, 2048, 4096])
