@@ -820,8 +820,8 @@ class ServingEngine:
         (``ops/lowering.py``) for ``status()``: the step's cache writes
         (``"row_write"``: ``"pallas"`` on a TPU, ``"scatter"`` elsewhere,
         both joined by ``+`` where the shapes split them) and a latent
-        attention prefill's core (``"mla_prefill"``: ``"pallas"`` /
-        ``"xla"``)."""
+        attention's prefill and absorbed decode cores (``"mla_prefill"``,
+        ``"mla_decode"``: ``"pallas"`` / ``"xla"``)."""
 
         @wraps(impl)
         def traced(*args):
@@ -2418,10 +2418,11 @@ class ServingEngine:
                                     list(self._inflight.values())),
             "chunks_run": self.chunks_run,
             # lowering of the chunk program's cache writes and of a latent
-            # attention prefill's core; None until a program that holds the
-            # op has been traced
+            # attention's prefill and decode cores; None until a program
+            # that holds the op has been traced
             "row_write": self.lowerings.get("row_write"),
             "mla_prefill": self.lowerings.get("mla_prefill"),
+            "mla_decode": self.lowerings.get("mla_decode"),
             "paged": self.paged,
             "disagg": self.disagg,
             "spec": self.spec,

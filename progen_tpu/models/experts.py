@@ -25,7 +25,8 @@ F32 = jnp.float32
 # family adds its own keys to these
 STAT_KEYS = ("moe.tokens", "moe.held_load", "moe.prefill_held",
              "moe.decode_layers", "moe.experts_touched",
-             "mla.decode_rows", "mla.context_tokens")
+             "mla.decode_rows", "mla.context_tokens",
+             "mla.cache_rows_read")
 
 
 def zero_stats(keys, held: int) -> dict:

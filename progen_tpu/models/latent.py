@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from progen_tpu.core.precision import Policy, make_policy
 from progen_tpu.models.experts import zero_stats
+from progen_tpu.ops.mla_decode import decode_attention, rows_visited
 from progen_tpu.ops.mla_prefill import prefill_attention
 from progen_tpu.ops.row_write import write_rows
 
@@ -190,7 +191,9 @@ def mla_prefill(x, p, c, lengths=None):
 def mla_decode(x, pos, cache, p, c):
     """One token per row in the ABSORBED form: ``x (S, h)`` at ``pos (S,)``
     against ``cache (S, T, latent)``, which gains the row's new entry at
-    ``pos``.  Returns ``(out (S, h), cache)``."""
+    ``pos`` and is then attended up to it (``ops/mla_decode.py``: one kernel
+    that reads each slot's rows once on the chip, three XLA ops over the
+    whole cache elsewhere).  Returns ``(out (S, h), cache)``."""
     s = x.shape[0]
     rank = c.kv_lora_rank
     with jax.named_scope("mla.decode"):
@@ -199,14 +202,9 @@ def mla_decode(x, pos, cache, p, c):
         wk, wv = _wkvb(p, c, x.dtype)
         q_lat = jnp.einsum("shd,lhd->shl", q_nope[:, 0], wk)
         q_cat = jnp.concatenate([q_lat, q_rope[:, 0]], axis=-1)
-        scale = 1.0 / math.sqrt(c.qk_nope_head_dim + c.qk_rope_head_dim)
-        logits = jnp.einsum("shl,stl->sht", q_cat, cache.astype(x.dtype),
-                            preferred_element_type=F32) * scale
-        seen = jnp.arange(cache.shape[1])[None, :] <= pos[:, None]
-        probs = jax.nn.softmax(
-            jnp.where(seen[:, None], logits, -jnp.inf), axis=-1)
-        o_lat = jnp.einsum("sht,stl->shl", probs.astype(x.dtype),
-                           cache[..., :rank].astype(x.dtype))
+        o_lat = decode_attention(
+            q_cat, cache, pos + 1, rank,
+            1.0 / math.sqrt(c.qk_nope_head_dim + c.qk_rope_head_dim))
         o = jnp.einsum("shl,lhd->shd", o_lat, wv)
         return mm(o.reshape(s, -1), p["wo"]), cache
 
@@ -280,6 +278,10 @@ def decode_step(stack, params, tok, pos, caches, live, config,
     stats["mla.decode_rows"] = jnp.sum(live).astype(F32)
     stats["mla.context_tokens"] = jnp.sum(
         jnp.where(live, pos + 1, 0)).astype(F32)
+    # every block's core has these shapes: the rows ONE of them reads
+    stats["mla.cache_rows_read"] = rows_visited(
+        dt, next(iter(caches.values())), pos + 1,
+        c.kv_lora_rank) * jnp.any(live)
     out = _logits(x, params, c), caches, stats
     if with_choices:
         return out + (jnp.stack(chosen),)

@@ -1,10 +1,10 @@
 """What an op that owns two lowerings of one contract can observe, and a
 note of which one it took.
 
-``ops/row_write.py`` and ``ops/mla_prefill.py`` each keep a Pallas kernel
-and an XLA form behind one function and choose between them from the
-backend, the mesh in scope and the shapes, never from a knob.  The choice is
-made while a program is traced, so a caller that traces one
+``ops/row_write.py``, ``ops/mla_prefill.py`` and ``ops/mla_decode.py`` each
+keep a Pallas kernel and an XLA form behind one function and choose between
+them from the backend, the mesh in scope and the shapes, never from a knob.
+The choice is made while a program is traced, so a caller that traces one
 (``ServingEngine`` around its chunk and admission programs) can collect it:
 :func:`record_lowerings` yields ``{op name: {lowering, ...}}`` for the ops
 traced inside the block, at no cost per step.

@@ -325,3 +325,22 @@ def test_mla_prefill_kernel_compiles_for_v5e(shape, no_persistent_cache,
         shape((r, bucket, 64), bf16),
         shape((r, heads, bucket, 128), bf16),
         shape((r,), jnp.int32))
+
+
+@pytest.mark.parametrize("slots,heads,max_len", [(64, 128, 3072),
+                                                 (32, 64, 4096)],
+                         ids=["dsv2", "longcat"])
+def test_mla_decode_kernel_compiles_for_v5e(shape, no_persistent_cache,
+                                            slots, heads, max_len):
+    """The absorbed decode step's attention core (``ops/mla_decode.py``,
+    ``mla_decode_fwd``) at the shapes of ``serve-dsv2-decode-backlog`` and
+    ``serve-longcat-backlog``: a cache whose last axis (576) is 4.5 lane
+    tiles taken whole, with the key tile the chip path takes."""
+    from progen_tpu.ops.mla_decode import pallas_decode_attention
+
+    bf16 = jnp.bfloat16
+    _assert_kernel_compiles(
+        lambda q, c, n: pallas_decode_attention(q, c, n, 512, 192 ** -0.5,
+                                                interpret=False),
+        shape((slots, heads, 576), bf16), shape((slots, max_len, 576), bf16),
+        shape((slots,), jnp.int32))
