@@ -48,6 +48,8 @@ this seam, ``PagedGates`` is ProGen's paged step.
 
 from __future__ import annotations
 
+import importlib
+
 import jax.numpy as jnp
 
 from progen_tpu.core.precision import Policy
@@ -132,19 +134,21 @@ class ProGenFamily:
         return {}
 
 
+# the families that are plain functions over ``models/driver.py``, imported
+# only when a config is not ProGen's: (module, its config, its family)
+_DRIVER_FAMILIES = (
+    ("progen_tpu.models.longcat", "LongCatConfig", "LongCatFamily"),
+    ("progen_tpu.models.deepseek_v2", "DeepSeekV2Config", "DeepSeekV2Family"),
+    ("progen_tpu.models.trinity", "TrinityConfig", "TrinityFamily"),
+)
+
+
 def family_for(config, policy: Policy, weights: str = "bf16"):
     """The family that serves ``config``."""
     if isinstance(config, ProGenConfig):
         return ProGenFamily(config, policy, weights)
-    from progen_tpu.models.longcat import LongCatConfig, LongCatFamily
-
-    if isinstance(config, LongCatConfig):
-        return LongCatFamily(config, policy)
-    from progen_tpu.models.deepseek_v2 import (
-        DeepSeekV2Config,
-        DeepSeekV2Family,
-    )
-
-    if isinstance(config, DeepSeekV2Config):
-        return DeepSeekV2Family(config, policy)
+    for module, config_name, family_name in _DRIVER_FAMILIES:
+        models = importlib.import_module(module)
+        if isinstance(config, getattr(models, config_name)):
+            return getattr(models, family_name)(config, policy)
     raise TypeError(f"no model family serves a {type(config).__name__}")

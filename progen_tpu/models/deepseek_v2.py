@@ -5,8 +5,8 @@ expert-parallel deployment.
 
 What DeepSeek-V2 alone has: its config (YaRN table, the softmax scale's
 ``mscale^2``), the group-limited router, the sequential layer's wiring and
-the seeded weights' layout.  The latent attention, the model driver and the
-engine's seam are ``models/latent.py``; the held experts' grouped product,
+the seeded weights' layout.  The latent attention is ``models/latent.py``,
+the model driver and the engine's seam ``models/driver.py``; the held experts' grouped product,
 its window and the counters are ``models/experts.py`` — both shared with
 ``models/longcat.py``.
 
@@ -133,6 +133,7 @@ class DeepSeekV2Config:
         return yarn_mscale(s.factor, s.mscale_all_dim) ** 2
 
     kv_gain = 1.0
+    embed_gain = 1.0    # no factor on the embedding (models/driver.py)
 
     def yarn_bounds(self, d: int) -> tuple[int, int]:
         """The first and last of the ``d / 2`` frequencies between which
@@ -261,7 +262,8 @@ def moe_share(u, layer, c: DeepSeekV2Config, live):
     return y.astype(u.dtype), ids, stats
 
 
-STAT_KEYS = experts.STAT_KEYS + ("moe.held_groups_chosen",)
+STAT_KEYS = (experts.STAT_KEYS + latent.STAT_KEYS
+             + ("moe.held_groups_chosen",))
 
 
 def zero_stats(c: DeepSeekV2Config) -> dict:

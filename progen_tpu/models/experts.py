@@ -1,6 +1,7 @@
 """One chip's share of an expert layer: the grouped product over the
 experts the chip holds, its window, and the device counters the families
-with such a layer carry (``models/longcat.py``, ``models/deepseek_v2.py``).
+with such a layer carry (``models/longcat.py``, ``models/deepseek_v2.py``,
+``models/trinity.py``).
 
 The router is as wide as published and picks ``moe_topk`` whatever the chip
 holds; the layer adds the terms of the ``experts_held`` real experts from
@@ -22,11 +23,10 @@ import jax.numpy as jnp
 F32 = jnp.float32
 
 # what every family with a share counts (docs/OBSERVABILITY.md §3); a
-# family adds its own keys to these
+# family adds its own keys and its attention's (``latent.STAT_KEYS``,
+# ``trinity.ATTN_STAT_KEYS``) to these
 STAT_KEYS = ("moe.tokens", "moe.held_load", "moe.prefill_held",
-             "moe.decode_layers", "moe.experts_touched",
-             "mla.decode_rows", "mla.context_tokens",
-             "mla.cache_rows_read")
+             "moe.decode_layers", "moe.experts_touched")
 
 
 def zero_stats(keys, held: int) -> dict:

@@ -1,6 +1,8 @@
-"""Latent attention (MLA) and the model driver around it, shared by the
-families that cache a latent row per token (``models/longcat.py``,
-``models/deepseek_v2.py``).
+"""Latent attention (MLA): the attention block of the families that cache a
+latent row per token (``models/longcat.py``, ``models/deepseek_v2.py``).
+The model driver around it — embedding, a family's stack, logits, the
+engine's seam — is ``models/driver.py``, which both families share with
+``models/trinity.py``; its names are re-exported here.
 
 Plain functions over a parameter dict (no flax).  A family brings a config
 and its layer stack; everything here reads the config's fields and asks it
@@ -18,46 +20,45 @@ rope)^-1/2``: a family with another softmax scale folds the factor into
 ``q_lat . c_kv + q_rope . k_r``, ``o_lat = softmax . c_kv``, ``o = o_lat
 W_kvb[v]``.
 
-**The driver.**  ``prefill`` runs R rows of P tokens through a family's
-``stack`` and returns logits plus each attention block's latent cache rows;
-``decode_step`` advances S rows by one token in the absorbed form.
-``LatentFamily`` is what ``ServingEngine`` calls (``decode/family.py``).
-
-Precision: parameters and matrix products in the policy's dtypes (bfloat16
-as published); the routers, every softmax, the norms' statistics and the
-logits in float32.
+**What a latent block states about its cache** (``LatentBlock``, the one
+kind every block of these families is): one leaf ``(slots, max_len,
+latent_width)``; the token at position ``p`` lies in row ``p``; a slot at
+position ``pos`` has ``pos + 1`` rows.
 """
 
 from __future__ import annotations
 
 import math
+from collections import defaultdict
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 
-from progen_tpu.core.precision import Policy, make_policy
-from progen_tpu.models.experts import zero_stats
+from progen_tpu.core.precision import Policy
+from progen_tpu.models import driver
+from progen_tpu.models.driver import (  # noqa: F401
+    F32,
+    bf16_policy,
+    init_ffn,
+    init_norm,
+    init_params,
+    mm,
+    normal,
+    rms_norm,
+    rope,
+    swiglu,
+)
 from progen_tpu.ops.mla_decode import decode_attention, rows_visited
 from progen_tpu.ops.mla_prefill import prefill_attention
 from progen_tpu.ops.row_write import write_rows
 
-F32 = jnp.float32
-
-
-def bf16_policy() -> Policy:
-    """Parameters stored in bfloat16, as the sources publish them."""
-    return make_policy(True, param_dtype=jnp.bfloat16)
+# the device counters of a latent family's decode steps, beside the shared
+# ``experts.STAT_KEYS`` (docs/OBSERVABILITY.md section 3)
+STAT_KEYS = ("mla.decode_rows", "mla.context_tokens", "mla.cache_rows_read")
 
 
 # ------------------------------------------------------------------ weights
-
-
-def normal(key, shape, std, dtype):
-    return (jax.random.normal(key, shape, F32) * std).astype(dtype)
-
-
-def init_norm(key, shape, dt):
-    return normal(key, shape, 0.05, F32).astype(dt) + 1
 
 
 def init_attn(key, c, dt):
@@ -79,64 +80,7 @@ def init_attn(key, c, dt):
     }
 
 
-def init_ffn(key, h, width, gain, dt, lead=()):
-    ks = jax.random.split(key, 3)
-    return {
-        "wg": normal(ks[0], lead + (h, width), h ** -0.5, dt),
-        "wu": normal(ks[1], lead + (h, width), h ** -0.5, dt),
-        "wd": normal(ks[2], lead + (width, h), gain * width ** -0.5, dt),
-    }
-
-
-def init_params(config, key, policy: Policy, init_layer):
-    """Seeded weights, made on the device one layer per program so that no
-    more than a layer's random bits are live beside the weights.
-    ``init_layer(key, index)`` makes one layer's dict."""
-    c, dt, h = config, policy.param_dtype, config.hidden_size
-    keys = jax.random.split(key, c.num_layers + 3)
-    return {
-        "embed": jax.jit(lambda k: normal(k, (c.vocab_size, h), 1.0, dt))(
-            keys[0]),
-        "head": jax.jit(lambda k: normal(k, (h, c.vocab_size), h ** -0.5,
-                                         dt))(keys[1]),
-        "final_norm": jax.jit(lambda k: init_norm(k, (h,), dt))(keys[2]),
-        "layers": [init_layer(keys[3 + i], i) for i in range(c.num_layers)],
-    }
-
-
-# ------------------------------------------------------------------- pieces
-
-
-def rms_norm(x, scale, eps):
-    """Statistics in float32, the result in ``x``'s dtype."""
-    xf = x.astype(F32)
-    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale.astype(
-        x.dtype)
-
-
-def rope(x, positions, inv_freq):
-    """Half-split rotation of ``x (..., n, [heads,] d)`` at ``positions
-    (..., n)``; ``inv_freq(d)`` gives the ``d / 2`` frequencies; tables in
-    float32."""
-    d = x.shape[-1]
-    inv = inv_freq(d)
-    ang = positions.astype(F32)[..., None] * inv
-    if x.ndim == ang.ndim + 1:          # a heads axis between n and d
-        ang = ang[..., None, :]
-    sin, cos = jnp.sin(ang), jnp.cos(ang)
-    x1, x2 = x[..., : d // 2].astype(F32), x[..., d // 2:].astype(F32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
-
-
-def mm(x, w):
-    return jnp.dot(x, w.astype(x.dtype))
-
-
-def swiglu(x, p, scope="ffn.dense"):
-    with jax.named_scope(scope):
-        return mm(jax.nn.silu(mm(x, p["wg"])) * mm(x, p["wu"]), p["wd"])
+# ---------------------------------------------------------------------- MLA
 
 
 def mla_project(x, p, c, positions):
@@ -209,154 +153,72 @@ def mla_decode(x, pos, cache, p, c):
         return mm(o.reshape(s, -1), p["wo"]), cache
 
 
-# --------------------------------------------------------------- the driver
+# ------------------------------------------- the block, and the driver over it
 
 
-def _logits(x, params, c):
-    x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
-    return jnp.dot(x, params["head"].astype(x.dtype),
-                   preferred_element_type=F32)
+class LatentBlock:
+    """The one kind of attention block of a latent family
+    (``models/driver.py`` says what a block is)."""
+
+    def __init__(self, config):
+        self.config = config
+
+    def init_cache(self, slots: int, max_len: int, dtype):
+        return jnp.zeros((slots, max_len, self.config.latent_width), dtype)
+
+    def prefill(self, x, p, lengths):
+        return mla_prefill(x, p, self.config, lengths)
+
+    def cache_rows(self, rows, lengths, max_len: int):
+        n = rows.shape[1]
+        return rows[:, :max_len] if n >= max_len else jnp.pad(
+            rows, ((0, 0), (0, max_len - n), (0, 0)))
+
+    def decode(self, x, pos, cache, p):
+        return mla_decode(x, pos, cache, p, self.config)
 
 
-def prefill(stack, params, tokens, lengths, config, policy: Policy, *,
-            logit_positions=None, with_choices: bool = False):
-    """``tokens (R, P)`` right-padded rows of ``lengths (R,)`` real tokens
-    -> ``(logits (R, K, V) float32 at logit_positions (R, K)`` (default the
-    last real position, K = 1), ``latent rows {block: (R, P, latent)},
-    stats)``.  ``stack(x, params, config, attend, live)`` is the family's
-    layers over flat tokens, ``attend(x, block name, weights)`` the one
-    thing prefill and decode differ in; it returns ``(x, stats, chosen ids
-    per expert layer, held experts touched)``.  Padding, and the whole of
-    a row of length 0 (an admission row that carries no request), is
-    computed by the dense FFNs (the shapes are static) but not by the
-    experts, and by attention only where the blocked XLA form runs
-    (``ops/mla_prefill.py``: the kernel visits no tile past a row's
-    length); it is not counted, and no real position's output depends on
-    what it holds."""
-    c = config
-    dt = policy.compute_dtype
-    r, n = tokens.shape
-    live = (jnp.arange(n)[None, :] < lengths[:, None]).reshape(-1)
-    rows = {}
+def attention_stats(config, dt, caches, pos, live) -> dict:
+    """A decode step's ``mla.*`` counters."""
+    return {
+        "mla.decode_rows": jnp.sum(live).astype(F32),
+        "mla.context_tokens": jnp.sum(
+            jnp.where(live, pos + 1, 0)).astype(F32),
+        # every block's core has these shapes: the rows ONE of them reads
+        "mla.cache_rows_read": rows_visited(
+            dt, next(iter(caches.values())), pos + 1,
+            config.kv_lora_rank) * jnp.any(live),
+    }
 
-    def attend(x, name, p):
-        out, latent = mla_prefill(x.reshape(r, n, -1), p, c, lengths)
-        rows[name] = latent
-        return out.reshape(r * n, -1)
 
-    x = params["embed"][tokens.reshape(-1)].astype(dt)
-    x, stats, chosen, _ = stack(x, params, c, attend, live)
-    stats["moe.prefill_held"] = jnp.sum(stats["moe.held_load"])
-    if logit_positions is None:       # a row of no tokens reads position 0
-        logit_positions = jnp.maximum(lengths - 1, 0)[:, None]
-    x = jnp.take_along_axis(x.reshape(r, n, -1),
-                            logit_positions[..., None], axis=1)
-    out = _logits(x, params, c), rows, stats
-    if with_choices:
-        return out + (jnp.stack(chosen).reshape(len(chosen), r, n, -1),)
-    return out
+def _any_name(config):
+    """``{any name: a latent block}``: every block is of the one kind, and
+    these functions are not told the stack's names."""
+    return defaultdict(partial(LatentBlock, config))
+
+
+def prefill(stack, params, tokens, lengths, config, policy: Policy,
+            **kwargs):
+    """``driver.prefill`` over latent blocks: the per-token cache rows are
+    ``{block: (R, P, latent)}``."""
+    return driver.prefill(stack, _any_name(config), params, tokens, lengths,
+                          config, policy, **kwargs)
 
 
 def decode_step(stack, params, tok, pos, caches, live, config,
-                policy: Policy, *, with_choices: bool = False):
-    """One token per row: ``tok (S,)`` at ``pos (S,)`` -> ``(logits (S, V)
-    float32, caches, stats)``.  Rows that are not ``live`` run (the batch
-    is static) but are not counted and reach no expert."""
-    c = config
-    dt = policy.compute_dtype
-    caches = dict(caches)
-
-    def attend(x, name, p):
-        out, caches[name] = mla_decode(x, pos, caches[name], p, c)
-        return out
-
-    x = params["embed"][tok].astype(dt)
-    x, stats, chosen, touched = stack(x, params, c, attend, live)
-    stats["moe.decode_layers"] = jnp.asarray(
-        len(chosen), F32) * jnp.any(live)
-    stats["moe.experts_touched"] = touched
-    stats["mla.decode_rows"] = jnp.sum(live).astype(F32)
-    stats["mla.context_tokens"] = jnp.sum(
-        jnp.where(live, pos + 1, 0)).astype(F32)
-    # every block's core has these shapes: the rows ONE of them reads
-    stats["mla.cache_rows_read"] = rows_visited(
-        dt, next(iter(caches.values())), pos + 1,
-        c.kv_lora_rank) * jnp.any(live)
-    out = _logits(x, params, c), caches, stats
-    if with_choices:
-        return out + (jnp.stack(chosen),)
-    return out
+                policy: Policy, **kwargs):
+    """``driver.decode_step`` over latent blocks."""
+    return driver.decode_step(
+        stack, _any_name(config), partial(attention_stats, config),
+        params, tok, pos, caches, live, config, policy, **kwargs)
 
 
-# ------------------------------------------------------- the engine's seam
+class LatentFamily(driver.Family):
+    """A family whose every attention block is a :class:`LatentBlock`; it
+    brings ``cache_names(config)``, the blocks' names in its stack."""
 
+    def blocks_of(self, config):
+        return dict.fromkeys(self.cache_names(config), LatentBlock(config))
 
-class LatentFamily:
-    """What ``ServingEngine``'s plain dense path calls
-    (``decode/family.py``).  The cache is a second kind beside ProGen's
-    rings: per attention block a latent row per token, ``max_len`` long.
-    A family names itself and brings ``stack`` (its layers), ``stat_keys``
-    (its device counters) and ``cache_names(config)``."""
-
-    name: str
-    stat_keys: tuple
-    position_masks = False      # the state holds an (S, V) mask, not (S, L, V)
-    idle_length = 0             # a row without a request has no token
-    modes = frozenset()         # the plain dense path only
-    step_model = prefill_model = None
-
-    def __init__(self, config, policy: Policy):
-        self.config = config
-        self.policy = policy
-        self.bucket_base = config.prefill_bucket
-        self.vocab = config.vocab_size
-        self.seq_len = config.seq_len
-
-    def embedder(self, mesh=None, strategies=()):
-        return None
-
-    def init_caches(self, slots: int, max_len: int):
-        return {name: jnp.zeros((slots, max_len, self.config.latent_width),
-                                self.policy.compute_dtype)
-                for name in self.cache_names(self.config)}
-
-    def init_stats(self) -> dict:
-        return zero_stats(self.stat_keys, self.config.experts_held)
-
-    def bucket(self, prime_len: int, max_len: int) -> int:
-        b = self.bucket_base
-        while b < prime_len:
-            b *= 2
-        return min(b, -(-max_len // self.bucket_base) * self.bucket_base)
-
-    def buckets(self, cap: int, max_len: int) -> list[int]:
-        out = []
-        p = 1
-        while p <= cap:
-            out.append(self.bucket(p, max_len))
-            p = out[-1] + 1
-        return out
-
-    def prefill(self, params, tokens, lengths, max_len, adapters=None,
-                tenant=None):
-        logits, rows, stats = prefill(self.stack, params, tokens, lengths,
-                                      self.config, self.policy)
-        n = tokens.shape[1]
-        caches = {k: v[:, :max_len] if n >= max_len else jnp.pad(
-            v, ((0, 0), (0, max_len - n), (0, 0))) for k, v in rows.items()}
-        return logits[:, 0], caches, stats
-
-    def decode_step(self, params, tok, pos, caches, live, adapters=None,
-                    tenant=None):
-        return decode_step(self.stack, params, tok, pos, caches, live,
-                           self.config, self.policy)
-
-    def publish(self, stats: dict) -> dict:
-        """Registry gauges from the fetched counters (cumulative since the
-        engine was built): name -> value."""
-        out = {k: float(v) for k, v in stats.items() if k != "moe.held_load"}
-        load = stats["moe.held_load"]
-        out["moe.held_assignments"] = float(load.sum())
-        out["moe.held_load_max"] = float(load.max())
-        out["moe.held_load_mean"] = float(load.mean())
-        return out
+    def attention_stats(self, dt, caches, pos, live):
+        return attention_stats(self.config, dt, caches, pos, live)

@@ -4,9 +4,9 @@ deployment.
 
 What LongCat alone has: its config, the router (softmax over real and
 identity experts, chosen by ``p + b``), the double layer's wiring and the
-seeded weights' layout.  The latent attention, the model driver
-(``prefill`` / ``decode_step``) and the engine's seam are
-``models/latent.py``; the held experts' grouped product, its window and
+seeded weights' layout.  The latent attention is ``models/latent.py``, the
+model driver (``prefill`` / ``decode_step``) and the engine's seam
+``models/driver.py``; the held experts' grouped product, its window and
 the counters are ``models/experts.py`` — both shared with
 ``models/deepseek_v2.py``.
 
@@ -107,6 +107,8 @@ class LongCatConfig:
         return (math.sqrt(self.hidden_size / self.kv_lora_rank)
                 if self.mla_scale_kv_lora else 1.0)
 
+    embed_gain = 1.0    # no factor on the embedding (models/driver.py)
+
     def rope_inv_freq(self, d: int):
         return 1.0 / (self.rope_theta ** (jnp.arange(0, d, 2, dtype=F32) / d))
 
@@ -186,7 +188,8 @@ def moe_share(u, layer, c: LongCatConfig, live):
     return y.astype(u.dtype), ids, stats
 
 
-STAT_KEYS = ("moe.tokens", "moe.real_chosen") + experts.STAT_KEYS[1:]
+STAT_KEYS = (("moe.tokens", "moe.real_chosen") + experts.STAT_KEYS[1:]
+             + latent.STAT_KEYS)
 
 
 def zero_stats(c: LongCatConfig) -> dict:

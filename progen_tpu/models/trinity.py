@@ -1,0 +1,471 @@
+"""Trinity (``model_type`` ``afmoe``): gated grouped-query attention with
+RMSNorm on q and k, sliding-window blocks and full-attention blocks mixed in
+one model, a leading dense layer, then expert layers with sigmoid top-k
+routing beside a shared expert, as ONE CHIP'S SHARE of an expert-parallel
+deployment.
+
+What Trinity alone has: its config, the two kinds of attention block and
+what each states about its cache, the sigmoid router with its selection
+bias, the four-norm layer's wiring and the seeded weights' layout.  The
+model driver and the engine's seam are ``models/driver.py``; the held
+experts' grouped product, its window and the ``moe.*`` counters are
+``models/experts.py`` — both shared with ``models/longcat.py`` and
+``models/deepseek_v2.py``.  The attention cores are ``ops/gqa.py``.
+
+Layer ``l`` (sandwich norms, ``N_*`` RMSNorms with a learned scale)::
+
+    a = x + N_post_attn(Attn_l(N_in(x)))
+    out = a + N_post_mlp(F_l(N_pre_mlp(a)))
+
+``x = E[token] * sqrt(hidden)`` where ``mup_enabled``.  ``F_l`` is a dense
+SwiGLU of width ``intermediate_size`` for ``l < num_dense_layers`` and the
+expert layer after: ``F(u) = S(u) + sum_i w_i E_i(u)``, ``S`` one SwiGLU of
+width ``num_shared_experts * moe_intermediate_size`` on every token.
+
+**Attention.**  ``q = u W_q`` (H heads of d), ``k = u W_k``, ``v = u W_v``
+(KV heads of d), ``g = sigmoid(u W_gate)`` (H x d); q and k RMS-normed per
+head over d.  A ``sliding_attention`` block rotates q and k (half-split
+RoPE on all d) and token ``i`` sees ``j`` iff ``0 <= i - j <
+sliding_window``; a ``full_attention`` block has NO rotary embedding and
+sees ``j <= i``.  Scores scaled by ``d^-1/2``, softmax in float32, each
+key/value head serves ``H / KV`` query heads; result ``((softmax . v) * g)
+W_o``.
+
+**Two kinds of cache in one slot** (``KVBlock``; ``models/driver.py`` says
+what a block is).  Both hold ``{"k", "v"}: (slots, KV, rows, d)`` — the
+token axis second to last, so that the chip tiles ``(rows, d)`` and the
+step's write is ``ops/row_write.py``'s kernel — and both are read by one
+decode core (``ops/gqa.py:decode_attention``) up to a per-slot count.  They
+differ in two lines:
+
+* a sliding block's cache is a RING of ``min(sliding_window, max_len)``
+  rows: the token at position ``p`` lies in row ``p % rows`` and a slot at
+  ``pos`` has ``min(pos + 1, rows)`` of them (rotated keys are cached, so
+  their order in the ring does not matter);
+* a full block's cache GROWS with the request: ``max_len`` rows, the token
+  at ``p`` in row ``p``, ``pos + 1`` of them.
+
+**The router**, float32: ``s = sigmoid(u W_r)`` over ``num_experts``; the
+``num_experts_per_tok`` largest of ``s + b`` are chosen (``b`` the
+balancing bias, a buffer: it picks and does not weigh); ``w_i = route_scale
+* s_i / (sum_chosen s + 1e-20)`` (``route_norm``).  ``n_group = topk_group
+= 1``: no group limit.
+
+**The share.**  As the sibling families': the router keeps its width and
+top-k whatever is held; the layer adds the terms of the held experts and
+leaves out the absent ones' (BEFORE ``N_post_mlp``, which therefore norms
+this chip's partial sum: a deployment norms the sum of all shares);
+attention, the dense layer and the shared expert are whole on every chip.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+
+from progen_tpu.core.precision import Policy
+from progen_tpu.models import driver, experts
+from progen_tpu.models.driver import (  # noqa: F401
+    F32,
+    bf16_policy,
+    mm,
+    rms_norm,
+    swiglu,
+)
+from progen_tpu.models.experts import held_experts
+from progen_tpu.ops import gqa
+from progen_tpu.ops.row_write import write_rows
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+
+
+@dataclasses.dataclass(frozen=True)
+class TrinityConfig:
+    """The published keys (catalog names) plus the share this chip holds
+    and the scales of the seeded weights."""
+
+    vocab_size: int = 200192
+    hidden_size: int = 2048
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 1024
+    num_hidden_layers: int = 32
+    num_dense_layers: int = 2
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    # one of SLIDING / FULL a layer; empty: every
+    # ``global_attn_every_n_layers``-th layer full, the others sliding
+    layer_types: tuple = ()
+    global_attn_every_n_layers: int = 4
+    sliding_window: int = 2048
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    num_shared_experts: int = 1
+    n_group: int = 1
+    topk_group: int = 1
+    route_norm: bool = True
+    route_scale: float = 2.826
+    score_func: str = "sigmoid"
+    mup_enabled: bool = True
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    max_position_embeddings: int = 131072
+    # the share: experts ``first_expert .. first_expert + held - 1``
+    experts_held: int = 128
+    first_expert: int = 0
+    # seeded weights (``init_params``): the router logits' spread a token,
+    # and the selection bias's (in units of a score: it moves some choices)
+    router_logit_std: float = 1.0
+    router_bias_std: float = 0.02
+    # the engine pads primes to ``prefill_bucket * 2^k`` tokens
+    prefill_bucket: int = 512
+
+    # what the shared code reads under its own names
+    @property
+    def num_layers(self) -> int:
+        return self.num_hidden_layers
+
+    @property
+    def moe_topk(self) -> int:
+        return self.num_experts_per_tok
+
+    @property
+    def router_width(self) -> int:
+        return self.num_experts
+
+    @property
+    def seq_len(self) -> int:
+        return self.max_position_embeddings
+
+    @property
+    def embed_gain(self) -> float:
+        return math.sqrt(self.hidden_size) if self.mup_enabled else 1.0
+
+    def rope_inv_freq(self, d: int):
+        return 1.0 / (self.rope_theta ** (jnp.arange(0, d, 2, dtype=F32) / d))
+
+    @classmethod
+    def from_dict(cls, d) -> "TrinityConfig":
+        names = {f.name for f in dataclasses.fields(cls)}
+        d = {k: v for k, v in d.items() if k in names}
+        if "layer_types" in d:
+            d["layer_types"] = tuple(d["layer_types"])
+        return cls(**d)
+
+    def __post_init__(self):
+        if not self.layer_types:
+            every = self.global_attn_every_n_layers
+            object.__setattr__(self, "layer_types", tuple(
+                FULL if (i + 1) % every == 0 else SLIDING
+                for i in range(self.num_hidden_layers)))
+        if (len(self.layer_types) != self.num_hidden_layers
+                or set(self.layer_types) - {SLIDING, FULL}):
+            raise ValueError(
+                f"layer_types must name {self.num_hidden_layers} layers, "
+                f"each {SLIDING!r} or {FULL!r}: {self.layer_types}")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"{self.num_attention_heads} query heads do not split over "
+                f"{self.num_key_value_heads} key/value heads")
+        if not (0 <= self.first_expert
+                and self.first_expert + self.experts_held
+                <= self.num_experts):
+            raise ValueError(
+                f"experts {self.first_expert}..+{self.experts_held} are not "
+                f"among the {self.num_experts} routed experts")
+        if self.score_func != "sigmoid" or self.n_group != 1 \
+                or self.topk_group != 1:
+            raise ValueError(
+                "the router is sigmoid top-k with no group limit: "
+                f"score_func {self.score_func!r}, n_group {self.n_group}, "
+                f"topk_group {self.topk_group} are not supported")
+
+
+# ------------------------------------------------------------------ weights
+
+
+def _init_attn(key, c: TrinityConfig, dt):
+    h, d = c.hidden_size, c.head_dim
+    q, kv = c.num_attention_heads * d, c.num_key_value_heads * d
+    ks = jax.random.split(key, 7)
+    return {
+        "wq": driver.normal(ks[0], (h, q), h ** -0.5, dt),
+        "wk": driver.normal(ks[1], (h, kv), h ** -0.5, dt),
+        "wv": driver.normal(ks[2], (h, kv), h ** -0.5, dt),
+        "wgate": driver.normal(ks[3], (h, q), h ** -0.5, dt),
+        "wo": driver.normal(ks[4], (q, h), q ** -0.5, dt),
+        "q_norm": driver.init_norm(ks[5], (d,), dt),
+        "k_norm": driver.init_norm(ks[6], (d,), dt),
+    }
+
+
+def _init_layer(key, c: TrinityConfig, dt, dense: bool):
+    ks = jax.random.split(key, 7)
+    h = c.hidden_size
+    layer = {"norm": driver.init_norm(ks[0], (4, h), dt),
+             "attn": _init_attn(ks[1], c, dt)}
+    if dense:
+        layer["ffn"] = driver.init_ffn(ks[2], h, c.intermediate_size, 1.0, dt)
+        return layer
+    # logits spread by ``router_logit_std`` per token (the normed input has
+    # unit RMS), so choices differ between tokens; the bias is a float32
+    # buffer, as the release keeps it
+    layer["router"] = {
+        "w": driver.normal(ks[3], (h, c.num_experts),
+                           c.router_logit_std * h ** -0.5, dt),
+        "bias": driver.normal(ks[4], (c.num_experts,), c.router_bias_std,
+                              F32)}
+    layer["experts"] = driver.init_ffn(ks[5], h, c.moe_intermediate_size,
+                                       1.0, dt, lead=(c.experts_held,))
+    layer["shared"] = driver.init_ffn(
+        ks[6], h, c.num_shared_experts * c.moe_intermediate_size, 1.0, dt)
+    return layer
+
+
+def init_params(config: TrinityConfig, key, policy: Policy | None = None):
+    policy = policy or bf16_policy()
+    layer = {dense: jax.jit(partial(_init_layer, c=config,
+                                    dt=policy.param_dtype, dense=dense))
+             for dense in (True, False)}
+    return driver.init_params(
+        config, key, policy,
+        lambda k, i: layer[i < config.num_dense_layers](k))
+
+
+# ---------------------------------------------------------------- attention
+
+
+def project(x, p, c: TrinityConfig, positions, rotary: bool):
+    """``x (..., n, h)`` at ``positions (..., n)`` -> ``q (..., n, H, d)``,
+    ``k, v (..., n, KV, d)`` (q and k normed per head, rotated where
+    ``rotary``) and the gate ``(..., n, H * d)``."""
+    d = c.head_dim
+    with jax.named_scope("attn.project"):
+        q = mm(x, p["wq"])
+        q = q.reshape(q.shape[:-1] + (c.num_attention_heads, d))
+        k = mm(x, p["wk"])
+        k = k.reshape(k.shape[:-1] + (c.num_key_value_heads, d))
+        v = mm(x, p["wv"]).reshape(k.shape)
+        q = rms_norm(q, p["q_norm"], c.rms_norm_eps)
+        k = rms_norm(k, p["k_norm"], c.rms_norm_eps)
+        if rotary:
+            q = driver.rope(q, positions, c.rope_inv_freq)
+            k = driver.rope(k, positions, c.rope_inv_freq)
+    with jax.named_scope("attn.gate"):
+        gate = jax.nn.sigmoid(mm(x, p["wgate"]))
+    return q, k, v, gate
+
+
+class KVBlock:
+    """One attention block and what it states about its cache
+    (``models/driver.py`` says what a block is): ``window`` rows in a ring
+    for a sliding block, ``max_len`` rows that grow with the request for a
+    full one (``window`` None).  The module docstring has both layouts."""
+
+    def __init__(self, config: TrinityConfig, window: int | None):
+        self.config = config
+        self.window = window
+        self.scope = "attn.full" if window is None else "attn.window"
+        self.scale = 1.0 / math.sqrt(config.head_dim)
+
+    def rows(self, max_len: int) -> int:
+        """Rows a slot's cache has in an engine of ``max_len``."""
+        return max_len if self.window is None else min(self.window, max_len)
+
+    def place(self, pos, rows: int):
+        """``(the row the token at pos lies in, the rows a slot at pos
+        has)`` of a cache of ``rows`` rows."""
+        if self.window is None:
+            return pos, pos + 1
+        return pos % rows, jnp.minimum(pos + 1, rows)
+
+    def init_cache(self, slots: int, max_len: int, dtype):
+        c = self.config
+        shape = (slots, c.num_key_value_heads, self.rows(max_len),
+                 c.head_dim)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+    def prefill(self, x, p, lengths):
+        """Attention over ``x (R, P, h)``; the per-token rows are ``{"k",
+        "v"}: (R, KV, P, d)``."""
+        r, n, _ = x.shape
+        positions = jnp.broadcast_to(jnp.arange(n), (r, n))
+        q, k, v, gate = project(x, p, self.config, positions,
+                                rotary=self.window is not None)
+        k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+        with jax.named_scope(self.scope):
+            o = gqa.prefill_attention(q, k, v, self.scale, self.window)
+        return mm(o * gate, p["wo"]), {"k": k, "v": v}
+
+    def cache_rows(self, rows, lengths, max_len: int):
+        """The per-token rows of R primes as R slots' caches: a full block
+        keeps the first ``max_len`` tokens where they are; a ring takes,
+        for each of its rows ``j``, the LAST real token ``p < length`` with
+        ``p % rows == j`` (rows no token has reached hold anything: no
+        count reaches them)."""
+        n = rows["k"].shape[2]
+        size = self.rows(max_len)
+        if self.window is None:
+            return {name: a[:, :, :size] if n >= size else jnp.pad(
+                a, ((0, 0), (0, 0), (0, size - n), (0, 0)))
+                for name, a in rows.items()}
+        j = jnp.arange(size)[None, :]
+        last = lengths[:, None] - 1
+        at = jnp.clip(j + size * ((last - j) // size), 0, n - 1)
+        return {name: jnp.take_along_axis(a, at[:, None, :, None], axis=2)
+                for name, a in rows.items()}
+
+    def decode(self, x, pos, cache, p):
+        """One token a row: ``x (S, h)`` at ``pos (S,)``; the new key and
+        value are written (``ops/row_write.py``) and the slot's rows
+        attended."""
+        q, k, v, gate = project(x[:, None], p, self.config, pos[:, None],
+                                rotary=self.window is not None)
+        at, counts = self.place(pos, cache["k"].shape[2])
+        with jax.named_scope(self.scope):
+            keys, values = write_rows(
+                (cache["k"], cache["v"]),
+                (k[:, 0].astype(cache["k"].dtype),
+                 v[:, 0].astype(cache["v"].dtype)), at, axis=1)
+            o = gqa.decode_attention(q[:, 0], keys, values, counts,
+                                     self.scale)
+        return mm(o * gate[:, 0], p["wo"]), {"k": keys, "v": values}
+
+
+
+def blocks_of(c: TrinityConfig) -> dict:
+    kinds = {SLIDING: KVBlock(c, c.sliding_window), FULL: KVBlock(c, None)}
+    return {f"l{i}": kinds[kind] for i, kind in enumerate(c.layer_types)}
+
+
+ATTN_STAT_KEYS = ("attn.decode_rows", "attn.context_tokens",
+                  "attn.window_tokens", "attn.window_rows_read",
+                  "attn.full_rows_read")
+
+
+def attention_stats(blocks: dict, caches, pos, live) -> dict:
+    """A decode step's ``attn.*`` counters: its live rows, their contexts,
+    the part of them a window keeps, and the cache rows ONE block of each
+    kind reads under the lowering that ran."""
+    seen = jnp.where(live, pos + 1, 0)
+    stats = {"attn.decode_rows": jnp.sum(live).astype(F32),
+             "attn.context_tokens": jnp.sum(seen).astype(F32),
+             "attn.window_tokens": jnp.zeros((), F32),
+             "attn.window_rows_read": jnp.zeros((), F32),
+             "attn.full_rows_read": jnp.zeros((), F32)}
+    for kind in ("window", "full"):
+        name = next((n for n, b in blocks.items()
+                     if (b.window is None) == (kind == "full")), None)
+        if name is None:
+            continue
+        cache = caches[name]
+        stats[f"attn.{kind}_rows_read"] = (
+            gqa.rows_visited(cache["k"]) * jnp.any(live))
+        if kind == "window":
+            stats["attn.window_tokens"] = jnp.sum(jnp.minimum(
+                seen, cache["k"].shape[2])).astype(F32)
+    return stats
+
+
+# ------------------------------------------------------------------ experts
+
+
+def route(u, router, c: TrinityConfig):
+    """``(ids (T, k), weights (T, k))``, float32 throughout: the largest
+    ``s + b`` are chosen, the weights come from ``s`` alone."""
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(u.astype(F32), router["w"].astype(F32),
+                         precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)
+        _, ids = jax.lax.top_k(scores + router["bias"].astype(F32),
+                               c.num_experts_per_tok)
+        w = jnp.take_along_axis(scores, ids, axis=-1)
+        if c.route_norm:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return ids, w * c.route_scale
+
+
+def moe_share(u, layer, c: TrinityConfig, live):
+    """This chip's share of the ROUTED experts over ``u (T, h)`` (the
+    shared expert is the caller's: every chip computes it alike) and what
+    it counted over the ``live`` tokens."""
+    ids, w = route(u, layer["router"], c)
+    y, load = held_experts(u, ids, w, live, layer["experts"], c)
+    stats = {"moe.tokens": jnp.sum(live).astype(F32),
+             "moe.held_load": load.astype(F32)}
+    return y.astype(u.dtype), ids, stats
+
+
+STAT_KEYS = experts.STAT_KEYS + ATTN_STAT_KEYS
+
+
+def zero_stats(c: TrinityConfig) -> dict:
+    """Device-side counters, all float32 sums (docs/OBSERVABILITY.md §3)."""
+    return experts.zero_stats(STAT_KEYS, c.experts_held)
+
+
+# -------------------------------------------------------------------- model
+
+
+def _layers(x, params, c, attend, live):
+    """The stack over ``x (T, h)`` flat tokens (``driver.prefill`` says
+    what the driver asks of it)."""
+    stats = zero_stats(c)
+    chosen, touched = [], 0.0
+    for i, layer in enumerate(params["layers"]):
+        n, eps = layer["norm"], c.rms_norm_eps
+        attn = attend(rms_norm(x, n[0], eps), f"l{i}", layer["attn"])
+        a = x + rms_norm(attn, n[1], eps)
+        u = rms_norm(a, n[2], eps)
+        if "experts" not in layer:
+            x = a + rms_norm(swiglu(u, layer["ffn"]), n[3], eps)
+            continue
+        m, ids, s = moe_share(u, layer, c, live)
+        stats = experts.add_stats(stats, s)
+        touched += jnp.sum(s["moe.held_load"] > 0).astype(F32)
+        chosen.append(ids)
+        f = m + swiglu(u, layer["shared"], scope="moe.shared")
+        x = a + rms_norm(f, n[3], eps)
+    return x, stats, chosen, touched
+
+
+def prefill(params, tokens, lengths, config: TrinityConfig,
+            policy: Policy | None = None, **kwargs):
+    """``driver.prefill`` over Trinity's stack and blocks: the per-token
+    cache rows are ``{block: {"k", "v"}: (R, KV, P, d)}``."""
+    return driver.prefill(_layers, blocks_of(config), params, tokens,
+                          lengths, config, policy or bf16_policy(), **kwargs)
+
+
+def caches_from(rows, lengths, config: TrinityConfig, max_len: int):
+    """The per-token rows :func:`prefill` returned, as the caches of R
+    slots in an engine of ``max_len``."""
+    blocks = blocks_of(config)
+    return {name: blocks[name].cache_rows(v, lengths, max_len)
+            for name, v in rows.items()}
+
+
+def decode_step(params, tok, pos, caches, live, config: TrinityConfig,
+                policy: Policy | None = None, **kwargs):
+    """``driver.decode_step`` over Trinity's stack and blocks."""
+    blocks = blocks_of(config)
+    return driver.decode_step(
+        _layers, blocks,
+        lambda dt, caches, pos, live: attention_stats(blocks, caches, pos,
+                                                      live),
+        params, tok, pos, caches, live, config, policy or bf16_policy(),
+        **kwargs)
+
+
+class TrinityFamily(driver.Family):
+    name = "trinity"
+    stat_keys = STAT_KEYS
+    stack = staticmethod(_layers)
+    blocks_of = staticmethod(blocks_of)
+
+    def attention_stats(self, dt, caches, pos, live):
+        return attention_stats(self.blocks, caches, pos, live)

@@ -169,6 +169,8 @@ ROW_WRITES = {
     "base-gate": ((16, 2048, 3072), 1),
     "longcat-latent": ((32, 4096, 576), 1),
     "dsv2-latent": ((64, 3072, 576), 1),
+    "trinity-ring": ((64, 4, 2048, 128), 2),
+    "trinity-grown": ((64, 4, 9216, 128), 2),
 }
 
 
@@ -287,6 +289,52 @@ def test_dsv2_absorbed_decode_compiles_for_the_chip(shape,
             shape((64,), jnp.int32),
             shape((64, 3072, c.latent_width), jnp.bfloat16), p).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024 ** 3
+
+
+# ---- Trinity's pieces at published widths (models/trinity.py) ----
+
+
+@pytest.mark.parametrize("tokens", [64, 4 * 8192], ids=["decode", "prefill"])
+def test_trinity_expert_share_compiles_for_the_chip(shape, tokens,
+                                                    no_persistent_cache):
+    """One expert layer's share (sigmoid router with its bias, sort, the
+    three ``ragged_dot``s over 16 held experts of width 1024, scatter-add)
+    at a decode step's 64 rows and at an admission run's 4 x 8192."""
+    from progen_tpu.models import trinity
+
+    c = trinity.TrinityConfig(num_hidden_layers=2, num_dense_layers=1,
+                              vocab_size=25024, experts_held=16)
+    layer = _longcat_shapes(
+        shape, lambda k: trinity._init_layer(k, c, jnp.bfloat16, False),
+        jax.random.key(0))
+    u = shape((tokens, c.hidden_size), jnp.bfloat16)
+    live = shape((tokens,), jnp.bool_)
+    _assert_kernel_compiles(
+        lambda layer, u, live: trinity.moe_share(u, layer, c, live),
+        layer, u, live)
+
+
+@pytest.mark.parametrize("window,rows", [(2048, 2048), (None, 9216)],
+                         ids=["ring", "grown"])
+def test_trinity_decode_block_compiles_for_the_chip(shape, window, rows,
+                                                    no_persistent_cache):
+    """One attention block's decode step of 64 rows over a slot's ring or
+    grown keys (the XLA core: scores ``(64, 32, rows)`` float32)."""
+    from progen_tpu.models import trinity
+
+    c = trinity.TrinityConfig(num_hidden_layers=2, num_dense_layers=1,
+                              vocab_size=25024, experts_held=16)
+    block = trinity.KVBlock(c, window)
+    p = _longcat_shapes(
+        shape, lambda k: trinity._init_attn(k, c, jnp.bfloat16),
+        jax.random.key(0))
+    kv = shape((64, 4, rows, 128), jnp.bfloat16)
+    compiled = jax.jit(block.decode).lower(
+        shape((64, c.hidden_size), jnp.bfloat16), shape((64,), jnp.int32),
+        {"k": kv, "v": kv}, p).compile()
+    # the scores and their softmax, not a second cache
+    one_cache = 64 * 4 * rows * 128 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * one_cache
 
 
 @pytest.mark.parametrize("bucket", [512, 1024])
