@@ -819,9 +819,10 @@ class ServingEngine:
         tracing it notes which lowering each op that owns two took
         (``ops/lowering.py``) for ``status()``: the step's cache writes
         (``"row_write"``: ``"pallas"`` on a TPU, ``"scatter"`` elsewhere,
-        both joined by ``+`` where the shapes split them) and a latent
+        both joined by ``+`` where the shapes split them), a latent
         attention's prefill and absorbed decode cores (``"mla_prefill"``,
-        ``"mla_decode"``: ``"pallas"`` / ``"xla"``)."""
+        ``"mla_decode"``: ``"pallas"`` / ``"xla"``) and a grouped-query
+        attention's prefill core (``"gqa_prefill"``, the same two)."""
 
         @wraps(impl)
         def traced(*args):
@@ -2417,12 +2418,14 @@ class ServingEngine:
             "inflight_uids": sorted(r.uid for r in
                                     list(self._inflight.values())),
             "chunks_run": self.chunks_run,
-            # lowering of the chunk program's cache writes and of a latent
-            # attention's prefill and decode cores; None until a program
-            # that holds the op has been traced
+            # lowering of the chunk program's cache writes, of a latent
+            # attention's prefill and decode cores and of a grouped-query
+            # attention's prefill core; None until a program that holds
+            # the op has been traced
             "row_write": self.lowerings.get("row_write"),
             "mla_prefill": self.lowerings.get("mla_prefill"),
             "mla_decode": self.lowerings.get("mla_decode"),
+            "gqa_prefill": self.lowerings.get("gqa_prefill"),
             "paged": self.paged,
             "disagg": self.disagg,
             "spec": self.spec,

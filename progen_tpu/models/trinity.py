@@ -298,7 +298,8 @@ class KVBlock:
                                 rotary=self.window is not None)
         k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
         with jax.named_scope(self.scope):
-            o = gqa.prefill_attention(q, k, v, self.scale, self.window)
+            o = gqa.prefill_attention(q, k, v, self.scale, self.window,
+                                      lengths)
         return mm(o * gate, p["wo"]), {"k": k, "v": v}
 
     def cache_rows(self, rows, lengths, max_len: int):
@@ -344,7 +345,8 @@ def blocks_of(c: TrinityConfig) -> dict:
 
 ATTN_STAT_KEYS = ("attn.decode_rows", "attn.context_tokens",
                   "attn.window_tokens", "attn.window_rows_read",
-                  "attn.full_rows_read")
+                  "attn.full_rows_read", "attn.prefill_pairs_allowed",
+                  "attn.prefill_pairs_visited")
 
 
 def attention_stats(blocks: dict, caches, pos, live) -> dict:
@@ -369,6 +371,21 @@ def attention_stats(blocks: dict, caches, pos, live) -> dict:
             stats["attn.window_tokens"] = jnp.sum(jnp.minimum(
                 seen, cache["k"].shape[2])).astype(F32)
     return stats
+
+
+def prefill_attention_stats(blocks: dict, n: int, lengths, dt) -> dict:
+    """A prefill's ``attn.prefill_pairs_*`` counters over rows of
+    ``lengths (R,)`` padded to ``n``: the query-key pairs the mask allows
+    at real positions and the pairs the lowering that runs computes
+    (``ops/gqa.py``), summed over the attention blocks, per head."""
+    allowed = visited = jnp.zeros((), F32)
+    for block in blocks.values():
+        lowering = gqa.prefill_lowering(n, block.config.head_dim, dt,
+                                        block.window)
+        allowed += gqa.pairs_allowed(lengths, block.window)
+        visited += gqa.pairs_visited(lengths, n, block.window, lowering)
+    return {"attn.prefill_pairs_allowed": allowed,
+            "attn.prefill_pairs_visited": visited}
 
 
 # ------------------------------------------------------------------ experts
@@ -436,9 +453,15 @@ def _layers(x, params, c, attend, live):
 def prefill(params, tokens, lengths, config: TrinityConfig,
             policy: Policy | None = None, **kwargs):
     """``driver.prefill`` over Trinity's stack and blocks: the per-token
-    cache rows are ``{block: {"k", "v"}: (R, KV, P, d)}``."""
-    return driver.prefill(_layers, blocks_of(config), params, tokens,
-                          lengths, config, policy or bf16_policy(), **kwargs)
+    cache rows are ``{block: {"k", "v"}: (R, KV, P, d)}``; the stats gain
+    the attention cores' pair counters."""
+    policy = policy or bf16_policy()
+    blocks = blocks_of(config)
+    out = driver.prefill(_layers, blocks, params, tokens, lengths, config,
+                         policy, **kwargs)
+    out[2].update(prefill_attention_stats(
+        blocks, tokens.shape[1], lengths, policy.compute_dtype))
+    return out
 
 
 def caches_from(rows, lengths, config: TrinityConfig, max_len: int):
@@ -469,3 +492,10 @@ class TrinityFamily(driver.Family):
 
     def attention_stats(self, dt, caches, pos, live):
         return attention_stats(self.blocks, caches, pos, live)
+
+    def prefill(self, params, tokens, lengths, max_len, adapters=None,
+                tenant=None):
+        logits, rows, stats = prefill(params, tokens, lengths, self.config,
+                                      self.policy)
+        return logits[:, 0], caches_from(rows, lengths, self.config,
+                                         max_len), stats

@@ -1,22 +1,68 @@
 """Grouped-query attention cores: ``H`` query heads in groups of ``H / KV``
 that share one of ``KV`` key/value heads (query head ``h`` reads key/value
 head ``h // (H / KV)``), with a causal mask and an optional per-token
-sliding window.  Both are plain XLA: the baseline a kernel would start from.
+sliding window.
 
 :func:`prefill_attention` — ``q (R, P, H, d)`` against ``k, v (R, KV, P,
-d)``: position ``i`` sees ``j`` iff ``j <= i`` and, under a ``window``,
-``i - j < window``.  Blocks of ``QUERY_BLOCK`` query rows against the keys
-they can see, float32 softmax (divided by its sum after the value product);
-no ``(P, P)`` tensor exists.  Under a window
-every block has ONE shape — its own rows and the ``window`` before them, the
-keys padded in front so that the first blocks have it too — and the blocks
-are a ``lax.map`` over one body; without one a block's keys grow with it, so
-``FULL_GROUP`` consecutive blocks share the keys of the last of them (a map
-over one body a group, a tenth more keys than the causal half) and the
-groups are unrolled: an 8192-token prefill is 8 bodies a full layer and 1 a
-sliding layer, not 32 each, which is what its compile time and the size of
-its cache entry follow.  Pad positions are computed; a real position sees
-real keys only (the mask is causal), so its output does not depend on them.
+d)``, ``lengths (R,)`` leading positions of each row real: position ``i``
+sees ``j`` iff ``j <= i``, under a ``window`` ``i - j < window``, and ``j <
+length``.  It returns the heads' outputs laid out as the output projection
+reads them, ``(R, P, H * d)``.  The contract is the output AT REAL
+POSITIONS (a real query sees real keys only, because attention is causal);
+a pad position's output is finite and otherwise unspecified — nothing
+downstream of a prefill reads it.  Two lowerings keep that contract, chosen
+from what the code can observe and never from a knob (as
+``ops/mla_prefill.py`` and ``ops/row_write.py``):
+
+* **Pallas kernel** ``gqa_prefill_fwd`` — on a TPU backend, no mesh in
+  scope, 2- or 4-byte floats, ``d`` a multiple of 128 (the lane tile: a
+  block of ``q`` and of the output is one head's columns of ``(R, P, H *
+  d)``, so neither is ever transposed), ``P`` a multiple of ``MIN_TILE``
+  (Trinity's prefill buckets are 512 * 2^k) and ``window`` None or a
+  multiple of the key tile.  One flash kernel for both kinds of block,
+  ``window`` a static parameter that changes which tiles are visited and
+  nothing else.  Grid ``(R, H, P / bq, key steps)``, the key axis
+  innermost and RELATIVE: step ``ki`` of query tile ``qi`` is key tile
+  ``first + ki``, where :func:`key_tiles` gives the ``first`` and ``last``
+  key tile a real query of the tile sees — nothing above the diagonal,
+  nothing past the row's length, nothing wholly left of the window — so
+  under a window the axis is as long as the window's span of tiles and not
+  ``P / bk``.  Steps past ``last`` are neither computed nor fetched (their
+  index map stays on ``last``); a query tile that starts at or past the
+  row's length is not computed and reads as zeros; a row of length 0 costs
+  no attention.  ``k, v`` stay ``(R, KV, P, d)``: the index map sends query
+  head ``h`` to key/value head ``h // (H / KV)``, no key is repeated.  A
+  ``(bq, bk)`` score tile ``q k^T`` is accumulated in float32 from the
+  compute-dtype operands and scaled in float32, lives in VMEM only, and
+  updates a float32 running maximum, sum and output accumulator;
+  probabilities are cast to the compute dtype for the value product alone,
+  and the ONE division by the sum comes at the end.  Only tiles that the
+  diagonal or the window's left edge crosses pay for the iota mask.  **The
+  first VISITED tile initialises** the running maximum, sum and
+  accumulator (step ``ki == 0``), and the mask is a large FINITE negative,
+  not ``-inf``: the first visited tile under a window shows its key to the
+  tile's first rows only, and a row that has seen no key yet carries a
+  maximum of ``MASKED`` and weights that the first key it does see scales
+  by ``exp(MASKED - m) == 0`` exactly.
+* **blocked XLA** (:func:`blocked_prefill_attention`) — everywhere else
+  (the CPU of tier-1, the tests' tiny widths, any trace under a mesh), and
+  the kernel's test oracle: blocks of ``QUERY_BLOCK`` query rows against
+  the keys they can see, float32 softmax (divided by its sum after the
+  value product); no ``(P, P)`` tensor exists.  Under a window every block
+  has ONE shape — its own rows and the ``window`` before them, the keys
+  padded in front so that the first blocks have it too — and the blocks are
+  a ``lax.map`` over one body; without one a block's keys grow with it, so
+  ``FULL_GROUP`` consecutive blocks share the keys of the last of them (a
+  map over one body a group, a tenth more keys than the causal half) and
+  the groups are unrolled.  It takes no notice of ``lengths``: pad
+  positions and empty rows are computed in full.
+
+Which one a traced call took is noted under ``"gqa_prefill"``
+(``ops/lowering.py``; ``ServingEngine.status()["gqa_prefill"]``), and
+:func:`pairs_visited` counts the query-key pairs it computes beside the
+pairs the mask allows (:func:`pairs_allowed`; the counters
+``attn.prefill_pairs_visited`` / ``attn.prefill_pairs_allowed``) from the
+same :func:`key_tiles` the kernel's grid follows.
 
 :func:`decode_attention` — one query a slot, ``q (S, H, d)``, against the
 first ``counts (S,)`` rows of ``k, v (S, KV, T, d)`` in whatever order they
@@ -24,20 +70,35 @@ lie: a ring of the last ``T`` tokens and a cache that grows with the
 request differ only in where the caller wrote the row and in ``counts``
 (keys carry their own rotary phase, and a softmax does not care for the
 order of its terms).  ``counts`` is at least 1 everywhere (a decode step
-has just written the row it stands on).  The whole cache is read: scores
-``(S, H, T)`` in float32, a masked softmax, the value product.
+has just written the row it stands on).  Plain XLA; the whole cache is
+read: scores ``(S, H, T)`` in float32, a masked softmax, the value product.
 :func:`rows_visited` says how many rows that is (the counters
 ``attn.window_rows_read`` / ``attn.full_rows_read``).
 """
 
 from __future__ import annotations
 
+import functools
+import itertools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from progen_tpu.ops.lowering import mesh_in_scope as _mesh_in_scope
+from progen_tpu.ops.lowering import note
+from progen_tpu.ops.lowering import on_tpu as _on_tpu
 
 F32 = jnp.float32
-QUERY_BLOCK = 256     # prefill: query rows per score block
-FULL_GROUP = 4        # prefill, no window: blocks that share one key span
+QUERY_BLOCK = 256     # blocked XLA form: query rows per score block
+FULL_GROUP = 4        # blocked XLA form, no window: blocks sharing a key span
+# the kernel's query and key tile on a v5e (PERF.md section 6, PR 35, has
+# the pairs measured), halved down to ``MIN_TILE`` until it divides P
+TILE, MIN_TILE = 1024, 512
+MASKED = -0.7 * float(jnp.finfo(F32).max)   # a masked score: finite
+
+
+# ------------------------------------------------------ the blocked XLA form
 
 
 def _score_block(q, k, v, first_row, first_key, scale, window):
@@ -69,8 +130,23 @@ def _rows(x, start, size):
     return jax.lax.dynamic_slice_in_dim(x, start, size, axis=x.ndim - 2)
 
 
-def prefill_attention(q, k, v, scale, window=None):
-    """``(R, P, H * d)`` in ``q``'s dtype."""
+def _blocked_bodies(n: int, window) -> list:
+    """``(first block, blocks, keys each sees)`` for every traced body of
+    the blocked form over ``n`` positions: one under a window, one a
+    group of ``FULL_GROUP`` blocks without."""
+    bq = min(QUERY_BLOCK, n)
+    blocks = -(-n // bq)
+    if window is not None:
+        # the furthest any block looks back: the window, or all there is
+        return [(0, blocks, min(window, (blocks - 1) * bq) + bq)]
+    return [(first, min(FULL_GROUP, blocks - first),
+             min(first + FULL_GROUP, blocks) * bq)
+            for first in range(0, blocks, FULL_GROUP)]
+
+
+def blocked_prefill_attention(q, k, v, scale, window=None):
+    """The XLA form: every position of every row computed, the score
+    tensor ``(R, KV, G, QUERY_BLOCK, keys)`` float32."""
     r, n, heads, d = q.shape
     kv = k.shape[1]
     bq = min(QUERY_BLOCK, n)
@@ -79,9 +155,9 @@ def prefill_attention(q, k, v, scale, window=None):
     q = q.reshape(r, n, kv, heads // kv, d).transpose(0, 2, 3, 1, 4)
     q = jnp.pad(q, ((0, 0),) * 3 + ((0, pad), (0, 0)))
     k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0))) for a in (k, v))
+    bodies = _blocked_bodies(n, window)
     if window is not None:
-        # the furthest any block looks back: the window, or all there is
-        back = min(window, (blocks - 1) * bq)
+        back = bodies[0][2] - bq
         k, v = (jnp.pad(a, ((0, 0), (0, 0), (back, 0), (0, 0)))
                 for a in (k, v))
 
@@ -94,19 +170,252 @@ def prefill_attention(q, k, v, scale, window=None):
         out = jax.lax.map(block, jnp.arange(blocks))
     else:
         outs = []
-        for first in range(0, blocks, FULL_GROUP):
-            last = min(first + FULL_GROUP, blocks)
-            keys, values = k[:, :, :last * bq], v[:, :, :last * bq]
+        for first, count, span in bodies:
+            keys, values = k[:, :, :span], v[:, :, :span]
 
             def block(i, keys=keys, values=values):
                 return _score_block(_rows(q, i * bq, bq), keys, values,
                                     i * bq, 0, scale, None)
 
-            outs.append(jax.lax.map(block, jnp.arange(first, last)))
+            outs.append(jax.lax.map(block, jnp.arange(first, first + count)))
         out = jnp.concatenate(outs, axis=0)
     # (blocks, R, KV, G, bq, d) -> (R, P, H * d)
     out = out.transpose(1, 0, 4, 2, 3, 5).reshape(r, blocks * bq, heads * d)
     return out[:, :n]
+
+
+# --------------------------------------------------------------- the kernel
+
+
+def key_tiles(qi, length, bq: int, bk: int, window, xp=jnp):
+    """``(live, first, last)`` for query tile ``qi`` (rows ``qi * bq ..``)
+    of a row of ``length`` real positions: whether the tile holds a real
+    query, and the first and last key tile (of ``bk`` keys; inclusive) in
+    which a real query of the tile sees a key — ``last`` lies under the
+    diagonal and under the length, ``first`` holds the leftmost key of the
+    tile's first row's window.  Where ``live``, ``first <= last`` and every
+    tile between holds a seen pair.  The one visit rule: the kernel's grid
+    and index maps, and :func:`pairs_visited`, all call this (``xp``:
+    ``numpy`` for static arguments inside a trace)."""
+    q0 = qi * bq
+    last = xp.minimum(q0 + bq - 1, xp.maximum(length - 1, 0)) // bk
+    first = xp.zeros_like(last) if window is None else (
+        xp.maximum(q0 - window + 1, 0) // bk)
+    return q0 < length, first, last
+
+
+def key_steps(n: int, bq: int, bk: int, window) -> int:
+    """The length of the kernel's key axis: the most key tiles any query
+    tile of ``n`` positions visits."""
+    return max(int(last - first + 1) for _, first, last in (
+        key_tiles(qi, n, bq, bk, window, np) for qi in range(n // bq)))
+
+
+def _dot_t(a, b):  # a @ b^T, float32 accumulate
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=F32)
+
+
+def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+                  *, scale, bq, bk, window):
+    from jax.experimental import pallas as pl
+
+    length = len_ref[pl.program_id(0)]
+    qi, ki = pl.program_id(2), pl.program_id(3)
+    live, first, last = key_tiles(qi, length, bq, bk, window)
+    kt = first + ki
+    q0, k0 = qi * bq, kt * bk
+
+    @pl.when(ki == 0)       # the first visited tile, where there is one
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, F32)
+        l_ref[...] = jnp.zeros(l_ref.shape, F32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
+
+    def tile(under_diagonal, inside_window):
+        s = _dot_t(q_ref[0], k_ref[0, 0]) * scale
+        if not (under_diagonal and inside_window):
+            gap = (q0 - k0) + (
+                jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+            seen = under_diagonal or gap >= 0
+            if not inside_window:
+                seen = seen & (gap < window)
+            s = jnp.where(seen, s, MASKED)
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.exp(s - m_next)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[0, 0], preferred_element_type=F32)
+        m_ref[...] = m_next
+
+    # a tile needs a mask only where the diagonal or the window's left
+    # edge crosses it: some (row, key) of it with key > row, or with
+    # row - key >= window
+    visited = live & (kt <= last)
+    under_diagonal = k0 + bk - 1 <= q0
+    inside_window = True if window is None else q0 + bq - 1 - k0 < window
+    for under, inside in itertools.product(
+            (True, False), (True,) if window is None else (True, False)):
+        pl.when(visited
+                & (under_diagonal if under else ~under_diagonal)
+                & (inside_window if inside else ~inside_window)
+                )(functools.partial(tile, under, inside))
+
+    done = ki == pl.num_programs(3) - 1
+
+    @pl.when(done & live)
+    def _():
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+    @pl.when(done & ~live)
+    def _():
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+
+def fitted_tile(n: int) -> int:
+    """The largest of ``TILE``, ``TILE / 2``, ... down to ``MIN_TILE`` that
+    divides ``n``."""
+    tile = TILE
+    while tile > MIN_TILE and n % tile:
+        tile //= 2
+    return tile
+
+
+def pallas_prefill_attention(q, k, v, lengths, scale, window=None, *,
+                             block_q=None, block_k=None, interpret=None):
+    """The kernel lowering.  ``q (R, P, H * d)``, ``k, v (R, KV, P, d)``,
+    ``lengths (R,)`` -> ``(R, P, H * d)``.  ``interpret=None`` auto-selects
+    the Pallas interpreter off-TPU; ``block_q`` / ``block_k`` default to
+    :func:`fitted_tile`."""
+    n = k.shape[2]
+    bq = block_q or fitted_tile(n)
+    bk = block_k or fitted_tile(n)
+    if n % bq or n % bk:
+        raise ValueError(f"tiles ({bq}, {bk}) do not divide P = {n}")
+    if interpret is None:
+        interpret = not _on_tpu()
+    return _flash_call(q, k, v, lengths.astype(jnp.int32), scale=scale,
+                       window=window, bq=bq, bk=bk, interpret=interpret)
+
+
+# jitted so that the blocks of one kind in a model share ONE traced and
+# lowered kernel: nine calls of an 8192-token admission lower in 0.1 s
+# instead of 0.6-1.6 s, and the program holds two Mosaic kernels, not nine
+@functools.partial(jax.jit, static_argnames=("scale", "window", "bq", "bk",
+                                             "interpret"))
+def _flash_call(q, k, v, lengths, *, scale, window, bq, bk, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    r, kv, n, d = k.shape
+    heads = q.shape[-1] // d
+    group = heads // kv
+
+    def q_map(ri, hi, qi, ki, len_ref):
+        # a tile past the length is not computed: keep the last real one
+        return ri, jnp.minimum(
+            qi, jnp.maximum(len_ref[ri] - 1, 0) // bq), hi
+
+    def kv_map(ri, hi, qi, ki, len_ref):
+        # steps past the last visited tile stay on it, and a query tile
+        # past the length stays on the row's last: nothing is fetched
+        live, first, last = key_tiles(qi, len_ref[ri], bq, bk, window)
+        return ri, hi // group, jnp.minimum(
+            jnp.where(live, first + ki, last), last), 0
+
+    return pl.pallas_call(
+        functools.partial(_flash_kernel, scale=scale, bq=bq, bk=bk,
+                          window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(r, heads, n // bq, key_steps(n, bq, bk, window)),
+            in_specs=[
+                pl.BlockSpec((1, bq, d), q_map),
+                pl.BlockSpec((1, 1, bk, d), kv_map),
+                pl.BlockSpec((1, 1, bk, d), kv_map),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, bq, d), lambda ri, hi, qi, ki, len_ref: (ri, qi, hi)),
+            scratch_shapes=[pltpu.VMEM((bq, 1), F32),
+                            pltpu.VMEM((bq, 1), F32),
+                            pltpu.VMEM((bq, d), F32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((r, n, heads * d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=interpret,
+        name="gqa_prefill_fwd",
+    )(lengths, q, k, v)
+
+
+def prefill_lowering(n: int, d: int, dtype, window) -> str:
+    """``"pallas"`` or ``"xla"``: what :func:`prefill_attention` takes for
+    ``n`` positions of heads ``d`` wide in ``dtype``, traced here and now
+    (the module docstring has the rule)."""
+    kernel = (_on_tpu() and not _mesh_in_scope()
+              and jnp.issubdtype(dtype, jnp.floating)
+              and jnp.dtype(dtype).itemsize in (2, 4)
+              and d % 128 == 0 and n % MIN_TILE == 0
+              and (window is None or window % fitted_tile(n) == 0))
+    return "pallas" if kernel else "xla"
+
+
+def prefill_attention(q, k, v, scale, window=None, lengths=None):
+    """``(R, P, H * d)`` in ``q``'s dtype, exact at the first ``lengths
+    (R,)`` positions of each row (default: all ``P``).  The lowering is
+    chosen as the module docstring says."""
+    r, n, heads, d = q.shape
+    lowering = prefill_lowering(n, d, q.dtype, window)
+    note("gqa_prefill", lowering)
+    if lowering == "xla":
+        return blocked_prefill_attention(q, k, v, scale, window)
+    if lengths is None:
+        lengths = jnp.full((r,), n, jnp.int32)
+    return pallas_prefill_attention(q.reshape(r, n, heads * d), k, v,
+                                    lengths, scale, window)
+
+
+def pairs_allowed(lengths, window=None):
+    """Query-key pairs the mask allows at the real positions of rows of
+    ``lengths (R,)``, one head, as a float32 scalar: position ``i <
+    length`` sees ``min(i + 1, window)`` keys."""
+    n = lengths.astype(F32)
+    pairs = n * (n + 1) / 2
+    if window is not None:
+        past = jnp.maximum(n - window, 0)   # positions with a full window
+        pairs = pairs - past * (past + 1) / 2
+    return jnp.sum(pairs)
+
+
+def tiles_visited(lengths, n: int, bq: int, bk: int, window):
+    """Key tiles the kernel computes for rows of ``lengths (R,)`` padded
+    to ``n``, one head: :func:`key_tiles` over every query tile."""
+    live, first, last = key_tiles(jnp.arange(n // bq)[None, :],
+                                  lengths[:, None], bq, bk, window)
+    return jnp.sum(jnp.where(live, last - first + 1, 0))
+
+
+def pairs_visited(lengths, n: int, window, lowering: str):
+    """Query-key pairs :func:`prefill_attention` computes for rows of
+    ``lengths (R,)`` padded to ``n``, one head, under ``lowering``, as a
+    float32 scalar: the blocked form's every block of every row at its
+    full key span, pads and empty rows included; the kernel's visited
+    tiles (the rule its grid follows) times ``bq * bk``."""
+    if lowering == "xla":
+        bq = min(QUERY_BLOCK, n)
+        pairs = sum(count * bq * span
+                    for _, count, span in _blocked_bodies(n, window))
+        return jnp.asarray(lengths.shape[0] * pairs, F32)
+    tile = fitted_tile(n)
+    return tiles_visited(lengths, n, tile, tile, window).astype(F32) * (
+        tile * tile)
+
+
+# ------------------------------------------------------------------- decode
 
 
 def decode_attention(q, k, v, counts, scale):

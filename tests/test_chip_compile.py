@@ -375,6 +375,26 @@ def test_mla_prefill_kernel_compiles_for_v5e(shape, no_persistent_cache,
         shape((r,), jnp.int32))
 
 
+@pytest.mark.parametrize("window", [2048, None], ids=["sliding", "full"])
+@pytest.mark.parametrize("bucket", [512, 1024, 2048, 4096, 8192])
+def test_gqa_prefill_kernel_compiles_for_v5e(shape, no_persistent_cache,
+                                             bucket, window):
+    """The grouped-query prefill's flash kernel (``ops/gqa.py``,
+    ``gqa_prefill_fwd``) at an admission run of
+    ``serve-trinity-mixedlen-backlog``: 4 rows, 32 query heads over 4
+    key/value heads of 128, bfloat16, each prefill bucket the cell warms,
+    a sliding block's window and a full block's none, with the tiles the
+    chip path takes."""
+    from progen_tpu.ops.gqa import pallas_prefill_attention
+
+    r, heads, kv, d, bf16 = 4, 32, 4, 128, jnp.bfloat16
+    _assert_kernel_compiles(
+        lambda q, k, v, n: pallas_prefill_attention(
+            q, k, v, n, d ** -0.5, window, interpret=False),
+        shape((r, bucket, heads * d), bf16), shape((r, kv, bucket, d), bf16),
+        shape((r, kv, bucket, d), bf16), shape((r,), jnp.int32))
+
+
 @pytest.mark.parametrize("slots,heads,max_len", [(64, 128, 3072),
                                                  (32, 64, 4096)],
                          ids=["dsv2", "longcat"])

@@ -1,0 +1,404 @@
+"""``ops/gqa.py:prefill_attention``: the causal grouped-query attention
+core with and without a window, two lowerings, one contract.  The Pallas
+flash kernel (``gqa_prefill_fwd``) runs under the interpreter here, at the
+head width Trinity publishes (128) and small tiles: against the blocked XLA
+form and a dense masked float32 reference at every real position, for
+windows under, at and over a tile, groups of 1 and 8, lengths at 0, 1, a
+tile's edge, the window's edge and ``P``; real positions bit-equal whatever
+the padding holds; skipped tiles written as zeros; the tile-visit rule
+against a brute-force count; the pair counters; and the choice of lowering
+from backend, mesh and shape, as ``status()`` shows it."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from perf.lib import reference_trinity as ref
+from progen_tpu.models import trinity as tr
+from progen_tpu.ops import gqa
+from progen_tpu.ops.lowering import record_lowerings
+from tests.trinity_tiny import TINY, as_dict, make
+
+D, R, P, TILE = 128, 2, 512, 128
+SCALE = D ** -0.5
+TOL = {"float32": 2e-5, "bfloat16": 2e-2}
+
+
+def _operands(p, group, dtype, seed=0, rows=R):
+    """``q (R, P, H, d)``, ``k, v (R, KV, P, d)`` with O(1) logits: one
+    key/value head under 8 query heads, or two under 2."""
+    kv = 1 if group > 1 else 2
+    ks = jax.random.split(jax.random.key(seed), 3)
+
+    def normal(k, shape, gain=1.0):
+        return (jax.random.normal(k, shape, jnp.float32) * gain).astype(dtype)
+
+    return (normal(ks[0], (rows, p, kv * group, D)),
+            normal(ks[1], (rows, kv, p, D), 3.0),
+            normal(ks[2], (rows, kv, p, D)))
+
+
+def _kernel(q, k, v, lengths, window, **tiles):
+    r, p, heads, d = q.shape
+    with jax.default_matmul_precision("highest"):
+        return gqa.pallas_prefill_attention(
+            q.reshape(r, p, heads * d), k, v, jnp.asarray(lengths, jnp.int32),
+            SCALE, window, interpret=True, **tiles)
+
+
+def _blocked(q, k, v, window):
+    with jax.default_matmul_precision("highest"):
+        return gqa.blocked_prefill_attention(q, k, v, SCALE, window)
+
+
+def _dense(q, k, v, window):
+    """Every position against every key under the mask, float64."""
+    q, k, v = (np.asarray(a.astype(jnp.float32), np.float64)
+               for a in (q, k, v))
+    r, p, heads, d = q.shape
+    group = heads // k.shape[1]
+    gap = np.arange(p)[:, None] - np.arange(p)[None, :]
+    seen = gap >= 0 if window is None else (gap >= 0) & (gap < window)
+    out = np.zeros((r, p, heads, d))
+    for h in range(heads):
+        s = np.einsum("rqd,rkd->rqk", q[:, :, h], k[:, h // group]) * SCALE
+        s = np.where(seen, s, -np.inf)
+        w = np.exp(s - s.max(-1, keepdims=True))
+        out[:, :, h] = np.einsum("rqk,rkd->rqd", w / w.sum(-1, keepdims=True),
+                                 v[:, h // group])
+    return out.reshape(r, p, heads * d)
+
+
+def _f32(x):
+    return np.asarray(x.astype(jnp.float32))
+
+
+WINDOWS = {"no-window": None, "under-a-tile": 40, "one-tile": TILE,
+           "three-tiles": 3 * TILE, "over-P": 4 * P}
+# lengths of the two rows
+LENGTHS = {
+    "0-and-1": lambda w: [0, 1],
+    "a-tile-edge-1": lambda w: [2 * TILE - 1, 2 * TILE + 1],
+    "a-tile-edge-and-P": lambda w: [TILE, P],
+    "the-window-1": lambda w: [min(max((w or 300) - 1, 1), P),
+                                min((w or 300) + 1, P)],
+}
+
+
+@pytest.mark.parametrize("case", list(LENGTHS))
+@pytest.mark.parametrize("window", list(WINDOWS))
+@pytest.mark.parametrize("group,dtype,tiles", [
+    (8, "bfloat16", (128, 128)), (8, "float32", (256, 128)),
+    (1, "bfloat16", (128, 256)), (1, "float32", (128, 128))],
+    ids=lambda v: str(v))
+def test_kernel_equals_the_blocked_form_and_a_dense_reference(
+        group, dtype, tiles, window, case):
+    window = WINDOWS[window]
+    q, k, v = _operands(P, group, jnp.dtype(dtype))
+    lengths = LENGTHS[case](window)
+    got = _f32(_kernel(q, k, v, lengths, window, block_q=tiles[0],
+                       block_k=tiles[1]))
+    blocked = _f32(_blocked(q, k, v, window))
+    dense = _dense(q, k, v, window)
+    assert got.shape == (R, P, q.shape[2] * D) and np.isfinite(got).all()
+    assert float(np.abs(dense).max()) > 1.0      # not a vacuous bound
+    for row, n in enumerate(lengths):
+        if n:
+            assert float(np.abs(got[row, :n] - blocked[row, :n]).max()) \
+                < TOL[dtype]
+            assert float(np.abs(got[row, :n] - dense[row, :n]).max()) \
+                < TOL[dtype]
+        # a query tile that starts at or past the length reads as zeros
+        first_dead = -(-n // tiles[0]) * tiles[0]
+        assert not got[row, first_dead:].any()
+
+
+def test_the_window_bites():
+    """The agreement above is not that of masks that never matter."""
+    q, k, v = _operands(P, 8, jnp.float32)
+    full = _f32(_kernel(q, k, v, [P, P], None, block_q=128, block_k=128))
+    windowed = _f32(_kernel(q, k, v, [P, P], 40, block_q=128, block_k=128))
+    np.testing.assert_allclose(full[:, :40], windowed[:, :40], atol=2e-5)
+    assert float(np.abs(full - windowed)[:, 40:].max()) > 0.5
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("window", [None, 40, 2 * TILE])
+def test_real_positions_are_bit_equal_whatever_the_padding_holds(dtype,
+                                                                 window):
+    """Junk in the operands at pad positions changes no bit at a real one,
+    inside a partly real tile or elsewhere."""
+    lengths = [300, 129]
+    q, k, v = _operands(P, 8, jnp.dtype(dtype))
+    pad = jnp.arange(P)[None, :] >= jnp.asarray(lengths)[:, None]  # (R, P)
+
+    def junk(x, axis):
+        shape = [1] * x.ndim
+        shape[0], shape[axis] = R, P
+        return jnp.where(pad.reshape(shape), jnp.asarray(37.5, x.dtype), x)
+
+    kw = dict(block_q=128, block_k=256)
+    got = _kernel(q, k, v, lengths, window, **kw)
+    again = _kernel(junk(q, 1), junk(k, 2), junk(v, 2), lengths, window, **kw)
+    for row, n in enumerate(lengths):
+        np.testing.assert_array_equal(_f32(got[row, :n]),
+                                      _f32(again[row, :n]))
+    assert np.isfinite(_f32(again)).all()
+
+
+@pytest.mark.parametrize("window", [None, TILE])
+def test_rows_of_length_0_cost_nothing_and_read_as_zeros(window):
+    """Every row empty: no tile is visited (NaN operands would show), and
+    every output is written, as zeros."""
+    ops = [jnp.full_like(x, jnp.nan) for x in _operands(P, 8, jnp.bfloat16)]
+    got = _f32(_kernel(*ops, [0, 0], window, block_q=128, block_k=128))
+    assert got.shape == (R, P, 8 * D) and not got.any()
+
+
+# ---- the tile-visit rule and the counters ----------------------------------
+
+
+@pytest.mark.parametrize("bq,bk", [(4, 4), (8, 4), (4, 8)])
+@pytest.mark.parametrize("window", [None, 1, 3, 4, 8, 11, 100])
+def test_the_visit_rule_visits_exactly_the_tiles_that_hold_an_allowed_pair(
+        bq, bk, window):
+    """Brute force over every length of a 32-position row: the tiles
+    between ``first`` and ``last`` of a live query tile are those with an
+    allowed pair, the kernel's key axis is long enough to reach them all,
+    and :func:`tiles_visited` counts what its ``pl.when`` conditions
+    admit."""
+    n = 32
+    steps = gqa.key_steps(n, bq, bk, window)
+    i, j = np.arange(n)[:, None], np.arange(n)[None, :]
+    for length in range(n + 1):
+        allowed = (j <= i) & (i < length) & (j < length)
+        if window is not None:
+            allowed &= i - j < window
+        holds = allowed.reshape(n // bq, bq, n // bk, bk).any(axis=(1, 3))
+        admitted = np.zeros_like(holds)
+        for qi in range(n // bq):
+            live, first, last = (np.asarray(x) for x in gqa.key_tiles(
+                qi, length, bq, bk, window))
+            assert not live or last - first + 1 <= steps
+            for ki in range(steps):         # the kernel's grid and condition
+                if live and first + ki <= last:
+                    admitted[qi, first + ki] = True
+        np.testing.assert_array_equal(admitted, holds)
+        assert int(gqa.tiles_visited(jnp.array([length]), n, bq, bk,
+                                     window)) == holds.sum()
+    # under a window the axis spans the window's tiles, not the row's
+    assert steps == n // bk if window is None or window >= n else (
+        steps <= -(-(bq + window - 1) // bk) + 1)
+
+
+@pytest.mark.parametrize("window", [None, 1, 5, 16, 700])
+def test_pairs_allowed_is_a_count_of_the_mask(window):
+    lengths = [0, 1, 5, 6, 16, 17, 40]
+    for n in lengths:
+        i, j = np.arange(n)[:, None], np.arange(n)[None, :]
+        seen = j <= i if window is None else (j <= i) & (i - j < window)
+        assert float(gqa.pairs_allowed(jnp.array([n]), window)) == seen.sum()
+    assert float(gqa.pairs_allowed(jnp.array(lengths), window)) == sum(
+        float(gqa.pairs_allowed(jnp.array([n]), window)) for n in lengths)
+
+
+@pytest.mark.parametrize("window", [None, 1024, 2048])
+def test_both_lowerings_visit_more_than_allowed_and_the_kernel_less(window):
+    """A run of unequal lengths, one row empty: the kernel's tiles follow
+    the lengths, the blocked form's blocks do not."""
+    n, lengths = 4096, jnp.array([4000, 1023, 2500, 0])
+    allowed = float(gqa.pairs_allowed(lengths, window))
+    xla = float(gqa.pairs_visited(lengths, n, window, "xla"))
+    kernel = float(gqa.pairs_visited(lengths, n, window, "pallas"))
+    assert allowed <= kernel < xla
+    assert kernel / allowed < 2.5 < xla / allowed
+    # the blocked form computes every row alike, whatever it holds
+    assert xla == float(gqa.pairs_visited(jnp.full((4,), n), n, window,
+                                          "xla"))
+    full = jnp.full((4,), n)
+    assert float(gqa.pairs_visited(full, n, window, "pallas")) >= float(
+        gqa.pairs_allowed(full, window))
+
+
+def test_the_blocked_forms_pair_count_is_its_score_tensors():
+    """``pairs_visited(..., "xla")`` against the float32 score blocks the
+    traced blocked form holds."""
+    for n, window, want in [(40, None, 40 * 40), (40, 16, 40 * 40),
+                            (600, 16, 3 * 256 * 272),
+                            (1024, None, 4 * 256 * 1024),
+                            (2048, None, 4 * 256 * 1024 + 4 * 256 * 2048),
+                            (2048, 512, 8 * 256 * 768)]:
+        assert float(gqa.pairs_visited(jnp.array([3]), n, window,
+                                       "xla")) == want
+        keys = sorted({span for _, _, span in gqa._blocked_bodies(n, window)})
+        q = jax.ShapeDtypeStruct((1, n, 2, 8), jnp.float32)
+        k = jax.ShapeDtypeStruct((1, 1, n, 8), jnp.float32)
+        jaxpr = str(jax.make_jaxpr(lambda q, k, v: (
+            gqa.blocked_prefill_attention(q, k, v, 0.3, window)))(q, k, k))
+        for t in keys:
+            assert f"f32[1,1,2,{min(256, n)},{t}]" in jaxpr
+
+
+# ---- which lowering, and where it is stated --------------------------------
+
+
+def _lowering(shape, window, dtype=jnp.bfloat16, monkeypatch=None,
+              on_tpu=False):
+    r, p, heads, kv, d = shape
+    if monkeypatch is not None:
+        monkeypatch.setattr(gqa, "_on_tpu", lambda: on_tpu)
+    args = [jax.ShapeDtypeStruct(s, dtype) for s in (
+        (r, p, heads, d), (r, kv, p, d), (r, kv, p, d))]
+    with record_lowerings() as chosen:
+        jaxpr = str(jax.make_jaxpr(lambda q, k, v, n: gqa.prefill_attention(
+            q, k, v, 0.1, window, n))(
+                *args, jax.ShapeDtypeStruct((r,), jnp.int32)))
+    return chosen["gqa_prefill"], jaxpr
+
+
+def test_cpu_default_is_the_blocked_form():
+    paths, jaxpr = _lowering((4, 512, 32, 4, 128), 2048)
+    assert paths == {"xla"} and "pallas_call" not in jaxpr
+
+
+@pytest.mark.parametrize("shape,window,dtype,want", [
+    ((4, 8192, 32, 4, 128), 2048, jnp.bfloat16, "pallas"),
+    ((4, 512, 32, 4, 128), None, jnp.bfloat16, "pallas"),
+    ((2, 1536, 8, 8, 128), 512, jnp.float32, "pallas"),
+    ((1, 1024, 4, 2, 256), None, jnp.bfloat16, "pallas"),
+    ((2, 384, 8, 2, 128), None, jnp.bfloat16, "xla"),    # P off the tile
+    ((2, 512, 8, 2, 64), None, jnp.bfloat16, "xla"),     # d
+    ((2, 1024, 8, 2, 128), 700, jnp.bfloat16, "xla"),    # window off the tile
+    ((2, 16, 4, 2, 8), 8, jnp.float32, "xla"),           # the tests' TINY
+], ids=["published-sliding", "published-full", "f32-P1536", "wider", "P-384",
+        "d-64", "window-700", "tiny"])
+def test_on_tpu_the_shape_decides(monkeypatch, shape, window, dtype, want):
+    paths, jaxpr = _lowering(shape, window, dtype, monkeypatch, on_tpu=True)
+    assert paths == {want}
+    assert ("pallas_call" in jaxpr) == (want == "pallas")
+    # the kernel takes q and gives its output where ``wo`` reads it: no
+    # transposed copy, no padded keys, no stacked blocks
+    assert ("transpose" in jaxpr) == (want == "xla")
+    assert ("pad" in jaxpr) == (want == "xla")
+
+
+def test_a_mesh_in_scope_keeps_the_blocked_form(monkeypatch, devices8):
+    mesh = jax.sharding.Mesh(np.asarray(devices8[:2]), ("data",))
+    with mesh:
+        paths, jaxpr = _lowering((2, 512, 8, 2, 128), None,
+                                 monkeypatch=monkeypatch, on_tpu=True)
+    assert paths == {"xla"} and "pallas_call" not in jaxpr
+
+
+# Trinity's tiny model at the published head width, a window of one (test)
+# tile and a prefill of four: the sliding blocks skip tiles on every side
+WIDE = dataclasses.replace(TINY, head_dim=D, sliding_window=TILE,
+                           max_position_embeddings=1024, prefill_bucket=P)
+
+
+def _force_kernel(monkeypatch):
+    monkeypatch.setattr(gqa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(gqa, "TILE", TILE)
+    monkeypatch.setattr(gqa, "MIN_TILE", TILE)
+    monkeypatch.setattr(
+        gqa, "pallas_prefill_attention",
+        lambda *a, _f=gqa.pallas_prefill_attention: _f(*a, interpret=True))
+
+
+def test_prefill_through_the_kernel(monkeypatch):
+    """``trinity.prefill`` at the published head width with the kernel
+    forced (interpreter): one call a block and no score block, transposed
+    query or stacked output in the trace; logits at real positions the
+    reference's and the blocked form's; bit-equal whatever the padding
+    holds; the pair counters fall; an empty row's attention reads as
+    zeros."""
+    params, policy = make(WIDE)
+    toks = jax.random.randint(jax.random.key(1), (R, P), 1, WIDE.vocab_size)
+    lengths = jnp.array([P - 100, 140])
+    at = jnp.broadcast_to(jnp.arange(0, P, 4), (R, P // 4))
+
+    def run(tokens, lens):
+        # a fresh function per lowering: ``jax.jit`` would keep the trace
+        with jax.default_matmul_precision("highest"):
+            logits, _, stats = tr.prefill(params, tokens, lens, WIDE, policy,
+                                          logit_positions=at)
+        return logits, stats
+
+    with jax.default_matmul_precision("highest"):
+        want = ref.forward(params, toks, as_dict(WIDE))[:, ::4]
+    blocked, blocked_stats = run(toks, lengths)
+    _force_kernel(monkeypatch)
+    with record_lowerings() as chosen:
+        jaxpr = str(jax.make_jaxpr(lambda t, n: tr.prefill(
+            params, t, n, WIDE, policy)[0])(toks, lengths))
+    assert chosen == {"gqa_prefill": {"pallas"}}
+    # one call a block, ONE traced kernel a kind of block
+    assert jaxpr.count("name=_flash_call") == WIDE.num_layers
+    assert jaxpr.count("pallas_call") == 2
+    assert "dynamic_update_slice" not in jaxpr
+    assert f"f32[{R},{WIDE.num_key_value_heads},2,256," not in jaxpr
+
+    got, stats = run(toks, lengths)
+    junk = jnp.where(jnp.arange(P)[None, :] < lengths[:, None], toks, 5)
+    again, _ = run(junk, lengths)
+    for row, n in enumerate(np.asarray(lengths)):
+        real = np.asarray(at[row]) < n
+        assert float(jnp.abs(got[row, real] - blocked[row, real]).max()) < 2e-4
+        assert float(jnp.abs(got[row, real] - want[row, real]).max()) < 2e-4
+        np.testing.assert_array_equal(np.asarray(got[row, real]),
+                                      np.asarray(again[row, real]))
+    assert float(want.std()) > 0.3
+
+    allowed = float(stats["attn.prefill_pairs_allowed"])
+    assert allowed == float(blocked_stats["attn.prefill_pairs_allowed"])
+    assert allowed == sum(
+        float(gqa.pairs_allowed(lengths, b.window))
+        for b in tr.blocks_of(WIDE).values())
+    assert allowed <= float(stats["attn.prefill_pairs_visited"]) < float(
+        blocked_stats["attn.prefill_pairs_visited"])
+
+    x = jax.random.normal(jax.random.key(2), (R, P, WIDE.hidden_size))
+    for name in ("l0", "l3"):       # a sliding block and the full one
+        out, _ = tr.blocks_of(WIDE)[name].prefill(
+            x, params["layers"][int(name[1])]["attn"], jnp.array([P, 0]))
+        assert not np.asarray(out[1]).any() and np.asarray(out[0]).any()
+
+
+def test_engine_states_the_lowering_and_publishes_the_pair_counters():
+    """``status()["gqa_prefill"]``: ``None`` before an admission program is
+    traced, then what the trace chose — the blocked form on the CPU; the
+    two pair counters reach the registry with the flags fetch."""
+    from progen_tpu.decode import Request, ServingEngine
+    from progen_tpu.decode.engine import SLOTS_PER_ADMIT_ROW
+    from progen_tpu.observe.metrics import get_registry
+
+    params, policy = make()
+    eng = ServingEngine(TINY, params, policy=policy,
+                        num_slots=SLOTS_PER_ADMIT_ROW, chunk_size=4,
+                        max_len=32)
+    assert eng.status()["gqa_prefill"] is None
+    primes = ([3, 4, 5], list(range(1, 12)))
+    for uid, tokens in enumerate(primes):
+        eng.submit(Request(uid=uid, tokens=tokens, max_new_tokens=3,
+                           temperature=0.0, seed=1))
+    assert len(eng.run_until_idle(max_chunks=20)) == 2
+    status = eng.status()
+    assert status["gqa_prefill"] == "xla"
+    assert status["mla_prefill"] is None
+    # one admission run a request (one row an admission at these slots):
+    # three sliding blocks of window 8, one full, one more sliding
+    blocks = tr.blocks_of(TINY).values()
+    allowed = sum(float(gqa.pairs_allowed(jnp.array([len(t)]), b.window))
+                  for t in primes for b in blocks)
+    visited = sum(float(gqa.pairs_visited(
+        jnp.array([len(t)]), eng.family.bucket(len(t), 32), b.window, "xla"))
+        for t in primes for b in blocks)
+    stats = eng.model_stats
+    assert stats["attn.prefill_pairs_allowed"] == allowed
+    assert stats["attn.prefill_pairs_visited"] == visited > allowed
+    snap = get_registry().snapshot()
+    assert snap["attn.prefill_pairs_allowed"]["value"] == allowed
+    assert snap["attn.prefill_pairs_visited"]["value"] == visited
