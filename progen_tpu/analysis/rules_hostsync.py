@@ -47,7 +47,8 @@ HOT_ZONES: tuple[Zone, ...] = (
         r"|_publish_train_health|_statusz_health|_statusz_status)$",
         frozenset({"meter", "tracker", "config", "model_config", "store",
                    "_recorder", "_tracer", "lr_schedule", "cfg",
-                   "_watchdog", "_preempt_requested"}),
+                   "_watchdog", "_preempt_requested", "_xla_compiles",
+                   "_recompiles"}),
         # the log dict holds host floats from the loop's one batched
         # jax.device_get — publishing them is not a new sync
         frozenset({"log"}),
@@ -66,7 +67,8 @@ HOT_ZONES: tuple[Zone, ...] = (
         r"|submit_embed|_embed_round|run_embed_round|embed_pending"
         r"|_build_lmask|status|_maybe_preempt|_preempt_slot|qos_status"
         r"|_publish_qos_gauges|submit_fork|_release_forks|forget_ttft"
-        r"|prefix_digest|cache_status|_publish_cache_gauges)$",
+        r"|prefix_digest|cache_status|_publish_cache_gauges|_run_step"
+        r"|_judge_step)$",
         frozenset({"_inflight", "_queue", "completions", "config",
                    "num_slots", "max_len", "chunks_run", "_pool",
                    "_layout", "_admit_order", "_admit_seq", "page_size",
@@ -82,7 +84,12 @@ HOT_ZONES: tuple[Zone, ...] = (
                    "fork_groups", "_fork_wait", "_ttft", "_admitted",
                    "_open_stages", "_step_no",
                    "_step_wait", "_queue_wait_hist", "_ttft_hist",
-                   "_step_host_hist", "admit_rows", "_admit_rows_hist"}),
+                   "_step_host_hist", "admit_rows", "_admit_rows_hist",
+                   "_prefill_real", "_prefill_slots", "_chunk_rows_hist",
+                   "_xla_compiles", "_gc_pauses", "_steps",
+                   "_compiles_in_step", "_mean_host", "_mean_gap",
+                   "_mean_stage", "_step_stages", "_admit_pads",
+                   "_last_return"}),
         # requests, admission rows and snapshots are host payloads by API
         # contract: numpy masks, python ints, JSON-safe dicts — never
         # device arrays
@@ -182,7 +189,14 @@ HOT_ZONES: tuple[Zone, ...] = (
          frozenset({"inputs", "burn_rates"})),
     # span recording sits on every hot path above: it must never sync
     # (spans carry pre-computed floats, never device values)
-    Zone(r"observe/trace\.py$", r"Tracer\.(span|add|event)$"),
+    Zone(r"observe/trace\.py$", r"Tracer\.(span|add|event|incident)$"),
+    # the compile and collector listeners run INSIDE jit dispatch and
+    # inside every collection, wherever those fall — a step of the engine,
+    # a dispatch of the trainer: durations and names from JAX's and
+    # CPython's own events, host floats by their contracts
+    Zone(r"observe/compiles\.py$",
+         r"(_on_event|_on_duration|_on_gc|set_step|_step_arg)$",
+         frozenset(), frozenset({"duration", "info", "kw"})),
     # the introspection plane reads host snapshots only: any sync in a
     # handler would break the zero-perturbation invariant (an enabled
     # run must be token-identical to a disabled one)

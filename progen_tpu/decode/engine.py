@@ -89,6 +89,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from progen_tpu.core.precision import Policy, make_policy
+from progen_tpu.observe import compiles as _compiles
 from progen_tpu.observe import metrics as _metrics
 from progen_tpu.observe import trace as _obs_trace
 from progen_tpu.observe.robustness import RobustnessCounters
@@ -135,6 +136,45 @@ _MAX_DEFER_STREAK = 16
 # padding costs a whole prime's prefill and another run of the program a
 # fixed pass over the state; PERF.md section 6 (PR 26) has the sweep on
 # the chip: 4 rows at 64 slots, 1 at 16
+# A step STOOD STILL (incident ``serve.slow_step``) when its host self time,
+# the gap before it or one of its closed stages is over SLOW_FACTOR times the
+# engine's own running mean of that quantity AND SLOW_FLOOR_S above it.  The
+# factor: a stage's program takes the same time every run but for its live
+# rows (a chunk's cache reads follow the context: within a third of the
+# mean on the cells' traffic), so twice the mean is no ordinary run.  The
+# floor: host self time and gaps are a few ms with a harvest ten times the
+# rest, so a multiple alone would file one incident a harvest; 50 ms is a
+# seventh of what a cell's rate may lose in its window (1 % of 35 s) and
+# twice the shortest step the cells run, so an incident names seconds that
+# can show end to end and a warmed engine files none.  A mean judges
+# nothing before it has SLOW_MIN_SAMPLES observations.
+SLOW_FACTOR = 2.0
+SLOW_FLOOR_S = 0.050
+SLOW_MIN_SAMPLES = 8
+
+
+class _RunningMean:
+    """Mean of the observations that were NOT slow, and the rule above."""
+
+    __slots__ = ("n", "mean")
+
+    def __init__(self):
+        self.n = 0
+        self.mean = 0.0
+
+    def excess(self, v: float) -> float:
+        """Seconds of ``v`` over the mean where ``v`` is slow, else 0."""
+        over = v - self.mean
+        if (self.n >= SLOW_MIN_SAMPLES and over >= SLOW_FLOOR_S
+                and v > SLOW_FACTOR * self.mean):
+            return over
+        return 0.0
+
+    def add(self, v: float) -> None:
+        self.n += 1
+        self.mean += (v - self.mean) / self.n
+
+
 SLOTS_PER_ADMIT_ROW = 16
 
 
@@ -463,6 +503,33 @@ class ServingEngine:
         self._queue_wait_hist = registry.histogram("engine.queue_wait_s")
         self._ttft_hist = registry.histogram("engine.ttft_s")
         self._step_host_hist = registry.histogram("engine.step_host_s")
+        # token slots an admission run computes and the real prime tokens
+        # among them (host integers; real / slots = the share of a prefill
+        # that is not padding), and the rows a chunk was dispatched with
+        self._prefill_real = registry.counter("engine.prefill_tokens_real")
+        self._prefill_slots = registry.counter("engine.prefill_token_slots")
+        self._chunk_rows_hist = registry.histogram("engine.chunk_rows")
+        # compiles and collector pauses come from the process's listeners
+        # (observe/compiles.py); a step compares their totals at its two
+        # ends.  ``engine.compiles_in_step`` reads 0 for ever once warm
+        _compiles.install()
+        self._xla_compiles = registry.counter("xla.compiles")
+        self._gc_pauses = registry.histogram("host.gc_pause_s")
+        self._steps = registry.counter("engine.steps")
+        self._compiles_in_step = registry.counter("engine.compiles_in_step")
+        # the slow-step rule's means (SLOW_FACTOR above): host self time,
+        # the gap between steps, and each stage program's time — the chunk
+        # program, an admission group by the padded lengths of its runs
+        self._mean_host = _RunningMean()
+        self._mean_gap = _RunningMean()
+        self._mean_stage: dict[Any, _RunningMean] = {}
+        # (program, seconds) of the stages closed in the step() in progress,
+        # None outside one: a prefill worker runs rounds and never steps
+        self._step_stages: list[tuple] | None = None
+        self._admit_pads: list[int] = []      # p_pad of each run in flight
+        # return instant of the last step() that left work behind, else
+        # None: a caller that sleeps with nothing to do is not a stall
+        self._last_return: float | None = None
         # stages dispatched and not yet known done, ``(stage, kind, t0,
         # requests)``: a dispatch returns before the device has run, so
         # the next fetch of the slot flags closes them (_close_stages).
@@ -654,9 +721,13 @@ class ServingEngine:
         tagged with the ``step()`` it belongs to."""
         return self._tracer.span(name, step=self._step_no, **args)
 
-    def _record_stage(self, stage: str, dt: float) -> None:
+    def _record_stage(self, stage: str, dt: float, program=None) -> None:
+        """``program`` names what ran, for the slow-step rule: the time of
+        a stage is judged against the mean of its own program."""
         self.stage_seconds[stage] += dt
         self._stage_hist[stage].observe(dt)
+        if program is not None and self._step_stages is not None:
+            self._step_stages.append((program, dt))
 
     def _close_stages(self, now: float) -> None:
         """The flags fetch returned at ``now``: every dispatched stage has
@@ -667,7 +738,13 @@ class ServingEngine:
         stages, self._open_stages = self._open_stages, []
         ends = [t0 for _, _, t0, _ in stages[1:]] + [now]
         for (stage, kind, t0, batch), end in zip(stages, ends):
-            self._record_stage(stage, end - t0)
+            program = None
+            if stage == "decode_chunk_s":
+                program = "chunk"
+            elif stage == "prefill_s":
+                program = ("admit", *self._admit_pads)
+                self._admit_pads.clear()
+            self._record_stage(stage, end - t0, program)
             if self._tracer.enabled:
                 self._tracer.add(f"serve.{kind}_work", t0, end - t0,
                                  step=self._step_no, stage=stage,
@@ -1482,6 +1559,9 @@ class ServingEngine:
                 raise
             self._publish_prefixes(placed)
             self._admit_rows_hist.observe(len(requests))
+            self._prefill_real.inc(sum(len(r.tokens) for r in requests))
+            self._prefill_slots.inc(self.admit_rows * p_pad)
+            self._admit_pads.append(p_pad)
             # the admit program samples each request's first token; that
             # it has RUN is known at the next flags fetch, which stamps
             # first-token time and closes the stage: ONE stage per
@@ -1718,7 +1798,8 @@ class ServingEngine:
         # worker samples each request's first token; when it existed is
         # known where the handle is next read: at the flags fetch after
         # the decode-side merge here, on the driver's clock in a cluster
-        self._record_stage("prefill_s", time.perf_counter() - t0)
+        self._record_stage("prefill_s", time.perf_counter() - t0,
+                           ("prefill", p_pad))
         self._handoff.put(Handle(requests=batch, state=h, p_pad=p_pad))
 
     def _admit_from_handoff(self) -> None:
@@ -1952,6 +2033,7 @@ class ServingEngine:
                                       key=("chunk",))
                 self._open_stages.append(
                     ("decode_chunk_s", "chunk", t0, batch))
+                self._chunk_rows_hist.observe(len(batch))
                 if self.spec:
                     out, stats = out
                     # lazy device-side accumulation — spec_counters()
@@ -1995,7 +2077,41 @@ class ServingEngine:
         t_step = time.perf_counter()
         self._step_no += 1
         self._step_wait = 0.0
+        # the PROCESS's number of this step, which incidents carry: the
+        # counter a reader finds the last N steps by, so two engines in
+        # one process number their steps in one sequence
+        self._steps.inc()
+        step = self._steps.value
+        gap = (None if self._last_return is None
+               else t_step - self._last_return)
+        self._last_return = None
+        compiles_before = self._xla_compiles.value
+        gc_before = self._gc_pauses.sum
         chunks_before = self.chunks_run
+        self._step_stages = []
+        _compiles.set_step(step)   # on compiles and pauses filed meanwhile
+        try:
+            completed = self._run_step()
+            now = time.perf_counter()
+            # the host's self time: the step's wall less what it spent
+            # waiting for the device in the flags fetch
+            host = now - t_step - self._step_wait
+            if self.chunks_run > chunks_before:
+                self._step_host_hist.observe(host)
+            compiled = self._xla_compiles.value - compiles_before
+            if compiled:
+                self._compiles_in_step.inc(compiled)
+            self._judge_step(step, t_step, now, host, gap, compiled,
+                             self._gc_pauses.sum - gc_before)
+        finally:
+            # a step that raised judges nothing and leaves nothing of
+            # itself to the next one
+            self._step_stages = None
+            _compiles.set_step(None)
+        self._last_return = now if self.has_work else None
+        return completed
+
+    def _run_step(self) -> list[Completion]:
         completed = self._drain_pending()
         if self._watchdog is not None:
             self._watchdog.beat("serve.step")
@@ -2036,12 +2152,46 @@ class ServingEngine:
         self.qos_status()
         if self.paged:
             self._publish_cache_gauges()
-        if self.chunks_run > chunks_before:
-            # the host's self time in a step that ran a chunk: its wall
-            # less what it spent waiting for the device in the flags fetch
-            self._step_host_hist.observe(
-                time.perf_counter() - t_step - self._step_wait)
         return completed
+
+    def _judge_step(self, step: int, t_step: float, now: float, host: float,
+                    gap: float | None, compiled: int, gc_s: float) -> None:
+        """The slow-step rule (SLOW_FACTOR above) at the end of a step:
+        ONE incident ``serve.slow_step`` if any of its quantities stood
+        still, else the quantities enter their means.  A stage's clock
+        starts before its dispatch, so host seconds spent there (a
+        compile) are in the stage too: they count once, as the host's."""
+        stages = self._step_stages
+        host_x = self._mean_host.excess(host)
+        gap_x = 0.0 if gap is None else self._mean_gap.excess(gap)
+        device_x = 0.0
+        for program, dt in stages:
+            mean = self._mean_stage.get(program)
+            if mean is not None:
+                device_x += mean.excess(dt)
+        if host_x:
+            device_x -= host_x
+            if device_x < SLOW_FLOOR_S:
+                device_x = 0.0
+        excess = host_x + gap_x + device_x
+        if excess:
+            which = max((host_x, "host"), (gap_x, "gap"),
+                        (device_x, "device"))[1]
+            self._tracer.incident(
+                "serve.slow_step", t_step, now - t_step, step=step,
+                which=which, excess=excess, wall=now - t_step, host=host,
+                device_wait=self._step_wait, gap=gap, gc_s=gc_s,
+                compiles=compiled,
+                stages=[[str(p), dt] for p, dt in stages])
+        else:
+            self._mean_host.add(host)
+            if gap is not None:
+                self._mean_gap.add(gap)
+            for program, dt in stages:
+                mean = self._mean_stage.get(program)
+                if mean is None:
+                    mean = self._mean_stage[program] = _RunningMean()
+                mean.add(dt)
 
     # ----------------------------------------- multi-process handoff API
 
@@ -2418,6 +2568,8 @@ class ServingEngine:
             "inflight_uids": sorted(r.uid for r in
                                     list(self._inflight.values())),
             "chunks_run": self.chunks_run,
+            # programs compiled inside a step(): 0 for ever once warm
+            "compiles_in_step": self._compiles_in_step.value,
             # lowering of the chunk program's cache writes, of a latent
             # attention's prefill and decode cores and of a grouped-query
             # attention's prefill core; None until a program that holds

@@ -25,7 +25,13 @@ span in the ring alone: an annotation cannot be back-dated.
 Disabled (the default) the ring costs one attribute check per call: a
 tracer with no annotation returns a shared no-op context manager from
 ``span()``, and ``add()``/``event()`` return before allocating the
-record.  This file imports nothing but the stdlib —
+record.
+
+Beside the ring the tracer keeps **incidents**: the rare events a program
+must never lose (a compilation, a long collector pause, a step that stood
+still), kept whether or not the ring is on — ``Tracer.incident``.
+
+This file imports nothing but the stdlib —
 ``resilience/watchdog.py`` dumps the ring on a trip and must not pull in
 jax to do it; the annotation is imported at the first ``span()``.
 """
@@ -49,6 +55,9 @@ __all__ = [
 ]
 
 DEFAULT_CAPACITY = 4096
+# incidents kept: a process compiles a few hundred programs at set-up and
+# then should add none, so the last 256 reach back past any window
+INCIDENT_CAPACITY = 256
 
 
 class _NoopSpan:
@@ -130,6 +139,7 @@ class Tracer:
         # tracer that feeds its ring alone
         self.annotation = annotation
         self._ring = deque(maxlen=capacity)
+        self._incidents = deque(maxlen=INCIDENT_CAPACITY)
         self._meta = {}
 
     # -- recording ---------------------------------------------------------
@@ -161,6 +171,24 @@ class Tracer:
             return
         self.add(name, time.perf_counter(), 0.0, trace=trace, **args)
 
+    def incident(self, name, t0, dur, **args):
+        """Record a RARE event whether or not the ring is enabled: kept in
+        a second bounded deque (``INCIDENT_CAPACITY``), and in the ring as
+        well when it is on.  ``t0`` is a ``time.perf_counter()`` instant.
+
+        Contract: incidents are rare by construction — never one per step
+        or per request.  A warmed engine stepping with no compile, no long
+        collector pause and no stall adds none, so what the store holds
+        after a run is the list of what went wrong in it.  The caller
+        passes ``step=``, the loop iteration the event fell in, where
+        there is one (for an engine the process's count of ``step()``
+        calls, the counter ``engine.steps``), so a reader can select "the
+        last N steps of the process" without a clock."""
+        rec = {"name": name, "ts": t0, "dur": dur, "args": args}
+        self._incidents.append(rec)
+        if self.enabled:
+            self._ring.append(rec)
+
     def set_meta(self, **kw):
         """Attach metadata (e.g. the driver's per-worker clock offsets) to
         this process's dump."""
@@ -171,8 +199,12 @@ class Tracer:
     def ring(self):
         return list(self._ring)
 
+    def incidents(self):
+        return list(self._incidents)
+
     def clear(self):
         self._ring.clear()
+        self._incidents.clear()
         self._meta.clear()
 
     def dump_obj(self):
@@ -183,6 +215,7 @@ class Tracer:
             "wall": time.time(),
             "meta": dict(self._meta),
             "spans": list(self._ring),
+            "incidents": list(self._incidents),
         }
 
     def dump(self, path):

@@ -204,12 +204,14 @@ class Watchdog:
         # the span ring rides along when this process is tracing: the
         # last N spans before the stall are exactly the diagnosis a hung
         # serve/train loop needs (import stays lazy — observe.trace is
-        # stdlib, but the observe package itself is not)
+        # stdlib, but the observe package itself is not).  Incidents
+        # (compiles, collector pauses, slow steps) are kept with the ring
+        # off too, so a process that filed any dumps them
         try:
             from progen_tpu.observe.trace import get_tracer
 
             tracer = get_tracer()
-            if tracer.enabled and tracer.ring():
+            if (tracer.enabled and tracer.ring()) or tracer.incidents():
                 trace_path = os.path.join(
                     self.out_dir, f"watchdog_trace_{stamp}.json")
                 tracer.dump(trace_path)

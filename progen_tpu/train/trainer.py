@@ -42,6 +42,7 @@ from progen_tpu.data import decode_tokens, iterator_from_tfrecords_folder
 from progen_tpu.data.prefetch import DevicePrefetcher, SuperbatchStager
 from progen_tpu.decode import make_sampler
 from progen_tpu.models import ProGen, ProGenConfig
+from progen_tpu.observe import compiles
 from progen_tpu.observe import (
     ThroughputMeter,
     Tracker,
@@ -327,6 +328,12 @@ class Trainer:
         # lands in the flight recorder so a watchdog trip shows the
         # loop's recent phases even when tracing is off
         self._tracer = get_tracer()
+        # compiles come from the process's listeners (observe/compiles.py);
+        # ``train.recompiles`` counts those that fell in the dispatch of a
+        # step program that had been dispatched before: 0 for ever
+        compiles.install()
+        self._xla_compiles = get_registry().counter("xla.compiles")
+        self._recompiles = get_registry().counter("train.recompiles")
         self._watchdog: Watchdog | None = None
         # live introspection plane: health/status read the flight
         # recorder and registry (host floats published at the loop's one
@@ -727,6 +734,7 @@ class Trainer:
                 pending_tokens,
             )
         finally:
+            compiles.set_step(None)
             if watchdog is not None:
                 watchdog.stop()
             self._watchdog = None
@@ -774,6 +782,9 @@ class Trainer:
                         if watchdog is not None and epoch == 1 and i == 0
                         else contextlib.nullcontext()
                     )
+                    # on incidents filed during this iteration
+                    compiles.set_step(global_step + 1)
+                    compiled = self._xla_compiles.value
                     # dispatch time only (the step runs async on device);
                     # a long span here means input starvation or a compile:
                     # the feed's child span inside it says which
@@ -785,6 +796,10 @@ class Trainer:
                                 batch = (next(train_it) if prefetched else
                                          self._to_device(next(train_it)))
                             state, metrics = self.fns.train_step(state, batch)
+                    if (self._xla_compiles.value != compiled
+                            and not (epoch == 1 and i == 0)):
+                        self._recompiles.inc(
+                            self._xla_compiles.value - compiled)
                     global_step += 1
                     # monotonic, never wrapped: the checkpointed cursor must
                     # identify the position in the multi-epoch STREAM
@@ -952,7 +967,11 @@ class Trainer:
                         if watchdog is not None and k not in compiled_ks
                         else contextlib.nullcontext()
                     )
+                    warm = k in compiled_ks
                     compiled_ks.add(k)
+                    # on incidents filed during this iteration
+                    compiles.set_step(global_step + span)
+                    compiled = self._xla_compiles.value
                     with self._phase("train.step_dispatch",
                                      step=global_step + span,
                                      span=span), grace:
@@ -962,6 +981,9 @@ class Trainer:
                                 superbatch = stager.get(k)
                             state, metrics = self.fns.train_multi_step(
                                 state, superbatch)
+                    if warm and self._xla_compiles.value != compiled:
+                        self._recompiles.inc(
+                            self._xla_compiles.value - compiled)
                     done += span
                     global_step += span
                     seq_cursor = seq_cursor + effective_batch * span

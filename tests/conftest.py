@@ -27,3 +27,23 @@ def devices8():
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 virtual CPU devices, got {len(devs)}"
     return devs
+
+
+@pytest.fixture
+def observers(monkeypatch):
+    """A metrics registry and a process tracer of this test's own, with the
+    compile and collector listeners (``observe/compiles.py``) installed on
+    them and removed after; what the process had is put back."""
+    from progen_tpu.observe import compiles, metrics, trace
+
+    registry = metrics.MetricsRegistry()
+    tracer = trace.Tracer()
+    monkeypatch.setattr(metrics, "_REGISTRY", registry)
+    monkeypatch.setattr(trace, "_TRACER", tracer)
+    was = compiles.installed()
+    compiles.uninstall()
+    compiles.install()
+    yield registry, tracer
+    compiles.uninstall()
+    if was:
+        compiles.install()

@@ -22,6 +22,7 @@ from progen_tpu.core.precision import make_policy
 from progen_tpu.decode import Request, ServingEngine
 from progen_tpu.decode import engine as engine_mod
 from progen_tpu.models import ProGen, ProGenConfig
+from progen_tpu.observe import compiles
 from progen_tpu.observe import trace as trace_mod
 from progen_tpu.observe.trace import Tracer, configure_tracing, get_tracer
 from progen_tpu.parallel import unbox
@@ -300,10 +301,13 @@ def test_every_engine_span_has_its_annotation(served, mode, ring,
             "serve.chunk_work"} <= names
     entered = [n for kind, n in _Annotation.log if kind == "enter"]
     exited = [n for kind, n in _Annotation.log if kind == "exit"]
-    # the back-dated work spans and the instant events are the ring's alone
+    # the back-dated work spans, the instant events and the three kinds of
+    # incident (this engine's programs compile as it runs) are the ring's
+    # alone
     ring_only = {"serve.admit_work", "serve.chunk_work"}
+    incidents = {"xla.compile", "host.gc", "serve.slow_step"}
     timed = [s["name"] for s in spans
-             if s["name"] not in ring_only and s["dur"] > 0.0]
+             if s["name"] not in ring_only | incidents and s["dur"] > 0.0]
     assert sorted(entered) == sorted(exited) == sorted(timed)
     # children carry the step they belong to; the work spans end at a fetch
     waits = [s for s in spans if s["name"] == "serve.device_wait"]
@@ -360,6 +364,349 @@ def test_tokens_identical_with_ring_on_and_off(served, mode):
     on, spans = run(True)
     assert on == off and len(on) == 5
     assert no_spans == [] and spans
+
+
+# ------------------------------------------------- (g) what no span owns
+
+
+@pytest.fixture
+def watched(observers):
+    """``observers`` with the collector held still, so that a full pass
+    over the test process's heap (its own test: ``test_incidents.py``) is
+    not this engine's incident."""
+    import gc
+
+    gc.collect()
+    gc.disable()
+    yield observers
+    gc.enable()
+
+
+def _value(registry, name):
+    return registry.snapshot()[name]["value"]
+
+
+def _warm_engine(served, **kw):
+    """Two slots, the 4-token bucket and every other program compiled, and
+    enough steps behind it for the slow-step rule's means to judge."""
+    eng = _engine(served, "dense", **kw)
+    eng.aot_warmup(max_prime=4)
+    for r in _requests(12, seed=3):
+        r.tokens = r.tokens[:3]
+        eng.submit(r)
+    eng.run_until_idle()
+    eng.completions.clear()
+    return eng
+
+
+def test_a_warmed_engine_files_nothing_then_a_cold_bucket_files_its_compile(
+        served, watched):
+
+    registry, tracer = watched
+    eng = _warm_engine(served)
+    assert compiles.installed()     # the constructor's doing
+    tracer.clear()
+    assert _value(registry, "engine.compiles_in_step") == 0
+    steps_before = _value(registry, "engine.steps")
+    uid = 100
+    for _ in range(50):
+        while eng.pending < 2:
+            r, = _requests(1, seed=uid)
+            r.uid, r.tokens = uid, r.tokens[:3]
+            eng.submit(r)
+            uid += 1
+        eng.step()
+    assert _value(registry, "engine.steps") == steps_before + 50
+    assert tracer.incidents() == []
+    assert _value(registry, "engine.compiles_in_step") == 0
+    assert eng.status()["compiles_in_step"] == 0
+    # no step in progress between steps
+    assert getattr(compiles._thread, "step", None) is None
+    eng.run_until_idle()
+    # a prime of 6 tokens: the 8-token bucket was not warmed
+    cold, = _requests(1, seed=1)
+    cold.uid, cold.tokens = 999, [3, 4, 5, 6, 7, 8]
+    eng.submit(cold)
+    eng.step()
+    filed = [i for i in tracer.incidents() if i["name"] == "xla.compile"]
+    # the process's number of that step: the counter a reader counts from
+    step = _value(registry, "engine.steps")
+    assert filed and all(i["args"]["step"] == step for i in filed)
+    assert any("_admit" in i["args"]["program"] for i in filed)
+    assert _value(registry, "engine.compiles_in_step") == len(filed)
+    assert eng.status()["compiles_in_step"] == len(filed)
+    # the step that compiled stood still, and says so itself
+    slow = [i for i in tracer.incidents() if i["name"] == "serve.slow_step"]
+    for i in slow:
+        assert i["args"]["compiles"] == len(filed)
+        assert i["args"]["step"] == step
+
+
+def test_a_slow_callback_is_one_host_incident_with_its_excess(
+        served, watched):
+    registry, tracer = watched
+    eng = _warm_engine(served)
+    tracer.clear()
+    r, = _requests(1, seed=5)
+    r.tokens = r.tokens[:3]
+    r.on_complete = lambda comp: time.sleep(0.2)
+    eng.submit(r)
+    eng.run_until_idle()
+    incident, = tracer.incidents()
+    args = incident["args"]
+    assert incident["name"] == "serve.slow_step" and args["which"] == "host"
+    assert args["excess"] == pytest.approx(0.2, rel=0.2)
+    assert args["host"] >= 0.2 and args["wall"] >= args["host"]
+    assert args["wall"] == pytest.approx(
+        args["host"] + args["device_wait"], abs=1e-6)
+    assert args["compiles"] == 0 and args["gc_s"] == 0.0
+    assert 1 <= args["step"] <= _value(registry, "engine.steps")
+    # the slow step stayed out of the mean it was judged by
+    assert eng._mean_host.mean < 0.05
+
+
+def test_a_pause_between_steps_is_a_gap_and_an_idle_loop_is_none(
+        served, watched):
+    registry, tracer = watched
+    eng = _warm_engine(served)
+    tracer.clear()
+    for r in _requests(2, seed=6):
+        r.tokens = r.tokens[:3]
+        eng.submit(r)
+    eng.step()
+    assert eng.has_work
+    time.sleep(0.2)     # the caller holds the engine up with work waiting
+    eng.step()
+    incident, = tracer.incidents()
+    args = incident["args"]
+    assert incident["name"] == "serve.slow_step" and args["which"] == "gap"
+    assert args["gap"] == pytest.approx(0.2, rel=0.2)
+    assert args["excess"] == pytest.approx(0.2, rel=0.2)
+    eng.run_until_idle()
+    tracer.clear()
+    # an open loop with nothing to do sleeps: not a stall
+    assert not eng.has_work
+    time.sleep(0.2)
+    r, = _requests(1, seed=7)
+    r.uid, r.tokens = 50, r.tokens[:3]
+    eng.submit(r)
+    eng.run_until_idle()
+    assert tracer.incidents() == []
+
+
+def test_a_slow_device_is_a_device_incident_of_its_program(
+        served, watched, monkeypatch):
+    registry, tracer = watched
+    eng = _warm_engine(served)
+    tracer.clear()
+    real = engine_mod._host_fetch
+    slow = {"in": 0}
+
+    def fetch(tree):
+        slow["in"] -= 1
+        if slow["in"] == 0:     # the device is not done yet
+            time.sleep(0.2)
+        return real(tree)
+
+    monkeypatch.setattr(engine_mod, "_host_fetch", fetch)
+    for r in _requests(2, seed=8):
+        r.tokens = r.tokens[:3]
+        eng.submit(r)
+    eng.step()
+    # a chunk step alone: a flags fetch with nothing in flight, the chunk's
+    # dispatch, then the flags fetch that waits for it — held up
+    slow["in"] = 2
+    eng.step()
+    eng.run_until_idle()
+    incident, = tracer.incidents()
+    args = incident["args"]
+    assert args["which"] == "device" and args["device_wait"] >= 0.2
+    assert args["excess"] == pytest.approx(0.2, rel=0.2)
+    assert args["host"] < 0.05
+    assert [name for name, _ in args["stages"]] == ["chunk"]
+
+
+def test_prefill_rounds_outside_a_step_keep_no_stage(served, watched):
+    """A prefill worker runs rounds for the life of its process and never
+    steps: nothing of a round may be kept for a step that never comes."""
+    registry, tracer = watched
+    eng = _engine(served, "dense", disagg=True)
+    steps_before = _value(registry, "engine.steps")
+    for r in _requests(6, seed=11):
+        eng.submit(r)
+    handles = 0
+    while eng.pending:
+        handles += eng.run_prefill_round() is not None
+    assert handles >= 3 and eng._step_stages is None
+    assert eng.stage_seconds["prefill_s"] > 0      # the stage's clock ran
+    assert eng._mean_stage == {} and eng._admit_pads == []
+    assert _value(registry, "engine.steps") == steps_before
+    assert [i for i in tracer.incidents()
+            if i["name"] == "serve.slow_step"] == []
+    # the same engine stepping judges its prefill rounds by their bucket
+    for r in _requests(4, seed=12):
+        r.uid += 100
+        eng.submit(r)
+    eng.run_until_idle()
+    assert eng._step_stages is None
+    assert {"chunk"} < set(eng._mean_stage) <= {
+        "chunk", ("prefill", 4), ("prefill", 8)}
+
+
+def test_a_step_that_raises_leaves_nothing_to_the_next(served, watched,
+                                                       monkeypatch):
+    registry, tracer = watched
+    eng = _warm_engine(served)
+    tracer.clear()
+    for r in _requests(2, seed=13):
+        r.tokens = r.tokens[:3]
+        eng.submit(r)
+    eng.step()
+    assert eng.has_work and eng._last_return is not None
+    real = eng._dispatch_chunk
+
+    def broken():
+        real()                      # its stages close, then it fails
+        raise RuntimeError("lost the device")
+
+    monkeypatch.setattr(eng, "_dispatch_chunk", broken)
+    with pytest.raises(RuntimeError):
+        eng.step()
+    assert eng._step_stages is None
+    assert getattr(compiles._thread, "step", None) is None
+    # the failed step's seconds are not the next step's gap
+    assert eng._last_return is None
+    monkeypatch.setattr(eng, "_dispatch_chunk", real)
+    eng.run_until_idle()
+    assert tracer.incidents() == []
+
+
+def test_two_engines_number_their_steps_in_one_sequence(served, watched):
+    """An incident's ``step`` is the PROCESS's count of ``step()`` calls
+    (the counter ``engine.steps``), whichever engine made them: a reader
+    that takes the last N of that counter takes the right incidents."""
+    registry, tracer = watched
+    first, second = _warm_engine(served), _warm_engine(served)
+    tracer.clear()
+    r, = _requests(1, seed=5)
+    r.tokens = r.tokens[:3]
+    r.on_complete = lambda comp: time.sleep(0.2)
+    second.submit(r)
+    second.run_until_idle()
+    incident, = tracer.incidents()
+    assert incident["args"]["which"] == "host"
+    total = _value(registry, "engine.steps")
+    assert total == first._step_no + second._step_no
+    assert second._step_no < incident["args"]["step"] <= total
+
+
+def test_admission_and_chunk_counts_against_hand_counts(served, watched,
+                                                        monkeypatch):
+    registry, tracer = watched
+    monkeypatch.setattr(engine_mod, "SLOTS_PER_ADMIT_ROW", 2)
+    eng = _engine(served, "dense", num_slots=4)
+    assert eng.admit_rows == 2
+    reqs = _requests(3, max_new=6, seed=2)
+    for r, n in zip(reqs, (2, 5, 6)):     # buckets 4, 8, 8
+        r.tokens = list(range(3, 3 + n))
+        eng.submit(r)
+    eng.step()
+    # two runs of two rows: (2, 5) padded to 8 and (6,) padded to 8
+    assert eng._admit_rows_hist.count == 2
+    assert _value(registry, "engine.prefill_tokens_real") == 2 + 5 + 6
+    assert _value(registry, "engine.prefill_token_slots") == 2 * 8 + 2 * 8
+    rows = registry.snapshot()["engine.chunk_rows"]
+    assert (rows["count"], rows["sum"]) == (1, 3)
+    eng.run_until_idle()
+    rows = registry.snapshot()["engine.chunk_rows"]
+    assert rows["count"] == eng.chunks_run and rows["max"] == 3
+    assert _value(registry, "engine.prefill_tokens_real") == 13
+    # the stage's program is the admission group by its padded lengths
+    assert ("admit", 8, 8) in eng._mean_stage and "chunk" in eng._mean_stage
+
+
+@pytest.mark.parametrize("mode", sorted(MODES))
+def test_tokens_identical_with_and_without_the_listeners(served, mode):
+
+    def run(listening):
+        eng = _engine(served, mode)     # installs them
+        if not listening:
+            compiles.uninstall()
+        reqs = [Request(uid=i, tokens=[3 + i, 5, 7], max_new_tokens=8,
+                        top_k=8, temperature=0.9, seed=40 + i)
+                for i in range(5)]
+        _, comps = _timed_run(eng, reqs)
+        return {u: c.tokens.tolist() for u, c in comps.items()}
+
+    was = compiles.installed()
+    try:
+        off, on = run(False), run(True)
+    finally:
+        compiles.uninstall()
+        if was:
+            compiles.install()
+    assert on == off and len(on) == 5
+
+
+def test_engines_share_one_set_of_listeners(served):
+    import gc
+
+    from jax._src import monitoring
+
+
+    was = compiles.installed()
+    try:
+        for _ in range(3):
+            _engine(served, "dense")
+        assert monitoring.get_event_duration_listeners().count(
+            compiles._on_duration) == 1
+        assert monitoring.get_event_listeners().count(
+            compiles._on_event) == 1
+        assert gc.callbacks.count(compiles._on_gc) == 1
+    finally:
+        compiles.uninstall()
+        if was:
+            compiles.install()
+    assert compiles._on_gc not in gc.callbacks or was
+
+
+def test_trainer_counts_no_recompile_and_leaves_no_step_behind(
+        tmp_path, watched):
+    from progen_tpu.data import shard_filename, write_tfrecord
+    from progen_tpu.observe import Tracker
+    from progen_tpu.train.trainer import Trainer, TrainerConfig
+
+    registry, tracer = watched
+    cfg = ProGenConfig(
+        num_tokens=128, dim=16, seq_len=16, depth=2, window_size=8,
+        global_mlp_depth=1, heads=2, dim_head=8, ff_mult=2,
+    )
+    rng = np.random.default_rng(0)
+    records = [bytes(rng.integers(65, 90, rng.integers(6, 14)))
+               for _ in range(32)]
+    data = tmp_path / "data"
+    data.mkdir()
+    write_tfrecord(data / shard_filename(0, 32, "train"), records)
+    write_tfrecord(data / shard_filename(0, 8, "valid"), records[:8])
+    trainer = Trainer(
+        model_config=cfg,
+        cfg=TrainerConfig(
+            batch_size=2, epochs=50, learning_rate=1e-3,
+            validate_every=1000, sample_every=1000, checkpoint_every=1000,
+            mixed_precision=False, log_every=2, max_steps=5,
+            warm_sampler=False),
+        data_path=str(data), checkpoint_path=str(tmp_path / "ckpt"),
+        tracker=Tracker(out_dir=str(tmp_path / "runs")), use_mesh=False)
+    assert trainer.run()["step"] == 5
+    trainer.store.close()
+    assert _value(registry, "train.recompiles") == 0
+    assert _value(registry, "xla.compiles") > 0
+    assert getattr(compiles._thread, "step", None) is None
+    # the step program compiled in the first iteration, and says which
+    steps = {i["args"].get("step") for i in tracer.incidents()
+             if i["name"] == "xla.compile"
+             and "train_step" in i["args"]["program"]}
+    assert steps == {1}
 
 
 # ------------------------------------------------- (e) the feed's wait

@@ -316,7 +316,13 @@ def test_watchdog_beats_keep_it_alive(tmp_path):
     assert not wd.tripped and not exits
 
 
-def test_watchdog_trips_within_deadline_and_dumps(tmp_path):
+def test_watchdog_trips_within_deadline_and_dumps(tmp_path, monkeypatch):
+    from progen_tpu.observe import trace as trace_mod
+
+    # a process tracer with no incident and no ring: the trip writes the
+    # stacks and the flight recorder and nothing else (the third artifact,
+    # the tracer's dump, has its own test in test_incidents.py)
+    monkeypatch.setattr(trace_mod, "_TRACER", trace_mod.Tracer())
     rec = FlightRecorder()
     rec.record("step", step=1, loss=2.5)
     exits = []
