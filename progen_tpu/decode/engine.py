@@ -645,9 +645,13 @@ class ServingEngine:
         self._pool = self._layout.pool
         # op -> the lowering its calls took in the programs traced so far
         self.lowerings: dict[str, str] = {}
+        # the same per program ("chunk", "admit"): the experts' product is
+        # in both and takes another lowering in each
+        self.program_lowerings: dict[str, dict[str, str]] = {}
         self._decode_chunk = self._jit_noting(
-            self._decode_chunk_spec_impl if spec else self._decode_chunk_impl)
-        self._admit = self._jit_noting(self._admit_impl)
+            self._decode_chunk_spec_impl if spec else self._decode_chunk_impl,
+            "chunk")
+        self._admit = self._jit_noting(self._admit_impl, "admit")
         self.model_stats: dict = {}     # the family's counters as last fetched
         if remote_prefill and not disagg:
             raise ValueError("remote_prefill requires disagg=True")
@@ -883,7 +887,7 @@ class ServingEngine:
         self._layout.use_impl("xla")
         self._decode_chunk = self._jit_noting(
             self._decode_chunk_spec_impl if self.spec
-            else self._decode_chunk_impl)
+            else self._decode_chunk_impl, "chunk")
         self._aot.pop(("chunk",), None)
         self._compiled_keys.discard(("chunk",))
         print("serving: pallas paged kernel failed; degraded to the "
@@ -891,22 +895,27 @@ class ServingEngine:
 
     # ------------------------------------------------------------- decoding
 
-    def _jit_noting(self, impl):
-        """``jax.jit(impl)`` for a decode-chunk or admission program;
-        tracing it notes which lowering each op that owns two took
-        (``ops/lowering.py``) for ``status()``: the step's cache writes
-        (``"row_write"``: ``"pallas"`` on a TPU, ``"scatter"`` elsewhere,
-        both joined by ``+`` where the shapes split them), a latent
-        attention's prefill and absorbed decode cores (``"mla_prefill"``,
-        ``"mla_decode"``: ``"pallas"`` / ``"xla"``) and a grouped-query
-        attention's prefill core (``"gqa_prefill"``, the same two)."""
+    def _jit_noting(self, impl, program):
+        """``jax.jit(impl)`` for a decode-chunk or admission program
+        (``program``: ``"chunk"`` / ``"admit"``); tracing it notes which
+        lowering each op that owns two took (``ops/lowering.py``) for
+        ``status()``: the step's cache writes (``"row_write"``:
+        ``"pallas"`` on a TPU, ``"scatter"`` elsewhere, both joined by
+        ``+`` where the shapes split them), a latent attention's prefill
+        and absorbed decode cores (``"mla_prefill"``, ``"mla_decode"``:
+        ``"pallas"`` / ``"xla"``), a grouped-query attention's prefill core
+        (``"gqa_prefill"``, the same two) and the held experts' product
+        (``"moe_experts"``, the same two, stated per program: it is in
+        both)."""
 
         @wraps(impl)
         def traced(*args):
             with record_lowerings() as chosen:
                 out = impl(*args)
-            for op, paths in chosen.items():
-                self.lowerings[op] = "+".join(sorted(paths))
+            took = {op: "+".join(sorted(paths))
+                    for op, paths in chosen.items()}
+            self.lowerings.update(took)
+            self.program_lowerings.setdefault(program, {}).update(took)
             return out
 
         return jax.jit(traced)
@@ -2578,6 +2587,12 @@ class ServingEngine:
             "mla_prefill": self.lowerings.get("mla_prefill"),
             "mla_decode": self.lowerings.get("mla_decode"),
             "gqa_prefill": self.lowerings.get("gqa_prefill"),
+            # the held experts' product, by program: {"chunk": ..,
+            # "admit": ..} as far as traced, None for a family without
+            "moe_experts": {
+                program: took["moe_experts"]
+                for program, took in self.program_lowerings.items()
+                if "moe_experts" in took} or None,
             "paged": self.paged,
             "disagg": self.disagg,
             "spec": self.spec,

@@ -6,10 +6,22 @@ with such a layer carry (``models/longcat.py``, ``models/deepseek_v2.py``,
 The router is as wide as published and picks ``moe_topk`` whatever the chip
 holds; the layer adds the terms of the ``experts_held`` real experts from
 ``first_expert`` on and leaves out what the absent experts would add.
-Nothing stands in for absent chips.  Tokens are grouped by held expert (a
-sort of the assignments) and multiplied by ``jax.lax.ragged_dot`` in windows
-of ``capacity`` assignments: a window that overflows runs the loop again, so
-no assignment is ever dropped.
+Nothing stands in for absent chips.  No assignment is ever dropped, and
+:func:`held_experts` computes them in one of two ways, chosen from what the
+code can observe and never from a knob (``ops/moe_decode.py:fitted_tile``;
+noted under ``"moe_experts"``, ``ops/lowering.py``):
+
+* a call that carries a DECODE step's handful of tokens (at most 128, on a
+  TPU with no mesh in scope, tokens and weights of one float type, widths
+  on the lane tile) goes through the kernel ``moe_decode_fwd``: each
+  touched expert's gate, up and down matrices streamed once, back to back,
+  every token through every touched expert with its routing weight (zero
+  where it is not the expert's) selecting, so there is neither sort nor
+  gather nor scatter-add;
+* every other call (an admission's thousands of tokens, the CPU, a mesh)
+  groups the tokens by held expert (a sort of the assignments) and
+  multiplies them by ``jax.lax.ragged_dot`` in windows of ``capacity``
+  assignments: a window that overflows runs the loop again.
 
 A config here has ``experts_held``, ``first_expert``, ``moe_topk`` and
 ``router_width``.
@@ -20,13 +32,16 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from progen_tpu.ops import moe_decode
+from progen_tpu.ops.lowering import note
+
 F32 = jnp.float32
 
 # what every family with a share counts (docs/OBSERVABILITY.md §3); a
 # family adds its own keys and its attention's (``latent.STAT_KEYS``,
 # ``trinity.ATTN_STAT_KEYS``) to these
 STAT_KEYS = ("moe.tokens", "moe.held_load", "moe.prefill_held",
-             "moe.decode_layers", "moe.experts_touched")
+             "moe.decode_layers", "moe.experts_touched", "moe.expert_passes")
 
 
 def zero_stats(keys, held: int) -> dict:
@@ -56,14 +71,21 @@ def held_experts(u, ids, w, live, experts, c, capacity=None):
     load (held,))`` where ``load`` counts the live tokens' assignments to
     each held expert.  Assignments of tokens that are not ``live``
     (padding, finished rows) are not computed.  ``capacity``: assignments
-    per window of the grouped product (default :func:`moe_capacity`); a
-    window that overflows runs again, so it changes no result."""
+    per window of the grouped product where that form runs (default
+    :func:`moe_capacity`); a window that overflows runs again, so it
+    changes no result."""
     t, k = ids.shape
     held = c.experts_held
+    tile = moe_decode.fitted_tile(u, experts)
+    note("moe_experts", "xla" if tile is None else "pallas")
     with jax.named_scope("moe.experts"):
         local = ids - c.first_expert
         mine = (local >= 0) & (local < held) & live[:, None]
         group = jnp.where(mine, local, held).reshape(-1)
+        if tile is not None:
+            load = jnp.bincount(group, length=held + 1)[:held]
+            return _streamed(u, group.reshape(t, k), w, load, experts,
+                             tile), load
         order = jnp.argsort(group)                    # held first, by expert
         load = jnp.bincount(group, length=held + 1)[:held]
         ends = jnp.cumsum(load)
@@ -94,3 +116,28 @@ def held_experts(u, ids, w, live, experts, c, capacity=None):
             lambda carry: carry[0] * cap < n_mine, window,
             (jnp.zeros((), jnp.int32), jnp.zeros(u.shape, F32)))
         return y, load
+
+
+def _streamed(u, group, w, load, experts, tile):
+    """The terms of ``held_experts`` through ``moe_decode_fwd``: the
+    touched experts in ascending order, each with the routing weight of
+    every token (zero where ``group (T, k)`` does not name it)."""
+    held = load.shape[0]
+    touched = load > 0
+    eid = jnp.argsort(~touched)                  # stable: the touched first
+    names = group[None] == jnp.arange(held)[:, None, None]
+    wt = jnp.sum(jnp.where(names, w.astype(F32)[None], 0.0), axis=-1)
+    return moe_decode.pallas_expert_terms(
+        u, eid, jnp.sum(touched), wt[eid], experts["wg"], experts["wu"],
+        experts["wd"], tile=tile)
+
+
+def expert_passes(u, experts, load):
+    """How many times the lowering :func:`held_experts` takes for ``u``
+    streams an expert's three matrices, by the lowering's own reckoning, as
+    a float32 scalar: one work item a touched expert under the kernel (all
+    of a call's tokens fit one item), 0 under the XLA form, whose reads the
+    program cannot know."""
+    if moe_decode.fitted_tile(u, experts) is None:
+        return jnp.zeros((), F32)
+    return jnp.sum(load > 0).astype(F32)

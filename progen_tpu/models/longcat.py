@@ -38,7 +38,8 @@ import jax.numpy as jnp
 
 from progen_tpu.core.precision import Policy
 from progen_tpu.models import experts, latent
-from progen_tpu.models.experts import held_experts, moe_capacity  # noqa: F401
+from progen_tpu.models.experts import (  # noqa: F401
+    expert_passes, held_experts, moe_capacity)
 from progen_tpu.models.latent import (  # noqa: F401
     F32,
     bf16_policy,
@@ -184,7 +185,8 @@ def moe_share(u, layer, c: LongCatConfig, live):
     real = jnp.sum((ids < c.n_routed_experts) & live[:, None])
     stats = {"moe.tokens": jnp.sum(live).astype(F32),
              "moe.real_chosen": real.astype(F32),
-             "moe.held_load": load.astype(F32)}
+             "moe.held_load": load.astype(F32),
+             "moe.expert_passes": expert_passes(u, layer["experts"], load)}
     return y.astype(u.dtype), ids, stats
 
 

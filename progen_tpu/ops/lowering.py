@@ -1,10 +1,12 @@
 """What an op that owns two lowerings of one contract can observe, and a
 note of which one it took.
 
-``ops/row_write.py``, ``ops/mla_prefill.py``, ``ops/mla_decode.py`` and
-``ops/gqa.py`` each keep a Pallas kernel and an XLA form behind one function
-and choose between them from the backend, the mesh in scope and the shapes,
-never from a knob.
+``ops/row_write.py``, ``ops/mla_prefill.py``, ``ops/mla_decode.py``,
+``ops/gqa.py`` and ``models/experts.py:held_experts`` (its kernel is
+``ops/moe_decode.py``; the note ``"moe_experts"``: ``"pallas"`` for a decode
+step's handful of tokens, ``"xla"`` for an admission's thousands) each keep
+a Pallas kernel and an XLA form behind one function and choose between them
+from the backend, the mesh in scope and the shapes, never from a knob.
 The choice is made while a program is traced, so a caller that traces one
 (``ServingEngine`` around its chunk and admission programs) can collect it:
 :func:`record_lowerings` yields ``{op name: {lowering, ...}}`` for the ops

@@ -412,3 +412,28 @@ def test_mla_decode_kernel_compiles_for_v5e(shape, no_persistent_cache,
                                                 interpret=False),
         shape((slots, heads, 576), bf16), shape((slots, max_len, 576), bf16),
         shape((slots,), jnp.int32))
+
+
+@pytest.mark.parametrize("tokens,h,inner,held", [
+    (64, 5120, 1536, 40), (32, 6144, 2048, 16), (64, 2048, 1024, 16)],
+    ids=["dsv2", "longcat", "trinity"])
+def test_moe_decode_kernel_compiles_for_v5e(shape, no_persistent_cache,
+                                            tokens, h, inner, held):
+    """The held experts' decode product (``ops/moe_decode.py``,
+    ``moe_decode_fwd``) at a decode call of the three expert cells, at the
+    published widths and with the inner tile the chip path takes: three
+    streamed tiles a step, double-buffered, under the ``vmem_limit_bytes``
+    the call states.  Outside the kernel the call keeps only the padded
+    tokens, the listed experts and their routing weights."""
+    from progen_tpu.ops.moe_decode import pallas_expert_terms
+
+    bf16 = jnp.bfloat16
+    fn = jax.jit(lambda u, e, n, wt, wg, wu, wd: pallas_expert_terms(
+        u, e, n, wt, wg, wu, wd, interpret=False))
+    compiled = fn.lower(
+        shape((tokens, h), bf16), shape((held,), jnp.int32),
+        shape((), jnp.int32), shape((held, tokens), jnp.float32),
+        shape((held, h, inner), bf16), shape((held, h, inner), bf16),
+        shape((held, inner, h), bf16)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * tokens * h * 4

@@ -334,7 +334,8 @@ def test_prefill_through_the_kernel(monkeypatch):
     with record_lowerings() as chosen:
         jaxpr = str(jax.make_jaxpr(lambda t, n: tr.prefill(
             params, t, n, WIDE, policy)[0])(toks, lengths))
-    assert chosen == {"gqa_prefill": {"pallas"}}
+    # (the experts of a prefill keep today's form whatever the backend)
+    assert chosen == {"gqa_prefill": {"pallas"}, "moe_experts": {"xla"}}
     # one call a block, ONE traced kernel a kind of block
     assert jaxpr.count("name=_flash_call") == WIDE.num_layers
     assert jaxpr.count("pallas_call") == 2
