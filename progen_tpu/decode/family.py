@@ -135,11 +135,17 @@ class ProGenFamily:
 
 
 # the families that are plain functions over ``models/driver.py``, imported
-# only when a config is not ProGen's: (module, its config, its family)
+# only when a config is not ProGen's: (module, its config, its family).
+# Three hold one chip's share of an expert layer (LongCat-Flash and
+# DeepSeek-V2 over latent attention, Trinity over rings and grown keys); the
+# fourth, Granite 4.0-H, has no experts and a recurrent state beside its
+# grown keys
 _DRIVER_FAMILIES = (
     ("progen_tpu.models.longcat", "LongCatConfig", "LongCatFamily"),
     ("progen_tpu.models.deepseek_v2", "DeepSeekV2Config", "DeepSeekV2Family"),
     ("progen_tpu.models.trinity", "TrinityConfig", "TrinityFamily"),
+    ("progen_tpu.models.granite_hybrid", "GraniteHybridConfig",
+     "GraniteHybridFamily"),
 )
 
 

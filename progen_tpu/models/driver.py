@@ -2,29 +2,47 @@
 parameter dict (no flax): embedding -> a family's layer stack -> logits,
 the seeded weights' common pieces, and the seam ``ServingEngine`` calls
 (``decode/family.py``).  What a family brings is its config, its ``stack``
-and **its attention blocks, each of which states its own cache**
+and **its mixer blocks, each of which states its own cache**
 (``blocks_of(config)``):
 ``models/latent.py`` has one kind (a latent row per token, ``max_len``
 long), ``models/trinity.py`` two in one model (a ring of ``sliding_window``
-rows beside keys and values that grow with the request).
+rows beside keys and values that grow with the request, both
+``models/kv.py``'s), ``models/granite_hybrid.py`` a RECURRENT STATE (a
+carry and a convolution tail a slot, as large at token 1 as at token
+100,000) beside grown keys.
 
 **A block** (``blocks_of(config)`` gives ``{name: block}``, one per
-attention block of the stack, in the stack's order) is an object with
+mixer of the stack, in the stack's order) is an object with
 
 ``init_cache(slots, max_len, dtype)``
     the block's cache of ``slots`` idle rows: a pytree whose every leaf
     has the slot as its leading axis.  Its other axes are the block's own
-    business: the driver, the family and the engine never look inside
+    business — rows per token, a ring, a state that does not depend on
+    ``max_len`` at all: the driver, the family and the engine never look
+    inside
 ``prefill(x (R, P, h), weights, lengths (R,))``
-    ``(out (R, P, h), rows)``: attention over R right-padded rows, and per
-    TOKEN what the cache will hold of it (leaves ``(R, ..P.., ...)``)
+    ``(out (R, P, h), rows)``: the mixer over R right-padded rows, and
+    whatever the block's own ``cache_rows`` lays out (leaves with R
+    leading): per TOKEN what the cache will hold of it for a block that
+    caches rows; for a state block the state at each row's TRUE length
 ``cache_rows(rows, lengths, max_len)``
-    those per-token rows laid out as the block's cache of R slots: where a
-    token's row goes and how many rows a slot has is said here and in
-    ``decode`` alone
+    what ``prefill`` returned laid out as the block's cache of R slots:
+    where a token's row goes and how many rows a slot has is said here and
+    in ``decode`` alone
 ``decode(x (S, h), pos (S,), cache, weights)``
     ``(out (S, h), cache)``: one token a row at position ``pos``, written
-    into the cache and attended up to it
+    into the cache (or folded into the state) and mixed up to it
+
+**Experts are a family's statement**, not the driver's assumption.  A
+family with a share of an expert layer (``models/experts.py``) names
+``"moe.held_load"`` among its ``stat_keys``, its stack returns the
+counters, the routers' choices and the held experts touched, and its config
+has ``experts_held``; the driver then adds the ``moe.*`` counters that are
+a whole call's.  A family without experts returns no such counter, no
+choices and 0 touched, and nothing here reads ``experts_held``.  Likewise
+the head: ``params["head"]`` where the family unties it, the embedding's
+transpose where ``params`` has none (``tie_word_embeddings``), and the
+logits divided by the config's ``logits_scaling`` where it has one.
 
 Precision: parameters and matrix products in the policy's dtypes (bfloat16
 as published); the routers, every softmax, the norms' statistics and the
@@ -67,22 +85,30 @@ def init_ffn(key, h, width, gain, dt, lead=()):
     }
 
 
-def init_params(config, key, policy: Policy, init_layer):
+def init_params(config, key, policy: Policy, init_layer, *,
+                embed_std=None, tied_head: bool = False,
+                final_norm_gain: float = 1.0):
     """Seeded weights, made on the device one layer per program so that no
     more than a layer's random bits are live beside the weights.
     ``init_layer(key, index)`` makes one layer's dict.  The embedding's
-    rows are ``normal(0, 1 / embed_gain)``, so that the stream starts at
-    unit scale whatever factor the family puts on the embedding."""
+    rows are ``normal(0, embed_std)``, by default ``1 / embed_gain`` so
+    that the stream starts at unit scale whatever factor the family puts on
+    the embedding.  ``tied_head``: no ``"head"`` (the logits read the
+    embedding); ``final_norm_gain`` multiplies the last norm's scale."""
     c, dt, h = config, policy.param_dtype, config.hidden_size
     keys = jax.random.split(key, c.num_layers + 3)
-    return {
+    std = 1.0 / c.embed_gain if embed_std is None else embed_std
+    params = {
         "embed": jax.jit(lambda k: normal(
-            k, (c.vocab_size, h), 1.0 / c.embed_gain, dt))(keys[0]),
-        "head": jax.jit(lambda k: normal(k, (h, c.vocab_size), h ** -0.5,
-                                         dt))(keys[1]),
-        "final_norm": jax.jit(lambda k: init_norm(k, (h,), dt))(keys[2]),
+            k, (c.vocab_size, h), std, dt))(keys[0]),
+        "final_norm": jax.jit(lambda k: init_norm(k, (h,), dt) * jnp.asarray(
+            final_norm_gain, dt))(keys[2]),
         "layers": [init_layer(keys[3 + i], i) for i in range(c.num_layers)],
     }
+    if not tied_head:
+        params["head"] = jax.jit(lambda k: normal(
+            k, (h, c.vocab_size), h ** -0.5, dt))(keys[1])
+    return params
 
 
 # ------------------------------------------------------------------- pieces
@@ -129,8 +155,15 @@ def _embed(params, tokens, c, dt):
 
 def _logits(x, params, c):
     x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
-    return jnp.dot(x, params["head"].astype(x.dtype),
-                   preferred_element_type=F32)
+    if "head" in params:
+        out = jnp.dot(x, params["head"].astype(x.dtype),
+                      preferred_element_type=F32)
+    else:       # tied: the embedding (V, h) read along h, never transposed
+        out = jnp.einsum("...h,vh->...v", x,
+                         params["embed"].astype(x.dtype),
+                         preferred_element_type=F32)
+    scaling = getattr(c, "logits_scaling", 1)
+    return out / scaling if scaling != 1 else out
 
 
 # --------------------------------------------------------------- the driver
@@ -146,11 +179,12 @@ def prefill(stack, blocks, params, tokens, lengths, config, policy: Policy,
     attend, live)`` is the family's layers over flat tokens, ``attend(x,
     block name, weights)`` the one thing prefill and decode differ in; it
     returns ``(x, stats, chosen ids per expert layer, held experts
-    touched)``.  Padding, and the whole of a row of length 0 (an admission
-    row that carries no request), is computed by the dense FFNs (the shapes
-    are static) but not by the experts, and by attention only where a
-    blocked XLA form runs; it is not counted, and no real position's output
-    depends on what it holds."""
+    touched)`` (no ``moe.*`` counter, no choices and 0 touched from a family
+    without experts).  Padding, and the whole of a row of length 0 (an
+    admission row that carries no request), is computed by the dense FFNs
+    (the shapes are static) but not by the experts, and by attention only
+    where a blocked XLA form runs; it is not counted, and no real position's
+    output depends on what it holds."""
     c = config
     dt = policy.compute_dtype
     r, n = tokens.shape
@@ -164,9 +198,10 @@ def prefill(stack, blocks, params, tokens, lengths, config, policy: Policy,
 
     x = _embed(params, tokens.reshape(-1), c, dt)
     x, stats, chosen, _ = stack(x, params, c, attend, live)
-    stats["moe.prefill_held"] = jnp.sum(stats["moe.held_load"])
-    # a decode step's counter, as ``moe.experts_touched`` beside it
-    stats["moe.expert_passes"] = jnp.zeros((), F32)
+    if "moe.held_load" in stats:
+        stats["moe.prefill_held"] = jnp.sum(stats["moe.held_load"])
+        # a decode step's counter, as ``moe.experts_touched`` beside it
+        stats["moe.expert_passes"] = jnp.zeros((), F32)
     if logit_positions is None:       # a row of no tokens reads position 0
         logit_positions = jnp.maximum(lengths - 1, 0)[:, None]
     x = jnp.take_along_axis(x.reshape(r, n, -1),
@@ -195,9 +230,10 @@ def decode_step(stack, blocks, attention_stats, params, tok, pos, caches,
 
     x = _embed(params, tok, c, dt)
     x, stats, chosen, touched = stack(x, params, c, attend, live)
-    stats["moe.decode_layers"] = jnp.asarray(
-        len(chosen), F32) * jnp.any(live)
-    stats["moe.experts_touched"] = touched
+    if "moe.held_load" in stats:
+        stats["moe.decode_layers"] = jnp.asarray(
+            len(chosen), F32) * jnp.any(live)
+        stats["moe.experts_touched"] = touched
     stats.update(attention_stats(dt, caches, pos, live))
     out = _logits(x, params, c), caches, stats
     if with_choices:
@@ -208,12 +244,18 @@ def decode_step(stack, blocks, attention_stats, params, tok, pos, caches,
 # ------------------------------------------------------- the engine's seam
 
 
+def zero_scalars(keys) -> dict:
+    """The counters of a family without experts, all scalars, at zero."""
+    return {k: jnp.zeros((), F32) for k in keys}
+
+
 class Family:
     """What ``ServingEngine``'s plain dense path calls
     (``decode/family.py``), for a family of this driver.  A family names
     itself and brings ``stack`` (its layers), ``stat_keys`` (its device
-    counters), ``blocks_of(config)`` (its attention blocks by name, each
-    stating its cache) and ``attention_stats``."""
+    counters; ``"moe.held_load"`` among them says it has experts),
+    ``blocks_of(config)`` (its mixer blocks by name, each stating its
+    cache) and ``attention_stats``."""
 
     name: str
     stat_keys: tuple
@@ -239,7 +281,9 @@ class Family:
                 for name, block in self.blocks.items()}
 
     def init_stats(self) -> dict:
-        return zero_stats(self.stat_keys, self.config.experts_held)
+        if "moe.held_load" in self.stat_keys:
+            return zero_stats(self.stat_keys, self.config.experts_held)
+        return zero_scalars(self.stat_keys)
 
     def bucket(self, prime_len: int, max_len: int) -> int:
         b = self.bucket_base
@@ -274,8 +318,9 @@ class Family:
         """Registry gauges from the fetched counters (cumulative since the
         engine was built): name -> value."""
         out = {k: float(v) for k, v in stats.items() if k != "moe.held_load"}
-        load = stats["moe.held_load"]
-        out["moe.held_assignments"] = float(load.sum())
-        out["moe.held_load_max"] = float(load.max())
-        out["moe.held_load_mean"] = float(load.mean())
+        load = stats.get("moe.held_load")
+        if load is not None:
+            out["moe.held_assignments"] = float(load.sum())
+            out["moe.held_load_max"] = float(load.max())
+            out["moe.held_load_mean"] = float(load.mean())
         return out

@@ -1,0 +1,153 @@
+"""A grouped-query attention block that caches its keys and values, and
+what it states about that cache (``models/driver.py`` says what a block
+is).  Shared by the families whose attention is grouped-query
+(``models/trinity.py``, ``models/granite_hybrid.py``): a family brings the
+PROJECTION (:meth:`KVBlock.project` and :meth:`KVBlock.finish`: its
+matrices, norms, rotation, gate and output) and the scale of its scores;
+the cache's layout, the prefill's rows laid out as a slot's cache and the
+decode step's write-then-attend are here.  The attention cores are
+``ops/gqa.py``.
+
+Both kinds of cache hold ``{"k", "v"}: (slots, KV, rows, d)`` — the token
+axis second to last, so that the chip tiles ``(rows, d)`` and the step's
+write is ``ops/row_write.py``'s kernel — and both are read by one decode
+core (``ops/gqa.py:decode_attention``) up to a per-slot count.  They differ
+in two lines:
+
+* a windowed block's cache is a RING of ``min(window, max_len)`` rows: the
+  token at position ``p`` lies in row ``p % rows`` and a slot at ``pos``
+  has ``min(pos + 1, rows)`` of them (keys are cached as projected, rotated
+  where the family rotates them, so their order in the ring does not
+  matter);
+* a full block's cache (``window`` None) GROWS with the request:
+  ``max_len`` rows, the token at ``p`` in row ``p``, ``pos + 1`` of them.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from progen_tpu.ops import gqa
+from progen_tpu.ops.row_write import write_rows
+
+F32 = jnp.float32
+
+DECODE_STAT_KEYS = ("attn.decode_rows", "attn.context_tokens",
+                    "attn.window_tokens", "attn.window_rows_read",
+                    "attn.full_rows_read")
+
+
+class KVBlock:
+    """One attention block of ``kv_heads`` key/value heads of ``head_dim``,
+    scores scaled by ``scale``: ``window`` rows in a ring, or ``max_len``
+    rows that grow with the request (``window`` None).  A family's subclass
+    has :meth:`project` and :meth:`finish`."""
+
+    def __init__(self, kv_heads: int, head_dim: int, scale: float,
+                 window: int | None = None):
+        self.kv_heads = kv_heads
+        self.head_dim = head_dim
+        self.scale = scale
+        self.window = window
+        self.scope = "attn.full" if window is None else "attn.window"
+
+    def project(self, x, p, positions):
+        """``x (R, n, h)`` at ``positions (R, n)`` -> ``(q (R, n, H, d), k
+        (R, n, KV, d), v (R, n, KV, d), rest)``; ``rest`` is whatever else
+        of the token :meth:`finish` takes (a gate; None), leaves ``(R, n,
+        ...)``."""
+        raise NotImplementedError
+
+    def finish(self, o, rest, p):
+        """The block's output ``(..., h)`` from the core's ``o (..., H *
+        d)`` and ``rest`` as :meth:`project` gave it (a decode step passes
+        both without the token axis)."""
+        raise NotImplementedError
+
+    def rows(self, max_len: int) -> int:
+        """Rows a slot's cache has in an engine of ``max_len``."""
+        return max_len if self.window is None else min(self.window, max_len)
+
+    def place(self, pos, rows: int):
+        """``(the row the token at pos lies in, the rows a slot at pos
+        has)`` of a cache of ``rows`` rows."""
+        if self.window is None:
+            return pos, pos + 1
+        return pos % rows, jnp.minimum(pos + 1, rows)
+
+    def init_cache(self, slots: int, max_len: int, dtype):
+        shape = (slots, self.kv_heads, self.rows(max_len), self.head_dim)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+    def prefill(self, x, p, lengths):
+        """Attention over ``x (R, P, h)``; the per-token rows are ``{"k",
+        "v"}: (R, KV, P, d)``."""
+        r, n, _ = x.shape
+        positions = jnp.broadcast_to(jnp.arange(n), (r, n))
+        q, k, v, rest = self.project(x, p, positions)
+        k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+        with jax.named_scope(self.scope):
+            o = gqa.prefill_attention(q, k, v, self.scale, self.window,
+                                      lengths)
+        return self.finish(o, rest, p), {"k": k, "v": v}
+
+    def cache_rows(self, rows, lengths, max_len: int):
+        """The per-token rows of R primes as R slots' caches: a full block
+        keeps the first ``max_len`` tokens where they are; a ring takes,
+        for each of its rows ``j``, the LAST real token ``p < length`` with
+        ``p % rows == j`` (rows no token has reached hold anything: no
+        count reaches them)."""
+        n = rows["k"].shape[2]
+        size = self.rows(max_len)
+        if self.window is None:
+            return {name: a[:, :, :size] if n >= size else jnp.pad(
+                a, ((0, 0), (0, 0), (0, size - n), (0, 0)))
+                for name, a in rows.items()}
+        j = jnp.arange(size)[None, :]
+        last = lengths[:, None] - 1
+        at = jnp.clip(j + size * ((last - j) // size), 0, n - 1)
+        return {name: jnp.take_along_axis(a, at[:, None, :, None], axis=2)
+                for name, a in rows.items()}
+
+    def decode(self, x, pos, cache, p):
+        """One token a row: ``x (S, h)`` at ``pos (S,)``; the new key and
+        value are written (``ops/row_write.py``) and the slot's rows
+        attended."""
+        q, k, v, rest = self.project(x[:, None], p, pos[:, None])
+        at, counts = self.place(pos, cache["k"].shape[2])
+        with jax.named_scope(self.scope):
+            keys, values = write_rows(
+                (cache["k"], cache["v"]),
+                (k[:, 0].astype(cache["k"].dtype),
+                 v[:, 0].astype(cache["v"].dtype)), at, axis=1)
+            o = gqa.decode_attention(q[:, 0], keys, values, counts,
+                                     self.scale)
+        rest = jax.tree.map(lambda a: a[:, 0], rest)
+        return self.finish(o, rest, p), {"k": keys, "v": values}
+
+
+def decode_stats(blocks: dict, caches, pos, live) -> dict:
+    """A decode step's ``attn.*`` counters over the :class:`KVBlock`s among
+    ``blocks``: its live rows, their contexts, the part of them a window
+    keeps, and the cache rows ONE block of each kind reads under the
+    lowering that ran."""
+    seen = jnp.where(live, pos + 1, 0)
+    stats = {"attn.decode_rows": jnp.sum(live).astype(F32),
+             "attn.context_tokens": jnp.sum(seen).astype(F32),
+             "attn.window_tokens": jnp.zeros((), F32),
+             "attn.window_rows_read": jnp.zeros((), F32),
+             "attn.full_rows_read": jnp.zeros((), F32)}
+    kv = {n: b for n, b in blocks.items() if isinstance(b, KVBlock)}
+    for kind in ("window", "full"):
+        name = next((n for n, b in kv.items()
+                     if (b.window is None) == (kind == "full")), None)
+        if name is None:
+            continue
+        cache = caches[name]
+        stats[f"attn.{kind}_rows_read"] = (
+            gqa.rows_visited(cache["k"]) * jnp.any(live))
+        if kind == "window":
+            stats["attn.window_tokens"] = jnp.sum(jnp.minimum(
+                seen, cache["k"].shape[2])).astype(F32)
+    return stats

@@ -31,12 +31,13 @@ sees ``j <= i``.  Scores scaled by ``d^-1/2``, softmax in float32, each
 key/value head serves ``H / KV`` query heads; result ``((softmax . v) * g)
 W_o``.
 
-**Two kinds of cache in one slot** (``KVBlock``; ``models/driver.py`` says
-what a block is).  Both hold ``{"k", "v"}: (slots, KV, rows, d)`` — the
-token axis second to last, so that the chip tiles ``(rows, d)`` and the
-step's write is ``ops/row_write.py``'s kernel — and both are read by one
-decode core (``ops/gqa.py:decode_attention``) up to a per-slot count.  They
-differ in two lines:
+**Two kinds of cache in one slot** (``models/kv.py:KVBlock``, shared with
+``models/granite_hybrid.py``, under this family's projection;
+``models/driver.py`` says what a block is).  Both hold ``{"k", "v"}:
+(slots, KV, rows, d)`` — the token axis second to last, so that the chip
+tiles ``(rows, d)`` and the step's write is ``ops/row_write.py``'s kernel —
+and both are read by one decode core (``ops/gqa.py:decode_attention``) up
+to a per-slot count.  They differ in two lines:
 
 * a sliding block's cache is a RING of ``min(sliding_window, max_len)``
   rows: the token at position ``p`` lies in row ``p % rows`` and a slot at
@@ -68,7 +69,7 @@ import jax
 import jax.numpy as jnp
 
 from progen_tpu.core.precision import Policy
-from progen_tpu.models import driver, experts
+from progen_tpu.models import driver, experts, kv
 from progen_tpu.models.driver import (  # noqa: F401
     F32,
     bf16_policy,
@@ -78,7 +79,6 @@ from progen_tpu.models.driver import (  # noqa: F401
 )
 from progen_tpu.models.experts import expert_passes, held_experts
 from progen_tpu.ops import gqa
-from progen_tpu.ops.row_write import write_rows
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -260,82 +260,23 @@ def project(x, p, c: TrinityConfig, positions, rotary: bool):
     return q, k, v, gate
 
 
-class KVBlock:
-    """One attention block and what it states about its cache
-    (``models/driver.py`` says what a block is): ``window`` rows in a ring
-    for a sliding block, ``max_len`` rows that grow with the request for a
-    full one (``window`` None).  The module docstring has both layouts."""
+class KVBlock(kv.KVBlock):
+    """One of Trinity's attention blocks (``models/kv.py`` has the cache's
+    two layouts and the step): gated, q and k normed per head, rotated in a
+    sliding block (``window`` rows in a ring) and not in a full one
+    (``window`` None)."""
 
     def __init__(self, config: TrinityConfig, window: int | None):
+        super().__init__(config.num_key_value_heads, config.head_dim,
+                         1.0 / math.sqrt(config.head_dim), window)
         self.config = config
-        self.window = window
-        self.scope = "attn.full" if window is None else "attn.window"
-        self.scale = 1.0 / math.sqrt(config.head_dim)
 
-    def rows(self, max_len: int) -> int:
-        """Rows a slot's cache has in an engine of ``max_len``."""
-        return max_len if self.window is None else min(self.window, max_len)
+    def project(self, x, p, positions):
+        return project(x, p, self.config, positions,
+                       rotary=self.window is not None)
 
-    def place(self, pos, rows: int):
-        """``(the row the token at pos lies in, the rows a slot at pos
-        has)`` of a cache of ``rows`` rows."""
-        if self.window is None:
-            return pos, pos + 1
-        return pos % rows, jnp.minimum(pos + 1, rows)
-
-    def init_cache(self, slots: int, max_len: int, dtype):
-        c = self.config
-        shape = (slots, c.num_key_value_heads, self.rows(max_len),
-                 c.head_dim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-
-    def prefill(self, x, p, lengths):
-        """Attention over ``x (R, P, h)``; the per-token rows are ``{"k",
-        "v"}: (R, KV, P, d)``."""
-        r, n, _ = x.shape
-        positions = jnp.broadcast_to(jnp.arange(n), (r, n))
-        q, k, v, gate = project(x, p, self.config, positions,
-                                rotary=self.window is not None)
-        k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-        with jax.named_scope(self.scope):
-            o = gqa.prefill_attention(q, k, v, self.scale, self.window,
-                                      lengths)
-        return mm(o * gate, p["wo"]), {"k": k, "v": v}
-
-    def cache_rows(self, rows, lengths, max_len: int):
-        """The per-token rows of R primes as R slots' caches: a full block
-        keeps the first ``max_len`` tokens where they are; a ring takes,
-        for each of its rows ``j``, the LAST real token ``p < length`` with
-        ``p % rows == j`` (rows no token has reached hold anything: no
-        count reaches them)."""
-        n = rows["k"].shape[2]
-        size = self.rows(max_len)
-        if self.window is None:
-            return {name: a[:, :, :size] if n >= size else jnp.pad(
-                a, ((0, 0), (0, 0), (0, size - n), (0, 0)))
-                for name, a in rows.items()}
-        j = jnp.arange(size)[None, :]
-        last = lengths[:, None] - 1
-        at = jnp.clip(j + size * ((last - j) // size), 0, n - 1)
-        return {name: jnp.take_along_axis(a, at[:, None, :, None], axis=2)
-                for name, a in rows.items()}
-
-    def decode(self, x, pos, cache, p):
-        """One token a row: ``x (S, h)`` at ``pos (S,)``; the new key and
-        value are written (``ops/row_write.py``) and the slot's rows
-        attended."""
-        q, k, v, gate = project(x[:, None], p, self.config, pos[:, None],
-                                rotary=self.window is not None)
-        at, counts = self.place(pos, cache["k"].shape[2])
-        with jax.named_scope(self.scope):
-            keys, values = write_rows(
-                (cache["k"], cache["v"]),
-                (k[:, 0].astype(cache["k"].dtype),
-                 v[:, 0].astype(cache["v"].dtype)), at, axis=1)
-            o = gqa.decode_attention(q[:, 0], keys, values, counts,
-                                     self.scale)
-        return mm(o * gate[:, 0], p["wo"]), {"k": keys, "v": values}
-
+    def finish(self, o, gate, p):
+        return mm(o * gate, p["wo"])
 
 
 def blocks_of(c: TrinityConfig) -> dict:
@@ -343,34 +284,9 @@ def blocks_of(c: TrinityConfig) -> dict:
     return {f"l{i}": kinds[kind] for i, kind in enumerate(c.layer_types)}
 
 
-ATTN_STAT_KEYS = ("attn.decode_rows", "attn.context_tokens",
-                  "attn.window_tokens", "attn.window_rows_read",
-                  "attn.full_rows_read", "attn.prefill_pairs_allowed",
-                  "attn.prefill_pairs_visited")
-
-
-def attention_stats(blocks: dict, caches, pos, live) -> dict:
-    """A decode step's ``attn.*`` counters: its live rows, their contexts,
-    the part of them a window keeps, and the cache rows ONE block of each
-    kind reads under the lowering that ran."""
-    seen = jnp.where(live, pos + 1, 0)
-    stats = {"attn.decode_rows": jnp.sum(live).astype(F32),
-             "attn.context_tokens": jnp.sum(seen).astype(F32),
-             "attn.window_tokens": jnp.zeros((), F32),
-             "attn.window_rows_read": jnp.zeros((), F32),
-             "attn.full_rows_read": jnp.zeros((), F32)}
-    for kind in ("window", "full"):
-        name = next((n for n, b in blocks.items()
-                     if (b.window is None) == (kind == "full")), None)
-        if name is None:
-            continue
-        cache = caches[name]
-        stats[f"attn.{kind}_rows_read"] = (
-            gqa.rows_visited(cache["k"]) * jnp.any(live))
-        if kind == "window":
-            stats["attn.window_tokens"] = jnp.sum(jnp.minimum(
-                seen, cache["k"].shape[2])).astype(F32)
-    return stats
+ATTN_STAT_KEYS = kv.DECODE_STAT_KEYS + ("attn.prefill_pairs_allowed",
+                                        "attn.prefill_pairs_visited")
+attention_stats = kv.decode_stats
 
 
 def prefill_attention_stats(blocks: dict, n: int, lengths, dt) -> dict:

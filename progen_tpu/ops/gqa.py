@@ -19,7 +19,9 @@ from what the code can observe and never from a knob (as
   block of ``q`` and of the output is one head's columns of ``(R, P, H *
   d)``, so neither is ever transposed), ``P`` a multiple of ``MIN_TILE``
   (Trinity's prefill buckets are 512 * 2^k) and ``window`` None or a
-  multiple of the key tile.  One flash kernel for both kinds of block,
+  multiple of the key tile.  So Trinity's head width of 128 takes the
+  kernel, and Granite 4.0-H's 64 (``models/granite_hybrid.py``) the blocked
+  XLA form below, on a TPU too.  One flash kernel for both kinds of block,
   ``window`` a static parameter that changes which tiles are visited and
   nothing else.  Grid ``(R, H, P / bq, key steps)``, the key axis
   innermost and RELATIVE: step ``ki`` of query tile ``qi`` is key tile

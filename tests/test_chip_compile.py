@@ -437,3 +437,66 @@ def test_moe_decode_kernel_compiles_for_v5e(shape, no_persistent_cache,
         shape((held, inner, h), bf16)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * tokens * h * 4
+
+
+# ---- Granite 4.0-H's whole programs at published widths ----
+
+
+@pytest.fixture(scope="module")
+def granite_engine():
+    """The engine of ``serve-granite-chat-backlog`` over ABSTRACT weights
+    (its 32 slots' state is real, on the host: 3.1 GB of zeros)."""
+    from progen_tpu.decode.engine import ServingEngine
+    from progen_tpu.models import granite_hybrid as gh
+
+    c, policy = gh.GraniteHybridConfig(), gh.bf16_policy()
+    params = jax.eval_shape(lambda k: gh.init_params(c, k, policy),
+                            jax.random.key(0))
+    return ServingEngine(c, params, policy=policy, num_slots=32,
+                         chunk_size=32, max_len=2560)
+
+
+@pytest.mark.parametrize("program", ["chunk", "admit-1024"])
+def test_granite_programs_compile_for_the_chip_and_fit_it(
+        shape, granite_engine, program, no_persistent_cache, monkeypatch):
+    """All 40 layers, the whole vocabulary, 32 slots of carry, tail and
+    grown keys: the chunk program (32 steps of every slot, the carry a
+    float32 scan carry) and the admission of 2 rows at the 1024 bucket (4
+    chunks of the scan a row), as the chip traces them (the step's key
+    writes as ``ops/row_write.py``'s kernel).  Arguments, results and
+    temporaries together stay under the chip's 16 GiB: the engine's
+    programs do not donate their state, so it is there twice."""
+    from progen_tpu.ops import gqa, lowering, row_write
+
+    for module in (row_write, gqa):
+        monkeypatch.setattr(module, "_on_tpu", lambda: True)
+    monkeypatch.setattr(lowering, "on_tpu", lambda: True)
+    eng = granite_engine
+
+    def placed(tree):
+        return jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+
+    params, state = placed(eng._params), placed(eng.state)
+    s, rows, lay = eng.num_slots, eng.admit_rows, eng._layout
+    assert rows == 2
+    if program == "chunk":
+        # a fresh wrapper: ``jax.jit`` keeps a trace across the patch
+        compiled = jax.jit(lambda *a: eng._decode_chunk_impl(*a)).lower(
+            params, state, *placed(lay.chunk_operands())).compile()
+    else:
+        prefill = [shape((rows, 1024), jnp.int32), shape((rows,), jnp.int32),
+                   shape((rows,), jnp.int32), shape((rows,), jnp.uint32),
+                   shape((rows,), jnp.int32), shape((rows,), jnp.float32),
+                   shape(eng._lmask_shape(rows), jnp.bool_)]
+        compiled = jax.jit(lambda *a: eng._admit_impl(*a)).lower(
+            params, state, shape((s,), jnp.int32), shape((s,), jnp.bool_),
+            *prefill, *placed(lay.write_tables(rows))).compile()
+    m = compiled.memory_analysis()
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
+    assert 6.38e9 < weights < 6.39e9 and 3.1e9 < held < 3.2e9
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert weights + 2 * held <= total < 15.5e9, m
+    if program == "chunk":
+        assert "tpu_custom_call" in compiled.as_text()      # the key writes
