@@ -76,15 +76,71 @@ def gumbel_topk_sample(key, logits, top_k: int | None, temperature: float = 1.0,
     return jnp.argmax(logits + noise, axis=-1)
 
 
+def _kth_largest_by_counting(scaled, k):
+    """``(B, 1)``: each row's ``k``-th largest value, the element
+    ``jnp.sort(scaled, axis=-1)[v - k]`` of the row, found without ordering
+    the row.  ``scaled`` is float32 ``(B, V)`` (the bitcast below is to
+    uint32), ``k`` int32 ``(B,)`` in ``1..V``.
+
+    Each float32 becomes a uint32 key whose unsigned order is the sort's
+    order (``-inf`` lowest, finite values by value, ``+inf``, every NaN of
+    either sign highest).  The k-th largest key is then built bit by bit
+    from the top: a bit stays set when at least ``k`` keys of the row are
+    still at or above the candidate.  Thirty-two compare-and-count passes
+    over ``(B, V)``, each one fused reduction, whatever ``k`` is; the
+    largest ``t`` with ``count(key >= t) >= k`` is a key the row holds,
+    multiplicity counted as the sort counts it.
+    """
+    bits = jax.lax.bitcast_convert_type(scaled, jnp.uint32)
+    top = jnp.uint32(1 << 31)
+    key = jnp.where(bits >= top, ~bits, bits | top)
+    key = jnp.where(jnp.isnan(scaled), jnp.uint32(0xFFFFFFFF), key)
+
+    def keep_bit(i, t):
+        cand = t | (top >> i.astype(jnp.uint32))
+        at_or_above = jnp.sum(key >= cand[:, None], axis=-1, dtype=jnp.int32)
+        return jnp.where(at_or_above >= k, cand, t)
+
+    t = jax.lax.fori_loop(0, 32, keep_bit, jnp.zeros(k.shape, jnp.uint32))
+    bits = jnp.where(t >= top, t ^ top, ~t)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)[:, None]
+
+
 def gumbel_topk_sample_batched(keys, logits, top_k, temperature, mask=None):
     """Per-row sampling for the serving engine: each row has its own key,
     top-k and temperature.
 
     ``keys``: ``(B,)`` typed PRNG keys; ``logits``: ``(B, V)``; ``top_k``:
     ``(B,)`` int32, ``0`` disables top-k for that row; ``temperature``:
-    ``(B,)`` f32, ``0.0`` means greedy for that row.  Dynamic per-row k
-    uses a full sort instead of ``lax.top_k`` (whose k is static) — V is
-    small (vocab 256) so the sort is noise next to the model step.
+    ``(B,)`` f32, ``0.0`` means greedy for that row.
+
+    ``top_k`` is an array, so ``lax.top_k`` (whose k is static) cannot cut
+    the rows.  What the cut needs of a row is ONE number, its k-th largest
+    scaled logit.  Sorting every row to read one element of it was, on a
+    sliced or whole chat vocabulary (16,384 to 100,352 columns), the largest
+    single operation of a decode step after the model's own, so the number
+    is found by counting (``_kth_largest_by_counting``), at every width:
+    on ProGen's 256 columns the 32 rounds cost a few microseconds more than
+    the tiny sort did and no cell can tell (PERF.md §6, PR 39).  The value
+    is the one a full ascending sort of the row hands out at ``[v - k]``,
+    so the mask, and under the same keys the tokens, are that form's:
+
+    * ties at the k-th value all survive the cut (``>=``), so a row may
+      keep more than ``k`` entries, exactly as many as under the sort;
+    * ``-inf`` entries (the ``mask``, or a row's own) are ordinary lowest
+      values; where more than ``V - k`` of a row are ``-inf`` the k-th is
+      ``-inf``, the cut keeps everything and ``-inf`` still loses every
+      argmax;
+    * ``-0.0`` and ``+0.0`` compare equal in the cut whichever of the two
+      is handed out as the k-th (a sort hands out the one its stable order
+      left there, the counting ``-0.0`` unless ``k`` falls among the
+      ``+0.0`` s: the only case in which the two values differ in a bit);
+    * a NaN logit ranks above ``+inf``, as in ``jnp.sort``, whatever its
+      sign bit.  With fewer than ``k`` NaNs in a row they take places among
+      the ``k`` but fail the ``>=`` and are cut; with ``k`` or more the
+      k-th is NaN, every comparison fails, the whole row is cut and the
+      draw returns token 0.  A row's NaNs are the model's fault and are not
+      repaired here.
 
     ``mask`` (optional ``(B, V)`` bool): per-row allowed-token constraint,
     applied before the greedy argmax so greedy rows respect it too.  A
@@ -99,8 +155,7 @@ def gumbel_topk_sample_batched(keys, logits, top_k, temperature, mask=None):
     greedy = jnp.argmax(logits, axis=-1)
     scaled = logits / jnp.maximum(temperature, 1e-8)[:, None]
     k_eff = jnp.where(top_k > 0, jnp.clip(top_k, 1, v), v)
-    srt = jnp.sort(scaled, axis=-1)  # ascending
-    kth = jnp.take_along_axis(srt, (v - k_eff)[:, None], axis=-1)
+    kth = _kth_largest_by_counting(scaled, k_eff)
     masked = apply_logit_mask(scaled, scaled >= kth)
     noise = jax.vmap(
         lambda k: jax.random.gumbel(k, (v,), jnp.float32))(keys)

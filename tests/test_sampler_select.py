@@ -1,0 +1,187 @@
+"""The batched sampler's k-th largest logit found by counting against the
+plain form, a full sort of every row, written here as the reference: the
+threshold is the sort's own element, the cut and, under the same keys, the
+tokens are the same — over widths from 16 columns to a whole chat
+vocabulary, per-row k (off, 1, 25, v - 1, v, past v), ties at the k-th
+value, rows mostly ``-inf``, signed zeros, bfloat16 logits, greedy and
+near-greedy rows beside sampled ones, and NaNs of either sign.  There is one
+form whatever the width, and no sort in the traced program."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from progen_tpu.decode import Request, ServingEngine, sampler
+from progen_tpu.decode.engine import SLOTS_PER_ADMIT_ROW
+from progen_tpu.decode.sampler import (
+    apply_logit_mask,
+    gumbel_topk_sample_batched,
+)
+from tests.granite_tiny import TINY, make
+
+ROWS = 12
+WIDTHS = (16, 256, 1_023, 4_096, 16_384, 25_024, 100_352)
+CASES = ("own_k", "ties", "mostly_masked", "signed_zeros", "bfloat16",
+         "temperatures", "nans")
+
+
+def reference_kth(scaled, k_eff):
+    v = scaled.shape[-1]
+    srt = jnp.sort(scaled, axis=-1)  # ascending
+    return jnp.take_along_axis(srt, (v - k_eff)[:, None], axis=-1)
+
+
+def reference_draw(keys, logits, top_k, temperature, mask=None):
+    """``gumbel_topk_sample_batched`` as it stood while it sorted every
+    row whatever its width."""
+    logits = logits.astype(jnp.float32)
+    if mask is not None:
+        logits = apply_logit_mask(logits, mask)
+    v = logits.shape[-1]
+    greedy = jnp.argmax(logits, axis=-1)
+    scaled = logits / jnp.maximum(temperature, 1e-8)[:, None]
+    k_eff = jnp.where(top_k > 0, jnp.clip(top_k, 1, v), v)
+    kth = reference_kth(scaled, k_eff)
+    masked = apply_logit_mask(scaled, scaled >= kth)
+    noise = jax.vmap(
+        lambda k: jax.random.gumbel(k, (v,), jnp.float32))(keys)
+    sampled = jnp.argmax(masked + noise, axis=-1)
+    return jnp.where(temperature == 0.0, greedy, sampled)
+
+
+def _inputs(case, v):
+    """logits, per-row k, temperature and mask of one case: every row its
+    own k in every case."""
+    rng = np.random.default_rng(v * 31 + CASES.index(case))
+    ks = np.resize([0, 1, 25, v - 1, v, v + 7], ROWS).astype(np.int32)
+    logits = rng.normal(size=(ROWS, v)).astype(np.float32)
+    temp = np.ones((ROWS,), np.float32)
+    mask = None
+    if case == "ties":
+        logits = np.round(logits, 1)
+    elif case == "mostly_masked":
+        # more than v - k entries masked for the rows of k = 25, v - 1, v:
+        # their k-th value is -inf and the cut keeps the whole row
+        mask = rng.random((ROWS, v)) < min(0.5, 12 / v)
+        mask[:, 3] = True
+    elif case == "signed_zeros":
+        logits = rng.choice(
+            np.array([-1.0, -0.0, 0.0, 1.0], np.float32), size=(ROWS, v),
+            p=[0.05, 0.45, 0.45, 0.05])
+    elif case == "bfloat16":
+        logits = jnp.asarray(logits * 4, jnp.bfloat16)
+    elif case == "temperatures":
+        temp = np.resize([0.0, 1e-6, 1.0, 0.7], ROWS).astype(np.float32)
+    elif case == "nans":
+        bits = logits.view(np.uint32)
+        bits[:, 1] = 0x7FC00000
+        bits[:, 2] = 0xFFC00000               # a NaN with its sign bit set
+        bits[ROWS // 2:, 4::3] = 0xFFC00001   # rows that are a third NaN
+    return (jnp.asarray(logits), jnp.asarray(ks), jnp.asarray(temp),
+            None if mask is None else jnp.asarray(mask))
+
+
+@pytest.mark.parametrize("v", WIDTHS)
+@pytest.mark.parametrize("case", CASES)
+def test_selection_is_the_sorts_element_and_draws_its_tokens(case, v):
+    logits, top_k, temp, mask = _inputs(case, v)
+    keys = jax.vmap(jax.random.key)(jnp.arange(ROWS, dtype=jnp.uint32) + v)
+
+    x = logits.astype(jnp.float32)
+    if mask is not None:
+        x = apply_logit_mask(x, mask)
+    scaled = x / jnp.maximum(temp, 1e-8)[:, None]
+    k_eff = jnp.where(top_k > 0, jnp.clip(top_k, 1, v), v)
+    want = np.asarray(jax.jit(reference_kth)(scaled, k_eff))
+    got = np.asarray(jax.jit(sampler._kth_largest_by_counting)(scaled, k_eff))
+    assert got.shape == want.shape == (ROWS, 1)
+    # bit for bit, but for which of two equal zeros is handed out and for
+    # a NaN's payload: neither reaches the cut
+    exact = ~np.isnan(want) & (want != 0)
+    np.testing.assert_array_equal(got.view(np.uint32)[exact],
+                                  want.view(np.uint32)[exact])
+    np.testing.assert_array_equal(got, want)    # NaN where NaN, 0 where 0
+    np.testing.assert_array_equal(np.asarray(scaled) >= got,
+                                  np.asarray(scaled) >= want)
+    if case == "mostly_masked":
+        assert np.isneginf(want[np.asarray(top_k) >= 25]).all()
+    if case == "nans":
+        # k or more NaNs in a row: the k-th is NaN and the whole row is cut
+        n_nan = np.isnan(np.asarray(scaled)).sum(axis=-1)
+        np.testing.assert_array_equal(np.isnan(want[:, 0]),
+                                      n_nan >= np.asarray(k_eff))
+        assert np.isnan(want).any() and not np.isnan(want).all()
+
+    drawn = np.asarray(jax.jit(gumbel_topk_sample_batched)(
+        keys, logits, top_k, temp, mask))
+    plain = np.asarray(jax.jit(reference_draw)(keys, logits, top_k, temp, mask))
+    np.testing.assert_array_equal(drawn, plain)
+    if mask is not None:
+        assert np.asarray(mask)[np.arange(ROWS), drawn].all()
+    if case == "temperatures":
+        np.testing.assert_array_equal(
+            drawn[:2], np.argmax(np.asarray(logits), axis=-1)[:2])
+
+
+@pytest.mark.parametrize("v", (256, 16_384, 25_024, 100_352))
+def test_no_width_traces_a_sort(v):
+    """The cells' vocabularies, ProGen's 256 columns among them: the traced
+    draw holds the 32 counting rounds and orders nothing."""
+    args = (jax.vmap(jax.random.key)(jnp.arange(4, dtype=jnp.uint32)),
+            jax.ShapeDtypeStruct((4, v), jnp.float32),
+            jax.ShapeDtypeStruct((4,), jnp.int32),
+            jax.ShapeDtypeStruct((4,), jnp.float32),
+            jax.ShapeDtypeStruct((4, v), jnp.bool_))
+    text = str(jax.make_jaxpr(gumbel_topk_sample_batched)(*args))
+    assert "sort" not in text and "scan" in text
+    assert "sort" in str(jax.make_jaxpr(reference_draw)(*args))
+
+
+def _serve(config, params, policy):
+    engine = ServingEngine(config, params, policy=policy,
+                           num_slots=SLOTS_PER_ADMIT_ROW, chunk_size=4,
+                           max_len=32)
+    never_zero = np.ones((config.vocab_size,), bool)
+    never_zero[0] = False
+    rng = np.random.default_rng(39)
+    for i, (k, temp) in enumerate(
+            [(25, 1.0), (1, 1.0), (None, 0.8), (config.vocab_size, 1.3),
+             (7, 0.0), (25, 1.0)]):
+        engine.submit(Request(
+            uid=i, max_new_tokens=6 + i % 3, seed=390 + i, temperature=temp,
+            top_k=k, logit_mask=never_zero,
+            tokens=rng.integers(1, config.vocab_size, 3 + 4 * i).tolist()))
+    done = engine.run_until_idle(200)
+    return {c.uid: list(c.tokens) for c in done}
+
+
+@pytest.mark.serving
+@pytest.mark.parametrize("vocab", (TINY.vocab_size, 1_088))
+def test_engine_serves_the_sort_forms_tokens(monkeypatch, vocab):
+    """Through ``ServingEngine``, at a narrow vocabulary and a wide one: the
+    tokens of the first draw (admission) and of every chunk step are those
+    it serves with the sort patched in."""
+    config = dataclasses.replace(TINY, vocab_size=vocab)
+    params, policy = make(config)
+    calls = []
+
+    def counted(form):
+        def kth(scaled, k):
+            calls.append(form.__name__)
+            return form(scaled, k)
+        return kth
+
+    monkeypatch.setattr(sampler, "_kth_largest_by_counting",
+                        counted(sampler._kth_largest_by_counting))
+    selected = _serve(config, params, policy)
+    assert calls and set(calls) == {"_kth_largest_by_counting"}
+    del calls[:]
+    monkeypatch.setattr(sampler, "_kth_largest_by_counting",
+                        counted(reference_kth))
+    sorted_ = _serve(config, params, policy)
+    assert calls and set(calls) == {"reference_kth"}
+    assert len(selected) == 6 and selected == sorted_
+    assert all(len(t) >= 6 for t in selected.values())
