@@ -24,6 +24,18 @@ style, PAPERS.md), with all device programs compiled once:
   then cached), each prefilled in ONE forward and gathered into the
   free slots while live slots' state rides through untouched.
 
+A family that GENERATES BY DIFFUSION OVER BLOCKS (it states a
+``block_length``, ``decode/family.py``) gets another chunk body
+(``_block_chunk_impl``) and another prefill half of admission
+(``_block_prefill_impl``), chosen from that statement and from nothing
+else: a scan step is one forward of ``B`` positions a slot, a block of
+mask tokens is denoised a few positions a forward under the family's
+remasking rule and then committed — written to the cache, entered into
+``seq`` —, ``pos``, ``done``, ``stop``, end of sequence and the harvest
+move by committed blocks, and admission hands over a prime whose last ``P
+mod B`` tokens open the first block instead of a sampled first token
+(docs/SERVING.md, "Generation by blocks").
+
 Three device programs serve every mode: the decode chunk, its
 speculative variant and admission (= prefill ∘ merge, the two halves
 disaggregated serving runs apart).  Where a slot's cache rows live —
@@ -107,8 +119,11 @@ from progen_tpu.decode.prefill import (
     mesh_trace_ctx,
 )
 from progen_tpu.decode.sampler import (
+    confident_positions,
     gumbel_topk_sample_batched,
+    gumbel_topk_sample_with_confidence,
     split_keys_batched,
+    transfer_counts,
 )
 from progen_tpu.decode.spec import check_draft_config, spec_round
 from progen_tpu.models.progen import ProGen, ProGenConfig
@@ -176,6 +191,16 @@ class _RunningMean:
 
 
 SLOTS_PER_ADMIT_ROW = 16
+
+# what a slot of a family that generates by blocks holds beside the rest
+# (``_block_chunk_impl``): where the block in progress starts, its tokens,
+# the denoise forwards it has had, and for each position of the row the
+# denoise forward that filled it (-1: a prime token, or not filled yet)
+_BLOCK_STATE = ("cursor", "block", "dstep", "fill")
+# the block step's counters, summed on the device beside the family's
+_DIFFUSION_STATS = ("diffusion.forwards", "diffusion.commit_forwards",
+                    "diffusion.tokens_committed", "diffusion.tokens_dropped",
+                    "diffusion.positions_kept")
 
 
 @jax.jit
@@ -275,6 +300,10 @@ class Request:
     # the engine's submit()/submit_embed() methods directly
     workload: str = "generate"
     priority: int = 0
+    # of a family that generates by blocks: report, for each generated
+    # token, the denoise forward of its block that filled it
+    # (``Completion.fill_steps``)
+    record_fill_steps: bool = False
 
 
 @dataclasses.dataclass
@@ -316,6 +345,10 @@ class Completion:
     # round that took it; the earliest across evict/replay) — None for a
     # request shed before any admission
     admit_time: float | None = None
+    # ``(len(tokens),)`` small integers where the request asked
+    # (``Request.record_fill_steps``): the denoise forward of its block, 0
+    # the first, at which each token was kept
+    fill_steps: np.ndarray | None = None
 
     @property
     def latency(self) -> float:
@@ -444,6 +477,13 @@ class ServingEngine:
         self.admit_rows = max(1, num_slots // SLOTS_PER_ADMIT_ROW)
         self.chunk_size = chunk_size
         self.max_len = min(max_len or config.seq_len, config.seq_len)
+        # how the family generates: None a token a row a step, else the
+        # length of the blocks it denoises and commits (decode/family.py)
+        self.block_length = self.family.block_length
+        if self.block_length and self.max_len % self.block_length:
+            raise ValueError(
+                f"max_len {self.max_len} is not a whole number of the "
+                f"{self.family.name} family's blocks of {self.block_length}")
         self.mesh = mesh
         self.strategies = tuple(strategies)
         # priority / weighted-fair / EDF scheduling queue — with default
@@ -648,9 +688,7 @@ class ServingEngine:
         # the same per program ("chunk", "admit"): the experts' product is
         # in both and takes another lowering in each
         self.program_lowerings: dict[str, dict[str, str]] = {}
-        self._decode_chunk = self._jit_noting(
-            self._decode_chunk_spec_impl if spec else self._decode_chunk_impl,
-            "chunk")
+        self._decode_chunk = self._jit_noting(self._chunk_impl(), "chunk")
         self._admit = self._jit_noting(self._admit_impl, "admit")
         self.model_stats: dict = {}     # the family's counters as last fetched
         if remote_prefill and not disagg:
@@ -700,6 +738,14 @@ class ServingEngine:
             "lmask": jnp.ones(self._lmask_shape(s), bool),
         }
         stats = self.family.init_stats()
+        if self.block_length:
+            b = self.block_length
+            state.update(
+                cursor=jnp.zeros((s,), jnp.int32),
+                block=jnp.full((s, b), self.family.mask_token_id, jnp.int32),
+                dstep=jnp.zeros((s,), jnp.int32),
+                fill=jnp.full((s, L), -1, jnp.int8))
+            stats = {**stats, **self._diffusion_zeros()}
         if stats:
             # the family's device-side counters: summed by its programs,
             # read with the flags fetch the harvest makes anyway
@@ -712,6 +758,14 @@ class ServingEngine:
             state["draft_caches"] = init_caches(
                 self.draft_config, s, self.policy, decode_len=L)
         return state
+
+    def _diffusion_zeros(self) -> dict:
+        """The block step's counters at zero: scalars, and the positions
+        kept by denoise forward."""
+        zeros = {k: jnp.zeros((), jnp.float32) for k in _DIFFUSION_STATS}
+        zeros["diffusion.positions_kept"] = jnp.zeros(
+            (self.family.denoising_steps,), jnp.float32)
+        return zeros
 
     def _lmask_shape(self, rows: int) -> tuple:
         if self.family.position_masks:
@@ -885,9 +939,7 @@ class ServingEngine:
         self.robust.fallback_activations += 1
         self.paged_impl = "xla"
         self._layout.use_impl("xla")
-        self._decode_chunk = self._jit_noting(
-            self._decode_chunk_spec_impl if self.spec
-            else self._decode_chunk_impl, "chunk")
+        self._decode_chunk = self._jit_noting(self._chunk_impl(), "chunk")
         self._aot.pop(("chunk",), None)
         self._compiled_keys.discard(("chunk",))
         print("serving: pallas paged kernel failed; degraded to the "
@@ -904,9 +956,10 @@ class ServingEngine:
         ``+`` where the shapes split them), a latent attention's prefill
         and absorbed decode cores (``"mla_prefill"``, ``"mla_decode"``:
         ``"pallas"`` / ``"xla"``), a grouped-query attention's prefill core
-        (``"gqa_prefill"``, the same two) and the held experts' product
-        (``"moe_experts"``, the same two, stated per program: it is in
-        both)."""
+        (``"gqa_prefill"``, the same two), the core of a step of B queries
+        a slot (``"gqa_block_decode"``: ``"xla"``) and the held experts'
+        product (``"moe_experts"``, the same two, stated per program: it is
+        in both)."""
 
         @wraps(impl)
         def traced(*args):
@@ -919,6 +972,15 @@ class ServingEngine:
             return out
 
         return jax.jit(traced)
+
+    def _chunk_impl(self):
+        """The chunk program's body, chosen from the mode and from what the
+        family states about how it generates."""
+        if self.spec:
+            return self._decode_chunk_spec_impl
+        if self.block_length:
+            return self._block_chunk_impl
+        return self._decode_chunk_impl
 
     def _decode_chunk_impl(self, params, state, *operands):
         """``chunk_size`` single-token steps of every slot.  ``operands``
@@ -972,6 +1034,118 @@ class ServingEngine:
                                     length=self.chunk_size)
         return state
 
+    def _block_chunk_impl(self, params, state, *operands):
+        """``chunk_size`` forwards of every slot of a family that generates
+        by diffusion over blocks (``decode/family.py``).  A slot holds the
+        block in progress at ``cursor .. cursor + B - 1`` — tokens, and the
+        mask token where none is kept yet — and every scan step is ONE
+        forward of all ``S x B`` positions, whatever phase a row is in:
+
+        * a row whose block still has a mask takes a DENOISE forward: a draw
+          at every position from that position's own logits (the request's
+          top-k, temperature and logit mask; the mask token is never
+          allowed) with the drawn token's confidence, and the masked
+          positions the family's remasking rule picks keep their draw.  No
+          key is stored;
+        * a row whose block has none takes the COMMIT forward: the same
+          forward over the final tokens, its keys and values written at the
+          block's rows; the block enters ``seq``, the cursor moves on and a
+          block of masks opens.  ``pos`` — the newest token that counts —
+          moves to the block's end, or to the first end-of-sequence token in
+          it, or to ``stop - 1``: tokens after either are dropped and the
+          row is done.
+
+        A row that is not live runs fully masked and keeps its state.  A
+        slot's key advances on its own live forwards only (each forward's
+        B draws come from one split of it), so a request's tokens depend on
+        neither its neighbours nor the step it was admitted at."""
+        lay, fam = self._layout, self.family
+        b, steps = fam.block_length, fam.denoising_steps
+        mask_id = fam.mask_token_id
+        per_step = jnp.asarray(transfer_counts(b, steps), jnp.int32)
+        threshold = (fam.confidence_threshold
+                     if fam.remasking == "low_confidence_dynamic" else None)
+        s, at = self.num_slots, jnp.arange(b)
+        col = jnp.arange(self.max_len)[None, :]
+        f32 = jnp.float32
+
+        def spread(block, p0):
+            """``block (S, B)`` laid along the row at ``p0 ..``: ``(the
+            values (S, L), where they lie (S, L))``."""
+            off = col - p0[:, None]
+            # B selects and no gather, which the chip runs row by row
+            values = sum(jnp.where(off == j, block[:, j, None], 0)
+                         for j in range(b))
+            return values, (off >= 0) & (off < b)
+
+        with self._trace_ctx():
+            def body(st, _):
+                live = lay.live(st, operands)
+                blk, p0, dstep = st["block"], st["cursor"], st["dstep"]
+                masked = blk == mask_id
+                commit = live & ~jnp.any(masked, axis=1)
+                denoise = live & ~commit
+                logits, caches, stats = fam.block_step(
+                    self._target_params(params), blk, p0, st["caches"],
+                    live, commit)
+                kd, sub = split_keys_batched(st["keys"])
+                keys = jax.vmap(lambda k: jax.random.split(k, b))(
+                    sub).reshape(s * b)
+                drawn, conf = gumbel_topk_sample_with_confidence(
+                    keys, logits.reshape(s * b, -1),
+                    jnp.repeat(st["top_k"], b), jnp.repeat(st["temp"], b),
+                    mask=jnp.repeat(st["lmask"], b, axis=0))
+                take = denoise[:, None] & confident_positions(
+                    conf.reshape(s, b), masked,
+                    per_step[jnp.clip(dstep, 0, steps - 1)], threshold)
+                filled = jnp.where(take, drawn.reshape(s, b).astype(
+                    jnp.int32), blk)
+
+                # the commit: which of the block's tokens count
+                where = p0[:, None] + at
+                generated = where >= st["start"][:, None]
+                eos = (generated & (where < st["stop"][:, None])
+                       & (blk == EOS_ID))
+                ended = jnp.any(eos, axis=1)
+                last = jnp.where(ended, p0 + jnp.argmax(eos, axis=1),
+                                 jnp.minimum(p0 + b, st["stop"]) - 1)
+                counted = jnp.where(commit, last - st["pos"], 0)
+                values, here = spread(blk, p0)
+                seq = jnp.where(here & commit[:, None], values, st["seq"])
+                steps_at, _ = spread(jnp.where(take, dstep[:, None], -1), p0)
+                fill = jnp.where(here & (steps_at >= 0),
+                                 steps_at.astype(jnp.int8), st["fill"])
+                out = {
+                    **st, "seq": seq, "caches": caches, "fill": fill,
+                    "pos": jnp.where(commit, last, st["pos"]),
+                    "done": st["done"] | (commit & (
+                        ended | (p0 + b >= st["stop"]))),
+                    "cursor": jnp.where(commit, p0 + b, p0),
+                    "block": jnp.where(commit[:, None], mask_id, filled),
+                    "dstep": jnp.where(commit, 0, dstep + denoise),
+                    "keys": jnp.where(live[:, None], kd, st["keys"]),
+                }
+                kept = jnp.sum(take, axis=1)
+                stats = {
+                    **stats,
+                    "diffusion.forwards": jnp.sum(live).astype(f32),
+                    "diffusion.commit_forwards": jnp.sum(commit).astype(f32),
+                    "diffusion.tokens_committed": jnp.sum(counted).astype(
+                        f32),
+                    "diffusion.tokens_dropped": jnp.sum(jnp.where(
+                        commit, jnp.sum(generated, axis=1) - counted,
+                        0)).astype(f32),
+                    "diffusion.positions_kept": jnp.sum(jnp.where(
+                        dstep[:, None] == jnp.arange(steps)[None, :],
+                        kept[:, None], 0), axis=0).astype(f32),
+                }
+                out["stats"] = jax.tree.map(jnp.add, st["stats"], stats)
+                return out, None
+
+            state, _ = jax.lax.scan(body, state, None,
+                                    length=self.chunk_size)
+        return state
+
     def _decode_chunk_spec_impl(self, params, state, *operands):
         """The chunk under speculative decoding: ``_spec_rounds``
         propose/verify/commit rounds (``decode/spec.py``) instead of
@@ -1019,7 +1193,9 @@ class ServingEngine:
         separate programs; unused handle rows carry a dummy one-token
         prime and land nowhere."""
         n = len(args) - self._layout.merge_operands
-        handle = self._prefill_worker_impl(params, *args[:n])
+        prefill = (self._block_prefill_impl if self.block_length
+                   else self._prefill_worker_impl)
+        handle = prefill(params, *args[:n])
         return self._merge_impl(state, *self._layout.split_handle(handle),
                                 src, mask, *args[n:])
 
@@ -1060,13 +1236,8 @@ class ServingEngine:
             split[:, 1], last, top_k, temp,
             mask=first_mrow).astype(jnp.int32)
 
-        L = self.max_len
-        rows, p_pad = tokens.shape
-        # p_pad is window-aligned and may overshoot L; real tokens never do
-        # (submit enforces prime + 1 <= max_len), so truncation drops pad only
-        tok_L = tokens[:, :L] if p_pad >= L else jnp.pad(
-            tokens, ((0, 0), (0, L - p_pad)))
-        seq = tok_L * (jnp.arange(L)[None, :] < lengths[:, None])
+        rows = tokens.shape[0]
+        seq = self._prime_rows(tokens, lengths)
         seq = seq.at[jnp.arange(rows), lengths].set(first)
         out = {
             "seq": seq,
@@ -1087,6 +1258,47 @@ class ServingEngine:
         if stats:
             out["stats"] = stats
         return out
+
+    def _prime_rows(self, tokens, lengths):
+        """``tokens (rows, P_pad)`` as rows of the slots' ``seq``: ``max_len``
+        wide, zero past each row's length.  ``P_pad`` is bucket-aligned and
+        may overshoot ``max_len``; real tokens never do (submit enforces
+        prime + 1 <= max_len), so truncation drops padding only."""
+        L, p_pad = self.max_len, tokens.shape[1]
+        tok_L = tokens[:, :L] if p_pad >= L else jnp.pad(
+            tokens, ((0, 0), (0, L - p_pad)))
+        return tok_L * (jnp.arange(L)[None, :] < lengths[:, None])
+
+    def _block_prefill_impl(self, params, tokens, lengths, stops, seeds,
+                            top_k, temp, lmask):
+        """``_prefill_worker_impl`` for a family that generates by blocks:
+        the prime's whole blocks are prefilled and cached, its last ``P mod
+        B`` tokens open the block in progress beside mask tokens, and NO
+        token is drawn here — the row's key starts at its seed and ``pos``
+        on the prime's last token."""
+        fam, b = self.family, self.block_length
+        with self._trace_ctx():
+            _, caches, stats = fam.prefill(
+                self._target_params(params), tokens, lengths, self.max_len)
+        L, rows = self.max_len, tokens.shape[0]
+        seq = self._prime_rows(tokens, lengths)
+        whole = lengths // b * b
+        where = whole[:, None] + jnp.arange(b)
+        block = jnp.where(
+            where < lengths[:, None],
+            jnp.take_along_axis(seq, jnp.minimum(where, L - 1), axis=1),
+            fam.mask_token_id)
+        keys = jax.vmap(jax.random.key)(seeds.astype(jnp.uint32))
+        return {
+            "seq": seq, "caches": caches, "pos": lengths - 1,
+            "start": lengths, "stop": stops,
+            "done": jnp.zeros((rows,), bool),
+            "keys": jax.random.key_data(keys), "top_k": top_k, "temp": temp,
+            "lmask": lmask, "cursor": whole, "block": block,
+            "dstep": jnp.zeros((rows,), jnp.int32),
+            "fill": jnp.full((rows, L), -1, jnp.int8),
+            "stats": {**stats, **self._diffusion_zeros()},
+        }
 
     def _merge_impl(self, state, hstate, gate_rows, src, mask, *extra):
         """The merge half of admission: gather handle rows (as many as the
@@ -1121,6 +1333,8 @@ class ServingEngine:
             "temp": take(hstate["temp"], state["temp"]),
             "lmask": take(hstate["lmask"], state["lmask"]),
         }
+        if self.block_length:
+            out.update({k: take(hstate[k], state[k]) for k in _BLOCK_STATE})
         if self.lora:
             out["tenant"] = take(hstate["tenant"], state["tenant"])
         if self.spec:
@@ -1679,6 +1893,8 @@ class ServingEngine:
                     lmask[idx, p: p + r.max_new_tokens] = m
                 else:
                     lmask[idx, p: p + m.shape[0]] = m
+        if self.block_length:       # the mask token is never a draw
+            lmask[..., self.family.mask_token_id] = False
         return lmask
 
     def _prefill_args(self, n_rows: int, rows: list, p_pad: int) -> tuple:
@@ -1983,6 +2199,10 @@ class ServingEngine:
         with self._span("serve.harvest") as harvest:
             seq, pos, start = _host_fetch(
                 (self.state["seq"], self.state["pos"], self.state["start"]))
+            fill = (_host_fetch(self.state["fill"])
+                    if self.block_length and any(
+                        self._inflight[i].record_fill_steps for i in ready)
+                    else None)
             out = []
             now = time.perf_counter()
             for i in ready:
@@ -1998,6 +2218,8 @@ class ServingEngine:
                     generation=self.generation,
                     first_token_time=self._ttft.pop(r.uid, None),
                     admit_time=self._admitted.pop(r.uid, None))
+                if r.record_fill_steps and fill is not None:
+                    comp.fill_steps = fill[i, start[i]: pos[i] + 1].copy()
                 out.append(comp)
                 if r.on_complete is not None:
                     r.on_complete(comp)
@@ -2011,7 +2233,14 @@ class ServingEngine:
         gauges (cumulative since the engine was built)."""
         self.model_stats = stats
         registry = _metrics.get_registry()
-        for name, value in self.family.publish(stats).items():
+        own = {k: stats[k] for k in _DIFFUSION_STATS if k in stats}
+        kept = own.pop("diffusion.positions_kept", ())
+        gauges = {**{k: float(v) for k, v in own.items()},
+                  **{f"diffusion.positions_kept.{i}": float(v)
+                     for i, v in enumerate(kept)},
+                  **self.family.publish({k: v for k, v in stats.items()
+                                         if k not in _DIFFUSION_STATS})}
+        for name, value in gauges.items():
             registry.gauge(name).set(value)
 
     def _dispatch_chunk(self) -> None:
@@ -2353,6 +2582,8 @@ class ServingEngine:
             entry["tenant"] = int(r.tenant)
         if int(r.priority) != 0:
             entry["priority"] = int(r.priority)
+        if r.record_fill_steps:
+            entry["record_fill_steps"] = True
         deadline = self._deadline_of(r)
         if deadline is not None:
             # perf_counter instants do not survive a process restart;
@@ -2393,7 +2624,8 @@ class ServingEngine:
                 temperature=e["temperature"], seed=e["seed"],
                 on_complete=on_complete, submit_time=now,
                 logit_mask=lmask, tenant=int(e.get("tenant", 0)),
-                priority=int(e.get("priority", 0)))
+                priority=int(e.get("priority", 0)),
+                record_fill_steps=bool(e.get("record_fill_steps", False)))
             if "deadline_remaining" in e:
                 r.deadline = now + e["deadline_remaining"]
             if e.get("workload") == "embed":
@@ -2587,6 +2819,9 @@ class ServingEngine:
             "mla_prefill": self.lowerings.get("mla_prefill"),
             "mla_decode": self.lowerings.get("mla_decode"),
             "gqa_prefill": self.lowerings.get("gqa_prefill"),
+            # the core of a step of B queries a slot (a family that
+            # generates by blocks)
+            "gqa_block_decode": self.lowerings.get("gqa_block_decode"),
             # the held experts' product, by program: {"chunk": ..,
             # "admit": ..} as far as traced, None for a family without
             "moe_experts": {

@@ -24,6 +24,29 @@ engine tests nothing else (no family's name, no config's type):
 ``embedder(mesh, strategies)``
     the embedding program, or None for a family without one
 
+**How it generates**, which the engine reads to choose the chunk program it
+builds and nothing else of it:
+
+``block_length``
+    ``None``: one token a row a step — ``decode_step`` below, the engine's
+    scan of ``chunk_size`` single-token steps, a first token drawn at
+    admission.  A number ``B``: GENERATION BY DIFFUSION OVER BLOCKS — a
+    step is one forward of B positions a row (``block_step``), a block of
+    mask tokens is denoised a few positions a forward and then committed
+    to the cache, and admission hands over a prime whose last ``P mod B``
+    tokens open the first block.  Such a family also states
+    ``mask_token_id`` (never drawn, never a committed token),
+    ``denoising_steps`` (T: a block is filled in at most T denoise
+    forwards), ``remasking`` (``low_confidence_static``: the ``B / T``
+    masked positions of highest confidence take their draw each forward;
+    ``low_confidence_dynamic``: every masked position whose confidence is
+    over ``confidence_threshold`` does, and at least the static count) and
+``block_step(params, tok (S, B), pos0 (S,), caches, live (S,), commit (S,))``
+    ``(logits (S, B, V), caches, stats)``: the B tokens of each row (mask
+    tokens among them) at ``pos0 .. pos0 + B - 1`` over the row's committed
+    cache and each other; the row's keys enter the cache only where
+    ``commit``.  The logits at a position predict that position's own token
+
 ``init_caches(slots, max_len)``
     the decode caches of ``slots`` rows, a pytree whose every leaf has the
     slot as its leading axis (the engine merges row-wise and knows no key)
@@ -81,6 +104,7 @@ class ProGenFamily:
     position_masks = True
     idle_length = 1
     modes = SERVING_MODES
+    block_length = None
 
     def __init__(self, config: ProGenConfig, policy: Policy,
                  weights: str = "bf16"):
@@ -139,13 +163,15 @@ class ProGenFamily:
 # Three hold one chip's share of an expert layer (LongCat-Flash and
 # DeepSeek-V2 over latent attention, Trinity over rings and grown keys); the
 # fourth, Granite 4.0-H, has no experts and a recurrent state beside its
-# grown keys
+# grown keys; the fifth, SDAR, holds whole expert layers and generates by
+# diffusion over blocks (it states a ``block_length``)
 _DRIVER_FAMILIES = (
     ("progen_tpu.models.longcat", "LongCatConfig", "LongCatFamily"),
     ("progen_tpu.models.deepseek_v2", "DeepSeekV2Config", "DeepSeekV2Family"),
     ("progen_tpu.models.trinity", "TrinityConfig", "TrinityFamily"),
     ("progen_tpu.models.granite_hybrid", "GraniteHybridConfig",
      "GraniteHybridFamily"),
+    ("progen_tpu.models.sdar", "SDARConfig", "SDARFamily"),
 )
 
 

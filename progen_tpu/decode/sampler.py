@@ -148,6 +148,13 @@ def gumbel_topk_sample_batched(keys, logits, top_k, temperature, mask=None):
     as ``-inf`` and loses every argmax, so constraints compose with
     per-row top-k exactly as in :func:`gumbel_topk_sample`.
     """
+    return _cut_and_draw(keys, logits, top_k, temperature, mask)[0]
+
+
+def _cut_and_draw(keys, logits, top_k, temperature, mask):
+    """:func:`gumbel_topk_sample_batched`'s draw, and what it drew from:
+    ``(tokens (B,), the float32 logits under the mask (B, V), the kept
+    entries (B, V) bool)``."""
     logits = logits.astype(jnp.float32)
     if mask is not None:
         logits = apply_logit_mask(logits, mask)
@@ -156,11 +163,69 @@ def gumbel_topk_sample_batched(keys, logits, top_k, temperature, mask=None):
     scaled = logits / jnp.maximum(temperature, 1e-8)[:, None]
     k_eff = jnp.where(top_k > 0, jnp.clip(top_k, 1, v), v)
     kth = _kth_largest_by_counting(scaled, k_eff)
-    masked = apply_logit_mask(scaled, scaled >= kth)
+    kept = scaled >= kth
+    masked = apply_logit_mask(scaled, kept)
     noise = jax.vmap(
         lambda k: jax.random.gumbel(k, (v,), jnp.float32))(keys)
     sampled = jnp.argmax(masked + noise, axis=-1)
-    return jnp.where(temperature == 0.0, greedy, sampled)
+    return jnp.where(temperature == 0.0, greedy, sampled), logits, kept
+
+
+def gumbel_topk_sample_with_confidence(keys, logits, top_k, temperature,
+                                       mask=None):
+    """:func:`gumbel_topk_sample_batched`'s draw — the same tokens, bit for
+    bit, under the same keys — and each drawn token's CONFIDENCE: its
+    probability under the distribution it was drawn from, the softmax over
+    the entries the mask and the top-k cut kept of ``logits /
+    temperature`` (float32).  A greedy row (``temperature == 0``) takes the
+    argmax and reads its probability at temperature 1: at temperature 0
+    every draw would be certain and no position of a block more confident
+    than another.  ``(tokens (B,) int, confidence (B,) float32)``; what a
+    block-diffusion step keeps of a draw is decided from the second
+    (:func:`confident_positions`)."""
+    with jax.named_scope("sample.confidence"):
+        tokens, logits, kept = _cut_and_draw(keys, logits, top_k,
+                                             temperature, mask)
+        scale = jnp.where(temperature == 0.0, 1.0,
+                          jnp.maximum(temperature, 1e-8))[:, None]
+        scaled = apply_logit_mask(logits / scale, kept)
+        top = jnp.max(scaled, axis=-1, keepdims=True)
+        drawn = jnp.take_along_axis(scaled, tokens[:, None], axis=-1)
+        total = jnp.sum(jnp.exp(scaled - top), axis=-1)
+        return tokens, jnp.exp(drawn - top)[:, 0] / total
+
+
+def transfer_counts(block_length: int, steps: int):
+    """Positions the STATIC rule fills at each of ``steps`` denoise
+    forwards of a block, as a tuple: ``block_length // steps`` each, the
+    first ``block_length % steps`` forwards one more."""
+    base, extra = divmod(block_length, steps)
+    return tuple(base + (i < extra) for i in range(steps))
+
+
+def confident_positions(confidence, masked, count, threshold=None):
+    """Which masked positions of each block take their draw: ``confidence
+    (S, B)`` float32, ``masked (S, B)`` bool, ``count (S,)`` the static
+    rule's number for the row's denoise step -> ``(S, B)`` bool.
+
+    *static* (``threshold`` None): the ``count`` masked positions of highest
+    confidence, ties to the lower index; a row with fewer masked positions
+    (a first block that holds prompt tokens) takes them all.  *dynamic*:
+    every masked position whose confidence is OVER ``threshold``, and at
+    least the static rule's.  A position that holds a token already is
+    never taken."""
+    conf = jnp.where(masked, confidence, -jnp.inf)
+    # rank 0 = the most confident; a stable descending order by comparison
+    # (B is a handful: no sort)
+    at = jnp.arange(conf.shape[-1])
+    ahead = (conf[:, None, :] > conf[:, :, None]) | (
+        (conf[:, None, :] == conf[:, :, None])
+        & (at[None, None, :] < at[None, :, None]))
+    rank = jnp.sum(ahead, axis=-1)
+    take = rank < count[:, None]
+    if threshold is not None:
+        take = take | (conf > threshold)
+    return take & masked
 
 
 def split_keys_batched(key_data):

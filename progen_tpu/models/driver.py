@@ -9,7 +9,9 @@ long), ``models/trinity.py`` two in one model (a ring of ``sliding_window``
 rows beside keys and values that grow with the request, both
 ``models/kv.py``'s), ``models/granite_hybrid.py`` a RECURRENT STATE (a
 carry and a convolution tail a slot, as large at token 1 as at token
-100,000) beside grown keys.
+100,000) beside grown keys, ``models/sdar.py`` grown keys that take a BLOCK
+of tokens a step and are written only when the block is committed
+(:func:`block_step`).
 
 **A block** (``blocks_of(config)`` gives ``{name: block}``, one per
 mixer of the stack, in the stack's order) is an object with
@@ -32,6 +34,12 @@ mixer of the stack, in the stack's order) is an object with
 ``decode(x (S, h), pos (S,), cache, weights)``
     ``(out (S, h), cache)``: one token a row at position ``pos``, written
     into the cache (or folded into the state) and mixed up to it
+``decode_block(x (S, B, h), pos0 (S,), cache, weights, commit (S,))``
+    only of a block whose family generates B tokens a row a step
+    (:func:`block_step`; ``models/kv.py``'s grown keys have it): ``(out (S,
+    B, h), cache)``, the B tokens at ``pos0 .. pos0 + B - 1`` mixed over
+    the slot's committed rows and each other, and written into the cache
+    where ``commit`` and not at all where not
 
 **Experts are a family's statement**, not the driver's assumption.  A
 family with a share of an expert layer (``models/experts.py``) names
@@ -212,6 +220,16 @@ def prefill(stack, blocks, params, tokens, lengths, config, policy: Policy,
     return out
 
 
+def _step_moe_stats(stats, chosen, touched, live) -> None:
+    """A step's own ``moe.*`` counters, for a family with experts: the
+    expert layers it ran (if any row was live) and the held experts it
+    touched."""
+    if "moe.held_load" in stats:
+        stats["moe.decode_layers"] = jnp.asarray(
+            len(chosen), F32) * jnp.any(live)
+        stats["moe.experts_touched"] = touched
+
+
 def decode_step(stack, blocks, attention_stats, params, tok, pos, caches,
                 live, config, policy: Policy, *, with_choices: bool = False):
     """One token per row: ``tok (S,)`` at ``pos (S,)`` -> ``(logits (S, V)
@@ -230,14 +248,43 @@ def decode_step(stack, blocks, attention_stats, params, tok, pos, caches,
 
     x = _embed(params, tok, c, dt)
     x, stats, chosen, touched = stack(x, params, c, attend, live)
-    if "moe.held_load" in stats:
-        stats["moe.decode_layers"] = jnp.asarray(
-            len(chosen), F32) * jnp.any(live)
-        stats["moe.experts_touched"] = touched
+    _step_moe_stats(stats, chosen, touched, live)
     stats.update(attention_stats(dt, caches, pos, live))
     out = _logits(x, params, c), caches, stats
     if with_choices:
         return out + (jnp.stack(chosen),)
+    return out
+
+
+def block_step(stack, blocks, attention_stats, params, tok, pos0, caches,
+               live, commit, config, policy: Policy, *,
+               with_choices: bool = False):
+    """``B`` tokens per row, for a family that generates by diffusion over
+    blocks: ``tok (S, B)`` (mask tokens among them) at ``pos0 .. pos0 + B
+    - 1`` -> ``(logits (S, B, V) float32, caches, stats)``.  Every layer is
+    one forward of all ``S * B`` tokens; a row's keys and values enter its
+    cache only where ``commit (S,)`` (a denoise forward stores nothing).
+    Rows that are not ``live`` run but are not counted and reach no expert.
+    ``attention_stats(dtype, caches, pos0, live)`` as :func:`decode_step`'s,
+    of a step of B query rows a slot."""
+    c = config
+    dt = policy.compute_dtype
+    caches = dict(caches)
+    s, b = tok.shape
+
+    def attend(x, name, p):
+        out, caches[name] = blocks[name].decode_block(
+            x.reshape(s, b, -1), pos0, caches[name], p, commit)
+        return out.reshape(s * b, -1)
+
+    x = _embed(params, tok.reshape(-1), c, dt)
+    x, stats, chosen, touched = stack(x, params, c, attend,
+                                      jnp.repeat(live, b))
+    _step_moe_stats(stats, chosen, touched, live)
+    stats.update(attention_stats(dt, caches, pos0, live))
+    out = _logits(x, params, c).reshape(s, b, -1), caches, stats
+    if with_choices:
+        return out + (jnp.stack(chosen).reshape(len(chosen), s, b, -1),)
     return out
 
 
@@ -262,6 +309,7 @@ class Family:
     position_masks = False      # the state holds an (S, V) mask, not (S, L, V)
     idle_length = 0             # a row without a request has no token
     modes = frozenset()         # the plain dense path only
+    block_length = None         # one token a row a step (decode/family.py)
     step_model = prefill_model = None
 
     def __init__(self, config, policy: Policy):

@@ -23,6 +23,17 @@ noted under ``"moe_experts"``, ``ops/lowering.py``):
   multiplies them by ``jax.lax.ragged_dot`` in windows of ``capacity``
   assignments: a window that overflows runs the loop again.
 
+A chip may also hold the WHOLE layer (``experts_held == router_width``,
+``first_expert`` 0: ``models/sdar.py``, one stage of a pipeline): every
+assignment is then its own, ``moe.held_load`` is the router's whole
+histogram, and nothing below changes.  And a call may lie BETWEEN the two
+sizes above: a block-diffusion step sends a layer ``slots x block_length``
+tokens (256 in its cell: twice the kernel's 128, a sixteenth of an
+admission's), and as the rule stands takes the grouped form — one window
+of ``ragged_dot`` over 2,048 assignments to 128 experts, 16 rows an expert,
+which streams the layer's 1.2 GB at a third of the rate the kernel does
+(PERF.md section 6, PR 41; its first ``perf_opt``, section 7).
+
 A config here has ``experts_held``, ``first_expert``, ``moe_topk`` and
 ``router_width``.
 """

@@ -21,6 +21,15 @@ in two lines:
   matter);
 * a full block's cache (``window`` None) GROWS with the request:
   ``max_len`` rows, the token at ``p`` in row ``p``, ``pos + 1`` of them.
+
+A grown cache also takes a BLOCK of ``B`` tokens a row a step
+(:meth:`KVBlock.decode_block`, for a family that generates by diffusion
+over blocks, ``models/sdar.py``): the B queries at ``pos0 .. pos0 + B - 1``
+see the slot's ``pos0`` committed rows and each other's keys, whatever
+order they were filled in, and the write of the B new rows MAY BE WITHHELD
+row by row of the batch — a denoise forward leaves the cache as it found
+it, a commit forward writes its block.  Such a block's prefill mask is
+causal across blocks of ``block`` tokens and open inside one.
 """
 
 from __future__ import annotations
@@ -29,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from progen_tpu.ops import gqa
-from progen_tpu.ops.row_write import write_rows
+from progen_tpu.ops.row_write import write_row_blocks, write_rows
 
 F32 = jnp.float32
 
@@ -45,11 +54,16 @@ class KVBlock:
     has :meth:`project` and :meth:`finish`."""
 
     def __init__(self, kv_heads: int, head_dim: int, scale: float,
-                 window: int | None = None):
+                 window: int | None = None, block: int = 1):
+        if block != 1 and window is not None:
+            raise ValueError("a block mask goes with grown keys, not a ring")
         self.kv_heads = kv_heads
         self.head_dim = head_dim
         self.scale = scale
         self.window = window
+        # the prefill's mask: causal (1), or causal across blocks of
+        # ``block`` tokens and open inside one
+        self.block = block
         self.scope = "attn.full" if window is None else "attn.window"
 
     def project(self, x, p, positions):
@@ -89,7 +103,7 @@ class KVBlock:
         k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
         with jax.named_scope(self.scope):
             o = gqa.prefill_attention(q, k, v, self.scale, self.window,
-                                      lengths)
+                                      lengths, self.block)
         return self.finish(o, rest, p), {"k": k, "v": v}
 
     def cache_rows(self, rows, lengths, max_len: int):
@@ -126,6 +140,25 @@ class KVBlock:
         rest = jax.tree.map(lambda a: a[:, 0], rest)
         return self.finish(o, rest, p), {"k": keys, "v": values}
 
+    def decode_block(self, x, pos0, cache, p, commit):
+        """``B`` tokens a row: ``x (S, B, h)`` at ``pos0 .. pos0 + B - 1``
+        (``pos0 (S,)``, a multiple of B), mixed over the slot's ``pos0``
+        committed rows and each other; the B new keys and values are
+        written at those rows where ``commit (S,)`` and nowhere else."""
+        if self.window is not None:
+            raise NotImplementedError("a ring takes one token a step")
+        b = x.shape[1]
+        q, k, v, rest = self.project(x, p, pos0[:, None] + jnp.arange(b))
+        k = k.transpose(0, 2, 1, 3).astype(cache["k"].dtype)
+        v = v.transpose(0, 2, 1, 3).astype(cache["v"].dtype)
+        with jax.named_scope("attn.block"):
+            o = gqa.block_decode_attention(q, cache["k"], cache["v"], k, v,
+                                           pos0, self.scale)
+        with jax.named_scope("attn.commit"):
+            keys, values = write_row_blocks(
+                (cache["k"], cache["v"]), (k, v), pos0, commit)
+        return self.finish(o, rest, p), {"k": keys, "v": values}
+
 
 def decode_stats(blocks: dict, caches, pos, live) -> dict:
     """A decode step's ``attn.*`` counters over the :class:`KVBlock`s among
@@ -150,4 +183,13 @@ def decode_stats(blocks: dict, caches, pos, live) -> dict:
         if kind == "window":
             stats["attn.window_tokens"] = jnp.sum(jnp.minimum(
                 seen, cache["k"].shape[2])).astype(F32)
+    return stats
+
+
+def block_decode_stats(blocks: dict, caches, pos0, live, b: int) -> dict:
+    """:func:`decode_stats` of a step of ``b`` tokens a row: ``b`` query
+    rows a live slot, each slot's context the ``pos0`` rows committed
+    before its block."""
+    stats = decode_stats(blocks, caches, pos0 - 1, live)
+    stats["attn.decode_rows"] = b * stats["attn.decode_rows"]
     return stats

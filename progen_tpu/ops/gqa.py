@@ -1,7 +1,11 @@
 """Grouped-query attention cores: ``H`` query heads in groups of ``H / KV``
 that share one of ``KV`` key/value heads (query head ``h`` reads key/value
-head ``h // (H / KV)``), with a causal mask and an optional per-token
-sliding window.
+head ``h // (H / KV)``), under one of the TWO MASKS a prefill takes — the
+causal mask with an optional per-token sliding window, or (``block = B``,
+no window) the BLOCK mask of a family that generates by diffusion over
+blocks: position ``i`` sees ``j`` iff ``j // B <= i // B``, causal across
+blocks of ``B`` and open inside one — and the two cores of a decode step:
+one query a slot, or a block of ``B`` queries that see each other.
 
 :func:`prefill_attention` — ``q (R, P, H, d)`` against ``k, v (R, KV, P,
 d)``, ``lengths (R,)`` leading positions of each row real: position ``i``
@@ -76,6 +80,23 @@ has just written the row it stands on).  Plain XLA; the whole cache is
 read: scores ``(S, H, T)`` in float32, a masked softmax, the value product.
 :func:`rows_visited` says how many rows that is (the counters
 ``attn.window_rows_read`` / ``attn.full_rows_read``).
+
+**The block mask** changes one comparison in each lowering and nothing
+else: a query block, and a tile, start on a block's edge (``B`` divides
+``QUERY_BLOCK`` and, a power of two, the tiles), so the keys a block of
+query rows can see end where the causal mask's do, the visit rule
+(:func:`key_tiles`) stands, and only the tiles the diagonal crosses pay for
+the mask — there ``gap >= (row % B) - (B - 1)`` in place of ``gap >= 0``.
+``lengths`` are whole blocks (a real query then sees real keys only) and
+``P`` is a multiple of ``B``.  With ``block`` 1 both lowerings trace the
+program they traced before the mask existed.
+
+:func:`block_decode_attention` — ``B`` queries a slot, ``q (S, B, H, d)``,
+against the first ``counts (S,)`` rows of the cache AND the block's own B
+keys and values, handed in beside the cache and not written to it: one
+softmax over both (two score tensors under one running maximum, so the
+cache is never concatenated).  Plain XLA like :func:`decode_attention`, the
+whole cache read; noted as ``"gqa_block_decode"``.
 """
 
 from __future__ import annotations
@@ -103,17 +124,21 @@ MASKED = -0.7 * float(jnp.finfo(F32).max)   # a masked score: finite
 # ------------------------------------------------------ the blocked XLA form
 
 
-def _score_block(q, k, v, first_row, first_key, scale, window):
+def _score_block(q, k, v, first_row, first_key, scale, window, block=1):
     """``q (R, KV, G, bq, d)`` at rows ``first_row + arange(bq)`` against
     ``k, v (R, KV, t, d)`` at positions ``first_key + arange(t)`` (a
     position below 0 is padding): ``(R, KV, G, bq, d)``."""
     logits = jnp.einsum("rkgqd,rktd->rkgqt", q, k,
                         preferred_element_type=F32) * scale
     at = first_key + jnp.arange(k.shape[2])[None, :]
-    gap = first_row + jnp.arange(q.shape[3])[:, None] - at
-    seen = (gap >= 0) & (at >= 0)
-    if window is not None:
-        seen = seen & (gap < window)
+    if block == 1:
+        gap = first_row + jnp.arange(q.shape[3])[:, None] - at
+        seen = (gap >= 0) & (at >= 0)
+        if window is not None:
+            seen = seen & (gap < window)
+    else:       # causal across blocks of ``block``, every key inside one
+        rows = first_row + jnp.arange(q.shape[3])[:, None]
+        seen = at // block <= rows // block
     # float32 scores, maximum and sum; the unnormalised probabilities are
     # cast for the value product and the ONE division by the sum comes
     # after it, on (bq, d) numbers and not (bq, t) — the repo's kernels'
@@ -146,10 +171,18 @@ def _blocked_bodies(n: int, window) -> list:
             for first in range(0, blocks, FULL_GROUP)]
 
 
-def blocked_prefill_attention(q, k, v, scale, window=None):
+def blocked_prefill_attention(q, k, v, scale, window=None, block=1):
     """The XLA form: every position of every row computed, the score
-    tensor ``(R, KV, G, QUERY_BLOCK, keys)`` float32."""
+    tensor ``(R, KV, G, QUERY_BLOCK, keys)`` float32.  ``block``: the block
+    mask's length (a query block holds whole ones, so the keys a block of
+    query rows can see end where the causal mask's do)."""
     r, n, heads, d = q.shape
+    if block != 1 and (window is not None or QUERY_BLOCK % block
+                       or n % block):
+        raise ValueError(
+            f"a block mask of {block} takes no window ({window}) and "
+            f"divides the query block of {QUERY_BLOCK} and the {n} "
+            "positions (a last block cut short would see the padding)")
     kv = k.shape[1]
     bq = min(QUERY_BLOCK, n)
     blocks = -(-n // bq)
@@ -163,23 +196,23 @@ def blocked_prefill_attention(q, k, v, scale, window=None):
         k, v = (jnp.pad(a, ((0, 0), (0, 0), (back, 0), (0, 0)))
                 for a in (k, v))
 
-        def block(i):       # padded row ``s`` holds position ``s - back``
+        def body(i):        # padded row ``s`` holds position ``s - back``
             s = i * bq
             return _score_block(_rows(q, s, bq), _rows(k, s, back + bq),
                                 _rows(v, s, back + bq), s, s - back, scale,
                                 window)
 
-        out = jax.lax.map(block, jnp.arange(blocks))
+        out = jax.lax.map(body, jnp.arange(blocks))
     else:
         outs = []
         for first, count, span in bodies:
             keys, values = k[:, :, :span], v[:, :, :span]
 
-            def block(i, keys=keys, values=values):
+            def body(i, keys=keys, values=values):
                 return _score_block(_rows(q, i * bq, bq), keys, values,
-                                    i * bq, 0, scale, None)
+                                    i * bq, 0, scale, None, block)
 
-            outs.append(jax.lax.map(block, jnp.arange(first, first + count)))
+            outs.append(jax.lax.map(body, jnp.arange(first, first + count)))
         out = jnp.concatenate(outs, axis=0)
     # (blocks, R, KV, G, bq, d) -> (R, P, H * d)
     out = out.transpose(1, 0, 4, 2, 3, 5).reshape(r, blocks * bq, heads * d)
@@ -219,7 +252,7 @@ def _dot_t(a, b):  # a @ b^T, float32 accumulate
 
 
 def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-                  *, scale, bq, bk, window):
+                  *, scale, bq, bk, window, block=1):
     from jax.experimental import pallas as pl
 
     length = len_ref[pl.program_id(0)]
@@ -240,7 +273,15 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
             gap = (q0 - k0) + (
                 jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
                 - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-            seen = under_diagonal or gap >= 0
+            if block == 1:
+                seen = under_diagonal or gap >= 0
+            else:
+                # ``key // block <= row // block``: a row sees the keys up
+                # to the end of its own block (tiles start on a block's
+                # edge, ``block`` a power of two)
+                ahead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                         & (block - 1)) - (block - 1)
+                seen = under_diagonal or gap >= ahead
             if not inside_window:
                 seen = seen & (gap < window)
             s = jnp.where(seen, s, MASKED)
@@ -287,28 +328,38 @@ def fitted_tile(n: int) -> int:
 
 
 def pallas_prefill_attention(q, k, v, lengths, scale, window=None, *,
-                             block_q=None, block_k=None, interpret=None):
+                             block=1, block_q=None, block_k=None,
+                             interpret=None):
     """The kernel lowering.  ``q (R, P, H * d)``, ``k, v (R, KV, P, d)``,
     ``lengths (R,)`` -> ``(R, P, H * d)``.  ``interpret=None`` auto-selects
     the Pallas interpreter off-TPU; ``block_q`` / ``block_k`` default to
-    :func:`fitted_tile`."""
+    :func:`fitted_tile``; ``block`` is the block mask's length (a power of
+    two that divides both tiles, no window beside it; ``lengths`` whole
+    blocks)."""
     n = k.shape[2]
     bq = block_q or fitted_tile(n)
     bk = block_k or fitted_tile(n)
     if n % bq or n % bk:
         raise ValueError(f"tiles ({bq}, {bk}) do not divide P = {n}")
+    if block != 1 and (window is not None or block & (block - 1)
+                       or bq % block or bk % block):
+        raise ValueError(
+            f"a block mask of {block} takes no window ({window}) and is a "
+            f"power of two that divides the tiles ({bq}, {bk})")
     if interpret is None:
         interpret = not _on_tpu()
     return _flash_call(q, k, v, lengths.astype(jnp.int32), scale=scale,
-                       window=window, bq=bq, bk=bk, interpret=interpret)
+                       window=window, bq=bq, bk=bk, interpret=interpret,
+                       block=block)
 
 
 # jitted so that the blocks of one kind in a model share ONE traced and
 # lowered kernel: nine calls of an 8192-token admission lower in 0.1 s
 # instead of 0.6-1.6 s, and the program holds two Mosaic kernels, not nine
 @functools.partial(jax.jit, static_argnames=("scale", "window", "bq", "bk",
-                                             "interpret"))
-def _flash_call(q, k, v, lengths, *, scale, window, bq, bk, interpret):
+                                             "interpret", "block"))
+def _flash_call(q, k, v, lengths, *, scale, window, bq, bk, interpret,
+                block=1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -330,7 +381,7 @@ def _flash_call(q, k, v, lengths, *, scale, window, bq, bk, interpret):
 
     return pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, bq=bq, bk=bk,
-                          window=window),
+                          window=window, block=block),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(r, heads, n // bq, key_steps(n, bq, bk, window)),
@@ -354,7 +405,7 @@ def _flash_call(q, k, v, lengths, *, scale, window, bq, bk, interpret):
     )(lengths, q, k, v)
 
 
-def prefill_lowering(n: int, d: int, dtype, window) -> str:
+def prefill_lowering(n: int, d: int, dtype, window, block: int = 1) -> str:
     """``"pallas"`` or ``"xla"``: what :func:`prefill_attention` takes for
     ``n`` positions of heads ``d`` wide in ``dtype``, traced here and now
     (the module docstring has the rule)."""
@@ -362,30 +413,39 @@ def prefill_lowering(n: int, d: int, dtype, window) -> str:
               and jnp.issubdtype(dtype, jnp.floating)
               and jnp.dtype(dtype).itemsize in (2, 4)
               and d % 128 == 0 and n % MIN_TILE == 0
-              and (window is None or window % fitted_tile(n) == 0))
+              and (window is None or window % fitted_tile(n) == 0)
+              and (block == 1 or (window is None and not block & (block - 1)
+                                  and MIN_TILE % block == 0)))
     return "pallas" if kernel else "xla"
 
 
-def prefill_attention(q, k, v, scale, window=None, lengths=None):
+def prefill_attention(q, k, v, scale, window=None, lengths=None, block=1):
     """``(R, P, H * d)`` in ``q``'s dtype, exact at the first ``lengths
-    (R,)`` positions of each row (default: all ``P``).  The lowering is
-    chosen as the module docstring says."""
+    (R,)`` positions of each row (default: all ``P``).  ``block``: 1 for
+    the causal mask, else the block mask's length (``lengths`` then whole
+    blocks).  The lowering is chosen as the module docstring says."""
     r, n, heads, d = q.shape
-    lowering = prefill_lowering(n, d, q.dtype, window)
+    lowering = prefill_lowering(n, d, q.dtype, window, block)
     note("gqa_prefill", lowering)
     if lowering == "xla":
-        return blocked_prefill_attention(q, k, v, scale, window)
+        return blocked_prefill_attention(q, k, v, scale, window, block)
     if lengths is None:
         lengths = jnp.full((r,), n, jnp.int32)
+    # the causal call is the one it was (callers wrap it with that signature)
+    extra = {} if block == 1 else {"block": block}
     return pallas_prefill_attention(q.reshape(r, n, heads * d), k, v,
-                                    lengths, scale, window)
+                                    lengths, scale, window, **extra)
 
 
-def pairs_allowed(lengths, window=None):
+def pairs_allowed(lengths, window=None, block: int = 1):
     """Query-key pairs the mask allows at the real positions of rows of
     ``lengths (R,)``, one head, as a float32 scalar: position ``i <
-    length`` sees ``min(i + 1, window)`` keys."""
+    length`` sees ``min(i + 1, window)`` keys, or under a block mask the
+    ``(i // block + 1) * block`` up to its block's end (``lengths`` whole
+    blocks)."""
     n = lengths.astype(F32)
+    if block != 1:
+        return jnp.sum(n * (n + block) / 2)
     pairs = n * (n + 1) / 2
     if window is not None:
         past = jnp.maximum(n - window, 0)   # positions with a full window
@@ -440,3 +500,43 @@ def rows_visited(k):
     ``v``) in one call, as a float32 scalar: every row of every slot,
     whatever the counts."""
     return jnp.asarray(k.shape[0] * k.shape[2], F32)
+
+
+# ------------------------------------------------------- a block of queries
+
+
+def block_decode_attention(q, k, v, k_new, v_new, counts, scale):
+    """``B`` queries a slot, ``q (S, B, H, d)``, against the first ``counts
+    (S,)`` rows of ``k, v (S, KV, T, d)`` AND the block's own ``k_new,
+    v_new (S, KV, B, d)``, every one of which every query of the block sees
+    (bidirectional inside the block): one softmax over both.  ``counts``
+    may be 0 (a first block with nothing committed before it): the block's
+    own keys are always there.  Nothing is written: whether the block's
+    keys enter the cache is the caller's (``ops/row_write.py:
+    write_row_blocks``).  ``(S, B, H * d)`` in ``q``'s dtype.  Plain XLA, as
+    :func:`decode_attention`: the whole cache is read (:func:`rows_visited`),
+    scores ``(S, KV, G * B, T)`` in float32; noted as ``"gqa_block_decode"``
+    (``ops/lowering.py``)."""
+    note("gqa_block_decode", "xla")
+    s, b, heads, d = q.shape
+    kv, t = k.shape[1], k.shape[2]
+    group = heads // kv
+    q = q.reshape(s, b, kv, group, d).transpose(0, 2, 3, 1, 4).reshape(
+        s, kv, group * b, d)
+    past = jnp.einsum("skqd,sktd->skqt", q, k.astype(q.dtype),
+                      preferred_element_type=F32) * scale
+    seen = jnp.arange(t)[None, :] < counts[:, None]
+    past = jnp.where(seen[:, None, None], past, -jnp.inf)
+    own = jnp.einsum("skqd,skbd->skqb", q, k_new.astype(q.dtype),
+                     preferred_element_type=F32) * scale
+    # the block's own scores are finite, so the maximum is
+    top = jnp.maximum(jnp.max(past, axis=-1), jnp.max(own, axis=-1))[..., None]
+    p_past, p_own = jnp.exp(past - top), jnp.exp(own - top)
+    total = jnp.sum(p_past, axis=-1) + jnp.sum(p_own, axis=-1)
+    out = (jnp.einsum("skqt,sktd->skqd", p_past.astype(q.dtype),
+                      v.astype(q.dtype), preferred_element_type=F32)
+           + jnp.einsum("skqb,skbd->skqd", p_own.astype(q.dtype),
+                        v_new.astype(q.dtype), preferred_element_type=F32))
+    out = (out / total[..., None]).astype(q.dtype)
+    return out.reshape(s, kv, group, b, d).transpose(0, 3, 1, 2, 4).reshape(
+        s, b, heads * d)

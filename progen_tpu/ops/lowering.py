@@ -7,6 +7,8 @@ note of which one it took.
 step's handful of tokens, ``"xla"`` for an admission's thousands) each keep
 a Pallas kernel and an XLA form behind one function and choose between them
 from the backend, the mesh in scope and the shapes, never from a knob.
+(``ops/gqa.py:block_decode_attention`` has the XLA form alone so far and
+notes it as ``"gqa_block_decode"``, so that the note is there to change.)
 The choice is made while a program is traced, so a caller that traces one
 (``ServingEngine`` around its chunk and admission programs) can collect it:
 :func:`record_lowerings` yields ``{op name: {lowering, ...}}`` for the ops

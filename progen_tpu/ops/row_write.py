@@ -32,6 +32,13 @@ All three write the same bytes to the same places; out-of-range indices
 follow the scatter's rule (a negative index counts from the end, then
 clips).  :func:`record_paths` lets a caller that traces a program collect
 which lowering its writes took (``ServingEngine.status()["row_write"]``).
+
+:func:`write_row_blocks` is the same contract for a BLOCK of ``n`` rows a
+slot that a per-slot flag may withhold (a block-diffusion step writes a
+block's keys only when it commits it): the kernel ``row_block_write``
+brings in the one aligned tile that holds the block (``n`` divides the
+tile), replaces the block's rows where the flag is set and writes the tile
+back; elsewhere a slice update a slot.
 """
 
 from __future__ import annotations
@@ -154,3 +161,95 @@ def write_rows(cache, update, idx, axis):
         out = tuple(_scatter_rows(c, u, idx, axis)
                     for c, u in zip(caches, updates))
     return out if many else out[0]
+
+
+# ------------------------------------------------- a block of rows, or none
+
+
+def _block_kernel(idx_ref, n_ref, *refs, tile):
+    from jax.experimental import pallas as pl
+
+    n = len(refs) // 3
+    i = pl.program_id(0)
+    first = idx_ref[i] % tile
+    for c_ref, u_ref, o_ref in zip(refs[:n], refs[n:2 * n], refs[2 * n:]):
+        rows = jax.lax.broadcasted_iota(jnp.int32, c_ref.shape, 2)
+        hit = (rows >= first) & (rows < first + n_ref[i])
+        o_ref[...] = jnp.where(hit, u_ref[...], c_ref[...])
+
+
+def pallas_write_row_blocks(caches, updates, start, write, *, interpret=None):
+    """The kernel lowering of :func:`write_row_blocks`: as
+    :func:`pallas_write_rows`, the aligned tile that holds the block brought
+    in, the block's rows replaced where ``write`` and the tile written back
+    (unchanged where not).  The update arrives repeated to a whole tile
+    (``n`` divides the tile and ``start`` is a multiple of ``n``, so copy
+    ``j`` of the block lies where the block would), and the select needs no
+    shuffle inside the kernel."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = not _on_tpu()
+    shape, dtype = caches[0].shape, caches[0].dtype
+    b, (r, d) = shape[0], shape[-2:]
+    n = updates[0].shape[-2]
+    tile = 32 // dtype.itemsize  # sublane_tile, a host int for graftcheck
+    caches = [c.reshape(b, -1, r, d) for c in caches]
+    updates = [jnp.tile(u.reshape(b, -1, n, d), (1, 1, tile // n, 1))
+               for u in updates]
+    h = caches[0].shape[1]
+    tile_spec = pl.BlockSpec(
+        (1, h, tile, d),
+        lambda i, idx_ref, n_ref: (i, 0, idx_ref[i] // tile, 0))
+    block_spec = pl.BlockSpec((1, h, tile, d),
+                              lambda i, idx_ref, n_ref: (i, 0, 0, 0))
+    m = len(caches)
+    out = pl.pallas_call(
+        functools.partial(_block_kernel, tile=tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[tile_spec] * m + [block_spec] * m,
+            out_specs=[tile_spec] * m,
+        ),
+        out_shape=[jax.ShapeDtypeStruct(c.shape, dtype) for c in caches],
+        # operands 0 and 1 are the prefetched starts and counts
+        input_output_aliases={2 + i: i for i in range(m)},
+        interpret=interpret,
+        name="row_block_write",
+    )(_wrap(start, r), jnp.where(write, n, 0).astype(jnp.int32),
+      *caches, *updates)
+    return tuple(o.reshape(shape) for o in out)
+
+
+def write_row_blocks(caches, updates, start, write):
+    """Write the ``n`` rows ``updates[b] (…, n, d)`` into ``caches[b] (…,
+    R, d)`` at rows ``start[b] .. start[b] + n - 1`` where ``write[b]``, and
+    NOTHING where not: a block-diffusion step's commit (``models/kv.py``),
+    which most rows of most steps withhold.  ``caches`` / ``updates`` are
+    tuples of equal-shaped arrays written at the same rows (a layer's k and
+    v); ``start (B,)`` is a multiple of ``n``, ``start + n <= R``.  The
+    lowering is chosen as :func:`write_rows` chooses (noted under
+    ``"row_write"`` too): the kernel ``row_block_write`` on a TPU with no
+    mesh in scope where ``n`` divides the dtype's sublane tile and the tile
+    divides ``R``; elsewhere a slice update a slot, the withheld rows
+    re-written with what they held."""
+    first = caches[0]
+    n = updates[0].shape[-2]
+    kernel = (_on_tpu() and not _mesh_in_scope()
+              and _kernel_takes(first, first.ndim - 3)
+              and sublane_tile(first.dtype) % n == 0)
+    note("row_write", "pallas" if kernel else "scatter")
+    if kernel:
+        return pallas_write_row_blocks(tuple(caches), tuple(updates), start,
+                                       write)
+    axis = first.ndim - 3           # of the per-slot view
+
+    def one(c, u, i, w):
+        old = jax.lax.dynamic_slice_in_dim(c, i, n, axis)
+        return jax.lax.dynamic_update_slice_in_dim(
+            c, jnp.where(w, u, old), i, axis)
+
+    return tuple(jax.vmap(one)(c, u, start.astype(jnp.int32), write)
+                 for c, u in zip(caches, updates))

@@ -1,0 +1,186 @@
+"""The attention of a family that generates by blocks (``ops/gqa.py``,
+``ops/row_write.py``, ``models/kv.py``): the prefill under a mask that is
+causal across blocks and open inside one — both lowerings — against a dense
+mask; ``B`` queries over a cache plus themselves against the rows of a full
+forward; a commit that writes exactly its ``B`` rows and a denoise forward
+that writes none."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from progen_tpu.ops import gqa, row_write
+
+HEADS, KV, D = 4, 2, 16
+
+
+def _qkv(seed, r, n, d=D, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.key(seed), 3)
+    q = jax.random.normal(ks[0], (r, n, HEADS, d), dtype)
+    k = jax.random.normal(ks[1], (r, KV, n, d), dtype)
+    v = jax.random.normal(ks[2], (r, KV, n, d), dtype)
+    return q, k, v
+
+
+def _dense(q, k, v, scale, seen):
+    """Plain masked softmax: ``seen (n, n)`` bool."""
+    r, n, heads, d = q.shape
+    k = jnp.repeat(k, heads // k.shape[1], axis=1)
+    v = jnp.repeat(v, heads // v.shape[1], axis=1)
+    logits = jnp.einsum("rqhd,rhtd->rhqt", q, k) * scale
+    probs = jax.nn.softmax(jnp.where(seen, logits, -jnp.inf), axis=-1)
+    return jnp.einsum("rhqt,rhtd->rqhd", probs, v).reshape(r, n, heads * d)
+
+
+def _block_mask(n, b):
+    at = np.arange(n) // b
+    return at[None, :] <= at[:, None]
+
+
+@pytest.mark.parametrize("n,block", [
+    (4, 4), (8, 4), (8, 8), (40, 4), (40, 8), (256, 4), (260, 4), (264, 8),
+    (516, 4), (1096, 8), (1100, 4)])
+def test_blocked_form_under_the_block_mask_equals_a_dense_mask(n, block):
+    """Lengths below, at and across the mask's blocks and the form's query
+    blocks (256) and groups of them (1024)."""
+    q, k, v = _qkv(n, 2, n)
+    got = gqa.prefill_attention(q, k, v, 0.25, None, None, block)
+    want = _dense(q, k, v, 0.25, _block_mask(n, block))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    # the mask matters: the causal form differs inside every block
+    causal = gqa.prefill_attention(q, k, v, 0.25)
+    assert float(jnp.abs(causal - want).max()) > 1e-2
+    # its last position of each block sees what the block mask shows all
+    last = np.arange(block - 1, n, block)
+    np.testing.assert_allclose(causal[:, last], want[:, last], atol=2e-5)
+
+
+@pytest.mark.parametrize("lengths", [(64, 32), (12, 0), (40, 64)])
+@pytest.mark.parametrize("tiles", [(16, 16), (32, 16), (16, 32)])
+def test_kernel_under_the_block_mask_equals_a_dense_mask(lengths, tiles):
+    """The flash kernel in interpret mode, rows of whole blocks below, at
+    and across its tiles; real positions only (``ops/gqa.py``'s contract)."""
+    n, block = 64, 4
+    q, k, v = _qkv(7, 2, n)
+    got = gqa.pallas_prefill_attention(
+        q.reshape(2, n, HEADS * D), k, v, jnp.asarray(lengths), 0.25,
+        block=block, block_q=tiles[0], block_k=tiles[1], interpret=True)
+    want = _dense(q, k, v, 0.25, _block_mask(n, block))
+    for i, length in enumerate(lengths):
+        np.testing.assert_allclose(got[i, :length], want[i, :length],
+                                   atol=2e-5)
+    blocked = gqa.blocked_prefill_attention(q, k, v, 0.25, None, block)
+    np.testing.assert_allclose(got[0, :lengths[0]], blocked[0, :lengths[0]],
+                               atol=2e-5)
+
+
+def test_the_block_mask_refuses_what_it_cannot_keep():
+    q, k, v = _qkv(0, 1, 64)
+    with pytest.raises(ValueError, match="block mask"):
+        gqa.blocked_prefill_attention(q, k, v, 1.0, 8, 4)
+    with pytest.raises(ValueError, match="block mask"):
+        gqa.blocked_prefill_attention(q, k, v, 1.0, None, 3)
+    with pytest.raises(ValueError, match="block mask"):    # 60 = 7.5 x 8
+        gqa.blocked_prefill_attention(q[:, :60], k[:, :, :60], v[:, :, :60],
+                                      1.0, None, 8)
+    with pytest.raises(ValueError, match="block mask"):
+        gqa.pallas_prefill_attention(
+            q.reshape(1, 64, -1), k, v, jnp.asarray([64]), 1.0, block=3,
+            block_q=16, block_k=16, interpret=True)
+
+
+@pytest.mark.parametrize("shape,block,want", [
+    ((1024, 128), 4, "pallas"), ((1024, 128), 1, "pallas"),
+    ((1024, 128), 3, "xla"), ((1024, 64), 4, "xla"), ((640, 128), 4, "xla")])
+def test_on_tpu_the_block_mask_keeps_the_kernels_rule(monkeypatch, shape,
+                                                      block, want):
+    monkeypatch.setattr(gqa, "_on_tpu", lambda: True)
+    n, d = shape
+    assert gqa.prefill_lowering(n, d, jnp.bfloat16, None, block) == want
+    assert gqa.prefill_lowering(n, d, jnp.bfloat16, 2048, block) == (
+        want if block == 1 else "xla")
+
+
+@pytest.mark.parametrize("block", [4, 8])
+def test_pairs_allowed_under_the_block_mask_is_a_count_of_the_mask(block):
+    lengths = jnp.asarray([0, block, 5 * block, 64])
+    want = sum(int(_block_mask(int(n), block).sum()) for n in lengths)
+    assert float(gqa.pairs_allowed(lengths, None, block)) == want
+
+
+def test_b_queries_over_a_cache_and_themselves_are_a_full_forwards_rows():
+    """Slots at different cursors (one with nothing committed), rows of the
+    cache past the cursor holding anything."""
+    b, t, slots = 4, 32, 3
+    q, k, v = _qkv(3, slots, t)
+    counts = jnp.asarray([0, 8, 28])
+    want = _dense(q, k, v, 0.25, _block_mask(t, b))
+    junk = jax.random.normal(jax.random.key(9), k.shape)
+    at = jnp.arange(t)[None, None, :, None]
+    past = at < counts[:, None, None, None]
+    for i in range(slots):
+        lo = int(counts[i])
+        got = gqa.block_decode_attention(
+            jnp.stack([q[j, int(counts[j]):int(counts[j]) + b]
+                       for j in range(slots)]),
+            jnp.where(past, k, junk), jnp.where(past, v, junk),
+            jnp.stack([k[j, :, int(counts[j]):int(counts[j]) + b]
+                       for j in range(slots)]),
+            jnp.stack([v[j, :, int(counts[j]):int(counts[j]) + b]
+                       for j in range(slots)]), counts, 0.25)
+        np.testing.assert_allclose(got[i], want[i, lo:lo + b], atol=2e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("kernel", [False, True])
+def test_a_commit_writes_exactly_its_rows_and_a_denoise_forward_none(
+        dtype, kernel):
+    slots, rows, b = 4, 32, 4
+    ks = jax.random.split(jax.random.key(0), 4)
+    caches = tuple(jax.random.normal(k, (slots, KV, rows, D)).astype(dtype)
+                   for k in ks[:2])
+    updates = tuple(jax.random.normal(k, (slots, KV, b, D)).astype(dtype)
+                    for k in ks[2:])
+    start = jnp.asarray([0, 12, 28, 16])
+    write = jnp.asarray([True, False, True, False])
+    if kernel:
+        got = row_write.pallas_write_row_blocks(caches, updates, start,
+                                                write, interpret=True)
+    else:
+        got = row_write.write_row_blocks(caches, updates, start, write)
+    for old, new, upd in zip(caches, got, updates):
+        old, new, upd = (np.asarray(a, np.float32) for a in (old, new, upd))
+        for i in range(slots):
+            lo = int(start[i])
+            changed = (old[i] != new[i]).any(axis=(0, 2))
+            if write[i]:
+                assert changed.tolist() == [lo <= r < lo + b
+                                            for r in range(rows)]
+                np.testing.assert_array_equal(new[i, :, lo:lo + b], upd[i])
+            else:
+                assert not changed.any()
+
+
+def test_on_tpu_the_block_write_is_the_kernel_where_the_tile_takes_it(
+        monkeypatch):
+    from progen_tpu.ops.lowering import record_lowerings
+
+    monkeypatch.setattr(row_write, "_on_tpu", lambda: True)
+    took = {}
+
+    def fake(caches, updates, start, write):
+        took["kernel"] = True
+        return caches
+
+    monkeypatch.setattr(row_write, "pallas_write_row_blocks", fake)
+    cache = (jnp.zeros((2, KV, 32, D), jnp.bfloat16),)
+    with record_lowerings() as chosen:
+        row_write.write_row_blocks(
+            cache, (jnp.zeros((2, KV, 4, D), jnp.bfloat16),),
+            jnp.zeros((2,), jnp.int32), jnp.ones((2,), bool))
+        # a block of 3 does not divide the tile of 16: the slice update
+        row_write.write_row_blocks(
+            cache, (jnp.zeros((2, KV, 3, D), jnp.bfloat16),),
+            jnp.zeros((2,), jnp.int32), jnp.ones((2,), bool))
+    assert took and chosen["row_write"] == {"pallas", "scatter"}

@@ -500,3 +500,74 @@ def test_granite_programs_compile_for_the_chip_and_fit_it(
     assert weights + 2 * held <= total < 15.5e9, m
     if program == "chunk":
         assert "tpu_custom_call" in compiled.as_text()      # the key writes
+
+
+# ---- SDAR's whole programs at published widths ----
+
+
+@pytest.fixture(scope="module")
+def sdar_engine():
+    """The engine of ``serve-sdar-blockdiff-backlog`` over ABSTRACT weights
+    (its 64 slots' state is real, on the host: 2.0 GB of zeros)."""
+    from progen_tpu.decode.engine import ServingEngine
+    from progen_tpu.models import sdar
+
+    c = sdar.SDARConfig(num_hidden_layers=6, denoising_steps=2,
+                        remasking="low_confidence_static")
+    policy = sdar.bf16_policy()
+    params = jax.eval_shape(lambda k: sdar.init_params(c, k, policy),
+                            jax.random.key(0))
+    return ServingEngine(c, params, policy=policy, num_slots=64,
+                         chunk_size=30, max_len=2560)
+
+
+@pytest.mark.parametrize("program", ["chunk", "admit-1024"])
+def test_sdar_programs_compile_for_the_chip_and_fit_it(
+        shape, sdar_engine, program, no_persistent_cache, monkeypatch):
+    """6 whole expert layers (all 128 experts of each), the whole
+    vocabulary, 64 slots of grown keys: the chunk program (30 forwards of
+    64 x 4 positions: the block core in XLA, the commit's withheld write as
+    ``row_block_write``, the draw over 256 x 151,936 logits) and the
+    admission of 4 rows at the 1024 bucket (the flash kernel under the
+    block mask), as the chip traces them.  Arguments, results and
+    temporaries together stay under the chip's 16 GiB: the engine's
+    programs do not donate their state, so it is there twice."""
+    from progen_tpu.ops import gqa, lowering, moe_decode, row_write
+
+    for module in (row_write, gqa, moe_decode):
+        monkeypatch.setattr(module, "_on_tpu", lambda: True)
+    monkeypatch.setattr(lowering, "on_tpu", lambda: True)
+    eng = sdar_engine
+
+    def placed(tree):
+        return jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+
+    params, state = placed(eng._params), placed(eng.state)
+    s, rows, lay = eng.num_slots, eng.admit_rows, eng._layout
+    assert rows == 4
+    if program == "chunk":
+        compiled = jax.jit(lambda *a: eng._block_chunk_impl(*a)).lower(
+            params, state, *placed(lay.chunk_operands())).compile()
+    else:
+        prefill = [shape((rows, 1024), jnp.int32), shape((rows,), jnp.int32),
+                   shape((rows,), jnp.int32), shape((rows,), jnp.uint32),
+                   shape((rows,), jnp.int32), shape((rows,), jnp.float32),
+                   shape(eng._lmask_shape(rows), jnp.bool_)]
+        compiled = jax.jit(lambda *a: eng._admit_impl(*a)).lower(
+            params, state, shape((s,), jnp.int32), shape((s,), jnp.bool_),
+            *prefill, *placed(lay.write_tables(rows))).compile()
+    m = compiled.memory_analysis()
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
+    assert 8.72e9 < weights < 8.73e9 and 2.0e9 < held < 2.1e9
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    # an admission draws no token, so the compiler drops the head, the
+    # final norm and the last layer's experts from it (1.83 GB it does not
+    # even take as arguments): what it computes is the cache
+    floor = weights + 2 * held - (0 if program == "chunk" else 1.84e9)
+    assert floor <= total < 15.5e9, m
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("row_block_write" if program == "chunk"
+            else "gqa_prefill_fwd") in text

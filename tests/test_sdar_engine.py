@@ -1,0 +1,325 @@
+"""SDAR through ``ServingEngine``'s normal path (the seam of
+``decode/family.py``) by diffusion over blocks: primes at every ``P mod 4``
+serve the tokens of the reference's ``generate_block`` (the whole sequence
+recomputed at every forward) under both remasking rules, with the denoise
+forward that kept each; ``stop`` and end of sequence inside a block drop
+what follows; a request's tokens are the same alone and among neighbours
+admitted at other steps; nothing compiles after ``aot_warmup``; the modes
+the family does not state are refused by name; the block step's counters
+reach the registry."""
+
+import dataclasses
+
+import jax
+import numpy as np
+import pytest
+
+from perf.lib import reference_sdar as ref
+from progen_tpu.decode import Request, ServingEngine
+from progen_tpu.decode.engine import SLOTS_PER_ADMIT_ROW
+from progen_tpu.decode.family import UnsupportedFamilyMode, family_for
+from progen_tpu.models.sdar import SDARFamily
+from progen_tpu.observe.metrics import get_registry
+from tests.sdar_tiny import BLOCK, MASK_ID, TINY, as_dict, make
+
+pytestmark = pytest.mark.serving
+
+ADMIT_ROWS = 2
+SLOTS = ADMIT_ROWS * SLOTS_PER_ADMIT_ROW
+ENGINE = dict(num_slots=SLOTS, chunk_size=6, max_len=48)
+TOP_K = 5
+PRIMES = (5, 6, 7, 8, 13, 3)        # every P mod 4; one shorter than a block
+DYNAMIC = dataclasses.replace(TINY, remasking="low_confidence_dynamic",
+                              denoising_steps=4, confidence_threshold=0.3)
+
+
+@pytest.fixture(scope="module")
+def served():
+    return make()
+
+
+@pytest.fixture(scope="module")
+def engine(served):
+    params, policy = served
+    eng = ServingEngine(TINY, params, policy=policy, **ENGINE)
+    eng.warm = eng.aot_warmup()
+    return eng
+
+
+def _allowed(*also_banned):
+    mask = np.ones((TINY.vocab_size,), bool)
+    mask[[0, *also_banned]] = False
+    return mask
+
+
+def _requests(n, seed=0, sampled=False, first_uid=0, mask=None):
+    rng = np.random.default_rng(seed)
+    return [Request(
+        uid=first_uid + i, max_new_tokens=9 + i % 5, seed=50 + i,
+        temperature=0.8 if sampled else 0.0, top_k=TOP_K,
+        logit_mask=_allowed() if mask is None else mask,
+        record_fill_steps=True,
+        tokens=rng.integers(1, MASK_ID, PRIMES[i % len(PRIMES)]).tolist())
+        for i in range(n)]
+
+
+def _serve(engine, reqs):
+    for r in reqs:
+        engine.submit(r)
+    return {c.uid: c for c in engine.run_until_idle(200)}
+
+
+_FORWARDS = {}
+
+
+def _reference_tokens(params, r, config=TINY):
+    """``generate_block`` over ONE compiled forward a configuration (rows
+    padded to the engine's ``max_len``)."""
+    cfg = as_dict(config)
+    if config not in _FORWARDS:
+        fwd = jax.jit(lambda p, t, at: ref.forward_row(
+            p, t, cfg, logit_positions=at)[0])
+
+        def forward(p, t, at):
+            with jax.default_matmul_precision("highest"):
+                return fwd(p, t, at)
+
+        _FORWARDS[config] = forward
+    return ref.generate_block(
+        params, r.tokens, cfg, r.max_new_tokens, forward=_FORWARDS[config],
+        width=ENGINE["max_len"], top_k=r.top_k, temperature=r.temperature,
+        allowed_tokens=r.logit_mask)
+
+
+def test_greedy_requests_at_every_p_mod_4_serve_generate_blocks_tokens(
+        served, engine):
+    """Float32: the same tokens, kept at the same denoise forwards; more
+    requests than slots of an admission run, so rows sit in different
+    phases of their blocks."""
+    reqs = _requests(len(PRIMES))
+    done = _serve(engine, reqs)
+    assert {len(r.tokens) % BLOCK for r in reqs} == {0, 1, 2, 3}
+    for r in reqs:
+        tokens, fills = _reference_tokens(served[0], r)
+        c = done[r.uid]
+        assert c.finish_reason == "length" and c.ok
+        assert c.tokens.tolist() == tokens, r.uid
+        assert c.fill_steps.tolist() == fills, r.uid
+        assert len(tokens) == r.max_new_tokens
+        assert set(fills) <= {0, 1} and MASK_ID not in tokens
+    # a request that does not ask is not told
+    plain = Request(uid=99, tokens=reqs[0].tokens, max_new_tokens=5,
+                    temperature=0.0, logit_mask=_allowed())
+    assert _serve(engine, [plain])[99].fill_steps is None
+
+
+def test_the_dynamic_rule_serves_generate_blocks_tokens(served):
+    """A threshold the top-5 draws pass often: blocks take fewer denoise
+    forwards than the static rule's, row by row."""
+    params, policy = served
+    eng = ServingEngine(DYNAMIC, params, policy=policy, **ENGINE)
+    reqs = _requests(4, seed=3)
+    done = _serve(eng, reqs)
+    kept = np.asarray(eng.model_stats["diffusion.positions_kept"])
+    assert kept.shape == (4,) and kept[0] > kept[1] > 0
+    for r in reqs:
+        tokens, fills = _reference_tokens(params, r, DYNAMIC)
+        assert done[r.uid].tokens.tolist() == tokens
+        assert done[r.uid].fill_steps.tolist() == fills
+
+
+@pytest.mark.parametrize("new", [1, 2, 3, 5, 6, 7])
+def test_stop_inside_a_block_drops_what_follows(served, engine, new):
+    r = Request(uid=new, tokens=list(range(1, 7)), max_new_tokens=new,
+                temperature=0.0, top_k=TOP_K, logit_mask=_allowed())
+    before = dict(engine.model_stats or {})
+    c = _serve(engine, [r])[new]
+    tokens, _ = _reference_tokens(served[0], r)
+    assert c.tokens.tolist() == tokens and len(tokens) == new
+    assert c.finish_reason == "length"
+
+    def moved(key):
+        return float(engine.model_stats[key]) - float(before.get(key, 0))
+
+    assert moved("diffusion.tokens_committed") == new
+    # the prime of 6 leaves 2 positions of its block; whole blocks after
+    blocks = 1 + -(-max(new - 2, 0) // BLOCK)
+    assert moved("diffusion.tokens_dropped") == 2 + (blocks - 1) * 4 - new
+    assert moved("diffusion.commit_forwards") == blocks
+
+
+def test_end_of_sequence_inside_a_block_ends_the_request(served, engine):
+    """Only tokens 0 and 7 allowed: the best of the two at each position,
+    so a block soon holds a 0; the tokens after it are dropped."""
+    mask = np.zeros((TINY.vocab_size,), bool)
+    mask[[0, 7]] = True
+    hit = 0
+    for seed in range(6):
+        r = _requests(1, seed=seed, mask=mask, first_uid=200 + seed)[0]
+        r.max_new_tokens = 14
+        c = _serve(engine, [r])[r.uid]
+        tokens, fills = _reference_tokens(served[0], r)
+        assert c.tokens.tolist() == tokens
+        assert c.fill_steps.tolist() == fills
+        if 0 in tokens:
+            hit += 1
+            assert tokens.index(0) == len(tokens) - 1
+            assert c.finish_reason == "eos"
+    assert hit >= 3
+
+
+def test_a_requests_tokens_do_not_depend_on_its_neighbours(served, engine):
+    """Sampled: alone, and among neighbours admitted steps before it."""
+    reqs = _requests(5, seed=9, sampled=True, first_uid=300)
+    alone = {r.uid: _serve(engine, [r])[r.uid] for r in reqs}
+    for r in reqs[:3]:
+        engine.submit(r)
+    early = engine.step() + engine.step()
+    for r in reqs[3:]:
+        engine.submit(r)
+    together = {c.uid: c for c in early + engine.run_until_idle(200)}
+    for r in reqs:
+        assert together[r.uid].tokens.tolist() == alone[r.uid].tokens.tolist()
+        assert (together[r.uid].fill_steps.tolist()
+                == alone[r.uid].fill_steps.tolist())
+    # and they are draws: another seed, other tokens
+    again = dataclasses.replace(reqs[0], uid=399, seed=1234)
+    assert (_serve(engine, [again])[399].tokens.tolist()
+            != alone[reqs[0].uid].tokens.tolist())
+
+
+def test_sampled_tokens_keep_to_the_top_k_of_the_forward_that_kept_them(
+        served, engine):
+    """The probe rule at tiny size: the reference's replay of the served
+    trajectory ranks every kept token among its 5 best allowed logits at the
+    forward that kept it (float32: but for near-ties)."""
+    params = served[0]
+    reqs = _requests(4, seed=11, sampled=True, first_uid=400)
+    done = _serve(engine, reqs)
+    for r in reqs:
+        c = done[r.uid]
+        row, positions, allowed, index = ref.replay_row(
+            r.tokens, c.tokens, c.fill_steps, as_dict(TINY), 2, width=96)
+        with jax.default_matmul_precision("highest"):
+            logits, _ = ref.forward_row(
+                params, row, as_dict(TINY), positions=positions,
+                allowed=allowed, logit_positions=np.maximum(index, 0))
+        logits = np.array(logits)
+        logits[:, [0, MASK_ID]] = -np.inf
+        ok = index >= 0
+        served_logit = logits[np.arange(len(index)), c.tokens]
+        kth = np.sort(logits, axis=-1)[:, -TOP_K]
+        assert (served_logit[ok] >= kth[ok] - 1e-4).all()
+
+
+def test_nothing_compiles_after_warmup_and_the_state_holds_a_block(engine):
+    assert engine.warm["programs"] == 5     # buckets 8, 16, 32, 48; the chunk
+    # (the counter is the process's: other engines of this file compile)
+    before = engine.status()["compiles_in_step"]
+    _serve(engine, _requests(len(PRIMES), seed=2, first_uid=500))
+    assert engine.status()["compiles_in_step"] == before
+    state = engine.state
+    assert state["block"].shape == (SLOTS, BLOCK)
+    assert state["fill"].shape == (SLOTS, ENGINE["max_len"])
+    assert state["cursor"].shape == state["dstep"].shape == (SLOTS,)
+    assert state["caches"]["l0"]["k"].shape == (SLOTS, 2, 48, 16)
+    assert engine.lowerings == {
+        "gqa_prefill": "xla", "gqa_block_decode": "xla",
+        "moe_experts": "xla", "row_write": "scatter"}
+    assert engine.status()["gqa_block_decode"] == "xla"
+    assert engine.block_length == BLOCK
+
+
+@pytest.mark.parametrize("mode", [
+    {"paged": True}, {"spec": True}, {"disagg": True}, {"quantize": "weights"},
+    {"lora_bank": {}}], ids=lambda m: next(iter(m)))
+def test_a_mode_the_family_does_not_state_is_refused_by_name(served, mode):
+    params, policy = served
+    with pytest.raises(UnsupportedFamilyMode, match=next(iter(mode))):
+        ServingEngine(TINY, params, policy=policy, **ENGINE, **mode)
+
+
+def test_the_engine_refuses_what_a_block_cannot_take(served):
+    params, policy = served
+    with pytest.raises(ValueError, match="whole number"):
+        ServingEngine(TINY, params, policy=policy, num_slots=SLOTS,
+                      chunk_size=4, max_len=46)
+    eng = ServingEngine(TINY, params, policy=policy, **ENGINE)
+    with pytest.raises(UnsupportedFamilyMode, match="logit_mask"):
+        eng.submit(Request(uid=0, tokens=[1, 2], max_new_tokens=4,
+                           logit_mask=np.ones((4, TINY.vocab_size), bool)))
+
+
+def test_family_for_returns_the_family_and_what_it_states(served):
+    family = family_for(TINY, served[1])
+    assert isinstance(family, SDARFamily)
+    assert family.name == "sdar" and family.modes == frozenset()
+    assert family.idle_length == 0 and not family.position_masks
+    assert (family.block_length, family.mask_token_id,
+            family.denoising_steps, family.remasking) == (
+                BLOCK, MASK_ID, 2, "low_confidence_static")
+    assert family.buckets(20, 48) == [8, 16, 32]
+    with pytest.raises(NotImplementedError, match="block_step"):
+        family.decode_step(None, None, None, None, None)
+    # every other family states one token a row a step
+    from progen_tpu.models.configs import SMALL
+    from tests.trinity_tiny import TINY as TRINITY
+
+    assert family_for(TRINITY, served[1]).block_length is None
+    assert family_for(SMALL, served[1]).block_length is None
+
+
+def test_counters_ride_the_flags_fetch_into_the_registry(served):
+    params, policy = served
+    eng = ServingEngine(TINY, params, policy=policy, **ENGINE)
+    reqs = _requests(3, seed=5)
+    _serve(eng, reqs)
+    stats = eng.model_stats
+    new = sum(r.max_new_tokens for r in reqs)
+    assert stats["diffusion.tokens_committed"] == new
+    # each request's blocks: from the prime's last whole block to stop
+    spans = [(len(r.tokens) // BLOCK * BLOCK,
+              len(r.tokens) + r.max_new_tokens) for r in reqs]
+    blocks = sum(-(-(stop - whole) // BLOCK) for whole, stop in spans)
+    generated = sum(whole + -(-(stop - whole) // BLOCK) * BLOCK
+                    - len(r.tokens) for (whole, stop), r in zip(spans, reqs))
+    assert stats["diffusion.commit_forwards"] == blocks
+    assert stats["diffusion.tokens_dropped"] == generated - new
+    kept = np.asarray(stats["diffusion.positions_kept"])
+    assert kept.sum() == generated and kept.shape == (2,)
+    forwards = stats["diffusion.forwards"]
+    assert blocks * 2 <= forwards <= blocks * 3
+    assert stats["attn.decode_rows"] == BLOCK * forwards
+    assert stats["moe.tokens"] == 3 * (
+        BLOCK * forwards + sum(whole for whole, _ in spans))
+    assert stats["moe.held_load"].sum() == 2 * stats["moe.tokens"]
+    snap = get_registry().snapshot()
+    for name in ("diffusion.forwards", "diffusion.commit_forwards",
+                 "diffusion.tokens_committed", "diffusion.tokens_dropped",
+                 "moe.tokens", "attn.decode_rows", "attn.context_tokens"):
+        assert snap[name]["value"] == stats[name], name
+    assert snap["diffusion.positions_kept.0"]["value"] == kept[0]
+    assert snap["diffusion.positions_kept.1"]["value"] == kept[1]
+    assert snap["moe.held_assignments"]["value"] == stats[
+        "moe.held_load"].sum()
+
+
+def test_a_snapshot_replays_a_block_request_token_for_token(served, engine):
+    """Host-side state only: the replay starts from the prime and the seed,
+    so it serves the same blocks, and still reports their fill steps."""
+    params, policy = served
+    reqs = _requests(3, seed=21, sampled=True, first_uid=600)
+    want = _serve(engine, reqs)
+    for r in reqs:
+        engine.submit(r)
+    engine.step()
+    snap = engine.snapshot()
+    assert all(e["record_fill_steps"] for e in snap["requests"])
+    fresh = ServingEngine(TINY, params, policy=policy, **ENGINE)
+    assert fresh.restore(snap) == 3
+    got = {c.uid: c for c in fresh.run_until_idle(200)}
+    engine.run_until_idle(200)
+    for r in reqs:
+        assert got[r.uid].tokens.tolist() == want[r.uid].tokens.tolist()
+        assert (got[r.uid].fill_steps.tolist()
+                == want[r.uid].fill_steps.tolist())
