@@ -958,8 +958,9 @@ class ServingEngine:
         ``"pallas"`` / ``"xla"``), a grouped-query attention's prefill core
         (``"gqa_prefill"``, the same two), the core of a step of B queries
         a slot (``"gqa_block_decode"``: ``"xla"``) and the held experts'
-        product (``"moe_experts"``, the same two, stated per program: it is
-        in both)."""
+        product (``"moe_experts"``: ``"pallas"`` / ``"pallas_grouped"`` /
+        ``"xla"``, stated per program: it is in both, and an engine's
+        admission programs, one a bucket, may differ — joined by ``+``)."""
 
         @wraps(impl)
         def traced(*args):
@@ -968,7 +969,13 @@ class ServingEngine:
             took = {op: "+".join(sorted(paths))
                     for op, paths in chosen.items()}
             self.lowerings.update(took)
-            self.program_lowerings.setdefault(program, {}).update(took)
+            # an engine traces one admission program a bucket, and an op
+            # may take another lowering at another bucket's shapes: a
+            # program's entry names all that its traces took
+            mine = self.program_lowerings.setdefault(program, {})
+            for op, paths in chosen.items():
+                seen = paths | set(filter(None, mine.get(op, "").split("+")))
+                mine[op] = "+".join(sorted(seen))
             return out
 
         return jax.jit(traced)

@@ -49,7 +49,7 @@ import numpy as np
 
 from progen_tpu.core.precision import Policy
 from progen_tpu.models import experts, latent
-from progen_tpu.models.experts import expert_passes, held_experts
+from progen_tpu.models.experts import held_experts, kernel_counters
 from progen_tpu.models.latent import F32, bf16_policy, rms_norm, swiglu
 
 
@@ -259,7 +259,7 @@ def moe_share(u, layer, c: DeepSeekV2Config, live):
     stats = {"moe.tokens": jnp.sum(live).astype(F32),
              "moe.held_groups_chosen": mine.astype(F32),
              "moe.held_load": load.astype(F32),
-             "moe.expert_passes": expert_passes(u, layer["experts"], load)}
+             **kernel_counters(u, layer["experts"], load)}
     return y.astype(u.dtype), ids, stats
 
 

@@ -208,8 +208,9 @@ def prefill(stack, blocks, params, tokens, lengths, config, policy: Policy,
     x, stats, chosen, _ = stack(x, params, c, attend, live)
     if "moe.held_load" in stats:
         stats["moe.prefill_held"] = jnp.sum(stats["moe.held_load"])
-        # a decode step's counter, as ``moe.experts_touched`` beside it
+        # a decode step's counters, as ``moe.experts_touched`` beside them
         stats["moe.expert_passes"] = jnp.zeros((), F32)
+        stats["moe.rows_computed"] = jnp.zeros((), F32)
     if logit_positions is None:       # a row of no tokens reads position 0
         logit_positions = jnp.maximum(lengths - 1, 0)[:, None]
     x = jnp.take_along_axis(x.reshape(r, n, -1),

@@ -7,32 +7,33 @@ The router is as wide as published and picks ``moe_topk`` whatever the chip
 holds; the layer adds the terms of the ``experts_held`` real experts from
 ``first_expert`` on and leaves out what the absent experts would add.
 Nothing stands in for absent chips.  No assignment is ever dropped, and
-:func:`held_experts` computes them in one of two ways, chosen from what the
-code can observe and never from a knob (``ops/moe_decode.py:fitted_tile``;
-noted under ``"moe_experts"``, ``ops/lowering.py``):
+:func:`held_experts` computes them in one of three ways, chosen from what
+the code can observe and never from a knob (``ops/moe_decode.py:
+fitted_tile``; noted under ``"moe_experts"``, ``ops/lowering.py``).  On a
+TPU with no mesh in scope, tokens and weights of one float type and widths
+on the lane tile, a call's TOKENS decide:
 
-* a call that carries a DECODE step's handful of tokens (at most 128, on a
-  TPU with no mesh in scope, tokens and weights of one float type, widths
-  on the lane tile) goes through the kernel ``moe_decode_fwd``: each
-  touched expert's gate, up and down matrices streamed once, back to back,
-  every token through every touched expert with its routing weight (zero
-  where it is not the expert's) selecting, so there is neither sort nor
-  gather nor scatter-add;
-* every other call (an admission's thousands of tokens, the CPU, a mesh)
-  groups the tokens by held expert (a sort of the assignments) and
-  multiplies them by ``jax.lax.ragged_dot`` in windows of ``capacity``
+* a DECODE step's handful (at most 128: ``"pallas"``) goes through the
+  kernel ``moe_decode_fwd``: each touched expert's gate, up and down
+  matrices streamed once, back to back, every token through every touched
+  expert with its routing weight (zero where it is not the expert's)
+  selecting, so there is neither sort nor gather nor scatter-add;
+* a few hundred (129 to 1,024 — a block-diffusion step's ``slots x
+  block_length``, 256 in its cell, and the admissions under 2,048 tokens:
+  ``"pallas_grouped"``) go through the kernel ``moe_grouped_fwd``: the
+  same stream, one pass an expert, but each expert multiplied by its OWN
+  rows only, gathered into row tiles of 32 — all rows through every expert
+  would cost more MXU time than the stream takes from 256 rows on — and
+  the terms gathered back per token (:func:`_grouped`);
+* every other call (an admission's thousands of tokens, the CPU, a mesh:
+  ``"xla"``) groups the tokens by held expert (a sort of the assignments)
+  and multiplies them by ``jax.lax.ragged_dot`` in windows of ``capacity``
   assignments: a window that overflows runs the loop again.
 
 A chip may also hold the WHOLE layer (``experts_held == router_width``,
 ``first_expert`` 0: ``models/sdar.py``, one stage of a pipeline): every
 assignment is then its own, ``moe.held_load`` is the router's whole
-histogram, and nothing below changes.  And a call may lie BETWEEN the two
-sizes above: a block-diffusion step sends a layer ``slots x block_length``
-tokens (256 in its cell: twice the kernel's 128, a sixteenth of an
-admission's), and as the rule stands takes the grouped form — one window
-of ``ragged_dot`` over 2,048 assignments to 128 experts, 16 rows an expert,
-which streams the layer's 1.2 GB at a third of the rate the kernel does
-(PERF.md section 6, PR 41; its first ``perf_opt``, section 7).
+histogram, and nothing below changes.
 
 A config here has ``experts_held``, ``first_expert``, ``moe_topk`` and
 ``router_width``.
@@ -52,7 +53,8 @@ F32 = jnp.float32
 # family adds its own keys and its attention's (``latent.STAT_KEYS``,
 # ``trinity.ATTN_STAT_KEYS``) to these
 STAT_KEYS = ("moe.tokens", "moe.held_load", "moe.prefill_held",
-             "moe.decode_layers", "moe.experts_touched", "moe.expert_passes")
+             "moe.decode_layers", "moe.experts_touched", "moe.expert_passes",
+             "moe.rows_computed")
 
 
 def zero_stats(keys, held: int) -> dict:
@@ -87,16 +89,17 @@ def held_experts(u, ids, w, live, experts, c, capacity=None):
     changes no result."""
     t, k = ids.shape
     held = c.experts_held
-    tile = moe_decode.fitted_tile(u, experts)
-    note("moe_experts", "xla" if tile is None else "pallas")
+    tiles = moe_decode.fitted_tile(u, experts)
+    note("moe_experts", "xla" if tiles is None else tiles.lowering)
     with jax.named_scope("moe.experts"):
         local = ids - c.first_expert
         mine = (local >= 0) & (local < held) & live[:, None]
         group = jnp.where(mine, local, held).reshape(-1)
-        if tile is not None:
+        if tiles is not None:
             load = jnp.bincount(group, length=held + 1)[:held]
-            return _streamed(u, group.reshape(t, k), w, load, experts,
-                             tile), load
+            form = _streamed if tiles.rows is None else _grouped
+            return form(u, group.reshape(t, k), w, load, experts,
+                        tiles), load
         order = jnp.argsort(group)                    # held first, by expert
         load = jnp.bincount(group, length=held + 1)[:held]
         ends = jnp.cumsum(load)
@@ -129,7 +132,7 @@ def held_experts(u, ids, w, live, experts, c, capacity=None):
         return y, load
 
 
-def _streamed(u, group, w, load, experts, tile):
+def _streamed(u, group, w, load, experts, tiles):
     """The terms of ``held_experts`` through ``moe_decode_fwd``: the
     touched experts in ascending order, each with the routing weight of
     every token (zero where ``group (T, k)`` does not name it)."""
@@ -140,15 +143,67 @@ def _streamed(u, group, w, load, experts, tile):
     wt = jnp.sum(jnp.where(names, w.astype(F32)[None], 0.0), axis=-1)
     return moe_decode.pallas_expert_terms(
         u, eid, jnp.sum(touched), wt[eid], experts["wg"], experts["wu"],
-        experts["wd"], tile=tile)
+        experts["wd"], tile=tiles.inner)
 
 
-def expert_passes(u, experts, load):
-    """How many times the lowering :func:`held_experts` takes for ``u``
-    streams an expert's three matrices, by the lowering's own reckoning, as
-    a float32 scalar: one work item a touched expert under the kernel (all
-    of a call's tokens fit one item), 0 under the XLA form, whose reads the
+def _grouped(u, group, w, load, experts, tiles):
+    """The terms of ``held_experts`` through ``moe_grouped_fwd``: each
+    touched expert's own rows, gathered into whole row tiles (an expert's
+    tiles side by side, in ascending order of expert, so that its matrices
+    are streamed once), the result in that grouped order and summed back
+    per token by a gather of its ``k`` rows."""
+    t, k = group.shape
+    held, rt = load.shape[0], tiles.rows
+    # the work list: an expert with rows takes ceil(rows / rt) items
+    items = t * k // rt + min(held, t * k)
+    ntile = -(-load // rt)
+    tile_end = jnp.cumsum(ntile)
+    tile_start = tile_end - ntile                  # an expert's first item
+    item = jnp.arange(items)
+    eid = jnp.minimum(jnp.searchsorted(tile_end, item, side="right"),
+                      held - 1)
+    # what each grouped row holds: the expert's r-th assignment in token
+    # order (a stable sort's), or nothing (a tile's padding, weight 0)
+    order = jnp.argsort(group.reshape(-1))
+    start = jnp.cumsum(load) - load
+    r = ((item - tile_start[eid]) * rt)[:, None] + jnp.arange(rt)
+    real = (item < tile_end[-1])[:, None] & (r < load[eid][:, None])
+    src = order[jnp.where(real, start[eid][:, None] + r, 0)].reshape(-1)
+    out = moe_decode.pallas_grouped_terms(
+        u[src // k], eid, tile_end[-1],
+        jnp.where(real.reshape(-1), w.reshape(-1)[src], 0.0), experts["wg"],
+        experts["wu"], experts["wd"], row_tile=rt, tile=tiles.inner)
+    # where each assignment's term is: its expert's first row and its place
+    # among the expert's assignments (the inverse of ``order``)
+    e = jnp.minimum(group, held - 1)
+    row = (tile_start * rt - start)[e] + jnp.argsort(order).reshape(t, k)
+    mine = group < held
+    terms = out[jnp.where(mine, row, 0)]                     # (T, k, h)
+    return jnp.sum(jnp.where(mine[..., None], terms, 0.0), axis=1)
+
+
+def kernel_counters(u, experts, load) -> dict:
+    """What the lowering :func:`held_experts` takes for ``u`` does, by the
+    lowering's own reckoning, as float32 scalars: ``moe.expert_passes``,
+    how many times it streams an expert's three matrices, and
+    ``moe.rows_computed``, the rows it passes through them.  Under
+    ``moe_decode_fwd`` one work item a touched expert, all of the call's
+    (padded) rows in each; under ``moe_grouped_fwd`` one item a row tile of
+    an expert's own rows, and a pass a touched expert where a step holds
+    the whole inner width (consecutive items keep the weight blocks), a
+    pass an item where it does not; 0 under the XLA form, whose reads the
     program cannot know."""
-    if moe_decode.fitted_tile(u, experts) is None:
-        return jnp.zeros((), F32)
-    return jnp.sum(load > 0).astype(F32)
+    tiles = moe_decode.fitted_tile(u, experts)
+    touched = jnp.sum(load > 0)
+    if tiles is None:
+        passes = rows = jnp.zeros((), F32)
+    elif tiles.rows is None:
+        passes = touched
+        rows = touched * (-(-u.shape[0] // moe_decode.ROW_GROUP)
+                          * moe_decode.ROW_GROUP)
+    else:
+        items = jnp.sum(-(-load // tiles.rows))
+        whole = tiles.inner == experts["wg"].shape[-1]
+        passes, rows = touched if whole else items, items * tiles.rows
+    return {"moe.expert_passes": passes.astype(F32),
+            "moe.rows_computed": rows.astype(F32)}

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from progen_tpu.core.precision import Policy
 from progen_tpu.models import experts, latent
 from progen_tpu.models.experts import (  # noqa: F401
-    expert_passes, held_experts, moe_capacity)
+    held_experts, kernel_counters, moe_capacity)
 from progen_tpu.models.latent import (  # noqa: F401
     F32,
     bf16_policy,
@@ -186,7 +186,7 @@ def moe_share(u, layer, c: LongCatConfig, live):
     stats = {"moe.tokens": jnp.sum(live).astype(F32),
              "moe.real_chosen": real.astype(F32),
              "moe.held_load": load.astype(F32),
-             "moe.expert_passes": expert_passes(u, layer["experts"], load)}
+             **kernel_counters(u, layer["experts"], load)}
     return y.astype(u.dtype), ids, stats
 
 

@@ -54,7 +54,7 @@ from progen_tpu.models.driver import (  # noqa: F401
     mm,
     rms_norm,
 )
-from progen_tpu.models.experts import expert_passes, held_experts
+from progen_tpu.models.experts import held_experts, kernel_counters
 from progen_tpu.ops import gqa
 
 REMASKING = ("low_confidence_static", "low_confidence_dynamic")
@@ -289,7 +289,7 @@ def _layers(x, params, c, attend, live):
         stats = experts.add_stats(stats, {
             "moe.tokens": jnp.sum(live).astype(F32),
             "moe.held_load": load.astype(F32),
-            "moe.expert_passes": expert_passes(t, layer["experts"], load)})
+            **kernel_counters(t, layer["experts"], load)})
         touched += jnp.sum(load > 0).astype(F32)
         chosen.append(ids)
         x = a + y.astype(x.dtype)

@@ -77,7 +77,7 @@ from progen_tpu.models.driver import (  # noqa: F401
     rms_norm,
     swiglu,
 )
-from progen_tpu.models.experts import expert_passes, held_experts
+from progen_tpu.models.experts import held_experts, kernel_counters
 from progen_tpu.ops import gqa
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -330,7 +330,7 @@ def moe_share(u, layer, c: TrinityConfig, live):
     y, load = held_experts(u, ids, w, live, layer["experts"], c)
     stats = {"moe.tokens": jnp.sum(live).astype(F32),
              "moe.held_load": load.astype(F32),
-             "moe.expert_passes": expert_passes(u, layer["experts"], load)}
+             **kernel_counters(u, layer["experts"], load)}
     return y.astype(u.dtype), ids, stats
 
 

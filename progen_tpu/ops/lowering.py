@@ -2,11 +2,12 @@
 note of which one it took.
 
 ``ops/row_write.py``, ``ops/mla_prefill.py``, ``ops/mla_decode.py``,
-``ops/gqa.py`` and ``models/experts.py:held_experts`` (its kernel is
-``ops/moe_decode.py``; the note ``"moe_experts"``: ``"pallas"`` for a decode
-step's handful of tokens, ``"xla"`` for an admission's thousands) each keep
-a Pallas kernel and an XLA form behind one function and choose between them
-from the backend, the mesh in scope and the shapes, never from a knob.
+``ops/gqa.py`` and ``models/experts.py:held_experts`` (its kernels are
+``ops/moe_decode.py``'s; the note ``"moe_experts"``: ``"pallas"`` for a
+decode step's handful of tokens, ``"pallas_grouped"`` for a block step's
+few hundred, ``"xla"`` for an admission's thousands) each keep a Pallas
+lowering and an XLA form behind one function and choose between them from
+the backend, the mesh in scope and the shapes, never from a knob.
 (``ops/gqa.py:block_decode_attention`` has the XLA form alone so far and
 notes it as ``"gqa_block_decode"``, so that the note is there to change.)
 The choice is made while a program is traced, so a caller that traces one

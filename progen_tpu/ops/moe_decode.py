@@ -1,6 +1,8 @@
-"""The held experts' product for a DECODE step's handful of tokens: every
-touched expert's gate, up and down matrices streamed from HBM once, back
-to back, through one software pipeline.
+"""The held experts' product where the WEIGHTS are the work: every touched
+expert's gate, up and down matrices streamed from HBM once, back to back,
+through one software pipeline — for a decode step's handful of tokens
+(``moe_decode_fwd``) and for a block step's few hundred
+(``moe_grouped_fwd``).
 
 :func:`pallas_expert_terms` takes ``u (T, h)``, a list ``eid (held,)`` of
 the experts to visit (the first ``n_real`` entries are real), each listed
@@ -17,20 +19,38 @@ through every listed expert and the routing weight (zero for the others)
 selects: no sort, no gather and no scatter-add around the kernel, and the
 time is the touched experts' bytes over the rate they stream at.
 
-The kernel ``moe_decode_fwd``: grid ``(held, inner / ik)``, one ITEM an
-expert of the list, one step an ``ik``-wide slice of its inner width: the
-tiles ``wg[:, cols]``, ``wu[:, cols]`` ``(h, ik)`` and ``wd[cols] (ik, h)``
-arrive together, ``silu(u wg) * (u wu)`` for those columns is formed in
-float32 from compute-dtype operands and cast once, and its product with
-the ``wd`` tile (float32) is weighted in float32 and added to the resident
-float32 output — the down product's sum over inner tiles and the sum over
-experts are one accumulator.  ``eid`` and ``n_real`` are scalar-prefetched
-and the index maps read them, so while an expert's last tiles are used the
-next expert's first are in flight (the pipeline does not drain between
-experts); an item past ``n_real`` points at the blocks the last real item
-left, so nothing is fetched for it, and does no work.  (Tiles cut along
-``h`` instead — contiguous runs, a float32 ``(T, inner)`` accumulator, two
-phases an expert — stream no faster on a v5e: PERF.md section 6, PR 37.)
+Past 128 tokens that trick stops paying (256 rows through 117 experts are
+1.4 ms of the MXU's peak beside 1.35 ms of stream, and a tile is then used
+for twice the time it loads in), while an expert's OWN rows are still few
+(a block-diffusion step: 256 tokens, 16-20 rows an expert).
+:func:`pallas_grouped_terms` takes the rows GROUPED by expert instead —
+``xs (items * row_tile, h)``, every run of ``row_tile`` rows one ITEM, all
+of one expert ``eid[i]``, an expert with more rows than a tile in
+consecutive items, each row with its routing weight (zero for a tile's
+padding) — and returns each real item's rows through its expert, weighted,
+in the same grouped order; the caller (``models/experts.py:_grouped``)
+gathers the rows in and sums each token's ``k`` rows back by a gather.
+
+Both kernels: grid ``(items, inner / ik)``, one step an ``ik``-wide slice
+of the item's expert: the tiles ``wg[:, cols]``, ``wu[:, cols]`` ``(h,
+ik)`` and ``wd[cols] (ik, h)`` arrive together, ``silu(x wg) * (x wu)`` for
+those columns is formed in float32 from compute-dtype operands and cast
+once, and its product with the ``wd`` tile (float32) is weighted in float32
+and added to a float32 output block — the whole resident ``y`` under
+``moe_decode_fwd`` (the down product's sum over inner tiles and the sum over
+experts are one accumulator), the item's own ``(row_tile, h)`` block under
+``moe_grouped_fwd``.  ``eid`` and ``n_real`` are scalar-prefetched and the
+index maps read them, so while an expert's last tiles are used the next
+expert's first are in flight (the pipeline does not drain between
+experts); consecutive items of one expert name the same weight blocks, so
+where a step holds the whole inner width (SDAR's 768) its second row tile
+costs MXU time and no second stream; an item past ``n_real`` points at the
+blocks the last real item left, so nothing is fetched or written for it,
+and does no work.  (Tiles cut along ``h`` instead — contiguous runs, a
+float32 ``(T, inner)`` accumulator, two phases an expert — stream no faster
+on a v5e: PERF.md section 6, PR 37.  The tile of an expert's rows formed
+INSIDE the kernel by a one-hot selection on the MXU, ``u`` and ``y``
+resident: PERF.md section 6, PR 42.)
 
 Which lowering a call of ``models/experts.py:held_experts`` takes is
 decided by :func:`fitted_tile` from what the code can observe and never
@@ -39,6 +59,8 @@ from a knob (as ``ops/mla_decode.py`` and its siblings), and noted under
 """
 
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +79,29 @@ LANE = 128              # widths and tiles are whole lane tiles
 # largest whole number of lane tiles that divides the inner width under it
 STEP_BYTES = 12 << 20
 ROW_GROUP = 16          # token rows are padded to whole bfloat16 sublane tiles
+# above MAX_TOKENS an expert gets its OWN rows, in tiles of this many.  One
+# layer of a block step's shapes alone on a v5e (256 tokens, 128 experts of
+# 2048 x 768, top-8, 15 rows an expert and the fullest 2.8 times the mean,
+# about what the cell reads), ms a call: 1.88 at 16, 1.83 at 32, 1.92 at 64
+# beside 4.68 for the XLA form; at 1,024 tokens 2.92, 2.53, 2.58 beside
+# 5.74 (at 128, under a synthetic skew of 7 times the mean: 2.57 and 3.27
+# where 32 reads 1.85 and 2.42) — a taller tile is more padding to gather,
+# a shorter one more items.  The cell end to end, tokens a second: 2,821 at
+# 16, 2,833 at 32, 2,756 at 64 (PERF.md section 6, PR 42)
+ROW_TILE = 32
+# the most tokens a call may carry and take a kernel at all.  The same
+# layer, XLA form | this kernel: 512 tokens 4.97 | 1.98 ms, 1,024 5.74 |
+# 2.53, 2,048 (under the synthetic skew) 7.34 | 3.99 — the kernel wins
+# wherever it was measured, so the edge is not the kernel's but the largest
+# admission shape measured END TO END with it, 1,024 tokens: against an edge
+# of 512 a cell that holds the layer whole gains 0.6 % of its tokens a
+# second (4 rows x 256: 64 rows an expert) and a cell that holds 1/48 of
+# the router reads the same within its noise (2 rows x 512: 16 rows an
+# expert, and 672 MiB of temporaries under that program's peak).  From
+# 2,048 on an expert's rows are MXU work, and `_grouped`'s static bound of
+# items (T k / ROW_TILE + held, whatever share of the router the chip
+# holds) is the cost to cure first: ROADMAP S9 (b)
+MAX_GROUPED_TOKENS = 1024
 
 
 def xla_expert_terms(u, eid, n_real, wt, wg, wu, wd):
@@ -74,6 +119,19 @@ def xla_expert_terms(u, eid, n_real, wt, wg, wu, wd):
     return jax.lax.fori_loop(0, n_real, item, jnp.zeros(u.shape, F32))
 
 
+def _item_product(x_ref, wt_ref, wg_ref, wu_ref, wd_ref):
+    """One step of either kernel: the rows ``x (R, h)`` through an inner
+    slice of one expert, and their routing weights ``(R, 1)``; what the
+    step adds is ``where(w != 0, out * w, 0)``: a row whose weight is zero
+    adds nothing, whatever it holds."""
+    x = x_ref[...]
+    gate = jnp.dot(x, wg_ref[...], preferred_element_type=F32)
+    up = jnp.dot(x, wu_ref[...], preferred_element_type=F32)
+    act = (gate * jax.nn.sigmoid(gate) * up).astype(x.dtype)
+    out = jnp.dot(act, wd_ref[...], preferred_element_type=F32)
+    return out, wt_ref[...]
+
+
 def _kernel(eid_ref, n_ref, u_ref, wt_ref, wg_ref, wu_ref, wd_ref, y_ref):
     from jax.experimental import pallas as pl
 
@@ -85,13 +143,28 @@ def _kernel(eid_ref, n_ref, u_ref, wt_ref, wg_ref, wu_ref, wd_ref, y_ref):
 
     @pl.when(i < n_ref[0])
     def _():
-        x = u_ref[...]                                       # (T, h)
-        gate = jnp.dot(x, wg_ref[...], preferred_element_type=F32)
-        up = jnp.dot(x, wu_ref[...], preferred_element_type=F32)
-        act = (gate * jax.nn.sigmoid(gate) * up).astype(x.dtype)
-        out = jnp.dot(act, wd_ref[...], preferred_element_type=F32)
-        w = wt_ref[...]                                      # (T, 1)
+        out, w = _item_product(u_ref, wt_ref, wg_ref, wu_ref, wd_ref)
         y_ref[...] += jnp.where(w != 0, out * w, 0.0)
+
+
+def _grouped_kernel(eid_ref, n_ref, x_ref, wt_ref, wg_ref, wu_ref, wd_ref,
+                    o_ref):
+    from jax.experimental import pallas as pl
+
+    i, s = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(i < n_ref[0])
+    def _():
+        out, w = _item_product(x_ref, wt_ref, wg_ref, wu_ref, wd_ref)
+        term = jnp.where(w != 0, out * w, 0.0)
+
+        @pl.when(s == 0)
+        def _():
+            o_ref[...] = term
+
+        @pl.when(s != 0)
+        def _():
+            o_ref[...] += term
 
 
 def inner_tile(h: int, inner: int, itemsize: int) -> int:
@@ -104,36 +177,26 @@ def inner_tile(h: int, inner: int, itemsize: int) -> int:
     return best
 
 
-def pallas_expert_terms(u, eid, n_real, wt, wg, wu, wd, *, tile=None,
-                        interpret=None):
-    """The kernel lowering; ``interpret=None`` auto-selects the Pallas
-    interpreter off-TPU; ``tile`` (of the inner width) defaults to
-    :func:`inner_tile`."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    if interpret is None:
-        interpret = not _on_tpu()
-    t, h = u.shape
-    items, _, inner = wg.shape
-    itemsize = u.dtype.itemsize
-    ik = tile or inner_tile(h, inner, itemsize)
-    if inner % ik:
-        raise ValueError(f"tile {ik} does not divide inner = {inner}")
-    steps = inner // ik
-    rows = -(-t // ROW_GROUP) * ROW_GROUP       # whole sublane tiles
-    u = jnp.pad(u, ((0, rows - t), (0, 0)))
-    wt = jnp.pad(wt.astype(F32), ((0, 0), (0, rows - t)))[..., None]
+def _work_list(eid, n_real):
+    """``(eid, n_real (1,))`` as the kernels prefetch them: past the real
+    items the list repeats the last real one, so that the index maps name
+    the blocks already held."""
     n_real = jnp.asarray(n_real, jnp.int32).reshape(1)
     last = jnp.maximum(n_real[0] - 1, 0)
     eid = eid.astype(jnp.int32)
-    eid = jnp.where(jnp.arange(items) < n_real[0], eid, eid[last])
+    return jnp.where(jnp.arange(eid.shape[0]) < n_real[0], eid,
+                     eid[last]), n_real
 
-    def fixed(i, s, eid_ref, n_ref):
-        return 0, 0
 
-    def weight_map(i, s, eid_ref, n_ref):
-        return jnp.minimum(i, jnp.maximum(n_ref[0] - 1, 0)), 0, 0
+def _item_map(i, s, eid_ref, n_ref):
+    """An item's own block of a per-item operand; past the list, the last
+    real item's."""
+    return jnp.minimum(i, jnp.maximum(n_ref[0] - 1, 0))
+
+
+def _weight_specs(h, ik, steps):
+    """Block specs of the streamed ``wg``, ``wu`` and ``wd`` tiles."""
+    from jax.experimental import pallas as pl
 
     def tile_of(i, s, n_ref):
         # past the list: the last tile, which the last real item left
@@ -144,6 +207,43 @@ def pallas_expert_terms(u, eid, n_real, wt, wg, wu, wd, *, tile=None,
 
     def down_map(i, s, eid_ref, n_ref):
         return eid_ref[i], tile_of(i, s, n_ref), 0
+
+    return [pl.BlockSpec((None, h, ik), gate_up_map),
+            pl.BlockSpec((None, h, ik), gate_up_map),
+            pl.BlockSpec((None, ik, h), down_map)]
+
+
+def _inner_steps(h, inner, itemsize, tile):
+    ik = tile or inner_tile(h, inner, itemsize)
+    if inner % ik:
+        raise ValueError(f"tile {ik} does not divide inner = {inner}")
+    return ik, inner // ik
+
+
+def pallas_expert_terms(u, eid, n_real, wt, wg, wu, wd, *, tile=None,
+                        interpret=None):
+    """The kernel lowering for at most ``MAX_TOKENS`` tokens;
+    ``interpret=None`` auto-selects the Pallas interpreter off-TPU;
+    ``tile`` (of the inner width) defaults to :func:`inner_tile`."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = not _on_tpu()
+    t, h = u.shape
+    items, _, inner = wg.shape
+    itemsize = u.dtype.itemsize
+    ik, steps = _inner_steps(h, inner, itemsize, tile)
+    rows = -(-t // ROW_GROUP) * ROW_GROUP       # whole sublane tiles
+    u = jnp.pad(u, ((0, rows - t), (0, 0)))
+    wt = jnp.pad(wt.astype(F32), ((0, 0), (0, rows - t)))[..., None]
+    eid, n_real = _work_list(eid, n_real)
+
+    def fixed(i, s, eid_ref, n_ref):
+        return 0, 0
+
+    def weight_map(i, s, eid_ref, n_ref):
+        return _item_map(i, s, eid_ref, n_ref), 0, 0
 
     vmem = (2 * 3 * h * ik * itemsize               # the streamed tiles
             + rows * h * (itemsize + 3 * 4)         # u, y twice, a product
@@ -156,9 +256,7 @@ def pallas_expert_terms(u, eid, n_real, wt, wg, wu, wd, *, tile=None,
             in_specs=[
                 pl.BlockSpec((rows, h), fixed, pipeline_mode=pl.Buffered(1)),
                 pl.BlockSpec((None, rows, 1), weight_map),
-                pl.BlockSpec((None, h, ik), gate_up_map),
-                pl.BlockSpec((None, h, ik), gate_up_map),
-                pl.BlockSpec((None, ik, h), down_map),
+                *_weight_specs(h, ik, steps),
             ],
             out_specs=pl.BlockSpec((rows, h), fixed),
         ),
@@ -172,12 +270,76 @@ def pallas_expert_terms(u, eid, n_real, wt, wg, wu, wd, *, tile=None,
     return y[:t]
 
 
-def fitted_tile(u, experts):
-    """The kernel's inner tile for ``u (T, h)`` over the stacked
-    ``experts`` (arrays or their shapes), ``None`` where the XLA form runs:
-    the kernel on a TPU backend with no mesh in scope, one 2- or 4-byte
-    float type for tokens and weights, ``h`` and the inner width multiples
-    of ``LANE`` and at most ``MAX_TOKENS`` tokens."""
+def pallas_grouped_terms(xs, eid, n_real, wt, wg, wu, wd, *, row_tile,
+                         tile=None, interpret=None):
+    """The kernel lowering for rows GROUPED by expert: ``xs (items *
+    row_tile, h)``, item ``i`` the rows ``[i * row_tile, (i + 1) *
+    row_tile)`` and all of them expert ``eid[i]``'s, ``wt (items *
+    row_tile,)`` each row's routing weight (zero for a tile's padding); the
+    first ``n_real`` items are real, and consecutive items of one expert
+    keep its weight blocks.  Returns ``(items * row_tile, h)`` float32:
+    each real item's rows through its expert, weighted (zero where the
+    weight is); rows past the real items are NOT WRITTEN."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = not _on_tpu()
+    n, h = xs.shape
+    items, inner = eid.shape[0], wg.shape[-1]
+    if n != items * row_tile:
+        raise ValueError(f"{n} rows are not {items} tiles of {row_tile}")
+    itemsize = xs.dtype.itemsize
+    ik, steps = _inner_steps(h, inner, itemsize, tile)
+    eid, n_real = _work_list(eid, n_real)
+
+    def rows_map(i, s, eid_ref, n_ref):
+        return _item_map(i, s, eid_ref, n_ref), 0
+
+    vmem = (2 * 3 * h * ik * itemsize               # the streamed tiles
+            + 2 * row_tile * h * (itemsize + 2 * 4)  # rows in, terms out
+            + 3 * row_tile * ik * 4 + 2 * row_tile * LANE * 4)
+    return pl.pallas_call(
+        _grouped_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(items, steps),
+            in_specs=[
+                pl.BlockSpec((row_tile, h), rows_map),
+                pl.BlockSpec((row_tile, 1), rows_map),
+                *_weight_specs(h, ik, steps),
+            ],
+            out_specs=pl.BlockSpec((row_tile, h), rows_map),
+        ),
+        out_shape=jax.ShapeDtypeStruct((n, h), F32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem + (8 << 20)),
+        interpret=interpret,
+        name="moe_grouped_fwd",
+    )(eid, n_real, xs, wt.astype(F32)[:, None], wg, wu, wd)
+
+
+class Tiles(NamedTuple):
+    """What :func:`fitted_tile` fits a kernel call with: the ``inner`` tile
+    of a step and the ``rows`` of a row tile — ``None`` where every token
+    goes through every touched expert (``moe_decode_fwd``)."""
+    inner: int
+    rows: int | None
+
+    @property
+    def lowering(self) -> str:
+        return "pallas" if self.rows is None else "pallas_grouped"
+
+
+def fitted_tile(u, experts) -> Tiles | None:
+    """The kernel's tiles for ``u (T, h)`` over the stacked ``experts``
+    (arrays or their shapes), ``None`` where the XLA form runs: a kernel on
+    a TPU backend with no mesh in scope, one 2- or 4-byte float type for
+    tokens and weights, ``h`` and the inner width multiples of ``LANE`` and
+    at most ``MAX_GROUPED_TOKENS`` tokens — ``moe_decode_fwd`` up to
+    ``MAX_TOKENS`` of them, ``moe_grouped_fwd`` in row tiles of
+    ``ROW_TILE`` above."""
     dtype = jnp.dtype(u.dtype)
     t, h = u.shape
     inner = experts["wg"].shape[-1]
@@ -186,5 +348,9 @@ def fitted_tile(u, experts):
                       for k in ("wg", "wu", "wd"))
               and jnp.issubdtype(dtype, jnp.floating)
               and dtype.itemsize in (2, 4)
-              and h % LANE == 0 and inner % LANE == 0 and t <= MAX_TOKENS)
-    return inner_tile(h, inner, dtype.itemsize) if kernel else None
+              and h % LANE == 0 and inner % LANE == 0
+              and t <= MAX_GROUPED_TOKENS)
+    if not kernel:
+        return None
+    return Tiles(inner_tile(h, inner, dtype.itemsize),
+                 None if t <= MAX_TOKENS else ROW_TILE)

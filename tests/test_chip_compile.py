@@ -439,6 +439,33 @@ def test_moe_decode_kernel_compiles_for_v5e(shape, no_persistent_cache,
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * tokens * h * 4
 
 
+@pytest.mark.parametrize("tokens,k,h,inner,held", [
+    (256, 8, 2048, 768, 128), (1024, 8, 2048, 768, 128),
+    (1024, 12, 6144, 2048, 16)],
+    ids=["sdar-block-step", "sdar-admit-256", "longcat-admit-512"])
+def test_moe_grouped_kernel_compiles_for_v5e(shape, no_persistent_cache,
+                                             tokens, k, h, inner, held):
+    """The held experts' product over an expert's OWN rows
+    (``ops/moe_decode.py``, ``moe_grouped_fwd``) at SDAR's block step and
+    its largest admission in the kernel's range — one step an item, 9.4 MB
+    of weights double-buffered — and at LongCat's 2 rows x 512, whose inner
+    tile takes eight steps, with the row tile and the work list's static
+    bound the chip path takes."""
+    from progen_tpu.ops import moe_decode as md
+
+    bf16 = jnp.bfloat16
+    rt = md.ROW_TILE
+    items = tokens * k // rt + held
+    fn = jax.jit(lambda xs, e, n, wt, wg, wu, wd: md.pallas_grouped_terms(
+        xs, e, n, wt, wg, wu, wd, row_tile=rt, interpret=False))
+    compiled = fn.lower(
+        shape((items * rt, h), bf16), shape((items,), jnp.int32),
+        shape((), jnp.int32), shape((items * rt,), jnp.float32),
+        shape((held, h, inner), bf16), shape((held, h, inner), bf16),
+        shape((held, inner, h), bf16)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 # ---- Granite 4.0-H's whole programs at published widths ----
 
 

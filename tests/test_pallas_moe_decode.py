@@ -1,11 +1,14 @@
-"""``models/experts.py:held_experts`` for a decode step's handful of tokens:
-two lowerings, one contract.  The Pallas kernel (``moe_decode_fwd``,
-``ops/moe_decode.py``) runs under the interpreter here, over the three tiny
-configurations' own expert weights and routers: against today's XLA form
+"""``models/experts.py:held_experts`` for a decode step's handful of tokens
+and for a block step's few hundred: three lowerings, one contract.  The
+Pallas kernels (``moe_decode_fwd`` — every token through every touched
+expert — and ``moe_grouped_fwd`` — an expert's own rows in row tiles —,
+``ops/moe_decode.py``) run under the interpreter here, over the tiny
+configurations' own expert weights and routers: against the XLA form
 (windows of ``ragged_dot``) and against a dense float32 loop over the held
 experts, on the cases that break grouped kernels; the choice of lowering
-from backend, mesh and shape at each cell's decode and admission shapes, as
-``status()`` shows it; and the counter ``moe.expert_passes``."""
+from backend, mesh and shape at each cell's decode, block-step and
+admission shapes, as ``status()`` shows it; and the counters
+``moe.expert_passes`` and ``moe.rows_computed``."""
 
 import dataclasses
 import types
@@ -18,10 +21,11 @@ import pytest
 from progen_tpu.models import deepseek_v2 as ds
 from progen_tpu.models import experts
 from progen_tpu.models import longcat as lc
+from progen_tpu.models import sdar
 from progen_tpu.models import trinity as tr
 from progen_tpu.ops import moe_decode as md
 from progen_tpu.ops.lowering import record_lowerings
-from tests import deepseek_v2_tiny, longcat_tiny, trinity_tiny
+from tests import deepseek_v2_tiny, longcat_tiny, sdar_tiny, trinity_tiny
 
 F32 = jnp.float32
 # (family module, tiny config, make, index of an expert layer)
@@ -32,26 +36,36 @@ FAMILIES = {
 }
 # the bfloat16 bound of the siblings' kernel tests, and float32's
 TOL = {"float32": 2e-5, "bfloat16": 2e-2}
+# what the grouped kernel's tests add: SDAR holds the whole layer
+LAYERS = {**FAMILIES, "sdar": (sdar, sdar_tiny.TINY, sdar_tiny.make, 0)}
 
 
-def _kernel_path(monkeypatch, lane=16, step_bytes=None):
+def _kernel_path(monkeypatch, lane=16, step_bytes=None, row_tile=None,
+                 most=None):
     """The chip's choice with the interpreter behind it, at the tiny
-    widths: a lane tile of ``lane`` and, with ``step_bytes``, a limit
-    small enough that an expert takes several steps."""
+    widths: a lane tile of ``lane``, with ``step_bytes`` a limit small
+    enough that an expert takes several steps, with ``row_tile`` row tiles
+    small enough that an expert takes several items, and with ``most =
+    (MAX_TOKENS, MAX_GROUPED_TOKENS)`` other edges of the rule's ranges."""
     monkeypatch.setattr(md, "_on_tpu", lambda: True)
     monkeypatch.setattr(md, "LANE", lane)
     if step_bytes:
         monkeypatch.setattr(md, "STEP_BYTES", step_bytes)
-    monkeypatch.setattr(
-        md, "pallas_expert_terms",
-        lambda *a, _f=md.pallas_expert_terms, **kw: _f(
-            *a, **{**kw, "interpret": True}))
+    if row_tile:
+        monkeypatch.setattr(md, "ROW_TILE", row_tile)
+    if most:
+        monkeypatch.setattr(md, "MAX_TOKENS", most[0])
+        monkeypatch.setattr(md, "MAX_GROUPED_TOKENS", most[1])
+    for name in ("pallas_expert_terms", "pallas_grouped_terms"):
+        monkeypatch.setattr(
+            md, name, lambda *a, _f=getattr(md, name), **kw: _f(
+                *a, **{**kw, "interpret": True}))
 
 
 def _layer(family, mixed=False, held=None, first=0):
     """One expert layer of the tiny configuration, holding ``held`` experts
     from ``first`` on (default: all of them)."""
-    module, config, make, index = FAMILIES[family]
+    module, config, make, index = LAYERS[family]
     params, policy = make(config, mixed)
     layer = params["layers"][index]
     if held is not None:
@@ -80,8 +94,8 @@ def _dense(u, ids, w, live, layer, c):
     return y
 
 
-def _both(monkeypatch, u, ids, w, live, layer, c, **tiles):
-    """``held_experts`` under today's form, then under the kernel."""
+def _both(monkeypatch, u, ids, w, live, layer, c, kernel="pallas", **tiles):
+    """``held_experts`` under the XLA form, then under the kernel."""
     with jax.default_matmul_precision("highest"):
         want, load = experts.held_experts(u, ids, w, live, layer["experts"],
                                           c)
@@ -89,7 +103,7 @@ def _both(monkeypatch, u, ids, w, live, layer, c, **tiles):
         with record_lowerings() as chosen:
             got, load2 = experts.held_experts(u, ids, w, live,
                                               layer["experts"], c)
-    assert chosen["moe_experts"] == {"pallas"}
+    assert chosen["moe_experts"] == {kernel}
     assert got.shape == u.shape and got.dtype == F32
     np.testing.assert_array_equal(np.asarray(load), np.asarray(load2))
     return np.asarray(got), np.asarray(want), np.asarray(load)
@@ -249,6 +263,194 @@ def test_expert_terms_by_hand_over_a_short_list():
                                interpret=True)
 
 
+# ---- a block step's few hundred tokens: an expert's own rows in row tiles ---
+
+ROW_TILE = 8            # the tests' row tile: an expert takes several items
+
+
+def _one_expert_on_a_tiles_edge(ids, c):
+    """The second held expert gets exactly two row tiles of rows, and no
+    other assignment."""
+    a, b = c.first_expert + 1, c.first_expert + 2
+    ids = jnp.where(ids == a, b, ids)
+    return ids.at[:2 * ROW_TILE, 0].set(a)
+
+
+def _second_expert_idle(ids, c):
+    return jnp.where(ids == c.first_expert + 1, c.first_expert, ids)
+
+
+# ``(tokens, live, ids -> ids)``; each on a layer held whole (SDAR) and on
+# a share in the middle of the router (dsv2: 3 experts from the third on)
+GROUPED_CASES = {
+    "129-tokens": (129, lambda t: jnp.arange(t) % 5 != 0, None),
+    "256-tokens": (256, lambda t: jnp.arange(t) % 5 != 0, None),
+    "1024-tokens": (1024, lambda t: jnp.arange(t) % 3 != 0, None),
+    "an-expert-without-rows": (256, lambda t: jnp.arange(t) % 4 != 1,
+                               _second_expert_idle),
+    "rows-end-on-a-tiles-edge": (256, lambda t: jnp.ones(t, bool),
+                                 _one_expert_on_a_tiles_edge),
+    "all-to-one-expert": (160, lambda t: jnp.arange(t) % 7 != 0,
+                          _all_to_one),
+    "no-live-row": (256, lambda t: jnp.zeros(t, bool), None),
+}
+SHARES = {"sdar": (None, 0), "dsv2": (3, 2)}
+GROUPED = [("sdar", case) for case in GROUPED_CASES] + [
+    ("dsv2", "256-tokens"), ("dsv2", "an-expert-without-rows"),
+    ("dsv2", "rows-end-on-a-tiles-edge")]
+
+
+def _listed_weights(ids, w, live, c):
+    """``(eid, n_real, wt (held, T))`` of ``ops/moe_decode.py``'s contract
+    for the held experts: the touched first."""
+    held = jnp.arange(c.experts_held) + c.first_expert
+    names = (ids[None] == held[:, None, None]) & live[None, :, None]
+    wt = jnp.sum(jnp.where(names, w[None], 0.0), axis=-1)
+    touched = names.any(axis=(1, 2))
+    eid = jnp.argsort(~touched)
+    return eid, jnp.sum(touched), wt[eid]
+
+
+@pytest.mark.parametrize("family,case", GROUPED,
+                         ids=[f"{f}-{c}" for f, c in GROUPED])
+def test_grouped_kernel_equals_the_xla_form_the_oracle_and_the_dense_loop(
+        monkeypatch, family, case):
+    t, live_of, rewrite = GROUPED_CASES[case]
+    held, first = SHARES[family]
+    module, c, layer, _ = _layer(family, held=held, first=first)
+    u = jax.random.normal(jax.random.key(13), (t, c.hidden_size))
+    ids, w = _routed(module, c, layer, u)
+    if rewrite is not None:
+        ids = rewrite(ids, c)
+    live = live_of(t)
+    # two inner steps an item, row tiles of 8: most experts take several
+    inner = layer["experts"]["wg"].shape[-1]
+    got, want, load = _both(
+        monkeypatch, u, ids, w, live, layer, c, kernel="pallas_grouped",
+        lane=8, step_bytes=3 * c.hidden_size * (inner // 2) * 4,
+        row_tile=ROW_TILE, most=(128, 1024))
+    assert md.inner_tile(c.hidden_size, inner, 4) == inner // 2
+    e = layer["experts"]
+    with jax.default_matmul_precision("highest"):
+        oracle = np.asarray(md.xla_expert_terms(
+            u, *_listed_weights(ids, w, live, c), e["wg"], e["wu"], e["wd"]))
+    dense = np.asarray(_dense(u, ids, w, live, layer, c))
+    assert np.isfinite(got).all()
+    if case == "no-live-row":
+        assert load.sum() == 0 and not got.any() and not want.any()
+        return
+    if family == "dsv2":             # assignments below and above the share
+        local = np.asarray(ids) - c.first_expert
+        assert (local < 0).any() and (local >= c.experts_held).any()
+    if case == "an-expert-without-rows":
+        assert load[1] == 0 < load[0]
+    if case == "rows-end-on-a-tiles-edge":
+        assert load[1] == 2 * ROW_TILE
+    if case == "all-to-one-expert":
+        assert load[1] == int(live.sum()) * c.moe_topk == load.sum()
+    assert load.max() > ROW_TILE                # more rows than one tile
+    assert float(np.abs(dense).max()) > 0.05
+    assert float(np.abs(got - want).max()) < TOL["float32"]
+    assert float(np.abs(got - oracle).max()) < TOL["float32"]
+    assert float(np.abs(got - dense).max()) < 10 * TOL["float32"]
+    assert not got[~np.asarray(live)].any()
+
+
+@pytest.mark.parametrize("family", list(SHARES))
+def test_grouped_bfloat16_operands_float32_sums(monkeypatch, family):
+    """As the all-rows kernel's: within the siblings' bfloat16 bound of the
+    XLA form, and no further from the float32 dense loop than it is."""
+    held, first = SHARES[family]
+    module, c, layer, dtype = _layer(family, mixed=True, held=held,
+                                     first=first)
+    assert dtype == jnp.bfloat16
+    u = jax.random.normal(jax.random.key(15), (256, c.hidden_size)).astype(
+        dtype)
+    ids, w = _routed(module, c, layer, u)
+    live = jnp.arange(256) % 6 != 0
+    got, want, _ = _both(monkeypatch, u, ids, w, live, layer, c,
+                         kernel="pallas_grouped", row_tile=16)
+    dense = np.asarray(_dense(u, ids, w, live, layer, c))
+    scale = float(np.abs(dense).max())
+    assert scale > 0.05
+    assert float(np.abs(got - want).max()) < TOL["bfloat16"] * max(1, scale)
+    assert (float(np.abs(got - dense).max())
+            <= float(np.abs(want - dense).max()) + 1e-6)
+
+
+def test_grouped_junk_outside_an_experts_rows_changes_no_bit(monkeypatch):
+    """Rows that are not ``live`` reach no expert (a NaN there shows
+    nowhere), and what a row tile's padding holds is weighted zero and
+    selected away: the kernel over NaN padding writes the bits it writes
+    over zeros."""
+    module, c, layer, _ = _layer("sdar")
+    u = jax.random.normal(jax.random.key(17), (160, c.hidden_size))
+    ids, w = _routed(module, c, layer, u)
+    live = jnp.arange(160) % 4 != 0
+    _kernel_path(monkeypatch, row_tile=ROW_TILE)
+    with record_lowerings() as chosen:
+        got, again = (experts.held_experts(
+            jnp.where(live[:, None], u, fill), ids, w, live,
+            layer["experts"], c)[0] for fill in (0.0, jnp.nan))
+    assert chosen["moe_experts"] == {"pallas_grouped"}
+    assert float(jnp.abs(got).max()) > 0.05
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(again))
+    # the kernel's own contract: 3 items of 8 rows, 5 + 8 + 2 real
+    e = layer["experts"]
+    xs = jax.random.normal(jax.random.key(19), (24, c.hidden_size))
+    wt = jnp.where(jnp.arange(24) % 8 < jnp.repeat(jnp.array([5, 8, 2]), 8),
+                   0.5, 0.0)
+    eid = jnp.array([1, 4, 4])
+    clean, dirty = (md.pallas_grouped_terms(
+        jnp.where(wt[:, None] != 0, xs, fill), eid, 3, wt, e["wg"], e["wu"],
+        e["wd"], row_tile=8, interpret=True) for fill in (0.0, jnp.nan))
+    np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
+    assert not np.asarray(clean)[np.asarray(wt) == 0].any()
+    assert np.abs(np.asarray(clean)[np.asarray(wt) != 0]).min() > 0
+
+
+def test_grouped_terms_by_hand_over_a_short_list():
+    """``pallas_grouped_terms``'s own contract: item ``i`` is rows ``[8 i,
+    8 i + 8)`` through expert ``eid[i]``, weighted; the first ``n_real``
+    items are computed, in one or in several inner steps, and what lies
+    past them is neither read nor written."""
+    h, inner, held, rt = 256, 384, 6, 8
+    ks = jax.random.split(jax.random.key(1), 6)
+    xs = jax.random.normal(ks[0], (5 * rt, h))
+    wg = jax.random.normal(ks[1], (held, h, inner)) * h ** -0.5
+    wu = jax.random.normal(ks[2], (held, h, inner)) * h ** -0.5
+    wd = jax.random.normal(ks[3], (held, inner, h)) * inner ** -0.5
+    wt = jax.random.uniform(ks[4], (5 * rt,)) * (
+        jax.random.uniform(ks[5], (5 * rt,)) < 0.7)
+    eid = jnp.array([0, 3, 3, 5, 2])            # expert 3 in two items
+    with jax.default_matmul_precision("highest"):
+        by_hand = jnp.concatenate([
+            wt[i * rt:(i + 1) * rt, None] * (
+                (jax.nn.silu(x @ wg[e]) * (x @ wu[e])) @ wd[e])
+            for i, e in enumerate([0, 3, 3, 5])
+            for x in [xs[i * rt:(i + 1) * rt]]])
+        for tile in (128, 384, None):
+            got = md.pallas_grouped_terms(xs, eid, 4, wt, wg, wu, wd,
+                                          row_tile=rt, tile=tile,
+                                          interpret=True)
+            assert got.shape == (5 * rt, h) and got.dtype == F32
+            assert float(jnp.abs(got[:4 * rt] - by_hand).max()) < TOL[
+                "float32"]
+        # a NaN expert past the list is not read
+        poisoned = md.pallas_grouped_terms(
+            xs, eid, 4, wt, wg.at[2].set(jnp.nan), wu, wd, row_tile=rt,
+            interpret=True)
+    np.testing.assert_array_equal(np.asarray(poisoned[:4 * rt]),
+                                  np.asarray(got[:4 * rt]))
+    assert float(jnp.abs(by_hand).max()) > 0.1
+    with pytest.raises(ValueError, match="does not divide"):
+        md.pallas_grouped_terms(xs, eid, 4, wt, wg, wu, wd, row_tile=rt,
+                                tile=256, interpret=True)
+    with pytest.raises(ValueError, match="tiles of"):
+        md.pallas_grouped_terms(xs[:-1], eid, 4, wt[:-1], wg, wu, wd,
+                                row_tile=rt, interpret=True)
+
+
 # ---- which lowering, and where it is stated --------------------------------
 
 # tokens of a decode call and of the smallest admission run, hidden and
@@ -258,6 +460,9 @@ CELLS = {
     "longcat": (32, 2 * 512, 6144, 2048, 16),
     "trinity": (64, 4 * 512, 2048, 1024, 16),
 }
+# SDAR's block step (64 slots x 4 positions) and its admissions' buckets of
+# 4 rows, at its widths (all 128 experts held, top-8)
+SDAR = {"h": 2048, "inner": 768, "held": 128, "k": 8, "router": 128}
 
 
 def _shapes(t, h, inner, held, dtype=jnp.bfloat16, weights=None):
@@ -283,9 +488,40 @@ def _lowering(t, h, inner, held, k=6, router=160, **kw):
     return chosen["moe_experts"], jaxpr
 
 
+@pytest.mark.parametrize("tokens,want", [
+    (64 * 4, "pallas_grouped"), (4 * 128, "pallas_grouped"),
+    (4 * 256, "pallas_grouped"), (4 * 512, "xla"), (4 * 1024, "xla")],
+    ids=["block-step", "admit-128", "admit-256", "admit-512", "admit-1024"])
+def test_on_tpu_sdars_block_step_takes_the_grouped_kernel(monkeypatch,
+                                                          tokens, want):
+    """The cell's block step (256 tokens) and its two smallest admission
+    shapes (512 and 1,024 tokens) carry few rows an expert and take
+    ``moe_grouped_fwd`` — no ``ragged_dot``, no window loop, no scatter-add
+    of rows; from 2,048 tokens on a call is the XLA form."""
+    monkeypatch.setattr(md, "_on_tpu", lambda: True)
+    paths, jaxpr = _lowering(tokens, **SDAR)
+    assert paths == {want}
+    if want == "xla":
+        assert "pallas_call" not in jaxpr and "ragged_dot" in jaxpr
+        return
+    assert jaxpr.count("pallas_call") == 1
+    assert "name=moe_grouped_fwd" in jaxpr
+    for gone in ("ragged_dot", "while"):
+        assert gone not in jaxpr
+    # the one scatter-add is ``load``'s bincount over the assignments
+    assert jaxpr.count("= scatter") == 1
+    assert f"i32[{SDAR['held'] + 1}] = scatter-add" in jaxpr
+    assert md.inner_tile(SDAR["h"], SDAR["inner"], 2) == SDAR["inner"]
+
+
 @pytest.mark.parametrize("cell", list(CELLS))
-def test_on_tpu_decode_takes_the_kernel_and_admission_todays_form(
+def test_on_tpu_decode_takes_the_kernel_and_admission_by_its_tokens(
         monkeypatch, cell):
+    """A sibling cell's decode step is ``moe_decode_fwd``; its smallest
+    admission the XLA form from 2,048 tokens on (DeepSeek-V2's and
+    Trinity's 4 rows x 512) and ``moe_grouped_fwd`` under that (LongCat's 2
+    rows x 512 = 1,024: ``MAX_GROUPED_TOKENS``); every larger bucket is the
+    XLA form."""
     decode, admit, h, inner, held = CELLS[cell]
     monkeypatch.setattr(md, "_on_tpu", lambda: True)
     paths, jaxpr = _lowering(decode, h, inner, held)
@@ -293,6 +529,10 @@ def test_on_tpu_decode_takes_the_kernel_and_admission_todays_form(
     assert jaxpr.count("pallas_call") == 1 and "ragged_dot" not in jaxpr
     assert "sort" in jaxpr and "while" not in jaxpr
     paths, jaxpr = _lowering(admit, h, inner, held)
+    if admit <= md.MAX_GROUPED_TOKENS:
+        assert cell == "longcat" and paths == {"pallas_grouped"}
+        assert "name=moe_grouped_fwd" in jaxpr and "ragged_dot" not in jaxpr
+        paths, jaxpr = _lowering(2 * admit, h, inner, held)
     assert paths == {"xla"}
     assert "pallas_call" not in jaxpr and "ragged_dot" in jaxpr
     # and the tile the cell's widths get: whole, under the byte limit
@@ -312,26 +552,41 @@ def test_an_admission_traces_to_the_same_text_whatever_the_backend(
 
 @pytest.mark.parametrize("shape,kw,want", [
     ((128, 256, 128, 8), {}, "pallas"),                 # the most tokens
-    ((129, 256, 128, 8), {}, "xla"),
+    ((129, 256, 128, 8), {}, "pallas_grouped"),         # one more
+    ((256, 256, 128, 8), {}, "pallas_grouped"),
+    ((md.MAX_GROUPED_TOKENS, 256, 128, 8), {}, "pallas_grouped"),
+    ((md.MAX_GROUPED_TOKENS + 1, 256, 128, 8), {}, "xla"),
+    ((2048, 256, 128, 8), {}, "xla"),
+    ((32768, 256, 128, 8), {}, "xla"),                  # Trinity's long runs
     ((64, 256, 128, 8), {"dtype": jnp.float32}, "pallas"),
+    ((256, 256, 128, 8), {"dtype": jnp.float32}, "pallas_grouped"),
     ((64, 256, 128, 8), {"weights": jnp.float32}, "xla"),   # two types
+    ((256, 256, 128, 8), {"weights": jnp.float32}, "xla"),
     ((64, 200, 128, 8), {}, "xla"),                     # h off the lane tile
+    ((256, 200, 128, 8), {}, "xla"),
     ((64, 256, 96, 8), {}, "xla"),                      # the inner width
     ((4, 32, 16, 8), {"dtype": jnp.float32}, "xla"),    # the tests' TINY
-], ids=["t-128", "t-129", "float32", "f32-weights", "h-200", "inner-96",
-        "tiny"])
+], ids=["t-128", "t-129", "t-256", "t-most-grouped", "t-one-more", "t-2048",
+        "t-32768", "float32", "float32-256", "f32-weights",
+        "f32-weights-256", "h-200", "h-200-256", "inner-96", "tiny"])
 def test_on_tpu_the_shape_decides(monkeypatch, shape, kw, want):
     monkeypatch.setattr(md, "_on_tpu", lambda: True)
+    assert md.MAX_TOKENS == 128 < md.MAX_GROUPED_TOKENS < 2048
     paths, jaxpr = _lowering(*shape, **kw)
     assert paths == {want}
-    assert ("pallas_call" in jaxpr) == (want == "pallas")
+    assert ("pallas_call" in jaxpr) == (want != "xla")
+    if want != "xla":
+        kernel = {"pallas": "moe_decode_fwd",
+                  "pallas_grouped": "moe_grouped_fwd"}[want]
+        assert f"name={kernel}" in jaxpr
 
 
-def test_a_mesh_in_scope_keeps_todays_form(monkeypatch, devices8):
+@pytest.mark.parametrize("tokens", [64, 256])
+def test_a_mesh_in_scope_keeps_todays_form(monkeypatch, devices8, tokens):
     monkeypatch.setattr(md, "_on_tpu", lambda: True)
     mesh = jax.sharding.Mesh(np.asarray(devices8[:2]), ("data",))
     with mesh:
-        paths, jaxpr = _lowering(64, 5120, 1536, 40)
+        paths, jaxpr = _lowering(tokens, 5120, 1536, 40)
     assert paths == {"xla"} and "pallas_call" not in jaxpr
 
 
@@ -355,11 +610,51 @@ def test_expert_passes_by_hand(monkeypatch):
     w = jnp.ones(ids.shape, F32)
     _, load = experts.held_experts(u, ids, w, live, layer["experts"], c)
     assert np.asarray(load).tolist() == [0, 3, 3, 0, 0, 0, 0, 0, 0, 1, 0, 0]
-    assert float(experts.expert_passes(u, layer["experts"], load)) == 0
+
+    def counted(load, tokens=u):
+        got = experts.kernel_counters(tokens, layer["experts"], load)
+        assert sorted(got) == ["moe.expert_passes", "moe.rows_computed"]
+        assert all(v.dtype == F32 and v.shape == () for v in got.values())
+        return float(got["moe.expert_passes"]), float(
+            got["moe.rows_computed"])
+
+    assert counted(load) == (0, 0)
     _kernel_path(monkeypatch)
-    assert float(experts.expert_passes(u, layer["experts"], load)) == 3
+    # three touched experts, the call's 8 rows padded to a sublane tile of 16
+    assert md.ROW_GROUP == 16 and counted(load) == (3, 3 * 16)
     idle = jnp.zeros_like(load)
-    assert float(experts.expert_passes(u, layer["experts"], idle)) == 0
+    assert counted(idle) == (0, 0)
+
+
+@pytest.mark.parametrize("steps,passes", [(1, 4), (2, 2 + 1 + 1 + 3)],
+                         ids=["whole-inner-width", "two-inner-steps"])
+def test_grouped_counters_by_hand(monkeypatch, steps, passes):
+    """One item a row tile of an expert's own rows: 9, 8, 1 and 17 rows in
+    tiles of 8 are 2 + 1 + 1 + 3 items and 56 rows through the MXU for 35
+    assignments; an expert's matrices are fetched once where a step holds
+    the whole inner width (its items lie side by side and keep the
+    blocks), once an ITEM where the inner width takes several steps."""
+    module, c, layer, _ = _layer("sdar")
+    inner = layer["experts"]["wg"].shape[-1]
+    _kernel_path(monkeypatch, lane=8, row_tile=8, most=(4, 64),
+                 step_bytes=3 * c.hidden_size * (inner // steps) * 4)
+    rows = {1: 9, 2: 8, 5: 1, 6: 17}
+    ids = jnp.concatenate([jnp.full((n, 1), e) for e, n in rows.items()])
+    t = ids.shape[0]
+    assert t == 35 and md.MAX_TOKENS < t <= md.MAX_GROUPED_TOKENS
+    u = jax.random.normal(jax.random.key(21), (t, c.hidden_size))
+    c = dataclasses.replace(c, num_experts_per_tok=1)
+    live = jnp.ones(t, bool)
+    with record_lowerings() as chosen:
+        y, load = experts.held_experts(u, ids, jnp.ones((t, 1), F32), live,
+                                       layer["experts"], c)
+    assert chosen["moe_experts"] == {"pallas_grouped"}
+    assert np.asarray(load).tolist() == [0, 9, 8, 0, 0, 1, 17, 0]
+    got = experts.kernel_counters(u, layer["experts"], load)
+    assert float(got["moe.expert_passes"]) == passes
+    assert float(got["moe.rows_computed"]) == (2 + 1 + 1 + 3) * 8
+    want = _dense(u, ids, jnp.ones((t, 1), F32), live, layer, c)
+    assert float(jnp.abs(y - want).max()) < 10 * TOL["float32"]
 
 
 @pytest.mark.parametrize("family", list(FAMILIES))
@@ -367,14 +662,19 @@ def test_a_decode_step_counts_its_passes_and_a_prefill_none(monkeypatch,
                                                             family):
     module, config, make, _ = FAMILIES[family]
     params, policy = make(config)
-    assert "moe.expert_passes" in module.STAT_KEYS
+    for key in ("moe.expert_passes", "moe.rows_computed"):
+        assert key in module.STAT_KEYS
     tokens = jnp.arange(12, dtype=jnp.int32).reshape(2, 6) + 3
 
     def prefill():
         return module.prefill(params, tokens, jnp.array([6, 4]), config,
                               policy)[2]
 
-    assert float(prefill()["moe.expert_passes"]) == 0
+    def counted(stats):
+        return (float(stats["moe.expert_passes"]),
+                float(stats["moe.rows_computed"]))
+
+    assert counted(prefill()) == (0, 0)
     fam = {"longcat": lc.LongCatFamily, "dsv2": ds.DeepSeekV2Family,
            "trinity": tr.TrinityFamily}[family](config, policy)
     caches = fam.init_caches(4, 16)
@@ -387,15 +687,60 @@ def test_a_decode_step_counts_its_passes_and_a_prefill_none(monkeypatch,
                                   config, policy)[2]
 
     before = decode()
-    assert float(before["moe.expert_passes"]) == 0     # the CPU: today's form
+    assert counted(before) == (0, 0)                   # the CPU: the XLA form
     _kernel_path(monkeypatch, lane=8)
-    after = decode()
-    assert (float(after["moe.expert_passes"])
-            == float(after["moe.experts_touched"]) > 0)
+    with record_lowerings() as chosen:
+        after = decode()
+    assert chosen["moe_experts"] == {"pallas"}
+    touched = float(after["moe.experts_touched"])
+    # every touched expert once, the step's 4 rows padded to a sublane tile
+    assert touched > 0 and counted(after) == (touched, touched * md.ROW_GROUP)
     np.testing.assert_array_equal(np.asarray(before["moe.held_load"]),
                                   np.asarray(after["moe.held_load"]))
     # and with the kernel on a tiny prefill still counts none
-    assert float(prefill()["moe.expert_passes"]) == 0
+    assert counted(prefill()) == (0, 0)
+
+
+def test_a_block_step_counts_its_passes_and_a_prefill_none(monkeypatch):
+    """``models/sdar.py``: a block step of 2 slots x 4 positions sums its
+    three expert layers' passes and rows under the grouped kernel (the
+    rule's lower edge moved under the step's 8 tokens), nothing under the
+    XLA form; a prefill counts neither whatever ran."""
+    config = sdar_tiny.TINY
+    params, policy = sdar_tiny.make(config)
+    block, mask = sdar_tiny.BLOCK, sdar_tiny.MASK_ID
+    toks = jnp.arange(2 * 32, dtype=jnp.int32).reshape(2, 32) % 90 + 1
+    primes = jnp.asarray([20, 8])
+    blk = jnp.full((2, block), mask, jnp.int32)
+
+    def both():
+        # a fresh trace per lowering
+        @jax.jit
+        def run(params):
+            _, rows, before = sdar.prefill(params, toks, primes, config,
+                                           policy)
+            caches = sdar.caches_from(rows, primes, config, 48)
+            return before, sdar.block_step(
+                params, blk, primes, caches, jnp.asarray([True, True]),
+                jnp.zeros(2, bool), config, policy)[2]
+        return run(params)
+
+    for stats in both():
+        assert float(stats["moe.expert_passes"]) == 0
+        assert float(stats["moe.rows_computed"]) == 0
+    _kernel_path(monkeypatch, lane=8, row_tile=8, most=(4, 512))
+    with record_lowerings() as chosen:
+        before, step = both()
+    # the step's 8 tokens took the kernel, the prefill's 64 too
+    assert chosen["moe_experts"] == {"pallas_grouped"}
+    assert float(before["moe.expert_passes"]) == 0
+    assert float(before["moe.rows_computed"]) == 0
+    touched = float(step["moe.experts_touched"])
+    assert float(step["moe.expert_passes"]) == touched > 0
+    # no expert has more than 8 of a layer's 16 assignments: a tile each
+    assignments = 2 * block * config.num_experts_per_tok * 3
+    assert float(jnp.sum(step["moe.held_load"])) == assignments
+    assert float(step["moe.rows_computed"]) == 8 * touched > assignments
 
 
 def test_cpu_notes_xla_and_the_engine_states_it_per_program():
@@ -421,3 +766,17 @@ def test_cpu_notes_xla_and_the_engine_states_it_per_program():
     snap = get_registry().snapshot()
     assert snap["moe.experts_touched"]["value"] > 0
     assert snap["moe.expert_passes"]["value"] == 0
+    assert snap["moe.rows_computed"]["value"] == 0
+    # an engine has one admission program a bucket, and the rule may give
+    # each another lowering: the program's entry names all its traces took
+    from progen_tpu.ops.lowering import note
+
+    def impl(x):
+        note("moe_experts", "pallas_grouped" if x.shape[0] <= 1024 else "xla")
+        return x + 1
+
+    admit = eng._jit_noting(impl, "admit")
+    for tokens in (2048, 512, 4096):
+        admit(jnp.zeros(tokens))
+    assert eng.status()["moe_experts"] == {"chunk": "xla",
+                                           "admit": "pallas_grouped+xla"}
