@@ -960,7 +960,9 @@ class ServingEngine:
         a slot (``"gqa_block_decode"``: ``"xla"``) and the held experts'
         product (``"moe_experts"``: ``"pallas"`` / ``"pallas_grouped"`` /
         ``"xla"``, stated per program: it is in both, and an engine's
-        admission programs, one a bucket, may differ — joined by ``+``)."""
+        admission programs, one a bucket, may differ — joined by ``+``) and
+        the draw's k-th largest logit (``"sample_kth"``: ``"xla"`` /
+        ``"xla_tiled"``, per program too)."""
 
         @wraps(impl)
         def traced(*args):
@@ -2831,10 +2833,11 @@ class ServingEngine:
             "gqa_block_decode": self.lowerings.get("gqa_block_decode"),
             # the held experts' product, by program: {"chunk": ..,
             # "admit": ..} as far as traced, None for a family without
-            "moe_experts": {
-                program: took["moe_experts"]
-                for program, took in self.program_lowerings.items()
-                if "moe_experts" in took} or None,
+            "moe_experts": self._lowering_by_program("moe_experts"),
+            # how the draw's k-th largest logit is counted: all rows in
+            # one loop ("xla") or a group of rows at a time, where only a
+            # group's keys stay on the chip ("xla_tiled"), by program
+            "sample_kth": self._lowering_by_program("sample_kth"),
             "paged": self.paged,
             "disagg": self.disagg,
             "spec": self.spec,
@@ -2844,6 +2847,13 @@ class ServingEngine:
             "cache": self.cache_status(),
             "robust": self.robustness_counters(),
         }
+
+    def _lowering_by_program(self, op: str) -> dict | None:
+        """``{"chunk": .., "admit": ..}`` as far as traced: the lowering
+        ``op`` took in each program that holds it; None where none does."""
+        return {program: took[op]
+                for program, took in self.program_lowerings.items()
+                if op in took} or None
 
     def cache_status(self) -> dict | None:
         """Prefix-cache occupancy and sharing for /statusz — host dicts

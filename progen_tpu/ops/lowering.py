@@ -8,6 +8,11 @@ decode step's handful of tokens, ``"pallas_grouped"`` for a block step's
 few hundred, ``"xla"`` for an admission's thousands) each keep a Pallas
 lowering and an XLA form behind one function and choose between them from
 the backend, the mesh in scope and the shapes, never from a knob.
+``decode/sampler.py:_kth_largest_by_counting`` chooses in the same way
+between two XLA forms of one loop (the note ``"sample_kth"``: ``"xla"``
+where a draw's keys fit on the chip and the compiler keeps them there,
+``"xla_tiled"`` where only a group of rows does and the loop runs a group
+at a time).
 (``ops/gqa.py:block_decode_attention`` has the XLA form alone so far and
 notes it as ``"gqa_block_decode"``, so that the note is there to change.)
 The choice is made while a program is traced, so a caller that traces one

@@ -15,6 +15,8 @@ file (a second file can land on another xdist worker, where its fixture
 would skip).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -532,6 +534,20 @@ def test_granite_programs_compile_for_the_chip_and_fit_it(
 # ---- SDAR's whole programs at published widths ----
 
 
+def _buffers_of(text, kind):
+    """The instructions of a compiled program whose RESULT holds ``kind``
+    and is a buffer of its own — a fusion's, a loop's, a copy's, a
+    kernel's (what a fused computation passes along inside is not)."""
+    hits = []
+    for line in text.splitlines():
+        _, sep, rest = line.partition(" = ")
+        op = re.search(r" (fusion|while|copy|custom-call)\(", rest)
+        if sep and op and kind in re.sub(r"\{[^{}]*\}", "",
+                                         rest[:op.start()]):
+            hits.append(line.strip())
+    return hits
+
+
 @pytest.fixture(scope="module")
 def sdar_engine():
     """The engine of ``serve-sdar-blockdiff-backlog`` over ABSTRACT weights
@@ -559,9 +575,10 @@ def test_sdar_programs_compile_for_the_chip_and_fit_it(
     block mask), as the chip traces them.  Arguments, results and
     temporaries together stay under the chip's 16 GiB: the engine's
     programs do not donate their state, so it is there twice."""
+    from progen_tpu.decode import sampler
     from progen_tpu.ops import gqa, lowering, moe_decode, row_write
 
-    for module in (row_write, gqa, moe_decode):
+    for module in (row_write, gqa, moe_decode, sampler):
         monkeypatch.setattr(module, "_on_tpu", lambda: True)
     monkeypatch.setattr(lowering, "on_tpu", lambda: True)
     eng = sdar_engine
@@ -598,3 +615,12 @@ def test_sdar_programs_compile_for_the_chip_and_fit_it(
     assert "tpu_custom_call" in text
     assert ("row_block_write" if program == "chunk"
             else "gqa_prefill_fwd") in text
+    # the draw's 32 rounds go by groups of 32 rows whose keys the compiler
+    # keeps in the chip's own memory (memory space 1): no uint32 array of
+    # the draw's whole shape is left for a loop to read from HBM 32 times
+    assert not _buffers_of(text, "u32[256,151936]")
+    if program == "chunk":
+        loops = [line for line in _buffers_of(text, "u32[32,151936]")
+                 if " while(" in line]
+        assert len(loops) == 1
+        assert "u32[32,151936]{1,0:T(8,128)S(1)}" in loops[0]

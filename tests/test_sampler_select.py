@@ -5,7 +5,11 @@ tokens are the same — over widths from 16 columns to a whole chat
 vocabulary, per-row k (off, 1, 25, v - 1, v, past v), ties at the k-th
 value, rows mostly ``-inf``, signed zeros, bfloat16 logits, greedy and
 near-greedy rows beside sampled ones, and NaNs of either sign.  There is one
-form whatever the width, and no sort in the traced program."""
+algorithm whatever the width, and no sort in the traced program; where the
+rows do not fit on the chip together the same rounds run a group of rows at
+a time, held here to the same sort over the same cases with more than one
+group and a last group that is not full, and the shape alone decides which
+of the two a draw takes."""
 
 import dataclasses
 
@@ -19,13 +23,36 @@ from progen_tpu.decode.engine import SLOTS_PER_ADMIT_ROW
 from progen_tpu.decode.sampler import (
     apply_logit_mask,
     gumbel_topk_sample_batched,
+    gumbel_topk_sample_with_confidence,
 )
+from progen_tpu.ops.lowering import record_lowerings
 from tests.granite_tiny import TINY, make
 
 ROWS = 12
 WIDTHS = (16, 256, 1_023, 4_096, 16_384, 25_024, 100_352)
 CASES = ("own_k", "ties", "mostly_masked", "signed_zeros", "bfloat16",
          "temperatures", "nans")
+# (width, rows the budget holds) at which the draw is made to go by groups:
+# the 12 rows are a group of 8 and a last one of 4 (a budget of 8 rows), or
+# three groups of 4 (a budget of 5 rows: the power of two under it)
+TILED = ((1_280, 8), (4_480, 5))
+# the rows and columns of every cell's draw (BENCHMARK.json's cells; the
+# block step's is slots x block)
+CELL_DRAWS = {
+    "serve-small-steady": (64, 256), "serve-base-backlog": (16, 256),
+    "serve-longcat-backlog": (32, 16_384),
+    "serve-trinity-mixedlen-backlog": (64, 25_024),
+    "serve-dsv2-decode-backlog": (64, 25_600),
+    "serve-granite-chat-backlog": (32, 100_352),
+    "serve-sdar-blockdiff-backlog": (256, 151_936)}
+
+
+def _force_groups(monkeypatch, budget=None):
+    """The chip's choice on the CPU: the gate sees a TPU and (for the tests'
+    small arrays) a budget of ``budget`` bytes."""
+    monkeypatch.setattr(sampler, "_on_tpu", lambda: True)
+    if budget is not None:
+        monkeypatch.setattr(sampler, "ROUNDS_ON_CHIP_BYTES", budget)
 
 
 def reference_kth(scaled, k_eff):
@@ -84,11 +111,22 @@ def _inputs(case, v):
             None if mask is None else jnp.asarray(mask))
 
 
-@pytest.mark.parametrize("v", WIDTHS)
+@pytest.mark.parametrize(("v", "fit"),
+                         [(v, None) for v in WIDTHS] + list(TILED))
 @pytest.mark.parametrize("case", CASES)
-def test_selection_is_the_sorts_element_and_draws_its_tokens(case, v):
+def test_selection_is_the_sorts_element_and_draws_its_tokens(
+        monkeypatch, case, v, fit):
     logits, top_k, temp, mask = _inputs(case, v)
     keys = jax.vmap(jax.random.key)(jnp.arange(ROWS, dtype=jnp.uint32) + v)
+    if fit:
+        # (a fresh function per lowering: ``jax.jit`` would keep the trace)
+        untiled = jax.jit(lambda *a: gumbel_topk_sample_with_confidence(*a))(
+            keys, logits, top_k, temp, mask)
+        _force_groups(monkeypatch, fit * v * 4)
+    with record_lowerings() as chosen:
+        jax.make_jaxpr(lambda *a: gumbel_topk_sample_batched(*a))(
+            keys, logits, top_k, temp, mask)
+    assert chosen == {"sample_kth": {"xla_tiled" if fit else "xla"}}
 
     x = logits.astype(jnp.float32)
     if mask is not None:
@@ -96,7 +134,8 @@ def test_selection_is_the_sorts_element_and_draws_its_tokens(case, v):
     scaled = x / jnp.maximum(temp, 1e-8)[:, None]
     k_eff = jnp.where(top_k > 0, jnp.clip(top_k, 1, v), v)
     want = np.asarray(jax.jit(reference_kth)(scaled, k_eff))
-    got = np.asarray(jax.jit(sampler._kth_largest_by_counting)(scaled, k_eff))
+    got = np.asarray(jax.jit(
+        lambda *a: sampler._kth_largest_by_counting(*a))(scaled, k_eff))
     assert got.shape == want.shape == (ROWS, 1)
     # bit for bit, but for which of two equal zeros is handed out and for
     # a NaN's payload: neither reaches the cut
@@ -115,7 +154,7 @@ def test_selection_is_the_sorts_element_and_draws_its_tokens(case, v):
                                       n_nan >= np.asarray(k_eff))
         assert np.isnan(want).any() and not np.isnan(want).all()
 
-    drawn = np.asarray(jax.jit(gumbel_topk_sample_batched)(
+    drawn = np.asarray(jax.jit(lambda *a: gumbel_topk_sample_batched(*a))(
         keys, logits, top_k, temp, mask))
     plain = np.asarray(jax.jit(reference_draw)(keys, logits, top_k, temp, mask))
     np.testing.assert_array_equal(drawn, plain)
@@ -124,6 +163,95 @@ def test_selection_is_the_sorts_element_and_draws_its_tokens(case, v):
     if case == "temperatures":
         np.testing.assert_array_equal(
             drawn[:2], np.argmax(np.asarray(logits), axis=-1)[:2])
+    if fit:
+        # the block step's draw by groups: the one loop's tokens and
+        # confidences, bit for bit
+        tokens, conf = jax.jit(
+            lambda *a: gumbel_topk_sample_with_confidence(*a))(
+                keys, logits, top_k, temp, mask)
+        np.testing.assert_array_equal(np.asarray(tokens), drawn)
+        np.testing.assert_array_equal(np.asarray(tokens),
+                                      np.asarray(untiled[0]))
+        np.testing.assert_array_equal(
+            np.asarray(conf).view(np.uint32),
+            np.asarray(untiled[1]).view(np.uint32))
+
+
+@pytest.mark.parametrize(("rows", "v", "fit", "groups"), [
+    (40, 2_560, 16, (3, 16)), (33, 1_152, 32, (2, 32)), (9, 130, 8, (2, 8)),
+    (50, 384, 24, (4, 16)), (7, 96, 7, None), (64, 256, 1, (64, 1))])
+def test_groups_of_other_sizes_hand_out_the_sorts_element(
+        monkeypatch, rows, v, fit, groups):
+    """Budgets of 1 to 32 rows: groups of the power of two under the
+    budget, whole and with a last group of 8, 1, 1 and 2 rows, a width off
+    the lane grid, and one loop where the budget holds every row; k from 1
+    to v."""
+    rng = np.random.default_rng(rows + v)
+    x = np.round(rng.normal(size=(rows, v)), 1).astype(np.float32)
+    x[:, ::5] = -np.inf
+    x[1, :] = rng.choice(np.array([-0.0, 0.0], np.float32), size=v)
+    x.view(np.uint32)[2, 7:40:3] = 0xFFC00000
+    k = jnp.asarray(np.resize([1, 2, 25, v - 1, v, v // 2], rows).astype(
+        np.int32))
+    want = np.asarray(reference_kth(jnp.asarray(x), k))
+    _force_groups(monkeypatch, fit * v * 4)
+    assert sampler._group_rows(rows, v) == (groups and groups[1])
+    jaxpr = str(jax.make_jaxpr(
+        lambda *a: sampler._kth_largest_by_counting(*a))(jnp.asarray(x), k))
+    if groups:
+        assert f"u32[{groups[0]},{groups[1]},{v}]" not in jaxpr
+        assert f"u32[{groups[1]},{v}]" in jaxpr
+    else:
+        assert f"u32[{rows},{v}]" in jaxpr
+    got = np.asarray(sampler._kth_largest_by_counting(jnp.asarray(x), k))
+    np.testing.assert_array_equal(got, want)
+    exact = ~np.isnan(want) & (want != 0)
+    np.testing.assert_array_equal(got.view(np.uint32)[exact],
+                                  want.view(np.uint32)[exact])
+    assert np.isnan(want[2, 0]) == (int(k[2]) <= 11)
+
+
+def _traced_draw(rows, v):
+    """``(jaxpr text, lowering notes)`` of a fresh trace of the block
+    step's draw at ``(rows, v)`` (``jax.make_jaxpr`` keeps a function's
+    trace: a lambda per call)."""
+    args = (jax.vmap(jax.random.key)(jnp.arange(rows, dtype=jnp.uint32)),
+            jax.ShapeDtypeStruct((rows, v), jnp.float32),
+            jax.ShapeDtypeStruct((rows,), jnp.int32),
+            jax.ShapeDtypeStruct((rows,), jnp.float32),
+            jax.ShapeDtypeStruct((rows, v), jnp.bool_))
+    with record_lowerings() as chosen:
+        text = str(jax.make_jaxpr(
+            lambda *a: gumbel_topk_sample_with_confidence(*a))(*args))
+    return text, chosen
+
+
+@pytest.mark.parametrize("cell", CELL_DRAWS)
+def test_the_shape_decides_where_the_rounds_run(monkeypatch, cell):
+    """On the chip (its choice forced here) every token-by-token cell's
+    draw traces the one loop, to the letter, and notes ``"xla"``; the block
+    step's 256 x 151,936 goes by groups of 32 rows, and no uint32 array of
+    its whole shape is left in the program."""
+    rows, v = CELL_DRAWS[cell]
+    text, off_chip = _traced_draw(rows, v)
+    assert off_chip == {"sample_kth": {"xla"}} and "pallas_call" not in text
+    _force_groups(monkeypatch)
+    forced, chosen = _traced_draw(rows, v)
+    if cell != "serve-sdar-blockdiff-backlog":
+        assert chosen == {"sample_kth": {"xla"}} and forced == text
+        return
+    assert chosen == {"sample_kth": {"xla_tiled"}}
+    assert sampler._group_rows(rows, v) == 32
+    # the keys exist a group at a time, never as one array of the draw's
+    # shape for a loop to read 32 times
+    keys = "u32[{}] = bitcast_convert_type[new_dtype=uint32]"
+    assert keys.format(f"{rows},{v}") in text
+    assert keys.format(f"{rows},{v}") not in forced
+    assert keys.format(f"32,{v}") in forced and "pallas_call" not in forced
+    # a mesh in scope keeps the one loop whatever the shape
+    with jax.sharding.Mesh(np.array(jax.devices()[:1]), ("x",)):
+        meshed, chosen = _traced_draw(rows, v)
+    assert chosen == {"sample_kth": {"xla"}} and meshed == text
 
 
 @pytest.mark.parametrize("v", (256, 16_384, 25_024, 100_352))

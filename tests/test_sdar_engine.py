@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from perf.lib import reference_sdar as ref
-from progen_tpu.decode import Request, ServingEngine
+from progen_tpu.decode import Request, ServingEngine, sampler
 from progen_tpu.decode.engine import SLOTS_PER_ADMIT_ROW
 from progen_tpu.decode.family import UnsupportedFamilyMode, family_for
 from progen_tpu.models.sdar import SDARFamily
@@ -225,7 +225,9 @@ def test_nothing_compiles_after_warmup_and_the_state_holds_a_block(engine):
     assert state["caches"]["l0"]["k"].shape == (SLOTS, 2, 48, 16)
     assert engine.lowerings == {
         "gqa_prefill": "xla", "gqa_block_decode": "xla",
-        "moe_experts": "xla", "row_write": "scatter"}
+        "moe_experts": "xla", "row_write": "scatter", "sample_kth": "xla"}
+    # the draw is the chunk program's alone: an admission draws nothing
+    assert engine.status()["sample_kth"] == {"chunk": "xla"}
     assert engine.status()["gqa_block_decode"] == "xla"
     assert engine.block_length == BLOCK
 
@@ -323,3 +325,33 @@ def test_a_snapshot_replays_a_block_request_token_for_token(served, engine):
         assert got[r.uid].tokens.tolist() == want[r.uid].tokens.tolist()
         assert (got[r.uid].fill_steps.tolist()
                 == want[r.uid].fill_steps.tolist())
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+def test_the_tiled_draw_serves_the_one_loops_tokens(monkeypatch, served,
+                                                    sampled):
+    """Under a budget that holds 24 of the block step's 128 rows, as the
+    chip's 32 MiB hold 55 of SDAR's 256: the chunk program's draw goes by
+    eight groups of 16 rows (the chip's by eight of 32), the status says so
+    by program, and every request's tokens and fill steps are the one
+    loop's."""
+    params, policy = served
+
+    def serve():
+        eng = ServingEngine(TINY, params, policy=policy, **ENGINE)
+        assert eng.status()["sample_kth"] is None
+        done = _serve(eng, _requests(len(PRIMES), seed=43, sampled=sampled,
+                                     first_uid=700, mask=_allowed(MASK_ID)))
+        return eng.status()["sample_kth"], {
+            uid: (c.tokens.tolist(), c.fill_steps.tolist())
+            for uid, c in done.items()}
+
+    took, want = serve()
+    assert took == {"chunk": "xla"}
+    monkeypatch.setattr(sampler, "_on_tpu", lambda: True)
+    monkeypatch.setattr(sampler, "ROUNDS_ON_CHIP_BYTES",
+                        24 * TINY.vocab_size * 4)
+    assert sampler._group_rows(SLOTS * BLOCK, TINY.vocab_size) == 16
+    took, got = serve()
+    assert took == {"chunk": "xla_tiled"}
+    assert len(got) == len(PRIMES) and got == want
