@@ -24,13 +24,8 @@ with N slots, for equal-budget concurrency comparisons — the record's
 ``max_in_flight`` and ``gate_hbm_bytes`` fields carry the comparison
 (see benchmarks/paged.md).
 
-``--spec`` turns on speculative decoding (``decode/spec.py``):
-``--spec-k`` drafted tokens per verify round, ``--draft tiny`` a shrunk
-random-weight draft (``draft_config_for``) instead of the default
-identity draft.  The record gains ``accepted_tokens_per_step`` (emitted
-tokens per fused verify round — above 1.0 means each decode dispatch
-produced more than one token).  ``--disagg`` splits serving into the
-prefill-worker/handoff-queue/decode-pool stages (``decode/handoff.py``);
+``--disagg`` splits serving into the prefill-worker/handoff-queue/
+decode-pool stages (``decode/handoff.py``);
 the record then ALSO replays the identical arrival schedule on an inline
 engine and carries ``p95_latency_s_inline`` etc. for the side-by-side.
 ``--long-frac`` mixes that fraction of near-``max_len`` primes into the
@@ -150,18 +145,6 @@ def main() -> None:
                     help="with --quantize --verify: minimum greedy "
                          "token-match rate vs the full-precision engine "
                          "(the accuracy-verify tier, docs/SERVING.md §12)")
-    ap.add_argument("--spec", action="store_true",
-                    help="speculative decoding: draft-propose/target-"
-                         "verify rounds instead of single-token steps "
-                         "(token-identical output)")
-    ap.add_argument("--spec-k", type=int, default=4,
-                    help="drafted tokens per speculative round")
-    ap.add_argument("--draft", choices=("identity", "tiny"),
-                    default="identity",
-                    help="draft model: 'identity' reuses the target "
-                         "(every proposal accepted — isolates dispatch "
-                         "overhead), 'tiny' a shrunk random-init config "
-                         "(realistic acceptance dynamics)")
     ap.add_argument("--disagg", action="store_true",
                     help="disaggregated prefill/decode: prefill worker + "
                          "bounded handoff queue + donating merge; the "
@@ -228,7 +211,7 @@ def main() -> None:
                          "stream mixing all four first-class workloads "
                          "through one engine; the record carries "
                          "per-workload p50/p95 latency.  Not combinable "
-                         "with --spec/--disagg/--serve-procs/--chaos")
+                         "with --disagg/--serve-procs/--chaos")
     ap.add_argument("--lora-tenants", type=int, default=4,
                     help="adapter bank size T for the lora workload "
                          "(tenant 0 is the zero-adapter base; lora "
@@ -329,18 +312,18 @@ def main() -> None:
             raise SystemExit("--quantize weights+pages requires --paged")
 
     if args.trace_file:
-        if (args.spec or args.disagg or args.serve_procs or args.chaos
+        if (args.disagg or args.serve_procs or args.chaos
                 or args.scenario_mix):
             raise SystemExit("--trace-file drives one in-process engine; "
-                             "drop --spec/--disagg/--serve-procs/--chaos/"
+                             "drop --disagg/--serve-procs/--chaos/"
                              "--scenario-mix")
         _run_trace(args, cfg, params, policy)
         return
 
     mix = _parse_mix(args.scenario_mix) if args.scenario_mix else None
-    if mix and (args.spec or args.disagg or args.serve_procs or args.chaos):
+    if mix and (args.disagg or args.serve_procs or args.chaos):
         raise SystemExit("--scenario-mix drives one in-process engine; "
-                         "drop --spec/--disagg/--serve-procs/--chaos")
+                         "drop --disagg/--serve-procs/--chaos")
 
     rng = np.random.default_rng(args.seed)
     pmax = min(args.prime_max, cfg.seq_len - args.max_new - 1)
@@ -456,13 +439,6 @@ def main() -> None:
         paged_impl=args.paged_impl, prefix_cache=not args.no_prefix_cache,
     ) if args.paged else {}
 
-    spec_kwargs: dict = {}
-    if args.spec:
-        spec_kwargs = dict(spec=True, spec_k=args.spec_k)
-        if args.draft == "tiny":
-            from progen_tpu.models.configs import draft_config_for
-
-            spec_kwargs["draft_config"] = draft_config_for(cfg)
     # unconditional: mk_engine applies it only when use_disagg resolves
     # True (and --serve-procs builds sp-disagg comparison engines even
     # without --disagg)
@@ -478,8 +454,7 @@ def main() -> None:
         lora_kwargs = dict(lora_bank=random_lora_bank(
             cfg, args.lora_tenants, args.lora_rank, seed=args.seed + 7))
 
-    def mk_engine(*, robust: bool, use_spec: bool | None = None,
-                  use_disagg: bool | None = None,
+    def mk_engine(*, robust: bool, use_disagg: bool | None = None,
                   use_lora: bool = True,
                   use_quant: bool = True) -> ServingEngine:
         kw = dict(paged_kwargs)
@@ -489,8 +464,6 @@ def main() -> None:
             # the full-precision reference holds the SAME byte budget,
             # which at bf16 rows means fewer pages
             kw["num_pages"] = num_pages_fp
-        if use_spec if use_spec is not None else args.spec:
-            kw.update(spec_kwargs)
         if use_disagg if use_disagg is not None else args.disagg:
             kw.update(disagg_kwargs)
         if use_lora:
@@ -646,19 +619,6 @@ def main() -> None:
             record["lora_tenants"] = args.lora_tenants
             record["lora_rank"] = args.lora_rank
             record["adapter_hbm_bytes"] = plan.adapter_bytes
-    if args.spec:
-        sc = engine.spec_counters()
-        record.update({
-            "spec": True,
-            "spec_k": args.spec_k,
-            "draft": args.draft,
-            "spec_emitted_tokens": sc["spec_emitted_tokens"],
-            "spec_verify_rounds": sc["spec_verify_rounds"],
-            # emitted tokens per fused verify dispatch: > 1.0 means each
-            # decode-step program produced more than one token
-            "accepted_tokens_per_step": round(
-                sc["accepted_tokens_per_round"], 3),
-        })
     if args.disagg:
         # replay the IDENTICAL specs + arrival schedule inline so the
         # record carries the interference comparison disaggregation
@@ -1145,18 +1105,11 @@ def _run_multiproc(args, cfg, max_len, paged_kwargs, mk_engine, warm,
                      max_len=max_len,
                      prefill_batch=args.prefill_batch,
                      handoff_depth=args.handoff_depth, **paged_kwargs)
-    draft_config = None
-    if args.spec:
-        engine_kw.update(spec=True, spec_k=args.spec_k)
-        if args.draft == "tiny":
-            from progen_tpu.models.configs import draft_config_for
-
-            draft_config = draft_config_for(cfg)
     # init_seed=0 + mixed_precision=True is EXACTLY this script's param
     # recipe, so the workers' params are bit-identical to the in-process
     # comparison engines' — token identity is assertable
     wspec = make_spec(cfg, mixed_precision=True, init_seed=0,
-                      engine=engine_kw, draft_config=draft_config,
+                      engine=engine_kw,
                       statusz=args.statusz,
                       trace=({"dir": os.path.abspath(args.trace_out)}
                              if args.trace else None))
@@ -1286,7 +1239,6 @@ def _run_multiproc(args, cfg, max_len, paged_kwargs, mk_engine, warm,
         "max_new_tokens": args.max_new,
         "max_len": max_len,
         "paged": args.paged,
-        "spec": args.spec,
         "prefill_procs": args.prefill_procs,
         "replicas": args.replicas,
         "prefill_batch": engine_kw["prefill_batch"],
@@ -1322,7 +1274,7 @@ def _run_multiproc(args, cfg, max_len, paged_kwargs, mk_engine, warm,
     if args.verify:
         # token identity: every cluster completion must match the plain
         # single-process engine on the same (tokens, seed) set
-        plain = mk_engine(robust=False, use_spec=False, use_disagg=False)
+        plain = mk_engine(robust=False, use_disagg=False)
         for uid in range(args.requests):
             plain.submit(make_request(uid, time.perf_counter()))
         clean = {c.uid: c.tokens.tolist() for c in plain.run_until_idle()}
@@ -1497,7 +1449,7 @@ def _run_fleetcache(args, cfg, params, max_len, paged_kwargs,
         # placement is a performance hint, never a correctness input:
         # both clusters must be token-identical to the plain
         # single-process engine on the same (tokens, seed) set
-        plain = mk_engine(robust=False, use_spec=False, use_disagg=False)
+        plain = mk_engine(robust=False, use_disagg=False)
         for uid in range(args.requests):
             plain.submit(make_request(uid, time.perf_counter()))
         clean = {c.uid: [int(t) for t in c.tokens]
@@ -1695,9 +1647,9 @@ def _verify_quant(mk_engine, specs, args, cfg, params, policy) -> dict:
 def _verify(mk_engine, make_request, done, args) -> None:
     """Fault-free rerun + snapshot/restore replay, both asserted
     token-identical to the measured run's non-shed completions.  With
-    ``--spec`` (or ``--disagg``) the fault-free rerun is ALSO compared
-    against a plain inline non-speculative engine, so the whole
-    serving-mode matrix is pinned to one token stream."""
+    ``--disagg`` the fault-free rerun is ALSO compared against a plain
+    inline engine, so both serving modes are pinned to one token
+    stream."""
     import time
 
     clean_eng = mk_engine(robust=False)
@@ -1710,37 +1662,15 @@ def _verify(mk_engine, make_request, done, args) -> None:
     assert not mismatched, (
         f"chaos run diverged from fault-free run for uids {mismatched}")
 
-    if args.spec or args.disagg:
-        plain_eng = mk_engine(robust=False, use_spec=False,
-                              use_disagg=False)
+    if args.disagg:
+        plain_eng = mk_engine(robust=False, use_disagg=False)
         for uid in range(args.requests):
             plain_eng.submit(make_request(uid, time.perf_counter()))
         plain = {c.uid: c.tokens.tolist()
                  for c in plain_eng.run_until_idle()}
         assert clean == plain, (
-            "spec/disagg serving diverged from the plain engine — "
+            "disagg serving diverged from the plain engine — "
             "bit-exactness contract broken")
-    if args.spec:
-        # explicit greedy check: temperature 0, no top-k, spec vs plain
-        from progen_tpu.decode import Request as Rq
-
-        greedy = {}
-        for use_spec, sink in ((True, {}), (False, {})):
-            eng = mk_engine(robust=False, use_spec=use_spec,
-                            use_disagg=False)
-            for uid in range(min(4, args.requests)):
-                base = make_request(uid, time.perf_counter())
-                eng.submit(Rq(
-                    uid=uid, tokens=base.tokens,
-                    max_new_tokens=base.max_new_tokens, top_k=None,
-                    temperature=0.0, seed=base.seed,
-                    submit_time=base.submit_time))
-            sink.update({c.uid: c.tokens.tolist()
-                         for c in eng.run_until_idle()})
-            greedy[use_spec] = sink
-        assert greedy[True] == greedy[False], (
-            "greedy speculative output != greedy non-speculative output")
-
     # snapshot mid-run, replay on a FRESH engine, assert token identity
     snap_eng = mk_engine(robust=False)
     for uid in range(args.requests):

@@ -43,8 +43,6 @@ DET_ZONES: tuple[DetZone, ...] = (
     DetZone(r"progen_tpu/decode/paging\.py$", r".*",
             why="which pages a slot shares, takes or gives back is replayed "
                 "with the schedule"),
-    DetZone(r"progen_tpu/decode/spec\.py$", r".*",
-            why="spec accept/reject is part of token identity"),
     DetZone(
         r"progen_tpu/decode/engine\.py$",
         r"(?:.*\.)?(submit_fork|_release_forks|_maybe_preempt|_preempt_slot"

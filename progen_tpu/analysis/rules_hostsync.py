@@ -77,8 +77,7 @@ HOT_ZONES: tuple[Zone, ...] = (
                    "_draining", "_aot", "_compiled_keys", "_defer_streak",
                    "fault_retries", "max_queue", "shed_policy",
                    "paged_impl", "_watchdog", "_handoff", "disagg",
-                   "spec", "spec_k", "prefill_batch", "_max_advance",
-                   "_spec_rounds", "remote_prefill", "stage_seconds",
+                   "prefill_batch", "remote_prefill", "stage_seconds",
                    "_tracer", "_stage_hist", "_embed_queue", "lora",
                    "qos_weights", "_qos_gauge_keys", "prefix_lookups",
                    "fork_groups", "_fork_wait", "_ttft", "_admitted",

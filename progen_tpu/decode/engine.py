@@ -36,8 +36,8 @@ move by committed blocks, and admission hands over a prime whose last ``P
 mod B`` tokens open the first block instead of a sampled first token
 (docs/SERVING.md, "Generation by blocks").
 
-Three device programs serve every mode: the decode chunk, its
-speculative variant and admission (= prefill ∘ merge, the two halves
+Two device programs serve every mode: the decode chunk (one of the two
+bodies above) and admission (= prefill ∘ merge, the two halves
 disaggregated serving runs apart).  Where a slot's cache rows live —
 in the slot, or its gate rows in a page pool — is a CACHE LAYOUT
 (``decode/paging.py``) chosen at construction; the programs and the one
@@ -62,18 +62,14 @@ into an explicit PREFILL stage (a worker program per bucket producing
 cache HANDLES into a bounded handoff queue, ``decode/handoff.py``) and a
 DECODE stage that admits from the queue via a donating merge program —
 decode chunks dispatch BEFORE the round's prefill, so a long prefill
-never stalls in-flight decode.  Speculative mode (``spec=True``,
-``decode/spec.py``) replaces the chunk's sequential target steps with
-draft-propose/target-verify rounds whose output is token-identical to
-plain decoding for any draft.
+never stalls in-flight decode.
 
 Robustness (docs/RESILIENCE.md): every serving phase runs behind a named
 fault-injection point (``serve.submit`` / ``serve.admit`` /
 ``serve.prefill`` / ``serve.decode_chunk`` / ``serve.harvest`` /
-``serve.page_alloc``, plus ``serve.handoff`` for the disaggregated merge
-and ``serve.verify`` replacing ``serve.decode_chunk`` under speculative
-decoding).  Because each phase is FUNCTIONAL — state in,
-state out, ``self.state`` replaced only on success — a transient fault is
+``serve.page_alloc``, plus ``serve.handoff`` for the disaggregated
+merge).  Because each phase is FUNCTIONAL — state in, state out,
+``self.state`` replaced only on success — a transient fault is
 contained by re-running the failed dispatch in place; a fatal fault sheds
 only the requests whose work was lost, as typed completions
 (``FAILED_FAULT``) rather than exceptions.  Requests carry optional
@@ -109,15 +105,10 @@ from progen_tpu.resilience import faults
 from progen_tpu.resilience.retry import RetryError, default_classifier
 from progen_tpu.resilience.watchdog import Watchdog
 from progen_tpu.decode.family import UnsupportedFamilyMode, family_for
-from progen_tpu.decode.incremental import ProGenDecodeStep, init_caches
 from progen_tpu.decode.paging import PagedGates, SlotCaches, pages_for_span
 from progen_tpu.decode.handoff import Handle, HandoffQueue
 from progen_tpu.decode.qos import QoSQueue
-from progen_tpu.decode.prefill import (
-    _constrain_caches,
-    harvest_caches,
-    mesh_trace_ctx,
-)
+from progen_tpu.decode.prefill import _constrain_caches, mesh_trace_ctx
 from progen_tpu.decode.sampler import (
     confident_positions,
     gumbel_topk_sample_batched,
@@ -125,8 +116,7 @@ from progen_tpu.decode.sampler import (
     split_keys_batched,
     transfer_counts,
 )
-from progen_tpu.decode.spec import check_draft_config, spec_round
-from progen_tpu.models.progen import ProGen, ProGenConfig
+from progen_tpu.models.progen import ProGenConfig
 from progen_tpu.ops.lowering import record_lowerings
 from progen_tpu.ops.row_write import write_rows
 
@@ -421,16 +411,6 @@ class ServingEngine:
     ``shed_policy="shed-oldest"`` the victim is the lowest class's
     oldest request, never a strictly higher class than the newcomer.
 
-    **Speculative decoding** (``spec=True``): a draft model
-    (``draft_config``/``draft_params``; defaults to the IDENTITY draft —
-    the target itself, 100% acceptance) proposes ``spec_k`` tokens per
-    round, verified in one fused target scan (``decode/spec.py``).
-    Output is token-identical to plain decoding for ANY draft — greedy
-    and sampled alike — so per-request seed determinism and
-    snapshot/replay survive unchanged.  ``draft_config`` without
-    ``draft_params`` random-initializes the draft (testing convenience;
-    a real deployment loads a trained draft).
-
     **Disaggregated serving** (``disagg=True``): prefill runs as its own
     worker program over FIFO-prefix batches of up to ``prefill_batch``
     requests sharing a bucket, producing cache handles into a bounded
@@ -452,8 +432,6 @@ class ServingEngine:
                  prefix_cache: bool = True,
                  max_queue: int | None = None, shed_policy: str = "reject",
                  fault_retries: int = 3, watchdog: Watchdog | None = None,
-                 spec: bool = False, draft_config: ProGenConfig | None = None,
-                 draft_params=None, spec_k: int = 4,
                  disagg: bool = False, prefill_batch: int | None = None,
                  handoff_depth: int = 2, remote_prefill: bool = False,
                  lora_bank=None, qos_weights: dict | None = None,
@@ -465,7 +443,7 @@ class ServingEngine:
         # state is refused here, by name and with no fallback
         self._weights_mode = "int8" if quantize else "bf16"
         self.family = family_for(config, self.policy, self._weights_mode)
-        asked = {"paged": paged, "spec": spec, "disagg": disagg,
+        asked = {"paged": paged, "disagg": disagg,
                  "lora_bank": lora_bank is not None,
                  "quantize": quantize is not None, "mesh": mesh is not None}
         for mode, on in asked.items():
@@ -598,64 +576,21 @@ class ServingEngine:
         if quantize:
             params = self._quantize_variables(params)
 
-        self.spec = spec
         self.disagg = disagg
         self.lora = lora_bank is not None
         if self.lora:
-            # composition bounds: the adapter gather composes with dense,
-            # paged, and disaggregated decode (the handle carries a
-            # ``tenant`` leaf in its state tree); the spec draft/commit
-            # scans do not carry tenant state (yet)
-            if spec:
-                raise ValueError("lora_bank does not compose with spec=True")
+            # the adapter gather composes with dense, paged and
+            # disaggregated decode (the handle carries a ``tenant`` leaf
+            # in its state tree)
             from progen_tpu.workloads.lora import validate_lora_bank
 
             self.num_tenants = validate_lora_bank(config, lora_bank)
             lora_bank = jax.tree.map(jnp.asarray, lora_bank)
-        else:
-            self.num_tenants = 1
-        if spec:
-            if spec_k < 1:
-                raise ValueError(f"spec_k must be >= 1, got {spec_k}")
-            self.spec_k = int(spec_k)
-            self.draft_config = draft_config or config
-            check_draft_config(config, self.draft_config)
-            # an identity draft shares the (possibly quantized) target
-            # params, so its models must match the target's weight mode;
-            # an explicit draft stays full precision
-            identity_draft = draft_params is None and draft_config is None
-            draft_weights = self._weights_mode if identity_draft else "bf16"
-            if draft_params is None:
-                if draft_config is None:
-                    draft_params = params  # identity draft
-                else:
-                    from progen_tpu.parallel import unbox
-
-                    toks = jnp.zeros((1, self.draft_config.seq_len),
-                                     jnp.int32)
-                    draft_params = unbox(jax.jit(ProGen(
-                        config=self.draft_config,
-                        policy=self.policy).init)(jax.random.key(0), toks))
-            # rounds per dispatch: a fully-accepted round advances k+1
-            # positions, so the chunk budget is kept in emitted tokens
-            self._spec_rounds = max(1, chunk_size // (self.spec_k + 1))
-            self._max_advance = self._spec_rounds * (self.spec_k + 1)
-            self._draft_step_model = ProGenDecodeStep(
-                config=self.draft_config, policy=self.policy,
-                weights=draft_weights)
-            self._draft_prefill_model = ProGen(config=self.draft_config,
-                                               policy=self.policy,
-                                               weights=draft_weights)
-            self._spec_emitted = jnp.zeros((), jnp.int32)
-            self._spec_verify_rounds = jnp.zeros((), jnp.int32)
-            self._params = {"target": params, "draft": draft_params}
-        elif self.lora:
-            self._max_advance = chunk_size
             # the bank rides the params pytree so every AOT program takes
             # it as a real argument (hot-swappable without recompiles)
             self._params = {"base": params, "adapters": lora_bank}
         else:
-            self._max_advance = chunk_size
+            self.num_tenants = 1
             self._params = params
         # weight generation: bumped by reload_weights(); completions are
         # stamped with the generation current when they finish (in the
@@ -752,11 +687,6 @@ class ServingEngine:
             state["stats"] = stats
         if self.lora:
             state["tenant"] = jnp.zeros((s,), jnp.int32)
-        if self.spec:
-            # the draft's caches stay DENSE per slot even in paged mode:
-            # the draft is tiny, paging its rows would buy nothing
-            state["draft_caches"] = init_caches(
-                self.draft_config, s, self.policy, decode_len=L)
         return state
 
     def _diffusion_zeros(self) -> dict:
@@ -902,14 +832,9 @@ class ServingEngine:
         return fn(self._target_params(self._params), tokens, lengths)
 
     def _target_params(self, params):
-        """Under speculative decoding ``self._params`` bundles target and
-        draft weights; under LoRA it bundles the base tree and the
-        adapter bank; plain serving passes the target tree through."""
-        if self.spec:
-            return params["target"]
-        if self.lora:
-            return params["base"]
-        return params
+        """The model's own weights: under LoRA ``self._params`` bundles the
+        base tree and the adapter bank, otherwise it is the tree itself."""
+        return params["base"] if self.lora else params
 
     def _adapters(self, params):
         """The stacked adapter bank when serving LoRA, else ``None`` (the
@@ -983,10 +908,8 @@ class ServingEngine:
         return jax.jit(traced)
 
     def _chunk_impl(self):
-        """The chunk program's body, chosen from the mode and from what the
-        family states about how it generates."""
-        if self.spec:
-            return self._decode_chunk_spec_impl
+        """The chunk program's body, chosen from what the family states
+        about how it generates."""
         if self.block_length:
             return self._block_chunk_impl
         return self._decode_chunk_impl
@@ -1155,42 +1078,6 @@ class ServingEngine:
                                     length=self.chunk_size)
         return state
 
-    def _decode_chunk_spec_impl(self, params, state, *operands):
-        """The chunk under speculative decoding: ``_spec_rounds``
-        propose/verify/commit rounds (``decode/spec.py``) instead of
-        ``chunk_size`` single-token target steps.  A verify step is taken
-        by its ``live`` rows only and the layout rolls the others back (a
-        paged pool's writes are masked inside the step: a live verify step
-        consumes a token the round has already committed, so its write is
-        final).  Returns ``(state, stats)``; emitted-token and verify-round
-        counts stay on device (``spec_counters`` reads them off the hot
-        path)."""
-        lay = self._layout
-        tgt, drf = params["target"], params["draft"]
-        with self._trace_ctx():
-            state = {**state, "caches": _constrain_caches(
-                state["caches"], self.mesh, self.strategies)}
-
-            def target_step(tok, pos, caches, live):
-                return lay.step(tgt, tok, pos, caches, live, None, None,
-                                operands)[:2]
-
-            def draft_step(tok, pos, dc):
-                return self._draft_step_model.apply(drf, tok, pos, dc)
-
-            emitted = jnp.zeros((), jnp.int32)
-            rounds = jnp.zeros((), jnp.int32)
-            for _ in range(self._spec_rounds):
-                live0 = lay.live(state, operands)
-                state, em = spec_round(
-                    state, spec_k=self.spec_k, max_len=self.max_len,
-                    eos_id=EOS_ID, target_step=target_step,
-                    draft_step=draft_step, merge_caches=lay.rollback,
-                    live0=live0)
-                emitted = emitted + jnp.sum(em)
-                rounds = rounds + jnp.any(live0).astype(jnp.int32)
-        return state, {"emitted": emitted, "rounds": rounds}
-
     def _admit_impl(self, params, state, src, mask, *args):
         """One admission run: prefill only the rows being admitted —
         ``args`` is ``_prefill_worker_impl``'s arguments over ``R =
@@ -1227,12 +1114,6 @@ class ServingEngine:
                 self._target_params(params), tokens, lengths, self.max_len,
                 self._adapters(params), tenant)
             caches = _constrain_caches(caches, self.mesh, self.strategies)
-            if self.spec:
-                _, dvarz = self._draft_prefill_model.apply(
-                    params["draft"], tokens, mutable=["cache"])
-                draft_caches = harvest_caches(
-                    self.draft_config, dvarz["cache"], lengths,
-                    self.policy, self.max_len)
 
         keys = jax.vmap(jax.random.key)(seeds.astype(jnp.uint32))
         split = jax.vmap(jax.random.split)(keys)
@@ -1262,8 +1143,6 @@ class ServingEngine:
         }
         if self.lora:
             out["tenant"] = tenant
-        if self.spec:
-            out["draft_caches"] = draft_caches
         if stats:
             out["stats"] = stats
         return out
@@ -1346,9 +1225,6 @@ class ServingEngine:
             out.update({k: take(hstate[k], state[k]) for k in _BLOCK_STATE})
         if self.lora:
             out["tenant"] = take(hstate["tenant"], state["tenant"])
-        if self.spec:
-            out["draft_caches"] = jax.tree.map(
-                take, hstate["draft_caches"], state["draft_caches"])
         if "stats" in state:
             # counters are sums, not rows: the handle's join the state's
             out["stats"] = jax.tree.map(jnp.add, state["stats"],
@@ -2140,13 +2016,10 @@ class ServingEngine:
             for slot in slots:
                 # last position the chunk can consume: done fires when
                 # new_pos + 1 >= stop, so a live slot never consumes past
-                # stop - 2; gate rows are written at consumed positions.
-                # _max_advance == chunk_size except under speculation,
-                # where a chunk of fully-accepted rounds can advance
-                # rounds * (k + 1) positions
+                # stop - 2; gate rows are written at consumed positions
                 r = self._inflight[slot]
                 stop = min(len(r.tokens) + r.max_new_tokens, self.max_len)
-                last = min(int(pos[slot]) + self._max_advance - 1, stop - 2)
+                last = min(int(pos[slot]) + self.chunk_size - 1, stop - 2)
                 need = pages_for_span(last, self.page_size)
                 pages = lay.slot_pages[slot]
                 delta = need - len(pages)
@@ -2269,26 +2142,18 @@ class ServingEngine:
             if not self._inflight:
                 return  # everything got evicted back to the queue
         args = self._layout.chunk_operands()
-        point = "serve.verify" if self.spec else "serve.decode_chunk"
         while True:
             t0 = time.perf_counter()
             try:
                 batch = list(self._inflight.values())
                 with self._span("serve.decode_chunk",
                                 uids=[r.uid for r in batch]):
-                    out = self._guard(point, self._chunk_call, *args,
+                    out = self._guard("serve.decode_chunk",
+                                      self._chunk_call, *args,
                                       key=("chunk",))
                 self._open_stages.append(
                     ("decode_chunk_s", "chunk", t0, batch))
                 self._chunk_rows_hist.observe(len(batch))
-                if self.spec:
-                    out, stats = out
-                    # lazy device-side accumulation — spec_counters()
-                    # fetches these once, off the hot path
-                    self._spec_emitted = self._spec_emitted + \
-                        stats["emitted"]
-                    self._spec_verify_rounds = self._spec_verify_rounds + \
-                        stats["rounds"]
                 self.state = out
                 self.chunks_run += 1
                 return
@@ -2682,11 +2547,7 @@ class ServingEngine:
                         f"{a.shape}/{a.dtype} vs {b.shape}/{b.dtype}")
             return new
 
-        if self.spec:
-            if params is not None:
-                self._params = {**self._params, "target": _swap(
-                    params, self._params["target"], "params")}
-        elif self.lora:
+        if self.lora:
             bundle = dict(self._params)
             if params is not None:
                 bundle["base"] = _swap(params, self._params["base"],
@@ -2782,32 +2643,12 @@ class ServingEngine:
         return {"programs": programs,
                 "seconds": time.perf_counter() - t0}
 
-    def spec_counters(self) -> dict:
-        """Speculation throughput counters — ONE device fetch, so call
-        this off the hot path (the chunk impls accumulate the counts
-        lazily on device).  ``accepted_tokens_per_round`` above 1.0 means
-        each fused verify round emitted more than one token on average:
-        the dispatch-count win speculative decoding buys."""
-        if not self.spec:
-            return {}
-        emitted, rounds = _host_fetch(
-            (self._spec_emitted, self._spec_verify_rounds))
-        emitted, rounds = int(emitted), int(rounds)
-        return {
-            "spec_k": self.spec_k,
-            "spec_emitted_tokens": emitted,
-            "spec_verify_rounds": rounds,
-            "accepted_tokens_per_round":
-                (emitted / rounds) if rounds else 0.0,
-        }
-
     def status(self) -> dict:
         """Live engine state for the /statusz endpoint — HOST bookkeeping
         only (queues, slot maps, counters, stage walls).  This is served
         from the statusz HTTP thread concurrently with the stage loop, so
-        it must never sync the device: ``spec_counters`` is deliberately
-        absent (it costs a ``jax.device_get``), and everything read here
-        is a plain host dict/int the GIL keeps coherent."""
+        it must never sync the device: everything read here is a plain
+        host dict/int the GIL keeps coherent."""
         active = len(self._inflight)
         return {
             "slots": {"total": self.num_slots, "active": active,
@@ -2840,7 +2681,6 @@ class ServingEngine:
             "sample_kth": self._lowering_by_program("sample_kth"),
             "paged": self.paged,
             "disagg": self.disagg,
-            "spec": self.spec,
             "stage_seconds": {k: round(v, 6) for k, v in
                               list(self.stage_seconds.items())},
             "qos": self.qos_status(),

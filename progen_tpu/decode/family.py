@@ -62,7 +62,7 @@ builds and nothing else of it:
 ``publish(stats)``
     registry gauge values from the fetched counters
 
-Paged, speculative, LoRA, disaggregated, quantized and mesh serving are
+Paged, LoRA, disaggregated, quantized and mesh serving are
 ProGen's alone today (its ``modes``); there is no fallback.  Their programs
 are the plain path's own — one chunk body, one admission — reading the
 engine's cache layout (``decode/paging.py``): ``SlotCaches`` steps through
@@ -88,7 +88,7 @@ from progen_tpu.models.progen import ProGen, ProGenConfig
 
 # every mode beyond the plain dense path, by ``ServingEngine``'s argument
 SERVING_MODES = frozenset(
-    {"paged", "spec", "disagg", "lora_bank", "quantize", "mesh"})
+    {"paged", "disagg", "lora_bank", "quantize", "mesh"})
 
 
 class UnsupportedFamilyMode(ValueError):

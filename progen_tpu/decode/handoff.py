@@ -66,9 +66,8 @@ class Handle:
     state slabs belongs to ``requests[i]``; later rows are dummy).
     ``state``: device arrays, ``(num_slots, ...)``-shaped — seq, caches
     (dense gate rows even in paged mode; the merge scatters them into
-    the pool), pos/start/stop/done/keys/top_k/temp, plus draft caches
-    under speculative decoding.  ``p_pad``: the prefill bucket that
-    produced it (observability; the merge program is bucket-agnostic).
+    the pool), pos/start/stop/done/keys/top_k/temp.  ``p_pad``: the
+    prefill bucket that produced it (observability; the merge program is bucket-agnostic).
     """
 
     requests: list

@@ -25,12 +25,8 @@ Module/parameter names exactly mirror ``progen_tpu.models.progen.ProGen``
 (``attn{i}``/``ff{i}``/``embed``/``norm_out``/``to_logits`` with identical
 submodule names), so trained parameters bind directly to the decode graph.
 
-Speculative decoding (``decode/spec.py``) reuses this step for BOTH the
-target and the tiny draft model (a second ``ProGenDecodeStep`` over
-``draft_config_for``'s shrunk config); callers that run a step on a
-throwaway cache copy past a row's logical end must clamp positions to
-``[0, decode_len)`` themselves — the step trusts ``pos`` to index the
-SGU weight rows, it never bounds-checks it.
+The step trusts ``pos`` to index the SGU weight rows and never
+bounds-checks it: callers keep positions in ``[0, decode_len)``.
 """
 
 from __future__ import annotations
@@ -505,7 +501,7 @@ class ProGenPagedDecodeStep(nn.Module):
     page ``table`` instead of a per-slot dense cache.  ``write_ok`` masks
     the pool scatter only — ring/carry writes are merged by liveness in
     the engine's chunk body (a paused row must not clobber its carries
-    with a speculative step's values, since its ``pos`` does not advance).
+    with a masked step's values, since its ``pos`` does not advance).
     """
 
     config: ProGenConfig

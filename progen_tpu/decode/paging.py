@@ -295,10 +295,6 @@ class SlotCaches:
         reads its caches before an admission overwrites them."""
         return new
 
-    def rollback(self, live, new, old):
-        """After a speculative step: only the ``live`` rows took it."""
-        return _where_rows(live, new, old)
-
     def split_handle(self, hstate):
         """``(handle, gate rows)``: what of a handle the merge may donate
         and what it scatters."""
@@ -395,17 +391,13 @@ class PagedGates:
             params, tok, pos, caches, table, live, adapters, tenant)
         return logits, caches, {}
 
-    def rollback(self, live, new, old):
-        """A row that did not take the step keeps its rings and carries;
-        the pool (and its scales) is written through, because its writes
-        are masked inside the step (``write_ok``)."""
+    def idle_keeps(self, live, new, old):
+        """A paused row runs the step fully masked and resumes later: it
+        keeps its rings and carries, which still hold position ``pos -
+        1``'s activations; the pool (and its scales) is written through,
+        because its writes are masked inside the step (``write_ok``)."""
         return {**new, **{k: _where_rows(live, new[k], old[k])
                           for k in self._RING_KEYS}}
-
-    # a paused row runs the step fully masked and resumes later: its
-    # carries still hold position ``pos - 1``'s activations, and the
-    # discarded step must not overwrite them
-    idle_keeps = rollback
 
     def split_handle(self, hstate):
         # the gate slabs scatter into the pool, so they can alias nothing:

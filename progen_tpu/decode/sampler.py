@@ -293,11 +293,8 @@ def split_keys_batched(key_data):
     """Advance a batch of raw uint32 key data one split: returns
     ``(next_key_data, subkeys)``.  The serving engine's per-slot key
     chains live as RAW key data (``jax.random.key_data``) so they can
-    ride through jitted state dicts; every consumer of the chain — the
-    decode chunk bodies, the speculative draft-propose and target-verify
-    scans — must derive subkeys the same way, or bit-exactness between
-    the speculative and plain paths breaks.  This helper is that one
-    way."""
+    ride through jitted state dicts; every consumer of the chain (the
+    decode chunk bodies) derives subkeys this one way."""
     keys = jax.random.wrap_key_data(key_data)
     split = jax.vmap(jax.random.split)(keys)  # (B, 2) keys
     return jax.random.key_data(split[:, 0]), split[:, 1]

@@ -1,5 +1,3 @@
-from progen_tpu.models.configs import draft_config_for
 from progen_tpu.models.progen import FeedForward, LocalAttention, ProGen, ProGenConfig, SGU
 
-__all__ = ["FeedForward", "LocalAttention", "ProGen", "ProGenConfig", "SGU",
-           "draft_config_for"]
+__all__ = ["FeedForward", "LocalAttention", "ProGen", "ProGenConfig", "SGU"]

@@ -7,8 +7,6 @@ ladder the TPU build targets.
 
 from __future__ import annotations
 
-import dataclasses
-
 from progen_tpu.models.progen import ProGenConfig
 
 # Reference repo's default toy config (configs/model/default.toml:1-9).
@@ -56,25 +54,3 @@ CONFIGS = {
     "xl": XL,
 }
 
-
-def draft_config_for(target: ProGenConfig, *, dim: int | None = None,
-                     depth: int | None = None, heads: int | None = None,
-                     dim_head: int | None = None) -> ProGenConfig:
-    """A tiny draft config for speculative decoding against ``target``.
-
-    The draft MUST share ``num_tokens`` (proposals live in the target's
-    vocabulary), ``window_size`` (the serving engine's prefill buckets are
-    window-aligned, and one padded prime batch prefills both models) and
-    ``seq_len`` (positions mean the same thing to both).  Everything that
-    only affects capacity — width, depth, heads — shrinks; the default is
-    a quarter-width, two-layer model with one gMLP layer.
-    """
-    depth = depth if depth is not None else min(2, target.depth)
-    return dataclasses.replace(
-        target,
-        dim=dim if dim is not None else max(8, target.dim // 4),
-        depth=depth,
-        heads=heads if heads is not None else max(1, target.heads // 2),
-        dim_head=dim_head if dim_head is not None else target.dim_head,
-        global_mlp_depth=min(1, depth),
-    )

@@ -12,9 +12,8 @@ token-identical to a disabled one.  That holds because every handler
 reads host-side bookkeeping only — engine ``status()`` (host dicts),
 registry snapshots (host floats), the tracer ring, flight-recorder
 events.  Nothing here may ever call ``jax.device_get`` or touch a device
-array (``ServingEngine.spec_counters`` is deliberately NOT surfaced: it
-costs a device fetch).  Handlers run on the HTTP thread concurrently
-with the serving loop; they read via provider callables and a racy read
+array.  Handlers run on the HTTP thread concurrently with the serving
+loop; they read via provider callables and a racy read
 of a mutating dict is answered with a 503 the client retries, never a
 crash and never a lock the hot path could contend on.
 

@@ -44,14 +44,14 @@ from collections import deque
 
 
 def make_spec(config, *, mixed_precision: bool = True, init_seed: int = 0,
-              checkpoint_path: str | None = None, draft: str = "identity",
-              engine: dict | None = None, draft_config=None,
+              checkpoint_path: str | None = None,
+              engine: dict | None = None,
               heartbeat_s: float = 1.0, trace: dict | None = None,
               statusz: bool = False, lora: dict | None = None,
               aot_warmup: bool = False,
               warmup_max_prime: int | None = None) -> dict:
     """Build the JSON-able worker spec.  ``engine`` holds
-    :class:`ServingEngine` kwargs (slots/chunk/paged/spec/...,
+    :class:`ServingEngine` kwargs (slots/chunk/paged/...,
     including ``quantize`` — every worker built from the spec quantizes
     the same full-precision init/checkpoint tree, so int8 replicas stay
     bit-identical to each other); ``disagg`` is implied.  Params come from ``checkpoint_path`` when
@@ -78,7 +78,6 @@ def make_spec(config, *, mixed_precision: bool = True, init_seed: int = 0,
         "mixed_precision": bool(mixed_precision),
         "init_seed": int(init_seed),
         "checkpoint_path": checkpoint_path,
-        "draft": draft,
         "engine": dict(engine or {}),
         "heartbeat_s": float(heartbeat_s),
     }
@@ -92,8 +91,6 @@ def make_spec(config, *, mixed_precision: bool = True, init_seed: int = 0,
         spec["aot_warmup"] = True
         if warmup_max_prime is not None:
             spec["warmup_max_prime"] = int(warmup_max_prime)
-    if draft_config is not None:
-        spec["draft_config"] = draft_config.to_dict()
     return spec
 
 
@@ -134,8 +131,6 @@ def build_engine_from_spec(spec: dict, *, remote_prefill: bool = False,
             jax.random.key(int(spec.get("init_seed", 0))), toks))
     kw = dict(spec.get("engine", {}))
     kw["disagg"] = True
-    if kw.get("spec") and "draft_config" in spec:
-        kw["draft_config"] = ProGenConfig.from_dict(spec["draft_config"])
     if spec.get("lora"):
         # spec-driven bank: random_lora_bank is deterministic per seed,
         # so every process rebuilds the SAME adapters (like init params)

@@ -419,9 +419,6 @@ class ServingMemoryPlan:
     pool_bytes: int           # paged mode only (0 when dense)
     table_bytes: int
     num_slots: int
-    # speculative decoding: the draft model's dense caches per slot
-    # (rings + carries + full gate slab — the draft is never paged)
-    draft_bytes_per_slot: int = 0
     # disaggregated serving: the bounded handoff queue can hold up to
     # ``handoff_depth`` full (num_slots, ...)-shaped handles in flight
     handoff_bytes: int = 0
@@ -453,8 +450,7 @@ class ServingMemoryPlan:
     @property
     def total_bytes(self) -> int:
         return (self.num_slots * (self.fixed_bytes_per_slot
-                                  + self.gate_bytes_per_slot
-                                  + self.draft_bytes_per_slot)
+                                  + self.gate_bytes_per_slot)
                 + self.pool_bytes + self.table_bytes
                 + self.handoff_bytes + self.adapter_bytes)
 
@@ -514,7 +510,7 @@ def weight_hbm_bytes(cfg, *, quantize: bool = False) -> int:
 def serving_plan(cfg, *, num_slots: int, max_len: int | None = None,
                  mixed_precision: bool = True, paged: bool = False,
                  page_size: int = 16, num_pages: int | None = None,
-                 draft_cfg=None, disagg: bool = False,
+                 disagg: bool = False,
                  handoff_depth: int = 2, lora_tenants: int = 0,
                  lora_rank: int = 0,
                  gate_dtype: str = "bf16") -> ServingMemoryPlan:
@@ -526,13 +522,10 @@ def serving_plan(cfg, *, num_slots: int, max_len: int | None = None,
     layer) in paged mode.  ``num_pages`` defaults like the engine's
     (full budget: every slot can reach ``max_len``).
 
-    ``draft_cfg`` (speculative decoding) adds the draft model's DENSE
-    caches per slot — rings, carries and a full gate slab, since the
-    draft is never paged.  ``disagg`` adds the handoff queue's worst
-    case: ``handoff_depth`` handles, each a full ``(num_slots, ...)``
-    state copy with dense gate slabs (even in paged mode — the worker
-    hands off dense rows and the merge scatters them into the pool), plus
-    the draft caches when both modes are on.
+    ``disagg`` adds the handoff queue's worst case: ``handoff_depth``
+    handles, each a full ``(num_slots, ...)`` state copy with dense gate
+    slabs (even in paged mode — the worker hands off dense rows and the
+    merge scatters them into the pool).
 
     The per-slot ``(max_len, vocab)`` bool logit mask (constrained
     infilling) is counted unconditionally — the engine allocates it for
@@ -540,7 +533,7 @@ def serving_plan(cfg, *, num_slots: int, max_len: int | None = None,
     adapter bank (one copy, all slots share it).
 
     ``gate_dtype="int8"`` prices 8-bit gate pages: the POOL shrinks ~2x
-    while dense slabs, draft caches and handoff slabs stay in compute
+    while dense slabs and handoff slabs stay in compute
     dtype (quantization happens at the page-pool boundary).  Requires
     ``paged=True``, mirroring the engine."""
     act = 2 if mixed_precision else 4
@@ -566,20 +559,12 @@ def serving_plan(cfg, *, num_slots: int, max_len: int | None = None,
         pool_b = 0
         gate_b = L * row_b
         table_b = 0
-    draft_b = 0
-    if draft_cfg is not None:
-        d_ring = 2 * draft_cfg.window_size
-        draft_b = (draft_cfg.depth * 2 * draft_cfg.heads * d_ring
-                   * draft_cfg.dim_head * act
-                   + draft_cfg.depth * 2 * draft_cfg.dim * act
-                   + L * gate_row_bytes(draft_cfg, mixed_precision))
     handoff_b = 0
     if disagg:
         # a handle row always carries the DENSE gate slab and the logit
         # mask; ~40 B of per-row scalars (pos/start/stop/done/keys/knobs)
         # ride along
-        per_row = (ring_b + carry_b + seq_b + lmask_b + L * row_b
-                   + draft_b + 40)
+        per_row = ring_b + carry_b + seq_b + lmask_b + L * row_b + 40
         handoff_b = handoff_depth * num_slots * per_row
     adapter_b = 0
     if lora_tenants:
@@ -593,7 +578,6 @@ def serving_plan(cfg, *, num_slots: int, max_len: int | None = None,
         pool_bytes=pool_b,
         table_bytes=table_b,
         num_slots=num_slots,
-        draft_bytes_per_slot=draft_b,
         handoff_bytes=handoff_b,
         lmask_bytes_per_slot=lmask_b,
         adapter_bytes=adapter_b,

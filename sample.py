@@ -75,13 +75,6 @@ import click
               help="engine: AOT-compile every (prefill bucket, decode "
                    "chunk) program via jit(...).lower().compile() before "
                    "accepting traffic, so no request pays a JIT pause")
-@click.option("--spec", is_flag=True,
-              help="engine: speculative decoding — a draft model proposes "
-                   "--spec_k tokens per round, verified in one target step; "
-                   "greedy output is bit-identical to non-spec decode "
-                   "(docs/SERVING.md)")
-@click.option("--spec_k", default=4, help="engine: draft tokens proposed per "
-                                          "speculation round (with --spec)")
 @click.option("--disagg", is_flag=True,
               help="engine: disaggregated serving — prefill runs in a "
                    "separate worker program whose cache handles are merged "
@@ -144,8 +137,7 @@ import click
 def main(seed, checkpoint_path, prime, top_k, temperature, num_samples,
          seq_len, mesh_spec, strategies, serve, embed_mode, infill, slots,
          chunk, paged, page_size, quantize_mode, serve_attempts,
-         snapshot_path, aot_warmup,
-         spec, spec_k, disagg, serve_procs, prefill_procs, replicas,
+         snapshot_path, aot_warmup, disagg, serve_procs, prefill_procs, replicas,
          autoscale, min_prefill, max_prefill, min_replicas, max_replicas,
          swap_at, watchdog_timeout, statusz, trace, trace_out, xprof_dir):
     import os
@@ -287,8 +279,7 @@ def main(seed, checkpoint_path, prime, top_k, temperature, num_samples,
                 checkpoint_path=os.path.abspath(checkpoint_path),
                 engine=dict(num_slots=slots, chunk_size=chunk,
                             max_len=seq_len, paged=paged,
-                            page_size=page_size, spec=spec, spec_k=spec_k,
-                            quantize=quantize_mode),
+                            page_size=page_size, quantize=quantize_mode),
                 trace=({"dir": os.path.abspath(trace_out)}
                        if trace else None),
                 statusz=statusz)
@@ -364,7 +355,7 @@ def main(seed, checkpoint_path, prime, top_k, temperature, num_samples,
                 model_config, {"params": params}, policy=policy,
                 num_slots=slots, chunk_size=chunk, max_len=seq_len,
                 paged=paged, page_size=page_size, quantize=quantize_mode,
-                spec=spec, spec_k=spec_k, disagg=disagg,
+                disagg=disagg,
                 mesh=mesh, strategies=strategy_list,
                 params_shardings=param_sh, watchdog=watchdog)
             if aot_warmup:

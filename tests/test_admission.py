@@ -151,15 +151,6 @@ def test_group_of_n_serves_the_tokens_of_requests_served_alone(
 
 
 @pytest.mark.parametrize("n", [ADMIT_ROWS + 1, SLOTS])
-def test_group_under_spec_serves_the_plain_tokens(served, alone, n):
-    eng = _engine(served, spec=True, spec_k=2)
-    for r in _requests(n, sampled=True):
-        eng.submit(r)
-    got = _tokens(eng.run_until_idle(max_chunks=100))
-    assert got == {u: alone[(True, False)][u] for u in range(n)}
-
-
-@pytest.mark.parametrize("n", [ADMIT_ROWS + 1, SLOTS])
 def test_group_under_lora_keeps_each_rows_tenant(served, n):
     """The tenant rides the R-row handle: row k of a run carries request
     k's adapter into whichever slot it lands in."""

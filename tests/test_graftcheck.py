@@ -1279,10 +1279,10 @@ def test_det_ambient_rng():
         """
         import random
 
-        def draft(xs):
+        def victim(xs):
             return xs[int(random.random() * len(xs))]
         """,
-        path="progen_tpu/decode/spec.py",
+        path="progen_tpu/decode/paging.py",
         rules=["det-ambient-rng"],
     )
     assert rule_names(findings) == ["det-ambient-rng"]
@@ -1290,11 +1290,11 @@ def test_det_ambient_rng():
         """
         import random
 
-        def draft(xs, seed):
+        def victim(xs, seed):
             rng = random.Random(seed)
             return xs[rng.randrange(len(xs))]
         """,
-        path="progen_tpu/decode/spec.py",
+        path="progen_tpu/decode/paging.py",
         rules=["det-ambient-rng"],
     )
     assert findings == []

@@ -198,7 +198,7 @@ def test_nothing_compiles_after_warmup_and_a_slot_holds_state_and_keys(
 
 
 @pytest.mark.parametrize("mode", [
-    dict(paged=True), dict(spec=True), dict(disagg=True),
+    dict(paged=True), dict(disagg=True),
     dict(lora_bank={}), dict(quantize="weights"), dict(mesh=object())],
     ids=lambda m: next(iter(m)))
 def test_a_mode_outside_the_familys_is_refused_by_name(served, mode):

@@ -146,7 +146,7 @@ def test_the_mask_is_one_row_a_slot_and_bans_token_zero(served, engine):
 
 
 @pytest.mark.parametrize("mode", [
-    dict(paged=True), dict(spec=True), dict(disagg=True),
+    dict(paged=True), dict(disagg=True),
     dict(lora_bank={}), dict(quantize="weights"), dict(mesh=object())],
     ids=lambda m: next(iter(m)))
 def test_modes_that_are_progens_alone_are_refused_by_name(served, mode):

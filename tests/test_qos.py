@@ -8,7 +8,7 @@ pre-QoS behavior is unchanged.  A high-priority arrival preempts
 lower-priority in-flight work (pause-free restart replay), and because
 each request's trajectory depends only on (params, prime, seed, knobs),
 preemption trades latency, never tokens — asserted here across dense,
-paged, speculative, and real 2-process cluster serving.
+paged, and real 2-process cluster serving.
 """
 
 import time
@@ -266,7 +266,7 @@ def test_priority_aware_shed_oldest(trained):
     assert {c.uid for c in done if c.ok} == {0, 1, 4}
 
 
-@pytest.mark.parametrize("variant", ["dense", "paged", "spec"])
+@pytest.mark.parametrize("variant", ["dense", "paged"])
 def test_preemption_token_identity(trained, variant):
     """A high-priority arrival preempts the low-priority in-flight
     request; the victim replays from scratch and its tokens are
@@ -274,7 +274,6 @@ def test_preemption_token_identity(trained, variant):
     every engine mode."""
     _, params, policy = trained
     kw = {"paged": dict(paged=True, page_size=4, num_pages=32),
-          "spec": dict(spec=True, spec_k=2),
           "dense": {}}[variant]
     pr = _primes(2, seed=3)
     reqs = [_req(0, pr[0], max_new=8), _req(1, pr[1], priority=2)]

@@ -233,7 +233,7 @@ def test_nothing_compiles_after_warmup_and_the_state_holds_a_block(engine):
 
 
 @pytest.mark.parametrize("mode", [
-    {"paged": True}, {"spec": True}, {"disagg": True}, {"quantize": "weights"},
+    {"paged": True}, {"disagg": True}, {"quantize": "weights"},
     {"lora_bank": {}}], ids=lambda m: next(iter(m)))
 def test_a_mode_the_family_does_not_state_is_refused_by_name(served, mode):
     params, policy = served

@@ -33,6 +33,23 @@ def _reference(params, row, at=None, **kwargs):
                                logit_positions=at, **kwargs)
 
 
+@jax.jit
+def _forward_at(params, row, at):
+    return ref.forward_row(params, row, CFG, logit_positions=at,
+                           key_positions=at)
+
+
+def _block_reference(params, row, p0):
+    """``_reference`` of ``row`` (whole blocks) at the block that starts at
+    ``p0``: ``(logits (BLOCK, V), routing, keys)``, through ONE compiled
+    forward of the row zero-padded to ``MAX_LEN`` — the padding is later
+    blocks, which no position of the row sees."""
+    padded = np.zeros(MAX_LEN, np.int32)
+    padded[:len(row)] = row
+    with jax.default_matmul_precision("highest"):
+        return _forward_at(params, padded, np.arange(p0, p0 + BLOCK))
+
+
 def test_the_tiny_model_has_every_mechanism():
     params, _ = make()
     assert len(params["layers"]) == 3 and "head" in params
@@ -152,9 +169,7 @@ def test_prefill_denoise_commit_through_the_cache_matches_the_reference(
             for i, (t, f, _, _) in enumerate(paths):
                 p0 = int(pos0[i])
                 row = np.concatenate([t[:p0], tok[i]])
-                want, _, keys = _reference(
-                    params, row, np.arange(p0, p0 + BLOCK),
-                    key_positions=np.arange(p0, p0 + BLOCK))
+                want, _, keys = _block_reference(params, row, p0)
                 diff = np.abs(logits[i] - want)
                 # bfloat16 at these tiny widths: a routing that differs
                 # (2 of 8 experts, near-ties) moves a token's logits by more
@@ -197,9 +212,9 @@ def test_a_replay_row_is_every_forward_of_the_trajectory_at_once():
             p0, s = q // BLOCK * BLOCK, fills[q]
             block = np.where(fills[p0:p0 + BLOCK] < s,
                              tokens[p0:p0 + BLOCK], MASK_ID)
-            want, _ = _reference(
-                params, np.concatenate([tokens[:p0], block]), np.asarray([q]))
-            np.testing.assert_allclose(got[g], want[0], atol=2e-5)
+            want = _block_reference(
+                params, np.concatenate([tokens[:p0], block]), p0)[0]
+            np.testing.assert_allclose(got[g], want[q - p0], atol=2e-5)
     # a last, partial block's tokens are not replayed
     _, _, _, index = ref.replay_row(tokens[:n], tokens[n:end - 1],
                                     fills[n:end - 1], CFG, 2)

@@ -172,7 +172,6 @@ def _tiny_spec(variant="dense", trace_dir=None):
     kw.update({
         "dense": {},
         "paged": dict(paged=True, page_size=4, num_pages=32),
-        "spec": dict(spec=True, spec_k=2),
     }[variant])
     return make_spec(cfg, mixed_precision=False, init_seed=7, engine=kw,
                      trace={"dir": trace_dir} if trace_dir else None)
@@ -189,10 +188,7 @@ def test_request_wire_carries_trace_context():
 
 
 @pytest.mark.multiproc
-@pytest.mark.parametrize("variant", [
-    "dense", "paged",
-    pytest.param("spec", marks=pytest.mark.slow),
-])
+@pytest.mark.parametrize("variant", ["dense", "paged"])
 def test_handle_frame_carries_trace_context(variant):
     """Every request row on a handle frame names its trace id (the uid)
     plus the sender's clock, and the producer's trace_ctx extra header

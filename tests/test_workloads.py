@@ -7,8 +7,6 @@ The engine-level invariants, each against the same tiny model:
   paged, greedy and sampled (the mask path costs nothing when unused);
 * a scaffold-constrained request NEVER emits a masked token, and frozen
   interior positions are forced regardless of key/top-k/temperature;
-* speculative decoding under a mask stays token-identical to the plain
-  engine (draft and target are masked identically);
 * a zero-adapter LoRA tenant is bit-identical to the bankless engine,
   tenants batch together in one decode chunk, and paged == dense;
 * the embeddings endpoint matches the standalone embedder bit-exactly
@@ -29,7 +27,6 @@ from progen_tpu.decode.sampler import (
     gumbel_topk_sample,
     gumbel_topk_sample_batched,
 )
-from progen_tpu.models.configs import draft_config_for
 from progen_tpu.models.progen import ProGen, ProGenConfig
 from progen_tpu.workloads import (
     ScaffoldSpec,
@@ -38,6 +35,7 @@ from progen_tpu.workloads import (
     mask_to_wire,
     random_lora_bank,
 )
+from tests.parity import assert_same_steps
 
 pytestmark = pytest.mark.workloads
 
@@ -236,22 +234,6 @@ def test_scaffold_constraint_enforced(params, scaffold, sampled):
         assert gen[4] == 9
 
 
-def test_spec_decode_infill_token_identical(params, scaffold):
-    req = dict(seed=42, top_k=6, temperature=1.1,
-               **scaffold.request_kwargs())
-    plain = mk_engine(params)
-    plain.submit(Request(uid="inf", **req))
-    expect = completions(plain.run_until_idle())
-
-    dcfg = draft_config_for(CFG)
-    dparams = ProGen(config=dcfg).init(
-        jax.random.key(1), jnp.zeros((1, dcfg.seq_len), jnp.int32))
-    eng = mk_engine(params, spec=True, draft_params=dparams,
-                    draft_config=dcfg, spec_k=2)
-    eng.submit(Request(uid="inf", **req))
-    assert completions(eng.run_until_idle()) == expect
-
-
 # ----------------------------------------------------------- engine: lora
 
 def test_lora_tenant0_bit_identical(params, bank, dense_base):
@@ -390,9 +372,7 @@ def test_workload_validation_errors(params, bank):
                                                     bool)))
     with pytest.raises(ValueError):   # embed needs a non-empty prime
         eng.submit_embed(Request(uid="x", tokens=[], max_new_tokens=1))
-    with pytest.raises(ValueError):   # lora composes with paged, not spec
-        mk_engine(params, lora_bank=bank, spec=True)
-    # ...but DOES compose with disaggregated decode: the handle carries a
+    # LoRA composes with disaggregated decode: the handle carries a
     # tenant leaf, and the rolling hot-swap path (docs/SERVING.md §9) ships
     # banks to disaggregated workers
     eng = mk_engine(params, lora_bank=bank, disagg=True)
@@ -452,15 +432,13 @@ def test_lora_train_frozen_base_superstep_and_bank():
     assert any(np.abs(np.asarray(site["b"])).max() > 0
                for layer in trained.values() for site in layer.values())
 
-    # fused superstep == sequential per-step walk, bit for bit
+    # fused superstep == sequential per-step walk (tests/parity.py)
     state2 = fns.init_state(jax.random.key(0))
     state2 = state2.replace(params=init_from_base(state2.params, base_params))
     for kk in range(K):
         for aa in range(accum):
             state2, _ = fns.train_step(state2, superbatch[kk, aa])
-    for x, y in zip(jax.tree.leaves(jax.device_get(state.params)),
-                    jax.tree.leaves(jax.device_get(state2.params))):
-        assert np.array_equal(x, y)
+    assert_same_steps(state.params, state2.params)
 
     # trained factors -> serving bank: tenant 1 reproduces the training
     # forward through the engine-side apply_lora path

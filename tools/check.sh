@@ -63,14 +63,13 @@ JAX_PLATFORMS=cpu python benchmarks/bench_serving.py \
     --max-new 6 --prime-min 4 --prime-max 12 \
     --chaos --verify --ttl 60
 
-echo "== spec-decode smoke =="
-# speculative + disaggregated serving on CPU with --verify: asserts the
-# spec/disagg output is token-identical to the plain engine in the same
-# process (greedy AND sampled; full numbers: benchmarks/spec.md)
+echo "== disagg-serving smoke =="
+# disaggregated serving on CPU with --verify: asserts the disagg output
+# is token-identical to the plain engine in the same process
 JAX_PLATFORMS=cpu python benchmarks/bench_serving.py \
     --config default --requests 4 --rate 50 --slots 2 --chunk 4 \
     --max-new 6 --prime-min 4 --prime-max 12 \
-    --spec --spec-k 2 --disagg --verify
+    --disagg --verify
 
 echo "== multiproc-serving smoke =="
 # real 2-process disaggregated cluster (prefill worker + decode replica
