@@ -33,14 +33,13 @@ def test_listed_with_its_cell_and_silent_on_an_empty_registry(
     cell, moves = CELLS[name]
     bench = harness.load_benchmark()
     entry = next(m for m in bench["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == [cell] and entry["moves"] == moves
+    assert cell in entry["workloads"] and entry["moves"] == moves
     assert entry["source"] == "program_span" and entry["unit"] == "rows"
     layers = {m["layer"] for m in bench["per_layer"]
               if m["name"].startswith("engine.admit_ms")}
     assert {entry["layer"]} == layers
     spec = harness.load_metric(name)
     assert spec["reader"] == "perf/readers/registry_mean.py"
-    assert spec["workloads"] == [cell]
     reader = harness.load_module(spec["reader"])
     # a program without the histogram (the parent), then one that has it
     # and observed nothing: the line leaves the metric out

@@ -12,11 +12,19 @@ import shutil
 import pytest
 
 from perf.lib import deepseek_v2_cost, harness
+from perf.tests.backlog import NOT_ON_A_CPU, SHARED
 
 CELL = "serve-dsv2-decode-backlog"
 CONFIG = harness.load_config("deepseek-v2-ep4")
 BENCH = harness.load_benchmark()
 REDUCED = ("num_hidden_layers", "experts_held", "vocab_size")
+# the cell's per-layer metrics as a SET of names: what every backlog cell
+# reports, what the expert and latent families share, and this family's own
+OWN = {"decode.hbm_share.dsv2", "moe.experts_touched_share.dsv2",
+       "moe.held_groups_per_token.dsv2"}
+METRICS = SHARED | OWN | {
+    "moe.held_assignments_per_token", "moe.held_load_max_over_mean",
+    "moe.expert_passes_per_touched", "mla.rows_read_per_live_row"}
 WIDTHS = ("hidden_size", "intermediate_size", "moe_intermediate_size",
           "num_attention_heads", "kv_lora_rank", "q_lora_rank",
           "qk_rope_head_dim", "qk_nope_head_dim", "v_head_dim",
@@ -93,18 +101,16 @@ def test_the_programs_defaults_are_the_published_widths():
 def test_benchmark_entries_of_the_cell():
     entry = next(w for w in BENCH["workloads"] if w["name"] == CELL)
     assert entry["chips"] == 1 and entry["traffic"] == "backlog-longgen"
-    assert entry == BENCH["workloads"][-1]          # appended
     listed = next(c for c in BENCH["configs"] if c["name"] == entry["config"])
-    assert listed == BENCH["configs"][-1]
     assert listed["reduced"] == CONFIG["reduced"]
     assert listed["source"] == CONFIG["source"]
     assert sum(w["chips"] == 4 for w in BENCH["workloads"]) == 0
     e2e = {m["name"] for m in harness.cell_metrics(BENCH, CELL, "end_to_end")}
     assert e2e == {"setup_s", "serve_tok_s"}
     layer = harness.cell_metrics(BENCH, CELL, "per_layer")
-    assert len(layer) == 10 and layer == BENCH["per_layer"][-10:]
-    assert all(m["workloads"] == [CELL] and m["moves"] == "serve_tok_s"
-               and m["name"].endswith(".dsv2") for m in layer)
+    assert {m["name"] for m in layer} == METRICS
+    assert all(m["moves"] in e2e for m in layer)
+    assert {m["name"] for m in layer if m["workloads"] == [CELL]} == OWN
     traffic = harness.load_traffic(entry["traffic"])
     assert traffic["arrivals"] == {"kind": "backlog",
                                    "requests_per_second": 10.0}
@@ -230,7 +236,7 @@ def _dump(path, obj):
 
 
 @pytest.fixture()
-def checkout(tmp_path, monkeypatch):
+def checkout(tmp_path, monkeypatch, own_registry):
     """A temporary copy of the benchmark with a tiny cell of this family
     ADDED: new files and new entries only."""
     root = tmp_path / "checkout"
@@ -283,11 +289,6 @@ def checkout(tmp_path, monkeypatch):
         return jax.devices()
 
     monkeypatch.setattr(copy, "require_tpu", any_devices)
-    # a registry of this test's own: the process's holds what other tests'
-    # engines observed, and theirs must not hold this family's
-    from progen_tpu.observe import metrics
-
-    monkeypatch.setattr(metrics, "_REGISTRY", metrics.MetricsRegistry())
     return root, copy
 
 
@@ -301,15 +302,11 @@ def test_the_cell_runs_end_to_end_at_a_tiny_size(checkout):
     assert traced["correct"] is True and traced["failed"] == 0
     # no TPU plane for a CPU: the idle share's reader finds nothing and the
     # metric is left out of the line; the rest report
-    assert set(traced["metrics"]) == {
-        "engine.step_ms.dsv2", "engine.chunk_step_ms.dsv2",
-        "engine.admit_ms.dsv2", "engine.admit_rows.dsv2",
-        "engine.occupancy.dsv2", "moe.held_assignments_per_token.dsv2",
-        "moe.held_load_max_over_mean.dsv2", "moe.experts_touched_share.dsv2"}
-    per_token = traced["metrics"]["moe.held_assignments_per_token.dsv2"][
-        "value"]
+    assert set(traced["metrics"]) == METRICS - NOT_ON_A_CPU - {
+        "decode.hbm_share.dsv2"}
+    per_token = traced["metrics"]["moe.held_assignments_per_token"]["value"]
     assert 0 < per_token <= TINY["num_experts_per_tok"]
-    assert traced["metrics"]["moe.held_load_max_over_mean.dsv2"]["value"] >= 1
+    assert traced["metrics"]["moe.held_load_max_over_mean"]["value"] >= 1
     assert not [p for p in os.listdir(root) if p not in
                 ("perf", "BENCHMARK.json", ".jax_cache")]
     # the share's reader on what the run left in the registry, against a
@@ -326,8 +323,8 @@ def test_readers_of_the_new_metrics_find_nothing_in_a_program_without_them(
 
     monkeypatch.setattr(metrics, "_REGISTRY", metrics.MetricsRegistry())
     for name in ("decode.hbm_share.dsv2", "moe.experts_touched_share.dsv2",
-                 "moe.held_assignments_per_token.dsv2",
-                 "moe.held_load_max_over_mean.dsv2"):
+                 "moe.held_assignments_per_token",
+                 "moe.held_load_max_over_mean"):
         spec = harness.load_metric(name)
         reader = harness.load_module(spec["reader"])
         assert reader.read({"config": CONFIG, "device_kind": "TPU v5 lite"},

@@ -63,8 +63,74 @@ def test_metric_file_agrees_and_names_a_reader(entry):
     spec = harness.load_metric(entry["name"])
     for key in ("name", "unit", "better", "layer", "moves", "source"):
         assert spec[key] == entry[key], key
-    assert set(entry["workloads"]) <= set(spec["workloads"])
+    # a metric's cells are listed in ONE place, BENCHMARK.json's entry: a
+    # cell joins a quantity by an entry's list, never by a file of a new name
+    assert "workloads" not in spec
+    assert set(entry["workloads"]) <= {w["name"] for w in BENCH["workloads"]}
     assert callable(harness.load_module(spec["reader"]).read)
+
+
+def _quantity(entry, spec=None):
+    """What a per-layer entry IS, whatever it is called: the reader, its
+    arguments (its metric file's, or ``spec``'s), and what the number is
+    held to."""
+    spec = spec or harness.load_metric(entry["name"])
+    return (spec["reader"], json.dumps(spec["args"], sort_keys=True),
+            entry["unit"], entry["better"], entry["source"], entry["moves"])
+
+
+def test_one_entry_a_quantity_and_room_left():
+    """No two entries are one quantity under two names (a cell that reports
+    a quantity the benchmark has joins its ``workloads`` list), every file
+    under ``metrics/`` has its entry, and the list is inside the contract's
+    128."""
+    layer = BENCH["per_layer"]
+    assert len(layer) <= 128, (
+        f"per_layer holds {len(layer)} entries, {len(layer) - 128} over the "
+        f"contract's 128")
+    seen = {}
+    for entry in layer:
+        other = seen.setdefault(_quantity(entry), entry["name"])
+        assert other == entry["name"], (
+            f"{entry['name']} is {other} under another name: append its "
+            f"cells to {other}'s workloads ({len(layer)} of 128 entries "
+            f"used, {128 - len(layer)} left)")
+    files = {f.removesuffix(".json")
+             for f in os.listdir(os.path.join(harness.PERF, "metrics"))}
+    assert files == {m["name"] for m in layer}
+
+
+def test_every_reading_of_pr44_has_one_entry_that_reads_it_in_that_cell():
+    """``data/per_layer_pr44.json`` is the list as it stood before the
+    per-cell copies were merged (PR 45), each entry with its reader and
+    arguments: every (entry, cell) of it is read today by exactly one entry,
+    with that reader and those arguments, whose list holds the cell."""
+    with open(os.path.join(os.path.dirname(__file__), "data",
+                           "per_layer_pr44.json")) as f:
+        before = json.load(f)
+    assert len(before) == 128
+    today = {}
+    for entry in BENCH["per_layer"]:
+        today.setdefault(_quantity(entry), []).append(entry)
+    read_before = set()
+    for old in before:
+        same = today.get(_quantity(old, spec=old), ())
+        for cell in old["workloads"]:
+            now = [m for m in same if cell in m["workloads"]]
+            assert len(now) == 1, (old["name"], cell)
+            read_before.add((now[0]["name"], cell))
+    # what is read today and was not: SDAR's seven (ISSUE 41 named them and
+    # the list was full) and ProGen-base's padding share
+    read_today = {(m["name"], cell) for m in BENCH["per_layer"]
+                  for cell in m["workloads"]}
+    sdar = "serve-sdar-blockdiff-backlog"
+    assert read_today - read_before == {
+        ("engine.admit_rows.backlog", sdar),
+        ("engine.chunk_rows.backlog", sdar),
+        ("window.compiles.backlog", sdar), ("xla.compile_s", sdar),
+        ("xla.cache_misses", sdar), ("moe.expert_passes_per_touched", sdar),
+        ("moe.held_assignments_per_token", sdar),
+        ("engine.prefill_real_share.backlog", "serve-base-backlog")}
 
 
 @pytest.mark.parametrize("entry", BENCH["workloads"], ids=lambda w: w["name"])

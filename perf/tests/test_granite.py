@@ -11,23 +11,20 @@ import shutil
 import pytest
 
 from perf.lib import granite_cost, harness
+from perf.tests.backlog import NOT_ON_A_CPU, SHARED
 
 CELL = "serve-granite-chat-backlog"
 CONFIG = harness.load_config("granite-4.0-h-micro")
 BENCH = harness.load_benchmark()
 MAMBA, ATTENTION = "mamba", "attention"
-# the cell's per-layer metrics, as a SET of names: where they lie in
-# BENCHMARK.json's list is a later PR's to change
-METRICS = {
-    "engine.step_ms", "engine.chunk_step_ms", "engine.admit_ms",
-    "engine.admit_rows", "engine.occupancy", "engine.chunk_rows",
-    "engine.prefill_real_share", "device.idle_share", "xla.compile_s",
-    "xla.cache_misses", "window.compiles", "window.stall_ms",
-    "attn.full_rows_read_per_live_row", "decode.hbm_share", "prefill.mfu",
-    "ssm.state_share_of_step_bytes", "ssm.scan_slots_per_real_token"}
-SHARES = {"decode.hbm_share", "prefill.mfu", "ssm.state_share_of_step_bytes"}
-FROM_THE_FAMILY = SHARES | {"attn.full_rows_read_per_live_row",
-                            "ssm.scan_slots_per_real_token"}
+# the cell's per-layer metrics as a SET of names: what every backlog cell
+# reports, what the families share, and this family's own (where an entry
+# lies in BENCHMARK.json's list is a later PR's to change)
+SHARES = {"decode.hbm_share.granite", "prefill.mfu.granite",
+          "ssm.state_share_of_step_bytes.granite"}
+OWN = SHARES | {"ssm.scan_slots_per_real_token.granite"}
+FROM_THE_FAMILY = OWN | {"attn.full_rows_read_per_live_row"}
+METRICS = SHARED | FROM_THE_FAMILY
 
 TINY = dict(
     name="tiny-granite", source="perf/tests", reduced=[], vocab_size=96,
@@ -106,14 +103,13 @@ def test_benchmark_entries_of_the_cell():
     e2e = {m["name"] for m in harness.cell_metrics(BENCH, CELL, "end_to_end")}
     assert e2e == {"setup_s", "serve_tok_s"}
     layer = harness.cell_metrics(BENCH, CELL, "per_layer")
-    assert {m["name"] for m in layer} == {f"{m}.granite" for m in METRICS}
-    assert len(layer) == len(METRICS)
+    assert {m["name"] for m in layer} == METRICS
+    assert {m["name"] for m in layer if m["workloads"] == [CELL]} == OWN
     for m in layer:       # each has its file, and the file says the same
-        assert m["workloads"] == [CELL]
         assert m["moves"] == ("setup_s" if m["name"].startswith("xla.")
                               else "serve_tok_s")
         spec = harness.load_metric(m["name"])
-        assert {k: spec[k] for k in m} == m
+        assert all(spec[k] == v for k, v in m.items() if k != "workloads")
         assert os.path.exists(os.path.join(harness.ROOT, spec["reader"]))
     for text in [entry["why"], listed["why"]]:
         assert 0 < len(text) <= 200
@@ -346,7 +342,7 @@ def _dump(path, obj):
 
 
 @pytest.fixture()
-def checkout(tmp_path, monkeypatch):
+def checkout(tmp_path, monkeypatch, own_registry):
     """A temporary copy of the benchmark with a tiny cell of this family
     ADDED: new files and new entries only."""
     root = tmp_path / "checkout"
@@ -382,8 +378,7 @@ def checkout(tmp_path, monkeypatch):
     for m in bench["end_to_end"] + bench["per_layer"]:
         # the shares of a peak are left out: the table of peaks has no row
         # for a CPU, and that is an error there, not a default
-        if CELL in m.get("workloads", ()) and m["name"].removesuffix(
-                ".granite") not in SHARES:
+        if CELL in m.get("workloads", ()) and m["name"] not in SHARES:
             m["workloads"].append("serve-tiny-granite")
     _dump(root / "BENCHMARK.json", bench)
 
@@ -399,11 +394,6 @@ def checkout(tmp_path, monkeypatch):
         return jax.devices()
 
     monkeypatch.setattr(copy, "require_tpu", any_devices)
-    # a registry of this test's own: the process's holds what other tests'
-    # engines observed, and theirs must not hold this family's
-    from progen_tpu.observe import metrics
-
-    monkeypatch.setattr(metrics, "_REGISTRY", metrics.MetricsRegistry())
     return root, copy
 
 
@@ -418,8 +408,7 @@ def test_the_cell_runs_end_to_end_at_a_tiny_size(checkout):
     assert traced["correct"] is True and traced["failed"] == 0
     # no TPU plane for a CPU: the idle share's reader finds nothing and the
     # metric is left out of the line; the rest report
-    assert set(traced["metrics"]) == {
-        f"{m}.granite" for m in METRICS - SHARES - {"device.idle_share"}}
+    assert set(traced["metrics"]) == METRICS - SHARES - NOT_ON_A_CPU
     value = {k.removesuffix(".granite"): v["value"]
              for k, v in traced["metrics"].items()}
     # the XLA decode core reads every row of every slot: far more than the
@@ -434,7 +423,7 @@ def test_the_cell_runs_end_to_end_at_a_tiny_size(checkout):
     obs = {"config": TINY, "device_kind": "TPU v5 lite",
            "counters": {"admitted_primes": [5, 20]}}
     for name in SHARES:
-        spec = copy.load_metric(f"{name}.granite")
+        spec = copy.load_metric(name)
         assert copy.load_module(spec["reader"]).read(obs, spec) > 0
     spec = copy.load_metric("ssm.state_share_of_step_bytes.granite")
     assert copy.load_module(spec["reader"]).read(obs, spec) < 100
@@ -449,6 +438,6 @@ def test_readers_of_the_new_metrics_find_nothing_in_a_program_without_them(
     obs = {"config": CONFIG, "device_kind": "TPU v5 lite",
            "counters": {"admitted_primes": [300]}}
     for name in FROM_THE_FAMILY:
-        spec = harness.load_metric(f"{name}.granite")
+        spec = harness.load_metric(name)
         reader = harness.load_module(spec["reader"])
         assert reader.read(obs, spec) is None, name

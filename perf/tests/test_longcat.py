@@ -11,6 +11,7 @@ import shutil
 import pytest
 
 from perf.lib import harness, longcat_cost
+from perf.tests.backlog import NOT_ON_A_CPU, SHARED
 
 CELL = "serve-longcat-backlog"
 CONFIG = harness.load_config("longcat-flash-chat-ep32")
@@ -22,6 +23,12 @@ WIDTHS = ("hidden_size", "ffn_hidden_size", "expert_ffn_hidden_size",
           "rope_theta", "rms_norm_eps")
 
 PEAK_SHARES = ("decode.hbm_share.longcat", "prefill.mfu.longcat")
+# the cell's per-layer metrics as a SET of names: what every backlog cell
+# reports and what this family adds
+OWN = {*PEAK_SHARES, "moe.real_experts_per_token"}
+METRICS = SHARED | OWN | {
+    "moe.held_load_max_over_mean", "moe.expert_passes_per_touched",
+    "mla.rows_read_per_live_row"}
 
 TINY = dict(
     name="tiny-longcat", source="perf/tests", reduced=[], vocab_size=64,
@@ -83,9 +90,9 @@ def test_benchmark_entries_of_the_cell():
     e2e = {m["name"] for m in harness.cell_metrics(BENCH, CELL, "end_to_end")}
     assert e2e == {"setup_s", "serve_tok_s"}
     layer = harness.cell_metrics(BENCH, CELL, "per_layer")
-    assert len(layer) == 10
-    assert all(m["workloads"] == [CELL] and m["moves"] == "serve_tok_s"
-               for m in layer)
+    assert {m["name"] for m in layer} == METRICS
+    assert all(m["moves"] in e2e for m in layer)
+    assert {m["name"] for m in layer if m["workloads"] == [CELL]} == OWN
     traffic = harness.load_traffic(entry["traffic"])
     assert traffic["arrivals"] == {"kind": "backlog",
                                    "requests_per_second": 16.0}
@@ -144,7 +151,7 @@ def _dump(path, obj):
 
 
 @pytest.fixture()
-def checkout(tmp_path, monkeypatch):
+def checkout(tmp_path, monkeypatch, own_registry):
     """A temporary copy of the benchmark with a tiny cell of this family
     ADDED: new files and new entries only."""
     root = tmp_path / "checkout"
@@ -195,11 +202,6 @@ def checkout(tmp_path, monkeypatch):
         return jax.devices()
 
     monkeypatch.setattr(copy, "require_tpu", any_devices)
-    # a registry of this test's own: the process's holds what other tests'
-    # engines observed, and theirs must not hold this family's
-    from progen_tpu.observe import metrics
-
-    monkeypatch.setattr(metrics, "_REGISTRY", metrics.MetricsRegistry())
     return root, copy
 
 
@@ -213,11 +215,7 @@ def test_the_cell_runs_end_to_end_at_a_tiny_size(checkout):
     assert traced["correct"] is True and traced["failed"] == 0
     # no TPU plane for a CPU: the idle share's reader finds nothing and the
     # metric is left out of the line; the rest report
-    assert set(traced["metrics"]) == {
-        "engine.step_ms.longcat", "engine.chunk_step_ms.longcat",
-        "engine.admit_ms.longcat", "engine.admit_rows.longcat",
-        "engine.occupancy.longcat", "moe.real_experts_per_token",
-        "moe.held_load_max_over_mean"}
+    assert set(traced["metrics"]) == METRICS - set(PEAK_SHARES) - NOT_ON_A_CPU
     real = traced["metrics"]["moe.real_experts_per_token"]["value"]
     assert 0 < real <= TINY["moe_topk"]
     assert traced["metrics"]["moe.held_load_max_over_mean"]["value"] >= 1

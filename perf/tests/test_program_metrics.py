@@ -36,10 +36,9 @@ def test_listed_with_its_cells_and_silent_on_an_empty_source(
 
     entry = next(m for m in harness.load_benchmark()["per_layer"]
                  if m["name"] == name)
-    assert entry["workloads"] == CELLS[name]
+    assert set(CELLS[name]) <= set(entry["workloads"])
     assert entry["source"] == "program_span" and entry["unit"] == "ms"
     spec = harness.load_metric(name)
-    assert set(CELLS[name]) <= set(spec["workloads"])
     reader = harness.load_module(spec["reader"])
     # a program that recorded nothing: the line leaves the metric out
     monkeypatch.setattr(metrics, "_REGISTRY", metrics.MetricsRegistry())

@@ -13,24 +13,23 @@ import numpy as np
 import pytest
 
 from perf.lib import harness, reference_sdar, sdar_cost
+from perf.tests.backlog import NOT_ON_A_CPU, SHARED
 
 CELL = "serve-sdar-blockdiff-backlog"
 CONFIG = harness.load_config("sdar-30b-a3b-chat-pp8")
 BENCH = harness.load_benchmark()
-# the cell's per-layer metrics, as a SET of names: where they lie in
-# BENCHMARK.json's list is a later PR's to change
-METRICS = {
-    "decode.hbm_share", "prefill.mfu", "diffusion.tokens_per_forward",
-    "diffusion.commit_share_of_forwards", "moe.rows_per_touched_expert",
-    "moe.experts_touched_share", "moe.held_load_max_over_mean",
-    "attn.full_rows_read_per_live_row", "engine.step_ms",
-    "engine.chunk_step_ms", "engine.admit_ms", "engine.occupancy",
-    "engine.prefill_real_share", "device.idle_share", "window.stall_ms"}
-SHARES = {"decode.hbm_share", "prefill.mfu"}
-FROM_THE_FAMILY = SHARES | {
-    "diffusion.tokens_per_forward", "diffusion.commit_share_of_forwards",
-    "moe.rows_per_touched_expert", "moe.experts_touched_share",
-    "moe.held_load_max_over_mean", "attn.full_rows_read_per_live_row"}
+# the cell's per-layer metrics as a SET of names: what every backlog cell
+# reports, what the families share, and this family's own (where an entry
+# lies in BENCHMARK.json's list is a later PR's to change)
+SHARES = {"decode.hbm_share.sdar", "prefill.mfu.sdar"}
+OWN = SHARES | {
+    "diffusion.tokens_per_forward.sdar",
+    "diffusion.commit_share_of_forwards.sdar",
+    "moe.rows_per_touched_expert.sdar", "moe.experts_touched_share.sdar"}
+FROM_THE_FAMILY = OWN | {
+    "moe.held_load_max_over_mean", "moe.held_assignments_per_token",
+    "moe.expert_passes_per_touched", "attn.full_rows_read_per_live_row"}
+METRICS = SHARED | FROM_THE_FAMILY
 
 TINY = dict(
     name="tiny-sdar", source="perf/tests", reduced=[], vocab_size=96,
@@ -110,12 +109,13 @@ def test_benchmark_entries_of_the_cell():
     e2e = {m["name"] for m in harness.cell_metrics(BENCH, CELL, "end_to_end")}
     assert e2e == {"setup_s", "serve_tok_s"}
     layer = harness.cell_metrics(BENCH, CELL, "per_layer")
-    assert {m["name"] for m in layer} == {f"{m}.sdar" for m in METRICS}
-    assert len(layer) == len(METRICS)
+    assert {m["name"] for m in layer} == METRICS
+    assert {m["name"] for m in layer if m["workloads"] == [CELL]} == OWN
     for m in layer:       # each has its file, and the file says the same
-        assert m["workloads"] == [CELL] and m["moves"] == "serve_tok_s"
+        assert m["moves"] == ("setup_s" if m["name"].startswith("xla.")
+                              else "serve_tok_s")
         spec = harness.load_metric(m["name"])
-        assert {k: spec[k] for k in m} == m
+        assert all(spec[k] == v for k, v in m.items() if k != "workloads")
         assert os.path.exists(os.path.join(harness.ROOT, spec["reader"]))
     for text in [entry["why"], listed["why"]]:
         assert 0 < len(text) <= 200
@@ -322,7 +322,7 @@ def _dump(path, obj):
 
 
 @pytest.fixture()
-def checkout(tmp_path, monkeypatch):
+def checkout(tmp_path, monkeypatch, own_registry):
     """A temporary copy of the benchmark with a tiny cell of this family
     ADDED: new files and new entries only."""
     root = tmp_path / "checkout"
@@ -360,8 +360,7 @@ def checkout(tmp_path, monkeypatch):
     for m in bench["end_to_end"] + bench["per_layer"]:
         # the shares of a peak are left out: the table of peaks has no row
         # for a CPU, and that is an error there, not a default
-        if CELL in m.get("workloads", ()) and m["name"].removesuffix(
-                ".sdar") not in SHARES:
+        if CELL in m.get("workloads", ()) and m["name"] not in SHARES:
             m["workloads"].append("serve-tiny-sdar")
     _dump(root / "BENCHMARK.json", bench)
 
@@ -377,11 +376,6 @@ def checkout(tmp_path, monkeypatch):
         return jax.devices()
 
     monkeypatch.setattr(copy, "require_tpu", any_devices)
-    # a registry of this test's own: the process's holds what other tests'
-    # engines observed, and theirs must not hold this family's
-    from progen_tpu.observe import metrics
-
-    monkeypatch.setattr(metrics, "_REGISTRY", metrics.MetricsRegistry())
     return root, copy
 
 
@@ -395,8 +389,7 @@ def test_the_cell_runs_end_to_end_at_a_tiny_size(checkout):
     assert traced["correct"] is True and traced["failed"] == 0
     # no TPU plane for a CPU: the idle share's reader finds nothing and the
     # metric is left out of the line; the rest report
-    assert set(traced["metrics"]) == {
-        f"{m}.sdar" for m in METRICS - SHARES - {"device.idle_share"}}
+    assert set(traced["metrics"]) == METRICS - SHARES - NOT_ON_A_CPU
     value = {k.removesuffix(".sdar"): v["value"]
              for k, v in traced["metrics"].items()}
     # 4 tokens a block of 3 forwards, less what last blocks drop
@@ -413,7 +406,7 @@ def test_the_cell_runs_end_to_end_at_a_tiny_size(checkout):
            "workload": {"traffic": {"sampling": {"block_length": 4}}},
            "counters": {"admitted_primes": [5, 20]}}
     for name in SHARES:
-        spec = copy.load_metric(f"{name}.sdar")
+        spec = copy.load_metric(name)
         assert copy.load_module(spec["reader"]).read(obs, spec) > 0
 
 
@@ -427,6 +420,6 @@ def test_readers_of_the_new_metrics_find_nothing_in_a_program_without_them(
            "workload": {"traffic": {"sampling": {"block_length": 4}}},
            "counters": {"admitted_primes": [300]}}
     for name in FROM_THE_FAMILY:
-        spec = harness.load_metric(f"{name}.sdar")
+        spec = harness.load_metric(name)
         reader = harness.load_module(spec["reader"])
         assert reader.read(obs, spec) is None, name

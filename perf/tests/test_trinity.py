@@ -11,6 +11,7 @@ import shutil
 import pytest
 
 from perf.lib import harness, trinity_cost
+from perf.tests.backlog import NOT_ON_A_CPU, SHARED
 
 CELL = "serve-trinity-mixedlen-backlog"
 CONFIG = harness.load_config("trinity-mini-ep8")
@@ -24,13 +25,18 @@ WIDTHS = ("hidden_size", "intermediate_size", "moe_intermediate_size",
           "rope_theta", "rms_norm_eps", "sliding_window", "mup_enabled",
           "global_attn_every_n_layers", "max_position_embeddings")
 SLIDING, FULL = "sliding_attention", "full_attention"
-METRICS = (
-    "engine.step_ms", "engine.chunk_step_ms", "engine.admit_ms",
-    "engine.admit_rows", "engine.occupancy", "device.idle_share",
+# the cell's per-layer metrics as a SET of names: what every backlog cell
+# reports, what the families share (experts, grown keys), this family's own
+SHARES = {"decode.hbm_share.trinity", "prefill.mfu.trinity"}
+OWN = SHARES | {
+    "moe.experts_touched_share.trinity",
+    "attn.window_rows_read_per_live_row.trinity",
+    "attn.window_share_of_context.trinity",
+    "attn.prefill_pairs_visited_per_allowed.trinity"}
+FROM_THE_FAMILY = OWN | {
     "moe.held_assignments_per_token", "moe.held_load_max_over_mean",
-    "moe.experts_touched_share", "attn.full_rows_read_per_live_row",
-    "attn.window_rows_read_per_live_row", "attn.window_share_of_context",
-    "decode.hbm_share", "prefill.mfu")
+    "moe.expert_passes_per_touched", "attn.full_rows_read_per_live_row"}
+METRICS = SHARED | FROM_THE_FAMILY
 
 TINY = dict(
     name="tiny-trinity", source="perf/tests", reduced=[], vocab_size=64,
@@ -122,12 +128,12 @@ def test_benchmark_entries_of_the_cell():
     e2e = {m["name"] for m in harness.cell_metrics(BENCH, CELL, "end_to_end")}
     assert e2e == {"setup_s", "serve_tok_s"}
     layer = harness.cell_metrics(BENCH, CELL, "per_layer")
-    assert [m["name"] for m in layer] == [f"{m}.trinity" for m in METRICS]
-    assert all(m["workloads"] == [CELL] and m["moves"] == "serve_tok_s"
-               for m in layer)
+    assert {m["name"] for m in layer} == METRICS
+    assert all(m["moves"] in e2e for m in layer)
+    assert {m["name"] for m in layer if m["workloads"] == [CELL]} == OWN
     for m in layer:       # each has its file, and the file says the same
         spec = harness.load_metric(m["name"])
-        assert {k: spec[k] for k in m} == m
+        assert all(spec[k] == v for k, v in m.items() if k != "workloads")
         assert os.path.exists(os.path.join(harness.ROOT, spec["reader"]))
     for text in [entry["why"], listed["why"]]:
         assert 0 < len(text) <= 200
@@ -360,7 +366,7 @@ def _dump(path, obj):
 
 
 @pytest.fixture()
-def checkout(tmp_path, monkeypatch):
+def checkout(tmp_path, monkeypatch, own_registry):
     """A temporary copy of the benchmark with a tiny cell of this family
     ADDED: new files and new entries only."""
     root = tmp_path / "checkout"
@@ -397,8 +403,7 @@ def checkout(tmp_path, monkeypatch):
     for m in bench["end_to_end"] + bench["per_layer"]:
         # the shares of a peak are left out: the table of peaks has no row
         # for a CPU, and that is an error there, not a default
-        if CELL in m.get("workloads", ()) and m["name"] not in (
-                "decode.hbm_share.trinity", "prefill.mfu.trinity"):
+        if CELL in m.get("workloads", ()) and m["name"] not in SHARES:
             m["workloads"].append("serve-tiny-trinity")
     _dump(root / "BENCHMARK.json", bench)
 
@@ -414,11 +419,6 @@ def checkout(tmp_path, monkeypatch):
         return jax.devices()
 
     monkeypatch.setattr(copy, "require_tpu", any_devices)
-    # a registry of this test's own: the process's holds what other tests'
-    # engines observed, and theirs must not hold this family's
-    from progen_tpu.observe import metrics
-
-    monkeypatch.setattr(metrics, "_REGISTRY", metrics.MetricsRegistry())
     return root, copy
 
 
@@ -433,9 +433,7 @@ def test_the_cell_runs_end_to_end_at_a_tiny_size(checkout):
     assert traced["correct"] is True and traced["failed"] == 0
     # no TPU plane for a CPU: the idle share's reader finds nothing and the
     # metric is left out of the line; the rest report
-    assert set(traced["metrics"]) == {
-        f"{m}.trinity" for m in METRICS if m not in (
-            "device.idle_share", "decode.hbm_share", "prefill.mfu")}
+    assert set(traced["metrics"]) == METRICS - SHARES - NOT_ON_A_CPU
     value = {k.removesuffix(".trinity"): v["value"]
              for k, v in traced["metrics"].items()}
     # 3 of 8 a token, 2 of 8 held: 0.75 assignments a token on average
@@ -452,7 +450,7 @@ def test_the_cell_runs_end_to_end_at_a_tiny_size(checkout):
     # v5e's peaks: the arithmetic runs; the numbers mean nothing here
     obs = {"config": TINY, "device_kind": "TPU v5 lite",
            "counters": {"admitted_primes": [5, 20]}}
-    for name in ("decode.hbm_share.trinity", "prefill.mfu.trinity"):
+    for name in SHARES:
         spec = copy.load_metric(name)
         assert copy.load_module(spec["reader"]).read(obs, spec) > 0
 
@@ -465,7 +463,7 @@ def test_readers_of_the_new_metrics_find_nothing_in_a_program_without_them(
     monkeypatch.setattr(metrics, "_REGISTRY", metrics.MetricsRegistry())
     obs = {"config": CONFIG, "device_kind": "TPU v5 lite",
            "counters": {"admitted_primes": [300]}}
-    for name in METRICS[6:]:
-        spec = harness.load_metric(f"{name}.trinity")
+    for name in FROM_THE_FAMILY:
+        spec = harness.load_metric(name)
         reader = harness.load_module(spec["reader"])
         assert reader.read(obs, spec) is None, name
