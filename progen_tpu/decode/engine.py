@@ -626,6 +626,7 @@ class ServingEngine:
         self._decode_chunk = self._jit_noting(self._chunk_impl(), "chunk")
         self._admit = self._jit_noting(self._admit_impl, "admit")
         self.model_stats: dict = {}     # the family's counters as last fetched
+        self.model_gauges: dict = {}    # ... as published: name -> float
         if remote_prefill and not disagg:
             raise ValueError("remote_prefill requires disagg=True")
         self.remote_prefill = remote_prefill
@@ -2122,6 +2123,7 @@ class ServingEngine:
                      for i, v in enumerate(kept)},
                   **self.family.publish({k: v for k, v in stats.items()
                                          if k not in _DIFFUSION_STATS})}
+        self.model_gauges = gauges
         for name, value in gauges.items():
             registry.gauge(name).set(value)
 
@@ -2679,6 +2681,9 @@ class ServingEngine:
             # one loop ("xla") or a group of rows at a time, where only a
             # group's keys stay on the chip ("xla_tiled"), by program
             "sample_kth": self._lowering_by_program("sample_kth"),
+            # the device counters as last fetched with the slot flags,
+            # under their registry names ({} for a family without)
+            "model_stats": dict(self.model_gauges),
             "paged": self.paged,
             "disagg": self.disagg,
             "stage_seconds": {k: round(v, 6) for k, v in

@@ -164,7 +164,9 @@ class ProGenFamily:
 # DeepSeek-V2 over latent attention, Trinity over rings and grown keys); the
 # fourth, Granite 4.0-H, has no experts and a recurrent state beside its
 # grown keys; the fifth, SDAR, holds whole expert layers and generates by
-# diffusion over blocks (it states a ``block_length``)
+# diffusion over blocks (it states a ``block_length``); the sixth, LFM2,
+# holds whole expert layers too, a token a step, under mixers whose whole
+# cache is a convolution's tail beside a few blocks of grown keys
 _DRIVER_FAMILIES = (
     ("progen_tpu.models.longcat", "LongCatConfig", "LongCatFamily"),
     ("progen_tpu.models.deepseek_v2", "DeepSeekV2Config", "DeepSeekV2Family"),
@@ -172,6 +174,7 @@ _DRIVER_FAMILIES = (
     ("progen_tpu.models.granite_hybrid", "GraniteHybridConfig",
      "GraniteHybridFamily"),
     ("progen_tpu.models.sdar", "SDARConfig", "SDARFamily"),
+    ("progen_tpu.models.lfm2", "LFM2Config", "LFM2Family"),
 )
 
 

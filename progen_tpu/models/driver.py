@@ -11,7 +11,8 @@ rows beside keys and values that grow with the request, both
 carry and a convolution tail a slot, as large at token 1 as at token
 100,000) beside grown keys, ``models/sdar.py`` grown keys that take a BLOCK
 of tokens a step and are written only when the block is committed
-(:func:`block_step`).
+(:func:`block_step`), ``models/lfm2.py`` blocks whose WHOLE cache is a
+convolution's two-row tail beside a few blocks of grown keys.
 
 **A block** (``blocks_of(config)`` gives ``{name: block}``, one per
 mixer of the stack, in the stack's order) is an object with

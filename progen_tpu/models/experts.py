@@ -1,7 +1,8 @@
 """One chip's share of an expert layer: the grouped product over the
 experts the chip holds, its window, and the device counters the families
 with such a layer carry (``models/longcat.py``, ``models/deepseek_v2.py``,
-``models/trinity.py``).
+``models/trinity.py``, ``models/sdar.py``, ``models/lfm2.py``), and the
+sigmoid router two of them share (:func:`sigmoid_route`).
 
 The router is as wide as published and picks ``moe_topk`` whatever the chip
 holds; the layer adds the terms of the ``experts_held`` real experts from
@@ -31,7 +32,8 @@ on the lane tile, a call's TOKENS decide:
   assignments: a window that overflows runs the loop again.
 
 A chip may also hold the WHOLE layer (``experts_held == router_width``,
-``first_expert`` 0: ``models/sdar.py``, one stage of a pipeline): every
+``first_expert`` 0: ``models/sdar.py`` and ``models/lfm2.py``, one stage
+of a pipeline): every
 assignment is then its own, ``moe.held_load`` is the router's whole
 histogram, and nothing below changes.
 
@@ -66,6 +68,26 @@ def zero_stats(keys, held: int) -> dict:
 
 def add_stats(a: dict, b: dict) -> dict:
     return {k: a[k] + b[k] if k in b else a[k] for k in a}
+
+
+def sigmoid_route(u, router, topk: int, *, norm: bool, scale: float,
+                  eps: float):
+    """The sigmoid router with a selection bias, of the families that have
+    it (``models/trinity.py``, ``models/lfm2.py``): ``(ids (T, k), weights
+    (T, k))``, float32 throughout.  ``s = sigmoid(u W_r)``; the ``topk``
+    largest of ``s + b`` are chosen (``b = router["bias"]`` picks and does
+    not weigh); the weights are the chosen ``s`` alone, over ``sum s + eps``
+    where ``norm``, times ``scale``.  ``eps`` is the family's own (Trinity
+    1e-20, LFM2 1e-6)."""
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(u.astype(F32), router["w"].astype(F32),
+                         precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)
+        _, ids = jax.lax.top_k(scores + router["bias"].astype(F32), topk)
+        w = jnp.take_along_axis(scores, ids, axis=-1)
+        if norm:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
+        return ids, w * scale
 
 
 def moe_capacity(c, tokens: int) -> int:

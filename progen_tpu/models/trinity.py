@@ -309,17 +309,12 @@ def prefill_attention_stats(blocks: dict, n: int, lengths, dt) -> dict:
 
 def route(u, router, c: TrinityConfig):
     """``(ids (T, k), weights (T, k))``, float32 throughout: the largest
-    ``s + b`` are chosen, the weights come from ``s`` alone."""
-    with jax.named_scope("moe.route"):
-        logits = jnp.dot(u.astype(F32), router["w"].astype(F32),
-                         precision=jax.lax.Precision.HIGHEST)
-        scores = jax.nn.sigmoid(logits)
-        _, ids = jax.lax.top_k(scores + router["bias"].astype(F32),
-                               c.num_experts_per_tok)
-        w = jnp.take_along_axis(scores, ids, axis=-1)
-        if c.route_norm:
-            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-        return ids, w * c.route_scale
+    ``s + b`` are chosen, the weights come from ``s`` alone
+    (``models/experts.py:sigmoid_route``, shared with ``models/lfm2.py``,
+    at Trinity's ``1e-20``)."""
+    return experts.sigmoid_route(
+        u, router, c.num_experts_per_tok, norm=c.route_norm,
+        scale=c.route_scale, eps=1e-20)
 
 
 def moe_share(u, layer, c: TrinityConfig, live):

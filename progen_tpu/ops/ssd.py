@@ -47,8 +47,10 @@ Both are plain XLA in this PR and say so under ``"ssd_prefill"`` /
 convolution of width ``K`` over ``u (R, P, C)`` with zeros before a row's
 first token, the last ``K - 1`` REAL inputs of each row (zeros where the
 row is shorter) as the decode's tail, and the one-token form over ``(tail,
-u_t)``.  Four multiply-adds a channel, summed and returned in float32 (the
-caller's activation rounds it once).
+u_t)``.  ``K`` multiply-adds a channel, summed and returned in float32 (the
+caller rounds it once); ``bias`` None for a convolution without one
+(``models/lfm2.py``'s short convolution: three taps, no bias, no
+activation; Granite's has four taps, a bias and a ``silu``).
 """
 
 from __future__ import annotations
@@ -136,14 +138,14 @@ def _taps(window, w, bias):
     """``window (..., K, C)`` against ``w (C, K)``: the taps summed in
     float32."""
     out = jnp.sum(window.astype(F32) * w.astype(F32).T, axis=-2)
-    return out + bias.astype(F32)
+    return out if bias is None else out + bias.astype(F32)
 
 
 def causal_conv(u, w, bias):
     k = w.shape[1]
     p = u.shape[1]
     front = jnp.pad(u, ((0, 0), (k - 1, 0), (0, 0)))
-    out = bias.astype(F32)
+    out = 0.0 if bias is None else bias.astype(F32)
     for j in range(k):          # tap j reads the input k - 1 - j tokens back
         out = out + front[:, j:j + p].astype(F32) * w[:, j].astype(F32)
     return out

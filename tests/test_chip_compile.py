@@ -624,3 +624,72 @@ def test_sdar_programs_compile_for_the_chip_and_fit_it(
                  if " while(" in line]
         assert len(loops) == 1
         assert "u32[32,151936]{1,0:T(8,128)S(1)}" in loops[0]
+
+
+# ---- LFM2's whole programs at published widths ----
+
+
+@pytest.fixture(scope="module")
+def lfm2_engine():
+    """The engine of ``serve-lfm2-longgen-backlog`` over ABSTRACT weights
+    (its 128 slots' state is real, on the host: 2.4 GB of zeros)."""
+    from progen_tpu.decode.engine import ServingEngine
+    from progen_tpu.models import lfm2
+
+    c = lfm2.LFM2Config(num_hidden_layers=12,
+                        layer_types=lfm2.LFM2Config().layer_types[:12])
+    policy = lfm2.bf16_policy()
+    params = jax.eval_shape(lambda k: lfm2.init_params(c, k, policy),
+                            jax.random.key(0))
+    return ServingEngine(c, params, policy=policy, num_slots=128,
+                         chunk_size=32, max_len=3072)
+
+
+@pytest.mark.parametrize("program", ["chunk", "admit-1024"])
+def test_lfm2_programs_compile_for_the_chip_and_fit_it(
+        shape, lfm2_engine, program, no_persistent_cache, monkeypatch):
+    """The first 12 layers (9 short convolutions, 3 attention; 2 dense, 10
+    with all 32 experts), the whole vocabulary, 128 slots of two-row tails
+    and grown keys: the chunk program (32 steps of every slot: 128 tokens a
+    call is the last size ``moe_decode_fwd`` takes, the key writes
+    ``ops/row_write.py``'s kernel) and the admission of 8 rows at the 1024
+    bucket (8,192 tokens through ``ragged_dot``), as the chip traces them.
+    Arguments, results and temporaries together stay under the chip's 16
+    GiB: the engine's programs do not donate their state, so it is there
+    twice."""
+    from progen_tpu.decode import sampler
+    from progen_tpu.ops import gqa, lowering, moe_decode, row_write
+
+    for module in (row_write, gqa, moe_decode, sampler):
+        monkeypatch.setattr(module, "_on_tpu", lambda: True)
+    monkeypatch.setattr(lowering, "on_tpu", lambda: True)
+    eng = lfm2_engine
+
+    def placed(tree):
+        return jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+
+    params, state = placed(eng._params), placed(eng.state)
+    s, rows, lay = eng.num_slots, eng.admit_rows, eng._layout
+    assert rows == 8
+    if program == "chunk":
+        compiled = jax.jit(lambda *a: eng._decode_chunk_impl(*a)).lower(
+            params, state, *placed(lay.chunk_operands())).compile()
+    else:
+        prefill = [shape((rows, 1024), jnp.int32), shape((rows,), jnp.int32),
+                   shape((rows,), jnp.int32), shape((rows,), jnp.uint32),
+                   shape((rows,), jnp.int32), shape((rows,), jnp.float32),
+                   shape(eng._lmask_shape(rows), jnp.bool_)]
+        compiled = jax.jit(lambda *a: eng._admit_impl(*a)).lower(
+            params, state, shape((s,), jnp.int32), shape((s,), jnp.bool_),
+            *prefill, *placed(lay.write_tables(rows))).compile()
+    m = compiled.memory_analysis()
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
+    assert 7.85e9 < weights < 7.87e9 and 2.4e9 < held < 2.5e9
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert weights + 2 * held <= total < 15.5e9, m
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if program == "chunk":
+        assert "moe_decode_fwd" in text

@@ -163,8 +163,11 @@ jax.block_until_ready(jax.jit(cached_probe_fn)(x))
 jax.clear_caches()
 jax.block_until_ready(jax.jit(cached_probe_fn)(x))
 snap = get_registry().snapshot()
+# the compiles only: a collector pause filed in between (``host.gc``, under
+# load) has no ``program``
 mine = [i["args"]["cache"] for i in get_tracer().incidents()
-        if "cached_probe_fn" in i["args"]["program"]]
+        if i["name"] == "xla.compile"
+        and "cached_probe_fn" in i["args"]["program"]]
 print(json.dumps({"mine": mine, "hits": snap["xla.cache_hits"]["value"],
                   "misses": snap["xla.cache_misses"]["value"],
                   "compiles": snap["xla.compiles"]["value"]}))
