@@ -882,8 +882,9 @@ class ServingEngine:
         ``+`` where the shapes split them), a latent attention's prefill
         and absorbed decode cores (``"mla_prefill"``, ``"mla_decode"``:
         ``"pallas"`` / ``"xla"``), a grouped-query attention's prefill core
-        (``"gqa_prefill"``, the same two), the core of a step of B queries
-        a slot (``"gqa_block_decode"``: ``"xla"``) and the held experts'
+        and one-query decode core (``"gqa_prefill"``, ``"gqa_decode"``: the
+        same two), the core of a step of B queries a slot
+        (``"gqa_block_decode"``: ``"xla"``) and the held experts'
         product (``"moe_experts"``: ``"pallas"`` / ``"pallas_grouped"`` /
         ``"xla"``, stated per program: it is in both, and an engine's
         admission programs, one a bucket, may differ — joined by ``+``) and
@@ -2665,12 +2666,13 @@ class ServingEngine:
             "compiles_in_step": self._compiles_in_step.value,
             # lowering of the chunk program's cache writes, of a latent
             # attention's prefill and decode cores and of a grouped-query
-            # attention's prefill core; None until a program that holds
-            # the op has been traced
+            # attention's prefill and decode cores; None until a program
+            # that holds the op has been traced
             "row_write": self.lowerings.get("row_write"),
             "mla_prefill": self.lowerings.get("mla_prefill"),
             "mla_decode": self.lowerings.get("mla_decode"),
             "gqa_prefill": self.lowerings.get("gqa_prefill"),
+            "gqa_decode": self.lowerings.get("gqa_decode"),
             # the core of a step of B queries a slot (a family that
             # generates by blocks)
             "gqa_block_decode": self.lowerings.get("gqa_block_decode"),

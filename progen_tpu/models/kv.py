@@ -160,11 +160,12 @@ class KVBlock:
         return self.finish(o, rest, p), {"k": keys, "v": values}
 
 
-def decode_stats(blocks: dict, caches, pos, live) -> dict:
+def decode_stats(blocks: dict, caches, pos, live, block_form=False) -> dict:
     """A decode step's ``attn.*`` counters over the :class:`KVBlock`s among
     ``blocks``: its live rows, their contexts, the part of them a window
     keeps, and the cache rows ONE block of each kind reads under the
-    lowering that ran."""
+    lowering that ran (``block_form``: the step is
+    :meth:`KVBlock.decode_block`'s, whose core has the XLA form alone)."""
     seen = jnp.where(live, pos + 1, 0)
     stats = {"attn.decode_rows": jnp.sum(live).astype(F32),
              "attn.context_tokens": jnp.sum(seen).astype(F32),
@@ -177,12 +178,16 @@ def decode_stats(blocks: dict, caches, pos, live) -> dict:
                      if (b.window is None) == (kind == "full")), None)
         if name is None:
             continue
-        cache = caches[name]
+        k, v = caches[name]["k"], caches[name]["v"]
+        # the step's query is in the caches' dtype (``driver.Family``)
+        lowering = "xla" if block_form else gqa.decode_lowering(k.dtype, k, v)
+        counts = None if lowering == "xla" else kv[name].place(
+            pos, k.shape[2])[1]
         stats[f"attn.{kind}_rows_read"] = (
-            gqa.rows_visited(cache["k"]) * jnp.any(live))
+            gqa.rows_visited(k, counts, lowering) * jnp.any(live))
         if kind == "window":
             stats["attn.window_tokens"] = jnp.sum(jnp.minimum(
-                seen, cache["k"].shape[2])).astype(F32)
+                seen, k.shape[2])).astype(F32)
     return stats
 
 
@@ -190,6 +195,6 @@ def block_decode_stats(blocks: dict, caches, pos0, live, b: int) -> dict:
     """:func:`decode_stats` of a step of ``b`` tokens a row: ``b`` query
     rows a live slot, each slot's context the ``pos0`` rows committed
     before its block."""
-    stats = decode_stats(blocks, caches, pos0 - 1, live)
+    stats = decode_stats(blocks, caches, pos0 - 1, live, block_form=True)
     stats["attn.decode_rows"] = b * stats["attn.decode_rows"]
     return stats

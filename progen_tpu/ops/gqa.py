@@ -76,10 +76,48 @@ lie: a ring of the last ``T`` tokens and a cache that grows with the
 request differ only in where the caller wrote the row and in ``counts``
 (keys carry their own rotary phase, and a softmax does not care for the
 order of its terms).  ``counts`` is at least 1 everywhere (a decode step
-has just written the row it stands on).  Plain XLA; the whole cache is
-read: scores ``(S, H, T)`` in float32, a masked softmax, the value product.
-:func:`rows_visited` says how many rows that is (the counters
-``attn.window_rows_read`` / ``attn.full_rows_read``).
+has just written the row it stands on).  ``(S, H * d)`` in ``q``'s dtype.
+Two lowerings keep that contract, chosen as the prefill's are:
+
+* **Pallas kernel** ``gqa_decode_fwd`` — on a TPU backend, no mesh in
+  scope, ``q``, ``k`` and ``v`` of one 2- or 4-byte float type, ``d`` a
+  multiple of 128 and ``T`` a multiple of ``MIN_TILE``: Trinity's rings and
+  grown caches and LFM2's packed cache rows; Granite 4.0-H's 64-wide heads
+  keep the XLA form on a TPU too.  Grid ``(S, T / bk)``, slots
+  ``"parallel"``, the key axis innermost and ``"arbitrary"``; ALL ``KV``
+  heads ride in one block ``(1, KV, bk, d)`` of ``k`` and of ``v``, and
+  the body walks them unrolled — a grid axis over the heads costs a fixed
+  price ``KV`` times a slot and was 1.4 times slower at Trinity's grown
+  caches (PERF.md section 6, PR 47).  Query head ``h`` reads key/value
+  head ``h // G``: the query goes in as ``(S, KV, G, d)``, a head's ``G``
+  rows one ``(G, d)`` operand (8 rows of bfloat16 are half a sublane tile;
+  the chip's compiler takes them as they are, and padding them to 16
+  bought nothing).  A ``(G, bk)`` score tile is accumulated in float32
+  from the compute-dtype operands and scaled in float32, lives in VMEM
+  only, and updates a float32 running maximum, running sum and ``(G, d)``
+  accumulator a head; probabilities are cast to the compute dtype for the
+  value product alone, and the one division by the sum comes at the end.
+  ``counts`` is scalar-prefetched: a key tile with ``k0 >= counts[s]`` is
+  neither visited nor fetched, and only the tile the count crosses pays
+  for the iota mask.  The steps past a slot's last tile point their index
+  map at the NEXT slot's first tile (always visited), so its fetch starts
+  as soon as the slot's last tile is in and not at the slot's last grid
+  step, where nothing would overlap it: a slot that ends early otherwise
+  costs one exposed tile fetch (0.68 -> 0.56 ms a call at Trinity's grown
+  caches).  It aliases nothing and writes only the output, so a cache
+  that is a scan's carry, and the output of ``row_write`` one line above,
+  is read where it lies.
+* **XLA** (:func:`xla_decode_attention`) — everywhere else (the CPU of
+  tier-1, the tests' tiny widths, any trace under a mesh, mixed dtypes):
+  scores ``(S, H, T)`` in float32, a masked softmax, the value product;
+  the whole cache is read.
+
+Which one a traced call took is noted under ``"gqa_decode"``
+(``ServingEngine.status()["gqa_decode"]``; :func:`decode_lowering` says it
+without tracing), and :func:`rows_visited` says how many cache rows that
+lowering reads (the counters ``attn.window_rows_read`` /
+``attn.full_rows_read``): whole key tiles up to each slot's count under
+the kernel, ``S * T`` under the XLA form.
 
 **The block mask** changes one comparison in each lowering and nothing
 else: a query block, and a tile, start on a block's edge (``B`` divides
@@ -95,8 +133,9 @@ program they traced before the mask existed.
 against the first ``counts (S,)`` rows of the cache AND the block's own B
 keys and values, handed in beside the cache and not written to it: one
 softmax over both (two score tensors under one running maximum, so the
-cache is never concatenated).  Plain XLA like :func:`decode_attention`, the
-whole cache read; noted as ``"gqa_block_decode"``.
+cache is never concatenated).  Plain XLA like
+:func:`xla_decode_attention`, the whole cache read; noted as
+``"gqa_block_decode"``.
 """
 
 from __future__ import annotations
@@ -118,6 +157,8 @@ FULL_GROUP = 4        # blocked XLA form, no window: blocks sharing a key span
 # the kernel's query and key tile on a v5e (PERF.md section 6, PR 35, has
 # the pairs measured), halved down to ``MIN_TILE`` until it divides P
 TILE, MIN_TILE = 1024, 512
+# the decode kernel's key tile for long caches (``fitted_decode_tile``)
+DECODE_TILE = 1024
 MASKED = -0.7 * float(jnp.finfo(F32).max)   # a masked score: finite
 
 
@@ -318,13 +359,18 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
 
 
-def fitted_tile(n: int) -> int:
-    """The largest of ``TILE``, ``TILE / 2``, ... down to ``MIN_TILE`` that
+def _halved_to_fit(tile: int, n: int) -> int:
+    """``tile``, ``tile / 2``, ... down to ``MIN_TILE``: the first that
     divides ``n``."""
-    tile = TILE
     while tile > MIN_TILE and n % tile:
         tile //= 2
     return tile
+
+
+def fitted_tile(n: int) -> int:
+    """The largest of ``TILE``, ``TILE / 2``, ... down to ``MIN_TILE`` that
+    divides ``n``."""
+    return _halved_to_fit(TILE, n)
 
 
 def pallas_prefill_attention(q, k, v, lengths, scale, window=None, *,
@@ -405,13 +451,19 @@ def _flash_call(q, k, v, lengths, *, scale, window, bq, bk, interpret,
     )(lengths, q, k, v)
 
 
+def _kernel_takes(dtype) -> bool:
+    """What both kernels ask before any shape: a TPU backend, no mesh in
+    scope, a 2- or 4-byte float type."""
+    return (_on_tpu() and not _mesh_in_scope()
+            and jnp.issubdtype(dtype, jnp.floating)
+            and jnp.dtype(dtype).itemsize in (2, 4))
+
+
 def prefill_lowering(n: int, d: int, dtype, window, block: int = 1) -> str:
     """``"pallas"`` or ``"xla"``: what :func:`prefill_attention` takes for
     ``n`` positions of heads ``d`` wide in ``dtype``, traced here and now
     (the module docstring has the rule)."""
-    kernel = (_on_tpu() and not _mesh_in_scope()
-              and jnp.issubdtype(dtype, jnp.floating)
-              and jnp.dtype(dtype).itemsize in (2, 4)
+    kernel = (_kernel_takes(dtype)
               and d % 128 == 0 and n % MIN_TILE == 0
               and (window is None or window % fitted_tile(n) == 0)
               and (block == 1 or (window is None and not block & (block - 1)
@@ -480,8 +532,9 @@ def pairs_visited(lengths, n: int, window, lowering: str):
 # ------------------------------------------------------------------- decode
 
 
-def decode_attention(q, k, v, counts, scale):
-    """``(S, H * d)`` in ``q``'s dtype."""
+def xla_decode_attention(q, k, v, counts, scale):
+    """The XLA form: scores over the whole static cache in float32.
+    ``(S, H * d)`` in ``q``'s dtype."""
     s, heads, d = q.shape
     kv, t = k.shape[1], k.shape[2]
     q = q.reshape(s, kv, heads // kv, d)
@@ -495,11 +548,158 @@ def decode_attention(q, k, v, counts, scale):
     return out.astype(q.dtype).reshape(s, heads * d)
 
 
-def rows_visited(k):
+def _decode_kernel(cnt_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                   acc_ref, *, scale, bk):
+    from jax.experimental import pallas as pl
+
+    count = cnt_ref[pl.program_id(0)]
+    ki = pl.program_id(1)
+    k0 = ki * bk
+
+    @pl.when(ki == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, F32)
+        l_ref[...] = jnp.zeros(l_ref.shape, F32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
+
+    def head(h, crossed):
+        s = _dot_t(q_ref[0, h], k_ref[0, h]) * scale           # (G, bk)
+        if crossed:
+            cols = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(cols < count, s, -jnp.inf)
+        # key tile 0 is always visited first and holds the slot's row 0
+        # (counts >= 1), so ``m_next`` is finite from the first tile on
+        m_prev = m_ref[h]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.exp(s - m_next)
+        l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = alpha * acc_ref[h] + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[0, h], preferred_element_type=F32)
+        m_ref[h] = m_next
+
+    def tile(crossed):
+        for h in range(k_ref.shape[1]):     # the key/value heads, unrolled
+            head(h, crossed)
+
+    # a key tile is visited if it holds a row the slot has; it needs the
+    # mask only where the count ends inside it
+    seen = k0 < count
+    crossed = k0 + bk > count
+    pl.when(seen & crossed)(functools.partial(tile, True))
+    pl.when(seen & jnp.logical_not(crossed))(functools.partial(tile, False))
+
+    @pl.when(ki == pl.num_programs(1) - 1)
+    def _():
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def fitted_decode_tile(t: int) -> int:
+    """The decode kernel's key tile for caches of ``t`` rows.  A slot
+    reads half a tile past its count on average and a visited grid step
+    costs about 0.5 us beside its bytes, so long caches take
+    ``DECODE_TILE`` (fewer steps: 0.56 against 0.64 ms a call at 64 x
+    9,216) and caches under four of them ``MIN_TILE`` (fewer rows: 0.54
+    against 0.61 ms at 128 x 3,072; PERF.md section 6, PR 47, has the
+    table); halved down to ``MIN_TILE`` until it divides ``t``."""
+    return _halved_to_fit(
+        DECODE_TILE if t >= 4 * DECODE_TILE else MIN_TILE, t)
+
+
+def pallas_decode_attention(q, k, v, counts, scale, *, block_k=None,
+                            interpret=None):
+    """The kernel lowering of :func:`decode_attention`.  ``interpret=None``
+    auto-selects the Pallas interpreter off-TPU; ``block_k`` defaults to
+    :func:`fitted_decode_tile`."""
+    t = k.shape[2]
+    bk = block_k or fitted_decode_tile(t)
+    if t % bk:
+        raise ValueError(f"tile {bk} does not divide T = {t}")
+    if interpret is None:
+        interpret = not _on_tpu()
+    return _decode_call(q, k, v, counts.astype(jnp.int32), scale=scale,
+                        bk=bk, interpret=interpret)
+
+
+# jitted as ``_flash_call`` is: a model's rings, and its grown caches, share
+# ONE traced and lowered kernel each, and the chunk program holds two Mosaic
+# kernels, not one a layer
+@functools.partial(jax.jit, static_argnames=("scale", "bk", "interpret"))
+def _decode_call(q, k, v, counts, *, scale, bk, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s, heads, d = q.shape
+    kv, t = k.shape[1], k.shape[2]
+    group = heads // kv
+
+    def slot_map(si, ki, cnt_ref):
+        return si, 0, 0, 0
+
+    def cache_map(si, ki, cnt_ref):
+        # a tile past the count is neither visited nor fetched: the steps
+        # past a slot's last tile point at the NEXT slot's first tile
+        # (always visited: counts >= 1), so that its fetch starts at once
+        # and not at the slot's last step with nothing to overlap it; the
+        # last slot's stay on its last tile
+        last = jnp.maximum(cnt_ref[si] - 1, 0) // bk
+        done, more = ki > last, si + 1 < s
+        return (jnp.where(done & more, si + 1, si), 0,
+                jnp.where(done, jnp.where(more, 0, last), ki), 0)
+
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, bk=bk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(s, t // bk),
+            in_specs=[pl.BlockSpec((1, kv, group, d), slot_map),
+                      pl.BlockSpec((1, kv, bk, d), cache_map),
+                      pl.BlockSpec((1, kv, bk, d), cache_map)],
+            out_specs=pl.BlockSpec((1, kv, group, d), slot_map),
+            scratch_shapes=[pltpu.VMEM((kv, group, 1), F32),
+                            pltpu.VMEM((kv, group, 1), F32),
+                            pltpu.VMEM((kv, group, d), F32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((s, kv, group, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="gqa_decode_fwd",
+    )(counts, q.reshape(s, kv, group, d), k, v)
+    return out.reshape(s, heads * d)
+
+
+def decode_lowering(q_dtype, k, v) -> str:
+    """``"pallas"`` or ``"xla"``: what :func:`decode_attention` takes for
+    a query of ``q_dtype`` over caches ``k`` and ``v`` (arrays or their
+    shapes), traced here and now (the module docstring has the rule)."""
+    dtype = jnp.dtype(q_dtype)
+    kernel = (_kernel_takes(dtype)
+              and dtype == jnp.dtype(k.dtype) == jnp.dtype(v.dtype)
+              and k.shape[3] % 128 == 0 and k.shape[2] % MIN_TILE == 0)
+    return "pallas" if kernel else "xla"
+
+
+def decode_attention(q, k, v, counts, scale):
+    """``(S, H * d)`` in ``q``'s dtype: ``q (S, H, d)`` over the first
+    ``counts (S,)`` (each at least 1) rows of ``k, v (S, KV, T, d)``.  The
+    lowering is chosen as the module docstring says."""
+    lowering = decode_lowering(q.dtype, k, v)
+    note("gqa_decode", lowering)
+    if lowering == "xla":
+        return xla_decode_attention(q, k, v, counts, scale)
+    return pallas_decode_attention(q, k, v, counts, scale)
+
+
+def rows_visited(k, counts, lowering: str):
     """Cache rows :func:`decode_attention` reads of ``k`` (and as many of
-    ``v``) in one call, as a float32 scalar: every row of every slot,
-    whatever the counts."""
-    return jnp.asarray(k.shape[0] * k.shape[2], F32)
+    ``v``) in one call under ``lowering``, as a float32 scalar: the
+    kernel's whole key tiles up to each slot's count; the XLA form's every
+    row of every slot, whatever the ``counts`` (they are not looked at)."""
+    if lowering == "xla":
+        return jnp.asarray(k.shape[0] * k.shape[2], F32)
+    tile = fitted_decode_tile(k.shape[2])
+    return (jnp.sum(-(-counts // tile)) * tile).astype(F32)
 
 
 # ------------------------------------------------------- a block of queries
@@ -514,7 +714,8 @@ def block_decode_attention(q, k, v, k_new, v_new, counts, scale):
     own keys are always there.  Nothing is written: whether the block's
     keys enter the cache is the caller's (``ops/row_write.py:
     write_row_blocks``).  ``(S, B, H * d)`` in ``q``'s dtype.  Plain XLA, as
-    :func:`decode_attention`: the whole cache is read (:func:`rows_visited`),
+    :func:`xla_decode_attention`: the whole cache is read
+    (:func:`rows_visited` under ``"xla"``),
     scores ``(S, KV, G * B, T)`` in float32; noted as ``"gqa_block_decode"``
     (``ops/lowering.py``)."""
     note("gqa_block_decode", "xla")

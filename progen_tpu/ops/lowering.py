@@ -13,8 +13,11 @@ between two XLA forms of one loop (the note ``"sample_kth"``: ``"xla"``
 where a draw's keys fit on the chip and the compiler keeps them there,
 ``"xla_tiled"`` where only a group of rows does and the loop runs a group
 at a time).
-(``ops/gqa.py:block_decode_attention`` has the XLA form alone so far and
-notes it as ``"gqa_block_decode"``, so that the note is there to change.)
+(``ops/gqa.py`` owns three such ops: the prefill core, ``"gqa_prefill"``;
+the one-query decode core, ``"gqa_decode"`` — the kernel at head widths on
+the lane tile, the XLA form at Granite 4.0-H's 64 —; and
+``block_decode_attention``, which has the XLA form alone so far and notes
+it as ``"gqa_block_decode"``, so that the note is there to change.)
 The choice is made while a program is traced, so a caller that traces one
 (``ServingEngine`` around its chunk and admission programs) can collect it:
 :func:`record_lowerings` yields ``{op name: {lowering, ...}}`` for the ops

@@ -15,6 +15,8 @@ file (a second file can land on another xdist worker, where its fixture
 would skip).
 """
 
+import json
+import os
 import re
 
 import jax
@@ -74,6 +76,20 @@ def no_persistent_cache():
 def _assert_kernel_compiles(fn, *args):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, "kernel did not lower to Mosaic"
+
+
+def _buffers_of(text, kind):
+    """The instructions of a compiled program whose RESULT holds ``kind``
+    and is a buffer of its own — a fusion's, a loop's, a copy's, a
+    kernel's (what a fused computation passes along inside is not)."""
+    hits = []
+    for line in text.splitlines():
+        _, sep, rest = line.partition(" = ")
+        op = re.search(r" (fusion|while|copy|custom-call)\(", rest)
+        if sep and op and kind in re.sub(r"\{[^{}]*\}", "",
+                                         rest[:op.start()]):
+            hits.append(line.strip())
+    return hits
 
 
 def _grad_of(fn, nargs):
@@ -321,7 +337,9 @@ def test_trinity_expert_share_compiles_for_the_chip(shape, tokens,
 def test_trinity_decode_block_compiles_for_the_chip(shape, window, rows,
                                                     no_persistent_cache):
     """One attention block's decode step of 64 rows over a slot's ring or
-    grown keys (the XLA core: scores ``(64, 32, rows)`` float32)."""
+    grown keys through the XLA core (scores ``(64, 32, rows)`` float32):
+    what a trace under a mesh keeps on the chip; the kernel's own case is
+    ``test_gqa_decode_kernel_compiles_for_v5e``."""
     from progen_tpu.models import trinity
 
     c = trinity.TrinityConfig(num_hidden_layers=2, num_dense_layers=1,
@@ -416,6 +434,27 @@ def test_mla_decode_kernel_compiles_for_v5e(shape, no_persistent_cache,
         shape((slots,), jnp.int32))
 
 
+@pytest.mark.parametrize("slots,rows", [(64, 2048), (64, 9216), (128, 3072)],
+                         ids=["trinity-ring", "trinity-grown", "lfm2"])
+def test_gqa_decode_kernel_compiles_for_v5e(shape, no_persistent_cache,
+                                            slots, rows):
+    """The grouped-query decode core (``ops/gqa.py``, ``gqa_decode_fwd``)
+    at the shapes of ``serve-trinity-mixedlen-backlog`` (a ring and grown
+    keys) and ``serve-lfm2-longgen-backlog``: 32 query heads over 4
+    key/value heads of 128, bfloat16 — a head's 8 query rows half a
+    sublane tile, all four key/value heads in one block —, with the key
+    tile the chip path takes."""
+    from progen_tpu.ops.gqa import pallas_decode_attention
+
+    bf16 = jnp.bfloat16
+    cache = shape((slots, 4, rows, 128), bf16)
+    _assert_kernel_compiles(
+        lambda q, k, v, n: pallas_decode_attention(q, k, v, n, 128 ** -0.5,
+                                                   interpret=False),
+        shape((slots, 32, 128), bf16), cache, cache, shape((slots,),
+                                                           jnp.int32))
+
+
 @pytest.mark.parametrize("tokens,h,inner,held", [
     (64, 5120, 1536, 40), (32, 6144, 2048, 16), (64, 2048, 1024, 16)],
     ids=["dsv2", "longcat", "trinity"])
@@ -466,6 +505,51 @@ def test_moe_grouped_kernel_compiles_for_v5e(shape, no_persistent_cache,
         shape((held, h, inner), bf16), shape((held, h, inner), bf16),
         shape((held, inner, h), bf16)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_trinity_chunk_program_compiles_for_the_chip_and_fits_it(
+        shape, no_persistent_cache, monkeypatch):
+    """The chunk program of ``serve-trinity-mixedlen-backlog`` over
+    ABSTRACT weights (its 64 slots' state is real, on the host, for this
+    test alone: 4.3 GB of zeros): 32 steps of 9 layers, the rings and
+    grown keys written by ``row_write`` and read by ``gqa_decode_fwd`` up
+    to each slot's count, with no float32 score tensor over a whole cache;
+    arguments, results and temporaries under the 11.38 GB the cell's file
+    states for it."""
+    from progen_tpu.decode import sampler
+    from progen_tpu.decode.engine import ServingEngine
+    from progen_tpu.models import trinity
+    from progen_tpu.ops import gqa, lowering, moe_decode, row_write
+
+    for module in (row_write, gqa, moe_decode, sampler):
+        monkeypatch.setattr(module, "_on_tpu", lambda: True)
+    monkeypatch.setattr(lowering, "on_tpu", lambda: True)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "configs",
+                           "trinity-mini-ep8.json")) as f:
+        c = trinity.TrinityConfig.from_dict(json.load(f))
+    policy = trinity.bf16_policy()
+    params = jax.eval_shape(lambda k: trinity.init_params(c, k, policy),
+                            jax.random.key(0))
+    eng = ServingEngine(c, params, policy=policy, num_slots=64,
+                        chunk_size=32, max_len=9216)
+
+    def placed(tree):
+        return jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+
+    compiled = jax.jit(lambda *a: eng._decode_chunk_impl(*a)).lower(
+        placed(eng._params), placed(eng.state),
+        *placed(eng._layout.chunk_operands())).compile()
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(eng.state))
+    assert 4.29e9 < held < 4.31e9
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert 2 * held <= total < 11.4e9, m
+    text = compiled.as_text()
+    assert "gqa_decode_fwd" in text and "row_write" in text
+    assert not _buffers_of(text, "f32[64,4,8,9216]")
+    assert not _buffers_of(text, "f32[64,4,8,2048]")
 
 
 # ---- Granite 4.0-H's whole programs at published widths ----
@@ -532,20 +616,6 @@ def test_granite_programs_compile_for_the_chip_and_fit_it(
 
 
 # ---- SDAR's whole programs at published widths ----
-
-
-def _buffers_of(text, kind):
-    """The instructions of a compiled program whose RESULT holds ``kind``
-    and is a buffer of its own — a fusion's, a loop's, a copy's, a
-    kernel's (what a fused computation passes along inside is not)."""
-    hits = []
-    for line in text.splitlines():
-        _, sep, rest = line.partition(" = ")
-        op = re.search(r" (fusion|while|copy|custom-call)\(", rest)
-        if sep and op and kind in re.sub(r"\{[^{}]*\}", "",
-                                         rest[:op.start()]):
-            hits.append(line.strip())
-    return hits
 
 
 @pytest.fixture(scope="module")
@@ -692,4 +762,7 @@ def test_lfm2_programs_compile_for_the_chip_and_fit_it(
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     if program == "chunk":
-        assert "moe_decode_fwd" in text
+        # the chunk's stated peak, the decode core a kernel
+        assert total < 12.8e9, m
+        assert "moe_decode_fwd" in text and "gqa_decode_fwd" in text
+        assert not _buffers_of(text, "f32[128,4,8,3072]")

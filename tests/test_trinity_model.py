@@ -353,4 +353,4 @@ def test_decode_core_reads_a_slots_rows_in_any_order_up_to_its_count():
         np.testing.assert_allclose(got[s], want, atol=1e-5)
     np.testing.assert_allclose(shuffled[0], got[2], atol=1e-5)
     np.testing.assert_array_equal(junk, got)
-    assert float(gqa.rows_visited(k)) == 3 * 12
+    assert float(gqa.rows_visited(k, counts, "xla")) == 3 * 12
