@@ -28,11 +28,13 @@ A family that GENERATES BY DIFFUSION OVER BLOCKS (it states a
 ``block_length``, ``decode/family.py``) gets another chunk body
 (``_block_chunk_impl``) and another prefill half of admission
 (``_block_prefill_impl``), chosen from that statement and from nothing
-else: a scan step is one forward of ``B`` positions a slot, a block of
-mask tokens is denoised a few positions a forward under the family's
-remasking rule and then committed — written to the cache, entered into
-``seq`` —, ``pos``, ``done``, ``stop``, end of sequence and the harvest
-move by committed blocks, and admission hands over a prime whose last ``P
+else: a scan step is one forward a slot, a block of mask tokens is denoised
+a few positions a forward under the family's remasking rule and then
+committed — entered into ``seq`` by the forward that fills its last mask,
+written to the cache by the forward that opens the next block, which
+carries it in front of the block in progress —, ``pos``, ``done``,
+``stop``, end of sequence and the harvest move by finished blocks, and
+admission hands over a prime whose last ``P
 mod B`` tokens open the first block instead of a sampled first token
 (docs/SERVING.md, "Generation by blocks").
 
@@ -184,9 +186,11 @@ SLOTS_PER_ADMIT_ROW = 16
 
 # what a slot of a family that generates by blocks holds beside the rest
 # (``_block_chunk_impl``): where the block in progress starts, its tokens,
-# the denoise forwards it has had, and for each position of the row the
-# denoise forward that filled it (-1: a prime token, or not filled yet)
-_BLOCK_STATE = ("cursor", "block", "dstep", "fill")
+# the denoise forwards it has had, for each position of the row the denoise
+# forward that filled it (-1: a prime token, or not filled yet), and the
+# block finished last where no forward has written its keys yet
+_BLOCK_STATE = ("cursor", "block", "dstep", "fill", "pending",
+                "has_pending")
 # the block step's counters, summed on the device beside the family's
 _DIFFUSION_STATS = ("diffusion.forwards", "diffusion.commit_forwards",
                     "diffusion.tokens_committed", "diffusion.tokens_dropped",
@@ -680,7 +684,10 @@ class ServingEngine:
                 cursor=jnp.zeros((s,), jnp.int32),
                 block=jnp.full((s, b), self.family.mask_token_id, jnp.int32),
                 dstep=jnp.zeros((s,), jnp.int32),
-                fill=jnp.full((s, L), -1, jnp.int8))
+                fill=jnp.full((s, L), -1, jnp.int8),
+                pending=jnp.full((s, b), self.family.mask_token_id,
+                                 jnp.int32),
+                has_pending=jnp.zeros((s,), bool))
             stats = {**stats, **self._diffusion_zeros()}
         if stats:
             # the family's device-side counters: summed by its programs,
@@ -972,27 +979,36 @@ class ServingEngine:
         """``chunk_size`` forwards of every slot of a family that generates
         by diffusion over blocks (``decode/family.py``).  A slot holds the
         block in progress at ``cursor .. cursor + B - 1`` — tokens, and the
-        mask token where none is kept yet — and every scan step is ONE
-        forward of all ``S x B`` positions, whatever phase a row is in:
+        mask token where none is kept yet — and, where ``has_pending``, the
+        block it finished last (``pending``, at ``cursor - B .. cursor - 1``),
+        whose keys no forward has written yet.  Every scan step is ONE
+        forward of all ``S x 2B`` positions, and every live row's is a
+        DENOISE forward:
 
-        * a row whose block still has a mask takes a DENOISE forward: a draw
-          at every position from that position's own logits (the request's
-          top-k, temperature and logit mask; the mask token is never
-          allowed) with the drawn token's confidence, and the masked
-          positions the family's remasking rule picks keep their draw.  No
-          key is stored;
-        * a row whose block has none takes the COMMIT forward: the same
-          forward over the final tokens, its keys and values written at the
-          block's rows; the block enters ``seq``, the cursor moves on and a
-          block of masks opens.  ``pos`` — the newest token that counts —
-          moves to the block's end, or to the first end-of-sequence token in
-          it, or to ``stop - 1``: tokens after either are dropped and the
-          row is done.
+        * a draw at every position of the block in progress from that
+          position's own logits (the request's top-k, temperature and logit
+          mask; the mask token is never allowed) with the drawn token's
+          confidence; the masked positions the family's remasking rule
+          picks keep their draw.  The block's own keys are not stored;
+        * where a pending block rides, the same forward computes its keys
+          and values, the block in progress sees them beside its own, and
+          they are written at the pending block's rows: a block's commit
+          costs no forward of its own;
+        * where the forward fills the block's last mask, the block is
+          FINISHED in the same step, with no forward: it enters ``seq``,
+          the cursor moves on, a block of masks opens and the finished
+          block becomes the slot's pending one.  ``pos`` — the newest token
+          that counts — moves to the block's end, or to the first
+          end-of-sequence token in it, or to ``stop - 1``: tokens after
+          either are dropped and the row is done.  A row that is done keeps
+          no pending block: nobody will read those keys.
 
-        A row that is not live runs fully masked and keeps its state.  A
-        slot's key advances on its own live forwards only (each forward's
-        B draws come from one split of it), so a request's tokens depend on
-        neither its neighbours nor the step it was admitted at."""
+        A row that is not live runs fully masked and keeps its state; the
+        pending half of a row without a pending block is filler
+        (``models/driver.py:block_step``).  A slot's key advances on its
+        own live forwards only (each forward's B draws come from one split
+        of it), so a request's tokens depend on neither its neighbours nor
+        the step it was admitted at."""
         lay, fam = self._layout, self.family
         b, steps = fam.block_length, fam.denoising_steps
         mask_id = fam.mask_token_id
@@ -1016,12 +1032,10 @@ class ServingEngine:
             def body(st, _):
                 live = lay.live(st, operands)
                 blk, p0, dstep = st["block"], st["cursor"], st["dstep"]
-                masked = blk == mask_id
-                commit = live & ~jnp.any(masked, axis=1)
-                denoise = live & ~commit
+                riding = live & st["has_pending"]
                 logits, caches, stats = fam.block_step(
                     self._target_params(params), blk, p0, st["caches"],
-                    live, commit)
+                    live, riding, st["pending"])
                 kd, sub = split_keys_batched(st["keys"])
                 keys = jax.vmap(lambda k: jax.random.split(k, b))(
                     sub).reshape(s * b)
@@ -1029,45 +1043,52 @@ class ServingEngine:
                     keys, logits.reshape(s * b, -1),
                     jnp.repeat(st["top_k"], b), jnp.repeat(st["temp"], b),
                     mask=jnp.repeat(st["lmask"], b, axis=0))
-                take = denoise[:, None] & confident_positions(
-                    conf.reshape(s, b), masked,
+                take = live[:, None] & confident_positions(
+                    conf.reshape(s, b), blk == mask_id,
                     per_step[jnp.clip(dstep, 0, steps - 1)], threshold)
                 filled = jnp.where(take, drawn.reshape(s, b).astype(
                     jnp.int32), blk)
+                finish = live & ~jnp.any(filled == mask_id, axis=1)
 
-                # the commit: which of the block's tokens count
+                # a finished block: which of its tokens count
                 where = p0[:, None] + at
                 generated = where >= st["start"][:, None]
                 eos = (generated & (where < st["stop"][:, None])
-                       & (blk == EOS_ID))
+                       & (filled == EOS_ID))
                 ended = jnp.any(eos, axis=1)
                 last = jnp.where(ended, p0 + jnp.argmax(eos, axis=1),
                                  jnp.minimum(p0 + b, st["stop"]) - 1)
-                counted = jnp.where(commit, last - st["pos"], 0)
-                values, here = spread(blk, p0)
-                seq = jnp.where(here & commit[:, None], values, st["seq"])
+                counted = jnp.where(finish, last - st["pos"], 0)
+                done = finish & (ended | (p0 + b >= st["stop"]))
+                values, here = spread(filled, p0)
+                seq = jnp.where(here & finish[:, None], values, st["seq"])
                 steps_at, _ = spread(jnp.where(take, dstep[:, None], -1), p0)
                 fill = jnp.where(here & (steps_at >= 0),
                                  steps_at.astype(jnp.int8), st["fill"])
                 out = {
                     **st, "seq": seq, "caches": caches, "fill": fill,
-                    "pos": jnp.where(commit, last, st["pos"]),
-                    "done": st["done"] | (commit & (
-                        ended | (p0 + b >= st["stop"]))),
-                    "cursor": jnp.where(commit, p0 + b, p0),
-                    "block": jnp.where(commit[:, None], mask_id, filled),
-                    "dstep": jnp.where(commit, 0, dstep + denoise),
+                    "pos": jnp.where(finish, last, st["pos"]),
+                    "done": st["done"] | done,
+                    "cursor": jnp.where(finish, p0 + b, p0),
+                    "block": jnp.where(finish[:, None], mask_id, filled),
+                    "dstep": jnp.where(finish, 0, dstep + live),
+                    "pending": jnp.where(finish[:, None], filled,
+                                         st["pending"]),
+                    # what rode is written; a row that is not live keeps
+                    # what it has
+                    "has_pending": jnp.where(live, finish & ~done,
+                                             st["has_pending"]),
                     "keys": jnp.where(live[:, None], kd, st["keys"]),
                 }
                 kept = jnp.sum(take, axis=1)
                 stats = {
                     **stats,
                     "diffusion.forwards": jnp.sum(live).astype(f32),
-                    "diffusion.commit_forwards": jnp.sum(commit).astype(f32),
+                    "diffusion.commit_forwards": jnp.sum(riding).astype(f32),
                     "diffusion.tokens_committed": jnp.sum(counted).astype(
                         f32),
                     "diffusion.tokens_dropped": jnp.sum(jnp.where(
-                        commit, jnp.sum(generated, axis=1) - counted,
+                        finish, jnp.sum(generated, axis=1) - counted,
                         0)).astype(f32),
                     "diffusion.positions_kept": jnp.sum(jnp.where(
                         dstep[:, None] == jnp.arange(steps)[None, :],
@@ -1187,6 +1208,8 @@ class ServingEngine:
             "lmask": lmask, "cursor": whole, "block": block,
             "dstep": jnp.zeros((rows,), jnp.int32),
             "fill": jnp.full((rows, L), -1, jnp.int8),
+            "pending": jnp.full((rows, b), fam.mask_token_id, jnp.int32),
+            "has_pending": jnp.zeros((rows,), bool),
             "stats": {**stats, **self._diffusion_zeros()},
         }
 

@@ -31,21 +31,30 @@ builds and nothing else of it:
     ``None``: one token a row a step — ``decode_step`` below, the engine's
     scan of ``chunk_size`` single-token steps, a first token drawn at
     admission.  A number ``B``: GENERATION BY DIFFUSION OVER BLOCKS — a
-    step is one forward of B positions a row (``block_step``), a block of
-    mask tokens is denoised a few positions a forward and then committed
-    to the cache, and admission hands over a prime whose last ``P mod B``
-    tokens open the first block.  Such a family also states
+    step is one forward a row (``block_step``), a block of mask tokens is
+    denoised a few positions a forward, its keys enter the cache in the
+    forward that opens the NEXT block (the finished block rides in front of
+    the block in progress: no forward is a commit's alone), and admission
+    hands over a prime whose last ``P mod B`` tokens open the first block.
+    Such a family also states
     ``mask_token_id`` (never drawn, never a committed token),
     ``denoising_steps`` (T: a block is filled in at most T denoise
     forwards), ``remasking`` (``low_confidence_static``: the ``B / T``
     masked positions of highest confidence take their draw each forward;
     ``low_confidence_dynamic``: every masked position whose confidence is
     over ``confidence_threshold`` does, and at least the static count) and
-``block_step(params, tok (S, B), pos0 (S,), caches, live (S,), commit (S,))``
+``block_step(params, tok (S, B), pos0 (S,), caches, live (S,), commit (S,), pending (S, B) = None)``
     ``(logits (S, B, V), caches, stats)``: the B tokens of each row (mask
     tokens among them) at ``pos0 .. pos0 + B - 1`` over the row's committed
-    cache and each other; the row's keys enter the cache only where
-    ``commit``.  The logits at a position predict that position's own token
+    cache and each other.  The logits at a position predict that position's
+    own token.  ``commit`` says in which rows keys enter the cache in this
+    forward: with ``pending`` — each row's finished block at ``pos0 - B ..
+    pos0 - 1``, not in the cache yet — they are the pending block's, which
+    the forward carries in front of ``tok`` (``tok`` sees its keys beside
+    its own; a row whose ``commit`` is false has no pending block, and what
+    ``pending`` holds there reaches nothing); without, they are ``tok``'s
+    own.  ``tok``'s keys are never stored by a forward that carries a
+    pending block
 
 ``init_caches(slots, max_len)``
     the decode caches of ``slots`` rows, a pytree whose every leaf has the

@@ -35,12 +35,15 @@ mixer of the stack, in the stack's order) is an object with
 ``decode(x (S, h), pos (S,), cache, weights)``
     ``(out (S, h), cache)``: one token a row at position ``pos``, written
     into the cache (or folded into the state) and mixed up to it
-``decode_block(x (S, B, h), pos0 (S,), cache, weights, commit (S,))``
+``decode_block(x (S, B or 2B, h), pos0 (S,), cache, weights, commit (S,), queries=None)``
     only of a block whose family generates B tokens a row a step
-    (:func:`block_step`; ``models/kv.py``'s grown keys have it): ``(out (S,
-    B, h), cache)``, the B tokens at ``pos0 .. pos0 + B - 1`` mixed over
-    the slot's committed rows and each other, and written into the cache
-    where ``commit`` and not at all where not
+    (:func:`block_step`; ``models/kv.py``'s grown keys have it): ``(out,
+    cache)`` of ``x``'s shape (of its last ``queries`` rows alone where
+    that is given), the B tokens at ``pos0 .. pos0 + B - 1``
+    mixed over the slot's committed rows and each other — behind, where
+    ``x`` is ``2B`` long, the finished block at ``pos0 - B`` whose keys are
+    this forward's to write.  The FIRST B rows of ``x`` are written into
+    the cache where ``commit`` and nothing where not
 
 **Experts are a family's statement**, not the driver's assumption.  A
 family with a share of an expert layer (``models/experts.py``) names
@@ -259,34 +262,63 @@ def decode_step(stack, blocks, attention_stats, params, tok, pos, caches,
 
 
 def block_step(stack, blocks, attention_stats, params, tok, pos0, caches,
-               live, commit, config, policy: Policy, *,
+               live, commit, config, policy: Policy, *, pending=None,
                with_choices: bool = False):
     """``B`` tokens per row, for a family that generates by diffusion over
     blocks: ``tok (S, B)`` (mask tokens among them) at ``pos0 .. pos0 + B
-    - 1`` -> ``(logits (S, B, V) float32, caches, stats)``.  Every layer is
-    one forward of all ``S * B`` tokens; a row's keys and values enter its
-    cache only where ``commit (S,)`` (a denoise forward stores nothing).
-    Rows that are not ``live`` run but are not counted and reach no expert.
-    ``attention_stats(dtype, caches, pos0, live)`` as :func:`decode_step`'s,
-    of a step of B query rows a slot."""
+    - 1`` -> ``(logits (S, B, V) float32, caches, stats)``.  ``commit (S,)``
+    says in which rows keys and values enter the cache IN THIS FORWARD, and
+    nothing is stored where not.  They are ``tok``'s own where ``pending``
+    is None.  With ``pending (S, B)`` — each slot's finished block, final
+    tokens at ``pos0 - B .. pos0 - 1`` whose keys no forward has written —
+    they are the pending block's: it rides in front of ``tok``, every layer
+    is one forward of all ``S * 2B`` tokens (``decode_block`` says what
+    each half sees) but the last, which needs the pending rows' keys and
+    values only — its attention returns ``tok``'s rows and the stack goes
+    on with them (``stack(..., tail=)``: what takes ``tok``'s rows of an
+    array of all) — and the head runs over ``tok``'s rows alone.  A slot
+    whose ``commit`` is false has no pending block: its front half is
+    filler that no other row sees, written nowhere, reaching no expert and
+    counted by no counter.  Rows that are not ``live`` run but are not
+    counted and reach no expert (``commit`` is false there).
+    ``attention_stats(dtype, caches, pos0, live, riding)`` as
+    :func:`decode_step`'s, of a step of B query rows a slot and B more
+    where a pending block rides (``riding``: ``commit`` with a pending
+    block, None without)."""
     c = config
     dt = policy.compute_dtype
     caches = dict(caches)
     s, b = tok.shape
+    rows_live, riding = live[:, None], None
+    if pending is not None:
+        riding = commit
+        tok = jnp.concatenate([pending, tok], axis=1)
+        rows_live = jnp.stack([commit, live], axis=1)
+    rows_live = jnp.repeat(rows_live, b, axis=1).reshape(-1)
+    n = tok.shape[1]
+    last = list(blocks)[-1]
+
+    def mine(x):
+        """``tok``'s rows of ``x (S * n, ...)``."""
+        return x.reshape((s, n) + x.shape[1:])[:, n - b:].reshape(
+            (s * b,) + x.shape[1:])
 
     def attend(x, name, p):
+        # the last layer asks nothing of a pending block but its keys
         out, caches[name] = blocks[name].decode_block(
-            x.reshape(s, b, -1), pos0, caches[name], p, commit)
-        return out.reshape(s * b, -1)
+            x.reshape(s, n, -1), pos0, caches[name], p, commit,
+            b if name == last else None)
+        return out.reshape(-1, out.shape[-1])
 
     x = _embed(params, tok.reshape(-1), c, dt)
-    x, stats, chosen, touched = stack(x, params, c, attend,
-                                      jnp.repeat(live, b))
+    x, stats, chosen, touched = stack(x, params, c, attend, rows_live,
+                                      tail=mine)
     _step_moe_stats(stats, chosen, touched, live)
-    stats.update(attention_stats(dt, caches, pos0, live))
+    stats.update(attention_stats(dt, caches, pos0, live, riding))
     out = _logits(x, params, c).reshape(s, b, -1), caches, stats
     if with_choices:
-        return out + (jnp.stack(chosen).reshape(len(chosen), s, b, -1),)
+        return out + (jnp.stack([
+            ids.reshape(s, -1, ids.shape[-1])[:, -b:] for ids in chosen]),)
     return out
 
 

@@ -28,8 +28,11 @@ over blocks, ``models/sdar.py``): the B queries at ``pos0 .. pos0 + B - 1``
 see the slot's ``pos0`` committed rows and each other's keys, whatever
 order they were filled in, and the write of the B new rows MAY BE WITHHELD
 row by row of the batch — a denoise forward leaves the cache as it found
-it, a commit forward writes its block.  Such a block's prefill mask is
-causal across blocks of ``block`` tokens and open inside one.
+it.  A finished block's keys are written by the forward that OPENS THE NEXT
+block: the step then takes ``2 B`` tokens a row, the pending block in front
+of the block in progress, under the mask the prefill has — causal across
+blocks of ``block`` tokens and open inside one — with the pending keys in
+the forward's own tile beside the block's.
 """
 
 from __future__ import annotations
@@ -140,23 +143,49 @@ class KVBlock:
         rest = jax.tree.map(lambda a: a[:, 0], rest)
         return self.finish(o, rest, p), {"k": keys, "v": values}
 
-    def decode_block(self, x, pos0, cache, p, commit):
-        """``B`` tokens a row: ``x (S, B, h)`` at ``pos0 .. pos0 + B - 1``
-        (``pos0 (S,)``, a multiple of B), mixed over the slot's ``pos0``
-        committed rows and each other; the B new keys and values are
-        written at those rows where ``commit (S,)`` and nowhere else."""
+    def decode_block(self, x, pos0, cache, p, commit, queries=None):
+        """``B`` tokens a row (``B`` the mask's ``block``): ``x (S, B, h)``
+        at ``pos0 .. pos0 + B - 1`` (``pos0 (S,)``, a multiple of B), mixed
+        over the slot's ``pos0`` committed rows and each other; the B new
+        keys and values are written at those rows where ``commit (S,)`` and
+        nowhere else.
+
+        Or ``2 B`` tokens a row, ``x (S, 2B, h)``: the PENDING block at
+        ``pos0 - B .. pos0 - 1`` — final tokens whose keys no forward has
+        written yet — in front of the block in progress at ``pos0``.  Where
+        ``commit`` the pending keys are this forward's: the slot has ``pos0
+        - B`` committed rows, the block in progress sees the pending keys
+        beside its own, and they are written at their rows.  Where not, the
+        front half is filler: the slot's ``pos0`` committed rows hold that
+        block already, no query of the block in progress sees the filler's
+        keys, and nothing is written.  Either way the keys that are written
+        are the FIRST B rows of ``x``, at the position ``x`` starts at.
+        ``queries``: only the last so many rows of ``x`` are queries, and
+        the output is theirs alone (the stack's last layer asks nothing of
+        a pending block but its keys and values)."""
         if self.window is not None:
             raise NotImplementedError("a ring takes one token a step")
-        b = x.shape[1]
-        q, k, v, rest = self.project(x, p, pos0[:, None] + jnp.arange(b))
+        n, b = x.shape[1], self.block
+        if n not in (b, 2 * b):
+            raise ValueError(
+                f"a step takes a block of {b} tokens a row, or a pending "
+                f"block in front of it ({2 * b}): not {n}")
+        first = pos0 - (n - b)
+        q, k, v, rest = self.project(x, p, first[:, None] + jnp.arange(n))
         k = k.transpose(0, 2, 1, 3).astype(cache["k"].dtype)
         v = v.transpose(0, 2, 1, 3).astype(cache["v"].dtype)
+        if queries is not None:
+            q, rest = jax.tree.map(lambda a: a[:, n - queries:], (q, rest))
         with jax.named_scope("attn.block"):
-            o = gqa.block_decode_attention(q, cache["k"], cache["v"], k, v,
-                                           pos0, self.scale)
+            # the committed rows: all before ``pos0``, less a pending block
+            # whose keys are this forward's
+            o = gqa.block_decode_attention(
+                q, cache["k"], cache["v"], k, v, pos0 - (n - b) * commit,
+                self.scale, commit if n > b else None)
         with jax.named_scope("attn.commit"):
             keys, values = write_row_blocks(
-                (cache["k"], cache["v"]), (k, v), pos0, commit)
+                (cache["k"], cache["v"]), (k[:, :, :b], v[:, :, :b]),
+                jnp.maximum(first, 0), commit)
         return self.finish(o, rest, p), {"k": keys, "v": values}
 
 
@@ -191,10 +220,17 @@ def decode_stats(blocks: dict, caches, pos, live, block_form=False) -> dict:
     return stats
 
 
-def block_decode_stats(blocks: dict, caches, pos0, live, b: int) -> dict:
+def block_decode_stats(blocks: dict, caches, pos0, live, b: int,
+                       riding=None) -> dict:
     """:func:`decode_stats` of a step of ``b`` tokens a row: ``b`` query
-    rows a live slot, each slot's context the ``pos0`` rows committed
-    before its block."""
+    rows a live slot, each slot's context the rows committed before the
+    forward.  ``riding (S,)``: the slots whose pending block is in this
+    forward (``KVBlock.decode_block``) — ``b`` more query rows each, and a
+    context that ends before the pending block."""
+    rows = jnp.sum(live)
+    if riding is not None:
+        pos0 = pos0 - b * riding
+        rows = rows + jnp.sum(riding)
     stats = decode_stats(blocks, caches, pos0 - 1, live, block_form=True)
-    stats["attn.decode_rows"] = b * stats["attn.decode_rows"]
+    stats["attn.decode_rows"] = (b * rows).astype(F32)
     return stats

@@ -4,7 +4,9 @@ softmax top-k routing and no shared expert — that GENERATES BY DIFFUSION
 OVER BLOCKS: the attention mask is causal across blocks of ``block_length``
 tokens and open inside one, the logits at a position predict that
 position's own token (no shift), and a block of mask tokens is denoised a
-few positions a forward, then committed to the cache.
+few positions a forward, then committed to the cache — by the forward that
+opens the next block, which carries the finished block in front of the one
+in progress (``models/kv.py:KVBlock.decode_block``).
 
 What SDAR alone has: its config, the router, the two-norm layer's wiring,
 the attention block's projection and the seeded weights' layout.  The model
@@ -275,14 +277,20 @@ def zero_stats(c: SDARConfig) -> dict:
 # -------------------------------------------------------------------- model
 
 
-def _layers(x, params, c, attend, live):
+def _layers(x, params, c, attend, live, tail=None):
     """The stack over ``x (T, h)`` flat tokens (``driver.prefill`` says
-    what the driver asks of it)."""
+    what the driver asks of it).  ``tail``: the LAST layer's attention
+    returns fewer rows than it was given (``driver.block_step``), and
+    ``tail(array of all rows)`` gives those rows of it: the stack goes on
+    with them alone."""
     stats = zero_stats(c)
     chosen, touched = [], 0.0
     for i, layer in enumerate(params["layers"]):
         n, eps = layer["norm"], c.rms_norm_eps
-        a = x + attend(rms_norm(x, n[0], eps), f"l{i}", layer["attn"])
+        out = attend(rms_norm(x, n[0], eps), f"l{i}", layer["attn"])
+        if out.shape[0] != x.shape[0]:
+            x, live = tail(x), tail(live)
+        a = x + out
         t = rms_norm(a, n[1], eps)
         ids, w = route(t, layer["router"], c)
         y, load = held_experts(t, ids, w, live, layer["experts"], c)
@@ -328,13 +336,14 @@ def caches_from(rows, lengths, config: SDARConfig, max_len: int):
 def block_step(params, tok, pos0, caches, live, commit, config: SDARConfig,
                policy: Policy | None = None, **kwargs):
     """``driver.block_step`` over SDAR's stack and blocks: one forward of
-    ``tok (S, B)`` at ``pos0 .. pos0 + B - 1``, the keys written where
-    ``commit``."""
+    ``tok (S, B)`` at ``pos0 .. pos0 + B - 1``; where ``commit``, keys are
+    written — ``tok``'s own, or with ``pending=(S, B)`` the finished block's
+    at ``pos0 - B``, which then rides in front of ``tok``."""
     blocks = blocks_of(config)
     return driver.block_step(
         _layers, blocks,
-        lambda dt, caches, pos0, live: kv.block_decode_stats(
-            blocks, caches, pos0, live, tok.shape[1]),
+        lambda dt, caches, pos0, live, riding: kv.block_decode_stats(
+            blocks, caches, pos0, live, tok.shape[1], riding),
         params, tok, pos0, caches, live, commit, config,
         policy or bf16_policy(), **kwargs)
 
@@ -367,6 +376,7 @@ class SDARFamily(driver.Family):
         raise NotImplementedError(
             "the sdar family generates a block a step: block_step")
 
-    def block_step(self, params, tok, pos0, caches, live, commit):
+    def block_step(self, params, tok, pos0, caches, live, commit,
+                   pending=None):
         return block_step(params, tok, pos0, caches, live, commit,
-                          self.config, self.policy)
+                          self.config, self.policy, pending=pending)

@@ -133,7 +133,11 @@ program they traced before the mask existed.
 against the first ``counts (S,)`` rows of the cache AND the block's own B
 keys and values, handed in beside the cache and not written to it: one
 softmax over both (two score tensors under one running maximum, so the
-cache is never concatenated).  Plain XLA like
+cache is never concatenated).  The same call takes TWO blocks a slot (``2B``
+tokens, ``lead (S,)``): the block in front, whose keys are not in the cache
+yet, beside the block after it, which sees the front block's keys where
+``lead`` (and finds them among the cache's rows where not); the queries may
+be the second block's alone.  Plain XLA like
 :func:`xla_decode_attention`, the whole cache read; noted as
 ``"gqa_block_decode"``.
 """
@@ -705,32 +709,63 @@ def rows_visited(k, counts, lowering: str):
 # ------------------------------------------------------- a block of queries
 
 
-def block_decode_attention(q, k, v, k_new, v_new, counts, scale):
-    """``B`` queries a slot, ``q (S, B, H, d)``, against the first ``counts
-    (S,)`` rows of ``k, v (S, KV, T, d)`` AND the block's own ``k_new,
-    v_new (S, KV, B, d)``, every one of which every query of the block sees
-    (bidirectional inside the block): one softmax over both.  ``counts``
-    may be 0 (a first block with nothing committed before it): the block's
-    own keys are always there.  Nothing is written: whether the block's
-    keys enter the cache is the caller's (``ops/row_write.py:
-    write_row_blocks``).  ``(S, B, H * d)`` in ``q``'s dtype.  Plain XLA, as
-    :func:`xla_decode_attention`: the whole cache is read
-    (:func:`rows_visited` under ``"xla"``),
-    scores ``(S, KV, G * B, T)`` in float32; noted as ``"gqa_block_decode"``
+def block_decode_attention(q, k, v, k_new, v_new, counts, scale, lead=None):
+    """``n`` tokens a slot against the first ``counts (S,)`` rows of ``k, v
+    (S, KV, T, d)`` AND the forward's own ``k_new, v_new (S, KV, n, d)``:
+    one softmax over both.  ``q (S, m, H, d)`` are the queries of the LAST
+    ``m <= n`` of them.  ``lead`` None: the ``n`` rows are ONE block, every
+    key of which every query of it sees (bidirectional inside the block).
+    ``lead (S,)``: they are TWO blocks of ``n / 2``, causal across and open
+    inside — the front block's queries see their own block's keys, the
+    second block's see their own and, where ``lead``, the front block's
+    (where not, the front block is a slot's filler: the caller counts its
+    rows among the cache's).  ``counts`` may be 0 (a first block with
+    nothing committed before it): a query's own block is always there.
+    Nothing is written: whether a block's keys enter the cache is the
+    caller's (``ops/row_write.py:write_row_blocks``).  ``(S, m, H * d)`` in
+    ``q``'s dtype.  Plain XLA, as :func:`xla_decode_attention`: the whole
+    cache is read (:func:`rows_visited` under ``"xla"``), scores ``(S, KV,
+    G * m, T)`` in float32; noted as ``"gqa_block_decode"``
     (``ops/lowering.py``)."""
     note("gqa_block_decode", "xla")
-    s, b, heads, d = q.shape
-    kv, t = k.shape[1], k.shape[2]
+    n = k_new.shape[2]
+    seen = jnp.arange(k.shape[2])[None, :] < counts[:, None]
+    if lead is None:
+        return _block_core(q, k, v, k_new, v_new, seen, None, scale)
+    m, b = q.shape[1], n // 2
+    second = jnp.arange(n) >= b                     # by row of the forward
+    asks = second[n - m:, None]
+    among = (asks == second[None, :]) | (
+        lead[:, None, None] & asks & ~second[None, :])      # (S, m, n)
+    # a block of queries a pass over the cache: the float32 scores of
+    # ``G * b`` query rows a key/value head stay where the cache's read
+    # bounds the pass, and those of twice as many do not (PERF.md section
+    # 6, PR 48: 0.45 ms a layer against 1.18 at 64 x 2,560 rows)
+    return jnp.concatenate([
+        _block_core(q[:, i:i + b], k, v, k_new, v_new, seen,
+                    among[:, i:i + b], scale)
+        for i in range(0, m, b)], axis=1)
+
+
+def _block_core(q, k, v, k_new, v_new, seen, among, scale):
+    """:func:`block_decode_attention` of the queries ``q (S, m, H, d)``
+    over the cache's rows ``seen (S, T)`` and those of the forward's own
+    keys that ``among (S, m, n)`` allows (None: all)."""
+    s, m, heads, d = q.shape
+    kv = k.shape[1]
     group = heads // kv
-    q = q.reshape(s, b, kv, group, d).transpose(0, 2, 3, 1, 4).reshape(
-        s, kv, group * b, d)
+    q = q.reshape(s, m, kv, group, d).transpose(0, 2, 3, 1, 4).reshape(
+        s, kv, group * m, d)
     past = jnp.einsum("skqd,sktd->skqt", q, k.astype(q.dtype),
                       preferred_element_type=F32) * scale
-    seen = jnp.arange(t)[None, :] < counts[:, None]
     past = jnp.where(seen[:, None, None], past, -jnp.inf)
     own = jnp.einsum("skqd,skbd->skqb", q, k_new.astype(q.dtype),
                      preferred_element_type=F32) * scale
-    # the block's own scores are finite, so the maximum is
+    if among is not None:
+        # (S, m queries, n keys) -> the query axis as ``q`` has it, G * m
+        own = jnp.where(jnp.tile(among, (1, group, 1))[:, None], own,
+                        -jnp.inf)
+    # a query's scores of its own block are finite, so the maximum is
     top = jnp.maximum(jnp.max(past, axis=-1), jnp.max(own, axis=-1))[..., None]
     p_past, p_own = jnp.exp(past - top), jnp.exp(own - top)
     total = jnp.sum(p_past, axis=-1) + jnp.sum(p_own, axis=-1)
@@ -739,5 +774,5 @@ def block_decode_attention(q, k, v, k_new, v_new, counts, scale):
            + jnp.einsum("skqb,skbd->skqd", p_own.astype(q.dtype),
                         v_new.astype(q.dtype), preferred_element_type=F32))
     out = (out / total[..., None]).astype(q.dtype)
-    return out.reshape(s, kv, group, b, d).transpose(0, 3, 1, 2, 4).reshape(
-        s, b, heads * d)
+    return out.reshape(s, kv, group, m, d).transpose(0, 3, 1, 2, 4).reshape(
+        s, m, heads * d)

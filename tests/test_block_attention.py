@@ -132,6 +132,54 @@ def test_b_queries_over_a_cache_and_themselves_are_a_full_forwards_rows():
         np.testing.assert_allclose(got[i], want[i, lo:lo + b], atol=2e-5)
 
 
+@pytest.mark.parametrize("lead", [(True, True, True), (False, True, False),
+                                  (False, False, False)])
+def test_two_blocks_over_a_cache_are_a_full_forwards_rows(lead):
+    """A pending block in front of the block in progress: where ``lead``
+    both blocks' rows are the full forward's (the second sees the front
+    block's keys in the forward's own tile); where not, the front block is
+    filler the second block does not see — it finds that block among the
+    cache's rows, and its rows are the full forward's all the same."""
+    b, t, slots = 4, 32, 3
+    q, k, v = _qkv(5, slots, t)
+    cursor = np.asarray([4, 12, 28])        # where the second block starts
+    lead = np.asarray(lead)
+    want = _dense(q, k, v, 0.25, _block_mask(t, b))
+    counts = jnp.asarray(cursor - b * lead)
+    junk = jax.random.normal(jax.random.key(9), k.shape)
+    at = jnp.arange(t)[None, None, :, None]
+    past = at < counts[:, None, None, None]
+    fq, fk, fv = _qkv(11, slots, b)         # a filler front block
+    rows = [np.arange(c - b, c + b) for c in cursor]
+    q_new = jnp.stack([q[j, r] for j, r in enumerate(rows)])
+    k_new = jnp.stack([k[j][:, r] for j, r in enumerate(rows)])
+    v_new = jnp.stack([v[j][:, r] for j, r in enumerate(rows)])
+    filler = ~lead[:, None, None, None]
+    q_new = q_new.at[:, :b].set(jnp.where(filler, fq, q_new[:, :b]))
+    k_new = k_new.at[:, :, :b].set(jnp.where(filler, fk, k_new[:, :, :b]))
+    v_new = v_new.at[:, :, :b].set(jnp.where(filler, fv, v_new[:, :, :b]))
+    got = gqa.block_decode_attention(
+        q_new, jnp.where(past, k, junk), jnp.where(past, v, junk), k_new,
+        v_new, counts, 0.25, jnp.asarray(lead))
+    assert got.shape == (slots, 2 * b, HEADS * D)
+    for i, c in enumerate(cursor):
+        np.testing.assert_allclose(got[i, b:], want[i, c:c + b], atol=2e-5)
+        if lead[i]:
+            np.testing.assert_allclose(got[i, :b], want[i, c - b:c],
+                                       atol=2e-5)
+    # the second block's rows do not depend on what the filler holds
+    other = gqa.block_decode_attention(
+        jnp.where(filler, 2 * q_new, q_new).at[:, b:].set(q_new[:, b:]),
+        jnp.where(past, k, junk), jnp.where(past, v, junk),
+        k_new.at[:, :, :b].set(jnp.where(filler, -k_new[:, :, :b],
+                                         k_new[:, :, :b])),
+        v_new.at[:, :, :b].set(jnp.where(filler, 3 * v_new[:, :, :b],
+                                         v_new[:, :, :b])),
+        counts, 0.25, jnp.asarray(lead))
+    np.testing.assert_array_equal(np.asarray(other[:, b:]),
+                                  np.asarray(got[:, b:]))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("kernel", [False, True])
 def test_a_commit_writes_exactly_its_rows_and_a_denoise_forward_none(

@@ -481,14 +481,17 @@ def test_moe_decode_kernel_compiles_for_v5e(shape, no_persistent_cache,
 
 
 @pytest.mark.parametrize("tokens,k,h,inner,held", [
-    (256, 8, 2048, 768, 128), (1024, 8, 2048, 768, 128),
-    (1024, 12, 6144, 2048, 16)],
-    ids=["sdar-block-step", "sdar-admit-256", "longcat-admit-512"])
+    (256, 8, 2048, 768, 128), (512, 8, 2048, 768, 128),
+    (1024, 8, 2048, 768, 128), (1024, 12, 6144, 2048, 16)],
+    ids=["sdar-admit-128", "sdar-block-step", "sdar-admit-256",
+         "longcat-admit-512"])
 def test_moe_grouped_kernel_compiles_for_v5e(shape, no_persistent_cache,
                                              tokens, k, h, inner, held):
     """The held experts' product over an expert's OWN rows
-    (``ops/moe_decode.py``, ``moe_grouped_fwd``) at SDAR's block step and
-    its largest admission in the kernel's range — one step an item, 9.4 MB
+    (``ops/moe_decode.py``, ``moe_grouped_fwd``) at SDAR's block step (64
+    slots x 8 token rows since PR 48: the pending block in front of the
+    block in progress), its admissions of 4 x 128 and 4 x 256 — the largest
+    in the kernel's range — one step an item, 9.4 MB
     of weights double-buffered — and at LongCat's 2 rows x 512, whose inner
     tile takes eight steps, with the row tile and the work list's static
     bound the chip path takes."""
@@ -639,12 +642,16 @@ def test_sdar_programs_compile_for_the_chip_and_fit_it(
         shape, sdar_engine, program, no_persistent_cache, monkeypatch):
     """6 whole expert layers (all 128 experts of each), the whole
     vocabulary, 64 slots of grown keys: the chunk program (30 forwards of
-    64 x 4 positions: the block core in XLA, the commit's withheld write as
-    ``row_block_write``, the draw over 256 x 151,936 logits) and the
-    admission of 4 rows at the 1024 bucket (the flash kernel under the
-    block mask), as the chip traces them.  Arguments, results and
-    temporaries together stay under the chip's 16 GiB: the engine's
-    programs do not donate their state, so it is there twice."""
+    64 x 8 positions — each slot's pending block in front of its block in
+    progress: the block core in XLA, the pending block's withheld write as
+    ``row_block_write``, the draw over the 256 x 151,936 logits of the
+    blocks in progress) and the admission of 4 rows at the 1024 bucket (the
+    flash kernel under the block mask), as the chip traces them.
+    Arguments, results and temporaries together stay under the chip's
+    16 GiB: the engine's programs do not donate their state, so it is there
+    twice.  The chunk program for the described chip at PR 48: arguments
+    10.746 GB, results 2.024, temporaries 0.621 (0.459 with B positions a
+    slot), 13.39 GB together."""
     from progen_tpu.decode import sampler
     from progen_tpu.ops import gqa, lowering, moe_decode, row_write
 

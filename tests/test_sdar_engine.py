@@ -128,6 +128,11 @@ def test_the_dynamic_rule_serves_generate_blocks_tokens(served):
         assert done[r.uid].fill_steps.tolist() == fills
 
 
+def _moved(engine, before, key):
+    """How far the engine's counter ``key`` moved since ``before``."""
+    return float(engine.model_stats[key]) - float(before.get(key, 0))
+
+
 @pytest.mark.parametrize("new", [1, 2, 3, 5, 6, 7])
 def test_stop_inside_a_block_drops_what_follows(served, engine, new):
     r = Request(uid=new, tokens=list(range(1, 7)), max_new_tokens=new,
@@ -139,13 +144,71 @@ def test_stop_inside_a_block_drops_what_follows(served, engine, new):
     assert c.finish_reason == "length"
 
     def moved(key):
-        return float(engine.model_stats[key]) - float(before.get(key, 0))
+        return _moved(engine, before, key)
 
     assert moved("diffusion.tokens_committed") == new
     # the prime of 6 leaves 2 positions of its block; whole blocks after
     blocks = 1 + -(-max(new - 2, 0) // BLOCK)
     assert moved("diffusion.tokens_dropped") == 2 + (blocks - 1) * 4 - new
-    assert moved("diffusion.commit_forwards") == blocks
+    # the last block ends the request: nobody reads its keys, none written
+    assert moved("diffusion.commit_forwards") == blocks - 1
+
+
+@pytest.mark.parametrize("blocks,short", [(1, 0), (2, 0), (4, 0), (3, 1),
+                                          (2, 3)])
+def test_a_block_costs_two_forwards_and_its_commit_rides_the_next(
+        served, engine, blocks, short):
+    """A prime of whole blocks under the static rule (2 positions a forward
+    of 4): ``n`` blocks take ``2 n`` live forwards — none is a commit's
+    alone — and ``n - 1`` pending blocks are written, each by the forward
+    that opens the next block; the last block's keys, which nobody reads,
+    are not, whether the request ends on the block's edge or inside it."""
+    new = blocks * BLOCK - short
+    r = Request(uid=800 + new, tokens=list(range(1, 9)), max_new_tokens=new,
+                temperature=0.0, top_k=TOP_K, logit_mask=_allowed())
+    before = dict(engine.model_stats or {})
+    c = _serve(engine, [r])[r.uid]
+    tokens, _ = _reference_tokens(served[0], r)
+    assert c.tokens.tolist() == tokens and len(tokens) == new
+    assert _moved(engine, before, "diffusion.forwards") == 2 * blocks
+    assert _moved(engine, before, "diffusion.commit_forwards") == blocks - 1
+    assert _moved(engine, before, "diffusion.tokens_committed") == new
+    assert _moved(engine, before, "diffusion.tokens_dropped") == short
+    # a forward's context ends before the pending block that rides it
+    starts = 8 + BLOCK * np.arange(blocks)
+    assert _moved(engine, before, "attn.context_tokens") == (
+        2 * starts.sum() - BLOCK * (blocks - 1))
+    assert _moved(engine, before, "attn.decode_rows") == BLOCK * (
+        2 * blocks + blocks - 1)
+    state = engine.state
+    assert state["pending"].shape == (SLOTS, BLOCK)
+    assert not np.asarray(state["has_pending"]).any()
+
+
+def test_a_request_that_ends_on_end_of_sequence_writes_no_last_block(
+        served, engine):
+    """Only tokens 0 and 7 allowed, as below: the block that holds the
+    first 0 ends the request and keeps no pending block."""
+    mask = np.zeros((TINY.vocab_size,), bool)
+    mask[[0, 7]] = True
+    hit = 0
+    for seed in range(6):
+        r = _requests(1, seed=seed, mask=mask, first_uid=900 + seed)[0]
+        r.max_new_tokens = 14
+        before = dict(engine.model_stats or {})
+        c = _serve(engine, [r])[r.uid]
+        if c.finish_reason != "eos":
+            continue
+        hit += 1
+        tail = len(r.tokens) % BLOCK
+        blocks = -(-(tail + len(c.tokens)) // BLOCK)
+        assert blocks < -(-(tail + 14) // BLOCK) or c.tokens[-1] == 0
+        assert _moved(engine, before, "diffusion.commit_forwards") == (
+            blocks - 1)
+        assert _moved(engine, before, "diffusion.forwards") == (
+            -(-(BLOCK - tail) // 2) + 2 * (blocks - 1))
+    assert hit >= 3
+    assert not np.asarray(engine.state["has_pending"]).any()
 
 
 def test_end_of_sequence_inside_a_block_ends_the_request(served, engine):
@@ -171,10 +234,13 @@ def test_end_of_sequence_inside_a_block_ends_the_request(served, engine):
 def test_a_requests_tokens_do_not_depend_on_its_neighbours(served, engine):
     """Sampled: alone, and among neighbours admitted steps before it."""
     reqs = _requests(5, seed=9, sampled=True, first_uid=300)
+    for r in reqs:      # the first three are mid-flight when the rest come
+        r.max_new_tokens += 8
     alone = {r.uid: _serve(engine, [r])[r.uid] for r in reqs}
     for r in reqs[:3]:
         engine.submit(r)
-    early = engine.step() + engine.step()
+    early = engine.step()
+    assert not early
     for r in reqs[3:]:
         engine.submit(r)
     together = {c.uid: c for c in early + engine.run_until_idle(200)}
@@ -285,15 +351,20 @@ def test_counters_ride_the_flags_fetch_into_the_registry(served):
     blocks = sum(-(-(stop - whole) // BLOCK) for whole, stop in spans)
     generated = sum(whole + -(-(stop - whole) // BLOCK) * BLOCK
                     - len(r.tokens) for (whole, stop), r in zip(spans, reqs))
-    assert stats["diffusion.commit_forwards"] == blocks
+    # a block's keys ride the forward that opens the next: every block but
+    # each request's last writes, and no forward is a commit's alone
+    writes = stats["diffusion.commit_forwards"]
+    assert writes == blocks - len(reqs)
     assert stats["diffusion.tokens_dropped"] == generated - new
     kept = np.asarray(stats["diffusion.positions_kept"])
     assert kept.sum() == generated and kept.shape == (2,)
     forwards = stats["diffusion.forwards"]
-    assert blocks * 2 <= forwards <= blocks * 3
-    assert stats["attn.decode_rows"] == BLOCK * forwards
-    assert stats["moe.tokens"] == 3 * (
-        BLOCK * forwards + sum(whole for whole, _ in spans))
+    assert blocks <= forwards <= blocks * 2
+    # a pending block that rides is B more rows of its forward, in every
+    # layer but the last, which needs its keys and values only
+    assert stats["attn.decode_rows"] == BLOCK * (forwards + writes)
+    assert stats["moe.tokens"] == BLOCK * (3 * forwards + 2 * writes) + (
+        3 * sum(whole for whole, _ in spans))
     assert stats["moe.held_load"].sum() == 2 * stats["moe.tokens"]
     snap = get_registry().snapshot()
     for name in ("diffusion.forwards", "diffusion.commit_forwards",
@@ -311,6 +382,8 @@ def test_a_snapshot_replays_a_block_request_token_for_token(served, engine):
     so it serves the same blocks, and still reports their fill steps."""
     params, policy = served
     reqs = _requests(3, seed=21, sampled=True, first_uid=600)
+    for r in reqs:      # more blocks than one chunk's forwards fill
+        r.max_new_tokens += 8
     want = _serve(engine, reqs)
     for r in reqs:
         engine.submit(r)

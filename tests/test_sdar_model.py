@@ -196,6 +196,106 @@ def test_prefill_denoise_commit_through_the_cache_matches_the_reference(
             caches = new
 
 
+def _folded_case(mixed, cursors, has, live):
+    """Slots whose block in progress starts at ``cursors``, with (``has``)
+    a finished block before it whose keys are not in the cache yet, or with
+    every earlier row cached: ``(params, policy, caches, blk, pend, cursors,
+    has & live, live)``."""
+    params, policy = make(mixed=mixed)
+    cursors, has, live = (np.asarray(a) for a in (cursors, has, live))
+    rng = np.random.default_rng(3)
+    toks = rng.integers(1, MASK_ID, (len(cursors), 32))
+    cached = cursors - BLOCK * has          # rows the cache holds
+    # (the prefill sees other tokens past what it caches: no row of the
+    # cache holds a later block's keys by accident)
+    primes = np.where(np.arange(32)[None] < cached[:, None], toks, 1)
+    _, rows, _ = sdar.prefill(params, primes, cached, TINY, policy)
+    caches = sdar.caches_from(rows, cached, TINY, MAX_LEN)
+    pend = np.stack([t[c - BLOCK:c] if h else np.full(BLOCK, MASK_ID)
+                     for t, c, h in zip(toks, cursors, has)])
+    blk = np.stack([np.where(rng.random(BLOCK) < 0.5, t[c:c + BLOCK], MASK_ID)
+                    for t, c in zip(toks, cursors)])
+    return params, policy, caches, blk, pend, cursors, has & live, live
+
+
+FOLDED = {
+    # pending blocks mixed across slots, beside a slot just admitted
+    "mixed": ((8, 12, 20, 16), (True, False, True, False), (True,) * 4),
+    # nothing committed: a first block pending at rows 0..3, and a slot
+    # whose prime is shorter than a block (its filler lies before row 0)
+    "first-block": ((4, 0, 4, 8), (True, False, False, True), (True,) * 4),
+    "dead-slot": ((8, 12, 20, 16), (True, True, False, False),
+                  (True, False, True, False)),
+}
+
+
+@pytest.mark.parametrize("mixed", [False, True], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", list(FOLDED))
+def test_a_pending_block_rides_the_next_forward_as_its_own_commit_would(
+        case, mixed):
+    """ONE forward with the pending block in front against the two it
+    replaces — a commit forward of the pending block, then a denoise forward
+    of the next: the same keys at the pending block's rows and nowhere
+    else, the same logits of the block in progress."""
+    params, policy, caches, blk, pend, cursors, riding, live = _folded_case(
+        mixed, *FOLDED[case])
+    step = jax.jit(lambda p, t, p0, c, live, commit, pending=None:
+                   sdar.block_step(p, t, p0, c, live, commit, TINY, policy,
+                                   pending=pending)[:2])
+    got, new = step(params, blk, cursors, caches, live, riding, pend)
+    _, committed = step(params, pend, np.maximum(cursors - BLOCK, 0), caches,
+                        live, riding)
+    want, after = step(params, blk, cursors, committed, live,
+                       np.zeros_like(live))
+    assert got.shape == want.shape == (len(cursors), BLOCK, TINY.vocab_size)
+    for i in np.flatnonzero(live):
+        diff = np.abs(got[i] - want[i])
+        # (bfloat16 at these widths: ``..._through_the_cache_...`` says why
+        # the mean is what is held)
+        assert float(diff.mean() if mixed else diff.max()) < (
+            0.2 if mixed else 2e-5)
+    for name in caches:
+        for part in ("k", "v"):
+            old, mine, theirs = (np.asarray(c[name][part], np.float32)
+                                 for c in (caches, new, after))
+            np.testing.assert_allclose(mine, theirs,
+                                       atol=0.1 if mixed else 2e-5)
+            changed = (old != mine).any(axis=(1, 3))
+            inside = ((np.arange(MAX_LEN)[None] >= cursors[:, None] - BLOCK)
+                      & (np.arange(MAX_LEN)[None] < cursors[:, None])
+                      & riding[:, None])
+            np.testing.assert_array_equal(changed, inside)
+
+
+def test_a_slot_without_a_pending_block_writes_nothing_and_shows_nothing():
+    """Bitwise: the filler in front of a block in progress reaches no other
+    row's logits, whatever it holds, and no cache row."""
+    params, policy, caches, blk, pend, cursors, riding, live = _folded_case(
+        False, (8, 12, 0, 16), (True, False, False, False), (True,) * 4)
+    step = jax.jit(lambda pending: sdar.block_step(
+        params, blk, cursors, caches, live, riding, TINY, policy,
+        pending=pending))
+    got, new, stats = step(pend)
+    other = pend.copy()
+    other[1:] = np.random.default_rng(0).integers(1, MASK_ID, (3, BLOCK))
+    again, new2, stats2 = step(other)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(again))
+    for a, b in zip(jax.tree.leaves(new), jax.tree.leaves(new2)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for name in caches:     # slots 1..3 keep every row they had
+        np.testing.assert_array_equal(np.asarray(new[name]["k"][1:]),
+                                      np.asarray(caches[name]["k"][1:]))
+    # and no counter counts it: 4 live blocks and the one that rides
+    for key in stats:
+        np.testing.assert_array_equal(np.asarray(stats[key]),
+                                      np.asarray(stats2[key]))
+    assert float(stats["attn.decode_rows"]) == 5 * BLOCK
+    # (the last of the 3 layers needs the riding block's keys and no more:
+    # its experts see the blocks in progress alone)
+    assert float(stats["moe.tokens"]) == (2 * 5 + 4) * BLOCK
+    assert float(stats["attn.context_tokens"]) == (8 - BLOCK) + 12 + 0 + 16
+
+
 def test_a_replay_row_is_every_forward_of_the_trajectory_at_once():
     """``reference_sdar.replay_row``: the reference's ONE forward of the
     clean row and its noisy copies reads, for every kept token, the logits
