@@ -175,7 +175,9 @@ class ProGenFamily:
 # grown keys; the fifth, SDAR, holds whole expert layers and generates by
 # diffusion over blocks (it states a ``block_length``); the sixth, LFM2,
 # holds whole expert layers too, a token a step, under mixers whose whole
-# cache is a convolution's tail beside a few blocks of grown keys
+# cache is a convolution's tail beside a few blocks of grown keys; the
+# seventh, Nemotron-H, a share again, in layers that are ONE sublayer each:
+# a state block, a grown-key block, or an expert layer that states no cache
 _DRIVER_FAMILIES = (
     ("progen_tpu.models.longcat", "LongCatConfig", "LongCatFamily"),
     ("progen_tpu.models.deepseek_v2", "DeepSeekV2Config", "DeepSeekV2Family"),
@@ -184,6 +186,7 @@ _DRIVER_FAMILIES = (
      "GraniteHybridFamily"),
     ("progen_tpu.models.sdar", "SDARConfig", "SDARFamily"),
     ("progen_tpu.models.lfm2", "LFM2Config", "LFM2Family"),
+    ("progen_tpu.models.nemotron_h", "NemotronHConfig", "NemotronHFamily"),
 )
 
 

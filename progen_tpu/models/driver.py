@@ -9,13 +9,17 @@ long), ``models/trinity.py`` two in one model (a ring of ``sliding_window``
 rows beside keys and values that grow with the request, both
 ``models/kv.py``'s), ``models/granite_hybrid.py`` a RECURRENT STATE (a
 carry and a convolution tail a slot, as large at token 1 as at token
-100,000) beside grown keys, ``models/sdar.py`` grown keys that take a BLOCK
-of tokens a step and are written only when the block is committed
-(:func:`block_step`), ``models/lfm2.py`` blocks whose WHOLE cache is a
-convolution's two-row tail beside a few blocks of grown keys.
+100,000: ``models/state.py``'s block) beside grown keys, ``models/sdar.py``
+grown keys that take a BLOCK of tokens a step and are written only when the
+block is committed (:func:`block_step`), ``models/lfm2.py`` blocks whose
+WHOLE cache is a convolution's two-row tail beside a few blocks of grown
+keys, ``models/nemotron_h.py`` layers that are ONE sublayer each — the state
+block at eight groups, a grown-key block, or an expert layer that states NO
+cache: ``blocks_of`` names a block only where a layer has one.
 
 **A block** (``blocks_of(config)`` gives ``{name: block}``, one per
-mixer of the stack, in the stack's order) is an object with
+mixer of the stack that keeps a cache, in the stack's order) is an object
+with
 
 ``init_cache(slots, max_len, dtype)``
     the block's cache of ``slots`` idle rows: a pytree whose every leaf
@@ -46,7 +50,8 @@ mixer of the stack, in the stack's order) is an object with
     the cache where ``commit`` and nothing where not
 
 **Experts are a family's statement**, not the driver's assumption.  A
-family with a share of an expert layer (``models/experts.py``) names
+family with a share of an expert layer (``models/experts.py``: experts of
+three matrices, a gated SwiGLU, or of two with ``relu^2`` between them) names
 ``"moe.held_load"`` among its ``stat_keys``, its stack returns the
 counters, the routers' choices and the held experts touched, and its config
 has ``experts_held``; the driver then adds the ``moe.*`` counters that are

@@ -1,8 +1,17 @@
 """One chip's share of an expert layer: the grouped product over the
 experts the chip holds, its window, and the device counters the families
 with such a layer carry (``models/longcat.py``, ``models/deepseek_v2.py``,
-``models/trinity.py``, ``models/sdar.py``, ``models/lfm2.py``), and the
-sigmoid router two of them share (:func:`sigmoid_route`).
+``models/trinity.py``, ``models/sdar.py``, ``models/lfm2.py``,
+``models/nemotron_h.py``), and the sigmoid router three of them share
+(:func:`sigmoid_route`).
+
+**Two expert forms**, told apart by what ``experts`` holds and never by a
+knob: ``{"wg", "wu", "wd"}``, a gated SwiGLU of three matrices, ``(silu(u
+W_g) * (u W_u)) W_d`` (five families); ``{"wu", "wd"}``, two matrices and
+no gate, ``relu(u W_u)^2 W_d`` (Nemotron-H's, which also run in a width
+that is not the model's: the layer projects into the latent before
+:func:`held_experts` and out of it after the sum — ``u`` here is whatever
+the experts take).  All three lowerings below compute both.
 
 The router is as wide as published and picks ``moe_topk`` whatever the chip
 holds; the layer adds the terms of the ``experts_held`` real experts from
@@ -15,10 +24,10 @@ TPU with no mesh in scope, tokens and weights of one float type and widths
 on the lane tile, a call's TOKENS decide:
 
 * a DECODE step's handful (at most 128: ``"pallas"``) goes through the
-  kernel ``moe_decode_fwd``: each touched expert's gate, up and down
-  matrices streamed once, back to back, every token through every touched
-  expert with its routing weight (zero where it is not the expert's)
-  selecting, so there is neither sort nor gather nor scatter-add;
+  kernel ``moe_decode_fwd``: each touched expert's matrices streamed once,
+  back to back, every token through every touched expert with its routing
+  weight (zero where it is not the expert's) selecting, so there is neither
+  sort nor gather nor scatter-add;
 * a few hundred (129 to 1,024 — a block-diffusion step's ``slots x
   block_length``, 256 in its cell, and the admissions under 2,048 tokens:
   ``"pallas_grouped"``) go through the kernel ``moe_grouped_fwd``: the
@@ -73,12 +82,13 @@ def add_stats(a: dict, b: dict) -> dict:
 def sigmoid_route(u, router, topk: int, *, norm: bool, scale: float,
                   eps: float):
     """The sigmoid router with a selection bias, of the families that have
-    it (``models/trinity.py``, ``models/lfm2.py``): ``(ids (T, k), weights
+    it (``models/trinity.py``, ``models/lfm2.py``,
+    ``models/nemotron_h.py``): ``(ids (T, k), weights
     (T, k))``, float32 throughout.  ``s = sigmoid(u W_r)``; the ``topk``
     largest of ``s + b`` are chosen (``b = router["bias"]`` picks and does
     not weigh); the weights are the chosen ``s`` alone, over ``sum s + eps``
     where ``norm``, times ``scale``.  ``eps`` is the family's own (Trinity
-    1e-20, LFM2 1e-6)."""
+    and Nemotron-H 1e-20, LFM2 1e-6)."""
     with jax.named_scope("moe.route"):
         logits = jnp.dot(u.astype(F32), router["w"].astype(F32),
                          precision=jax.lax.Precision.HIGHEST)
@@ -140,9 +150,11 @@ def held_experts(u, ids, w, live, experts, c, capacity=None):
             sizes = (jnp.clip(ends - base, 0, cap)
                      - jnp.clip(starts - base, 0, cap)).astype(jnp.int32)
             xs = u[tok]
-            gate = jax.lax.ragged_dot(xs, experts["wg"].astype(u.dtype), sizes)
+            gate = jax.lax.ragged_dot(
+                xs, experts["wg"].astype(u.dtype),
+                sizes) if "wg" in experts else None
             up = jax.lax.ragged_dot(xs, experts["wu"].astype(u.dtype), sizes)
-            out = jax.lax.ragged_dot(jax.nn.silu(gate) * up,
+            out = jax.lax.ragged_dot(moe_decode.activation(gate, up),
                                      experts["wd"].astype(u.dtype), sizes)
             wt = jnp.where(valid, weights[idx], 0.0)
             term = jnp.where(valid[:, None], out.astype(F32) * wt[:, None], 0.0)
@@ -164,7 +176,7 @@ def _streamed(u, group, w, load, experts, tiles):
     names = group[None] == jnp.arange(held)[:, None, None]
     wt = jnp.sum(jnp.where(names, w.astype(F32)[None], 0.0), axis=-1)
     return moe_decode.pallas_expert_terms(
-        u, eid, jnp.sum(touched), wt[eid], experts["wg"], experts["wu"],
+        u, eid, jnp.sum(touched), wt[eid], experts.get("wg"), experts["wu"],
         experts["wd"], tile=tiles.inner)
 
 
@@ -193,8 +205,9 @@ def _grouped(u, group, w, load, experts, tiles):
     src = order[jnp.where(real, start[eid][:, None] + r, 0)].reshape(-1)
     out = moe_decode.pallas_grouped_terms(
         u[src // k], eid, tile_end[-1],
-        jnp.where(real.reshape(-1), w.reshape(-1)[src], 0.0), experts["wg"],
-        experts["wu"], experts["wd"], row_tile=rt, tile=tiles.inner)
+        jnp.where(real.reshape(-1), w.reshape(-1)[src], 0.0),
+        experts.get("wg"), experts["wu"], experts["wd"], row_tile=rt,
+        tile=tiles.inner)
     # where each assignment's term is: its expert's first row and its place
     # among the expert's assignments (the inverse of ``order``)
     e = jnp.minimum(group, held - 1)
@@ -207,7 +220,7 @@ def _grouped(u, group, w, load, experts, tiles):
 def kernel_counters(u, experts, load) -> dict:
     """What the lowering :func:`held_experts` takes for ``u`` does, by the
     lowering's own reckoning, as float32 scalars: ``moe.expert_passes``,
-    how many times it streams an expert's three matrices, and
+    how many times it streams an expert's matrices, and
     ``moe.rows_computed``, the rows it passes through them.  Under
     ``moe_decode_fwd`` one work item a touched expert, all of the call's
     (padded) rows in each; under ``moe_grouped_fwd`` one item a row tile of
@@ -225,7 +238,7 @@ def kernel_counters(u, experts, load) -> dict:
                           * moe_decode.ROW_GROUP)
     else:
         items = jnp.sum(-(-load // tiles.rows))
-        whole = tiles.inner == experts["wg"].shape[-1]
+        whole = tiles.inner == experts["wu"].shape[-1]
         passes, rows = touched if whole else items, items * tiles.rows
     return {"moe.expert_passes": passes.astype(F32),
             "moe.rows_computed": rows.astype(F32)}

@@ -4,13 +4,15 @@ grouped-query attention layers with NO positional embedding whose keys grow
 with the request, a shared SwiGLU MLP in every layer, four muP multipliers
 and a head tied to the embedding.  Whole on one chip: nothing is a share.
 
-What Granite alone has: its config, the state block and what it states
-about its cache, the attention block's projection, the layer's wiring with
-its multipliers and the seeded weights' layout.  The model driver and the
-engine's seam are ``models/driver.py``; the grown-key cache and its decode
-step are ``models/kv.py`` (shared with ``models/trinity.py``); the
-recurrence and the convolution are ``ops/ssd.py``, the attention cores
-``ops/gqa.py``.
+What Granite alone has: its config, the attention block's projection,
+the layer's wiring with its multipliers and the seeded weights' layout.
+The model driver and the engine's seam are ``models/driver.py``; the
+grown-key cache and its decode step are ``models/kv.py`` (shared with
+``models/trinity.py``); the Mamba-2 mixer and what it states about its
+cache are ``models/state.py`` (shared with ``models/nemotron_h.py``: the
+block was here until a second family needed it; Granite gives it its sizes,
+``mamba_n_groups`` 1); the recurrence and the convolution are
+``ops/ssd.py``, the attention cores ``ops/gqa.py``.
 
 ``x0 = E[token] * embedding_multiplier``.  Layer ``l``, both kinds, pre-norm
 with scaled branches (``N_*`` RMSNorms with a learned scale)::
@@ -39,17 +41,9 @@ clamp on ``dt``: ``time_step_limit`` is the default), then the recurrence of
 ``ops/ssd.py`` plus ``D_h x_t``; ``y <- RMSNorm_w(y * silu(z))`` over all
 ``I`` channels (the gate BEFORE the norm, one group), then ``W_out``.
 
-**The state block's cache** (:class:`StateBlock`): ``{"ssm": (slots, heads,
-d_head, N) float32, "conv": (slots, d_conv - 1, I + 2 N)}``.  It does not
-depend on ``max_len``: 2.1 MB a slot and layer at the published widths,
-whatever the request's length, read AND written every token.  The carry is
-float32 (a bfloat16 one would re-round the whole state every token); the
-convolution tail, like keys and values, is in the compute dtype.  A
-prefill hands over the carry at each row's TRUE length and the row's last
-three real convolution inputs; an admission overwrites all of a slot's
-state, so a slot that idled serves its next request as a fresh one does.
-Rows that are not live run (the batch is static): their state is garbage
-but finite (every decay is at most 1 and the input is normed).
+**The state block's cache** (``models/state.py:StateBlock``): ``{"ssm":
+(slots, heads, d_head, N) float32, "conv": (slots, d_conv - 1, I + 2 N)}``,
+2.1 MB a slot and layer at the published widths whatever ``max_len``.
 """
 
 from __future__ import annotations
@@ -62,7 +56,7 @@ import jax
 import jax.numpy as jnp
 
 from progen_tpu.core.precision import Policy
-from progen_tpu.models import driver, kv
+from progen_tpu.models import driver, kv, state
 from progen_tpu.models.driver import (  # noqa: F401
     F32,
     bf16_policy,
@@ -70,7 +64,6 @@ from progen_tpu.models.driver import (  # noqa: F401
     rms_norm,
     swiglu,
 )
-from progen_tpu.ops import ssd
 
 MAMBA, ATTENTION = "mamba", "attention"
 _PUBLISHED_LAYERS = tuple(
@@ -186,31 +179,6 @@ class GraniteHybridConfig:
 # ------------------------------------------------------------------ weights
 
 
-def _log_uniform(key, shape, lo, hi):
-    return jnp.exp(jax.random.uniform(key, shape, F32, math.log(lo),
-                                      math.log(hi)))
-
-
-def _init_mamba(key, c: GraniteHybridConfig, dt):
-    h, inner, heads = c.hidden_size, c.mamba_inner, c.mamba_n_heads
-    ks = jax.random.split(key, 7)
-    step = _log_uniform(ks[3], (heads,), *c.dt_range)
-    return {
-        "in_proj": driver.normal(
-            ks[0], (h, inner + c.conv_channels + heads), h ** -0.5, dt),
-        "conv_w": driver.normal(ks[1], (c.conv_channels, c.mamba_d_conv),
-                                c.mamba_d_conv ** -0.5, dt),
-        "conv_b": driver.normal(ks[2], (c.conv_channels,), 0.05, dt),
-        # the recurrence's own parameters stay float32, as the release
-        # keeps them; softplus(dt_bias) is the drawn step
-        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
-        "a_log": jnp.log(_log_uniform(ks[4], (heads,), *c.a_range)),
-        "d": jnp.ones((heads,), F32),
-        "norm": driver.init_norm(ks[5], (inner,), dt),
-        "out_proj": driver.normal(ks[6], (inner, h), inner ** -0.5, dt),
-    }
-
-
 def _init_attn(key, c: GraniteHybridConfig, dt):
     h, d = c.hidden_size, c.head_dim
     q, kvw = c.num_attention_heads * d, c.num_key_value_heads * d
@@ -230,9 +198,11 @@ def _init_attn(key, c: GraniteHybridConfig, dt):
 
 def _init_layer(key, c: GraniteHybridConfig, dt, kind: str):
     ks = jax.random.split(key, 3)
-    mixer = _init_mamba if kind == MAMBA else _init_attn
+    mixer = (state_block(c).init_weights(
+        ks[1], c.hidden_size, dt, c.dt_range, c.a_range)
+        if kind == MAMBA else _init_attn(ks[1], c, dt))
     return {"norm": driver.init_norm(ks[0], (2, c.hidden_size), dt),
-            "mixer": mixer(ks[1], c, dt),
+            "mixer": mixer,
             "ffn": driver.init_ffn(ks[2], c.hidden_size,
                                    c.shared_intermediate_size, 1.0, dt)}
 
@@ -279,84 +249,15 @@ class AttentionBlock(kv.KVBlock):
         return mm(o, p["wo"])
 
 
-class StateBlock:
-    """A Mamba-2 mixer and what it states about its cache
-    (``models/driver.py`` says what a block is): the carry and the
-    convolution's tail, a slot; the module docstring has the layout."""
-
-    def __init__(self, config: GraniteHybridConfig):
-        self.config = config
-
-    def init_cache(self, slots: int, max_len: int, dtype):
-        c = self.config
-        return {"ssm": jnp.zeros((slots, c.mamba_n_heads, c.mamba_d_head,
-                                  c.mamba_d_state), F32),
-                "conv": jnp.zeros((slots, c.mamba_d_conv - 1,
-                                   c.conv_channels), dtype)}
-
-    def _split_in(self, x, p):
-        c = self.config
-        with jax.named_scope("ssm.in_proj"):
-            zxbcdt = mm(x, p["in_proj"])
-        inner = c.mamba_inner
-        z = zxbcdt[..., :inner]
-        xbc = zxbcdt[..., inner:inner + c.conv_channels]
-        dt = jax.nn.softplus(zxbcdt[..., inner + c.conv_channels:].astype(F32)
-                             + p["dt_bias"])
-        return z, xbc, dt
-
-    def _split_conv(self, xbc):
-        c = self.config
-        inner, n = c.mamba_inner, c.mamba_d_state
-        x = xbc[..., :inner]
-        x = x.reshape(x.shape[:-1] + (c.mamba_n_heads, c.mamba_d_head))
-        return x, xbc[..., inner:inner + n], xbc[..., inner + n:]
-
-    def _out(self, y, x, z, p):
-        """``y`` float32 from the recurrence: the skip, the gate, the norm
-        over all channels, the output projection."""
-        c = self.config
-        y = y + p["d"][:, None] * x.astype(F32)
-        y = y.reshape(y.shape[:-2] + (c.mamba_inner,)).astype(z.dtype)
-        y = rms_norm(y * jax.nn.silu(z), p["norm"], c.rms_norm_eps)
-        with jax.named_scope("ssm.out_proj"):
-            return mm(y, p["out_proj"])
-
-    def prefill(self, u, p, lengths):
-        """The mixer over ``u (R, P, h)``; what the slot will hold is the
-        carry at each row's true length and its last three real
-        convolution inputs."""
-        c = self.config
-        z, xbc, dt = self._split_in(u, p)
-        with jax.named_scope("ssm.conv"):
-            tail = ssd.conv_tail(xbc, lengths, c.mamba_d_conv)
-            xbc = jax.nn.silu(ssd.causal_conv(
-                xbc, p["conv_w"], p["conv_b"])).astype(u.dtype)
-        x, b, cc = self._split_conv(xbc)
-        with jax.named_scope("ssm.scan"):
-            y, state = ssd.ssd_scan(x, dt, -jnp.exp(p["a_log"]), b, cc,
-                                    lengths, c.mamba_chunk_size)
-        return self._out(y, x, z, p), {"ssm": state, "conv": tail}
-
-    def cache_rows(self, rows, lengths, max_len: int):
-        return rows
-
-    def decode(self, u, pos, cache, p):
-        """One token a row: the tail shifted, the carry updated."""
-        z, xbc, dt = self._split_in(u, p)
-        with jax.named_scope("ssm.conv"):
-            xbc, tail = ssd.conv_step(cache["conv"], xbc, p["conv_w"],
-                                      p["conv_b"])
-            xbc = jax.nn.silu(xbc).astype(u.dtype)
-        x, b, cc = self._split_conv(xbc)
-        with jax.named_scope("ssm.step"):
-            y, state = ssd.ssd_step(cache["ssm"], x, dt,
-                                    -jnp.exp(p["a_log"]), b, cc)
-        return self._out(y, x, z, p), {"ssm": state, "conv": tail}
+def state_block(c: GraniteHybridConfig) -> state.StateBlock:
+    """Granite's sizes of the shared Mamba-2 block."""
+    return state.StateBlock(c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state,
+                            c.mamba_n_groups, c.mamba_d_conv, c.rms_norm_eps,
+                            c.mamba_chunk_size)
 
 
 def blocks_of(c: GraniteHybridConfig) -> dict:
-    kinds = {MAMBA: StateBlock(c), ATTENTION: AttentionBlock(c)}
+    kinds = {MAMBA: state_block(c), ATTENTION: AttentionBlock(c)}
     return {f"l{i}": kinds[kind] for i, kind in enumerate(c.layer_types)}
 
 
@@ -365,20 +266,15 @@ def mamba_layers(c: GraniteHybridConfig) -> int:
 
 
 # device-side counters, all float32 sums (docs/OBSERVABILITY.md section 3):
-# decode steps that had a live row, and live rows x mamba layers they
-# updated; real prime tokens x mamba layers a prefill scanned, and the token
-# slots it computed for them (padding and partial chunks included); the
-# attention blocks' as Trinity's full blocks
-STAT_KEYS = ("ssm.decode_steps", "ssm.step_rows", "ssm.prefill_tokens",
-             "ssm.prefill_slots", "attn.decode_rows", "attn.context_tokens",
-             "attn.full_rows_read")
+# the state block's four; the attention blocks' as Trinity's full blocks
+STAT_KEYS = state.STAT_KEYS + ("attn.decode_rows", "attn.context_tokens",
+                               "attn.full_rows_read")
 
 
 def decode_stats(blocks: dict, c: GraniteHybridConfig, caches, pos,
                  live) -> dict:
     attn = kv.decode_stats(blocks, caches, pos, live)
-    return {"ssm.decode_steps": jnp.any(live).astype(F32),
-            "ssm.step_rows": mamba_layers(c) * jnp.sum(live).astype(F32),
+    return {**state.decode_stats(blocks, live),
             **{k: attn[k] for k in STAT_KEYS if k in attn}}
 
 
@@ -404,11 +300,8 @@ def prefill(params, tokens, lengths, config: GraniteHybridConfig,
     attention block's per-token ``{"k", "v"}: (R, KV, P, d)``."""
     out = driver.prefill(_layers, blocks_of(config), params, tokens, lengths,
                          config, policy or bf16_policy(), **kwargs)
-    r, n = tokens.shape
-    layers = mamba_layers(config)
-    out[2]["ssm.prefill_tokens"] = layers * jnp.sum(lengths).astype(F32)
-    out[2]["ssm.prefill_slots"] = jnp.asarray(
-        layers * ssd.scanned_slots(r, n, config.mamba_chunk_size), F32)
+    out[2].update(state.prefill_stats(blocks_of(config), tokens.shape,
+                                      lengths))
     return out
 
 

@@ -1,0 +1,197 @@
+"""A Mamba-2 mixer that carries a recurrent state, and what it states about
+its cache (``models/driver.py`` says what a block is).  Shared by the
+families with such layers (``models/granite_hybrid.py``: one B/C group,
+beside an MLP in every layer; ``models/nemotron_h.py``: eight groups, the
+layer's only sublayer), as ``models/kv.py`` is by those whose attention is
+grouped-query: a family brings SIZES — heads, head size, state, groups,
+taps, eps, the scan's chunk — and the weights in the layout below; the
+projection's split, the convolution, the recurrence (``ops/ssd.py``), the
+gated norm and the cache are here.
+
+``I = heads * head_dim``, ``G`` groups of ``N`` states, ``K`` taps, ``u (...,
+h)`` the normed stream::
+
+    [z (I) | xBC (I + 2 G N) | dt (heads)] = u W_in         (no bias)
+    xBC_t <- silu(sum_j w_conv[:, j] * xBC_{t-(K-1)+j} + b_conv)
+    [x (heads, head_dim) | B (G, N) | C (G, N)] = xBC_t
+    dt = softplus(dt + dt_bias_h),  a_h = -exp(A_log_h)     (float32, no clamp)
+    S_t = exp(dt a_h) S_{t-1} + dt x_t (x) B_t,g,  y_t = S_t C_t,g + D_h x_t
+    out = RMSNorm_w(y * silu(z)) W_out
+
+with ``g = h // (heads / G)`` the head's group, the convolution depthwise
+and causal with zeros before the row's first token, and the norm — the gate
+BEFORE it — over each group of ``I / G`` channels by itself (all ``I`` where
+``G`` is 1).  With one group no group axis is made anywhere: Granite's
+programs are the ops they were before the block was shared
+(``tests/test_program_identity.py``).
+
+**The cache**: ``{"ssm": (slots, heads, head_dim, N) float32, "conv":
+(slots, K - 1, I + 2 G N)}``.  It does not depend on ``max_len``: 2.1 MB a
+slot and layer at Granite's widths, 4.19 MB at Nemotron-H's, whatever the
+request's length, read AND written every token.  The carry is float32 (a
+bfloat16 one would re-round the whole state every token); the convolution
+tail, like keys and values, is in the compute dtype.  A prefill hands over
+the carry at each row's TRUE length and the row's last ``K - 1`` real
+convolution inputs (zeros where the row is shorter); an admission
+overwrites all of a slot's state, so a slot that idled serves its next
+request as a fresh one does.  Rows that are not live run (the batch is
+static): their state is garbage but finite (every decay is at most 1 and
+the input is normed).
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+from progen_tpu.models.driver import F32, init_norm, mm, normal, rms_norm
+from progen_tpu.ops import ssd
+
+
+def _log_uniform(key, shape, lo, hi):
+    return jnp.exp(jax.random.uniform(key, shape, F32, math.log(lo),
+                                      math.log(hi)))
+
+
+class StateBlock:
+    """One Mamba-2 mixer of ``heads`` heads of ``head_dim`` over ``groups``
+    B/C groups of ``state`` states, a convolution of ``conv`` taps, norms at
+    ``eps`` and a prefill scan in chunks of ``chunk`` tokens."""
+
+    def __init__(self, heads: int, head_dim: int, state: int, groups: int,
+                 conv: int, eps: float, chunk: int):
+        if heads % groups:
+            raise ValueError(
+                f"{heads} heads do not split over {groups} groups")
+        self.heads, self.head_dim, self.state = heads, head_dim, state
+        self.groups, self.conv, self.eps, self.chunk = (groups, conv, eps,
+                                                        chunk)
+        self.inner = heads * head_dim
+        self.conv_channels = self.inner + 2 * groups * state
+
+    def init_weights(self, key, hidden: int, dt, dt_range, a_range) -> dict:
+        """Seeded weights: the per-head step ``softplus(dt_bias)`` and ``A``
+        drawn log-uniform from ``dt_range`` / ``a_range``."""
+        inner, heads = self.inner, self.heads
+        ks = jax.random.split(key, 7)
+        step = _log_uniform(ks[3], (heads,), *dt_range)
+        return {
+            "in_proj": normal(
+                ks[0], (hidden, inner + self.conv_channels + heads),
+                hidden ** -0.5, dt),
+            "conv_w": normal(ks[1], (self.conv_channels, self.conv),
+                             self.conv ** -0.5, dt),
+            "conv_b": normal(ks[2], (self.conv_channels,), 0.05, dt),
+            # the recurrence's own parameters stay float32, as the releases
+            # keep them; softplus(dt_bias) is the drawn step
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "a_log": jnp.log(_log_uniform(ks[4], (heads,), *a_range)),
+            "d": jnp.ones((heads,), F32),
+            "norm": init_norm(ks[5], (inner,), dt),
+            "out_proj": normal(ks[6], (inner, hidden), inner ** -0.5, dt),
+        }
+
+    def init_cache(self, slots: int, max_len: int, dtype):
+        return {"ssm": jnp.zeros((slots, self.heads, self.head_dim,
+                                  self.state), F32),
+                "conv": jnp.zeros((slots, self.conv - 1,
+                                   self.conv_channels), dtype)}
+
+    def _split_in(self, x, p):
+        with jax.named_scope("ssm.in_proj"):
+            zxbcdt = mm(x, p["in_proj"])
+        inner = self.inner
+        z = zxbcdt[..., :inner]
+        xbc = zxbcdt[..., inner:inner + self.conv_channels]
+        dt = jax.nn.softplus(
+            zxbcdt[..., inner + self.conv_channels:].astype(F32)
+            + p["dt_bias"])
+        return z, xbc, dt
+
+    def _split_conv(self, xbc):
+        inner, width = self.inner, self.groups * self.state
+        x = xbc[..., :inner]
+        x = x.reshape(x.shape[:-1] + (self.heads, self.head_dim))
+        b, c = xbc[..., inner:inner + width], xbc[..., inner + width:]
+        if self.groups > 1:
+            b, c = (v.reshape(v.shape[:-1] + (self.groups, self.state))
+                    for v in (b, c))
+        return x, b, c
+
+    def _out(self, y, x, z, p):
+        """``y`` float32 from the recurrence: the skip, the gate, the norm
+        a group of channels, the output projection."""
+        y = y + p["d"][:, None] * x.astype(F32)
+        y = y.reshape(y.shape[:-2] + (self.inner,)).astype(z.dtype)
+        with jax.named_scope("ssm.norm"):
+            y, scale = y * jax.nn.silu(z), p["norm"]
+            if self.groups > 1:
+                split = (self.groups, self.inner // self.groups)
+                y = rms_norm(y.reshape(y.shape[:-1] + split),
+                             scale.reshape(split),
+                             self.eps).reshape(y.shape)
+            else:
+                y = rms_norm(y, scale, self.eps)
+        with jax.named_scope("ssm.out_proj"):
+            return mm(y, p["out_proj"])
+
+    def prefill(self, u, p, lengths):
+        """The mixer over ``u (R, P, h)``; what the slot will hold is the
+        carry at each row's true length and its last ``K - 1`` real
+        convolution inputs."""
+        z, xbc, dt = self._split_in(u, p)
+        with jax.named_scope("ssm.conv"):
+            tail = ssd.conv_tail(xbc, lengths, self.conv)
+            xbc = jax.nn.silu(ssd.causal_conv(
+                xbc, p["conv_w"], p["conv_b"])).astype(u.dtype)
+        x, b, cc = self._split_conv(xbc)
+        with jax.named_scope("ssm.scan"):
+            y, state = ssd.ssd_scan(x, dt, -jnp.exp(p["a_log"]), b, cc,
+                                    lengths, self.chunk)
+        return self._out(y, x, z, p), {"ssm": state, "conv": tail}
+
+    def cache_rows(self, rows, lengths, max_len: int):
+        return rows
+
+    def decode(self, u, pos, cache, p):
+        """One token a row: the tail shifted, the carry updated."""
+        z, xbc, dt = self._split_in(u, p)
+        with jax.named_scope("ssm.conv"):
+            xbc, tail = ssd.conv_step(cache["conv"], xbc, p["conv_w"],
+                                      p["conv_b"])
+            xbc = jax.nn.silu(xbc).astype(u.dtype)
+        x, b, cc = self._split_conv(xbc)
+        with jax.named_scope("ssm.step"):
+            y, state = ssd.ssd_step(cache["ssm"], x, dt,
+                                    -jnp.exp(p["a_log"]), b, cc)
+        return self._out(y, x, z, p), {"ssm": state, "conv": tail}
+
+
+# the block's device counters, all float32 sums (docs/OBSERVABILITY.md
+# section 3): decode steps that had a live row, and live rows x state layers
+# they updated; real prime tokens x state layers a prefill scanned, and the
+# token slots it computed for them (padding and partial chunks included)
+STAT_KEYS = ("ssm.decode_steps", "ssm.step_rows", "ssm.prefill_tokens",
+             "ssm.prefill_slots")
+
+
+def _state_blocks(blocks: dict) -> list:
+    return [b for b in blocks.values() if isinstance(b, StateBlock)]
+
+
+def decode_stats(blocks: dict, live) -> dict:
+    """A decode step's ``ssm.*`` counters."""
+    return {"ssm.decode_steps": jnp.any(live).astype(F32),
+            "ssm.step_rows": len(_state_blocks(blocks)) * jnp.sum(
+                live).astype(F32)}
+
+
+def prefill_stats(blocks: dict, tokens_shape, lengths) -> dict:
+    """A prefill's ``ssm.*`` counters over rows of ``lengths`` padded to
+    ``tokens_shape = (R, P)``."""
+    mine = _state_blocks(blocks)
+    slots = sum(ssd.scanned_slots(*tokens_shape, b.chunk) for b in mine)
+    return {"ssm.prefill_tokens": len(mine) * jnp.sum(lengths).astype(F32),
+            "ssm.prefill_slots": jnp.asarray(slots, F32)}

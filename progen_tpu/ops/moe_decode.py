@@ -1,5 +1,6 @@
 """The held experts' product where the WEIGHTS are the work: every touched
-expert's gate, up and down matrices streamed from HBM once, back to back,
+expert's matrices — gate, up and down, or up and down alone (the two
+expert forms, below) — streamed from HBM once, back to back,
 through one software pipeline — for a decode step's handful of tokens
 (``moe_decode_fwd``) and for a block step's few hundred
 (``moe_grouped_fwd``).
@@ -13,7 +14,12 @@ token is not the expert's) and the stacked ``wg``/``wu (held, h, inner)``,
     y = sum over the first n_real i of
         wt[i][:, None] * ((silu(u wg[eid[i]]) * (u wu[eid[i]])) wd[eid[i]])
 
-over the rows whose weight is not zero.  With ``T`` at most the MXU's 128
+over the rows whose weight is not zero.  **Two expert forms**, told apart
+by what the caller holds and never by a knob: three matrices, the gated
+SwiGLU above; or two (``wg`` None: ``models/nemotron_h.py``'s latent
+experts), ``relu(u wu[e])^2 wd[e]`` — no gate, the activation squared.
+Everything below is the same for both but the step's tiles, two where
+there is no gate.  With ``T`` at most the MXU's 128
 rows a weight tile takes no longer to use than to load, so ALL tokens go
 through every listed expert and the routing weight (zero for the others)
 selects: no sort, no gather and no scatter-add around the kernel, and the
@@ -74,9 +80,10 @@ F32 = jnp.float32
 # rows take to pass it)
 MAX_TOKENS = 128
 LANE = 128              # widths and tiles are whole lane tiles
-# bytes of one step's three tiles on a v5e (PERF.md section 6, PR 37, has
-# the tiles measured at the three cells' widths): the inner tile is the
-# largest whole number of lane tiles that divides the inner width under it
+# bytes of one step's tiles (three, or two without a gate) on a v5e (PERF.md
+# section 6, PR 37, has the tiles measured at the three cells' widths): the
+# inner tile is the largest whole number of lane tiles that divides the
+# inner width under it
 STEP_BYTES = 12 << 20
 ROW_GROUP = 16          # token rows are padded to whole bfloat16 sublane tiles
 # above MAX_TOKENS an expert gets its OWN rows, in tiles of this many.  One
@@ -104,14 +111,24 @@ ROW_TILE = 32
 MAX_GROUPED_TOKENS = 1024
 
 
+def activation(gate, up):
+    """An expert's hidden row from its float32 products: ``silu(gate) *
+    up``, or ``relu(up)^2`` where the expert has no gate."""
+    if gate is None:
+        return jnp.square(jax.nn.relu(up))
+    return jax.nn.silu(gate) * up
+
+
 def xla_expert_terms(u, eid, n_real, wt, wg, wu, wd):
     """The contract in plain XLA (the tests' oracle): a loop over the
-    listed experts, float32 accumulation."""
+    listed experts, float32 accumulation; ``wg`` None for experts of two
+    matrices."""
     def item(i, y):
         e = eid[i]
-        gate = jnp.dot(u, wg[e].astype(u.dtype), preferred_element_type=F32)
+        gate = None if wg is None else jnp.dot(
+            u, wg[e].astype(u.dtype), preferred_element_type=F32)
         up = jnp.dot(u, wu[e].astype(u.dtype), preferred_element_type=F32)
-        out = jnp.dot((jax.nn.silu(gate) * up).astype(u.dtype),
+        out = jnp.dot(activation(gate, up).astype(u.dtype),
                       wd[e].astype(u.dtype), preferred_element_type=F32)
         w = wt[i][:, None]
         return y + jnp.where(w != 0, out * w, 0.0)
@@ -119,21 +136,28 @@ def xla_expert_terms(u, eid, n_real, wt, wg, wu, wd):
     return jax.lax.fori_loop(0, n_real, item, jnp.zeros(u.shape, F32))
 
 
-def _item_product(x_ref, wt_ref, wg_ref, wu_ref, wd_ref):
+def _item_product(x_ref, wt_ref, w_refs):
     """One step of either kernel: the rows ``x (R, h)`` through an inner
-    slice of one expert, and their routing weights ``(R, 1)``; what the
-    step adds is ``where(w != 0, out * w, 0)``: a row whose weight is zero
-    adds nothing, whatever it holds."""
+    slice of one expert — ``w_refs`` its tiles, ``(gate, up, down)`` or
+    ``(up, down)`` — and their routing weights ``(R, 1)``; what the step
+    adds is ``where(w != 0, out * w, 0)``: a row whose weight is zero adds
+    nothing, whatever it holds."""
     x = x_ref[...]
-    gate = jnp.dot(x, wg_ref[...], preferred_element_type=F32)
+    *wg_ref, wu_ref, wd_ref = w_refs
+    if wg_ref:
+        gate = jnp.dot(x, wg_ref[0][...], preferred_element_type=F32)
     up = jnp.dot(x, wu_ref[...], preferred_element_type=F32)
-    act = (gate * jax.nn.sigmoid(gate) * up).astype(x.dtype)
-    out = jnp.dot(act, wd_ref[...], preferred_element_type=F32)
+    act = (gate * jax.nn.sigmoid(gate) * up if wg_ref
+           else jnp.square(jnp.maximum(up, 0.0)))
+    out = jnp.dot(act.astype(x.dtype), wd_ref[...],
+                  preferred_element_type=F32)
     return out, wt_ref[...]
 
 
-def _kernel(eid_ref, n_ref, u_ref, wt_ref, wg_ref, wu_ref, wd_ref, y_ref):
+def _kernel(eid_ref, n_ref, u_ref, wt_ref, *refs):
     from jax.experimental import pallas as pl
+
+    *w_refs, y_ref = refs
 
     i, s = pl.program_id(0), pl.program_id(1)
 
@@ -143,19 +167,19 @@ def _kernel(eid_ref, n_ref, u_ref, wt_ref, wg_ref, wu_ref, wd_ref, y_ref):
 
     @pl.when(i < n_ref[0])
     def _():
-        out, w = _item_product(u_ref, wt_ref, wg_ref, wu_ref, wd_ref)
+        out, w = _item_product(u_ref, wt_ref, w_refs)
         y_ref[...] += jnp.where(w != 0, out * w, 0.0)
 
 
-def _grouped_kernel(eid_ref, n_ref, x_ref, wt_ref, wg_ref, wu_ref, wd_ref,
-                    o_ref):
+def _grouped_kernel(eid_ref, n_ref, x_ref, wt_ref, *refs):
     from jax.experimental import pallas as pl
 
+    *w_refs, o_ref = refs
     i, s = pl.program_id(0), pl.program_id(1)
 
     @pl.when(i < n_ref[0])
     def _():
-        out, w = _item_product(x_ref, wt_ref, wg_ref, wu_ref, wd_ref)
+        out, w = _item_product(x_ref, wt_ref, w_refs)
         term = jnp.where(w != 0, out * w, 0.0)
 
         @pl.when(s == 0)
@@ -167,12 +191,16 @@ def _grouped_kernel(eid_ref, n_ref, x_ref, wt_ref, wg_ref, wu_ref, wd_ref,
             o_ref[...] += term
 
 
-def inner_tile(h: int, inner: int, itemsize: int) -> int:
+def inner_tile(h: int, inner: int, itemsize: int, matrices: int = 3) -> int:
     """The largest multiple of ``LANE`` that divides ``inner`` and keeps a
-    step's three tiles under ``STEP_BYTES`` (``LANE`` where none does)."""
+    step's tiles — one of each of the expert's ``matrices`` — under
+    ``STEP_BYTES`` (``LANE`` where none does).  Nemotron-H's latent experts
+    (``h`` 1024, inner 2688 = 21 x 128, two matrices, bfloat16): the whole
+    2688, 11.0 MB a step, one step an expert; counted as three tiles the
+    divisors under the budget would end at 896."""
     best = LANE
     for ik in range(LANE, inner + 1, LANE):
-        if inner % ik == 0 and 3 * h * ik * itemsize <= STEP_BYTES:
+        if inner % ik == 0 and matrices * h * ik * itemsize <= STEP_BYTES:
             best = ik
     return best
 
@@ -194,8 +222,9 @@ def _item_map(i, s, eid_ref, n_ref):
     return jnp.minimum(i, jnp.maximum(n_ref[0] - 1, 0))
 
 
-def _weight_specs(h, ik, steps):
-    """Block specs of the streamed ``wg``, ``wu`` and ``wd`` tiles."""
+def _weight_specs(h, ik, steps, gated: bool):
+    """Block specs of the streamed tiles: ``wg`` (where ``gated``), ``wu``
+    and ``wd``."""
     from jax.experimental import pallas as pl
 
     def tile_of(i, s, n_ref):
@@ -208,13 +237,12 @@ def _weight_specs(h, ik, steps):
     def down_map(i, s, eid_ref, n_ref):
         return eid_ref[i], tile_of(i, s, n_ref), 0
 
-    return [pl.BlockSpec((None, h, ik), gate_up_map),
-            pl.BlockSpec((None, h, ik), gate_up_map),
-            pl.BlockSpec((None, ik, h), down_map)]
+    return [pl.BlockSpec((None, h, ik), gate_up_map)] * (1 + gated) + [
+        pl.BlockSpec((None, ik, h), down_map)]
 
 
-def _inner_steps(h, inner, itemsize, tile):
-    ik = tile or inner_tile(h, inner, itemsize)
+def _inner_steps(h, inner, itemsize, tile, matrices):
+    ik = tile or inner_tile(h, inner, itemsize, matrices)
     if inner % ik:
         raise ValueError(f"tile {ik} does not divide inner = {inner}")
     return ik, inner // ik
@@ -222,18 +250,20 @@ def _inner_steps(h, inner, itemsize, tile):
 
 def pallas_expert_terms(u, eid, n_real, wt, wg, wu, wd, *, tile=None,
                         interpret=None):
-    """The kernel lowering for at most ``MAX_TOKENS`` tokens;
-    ``interpret=None`` auto-selects the Pallas interpreter off-TPU;
-    ``tile`` (of the inner width) defaults to :func:`inner_tile`."""
+    """The kernel lowering for at most ``MAX_TOKENS`` tokens; ``wg`` None
+    for experts of two matrices; ``interpret=None`` auto-selects the Pallas
+    interpreter off-TPU; ``tile`` (of the inner width) defaults to
+    :func:`inner_tile`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     if interpret is None:
         interpret = not _on_tpu()
     t, h = u.shape
-    items, _, inner = wg.shape
+    items, _, inner = wu.shape
     itemsize = u.dtype.itemsize
-    ik, steps = _inner_steps(h, inner, itemsize, tile)
+    weights = [w for w in (wg, wu, wd) if w is not None]
+    ik, steps = _inner_steps(h, inner, itemsize, tile, len(weights))
     rows = -(-t // ROW_GROUP) * ROW_GROUP       # whole sublane tiles
     u = jnp.pad(u, ((0, rows - t), (0, 0)))
     wt = jnp.pad(wt.astype(F32), ((0, 0), (0, rows - t)))[..., None]
@@ -245,7 +275,7 @@ def pallas_expert_terms(u, eid, n_real, wt, wg, wu, wd, *, tile=None,
     def weight_map(i, s, eid_ref, n_ref):
         return _item_map(i, s, eid_ref, n_ref), 0, 0
 
-    vmem = (2 * 3 * h * ik * itemsize               # the streamed tiles
+    vmem = (2 * len(weights) * h * ik * itemsize    # the streamed tiles
             + rows * h * (itemsize + 3 * 4)         # u, y twice, a product
             + 3 * rows * ik * 4 + 2 * rows * LANE * 4)
     y = pl.pallas_call(
@@ -256,7 +286,7 @@ def pallas_expert_terms(u, eid, n_real, wt, wg, wu, wd, *, tile=None,
             in_specs=[
                 pl.BlockSpec((rows, h), fixed, pipeline_mode=pl.Buffered(1)),
                 pl.BlockSpec((None, rows, 1), weight_map),
-                *_weight_specs(h, ik, steps),
+                *_weight_specs(h, ik, steps, wg is not None),
             ],
             out_specs=pl.BlockSpec((rows, h), fixed),
         ),
@@ -266,7 +296,7 @@ def pallas_expert_terms(u, eid, n_real, wt, wg, wu, wd, *, tile=None,
             vmem_limit_bytes=vmem + (8 << 20)),
         interpret=interpret,
         name="moe_decode_fwd",
-    )(eid, n_real, u, wt, wg, wu, wd)
+    )(eid, n_real, u, wt, *weights)
     return y[:t]
 
 
@@ -279,24 +309,26 @@ def pallas_grouped_terms(xs, eid, n_real, wt, wg, wu, wd, *, row_tile,
     first ``n_real`` items are real, and consecutive items of one expert
     keep its weight blocks.  Returns ``(items * row_tile, h)`` float32:
     each real item's rows through its expert, weighted (zero where the
-    weight is); rows past the real items are NOT WRITTEN."""
+    weight is); rows past the real items are NOT WRITTEN.  ``wg`` None for
+    experts of two matrices."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     if interpret is None:
         interpret = not _on_tpu()
     n, h = xs.shape
-    items, inner = eid.shape[0], wg.shape[-1]
+    items, inner = eid.shape[0], wu.shape[-1]
     if n != items * row_tile:
         raise ValueError(f"{n} rows are not {items} tiles of {row_tile}")
     itemsize = xs.dtype.itemsize
-    ik, steps = _inner_steps(h, inner, itemsize, tile)
+    weights = [w for w in (wg, wu, wd) if w is not None]
+    ik, steps = _inner_steps(h, inner, itemsize, tile, len(weights))
     eid, n_real = _work_list(eid, n_real)
 
     def rows_map(i, s, eid_ref, n_ref):
         return _item_map(i, s, eid_ref, n_ref), 0
 
-    vmem = (2 * 3 * h * ik * itemsize               # the streamed tiles
+    vmem = (2 * len(weights) * h * ik * itemsize    # the streamed tiles
             + 2 * row_tile * h * (itemsize + 2 * 4)  # rows in, terms out
             + 3 * row_tile * ik * 4 + 2 * row_tile * LANE * 4)
     return pl.pallas_call(
@@ -307,7 +339,7 @@ def pallas_grouped_terms(xs, eid, n_real, wt, wg, wu, wd, *, row_tile,
             in_specs=[
                 pl.BlockSpec((row_tile, h), rows_map),
                 pl.BlockSpec((row_tile, 1), rows_map),
-                *_weight_specs(h, ik, steps),
+                *_weight_specs(h, ik, steps, wg is not None),
             ],
             out_specs=pl.BlockSpec((row_tile, h), rows_map),
         ),
@@ -317,7 +349,7 @@ def pallas_grouped_terms(xs, eid, n_real, wt, wg, wu, wd, *, row_tile,
             vmem_limit_bytes=vmem + (8 << 20)),
         interpret=interpret,
         name="moe_grouped_fwd",
-    )(eid, n_real, xs, wt.astype(F32)[:, None], wg, wu, wd)
+    )(eid, n_real, xs, wt.astype(F32)[:, None], *weights)
 
 
 class Tiles(NamedTuple):
@@ -334,7 +366,8 @@ class Tiles(NamedTuple):
 
 def fitted_tile(u, experts) -> Tiles | None:
     """The kernel's tiles for ``u (T, h)`` over the stacked ``experts``
-    (arrays or their shapes), ``None`` where the XLA form runs: a kernel on
+    (arrays or their shapes: ``{"wg", "wu", "wd"}``, or ``{"wu", "wd"}``
+    for experts without a gate), ``None`` where the XLA form runs: a kernel on
     a TPU backend with no mesh in scope, one 2- or 4-byte float type for
     tokens and weights, ``h`` and the inner width multiples of ``LANE`` and
     at most ``MAX_GROUPED_TOKENS`` tokens — ``moe_decode_fwd`` up to
@@ -342,15 +375,14 @@ def fitted_tile(u, experts) -> Tiles | None:
     ``ROW_TILE`` above."""
     dtype = jnp.dtype(u.dtype)
     t, h = u.shape
-    inner = experts["wg"].shape[-1]
+    inner = experts["wu"].shape[-1]
     kernel = (_on_tpu() and not _mesh_in_scope()
-              and all(jnp.dtype(experts[k].dtype) == dtype
-                      for k in ("wg", "wu", "wd"))
+              and all(jnp.dtype(w.dtype) == dtype for w in experts.values())
               and jnp.issubdtype(dtype, jnp.floating)
               and dtype.itemsize in (2, 4)
               and h % LANE == 0 and inner % LANE == 0
               and t <= MAX_GROUPED_TOKENS)
     if not kernel:
         return None
-    return Tiles(inner_tile(h, inner, dtype.itemsize),
+    return Tiles(inner_tile(h, inner, dtype.itemsize, len(experts)),
                  None if t <= MAX_TOKENS else ROW_TILE)

@@ -2,15 +2,19 @@
 right-padded rows for prefill and a one-token update of every slot's carry
 for decode, with the depthwise causal convolution that feeds both.
 
-Per head ``h`` (``x_t (D,)``, ``B_t, C_t (N,)`` shared by all heads, one
-group; ``dt_t > 0`` the step, ``a_h < 0``), everything in float32::
+Per head ``h`` of group ``g = h // (H / G)`` (``x_t (D,)``, ``B_t,g, C_t,g
+(N,)`` shared by the ``H / G`` heads of a group; ``dt_t > 0`` the step,
+``a_h < 0``), everything in float32::
 
-    S_t = exp(dt_t a_h) S_{t-1} + dt_t x_t (x) B_t         # (D, N), S_{-1} = 0
-    y_t = S_t C_t
+    S_t = exp(dt_t a_h) S_{t-1} + dt_t x_t (x) B_t,g       # (D, N), S_{-1} = 0
+    y_t = S_t C_t,g
 
-(the caller adds the skip ``D_h x_t``, the gate and the norm).
+(the caller adds the skip ``D_h x_t``, the gate and the norm).  ``b`` and
+``c`` come as ``(..., G, N)``, or as ``(..., N)`` where there is ONE group
+(Granite's ``mamba_n_groups`` 1: no axis of one is made, so that model's
+programs are the ops they always were; Nemotron-H has eight).
 
-:func:`ssd_scan` — ``x (R, P, H, D)``, ``dt (R, P, H)``, ``b, c (R, P,
+:func:`ssd_scan` — ``x (R, P, H, D)``, ``dt (R, P, H)``, ``b, c (R, P, [G,]
 N)`` over rows of ``lengths (R,)`` real leading tokens: ``(y (R, P, H, D)
 float32, S (R, H, D, N) float32)``, the carry AT EACH ROW'S TRUE LENGTH.
 ``dt`` is zeroed at and past ``lengths`` (decay 1, no input), so padding
@@ -22,7 +26,7 @@ sum of ``dt a`` inside a chunk — a sum of non-positive numbers, kept in log
 space and float32, so every exponent taken is of a non-positive number —
 there are FOUR products a chunk:
 
-1. ``G = C B^T`` ``(q, q)``, shared by the heads;
+1. ``G = C B^T`` ``(q, q)`` a group, shared by the group's heads;
 2. ``y_diag = (G * exp(cum_i - cum_j) [i >= j]) (dt x)`` per head: what the
    chunk's own tokens hand each other;
 3. ``states = B^T (exp(cum_last - cum_j) dt x)`` per head ``(D, N)``: what
@@ -40,8 +44,8 @@ written once: ``(y (S, H, D) float32, state)``.  No matrix unit: the update
 and the read-out are float32 elementwise passes over the carry, so the
 carry is never rounded.
 
-Both are plain XLA in this PR and say so under ``"ssd_prefill"`` /
-``"ssd_step"`` (``ops/lowering.py``), where a kernel would say ``"pallas"``.
+Both are plain XLA and say so under ``"ssd_prefill"`` / ``"ssd_step"``
+(``ops/lowering.py``), where a kernel would say ``"pallas"``.
 
 :func:`causal_conv` / :func:`conv_tail` / :func:`conv_step` — the depthwise
 convolution of width ``K`` over ``u (R, P, C)`` with zeros before a row's
@@ -50,7 +54,8 @@ row is shorter) as the decode's tail, and the one-token form over ``(tail,
 u_t)``.  ``K`` multiply-adds a channel, summed and returned in float32 (the
 caller rounds it once); ``bias`` None for a convolution without one
 (``models/lfm2.py``'s short convolution: three taps, no bias, no
-activation; Granite's has four taps, a bias and a ``silu``).
+activation; the Mamba-2 block's, ``models/state.py``, has four taps, a
+bias and a ``silu``).
 """
 
 from __future__ import annotations
@@ -75,6 +80,11 @@ def ssd_scan(x, dt, a, b, c, lengths, chunk: int):
     r, p, h, d = x.shape
     n = b.shape[-1]
     dtype = x.dtype
+    # a group axis: the heads are (group, head of the group) below and the
+    # products carry both letters; none: one letter, as ever
+    groups = b.shape[2:-1]
+    hs, gs = ("ge", "g") if groups else ("h", "")
+    heads = groups + (h // groups[0],) if groups else (h,)
     real = jnp.arange(p)[None, :] < lengths[:, None]
     dt = jnp.where(real[..., None], dt.astype(F32), 0.0)
     q = min(chunk, p)
@@ -86,25 +96,33 @@ def ssd_scan(x, dt, a, b, c, lengths, chunk: int):
     nc = (p + pad) // q
     x = x.reshape(r, nc, q, h, d)
     dt = dt.reshape(r, nc, q, h)
-    b, c = b.reshape(r, nc, q, n), c.reshape(r, nc, q, n)
+    b, c = (v.reshape((r, nc, q) + groups + (n,)) for v in (b, c))
     cum = jnp.cumsum(dt * a.astype(F32), axis=2)           # (r, nc, q, h) <= 0
     xdt = x.astype(F32) * dt[..., None]
+
+    def by_group(v, axis):
+        """``v``'s head axis as the products' head letters."""
+        return v.reshape(v.shape[:axis] + heads + v.shape[axis + 1:])
 
     # inside a chunk: token i takes from j <= i what has decayed since
     lower = jnp.tril(jnp.ones((q, q), bool))
     by_head = cum.swapaxes(2, 3)                             # (r, nc, h, q)
     seg = by_head[..., :, None] - by_head[..., None, :]     # cum_i - cum_j
     decay = jnp.where(lower, jnp.exp(jnp.where(lower, seg, 0.0)), 0.0)
-    g = jnp.einsum("rcin,rcjn->rcij", c, b, preferred_element_type=F32)
-    y = jnp.einsum("rchij,rcjhd->rcihd",
-                   (g[:, :, None] * decay).astype(dtype), xdt.astype(dtype),
+    g = jnp.einsum(f"rci{gs}n,rcj{gs}n->rc{gs}ij", c, b,
+                   preferred_element_type=F32)
+    y = jnp.einsum(f"rc{hs}ij,rcj{hs}d->rci{hs}d",
+                   (jnp.expand_dims(g, -3) * by_group(decay, 2)).astype(
+                       dtype),
+                   by_group(xdt, 3).astype(dtype),
                    preferred_element_type=F32)
 
     # what each chunk adds to the carry, and the carry before each chunk
     to_end = jnp.exp(cum[:, :, -1:, :] - cum)
-    states = jnp.einsum("rcjn,rcjhd->rchdn", b,
-                        (xdt * to_end[..., None]).astype(dtype),
+    states = jnp.einsum(f"rcj{gs}n,rcj{hs}d->rc{hs}dn", b,
+                        by_group(xdt * to_end[..., None], 3).astype(dtype),
                         preferred_element_type=F32)
+    states = states.reshape(r, nc, h, d, n)
     whole = jnp.exp(cum[:, :, -1, :])                        # (r, nc, h)
 
     def carry_on(s, chunk_of):
@@ -114,20 +132,32 @@ def ssd_scan(x, dt, a, b, c, lengths, chunk: int):
     final, before = jax.lax.scan(
         carry_on, jnp.zeros((r, h, d, n), F32),
         (states.swapaxes(0, 1), whole.swapaxes(0, 1)))
-    y_off = jnp.einsum("rcin,crhdn->rcihd", c, before.astype(dtype),
+    y_off = jnp.einsum(f"rci{gs}n,cr{hs}dn->rci{hs}d", c,
+                       by_group(before, 2).astype(dtype),
                        preferred_element_type=F32)
-    y = y + y_off * jnp.exp(cum)[..., None]
+    y = y.reshape(r, nc, q, h, d) + y_off.reshape(
+        r, nc, q, h, d) * jnp.exp(cum)[..., None]
     return y.reshape(r, nc * q, h, d)[:, :p], final
+
+
+def _per_head(v, heads: int):
+    """``b`` or ``c`` of one token a slot, ``(S, [G,] N)``, against the
+    carry ``(S, H, D, N)``: a group's row under each of its heads."""
+    if v.ndim == 3:
+        v = jnp.repeat(v, heads // v.shape[1], axis=1)[:, :, None, :]
+    else:
+        v = v[:, None, None, :]
+    return v.astype(F32)
 
 
 def ssd_step(state, x, dt, a, b, c):
     note("ssd_step", "xla")
     dt = dt.astype(F32)
+    heads = state.shape[1]
     keep = jnp.exp(dt * a.astype(F32))                       # (S, H)
-    add = (x.astype(F32) * dt[..., None])[..., None] * b.astype(
-        F32)[:, None, None, :]
+    add = (x.astype(F32) * dt[..., None])[..., None] * _per_head(b, heads)
     state = state * keep[..., None, None] + add
-    y = jnp.sum(state * c.astype(F32)[:, None, None, :], axis=-1)
+    y = jnp.sum(state * _per_head(c, heads), axis=-1)
     return y, state
 
 

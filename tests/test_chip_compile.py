@@ -455,17 +455,28 @@ def test_gqa_decode_kernel_compiles_for_v5e(shape, no_persistent_cache,
                                                            jnp.int32))
 
 
-@pytest.mark.parametrize("tokens,h,inner,held", [
-    (64, 5120, 1536, 40), (32, 6144, 2048, 16), (64, 2048, 1024, 16)],
-    ids=["dsv2", "longcat", "trinity"])
+def _expert_shapes(shape, held, h, inner, gated):
+    """The stacked experts' abstract matrices: gate (None without one), up,
+    down."""
+    bf16 = jnp.bfloat16
+    return [shape((held, h, inner), bf16) if gated else None,
+            shape((held, h, inner), bf16), shape((held, inner, h), bf16)]
+
+
+@pytest.mark.parametrize("tokens,h,inner,held,gated", [
+    (64, 5120, 1536, 40, True), (32, 6144, 2048, 16, True),
+    (64, 2048, 1024, 16, True), (64, 1024, 2688, 128, False)],
+    ids=["dsv2", "longcat", "trinity", "nemotron3-two-matrix"])
 def test_moe_decode_kernel_compiles_for_v5e(shape, no_persistent_cache,
-                                            tokens, h, inner, held):
+                                            tokens, h, inner, held, gated):
     """The held experts' decode product (``ops/moe_decode.py``,
-    ``moe_decode_fwd``) at a decode call of the three expert cells, at the
+    ``moe_decode_fwd``) at a decode call of the expert cells, at the
     published widths and with the inner tile the chip path takes: three
-    streamed tiles a step, double-buffered, under the ``vmem_limit_bytes``
-    the call states.  Outside the kernel the call keeps only the padded
-    tokens, the listed experts and their routing weights."""
+    streamed tiles a step (two for Nemotron-H's experts without a gate, the
+    whole inner width of 2688 a step), double-buffered, under the
+    ``vmem_limit_bytes`` the call states.  Outside the kernel the call
+    keeps only the padded tokens, the listed experts and their routing
+    weights."""
     from progen_tpu.ops.moe_decode import pallas_expert_terms
 
     bf16 = jnp.bfloat16
@@ -474,19 +485,20 @@ def test_moe_decode_kernel_compiles_for_v5e(shape, no_persistent_cache,
     compiled = fn.lower(
         shape((tokens, h), bf16), shape((held,), jnp.int32),
         shape((), jnp.int32), shape((held, tokens), jnp.float32),
-        shape((held, h, inner), bf16), shape((held, h, inner), bf16),
-        shape((held, inner, h), bf16)).compile()
+        *_expert_shapes(shape, held, h, inner, gated)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * tokens * h * 4
 
 
-@pytest.mark.parametrize("tokens,k,h,inner,held", [
-    (256, 8, 2048, 768, 128), (512, 8, 2048, 768, 128),
-    (1024, 8, 2048, 768, 128), (1024, 12, 6144, 2048, 16)],
+@pytest.mark.parametrize("tokens,k,h,inner,held,gated", [
+    (256, 8, 2048, 768, 128, True), (512, 8, 2048, 768, 128, True),
+    (1024, 8, 2048, 768, 128, True), (1024, 12, 6144, 2048, 16, True),
+    (1024, 22, 1024, 2688, 128, False)],
     ids=["sdar-admit-128", "sdar-block-step", "sdar-admit-256",
-         "longcat-admit-512"])
+         "longcat-admit-512", "nemotron3-admit-256-two-matrix"])
 def test_moe_grouped_kernel_compiles_for_v5e(shape, no_persistent_cache,
-                                             tokens, k, h, inner, held):
+                                             tokens, k, h, inner, held,
+                                             gated):
     """The held experts' product over an expert's OWN rows
     (``ops/moe_decode.py``, ``moe_grouped_fwd``) at SDAR's block step (64
     slots x 8 token rows since PR 48: the pending block in front of the
@@ -505,8 +517,7 @@ def test_moe_grouped_kernel_compiles_for_v5e(shape, no_persistent_cache,
     compiled = fn.lower(
         shape((items * rt, h), bf16), shape((items,), jnp.int32),
         shape((), jnp.int32), shape((items * rt,), jnp.float32),
-        shape((held, h, inner), bf16), shape((held, h, inner), bf16),
-        shape((held, inner, h), bf16)).compile()
+        *_expert_shapes(shape, held, h, inner, gated)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -773,3 +784,76 @@ def test_lfm2_programs_compile_for_the_chip_and_fit_it(
         assert total < 12.8e9, m
         assert "moe_decode_fwd" in text and "gqa_decode_fwd" in text
         assert not _buffers_of(text, "f32[128,4,8,3072]")
+
+
+# ---- Nemotron-H's whole programs at published widths ----
+
+
+@pytest.fixture(scope="module")
+def nemotron_engine():
+    """The engine of ``serve-nemotron3-longgen-backlog`` over ABSTRACT
+    weights (its 64 slots' state is real, on the host: 1.6 GB of zeros)."""
+    from progen_tpu.decode.engine import ServingEngine
+    from progen_tpu.models import nemotron_h
+
+    c = nemotron_h.NemotronHConfig(
+        num_hidden_layers=11, vocab_size=32768, experts_held=128,
+        hybrid_override_pattern=nemotron_h.NemotronHConfig(
+        ).hybrid_override_pattern[:11])
+    policy = nemotron_h.bf16_policy()
+    params = jax.eval_shape(lambda k: nemotron_h.init_params(c, k, policy),
+                            jax.random.key(0))
+    return ServingEngine(c, params, policy=policy, num_slots=64,
+                         chunk_size=32, max_len=3072)
+
+
+@pytest.mark.parametrize("program", ["chunk", "admit-1024"])
+def test_nemotron_programs_compile_for_the_chip_and_fit_it(
+        shape, nemotron_engine, program, no_persistent_cache, monkeypatch):
+    """Stage 0 of 8 (``MEMEMEM*EME``: 5 Mamba-2 mixers of eight groups, 5
+    latent expert layers with 128 of 512 two-matrix experts, 1 attention
+    layer), a quarter of the vocabulary, 64 slots of float32 carries, tails
+    and grown keys: the chunk program (32 steps of every slot: 64 tokens a
+    call through ``moe_decode_fwd`` in its two-matrix form) and the
+    admission of 4 rows at the 1024 bucket (4,096 tokens through
+    ``ragged_dot``, two products a window), as the chip traces them.
+    Arguments, results and temporaries together stay under the chip's 16
+    GiB: the engine's programs do not donate their state, so it is there
+    twice."""
+    from progen_tpu.decode import sampler
+    from progen_tpu.ops import gqa, lowering, moe_decode, row_write
+
+    for module in (row_write, gqa, moe_decode, sampler):
+        monkeypatch.setattr(module, "_on_tpu", lambda: True)
+    monkeypatch.setattr(lowering, "on_tpu", lambda: True)
+    eng = nemotron_engine
+
+    def placed(tree):
+        return jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+
+    params, state = placed(eng._params), placed(eng.state)
+    s, rows, lay = eng.num_slots, eng.admit_rows, eng._layout
+    assert rows == 4
+    if program == "chunk":
+        compiled = jax.jit(lambda *a: eng._decode_chunk_impl(*a)).lower(
+            params, state, *placed(lay.chunk_operands())).compile()
+    else:
+        prefill = [shape((rows, 1024), jnp.int32), shape((rows,), jnp.int32),
+                   shape((rows,), jnp.int32), shape((rows,), jnp.uint32),
+                   shape((rows,), jnp.int32), shape((rows,), jnp.float32),
+                   shape(eng._lmask_shape(rows), jnp.bool_)]
+        compiled = jax.jit(lambda *a: eng._admit_impl(*a)).lower(
+            params, state, shape((s,), jnp.int32), shape((s,), jnp.bool_),
+            *prefill, *placed(lay.write_tables(rows))).compile()
+    m = compiled.memory_analysis()
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
+    assert 9.29e9 < weights < 9.31e9 and 1.5e9 < held < 1.7e9, (weights,
+                                                                 held)
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert weights + 2 * held <= total < 15.5e9, m
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if program == "chunk":
+        assert "moe_decode_fwd" in text and "gqa_decode_fwd" in text

@@ -223,7 +223,7 @@ def test_a_slots_state_does_not_depend_on_max_len_and_its_keys_do():
     assert family.blocks["l2"].window is None
     assert family.blocks["l2"].scale == TINY.attention_multiplier
     # the published widths: 2.1 MB of carry a slot and state layer
-    whole = gh.StateBlock(gh.GraniteHybridConfig())
+    whole = gh.state_block(gh.GraniteHybridConfig())
     shapes = jax.eval_shape(lambda: whole.init_cache(1, 2560, jnp.bfloat16))
     assert shapes["ssm"].shape == (1, 64, 64, 128)
     assert shapes["conv"].shape == (1, 3, 4352)

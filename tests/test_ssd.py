@@ -182,3 +182,82 @@ def test_conv_steps_continue_the_prefills_convolution():
         out, tail = ssd.conv_step(tail, new, w, bias)
         np.testing.assert_allclose(
             out, want[jnp.arange(3), lengths + j], atol=1e-5)
+
+
+# ------------------------------------------------- B and C in groups
+
+GH, G = 8, 4        # eight heads over four B/C groups: two heads a group
+
+
+def _grouped_inputs(rows, p, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    x = jax.random.normal(ks[0], (rows, p, GH, D))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (rows, p, GH)) - 1.0)
+    a = -jnp.exp(jax.random.uniform(ks[2], (GH,), minval=-1.0, maxval=2.0))
+    b = jax.random.normal(ks[3], (rows, p, G, N))
+    c = jax.random.normal(ks[4], (rows, p, G, N))
+    return x, dt, a, b, c
+
+
+def _sequential_grouped(x, dt, a, b, c, length):
+    """One row, token by token: head ``h`` reads group ``h // (GH / G)``."""
+    x, dt, a, b, c = (np.asarray(v, np.float64) for v in (x, dt, a, b, c))
+    state = np.zeros((GH, D, N))
+    ys = np.zeros((x.shape[0], GH, D))
+    for t in range(length):
+        for h in range(GH):
+            g = h // (GH // G)
+            state[h] = (state[h] * np.exp(dt[t, h] * a[h])
+                        + dt[t, h] * np.outer(x[t, h], b[t, g]))
+            ys[t, h] = state[h] @ c[t, g]
+    return ys, state
+
+
+@pytest.mark.parametrize("lengths,bucket", [
+    ((5, 8), 8), ((9, 16), 16), ((0, 21), 24), ((1, 23), 32)],
+    ids=["under-a-chunk", "whole-chunks", "an-empty-row", "ragged"])
+def test_grouped_scan_step_and_recurrence_agree(lengths, bucket):
+    """``G`` = 4: the chunked scan, the scan to ``n - 3`` then three
+    one-token steps, and the recurrence written out head by head give one
+    state and one output, right-padded rows included."""
+    x, dt, a, b, c = _grouped_inputs(2, bucket, seed=bucket)
+    n = jnp.asarray(lengths, jnp.int32)
+    y, state = jax.jit(ssd.ssd_scan, static_argnums=6)(x, dt, a, b, c, n,
+                                                       CHUNK)
+    assert y.shape == (2, bucket, GH, D) and state.shape == (2, GH, D, N)
+    for i, length in enumerate(lengths):
+        want_y, want_state = _sequential_grouped(x[i], dt[i], a, b[i], c[i],
+                                                 length)
+        np.testing.assert_allclose(y[i, :length], want_y[:length],
+                                   atol=2e-5, rtol=1e-5)
+        np.testing.assert_allclose(state[i], want_state, atol=2e-5,
+                                   rtol=1e-5)
+    back = 3
+    if min(lengths) < back:
+        return
+    _, stepped = ssd.ssd_scan(x, dt, a, b, c, n - back, CHUNK)
+    rows = jnp.arange(2)
+    for j in range(back):
+        t = n - back + j
+        y_t, stepped = ssd.ssd_step(stepped, x[rows, t], dt[rows, t], a,
+                                    b[rows, t], c[rows, t])
+        np.testing.assert_allclose(y_t, y[rows, t], atol=2e-5, rtol=1e-5)
+    np.testing.assert_allclose(stepped, state, atol=2e-5, rtol=1e-5)
+
+
+def test_one_group_with_and_without_its_axis_is_one_result():
+    """``b, c (..., N)`` is ``(..., 1, N)``: the form without the axis
+    (Granite's, whose program text is pinned) computes what the grouped
+    form computes at ``G`` = 1."""
+    x, dt, a, b, c = _inputs(2, 19, seed=5)
+    lengths = jnp.array([19, 11], jnp.int32)
+    y, state = ssd.ssd_scan(x, dt, a, b, c, lengths, CHUNK)
+    y1, state1 = ssd.ssd_scan(x, dt, a, b[:, :, None], c[:, :, None],
+                              lengths, CHUNK)
+    np.testing.assert_allclose(y1, y, atol=1e-6, rtol=1e-6)
+    np.testing.assert_allclose(state1, state, atol=1e-6, rtol=1e-6)
+    s, s1 = ssd.ssd_step(state, x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0]), \
+        ssd.ssd_step(state, x[:, 0], dt[:, 0], a, b[:, 0, None],
+                     c[:, 0, None])
+    np.testing.assert_array_equal(s[0], s1[0])
+    np.testing.assert_array_equal(s[1], s1[1])
