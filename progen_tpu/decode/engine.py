@@ -891,7 +891,7 @@ class ServingEngine:
         ``"pallas"`` / ``"xla"``), a grouped-query attention's prefill core
         and one-query decode core (``"gqa_prefill"``, ``"gqa_decode"``: the
         same two), the core of a step of B queries a slot
-        (``"gqa_block_decode"``: ``"xla"``) and the held experts'
+        (``"gqa_block_decode"``: the same two) and the held experts'
         product (``"moe_experts"``: ``"pallas"`` / ``"pallas_grouped"`` /
         ``"xla"``, stated per program: it is in both, and an engine's
         admission programs, one a bucket, may differ — joined by ``+``) and

@@ -194,7 +194,9 @@ def decode_stats(blocks: dict, caches, pos, live, block_form=False) -> dict:
     ``blocks``: its live rows, their contexts, the part of them a window
     keeps, and the cache rows ONE block of each kind reads under the
     lowering that ran (``block_form``: the step is
-    :meth:`KVBlock.decode_block`'s, whose core has the XLA form alone)."""
+    :meth:`KVBlock.decode_block`'s, ``pos + 1`` the rows committed before
+    it, and the core ``ops/gqa.py:block_decode_attention``, which has a
+    rule of its own: ``gqa.block_decode_lowering``)."""
     seen = jnp.where(live, pos + 1, 0)
     stats = {"attn.decode_rows": jnp.sum(live).astype(F32),
              "attn.context_tokens": jnp.sum(seen).astype(F32),
@@ -209,7 +211,8 @@ def decode_stats(blocks: dict, caches, pos, live, block_form=False) -> dict:
             continue
         k, v = caches[name]["k"], caches[name]["v"]
         # the step's query is in the caches' dtype (``driver.Family``)
-        lowering = "xla" if block_form else gqa.decode_lowering(k.dtype, k, v)
+        lowering = (gqa.block_decode_lowering if block_form
+                    else gqa.decode_lowering)(k.dtype, k, v)
         counts = None if lowering == "xla" else kv[name].place(
             pos, k.shape[2])[1]
         stats[f"attn.{kind}_rows_read"] = (

@@ -4,8 +4,9 @@ head ``h // (H / KV)``), under one of the TWO MASKS a prefill takes — the
 causal mask with an optional per-token sliding window, or (``block = B``,
 no window) the BLOCK mask of a family that generates by diffusion over
 blocks: position ``i`` sees ``j`` iff ``j // B <= i // B``, causal across
-blocks of ``B`` and open inside one — and the two cores of a decode step:
-one query a slot, or a block of ``B`` queries that see each other.
+blocks of ``B`` and open inside one — and the two cores of a decode step,
+each a kernel and an XLA form: one query a slot, or a block of ``B``
+queries that see each other.
 
 :func:`prefill_attention` — ``q (R, P, H, d)`` against ``k, v (R, KV, P,
 d)``, ``lengths (R,)`` leading positions of each row real: position ``i``
@@ -119,10 +120,10 @@ lowering reads (the counters ``attn.window_rows_read`` /
 ``attn.full_rows_read``): whole key tiles up to each slot's count under
 the kernel, ``S * T`` under the XLA form.
 
-**The block mask** changes one comparison in each lowering and nothing
-else: a query block, and a tile, start on a block's edge (``B`` divides
-``QUERY_BLOCK`` and, a power of two, the tiles), so the keys a block of
-query rows can see end where the causal mask's do, the visit rule
+**The block mask** changes one comparison in each prefill lowering and
+nothing else: a query block, and a tile, start on a block's edge (``B``
+divides ``QUERY_BLOCK`` and, a power of two, the tiles), so the keys a block
+of query rows can see end where the causal mask's do, the visit rule
 (:func:`key_tiles`) stands, and only the tiles the diagonal crosses pay for
 the mask — there ``gap >= (row % B) - (B - 1)`` in place of ``gap >= 0``.
 ``lengths`` are whole blocks (a real query then sees real keys only) and
@@ -132,14 +133,34 @@ program they traced before the mask existed.
 :func:`block_decode_attention` — ``B`` queries a slot, ``q (S, B, H, d)``,
 against the first ``counts (S,)`` rows of the cache AND the block's own B
 keys and values, handed in beside the cache and not written to it: one
-softmax over both (two score tensors under one running maximum, so the
-cache is never concatenated).  The same call takes TWO blocks a slot (``2B``
-tokens, ``lead (S,)``): the block in front, whose keys are not in the cache
-yet, beside the block after it, which sees the front block's keys where
-``lead`` (and finds them among the cache's rows where not); the queries may
-be the second block's alone.  Plain XLA like
-:func:`xla_decode_attention`, the whole cache read; noted as
-``"gqa_block_decode"``.
+softmax over both, the cache never concatenated.  The same call takes TWO
+blocks a slot (``2B`` tokens, ``lead (S,)``): the block in front, whose keys
+are not in the cache yet, beside the block after it, which sees the front
+block's keys where ``lead`` (and finds them among the cache's rows where
+not); the queries may be the second block's alone.  ``counts`` may be 0.
+Two lowerings keep that contract, chosen by the one-query core's rule and
+nothing else (:func:`block_decode_lowering`):
+
+* **Pallas kernel** ``gqa_block_decode_fwd`` — ``gqa_decode_fwd``'s grid,
+  tile and index maps with ``G * m`` query rows a key/value head (all ``m``
+  queries of the call in ONE pass: a tile's scores live in VMEM, so the
+  float32 score tensor that makes the XLA form split its queries does not
+  exist) and the forward's own ``n`` keys as one small tile at the slot's
+  FIRST grid step, under the two-block mask as an iota comparison with
+  ``lead`` scalar-prefetched beside ``counts``.  A query's own block is
+  always there, so the running maximum is finite before any cache tile
+  and a slot with nothing committed visits none; an idle step points at
+  the next slot's first tile whether that slot will visit it or not.  The
+  two kernels share the cache tile's update (``_cache_tile``).  SDAR's
+  cell: 64 x 2,560 rows, 64 and 32 query rows a head.
+* **XLA** (:func:`xla_block_decode_attention`) — everywhere else: two
+  score tensors under one running maximum, a pass over the WHOLE cache a
+  block of queries (PERF.md section 6, PR 48, has why two passes of
+  ``G * B`` rows beat one of twice as many there).
+
+Which one a traced call took is noted under ``"gqa_block_decode"``, and
+:func:`rows_visited` counts its rows as the one-query core's (a count of 0
+is no tile).
 """
 
 from __future__ import annotations
@@ -552,27 +573,22 @@ def xla_decode_attention(q, k, v, counts, scale):
     return out.astype(q.dtype).reshape(s, heads * d)
 
 
-def _decode_kernel(cnt_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                   acc_ref, *, scale, bk):
+def _cache_tile(count, k0, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *,
+                scale, bk):
+    """One grid step of a decode kernel over a slot's cache: the key tile
+    at rows ``k0 .. k0 + bk`` under the online softmax, for every
+    key/value head (``q_ref (1, KV, rows, d)``: a head's query rows one
+    operand)."""
     from jax.experimental import pallas as pl
 
-    count = cnt_ref[pl.program_id(0)]
-    ki = pl.program_id(1)
-    k0 = ki * bk
-
-    @pl.when(ki == 0)
-    def _():
-        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, F32)
-        l_ref[...] = jnp.zeros(l_ref.shape, F32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
-
     def head(h, crossed):
-        s = _dot_t(q_ref[0, h], k_ref[0, h]) * scale           # (G, bk)
+        s = _dot_t(q_ref[0, h], k_ref[0, h]) * scale           # (rows, bk)
         if crossed:
             cols = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(cols < count, s, -jnp.inf)
-        # key tile 0 is always visited first and holds the slot's row 0
-        # (counts >= 1), so ``m_next`` is finite from the first tile on
+        # the running maximum is finite before any cache tile past the
+        # first: key tile 0 holds the slot's row 0, or the block kernel's
+        # own keys came first
         m_prev = m_ref[h]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_next)
@@ -593,6 +609,26 @@ def _decode_kernel(cnt_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     pl.when(seen & crossed)(functools.partial(tile, True))
     pl.when(seen & jnp.logical_not(crossed))(functools.partial(tile, False))
 
+
+def _decode_kernel(cnt_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                   acc_ref, *, scale, bk):
+    from jax.experimental import pallas as pl
+
+    count = cnt_ref[pl.program_id(0)]
+    ki = pl.program_id(1)
+    k0 = ki * bk
+
+    @pl.when(ki == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, F32)
+        l_ref[...] = jnp.zeros(l_ref.shape, F32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
+
+    # key tile 0 is always visited first and holds the slot's row 0
+    # (counts >= 1), so the maximum is finite from the first tile on
+    _cache_tile(count, k0, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+                scale=scale, bk=bk)
+
     @pl.when(ki == pl.num_programs(1) - 1)
     def _():
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
@@ -610,19 +646,23 @@ def fitted_decode_tile(t: int) -> int:
         DECODE_TILE if t >= 4 * DECODE_TILE else MIN_TILE, t)
 
 
-def pallas_decode_attention(q, k, v, counts, scale, *, block_k=None,
-                            interpret=None):
-    """The kernel lowering of :func:`decode_attention`.  ``interpret=None``
-    auto-selects the Pallas interpreter off-TPU; ``block_k`` defaults to
-    :func:`fitted_decode_tile`."""
-    t = k.shape[2]
+def _decode_options(t: int, block_k, interpret) -> dict:
+    """``bk`` and ``interpret`` of a decode kernel over caches of ``t``
+    rows: ``block_k`` defaults to :func:`fitted_decode_tile`,
+    ``interpret=None`` auto-selects the Pallas interpreter off-TPU."""
     bk = block_k or fitted_decode_tile(t)
     if t % bk:
         raise ValueError(f"tile {bk} does not divide T = {t}")
-    if interpret is None:
-        interpret = not _on_tpu()
+    return {"bk": bk,
+            "interpret": not _on_tpu() if interpret is None else interpret}
+
+
+def pallas_decode_attention(q, k, v, counts, scale, *, block_k=None,
+                            interpret=None):
+    """The kernel lowering of :func:`decode_attention`
+    (:func:`_decode_options` has the two options)."""
     return _decode_call(q, k, v, counts.astype(jnp.int32), scale=scale,
-                        bk=bk, interpret=interpret)
+                        **_decode_options(k.shape[2], block_k, interpret))
 
 
 # jitted as ``_flash_call`` is: a model's rings, and its grown caches, share
@@ -709,6 +749,15 @@ def rows_visited(k, counts, lowering: str):
 # ------------------------------------------------------- a block of queries
 
 
+def block_decode_lowering(q_dtype, k, v) -> str:
+    """``"pallas"`` or ``"xla"``: what :func:`block_decode_attention` takes
+    for queries of ``q_dtype`` over caches ``k`` and ``v`` (arrays or their
+    shapes), traced here and now: the one-query core's rule — the two
+    kernels differ in the query rows a head, not in what they ask of the
+    backend, the mesh, the dtypes or the cache's shape."""
+    return decode_lowering(q_dtype, k, v)
+
+
 def block_decode_attention(q, k, v, k_new, v_new, counts, scale, lead=None):
     """``n`` tokens a slot against the first ``counts (S,)`` rows of ``k, v
     (S, KV, T, d)`` AND the forward's own ``k_new, v_new (S, KV, n, d)``:
@@ -723,11 +772,147 @@ def block_decode_attention(q, k, v, k_new, v_new, counts, scale, lead=None):
     nothing committed before it): a query's own block is always there.
     Nothing is written: whether a block's keys enter the cache is the
     caller's (``ops/row_write.py:write_row_blocks``).  ``(S, m, H * d)`` in
-    ``q``'s dtype.  Plain XLA, as :func:`xla_decode_attention`: the whole
-    cache is read (:func:`rows_visited` under ``"xla"``), scores ``(S, KV,
-    G * m, T)`` in float32; noted as ``"gqa_block_decode"``
-    (``ops/lowering.py``)."""
-    note("gqa_block_decode", "xla")
+    ``q``'s dtype.  The lowering is chosen as the module docstring says and
+    noted as ``"gqa_block_decode"`` (``ops/lowering.py``)."""
+    lowering = block_decode_lowering(q.dtype, k, v)
+    note("gqa_block_decode", lowering)
+    if lowering == "xla":
+        return xla_block_decode_attention(q, k, v, k_new, v_new, counts,
+                                          scale, lead)
+    return pallas_block_decode_attention(q, k, v, k_new, v_new, counts,
+                                         scale, lead)
+
+
+def _block_decode_kernel(cnt_ref, lead_ref, q_ref, k_ref, v_ref, kn_ref,
+                         vn_ref, o_ref, m_ref, l_ref, acc_ref, *, scale, bk,
+                         second_from):
+    """:func:`_decode_kernel` with ``G * m`` query rows a key/value head —
+    token-major, row ``j * G + g`` the ``j``-th query's — and the forward's
+    own ``n`` keys as one small tile BEFORE the cache's: a query sees its
+    own block there, so the running maximum is finite from the first step
+    on and a slot with nothing committed visits no cache tile at all.
+    ``second_from``: the first query row of the second of two blocks (None:
+    the ``n`` keys are one block, and there is no mask among them)."""
+    from jax.experimental import pallas as pl
+
+    si, ki = pl.program_id(0), pl.program_id(1)
+    count = cnt_ref[si]
+
+    @pl.when(ki == 0)
+    def _():
+        rows, n = q_ref.shape[2], kn_ref.shape[2]
+        seen = None
+        if second_from is not None:
+            # two blocks of ``b``, causal across and open inside: the
+            # front block's queries see columns under ``b``; the second
+            # block's their own and, where the slot leads, the front's
+            b = n // 2
+            row = jax.lax.broadcasted_iota(jnp.int32, (rows, n), 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, (rows, n), 1)
+            second = row >= second_from
+            first = jnp.where(second & (lead_ref[si] == 0), b, 0)
+            seen = (col >= first) & (col < jnp.where(second, n, b))
+
+        def head(h):    # the softmax's first tile: it sets what later add to
+            s = _dot_t(q_ref[0, h], kn_ref[0, h]) * scale      # (rows, n)
+            if seen is not None:
+                s = jnp.where(seen, s, -jnp.inf)
+            top = jnp.max(s, axis=-1, keepdims=True)
+            p = jnp.exp(s - top)
+            m_ref[h] = top
+            l_ref[h] = jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = jnp.dot(p.astype(vn_ref.dtype), vn_ref[0, h],
+                                 preferred_element_type=F32)
+
+        for h in range(k_ref.shape[1]):     # the key/value heads, unrolled
+            head(h)
+
+    _cache_tile(count, ki * bk, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+                scale=scale, bk=bk)
+
+    @pl.when(ki == pl.num_programs(1) - 1)
+    def _():
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def pallas_block_decode_attention(q, k, v, k_new, v_new, counts, scale,
+                                  lead=None, *, block_k=None,
+                                  interpret=None):
+    """The kernel lowering of :func:`block_decode_attention`
+    (:func:`_decode_options` has the two options)."""
+    two_blocks = lead is not None
+    lead = (lead.astype(jnp.int32) if two_blocks
+            else jnp.zeros(counts.shape, jnp.int32))
+    return _block_decode_call(
+        q, k, v, k_new.astype(k.dtype), v_new.astype(v.dtype),
+        counts.astype(jnp.int32), lead, scale=scale, two_blocks=two_blocks,
+        **_decode_options(k.shape[2], block_k, interpret))
+
+
+# jitted as ``_decode_call`` is: the layers of one query width share ONE
+# traced and lowered kernel (SDAR's chunk program holds two, for 2B and B
+# query rows, not one a layer)
+@functools.partial(jax.jit, static_argnames=("scale", "bk", "two_blocks",
+                                             "interpret"))
+def _block_decode_call(q, k, v, k_new, v_new, counts, lead, *, scale, bk,
+                       two_blocks, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s, m, heads, d = q.shape
+    kv, t = k.shape[1], k.shape[2]
+    group, n = heads // kv, k_new.shape[2]
+    rows = m * group
+
+    def slot_map(si, ki, cnt_ref, lead_ref):
+        return si, 0, 0, 0
+
+    def cache_map(si, ki, cnt_ref, lead_ref):
+        # ``_decode_call.cache_map``'s rule with a count of 0 meaning "no
+        # tile visited": the steps past a slot's tiles point at the NEXT
+        # slot's first tile, the last slot's stay on its last
+        tiles = (cnt_ref[si] + bk - 1) // bk
+        done, more = ki >= tiles, si + 1 < s
+        return (jnp.where(done & more, si + 1, si), 0,
+                jnp.where(done, jnp.where(more, 0, jnp.maximum(tiles - 1, 0)),
+                          ki), 0)
+
+    out = pl.pallas_call(
+        functools.partial(
+            _block_decode_kernel, scale=scale, bk=bk,
+            # query ``j`` of ``m`` is row ``n - m + j`` of the forward
+            second_from=(n // 2 - (n - m)) * group if two_blocks else None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(s, t // bk),
+            in_specs=[pl.BlockSpec((1, kv, rows, d), slot_map),
+                      pl.BlockSpec((1, kv, bk, d), cache_map),
+                      pl.BlockSpec((1, kv, bk, d), cache_map),
+                      pl.BlockSpec((1, kv, n, d), slot_map),
+                      pl.BlockSpec((1, kv, n, d), slot_map)],
+            out_specs=pl.BlockSpec((1, kv, rows, d), slot_map),
+            scratch_shapes=[pltpu.VMEM((kv, rows, 1), F32),
+                            pltpu.VMEM((kv, rows, 1), F32),
+                            pltpu.VMEM((kv, rows, d), F32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((s, kv, rows, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="gqa_block_decode_fwd",
+    )(counts, lead,
+      q.reshape(s, m, kv, group, d).transpose(0, 2, 1, 3, 4).reshape(
+          s, kv, rows, d), k, v, k_new, v_new)
+    return out.reshape(s, kv, m, group, d).transpose(0, 2, 1, 3, 4).reshape(
+        s, m, heads * d)
+
+
+def xla_block_decode_attention(q, k, v, k_new, v_new, counts, scale,
+                               lead=None):
+    """The XLA form of :func:`block_decode_attention`, as
+    :func:`xla_decode_attention`: the whole cache is read
+    (:func:`rows_visited` under ``"xla"``), scores ``(S, KV, G * b, T)`` in
+    float32."""
     n = k_new.shape[2]
     seen = jnp.arange(k.shape[2])[None, :] < counts[:, None]
     if lead is None:

@@ -16,8 +16,8 @@ at a time).
 (``ops/gqa.py`` owns three such ops: the prefill core, ``"gqa_prefill"``;
 the one-query decode core, ``"gqa_decode"`` — the kernel at head widths on
 the lane tile, the XLA form at Granite 4.0-H's 64 —; and
-``block_decode_attention``, which has the XLA form alone so far and notes
-it as ``"gqa_block_decode"``, so that the note is there to change.)
+``block_decode_attention``, a block of queries a slot, which follows the
+one-query core's rule and notes its choice as ``"gqa_block_decode"``.)
 The choice is made while a program is traced, so a caller that traces one
 (``ServingEngine`` around its chunk and admission programs) can collect it:
 :func:`record_lowerings` yields ``{op name: {lowering, ...}}`` for the ops

@@ -2,8 +2,11 @@
 ``ops/row_write.py``, ``models/kv.py``): the prefill under a mask that is
 causal across blocks and open inside one — both lowerings — against a dense
 mask; ``B`` queries over a cache plus themselves against the rows of a full
-forward; a commit that writes exactly its ``B`` rows and a denoise forward
-that writes none."""
+forward — the XLA form and the kernel ``gqa_block_decode_fwd`` under the
+interpreter, and the kernel against the XLA form for both query widths,
+every ``lead`` pattern, counts of 0, 1 and around a tile's edge, with NaN
+past a count —; a commit that writes exactly its ``B`` rows and a denoise
+forward that writes none."""
 
 import jax
 import jax.numpy as jnp
@@ -109,7 +112,18 @@ def test_pairs_allowed_under_the_block_mask_is_a_count_of_the_mask(block):
     assert float(gqa.pairs_allowed(lengths, None, block)) == want
 
 
-def test_b_queries_over_a_cache_and_themselves_are_a_full_forwards_rows():
+def _kernel(*args, **kw):
+    """The kernel lowering under the interpreter, four key tiles a cache of
+    32 rows."""
+    return gqa.pallas_block_decode_attention(*args, block_k=8,
+                                             interpret=True, **kw)
+
+
+FORMS = {"xla": gqa.block_decode_attention, "kernel": _kernel}
+
+
+@pytest.mark.parametrize("form", list(FORMS))
+def test_b_queries_over_a_cache_and_themselves_are_a_full_forwards_rows(form):
     """Slots at different cursors (one with nothing committed), rows of the
     cache past the cursor holding anything."""
     b, t, slots = 4, 32, 3
@@ -119,22 +133,25 @@ def test_b_queries_over_a_cache_and_themselves_are_a_full_forwards_rows():
     junk = jax.random.normal(jax.random.key(9), k.shape)
     at = jnp.arange(t)[None, None, :, None]
     past = at < counts[:, None, None, None]
+    got = FORMS[form](
+        jnp.stack([q[j, int(counts[j]):int(counts[j]) + b]
+                   for j in range(slots)]),
+        jnp.where(past, k, junk), jnp.where(past, v, junk),
+        jnp.stack([k[j, :, int(counts[j]):int(counts[j]) + b]
+                   for j in range(slots)]),
+        jnp.stack([v[j, :, int(counts[j]):int(counts[j]) + b]
+                   for j in range(slots)]), counts, 0.25)
     for i in range(slots):
         lo = int(counts[i])
-        got = gqa.block_decode_attention(
-            jnp.stack([q[j, int(counts[j]):int(counts[j]) + b]
-                       for j in range(slots)]),
-            jnp.where(past, k, junk), jnp.where(past, v, junk),
-            jnp.stack([k[j, :, int(counts[j]):int(counts[j]) + b]
-                       for j in range(slots)]),
-            jnp.stack([v[j, :, int(counts[j]):int(counts[j]) + b]
-                       for j in range(slots)]), counts, 0.25)
         np.testing.assert_allclose(got[i], want[i, lo:lo + b], atol=2e-5)
 
 
-@pytest.mark.parametrize("lead", [(True, True, True), (False, True, False),
-                                  (False, False, False)])
-def test_two_blocks_over_a_cache_are_a_full_forwards_rows(lead):
+LEADS = [(True, True, True), (False, True, False), (False, False, False)]
+
+
+@pytest.mark.parametrize("form", list(FORMS))
+@pytest.mark.parametrize("lead", LEADS)
+def test_two_blocks_over_a_cache_are_a_full_forwards_rows(lead, form):
     """A pending block in front of the block in progress: where ``lead``
     both blocks' rows are the full forward's (the second sees the front
     block's keys in the forward's own tile); where not, the front block is
@@ -158,9 +175,10 @@ def test_two_blocks_over_a_cache_are_a_full_forwards_rows(lead):
     q_new = q_new.at[:, :b].set(jnp.where(filler, fq, q_new[:, :b]))
     k_new = k_new.at[:, :, :b].set(jnp.where(filler, fk, k_new[:, :, :b]))
     v_new = v_new.at[:, :, :b].set(jnp.where(filler, fv, v_new[:, :, :b]))
-    got = gqa.block_decode_attention(
+    attend = FORMS[form]
+    got = attend(
         q_new, jnp.where(past, k, junk), jnp.where(past, v, junk), k_new,
-        v_new, counts, 0.25, jnp.asarray(lead))
+        v_new, counts, 0.25, lead=jnp.asarray(lead))
     assert got.shape == (slots, 2 * b, HEADS * D)
     for i, c in enumerate(cursor):
         np.testing.assert_allclose(got[i, b:], want[i, c:c + b], atol=2e-5)
@@ -168,16 +186,70 @@ def test_two_blocks_over_a_cache_are_a_full_forwards_rows(lead):
             np.testing.assert_allclose(got[i, :b], want[i, c - b:c],
                                        atol=2e-5)
     # the second block's rows do not depend on what the filler holds
-    other = gqa.block_decode_attention(
+    other = attend(
         jnp.where(filler, 2 * q_new, q_new).at[:, b:].set(q_new[:, b:]),
         jnp.where(past, k, junk), jnp.where(past, v, junk),
         k_new.at[:, :, :b].set(jnp.where(filler, -k_new[:, :, :b],
                                          k_new[:, :, :b])),
         v_new.at[:, :, :b].set(jnp.where(filler, 3 * v_new[:, :, :b],
                                          v_new[:, :, :b])),
-        counts, 0.25, jnp.asarray(lead))
+        counts, 0.25, lead=jnp.asarray(lead))
     np.testing.assert_array_equal(np.asarray(other[:, b:]),
                                   np.asarray(got[:, b:]))
+
+
+# the three slots' committed rows by what they do in a tiling of 32 rows by
+# 8: nothing (no tile visited), one row, one under a tile's edge, on it, one
+# past it, the full cache; the LAST slot matters by itself (its idle steps
+# stay on its own last tile, or on none)
+KERNEL_COUNTS = {"0-under-past": (0, 7, 9), "1-on-full": (1, 8, 32),
+                 "full-0-0": (32, 0, 0)}
+TOL = {"float32": 2e-5, "bfloat16": 2e-2}
+
+
+@pytest.mark.parametrize("dtype", list(TOL))
+@pytest.mark.parametrize("counts", list(KERNEL_COUNTS))
+@pytest.mark.parametrize("m,n,lead", [
+    *((m, 8, lead) for m in (4, 8) for lead in LEADS), (4, 4, None)],
+    ids=lambda v: "".join("FT"[x] for x in v) if isinstance(v, tuple)
+    else str(v))
+def test_kernel_equals_the_xla_form(m, n, lead, counts, dtype):
+    """``gqa_block_decode_fwd`` under the interpreter against
+    ``xla_block_decode_attention``: both blocks' queries (``m`` = 2B) and
+    the last layer's (``m`` = B) over two blocks' keys, and the B-wide call
+    of a first block or the direct check; rows past a count inside the tile
+    it crosses hold junk and the tiles wholly past it NaN — neither seen nor
+    read."""
+    slots, t, bk = 3, 32, 8
+    dtype = jnp.dtype(dtype)
+    q = _qkv(m, slots, m, dtype=dtype)[0]
+    _, k, v = _qkv(n + 1, slots, t, dtype=dtype)
+    _, k_new, v_new = _qkv(n + 2, slots, n, dtype=dtype)
+    counts = jnp.asarray(KERNEL_COUNTS[counts])
+    lead = None if lead is None else jnp.asarray(lead)
+    want = gqa.xla_block_decode_attention(q, k, v, k_new, v_new, counts, 0.25,
+                                          lead)
+    at = jnp.arange(t)[None, None, :, None]
+    edge = counts[:, None, None, None]
+
+    def spoiled(a):
+        junk = jnp.where(at >= edge, jnp.asarray(37.5, a.dtype), a)
+        return jnp.where(at >= -(-edge // bk) * bk, jnp.nan, junk)
+
+    got = _kernel(q, spoiled(k), spoiled(v), k_new, v_new, counts, 0.25,
+                  lead=lead)
+    assert got.shape == (slots, m, HEADS * D) and got.dtype == dtype
+    got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, want))
+    assert np.isfinite(got).all() and float(np.abs(want).max()) > 0.3
+    assert float(np.abs(got - want).max()) < TOL[dtype.name]
+
+
+def test_the_kernel_refuses_a_tile_that_does_not_divide_the_cache():
+    q, k, v = _qkv(0, 1, 32)
+    with pytest.raises(ValueError, match="does not divide"):
+        gqa.pallas_block_decode_attention(
+            q[:, :4], k, v, k[:, :, :4], v[:, :, :4], jnp.asarray([4]), 1.0,
+            block_k=12, interpret=True)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

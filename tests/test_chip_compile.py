@@ -455,6 +455,31 @@ def test_gqa_decode_kernel_compiles_for_v5e(shape, no_persistent_cache,
                                                            jnp.int32))
 
 
+@pytest.mark.parametrize("queries,tokens", [(8, 8), (4, 8), (4, 4)],
+                         ids=["two-blocks", "last-layer", "one-block"])
+def test_gqa_block_decode_kernel_compiles_for_v5e(shape, no_persistent_cache,
+                                                  queries, tokens):
+    """The block form's core (``ops/gqa.py``, ``gqa_block_decode_fwd``) at
+    the shapes of ``serve-sdar-blockdiff-backlog``: 64 slots of 2,560 rows,
+    4 key/value heads of 128, bfloat16, with the key tile the chip path
+    takes — both blocks' queries (64 query rows a key/value head) and the
+    last layer's (32) over the 8 keys of a pending block and the block in
+    progress, and the B-wide call of a first block and the direct check (4
+    keys, a quarter of a bfloat16 sublane tile, and no mask among them)."""
+    from progen_tpu.ops.gqa import pallas_block_decode_attention
+
+    bf16 = jnp.bfloat16
+    cache = shape((64, 4, 2560, 128), bf16)
+    own = shape((64, 4, tokens, 128), bf16)
+    two = tokens > 4
+    _assert_kernel_compiles(
+        lambda q, k, v, kn, vn, n, lead: pallas_block_decode_attention(
+            q, k, v, kn, vn, n, 128 ** -0.5, lead if two else None,
+            interpret=False),
+        shape((64, queries, 32, 128), bf16), cache, cache, own, own,
+        shape((64,), jnp.int32), shape((64,), jnp.bool_))
+
+
 def _expert_shapes(shape, held, h, inner, gated):
     """The stacked experts' abstract matrices: gate (None without one), up,
     down."""
@@ -654,8 +679,9 @@ def test_sdar_programs_compile_for_the_chip_and_fit_it(
     """6 whole expert layers (all 128 experts of each), the whole
     vocabulary, 64 slots of grown keys: the chunk program (30 forwards of
     64 x 8 positions — each slot's pending block in front of its block in
-    progress: the block core in XLA, the pending block's withheld write as
-    ``row_block_write``, the draw over the 256 x 151,936 logits of the
+    progress: the block core as ``gqa_block_decode_fwd``, the pending
+    block's withheld write as ``row_block_write``, the draw over the 256 x
+    151,936 logits of the
     blocks in progress) and the admission of 4 rows at the 1024 bucket (the
     flash kernel under the block mask), as the chip traces them.
     Arguments, results and temporaries together stay under the chip's
@@ -701,8 +727,9 @@ def test_sdar_programs_compile_for_the_chip_and_fit_it(
     assert floor <= total < 15.5e9, m
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    assert ("row_block_write" if program == "chunk"
-            else "gqa_prefill_fwd") in text
+    for kernel in (("row_block_write", "gqa_block_decode_fwd")
+                   if program == "chunk" else ("gqa_prefill_fwd",)):
+        assert kernel in text
     # the draw's 32 rounds go by groups of 32 rows whose keys the compiler
     # keeps in the chip's own memory (memory space 1): no uint32 array of
     # the draw's whole shape is left for a loop to read from HBM 32 times

@@ -8,7 +8,11 @@ a ring in wrapped order equal to the same rows in order; bit-equal
 whatever the cache holds past a count, tiles past it never read; the rows
 each lowering reads; the choice of lowering from backend, mesh, dtype and
 shape; ``KVBlock.decode`` through the kernel for a ring past its wrap and
-a grown cache; and ``status()["gqa_decode"]``."""
+a grown cache; and ``status()["gqa_decode"]``.  The block form's kernel
+(``gqa_block_decode_fwd``; its arithmetic is held in
+``tests/test_block_attention.py``) follows the same rule:
+``block_decode_lowering``, ``KVBlock.decode_block`` through it, and the
+counter that follows it."""
 
 import dataclasses
 
@@ -18,9 +22,11 @@ import numpy as np
 import pytest
 
 from progen_tpu.models import kv as kv_blocks
+from progen_tpu.models import sdar
 from progen_tpu.models import trinity as tr
 from progen_tpu.ops import gqa
 from progen_tpu.ops.lowering import record_lowerings
+from tests import sdar_tiny
 from tests.trinity_tiny import TINY, make
 
 D, SLOTS = 128, 4
@@ -207,6 +213,55 @@ def test_on_tpu_the_shape_decides(monkeypatch, shape, dtypes, want):
     assert (f"f32[{s},{kv},{heads // kv},{t}]" in jaxpr) == (want == "xla")
 
 
+def _block_lowering(shape, dtype=jnp.bfloat16, cache_dtype=None):
+    """What a traced ``block_decode_attention`` of 2B = 8 tokens a slot
+    notes, and its jaxpr."""
+    s, heads, kv, t, d = shape
+    cache = jax.ShapeDtypeStruct((s, kv, t, d), cache_dtype or dtype)
+    own = jax.ShapeDtypeStruct((s, kv, 8, d), cache_dtype or dtype)
+    args = (jax.ShapeDtypeStruct((s, 8, heads, d), dtype), cache, cache, own,
+            own, jax.ShapeDtypeStruct((s,), jnp.int32),
+            jax.ShapeDtypeStruct((s,), jnp.bool_))
+    with record_lowerings() as chosen:
+        jaxpr = str(jax.make_jaxpr(
+            lambda q, k, v, kn, vn, n, lead: gqa.block_decode_attention(
+                q, k, v, kn, vn, n, 0.1, lead))(*args))
+    assert chosen["gqa_block_decode"] == {
+        gqa.block_decode_lowering(dtype, cache, cache)}
+    return chosen["gqa_block_decode"], jaxpr
+
+
+@pytest.mark.parametrize("shape,dtypes,want", [
+    ((64, 32, 4, 2560, 128), (jnp.bfloat16, None), "pallas"),
+    ((2, 8, 8, 512, 256), (jnp.float32, None), "pallas"),
+    ((64, 32, 4, 2560, 64), (jnp.bfloat16, None), "xla"),
+    ((2, 8, 2, 2304, 128), (jnp.bfloat16, None), "xla"),     # 4.5 tiles
+    ((2, 8, 2, 512, 128), (jnp.bfloat16, jnp.float32), "xla"),
+    ((3, 4, 2, 48, 16), (jnp.float32, None), "xla"),         # the tests' TINY
+], ids=["sdar-cell", "f32-d256", "d64", "T-2304", "cache-f32", "tiny"])
+def test_on_tpu_the_shape_decides_for_the_block_form_too(monkeypatch, shape,
+                                                         dtypes, want):
+    """The one-query core's rule: a TPU backend, one float type, ``d`` on
+    the lane tile, ``T`` a multiple of ``MIN_TILE``.  The kernel writes no
+    ``(S, KV, G * B, T)`` score tensor, and both blocks' queries go in ONE
+    call where the XLA form splits them."""
+    assert _block_lowering(shape, *dtypes)[0] == {"xla"}        # the CPU
+    monkeypatch.setattr(gqa, "_on_tpu", lambda: True)
+    paths, jaxpr = _block_lowering(shape, *dtypes)
+    assert paths == {want}
+    assert jaxpr.count("pallas_call") == (want == "pallas")
+    s, heads, kv, t, _ = shape
+    assert (f"f32[{s},{kv},{heads // kv * 4},{t}]" in jaxpr) == (want == "xla")
+
+
+def test_a_mesh_in_scope_keeps_the_block_forms_xla_too(monkeypatch, devices8):
+    monkeypatch.setattr(gqa, "_on_tpu", lambda: True)
+    mesh = jax.sharding.Mesh(np.asarray(devices8[:2]), ("data",))
+    with mesh:
+        paths, jaxpr = _block_lowering((64, 32, 4, 2560, 128))
+    assert paths == {"xla"} and "pallas_call" not in jaxpr
+
+
 def test_a_mesh_in_scope_keeps_the_xla_form(monkeypatch, devices8):
     mesh = jax.sharding.Mesh(np.asarray(devices8[:2]), ("data",))
     with mesh:
@@ -226,10 +281,10 @@ def _force_kernel(monkeypatch):
     monkeypatch.setattr(gqa, "_on_tpu", lambda: True)
     monkeypatch.setattr(gqa, "DECODE_TILE", TILE)
     monkeypatch.setattr(gqa, "MIN_TILE", TILE)
-    monkeypatch.setattr(
-        gqa, "pallas_decode_attention",
-        lambda *a, _f=gqa.pallas_decode_attention, **kw: _f(
-            *a, **{**kw, "interpret": True}))
+    for name in ("pallas_decode_attention", "pallas_block_decode_attention"):
+        monkeypatch.setattr(
+            gqa, name, lambda *a, _f=getattr(gqa, name), **kw: _f(
+                *a, **{**kw, "interpret": True}))
 
 
 @pytest.mark.parametrize("name,pos", [
@@ -274,30 +329,121 @@ def test_kv_block_decode_through_the_kernel(monkeypatch, name, pos):
     assert float(jnp.abs(got - want).max()) < 2e-5
 
 
-def test_decode_stats_follow_the_lowering_and_the_block_form_does_not(
-        monkeypatch):
+# SDAR's tiny model at the published head width: blocks of 4 over grown
+# caches of four (test) tiles
+SDAR_WIDE = dataclasses.replace(sdar_tiny.TINY, head_dim=D,
+                                max_position_embeddings=MAX_LEN)
+
+
+@pytest.mark.parametrize("tokens,queries", [(8, None), (8, 4), (4, None)],
+                         ids=["two-blocks", "last-layer", "one-block"])
+def test_kv_block_decode_block_through_the_kernel(monkeypatch, tokens,
+                                                  queries):
+    """``KVBlock.decode_block`` at the published head width with the kernel
+    forced (interpreter): ONE kernel call for the core (the XLA form makes
+    a pass a query block) and no score tensor in the trace, the output that
+    of the XLA form, the same rows written — a pending block's where one
+    rides, none where not."""
+    b = sdar_tiny.BLOCK
+    params, _ = sdar_tiny.make(SDAR_WIDE)
+    block = sdar.blocks_of(SDAR_WIDE)["l0"]
+    p = params["layers"][0]["attn"]
+    # cursors: a first block, under / on / past a tile's edge, the last block
+    pos0 = jnp.array([tokens - b, 124, 128, 132, MAX_LEN - b])
+    commit = jnp.array([True, False, True, True, False])
+    slots = len(pos0)
+    x = jax.random.normal(jax.random.key(1),
+                          (slots, tokens, SDAR_WIDE.hidden_size))
+    shape = (slots, SDAR_WIDE.num_key_value_heads, MAX_LEN, D)
+    cache = {"k": jax.random.normal(jax.random.key(2), shape),
+             "v": jax.random.normal(jax.random.key(3), shape)}
+
+    def run():
+        with jax.default_matmul_precision("highest"):
+            return block.decode_block(x, pos0, cache, p, commit, queries)
+
+    want, want_cache = run()
+    assert want.shape == (slots, queries or tokens, SDAR_WIDE.hidden_size)
+    c = SDAR_WIDE
+    rows = c.num_attention_heads // c.num_key_value_heads * b
+    scores = f"f32[{slots},{c.num_key_value_heads},{rows},{MAX_LEN}]"
+
+    def trace():
+        with record_lowerings() as chosen:
+            jaxpr = str(jax.make_jaxpr(lambda x, c: block.decode_block(
+                x, pos0, c, p, commit, queries))(x, cache))
+        return chosen["gqa_block_decode"], jaxpr
+
+    paths, jaxpr = trace()
+    assert paths == {"xla"} and scores in jaxpr
+    _force_kernel(monkeypatch)
+    paths, jaxpr = trace()
+    assert paths == {"pallas"} and scores not in jaxpr
+    assert jaxpr.count("pallas_call") == 1      # the write stays a scatter
+    got, got_cache = run()
+    for leaf in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(got_cache[leaf]),
+                                      np.asarray(want_cache[leaf]))
+        wrote = (np.asarray(got_cache[leaf]) != np.asarray(cache[leaf])).any(
+            axis=(1, 3))
+        first = np.asarray(pos0) - (tokens - b)
+        for i in range(slots):
+            assert wrote[i].tolist() == [
+                bool(commit[i]) and first[i] <= r < first[i] + b
+                for r in range(MAX_LEN)]
+    assert float(jnp.abs(want).max()) > 0.05
+    assert float(jnp.abs(got - want).max()) < 2e-5
+
+
+@pytest.mark.parametrize("form", ["one-query", "block"])
+def test_decode_stats_follow_the_lowering(monkeypatch, form):
     """``attn.*_rows_read``: every row of every slot under the XLA form;
     under the kernel whole tiles up to each slot's count — a ring's count
-    stops at its rows —; a step of B queries a slot keeps the XLA form's
-    count whatever the backend."""
+    stops at its rows.  A step of B queries a slot follows ITS core's
+    lowering: whole tiles up to the rows committed before the forward, less
+    a pending block that rides it; a slot with nothing committed reads no
+    tile; with no live row the counter is 0."""
     blocks = tr.blocks_of(WIDE)
     slots = 3
     caches = {n: b.init_cache(slots, MAX_LEN, jnp.float32)
               for n, b in blocks.items()}
-    pos = jnp.array([0, 127, 300])
-    live = jnp.array([True, True, True])
-    stats = kv_blocks.decode_stats(blocks, caches, pos, live)
-    assert float(stats["attn.window_rows_read"]) == slots * WINDOW
-    assert float(stats["attn.full_rows_read"]) == slots * MAX_LEN
-    _force_kernel(monkeypatch)
-    stats = kv_blocks.decode_stats(blocks, caches, pos, live)
-    assert float(stats["attn.window_rows_read"]) == (1 + 1 + 2) * TILE
-    assert float(stats["attn.full_rows_read"]) == (1 + 1 + 3) * TILE
-    assert float(kv_blocks.decode_stats(
-        blocks, caches, pos, jnp.zeros(3, bool))["attn.full_rows_read"]) == 0
     full = {n: b for n, b in blocks.items() if b.window is None}
-    stats = kv_blocks.block_decode_stats(full, caches, pos + 1, live, 4)
+    live, dead = jnp.array([True, True, True]), jnp.zeros(3, bool)
+    if form == "one-query":
+        pos = jnp.array([0, 127, 300])
+
+        def read(live=live):
+            return kv_blocks.decode_stats(blocks, caches, pos, live)
+
+        want = (1 + 1 + 3) * TILE
+    else:
+        # committed rows 0, 128 (a tile, whole) and 300 + 4 less the
+        # pending block that rides the forward
+        pos0 = jnp.array([0, 128, 304])
+        riding = jnp.array([False, False, True])
+
+        def read(live=live):
+            return kv_blocks.block_decode_stats(full, caches, pos0, live, 4,
+                                                riding)
+
+        want = (0 + 1 + 3) * TILE
+    stats = read()
     assert float(stats["attn.full_rows_read"]) == slots * MAX_LEN
+    if form == "one-query":
+        assert float(stats["attn.window_rows_read"]) == slots * WINDOW
+    _force_kernel(monkeypatch)
+    stats = read()
+    assert float(stats["attn.full_rows_read"]) == want
+    if form == "one-query":
+        assert float(stats["attn.window_rows_read"]) == (1 + 1 + 2) * TILE
+    else:
+        # B query rows a live slot and B more where a pending block rides
+        assert float(stats["attn.decode_rows"]) == 4 * (3 + 1)
+        assert float(stats["attn.context_tokens"]) == 0 + 128 + 300
+        assert float(kv_blocks.block_decode_stats(
+            full, caches, pos0, live, 4)["attn.full_rows_read"]) == (
+                0 + 1 + 3) * TILE
+    assert float(read(dead)["attn.full_rows_read"]) == 0
 
 
 def test_cpu_notes_xla_and_the_engine_states_it():
