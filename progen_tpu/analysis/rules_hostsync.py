@@ -88,7 +88,8 @@ HOT_ZONES: tuple[Zone, ...] = (
                    "_xla_compiles", "_gc_pauses", "_steps",
                    "_compiles_in_step", "_mean_host", "_mean_gap",
                    "_mean_stage", "_step_stages", "_admit_pads",
-                   "_last_return"}),
+                   "_last_return", "_step_admits", "_step_chunk_rows",
+                   "_step_finished", "_step_done"}),
         # requests, admission rows and snapshots are host payloads by API
         # contract: numpy masks, python ints, JSON-safe dicts — never
         # device arrays
@@ -187,8 +188,10 @@ HOT_ZONES: tuple[Zone, ...] = (
          # PolicyInputs fields are host floats/dicts by contract
          frozenset({"inputs", "burn_rates"})),
     # span recording sits on every hot path above: it must never sync
-    # (spans carry pre-computed floats, never device values)
-    Zone(r"observe/trace\.py$", r"Tracer\.(span|add|event|incident)$"),
+    # (spans, incidents and step records carry pre-computed floats, never
+    # device values)
+    Zone(r"observe/trace\.py$",
+         r"Tracer\.(span|add|event|incident|step_record)$"),
     # the compile and collector listeners run INSIDE jit dispatch and
     # inside every collection, wherever those fall — a step of the engine,
     # a dispatch of the trainer: durations and names from JAX's and

@@ -89,7 +89,7 @@ import dataclasses
 import json
 import os
 import time
-from collections import deque
+from collections import defaultdict, deque
 from functools import partial, wraps
 from typing import Any, Callable, Sequence
 
@@ -143,46 +143,74 @@ _MAX_DEFER_STREAK = 16
 # padding costs a whole prime's prefill and another run of the program a
 # fixed pass over the state; PERF.md section 6 (PR 26) has the sweep on
 # the chip: 4 rows at 64 slots, 1 at 16
+SLOTS_PER_ADMIT_ROW = 16
+
 # A step STOOD STILL (incident ``serve.slow_step``) when its host self time,
 # the gap before it or one of its closed stages is over SLOW_FACTOR times the
-# engine's own running mean of that quantity AND SLOW_FLOOR_S above it.  The
-# factor: a stage's program takes the same time every run but for its live
-# rows (a chunk's cache reads follow the context: within a third of the
-# mean on the cells' traffic), so twice the mean is no ordinary run.  The
+# engine's own running mean of that quantity AND SLOW_FLOOR_S above it.  A
+# quantity is judged against its own REGIME: the chunk program by the power
+# of two at or above its rows in flight (its cache reads follow the live
+# rows and their context: a full chunk of 128 rows takes 2.3 times a probe's
+# near-empty one, within a bucket a chunk stays within a third of the mean
+# on the cells' traffic), an admission group by the padded lengths of its
+# runs, the host self time by the power of two at or above the admission
+# runs the step built (each costs the host its arrays and its mask: the
+# step that fills 128 slots in 16 runs takes 74 ms of host time where a
+# step of one run takes 8).  The factor: inside a regime a program takes
+# the same time every run, so twice the mean is no ordinary run.  The
 # floor: host self time and gaps are a few ms with a harvest ten times the
 # rest, so a multiple alone would file one incident a harvest; 50 ms is a
 # seventh of what a cell's rate may lose in its window (1 % of 35 s) and
 # twice the shortest step the cells run, so an incident names seconds that
 # can show end to end and a warmed engine files none.  A mean judges
-# nothing before it has SLOW_MIN_SAMPLES observations.
+# nothing before it has SLOW_MIN_SAMPLES observations, and the same number
+# of refusals IN A ROW is what it takes for a mean to call them its regime:
+# a stall does not come back eight times running, a longer context or a
+# busier host does, so those eight are filed and the mean starts over from
+# them.
 SLOW_FACTOR = 2.0
 SLOW_FLOOR_S = 0.050
 SLOW_MIN_SAMPLES = 8
+# step records in ``status()``: an admitting step and the chunk steps around
+# it, on one screen of /statusz; /tracez serves the newest 512 of the log
+LAST_STEPS = 8
 
 
-class _RunningMean:
-    """Mean of the observations that were NOT slow, and the rule above."""
+def _bucket(n: int) -> int:
+    """The power of two at or above ``n`` (0 for none): a regime's key."""
+    return 1 << (n - 1).bit_length() if n > 0 else 0
 
-    __slots__ = ("n", "mean")
+
+class _RegimeMean:
+    """Running mean of one quantity in one regime, and the rule above."""
+
+    __slots__ = ("n", "mean", "refused", "refused_sum")
 
     def __init__(self):
         self.n = 0
         self.mean = 0.0
+        self.refused = 0        # slow observations in a row, and their sum
+        self.refused_sum = 0.0
 
-    def excess(self, v: float) -> float:
-        """Seconds of ``v`` over the mean where ``v`` is slow, else 0."""
+    def observe(self, v: float) -> float:
+        """Seconds of ``v`` over the mean where ``v`` is slow, else 0.  A
+        slow ``v`` stays out of the mean, unless it is the last of
+        SLOW_MIN_SAMPLES in a row: then they are the mean."""
         over = v - self.mean
         if (self.n >= SLOW_MIN_SAMPLES and over >= SLOW_FLOOR_S
                 and v > SLOW_FACTOR * self.mean):
+            self.refused += 1
+            self.refused_sum += v
+            if self.refused == SLOW_MIN_SAMPLES:
+                self.n = self.refused
+                self.mean = self.refused_sum / self.n
+                self.refused, self.refused_sum = 0, 0.0
             return over
-        return 0.0
-
-    def add(self, v: float) -> None:
+        self.refused, self.refused_sum = 0, 0.0
         self.n += 1
         self.mean += (v - self.mean) / self.n
+        return 0.0
 
-
-SLOTS_PER_ADMIT_ROW = 16
 
 # what a slot of a family that generates by blocks holds beside the rest
 # (``_block_chunk_impl``): where the block in progress starts, its tokens,
@@ -539,15 +567,25 @@ class ServingEngine:
         self._gc_pauses = registry.histogram("host.gc_pause_s")
         self._steps = registry.counter("engine.steps")
         self._compiles_in_step = registry.counter("engine.compiles_in_step")
-        # the slow-step rule's means (SLOW_FACTOR above): host self time,
-        # the gap between steps, and each stage program's time — the chunk
-        # program, an admission group by the padded lengths of its runs
-        self._mean_host = _RunningMean()
-        self._mean_gap = _RunningMean()
-        self._mean_stage: dict[Any, _RunningMean] = {}
-        # (program, seconds) of the stages closed in the step() in progress,
-        # None outside one: a prefill worker runs rounds and never steps
+        # the slow-step rule's means (SLOW_FACTOR above), one a regime: the
+        # gap between steps; host self time by the bucket of the step's
+        # admission runs; each stage program's time — the chunk program by
+        # its rows' bucket, an admission group by the padded lengths of its
+        # runs
+        self._mean_gap = _RegimeMean()
+        self._mean_host: dict[int, _RegimeMean] = defaultdict(_RegimeMean)
+        self._mean_stage: dict[Any, _RegimeMean] = defaultdict(_RegimeMean)
+        # what the step() in progress leaves for its record (_judge_step):
+        # (program, seconds) of the stages it closed, None outside a step (a
+        # prefill worker runs rounds and never steps); (requests, real
+        # tokens, token slots) of each admission run; the rows its chunk was
+        # dispatched with; the requests it harvested; the return of its
+        # last flags fetch
         self._step_stages: list[tuple] | None = None
+        self._step_admits: list[tuple] = []
+        self._step_chunk_rows = 0
+        self._step_finished = 0
+        self._step_done: float | None = None
         self._admit_pads: list[int] = []      # p_pad of each run in flight
         # return instant of the last step() that left work behind, else
         # None: a caller that sleeps with nothing to do is not a stall
@@ -1691,9 +1729,12 @@ class ServingEngine:
                     self._queue.appendleft(r)
                 raise
             self._publish_prefixes(placed)
+            real = sum(len(r.tokens) for r in requests)
             self._admit_rows_hist.observe(len(requests))
-            self._prefill_real.inc(sum(len(r.tokens) for r in requests))
+            self._prefill_real.inc(real)
             self._prefill_slots.inc(self.admit_rows * p_pad)
+            self._step_admits.append(
+                (len(requests), real, self.admit_rows * p_pad))
             self._admit_pads.append(p_pad)
             # the admit program samples each request's first token; that
             # it has RUN is known at the next flags fetch, which stamps
@@ -1999,6 +2040,8 @@ class ServingEngine:
                     # flags fetch shows the merged state and stamps them
                     self._open_stages.append(
                         ("merge_s", "admit", t0, admitted))
+                    # prefilled elsewhere: a run of no token slots here
+                    self._step_admits.append((len(admitted), 0, 0))
             for r in expired:
                 self._shed(r, SHED_DEADLINE)
 
@@ -2096,6 +2139,7 @@ class ServingEngine:
                  self.state.get("stats")))
         now = time.perf_counter()
         self._step_wait += now - t0
+        self._step_done = now
         self._close_stages(now)
         if stats:
             self._publish_model_stats(stats)
@@ -2132,6 +2176,7 @@ class ServingEngine:
                     r.on_complete(comp)
             self._deactivate(ready)
             self.completions.extend(out)
+            self._step_finished += len(out)
             harvest.note(uids=[c.uid for c in out])
         return out
 
@@ -2180,6 +2225,7 @@ class ServingEngine:
                 self._open_stages.append(
                     ("decode_chunk_s", "chunk", t0, batch))
                 self._chunk_rows_hist.observe(len(batch))
+                self._step_chunk_rows = len(batch)
                 self.state = out
                 self.chunks_run += 1
                 return
@@ -2215,9 +2261,9 @@ class ServingEngine:
         t_step = time.perf_counter()
         self._step_no += 1
         self._step_wait = 0.0
-        # the PROCESS's number of this step, which incidents carry: the
-        # counter a reader finds the last N steps by, so two engines in
-        # one process number their steps in one sequence
+        # the PROCESS's number of this step, which its record and incidents
+        # carry: the counter a reader finds the last N steps by, so two
+        # engines in one process number their steps in one sequence
         self._steps.inc()
         step = self._steps.value
         gap = (None if self._last_return is None
@@ -2227,6 +2273,10 @@ class ServingEngine:
         gc_before = self._gc_pauses.sum
         chunks_before = self.chunks_run
         self._step_stages = []
+        self._step_admits = []
+        self._step_chunk_rows = 0
+        self._step_finished = 0
+        self._step_done = None
         _compiles.set_step(step)   # on compiles and pauses filed meanwhile
         try:
             completed = self._run_step()
@@ -2294,42 +2344,47 @@ class ServingEngine:
 
     def _judge_step(self, step: int, t_step: float, now: float, host: float,
                     gap: float | None, compiled: int, gc_s: float) -> None:
-        """The slow-step rule (SLOW_FACTOR above) at the end of a step:
-        ONE incident ``serve.slow_step`` if any of its quantities stood
-        still, else the quantities enter their means.  A stage's clock
-        starts before its dispatch, so host seconds spent there (a
-        compile) are in the stage too: they count once, as the host's."""
+        """The end of a step that did not raise.  ONE record of it goes to
+        the tracer's step log (docs/OBSERVABILITY.md section 3 has the
+        fields), built from host numbers the step already holds.  The
+        slow-step rule (SLOW_FACTOR above) judges the same numbers: where
+        any of them stood still the record is filed a second time, as the
+        incident ``serve.slow_step`` with ``which`` and ``excess``.  A
+        stage's clock starts before its dispatch, so host seconds spent
+        there (a compile) are in the stage too: they count once, as the
+        host's."""
         stages = self._step_stages
-        host_x = self._mean_host.excess(host)
-        gap_x = 0.0 if gap is None else self._mean_gap.excess(gap)
+        rows = self._step_chunk_rows
+        runs = self._step_admits
+        host_x = self._mean_host[_bucket(len(runs))].observe(host)
+        gap_x = 0.0 if gap is None else self._mean_gap.observe(gap)
         device_x = 0.0
         for program, dt in stages:
-            mean = self._mean_stage.get(program)
-            if mean is not None:
-                device_x += mean.excess(dt)
+            if program == "chunk":
+                program = ("chunk", _bucket(rows))
+            device_x += self._mean_stage[program].observe(dt)
         if host_x:
             device_x -= host_x
             if device_x < SLOW_FLOOR_S:
                 device_x = 0.0
+        rec = {
+            "step": step, "t0": t_step, "wall": now - t_step, "host": host,
+            "device_wait": self._step_wait, "gap": gap, "gc_s": gc_s,
+            "compiles": compiled,
+            "stages": [[str(p), dt] for p, dt in stages],
+            "t_done": self._step_done, "chunk_rows": rows,
+            "admitted": sum(n for n, _, _ in runs), "admit_runs": len(runs),
+            "prefill_tokens_real": sum(real for _, real, _ in runs),
+            "prefill_token_slots": sum(slots for _, _, slots in runs),
+            "finished": self._step_finished,
+        }
+        self._tracer.step_record(rec)
         excess = host_x + gap_x + device_x
         if excess:
             which = max((host_x, "host"), (gap_x, "gap"),
                         (device_x, "device"))[1]
-            self._tracer.incident(
-                "serve.slow_step", t_step, now - t_step, step=step,
-                which=which, excess=excess, wall=now - t_step, host=host,
-                device_wait=self._step_wait, gap=gap, gc_s=gc_s,
-                compiles=compiled,
-                stages=[[str(p), dt] for p, dt in stages])
-        else:
-            self._mean_host.add(host)
-            if gap is not None:
-                self._mean_gap.add(gap)
-            for program, dt in stages:
-                mean = self._mean_stage.get(program)
-                if mean is None:
-                    mean = self._mean_stage[program] = _RunningMean()
-                mean.add(dt)
+            self._tracer.incident("serve.slow_step", t_step, now - t_step,
+                                  **rec, which=which, excess=excess)
 
     # ----------------------------------------- multi-process handoff API
 
@@ -2687,6 +2742,8 @@ class ServingEngine:
             "chunks_run": self.chunks_run,
             # programs compiled inside a step(): 0 for ever once warm
             "compiles_in_step": self._compiles_in_step.value,
+            # the newest records of the process's step log (_judge_step)
+            "last_steps": self._tracer.steps()[-LAST_STEPS:],
             # lowering of the chunk program's cache writes, of a latent
             # attention's prefill and decode cores and of a grouped-query
             # attention's prefill and decode cores; None until a program

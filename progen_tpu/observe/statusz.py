@@ -28,8 +28,8 @@ Endpoints:
 - ``/metricsz`` — Prometheus text exposition (counters, gauges,
   cumulative histogram buckets ending in ``+Inf``) rendered from the
   ``metrics`` provider's registry snapshot.
-- ``/tracez``   — recent span ring and the tracer's incidents (JSON),
-  ``/flightz`` — flight recorder events (JSON).
+- ``/tracez``   — recent span ring, the tracer's incidents and the newest
+  of its step log (JSON), ``/flightz`` — flight recorder events (JSON).
 - ``/controlz`` — elastic control-plane journal (JSON): every
   scale/swap/retire decision with its cause signal, plus policy config
   and live fleet state.  Served only when a control plane registered
@@ -222,7 +222,8 @@ class StatuszServer:
             return self._json({"process": tracer.process,
                                "enabled": tracer.enabled,
                                "spans": tracer.ring()[-512:],
-                               "incidents": tracer.incidents()})
+                               "incidents": tracer.incidents(),
+                               "steps": tracer.steps()[-512:]})
         if path == "/flightz":
             return self._json({"events": self._call("flight", [])})
         if path == "/controlz":

@@ -27,9 +27,13 @@ tracer with no annotation returns a shared no-op context manager from
 ``span()``, and ``add()``/``event()`` return before allocating the
 record.
 
-Beside the ring the tracer keeps **incidents**: the rare events a program
-must never lose (a compilation, a long collector pause, a step that stood
-still), kept whether or not the ring is on — ``Tracer.incident``.
+Beside the ring the tracer keeps two stores whether or not the ring is on.
+**Incidents** are the rare events a program must never lose (a compilation,
+a long collector pause, a step that stood still) — ``Tracer.incident``.
+The **step log** holds one record for each iteration of the program's loop
+(an engine's ``step()``), the newest ``STEP_CAPACITY`` of them —
+``Tracer.step_record``: what every step took, where the incidents say
+which steps went wrong.
 
 This file imports nothing but the stdlib —
 ``resilience/watchdog.py`` dumps the ring on a trip and must not pull in
@@ -58,6 +62,11 @@ DEFAULT_CAPACITY = 4096
 # incidents kept: a process compiles a few hundred programs at set-up and
 # then should add none, so the last 256 reach back past any window
 INCIDENT_CAPACITY = 256
+# step records kept: the longest window a cell drives is the steady cell's
+# 35 s and its drain at one step a chunk of 32 x 4.6 ms (about 400 steps),
+# and a backlog cell steps under 250 times, so 4,096 records reach back ten
+# windows at 16 host numbers a record (about 3 MB of small objects at most)
+STEP_CAPACITY = 4096
 
 
 class _NoopSpan:
@@ -140,6 +149,7 @@ class Tracer:
         self.annotation = annotation
         self._ring = deque(maxlen=capacity)
         self._incidents = deque(maxlen=INCIDENT_CAPACITY)
+        self._steps = deque(maxlen=STEP_CAPACITY)
         self._meta = {}
 
     # -- recording ---------------------------------------------------------
@@ -171,10 +181,12 @@ class Tracer:
             return
         self.add(name, time.perf_counter(), 0.0, trace=trace, **args)
 
-    def incident(self, name, t0, dur, **args):
+    def incident(self, name, t0, dur, /, **args):
         """Record a RARE event whether or not the ring is enabled: kept in
         a second bounded deque (``INCIDENT_CAPACITY``), and in the ring as
-        well when it is on.  ``t0`` is a ``time.perf_counter()`` instant.
+        well when it is on.  ``t0`` is a ``time.perf_counter()`` instant
+        (positional only: a slow step's incident carries its step record,
+        whose own ``t0`` is among ``args``).
 
         Contract: incidents are rare by construction — never one per step
         or per request.  A warmed engine stepping with no compile, no long
@@ -189,6 +201,19 @@ class Tracer:
         if self.enabled:
             self._ring.append(rec)
 
+    def step_record(self, rec):
+        """Keep the record of one iteration of the program's loop whether
+        or not the ring is enabled: the newest ``STEP_CAPACITY`` in a third
+        bounded deque, and in the ring as a ``serve.step`` span when it is
+        on.  ``rec`` is a dict of host numbers with at least ``step`` (the
+        number an incident of the same iteration carries), ``t0`` (a
+        ``time.perf_counter()`` instant) and ``wall`` (seconds); the caller
+        builds it once and does not touch it again."""
+        self._steps.append(rec)
+        if self.enabled:
+            self._ring.append({"name": "serve.step", "ts": rec["t0"],
+                               "dur": rec["wall"], "args": rec})
+
     def set_meta(self, **kw):
         """Attach metadata (e.g. the driver's per-worker clock offsets) to
         this process's dump."""
@@ -202,9 +227,13 @@ class Tracer:
     def incidents(self):
         return list(self._incidents)
 
+    def steps(self):
+        return list(self._steps)
+
     def clear(self):
         self._ring.clear()
         self._incidents.clear()
+        self._steps.clear()
         self._meta.clear()
 
     def dump_obj(self):
@@ -216,6 +245,7 @@ class Tracer:
             "meta": dict(self._meta),
             "spans": list(self._ring),
             "incidents": list(self._incidents),
+            "steps": list(self._steps),
         }
 
     def dump(self, path):

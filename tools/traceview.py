@@ -16,6 +16,9 @@ clock via the offsets the driver recorded from worker clock echoes.
 
 ``--summarize`` prints per-span-name count/total/p50/p95 (through the
 same ``Histogram`` the benches use — one percentile code path).
+It then lists the engine's step records — the ``serve.step`` spans of the
+ring and each raw dump's ``steps`` store, which is kept with the ring off
+— the newest ``STEP_ROWS`` of them, one line a step with its stages.
 ``--top N`` prints the N slowest requests by first-span..last-span wall
 time, grouped by trace id (request uid).
 
@@ -37,6 +40,8 @@ import types
 from pathlib import Path
 
 REPO_ROOT = Path(__file__).resolve().parent.parent
+# step records listed by --summarize: two screens, the newest
+STEP_ROWS = 64
 
 
 def _import_observe():
@@ -108,6 +113,31 @@ def summarize(spans, metrics_mod) -> list[dict]:
     return rows
 
 
+def step_records(spans, dumps) -> list[dict]:
+    """The engine's step records, oldest first and once each: the args of
+    the ring's ``serve.step`` spans and the raw dumps' ``steps`` stores
+    (``Tracer.step_record``), by process and step number."""
+    found = {}
+    for s in spans:
+        if s["name"] == "serve.step":
+            found[s.get("process", "?"), s["args"]["step"]] = s["args"]
+    for d in dumps:
+        for rec in d.get("steps", ()):
+            found.setdefault((d.get("process", "main"), rec["step"]), rec)
+    return [found[key] for key in sorted(found)]
+
+
+def step_line(rec) -> str:
+    """One step on one line: its clocks in ms, its counts, its stages."""
+    gap = "-" if rec["gap"] is None else f"{rec['gap'] * 1e3:.1f}"
+    stages = "; ".join(f"{program} {dt * 1e3:.1f}"
+                       for program, dt in rec["stages"])
+    return (f"{rec['step']:>7}  {rec['wall'] * 1e3:>9.1f}  "
+            f"{rec['host'] * 1e3:>8.1f}  {rec['device_wait'] * 1e3:>8.1f}  "
+            f"{gap:>7}  {rec['chunk_rows']:>4}  {rec['admitted']:>5}  "
+            f"{rec['finished']:>4}  {stages}")
+
+
 def top_requests(spans, n: int) -> list[dict]:
     """The n slowest requests: wall time from a request's first span start
     to its last span end, across every process it touched."""
@@ -144,7 +174,8 @@ def main(argv=None) -> int:
 
     trace_mod, metrics_mod = _import_observe()
     spans, dumps = _collect(args.paths, trace_mod)
-    if not spans:
+    steps = step_records(spans, dumps)
+    if not spans and not (steps and args.summarize):
         # a driver-only or pre-traffic dump directory is a normal state
         # for the read-only views — report it and exit clean so scripted
         # `traceview --summarize` probes don't fail the pipeline
@@ -179,6 +210,14 @@ def main(argv=None) -> int:
             print(f"{r['name']:<{width}}  {r['count']:>6}  "
                   f"{r['total_s']:>10.4f}  {r['p50_ms']:>9.3f}  "
                   f"{r['p95_ms']:>9.3f}")
+        if steps:
+            print(f"\nsteps (newest {min(len(steps), STEP_ROWS)} of "
+                  f"{len(steps)}; ms):")
+            print(f"{'step':>7}  {'wall':>9}  {'host':>8}  {'wait':>8}  "
+                  f"{'gap':>7}  {'rows':>4}  {'admit':>5}  {'done':>4}  "
+                  f"stages")
+            for rec in steps[-STEP_ROWS:]:
+                print(step_line(rec))
 
     if args.top:
         print(f"\ntop {args.top} slowest requests:")
