@@ -931,8 +931,9 @@ class ServingEngine:
         same two), the core of a step of B queries a slot
         (``"gqa_block_decode"``: the same two) and the held experts'
         product (``"moe_experts"``: ``"pallas"`` / ``"pallas_grouped"`` /
-        ``"xla"``, stated per program: it is in both, and an engine's
-        admission programs, one a bucket, may differ — joined by ``+``) and
+        ``"pallas_sorted"`` / ``"xla"``, stated per program: it is in
+        both, and an engine's admission programs, one a bucket, may differ
+        — joined by ``+``) and
         the draw's k-th largest logit (``"sample_kth"``: ``"xla"`` /
         ``"xla_tiled"``, per program too)."""
 

@@ -259,7 +259,7 @@ def moe_share(u, layer, c: DeepSeekV2Config, live):
     stats = {"moe.tokens": jnp.sum(live).astype(F32),
              "moe.held_groups_chosen": mine.astype(F32),
              "moe.held_load": load.astype(F32),
-             **kernel_counters(u, layer["experts"], load)}
+             **kernel_counters(u, layer["experts"], load, c)}
     return y.astype(u.dtype), ids, stats
 
 

@@ -217,6 +217,8 @@ def prefill(stack, blocks, params, tokens, lengths, config, policy: Policy,
     x, stats, chosen, _ = stack(x, params, c, attend, live)
     if "moe.held_load" in stats:
         stats["moe.prefill_held"] = jnp.sum(stats["moe.held_load"])
+        # the rows the lowering passed through the experts for them
+        stats["moe.prefill_rows_computed"] = stats["moe.rows_computed"]
         # a decode step's counters, as ``moe.experts_touched`` beside them
         stats["moe.expert_passes"] = jnp.zeros((), F32)
         stats["moe.rows_computed"] = jnp.zeros((), F32)

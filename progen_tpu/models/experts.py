@@ -17,7 +17,7 @@ The router is as wide as published and picks ``moe_topk`` whatever the chip
 holds; the layer adds the terms of the ``experts_held`` real experts from
 ``first_expert`` on and leaves out what the absent experts would add.
 Nothing stands in for absent chips.  No assignment is ever dropped, and
-:func:`held_experts` computes them in one of three ways, chosen from what
+:func:`held_experts` computes them in one of four ways, chosen from what
 the code can observe and never from a knob (``ops/moe_decode.py:
 fitted_tile``; noted under ``"moe_experts"``, ``ops/lowering.py``).  On a
 TPU with no mesh in scope, tokens and weights of one float type and widths
@@ -35,10 +35,19 @@ on the lane tile, a call's TOKENS decide:
   rows only, gathered into row tiles of 32 — all rows through every expert
   would cost more MXU time than the stream takes from 256 rows on — and
   the terms gathered back per token (:func:`_grouped`);
-* every other call (an admission's thousands of tokens, the CPU, a mesh:
-  ``"xla"``) groups the tokens by held expert (a sort of the assignments)
-  and multiplies them by ``jax.lax.ragged_dot`` in windows of ``capacity``
-  assignments: a window that overflows runs the loop again.
+* an ADMISSION's thousands (more than 1,024 tokens: ``"pallas_sorted"``)
+  go through the kernel ``moe_sorted_fwd`` (:func:`_sorted`): the
+  assignments sorted by held expert, a window of them at a time
+  (:func:`sorted_window`: half of what the tokens would send the held
+  experts if every slot were live; a window that overflows runs the loop
+  again), the window's token rows gathered in sorted order, row tiles of
+  128 of them through the expert whose rows they are — a tile that
+  straddles experts once an expert, nothing for the tiles past the last
+  live row — and the terms scatter-added to their tokens;
+* every other call (the CPU, a mesh, two float types, widths off the lane
+  tile: ``"xla"``) groups the tokens by held expert likewise and multiplies
+  them by ``jax.lax.ragged_dot`` in windows of ``capacity`` assignments
+  (:func:`moe_capacity`): a window that overflows runs the loop again.
 
 A chip may also hold the WHOLE layer (``experts_held == router_width``,
 ``first_expert`` 0: ``models/sdar.py`` and ``models/lfm2.py``, one stage
@@ -65,7 +74,7 @@ F32 = jnp.float32
 # ``trinity.ATTN_STAT_KEYS``) to these
 STAT_KEYS = ("moe.tokens", "moe.held_load", "moe.prefill_held",
              "moe.decode_layers", "moe.experts_touched", "moe.expert_passes",
-             "moe.rows_computed")
+             "moe.rows_computed", "moe.prefill_rows_computed")
 
 
 def zero_stats(keys, held: int) -> dict:
@@ -116,9 +125,10 @@ def held_experts(u, ids, w, live, experts, c, capacity=None):
     load (held,))`` where ``load`` counts the live tokens' assignments to
     each held expert.  Assignments of tokens that are not ``live``
     (padding, finished rows) are not computed.  ``capacity``: assignments
-    per window of the grouped product where that form runs (default
-    :func:`moe_capacity`); a window that overflows runs again, so it
-    changes no result."""
+    per window of the sorted assignments where a form with windows runs
+    (default :func:`moe_capacity` under the XLA form,
+    :func:`sorted_window` under ``moe_sorted_fwd``); a window that
+    overflows runs again, so it changes no result."""
     t, k = ids.shape
     held = c.experts_held
     tiles = moe_decode.fitted_tile(u, experts)
@@ -127,6 +137,8 @@ def held_experts(u, ids, w, live, experts, c, capacity=None):
         local = ids - c.first_expert
         mine = (local >= 0) & (local < held) & live[:, None]
         group = jnp.where(mine, local, held).reshape(-1)
+        if tiles is not None and tiles.sorted:
+            return _sorted(u, group, w, experts, c, tiles, capacity)
         if tiles is not None:
             load = jnp.bincount(group, length=held + 1)[:held]
             form = _streamed if tiles.rows is None else _grouped
@@ -164,6 +176,69 @@ def held_experts(u, ids, w, live, experts, c, capacity=None):
             lambda carry: carry[0] * cap < n_mine, window,
             (jnp.zeros((), jnp.int32), jnp.zeros(u.shape, F32)))
         return y, load
+
+
+def sorted_window(c, tokens: int, k: int, row_tile: int,
+                  capacity=None) -> int:
+    """Rows of one window of the sorted assignments under
+    ``moe_sorted_fwd``: ``capacity``, by default HALF of what the tokens
+    send the held experts if every token slot is live (a third of an
+    admission's slots are: one window a call, as a rule — the kernel's
+    work ends at the live count, but the rows' gather and the terms'
+    scatter-add around it are the window's), in whole row tiles, at least
+    one and never more than hold the ``tokens * k`` assignments."""
+    rows = capacity or (c.moe_topk * c.experts_held * tokens
+                        // (2 * c.router_width))
+    tiles = min(-(-rows // row_tile), -(-(tokens * k) // row_tile))
+    return max(1, tiles) * row_tile
+
+
+def _in_window(starts, ends, base, cap):
+    """Where each expert's sorted rows ``[starts, ends)`` lie inside the
+    window of ``cap`` rows from ``base`` on: ``(lo, hi)``, equal where it
+    has none there."""
+    return jnp.clip(starts - base, 0, cap), jnp.clip(ends - base, 0, cap)
+
+
+def _sorted(u, group, w, experts, c, tiles, capacity=None):
+    """``held_experts`` through ``moe_sorted_fwd``: ``(y, load)``.  The
+    assignments sorted by expert (``group (T k,)``: the held expert, or
+    ``held`` for what is not this chip's or not live), a window of them at
+    a time: the window's token rows gathered in sorted order, ONE kernel
+    call over row tiles of them — a tile that straddles experts visited
+    once an expert, the work ending with the last live row —, and the
+    terms added to their tokens' rows.  (``load`` is a sum of comparisons:
+    on the chip ``bincount``'s scatter of 262,144 ones takes 2.3 ms where
+    this takes 3 us; PERF.md section 6, PR 53.)"""
+    t = u.shape[0]
+    k = group.shape[0] // t
+    order = jnp.argsort(group)                    # held first, by expert
+    load = jnp.sum(group[:, None] == jnp.arange(c.experts_held), axis=0,
+                   dtype=jnp.int32)
+    ends = jnp.cumsum(load)
+    starts, n_mine = ends - load, ends[-1]
+    cap = sorted_window(c, t, k, tiles.rows, capacity)
+    order = jnp.pad(order, (0, -(-(t * k) // cap) * cap - t * k))
+    weights = w.reshape(-1)
+
+    def window(carry):
+        it, y = carry
+        base = it * cap
+        idx = jax.lax.dynamic_slice(order, (base,), (cap,))
+        tok = idx // k
+        out = moe_decode.pallas_sorted_terms(
+            u[tok], weights[idx], *_in_window(starts, ends, base, cap),
+            experts.get("wg"), experts["wu"], experts["wd"],
+            row_tile=tiles.rows, tile=tiles.inner)
+        # what lies past the last real row may be a tile the kernel did
+        # not write: those rows go nowhere
+        valid = base + jnp.arange(cap) < n_mine
+        return it + 1, y.at[jnp.where(valid, tok, t)].add(out, mode="drop")
+
+    _, y = jax.lax.while_loop(
+        lambda carry: carry[0] * cap < n_mine, window,
+        (jnp.zeros((), jnp.int32), jnp.zeros(u.shape, F32)))
+    return y, load
 
 
 def _streamed(u, group, w, load, experts, tiles):
@@ -217,7 +292,7 @@ def _grouped(u, group, w, load, experts, tiles):
     return jnp.sum(jnp.where(mine[..., None], terms, 0.0), axis=1)
 
 
-def kernel_counters(u, experts, load) -> dict:
+def kernel_counters(u, experts, load, c) -> dict:
     """What the lowering :func:`held_experts` takes for ``u`` does, by the
     lowering's own reckoning, as float32 scalars: ``moe.expert_passes``,
     how many times it streams an expert's matrices, and
@@ -226,19 +301,31 @@ def kernel_counters(u, experts, load) -> dict:
     (padded) rows in each; under ``moe_grouped_fwd`` one item a row tile of
     an expert's own rows, and a pass a touched expert where a step holds
     the whole inner width (consecutive items keep the weight blocks), a
-    pass an item where it does not; 0 under the XLA form, whose reads the
-    program cannot know."""
+    pass an item where it does not; under ``moe_sorted_fwd`` one item a row
+    tile of the sorted rows an expert has a row in, window by window, and
+    the passes likewise (an expert whose rows two windows share is streamed
+    in both); 0 under the XLA form, whose reads the program cannot know."""
     tiles = moe_decode.fitted_tile(u, experts)
     touched = jnp.sum(load > 0)
+    whole = tiles is not None and tiles.inner == experts["wu"].shape[-1]
     if tiles is None:
         passes = rows = jnp.zeros((), F32)
     elif tiles.rows is None:
         passes = touched
         rows = touched * (-(-u.shape[0] // moe_decode.ROW_GROUP)
                           * moe_decode.ROW_GROUP)
+    elif tiles.sorted:
+        t, k = u.shape[0], c.moe_topk
+        cap = sorted_window(c, t, k, tiles.rows)
+        base = (jnp.arange(-(-(t * k) // cap)) * cap)[:, None]
+        ends = jnp.cumsum(load)
+        ntile = moe_decode.tiles_an_expert(       # (windows, held)
+            *_in_window(ends - load, ends, base, cap), tiles.rows)
+        items = jnp.sum(ntile)
+        passes = jnp.sum(ntile > 0) if whole else items
+        rows = items * tiles.rows
     else:
         items = jnp.sum(-(-load // tiles.rows))
-        whole = tiles.inner == experts["wu"].shape[-1]
         passes, rows = touched if whole else items, items * tiles.rows
     return {"moe.expert_passes": passes.astype(F32),
             "moe.rows_computed": rows.astype(F32)}

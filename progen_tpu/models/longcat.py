@@ -186,7 +186,7 @@ def moe_share(u, layer, c: LongCatConfig, live):
     stats = {"moe.tokens": jnp.sum(live).astype(F32),
              "moe.real_chosen": real.astype(F32),
              "moe.held_load": load.astype(F32),
-             **kernel_counters(u, layer["experts"], load)}
+             **kernel_counters(u, layer["experts"], load, c)}
     return y.astype(u.dtype), ids, stats
 
 

@@ -382,7 +382,7 @@ def moe_share(u, layer, c: NemotronHConfig, live):
         y = mm(y.astype(u.dtype), layer["latent_out"])
     stats = {"moe.tokens": jnp.sum(live).astype(F32),
              "moe.held_load": load.astype(F32),
-             **kernel_counters(v, layer["experts"], load)}
+             **kernel_counters(v, layer["experts"], load, c)}
     return y, ids, stats
 
 

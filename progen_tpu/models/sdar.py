@@ -297,7 +297,7 @@ def _layers(x, params, c, attend, live, tail=None):
         stats = experts.add_stats(stats, {
             "moe.tokens": jnp.sum(live).astype(F32),
             "moe.held_load": load.astype(F32),
-            **kernel_counters(t, layer["experts"], load)})
+            **kernel_counters(t, layer["experts"], load, c)})
         touched += jnp.sum(load > 0).astype(F32)
         chosen.append(ids)
         x = a + y.astype(x.dtype)

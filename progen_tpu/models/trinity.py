@@ -325,7 +325,7 @@ def moe_share(u, layer, c: TrinityConfig, live):
     y, load = held_experts(u, ids, w, live, layer["experts"], c)
     stats = {"moe.tokens": jnp.sum(live).astype(F32),
              "moe.held_load": load.astype(F32),
-             **kernel_counters(u, layer["experts"], load)}
+             **kernel_counters(u, layer["experts"], load, c)}
     return y.astype(u.dtype), ids, stats
 
 

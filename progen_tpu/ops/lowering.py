@@ -5,7 +5,8 @@ note of which one it took.
 ``ops/gqa.py`` and ``models/experts.py:held_experts`` (its kernels are
 ``ops/moe_decode.py``'s; the note ``"moe_experts"``: ``"pallas"`` for a
 decode step's handful of tokens, ``"pallas_grouped"`` for a block step's
-few hundred, ``"xla"`` for an admission's thousands) each keep a Pallas
+few hundred, ``"pallas_sorted"`` for an admission's thousands, ``"xla"``
+off the chip) each keep a Pallas
 lowering and an XLA form behind one function and choose between them from
 the backend, the mesh in scope and the shapes, never from a knob.
 ``decode/sampler.py:_kth_largest_by_counting`` chooses in the same way

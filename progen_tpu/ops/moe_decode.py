@@ -1,9 +1,11 @@
-"""The held experts' product where the WEIGHTS are the work: every touched
-expert's matrices — gate, up and down, or up and down alone (the two
-expert forms, below) — streamed from HBM once, back to back,
-through one software pipeline — for a decode step's handful of tokens
-(``moe_decode_fwd``) and for a block step's few hundred
-(``moe_grouped_fwd``).
+"""The held experts' product as Pallas kernels, three regimes of one
+contract.  Where the WEIGHTS are the work every touched expert's matrices —
+gate, up and down, or up and down alone (the two expert forms, below) — are
+streamed from HBM once, back to back, through one software pipeline: for a
+decode step's handful of tokens (``moe_decode_fwd``) and for a block
+step's few hundred (``moe_grouped_fwd``).  Where the ROWS are the work — an
+admission's thousands of tokens — an expert's matrices stay while row tiles
+of the rows as sorted by expert stream past them (``moe_sorted_fwd``).
 
 :func:`pallas_expert_terms` takes ``u (T, h)``, a list ``eid (held,)`` of
 the experts to visit (the first ``n_real`` entries are real), each listed
@@ -37,16 +39,38 @@ padding) — and returns each real item's rows through its expert, weighted,
 in the same grouped order; the caller (``models/experts.py:_grouped``)
 gathers the rows in and sums each token's ``k`` rows back by a gather.
 
-Both kernels: grid ``(items, inner / ik)``, one step an ``ik``-wide slice
-of the item's expert: the tiles ``wg[:, cols]``, ``wu[:, cols]`` ``(h,
+Past 1,024 tokens the padded grouping is what costs (its list is sized for
+``T k / 32 + held`` items whatever share of the router the chip holds, and
+every item's rows are gathered into a tile of their own).
+:func:`pallas_sorted_terms` takes the rows as SORTED by expert and not
+padded — ``xs (N, h)``, expert ``e``'s rows at ``[lo[e], hi[e])``, back to
+back — in row tiles of 128 read by the kernel's own ``BlockSpec``: the work
+list (:func:`sorted_work_list`) names ``(expert, row tile)`` items, an
+expert's tiles side by side, a tile that straddles two or three experts
+once an expert with the others' rows selected away; it is as long as the
+tiles that hold a row and the experts that share one (``N / 128 + held - 1``
+at most, read from a prefetched count: a grid step past the last real tile
+fetches and multiplies nothing), so the work follows the LIVE rows while
+the shapes follow the window.  The terms come back in sorted order,
+float32; the caller (``models/experts.py:_sorted``) gathers the rows in and
+scatter-adds the terms to their tokens.  A step holds an expert's whole
+inner width wherever three (two) tiles of it fit ``SORTED_STEP_BYTES``
+(Trinity's 12.6 MB, LFM2's 22, Nemotron-3's 11, SDAR's 9.4), so consecutive
+items of one expert re-use its matrices and only the rows move; DeepSeek-V2's
+47 MB and LongCat's 75 take two and four steps an item.
+
+All three kernels: grid ``(items, inner / ik)``, one step an ``ik``-wide
+slice of the item's expert: the tiles ``wg[:, cols]``, ``wu[:, cols]`` ``(h,
 ik)`` and ``wd[cols] (ik, h)`` arrive together, ``silu(x wg) * (x wu)`` for
 those columns is formed in float32 from compute-dtype operands and cast
 once, and its product with the ``wd`` tile (float32) is weighted in float32
 and added to a float32 output block — the whole resident ``y`` under
 ``moe_decode_fwd`` (the down product's sum over inner tiles and the sum over
 experts are one accumulator), the item's own ``(row_tile, h)`` block under
-``moe_grouped_fwd``.  ``eid`` and ``n_real`` are scalar-prefetched and the
-index maps read them, so while an expert's last tiles are used the next
+``moe_grouped_fwd``, the row tile's block under ``moe_sorted_fwd`` (the
+experts that share a tile add to it in turn).  The work list is
+scalar-prefetched and the index maps read it, so while an expert's last
+tiles are used the next
 expert's first are in flight (the pipeline does not drain between
 experts); consecutive items of one expert name the same weight blocks, so
 where a step holds the whole inner width (SDAR's 768) its second row tile
@@ -96,19 +120,39 @@ ROW_GROUP = 16          # token rows are padded to whole bfloat16 sublane tiles
 # a shorter one more items.  The cell end to end, tokens a second: 2,821 at
 # 16, 2,833 at 32, 2,756 at 64 (PERF.md section 6, PR 42)
 ROW_TILE = 32
-# the most tokens a call may carry and take a kernel at all.  The same
-# layer, XLA form | this kernel: 512 tokens 4.97 | 1.98 ms, 1,024 5.74 |
-# 2.53, 2,048 (under the synthetic skew) 7.34 | 3.99 — the kernel wins
-# wherever it was measured, so the edge is not the kernel's but the largest
-# admission shape measured END TO END with it, 1,024 tokens: against an edge
-# of 512 a cell that holds the layer whole gains 0.6 % of its tokens a
-# second (4 rows x 256: 64 rows an expert) and a cell that holds 1/48 of
+# the most tokens a call may carry and take the GROUPED kernel; above it the
+# sorted one runs.  One layer alone, XLA form | grouped kernel: 512 tokens
+# 4.97 | 1.98 ms, 1,024 5.74 | 2.53, 2,048 (under the synthetic skew) 7.34 |
+# 3.99 — so the edge is not where the grouped kernel stops winning but the
+# largest admission shape measured END TO END with it, 1,024 tokens: against
+# an edge of 512 a cell that holds the layer whole gains 0.6 % of its tokens
+# a second (4 rows x 256: 64 rows an expert) and a cell that holds 1/48 of
 # the router reads the same within its noise (2 rows x 512: 16 rows an
-# expert, and 672 MiB of temporaries under that program's peak).  From
-# 2,048 on an expert's rows are MXU work, and `_grouped`'s static bound of
-# items (T k / ROW_TILE + held, whatever share of the router the chip
-# holds) is the cost to cure first: ROADMAP S9 (b)
+# expert, and 672 MiB of temporaries under that program's peak).  Its list
+# is sized for T k / ROW_TILE + held items whatever share of the router the
+# chip holds and every item's rows are gathered into a tile of their own
+# (PERF.md section 6, PR 42), which is why it does not take the thousands;
+# whether the sorted kernel should take the hundreds too is ROADMAP S11 (c)
 MAX_GROUPED_TOKENS = 1024
+# above MAX_GROUPED_TOKENS (an admission's thousands of tokens) the rows are
+# MXU work: an expert's matrices stay while row tiles of the SORTED rows
+# stream.  One layer alone on a v5e at the cells' admission runs, a third of
+# the slots live, ms a call — the XLA form | the same with a quarter of its
+# window | megablox.gmm three times in that window | this kernel (PERF.md
+# section 6, PR 53): Trinity 4 x 8192 17.07 | 9.09 | 9.66 | 6.27; LFM2 8 x
+# 1024 9.85 | 7.49 | 7.26 | 4.36; Nemotron-3 4 x 1024 12.28 | 9.32 | 6.13 |
+# 3.34; DeepSeek-V2 4 x 512 11.03 | 9.76 | 7.78 | 7.23; LongCat 2 x 2048
+# 5.99 | 5.65 | 4.49 | 3.19; SDAR 4 x 512 6.92 | 5.95 | 5.12 | 2.93.
+# The row tile: 64 | 128 | 256 read 6.30 | 6.27 | 6.21 on Trinity (2,048
+# rows an expert if every slot were live) and 4.42 | 4.36 | 4.39 on LFM2;
+# 32 | 64 | 128 read 3.65 | 3.41 | 3.38 on Nemotron-3 (176), 8.04 | 6.99 |
+# 6.45 on DeepSeek-V2 (77: its expert takes two steps, so an item more is
+# a stream more) and 4.72 | 3.76 | 3.23 on LongCat (64) — the MXU's 128
+# rows are the fastest or tied everywhere, so the tile is a constant.  The
+# step's bytes: twice STEP_BYTES, so that LFM2's expert (22 MB) is one step
+# and its second row tile moves rows only
+SORTED_STEP_BYTES = 24 << 20
+SORTED_ROW_TILE = 128
 
 
 def activation(gate, up):
@@ -191,16 +235,46 @@ def _grouped_kernel(eid_ref, n_ref, x_ref, wt_ref, *refs):
             o_ref[...] += term
 
 
-def inner_tile(h: int, inner: int, itemsize: int, matrices: int = 3) -> int:
+def _sorted_kernel(eid_ref, tile_ref, lo_ref, hi_ref, n_ref, x_ref, wt_ref,
+                   *refs):
+    from jax.experimental import pallas as pl
+
+    *w_refs, o_ref = refs
+    i, s = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(i < n_ref[0])
+    def _():
+        out, w = _item_product(x_ref, wt_ref, w_refs)
+        rt, e, tile = x_ref.shape[0], eid_ref[i], tile_ref[i]
+        row = tile * rt + jax.lax.broadcasted_iota(jnp.int32, (rt, 1), 0)
+        # a tile that straddles experts is visited once an expert: the
+        # others' rows, and what lies past the last real row, add nothing
+        term = jnp.where((row >= lo_ref[e]) & (row < hi_ref[e]), out * w, 0.0)
+        opens = (s == 0) & ((i == 0)
+                            | (tile_ref[jnp.maximum(i - 1, 0)] != tile))
+
+        @pl.when(opens)
+        def _():
+            o_ref[...] = term
+
+        @pl.when(jnp.logical_not(opens))
+        def _():
+            o_ref[...] += term
+
+
+def inner_tile(h: int, inner: int, itemsize: int, matrices: int = 3,
+               step_bytes: int | None = None) -> int:
     """The largest multiple of ``LANE`` that divides ``inner`` and keeps a
     step's tiles — one of each of the expert's ``matrices`` — under
-    ``STEP_BYTES`` (``LANE`` where none does).  Nemotron-H's latent experts
-    (``h`` 1024, inner 2688 = 21 x 128, two matrices, bfloat16): the whole
+    ``step_bytes`` (default ``STEP_BYTES``; ``LANE`` where none does).
+    Nemotron-H's latent experts (``h`` 1024, inner 2688 = 21 x 128, two
+    matrices, bfloat16): the whole
     2688, 11.0 MB a step, one step an expert; counted as three tiles the
     divisors under the budget would end at 896."""
     best = LANE
     for ik in range(LANE, inner + 1, LANE):
-        if inner % ik == 0 and matrices * h * ik * itemsize <= STEP_BYTES:
+        if inner % ik == 0 and matrices * h * ik * itemsize <= (
+                step_bytes or STEP_BYTES):
             best = ik
     return best
 
@@ -222,6 +296,12 @@ def _item_map(i, s, eid_ref, n_ref):
     return jnp.minimum(i, jnp.maximum(n_ref[0] - 1, 0))
 
 
+def _tile_map(i, s, eid_ref, tile_ref, lo_ref, hi_ref, n_ref):
+    """The row tile an item of ``moe_sorted_fwd`` reads and writes (the
+    list repeats its last real item past the end)."""
+    return tile_ref[i], 0
+
+
 def _weight_specs(h, ik, steps, gated: bool):
     """Block specs of the streamed tiles: ``wg`` (where ``gated``), ``wu``
     and ``wd``."""
@@ -231,11 +311,12 @@ def _weight_specs(h, ik, steps, gated: bool):
         # past the list: the last tile, which the last real item left
         return jnp.where(i < n_ref[0], s, steps - 1)
 
-    def gate_up_map(i, s, eid_ref, n_ref):
-        return eid_ref[i], 0, tile_of(i, s, n_ref)
+    # the prefetched lists: the items' experts first, their count last
+    def gate_up_map(i, s, eid_ref, *lists):
+        return eid_ref[i], 0, tile_of(i, s, lists[-1])
 
-    def down_map(i, s, eid_ref, n_ref):
-        return eid_ref[i], tile_of(i, s, n_ref), 0
+    def down_map(i, s, eid_ref, *lists):
+        return eid_ref[i], tile_of(i, s, lists[-1]), 0
 
     return [pl.BlockSpec((None, h, ik), gate_up_map)] * (1 + gated) + [
         pl.BlockSpec((None, ik, h), down_map)]
@@ -352,16 +433,101 @@ def pallas_grouped_terms(xs, eid, n_real, wt, wg, wu, wd, *, row_tile,
     )(eid, n_real, xs, wt.astype(F32)[:, None], *weights)
 
 
+def tiles_an_expert(lo, hi, row_tile: int):
+    """How many row tiles of rows SORTED by expert hold a row of each: the
+    tiles ``[lo, hi)`` reaches into, none where it is empty."""
+    return jnp.where(hi > lo, (hi - 1) // row_tile - lo // row_tile + 1, 0)
+
+
+def sorted_work_list(lo, hi, tiles: int, row_tile: int):
+    """``moe_sorted_fwd``'s work list over rows SORTED by expert, expert
+    ``e``'s at ``[lo[e], hi[e])`` of ``tiles * row_tile`` rows (ascending,
+    back to back): ``(eid, tile, n)`` — item ``i < n`` is expert ``eid[i]``
+    over row tile ``tile[i]``, an expert's tiles side by side in ascending
+    order of expert, so a tile is revisited only by consecutive items; past
+    ``n`` the list repeats its last real item.  An expert with rows takes
+    the tiles it owns and at most one it shares with those before it: at
+    most ``tiles + held - 1`` items whatever the rows hold."""
+    held = lo.shape[0]
+    ntile = tiles_an_expert(lo, hi, row_tile)
+    tile_end = jnp.cumsum(ntile)
+    n = tile_end[-1]
+    item = jnp.minimum(jnp.arange(tiles + held - 1), jnp.maximum(n - 1, 0))
+    eid = jnp.minimum(jnp.searchsorted(tile_end, item, side="right"),
+                      held - 1)
+    tile = (lo // row_tile - (tile_end - ntile))[eid] + item
+    return (eid.astype(jnp.int32),
+            jnp.clip(tile, 0, tiles - 1).astype(jnp.int32),
+            n.astype(jnp.int32).reshape(1))
+
+
+def pallas_sorted_terms(xs, wt, lo, hi, wg, wu, wd, *, row_tile, tile=None,
+                        interpret=None):
+    """The kernel lowering for rows SORTED by expert and not padded: ``xs
+    (N, h)``, ``N`` a multiple of ``row_tile``, expert ``e``'s rows at
+    ``[lo[e], hi[e])`` (``lo``, ``hi (held,)``, ascending and back to back;
+    an expert without rows has ``lo == hi``), ``wt (N,)`` each row's
+    routing weight.  Returns ``(N, h)`` float32: each row through its
+    expert, weighted — the rows of every row tile that holds an expert's
+    row (zero where the row is nobody's); a tile no expert has a row in is
+    NOT WRITTEN, and the work ends with the last expert's last tile: the
+    grid steps past it fetch nothing and multiply nothing.  ``wg`` None
+    for experts of two matrices."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = not _on_tpu()
+    n, h = xs.shape
+    inner = wu.shape[-1]
+    if n % row_tile:
+        raise ValueError(f"{n} rows are not whole tiles of {row_tile}")
+    itemsize = xs.dtype.itemsize
+    weights = [w for w in (wg, wu, wd) if w is not None]
+    ik, steps = _inner_steps(h, inner, itemsize, tile, len(weights))
+    lo, hi = lo.astype(jnp.int32), hi.astype(jnp.int32)
+    eid, tiles, n_real = sorted_work_list(lo, hi, n // row_tile, row_tile)
+
+    vmem = (2 * len(weights) * h * ik * itemsize    # the streamed tiles
+            + 2 * row_tile * h * (itemsize + 4)     # rows in, terms out
+            + 2 * row_tile * h * 4                  # a product, a term
+            + 3 * row_tile * ik * 4 + 2 * row_tile * LANE * 4)
+    return pl.pallas_call(
+        _sorted_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(eid.shape[0], steps),
+            in_specs=[
+                pl.BlockSpec((row_tile, h), _tile_map),
+                pl.BlockSpec((row_tile, 1), _tile_map),
+                *_weight_specs(h, ik, steps, wg is not None),
+            ],
+            out_specs=pl.BlockSpec((row_tile, h), _tile_map),
+        ),
+        out_shape=jax.ShapeDtypeStruct((n, h), F32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem + (8 << 20)),
+        interpret=interpret,
+        name="moe_sorted_fwd",
+    )(eid, tiles, lo, hi, n_real, xs, wt.astype(F32)[:, None], *weights)
+
+
 class Tiles(NamedTuple):
     """What :func:`fitted_tile` fits a kernel call with: the ``inner`` tile
     of a step and the ``rows`` of a row tile — ``None`` where every token
-    goes through every touched expert (``moe_decode_fwd``)."""
+    goes through every touched expert (``moe_decode_fwd``) —, and whether
+    the row tiles are cut from the rows as SORTED (``moe_sorted_fwd``) or
+    from an expert's own rows padded to whole tiles (``moe_grouped_fwd``)."""
     inner: int
     rows: int | None
+    sorted: bool = False
 
     @property
     def lowering(self) -> str:
-        return "pallas" if self.rows is None else "pallas_grouped"
+        if self.rows is None:
+            return "pallas"
+        return "pallas_sorted" if self.sorted else "pallas_grouped"
 
 
 def fitted_tile(u, experts) -> Tiles | None:
@@ -369,10 +535,10 @@ def fitted_tile(u, experts) -> Tiles | None:
     (arrays or their shapes: ``{"wg", "wu", "wd"}``, or ``{"wu", "wd"}``
     for experts without a gate), ``None`` where the XLA form runs: a kernel on
     a TPU backend with no mesh in scope, one 2- or 4-byte float type for
-    tokens and weights, ``h`` and the inner width multiples of ``LANE`` and
-    at most ``MAX_GROUPED_TOKENS`` tokens — ``moe_decode_fwd`` up to
-    ``MAX_TOKENS`` of them, ``moe_grouped_fwd`` in row tiles of
-    ``ROW_TILE`` above."""
+    tokens and weights, ``h`` and the inner width multiples of ``LANE`` —
+    ``moe_decode_fwd`` up to ``MAX_TOKENS`` tokens, ``moe_grouped_fwd`` in
+    row tiles of ``ROW_TILE`` up to ``MAX_GROUPED_TOKENS``,
+    ``moe_sorted_fwd`` in row tiles of ``SORTED_ROW_TILE`` above."""
     dtype = jnp.dtype(u.dtype)
     t, h = u.shape
     inner = experts["wu"].shape[-1]
@@ -380,9 +546,11 @@ def fitted_tile(u, experts) -> Tiles | None:
               and all(jnp.dtype(w.dtype) == dtype for w in experts.values())
               and jnp.issubdtype(dtype, jnp.floating)
               and dtype.itemsize in (2, 4)
-              and h % LANE == 0 and inner % LANE == 0
-              and t <= MAX_GROUPED_TOKENS)
+              and h % LANE == 0 and inner % LANE == 0)
     if not kernel:
         return None
+    if t > MAX_GROUPED_TOKENS:
+        return Tiles(inner_tile(h, inner, dtype.itemsize, len(experts),
+                                SORTED_STEP_BYTES), SORTED_ROW_TILE, True)
     return Tiles(inner_tile(h, inner, dtype.itemsize, len(experts)),
                  None if t <= MAX_TOKENS else ROW_TILE)

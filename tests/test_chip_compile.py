@@ -546,6 +546,49 @@ def test_moe_grouped_kernel_compiles_for_v5e(shape, no_persistent_cache,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("tokens,k,router,h,inner,held,gated", [
+    (32768, 8, 128, 2048, 1024, 16, True), (8192, 4, 32, 2048, 1792, 32, True),
+    (4096, 22, 512, 1024, 2688, 128, False),
+    (2048, 6, 160, 5120, 1536, 40, True), (2048, 8, 128, 2048, 768, 128, True),
+    (4096, 12, 768, 6144, 2048, 16, True)],
+    ids=["trinity-admit-4x8192", "lfm2-admit-8x1024",
+         "nemotron3-admit-4x1024-two-matrix", "dsv2-admit-4x512",
+         "sdar-admit-4x512", "longcat-admit-2x2048"])
+def test_moe_sorted_kernel_compiles_for_v5e(shape, no_persistent_cache,
+                                            monkeypatch, tokens, k, router, h,
+                                            inner, held, gated):
+    """The held experts' product over row tiles of the SORTED rows
+    (``ops/moe_decode.py``, ``moe_sorted_fwd``) at an admission run of each
+    expert cell, over one window of ``experts.sorted_window`` rows with the
+    tiles ``fitted_tile`` gives the chip path: row tiles of 128, Trinity's
+    and LFM2's whole expert a step (12.6 and 22 MB, double-buffered),
+    DeepSeek-V2's and LongCat's in two and four steps, all under the
+    ``vmem_limit_bytes`` the call states."""
+    import types
+
+    from progen_tpu.models import experts
+    from progen_tpu.ops import moe_decode as md
+
+    bf16 = jnp.bfloat16
+    monkeypatch.setattr(md, "_on_tpu", lambda: True)
+    c = types.SimpleNamespace(experts_held=held, first_expert=0, moe_topk=k,
+                              router_width=router)
+    wg, wu, wd = _expert_shapes(shape, held, h, inner, gated)
+    tiles = md.fitted_tile(shape((tokens, h), bf16), {
+        name: w for name, w in (("wg", wg), ("wu", wu), ("wd", wd))
+        if w is not None})
+    assert tiles.sorted
+    rows = experts.sorted_window(c, tokens, k, tiles.rows)
+    fn = jax.jit(lambda xs, wt, lo, hi, wg, wu, wd: md.pallas_sorted_terms(
+        xs, wt, lo, hi, wg, wu, wd, row_tile=tiles.rows, tile=tiles.inner,
+        interpret=False))
+    compiled = fn.lower(
+        shape((rows, h), bf16), shape((rows,), jnp.float32),
+        shape((held,), jnp.int32), shape((held,), jnp.int32), wg, wu,
+        wd).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_trinity_chunk_program_compiles_for_the_chip_and_fits_it(
         shape, no_persistent_cache, monkeypatch):
     """The chunk program of ``serve-trinity-mixedlen-backlog`` over

@@ -206,20 +206,24 @@ def _dense(v, ids, w, live, layer, c):
     ("pallas", 13, dict(lane=8)),
     ("pallas_grouped", 40, dict(lane=8, most=(16, 64), row_tile=8,
                                 step_bytes=2 * 16 * 8 * 4)),
-    ("pallas_grouped", 40, dict(lane=8, most=(16, 64), row_tile=8))],
+    ("pallas_grouped", 40, dict(lane=8, most=(16, 64), row_tile=8)),
+    ("pallas_sorted", 40, dict(lane=8, most=(8, 16), sorted_tile=8,
+                               step_bytes=2 * 16 * 8 * 4)),
+    ("pallas_sorted", 40, dict(lane=8, most=(8, 16), sorted_tile=8))],
     ids=["ragged-windows", "decode-kernel-three-steps",
          "decode-kernel-off-the-row-group", "grouped-kernel-three-steps",
-         "grouped-kernel-one-step"])
+         "grouped-kernel-one-step", "sorted-kernel-three-steps",
+         "sorted-kernel-one-step"])
 @pytest.mark.parametrize("held,first", [(None, 0), (4, 8)],
                          ids=["all-held", "a-share-in-the-middle"])
 def test_two_matrix_experts_through_every_lowering(monkeypatch, lowering,
                                                    tokens, tiles, held,
                                                    first):
     """Experts of ``{"wu", "wd"}`` and ``relu^2``, in the latent's width:
-    the ``ragged_dot`` windows (two products), ``moe_decode_fwd`` and
-    ``moe_grouped_fwd`` under the interpreter, against a dense loop over
-    the held experts.  Tokens that are not live and assignments outside the
-    share add nothing."""
+    the ``ragged_dot`` windows (two products), ``moe_decode_fwd``,
+    ``moe_grouped_fwd`` and ``moe_sorted_fwd`` under the interpreter,
+    against a dense loop over the held experts.  Tokens that are not live
+    and assignments outside the share add nothing."""
     c, layer, _ = _expert_layer(held=held, first=first)
     assert sorted(layer["experts"]) == ["wd", "wu"]
     u = jax.random.normal(jax.random.key(3), (tokens, c.hidden_size))
@@ -232,7 +236,7 @@ def test_two_matrix_experts_through_every_lowering(monkeypatch, lowering,
             record_lowerings() as chosen:
         got, load = experts.held_experts(v, ids, w, live, layer["experts"],
                                          c)
-        counted = experts.kernel_counters(v, layer["experts"], load)
+        counted = experts.kernel_counters(v, layer["experts"], load, c)
     assert chosen["moe_experts"] == {lowering}
     dense = np.asarray(_dense(v, ids, w, live, layer, c))
     assert float(np.abs(dense).max()) > 0.05

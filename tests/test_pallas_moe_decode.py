@@ -1,14 +1,17 @@
-"""``models/experts.py:held_experts`` for a decode step's handful of tokens
-and for a block step's few hundred: three lowerings, one contract.  The
-Pallas kernels (``moe_decode_fwd`` — every token through every touched
-expert — and ``moe_grouped_fwd`` — an expert's own rows in row tiles —,
+"""``models/experts.py:held_experts`` for a decode step's handful of tokens,
+for a block step's few hundred and for an admission's thousands: four
+lowerings, one contract.  The Pallas kernels (``moe_decode_fwd`` — every
+token through every touched expert —, ``moe_grouped_fwd`` — an expert's
+own rows in padded row tiles — and ``moe_sorted_fwd`` — row tiles of the
+rows as sorted, a tile that straddles experts visited once an expert —,
 ``ops/moe_decode.py``) run under the interpreter here, over the tiny
 configurations' own expert weights and routers: against the XLA form
 (windows of ``ragged_dot``) and against a dense float32 loop over the held
 experts, on the cases that break grouped kernels; the choice of lowering
 from backend, mesh and shape at each cell's decode, block-step and
 admission shapes, as ``status()`` shows it; and the counters
-``moe.expert_passes`` and ``moe.rows_computed``."""
+``moe.expert_passes``, ``moe.rows_computed`` and
+``moe.prefill_rows_computed``."""
 
 import dataclasses
 import types
@@ -41,22 +44,28 @@ LAYERS = {**FAMILIES, "sdar": (sdar, sdar_tiny.TINY, sdar_tiny.make, 0)}
 
 
 def _kernel_path(monkeypatch, lane=16, step_bytes=None, row_tile=None,
-                 most=None):
+                 most=None, sorted_tile=None):
     """The chip's choice with the interpreter behind it, at the tiny
     widths: a lane tile of ``lane``, with ``step_bytes`` a limit small
-    enough that an expert takes several steps, with ``row_tile`` row tiles
-    small enough that an expert takes several items, and with ``most =
-    (MAX_TOKENS, MAX_GROUPED_TOKENS)`` other edges of the rule's ranges."""
+    enough that an expert takes several steps (of all three kernels), with
+    ``row_tile`` row tiles small enough that an expert takes several
+    items, with ``most = (MAX_TOKENS, MAX_GROUPED_TOKENS)`` other edges of
+    the rule's ranges, and with ``sorted_tile`` the row tile of
+    ``moe_sorted_fwd``."""
     monkeypatch.setattr(md, "_on_tpu", lambda: True)
     monkeypatch.setattr(md, "LANE", lane)
     if step_bytes:
         monkeypatch.setattr(md, "STEP_BYTES", step_bytes)
+        monkeypatch.setattr(md, "SORTED_STEP_BYTES", step_bytes)
     if row_tile:
         monkeypatch.setattr(md, "ROW_TILE", row_tile)
     if most:
         monkeypatch.setattr(md, "MAX_TOKENS", most[0])
         monkeypatch.setattr(md, "MAX_GROUPED_TOKENS", most[1])
-    for name in ("pallas_expert_terms", "pallas_grouped_terms"):
+    if sorted_tile:
+        monkeypatch.setattr(md, "SORTED_ROW_TILE", sorted_tile)
+    for name in ("pallas_expert_terms", "pallas_grouped_terms",
+                 "pallas_sorted_terms"):
         monkeypatch.setattr(
             md, name, lambda *a, _f=getattr(md, name), **kw: _f(
                 *a, **{**kw, "interpret": True}))
@@ -94,15 +103,17 @@ def _dense(u, ids, w, live, layer, c):
     return y
 
 
-def _both(monkeypatch, u, ids, w, live, layer, c, kernel="pallas", **tiles):
-    """``held_experts`` under the XLA form, then under the kernel."""
+def _both(monkeypatch, u, ids, w, live, layer, c, kernel="pallas",
+          capacity=None, **tiles):
+    """``held_experts`` under the XLA form, then under the kernel (which
+    alone gets ``capacity``, its window's rows)."""
     with jax.default_matmul_precision("highest"):
         want, load = experts.held_experts(u, ids, w, live, layer["experts"],
                                           c)
         _kernel_path(monkeypatch, **tiles)
         with record_lowerings() as chosen:
             got, load2 = experts.held_experts(u, ids, w, live,
-                                              layer["experts"], c)
+                                              layer["experts"], c, capacity)
     assert chosen["moe_experts"] == {kernel}
     assert got.shape == u.shape and got.dtype == F32
     np.testing.assert_array_equal(np.asarray(load), np.asarray(load2))
@@ -451,6 +462,235 @@ def test_grouped_terms_by_hand_over_a_short_list():
                                 row_tile=rt, interpret=True)
 
 
+# ---- an admission's thousands: row tiles of the rows as sorted --------------
+
+def _few_rows_an_expert(t):
+    """One token in four live: about five rows an expert, so a row tile of
+    8 holds two experts' rows and a tile of 16 three."""
+    return jnp.arange(t) % 4 == 0
+
+
+# ``(tokens, live, ids -> ids, rows of a window (None: the rule's), row
+# tile)``; the rule's edges are moved so that these few tokens are an
+# admission (``most=(16, 64)``)
+SORTED_CASES = {
+    "as-routed": (160, lambda t: jnp.arange(t) % 5 != 0, None, None, 8),
+    "tiles-straddle-two-experts": (96, _few_rows_an_expert, None, None, 8),
+    "tiles-straddle-three-experts": (96, _few_rows_an_expert, None, None,
+                                     16),
+    "an-expert-without-rows": (160, lambda t: jnp.arange(t) % 4 != 1,
+                               _second_expert_idle, None, 8),
+    "rows-end-on-a-tiles-edge": (160, lambda t: jnp.ones(t, bool),
+                                 _one_expert_on_a_tiles_edge, None, 8),
+    "all-to-one-expert": (160, lambda t: jnp.arange(t) % 7 != 0,
+                          _all_to_one, None, 8),
+    "no-live-row": (160, lambda t: jnp.zeros(t, bool), None, None, 8),
+    # three tiles a window, then one: the loop runs again and again
+    "a-window-overflows-and-runs-again": (
+        160, lambda t: jnp.arange(t) % 5 != 0, None, 24, 8),
+    "a-window-of-one-tile": (96, lambda t: jnp.arange(t) % 3 != 0, None, 5,
+                             8),
+}
+SORTED = [("sdar", case) for case in SORTED_CASES] + [
+    ("dsv2", "as-routed"), ("dsv2", "tiles-straddle-three-experts"),
+    ("dsv2", "an-expert-without-rows"),
+    ("dsv2", "a-window-overflows-and-runs-again")]
+
+
+def _experts_in_the_fullest_tile(load, rt):
+    """How many experts have a row in one row tile of the sorted rows, at
+    most."""
+    ends = np.cumsum(load)
+    owner = np.repeat(np.arange(len(load)), load)       # a row's expert
+    return max(len(set(owner[i:i + rt])) for i in range(0, ends[-1], rt))
+
+
+@pytest.mark.parametrize("family,case", SORTED,
+                         ids=[f"{f}-{c}" for f, c in SORTED])
+def test_sorted_kernel_equals_the_xla_form_the_oracle_and_the_dense_loop(
+        monkeypatch, family, case):
+    t, live_of, rewrite, capacity, rt = SORTED_CASES[case]
+    held, first = SHARES[family]
+    module, c, layer, _ = _layer(family, held=held, first=first)
+    u = jax.random.normal(jax.random.key(23), (t, c.hidden_size))
+    ids, w = _routed(module, c, layer, u)
+    if rewrite is not None:
+        ids = rewrite(ids, c)
+    live = live_of(t)
+    # two inner steps an item
+    inner = layer["experts"]["wg"].shape[-1]
+    got, want, load = _both(
+        monkeypatch, u, ids, w, live, layer, c, kernel="pallas_sorted",
+        capacity=capacity, lane=8, most=(16, 64), sorted_tile=rt,
+        step_bytes=3 * c.hidden_size * (inner // 2) * 4)
+    assert md.fitted_tile(u, layer["experts"]) == md.Tiles(inner // 2, rt,
+                                                           True)
+    e = layer["experts"]
+    with jax.default_matmul_precision("highest"):
+        oracle = np.asarray(md.xla_expert_terms(
+            u, *_listed_weights(ids, w, live, c), e["wg"], e["wu"], e["wd"]))
+    dense = np.asarray(_dense(u, ids, w, live, layer, c))
+    assert np.isfinite(got).all()
+    if case == "no-live-row":
+        assert load.sum() == 0 and not got.any() and not want.any()
+        return
+    if family == "dsv2":             # assignments below and above the share
+        local = np.asarray(ids) - c.first_expert
+        assert (local < 0).any() and (local >= c.experts_held).any()
+    if case.startswith("tiles-straddle"):
+        most = {"tiles-straddle-two-experts": 2,
+                "tiles-straddle-three-experts": 3}[case]
+        assert _experts_in_the_fullest_tile(load, rt) >= most
+    if case == "an-expert-without-rows":
+        assert load[1] == 0 < load[0]
+    if case == "rows-end-on-a-tiles-edge":
+        assert load[1] == 2 * ROW_TILE
+    if case == "all-to-one-expert":
+        assert load[1] == int(live.sum()) * c.moe_topk == load.sum()
+    if capacity:                                 # more than two windows
+        cap = experts.sorted_window(c, t, c.moe_topk, rt, capacity)
+        assert cap == -(-capacity // rt) * rt and load.sum() > 2 * cap
+    assert float(np.abs(dense).max()) > 0.05
+    assert float(np.abs(got - want).max()) < TOL["float32"]
+    assert float(np.abs(got - oracle).max()) < TOL["float32"]
+    assert float(np.abs(got - dense).max()) < 10 * TOL["float32"]
+    assert not got[~np.asarray(live)].any()
+
+
+@pytest.mark.parametrize("family", list(SHARES))
+def test_sorted_bfloat16_operands_float32_sums(monkeypatch, family):
+    """As the other kernels': within the siblings' bfloat16 bound of the
+    XLA form, and no further from the float32 dense loop than it is."""
+    held, first = SHARES[family]
+    module, c, layer, dtype = _layer(family, mixed=True, held=held,
+                                     first=first)
+    assert dtype == jnp.bfloat16
+    u = jax.random.normal(jax.random.key(25), (256, c.hidden_size)).astype(
+        dtype)
+    ids, w = _routed(module, c, layer, u)
+    live = jnp.arange(256) % 6 != 0
+    got, want, _ = _both(monkeypatch, u, ids, w, live, layer, c,
+                         kernel="pallas_sorted", most=(16, 64),
+                         sorted_tile=16)
+    dense = np.asarray(_dense(u, ids, w, live, layer, c))
+    scale = float(np.abs(dense).max())
+    assert scale > 0.05
+    assert float(np.abs(got - want).max()) < TOL["bfloat16"] * max(1, scale)
+    assert (float(np.abs(got - dense).max())
+            <= float(np.abs(want - dense).max()) + 1e-6)
+
+
+def test_sorted_junk_past_the_live_rows_changes_no_bit(monkeypatch):
+    """Rows that are not ``live`` reach no expert (a NaN there shows
+    nowhere); and in the kernel's own contract what lies past an expert's
+    rows — the rest of the last tile, the tiles after it — is selected
+    away or never visited: over NaN there the kernel writes the bits it
+    writes over zeros, and zeros in the rows of a visited tile that are
+    nobody's."""
+    module, c, layer, _ = _layer("sdar")
+    u = jax.random.normal(jax.random.key(27), (160, c.hidden_size))
+    ids, w = _routed(module, c, layer, u)
+    live = jnp.arange(160) % 4 != 0
+    _kernel_path(monkeypatch, most=(16, 64), sorted_tile=8)
+    with record_lowerings() as chosen:
+        got, again = (experts.held_experts(
+            jnp.where(live[:, None], u, fill), ids, w, live,
+            layer["experts"], c)[0] for fill in (0.0, jnp.nan))
+    assert chosen["moe_experts"] == {"pallas_sorted"}
+    assert float(jnp.abs(got).max()) > 0.05
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(again))
+    # the contract: 5 tiles of 8 rows; experts 1, 4 and 6 hold rows [0, 5),
+    # [5, 13) and [13, 19): tiles 0 to 2 are visited, 3 and 4 are not
+    e = layer["experts"]
+    held = e["wu"].shape[0]
+    xs = jax.random.normal(jax.random.key(29), (40, c.hidden_size))
+    load = jnp.zeros(held, jnp.int32).at[jnp.array([1, 4, 6])].set(
+        jnp.array([5, 8, 6]))
+    hi = jnp.cumsum(load)
+    wt = jnp.full(40, 0.5)
+    rows = jnp.arange(40)[:, None]
+    clean, dirty = (md.pallas_sorted_terms(
+        jnp.where(rows < 19, xs, fill), wt, hi - load, hi, e["wg"], e["wu"],
+        e["wd"], row_tile=8, interpret=True) for fill in (0.0, jnp.nan))
+    np.testing.assert_array_equal(np.asarray(clean)[:24],
+                                  np.asarray(dirty)[:24])
+    assert not np.asarray(clean)[19:24].any()
+    assert np.abs(np.asarray(clean)[:19]).min(axis=-1).max() > 0
+
+
+def test_sorted_work_list_by_hand():
+    """Loads of 5, 0, 6, 9 and 3 rows in tiles of 8: the first expert in
+    tile 0, the third in tiles 0 and 1, the fourth in 1 and 2, the fifth
+    in 2 — six items, tiles in ascending order, and past them the last
+    one again up to the bound of ``tiles + held - 1``."""
+    load = jnp.array([5, 0, 6, 9, 3])
+    hi = jnp.cumsum(load)
+    eid, tile, n = md.sorted_work_list(hi - load, hi, 4, 8)
+    assert int(n[0]) == 6 and eid.shape == tile.shape == (4 + 5 - 1,)
+    assert np.asarray(eid).tolist() == [0, 2, 2, 3, 3, 4, 4, 4]
+    assert np.asarray(tile).tolist() == [0, 0, 1, 1, 2, 2, 2, 2]
+    # no rows at all: no item, and the list names blocks that exist
+    zero = jnp.zeros(5, jnp.int32)
+    eid, tile, n = md.sorted_work_list(zero, zero, 4, 8)
+    assert int(n[0]) == 0
+    assert 0 <= int(eid.min()) and int(eid.max()) < 5
+    assert 0 <= int(tile.min()) and int(tile.max()) < 4
+    # every tile full and every expert but the first starting inside one:
+    # the bound is met
+    load = jnp.array([3, 8, 8, 8, 5])
+    hi = jnp.cumsum(load)
+    assert int(md.sorted_work_list(hi - load, hi, 4, 8)[2][0]) == 4 + 5 - 1
+
+
+@pytest.mark.parametrize("gated", [True, False],
+                         ids=["three-matrices", "two-matrices"])
+def test_sorted_terms_by_hand_over_a_short_list(gated):
+    """``pallas_sorted_terms``'s own contract: row ``r`` of the sorted
+    rows through the expert whose ``[lo, hi)`` holds it, weighted — both
+    expert forms, in one or in several inner steps; an expert without rows
+    is not read, and the rows of a visited tile that are nobody's are
+    zero."""
+    h, inner, held, rt = 256, 384, 6, 8
+    ks = jax.random.split(jax.random.key(2), 6)
+    xs = jax.random.normal(ks[0], (5 * rt, h))
+    wg = jax.random.normal(ks[1], (held, h, inner)) * h ** -0.5
+    wu = jax.random.normal(ks[2], (held, h, inner)) * h ** -0.5
+    wd = jax.random.normal(ks[3], (held, inner, h)) * inner ** -0.5
+    wt = jax.random.uniform(ks[4], (5 * rt,)) + 0.1
+    load = jnp.array([3, 0, 12, 7, 0, 5])       # 27 rows: tiles 0 to 3
+    hi = jnp.cumsum(load)
+    owner = np.repeat(np.arange(held), np.asarray(load))
+
+    def expert(x, e):
+        if gated:
+            return (jax.nn.silu(x @ wg[e]) * (x @ wu[e])) @ wd[e]
+        return jnp.square(jax.nn.relu(x @ wu[e])) @ wd[e]
+
+    with jax.default_matmul_precision("highest"):
+        by_hand = jnp.stack([wt[r] * expert(xs[r], e)
+                             for r, e in enumerate(owner)])
+        for tile in (128, 384, None):
+            got = md.pallas_sorted_terms(
+                xs, wt, hi - load, hi, wg if gated else None, wu, wd,
+                row_tile=rt, tile=tile, interpret=True)
+            assert got.shape == (5 * rt, h) and got.dtype == F32
+            assert float(jnp.abs(got[:27] - by_hand).max()) < TOL["float32"]
+            assert not np.asarray(got[27:32]).any()
+        # a NaN expert without rows is not read
+        poisoned = md.pallas_sorted_terms(
+            xs, wt, hi - load, hi, wg.at[1].set(jnp.nan) if gated else None,
+            wu.at[4].set(jnp.nan), wd, row_tile=rt, interpret=True)
+    np.testing.assert_array_equal(np.asarray(poisoned[:32]),
+                                  np.asarray(got[:32]))
+    assert float(jnp.abs(by_hand).max()) > 0.1
+    with pytest.raises(ValueError, match="does not divide"):
+        md.pallas_sorted_terms(xs, wt, hi - load, hi, wg, wu, wd,
+                               row_tile=rt, tile=256, interpret=True)
+    with pytest.raises(ValueError, match="whole tiles of"):
+        md.pallas_sorted_terms(xs[:-1], wt[:-1], hi - load, hi, wg, wu, wd,
+                               row_tile=rt, interpret=True)
+
+
 # ---- which lowering, and where it is stated --------------------------------
 
 # tokens of a decode call and of the smallest admission run, hidden and
@@ -490,38 +730,38 @@ def _lowering(t, h, inner, held, k=6, router=160, **kw):
 
 @pytest.mark.parametrize("tokens,want", [
     (64 * 4, "pallas_grouped"), (4 * 128, "pallas_grouped"),
-    (4 * 256, "pallas_grouped"), (4 * 512, "xla"), (4 * 1024, "xla")],
+    (4 * 256, "pallas_grouped"), (4 * 512, "pallas_sorted"),
+    (4 * 1024, "pallas_sorted")],
     ids=["block-step", "admit-128", "admit-256", "admit-512", "admit-1024"])
 def test_on_tpu_sdars_block_step_takes_the_grouped_kernel(monkeypatch,
                                                           tokens, want):
     """The cell's block step (256 tokens) and its two smallest admission
     shapes (512 and 1,024 tokens) carry few rows an expert and take
     ``moe_grouped_fwd`` — no ``ragged_dot``, no window loop, no scatter-add
-    of rows; from 2,048 tokens on a call is the XLA form."""
+    of rows; from 2,048 tokens on a call is ``moe_sorted_fwd`` inside the
+    window loop, with its scatter-add and without ``ragged_dot``."""
     monkeypatch.setattr(md, "_on_tpu", lambda: True)
     paths, jaxpr = _lowering(tokens, **SDAR)
     assert paths == {want}
-    if want == "xla":
-        assert "pallas_call" not in jaxpr and "ragged_dot" in jaxpr
+    assert jaxpr.count("pallas_call") == 1 and "ragged_dot" not in jaxpr
+    assert md.inner_tile(SDAR["h"], SDAR["inner"], 2) == SDAR["inner"]
+    if want == "pallas_sorted":
+        assert "name=moe_sorted_fwd" in jaxpr and "while" in jaxpr
         return
-    assert jaxpr.count("pallas_call") == 1
-    assert "name=moe_grouped_fwd" in jaxpr
-    for gone in ("ragged_dot", "while"):
-        assert gone not in jaxpr
+    assert "name=moe_grouped_fwd" in jaxpr and "while" not in jaxpr
     # the one scatter-add is ``load``'s bincount over the assignments
     assert jaxpr.count("= scatter") == 1
     assert f"i32[{SDAR['held'] + 1}] = scatter-add" in jaxpr
-    assert md.inner_tile(SDAR["h"], SDAR["inner"], 2) == SDAR["inner"]
 
 
 @pytest.mark.parametrize("cell", list(CELLS))
 def test_on_tpu_decode_takes_the_kernel_and_admission_by_its_tokens(
         monkeypatch, cell):
     """A sibling cell's decode step is ``moe_decode_fwd``; its smallest
-    admission the XLA form from 2,048 tokens on (DeepSeek-V2's and
+    admission ``moe_sorted_fwd`` from 2,048 tokens on (DeepSeek-V2's and
     Trinity's 4 rows x 512) and ``moe_grouped_fwd`` under that (LongCat's 2
-    rows x 512 = 1,024: ``MAX_GROUPED_TOKENS``); every larger bucket is the
-    XLA form."""
+    rows x 512 = 1,024: ``MAX_GROUPED_TOKENS``); every larger bucket is
+    ``moe_sorted_fwd``."""
     decode, admit, h, inner, held = CELLS[cell]
     monkeypatch.setattr(md, "_on_tpu", lambda: True)
     paths, jaxpr = _lowering(decode, h, inner, held)
@@ -533,21 +773,88 @@ def test_on_tpu_decode_takes_the_kernel_and_admission_by_its_tokens(
         assert cell == "longcat" and paths == {"pallas_grouped"}
         assert "name=moe_grouped_fwd" in jaxpr and "ragged_dot" not in jaxpr
         paths, jaxpr = _lowering(2 * admit, h, inner, held)
-    assert paths == {"xla"}
-    assert "pallas_call" not in jaxpr and "ragged_dot" in jaxpr
+    assert paths == {"pallas_sorted"}
+    assert jaxpr.count("pallas_call") == 1 and "ragged_dot" not in jaxpr
+    assert "name=moe_sorted_fwd" in jaxpr and "while" in jaxpr
     # and the tile the cell's widths get: whole, under the byte limit
     ik = md.inner_tile(h, inner, 2)
     assert ik == {"dsv2": 384, "longcat": 256, "trinity": 1024}[cell]
     assert inner % ik == 0 and 3 * h * ik * 2 <= md.STEP_BYTES
 
 
-def test_an_admission_traces_to_the_same_text_whatever_the_backend(
-        monkeypatch):
-    """A call too large for the kernel is today's code and nothing else:
-    with the chip's choice forced its trace is the CPU's, line for line."""
-    here = _lowering(2048, 256, 128, 8)[1]
+# an admission run of each expert cell (PERF.md section 4): ``(tokens, h,
+# inner, held, k, router width, matrices)``, the inner tile the rule gives
+# it and the rows of its window
+ADMISSIONS = {
+    "trinity-4x8192": ((32768, 2048, 1024, 16, 8, 128, 3), 1024, 16384),
+    "trinity-4x512": ((2048, 2048, 1024, 16, 8, 128, 3), 1024, 1024),
+    "lfm2-8x1024": ((8192, 2048, 1792, 32, 4, 32, 3), 1792, 16384),
+    "lfm2-8x256": ((2048, 2048, 1792, 32, 4, 32, 3), 1792, 4096),
+    "nemotron3-4x1024": ((4096, 1024, 2688, 128, 22, 512, 2), 2688, 11264),
+    "dsv2-4x512": ((2048, 5120, 1536, 40, 6, 160, 3), 768, 1536),
+    "sdar-4x512": ((2048, 2048, 768, 128, 8, 128, 3), 768, 8192),
+    "longcat-2x2048": ((4096, 6144, 2048, 16, 12, 768, 3), 512, 512),
+}
+
+
+@pytest.mark.parametrize("run", list(ADMISSIONS))
+def test_on_tpu_an_admissions_tiles_and_window(monkeypatch, run):
+    """``moe_sorted_fwd`` at the cells' admission runs: row tiles of the
+    MXU's 128 rows whatever an expert gets (one layer alone on the chip:
+    128 is the fastest or tied from LongCat's 64 rows an expert to
+    Trinity's 2,048), the inner tile the widest under
+    ``SORTED_STEP_BYTES`` — Trinity's, LFM2's, Nemotron-3's and SDAR's
+    whole expert a step, so its matrices stay while its row tiles stream
+    —, and a window of half the rows the tokens would send the held
+    experts if every slot were live."""
+    (t, h, inner, held, k, router, matrices), ik, window = ADMISSIONS[run]
     monkeypatch.setattr(md, "_on_tpu", lambda: True)
-    assert _lowering(2048, 256, 128, 8)[1] == here
+    u, e = _shapes(t, h, inner, held)
+    if matrices == 2:
+        del e["wg"]
+    c = types.SimpleNamespace(experts_held=held, first_expert=0, moe_topk=k,
+                              router_width=router)
+    tiles = md.fitted_tile(u, e)
+    assert tiles == md.Tiles(ik, 128, True)
+    assert tiles.lowering == "pallas_sorted"
+    assert matrices * h * ik * 2 <= md.SORTED_STEP_BYTES
+    assert (ik == inner) == (run.split("-")[0] in (
+        "trinity", "lfm2", "nemotron3", "sdar"))
+    assert experts.sorted_window(c, t, k, tiles.rows) == window
+    assert window % tiles.rows == 0 and window <= t * k
+
+
+def test_sorted_window_by_hand():
+    """Half the expectation with every slot live, in whole row tiles: at
+    least one, never more than hold the assignments; ``capacity``
+    overrides the rule and is rounded up to tiles."""
+    c = types.SimpleNamespace(experts_held=16, first_expert=0, moe_topk=8,
+                              router_width=128)
+    assert experts.sorted_window(c, 32768, 8, 128) == 16384
+    assert experts.sorted_window(c, 1100, 8, 128) == 640       # 550 -> 5
+    assert experts.sorted_window(c, 40, 8, 128) == 128         # 20 -> 1
+    assert experts.sorted_window(c, 40, 1, 128, 5000) == 128   # 40 rows
+    assert experts.sorted_window(c, 32768, 8, 128, 24) == 128
+    assert experts.sorted_window(c, 32768, 8, 8, 24) == 24
+
+
+@pytest.mark.parametrize("why", ["a-mesh-in-scope", "two-types"])
+def test_an_admission_traces_to_the_same_text_whatever_the_backend(
+        monkeypatch, devices8, why):
+    """Where the rule keeps the XLA form on a chip — a mesh in scope,
+    tokens and weights of two types — an admission is the CPU's code and
+    nothing else: with the chip's choice forced its trace is the CPU's,
+    line for line."""
+    kw = {"weights": jnp.float32} if why == "two-types" else {}
+    here = _lowering(2048, 256, 128, 8, **kw)
+    assert here[0] == {"xla"}
+    monkeypatch.setattr(md, "_on_tpu", lambda: True)
+    if why == "a-mesh-in-scope":
+        with jax.sharding.Mesh(np.asarray(devices8[:2]), ("data",)):
+            assert _lowering(2048, 256, 128, 8) == here
+    else:
+        assert _lowering(2048, 256, 128, 8, **kw) == here
+    assert _lowering(2048, 256, 128, 8)[0] == {"pallas_sorted"}
 
 
 @pytest.mark.parametrize("shape,kw,want", [
@@ -555,20 +862,24 @@ def test_an_admission_traces_to_the_same_text_whatever_the_backend(
     ((129, 256, 128, 8), {}, "pallas_grouped"),         # one more
     ((256, 256, 128, 8), {}, "pallas_grouped"),
     ((md.MAX_GROUPED_TOKENS, 256, 128, 8), {}, "pallas_grouped"),
-    ((md.MAX_GROUPED_TOKENS + 1, 256, 128, 8), {}, "xla"),
-    ((2048, 256, 128, 8), {}, "xla"),
-    ((32768, 256, 128, 8), {}, "xla"),                  # Trinity's long runs
+    ((md.MAX_GROUPED_TOKENS + 1, 256, 128, 8), {}, "pallas_sorted"),
+    ((2048, 256, 128, 8), {}, "pallas_sorted"),
+    ((32768, 256, 128, 8), {}, "pallas_sorted"),        # Trinity's long runs
     ((64, 256, 128, 8), {"dtype": jnp.float32}, "pallas"),
     ((256, 256, 128, 8), {"dtype": jnp.float32}, "pallas_grouped"),
     ((64, 256, 128, 8), {"weights": jnp.float32}, "xla"),   # two types
     ((256, 256, 128, 8), {"weights": jnp.float32}, "xla"),
+    ((2048, 256, 128, 8), {"weights": jnp.float32}, "xla"),
     ((64, 200, 128, 8), {}, "xla"),                     # h off the lane tile
     ((256, 200, 128, 8), {}, "xla"),
+    ((2048, 200, 128, 8), {}, "xla"),
     ((64, 256, 96, 8), {}, "xla"),                      # the inner width
+    ((2048, 256, 96, 8), {}, "xla"),
     ((4, 32, 16, 8), {"dtype": jnp.float32}, "xla"),    # the tests' TINY
 ], ids=["t-128", "t-129", "t-256", "t-most-grouped", "t-one-more", "t-2048",
         "t-32768", "float32", "float32-256", "f32-weights",
-        "f32-weights-256", "h-200", "h-200-256", "inner-96", "tiny"])
+        "f32-weights-256", "f32-weights-2048", "h-200", "h-200-256",
+        "h-200-2048", "inner-96", "inner-96-2048", "tiny"])
 def test_on_tpu_the_shape_decides(monkeypatch, shape, kw, want):
     monkeypatch.setattr(md, "_on_tpu", lambda: True)
     assert md.MAX_TOKENS == 128 < md.MAX_GROUPED_TOKENS < 2048
@@ -577,11 +888,12 @@ def test_on_tpu_the_shape_decides(monkeypatch, shape, kw, want):
     assert ("pallas_call" in jaxpr) == (want != "xla")
     if want != "xla":
         kernel = {"pallas": "moe_decode_fwd",
-                  "pallas_grouped": "moe_grouped_fwd"}[want]
+                  "pallas_grouped": "moe_grouped_fwd",
+                  "pallas_sorted": "moe_sorted_fwd"}[want]
         assert f"name={kernel}" in jaxpr
 
 
-@pytest.mark.parametrize("tokens", [64, 256])
+@pytest.mark.parametrize("tokens", [64, 256, 2048])
 def test_a_mesh_in_scope_keeps_todays_form(monkeypatch, devices8, tokens):
     monkeypatch.setattr(md, "_on_tpu", lambda: True)
     mesh = jax.sharding.Mesh(np.asarray(devices8[:2]), ("data",))
@@ -612,7 +924,7 @@ def test_expert_passes_by_hand(monkeypatch):
     assert np.asarray(load).tolist() == [0, 3, 3, 0, 0, 0, 0, 0, 0, 1, 0, 0]
 
     def counted(load, tokens=u):
-        got = experts.kernel_counters(tokens, layer["experts"], load)
+        got = experts.kernel_counters(tokens, layer["experts"], load, c)
         assert sorted(got) == ["moe.expert_passes", "moe.rows_computed"]
         assert all(v.dtype == F32 and v.shape == () for v in got.values())
         return float(got["moe.expert_passes"]), float(
@@ -650,11 +962,82 @@ def test_grouped_counters_by_hand(monkeypatch, steps, passes):
                                        layer["experts"], c)
     assert chosen["moe_experts"] == {"pallas_grouped"}
     assert np.asarray(load).tolist() == [0, 9, 8, 0, 0, 1, 17, 0]
-    got = experts.kernel_counters(u, layer["experts"], load)
+    got = experts.kernel_counters(u, layer["experts"], load, c)
     assert float(got["moe.expert_passes"]) == passes
     assert float(got["moe.rows_computed"]) == (2 + 1 + 1 + 3) * 8
     want = _dense(u, ids, jnp.ones((t, 1), F32), live, layer, c)
     assert float(jnp.abs(y - want).max()) < 10 * TOL["float32"]
+
+
+@pytest.mark.parametrize("steps,passes", [(1, 5), (2, 2 + 2 + 1 + 3)],
+                         ids=["whole-inner-width", "two-inner-steps"])
+def test_sorted_counters_by_hand(monkeypatch, steps, passes):
+    """One item a row tile an expert has a row in: 9, 8, 1 and 17 rows,
+    sorted back to back at [0, 9), [9, 17), [17, 18) and [18, 35), in
+    tiles of 8 and windows of 24 rows (half of 35, in whole tiles): the
+    first window holds 2 + 2 + 1 + 1 items — tiles 1 and 2 are visited by
+    two and by three experts —, the second the last expert's other 11
+    rows in 2: 64 rows through the MXU for 35 assignments.  An expert's
+    matrices are fetched once a window it has rows in where a step holds
+    the whole inner width (the last expert twice), once an ITEM where it
+    does not."""
+    module, c, layer, _ = _layer("sdar")
+    inner = layer["experts"]["wg"].shape[-1]
+    _kernel_path(monkeypatch, lane=8, most=(4, 16), sorted_tile=8,
+                 step_bytes=3 * c.hidden_size * (inner // steps) * 4)
+    rows = {1: 9, 2: 8, 5: 1, 6: 17}
+    ids = jnp.concatenate([jnp.full((n, 1), e) for e, n in rows.items()])
+    t = ids.shape[0]
+    assert t == 35 > md.MAX_GROUPED_TOKENS
+    u = jax.random.normal(jax.random.key(31), (t, c.hidden_size))
+    c = dataclasses.replace(c, num_experts_per_tok=1)
+    live = jnp.ones(t, bool)
+    with record_lowerings() as chosen:
+        y, load = experts.held_experts(u, ids, jnp.ones((t, 1), F32), live,
+                                       layer["experts"], c)
+    assert chosen["moe_experts"] == {"pallas_sorted"}
+    assert np.asarray(load).tolist() == [0, 9, 8, 0, 0, 1, 17, 0]
+    got = experts.kernel_counters(u, layer["experts"], load, c)
+    assert experts.sorted_window(c, t, 1, 8) == 24
+    assert float(got["moe.expert_passes"]) == passes
+    assert float(got["moe.rows_computed"]) == (2 + 2 + 1 + 1 + 2) * 8
+    want = _dense(u, ids, jnp.ones((t, 1), F32), live, layer, c)
+    assert float(jnp.abs(y - want).max()) < 10 * TOL["float32"]
+
+
+def test_a_prefill_counts_the_rows_its_lowering_computed(monkeypatch):
+    """``moe.prefill_rows_computed`` beside ``moe.prefill_held``: the rows
+    an admission's lowering passed through the experts over the rows that
+    had an assignment.  Nothing under the XLA form, whose products the
+    program cannot know; under ``moe_sorted_fwd`` whole row tiles, at
+    least the assignments and, a layer, less than a tile more an expert
+    and a straddled tile more an expert; the decode counters stay 0 in a
+    prefill."""
+    module, config, make, _ = FAMILIES["trinity"]
+    params, policy = make(config)
+    assert "moe.prefill_rows_computed" in experts.STAT_KEYS
+    tokens = jnp.arange(48, dtype=jnp.int32).reshape(2, 24) % 50 + 3
+    lengths = jnp.array([24, 9])
+
+    def prefill():
+        return module.prefill(params, tokens, lengths, config, policy)[2]
+
+    before = prefill()
+    assert float(before["moe.prefill_held"]) > 0
+    assert float(before["moe.prefill_rows_computed"]) == 0
+    rt = 8
+    _kernel_path(monkeypatch, lane=8, most=(4, 16), sorted_tile=rt)
+    with record_lowerings() as chosen:
+        after = prefill()
+    assert chosen["moe_experts"] == {"pallas_sorted"}
+    held, rows = (float(after[k]) for k in ("moe.prefill_held",
+                                            "moe.prefill_rows_computed"))
+    assert held == float(before["moe.prefill_held"])
+    expert_layers = config.num_hidden_layers - config.num_dense_layers
+    assert rows % rt == 0
+    assert held <= rows < held + expert_layers * config.experts_held * 2 * rt
+    assert float(after["moe.rows_computed"]) == 0
+    assert float(after["moe.expert_passes"]) == 0
 
 
 @pytest.mark.parametrize("family", list(FAMILIES))
@@ -780,3 +1163,35 @@ def test_cpu_notes_xla_and_the_engine_states_it_per_program():
         admit(jnp.zeros(tokens))
     assert eng.status()["moe_experts"] == {"chunk": "xla",
                                            "admit": "pallas_grouped+xla"}
+
+
+def test_the_admission_program_names_the_sorted_kernel(monkeypatch):
+    """With the chip's choice forced and the rule's edges at the engine's
+    slots, the chunk program (32 tokens) takes ``moe_decode_fwd`` and the
+    admission program (2 rows x 32 = 64 token slots) the sorted kernel:
+    ``engine.program_lowerings`` and ``status()`` say so, and the registry
+    carries the admission's rows beside its assignments."""
+    from progen_tpu.decode import Request, ServingEngine
+    from progen_tpu.decode.engine import SLOTS_PER_ADMIT_ROW
+    from progen_tpu.observe.metrics import get_registry
+
+    _, config, make, _ = FAMILIES["dsv2"]
+    params, policy = make(config)
+    slots = 2 * SLOTS_PER_ADMIT_ROW
+    _kernel_path(monkeypatch, lane=8, most=(slots, slots),
+                 sorted_tile=8)
+    eng = ServingEngine(config, params, policy=policy, num_slots=slots,
+                        chunk_size=4, max_len=32)
+    eng.submit(Request(uid=0, tokens=list(range(3, 23)), max_new_tokens=3,
+                       temperature=0.0, seed=1))
+    (done,) = eng.run_until_idle(max_chunks=10)
+    assert done.uid == 0
+    assert eng.program_lowerings["admit"]["moe_experts"] == "pallas_sorted"
+    assert eng.status()["moe_experts"] == {"chunk": "pallas",
+                                           "admit": "pallas_sorted"}
+    snap = get_registry().snapshot()
+    held = snap["moe.prefill_held"]["value"]
+    rows = snap["moe.prefill_rows_computed"]["value"]
+    # 20 real tokens, 3 of 16 experts each, all held, two expert layers
+    assert held == 20 * 3 * 2
+    assert rows % 8 == 0 and held <= rows < held + 2 * 16 * 2 * 8
