@@ -177,7 +177,10 @@ class ProGenFamily:
 # holds whole expert layers too, a token a step, under mixers whose whole
 # cache is a convolution's tail beside a few blocks of grown keys; the
 # seventh, Nemotron-H, a share again, in layers that are ONE sublayer each:
-# a state block, a grown-key block, or an expert layer that states no cache
+# a state block, a grown-key block, or an expert layer that states no cache;
+# the eighth, MiMo-V2, a share under two kinds of attention with two head
+# shapes: a short ring under a learned sink beside grown keys, the keys
+# wider than the values
 _DRIVER_FAMILIES = (
     ("progen_tpu.models.longcat", "LongCatConfig", "LongCatFamily"),
     ("progen_tpu.models.deepseek_v2", "DeepSeekV2Config", "DeepSeekV2Family"),
@@ -187,6 +190,7 @@ _DRIVER_FAMILIES = (
     ("progen_tpu.models.sdar", "SDARConfig", "SDARFamily"),
     ("progen_tpu.models.lfm2", "LFM2Config", "LFM2Family"),
     ("progen_tpu.models.nemotron_h", "NemotronHConfig", "NemotronHFamily"),
+    ("progen_tpu.models.mimo_v2", "MiMoV2Config", "MiMoV2Family"),
 )
 
 

@@ -72,6 +72,7 @@ import jax
 import jax.numpy as jnp
 
 from progen_tpu.core.precision import Policy, make_policy
+from progen_tpu.models import kv
 from progen_tpu.models.experts import zero_stats
 
 F32 = jnp.float32
@@ -407,6 +408,9 @@ class Family:
         """Registry gauges from the fetched counters (cumulative since the
         engine was built): name -> value."""
         out = {k: float(v) for k, v in stats.items() if k != "moe.held_load"}
+        # the cache bytes the grouped-query blocks read, from their rows
+        out.update(kv.byte_gauges(self.blocks, out,
+                                  self.policy.compute_dtype))
         load = stats.get("moe.held_load")
         if load is not None:
             out["moe.held_assignments"] = float(load.sum())

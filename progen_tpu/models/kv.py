@@ -1,18 +1,24 @@
 """A grouped-query attention block that caches its keys and values, and
 what it states about that cache (``models/driver.py`` says what a block
 is).  Shared by the families whose attention is grouped-query
-(``models/trinity.py``, ``models/granite_hybrid.py``): a family brings the
-PROJECTION (:meth:`KVBlock.project` and :meth:`KVBlock.finish`: its
-matrices, norms, rotation, gate and output) and the scale of its scores;
-the cache's layout, the prefill's rows laid out as a slot's cache and the
-decode step's write-then-attend are here.  The attention cores are
-``ops/gqa.py``.
+(``models/trinity.py``, ``models/granite_hybrid.py``, ``models/sdar.py``,
+``models/lfm2.py``, ``models/nemotron_h.py``, ``models/mimo_v2.py``): a
+family brings the PROJECTION (:meth:`KVBlock.project` and
+:meth:`KVBlock.finish`: its matrices, norms, rotation, gate and output) and
+the scale of its scores; the cache's layout, the prefill's rows laid out as
+a slot's cache and the decode step's write-then-attend are here.  The
+attention cores are ``ops/gqa.py``.
 
-Both kinds of cache hold ``{"k", "v"}: (slots, KV, rows, d)`` — the token
-axis second to last, so that the chip tiles ``(rows, d)`` and the step's
-write is ``ops/row_write.py``'s kernel — and both are read by one decode
-core (``ops/gqa.py:decode_attention``) up to a per-slot count.  They differ
-in two lines:
+Both kinds of cache hold ``{"k": (slots, KV, rows, d), "v": (slots, KV,
+rows, dv)}`` — the token axis second to last, so that the chip tiles
+``(rows, d)`` and the step's write is ``ops/row_write.py``'s kernel — and
+both are read by one decode core (``ops/gqa.py:decode_attention``) up to a
+per-slot count.  ``dv`` is ``d`` unless the family says otherwise
+(``v_head_dim``: MiMo's values are 128 wide beside keys of 192); the two
+arrays are then written by a call each, since one call of the row write
+takes arrays of one shape.  A block with a ``sink`` hands its layer's
+``p["sink"] (H,)`` to both cores: one more term of every softmax
+(``ops/gqa.py``).  They differ in two lines:
 
 * a windowed block's cache is a RING of ``min(window, max_len)`` rows: the
   token at position ``p`` lies in row ``p % rows`` and a slot at ``pos``
@@ -48,20 +54,30 @@ F32 = jnp.float32
 DECODE_STAT_KEYS = ("attn.decode_rows", "attn.context_tokens",
                     "attn.window_tokens", "attn.window_rows_read",
                     "attn.full_rows_read")
+# what :func:`byte_gauges` derives from the two ``*_rows_read`` counters
+# when they are published: no counter of their own rides in the scan
+BYTE_GAUGES = ("attn.window_bytes_read", "attn.full_bytes_read")
 
 
 class KVBlock:
-    """One attention block of ``kv_heads`` key/value heads of ``head_dim``,
-    scores scaled by ``scale``: ``window`` rows in a ring, or ``max_len``
-    rows that grow with the request (``window`` None).  A family's subclass
+    """One attention block of ``kv_heads`` key/value heads of ``head_dim``
+    (values ``v_head_dim`` wide where that is given), scores scaled by
+    ``scale``: ``window`` rows in a ring, or ``max_len`` rows that grow with
+    the request (``window`` None).  ``sink``: the layer's weights hold
+    ``"sink" (H,)``, a learned term of every softmax.  A family's subclass
     has :meth:`project` and :meth:`finish`."""
 
     def __init__(self, kv_heads: int, head_dim: int, scale: float,
-                 window: int | None = None, block: int = 1):
+                 window: int | None = None, block: int = 1, *,
+                 v_head_dim: int | None = None, sink: bool = False):
         if block != 1 and window is not None:
             raise ValueError("a block mask goes with grown keys, not a ring")
+        if block != 1 and (sink or v_head_dim not in (None, head_dim)):
+            raise ValueError("the block form takes one width and no sink")
         self.kv_heads = kv_heads
         self.head_dim = head_dim
+        self.v_head_dim = head_dim if v_head_dim is None else v_head_dim
+        self.sink = sink
         self.scale = scale
         self.window = window
         # the prefill's mask: causal (1), or causal across blocks of
@@ -71,14 +87,14 @@ class KVBlock:
 
     def project(self, x, p, positions):
         """``x (R, n, h)`` at ``positions (R, n)`` -> ``(q (R, n, H, d), k
-        (R, n, KV, d), v (R, n, KV, d), rest)``; ``rest`` is whatever else
+        (R, n, KV, d), v (R, n, KV, dv), rest)``; ``rest`` is whatever else
         of the token :meth:`finish` takes (a gate; None), leaves ``(R, n,
         ...)``."""
         raise NotImplementedError
 
     def finish(self, o, rest, p):
         """The block's output ``(..., h)`` from the core's ``o (..., H *
-        d)`` and ``rest`` as :meth:`project` gave it (a decode step passes
+        dv)`` and ``rest`` as :meth:`project` gave it (a decode step passes
         both without the token axis)."""
         raise NotImplementedError
 
@@ -93,20 +109,30 @@ class KVBlock:
             return pos, pos + 1
         return pos % rows, jnp.minimum(pos + 1, rows)
 
+    def row_bytes(self, dtype) -> int:
+        """One token's key and value in this block's cache."""
+        return (self.kv_heads * (self.head_dim + self.v_head_dim)
+                * jnp.dtype(dtype).itemsize)
+
+    def _sink(self, p) -> dict:
+        """The cores' ``sink`` argument, where the block has one."""
+        return {"sink": p["sink"]} if self.sink else {}
+
     def init_cache(self, slots: int, max_len: int, dtype):
-        shape = (slots, self.kv_heads, self.rows(max_len), self.head_dim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        shape = (slots, self.kv_heads, self.rows(max_len))
+        return {"k": jnp.zeros(shape + (self.head_dim,), dtype),
+                "v": jnp.zeros(shape + (self.v_head_dim,), dtype)}
 
     def prefill(self, x, p, lengths):
-        """Attention over ``x (R, P, h)``; the per-token rows are ``{"k",
-        "v"}: (R, KV, P, d)``."""
+        """Attention over ``x (R, P, h)``; the per-token rows are ``{"k":
+        (R, KV, P, d), "v": (R, KV, P, dv)}``."""
         r, n, _ = x.shape
         positions = jnp.broadcast_to(jnp.arange(n), (r, n))
         q, k, v, rest = self.project(x, p, positions)
         k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
         with jax.named_scope(self.scope):
             o = gqa.prefill_attention(q, k, v, self.scale, self.window,
-                                      lengths, self.block)
+                                      lengths, self.block, **self._sink(p))
         return self.finish(o, rest, p), {"k": k, "v": v}
 
     def cache_rows(self, rows, lengths, max_len: int):
@@ -133,13 +159,17 @@ class KVBlock:
         attended."""
         q, k, v, rest = self.project(x[:, None], p, pos[:, None])
         at, counts = self.place(pos, cache["k"].shape[2])
+        k, v = (k[:, 0].astype(cache["k"].dtype),
+                v[:, 0].astype(cache["v"].dtype))
         with jax.named_scope(self.scope):
-            keys, values = write_rows(
-                (cache["k"], cache["v"]),
-                (k[:, 0].astype(cache["k"].dtype),
-                 v[:, 0].astype(cache["v"].dtype)), at, axis=1)
+            if self.v_head_dim == self.head_dim:
+                keys, values = write_rows((cache["k"], cache["v"]), (k, v),
+                                          at, axis=1)
+            else:       # one call takes arrays of one shape
+                keys = write_rows(cache["k"], k, at, axis=1)
+                values = write_rows(cache["v"], v, at, axis=1)
             o = gqa.decode_attention(q[:, 0], keys, values, counts,
-                                     self.scale)
+                                     self.scale, **self._sink(p))
         rest = jax.tree.map(lambda a: a[:, 0], rest)
         return self.finish(o, rest, p), {"k": keys, "v": values}
 
@@ -210,9 +240,10 @@ def decode_stats(blocks: dict, caches, pos, live, block_form=False) -> dict:
         if name is None:
             continue
         k, v = caches[name]["k"], caches[name]["v"]
-        # the step's query is in the caches' dtype (``driver.Family``)
-        lowering = (gqa.block_decode_lowering if block_form
-                    else gqa.decode_lowering)(k.dtype, k, v)
+        # the step's query is in the caches' dtype (``driver.Family``); a
+        # sink, like two widths, keeps the XLA form (``gqa.decode_lowering``)
+        lowering = (gqa.block_decode_lowering(k.dtype, k, v) if block_form
+                    else gqa.decode_lowering(k.dtype, k, v, kv[name].sink))
         counts = None if lowering == "xla" else kv[name].place(
             pos, k.shape[2])[1]
         stats[f"attn.{kind}_rows_read"] = (
@@ -221,6 +252,48 @@ def decode_stats(blocks: dict, caches, pos, live, block_form=False) -> dict:
             stats["attn.window_tokens"] = jnp.sum(jnp.minimum(
                 seen, k.shape[2])).astype(F32)
     return stats
+
+
+PREFILL_STAT_KEYS = ("attn.prefill_pairs_allowed",
+                     "attn.prefill_pairs_visited")
+
+
+def prefill_stats(blocks: dict, n: int, lengths, dt) -> dict:
+    """A prefill's ``attn.prefill_pairs_*`` counters over rows of
+    ``lengths (R,)`` padded to ``n``, for a family whose blocks are all
+    :class:`KVBlock`s under the causal mask: the query-key pairs the mask
+    allows at real positions and the pairs the lowering that runs computes
+    (``ops/gqa.py``), summed over the attention blocks, per head."""
+    allowed = visited = jnp.zeros((), F32)
+    for block in blocks.values():
+        lowering = gqa.prefill_lowering(
+            n, block.head_dim, dt, block.window, dv=block.v_head_dim,
+            sink=block.sink)
+        allowed += gqa.pairs_allowed(lengths, block.window)
+        visited += gqa.pairs_visited(lengths, n, block.window, lowering)
+    return {"attn.prefill_pairs_allowed": allowed,
+            "attn.prefill_pairs_visited": visited}
+
+
+def byte_gauges(blocks: dict, gauges: dict, dtype) -> dict:
+    """``attn.window_bytes_read`` / ``attn.full_bytes_read`` from the
+    published row counters (host side, when the engine fetches them):
+    ``attn.<kind>_rows_read`` counts the cache rows ONE block of a kind
+    reads, so the bytes ALL blocks of that kind read are that times the
+    kind's own row bytes (:meth:`KVBlock.row_bytes`: keys and values at
+    their own widths) times the blocks of the kind.  The row bytes are
+    static, so the product needs no counter in the chunk's scan and no
+    family's program changes for it.  ``{}`` for a family without a
+    :class:`KVBlock` or without the counters."""
+    out = {}
+    kv = [b for b in blocks.values() if isinstance(b, KVBlock)]
+    for kind in ("window", "full"):
+        mine = [b for b in kv if (b.window is None) == (kind == "full")]
+        rows = gauges.get(f"attn.{kind}_rows_read")
+        if kv and rows is not None:
+            out[f"attn.{kind}_bytes_read"] = float(rows) * sum(
+                b.row_bytes(dtype) for b in mine)
+    return out
 
 
 def block_decode_stats(blocks: dict, caches, pos0, live, b: int,

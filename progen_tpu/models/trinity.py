@@ -78,7 +78,6 @@ from progen_tpu.models.driver import (  # noqa: F401
     swiglu,
 )
 from progen_tpu.models.experts import held_experts, kernel_counters
-from progen_tpu.ops import gqa
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -284,24 +283,9 @@ def blocks_of(c: TrinityConfig) -> dict:
     return {f"l{i}": kinds[kind] for i, kind in enumerate(c.layer_types)}
 
 
-ATTN_STAT_KEYS = kv.DECODE_STAT_KEYS + ("attn.prefill_pairs_allowed",
-                                        "attn.prefill_pairs_visited")
+ATTN_STAT_KEYS = kv.DECODE_STAT_KEYS + kv.PREFILL_STAT_KEYS
 attention_stats = kv.decode_stats
-
-
-def prefill_attention_stats(blocks: dict, n: int, lengths, dt) -> dict:
-    """A prefill's ``attn.prefill_pairs_*`` counters over rows of
-    ``lengths (R,)`` padded to ``n``: the query-key pairs the mask allows
-    at real positions and the pairs the lowering that runs computes
-    (``ops/gqa.py``), summed over the attention blocks, per head."""
-    allowed = visited = jnp.zeros((), F32)
-    for block in blocks.values():
-        lowering = gqa.prefill_lowering(n, block.config.head_dim, dt,
-                                        block.window)
-        allowed += gqa.pairs_allowed(lengths, block.window)
-        visited += gqa.pairs_visited(lengths, n, block.window, lowering)
-    return {"attn.prefill_pairs_allowed": allowed,
-            "attn.prefill_pairs_visited": visited}
+prefill_attention_stats = kv.prefill_stats
 
 
 # ------------------------------------------------------------------ experts

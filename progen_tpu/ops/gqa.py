@@ -12,7 +12,8 @@ queries that see each other.
 d)``, ``lengths (R,)`` leading positions of each row real: position ``i``
 sees ``j`` iff ``j <= i``, under a ``window`` ``i - j < window``, and ``j <
 length``.  It returns the heads' outputs laid out as the output projection
-reads them, ``(R, P, H * d)``.  The contract is the output AT REAL
+reads them, ``(R, P, H * d)`` (``H * dv`` where the values are another width, below).
+The contract is the output AT REAL
 POSITIONS (a real query sees real keys only, because attention is causal);
 a pad position's output is finite and otherwise unspecified — nothing
 downstream of a prefill reads it.  Two lowerings keep that contract, chosen
@@ -161,6 +162,26 @@ nothing else (:func:`block_decode_lowering`):
 Which one a traced call took is noted under ``"gqa_block_decode"``, and
 :func:`rows_visited` counts its rows as the one-query core's (a count of 0
 is no tile).
+
+**Two widths and a sink** (``models/mimo_v2.py``; PR 54).  The prefill core
+and the one-query decode core take values of ANOTHER WIDTH than the keys —
+``k (..., T, d)`` beside ``v (..., T, dv)``, the output ``H * dv`` columns —
+and an optional learned ``sink (H,)``: one more term of every query's
+softmax, a float a head, which takes mass and has no value — ``m = max(max_j
+s_ij, sink_h)``, ``p_ij = exp(s_ij - m) / (sum_j exp(s_ij - m) + exp(sink_h
+- m))``.  Only the XLA forms take them (the blocked form adds the term
+after the block's maximum, the decode form appends one column to the
+scores and drops it before the value product): **the kernels are not
+extended**, and :func:`prefill_lowering` / :func:`decode_lowering` answer
+``"xla"`` for a sink or ``dv != d`` as they do for a key width that is no
+multiple of 128 (MiMo's 192) and a ring shorter than ``MIN_TILE`` rows
+(MiMo's 128) — each alone keeps that family off the kernels on a TPU.  A
+window SHORTER than ``QUERY_BLOCK`` costs the blocked form a block of
+``QUERY_BLOCK + window`` keys for every ``QUERY_BLOCK`` rows, most of it
+masked; :func:`pairs_visited` counts it as it is.  Without a sink and with
+``dv == d`` every function traces the program it traced before the
+arguments existed (``tests/test_program_identity.py``).  The block mask and
+the block form of the decode step take neither.
 """
 
 from __future__ import annotations
@@ -190,10 +211,13 @@ MASKED = -0.7 * float(jnp.finfo(F32).max)   # a masked score: finite
 # ------------------------------------------------------ the blocked XLA form
 
 
-def _score_block(q, k, v, first_row, first_key, scale, window, block=1):
+def _score_block(q, k, v, first_row, first_key, scale, window, block=1,
+                 sink=None):
     """``q (R, KV, G, bq, d)`` at rows ``first_row + arange(bq)`` against
-    ``k, v (R, KV, t, d)`` at positions ``first_key + arange(t)`` (a
-    position below 0 is padding): ``(R, KV, G, bq, d)``."""
+    ``k (R, KV, t, d)``, ``v (R, KV, t, dv)`` at positions ``first_key +
+    arange(t)`` (a position below 0 is padding): ``(R, KV, G, bq, dv)``.
+    ``sink (KV, G, 1, 1)`` float32: one more term of every row's softmax,
+    which takes mass and has no value."""
     logits = jnp.einsum("rkgqd,rktd->rkgqt", q, k,
                         preferred_element_type=F32) * scale
     at = first_key + jnp.arange(k.shape[2])[None, :]
@@ -213,10 +237,16 @@ def _score_block(q, k, v, first_row, first_key, scale, window, block=1):
     # 1,098 ms through ``jax.nn.softmax`` (PERF.md section 6, PR 34).
     # Every row sees its own key, so the maximum is finite
     logits = jnp.where(seen, logits, -jnp.inf)
-    p = jnp.exp(logits - jnp.max(logits, axis=-1, keepdims=True))
+    top = jnp.max(logits, axis=-1, keepdims=True)
+    if sink is not None:        # the extra term, after the block's maximum
+        top = jnp.maximum(top, sink)
+    p = jnp.exp(logits - top)
     out = jnp.einsum("rkgqt,rktd->rkgqd", p.astype(v.dtype), v,
                      preferred_element_type=F32)
-    return (out / jnp.sum(p, axis=-1)[..., None]).astype(q.dtype)
+    total = jnp.sum(p, axis=-1)
+    if sink is not None:
+        total = total + jnp.exp(sink - top)[..., 0]
+    return (out / total[..., None]).astype(q.dtype)
 
 
 def _rows(x, start, size):
@@ -237,12 +267,16 @@ def _blocked_bodies(n: int, window) -> list:
             for first in range(0, blocks, FULL_GROUP)]
 
 
-def blocked_prefill_attention(q, k, v, scale, window=None, block=1):
+def blocked_prefill_attention(q, k, v, scale, window=None, block=1,
+                              sink=None):
     """The XLA form: every position of every row computed, the score
     tensor ``(R, KV, G, QUERY_BLOCK, keys)`` float32.  ``block``: the block
     mask's length (a query block holds whole ones, so the keys a block of
-    query rows can see end where the causal mask's do)."""
+    query rows can see end where the causal mask's do).  ``v``'s heads may
+    be another width than ``q``'s and ``k``'s (the output is ``H * dv``
+    wide); ``sink (H,)`` adds its term to every row's softmax."""
     r, n, heads, d = q.shape
+    dv = v.shape[-1]
     if block != 1 and (window is not None or QUERY_BLOCK % block
                        or n % block):
         raise ValueError(
@@ -250,6 +284,8 @@ def blocked_prefill_attention(q, k, v, scale, window=None, block=1):
             f"divides the query block of {QUERY_BLOCK} and the {n} "
             "positions (a last block cut short would see the padding)")
     kv = k.shape[1]
+    if sink is not None:
+        sink = sink.astype(F32).reshape(kv, heads // kv, 1, 1)
     bq = min(QUERY_BLOCK, n)
     blocks = -(-n // bq)
     pad = blocks * bq - n
@@ -266,7 +302,7 @@ def blocked_prefill_attention(q, k, v, scale, window=None, block=1):
             s = i * bq
             return _score_block(_rows(q, s, bq), _rows(k, s, back + bq),
                                 _rows(v, s, back + bq), s, s - back, scale,
-                                window)
+                                window, sink=sink)
 
         out = jax.lax.map(body, jnp.arange(blocks))
     else:
@@ -276,12 +312,12 @@ def blocked_prefill_attention(q, k, v, scale, window=None, block=1):
 
             def body(i, keys=keys, values=values):
                 return _score_block(_rows(q, i * bq, bq), keys, values,
-                                    i * bq, 0, scale, None, block)
+                                    i * bq, 0, scale, None, block, sink)
 
             outs.append(jax.lax.map(body, jnp.arange(first, first + count)))
         out = jnp.concatenate(outs, axis=0)
-    # (blocks, R, KV, G, bq, d) -> (R, P, H * d)
-    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(r, blocks * bq, heads * d)
+    # (blocks, R, KV, G, bq, dv) -> (R, P, H * dv)
+    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(r, blocks * bq, heads * dv)
     return out[:, :n]
 
 
@@ -484,11 +520,13 @@ def _kernel_takes(dtype) -> bool:
             and jnp.dtype(dtype).itemsize in (2, 4))
 
 
-def prefill_lowering(n: int, d: int, dtype, window, block: int = 1) -> str:
+def prefill_lowering(n: int, d: int, dtype, window, block: int = 1, *,
+                     dv: int | None = None, sink: bool = False) -> str:
     """``"pallas"`` or ``"xla"``: what :func:`prefill_attention` takes for
-    ``n`` positions of heads ``d`` wide in ``dtype``, traced here and now
-    (the module docstring has the rule)."""
-    kernel = (_kernel_takes(dtype)
+    ``n`` positions of heads ``d`` wide (values ``dv`` wide: default ``d``)
+    in ``dtype``, with a ``sink`` or without, traced here and now (the
+    module docstring has the rule)."""
+    kernel = (_kernel_takes(dtype) and not sink and dv in (None, d)
               and d % 128 == 0 and n % MIN_TILE == 0
               and (window is None or window % fitted_tile(n) == 0)
               and (block == 1 or (window is None and not block & (block - 1)
@@ -496,16 +534,19 @@ def prefill_lowering(n: int, d: int, dtype, window, block: int = 1) -> str:
     return "pallas" if kernel else "xla"
 
 
-def prefill_attention(q, k, v, scale, window=None, lengths=None, block=1):
-    """``(R, P, H * d)`` in ``q``'s dtype, exact at the first ``lengths
+def prefill_attention(q, k, v, scale, window=None, lengths=None, block=1,
+                      sink=None):
+    """``(R, P, H * dv)`` in ``q``'s dtype, exact at the first ``lengths
     (R,)`` positions of each row (default: all ``P``).  ``block``: 1 for
     the causal mask, else the block mask's length (``lengths`` then whole
-    blocks).  The lowering is chosen as the module docstring says."""
+    blocks).  ``sink (H,)``: a learned term of every row's softmax beside
+    its keys.  The lowering is chosen as the module docstring says."""
     r, n, heads, d = q.shape
-    lowering = prefill_lowering(n, d, q.dtype, window, block)
+    lowering = prefill_lowering(n, d, q.dtype, window, block,
+                                dv=v.shape[-1], sink=sink is not None)
     note("gqa_prefill", lowering)
     if lowering == "xla":
-        return blocked_prefill_attention(q, k, v, scale, window, block)
+        return blocked_prefill_attention(q, k, v, scale, window, block, sink)
     if lengths is None:
         lengths = jnp.full((r,), n, jnp.int32)
     # the causal call is the one it was (callers wrap it with that signature)
@@ -557,20 +598,27 @@ def pairs_visited(lengths, n: int, window, lowering: str):
 # ------------------------------------------------------------------- decode
 
 
-def xla_decode_attention(q, k, v, counts, scale):
+def xla_decode_attention(q, k, v, counts, scale, sink=None):
     """The XLA form: scores over the whole static cache in float32.
-    ``(S, H * d)`` in ``q``'s dtype."""
+    ``(S, H * dv)`` in ``q``'s dtype.  ``sink (H,)``: one more column of
+    the softmax, dropped before the value product."""
     s, heads, d = q.shape
     kv, t = k.shape[1], k.shape[2]
     q = q.reshape(s, kv, heads // kv, d)
     logits = jnp.einsum("skgd,sktd->skgt", q, k.astype(q.dtype),
                         preferred_element_type=F32) * scale
     seen = jnp.arange(t)[None, :] < counts[:, None]
-    probs = jax.nn.softmax(
-        jnp.where(seen[:, None, None], logits, -jnp.inf), axis=-1)
+    logits = jnp.where(seen[:, None, None], logits, -jnp.inf)
+    if sink is None:
+        probs = jax.nn.softmax(logits, axis=-1)
+    else:
+        column = jnp.broadcast_to(
+            sink.astype(F32).reshape(kv, heads // kv, 1), (s, kv, heads // kv, 1))
+        probs = jax.nn.softmax(
+            jnp.concatenate([logits, column], axis=-1), axis=-1)[..., :t]
     out = jnp.einsum("skgt,sktd->skgd", probs.astype(q.dtype),
                      v.astype(q.dtype), preferred_element_type=F32)
-    return out.astype(q.dtype).reshape(s, heads * d)
+    return out.astype(q.dtype).reshape(s, heads * v.shape[-1])
 
 
 def _cache_tile(count, k0, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *,
@@ -713,25 +761,28 @@ def _decode_call(q, k, v, counts, *, scale, bk, interpret):
     return out.reshape(s, heads * d)
 
 
-def decode_lowering(q_dtype, k, v) -> str:
+def decode_lowering(q_dtype, k, v, sink: bool = False) -> str:
     """``"pallas"`` or ``"xla"``: what :func:`decode_attention` takes for
     a query of ``q_dtype`` over caches ``k`` and ``v`` (arrays or their
-    shapes), traced here and now (the module docstring has the rule)."""
+    shapes), with a ``sink`` or without, traced here and now (the module
+    docstring has the rule)."""
     dtype = jnp.dtype(q_dtype)
-    kernel = (_kernel_takes(dtype)
+    kernel = (_kernel_takes(dtype) and not sink
+              and k.shape[3] == v.shape[3]
               and dtype == jnp.dtype(k.dtype) == jnp.dtype(v.dtype)
               and k.shape[3] % 128 == 0 and k.shape[2] % MIN_TILE == 0)
     return "pallas" if kernel else "xla"
 
 
-def decode_attention(q, k, v, counts, scale):
-    """``(S, H * d)`` in ``q``'s dtype: ``q (S, H, d)`` over the first
-    ``counts (S,)`` (each at least 1) rows of ``k, v (S, KV, T, d)``.  The
-    lowering is chosen as the module docstring says."""
-    lowering = decode_lowering(q.dtype, k, v)
+def decode_attention(q, k, v, counts, scale, sink=None):
+    """``(S, H * dv)`` in ``q``'s dtype: ``q (S, H, d)`` over the first
+    ``counts (S,)`` (each at least 1) rows of ``k (S, KV, T, d)`` and ``v
+    (S, KV, T, dv)``, with the learned term ``sink (H,)`` in the softmax
+    where given.  The lowering is chosen as the module docstring says."""
+    lowering = decode_lowering(q.dtype, k, v, sink is not None)
     note("gqa_decode", lowering)
     if lowering == "xla":
-        return xla_decode_attention(q, k, v, counts, scale)
+        return xla_decode_attention(q, k, v, counts, scale, sink)
     return pallas_decode_attention(q, k, v, counts, scale)
 
 

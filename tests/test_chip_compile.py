@@ -927,3 +927,82 @@ def test_nemotron_programs_compile_for_the_chip_and_fit_it(
     assert "tpu_custom_call" in text
     if program == "chunk":
         assert "moe_decode_fwd" in text and "gqa_decode_fwd" in text
+
+
+# ---- MiMo-V2's whole programs at published widths ----
+
+
+@pytest.fixture(scope="module")
+def mimo_engine():
+    """The engine of ``serve-mimo-longdoc-backlog`` over ABSTRACT weights
+    (its 16 slots' state is real, on the host: 1.5 GB of zeros)."""
+    from progen_tpu.decode.engine import ServingEngine
+    from progen_tpu.models import mimo_v2
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "perf",
+                           "configs", "mimo-v2.5-ep16.json")) as f:
+        c = mimo_v2.MiMoV2Config.from_dict(json.load(f))
+    policy = mimo_v2.bf16_policy()
+    params = jax.eval_shape(lambda k: mimo_v2.init_params(c, k, policy),
+                            jax.random.key(0))
+    return ServingEngine(c, params, policy=policy, num_slots=16,
+                         chunk_size=32, max_len=17408)
+
+
+@pytest.mark.parametrize("program", ["chunk", "admit-16384"])
+def test_mimo_programs_compile_for_the_chip_and_fit_it(
+        shape, mimo_engine, program, no_persistent_cache, monkeypatch):
+    """Layers 0-6 of 48 (the dense layer and one whole period: 5 sliding
+    layers of 8 key/value heads under a sink, 2 full ones of 4, keys 192
+    wide beside values of 128; 16 of 256 experts), an eighth of the
+    vocabulary, 16 slots of 128-row rings and 17,408 grown rows: the chunk
+    program (32 steps of every slot: 16 tokens a call through
+    ``moe_decode_fwd``, BOTH attention cores the XLA forms — no
+    ``gqa_decode_fwd`` in the text) and the admission of 1 row at the
+    16,384 bucket (through ``moe_sorted_fwd``; the blocked XLA attention,
+    no ``gqa_prefill_fwd``), as the chip traces them.  Arguments, results
+    and temporaries together stay under the chip's 16 GiB: the engine's
+    programs do not donate their state, so it is there twice."""
+    from progen_tpu.decode import sampler
+    from progen_tpu.ops import gqa, lowering, moe_decode, row_write
+
+    for module in (row_write, gqa, moe_decode, sampler):
+        monkeypatch.setattr(module, "_on_tpu", lambda: True)
+    monkeypatch.setattr(lowering, "on_tpu", lambda: True)
+    eng = mimo_engine
+
+    def placed(tree):
+        return jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+
+    params, state = placed(eng._params), placed(eng.state)
+    s, rows, lay = eng.num_slots, eng.admit_rows, eng._layout
+    assert rows == 1
+    if program == "chunk":
+        compiled = jax.jit(lambda *a: eng._decode_chunk_impl(*a)).lower(
+            params, state, *placed(lay.chunk_operands())).compile()
+    else:
+        prefill = [shape((rows, 16384), jnp.int32), shape((rows,), jnp.int32),
+                   shape((rows,), jnp.int32), shape((rows,), jnp.uint32),
+                   shape((rows,), jnp.int32), shape((rows,), jnp.float32),
+                   shape(eng._lmask_shape(rows), jnp.bool_)]
+        compiled = jax.jit(lambda *a: eng._admit_impl(*a)).lower(
+            params, state, shape((s,), jnp.int32), shape((s,), jnp.bool_),
+            *prefill, *placed(lay.write_tables(rows))).compile()
+    m = compiled.memory_analysis()
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
+    assert 6.85e9 < weights < 6.87e9 and 1.4e9 < held < 1.6e9, (weights,
+                                                                 held)
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    print(f"mimo {program}: weights {weights / 1e9:.2f} GB, state "
+          f"{held / 1e9:.2f} GB, temporaries {m.temp_size_in_bytes / 1e9:.2f}"
+          f" GB, total {total / 1e9:.2f} GB")
+    assert weights + 2 * held <= total < 15.5e9, m
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "gqa_decode_fwd" not in text and "gqa_prefill_fwd" not in text
+    if program == "chunk":
+        assert "moe_decode_fwd" in text and "row_write" in text
+    else:
+        assert "moe_sorted_fwd" in text
