@@ -16,9 +16,13 @@ both are read by one decode core (``ops/gqa.py:decode_attention``) up to a
 per-slot count.  ``dv`` is ``d`` unless the family says otherwise
 (``v_head_dim``: MiMo's values are 128 wide beside keys of 192); the two
 arrays are then written by a call each, since one call of the row write
-takes arrays of one shape.  A block with a ``sink`` hands its layer's
-``p["sink"] (H,)`` to both cores: one more term of every softmax
-(``ops/gqa.py``).  They differ in two lines:
+takes arrays of one shape, and read by the same decode core — on a TPU its
+kernel ``gqa_decode_fwd`` takes the two widths (a grown cache's rows up to
+a slot's count, in key tiles).  A block with a ``sink`` hands its layer's
+``p["sink"] (H,)`` to both cores: one more term of every softmax, which
+only their XLA forms have; a ring under ``gqa.MIN_TILE`` rows keeps the XLA
+decode core too (``ops/gqa.py``: "Two widths and a sink").  They differ in
+two lines:
 
 * a windowed block's cache is a RING of ``min(window, max_len)`` rows: the
   token at position ``p`` lies in row ``p % rows`` and a slot at ``pos``
@@ -241,7 +245,7 @@ def decode_stats(blocks: dict, caches, pos, live, block_form=False) -> dict:
             continue
         k, v = caches[name]["k"], caches[name]["v"]
         # the step's query is in the caches' dtype (``driver.Family``); a
-        # sink, like two widths, keeps the XLA form (``gqa.decode_lowering``)
+        # sink keeps the XLA form (``gqa.decode_lowering``)
         lowering = (gqa.block_decode_lowering(k.dtype, k, v) if block_form
                     else gqa.decode_lowering(k.dtype, k, v, kv[name].sink))
         counts = None if lowering == "xla" else kv[name].place(

@@ -62,15 +62,19 @@ layer adds the terms of the held experts (``first_expert .. first_expert +
 experts_held - 1``) and leaves out the absent ones'; attention and the dense
 layer are whole on every chip.
 
-**On the chip both kinds take the XLA attention cores**
-(``ServingEngine.status()["gqa_prefill"]`` / ``["gqa_decode"]`` read
-``"xla"``): a key width of 192 is no multiple of the lane tile, the values
-are another width, the sliding kind has a sink and a ring of 128 rows, under
-``gqa.MIN_TILE`` — each alone is enough (``ops/gqa.py``'s docstring).  So a
-decode step reads every row of both kinds of cache, and a prefill of a
-sliding layer computes ``QUERY_BLOCK + 128`` keys for every ``QUERY_BLOCK``
-rows; the counters (``attn.*_rows_read``, ``attn.*_bytes_read``,
-``attn.prefill_pairs_visited``) say what that costs.
+**On the chip the full layers' decode core is the kernel**
+``gqa_decode_fwd`` (PR 55: it takes keys 192 wide beside values of 128 and
+reads a slot's grown rows up to its count), and everything else of the
+attention the XLA forms: the sliding kind's decode core (a sink, and a ring
+of 128 rows, under ``gqa.MIN_TILE`` — each alone is enough) and both kinds'
+prefill core (a key width of 192 is no multiple of the lane tile, the values
+are another width, the sliding kind has a sink: ``ops/gqa.py``'s
+docstring).  ``ServingEngine.status()["gqa_decode"]`` reads ``"pallas+xla"``
+and ``["gqa_prefill"]`` ``"xla"``.  So a decode step reads every row of the
+RINGS only, and a prefill of a sliding layer computes ``QUERY_BLOCK + 128``
+keys for every ``QUERY_BLOCK`` rows; the counters (``attn.*_rows_read``,
+``attn.*_bytes_read``, ``attn.prefill_pairs_visited``) say what that
+costs.
 """
 
 from __future__ import annotations

@@ -81,34 +81,38 @@ order of its terms).  ``counts`` is at least 1 everywhere (a decode step
 has just written the row it stands on).  ``(S, H * d)`` in ``q``'s dtype.
 Two lowerings keep that contract, chosen as the prefill's are:
 
-* **Pallas kernel** ``gqa_decode_fwd`` — on a TPU backend, no mesh in
-  scope, ``q``, ``k`` and ``v`` of one 2- or 4-byte float type, ``d`` a
-  multiple of 128 and ``T`` a multiple of ``MIN_TILE``: Trinity's rings and
-  grown caches and LFM2's packed cache rows; Granite 4.0-H's 64-wide heads
-  keep the XLA form on a TPU too.  Grid ``(S, T / bk)``, slots
-  ``"parallel"``, the key axis innermost and ``"arbitrary"``; ALL ``KV``
-  heads ride in one block ``(1, KV, bk, d)`` of ``k`` and of ``v``, and
-  the body walks them unrolled — a grid axis over the heads costs a fixed
-  price ``KV`` times a slot and was 1.4 times slower at Trinity's grown
-  caches (PERF.md section 6, PR 47).  Query head ``h`` reads key/value
-  head ``h // G``: the query goes in as ``(S, KV, G, d)``, a head's ``G``
-  rows one ``(G, d)`` operand (8 rows of bfloat16 are half a sublane tile;
-  the chip's compiler takes them as they are, and padding them to 16
-  bought nothing).  A ``(G, bk)`` score tile is accumulated in float32
-  from the compute-dtype operands and scaled in float32, lives in VMEM
-  only, and updates a float32 running maximum, running sum and ``(G, d)``
-  accumulator a head; probabilities are cast to the compute dtype for the
-  value product alone, and the one division by the sum comes at the end.
-  ``counts`` is scalar-prefetched: a key tile with ``k0 >= counts[s]`` is
-  neither visited nor fetched, and only the tile the count crosses pays
-  for the iota mask.  The steps past a slot's last tile point their index
-  map at the NEXT slot's first tile (always visited), so its fetch starts
-  as soon as the slot's last tile is in and not at the slot's last grid
-  step, where nothing would overlap it: a slot that ends early otherwise
-  costs one exposed tile fetch (0.68 -> 0.56 ms a call at Trinity's grown
-  caches).  It aliases nothing and writes only the output, so a cache
-  that is a scan's carry, and the output of ``row_write`` one line above,
-  is read where it lies.
+* **Pallas kernel** ``gqa_decode_fwd`` — on a TPU backend, no mesh in scope,
+  no sink, ``q``, ``k`` and ``v`` of one 2- or 4-byte float type, ``T`` a
+  multiple of ``MIN_TILE`` and the head widths either ONE, a multiple of
+  128, or TWO (values ``dv`` wide beside keys of ``d``), both whole half
+  lane tiles with the keys' at least a tile: Trinity's rings and grown
+  caches, LFM2's packed cache rows and MiMo's full layers (keys 192 beside
+  values of 128); Granite 4.0-H's 64-wide heads keep the XLA form on a TPU
+  too.  Grid ``(S, T / bk)``, slots ``"parallel"``, the key axis innermost
+  and ``"arbitrary"``; ALL ``KV`` heads ride in one block ``(1, KV, bk, d)``
+  of ``k`` and ``(1, KV, bk, dv)`` of ``v`` — a block's last dimension is
+  the array's, so a 192-wide key tile goes in whole: one and a half lane
+  tiles, which the chip holds as two (below) —, and the body walks them
+  unrolled — a grid axis over the heads costs a fixed price ``KV`` times a
+  slot and was 1.4 times slower at Trinity's grown caches (PERF.md section
+  6, PR 47).  Query head ``h`` reads key/value head ``h // G``: the query
+  goes in as ``(S, KV, G, d)``, a head's ``G`` rows one ``(G, d)`` operand
+  (8 rows of bfloat16 are half a sublane tile; the chip's compiler takes
+  them as they are, and padding them to 16 bought nothing).  A ``(G, bk)``
+  score tile is accumulated in float32 from the compute-dtype operands and
+  scaled in float32, lives in VMEM only, and updates a float32 running
+  maximum, running sum and ``(G, dv)`` accumulator a head; probabilities are
+  cast to the compute dtype for the value product alone, and the one
+  division by the sum comes at the end.  ``counts`` is scalar-prefetched: a
+  key tile with ``k0 >= counts[s]`` is neither visited nor fetched, and only
+  the tile the count crosses pays for the iota mask.  The steps past a
+  slot's last tile point their index map at the NEXT slot's first tile
+  (always visited), so its fetch starts as soon as the slot's last tile is
+  in and not at the slot's last grid step, where nothing would overlap it: a
+  slot that ends early otherwise costs one exposed tile fetch (0.68 -> 0.56
+  ms a call at Trinity's grown caches).  It aliases nothing and writes only
+  the output, so a cache that is a scan's carry, and the output of
+  ``row_write`` one line above, is read where it lies.
 * **XLA** (:func:`xla_decode_attention`) — everywhere else (the CPU of
   tier-1, the tests' tiny widths, any trace under a mesh, mixed dtypes):
   scores ``(S, H, T)`` in float32, a masked softmax, the value product;
@@ -163,25 +167,38 @@ Which one a traced call took is noted under ``"gqa_block_decode"``, and
 :func:`rows_visited` counts its rows as the one-query core's (a count of 0
 is no tile).
 
-**Two widths and a sink** (``models/mimo_v2.py``; PR 54).  The prefill core
-and the one-query decode core take values of ANOTHER WIDTH than the keys —
-``k (..., T, d)`` beside ``v (..., T, dv)``, the output ``H * dv`` columns —
-and an optional learned ``sink (H,)``: one more term of every query's
-softmax, a float a head, which takes mass and has no value — ``m = max(max_j
-s_ij, sink_h)``, ``p_ij = exp(s_ij - m) / (sum_j exp(s_ij - m) + exp(sink_h
-- m))``.  Only the XLA forms take them (the blocked form adds the term
-after the block's maximum, the decode form appends one column to the
-scores and drops it before the value product): **the kernels are not
-extended**, and :func:`prefill_lowering` / :func:`decode_lowering` answer
-``"xla"`` for a sink or ``dv != d`` as they do for a key width that is no
-multiple of 128 (MiMo's 192) and a ring shorter than ``MIN_TILE`` rows
-(MiMo's 128) — each alone keeps that family off the kernels on a TPU.  A
-window SHORTER than ``QUERY_BLOCK`` costs the blocked form a block of
-``QUERY_BLOCK + window`` keys for every ``QUERY_BLOCK`` rows, most of it
-masked; :func:`pairs_visited` counts it as it is.  Without a sink and with
-``dv == d`` every function traces the program it traced before the
-arguments existed (``tests/test_program_identity.py``).  The block mask and
-the block form of the decode step take neither.
+**Two widths and a sink** (``models/mimo_v2.py``; PR 54, PR 55).  The
+prefill core and the one-query decode core take values of ANOTHER WIDTH than
+the keys — ``k (..., T, d)`` beside ``v (..., T, dv)``, the output ``H *
+dv`` columns — and an optional learned ``sink (H,)``: one more term of every
+query's softmax, a float a head, which takes mass and has no value — ``m =
+max(max_j s_ij, sink_h)``, ``p_ij = exp(s_ij - m) / (sum_j exp(s_ij - m) +
+exp(sink_h - m))``.  The XLA forms take both (the blocked form adds the term
+after the block's maximum, the decode form appends one column to the scores
+and drops it before the value product).  Of the kernels, **the one-query
+decode kernel takes two widths** (PR 55: MiMo's full layers, which have no
+sink and no window — 96 % of the bytes that family's decode cores read): the
+key block, the query block and the contraction are ``d`` wide, the value
+block, the accumulator and the output ``dv``, and nothing else of the kernel
+knows.  The whole 192-wide block ``(1, KV, bk, 192)`` lowers; the chip keeps
+such a row in two lane tiles (256 columns of HBM inside the program), so a
+key tile's fetch costs what a 256-wide one would.  Measured level with two
+products over columns ``[0:128]`` and ``[128:192]`` of the same tile, and
+0.045 ms a call behind keys PADDED to 256 in the cache, which would cost a
+fifth more cache memory (PERF.md section 6, PR 55, has the table).  **What
+still keeps the XLA forms:** a sink (both cores), a ring shorter than
+``MIN_TILE`` rows (MiMo's 128: its sliding layers' decode core), and for the
+PREFILL kernel two widths or a key width that is no multiple of 128 — a
+query block there is one head's columns of ``(R, P, H * d)``, and 192
+columns are no lane multiple.  A window SHORTER than ``QUERY_BLOCK`` costs
+the blocked form a block of ``QUERY_BLOCK + window`` keys for every
+``QUERY_BLOCK`` rows, most of it masked; :func:`pairs_visited` counts it as
+it is.  Without a sink and with ``dv == d`` every function traces the
+program it traced before the arguments existed
+(``tests/test_program_identity.py`` for the XLA forms;
+``tests/test_pallas_gqa_decode.py`` holds the decode kernels' jaxpr text at
+one width).  The block mask and the block form of the decode step take
+neither.
 """
 
 from __future__ import annotations
@@ -722,7 +739,7 @@ def _decode_call(q, k, v, counts, *, scale, bk, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     s, heads, d = q.shape
-    kv, t = k.shape[1], k.shape[2]
+    kv, t, dv = k.shape[1], k.shape[2], v.shape[3]
     group = heads // kv
 
     def slot_map(si, ki, cnt_ref):
@@ -746,19 +763,19 @@ def _decode_call(q, k, v, counts, *, scale, bk, interpret):
             grid=(s, t // bk),
             in_specs=[pl.BlockSpec((1, kv, group, d), slot_map),
                       pl.BlockSpec((1, kv, bk, d), cache_map),
-                      pl.BlockSpec((1, kv, bk, d), cache_map)],
-            out_specs=pl.BlockSpec((1, kv, group, d), slot_map),
+                      pl.BlockSpec((1, kv, bk, dv), cache_map)],
+            out_specs=pl.BlockSpec((1, kv, group, dv), slot_map),
             scratch_shapes=[pltpu.VMEM((kv, group, 1), F32),
                             pltpu.VMEM((kv, group, 1), F32),
-                            pltpu.VMEM((kv, group, d), F32)],
+                            pltpu.VMEM((kv, group, dv), F32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((s, kv, group, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s, kv, group, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="gqa_decode_fwd",
     )(counts, q.reshape(s, kv, group, d), k, v)
-    return out.reshape(s, heads * d)
+    return out.reshape(s, heads * dv)
 
 
 def decode_lowering(q_dtype, k, v, sink: bool = False) -> str:
@@ -767,10 +784,14 @@ def decode_lowering(q_dtype, k, v, sink: bool = False) -> str:
     shapes), with a ``sink`` or without, traced here and now (the module
     docstring has the rule)."""
     dtype = jnp.dtype(q_dtype)
-    kernel = (_kernel_takes(dtype) and not sink
-              and k.shape[3] == v.shape[3]
+    d, dv = k.shape[3], v.shape[3]
+    # one width on the lane tile, or two that are whole half lane tiles
+    # with the keys' at least one tile (MiMo's 192 beside 128)
+    widths = d % 128 == 0 if d == dv else (
+        d % 64 == 0 and dv % 64 == 0 and d >= 128)
+    kernel = (_kernel_takes(dtype) and not sink and widths
               and dtype == jnp.dtype(k.dtype) == jnp.dtype(v.dtype)
-              and k.shape[3] % 128 == 0 and k.shape[2] % MIN_TILE == 0)
+              and k.shape[2] % MIN_TILE == 0)
     return "pallas" if kernel else "xla"
 
 

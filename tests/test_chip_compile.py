@@ -434,25 +434,33 @@ def test_mla_decode_kernel_compiles_for_v5e(shape, no_persistent_cache,
         shape((slots,), jnp.int32))
 
 
-@pytest.mark.parametrize("slots,rows", [(64, 2048), (64, 9216), (128, 3072)],
-                         ids=["trinity-ring", "trinity-grown", "lfm2"])
+@pytest.mark.parametrize("slots,heads,rows,d,dv", [
+    (64, 32, 2048, 128, 128), (64, 32, 9216, 128, 128),
+    (128, 32, 3072, 128, 128), (16, 64, 17408, 192, 128),
+    (16, 32, 4096, 128, 64)],
+    ids=["trinity-ring", "trinity-grown", "lfm2", "mimo-full", "128-64"])
 def test_gqa_decode_kernel_compiles_for_v5e(shape, no_persistent_cache,
-                                            slots, rows):
+                                            slots, heads, rows, d, dv):
     """The grouped-query decode core (``ops/gqa.py``, ``gqa_decode_fwd``)
     at the shapes of ``serve-trinity-mixedlen-backlog`` (a ring and grown
     keys) and ``serve-lfm2-longgen-backlog``: 32 query heads over 4
     key/value heads of 128, bfloat16 — a head's 8 query rows half a
     sublane tile, all four key/value heads in one block —, with the key
-    tile the chip path takes."""
+    tile the chip path takes; and at TWO WIDTHS: the full layers of
+    ``serve-mimo-longdoc-backlog`` (64 query heads over 4 key/value heads,
+    keys 192 wide — a block one and a half lane tiles wide, whole — beside
+    values of 128, 17 key tiles of 1,024 a slot) and the narrowest pair
+    ``decode_lowering`` sends here (values of half a lane tile)."""
     from progen_tpu.ops.gqa import pallas_decode_attention
 
     bf16 = jnp.bfloat16
-    cache = shape((slots, 4, rows, 128), bf16)
+    keys, values = (shape((slots, 4, rows, d), bf16),
+                    shape((slots, 4, rows, dv), bf16))
     _assert_kernel_compiles(
-        lambda q, k, v, n: pallas_decode_attention(q, k, v, n, 128 ** -0.5,
+        lambda q, k, v, n: pallas_decode_attention(q, k, v, n, d ** -0.5,
                                                    interpret=False),
-        shape((slots, 32, 128), bf16), cache, cache, shape((slots,),
-                                                           jnp.int32))
+        shape((slots, heads, d), bf16), keys, values, shape((slots,),
+                                                            jnp.int32))
 
 
 @pytest.mark.parametrize("queries,tokens", [(8, 8), (4, 8), (4, 4)],
@@ -957,12 +965,14 @@ def test_mimo_programs_compile_for_the_chip_and_fit_it(
     wide beside values of 128; 16 of 256 experts), an eighth of the
     vocabulary, 16 slots of 128-row rings and 17,408 grown rows: the chunk
     program (32 steps of every slot: 16 tokens a call through
-    ``moe_decode_fwd``, BOTH attention cores the XLA forms — no
-    ``gqa_decode_fwd`` in the text) and the admission of 1 row at the
+    ``moe_decode_fwd``, the full layers' core ``gqa_decode_fwd`` at two
+    widths since PR 55 — ONE such kernel in the text, the two layers share
+    it —, the rings' core the XLA form) and the admission of 1 row at the
     16,384 bucket (through ``moe_sorted_fwd``; the blocked XLA attention,
-    no ``gqa_prefill_fwd``), as the chip traces them.  Arguments, results
-    and temporaries together stay under the chip's 16 GiB: the engine's
-    programs do not donate their state, so it is there twice."""
+    no ``gqa_prefill_fwd`` and no decode core), as the chip traces them.
+    Arguments, results and temporaries together stay under the chip's 16
+    GiB: the engine's programs do not donate their state, so it is there
+    twice."""
     from progen_tpu.decode import sampler
     from progen_tpu.ops import gqa, lowering, moe_decode, row_write
 
@@ -1000,8 +1010,8 @@ def test_mimo_programs_compile_for_the_chip_and_fit_it(
           f" GB, total {total / 1e9:.2f} GB")
     assert weights + 2 * held <= total < 15.5e9, m
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    assert "gqa_decode_fwd" not in text and "gqa_prefill_fwd" not in text
+    assert "tpu_custom_call" in text and "gqa_prefill_fwd" not in text
+    assert ("gqa_decode_fwd" in text) == (program == "chunk")
     if program == "chunk":
         assert "moe_decode_fwd" in text and "row_write" in text
     else:

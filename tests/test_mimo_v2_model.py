@@ -470,9 +470,13 @@ def test_decode_core_with_a_sink_and_two_widths(with_sink):
     np.testing.assert_array_equal(junk, got)
 
 
-def test_the_kernels_decline_a_sink_two_widths_and_these_shapes(monkeypatch):
-    """On a TPU too: each of the four alone sends MiMo's blocks to the XLA
-    forms (``ops/gqa.py``'s docstring)."""
+def test_the_decode_kernel_takes_two_widths_and_the_rest_is_declined(
+        monkeypatch):
+    """On a TPU: the one-query decode kernel takes the FULL layers' grown
+    caches, keys 192 wide beside values of 128 (PR 55); a sink or a ring
+    under a tile, each alone, keeps the sliding layers on the XLA decode
+    core, and the PREFILL kernel still declines a sink, two widths, a key
+    width of 192 and a window of 128 (``ops/gqa.py``'s docstring)."""
     monkeypatch.setattr(gqa, "_on_tpu", lambda: True)
     bf = jnp.bfloat16
     assert gqa.prefill_lowering(1024, 128, bf, None) == "pallas"
@@ -480,18 +484,31 @@ def test_the_kernels_decline_a_sink_two_widths_and_these_shapes(monkeypatch):
     assert gqa.prefill_lowering(1024, 128, bf, None, sink=True) == "xla"
     assert gqa.prefill_lowering(1024, 128, bf, None, dv=64) == "xla"
     assert gqa.prefill_lowering(1024, 192, bf, None) == "xla"
+    assert gqa.prefill_lowering(1024, 192, bf, None, dv=128) == "xla"
     assert gqa.prefill_lowering(1024, 128, bf, 128) == "xla"
     sd = jax.ShapeDtypeStruct
     k = sd((16, 4, 1024, 128), bf)
     assert gqa.decode_lowering(bf, k, k) == "pallas"
     assert gqa.decode_lowering(bf, k, k, sink=True) == "xla"
-    assert gqa.decode_lowering(bf, k, sd((16, 4, 1024, 256), bf)) == "xla"
-    assert gqa.decode_lowering(bf, sd((16, 4, 1024, 192), bf),
-                               sd((16, 4, 1024, 128), bf)) == "xla"
-    ring = sd((16, 8, 128, 128), bf)
-    assert gqa.decode_lowering(bf, ring, ring) == "xla"
-    # and the family's counters follow the lowering that runs
-    blocks = mm.blocks_of(mm.MiMoV2Config(num_hidden_layers=7))
+    assert gqa.decode_lowering(bf, sd((16, 4, 1024, 256), bf), k) == "pallas"
+    grown = (sd((16, 4, 17408, 192), bf), sd((16, 4, 17408, 128), bf))
+    assert gqa.decode_lowering(bf, *grown) == "pallas"
+    assert gqa.decode_lowering(bf, *grown, sink=True) == "xla"
+    assert gqa.decode_lowering(jnp.float32, *grown) == "xla"
+    ring = (sd((16, 8, 128, 192), bf), sd((16, 8, 128, 128), bf))
+    assert gqa.decode_lowering(bf, *ring) == "xla"
+    assert gqa.decode_lowering(bf, *ring, sink=True) == "xla"
+    # the cell's engine: one kind a lowering, and the full layers' counter
+    # stops at a slot's count while the rings' reads every row
+    c = mm.MiMoV2Config(num_hidden_layers=7)
+    blocks = mm.blocks_of(c)
+    caches = {"l0": dict(zip("kv", grown)), "l1": dict(zip("kv", ring))}
+    pos = jnp.array([0, 1023, 1024] + [6000] * 13)
+    stats = mm.attention_stats(
+        {n: blocks[n] for n in caches}, caches, pos, jnp.ones(16, bool))
+    assert float(stats["attn.full_rows_read"]) == 1024 * (1 + 1 + 2 + 13 * 6)
+    assert float(stats["attn.window_rows_read"]) == 16 * 128
+    # and the prefill's counters follow the lowering that runs
     stats = mm.prefill_attention_stats(blocks, 512, jnp.array([512]), bf)
     sliding = 2 * 256 * (256 + 128)     # a block sees itself + the window
     full = 2 * 256 * 512                # two blocks share the keys of both
