@@ -180,7 +180,9 @@ class ProGenFamily:
 # a state block, a grown-key block, or an expert layer that states no cache;
 # the eighth, MiMo-V2, a share under two kinds of attention with two head
 # shapes: a short ring under a learned sink beside grown keys, the keys
-# wider than the values
+# wider than the values; the ninth, dots3, a share over LATENT attention of
+# two shapes: rows an indexer thins to ``index_topk`` beside a ring of
+# latents, a gate a head on both
 _DRIVER_FAMILIES = (
     ("progen_tpu.models.longcat", "LongCatConfig", "LongCatFamily"),
     ("progen_tpu.models.deepseek_v2", "DeepSeekV2Config", "DeepSeekV2Family"),
@@ -191,6 +193,7 @@ _DRIVER_FAMILIES = (
     ("progen_tpu.models.lfm2", "LFM2Config", "LFM2Family"),
     ("progen_tpu.models.nemotron_h", "NemotronHConfig", "NemotronHFamily"),
     ("progen_tpu.models.mimo_v2", "MiMoV2Config", "MiMoV2Family"),
+    ("progen_tpu.models.dots3", "Dots3Config", "Dots3Family"),
 )
 
 

@@ -20,10 +20,28 @@ rope)^-1/2``: a family with another softmax scale folds the factor into
 ``q_lat . c_kv + q_rope . k_r``, ``o_lat = softmax . c_kv``, ``o = o_lat
 W_kvb[v]``.
 
-**What a latent block states about its cache** (``LatentBlock``, the one
-kind every block of these families is): one leaf ``(slots, max_len,
-latent_width)``; the token at position ``p`` lies in row ``p``; a slot at
-position ``pos`` has ``pos + 1`` rows.
+**What a latent block states about its cache** (``LatentBlock``): one leaf
+``(slots, max_len, latent_width)``; the token at position ``p`` lies in row
+``p``; a slot at position ``pos`` has ``pos + 1`` rows.  That is the one
+kind every block of LongCat and DeepSeek-V2 is, under ONE shape (the config
+itself).  ``models/dots3.py`` brings two shapes in one stack and asks three
+more things of a block, each an option of its constructor that changes
+nothing where it is not asked for:
+
+* ``window`` — the latent lies in a RING of ``min(window, max_len)`` rows
+  (``models/kv.py``'s rule: the token at ``p`` in row ``p % rows``, a slot
+  at ``pos`` has ``min(pos + 1, rows)`` of them; rows are cached rotated,
+  so their order in the ring does not matter); the admission's core is
+  ``ops/gqa.py``'s windowed form over the expanded heads, the step's
+  ``ops/mla_decode.py`` over the ring;
+* ``indexer`` — the cache is ``{"latent": .., "index": (slots, max_len,
+  index_head_dim)}``: a SECOND leaf, the indexer's key a token, written by
+  the same step; the attention runs over the ``index_topk`` rows the
+  indexer selects (``ops/dsa.py``; :func:`index_project` has its
+  equations);
+* ``gate`` — one sigmoid a head, ``o_h <- o_h * sigmoid(x W_g)_h``, between
+  the core and ``W_o`` (:func:`gate_heads`; ``x`` is the block's normed
+  input).
 """
 
 from __future__ import annotations
@@ -49,6 +67,7 @@ from progen_tpu.models.driver import (  # noqa: F401
     rope,
     swiglu,
 )
+from progen_tpu.ops import dsa, gqa
 from progen_tpu.ops.mla_decode import decode_attention, rows_visited
 from progen_tpu.ops.mla_prefill import prefill_attention
 from progen_tpu.ops.row_write import write_rows
@@ -83,10 +102,12 @@ def init_attn(key, c, dt):
 # ---------------------------------------------------------------------- MLA
 
 
-def mla_project(x, p, c, positions):
+def mla_project(x, p, c, positions, latent_query: bool = False):
     """``x (..., n, h)`` at ``positions (..., n)`` -> ``q_nope (..., n, H,
     nope)``, rotated ``q_rope (..., n, H, rope)`` and the cache row
-    ``[c_kv | rope(k_r)] (..., n, latent)``."""
+    ``[c_kv | rope(k_r)] (..., n, latent)``; with ``latent_query`` the
+    normed query latent ``c_q (..., n, q_lora_rank)`` too (an indexer reads
+    it)."""
     heads = c.num_attention_heads
     nope, rot = c.qk_nope_head_dim, c.qk_rope_head_dim
     with jax.named_scope("mla.project"):
@@ -102,7 +123,46 @@ def mla_project(x, p, c, positions):
             c_kv = c_kv * jnp.asarray(c.kv_gain, c_kv.dtype)
         k_r = rope(kva[..., c.kv_lora_rank:], positions, c.rope_inv_freq)
         q_rope = rope(q[..., nope:], positions, c.rope_inv_freq)
-        return q[..., :nope], q_rope, jnp.concatenate([c_kv, k_r], axis=-1)
+        out = q[..., :nope], q_rope, jnp.concatenate([c_kv, k_r], axis=-1)
+        return out + (c_q,) if latent_query else out
+
+
+def index_project(x, c_q, p, c, positions):
+    """The indexer's side of a token (DeepSeek-V3.2's; ``ops/dsa.py`` has
+    the score): ``q^I = c_q W^I_q * q_gain (..., n, J, d)`` — it reads the
+    query latent as the attention's query does —, ``k^I = LayerNorm(x
+    W^I_k) (..., n, d)``, one key for all ``J`` heads and the row the cache
+    keeps, the leading ``qk_rope_head_dim`` columns of both rotated
+    (half-split) at the block's own base, and ``w = x W^I_w * J^-1/2 *
+    d^-1/2 (..., n, J)`` in float32."""
+    heads, d, rot = c.index_n_heads, c.index_head_dim, c.qk_rope_head_dim
+
+    def rotated(a):
+        return jnp.concatenate(
+            [rope(a[..., :rot], positions, c.rope_inv_freq), a[..., rot:]],
+            axis=-1)
+
+    with jax.named_scope("dsa.project"):
+        q = mm(c_q, p["wiq"])
+        if c.q_gain != 1:
+            q = q * jnp.asarray(c.q_gain, q.dtype)
+        q = rotated(q.reshape(q.shape[:-1] + (heads, d)))
+        k = mm(x, p["wik"]).astype(F32)
+        k = k - jnp.mean(k, axis=-1, keepdims=True)
+        k = k * jax.lax.rsqrt(
+            jnp.mean(k * k, axis=-1, keepdims=True) + c.index_norm_eps)
+        k = (k * p["ik_scale"].astype(F32) + p["ik_bias"].astype(F32)
+             ).astype(x.dtype)
+        w = mm(x, p["wiw"]).astype(F32) * (heads * d) ** -0.5
+        return q, w, rotated(k)
+
+
+def gate_heads(o, x, p, heads: int):
+    """``o (..., H * v)`` with each head times its own sigmoid of ``x W_g
+    (..., H)`` (float32 inside the sigmoid)."""
+    g = jax.nn.sigmoid(mm(x, p["wgate"]).astype(F32)).astype(o.dtype)
+    return (o.reshape(o.shape[:-1] + (heads, -1)) * g[..., None]).reshape(
+        o.shape)
 
 
 def _wkvb(p, c, dtype):
@@ -112,70 +172,155 @@ def _wkvb(p, c, dtype):
     return w[..., : c.qk_nope_head_dim], w[..., c.qk_nope_head_dim:]
 
 
-def mla_prefill(x, p, c, lengths=None):
+def mla_prefill(x, p, c, lengths=None, *, window=None, indexer=False,
+                gate=False):
     """Full causal attention over ``x (R, P, h)`` in the NON-absorbed form
     (keys and values expanded from the latent once); the core is
     ``ops/mla_prefill.py``: a flash kernel on the chip at the published
     head widths, blocks of query rows in XLA elsewhere.  ``lengths (R,)``:
     the real leading positions of each row (default all); the output at a
     pad position is finite and otherwise unspecified.  Returns ``(out (R,
-    P, h), latent rows (R, P, latent))``."""
+    P, h), latent rows (R, P, latent))``.  The options are
+    :class:`LatentBlock`'s: under a ``window`` the core is ``ops/gqa.py``'s
+    windowed form over the expanded heads; with an ``indexer`` it is
+    ``ops/dsa.py``'s and the rows are ``{"latent": .., "index": (R, P,
+    index_head_dim)}``."""
     r, n, _ = x.shape
     with jax.named_scope("mla.prefill"):
         positions = jnp.broadcast_to(jnp.arange(n), (r, n))
-        q_nope, q_rope, latent = mla_project(x, p, c, positions)
+        q_nope, q_rope, latent, c_q = mla_project(x, p, c, positions, True)
         wk, wv = _wkvb(p, c, x.dtype)
         c_kv, k_r = latent[..., : c.kv_lora_rank], latent[..., c.kv_lora_rank:]
         k_nope = jnp.einsum("rnl,lhd->rhnd", c_kv, wk)
         v = jnp.einsum("rnl,lhd->rhnd", c_kv, wv)
-        o = prefill_attention(q_nope, q_rope, k_nope, k_r, v, lengths)
-        return mm(o, p["wo"]), latent
+        rows = latent
+        if indexer:
+            q_idx, w, k_idx = index_project(x, c_q, p, c, positions)
+            o = dsa.sparse_prefill_attention(
+                q_nope, q_rope, k_nope, k_r, v, q_idx, w, k_idx,
+                c.index_topk)
+            rows = {"latent": latent, "index": k_idx}
+        elif window is not None:
+            with jax.named_scope("attn.window"):
+                q, k = dsa.joined_heads(q_nope, q_rope, k_nope, k_r)
+                o = gqa.prefill_attention(q, k, v, q.shape[-1] ** -0.5,
+                                          window, lengths)
+        else:
+            o = prefill_attention(q_nope, q_rope, k_nope, k_r, v, lengths)
+        if gate:
+            o = gate_heads(o, x, p, c.num_attention_heads)
+        return mm(o, p["wo"]), rows
 
 
-def mla_decode(x, pos, cache, p, c):
+def mla_decode(x, pos, cache, p, c, *, window=None, indexer=False,
+               gate=False):
     """One token per row in the ABSORBED form: ``x (S, h)`` at ``pos (S,)``
     against ``cache (S, T, latent)``, which gains the row's new entry at
     ``pos`` and is then attended up to it (``ops/mla_decode.py``: one kernel
     that reads each slot's rows once on the chip, three XLA ops over the
-    whole cache elsewhere).  Returns ``(out (S, h), cache)``."""
+    whole cache elsewhere).  Returns ``(out (S, h), cache)``.  The options
+    are :class:`LatentBlock`'s: under a ``window`` the cache is the ring
+    and the core reads the rows the slot has in it; with an ``indexer`` the
+    cache is ``{"latent", "index"}``, both written here, and the core reads
+    the rows ``ops/dsa.py`` selects."""
     s = x.shape[0]
     rank = c.kv_lora_rank
+    scale = 1.0 / math.sqrt(c.qk_nope_head_dim + c.qk_rope_head_dim)
     with jax.named_scope("mla.decode"):
-        q_nope, q_rope, row = mla_project(x[:, None], p, c, pos[:, None])
-        cache = write_rows(cache, row[:, 0].astype(cache.dtype), pos, axis=0)
+        q_nope, q_rope, row, c_q = mla_project(x[:, None], p, c,
+                                               pos[:, None], True)
+        index = None
+        if indexer:
+            cache, index = cache["latent"], cache["index"]
+        at = pos if window is None else pos % cache.shape[1]
+        cache = write_rows(cache, row[:, 0].astype(cache.dtype), at, axis=0)
         wk, wv = _wkvb(p, c, x.dtype)
         q_lat = jnp.einsum("shd,lhd->shl", q_nope[:, 0], wk)
         q_cat = jnp.concatenate([q_lat, q_rope[:, 0]], axis=-1)
-        o_lat = decode_attention(
-            q_cat, cache, pos + 1, rank,
-            1.0 / math.sqrt(c.qk_nope_head_dim + c.qk_rope_head_dim))
-        o = jnp.einsum("shl,lhd->shd", o_lat, wv)
-        return mm(o.reshape(s, -1), p["wo"]), cache
+        if indexer:
+            q_idx, w, k_idx = index_project(x[:, None], c_q, p, c,
+                                            pos[:, None])
+            index = write_rows(index, k_idx[:, 0].astype(index.dtype), pos,
+                               axis=0)
+            picked, kept = dsa.select_rows(q_idx[:, 0], w[:, 0], index,
+                                           pos + 1, c.index_topk)
+            with jax.named_scope("attn.sparse"):
+                o_lat = dsa.sparse_decode_attention(q_cat, cache, picked,
+                                                    kept, rank, scale)
+            cache = {"latent": cache, "index": index}
+        elif window is not None:
+            with jax.named_scope("attn.window"):
+                o_lat = decode_attention(
+                    q_cat, cache, jnp.minimum(pos + 1, cache.shape[1]), rank,
+                    scale)
+        else:
+            o_lat = decode_attention(q_cat, cache, pos + 1, rank, scale)
+        o = jnp.einsum("shl,lhd->shd", o_lat, wv).reshape(s, -1)
+        if gate:
+            o = gate_heads(o, x, p, c.num_attention_heads)
+        return mm(o, p["wo"]), cache
 
 
 # ------------------------------------------- the block, and the driver over it
 
 
-class LatentBlock:
-    """The one kind of attention block of a latent family
-    (``models/driver.py`` says what a block is)."""
+def _grown(rows, max_len: int):
+    """Per-token rows ``(R, P, w)`` as ``max_len`` rows a slot."""
+    n = rows.shape[1]
+    return rows[:, :max_len] if n >= max_len else jnp.pad(
+        rows, ((0, 0), (0, max_len - n), (0, 0)))
 
-    def __init__(self, config):
+
+class LatentBlock:
+    """An attention block over latent rows of ``config``'s shape
+    (``models/driver.py`` says what a block is); the module docstring has
+    the three options."""
+
+    def __init__(self, config, *, window: int | None = None,
+                 indexer: bool = False, gate: bool = False):
+        if window is not None and indexer:
+            raise ValueError("an indexer selects among grown rows, not a "
+                             "ring's")
         self.config = config
+        self.window = window
+        self.indexer = indexer
+        self.gate = gate
+
+    @property
+    def options(self) -> dict:
+        return {"window": self.window, "indexer": self.indexer,
+                "gate": self.gate}
+
+    def rows(self, max_len: int) -> int:
+        """Latent rows a slot's cache has in an engine of ``max_len``."""
+        return max_len if self.window is None else min(self.window, max_len)
 
     def init_cache(self, slots: int, max_len: int, dtype):
-        return jnp.zeros((slots, max_len, self.config.latent_width), dtype)
+        c = self.config
+        latent = jnp.zeros((slots, self.rows(max_len), c.latent_width), dtype)
+        if not self.indexer:
+            return latent
+        return {"latent": latent,
+                "index": jnp.zeros((slots, max_len, c.index_head_dim), dtype)}
 
     def prefill(self, x, p, lengths):
-        return mla_prefill(x, p, self.config, lengths)
+        return mla_prefill(x, p, self.config, lengths, **self.options)
 
     def cache_rows(self, rows, lengths, max_len: int):
-        n = rows.shape[1]
-        return rows[:, :max_len] if n >= max_len else jnp.pad(
-            rows, ((0, 0), (0, max_len - n), (0, 0)))
+        if self.indexer:
+            return {name: _grown(a, max_len) for name, a in rows.items()}
+        if self.window is None:
+            return _grown(rows, max_len)
+        # a ring takes, for each of its rows ``j``, the LAST real token ``p
+        # < length`` with ``p % size == j`` (``models/kv.py``'s rule)
+        size = self.rows(max_len)
+        j = jnp.arange(size)[None, :]
+        last = lengths[:, None] - 1
+        at = jnp.clip(j + size * ((last - j) // size), 0, rows.shape[1] - 1)
+        return jnp.take_along_axis(rows, at[..., None], axis=1)
 
     def decode(self, x, pos, cache, p):
-        return mla_decode(x, pos, cache, p, self.config)
+        return mla_decode(x, pos, cache, p, self.config, **self.options)
 
 
 def attention_stats(config, dt, caches, pos, live) -> dict:

@@ -1,0 +1,194 @@
+"""Learned sparse attention over latent rows: a lightweight INDEXER scores
+every cached token for a query and the latent attention (``models/latent.py``)
+then runs over the ``top_k`` best of them only (DeepSeek-V3.2's sparse
+attention; ``models/dots3.py``'s full layers).
+
+The indexer keeps ONE key ``k^I (d,)`` a token, shared by its ``J`` heads,
+and a query brings ``q^I (J, d)`` and a weight a head ``w (J,)``::
+
+    I[t, s] = sum_j w[t, j] * relu(q^I[t, j] . k^I[s])        s <= t
+
+``S_t`` is the ``top_k`` largest ``I[t, .]`` (every visible key while there
+are no more than ``top_k``), and the attention's softmax runs over ``S_t``
+alone.  The products take the compute dtype's operands and accumulate in
+float32; the ReLU, the weighted sum over heads and the selection are float32.
+
+Everything here is plain XLA (no kernel yet), one form everywhere:
+
+* **a decode step** — :func:`select_rows` scores a slot's whole indexer
+  cache ``(S, T, d)`` (rows past the slot's count masked), ``lax.top_k``
+  gives the rows' numbers, and :func:`sparse_decode_attention` gathers those
+  latent rows and hands them to ``ops/mla_decode.py:decode_attention`` — on
+  a TPU the kernel ``mla_decode_fwd`` over ``top_k`` rows a slot, whatever
+  the context.  The indexer reads ``T`` rows a slot (``dsa.index_rows_read``).
+* **an admission** — :func:`sparse_prefill_attention`: the selection as a
+  dense MASK over the expanded keys, in SEGMENTS of ``top_k`` query rows
+  (:func:`segments`): segment ``g`` sees the keys up to its own end, the
+  first needs no indexer at all (its queries see ``top_k`` keys or fewer),
+  and inside a segment a ``lax.map`` over blocks of ``QUERY_BLOCK`` rows
+  keeps ``I`` and the score tensor a block high — ``(J, bq, keys)`` and
+  ``(H, bq, keys)`` float32.  A row's threshold is its ``top_k``-th largest
+  score (``lax.top_k``), the mask ``I >= threshold``.  Every pair under a
+  segment's key span is computed, selected or not: :func:`prefill_pairs`
+  says how many.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import contextvars
+
+import jax
+import jax.numpy as jnp
+
+from progen_tpu.ops import mla_decode
+
+F32 = jnp.float32
+QUERY_BLOCK = 128     # an admission's query rows per score block
+
+
+def index_scores(q_idx, w, k_idx):
+    """``I (..., n, T)`` float32 of ``q_idx (..., n, J, d)``, ``w (..., n,
+    J)`` float32 and ``k_idx (..., T, d)``: ``sum_j w_j relu(q_j . k)``."""
+    dots = jnp.einsum("...njd,...td->...jnt", q_idx, k_idx.astype(q_idx.dtype),
+                      preferred_element_type=F32)
+    # elementwise, so that float32 stays float32 on the chip's matrix unit
+    return jnp.sum(jax.nn.relu(dots) * jnp.swapaxes(w, -1, -2)[..., None],
+                   axis=-3)
+
+
+# ------------------------------------------------------------------- decode
+
+_recorder: contextvars.ContextVar = contextvars.ContextVar(
+    "dsa_selections", default=None)
+
+
+@contextlib.contextmanager
+def record_selections():
+    """Collect ``[(rows (S, K), kept (S,)), ...]``, one pair a call of
+    :func:`select_rows` traced inside the block, in the stack's order (as
+    ``ops/lowering.py:record_lowerings``): a caller that traces a decode
+    step and returns them can hold the selected sets against a
+    reference's."""
+    picked: list = []
+    token = _recorder.set(picked)
+    try:
+        yield picked
+    finally:
+        _recorder.reset(token)
+
+
+def select_rows(q_idx, w, index, counts, top_k: int):
+    """One query a slot: ``q_idx (S, J, d)``, ``w (S, J)`` over the first
+    ``counts (S,)`` rows of ``index (S, T, d)`` -> ``(rows (S, K) int32,
+    kept (S,))``, ``K = min(top_k, T)``: the numbers of the ``kept =
+    min(counts, K)`` best-scored rows FIRST (best first), then filler."""
+    with jax.named_scope("dsa.index"):
+        scores = index_scores(q_idx[:, None], w[:, None], index)[:, 0]
+        seen = jnp.arange(index.shape[1])[None, :] < counts[:, None]
+        scores = jnp.where(seen, scores, -jnp.inf)
+    with jax.named_scope("dsa.select"):
+        k = min(top_k, index.shape[1])
+        _, rows = jax.lax.top_k(scores, k)
+        kept = jnp.minimum(counts, k)
+    picked = _recorder.get()
+    if picked is not None:
+        picked.append((rows, kept))
+    return rows, kept
+
+
+def sparse_decode_attention(q_cat, cache, rows, kept, rank: int, scale):
+    """``ops/mla_decode.py:decode_attention`` over the selected rows:
+    ``cache (S, T, latent)`` gathered at ``rows (S, K)``, of which the first
+    ``kept (S,)`` count."""
+    picked = jnp.take_along_axis(cache, rows[..., None], axis=1)
+    return mla_decode.decode_attention(q_cat, picked, kept, rank, scale)
+
+
+# ---------------------------------------------------------------- admission
+
+
+def segments(n: int, top_k: int) -> tuple:
+    """``(segment length, query block)`` of an admission of ``n`` positions:
+    segments of ``top_k`` rows where they tile ``n``, else one of ``n``;
+    blocks of ``QUERY_BLOCK`` rows where they tile a segment, else one."""
+    seg = top_k if n > top_k and n % top_k == 0 else n
+    return seg, QUERY_BLOCK if seg % QUERY_BLOCK == 0 else seg
+
+
+def prefill_pairs(n: int, top_k: int) -> tuple:
+    """``(scored, attended)``: the query-key pairs the indexer scores and
+    the pairs the attention core computes (one head) for ONE row padded to
+    ``n`` positions — each segment's rows against the keys up to its end,
+    pads included; the indexer only where a segment's span passes
+    ``top_k``."""
+    seg, _ = segments(n, top_k)
+    ends = range(seg, n + 1, seg)
+    return (float(sum(seg * e for e in ends if e > top_k)),
+            float(sum(seg * e for e in ends)))
+
+
+def joined_heads(q_nope, q_rope, k_nope, k_r):
+    """The expanded operands of latent attention as whole heads: ``q (R, P,
+    H, nope + rope)`` and ``k (R, H, P, nope + rope)``, the one rotated key
+    ``k_r (R, P, rope)`` repeated for every head."""
+    r, _, heads, _ = q_nope.shape
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_r[:, None], (r, heads) + k_r.shape[1:])],
+        axis=-1)
+    return jnp.concatenate([q_nope, q_rope], axis=-1), k
+
+
+def sparse_prefill_attention(q_nope, q_rope, k_nope, k_r, v, q_idx, w, k_idx,
+                             top_k: int):
+    """Causal latent attention in the expanded form under the indexer's
+    selection: ``q_nope (R, P, H, nope)``, ``q_rope (R, P, H, rope)``,
+    ``k_nope (R, H, P, nope)``, ``k_r (R, P, rope)``, ``v (R, H, P, vd)``
+    as ``ops/mla_prefill.py`` takes them, scores scaled by ``(nope +
+    rope)^-1/2``; ``q_idx (R, P, J, d)``, ``w (R, P, J)`` float32, ``k_idx
+    (R, P, d)`` the indexer's.  ``(R, P, H * vd)``; every position is
+    computed, and a real one sees real keys only (attention is causal)."""
+    r, n = q_nope.shape[:2]
+    q, k = joined_heads(q_nope, q_rope, k_nope, k_r)
+    q = q.transpose(0, 2, 1, 3)
+    scale = q.shape[-1] ** -0.5
+    seg, bq = segments(n, top_k)
+
+    def rows(x, start, axis):
+        return jax.lax.dynamic_slice_in_dim(x, start, bq, axis=axis)
+
+    def block(first, end):
+        """Query rows ``first .. first + bq - 1`` over keys ``0 .. end -
+        1``; the selection is computed only where a row can see more than
+        ``top_k`` keys (``end > top_k``: a static fact of the segment)."""
+        gap = first + jnp.arange(bq)[:, None] - jnp.arange(end)[None, :]
+        seen = jnp.broadcast_to(gap >= 0, (r, bq, end))
+        if end > top_k:
+            with jax.named_scope("dsa.index"):
+                scores = jnp.where(seen, index_scores(
+                    rows(q_idx, first, 1), rows(w, first, 1),
+                    k_idx[:, :end]), -jnp.inf)
+            with jax.named_scope("dsa.select"):
+                kth = jax.lax.top_k(scores, top_k)[0][..., -1:]
+                seen = seen & (scores >= kth)
+        with jax.named_scope("attn.sparse"):
+            logits = jnp.einsum("rhqd,rhkd->rhqk", rows(q, first, 2),
+                                k[:, :, :end],
+                                preferred_element_type=F32) * scale
+            # unnormalised probabilities and ONE division after the value
+            # product, as ``ops/gqa.py``'s blocked form: every row sees
+            # itself, so the maximum is finite
+            logits = jnp.where(seen[:, None], logits, -jnp.inf)
+            p = jnp.exp(logits - jnp.max(logits, axis=-1, keepdims=True))
+            out = jnp.einsum("rhqk,rhkd->rqhd", p.astype(v.dtype),
+                             v[:, :, :end], preferred_element_type=F32)
+            total = jnp.sum(p, axis=-1).transpose(0, 2, 1)
+            return (out / total[..., None]).astype(v.dtype)
+
+    outs = []
+    for start in range(0, n, seg):
+        firsts = start + bq * jnp.arange(seg // bq)
+        out = jax.lax.map(lambda f, e=start + seg: block(f, e), firsts)
+        # (blocks, R, bq, H, vd) -> (R, seg, H * vd)
+        outs.append(out.transpose(1, 0, 2, 3, 4).reshape(r, seg, -1))
+    return jnp.concatenate(outs, axis=1)

@@ -1016,3 +1016,87 @@ def test_mimo_programs_compile_for_the_chip_and_fit_it(
         assert "moe_decode_fwd" in text and "row_write" in text
     else:
         assert "moe_sorted_fwd" in text
+
+
+# ---- dots3's whole programs at published widths ----
+
+
+@pytest.fixture(scope="module")
+def dots3_engine():
+    """The engine of ``serve-dots3-longdoc-backlog`` over ABSTRACT weights
+    (its 16 slots' state is real, on the host: 0.85 GB of zeros)."""
+    from progen_tpu.decode.engine import ServingEngine
+    from progen_tpu.models import dots3
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "perf",
+                           "configs", "dots3-note-prev-ep8.json")) as f:
+        c = dots3.Dots3Config.from_dict(json.load(f))
+    policy = dots3.bf16_policy()
+    params = jax.eval_shape(lambda k: dots3.init_params(c, k, policy),
+                            jax.random.key(0))
+    return ServingEngine(c, params, policy=policy, num_slots=16,
+                         chunk_size=32, max_len=17408)
+
+
+@pytest.mark.parametrize("program", ["chunk", "admit-16384"])
+def test_dots3_programs_compile_for_the_chip_and_fit_it(
+        shape, dots3_engine, program, no_persistent_cache, monkeypatch):
+    """Layers 0-4 of 46 (the dense layer and one whole period: 2 full
+    layers of 128 heads over 576-wide latents thinned to 2,048 rows by a
+    64-head indexer, 3 sliding ones of 64 heads over a 513-row ring of
+    1,088-wide latents; 32 of 256 experts beside a shared one), an eighth of
+    the vocabulary, 16 slots of 17,408 rows: the chunk program (32 steps of
+    every slot: the indexer's score and ``top_k`` over every slot's rows,
+    the gathered 2,048 rows through ``mla_decode_fwd`` — ONE such kernel in
+    the text, the two full layers share it, the rings' core the XLA form —,
+    16 tokens a call through ``moe_decode_fwd``) and the admission of 1 row
+    at the 16,384 bucket (through ``moe_sorted_fwd``; the masked XLA blocks
+    of ``ops/dsa.py`` and the windowed ones of ``ops/gqa.py``: no prefill
+    kernel, no decode core), as the chip traces them.  Arguments, results
+    and temporaries together stay under the chip's 16 GiB: the engine's
+    programs do not donate their state, so it is there twice."""
+    from progen_tpu.decode import sampler
+    from progen_tpu.ops import (gqa, lowering, mla_decode, mla_prefill,
+                                moe_decode, row_write)
+
+    for module in (row_write, gqa, mla_decode, mla_prefill, moe_decode,
+                   sampler):
+        monkeypatch.setattr(module, "_on_tpu", lambda: True)
+    monkeypatch.setattr(lowering, "on_tpu", lambda: True)
+    eng = dots3_engine
+
+    def placed(tree):
+        return jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+
+    params, state = placed(eng._params), placed(eng.state)
+    s, rows, lay = eng.num_slots, eng.admit_rows, eng._layout
+    assert rows == 1
+    if program == "chunk":
+        compiled = jax.jit(lambda *a: eng._decode_chunk_impl(*a)).lower(
+            params, state, *placed(lay.chunk_operands())).compile()
+    else:
+        prefill = [shape((rows, 16384), jnp.int32), shape((rows,), jnp.int32),
+                   shape((rows,), jnp.int32), shape((rows,), jnp.uint32),
+                   shape((rows,), jnp.int32), shape((rows,), jnp.float32),
+                   shape(eng._lmask_shape(rows), jnp.bool_)]
+        compiled = jax.jit(lambda *a: eng._admit_impl(*a)).lower(
+            params, state, shape((s,), jnp.int32), shape((s,), jnp.bool_),
+            *prefill, *placed(lay.write_tables(rows))).compile()
+    m = compiled.memory_analysis()
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
+    assert 8.17e9 < weights < 8.18e9 and 0.8e9 < held < 0.9e9, (weights,
+                                                                 held)
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    print(f"dots3 {program}: weights {weights / 1e9:.2f} GB, state "
+          f"{held / 1e9:.2f} GB, temporaries {m.temp_size_in_bytes / 1e9:.2f}"
+          f" GB, total {total / 1e9:.2f} GB")
+    assert weights + 2 * held <= total < 15.5e9, m
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mla_prefill_fwd" not in text
+    assert ("mla_decode_fwd" in text) == (program == "chunk")
+    if program == "chunk":
+        assert "moe_decode_fwd" in text and "row_write" in text
+    else:
+        assert "moe_sorted_fwd" in text

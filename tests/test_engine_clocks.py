@@ -303,21 +303,27 @@ def test_every_engine_span_has_its_annotation(served, mode, ring,
     exited = [n for kind, n in _Annotation.log if kind == "exit"]
     # the back-dated work spans, the step records, the instant events and
     # the three kinds of incident (this engine's programs compile as it
-    # runs) are the ring's alone
+    # runs) are the ring's alone.  Spans are told apart BY NAME and counted,
+    # never by a duration: on a loaded machine nothing about a clock's
+    # reading is certain but its order
     ring_only = {"serve.admit_work", "serve.chunk_work", "serve.step"}
     incidents = {"xla.compile", "host.gc", "serve.slow_step"}
+    instants = {"serve.submit", "serve.submit_embed", "serve.submit_fork",
+                "serve.shed", "serve.preempt"}
     timed = [s["name"] for s in spans
-             if s["name"] not in ring_only | incidents and s["dur"] > 0.0]
+             if s["name"] not in ring_only | incidents | instants]
     assert sorted(entered) == sorted(exited) == sorted(timed)
-    # children carry the step they belong to; the work spans end at a fetch
+    assert all(s["dur"] == 0.0 for s in spans if s["name"] in instants)
+    # children carry the step they belong to; a work span ends no earlier
+    # than a fetch of its own step returned
     waits = [s for s in spans if s["name"] == "serve.device_wait"]
     for s in spans:
-        if s["name"].startswith("serve.") and s["dur"] > 0.0:
+        if s["name"].startswith("serve.") and s["name"] not in instants:
             assert s["args"]["step"] >= 1
-        if s["name"] in ring_only:
+        if s["name"] in ("serve.admit_work", "serve.chunk_work"):
             end = s["ts"] + s["dur"]
-            assert any(w["ts"] + w["dur"] <= end <= w["ts"] + w["dur"] + 1e-3
-                       for w in waits)
+            assert any(w["args"]["step"] == s["args"]["step"]
+                       and w["ts"] + w["dur"] <= end for w in waits)
     assert {w["args"]["after"] for w in waits} <= {"admit", "chunk", "idle"}
 
 

@@ -197,21 +197,25 @@ def test_a_second_compile_after_clear_caches_counts_a_cache_hit(tmp_path):
 def test_every_collection_is_observed_and_a_long_pause_is_filed(
         observers, monkeypatch):
     registry, tracer = observers
+    # the limit is the TEST'S OWN both times, so that a loaded machine's
+    # pauses decide nothing: first one no pause reaches, then one every
+    # pause does
+    monkeypatch.setattr(compiles, "GC_INCIDENT_S", 1e9)
     gc.collect()
     seen = registry.snapshot()["host.gc_pause_s"]
-    assert seen["count"] >= 1 and 0 < seen["max"] < 5.0
-    # this collection was shorter than the limit or filed: never both
-    short = [i for i in tracer.incidents() if i["name"] == "host.gc"]
-    assert all(i["dur"] >= compiles.GC_INCIDENT_S for i in short)
+    assert seen["count"] >= 1 and seen["max"] > 0
+    # under the limit: observed, not filed
+    assert not [i for i in tracer.incidents() if i["name"] == "host.gc"]
     monkeypatch.setattr(compiles, "GC_INCIDENT_S", 0.0)
-    filed = len(tracer.incidents())
     compiles.set_step(4)
     gc.collect()
     compiles.set_step(None)
-    pause = tracer.incidents()[filed:][-1]
-    assert pause["name"] == "host.gc" and pause["args"]["generation"] == 2
+    # the full collection this test asked for, among whatever the
+    # interpreter ran by itself meanwhile
+    pause = [i for i in tracer.incidents() if i["name"] == "host.gc"
+             and i["args"]["generation"] == 2
+             and i["args"].get("step") == 4][-1]
     assert pause["args"]["seconds"] == pause["dur"] > 0
-    assert pause["args"]["step"] == 4
     assert registry.snapshot()["host.gc_pause_s"]["count"] > seen["count"]
 
 

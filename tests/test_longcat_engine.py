@@ -60,15 +60,26 @@ def _requests(n, seed=0, sampled=False, first_uid=0):
         for i in range(n)]
 
 
+@jax.jit
+def _reference_logits(params, row, at):
+    """The reference over one row padded to the engine's ``max_len``
+    (causality keeps the padding out of what is read): ONE program for
+    every length, where a call a length compiled the reference anew each
+    time (92-130 s of this file's fixture)."""
+    with jax.default_matmul_precision("highest"):
+        return ref.forward_row(params, row, as_dict(TINY),
+                               logit_positions=at)[0]
+
+
 def _sequential_greedy(params, r):
     """The plain sampler: the reference's full forward over everything so
     far, the best allowed token appended, again."""
     seq = list(r.tokens)
-    with jax.default_matmul_precision("highest"):
-        for _ in range(r.max_new_tokens):
-            logits = ref.forward_row(params, jnp.asarray(seq), as_dict(TINY),
-                                     logit_positions=jnp.array([len(seq) - 1]))
-            seq.append(1 + int(jnp.argmax(logits[0][0, 1:])))
+    for _ in range(r.max_new_tokens):
+        row = jnp.zeros((ENGINE["max_len"],), jnp.int32).at[:len(seq)].set(
+            jnp.asarray(seq))
+        logits = _reference_logits(params, row, jnp.array([len(seq) - 1]))
+        seq.append(1 + int(jnp.argmax(logits[0, 1:])))
     return seq[len(r.tokens):]
 
 

@@ -371,9 +371,11 @@ def test_kv_block_decode_through_the_kernel(monkeypatch, family, name, pos):
     pos = jnp.array(pos)
 
     def run():
-        # a fresh function per lowering: ``jax.jit`` would keep the trace
+        # one program a call, from a fresh function per lowering (``jax.jit``
+        # would keep the trace of a function it has seen): op by op the
+        # interpreter takes seconds a kernel
         with jax.default_matmul_precision("highest"):
-            return block.decode(x, pos, cache, p)
+            return jax.jit(lambda: block.decode(x, pos, cache, p))()
 
     want, want_cache = run()
     _force_kernel(monkeypatch)
@@ -421,9 +423,10 @@ def test_kv_block_decode_block_through_the_kernel(monkeypatch, tokens,
     cache = {"k": jax.random.normal(jax.random.key(2), shape),
              "v": jax.random.normal(jax.random.key(3), shape)}
 
-    def run():
+    def run():      # one program a call, a fresh function per lowering
         with jax.default_matmul_precision("highest"):
-            return block.decode_block(x, pos0, cache, p, commit, queries)
+            return jax.jit(lambda: block.decode_block(
+                x, pos0, cache, p, commit, queries))()
 
     want, want_cache = run()
     assert want.shape == (slots, queries or tokens, SDAR_WIDE.hidden_size)

@@ -107,13 +107,17 @@ def _both(monkeypatch, u, ids, w, live, layer, c, kernel="pallas",
           capacity=None, **tiles):
     """``held_experts`` under the XLA form, then under the kernel (which
     alone gets ``capacity``, its window's rows)."""
+    def held(capacity=None):
+        # one program a call (a fresh trace per lowering): the interpreter
+        # runs a kernel's grid op by op when it is not under ``jit``
+        return jax.jit(lambda *a: experts.held_experts(*a, c, capacity))(
+            u, ids, w, live, layer["experts"])
+
     with jax.default_matmul_precision("highest"):
-        want, load = experts.held_experts(u, ids, w, live, layer["experts"],
-                                          c)
+        want, load = held()
         _kernel_path(monkeypatch, **tiles)
         with record_lowerings() as chosen:
-            got, load2 = experts.held_experts(u, ids, w, live,
-                                              layer["experts"], c, capacity)
+            got, load2 = held(capacity)
     assert chosen["moe_experts"] == {kernel}
     assert got.shape == u.shape and got.dtype == F32
     np.testing.assert_array_equal(np.asarray(load), np.asarray(load2))
@@ -1019,8 +1023,9 @@ def test_a_prefill_counts_the_rows_its_lowering_computed(monkeypatch):
     tokens = jnp.arange(48, dtype=jnp.int32).reshape(2, 24) % 50 + 3
     lengths = jnp.array([24, 9])
 
-    def prefill():
-        return module.prefill(params, tokens, lengths, config, policy)[2]
+    def prefill():      # one program a call: a fresh trace per lowering
+        return jax.jit(lambda: module.prefill(params, tokens, lengths, config,
+                                              policy)[2])()
 
     before = prefill()
     assert float(before["moe.prefill_held"]) > 0
@@ -1050,8 +1055,10 @@ def test_a_decode_step_counts_its_passes_and_a_prefill_none(monkeypatch,
     tokens = jnp.arange(12, dtype=jnp.int32).reshape(2, 6) + 3
 
     def prefill():
-        return module.prefill(params, tokens, jnp.array([6, 4]), config,
-                              policy)[2]
+        # one program a call (a fresh trace per lowering): eagerly the
+        # blocked cores alone take seconds
+        return jax.jit(lambda: module.prefill(
+            params, tokens, jnp.array([6, 4]), config, policy)[2])()
 
     def counted(stats):
         return (float(stats["moe.expert_passes"]),
@@ -1065,9 +1072,9 @@ def test_a_decode_step_counts_its_passes_and_a_prefill_none(monkeypatch,
 
     def decode():
         # a fresh trace per lowering
-        return module.decode_step(params, jnp.array([5, 6, 7, 8]),
-                                  jnp.array([0, 1, 0, 2]), caches, live,
-                                  config, policy)[2]
+        return jax.jit(lambda: module.decode_step(
+            params, jnp.array([5, 6, 7, 8]), jnp.array([0, 1, 0, 2]), caches,
+            live, config, policy)[2])()
 
     before = decode()
     assert counted(before) == (0, 0)                   # the CPU: the XLA form

@@ -7,6 +7,7 @@ router against a NumPy transcription, the counters on a hand-sized batch,
 the attention cores against a plain masked softmax."""
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -28,6 +29,21 @@ def _tokens(seed=1, rows=2):
                               TINY.vocab_size)
 
 
+@jax.jit
+def _reference(params, toks):
+    """The reference's logits of every position, ONE program for the file
+    (eagerly its blocked attention alone takes seconds a call)."""
+    with jax.default_matmul_precision("highest"):
+        return ref.forward(params, toks, as_dict(TINY))
+
+
+@functools.partial(jax.jit, static_argnames=("policy",))
+def _prefill(params, toks, lengths, policy):
+    """One program a shape and precision for the file."""
+    with jax.default_matmul_precision("highest"):
+        return tr.prefill(params, toks, lengths, TINY, policy)
+
+
 def _served_logits(params, policy, toks, primes, bucket):
     """Logits of every position from ``prime - 1`` on, a row: the
     prefill's last position, then one decode step per token through the
@@ -35,8 +51,7 @@ def _served_logits(params, policy, toks, primes, bucket):
     position)."""
     rows = toks.shape[0]
     primes = jnp.asarray(primes)
-    first, per_token, _ = tr.prefill(params, toks[:, :bucket], primes, TINY,
-                                     policy)
+    first, per_token, _ = _prefill(params, toks[:, :bucket], primes, policy)
     caches = tr.caches_from(per_token, primes, TINY, MAX_LEN)
     step = jax.jit(lambda p, t, ps, c: tr.decode_step(
         p, t, ps, c, jnp.ones((rows,), bool), TINY, policy)[:2])
@@ -125,8 +140,8 @@ def test_prefill_then_decode_past_a_rings_wrap_matches_the_reference(
     params, policy = make(mixed=mixed)
     toks = _tokens()
     start = max(primes)
+    want = _reference(params, toks)
     with jax.default_matmul_precision("highest"):
-        want = ref.forward(params, toks, as_dict(TINY))
         got = _served_logits(params, policy, toks, primes, bucket)
     assert got.dtype == jnp.float32
     for row, prime in enumerate(primes):
