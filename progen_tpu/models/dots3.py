@@ -91,7 +91,7 @@ from progen_tpu.models.driver import (  # noqa: F401
 )
 from progen_tpu.models.experts import held_experts, kernel_counters
 from progen_tpu.models.latent import LatentBlock
-from progen_tpu.ops import dsa
+from progen_tpu.ops import dsa, mla_prefill
 from progen_tpu.ops.mla_decode import rows_visited
 
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -416,21 +416,25 @@ def attention_stats(blocks: dict, dt, caches, pos, live) -> dict:
     return out
 
 
-def prefill_attention_stats(blocks: dict, n: int, lengths) -> dict:
+def prefill_attention_stats(blocks: dict, n: int, lengths, dt) -> dict:
     """An admission's ``dsa.prefill_pairs_*`` over rows of ``lengths (R,)``
     padded to ``n``, summed over the full layers: the (query, key) pairs the
-    indexer scored, the pairs the sparse core computed a head
-    (``ops/dsa.py:prefill_pairs``: the XLA form's whole segments), and the
-    pairs the selection allows at real positions, ``sum_t min(t + 1,
-    index_topk)``."""
+    indexer scored, the pairs the sparse core computed a head under the
+    lowering that runs (``ops/dsa.py:prefill_pairs``: the XLA form's whole
+    segments, the kernel's visited tiles), and the pairs the selection
+    allows at real positions, ``sum_t min(t + 1, index_topk)``."""
     scored = attended = 0.0
     selected = jnp.zeros((), F32)
     for block in blocks.values():
         if block.indexer:
-            top_k = block.config.index_topk
-            a, b = dsa.prefill_pairs(n, top_k)
+            c = block.config
+            top_k = c.index_topk
+            lowering = mla_prefill.prefill_lowering(
+                n, c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim, dt)
+            a, b = dsa.prefill_pairs(n, top_k, lowering, lengths)
             scored += a * lengths.shape[0]
-            attended += b * lengths.shape[0]
+            attended += (b * lengths.shape[0] if lowering == "xla"
+                         else jnp.sum(b))
             m = lengths.astype(F32)
             past = jnp.maximum(m - top_k, 0)
             selected += jnp.sum(m * (m + 1) / 2 - past * (past + 1) / 2)
@@ -527,7 +531,8 @@ def prefill(params, tokens, lengths, config: Dots3Config,
     blocks = blocks_of(config)
     out = driver.prefill(_layers, blocks, params, tokens, lengths, config,
                          policy, **kwargs)
-    out[2].update(prefill_attention_stats(blocks, tokens.shape[1], lengths))
+    out[2].update(prefill_attention_stats(blocks, tokens.shape[1], lengths,
+                                          policy.compute_dtype))
     return out
 
 

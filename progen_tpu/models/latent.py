@@ -183,8 +183,10 @@ def mla_prefill(x, p, c, lengths=None, *, window=None, indexer=False,
     P, h), latent rows (R, P, latent))``.  The options are
     :class:`LatentBlock`'s: under a ``window`` the core is ``ops/gqa.py``'s
     windowed form over the expanded heads; with an ``indexer`` it is
-    ``ops/dsa.py``'s and the rows are ``{"latent": .., "index": (R, P,
-    index_head_dim)}``."""
+    ``ops/dsa.py``'s — the selection in XLA, and under it the SAME core
+    with the selection as its keep mask where the kernel applies, masked
+    blocks in XLA elsewhere — and the rows are ``{"latent": .., "index":
+    (R, P, index_head_dim)}``."""
     r, n, _ = x.shape
     with jax.named_scope("mla.prefill"):
         positions = jnp.broadcast_to(jnp.arange(n), (r, n))
@@ -198,7 +200,7 @@ def mla_prefill(x, p, c, lengths=None, *, window=None, indexer=False,
             q_idx, w, k_idx = index_project(x, c_q, p, c, positions)
             o = dsa.sparse_prefill_attention(
                 q_nope, q_rope, k_nope, k_r, v, q_idx, w, k_idx,
-                c.index_topk)
+                c.index_topk, lengths)
             rows = {"latent": latent, "index": k_idx}
         elif window is not None:
             with jax.named_scope("attn.window"):

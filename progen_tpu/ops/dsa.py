@@ -13,7 +13,9 @@ are no more than ``top_k``), and the attention's softmax runs over ``S_t``
 alone.  The products take the compute dtype's operands and accumulate in
 float32; the ReLU, the weighted sum over heads and the selection are float32.
 
-Everything here is plain XLA (no kernel yet), one form everywhere:
+The selection is plain XLA, one form everywhere; the attention it thins is
+``ops/mla_decode.py``'s and ``ops/mla_prefill.py``'s, each under its own
+choice of lowering:
 
 * **a decode step** — :func:`select_rows` scores a slot's whole indexer
   cache ``(S, T, d)`` (rows past the slot's count masked), ``lax.top_k``
@@ -26,11 +28,27 @@ Everything here is plain XLA (no kernel yet), one form everywhere:
   (:func:`segments`): segment ``g`` sees the keys up to its own end, the
   first needs no indexer at all (its queries see ``top_k`` keys or fewer),
   and inside a segment a ``lax.map`` over blocks of ``QUERY_BLOCK`` rows
-  keeps ``I`` and the score tensor a block high — ``(J, bq, keys)`` and
-  ``(H, bq, keys)`` float32.  A row's threshold is its ``top_k``-th largest
-  score (``lax.top_k``), the mask ``I >= threshold``.  Every pair under a
-  segment's key span is computed, selected or not: :func:`prefill_pairs`
-  says how many.
+  keeps ``I`` a block high, ``(J, bq, keys)`` float32.  A row's threshold
+  is its ``top_k``-th largest score (``lax.top_k``), the mask ``I >=
+  threshold`` (every score tied with the threshold is kept).  What reads
+  the mask is ``ops/mla_prefill.py``'s decision
+  (``mla_prefill.prefill_lowering``):
+
+  - where the flash kernel ``mla_prefill_fwd`` applies (a TPU, no mesh in
+    scope, the published head widths, ``P`` a multiple of 512) the blocks
+    emit the mask alone, one byte a pair for all heads, ``(R, P, P)``
+    int8, and ONE kernel call a layer takes it as its ``keep`` operand
+    with the rows' ``lengths``: a score tile lives in VMEM only, a tile
+    past a row's length or above the diagonal is neither computed nor
+    fetched.  The selection keeps at least an eighth of the keys under
+    these shapes, so no tile under the diagonal is without a kept pair and
+    none is skipped for the mask's sake;
+  - everywhere else (the CPU, the tests' tiny widths, a mesh) each block
+    computes its own masked core in XLA, the score tensor ``(H, bq,
+    keys)`` float32: every pair under a segment's key span, selected or
+    not, pads included.
+
+  :func:`prefill_pairs` says how many pairs either form computes.
 """
 
 from __future__ import annotations
@@ -41,7 +59,7 @@ import contextvars
 import jax
 import jax.numpy as jnp
 
-from progen_tpu.ops import mla_decode
+from progen_tpu.ops import mla_decode, mla_prefill
 
 F32 = jnp.float32
 QUERY_BLOCK = 128     # an admission's query rows per score block
@@ -116,16 +134,23 @@ def segments(n: int, top_k: int) -> tuple:
     return seg, QUERY_BLOCK if seg % QUERY_BLOCK == 0 else seg
 
 
-def prefill_pairs(n: int, top_k: int) -> tuple:
+def prefill_pairs(n: int, top_k: int, lowering: str = "xla",
+                  length=None) -> tuple:
     """``(scored, attended)``: the query-key pairs the indexer scores and
     the pairs the attention core computes (one head) for ONE row padded to
-    ``n`` positions — each segment's rows against the keys up to its end,
-    pads included; the indexer only where a segment's span passes
-    ``top_k``."""
+    ``n`` positions.  The indexer scores each segment's rows against the
+    keys up to its end, pads included, where a segment's span passes
+    ``top_k``.  The XLA core (``lowering`` ``"xla"``) computes the same
+    spans from the first segment on; the kernel (``"pallas"``) the tiles
+    it visits for a row of ``length`` real positions
+    (``mla_prefill.pairs_visited``; ``length`` may be an array of rows,
+    and so is ``attended`` then)."""
     seg, _ = segments(n, top_k)
     ends = range(seg, n + 1, seg)
-    return (float(sum(seg * e for e in ends if e > top_k)),
-            float(sum(seg * e for e in ends)))
+    scored = float(sum(seg * e for e in ends if e > top_k))
+    if lowering == "xla":
+        return scored, float(sum(seg * e for e in ends))
+    return scored, mla_prefill.pairs_visited(length, n)
 
 
 def joined_heads(q_nope, q_rope, k_nope, k_r):
@@ -140,27 +165,34 @@ def joined_heads(q_nope, q_rope, k_nope, k_r):
 
 
 def sparse_prefill_attention(q_nope, q_rope, k_nope, k_r, v, q_idx, w, k_idx,
-                             top_k: int):
+                             top_k: int, lengths=None):
     """Causal latent attention in the expanded form under the indexer's
     selection: ``q_nope (R, P, H, nope)``, ``q_rope (R, P, H, rope)``,
     ``k_nope (R, H, P, nope)``, ``k_r (R, P, rope)``, ``v (R, H, P, vd)``
     as ``ops/mla_prefill.py`` takes them, scores scaled by ``(nope +
     rope)^-1/2``; ``q_idx (R, P, J, d)``, ``w (R, P, J)`` float32, ``k_idx
-    (R, P, d)`` the indexer's.  ``(R, P, H * vd)``; every position is
-    computed, and a real one sees real keys only (attention is causal)."""
+    (R, P, d)`` the indexer's.  ``(R, P, H * vd)``, exact at the first
+    ``lengths (R,)`` positions of each row (default all): a real position
+    sees real keys only (attention is causal); the XLA core computes the
+    pads too, the kernel writes zeros past a row's last live tile."""
     r, n = q_nope.shape[:2]
-    q, k = joined_heads(q_nope, q_rope, k_nope, k_r)
-    q = q.transpose(0, 2, 1, 3)
-    scale = q.shape[-1] ** -0.5
     seg, bq = segments(n, top_k)
+    kernel = mla_prefill.prefill_lowering(
+        n, q_nope.shape[-1], q_rope.shape[-1], v.shape[-1],
+        v.dtype) == "pallas"
+    if not kernel:
+        q, k = joined_heads(q_nope, q_rope, k_nope, k_r)
+        q = q.transpose(0, 2, 1, 3)
+        scale = q.shape[-1] ** -0.5
 
     def rows(x, start, axis):
         return jax.lax.dynamic_slice_in_dim(x, start, bq, axis=axis)
 
-    def block(first, end):
-        """Query rows ``first .. first + bq - 1`` over keys ``0 .. end -
-        1``; the selection is computed only where a row can see more than
-        ``top_k`` keys (``end > top_k``: a static fact of the segment)."""
+    def selected(first, end):
+        """``(R, bq, end)``: the keys ``0 .. end - 1`` that query rows
+        ``first .. first + bq - 1`` attend; the selection is computed only
+        where a row can see more than ``top_k`` keys (``end > top_k``: a
+        static fact of the segment)."""
         gap = first + jnp.arange(bq)[:, None] - jnp.arange(end)[None, :]
         seen = jnp.broadcast_to(gap >= 0, (r, bq, end))
         if end > top_k:
@@ -171,13 +203,19 @@ def sparse_prefill_attention(q_nope, q_rope, k_nope, k_r, v, q_idx, w, k_idx,
             with jax.named_scope("dsa.select"):
                 kth = jax.lax.top_k(scores, top_k)[0][..., -1:]
                 seen = seen & (scores >= kth)
+        return seen
+
+    def block(first, end):
+        """The XLA core of one block of query rows over its segment's
+        keys."""
+        seen = selected(first, end)
         with jax.named_scope("attn.sparse"):
             logits = jnp.einsum("rhqd,rhkd->rhqk", rows(q, first, 2),
                                 k[:, :, :end],
                                 preferred_element_type=F32) * scale
             # unnormalised probabilities and ONE division after the value
-            # product, as ``ops/gqa.py``'s blocked form: every row sees
-            # itself, so the maximum is finite
+            # product, as ``ops/gqa.py``'s blocked form: every row keeps a
+            # key, so the maximum is finite
             logits = jnp.where(seen[:, None], logits, -jnp.inf)
             p = jnp.exp(logits - jnp.max(logits, axis=-1, keepdims=True))
             out = jnp.einsum("rhqk,rhkd->rqhd", p.astype(v.dtype),
@@ -185,10 +223,30 @@ def sparse_prefill_attention(q_nope, q_rope, k_nope, k_r, v, q_idx, w, k_idx,
             total = jnp.sum(p, axis=-1).transpose(0, 2, 1)
             return (out / total[..., None]).astype(v.dtype)
 
+    def mask(first, end):
+        return selected(first, end).astype(jnp.int8)
+
+    body = mask if kernel else block
     outs = []
     for start in range(0, n, seg):
+        end = start + seg
+        if kernel and end <= top_k:
+            # every visible key: the kernel's own causal rule suffices
+            outs.append(jnp.ones((r, seg, n), jnp.int8))
+            continue
         firsts = start + bq * jnp.arange(seg // bq)
-        out = jax.lax.map(lambda f, e=start + seg: block(f, e), firsts)
-        # (blocks, R, bq, H, vd) -> (R, seg, H * vd)
-        outs.append(out.transpose(1, 0, 2, 3, 4).reshape(r, seg, -1))
-    return jnp.concatenate(outs, axis=1)
+        out = jax.lax.map(lambda f, e=end: body(f, e), firsts)
+        if kernel:
+            # (blocks, R, bq, end) -> (R, seg, P): no key past the span
+            out = out.transpose(1, 0, 2, 3).reshape(r, seg, end)
+            outs.append(jnp.pad(out, ((0, 0), (0, 0), (0, n - end))))
+        else:
+            # (blocks, R, bq, H, vd) -> (R, seg, H * vd)
+            outs.append(out.transpose(1, 0, 2, 3, 4).reshape(r, seg, -1))
+    if not kernel:
+        return jnp.concatenate(outs, axis=1)
+    with jax.named_scope("attn.sparse"):
+        # one segment that needs no selection (``n <= top_k``): no mask
+        keep = jnp.concatenate(outs, axis=1) if n > top_k else None
+        return mla_prefill.prefill_attention(q_nope, q_rope, k_nope, k_r, v,
+                                             lengths, keep)

@@ -12,6 +12,7 @@ negative: one pair in 65,536): the admission keeps EVERY key tied with the
 What the families' tests share (``as_dict``, ``make``) is
 ``tests/longcat_tiny.py``'s."""
 
+import dataclasses
 import functools
 
 from progen_tpu.models import dots3 as dm
@@ -31,6 +32,31 @@ TINY = dm.Dots3Config(
     swa_rope_theta=100.0, sliding_window_size=WINDOW, n_routed_experts=8,
     num_experts_per_tok=2, max_position_embeddings=64, experts_held=8,
     first_expert=0, router_bias_std=0.05, prefill_bucket=8)
+
+
+# the FULL layers' heads at the published widths (128 + 64 beside 128), two
+# of them, and a selection of 512: what ``ops/mla_prefill.py``'s kernel takes
+# (the tests run it under the interpreter); everything else as tiny as above
+WIDE_TOP_K = 512
+WIDE = dataclasses.replace(
+    TINY, num_attention_heads=2, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128, index_head_dim=64, index_topk=WIDE_TOP_K,
+    max_position_embeddings=2048,
+    prefill_bucket=512)
+
+
+def force_prefill_kernel(monkeypatch, tile=256):
+    """``ops/mla_prefill.py``'s kernel lowering on the CPU: the backend test
+    patched, tiles of ``tile``, the interpreter."""
+    from progen_tpu.ops import mla_prefill
+
+    monkeypatch.setattr(mla_prefill, "_on_tpu", lambda: True)
+    monkeypatch.setattr(mla_prefill, "TILE", tile)
+    monkeypatch.setattr(mla_prefill, "MIN_TILE", tile)
+    monkeypatch.setattr(
+        mla_prefill, "pallas_prefill_attention",
+        lambda *a, _f=mla_prefill.pallas_prefill_attention: _f(
+            *a, interpret=True))
 
 
 @functools.cache

@@ -375,6 +375,27 @@ def test_mla_prefill_kernel_compiles_for_v5e_at_128_heads(
         shape((r,), jnp.int32))
 
 
+@pytest.mark.parametrize("bucket", [4096, 8192, 16384])
+def test_mla_prefill_kernel_compiles_for_v5e_under_a_keep_mask(
+        shape, no_persistent_cache, bucket):
+    """``mla_prefill_fwd`` as a full layer of ``serve-dots3-longdoc-backlog``
+    calls it: 1 row, 128 heads, the selection as an int8 ``(1, P, P)`` keep
+    mask in tiles beside the score tile, each bucket with a segment past the
+    first 2,048 rows."""
+    from progen_tpu.ops.mla_prefill import pallas_prefill_attention
+
+    r, heads, bf16 = 1, 128, jnp.bfloat16
+    _assert_kernel_compiles(
+        lambda *a: pallas_prefill_attention(*a, interpret=False),
+        shape((r, heads, bucket, 128), bf16),
+        shape((r, heads, bucket, 64), bf16),
+        shape((r, heads, bucket, 128), bf16),
+        shape((r, bucket, 64), bf16),
+        shape((r, heads, bucket, 128), bf16),
+        shape((r,), jnp.int32),
+        shape((r, bucket, bucket), jnp.int8))
+
+
 @pytest.mark.parametrize("bucket", [512, 1024, 2048, 4096])
 def test_mla_prefill_kernel_compiles_for_v5e(shape, no_persistent_cache,
                                              bucket):
@@ -1050,9 +1071,10 @@ def test_dots3_programs_compile_for_the_chip_and_fit_it(
     the gathered 2,048 rows through ``mla_decode_fwd`` — ONE such kernel in
     the text, the two full layers share it, the rings' core the XLA form —,
     16 tokens a call through ``moe_decode_fwd``) and the admission of 1 row
-    at the 16,384 bucket (through ``moe_sorted_fwd``; the masked XLA blocks
-    of ``ops/dsa.py`` and the windowed ones of ``ops/gqa.py``: no prefill
-    kernel, no decode core), as the chip traces them.  Arguments, results
+    at the 16,384 bucket (through ``moe_sorted_fwd``; the full layers' core
+    ``mla_prefill_fwd`` under the selection of ``ops/dsa.py`` as its keep
+    mask, the sliding ones the windowed XLA blocks of ``ops/gqa.py``; no
+    decode core), as the chip traces them.  Arguments, results
     and temporaries together stay under the chip's 16 GiB: the engine's
     programs do not donate their state, so it is there twice."""
     from progen_tpu.decode import sampler
@@ -1094,7 +1116,8 @@ def test_dots3_programs_compile_for_the_chip_and_fit_it(
           f" GB, total {total / 1e9:.2f} GB")
     assert weights + 2 * held <= total < 15.5e9, m
     text = compiled.as_text()
-    assert "tpu_custom_call" in text and "mla_prefill_fwd" not in text
+    assert "tpu_custom_call" in text
+    assert ("mla_prefill_fwd" in text) == (program != "chunk")
     assert ("mla_decode_fwd" in text) == (program == "chunk")
     if program == "chunk":
         assert "moe_decode_fwd" in text and "row_write" in text

@@ -8,7 +8,9 @@ readmitted after a longer request (stale latent rows, stale indexer rows, a
 stale ring) serves what a fresh one serves; nothing compiles after
 ``aot_warmup``; the modes that are ProGen's alone are refused by name; the
 family's counters and byte gauges reach the registry and
-``status()["model_stats"]``."""
+``status()["model_stats"]``; and with the full layers' heads at the published
+widths and the kernel forced, the admission says ``"mla_prefill": "pallas"``,
+serves the same tokens and counts the tiles it visited."""
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +23,9 @@ from progen_tpu.decode.engine import SLOTS_PER_ADMIT_ROW
 from progen_tpu.decode.family import UnsupportedFamilyMode, family_for
 from progen_tpu.models.dots3 import Dots3Family
 from progen_tpu.observe.metrics import get_registry
-from tests.dots3_tiny import TINY, TOP_K, WINDOW, as_dict, make
+from progen_tpu.ops import dsa
+from tests.dots3_tiny import (TINY, TOP_K, WIDE, WIDE_TOP_K, WINDOW, as_dict,
+                              force_prefill_kernel, make)
 
 pytestmark = pytest.mark.serving
 
@@ -237,3 +241,39 @@ def test_counters_and_byte_gauges_reach_the_registry_and_the_status(served,
     for name in ("dsa.index_bytes_read", "mla.cache_bytes_read",
                  "mla.window_bytes_read"):
         assert snap[name]["value"] == gauges[name], name
+
+
+def test_engine_states_the_kernel_and_serves_the_same_tokens(monkeypatch):
+    """The engine over ``WIDE`` (the full layers' heads 128 + 64 beside
+    128, a selection of 512), a prime of 600 in the 1,024 bucket: on the CPU
+    the admission is the masked blocks and says nothing under
+    ``"mla_prefill"``; with the kernel forced (interpreter, tiles of 256) it
+    says ``"pallas"``, the greedy tokens are the same, and
+    ``dsa.prefill_pairs_attended`` is the six tiles a row of 600 visits a
+    layer where the blocks count both whole segments."""
+    params, policy = make(WIDE)
+    prime = np.random.default_rng(0).integers(1, WIDE.vocab_size, 600)
+
+    def serve():
+        eng = ServingEngine(WIDE, params, policy=policy,
+                            num_slots=SLOTS_PER_ADMIT_ROW, chunk_size=4,
+                            max_len=1024 + 8)
+        eng.submit(Request(uid=0, tokens=prime.tolist(), max_new_tokens=5,
+                           temperature=0.0, seed=1,
+                           logit_mask=_never_zero()))
+        (done,) = eng.run_until_idle(max_chunks=10)
+        return list(done.tokens), eng.status(), eng.model_stats
+
+    want, status, stats = serve()
+    assert status["mla_prefill"] is None
+    # one row a run, two full layers
+    assert stats["dsa.prefill_pairs_attended"] == 2 * dsa.prefill_pairs(
+        1024, WIDE_TOP_K)[1]
+    force_prefill_kernel(monkeypatch)
+    got, status, kernel_stats = serve()
+    assert status["mla_prefill"] == "pallas"
+    assert status["gqa_prefill"] == "xla"       # the sliding layers' blocks
+    assert got == want
+    assert kernel_stats["dsa.prefill_pairs_attended"] == 2 * 6 * 256 ** 2
+    for name in ("dsa.prefill_pairs_scored", "dsa.prefill_pairs_selected"):
+        assert kernel_stats[name] == stats[name] > 0

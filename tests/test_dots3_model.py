@@ -6,7 +6,9 @@ prefilled and then decoded past ``index_topk`` (keys are dropped) and past
 the window (the ring wraps), the selected sets equal to the reference's,
 each omission the reference can plant failing the tolerance the program
 keeps, the two kinds of cache, the counters and byte gauges, and
-``ops/dsa.py``'s cores against a dense softmax."""
+``ops/dsa.py``'s cores against a dense softmax, and which lowering an
+admission's core takes: the flash kernel under the selection as its keep mask
+where ``ops/mla_prefill.py`` says it applies, the masked blocks elsewhere."""
 
 import dataclasses
 import functools
@@ -18,13 +20,26 @@ import pytest
 
 from perf.lib import reference_dots3 as ref
 from progen_tpu.models import dots3 as dm
-from progen_tpu.ops import dsa
-from tests.dots3_tiny import TINY, TOP_K, WINDOW, as_dict, make
+from progen_tpu.models import latent
+from progen_tpu.ops import dsa, mla_prefill
+from progen_tpu.ops.lowering import record_lowerings
+from tests.dots3_tiny import (TINY, TOP_K, WIDE, WIDE_TOP_K, WINDOW, as_dict,
+                              force_prefill_kernel, make)
 
 T, MAX_LEN = 32, 48
 # float32 end to end against float32 ``highest``: what is left is the order
 # of sums (the absorbed form, the one division after the value product)
 TOL = 5e-5
+
+
+def _published():
+    import json
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "perf", "configs",
+                        "dots3-note-prev-ep8.json")
+    with open(path) as f:
+        return dm.Dots3Config.from_dict(json.load(f))
 
 
 def _tokens(seed=1, rows=2):
@@ -238,13 +253,7 @@ def test_decode_counts_contexts_selections_and_the_rows_each_core_reads():
 
 
 def test_the_config_reads_the_published_keys_and_refuses_what_it_lacks():
-    import json
-    import os
-
-    path = os.path.join(os.path.dirname(__file__), "..", "perf", "configs",
-                        "dots3-note-prev-ep8.json")
-    with open(path) as f:
-        c = dm.Dots3Config.from_dict(json.load(f))
+    c = _published()
     assert c.num_hidden_layers == 5 and c.experts_held == 32
     assert c.layer_types == (dm.FULL, dm.FULL) + (dm.SLIDING,) * 3
     assert (c.index_topk, c.sliding_window_size) == (2048, 513)
@@ -338,3 +347,100 @@ def test_select_rows_puts_the_kept_rows_first_and_gathers_them():
     # a cache shorter than top_k keeps what there is
     rows, kept = dsa.select_rows(q_idx, w, index[:, :5], counts, top_k)
     assert rows.shape == (s, 5) and kept.tolist() == [3, 5, 5]
+
+
+# ------------------------------------- which lowering an admission's core takes
+
+
+@pytest.mark.parametrize("where,want", [
+    ("on-a-tpu", "pallas"), ("cpu-default", "xla"),
+    ("tiny-widths-on-a-tpu", "xla"), ("a-mesh-on-a-tpu", "xla")])
+def test_a_full_layer_traces_one_kernel_call_where_the_kernel_applies(
+        where, want, monkeypatch, devices8):
+    """A full layer's admission, abstract operands, ``P`` 4,096 of one
+    row: at the published widths on a TPU exactly ONE ``pallas_call``,
+    ``mla_prefill_fwd``, no float32 ``(1, 128, 128, keys)`` score block in
+    the text and the note ``"mla_prefill": "pallas"``; on the CPU, at the
+    tiny widths and under a mesh today's masked blocks and no note."""
+    import contextlib
+
+    c, n = (TINY, 32) if where.startswith("tiny") else (_published(), 4096)
+    policy = dm.bf16_policy()
+    params = jax.eval_shape(lambda k: dm.init_params(c, k, policy),
+                            jax.random.key(0))
+    shape = c.shape_of(dm.FULL)
+    if where != "cpu-default":
+        monkeypatch.setattr(mla_prefill, "_on_tpu", lambda: True)
+    mesh = (jax.sharding.Mesh(np.asarray(devices8[:2]), ("data",))
+            if where.startswith("a-mesh") else contextlib.nullcontext())
+    x = jax.ShapeDtypeStruct((1, n, c.hidden_size), jnp.bfloat16)
+    with mesh, record_lowerings() as chosen:
+        jaxpr = str(jax.make_jaxpr(lambda x, p, m: latent.mla_prefill(
+            x, p, shape, m, indexer=True, gate=True)[0])(
+            x, params["layers"][1]["attn"],
+            jax.ShapeDtypeStruct((1,), jnp.int32)))
+        got = dm.prefill_attention_stats(
+            dm.blocks_of(c), n, jnp.array([n - n // 4 - 1]), jnp.bfloat16)
+    heads = shape.num_attention_heads
+    blocks = f"f32[1,{heads},{dsa.segments(n, c.index_topk)[1]},"
+    if want == "pallas":
+        assert chosen == {"mla_prefill": {"pallas"}}
+        assert jaxpr.count("pallas_call") == 1
+        assert "name=mla_prefill_fwd" in jaxpr and blocks not in jaxpr
+        assert f"i8[1,{n},{n}]" in jaxpr      # the mask, a byte a pair
+    else:
+        assert "mla_prefill" not in chosen
+        assert "pallas_call" not in jaxpr and blocks in jaxpr
+    # the selection is the same text either way: one ``top_k`` a segment
+    # past the first
+    assert jaxpr.count("top_k[") == n // c.index_topk - 1
+    # a row of 3,071: three live query tiles of 1,024 under the kernel
+    scored, attended = dsa.prefill_pairs(n, c.index_topk)
+    assert float(got["dsa.prefill_pairs_scored"]) == 2 * scored
+    assert float(got["dsa.prefill_pairs_attended"]) == 2 * (
+        (1 + 2 + 3) * 1024 ** 2 if want == "pallas" else attended)
+
+
+def test_prefill_through_the_kernel_serves_the_blocks_logits(monkeypatch):
+    """``dots3.prefill`` with the full layers' heads at the published
+    widths, rows of 1,024 and 600 in a bucket of 1,024, a selection of 512:
+    the kernel under the selection as its keep mask (interpreter, tiles of
+    256) gives the masked blocks' logits at every real position, whatever
+    the padding holds; ``dsa.prefill_pairs_attended`` counts the tiles
+    visited where the blocks count whole segments, ``_scored`` and
+    ``_selected`` do not move."""
+    params, policy = make(WIDE)
+    n, lengths = 1024, jnp.array([1024, 600])
+    toks = jax.random.randint(jax.random.key(1), (2, n), 1, WIDE.vocab_size)
+    at = jnp.broadcast_to(jnp.arange(0, n, 8), (2, n // 8))
+
+    def run(tokens):
+        # a fresh function per lowering: ``jax.jit`` would keep the trace
+        with jax.default_matmul_precision("highest"), \
+                record_lowerings() as chosen:
+            logits, _, stats = dm.prefill(params, tokens, lengths, WIDE,
+                                          policy, logit_positions=at)
+        return logits, stats, chosen
+
+    want, blocked, chosen = run(toks)
+    assert "mla_prefill" not in chosen
+    force_prefill_kernel(monkeypatch)
+    got, stats, chosen = run(toks)
+    assert chosen["mla_prefill"] == {"pallas"}
+    junk, _, _ = run(jnp.where(jnp.arange(n)[None] < lengths[:, None],
+                               toks, 5))
+    for row, length in enumerate(lengths.tolist()):
+        real = np.asarray(at[row]) < length
+        assert float(jnp.abs(got[row, real] - want[row, real]).max()) < 2e-4
+        np.testing.assert_array_equal(np.asarray(got[row, real]),
+                                      np.asarray(junk[row, real]))
+    assert float(want.std()) > 0.3
+    scored, attended = dsa.prefill_pairs(n, WIDE_TOP_K)
+    assert attended == 512 * (512 + 1024)
+    assert float(blocked["dsa.prefill_pairs_attended"]) == 2 * 2 * attended
+    # tiles of 256: ten under the diagonal of 1,024 rows, six of 600
+    visited = dsa.prefill_pairs(n, WIDE_TOP_K, "pallas", lengths)[1]
+    assert visited.tolist() == [10 * 256 ** 2, 6 * 256 ** 2]
+    assert float(stats["dsa.prefill_pairs_attended"]) == 2 * 16 * 256 ** 2
+    for name in ("dsa.prefill_pairs_scored", "dsa.prefill_pairs_selected"):
+        assert float(stats[name]) == float(blocked[name]) > 0

@@ -4,9 +4,13 @@ interpreter here, at the head widths LongCat publishes (128 + 64, v 128):
 against the blocked XLA form at every real position, for lengths that end
 inside a tile, on a tile edge, at ``P`` and at 0; real positions bit-equal
 whatever the padding holds; skipped tiles written as zeros; and the choice
-of lowering from backend, mesh and shape, as ``status()`` shows it."""
+of lowering from backend, mesh and shape, as ``status()`` shows it.  Under a
+KEEP MASK (dots3's full layers bring the indexer's selection as one): equal
+to a dense masked softmax, rows that keep no key of their first tiles or not
+themselves included; and with none the kernel's text is PR 56's."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +21,7 @@ from progen_tpu.models import longcat as lc
 from progen_tpu.ops import mla_prefill as mp
 from progen_tpu.ops.lowering import record_lowerings
 from tests.longcat_tiny import TINY, make
+from tools import program_hash
 
 NOPE, ROPE, VD = 128, 64, 128
 R = 2
@@ -37,12 +42,12 @@ def _operands(p, heads, dtype, seed=0, rows=R):
             normal(ks[4], (rows, heads, p, VD)))
 
 
-def _kernel(q_nope, q_rope, k_nope, k_r, v, lengths, **tiles):
+def _kernel(q_nope, q_rope, k_nope, k_r, v, lengths, keep=None, **tiles):
     with jax.default_matmul_precision("highest"):
         return mp.pallas_prefill_attention(
             q_nope.transpose(0, 2, 1, 3), q_rope.transpose(0, 2, 1, 3),
-            k_nope, k_r, v, jnp.asarray(lengths, jnp.int32), interpret=True,
-            **tiles)
+            k_nope, k_r, v, jnp.asarray(lengths, jnp.int32), keep,
+            interpret=True, **tiles)
 
 
 def _blocked(*ops):
@@ -123,6 +128,157 @@ def test_rows_of_length_0_cost_nothing_and_read_as_zeros():
     ops = [jnp.full_like(x, jnp.nan) for x in _operands(p, 2, jnp.bfloat16)]
     got = _f32(_kernel(*ops, [0, 0], block_q=128, block_k=128))
     assert got.shape == (R, p, 2 * VD) and not got.any()
+
+# ---- under a keep mask ------------------------------------------------------
+
+T512 = dict(block_q=512, block_k=512)
+
+
+def _dense_masked_softmax(q_nope, q_rope, k_nope, k_r, v, keep):
+    """The whole ``(R, H, P, P)`` float32 score under ``s <= t`` and
+    ``keep``, one softmax: nothing of the kernel's or the blocked form's."""
+    q = jnp.concatenate([q_nope, q_rope], -1).astype(jnp.float32)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_r[:, None], k_nope.shape[:3]
+                                  + k_r.shape[-1:])], -1).astype(jnp.float32)
+    n = q.shape[1]
+    with jax.default_matmul_precision("highest"):
+        logits = jnp.einsum("rqhd,rhkd->rhqk", q, k) * q.shape[-1] ** -0.5
+        seen = jnp.tril(jnp.ones((n, n), bool)) & (keep != 0)[:, None]
+        probs = jax.nn.softmax(jnp.where(seen, logits, -jnp.inf), -1)
+        out = jnp.einsum("rhqk,rhkd->rqhd", probs, v.astype(jnp.float32))
+    return np.asarray(out.reshape(out.shape[0], n, -1))
+
+
+def _selection(p, seed, share=0.3):
+    """``keep (R, P, P)`` int8: a random ``share`` of the pairs and key 0
+    of every row — but in each row of ``ODD`` what its name says."""
+    keep = np.array(jax.random.bernoulli(
+        jax.random.key(seed), share, (R, p, p)), np.int8)
+    keep[:, :, 0] = 1
+    for row, keys in ODD.items():
+        keep[:, row] = 0
+        keep[:, row, list(keys)] = 1
+    return jnp.asarray(keep)
+
+
+# query row -> the only keys it keeps (tiles of 512): nothing of its first
+# key tile, nothing of its first two, not itself (and the last key before
+# it), nothing but its own
+ODD = {900: (600, 899), 1023: (1022,), 700: (3, 699), 513: (513,)}
+
+
+@pytest.mark.parametrize("p,heads,dtype", [
+    (1024, 2, "float32"), (1024, 4, "bfloat16"), (1536, 2, "bfloat16"),
+    (2048, 2, "float32"), (2048, 3, "bfloat16")], ids=lambda v: str(v))
+def test_masked_kernel_equals_a_dense_masked_softmax(p, heads, dtype):
+    """Every position of both rows, the odd rows among them: a row whose
+    kept keys all lie beyond its first key tile (its running maximum is
+    still the floor when they come) and one that does not keep itself."""
+    ops = _operands(p, heads, jnp.dtype(dtype))
+    keep = _selection(p, seed=p + heads)
+    got = _f32(_kernel(*ops, [p, p], keep, **T512))
+    want = _dense_masked_softmax(*ops, keep)
+    assert np.isfinite(got).all() and float(np.abs(want).max()) > 1.0
+    assert float(np.abs(got - want).max()) < TOL[dtype]
+    for row in ODD:
+        assert float(np.abs(got[:, row] - want[:, row]).max()) < TOL[dtype]
+        assert np.abs(got[:, row]).max() > 0
+
+
+def test_off_the_chip_a_mask_goes_to_the_blocked_form():
+    """``prefill_attention`` with a mask where the kernel does not apply:
+    the blocked XLA form under the same rule."""
+    p = 1024
+    ops = _operands(p, 2, jnp.float32)
+    keep = _selection(p, seed=11)
+    with record_lowerings() as chosen, \
+            jax.default_matmul_precision("highest"):
+        got = _f32(mp.prefill_attention(*ops, keep=keep))
+    assert chosen == {"mla_prefill": {"xla"}}
+    want = _dense_masked_softmax(*ops, keep)
+    assert float(np.abs(got - want).max()) < TOL["float32"]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_masked_rows_of_unequal_lengths_ignore_what_the_pads_hold(dtype):
+    """Junk in the operands AND in the mask at pad positions changes no bit
+    at a real one; query tiles past a row's length read as zeros."""
+    p, lengths = 1536, [1100, 513]
+    q_nope, q_rope, k_nope, k_r, v = _operands(p, 2, jnp.dtype(dtype))
+    keep = _selection(p, seed=7)
+    pad = jnp.arange(p)[None, :] >= jnp.asarray(lengths)[:, None]  # (R, P)
+
+    def junk(x, axis):
+        shape = [1] * x.ndim
+        shape[0], shape[axis] = R, p
+        return jnp.where(pad.reshape(shape), jnp.asarray(37.5, x.dtype), x)
+
+    got = _f32(_kernel(q_nope, q_rope, k_nope, k_r, v, lengths, keep, **T512))
+    other = jnp.where(pad[:, :, None] | pad[:, None, :], 1 - keep, keep)
+    again = _f32(_kernel(junk(q_nope, 1), junk(q_rope, 1), junk(k_nope, 2),
+                         junk(k_r, 1), junk(v, 2), lengths, other, **T512))
+    want = _dense_masked_softmax(q_nope, q_rope, k_nope, k_r, v, keep)
+    assert np.isfinite(again).all()
+    for row, n in enumerate(lengths):
+        np.testing.assert_array_equal(got[row, :n], again[row, :n])
+        assert float(np.abs(got[row, :n] - want[row, :n]).max()) < TOL[dtype]
+        assert not got[row, -(-n // 512) * 512:].any()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_mask_that_keeps_everything_is_the_unmasked_kernel(dtype):
+    """The same arithmetic on the same numbers: the floor under the maximum
+    changes no value once a key is kept.  Held to
+    the last place and not to the bit, because the interpreter compiles
+    the two bodies apart and XLA's CPU fusion sums a row of probabilities
+    in another order behind the mask's select (with the select taken out
+    of the masked body the two are bit-equal)."""
+    p, lengths = 1024, [1024, 700]
+    ops = _operands(p, 2, jnp.dtype(dtype))
+    plain = _f32(_kernel(*ops, lengths, **T512))
+    masked = _f32(_kernel(*ops, lengths, jnp.ones((R, p, p), jnp.int8),
+                          **T512))
+    ulp = {"float32": 2.0 ** -23, "bfloat16": 2.0 ** -7}[dtype]
+    assert np.abs(plain - masked).max() <= 2 * ulp * np.abs(plain).max()
+    assert not masked[1, 1024:].any()
+
+
+# sha256 heads of the kernel's jaxpr text WITHOUT a mask at the shapes of the
+# two cells that call it so, taken on PR 56's tree (34aff0b) before the kernel
+# took one: LongCat's and DeepSeek-V2's admissions trace what they traced,
+# letter for letter (``tests/golden/programs.json`` is the CPU's trace and
+# holds no Pallas lowering)
+KERNEL_TEXT = {
+    "longcat": ((2, 64, 4096), "99459dbaf36edac2"),
+    "deepseek_v2": ((4, 128, 1024), "1428685ae621b80a"),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_TEXT))
+def test_without_a_mask_the_kernel_traces_the_text_it_traced(case):
+    (r, heads, p), want = KERNEL_TEXT[case]
+    sd = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16)
+    head = program_hash.program_head(
+        lambda *a: mp.pallas_prefill_attention(*a, interpret=True),
+        (sd((r, heads, p, NOPE)), sd((r, heads, p, ROPE)),
+         sd((r, heads, p, NOPE)), sd((r, p, ROPE)), sd((r, heads, p, VD)),
+         jax.ShapeDtypeStruct((r,), jnp.int32)))
+    assert head == want
+    masked = program_hash.program_head(
+        lambda *a: mp.pallas_prefill_attention(*a, interpret=True),
+        (sd((r, heads, p, NOPE)), sd((r, heads, p, ROPE)),
+         sd((r, heads, p, NOPE)), sd((r, p, ROPE)), sd((r, heads, p, VD)),
+         jax.ShapeDtypeStruct((r,), jnp.int32),
+         jax.ShapeDtypeStruct((r, p, p), jnp.int8)))
+    assert masked != want
+
+
+def test_pairs_visited_counts_the_tiles_the_grid_visits():
+    """One head's pairs a row: live query tiles against the key tiles at or
+    under the diagonal and under the length (tiles of 1024 at P = 4096)."""
+    got = mp.pairs_visited(jnp.array([4096, 2049, 2048, 1, 0]), 4096)
+    assert got.tolist() == [t * 1024 ** 2 for t in (10, 6, 3, 1, 0)]
 
 
 # ---- which lowering, and where it is stated --------------------------------
