@@ -96,13 +96,18 @@ def _tokens(comps):
     return {c.uid: (c.tokens.tolist(), c.status) for c in comps}
 
 
-def _alone(served, reqs, **kw):
-    """Every request served by itself: a one-slot engine admits groups of
-    one, each in its own prefill bucket."""
-    eng = _engine(served, **{**kw, "num_slots": 1})
+def _one_at_a_time(eng, reqs):
+    """``reqs`` through a one-slot engine, which admits groups of one, each
+    in its own prefill bucket."""
+    assert eng.num_slots == 1 and not eng.has_work
     for r in reqs:
         eng.submit(r)
     return _tokens(eng.run_until_idle(max_chunks=100 * len(reqs)))
+
+
+def _alone(served, reqs, **kw):
+    """Every request served by itself."""
+    return _one_at_a_time(_engine(served, **{**kw, "num_slots": 1}), reqs)
 
 
 def _runs(eng):
@@ -110,14 +115,36 @@ def _runs(eng):
     return h.count, h.sum
 
 
+@pytest.fixture(scope="module")
+def idle_engine(served):
+    """``layout -> `` ONE engine a layout for the tests that read how far its
+    counters moved: handed over idle — nothing queued, no slot taken and,
+    for ``paged``, the pool whole — or the case before it left something."""
+    engines = {}
+
+    def engine_of(layout):
+        if layout not in engines:
+            engines[layout] = _engine(served, **LAYOUTS[layout])
+        eng = engines[layout]
+        assert not eng._queue and not eng._inflight and not eng.num_active
+        if layout == "paged":
+            assert eng._pool.free_pages + eng._pool.cached_pages == \
+                eng._pool.capacity
+        return eng
+
+    return engine_of
+
+
 # ------------------------------------------- (a) the same tokens, any group
 
 
 @pytest.fixture(scope="module")
 def alone(served):
-    """Reference completions, one request at a time, per sampling mode."""
-    return {(sampled, masked): _alone(
-        served, _requests(SLOTS, sampled=sampled, masked=masked))
+    """Reference completions, one request at a time, per sampling mode
+    (one one-slot engine for the four: the modes are the requests')."""
+    eng = _engine(served, num_slots=1)
+    return {(sampled, masked): _one_at_a_time(
+        eng, _requests(SLOTS, sampled=sampled, masked=masked))
         for sampled in (False, True) for masked in (False, True)}
 
 
@@ -129,8 +156,8 @@ def alone(served):
     for sampled in (False, True) for masked in (False, True)
     if layout == "slots" or sampled == masked])
 def test_group_of_n_serves_the_tokens_of_requests_served_alone(
-        served, alone, n, sampled, masked, layout):
-    eng = _engine(served, **LAYOUTS[layout])
+        idle_engine, alone, n, sampled, masked, layout):
+    eng = idle_engine(layout)
     runs0, rows0 = _runs(eng)
     for r in _requests(n, sampled=sampled, masked=masked):
         eng.submit(r)
@@ -337,8 +364,8 @@ def test_transient_exhaustion_in_the_second_run_requeues_it_in_order(
 
 
 @pytest.mark.parametrize("n", GROUPS)
-def test_admit_rows_per_run_and_prefill_s_per_admitting_step(served, n):
-    eng = _engine(served)
+def test_admit_rows_per_run_and_prefill_s_per_admitting_step(idle_engine, n):
+    eng = idle_engine("slots")
     rows_h, stage_h = eng._admit_rows_hist, eng._stage_hist["prefill_s"]
     runs0, rows0, stages0 = rows_h.count, rows_h.sum, stage_h.count
     for r in _requests(n):
@@ -351,6 +378,7 @@ def test_admit_rows_per_run_and_prefill_s_per_admitting_step(served, n):
     eng.step()                      # nothing queued: no admission
     assert rows_h.count - runs0 == math.ceil(n / ADMIT_ROWS)
     assert stage_h.count - stages0 == 1
+    eng.run_until_idle(max_chunks=100)      # the next case takes it idle
 
 
 def test_prefill_s_runs_from_the_first_dispatch_to_the_flags_fetch(

@@ -48,11 +48,15 @@ def test_blocked_form_under_the_block_mask_equals_a_dense_mask(n, block):
     """Lengths below, at and across the mask's blocks and the form's query
     blocks (256) and groups of them (1024)."""
     q, k, v = _qkv(n, 2, n)
-    got = gqa.prefill_attention(q, k, v, 0.25, None, None, block)
-    want = _dense(q, k, v, 0.25, _block_mask(n, block))
+    # one compiled program a form and length (eagerly the blocks are
+    # dispatched op by op)
+    prefill = jax.jit(gqa.prefill_attention, static_argnums=(3, 4, 5, 6))
+    got = prefill(q, k, v, 0.25, None, None, block)
+    want = jax.jit(_dense, static_argnums=3)(q, k, v, 0.25,
+                                             _block_mask(n, block))
     np.testing.assert_allclose(got, want, atol=2e-5)
     # the mask matters: the causal form differs inside every block
-    causal = gqa.prefill_attention(q, k, v, 0.25)
+    causal = prefill(q, k, v, 0.25, None, None, 1)
     assert float(jnp.abs(causal - want).max()) > 1e-2
     # its last position of each block sees what the block mask shows all
     last = np.arange(block - 1, n, block)

@@ -15,6 +15,8 @@ file (a second file can land on another xdist worker, where its fixture
 would skip).
 """
 
+import dataclasses
+import importlib
 import json
 import os
 import re
@@ -618,508 +620,259 @@ def test_moe_sorted_kernel_compiles_for_v5e(shape, no_persistent_cache,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_trinity_chunk_program_compiles_for_the_chip_and_fits_it(
-        shape, no_persistent_cache, monkeypatch):
-    """The chunk program of ``serve-trinity-mixedlen-backlog`` over
-    ABSTRACT weights (its 64 slots' state is real, on the host, for this
-    test alone: 4.3 GB of zeros): 32 steps of 9 layers, the rings and
-    grown keys written by ``row_write`` and read by ``gqa_decode_fwd`` up
-    to each slot's count, with no float32 score tensor over a whole cache;
-    arguments, results and temporaries under the 11.38 GB the cell's file
-    states for it."""
-    from progen_tpu.decode import sampler
-    from progen_tpu.decode.engine import ServingEngine
-    from progen_tpu.models import trinity
-    from progen_tpu.ops import gqa, lowering, moe_decode, row_write
+# ---- the families' whole programs at published widths ----
 
-    for module in (row_write, gqa, moe_decode, sampler):
-        monkeypatch.setattr(module, "_on_tpu", lambda: True)
-    monkeypatch.setattr(lowering, "on_tpu", lambda: True)
+
+def _perf_config(models, config_class, name):
+    """The cell's configuration as ``perf/configs/<name>.json`` has it."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "perf", "configs",
-                           "trinity-mini-ep8.json")) as f:
-        c = trinity.TrinityConfig.from_dict(json.load(f))
-    policy = trinity.bf16_policy()
-    params = jax.eval_shape(lambda k: trinity.init_params(c, k, policy),
-                            jax.random.key(0))
-    eng = ServingEngine(c, params, policy=policy, num_slots=64,
-                        chunk_size=32, max_len=9216)
-
-    def placed(tree):
-        return jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
-
-    compiled = jax.jit(lambda *a: eng._decode_chunk_impl(*a)).lower(
-        placed(eng._params), placed(eng.state),
-        *placed(eng._layout.chunk_operands())).compile()
-    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(eng.state))
-    assert 4.29e9 < held < 4.31e9
-    m = compiled.memory_analysis()
-    total = (m.argument_size_in_bytes + m.output_size_in_bytes
-             + m.temp_size_in_bytes - m.alias_size_in_bytes)
-    assert 2 * held <= total < 11.4e9, m
-    text = compiled.as_text()
-    assert "gqa_decode_fwd" in text and "row_write" in text
-    assert not _buffers_of(text, "f32[64,4,8,9216]")
-    assert not _buffers_of(text, "f32[64,4,8,2048]")
+    with open(os.path.join(root, "perf", "configs", f"{name}.json")) as f:
+        return getattr(models, config_class).from_dict(json.load(f))
 
 
-# ---- Granite 4.0-H's whole programs at published widths ----
+def _sdar_draws_by_groups_in_the_chips_own_memory(text):
+    """The draw's 32 rounds go by groups of 32 rows whose keys the compiler
+    keeps in the chip's own memory (memory space 1): ONE loop reads them."""
+    loops = [line for line in _buffers_of(text, "u32[32,151936]")
+             if " while(" in line]
+    assert len(loops) == 1
+    assert "u32[32,151936]{1,0:T(8,128)S(1)}" in loops[0]
+
+
+@dataclasses.dataclass(frozen=True)
+class Whole:
+    """One family's serving cell as its engine's programs are compiled for
+    the described chip over ABSTRACT weights (the slots' state is real, on
+    the host, for that family's cases alone)."""
+
+    cell: str
+    models: str                 # under ``progen_tpu.models``
+    config: object              # ``models -> `` the cell's configuration
+    engine: dict                # the cell's engine arguments
+    admit: tuple | None         # (rows a run, the bucket lowered)
+    state: tuple                # bounds on the slots' state, bytes
+    weights: tuple | None = None        # bounds on the weights, bytes
+    peak: float = 15.5e9        # arguments + results + temporaries, under
+    chunk_peak: float | None = None     # the chunk's stated peak
+    dropped: float = 0.0        # weights an admission does not even take
+    # the modules under ``progen_tpu`` whose ``_on_tpu`` says the chip is there
+    ops: tuple = ("ops.row_write", "ops.gqa", "ops.moe_decode",
+                  "decode.sampler")
+    chunk: tuple = ()           # names the chunk program's text must hold
+    admission: tuple = ()       # names the admission's text must hold
+    never: dict = dataclasses.field(default_factory=dict)   # program -> names
+    no_buffers: dict = dataclasses.field(default_factory=dict)  # -> shapes
+    chunk_also: object = None   # a further check of the chunk's text
+
+
+WHOLE_PROGRAMS = {
+    # 32 steps of 9 layers, the rings and grown keys written by
+    # ``row_write`` and read by ``gqa_decode_fwd`` up to each slot's count,
+    # with no float32 score tensor over a whole cache; under the 11.38 GB
+    # the cell's file states for it (4.3 GB of zeros on the host)
+    "trinity": Whole(
+        "serve-trinity-mixedlen-backlog", "trinity",
+        lambda m: _perf_config(m, "TrinityConfig", "trinity-mini-ep8"),
+        dict(num_slots=64, chunk_size=32, max_len=9216), admit=None,
+        state=(4.29e9, 4.31e9), peak=11.4e9,
+        chunk=("gqa_decode_fwd", "row_write"),
+        no_buffers={"chunk": ("f32[64,4,8,9216]", "f32[64,4,8,2048]")}),
+    # all 40 layers, the whole vocabulary, 32 slots of carry, tail and grown
+    # keys: the carry a float32 scan carry, the admission 4 chunks of the
+    # scan a row, the step's key writes ``ops/row_write.py``'s kernel
+    "granite": Whole(
+        "serve-granite-chat-backlog", "granite_hybrid",
+        lambda m: m.GraniteHybridConfig(),
+        dict(num_slots=32, chunk_size=32, max_len=2560), admit=(2, 1024),
+        weights=(6.38e9, 6.39e9), state=(3.1e9, 3.2e9),
+        ops=("ops.row_write", "ops.gqa"), chunk=("tpu_custom_call",)),
+    # 6 whole expert layers (all 128 experts of each), the whole vocabulary,
+    # 64 slots of grown keys: 30 forwards of 64 x 8 positions — the block
+    # core ``gqa_block_decode_fwd``, the pending block's withheld write
+    # ``row_block_write``, the draw over 256 x 151,936 logits — and 4 rows
+    # through the flash kernel under the block mask.  An admission draws no
+    # token, so the compiler drops the head, the final norm and the last
+    # layer's experts from it (1.83 GB it does not even take as arguments).
+    # The chunk program at PR 48: arguments 10.746 GB, results 2.024,
+    # temporaries 0.621, 13.39 GB together
+    "sdar": Whole(
+        "serve-sdar-blockdiff-backlog", "sdar",
+        lambda m: m.SDARConfig(num_hidden_layers=6, denoising_steps=2,
+                               remasking="low_confidence_static"),
+        dict(num_slots=64, chunk_size=30, max_len=2560), admit=(4, 1024),
+        weights=(8.72e9, 8.73e9), state=(2.0e9, 2.1e9), dropped=1.84e9,
+        chunk=("tpu_custom_call", "row_block_write", "gqa_block_decode_fwd"),
+        admission=("tpu_custom_call", "gqa_prefill_fwd"),
+        no_buffers={"chunk": ("u32[256,151936]",),
+                    "admit": ("u32[256,151936]",)},
+        chunk_also=_sdar_draws_by_groups_in_the_chips_own_memory),
+    # the first 12 layers (9 short convolutions, 3 attention; 2 dense, 10
+    # with all 32 experts), the whole vocabulary, 128 slots of two-row tails
+    # and grown keys: 128 tokens a call is the last size ``moe_decode_fwd``
+    # takes; 8 rows at the 1024 bucket (2.4 GB of zeros on the host)
+    "lfm2": Whole(
+        "serve-lfm2-longgen-backlog", "lfm2",
+        lambda m: m.LFM2Config(num_hidden_layers=12,
+                               layer_types=m.LFM2Config().layer_types[:12]),
+        dict(num_slots=128, chunk_size=32, max_len=3072), admit=(8, 1024),
+        weights=(7.85e9, 7.87e9), state=(2.4e9, 2.5e9), chunk_peak=12.8e9,
+        chunk=("tpu_custom_call", "moe_decode_fwd", "gqa_decode_fwd"),
+        admission=("tpu_custom_call",),
+        no_buffers={"chunk": ("f32[128,4,8,3072]",)}),
+    # stage 0 of 8 (``MEMEMEM*EME``), a quarter of the vocabulary, 64 slots
+    # of float32 carries, tails and grown keys: 64 tokens a call through
+    # ``moe_decode_fwd`` in its two-matrix form; 4 rows at the 1024 bucket
+    "nemotron": Whole(
+        "serve-nemotron3-longgen-backlog", "nemotron_h",
+        lambda m: m.NemotronHConfig(
+            num_hidden_layers=11, vocab_size=32768, experts_held=128,
+            hybrid_override_pattern=m.NemotronHConfig(
+            ).hybrid_override_pattern[:11]),
+        dict(num_slots=64, chunk_size=32, max_len=3072), admit=(4, 1024),
+        weights=(9.29e9, 9.31e9), state=(1.5e9, 1.7e9),
+        chunk=("tpu_custom_call", "moe_decode_fwd", "gqa_decode_fwd"),
+        admission=("tpu_custom_call",)),
+    # layers 0-6 of 48, an eighth of the vocabulary, 16 slots of 128-row
+    # rings and 17,408 grown rows: the full layers' core ``gqa_decode_fwd``
+    # at two widths since PR 55, the rings' core the XLA form; 1 row at the
+    # 16,384 bucket through ``moe_sorted_fwd`` and the blocked XLA attention
+    "mimo": Whole(
+        "serve-mimo-longdoc-backlog", "mimo_v2",
+        lambda m: _perf_config(m, "MiMoV2Config", "mimo-v2.5-ep16"),
+        dict(num_slots=16, chunk_size=32, max_len=17408), admit=(1, 16384),
+        weights=(6.85e9, 6.87e9), state=(1.4e9, 1.6e9),
+        chunk=("tpu_custom_call", "gqa_decode_fwd", "moe_decode_fwd",
+               "row_write"),
+        admission=("tpu_custom_call", "moe_sorted_fwd"),
+        never={"chunk": ("gqa_prefill_fwd",),
+               "admit": ("gqa_prefill_fwd", "gqa_decode_fwd")}),
+    # layers 0-4 of 46, an eighth of the vocabulary, 16 slots of 17,408
+    # rows: the indexer's score and ``top_k`` over every slot's rows, the
+    # gathered 2,048 rows through ``mla_decode_fwd``; 1 row at the 16,384
+    # bucket: the full layers' core ``mla_prefill_fwd`` under the selection
+    # of ``ops/dsa.py`` as its keep mask, the sliding ones the windowed XLA
+    # blocks of ``ops/gqa.py``
+    "dots3": Whole(
+        "serve-dots3-longdoc-backlog", "dots3",
+        lambda m: _perf_config(m, "Dots3Config", "dots3-note-prev-ep8"),
+        dict(num_slots=16, chunk_size=32, max_len=17408), admit=(1, 16384),
+        weights=(8.17e9, 8.18e9), state=(0.8e9, 0.9e9),
+        ops=("ops.row_write", "ops.gqa", "ops.mla_decode", "ops.mla_prefill",
+             "ops.moe_decode", "decode.sampler"),
+        chunk=("tpu_custom_call", "mla_decode_fwd", "moe_decode_fwd",
+               "row_write"),
+        admission=("tpu_custom_call", "mla_prefill_fwd", "moe_sorted_fwd"),
+        never={"chunk": ("mla_prefill_fwd",), "admit": ("mla_decode_fwd",)}),
+}
+
+PROGRAMS = [(name, program) for name, row in WHOLE_PROGRAMS.items()
+            for program in ("chunk", "admit")
+            if program == "chunk" or row.admit]
 
 
 @pytest.fixture(scope="module")
-def granite_engine():
-    """The engine of ``serve-granite-chat-backlog`` over ABSTRACT weights
-    (its 32 slots' state is real, on the host: 3.1 GB of zeros)."""
+def whole_engine():
+    """``name -> `` the row's engine over abstract weights, ONE family's
+    alive at a time: a family's cases follow each other, and the gigabytes
+    of zeros its slots' state is on the host go when the next is built."""
     from progen_tpu.decode.engine import ServingEngine
-    from progen_tpu.models import granite_hybrid as gh
 
-    c, policy = gh.GraniteHybridConfig(), gh.bf16_policy()
-    params = jax.eval_shape(lambda k: gh.init_params(c, k, policy),
-                            jax.random.key(0))
-    return ServingEngine(c, params, policy=policy, num_slots=32,
-                         chunk_size=32, max_len=2560)
+    alive = {}
+
+    def engine_of(name):
+        if name not in alive:
+            alive.clear()
+            row = WHOLE_PROGRAMS[name]
+            models = importlib.import_module(
+                f"progen_tpu.models.{row.models}")
+            config, policy = row.config(models), models.bf16_policy()
+            params = jax.eval_shape(
+                lambda k: models.init_params(config, k, policy),
+                jax.random.key(0))
+            alive[name] = ServingEngine(config, params, policy=policy,
+                                        **row.engine)
+        return alive[name]
+
+    yield engine_of
+    alive.clear()
 
 
-@pytest.mark.parametrize("program", ["chunk", "admit-1024"])
-def test_granite_programs_compile_for_the_chip_and_fit_it(
-        shape, granite_engine, program, no_persistent_cache, monkeypatch):
-    """All 40 layers, the whole vocabulary, 32 slots of carry, tail and
-    grown keys: the chunk program (32 steps of every slot, the carry a
-    float32 scan carry) and the admission of 2 rows at the 1024 bucket (4
-    chunks of the scan a row), as the chip traces them (the step's key
-    writes as ``ops/row_write.py``'s kernel).  Arguments, results and
-    temporaries together stay under the chip's 16 GiB: the engine's
-    programs do not donate their state, so it is there twice."""
-    from progen_tpu.ops import gqa, lowering, row_write
-
-    for module in (row_write, gqa):
-        monkeypatch.setattr(module, "_on_tpu", lambda: True)
-    monkeypatch.setattr(lowering, "on_tpu", lambda: True)
-    eng = granite_engine
+def _compiled_for_the_chip(eng, shape, bucket=None):
+    """The engine's chunk program (``bucket`` None) or its admission at
+    ``bucket`` compiled for the described chip: the ONE place in ``tests/``
+    that reaches the engine's private programs (``_chunk_impl()``, which is
+    ``_decode_chunk_impl`` or, for a family that generates by blocks,
+    ``_block_chunk_impl``; ``_admit_impl``; ``_layout``; ``_lmask_shape``;
+    ``_params``) — ROADMAP D14.  A fresh wrapper each: ``jax.jit`` keeps a
+    trace across a patch.  Returns ``(compiled, params, state)``, the last
+    two abstract."""
 
     def placed(tree):
         return jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
 
     params, state = placed(eng._params), placed(eng.state)
     s, rows, lay = eng.num_slots, eng.admit_rows, eng._layout
-    assert rows == 2
-    if program == "chunk":
-        # a fresh wrapper: ``jax.jit`` keeps a trace across the patch
-        compiled = jax.jit(lambda *a: eng._decode_chunk_impl(*a)).lower(
+    if bucket is None:
+        chunk = eng._chunk_impl()
+        compiled = jax.jit(lambda *a: chunk(*a)).lower(
             params, state, *placed(lay.chunk_operands())).compile()
     else:
-        prefill = [shape((rows, 1024), jnp.int32), shape((rows,), jnp.int32),
+        prefill = [shape((rows, bucket), jnp.int32), shape((rows,), jnp.int32),
                    shape((rows,), jnp.int32), shape((rows,), jnp.uint32),
                    shape((rows,), jnp.int32), shape((rows,), jnp.float32),
                    shape(eng._lmask_shape(rows), jnp.bool_)]
         compiled = jax.jit(lambda *a: eng._admit_impl(*a)).lower(
             params, state, shape((s,), jnp.int32), shape((s,), jnp.bool_),
             *prefill, *placed(lay.write_tables(rows))).compile()
-    m = compiled.memory_analysis()
-    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
-    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
-    assert 6.38e9 < weights < 6.39e9 and 3.1e9 < held < 3.2e9
-    total = (m.argument_size_in_bytes + m.output_size_in_bytes
-             + m.temp_size_in_bytes - m.alias_size_in_bytes)
-    assert weights + 2 * held <= total < 15.5e9, m
-    if program == "chunk":
-        assert "tpu_custom_call" in compiled.as_text()      # the key writes
+    return compiled, params, state
 
 
-# ---- SDAR's whole programs at published widths ----
+@pytest.mark.parametrize("name,program", PROGRAMS, ids=[
+    f"{name}-{program}" + (f"-{WHOLE_PROGRAMS[name].admit[1]}"
+                           if program == "admit" else "")
+    for name, program in PROGRAMS])
+def test_a_familys_programs_compile_for_the_chip_and_fit_it(
+        shape, whole_engine, name, program, no_persistent_cache, monkeypatch):
+    """The chunk program (``chunk_size`` steps of every slot) and the
+    admission of one run at the row's bucket, at the widths of the family's
+    serving cell (``WHOLE_PROGRAMS``), as the chip traces them.  Arguments,
+    results and temporaries together stay under the row's peak (the chip's
+    16 GiB unless the cell states less): the engine's programs do not
+    donate their state, so it is there twice."""
+    from progen_tpu.ops import lowering
 
-
-@pytest.fixture(scope="module")
-def sdar_engine():
-    """The engine of ``serve-sdar-blockdiff-backlog`` over ABSTRACT weights
-    (its 64 slots' state is real, on the host: 2.0 GB of zeros)."""
-    from progen_tpu.decode.engine import ServingEngine
-    from progen_tpu.models import sdar
-
-    c = sdar.SDARConfig(num_hidden_layers=6, denoising_steps=2,
-                        remasking="low_confidence_static")
-    policy = sdar.bf16_policy()
-    params = jax.eval_shape(lambda k: sdar.init_params(c, k, policy),
-                            jax.random.key(0))
-    return ServingEngine(c, params, policy=policy, num_slots=64,
-                         chunk_size=30, max_len=2560)
-
-
-@pytest.mark.parametrize("program", ["chunk", "admit-1024"])
-def test_sdar_programs_compile_for_the_chip_and_fit_it(
-        shape, sdar_engine, program, no_persistent_cache, monkeypatch):
-    """6 whole expert layers (all 128 experts of each), the whole
-    vocabulary, 64 slots of grown keys: the chunk program (30 forwards of
-    64 x 8 positions — each slot's pending block in front of its block in
-    progress: the block core as ``gqa_block_decode_fwd``, the pending
-    block's withheld write as ``row_block_write``, the draw over the 256 x
-    151,936 logits of the
-    blocks in progress) and the admission of 4 rows at the 1024 bucket (the
-    flash kernel under the block mask), as the chip traces them.
-    Arguments, results and temporaries together stay under the chip's
-    16 GiB: the engine's programs do not donate their state, so it is there
-    twice.  The chunk program for the described chip at PR 48: arguments
-    10.746 GB, results 2.024, temporaries 0.621 (0.459 with B positions a
-    slot), 13.39 GB together."""
-    from progen_tpu.decode import sampler
-    from progen_tpu.ops import gqa, lowering, moe_decode, row_write
-
-    for module in (row_write, gqa, moe_decode, sampler):
-        monkeypatch.setattr(module, "_on_tpu", lambda: True)
+    row = WHOLE_PROGRAMS[name]
+    for op in row.ops:
+        monkeypatch.setattr(importlib.import_module(f"progen_tpu.{op}"),
+                            "_on_tpu", lambda: True)
     monkeypatch.setattr(lowering, "on_tpu", lambda: True)
-    eng = sdar_engine
-
-    def placed(tree):
-        return jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
-
-    params, state = placed(eng._params), placed(eng.state)
-    s, rows, lay = eng.num_slots, eng.admit_rows, eng._layout
-    assert rows == 4
-    if program == "chunk":
-        compiled = jax.jit(lambda *a: eng._block_chunk_impl(*a)).lower(
-            params, state, *placed(lay.chunk_operands())).compile()
-    else:
-        prefill = [shape((rows, 1024), jnp.int32), shape((rows,), jnp.int32),
-                   shape((rows,), jnp.int32), shape((rows,), jnp.uint32),
-                   shape((rows,), jnp.int32), shape((rows,), jnp.float32),
-                   shape(eng._lmask_shape(rows), jnp.bool_)]
-        compiled = jax.jit(lambda *a: eng._admit_impl(*a)).lower(
-            params, state, shape((s,), jnp.int32), shape((s,), jnp.bool_),
-            *prefill, *placed(lay.write_tables(rows))).compile()
+    eng = whole_engine(name)
+    if program == "admit":
+        assert eng.admit_rows == row.admit[0]
+    compiled, params, state = _compiled_for_the_chip(
+        eng, shape, row.admit[1] if program == "admit" else None)
     m = compiled.memory_analysis()
     weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
     held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
-    assert 8.72e9 < weights < 8.73e9 and 2.0e9 < held < 2.1e9
+    assert row.state[0] < held < row.state[1], held
+    if row.weights:
+        assert row.weights[0] < weights < row.weights[1], weights
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
-    # an admission draws no token, so the compiler drops the head, the
-    # final norm and the last layer's experts from it (1.83 GB it does not
-    # even take as arguments): what it computes is the cache
-    floor = weights + 2 * held - (0 if program == "chunk" else 1.84e9)
-    assert floor <= total < 15.5e9, m
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    for kernel in (("row_block_write", "gqa_block_decode_fwd")
-                   if program == "chunk" else ("gqa_prefill_fwd",)):
-        assert kernel in text
-    # the draw's 32 rounds go by groups of 32 rows whose keys the compiler
-    # keeps in the chip's own memory (memory space 1): no uint32 array of
-    # the draw's whole shape is left for a loop to read from HBM 32 times
-    assert not _buffers_of(text, "u32[256,151936]")
-    if program == "chunk":
-        loops = [line for line in _buffers_of(text, "u32[32,151936]")
-                 if " while(" in line]
-        assert len(loops) == 1
-        assert "u32[32,151936]{1,0:T(8,128)S(1)}" in loops[0]
-
-
-# ---- LFM2's whole programs at published widths ----
-
-
-@pytest.fixture(scope="module")
-def lfm2_engine():
-    """The engine of ``serve-lfm2-longgen-backlog`` over ABSTRACT weights
-    (its 128 slots' state is real, on the host: 2.4 GB of zeros)."""
-    from progen_tpu.decode.engine import ServingEngine
-    from progen_tpu.models import lfm2
-
-    c = lfm2.LFM2Config(num_hidden_layers=12,
-                        layer_types=lfm2.LFM2Config().layer_types[:12])
-    policy = lfm2.bf16_policy()
-    params = jax.eval_shape(lambda k: lfm2.init_params(c, k, policy),
-                            jax.random.key(0))
-    return ServingEngine(c, params, policy=policy, num_slots=128,
-                         chunk_size=32, max_len=3072)
-
-
-@pytest.mark.parametrize("program", ["chunk", "admit-1024"])
-def test_lfm2_programs_compile_for_the_chip_and_fit_it(
-        shape, lfm2_engine, program, no_persistent_cache, monkeypatch):
-    """The first 12 layers (9 short convolutions, 3 attention; 2 dense, 10
-    with all 32 experts), the whole vocabulary, 128 slots of two-row tails
-    and grown keys: the chunk program (32 steps of every slot: 128 tokens a
-    call is the last size ``moe_decode_fwd`` takes, the key writes
-    ``ops/row_write.py``'s kernel) and the admission of 8 rows at the 1024
-    bucket (8,192 tokens through ``ragged_dot``), as the chip traces them.
-    Arguments, results and temporaries together stay under the chip's 16
-    GiB: the engine's programs do not donate their state, so it is there
-    twice."""
-    from progen_tpu.decode import sampler
-    from progen_tpu.ops import gqa, lowering, moe_decode, row_write
-
-    for module in (row_write, gqa, moe_decode, sampler):
-        monkeypatch.setattr(module, "_on_tpu", lambda: True)
-    monkeypatch.setattr(lowering, "on_tpu", lambda: True)
-    eng = lfm2_engine
-
-    def placed(tree):
-        return jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
-
-    params, state = placed(eng._params), placed(eng.state)
-    s, rows, lay = eng.num_slots, eng.admit_rows, eng._layout
-    assert rows == 8
-    if program == "chunk":
-        compiled = jax.jit(lambda *a: eng._decode_chunk_impl(*a)).lower(
-            params, state, *placed(lay.chunk_operands())).compile()
-    else:
-        prefill = [shape((rows, 1024), jnp.int32), shape((rows,), jnp.int32),
-                   shape((rows,), jnp.int32), shape((rows,), jnp.uint32),
-                   shape((rows,), jnp.int32), shape((rows,), jnp.float32),
-                   shape(eng._lmask_shape(rows), jnp.bool_)]
-        compiled = jax.jit(lambda *a: eng._admit_impl(*a)).lower(
-            params, state, shape((s,), jnp.int32), shape((s,), jnp.bool_),
-            *prefill, *placed(lay.write_tables(rows))).compile()
-    m = compiled.memory_analysis()
-    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
-    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
-    assert 7.85e9 < weights < 7.87e9 and 2.4e9 < held < 2.5e9
-    total = (m.argument_size_in_bytes + m.output_size_in_bytes
-             + m.temp_size_in_bytes - m.alias_size_in_bytes)
-    assert weights + 2 * held <= total < 15.5e9, m
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    if program == "chunk":
-        # the chunk's stated peak, the decode core a kernel
-        assert total < 12.8e9, m
-        assert "moe_decode_fwd" in text and "gqa_decode_fwd" in text
-        assert not _buffers_of(text, "f32[128,4,8,3072]")
-
-
-# ---- Nemotron-H's whole programs at published widths ----
-
-
-@pytest.fixture(scope="module")
-def nemotron_engine():
-    """The engine of ``serve-nemotron3-longgen-backlog`` over ABSTRACT
-    weights (its 64 slots' state is real, on the host: 1.6 GB of zeros)."""
-    from progen_tpu.decode.engine import ServingEngine
-    from progen_tpu.models import nemotron_h
-
-    c = nemotron_h.NemotronHConfig(
-        num_hidden_layers=11, vocab_size=32768, experts_held=128,
-        hybrid_override_pattern=nemotron_h.NemotronHConfig(
-        ).hybrid_override_pattern[:11])
-    policy = nemotron_h.bf16_policy()
-    params = jax.eval_shape(lambda k: nemotron_h.init_params(c, k, policy),
-                            jax.random.key(0))
-    return ServingEngine(c, params, policy=policy, num_slots=64,
-                         chunk_size=32, max_len=3072)
-
-
-@pytest.mark.parametrize("program", ["chunk", "admit-1024"])
-def test_nemotron_programs_compile_for_the_chip_and_fit_it(
-        shape, nemotron_engine, program, no_persistent_cache, monkeypatch):
-    """Stage 0 of 8 (``MEMEMEM*EME``: 5 Mamba-2 mixers of eight groups, 5
-    latent expert layers with 128 of 512 two-matrix experts, 1 attention
-    layer), a quarter of the vocabulary, 64 slots of float32 carries, tails
-    and grown keys: the chunk program (32 steps of every slot: 64 tokens a
-    call through ``moe_decode_fwd`` in its two-matrix form) and the
-    admission of 4 rows at the 1024 bucket (4,096 tokens through
-    ``ragged_dot``, two products a window), as the chip traces them.
-    Arguments, results and temporaries together stay under the chip's 16
-    GiB: the engine's programs do not donate their state, so it is there
-    twice."""
-    from progen_tpu.decode import sampler
-    from progen_tpu.ops import gqa, lowering, moe_decode, row_write
-
-    for module in (row_write, gqa, moe_decode, sampler):
-        monkeypatch.setattr(module, "_on_tpu", lambda: True)
-    monkeypatch.setattr(lowering, "on_tpu", lambda: True)
-    eng = nemotron_engine
-
-    def placed(tree):
-        return jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
-
-    params, state = placed(eng._params), placed(eng.state)
-    s, rows, lay = eng.num_slots, eng.admit_rows, eng._layout
-    assert rows == 4
-    if program == "chunk":
-        compiled = jax.jit(lambda *a: eng._decode_chunk_impl(*a)).lower(
-            params, state, *placed(lay.chunk_operands())).compile()
-    else:
-        prefill = [shape((rows, 1024), jnp.int32), shape((rows,), jnp.int32),
-                   shape((rows,), jnp.int32), shape((rows,), jnp.uint32),
-                   shape((rows,), jnp.int32), shape((rows,), jnp.float32),
-                   shape(eng._lmask_shape(rows), jnp.bool_)]
-        compiled = jax.jit(lambda *a: eng._admit_impl(*a)).lower(
-            params, state, shape((s,), jnp.int32), shape((s,), jnp.bool_),
-            *prefill, *placed(lay.write_tables(rows))).compile()
-    m = compiled.memory_analysis()
-    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
-    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
-    assert 9.29e9 < weights < 9.31e9 and 1.5e9 < held < 1.7e9, (weights,
-                                                                 held)
-    total = (m.argument_size_in_bytes + m.output_size_in_bytes
-             + m.temp_size_in_bytes - m.alias_size_in_bytes)
-    assert weights + 2 * held <= total < 15.5e9, m
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    if program == "chunk":
-        assert "moe_decode_fwd" in text and "gqa_decode_fwd" in text
-
-
-# ---- MiMo-V2's whole programs at published widths ----
-
-
-@pytest.fixture(scope="module")
-def mimo_engine():
-    """The engine of ``serve-mimo-longdoc-backlog`` over ABSTRACT weights
-    (its 16 slots' state is real, on the host: 1.5 GB of zeros)."""
-    from progen_tpu.decode.engine import ServingEngine
-    from progen_tpu.models import mimo_v2
-
-    with open(os.path.join(os.path.dirname(__file__), "..", "perf",
-                           "configs", "mimo-v2.5-ep16.json")) as f:
-        c = mimo_v2.MiMoV2Config.from_dict(json.load(f))
-    policy = mimo_v2.bf16_policy()
-    params = jax.eval_shape(lambda k: mimo_v2.init_params(c, k, policy),
-                            jax.random.key(0))
-    return ServingEngine(c, params, policy=policy, num_slots=16,
-                         chunk_size=32, max_len=17408)
-
-
-@pytest.mark.parametrize("program", ["chunk", "admit-16384"])
-def test_mimo_programs_compile_for_the_chip_and_fit_it(
-        shape, mimo_engine, program, no_persistent_cache, monkeypatch):
-    """Layers 0-6 of 48 (the dense layer and one whole period: 5 sliding
-    layers of 8 key/value heads under a sink, 2 full ones of 4, keys 192
-    wide beside values of 128; 16 of 256 experts), an eighth of the
-    vocabulary, 16 slots of 128-row rings and 17,408 grown rows: the chunk
-    program (32 steps of every slot: 16 tokens a call through
-    ``moe_decode_fwd``, the full layers' core ``gqa_decode_fwd`` at two
-    widths since PR 55 — ONE such kernel in the text, the two layers share
-    it —, the rings' core the XLA form) and the admission of 1 row at the
-    16,384 bucket (through ``moe_sorted_fwd``; the blocked XLA attention,
-    no ``gqa_prefill_fwd`` and no decode core), as the chip traces them.
-    Arguments, results and temporaries together stay under the chip's 16
-    GiB: the engine's programs do not donate their state, so it is there
-    twice."""
-    from progen_tpu.decode import sampler
-    from progen_tpu.ops import gqa, lowering, moe_decode, row_write
-
-    for module in (row_write, gqa, moe_decode, sampler):
-        monkeypatch.setattr(module, "_on_tpu", lambda: True)
-    monkeypatch.setattr(lowering, "on_tpu", lambda: True)
-    eng = mimo_engine
-
-    def placed(tree):
-        return jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
-
-    params, state = placed(eng._params), placed(eng.state)
-    s, rows, lay = eng.num_slots, eng.admit_rows, eng._layout
-    assert rows == 1
-    if program == "chunk":
-        compiled = jax.jit(lambda *a: eng._decode_chunk_impl(*a)).lower(
-            params, state, *placed(lay.chunk_operands())).compile()
-    else:
-        prefill = [shape((rows, 16384), jnp.int32), shape((rows,), jnp.int32),
-                   shape((rows,), jnp.int32), shape((rows,), jnp.uint32),
-                   shape((rows,), jnp.int32), shape((rows,), jnp.float32),
-                   shape(eng._lmask_shape(rows), jnp.bool_)]
-        compiled = jax.jit(lambda *a: eng._admit_impl(*a)).lower(
-            params, state, shape((s,), jnp.int32), shape((s,), jnp.bool_),
-            *prefill, *placed(lay.write_tables(rows))).compile()
-    m = compiled.memory_analysis()
-    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
-    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
-    assert 6.85e9 < weights < 6.87e9 and 1.4e9 < held < 1.6e9, (weights,
-                                                                 held)
-    total = (m.argument_size_in_bytes + m.output_size_in_bytes
-             + m.temp_size_in_bytes - m.alias_size_in_bytes)
-    print(f"mimo {program}: weights {weights / 1e9:.2f} GB, state "
+    print(f"{name} {program}: weights {weights / 1e9:.2f} GB, state "
           f"{held / 1e9:.2f} GB, temporaries {m.temp_size_in_bytes / 1e9:.2f}"
           f" GB, total {total / 1e9:.2f} GB")
-    assert weights + 2 * held <= total < 15.5e9, m
+    floor = (weights if row.weights else 0) + 2 * held - (
+        row.dropped if program == "admit" else 0)
+    assert floor <= total < row.peak, m
+    if program == "chunk" and row.chunk_peak:
+        assert total < row.chunk_peak, m
     text = compiled.as_text()
-    assert "tpu_custom_call" in text and "gqa_prefill_fwd" not in text
-    assert ("gqa_decode_fwd" in text) == (program == "chunk")
-    if program == "chunk":
-        assert "moe_decode_fwd" in text and "row_write" in text
-    else:
-        assert "moe_sorted_fwd" in text
-
-
-# ---- dots3's whole programs at published widths ----
-
-
-@pytest.fixture(scope="module")
-def dots3_engine():
-    """The engine of ``serve-dots3-longdoc-backlog`` over ABSTRACT weights
-    (its 16 slots' state is real, on the host: 0.85 GB of zeros)."""
-    from progen_tpu.decode.engine import ServingEngine
-    from progen_tpu.models import dots3
-
-    with open(os.path.join(os.path.dirname(__file__), "..", "perf",
-                           "configs", "dots3-note-prev-ep8.json")) as f:
-        c = dots3.Dots3Config.from_dict(json.load(f))
-    policy = dots3.bf16_policy()
-    params = jax.eval_shape(lambda k: dots3.init_params(c, k, policy),
-                            jax.random.key(0))
-    return ServingEngine(c, params, policy=policy, num_slots=16,
-                         chunk_size=32, max_len=17408)
-
-
-@pytest.mark.parametrize("program", ["chunk", "admit-16384"])
-def test_dots3_programs_compile_for_the_chip_and_fit_it(
-        shape, dots3_engine, program, no_persistent_cache, monkeypatch):
-    """Layers 0-4 of 46 (the dense layer and one whole period: 2 full
-    layers of 128 heads over 576-wide latents thinned to 2,048 rows by a
-    64-head indexer, 3 sliding ones of 64 heads over a 513-row ring of
-    1,088-wide latents; 32 of 256 experts beside a shared one), an eighth of
-    the vocabulary, 16 slots of 17,408 rows: the chunk program (32 steps of
-    every slot: the indexer's score and ``top_k`` over every slot's rows,
-    the gathered 2,048 rows through ``mla_decode_fwd`` — ONE such kernel in
-    the text, the two full layers share it, the rings' core the XLA form —,
-    16 tokens a call through ``moe_decode_fwd``) and the admission of 1 row
-    at the 16,384 bucket (through ``moe_sorted_fwd``; the full layers' core
-    ``mla_prefill_fwd`` under the selection of ``ops/dsa.py`` as its keep
-    mask, the sliding ones the windowed XLA blocks of ``ops/gqa.py``; no
-    decode core), as the chip traces them.  Arguments, results
-    and temporaries together stay under the chip's 16 GiB: the engine's
-    programs do not donate their state, so it is there twice."""
-    from progen_tpu.decode import sampler
-    from progen_tpu.ops import (gqa, lowering, mla_decode, mla_prefill,
-                                moe_decode, row_write)
-
-    for module in (row_write, gqa, mla_decode, mla_prefill, moe_decode,
-                   sampler):
-        monkeypatch.setattr(module, "_on_tpu", lambda: True)
-    monkeypatch.setattr(lowering, "on_tpu", lambda: True)
-    eng = dots3_engine
-
-    def placed(tree):
-        return jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
-
-    params, state = placed(eng._params), placed(eng.state)
-    s, rows, lay = eng.num_slots, eng.admit_rows, eng._layout
-    assert rows == 1
-    if program == "chunk":
-        compiled = jax.jit(lambda *a: eng._decode_chunk_impl(*a)).lower(
-            params, state, *placed(lay.chunk_operands())).compile()
-    else:
-        prefill = [shape((rows, 16384), jnp.int32), shape((rows,), jnp.int32),
-                   shape((rows,), jnp.int32), shape((rows,), jnp.uint32),
-                   shape((rows,), jnp.int32), shape((rows,), jnp.float32),
-                   shape(eng._lmask_shape(rows), jnp.bool_)]
-        compiled = jax.jit(lambda *a: eng._admit_impl(*a)).lower(
-            params, state, shape((s,), jnp.int32), shape((s,), jnp.bool_),
-            *prefill, *placed(lay.write_tables(rows))).compile()
-    m = compiled.memory_analysis()
-    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
-    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
-    assert 8.17e9 < weights < 8.18e9 and 0.8e9 < held < 0.9e9, (weights,
-                                                                 held)
-    total = (m.argument_size_in_bytes + m.output_size_in_bytes
-             + m.temp_size_in_bytes - m.alias_size_in_bytes)
-    print(f"dots3 {program}: weights {weights / 1e9:.2f} GB, state "
-          f"{held / 1e9:.2f} GB, temporaries {m.temp_size_in_bytes / 1e9:.2f}"
-          f" GB, total {total / 1e9:.2f} GB")
-    assert weights + 2 * held <= total < 15.5e9, m
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    assert ("mla_prefill_fwd" in text) == (program != "chunk")
-    assert ("mla_decode_fwd" in text) == (program == "chunk")
-    if program == "chunk":
-        assert "moe_decode_fwd" in text and "row_write" in text
-    else:
-        assert "moe_sorted_fwd" in text
+    for kernel in row.chunk if program == "chunk" else row.admission:
+        assert kernel in text, kernel
+    for kernel in row.never.get(program, ()):
+        assert kernel not in text, kernel
+    for buffer in row.no_buffers.get(program, ()):
+        assert not _buffers_of(text, buffer), buffer
+    if program == "chunk" and row.chunk_also:
+        row.chunk_also(text)

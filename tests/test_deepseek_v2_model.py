@@ -18,6 +18,7 @@ from perf.lib import reference_deepseek_v2 as ref
 from progen_tpu.models import deepseek_v2 as ds
 from progen_tpu.models import experts, latent
 from progen_tpu.models.longcat import LongCatConfig
+from tests.families import jitted, reference
 from tests.deepseek_v2_tiny import TINY, as_dict, make
 
 T, PRIME, MAX_LEN = 24, 10, 32
@@ -32,17 +33,16 @@ def _served_logits(params, policy, toks, config=TINY):
     """Logits of every position from ``PRIME - 1`` on: the prefill's last
     position, then one decode step per token through the cache."""
     rows = toks.shape[0]
-    first, rows_latent, _ = ds.prefill(params, toks[:, :16],
-                                       jnp.full((rows,), PRIME), config,
-                                       policy)
+    first, rows_latent, _ = jitted(ds.prefill)(
+        params, toks[:, :16], jnp.full((rows,), PRIME), config, policy)
     caches = {k: jnp.pad(v, ((0, 0), (0, MAX_LEN - 16), (0, 0)))
               for k, v in rows_latent.items()}
-    step = jax.jit(lambda p, t, ps, c: ds.decode_step(
-        p, t, ps, c, jnp.ones((rows,), bool), config, policy)[:2])
+    live = jnp.ones((rows,), bool)
     out = [first[:, 0]]
     for t in range(PRIME, T):
-        logits, caches = step(params, toks[:, t], jnp.full((rows,), t),
-                              caches)
+        logits, caches, _ = jitted(ds.decode_step)(
+            params, toks[:, t], jnp.full((rows,), t), caches, live, config,
+            policy)
         out.append(logits)
     return jnp.stack(out, axis=1)
 
@@ -62,12 +62,14 @@ def test_prefill_logits_match_the_reference_at_every_position():
     toks = _tokens()
     pos = jnp.broadcast_to(jnp.arange(T), (2, T))
     with jax.default_matmul_precision("highest"):
-        want = ref.forward(params, toks, as_dict(TINY))
-        got, rows, stats = ds.prefill(params, toks, jnp.array([T, 13]), TINY,
-                                      policy, logit_positions=pos)
+        want = reference(ref, TINY)(params, toks)
+        got, rows, stats = jitted(ds.prefill)(
+            params, toks, jnp.array([T, 13]), TINY, policy,
+            logit_positions=pos)
         junk = toks.at[1, 13:].set(5)
-        again, _, _ = ds.prefill(params, junk, jnp.array([T, 13]), TINY,
-                                 policy, logit_positions=pos)
+        again, _, _ = jitted(ds.prefill)(
+            params, junk, jnp.array([T, 13]), TINY, policy,
+            logit_positions=pos)
     assert float(jnp.abs(got[0] - want[0]).max()) < 2e-5
     assert float(jnp.abs(got[1, :13] - want[1, :13]).max()) < 2e-5
     np.testing.assert_array_equal(got[1, :13], again[1, :13])
@@ -85,7 +87,7 @@ def test_prefill_then_decode_matches_the_reference(mixed, tol):
     params, policy = make(mixed=mixed)
     toks = _tokens()
     with jax.default_matmul_precision("highest"):
-        want = ref.forward(params, toks, as_dict(TINY))[:, PRIME - 1:]
+        want = reference(ref, TINY)(params, toks)[:, PRIME - 1:]
         got = _served_logits(params, policy, toks)
     assert got.dtype == jnp.float32
     assert float(jnp.abs(got - want).max()) < tol
@@ -97,7 +99,7 @@ def test_decode_counts_rows_context_layers_and_touched_experts():
     caches = latent.LatentFamily.init_caches(
         ds.DeepSeekV2Family(TINY, policy), 2, MAX_LEN)
     live = jnp.array([True, False])
-    _, _, stats, chosen = ds.decode_step(
+    _, _, stats, chosen = jitted(ds.decode_step)(
         params, toks[:, 0], jnp.array([0, 0]), caches, live, TINY, policy,
         with_choices=True)
     assert chosen.shape == (2, 2, TINY.num_experts_per_tok)
@@ -214,9 +216,9 @@ def test_the_softmax_scale_rides_on_the_query():
     with jax.default_matmul_precision("highest"):
         want, _ = latent.mla_prefill(x, p, TINY)
         cache = jnp.zeros((2, MAX_LEN, TINY.latent_width))
+        step = jax.jit(latent.mla_decode, static_argnums=4)
         for t in range(T):
-            got, cache = latent.mla_decode(x[:, t], jnp.full((2,), t), cache,
-                                           p, TINY)
+            got, cache = step(x[:, t], jnp.full((2,), t), cache, p, TINY)
             assert float(jnp.abs(got - want[:, t]).max()) < 1e-5
         scaled = {**p, "wqb": p["wqb"] * TINY.q_gain}
         by_weight, _ = latent.mla_prefill(x, scaled, _Gainless(TINY))

@@ -13,7 +13,8 @@ import pytest
 from perf.lib import reference_deepseek_v2 as ref
 from progen_tpu.models import deepseek_v2 as ds
 from progen_tpu.models.latent import swiglu
-from tests.deepseek_v2_tiny import TINY, as_dict, make
+from tests.families import jitted, reference
+from tests.deepseek_v2_tiny import TINY, make
 
 TOKENS = 40
 
@@ -36,13 +37,13 @@ def test_shares_over_all_ranks_sum_to_the_uncut_layer(ranks):
     live = jnp.ones((TOKENS,), bool)
     held = TINY.n_routed_experts // ranks
     with jax.default_matmul_precision("highest"):
-        routed, _ = ref.routed(u, layer["router"], layer["experts"],
-                               as_dict(TINY))
+        routed, _ = reference(ref, TINY, "routed")(u, layer["router"],
+                                                   layer["experts"])
         whole = routed + ref.swiglu(u, layer["shared"])
         total = jnp.zeros_like(u)
         for rank in range(ranks):
             cut, part = _share(layer, TINY, rank * held, held)
-            y, _, _ = ds.moe_share(u, part, cut, live)
+            y, _, _ = jitted(ds.moe_share)(u, part, cut, live)
             total = total + y
         # every chip computes the shared experts alike: counted once
         shared = swiglu(u, layer["shared"], scope="moe.shared")
@@ -58,9 +59,10 @@ def test_routing_is_over_the_whole_router_whatever_is_held(first, held):
     cut, part = _share(layer, TINY, first, held)
     live = jnp.ones((TOKENS,), bool)
     with jax.default_matmul_precision("highest"):
-        got, ids, stats = ds.moe_share(u, part, cut, live)
-        _, all_ids, _ = ds.moe_share(u, layer, TINY, live)
-        want, _ = ref.routed(u, part["router"], part["experts"], as_dict(cut))
+        got, ids, stats = jitted(ds.moe_share)(u, part, cut, live)
+        _, all_ids, _ = jitted(ds.moe_share)(u, layer, TINY, live)
+        want, _ = reference(ref, cut, "routed")(u, part["router"],
+                                                part["experts"])
     np.testing.assert_array_equal(ids, all_ids)
     np.testing.assert_allclose(got, want, atol=2e-5)
     counts = np.bincount(np.asarray(ids).ravel(), minlength=16)
@@ -81,7 +83,7 @@ def test_routing_is_over_the_whole_router_whatever_is_held(first, held):
 def test_tokens_that_are_not_live_reach_no_expert_and_are_not_counted():
     layer, u = _layer_and_input()
     live = jnp.arange(TOKENS) < 25
-    y, _, stats = ds.moe_share(u, layer, TINY, live)
+    y, _, stats = jitted(ds.moe_share)(u, layer, TINY, live)
     assert float(jnp.abs(y[25:]).max()) == 0
     assert float(stats["moe.tokens"]) == 25
     assert float(stats["moe.held_load"].sum()) == 25 * 3

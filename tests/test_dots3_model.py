@@ -23,6 +23,7 @@ from progen_tpu.models import dots3 as dm
 from progen_tpu.models import latent
 from progen_tpu.ops import dsa, mla_prefill
 from progen_tpu.ops.lowering import record_lowerings
+from tests.families import fresh, jitted
 from tests.dots3_tiny import (TINY, TOP_K, WIDE, WIDE_TOP_K, WINDOW, as_dict,
                               force_prefill_kernel, make)
 
@@ -159,7 +160,7 @@ def _served(params, policy, toks, primes, bucket):
     — and each step's selections ``[(rows, kept)] x full layers``."""
     primes = jnp.asarray(primes)
     first, per_token, _ = _prefill(params, toks[:, :bucket], primes, policy)
-    caches = dm.caches_from(per_token, primes, TINY, MAX_LEN)
+    caches = jitted(dm.caches_from)(per_token, primes, TINY, MAX_LEN)
     step = functools.partial(_step, policy=policy)
     out, selections = [first[:, 0]], []
     for i in range(T - int(primes.max())):
@@ -326,7 +327,8 @@ def test_select_rows_puts_the_kept_rows_first_and_gathers_them():
     w = jax.random.normal(ks[1], (s, j))
     index = jax.random.normal(ks[2], (s, t, d))
     counts = jnp.array([3, 8, 20])
-    rows, kept = dsa.select_rows(q_idx, w, index, counts, top_k)
+    select_rows = jax.jit(dsa.select_rows, static_argnums=4)
+    rows, kept = select_rows(q_idx, w, index, counts, top_k)
     assert rows.shape == (s, top_k) and kept.tolist() == [3, 8, 8]
     scores = np.asarray(dsa.index_scores(q_idx[:, None], w[:, None],
                                          index)[:, 0])
@@ -337,7 +339,8 @@ def test_select_rows_puts_the_kept_rows_first_and_gathers_them():
     # the sparse core over the gathered rows is the dense core over the set
     q_cat = jax.random.normal(ks[3], (s, 2, 6))
     cache = jax.random.normal(ks[4], (s, t, 6))
-    got = dsa.sparse_decode_attention(q_cat, cache, rows, kept, 4, 0.5)
+    got = jax.jit(dsa.sparse_decode_attention, static_argnums=(4, 5))(
+        q_cat, cache, rows, kept, 4, 0.5)
     for i in range(s):
         rows_i = np.asarray(cache[i])[np.asarray(rows[i, :int(kept[i])])]
         logits = np.asarray(q_cat[i]) @ rows_i.T * 0.5
@@ -345,7 +348,7 @@ def test_select_rows_puts_the_kept_rows_first_and_gathers_them():
         want = (p / p.sum(-1, keepdims=True)) @ rows_i[:, :4]
         np.testing.assert_allclose(got[i], want, atol=2e-5)
     # a cache shorter than top_k keeps what there is
-    rows, kept = dsa.select_rows(q_idx, w, index[:, :5], counts, top_k)
+    rows, kept = select_rows(q_idx, w, index[:, :5], counts, top_k)
     assert rows.shape == (s, 5) and kept.tolist() == [3, 5, 5]
 
 
@@ -414,17 +417,26 @@ def test_prefill_through_the_kernel_serves_the_blocks_logits(monkeypatch):
     toks = jax.random.randint(jax.random.key(1), (2, n), 1, WIDE.vocab_size)
     at = jnp.broadcast_to(jnp.arange(0, n, 8), (2, n // 8))
 
-    def run(tokens):
-        # a fresh function per lowering: ``jax.jit`` would keep the trace
-        with jax.default_matmul_precision("highest"), \
-                record_lowerings() as chosen:
-            logits, _, stats = dm.prefill(params, tokens, lengths, WIDE,
-                                          policy, logit_positions=at)
-        return logits, stats, chosen
+    def lowered():
+        """``dm.prefill`` as ONE program, traced under what is patched NOW
+        (a fresh function per lowering: ``jax.jit`` would keep the trace;
+        eagerly the interpreter runs the kernel's grid op by op).  What a
+        trace notes is in the first call's ``chosen`` alone."""
+        prefill = fresh(dm.prefill)
 
-    want, blocked, chosen = run(toks)
+        def run(tokens):
+            with jax.default_matmul_precision("highest"), \
+                    record_lowerings() as chosen:
+                logits, _, stats = prefill(params, tokens, lengths, WIDE,
+                                           policy, logit_positions=at)
+            return logits, stats, chosen
+
+        return run
+
+    want, blocked, chosen = lowered()(toks)
     assert "mla_prefill" not in chosen
     force_prefill_kernel(monkeypatch)
+    run = lowered()
     got, stats, chosen = run(toks)
     assert chosen["mla_prefill"] == {"pallas"}
     junk, _, _ = run(jnp.where(jnp.arange(n)[None] < lengths[:, None],

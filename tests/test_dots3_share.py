@@ -12,6 +12,7 @@ import pytest
 
 from perf.lib import reference_dots3 as ref
 from progen_tpu.models import dots3 as dm
+from tests.families import jitted, reference
 from tests.dots3_tiny import TINY, as_dict, make
 
 TOKENS = 40
@@ -35,11 +36,11 @@ def test_shares_over_all_ranks_sum_to_the_uncut_layer(ranks):
     live = jnp.ones((TOKENS,), bool)
     held = TINY.n_routed_experts // ranks
     with jax.default_matmul_precision("highest"):
-        whole, _ = ref.routed(u, layer, as_dict(TINY))
+        whole, _ = reference(ref, TINY, "routed")(u, layer)
         total = ref.swiglu(u, layer["shared"])      # once, not once a rank
         for rank in range(ranks):
             cut, part = _share(layer, TINY, rank * held, held)
-            y, _, _ = dm.moe_share(u, part, cut, live)
+            y, _, _ = jitted(dm.moe_share)(u, part, cut, live)
             total = total + y
     np.testing.assert_allclose(total, whole, atol=2e-5)
     assert float(jnp.abs(ref.swiglu(u, layer["shared"])).max()) > 1e-3
@@ -51,8 +52,8 @@ def test_routing_is_over_the_whole_router_whatever_is_held(first, held):
     cut, part = _share(layer, TINY, first, held)
     live = jnp.ones((TOKENS,), bool)
     with jax.default_matmul_precision("highest"):
-        got, ids, stats = dm.moe_share(u, part, cut, live)
-        _, all_ids, _ = dm.moe_share(u, layer, TINY, live)
+        got, ids, stats = jitted(dm.moe_share)(u, part, cut, live)
+        _, all_ids, _ = jitted(dm.moe_share)(u, layer, TINY, live)
         want, want_ids = ref.routed(u, part, {**as_dict(cut),
                                               "shared_expert": False})
     np.testing.assert_array_equal(ids, all_ids)

@@ -17,7 +17,8 @@ from progen_tpu.models import driver
 from progen_tpu.models import granite_hybrid as gh
 from progen_tpu.models import kv
 from progen_tpu.ops.lowering import record_lowerings
-from tests.granite_tiny import CHUNK, TINY, as_dict, make
+from tests.families import fresh, jitted, reference
+from tests.granite_tiny import CHUNK, TINY, make
 
 T, MAX_LEN = 40, 48
 
@@ -32,18 +33,17 @@ def _served_logits(params, policy, toks, primes, bucket, config=TINY):
     prefill's last position, then one decode step per token through the
     caches (rows of different primes step together, each at its own
     position)."""
-    rows = toks.shape[0]
+    live = jnp.ones((toks.shape[0],), bool)
     primes = jnp.asarray(primes)
-    first, handed, _ = gh.prefill(params, toks[:, :bucket], primes, config,
-                                  policy)
-    caches = gh.caches_from(handed, primes, config, MAX_LEN)
-    step = jax.jit(lambda p, t, ps, c: gh.decode_step(
-        p, t, ps, c, jnp.ones((rows,), bool), config, policy)[:2])
+    first, handed, _ = jitted(gh.prefill)(params, toks[:, :bucket], primes,
+                                          config, policy)
+    caches = jitted(gh.caches_from)(handed, primes, config, MAX_LEN)
     out = [first[:, 0]]
     for i in range(T - int(primes.max())):
         pos = primes + i
         tok = jnp.take_along_axis(toks, pos[:, None], axis=1)[:, 0]
-        logits, caches = step(params, tok, pos, caches)
+        logits, caches, _ = jitted(gh.decode_step)(
+            params, tok, pos, caches, live, config, policy)
         out.append(logits)
     return jnp.stack(out, axis=1)
 
@@ -86,12 +86,12 @@ def test_prefill_logits_match_the_reference_at_every_position():
     pos = jnp.broadcast_to(jnp.arange(T), (2, T))
     lengths = jnp.array([T, 13])
     with jax.default_matmul_precision("highest"):
-        want = ref.forward(params, toks, as_dict(TINY))
-        got, handed, stats = gh.prefill(params, toks, lengths, TINY, policy,
-                                        logit_positions=pos)
+        want = reference(ref, TINY)(params, toks)
+        got, handed, stats = jitted(gh.prefill)(
+            params, toks, lengths, TINY, policy, logit_positions=pos)
         junk = toks.at[1, 13:].set(5)
-        again, handed_again, _ = gh.prefill(params, junk, lengths, TINY,
-                                            policy, logit_positions=pos)
+        again, handed_again, _ = jitted(gh.prefill)(
+            params, junk, lengths, TINY, policy, logit_positions=pos)
     assert float(jnp.abs(got[0] - want[0]).max()) < 5e-5
     assert float(jnp.abs(got[1, :13] - want[1, :13]).max()) < 5e-5
     assert float(want.std()) > 0.3              # not a vacuous bound
@@ -130,7 +130,7 @@ def test_prefill_then_decode_through_the_carry_matches_the_reference(
     toks = _tokens()
     start = max(primes)
     with jax.default_matmul_precision("highest"):
-        want = ref.forward(params, toks, as_dict(TINY))
+        want = reference(ref, TINY)(params, toks)
         got = _served_logits(params, policy, toks, primes, bucket)
     assert got.dtype == jnp.float32
     for row, prime in enumerate(primes):
@@ -156,8 +156,8 @@ def test_the_carry_reaches_the_logits():
             "a_log"] + 20.0}} if "a_log" in layer["mixer"] else layer
         for layer in params["layers"]])
     with jax.default_matmul_precision("highest"):
-        want = ref.forward(params, toks, as_dict(TINY))
-        other = ref.forward(forgetful, toks, as_dict(TINY))
+        want = reference(ref, TINY)(params, toks)
+        other = reference(ref, TINY)(forgetful, toks)
     np.testing.assert_allclose(want[:, 0], other[:, 0], atol=1e-5)
     assert float(jnp.abs(want - other)[:, 8:].max()) > 0.1
 
@@ -175,10 +175,10 @@ def test_each_multiplier_matters_and_is_the_references(name):
     other = dataclasses.replace(TINY, **{name: MULTIPLIERS[name]})
     pos = jnp.broadcast_to(jnp.arange(T), (2, T))
     with jax.default_matmul_precision("highest"):
-        base = ref.forward(params, toks, as_dict(TINY))
-        want = ref.forward(params, toks, as_dict(other))
-        got, _, _ = gh.prefill(params, toks, jnp.array([T, T]), other, policy,
-                               logit_positions=pos)
+        base = reference(ref, TINY)(params, toks)
+        want = reference(ref, other)(params, toks)
+        got, _, _ = jitted(gh.prefill)(params, toks, jnp.array([T, T]), other,
+                                       policy, logit_positions=pos)
         served = _served_logits(params, policy, toks, (12, 7), 16, other)
     assert float(jnp.abs(want - base).max()) > 0.05
     assert float(jnp.abs(got - want).max()) < 5e-5
@@ -252,7 +252,8 @@ def test_rows_that_idle_stay_finite_and_an_admission_overwrites_them():
     assert float(jnp.abs(caches["l0"]["ssm"]).max()) > 0
     toks = _tokens()
     primes = jnp.array([9, 4])
-    _, fresh, _ = family.prefill(params, toks[:, :16], primes, MAX_LEN)
+    _, fresh, _ = jitted(family.prefill)(params, toks[:, :16], primes,
+                                         MAX_LEN)
     merged = jax.tree.map(lambda old, new: old.at[:2].set(new), caches, fresh)
     for i in (0, 1, 3, 4, 5):      # nothing of the idle state is left
         for leaf in ("ssm", "conv"):
@@ -266,9 +267,11 @@ def test_decode_counts_state_rows_contexts_and_cache_rows_read():
     caches = family.init_caches(3, MAX_LEN)
     live = jnp.array([True, False, True])
     pos = jnp.array([2, 30, 20])
+    # a program of its own each: what a TRACE notes is what is read here
+    step = fresh(gh.decode_step)
     with record_lowerings() as chosen:
-        _, new, stats = gh.decode_step(params, jnp.array([4, 5, 6]), pos,
-                                       caches, live, TINY, policy)
+        _, new, stats = step(params, jnp.array([4, 5, 6]), pos, caches, live,
+                             TINY, policy)
     assert chosen["ssd_step"] == {"xla"} and "ssd_prefill" not in chosen
     assert float(stats["ssm.step_rows"]) == 5 * 2
     assert float(stats["attn.decode_rows"]) == 2
@@ -280,12 +283,12 @@ def test_decode_counts_state_rows_contexts_and_cache_rows_read():
     assert new["l0"]["ssm"].dtype == jnp.float32
     assert new["l0"]["conv"].dtype == caches["l0"]["conv"].dtype
     # no live row: nothing is counted
-    _, _, idle = gh.decode_step(params, jnp.array([4, 5, 6]), pos, caches,
-                                jnp.zeros((3,), bool), TINY, policy)
+    _, _, idle = step(params, jnp.array([4, 5, 6]), pos, caches,
+                      jnp.zeros((3,), bool), TINY, policy)
     assert all(float(v) == 0 for v in idle.values())
     with record_lowerings() as chosen:
-        gh.prefill(params, _tokens()[:, :16], jnp.array([16, 3]), TINY,
-                   policy)
+        fresh(gh.prefill)(params, _tokens()[:, :16], jnp.array([16, 3]), TINY,
+                          policy)
     assert chosen["ssd_prefill"] == {"xla"} and "ssd_step" not in chosen
 
 

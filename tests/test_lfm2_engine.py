@@ -1,34 +1,29 @@
 """LFM2 through ``ServingEngine``'s normal path (the seam of
-``decode/family.py``; no line of ``decode/engine.py`` names the family): rows
-of mixed lengths in one admission run — primes of 1 and 2 tokens, shorter
-than the convolution's taps, among them — serve the reference's argmax over
-the same tokens wherever its top-two gap exceeds a float32 rounding; a slot readmitted after a longer
-request serves what a fresh engine serves (a stale tail or stale keys fail
-it); a slot's state holds a two-row tail for each short-convolution layer
-beside the attention layers' grown keys; nothing compiles after
-``aot_warmup``; the modes that are ProGen's alone are refused by name; the
-family's counters reach the registry and ``engine.status()``."""
+``decode/family.py``; no line of ``decode/engine.py`` names the family): the
+tests every driver family runs (``tests/families.py``) over rows of mixed
+lengths in one admission run — primes of 1 and 2 tokens, shorter than the
+convolution's taps, among them; what is LFM2's own here: greedy requests
+serve the reference's argmax over the same tokens wherever its top-two gap
+exceeds a float32 rounding; a slot readmitted after a longer request serves
+what a fresh engine serves (a stale tail or stale keys fail it); a slot's
+state holds a two-row tail for each short-convolution layer beside the
+attention layers' grown keys; the counters reach ``engine.status()`` too."""
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from perf.lib import reference_lfm2 as ref
-from progen_tpu.decode import Request, ServingEngine
-from progen_tpu.decode.engine import SLOTS_PER_ADMIT_ROW
-from progen_tpu.decode.family import UnsupportedFamilyMode, family_for
+from progen_tpu.decode import ServingEngine
 from progen_tpu.models import lfm2
 from progen_tpu.observe.metrics import get_registry
-from tests.lfm2_tiny import TINY, as_dict, make
+from tests import families
+from tests.families import SLOTS
+from tests.lfm2_tiny import TINY
 
 pytestmark = pytest.mark.serving
 
-ADMIT_ROWS = 2
-SLOTS = ADMIT_ROWS * SLOTS_PER_ADMIT_ROW
-ENGINE = dict(num_slots=SLOTS, chunk_size=4, max_len=32)
-NEW, TOP_K = 7, 6
-PRIMES = (3, 12, 1, 21, 2, 9)       # two shorter than the three taps
+CASE = families.CASES["lfm2"]
+MAX_LEN = CASE.max_len
 CONV_LAYERS = TINY.layer_types.count(lfm2.CONV)
 EXPERT_LAYERS = TINY.num_hidden_layers - TINY.num_dense_layers
 # the reference's best logit must lead its second best by this much for a
@@ -38,186 +33,80 @@ TOP_TWO_GAP = 1e-4
 
 
 @pytest.fixture(scope="module")
-def served():
-    return make()
+def engine():
+    return families.engine_of(CASE)
 
 
-@pytest.fixture(scope="module")
-def engine(served):
-    params, policy = served
-    eng = ServingEngine(TINY, params, policy=policy, **ENGINE)
-    eng.warm = eng.aot_warmup()
-    return eng
-
-
-def _never_zero():
-    mask = np.ones((TINY.vocab_size,), bool)
-    mask[0] = False
-    return mask
-
-
-def _requests(n, seed=0, sampled=False, first_uid=0, primes=PRIMES):
-    """Primes of 1-21 tokens (the buckets of 8, 16 and 32), 7-9 new."""
-    rng = np.random.default_rng(seed)
-    return [Request(
-        uid=first_uid + i, max_new_tokens=NEW + i % 3, seed=50 + i,
-        temperature=0.8 if sampled else 0.0, top_k=TOP_K if sampled else None,
-        logit_mask=_never_zero(),
-        tokens=rng.integers(1, TINY.vocab_size,
-                            primes[i % len(primes)]).tolist())
-        for i in range(n)]
-
-
-def _serve(engine, reqs):
-    for r in reqs:
-        engine.submit(r)
-    return engine.run_until_idle(200)
-
-
-@jax.jit
-def _reference_logits(params, row, at):
-    """The reference over one row padded to the engine's ``max_len``
-    (causality keeps the padding out of what is read): one program."""
-    with jax.default_matmul_precision("highest"):
-        return ref.forward_row(params, row, as_dict(TINY),
-                               logit_positions=at)[0]
-
-
-def _padded(seq):
-    return jnp.zeros((ENGINE["max_len"],), jnp.int32).at[:len(seq)].set(
-        jnp.asarray(seq))
-
-
-def _held_to_the_reference(params, r, tokens):
+def _held_to_the_reference(r, tokens):
     """The served greedy tokens against the reference's argmax over the
     same sequence so far, wherever its top-two gap exceeds the tolerance;
     returns how many positions were held."""
-    seq = list(r.tokens)
     held = 0
-    for tok in tokens:
-        logits = np.asarray(_reference_logits(
-            params, _padded(seq), jnp.array([len(seq) - 1])))[0, 1:]
-        top = np.sort(logits)[-2:]
+    for i, (at, tok) in enumerate(zip(
+            families.probe_logits(CASE, r, tokens), tokens)):
+        top = np.sort(at)[-2:]
         if top[1] - top[0] > TOP_TWO_GAP:
-            assert tok == 1 + int(np.argmax(logits)), (r.uid, len(seq))
+            assert tok == 1 + int(np.argmax(at)), (r.uid, len(r.tokens) + i)
             held += 1
-        seq.append(tok)
     return held
 
 
-@pytest.mark.parametrize("n", [1, len(PRIMES)])
-def test_greedy_requests_of_mixed_lengths_serve_the_references_argmax(
-        served, engine, n):
-    reqs = _requests(n)
-    got = {c.uid: c.tokens.tolist() for c in _serve(engine, reqs)}
+def greedy(case, reqs, done):
+    got = families.tokens_of(done)
     assert {len(r.tokens) < TINY.conv_L_cache for r in reqs} == (
-        {False} if n == 1 else {False, True})
-    held = sum(_held_to_the_reference(served[0], r, got[r.uid])
-               for r in reqs)
+        {False} if len(reqs) == 1 else {False, True})
+    held = sum(_held_to_the_reference(r, got[r.uid]) for r in reqs)
     total = sum(r.max_new_tokens for r in reqs)
     assert held >= 0.9 * total              # the gap rarely excuses a token
 
 
 def test_a_slot_readmitted_after_a_longer_request_serves_as_a_fresh_engine(
-        served, engine):
+        engine):
     """Long requests fill every slot and finish (every slot then steps on
     after them), then short ones — one token, two tokens — are admitted
     into the same slots: an admission overwrites ALL of a slot's tail and
-    keys, so they serve what an engine that never held anything serves."""
-    params, policy = served
-    long = _requests(SLOTS + 3, seed=7, sampled=True, first_uid=400,
-                     primes=(21, 17, 19))
-    _serve(engine, long)
+    keys, so they serve what an engine that never held anything serves (the
+    second engine is what this tests against)."""
+    params, policy = CASE.served()
+    long = families.requests(CASE, SLOTS + 3, seed=7, sampled=True,
+                             first_uid=400, primes=(21, 17, 19))
+    families.serve(engine, long)
     caches = engine.state["caches"]
     assert all(bool(jnp.abs(c["conv"]).max(axis=(1, 2)).min() > 0)
                for c in caches.values() if "conv" in c)
-    short = _requests(SLOTS, seed=6, first_uid=500, primes=(1, 2, 5, 3))
-    got = {c.uid: c.tokens.tolist() for c in _serve(engine, short)}
-    fresh_engine = ServingEngine(TINY, params, policy=policy, **ENGINE)
-    fresh = {c.uid: c.tokens.tolist() for c in _serve(
-        fresh_engine, _requests(SLOTS, seed=6, first_uid=500,
-                                primes=(1, 2, 5, 3)))}
+    short = families.requests(CASE, SLOTS, seed=6, first_uid=500,
+                              primes=(1, 2, 5, 3))
+    got = families.tokens_of(families.serve(engine, short))
+    fresh_engine = ServingEngine(TINY, params, policy=policy,
+                                 **CASE.engine)
+    fresh = families.tokens_of(families.serve(
+        fresh_engine, families.requests(CASE, SLOTS, seed=6, first_uid=500,
+                                        primes=(1, 2, 5, 3))))
     assert got == fresh
-    assert _held_to_the_reference(served[0], short[0], got[500]) > 0
+    assert _held_to_the_reference(short[0], got[500]) > 0
 
 
-def test_sampled_requests_keep_to_the_probe_rule(served, engine):
-    """Every served token is among the reference's ``top_k`` best allowed
-    at its position (to a float32 rounding)."""
-    reqs = _requests(ADMIT_ROWS + 3, seed=4, sampled=True, first_uid=100)
-    out = {c.uid: c.tokens.tolist() for c in _serve(engine, reqs)}
-    for r in reqs:
-        seq = list(r.tokens) + out[r.uid]
-        p = len(r.tokens)
-        new = len(out[r.uid])
-        logits = _reference_logits(served[0], _padded(seq),
-                                   p - 1 + jnp.arange(NEW + 2))
-        at = np.asarray(logits)[:new, 1:]
-        tok = np.asarray(out[r.uid]) - 1
-        kth = np.sort(at, axis=-1)[:, -TOP_K]
-        assert (kth - at[np.arange(len(tok)), tok]).max() < 1e-4
-        assert 0 not in out[r.uid]
-
-
-def test_nothing_compiles_after_warmup_and_a_slot_holds_tails_and_keys(
-        engine):
-    assert sorted(k for k in engine._aot if k[0] == "admit") == [
-        ("admit", 8), ("admit", 16), ("admit", 32)]
-    assert engine.warm["programs"] == 4
-    events = []
-
-    def listener(name, secs, **kw):
-        if name.startswith("/jax/core/compile"):
-            events.append(name)
-
-    jax.monitoring.register_event_duration_secs_listener(listener)
-    try:
-        first = {c.uid: c.tokens.tolist() for c in _serve(
-            engine, _requests(SLOTS + 5, seed=3, sampled=True))}
-    finally:
-        jax.monitoring.unregister_event_duration_listener(listener)
-    assert events == [] and len(first) == SLOTS + 5
-    assert engine.state["lmask"].shape == (SLOTS, TINY.vocab_size)
+def slot_holds(engine):
     caches = engine.state["caches"]
     assert {n: sorted(c) for n, c in caches.items()} == {
         **{f"l{i}": ["conv"] for i in (0, 2, 3, 5)},
         **{f"l{i}": ["k", "v"] for i in (1, 4)}}
     assert caches["l0"]["conv"].shape == (SLOTS, 2, TINY.hidden_size)
-    assert caches["l1"]["k"].shape == (SLOTS, 1, ENGINE["max_len"], 16)
+    assert caches["l1"]["k"].shape == (SLOTS, 1, MAX_LEN, 16)
     status = engine.status()
     assert status["row_write"] == "scatter"          # the CPU's lowering
     assert status["moe_experts"] == {"chunk": "xla", "admit": "xla"}
 
 
-@pytest.mark.parametrize("mode", [
-    dict(paged=True), dict(disagg=True),
-    dict(lora_bank={}), dict(quantize="weights"), dict(mesh=object())],
-    ids=lambda m: next(iter(m)))
-def test_a_mode_outside_the_familys_is_refused_by_name(served, mode):
-    params, policy = served
-    with pytest.raises(UnsupportedFamilyMode, match=next(iter(mode))):
-        ServingEngine(TINY, params, policy=policy, **ENGINE, **mode)
-
-
-def test_family_for_returns_the_family_and_what_it_states(served):
-    family = family_for(TINY, served[1])
-    assert isinstance(family, lfm2.LFM2Family)
-    assert family.name == "lfm2" and family.modes == frozenset()
-    assert family.idle_length == 0 and not family.position_masks
+def states(family):
     assert family.block_length is None          # a token a row a step
     assert family.vocab == TINY.vocab_size
     assert family.seq_len == TINY.max_position_embeddings
-    assert family.buckets(20, 32) == [8, 16, 32]
     assert set(family.init_stats()) == set(lfm2.STAT_KEYS)
     assert family.init_stats()["moe.held_load"].shape == (TINY.experts_held,)
 
 
-def test_counters_ride_the_flags_fetch_into_the_registry_and_status(served):
-    params, policy = served
-    eng = ServingEngine(TINY, params, policy=policy, **ENGINE)
-    reqs = _requests(4, seed=5)
-    _serve(eng, reqs)
-    stats = eng.model_stats
+def counters(engine, reqs, stats, total):
     assert set(stats) == set(lfm2.STAT_KEYS)
     prime_tokens = sum(len(r.tokens) for r in reqs)
     steps = sum(r.max_new_tokens - 1 for r in reqs)   # the first is prefill's
@@ -237,14 +126,19 @@ def test_counters_ride_the_flags_fetch_into_the_registry_and_status(served):
                   for i in range(1, r.max_new_tokens))
     assert stats["attn.context_tokens"] == context
     # the XLA core reads every slot's every row, each decode step that ran
-    chunk_steps = stats["attn.full_rows_read"] / (SLOTS * ENGINE["max_len"])
+    chunk_steps = stats["attn.full_rows_read"] / (SLOTS * MAX_LEN)
     assert chunk_steps == int(chunk_steps) and chunk_steps >= max(
         r.max_new_tokens - 1 for r in reqs)
     snap = get_registry().snapshot()
     for name in lfm2.STAT_KEYS:
         if name != "moe.held_load":
-            assert snap[name]["value"] == stats[name], name
-    assert snap["moe.held_assignments"]["value"] == stats[
+            assert snap[name]["value"] == total[name], name
+    assert snap["moe.held_assignments"]["value"] == total[
         "moe.held_load"].sum()
-    assert eng.status()["model_stats"]["conv.tokens"] == stats[
+    assert engine.status()["model_stats"]["conv.tokens"] == total[
         "conv.tokens"]
+
+
+TestEngine = families.engine_tests(
+    CASE, slot_holds=slot_holds, states=states, counters=counters,
+    greedy=greedy)

@@ -21,6 +21,7 @@ from progen_tpu.models import experts, lfm2
 from progen_tpu.models import trinity as tr
 from progen_tpu.ops import ssd
 from tests import trinity_tiny
+from tests.families import jitted
 from tests.lfm2_tiny import TINY, as_dict, make
 
 T, MAX_LEN = 40, 48
@@ -55,7 +56,7 @@ def _served_logits(prefill, step, toks, primes, bucket):
     position)."""
     primes = jnp.asarray(primes)
     first, per_token = prefill(toks[:, :bucket], primes)
-    caches = lfm2.caches_from(per_token, primes, TINY, MAX_LEN)
+    caches = jitted(lfm2.caches_from)(per_token, primes, TINY, MAX_LEN)
     out = [first[:, 0]]
     for i in range(T - int(primes.max())):
         pos = primes + i

@@ -14,6 +14,7 @@ import pytest
 from perf.lib import reference_longcat as ref
 from perf.tools.longcat_lowp import lowered
 from progen_tpu.models import longcat as lc
+from tests.families import jitted, reference
 from tests.longcat_tiny import TINY, as_dict, make
 
 T, PRIME, MAX_LEN = 24, 10, 32
@@ -28,16 +29,16 @@ def _served_logits(params, policy, toks, config=TINY):
     """Logits of every position from ``PRIME - 1`` on: the prefill's last
     position, then one decode step per token through the cache."""
     rows = toks.shape[0]
-    first, latent, _ = lc.prefill(params, toks[:, :16],
-                                  jnp.full((rows,), PRIME), config, policy)
+    first, latent, _ = jitted(lc.prefill)(
+        params, toks[:, :16], jnp.full((rows,), PRIME), config, policy)
     caches = {k: jnp.pad(v, ((0, 0), (0, MAX_LEN - 16), (0, 0)))
               for k, v in latent.items()}
-    step = jax.jit(lambda p, t, ps, c: lc.decode_step(
-        p, t, ps, c, jnp.ones((rows,), bool), config, policy)[:2])
+    live = jnp.ones((rows,), bool)
     out = [first[:, 0]]
     for t in range(PRIME, T):
-        logits, caches = step(params, toks[:, t], jnp.full((rows,), t),
-                              caches)
+        logits, caches, _ = jitted(lc.decode_step)(
+            params, toks[:, t], jnp.full((rows,), t), caches, live, config,
+            policy)
         out.append(logits)
     return jnp.stack(out, axis=1)
 
@@ -48,7 +49,7 @@ def test_prefill_then_decode_matches_the_reference(mixed, tol):
     params, policy = make(mixed=mixed)
     toks = _tokens()
     with jax.default_matmul_precision("highest"):
-        want = ref.forward(params, toks, as_dict(TINY))[:, PRIME - 1:]
+        want = reference(ref, TINY)(params, toks)[:, PRIME - 1:]
         got = _served_logits(params, policy, toks)
     assert got.dtype == jnp.float32
     assert float(jnp.abs(got - want).max()) < tol
@@ -64,12 +65,12 @@ def test_ragged_rows_and_padding_do_not_leak():
     lengths = jnp.array([T, 13])
     pos = jnp.broadcast_to(jnp.arange(T), (2, T))
     with jax.default_matmul_precision("highest"):
-        want = ref.forward(params, toks, as_dict(TINY))
-        got, _, stats = lc.prefill(params, toks, lengths, TINY, policy,
-                                   logit_positions=pos)
+        want = reference(ref, TINY)(params, toks)
+        got, _, stats = jitted(lc.prefill)(params, toks, lengths, TINY,
+                                           policy, logit_positions=pos)
         junk = toks.at[1, 13:].set(5)
-        again, _, _ = lc.prefill(params, junk, lengths, TINY, policy,
-                                 logit_positions=pos)
+        again, _, _ = jitted(lc.prefill)(params, junk, lengths, TINY, policy,
+                                         logit_positions=pos)
     assert float(jnp.abs(got[0] - want[0]).max()) < 2e-5
     assert float(jnp.abs(got[1, :13] - want[1, :13]).max()) < 2e-5
     np.testing.assert_array_equal(got[1, :13], again[1, :13])
@@ -84,11 +85,11 @@ def test_absorbed_decode_equals_non_absorbed_attention():
     p = params["layers"][0]["attn"][1]
     x = jax.random.normal(jax.random.key(3), (2, T, TINY.hidden_size))
     with jax.default_matmul_precision("highest"):
-        want, latent = lc.mla_prefill(x, p, TINY)
+        want, latent = jax.jit(lc.mla_prefill, static_argnums=2)(x, p, TINY)
         cache = jnp.zeros((2, MAX_LEN, TINY.latent_width))
+        step = jax.jit(lc.mla_decode, static_argnums=4)
         for t in range(T):
-            got, cache = lc.mla_decode(x[:, t], jnp.full((2,), t), cache, p,
-                                       TINY)
+            got, cache = step(x[:, t], jnp.full((2,), t), cache, p, TINY)
             assert float(jnp.abs(got - want[:, t]).max()) < 1e-5
     np.testing.assert_allclose(cache[:, :T], latent, atol=1e-6)
     assert cache.shape[-1] == TINY.kv_lora_rank + TINY.qk_rope_head_dim
@@ -104,7 +105,8 @@ def test_identity_experts_are_chosen_and_weighted():
                         "wd": jnp.zeros_like(layer["experts"]["wd"])}
     u = jax.random.normal(jax.random.key(4), (40, TINY.hidden_size))
     with jax.default_matmul_precision("highest"):
-        y, ids, _ = lc.moe_share(u, layer, TINY, jnp.ones((40,), bool))
+        y, ids, _ = jitted(lc.moe_share)(u, layer, TINY,
+                                         jnp.ones((40,), bool))
         probs = jax.nn.softmax(u @ layer["router"]["w"], axis=-1)
     identity = ids >= TINY.n_routed_experts
     assert bool(identity.any()) and bool((~identity).any())
@@ -119,8 +121,9 @@ def test_router_chooses_by_p_plus_bias_and_weighs_by_p():
     router = params["layers"][1]["router"]
     u = jax.random.normal(jax.random.key(5), (64, TINY.hidden_size))
     with jax.default_matmul_precision("highest"):
-        ids, w = lc.route(u, router, TINY)
-        free, _ = lc.route(u, {**router, "bias": 0 * router["bias"]}, TINY)
+        ids, w = jitted(lc.route)(u, router, TINY)
+        free, _ = jitted(lc.route)(
+            u, {**router, "bias": 0 * router["bias"]}, TINY)
         probs = jax.nn.softmax(u @ router["w"], axis=-1)
     assert bool((jnp.sort(ids, -1) != jnp.sort(free, -1)).any())
     want = TINY.routed_scaling_factor * jnp.take_along_axis(probs, ids, -1)
@@ -137,11 +140,12 @@ def test_grouped_product_drops_nothing_when_a_window_overflows():
     u = jax.random.normal(jax.random.key(6), (48, TINY.hidden_size))
     live = jnp.arange(48) % 5 != 0
     with jax.default_matmul_precision("highest"):
-        ids, w = lc.route(u, layer["router"], TINY)
-        small, l1 = lc.held_experts(u, ids, w, live, layer["experts"], TINY,
-                                    capacity=8)
-        whole, l2 = lc.held_experts(u, ids, w, live, layer["experts"], TINY,
-                                    capacity=48 * TINY.moe_topk)
+        ids, w = jitted(lc.route)(u, layer["router"], TINY)
+        small, l1 = jitted(lc.held_experts)(
+            u, ids, w, live, layer["experts"], TINY, capacity=8)
+        whole, l2 = jitted(lc.held_experts)(
+            u, ids, w, live, layer["experts"], TINY,
+            capacity=48 * TINY.moe_topk)
     np.testing.assert_allclose(small[live], whole[live], atol=1e-5)
     np.testing.assert_array_equal(l1, l2)
     assert float(l1.sum()) > 8 * 3
@@ -154,12 +158,13 @@ def test_seeded_weights_spread_the_router_and_keep_activations_bounded():
     assignments there are."""
     params, policy = make()
     toks = _tokens(seed=8)
-    _, _, _, chosen = lc.prefill(params, toks, jnp.full((2,), T), TINY,
-                                 policy, with_choices=True)
+    _, _, _, chosen = jitted(lc.prefill)(
+        params, toks, jnp.full((2,), T), TINY, policy, with_choices=True)
     sets = {tuple(sorted(c.tolist())) for c in
             np.asarray(chosen[0]).reshape(-1, TINY.moe_topk)}
     assert len(sets) > 10
-    logits, _, _ = lc.prefill(params, toks, jnp.full((2,), T), TINY, policy)
+    logits, _, _ = jitted(lc.prefill)(params, toks, jnp.full((2,), T), TINY,
+                                      policy)
     assert 0.1 < float(jnp.abs(logits).mean()) < 10
     full = lc.LongCatConfig(experts_held=16)
     assert lc.moe_capacity(full, 8192) == 4096
@@ -175,12 +180,20 @@ def test_the_reference_one_notch_below_is_further_off_than_the_program():
     params, policy = make(mixed=True)
     toks = _tokens(seed=9)
     cfg = as_dict(TINY)
+
+    def forward_row():
+        """Traced anew at each call site: under ``lowered`` the reference's
+        operations are other functions, and ``again`` below must be the
+        program ``want`` was, compiled after the wrapping is undone."""
+        return jax.jit(lambda p, row: ref.forward_row(p, row, cfg))(
+            params, toks[0])
+
     with jax.default_matmul_precision("highest"):
-        want, sets = ref.forward_row(params, toks[0], cfg)
+        want, sets = forward_row()
         got = _served_logits(params, policy, toks)[0]
         with lowered(jnp.float8_e4m3fn):
-            low, low_sets = ref.forward_row(params, toks[0], cfg)
-        again, _ = ref.forward_row(params, toks[0], cfg)
+            low, low_sets = forward_row()
+        again, _ = forward_row()
     assert low.dtype == jnp.float32
     np.testing.assert_array_equal(again, want)    # the wrapping is undone
     program = float(jnp.abs(got - want[PRIME - 1:]).max())

@@ -11,7 +11,8 @@ import pytest
 
 from perf.lib import reference_longcat as ref
 from progen_tpu.models import longcat as lc
-from tests.longcat_tiny import TINY, as_dict, make
+from tests.families import jitted, reference
+from tests.longcat_tiny import TINY, make
 
 TOKENS = 40
 
@@ -34,17 +35,17 @@ def test_shares_over_all_ranks_sum_to_the_uncut_layer(ranks):
     live = jnp.ones((TOKENS,), bool)
     held = TINY.n_routed_experts // ranks
     with jax.default_matmul_precision("highest"):
-        whole, _ = ref.moe(u, layer["router"], layer["experts"],
-                           as_dict(TINY))
+        whole, _ = reference(ref, TINY, "moe")(u, layer["router"],
+                                               layer["experts"])
         total = jnp.zeros_like(u)
         for rank in range(ranks):
             cut, part = _share(layer, TINY, rank * held, held)
-            y, _, _ = lc.moe_share(u, part, cut, live)
+            y, _, _ = jitted(lc.moe_share)(u, part, cut, live)
             total = total + y
         # every share carries all identity terms: keep one copy
         none, part = _share(layer, TINY, 0, 0)
-        identity, _ = ref.moe(u, part["router"], part["experts"],
-                              as_dict(none))
+        identity, _ = reference(ref, none, "moe")(u, part["router"],
+                                                  part["experts"])
     np.testing.assert_allclose(total - (ranks - 1) * identity, whole,
                                atol=2e-5)
     assert float(jnp.abs(identity).max()) > 1e-3    # there were some
@@ -56,10 +57,11 @@ def test_routing_is_over_the_whole_router_whatever_is_held(first, held):
     cut, part = _share(layer, TINY, first, held)
     live = jnp.ones((TOKENS,), bool)
     with jax.default_matmul_precision("highest"):
-        _, ids, stats = lc.moe_share(u, part, cut, live)
-        _, all_ids, _ = lc.moe_share(u, layer, TINY, live)
-        want, _ = ref.moe(u, part["router"], part["experts"], as_dict(cut))
-        got, _, _ = lc.moe_share(u, part, cut, live)
+        _, ids, stats = jitted(lc.moe_share)(u, part, cut, live)
+        _, all_ids, _ = jitted(lc.moe_share)(u, layer, TINY, live)
+        want, _ = reference(ref, cut, "moe")(u, part["router"],
+                                             part["experts"])
+        got, _, _ = jitted(lc.moe_share)(u, part, cut, live)
     np.testing.assert_array_equal(ids, all_ids)
     assert int(ids.max()) >= TINY.n_routed_experts      # identity chosen
     np.testing.assert_allclose(got, want, atol=2e-5)

@@ -1,197 +1,71 @@
 """MiMo-V2 through ``ServingEngine``'s normal path (the seam of
-``decode/family.py``, unchanged): slots of mixed lengths — one token, one
-under, at and past the window at admission, all past it before they finish
-— serve the tokens of a plain sequential sampler over the reference's full
-forward; a slot's state holds a ring of one head shape for each sliding
-block and ``max_len`` rows of another for each full one, keys wider than
-values; a slot readmitted after a longer request serves what a fresh engine
-serves; nothing compiles after ``aot_warmup``; the modes that are ProGen's
-alone are refused by name; the family's counters and the two byte gauges
-reach the registry and ``status()["model_stats"]``."""
+``decode/family.py``, unchanged): the tests every driver family runs
+(``tests/families.py``) over slots of mixed lengths — one token, one under,
+at and past the window at admission, all past it before they finish; what is
+MiMo's own here: a slot's state holds a ring of one head shape for each
+sliding block and ``max_len`` rows of another for each full one, keys wider
+than values; a slot readmitted after a longer request serves what a fresh
+one serves; the two byte gauges reach the registry and
+``status()["model_stats"]``."""
 
-import jax
-import jax.numpy as jnp
-import numpy as np
 import pytest
 
-from perf.lib import reference_mimo as ref
-from progen_tpu.decode import Request, ServingEngine
-from progen_tpu.decode.engine import SLOTS_PER_ADMIT_ROW
-from progen_tpu.decode.family import UnsupportedFamilyMode, family_for
-from progen_tpu.models.mimo_v2 import MiMoV2Family
 from progen_tpu.observe.metrics import get_registry
-from tests.mimo_v2_tiny import TINY, WINDOW, as_dict, make
+from tests import families
+from tests.families import SLOTS
+from tests.mimo_v2_tiny import TINY, WINDOW
 
 pytestmark = pytest.mark.serving
 
-ADMIT_ROWS = 2
-SLOTS = ADMIT_ROWS * SLOTS_PER_ADMIT_ROW
-ENGINE = dict(num_slots=SLOTS, chunk_size=4, max_len=32)
-NEW, TOP_K = 7, 6
-# the ring's three edges (window 4), one token, and one past a chunk
-PRIMES = (1, WINDOW - 1, WINDOW, WINDOW + 1, 13, 21)
+CASE = families.CASES["mimo_v2"]
+MAX_LEN = CASE.max_len
+assert CASE.primes == (1, WINDOW - 1, WINDOW, WINDOW + 1, 13, 21)
 
 
 @pytest.fixture(scope="module")
-def served():
-    return make()
+def engine():
+    return families.engine_of(CASE)
 
 
-@pytest.fixture(scope="module")
-def engine(served):
-    params, policy = served
-    eng = ServingEngine(TINY, params, policy=policy, **ENGINE)
-    eng.warm = eng.aot_warmup()
-    return eng
-
-
-def _never_zero():
-    mask = np.ones((TINY.vocab_size,), bool)
-    mask[0] = False
-    return mask
-
-
-def _requests(n, seed=0, sampled=False, first_uid=0, primes=PRIMES):
-    """Primes of 1-21 tokens (the buckets of 8, 16 and 32), 7-9 new: every
-    request ends past the window."""
-    rng = np.random.default_rng(seed)
-    return [Request(
-        uid=first_uid + i, max_new_tokens=NEW + i % 3, seed=50 + i,
-        temperature=0.8 if sampled else 0.0, top_k=TOP_K if sampled else None,
-        logit_mask=_never_zero(),
-        tokens=rng.integers(1, TINY.vocab_size,
-                            primes[i % len(primes)]).tolist())
-        for i in range(n)]
-
-
-def _serve(engine, reqs):
-    for r in reqs:
-        engine.submit(r)
-    return engine.run_until_idle(200)
-
-
-@jax.jit
-def _reference_logits(params, row, at):
-    """The reference over one row padded to the engine's ``max_len``
-    (causality keeps the padding out of what is read): one program."""
-    with jax.default_matmul_precision("highest"):
-        return ref.forward_row(params, row, as_dict(TINY),
-                               logit_positions=at)[0]
-
-
-def _padded(seq):
-    return jnp.zeros((ENGINE["max_len"],), jnp.int32).at[:len(seq)].set(
-        jnp.asarray(seq))
-
-
-def _sequential_greedy(params, r):
-    """The plain sampler: the reference's full forward over everything so
-    far, the best allowed token appended, again."""
-    seq = list(r.tokens)
-    for _ in range(r.max_new_tokens):
-        logits = _reference_logits(params, _padded(seq),
-                                   jnp.array([len(seq) - 1]))
-        seq.append(1 + int(jnp.argmax(logits[0, 1:])))
-    return seq[len(r.tokens):]
-
-
-def test_greedy_requests_across_the_rings_edges_serve_the_plain_samplers_tokens(
-        served, engine):
-    reqs = _requests(len(PRIMES))
+def greedy(case, reqs, done):
     assert all(len(r.tokens) + r.max_new_tokens > WINDOW for r in reqs)
-    got = {c.uid: c.tokens.tolist() for c in _serve(engine, reqs)}
-    assert got == {r.uid: _sequential_greedy(served[0], r) for r in reqs}
+    families.serves_the_plain_samplers_tokens(case, reqs, done)
 
 
 def test_a_slot_readmitted_after_a_longer_request_serves_what_a_fresh_one_does(
-        served, engine):
+        engine):
     """Every slot holds a 21-token request's rings and grown rows, then
     takes a prime of 1-5 tokens: stale rows past the short request's count,
     in both kinds of cache, must reach nothing."""
-    long = _requests(SLOTS, seed=7, first_uid=200, primes=(21,))
-    assert len(_serve(engine, long)) == SLOTS
-    short = _requests(SLOTS, seed=8, first_uid=300,
-                      primes=(1, 2, WINDOW - 1, WINDOW, WINDOW + 1))
-    got = {c.uid: c.tokens.tolist() for c in _serve(engine, short)}
-    assert got == {r.uid: _sequential_greedy(served[0], r) for r in short}
+    long = families.requests(CASE, SLOTS, seed=7, first_uid=200, primes=(21,))
+    assert len(families.serve(engine, long)) == SLOTS
+    short = families.requests(
+        CASE, SLOTS, seed=8, first_uid=300,
+        primes=(1, 2, WINDOW - 1, WINDOW, WINDOW + 1))
+    families.serves_the_plain_samplers_tokens(
+        CASE, short, families.serve(engine, short))
 
 
-def test_sampled_requests_keep_to_the_probe_rule(served, engine):
-    """Every served token is among the reference's ``top_k`` best allowed
-    at its position (to a float32 rounding)."""
-    reqs = _requests(ADMIT_ROWS + 2, seed=4, sampled=True, first_uid=100)
-    out = {c.uid: c.tokens.tolist() for c in _serve(engine, reqs)}
-    for r in reqs:
-        seq = list(r.tokens) + out[r.uid]
-        p = len(r.tokens)
-        new = len(out[r.uid])
-        logits = _reference_logits(served[0], _padded(seq),
-                                   p - 1 + jnp.arange(NEW + 2))
-        at = np.asarray(logits)[:new, 1:]
-        tok = np.asarray(out[r.uid]) - 1
-        kth = np.sort(at, axis=-1)[:, -TOP_K]
-        assert (kth - at[np.arange(len(tok)), tok]).max() < 1e-4
-        assert 0 not in out[r.uid]
-
-
-def test_nothing_compiles_after_warmup_and_a_slot_holds_both_caches(engine):
-    assert sorted(k for k in engine._aot if k[0] == "admit") == [
-        ("admit", 8), ("admit", 16), ("admit", 32)]
-    assert engine.warm["programs"] == 4
-    events = []
-
-    def listener(name, secs, **kw):
-        if name.startswith("/jax/core/compile"):
-            events.append(name)
-
-    jax.monitoring.register_event_duration_secs_listener(listener)
-    try:
-        first = {c.uid: c.tokens.tolist() for c in _serve(
-            engine, _requests(SLOTS + 5, seed=3, sampled=True))}
-    finally:
-        jax.monitoring.unregister_event_duration_listener(listener)
-    assert events == [] and len(first) == SLOTS + 5
-    assert engine.state["lmask"].shape == (SLOTS, TINY.vocab_size)
+def slot_holds(engine):
     caches = engine.state["caches"]
     assert sorted(caches) == [f"l{i}" for i in range(7)]
-    rows = ENGINE["max_len"]
     assert {n: (c["k"].shape[1:], c["v"].shape[1:])
             for n, c in caches.items()} == {
         **{f"l{i}": ((2, WINDOW, 12), (2, WINDOW, 8))
            for i in (1, 2, 3, 4, 6)},
-        **{f"l{i}": ((1, rows, 12), (1, rows, 8)) for i in (0, 5)}}
+        **{f"l{i}": ((1, MAX_LEN, 12), (1, MAX_LEN, 8)) for i in (0, 5)}}
     status = engine.status()
     assert status["row_write"] == "scatter"     # the CPU's lowering
     assert status["gqa_prefill"] == status["gqa_decode"] == "xla"
 
 
-@pytest.mark.parametrize("mode", [
-    dict(paged=True), dict(disagg=True),
-    dict(lora_bank={}), dict(quantize="weights"), dict(mesh=object())],
-    ids=lambda m: next(iter(m)))
-def test_a_mode_outside_the_familys_is_refused_by_name(served, mode):
-    params, policy = served
-    with pytest.raises(UnsupportedFamilyMode, match=next(iter(mode))):
-        ServingEngine(TINY, params, policy=policy, **ENGINE, **mode)
-
-
-def test_family_for_returns_the_family_and_what_it_states(served):
-    family = family_for(TINY, served[1])
-    assert isinstance(family, MiMoV2Family)
-    assert family.name == "mimo_v2" and family.modes == frozenset()
-    assert family.idle_length == 0 and not family.position_masks
+def states(family):
     assert family.block_length is None
     assert family.vocab == TINY.vocab_size
     assert family.seq_len == TINY.max_position_embeddings
-    assert family.buckets(20, 32) == [8, 16, 32]
 
 
-def test_counters_and_byte_gauges_reach_the_registry_and_the_status(served):
-    params, policy = served
-    eng = ServingEngine(TINY, params, policy=policy, **ENGINE)
-    reqs = _requests(3, seed=5, primes=(3, 13, 6))
-    _serve(eng, reqs)
-    stats = eng.model_stats
+def counters(engine, reqs, stats, total):
     prime_tokens = sum(len(r.tokens) for r in reqs)
     steps = sum(r.max_new_tokens - 1 for r in reqs)   # the first is prefill's
     assert stats["moe.tokens"] == 6 * (prime_tokens + steps)
@@ -209,28 +83,32 @@ def test_counters_and_byte_gauges_reach_the_registry_and_the_status(served):
     chunk_steps = stats["attn.window_rows_read"] / (SLOTS * WINDOW)
     assert chunk_steps == int(chunk_steps) and chunk_steps >= max(
         r.max_new_tokens - 1 for r in reqs)
-    assert stats["attn.full_rows_read"] == (chunk_steps * SLOTS
-                                            * ENGINE["max_len"])
+    assert stats["attn.full_rows_read"] == chunk_steps * SLOTS * MAX_LEN
     # the pairs the blocked form computed beside those the masks allow
     assert stats["attn.prefill_pairs_visited"] > stats[
         "attn.prefill_pairs_allowed"] > 0
     # no byte counter rides in the state: the gauges are the rows at each
     # kind's own row bytes (float32 here) times the kind's blocks
     assert not [k for k in stats if k.endswith("_bytes_read")]
-    gauges = eng.status()["model_stats"]
+    gauges = engine.status()["model_stats"]
     ring_row, grown_row = 2 * (12 + 8) * 4, 1 * (12 + 8) * 4
     assert gauges["attn.window_bytes_read"] == (
-        stats["attn.window_rows_read"] * 5 * ring_row)
+        total["attn.window_rows_read"] * 5 * ring_row)
     assert gauges["attn.full_bytes_read"] == (
-        stats["attn.full_rows_read"] * 2 * grown_row)
+        total["attn.full_rows_read"] * 2 * grown_row)
     assert gauges["attn.full_bytes_read"] > gauges["attn.window_bytes_read"]
     snap = get_registry().snapshot()
     for name in ("moe.tokens", "moe.decode_layers", "moe.experts_touched",
                  "attn.decode_rows", "attn.context_tokens",
                  "attn.window_tokens", "attn.window_rows_read",
                  "attn.full_rows_read", "attn.prefill_pairs_visited"):
-        assert snap[name]["value"] == stats[name], name
+        assert snap[name]["value"] == total[name], name
     for name in ("attn.window_bytes_read", "attn.full_bytes_read"):
         assert snap[name]["value"] == gauges[name], name
-    assert snap["moe.held_assignments"]["value"] == stats[
+    assert snap["moe.held_assignments"]["value"] == total[
         "moe.held_load"].sum()
+
+
+TestEngine = families.engine_tests(
+    CASE, slot_holds=slot_holds, states=states, counters=counters,
+    greedy=greedy)

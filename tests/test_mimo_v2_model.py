@@ -20,6 +20,7 @@ from perf.lib import reference_mimo as ref
 from progen_tpu.models import experts, kv
 from progen_tpu.models import mimo_v2 as mm
 from progen_tpu.ops import gqa
+from tests.families import jitted, reference
 from tests.mimo_v2_tiny import TINY, WINDOW, as_dict, make
 
 T, MAX_LEN = 24, 32
@@ -152,10 +153,10 @@ def test_each_omission_fails_the_tolerance_the_program_keeps(
     toks = _tokens()
     pos = jnp.broadcast_to(jnp.arange(T), toks.shape)
     with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda w: ref.forward(w, toks, as_dict(PAIR)))(params)
-        got, _, _ = jax.jit(lambda w: mm.prefill(
-            w, toks, jnp.array([T, T]), PAIR, policy, logit_positions=pos))(
-            params)
+        # before any patch: the cached programs are the unpatched ones
+        want = reference(ref, PAIR)(params, toks)
+        got, _, _ = jitted(mm.prefill)(params, toks, jnp.array([T, T]), PAIR,
+                                       policy, logit_positions=pos)
     cfg = as_dict(PAIR)
     weights = params
     if omission == "no-sink":
@@ -182,17 +183,16 @@ def _served_logits(params, policy, toks, primes, bucket):
     prefill's last position, then one decode step per token through the
     caches (rows of different primes step together, each at its own
     position)."""
-    rows = toks.shape[0]
+    live = jnp.ones((toks.shape[0],), bool)
     primes = jnp.asarray(primes)
     first, per_token, _ = _prefill(params, toks[:, :bucket], primes, policy)
-    caches = mm.caches_from(per_token, primes, TINY, MAX_LEN)
-    step = jax.jit(lambda p, t, ps, c: mm.decode_step(
-        p, t, ps, c, jnp.ones((rows,), bool), TINY, policy)[:2])
+    caches = jitted(mm.caches_from)(per_token, primes, TINY, MAX_LEN)
     out = [first[:, 0]]
     for i in range(T - int(primes.max())):
         pos = primes + i
         tok = jnp.take_along_axis(toks, pos[:, None], axis=1)[:, 0]
-        logits, caches = step(params, tok, pos, caches)
+        logits, caches, _ = jitted(mm.decode_step)(
+            params, tok, pos, caches, live, TINY, policy)
         out.append(logits)
     return jnp.stack(out, axis=1)
 

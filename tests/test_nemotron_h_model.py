@@ -8,6 +8,7 @@ each kind of layer states about its cache, the counters and the config's
 refusals."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,7 @@ from progen_tpu.models import nemotron_h as nh
 from progen_tpu.ops import moe_decode as md
 from progen_tpu.ops import ssd
 from progen_tpu.ops.lowering import record_lowerings
+from tests.families import fresh, jitted, reference
 from tests.nemotron_h_tiny import TINY, as_dict, make, share
 from tests.test_pallas_moe_decode import _kernel_path
 
@@ -48,7 +50,7 @@ def rows():
 def wanted(weights, rows):
     """The reference's logits at every position of every row."""
     with jax.default_matmul_precision("highest"):
-        return np.asarray(ref.forward(weights[0], rows, as_dict(TINY)))
+        return np.asarray(reference(ref, TINY)(weights[0], rows))
 
 
 def test_forward_over_right_padded_rows_is_the_references(weights, rows,
@@ -56,8 +58,8 @@ def test_forward_over_right_padded_rows_is_the_references(weights, rows,
     params, policy = weights
     lengths = jnp.asarray(LENGTHS, jnp.int32)
     at = jnp.broadcast_to(jnp.arange(24), (4, 24))
-    logits, handed, stats = nh.prefill(params, rows, lengths, TINY, policy,
-                                       logit_positions=at)
+    logits, handed, stats = jitted(nh.prefill)(
+        params, rows, lengths, TINY, policy, logit_positions=at)
     for i, n in enumerate(LENGTHS):
         assert np.abs(np.asarray(logits[i, :n]) - wanted[i, :n]).max() < TOL
     assert float(wanted.std()) > 0.5            # not a vacuous bound
@@ -79,14 +81,14 @@ def test_prefill_then_decode_is_the_references_full_forward(weights, rows,
     every step's logits are the reference's at that position of the row."""
     params, policy = weights
     lengths = jnp.asarray([13, 1, 2, 20], jnp.int32)
-    _, handed, _ = nh.prefill(params, rows, lengths, TINY, policy)
-    caches = nh.caches_from(handed, lengths, TINY, MAX_LEN)
+    _, handed, _ = jitted(nh.prefill)(params, rows, lengths, TINY, policy)
+    caches = jitted(nh.caches_from)(handed, lengths, TINY, MAX_LEN)
     live = jnp.ones((4,), bool)
     for j in range(steps):
         pos = lengths + j
         tok = rows[jnp.arange(4), pos]
-        logits, caches, stats = nh.decode_step(params, tok, pos, caches,
-                                               live, TINY, policy)
+        logits, caches, stats = jitted(nh.decode_step)(
+            params, tok, pos, caches, live, TINY, policy)
         want = wanted[np.arange(4), np.asarray(pos)]
         assert np.abs(np.asarray(logits) - want).max() < TOL, j
     assert stats["ssm.step_rows"] == 3 * 4 and stats["ssm.decode_steps"] == 1
@@ -137,12 +139,14 @@ def test_bfloat16_where_float32_is_stated_fails_the_tolerance(
     params, policy = weights
     island(monkeypatch)
     lengths = jnp.asarray([13, 9, 12, 20], jnp.int32)
-    _, handed, _ = nh.prefill(params, rows, lengths, TINY, policy)
-    caches = nh.caches_from(handed, lengths, TINY, MAX_LEN)
-    worst = 0.0
+    # traced anew under the patch: ``jitted`` would hand back the float32
+    # programs the tests above compiled
+    _, handed, _ = fresh(nh.prefill)(params, rows, lengths, TINY, policy)
+    caches = jitted(nh.caches_from)(handed, lengths, TINY, MAX_LEN)
+    worst, step = 0.0, fresh(nh.decode_step)
     for j in range(3):
         pos = lengths + j
-        logits, caches, _ = nh.decode_step(
+        logits, caches, _ = step(
             params, rows[jnp.arange(4), pos], pos, caches,
             jnp.ones((4,), bool), TINY, policy)
         want = wanted[np.arange(4), np.asarray(pos)]
@@ -188,6 +192,7 @@ def _expert_layer(mixed=False, held=None, first=0):
     return c, layer, policy.compute_dtype
 
 
+@functools.partial(jax.jit, static_argnames="c")
 def _dense(v, ids, w, live, layer, c):
     """Every held expert over every token in float32."""
     e = {k: a.astype(F32) for k, a in layer["experts"].items()}
@@ -227,15 +232,17 @@ def test_two_matrix_experts_through_every_lowering(monkeypatch, lowering,
     c, layer, _ = _expert_layer(held=held, first=first)
     assert sorted(layer["experts"]) == ["wd", "wu"]
     u = jax.random.normal(jax.random.key(3), (tokens, c.hidden_size))
-    ids, w = nh.route(u, layer["router"], c)
+    ids, w = jitted(nh.route)(u, layer["router"], c)
     v = u @ layer["latent_in"]
     live = jnp.arange(tokens) % 5 != 0
     if tiles:
         _kernel_path(monkeypatch, **tiles)
     with jax.default_matmul_precision("highest"), \
             record_lowerings() as chosen:
-        got, load = experts.held_experts(v, ids, w, live, layer["experts"],
-                                         c)
+        # one program, traced under the lowering patched in above: eagerly
+        # the interpreter runs a kernel's grid op by op
+        got, load = fresh(experts.held_experts)(v, ids, w, live,
+                                                layer["experts"], c)
         counted = experts.kernel_counters(v, layer["experts"], load, c)
     assert chosen["moe_experts"] == {lowering}
     dense = np.asarray(_dense(v, ids, w, live, layer, c))

@@ -10,6 +10,7 @@ against a brute-force count; the pair counters; and the choice of lowering
 from backend, mesh and shape, as ``status()`` shows it."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +21,8 @@ from perf.lib import reference_trinity as ref
 from progen_tpu.models import trinity as tr
 from progen_tpu.ops import gqa
 from progen_tpu.ops.lowering import record_lowerings
-from tests.trinity_tiny import TINY, as_dict, make
+from tests.families import fresh, reference
+from tests.trinity_tiny import TINY, make
 
 D, R, P, TILE = 128, 2, 512, 128
 SCALE = D ** -0.5
@@ -50,8 +52,11 @@ def _kernel(q, k, v, lengths, window, **tiles):
 
 
 def _blocked(q, k, v, window):
+    """One compiled program a shape and window (eagerly the blocks are
+    dispatched op by op)."""
     with jax.default_matmul_precision("highest"):
-        return gqa.blocked_prefill_attention(q, k, v, SCALE, window)
+        return jax.jit(gqa.blocked_prefill_attention, static_argnums=(3, 4))(
+            q, k, v, SCALE, window)
 
 
 def _dense(q, k, v, window):
@@ -74,6 +79,15 @@ def _dense(q, k, v, window):
 
 def _f32(x):
     return np.asarray(x.astype(jnp.float32))
+
+
+@functools.lru_cache(maxsize=1)
+def _references(group, dtype, window):
+    """``(blocked, dense)`` over ``_operands(P, group, dtype)``: the lengths
+    reach the kernel alone, so the cases of one window, which follow each
+    other, compare against the same two arrays."""
+    q, k, v = _operands(P, group, jnp.dtype(dtype))
+    return _f32(_blocked(q, k, v, window)), _dense(q, k, v, window)
 
 
 WINDOWS = {"no-window": None, "under-a-tile": 40, "one-tile": TILE,
@@ -101,8 +115,7 @@ def test_kernel_equals_the_blocked_form_and_a_dense_reference(
     lengths = LENGTHS[case](window)
     got = _f32(_kernel(q, k, v, lengths, window, block_q=tiles[0],
                        block_k=tiles[1]))
-    blocked = _f32(_blocked(q, k, v, window))
-    dense = _dense(q, k, v, window)
+    blocked, dense = _references(group, dtype, window)
     assert got.shape == (R, P, q.shape[2] * D) and np.isfinite(got).all()
     assert float(np.abs(dense).max()) > 1.0      # not a vacuous bound
     for row, n in enumerate(lengths):
@@ -320,16 +333,23 @@ def test_prefill_through_the_kernel(monkeypatch):
     lengths = jnp.array([P - 100, 140])
     at = jnp.broadcast_to(jnp.arange(0, P, 4), (R, P // 4))
 
-    def run(tokens, lens):
-        # a fresh function per lowering: ``jax.jit`` would keep the trace
-        with jax.default_matmul_precision("highest"):
-            logits, _, stats = tr.prefill(params, tokens, lens, WIDE, policy,
-                                          logit_positions=at)
-        return logits, stats
+    def lowered():
+        """``tr.prefill`` as ONE program traced under what is patched NOW:
+        a fresh function per lowering (``jax.jit`` would keep the trace;
+        eagerly the interpreter runs the kernel's grid op by op)."""
+        prefill = fresh(tr.prefill)
+
+        def run(tokens, lens):
+            with jax.default_matmul_precision("highest"):
+                logits, _, stats = prefill(params, tokens, lens, WIDE,
+                                           policy, logit_positions=at)
+            return logits, stats
+
+        return run
 
     with jax.default_matmul_precision("highest"):
-        want = ref.forward(params, toks, as_dict(WIDE))[:, ::4]
-    blocked, blocked_stats = run(toks, lengths)
+        want = reference(ref, WIDE)(params, toks)[:, ::4]
+    blocked, blocked_stats = lowered()(toks, lengths)
     _force_kernel(monkeypatch)
     with record_lowerings() as chosen:
         jaxpr = str(jax.make_jaxpr(lambda t, n: tr.prefill(
@@ -342,6 +362,7 @@ def test_prefill_through_the_kernel(monkeypatch):
     assert "dynamic_update_slice" not in jaxpr
     assert f"f32[{R},{WIDE.num_key_value_heads},2,256," not in jaxpr
 
+    run = lowered()
     got, stats = run(toks, lengths)
     junk = jnp.where(jnp.arange(P)[None, :] < lengths[:, None], toks, 5)
     again, _ = run(junk, lengths)
@@ -363,7 +384,8 @@ def test_prefill_through_the_kernel(monkeypatch):
 
     x = jax.random.normal(jax.random.key(2), (R, P, WIDE.hidden_size))
     for name in ("l0", "l3"):       # a sliding block and the full one
-        out, _ = tr.blocks_of(WIDE)[name].prefill(
+        out, _ = jax.jit(lambda x, p, n, block=tr.blocks_of(WIDE)[name]:
+                         block.prefill(x, p, n))(
             x, params["layers"][int(name[1])]["attn"], jnp.array([P, 0]))
         assert not np.asarray(out[1]).any() and np.asarray(out[0]).any()
 

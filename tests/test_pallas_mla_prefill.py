@@ -20,6 +20,7 @@ import pytest
 from progen_tpu.models import longcat as lc
 from progen_tpu.ops import mla_prefill as mp
 from progen_tpu.ops.lowering import record_lowerings
+from tests.families import fresh
 from tests.longcat_tiny import TINY, make
 from tools import program_hash
 
@@ -51,8 +52,18 @@ def _kernel(q_nope, q_rope, k_nope, k_r, v, lengths, keep=None, **tiles):
 
 
 def _blocked(*ops):
+    """One compiled program a shape (eagerly the blocks are dispatched op
+    by op)."""
     with jax.default_matmul_precision("highest"):
-        return mp.blocked_prefill_attention(*ops)
+        return jax.jit(mp.blocked_prefill_attention)(*ops)
+
+
+@functools.lru_cache(maxsize=1)
+def _blocked_reference(p, heads, dtype):
+    """The blocked form over ``_operands(p, heads, dtype)``: the lengths and
+    the tiles reach the kernel alone, so the cases of one shape, which
+    follow each other, compare against the same array."""
+    return _f32(_blocked(*_operands(p, heads, jnp.dtype(dtype))))
 
 
 def _f32(x):
@@ -81,7 +92,7 @@ def test_kernel_equals_the_blocked_form_at_every_real_position(
     lengths = LENGTHS[case](p)
     kw = dict(block_q=tiles[0], block_k=tiles[1]) if tiles else {}
     got = _f32(_kernel(*ops, lengths, **kw))
-    want = _f32(_blocked(*ops))
+    want = _blocked_reference(p, heads, dtype)
     assert got.shape == (R, p, heads * VD) and np.isfinite(got).all()
     assert float(np.abs(want).max()) > 1.0      # not a vacuous bound
     for row, n in enumerate(lengths):
@@ -353,13 +364,20 @@ def test_prefill_through_the_kernel(monkeypatch):
     lengths = jnp.array([p - 100, 140])
     at = jnp.broadcast_to(jnp.arange(0, p, 4), (R, p // 4))
 
-    def run(tokens, lens):
-        # a fresh function per lowering: ``jax.jit`` would keep the trace
-        with jax.default_matmul_precision("highest"):
-            return lc.prefill(params, tokens, lens, WIDE, policy,
-                              logit_positions=at)[0]
+    def lowered():
+        """``lc.prefill`` as ONE program traced under what is patched NOW:
+        a fresh function per lowering (``jax.jit`` would keep the trace;
+        eagerly the interpreter runs the kernel's grid op by op)."""
+        prefill = fresh(lc.prefill)
 
-    want = run(toks, lengths)
+        def run(tokens, lens):
+            with jax.default_matmul_precision("highest"):
+                return prefill(params, tokens, lens, WIDE, policy,
+                               logit_positions=at)[0]
+
+        return run
+
+    want = lowered()(toks, lengths)
     _force_kernel(monkeypatch)
     with record_lowerings() as chosen:
         jaxpr = str(jax.make_jaxpr(lambda t, n: lc.prefill(
@@ -370,6 +388,7 @@ def test_prefill_through_the_kernel(monkeypatch):
     assert "dynamic_update_slice" not in jaxpr
     assert f"f32[{R},{WIDE.num_attention_heads},256," not in jaxpr
 
+    run = lowered()
     got = run(toks, lengths)
     junk = jnp.where(jnp.arange(p)[None, :] < lengths[:, None], toks, 5)
     again = run(junk, lengths)
@@ -380,8 +399,8 @@ def test_prefill_through_the_kernel(monkeypatch):
                                       np.asarray(again[row, real]))
 
     x = jax.random.normal(jax.random.key(2), (R, p, WIDE.hidden_size))
-    out, _ = lc.mla_prefill(x, params["layers"][0]["attn"][0], WIDE,
-                            jnp.array([p, 0]))
+    out, _ = jax.jit(lambda x, w, n: lc.mla_prefill(x, w, WIDE, n))(
+        x, params["layers"][0]["attn"][0], jnp.array([p, 0]))
     assert not np.asarray(out[1]).any() and np.asarray(out[0]).any()
 
 

@@ -14,6 +14,7 @@ admission shapes, as ``status()`` shows it; and the counters
 ``moe.prefill_rows_computed``."""
 
 import dataclasses
+import functools
 import types
 
 import jax
@@ -29,6 +30,7 @@ from progen_tpu.models import trinity as tr
 from progen_tpu.ops import moe_decode as md
 from progen_tpu.ops.lowering import record_lowerings
 from tests import deepseek_v2_tiny, longcat_tiny, sdar_tiny, trinity_tiny
+from tests.families import jitted
 
 F32 = jnp.float32
 # (family module, tiny config, make, index of an expert layer)
@@ -86,13 +88,15 @@ def _layer(family, mixed=False, held=None, first=0):
 
 
 def _routed(module, config, layer, u):
-    out = module.route(u, layer["router"], config)
+    out = jitted(module.route)(u, layer["router"], config)
     return out[0], out[1]                      # ids, weights
 
 
+@functools.partial(jax.jit, static_argnames="c")
 def _dense(u, ids, w, live, layer, c):
     """Every held expert over every token in float32, the assignments
-    picked out after: nothing grouped, nothing skipped."""
+    picked out after: nothing grouped, nothing skipped (one program a
+    shape: eagerly the loop compiles its every op anew at each)."""
     e = {k: v.astype(F32) for k, v in layer["experts"].items()}
     uf = u.astype(F32)
     y = jnp.zeros(uf.shape, F32)

@@ -1,14 +1,15 @@
 """SDAR through ``ServingEngine``'s normal path (the seam of
-``decode/family.py``) by diffusion over blocks: primes at every ``P mod 4``
-serve the tokens of the reference's ``generate_block`` (the whole sequence
-recomputed at every forward) under both remasking rules, with the denoise
-forward that kept each; ``stop`` and end of sequence inside a block drop
-what follows; a request's tokens are the same alone and among neighbours
-admitted at other steps; nothing compiles after ``aot_warmup``; the modes
-the family does not state are refused by name; the block step's counters
-reach the registry."""
+``decode/family.py``) by diffusion over blocks: the tests every driver family
+runs (``tests/families.py``), where the plain sampler is the reference's
+``generate_block`` (the whole sequence recomputed at every forward) over
+primes at every ``P mod 4`` and the probe rule is that of the forward that
+kept each token; what is SDAR's own here: both remasking rules; ``stop`` and
+end of sequence inside a block drop what follows; a request's tokens are the
+same alone and among neighbours admitted at other steps; a block costs two
+forwards; a snapshot replays a block request; the tiled draw."""
 
 import dataclasses
+import functools
 
 import jax
 import numpy as np
@@ -16,91 +17,55 @@ import pytest
 
 from perf.lib import reference_sdar as ref
 from progen_tpu.decode import Request, ServingEngine, sampler
-from progen_tpu.decode.engine import SLOTS_PER_ADMIT_ROW
 from progen_tpu.decode.family import UnsupportedFamilyMode, family_for
-from progen_tpu.models.sdar import SDARFamily
 from progen_tpu.observe.metrics import get_registry
-from tests.sdar_tiny import BLOCK, MASK_ID, TINY, as_dict, make
+from tests import families
+from tests.families import SLOTS
+from tests.sdar_tiny import BLOCK, MASK_ID, TINY, as_dict
 
 pytestmark = pytest.mark.serving
 
-ADMIT_ROWS = 2
-SLOTS = ADMIT_ROWS * SLOTS_PER_ADMIT_ROW
-ENGINE = dict(num_slots=SLOTS, chunk_size=6, max_len=48)
-TOP_K = 5
-PRIMES = (5, 6, 7, 8, 13, 3)        # every P mod 4; one shorter than a block
+CASE = families.CASES["sdar"]
+ENGINE = CASE.engine
+TOP_K, PRIMES = CASE.top_k, CASE.primes
+assert CASE.draw_below == MASK_ID
 DYNAMIC = dataclasses.replace(TINY, remasking="low_confidence_dynamic",
                               denoising_steps=4, confidence_threshold=0.3)
 
 
 @pytest.fixture(scope="module")
 def served():
-    return make()
+    return CASE.served()
 
 
 @pytest.fixture(scope="module")
-def engine(served):
-    params, policy = served
-    eng = ServingEngine(TINY, params, policy=policy, **ENGINE)
-    eng.warm = eng.aot_warmup()
-    return eng
+def engine():
+    return families.engine_of(CASE)
 
 
-def _allowed(*also_banned):
-    mask = np.ones((TINY.vocab_size,), bool)
-    mask[[0, *also_banned]] = False
-    return mask
-
-
-def _requests(n, seed=0, sampled=False, first_uid=0, mask=None):
-    rng = np.random.default_rng(seed)
-    return [Request(
-        uid=first_uid + i, max_new_tokens=9 + i % 5, seed=50 + i,
-        temperature=0.8 if sampled else 0.0, top_k=TOP_K,
-        logit_mask=_allowed() if mask is None else mask,
-        record_fill_steps=True,
-        tokens=rng.integers(1, MASK_ID, PRIMES[i % len(PRIMES)]).tolist())
-        for i in range(n)]
-
-
-def _serve(engine, reqs):
-    for r in reqs:
-        engine.submit(r)
-    return {c.uid: c for c in engine.run_until_idle(200)}
-
-
-_FORWARDS = {}
+_allowed = functools.partial(families.never_zero, CASE)
+_requests = functools.partial(families.requests, CASE)
+_serve = families.serve
 
 
 def _reference_tokens(params, r, config=TINY):
     """``generate_block`` over ONE compiled forward a configuration (rows
     padded to the engine's ``max_len``)."""
-    cfg = as_dict(config)
-    if config not in _FORWARDS:
-        fwd = jax.jit(lambda p, t, at: ref.forward_row(
-            p, t, cfg, logit_positions=at)[0])
-
-        def forward(p, t, at):
-            with jax.default_matmul_precision("highest"):
-                return fwd(p, t, at)
-
-        _FORWARDS[config] = forward
     return ref.generate_block(
-        params, r.tokens, cfg, r.max_new_tokens, forward=_FORWARDS[config],
+        params, r.tokens, as_dict(config), r.max_new_tokens,
+        forward=families.reference_logits(CASE, config),
         width=ENGINE["max_len"], top_k=r.top_k, temperature=r.temperature,
         allowed_tokens=r.logit_mask)
 
 
-def test_greedy_requests_at_every_p_mod_4_serve_generate_blocks_tokens(
-        served, engine):
+def greedy(case, reqs, done):
     """Float32: the same tokens, kept at the same denoise forwards; more
     requests than slots of an admission run, so rows sit in different
     phases of their blocks."""
-    reqs = _requests(len(PRIMES))
-    done = _serve(engine, reqs)
+    params = case.served()[0]
     assert {len(r.tokens) % BLOCK for r in reqs} == {0, 1, 2, 3}
     for r in reqs:
-        tokens, fills = _reference_tokens(served[0], r)
+        tokens, fills = _reference_tokens(params, r)
         c = done[r.uid]
         assert c.finish_reason == "length" and c.ok
         assert c.tokens.tolist() == tokens, r.uid
@@ -110,7 +75,7 @@ def test_greedy_requests_at_every_p_mod_4_serve_generate_blocks_tokens(
     # a request that does not ask is not told
     plain = Request(uid=99, tokens=reqs[0].tokens, max_new_tokens=5,
                     temperature=0.0, logit_mask=_allowed())
-    assert _serve(engine, [plain])[99].fill_steps is None
+    assert _serve(families.engine_of(case), [plain])[99].fill_steps is None
 
 
 def test_the_dynamic_rule_serves_generate_blocks_tokens(served):
@@ -254,22 +219,20 @@ def test_a_requests_tokens_do_not_depend_on_its_neighbours(served, engine):
             != alone[reqs[0].uid].tokens.tolist())
 
 
-def test_sampled_tokens_keep_to_the_top_k_of_the_forward_that_kept_them(
-        served, engine):
+def sampled(case, reqs, done):
     """The probe rule at tiny size: the reference's replay of the served
     trajectory ranks every kept token among its 5 best allowed logits at the
     forward that kept it (float32: but for near-ties)."""
-    params = served[0]
-    reqs = _requests(4, seed=11, sampled=True, first_uid=400)
-    done = _serve(engine, reqs)
+    params = case.served()[0]
+    replay = families.reference(ref, TINY, "forward_row")
     for r in reqs:
         c = done[r.uid]
         row, positions, allowed, index = ref.replay_row(
             r.tokens, c.tokens, c.fill_steps, as_dict(TINY), 2, width=96)
         with jax.default_matmul_precision("highest"):
-            logits, _ = ref.forward_row(
-                params, row, as_dict(TINY), positions=positions,
-                allowed=allowed, logit_positions=np.maximum(index, 0))
+            logits, _ = replay(
+                params, row, positions=positions, allowed=allowed,
+                logit_positions=np.maximum(index, 0))
         logits = np.array(logits)
         logits[:, [0, MASK_ID]] = -np.inf
         ok = index >= 0
@@ -278,12 +241,7 @@ def test_sampled_tokens_keep_to_the_top_k_of_the_forward_that_kept_them(
         assert (served_logit[ok] >= kth[ok] - 1e-4).all()
 
 
-def test_nothing_compiles_after_warmup_and_the_state_holds_a_block(engine):
-    assert engine.warm["programs"] == 5     # buckets 8, 16, 32, 48; the chunk
-    # (the counter is the process's: other engines of this file compile)
-    before = engine.status()["compiles_in_step"]
-    _serve(engine, _requests(len(PRIMES), seed=2, first_uid=500))
-    assert engine.status()["compiles_in_step"] == before
+def slot_holds(engine):
     state = engine.state
     assert state["block"].shape == (SLOTS, BLOCK)
     assert state["fill"].shape == (SLOTS, ENGINE["max_len"])
@@ -298,51 +256,33 @@ def test_nothing_compiles_after_warmup_and_the_state_holds_a_block(engine):
     assert engine.block_length == BLOCK
 
 
-@pytest.mark.parametrize("mode", [
-    {"paged": True}, {"disagg": True}, {"quantize": "weights"},
-    {"lora_bank": {}}], ids=lambda m: next(iter(m)))
-def test_a_mode_the_family_does_not_state_is_refused_by_name(served, mode):
-    params, policy = served
-    with pytest.raises(UnsupportedFamilyMode, match=next(iter(mode))):
-        ServingEngine(TINY, params, policy=policy, **ENGINE, **mode)
-
-
 def test_the_engine_refuses_what_a_block_cannot_take(served):
     params, policy = served
     with pytest.raises(ValueError, match="whole number"):
         ServingEngine(TINY, params, policy=policy, num_slots=SLOTS,
                       chunk_size=4, max_len=46)
-    eng = ServingEngine(TINY, params, policy=policy, **ENGINE)
     with pytest.raises(UnsupportedFamilyMode, match="logit_mask"):
-        eng.submit(Request(uid=0, tokens=[1, 2], max_new_tokens=4,
-                           logit_mask=np.ones((4, TINY.vocab_size), bool)))
+        families.engine_of(CASE).submit(Request(
+            uid=0, tokens=[1, 2], max_new_tokens=4,
+            logit_mask=np.ones((4, TINY.vocab_size), bool)))
 
 
-def test_family_for_returns_the_family_and_what_it_states(served):
-    family = family_for(TINY, served[1])
-    assert isinstance(family, SDARFamily)
-    assert family.name == "sdar" and family.modes == frozenset()
-    assert family.idle_length == 0 and not family.position_masks
+def states(family):
+    policy = CASE.served()[1]
     assert (family.block_length, family.mask_token_id,
             family.denoising_steps, family.remasking) == (
                 BLOCK, MASK_ID, 2, "low_confidence_static")
-    assert family.buckets(20, 48) == [8, 16, 32]
     with pytest.raises(NotImplementedError, match="block_step"):
         family.decode_step(None, None, None, None, None)
     # every other family states one token a row a step
     from progen_tpu.models.configs import SMALL
     from tests.trinity_tiny import TINY as TRINITY
 
-    assert family_for(TRINITY, served[1]).block_length is None
-    assert family_for(SMALL, served[1]).block_length is None
+    assert family_for(TRINITY, policy).block_length is None
+    assert family_for(SMALL, policy).block_length is None
 
 
-def test_counters_ride_the_flags_fetch_into_the_registry(served):
-    params, policy = served
-    eng = ServingEngine(TINY, params, policy=policy, **ENGINE)
-    reqs = _requests(3, seed=5)
-    _serve(eng, reqs)
-    stats = eng.model_stats
+def counters(engine, reqs, stats, total):
     new = sum(r.max_new_tokens for r in reqs)
     assert stats["diffusion.tokens_committed"] == new
     # each request's blocks: from the prime's last whole block to stop
@@ -370,11 +310,17 @@ def test_counters_ride_the_flags_fetch_into_the_registry(served):
     for name in ("diffusion.forwards", "diffusion.commit_forwards",
                  "diffusion.tokens_committed", "diffusion.tokens_dropped",
                  "moe.tokens", "attn.decode_rows", "attn.context_tokens"):
-        assert snap[name]["value"] == stats[name], name
+        assert snap[name]["value"] == total[name], name
+    kept = np.asarray(total["diffusion.positions_kept"])
     assert snap["diffusion.positions_kept.0"]["value"] == kept[0]
     assert snap["diffusion.positions_kept.1"]["value"] == kept[1]
-    assert snap["moe.held_assignments"]["value"] == stats[
+    assert snap["moe.held_assignments"]["value"] == total[
         "moe.held_load"].sum()
+
+
+TestEngine = families.engine_tests(
+    CASE, slot_holds=slot_holds, states=states, counters=counters,
+    greedy=greedy, sampled=sampled)
 
 
 def test_a_snapshot_replays_a_block_request_token_for_token(served, engine):

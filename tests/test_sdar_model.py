@@ -16,6 +16,7 @@ import pytest
 
 from perf.lib import reference_sdar as ref
 from progen_tpu.models import experts, sdar
+from tests.families import jitted, reference
 from tests.sdar_tiny import BLOCK, MASK_ID, TINY, as_dict, make
 
 T, MAX_LEN = 40, 48
@@ -28,9 +29,11 @@ def _tokens(seed=1, rows=2):
 
 
 def _reference(params, row, at=None, **kwargs):
+    """One compiled forward a row length (eagerly the reference is
+    dispatched op by op)."""
     with jax.default_matmul_precision("highest"):
-        return ref.forward_row(params, jnp.asarray(row), CFG,
-                               logit_positions=at, **kwargs)
+        return reference(ref, TINY, "forward_row")(
+            params, jnp.asarray(row), logit_positions=at, **kwargs)
 
 
 @jax.jit
@@ -92,7 +95,7 @@ def test_prefill_matches_the_reference_over_each_rows_whole_blocks(lengths):
     params, policy = make()
     toks, lengths = _tokens(), np.asarray(lengths)
     at = np.broadcast_to(np.arange(T), (2, T))
-    logits, rows, stats, chosen = sdar.prefill(
+    logits, rows, stats, chosen = jitted(sdar.prefill)(
         params, toks, lengths, TINY, policy, logit_positions=at,
         with_choices=True)
     for i, n in enumerate(lengths // BLOCK * BLOCK):
@@ -115,7 +118,7 @@ def test_bf16_prefill_stays_near_the_reference():
     params, policy = make(mixed=True)
     toks = _tokens()
     at = np.broadcast_to(np.arange(T), (2, T))
-    logits, _, _, chosen = sdar.prefill(
+    logits, _, _, chosen = jitted(sdar.prefill)(
         params, toks, np.asarray([T, T]), TINY, policy, logit_positions=at,
         with_choices=True)
     assert params["embed"].dtype == jnp.bfloat16
@@ -152,11 +155,13 @@ def test_prefill_denoise_commit_through_the_cache_matches_the_reference(
     padded = np.zeros((4, 32), np.int32)
     for i, (tokens, _, _, _) in enumerate(paths):
         padded[i, :primes[i]] = tokens[:primes[i]]
-    _, rows, _ = sdar.prefill(params, padded, primes, TINY, policy)
-    caches = sdar.caches_from(rows, primes, TINY, MAX_LEN)
+    _, rows, _ = jitted(sdar.prefill)(params, padded, primes, TINY, policy)
+    caches = jitted(sdar.caches_from)(rows, primes, TINY, MAX_LEN)
     live = np.ones(4, bool)
-    step = jax.jit(lambda p, t, p0, c, commit: sdar.block_step(
-        p, t, p0, c, live, commit, TINY, policy)[:2])
+    def step(p, t, p0, c, commit):
+        return jitted(sdar.block_step)(p, t, p0, c, live, commit, TINY,
+                                       policy)[:2]
+
     tol = 0.2 if mixed else 2e-5
     for j in range(2):
         pos0 = np.asarray([w + j * BLOCK for _, _, w, _ in paths])
@@ -209,8 +214,8 @@ def _folded_case(mixed, cursors, has, live):
     # (the prefill sees other tokens past what it caches: no row of the
     # cache holds a later block's keys by accident)
     primes = np.where(np.arange(32)[None] < cached[:, None], toks, 1)
-    _, rows, _ = sdar.prefill(params, primes, cached, TINY, policy)
-    caches = sdar.caches_from(rows, cached, TINY, MAX_LEN)
+    _, rows, _ = jitted(sdar.prefill)(params, primes, cached, TINY, policy)
+    caches = jitted(sdar.caches_from)(rows, cached, TINY, MAX_LEN)
     pend = np.stack([t[c - BLOCK:c] if h else np.full(BLOCK, MASK_ID)
                      for t, c, h in zip(toks, cursors, has)])
     blk = np.stack([np.where(rng.random(BLOCK) < 0.5, t[c:c + BLOCK], MASK_ID)
@@ -239,9 +244,10 @@ def test_a_pending_block_rides_the_next_forward_as_its_own_commit_would(
     else, the same logits of the block in progress."""
     params, policy, caches, blk, pend, cursors, riding, live = _folded_case(
         mixed, *FOLDED[case])
-    step = jax.jit(lambda p, t, p0, c, live, commit, pending=None:
-                   sdar.block_step(p, t, p0, c, live, commit, TINY, policy,
-                                   pending=pending)[:2])
+    def step(p, t, p0, c, live, commit, pending=None):
+        return jitted(sdar.block_step)(p, t, p0, c, live, commit, TINY,
+                                       policy, pending=pending)[:2]
+
     got, new = step(params, blk, cursors, caches, live, riding, pend)
     _, committed = step(params, pend, np.maximum(cursors - BLOCK, 0), caches,
                         live, riding)
@@ -329,7 +335,7 @@ def test_a_causal_mask_inside_the_block_fails_the_comparison():
     params, policy = make()
     toks = _tokens()[:1]
     at = np.arange(T)[None]
-    logits, _, _ = sdar.prefill(params, toks, np.asarray([T]), TINY, policy,
+    logits, _, _ = jitted(sdar.prefill)(params, toks, np.asarray([T]), TINY, policy,
                                 logit_positions=at)
     causal, _ = _reference(params, toks[0], allowed=np.tril(np.ones((T, T),
                                                                     bool)))
@@ -341,17 +347,17 @@ def test_the_renormalisation_and_the_qk_norms_each_matter():
     params, policy = make()
     toks, lengths = _tokens()[:1], np.asarray([T])
     at = np.arange(T)[None]
-    base = sdar.prefill(params, toks, lengths, TINY, policy,
+    base = jitted(sdar.prefill)(params, toks, lengths, TINY, policy,
                         logit_positions=at)[0]
     loose = dataclasses.replace(TINY, norm_topk_prob=False)
-    assert float(jnp.abs(sdar.prefill(params, toks, lengths, loose, policy,
+    assert float(jnp.abs(jitted(sdar.prefill)(params, toks, lengths, loose, policy,
                                       logit_positions=at)[0] - base).max()) > 0.1
     flat = jax.tree.map(lambda a: a, params)
     flat["layers"] = [{**layer, "attn": {
         **layer["attn"], "q_norm": jnp.ones_like(layer["attn"]["q_norm"]),
         "k_norm": jnp.ones_like(layer["attn"]["k_norm"])}}
         for layer in params["layers"]]
-    scaled = sdar.prefill(flat, toks, lengths, TINY, policy,
+    scaled = jitted(sdar.prefill)(flat, toks, lengths, TINY, policy,
                           logit_positions=at)[0]
     assert float(jnp.abs(scaled - base).max()) > 1e-3
     # and the router against a NumPy transcription
@@ -395,12 +401,12 @@ def test_a_block_step_counts_b_query_rows_a_live_slot():
     params, policy = make()
     toks = _tokens()
     primes = np.asarray([20, 8])
-    _, rows, _ = sdar.prefill(params, toks[:, :32], primes, TINY, policy)
-    caches = sdar.caches_from(rows, primes, TINY, MAX_LEN)
+    _, rows, _ = jitted(sdar.prefill)(params, toks[:, :32], primes, TINY, policy)
+    caches = jitted(sdar.caches_from)(rows, primes, TINY, MAX_LEN)
     blk = np.full((2, BLOCK), MASK_ID, np.int32)
-    _, _, stats = sdar.block_step(params, blk, primes, caches,
-                                  np.asarray([True, False]),
-                                  np.zeros(2, bool), TINY, policy)
+    _, _, stats = jitted(sdar.block_step)(
+        params, blk, primes, caches, np.asarray([True, False]),
+        np.zeros(2, bool), TINY, policy)
     assert float(stats["attn.decode_rows"]) == BLOCK
     assert float(stats["attn.context_tokens"]) == 20
     assert float(stats["attn.full_rows_read"]) == 2 * MAX_LEN

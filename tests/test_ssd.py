@@ -12,6 +12,12 @@ from progen_tpu.ops.lowering import record_lowerings
 
 H, D, N, CHUNK = 3, 4, 5, 8
 
+# one compiled program a shape (eagerly the chunked scan is dispatched op by
+# op); ``test_both_lowerings_say_which_they_are`` reads what a trace notes
+# and keeps the eager calls
+_scan = jax.jit(ssd.ssd_scan, static_argnums=6)
+_step = jax.jit(ssd.ssd_step)
+
 
 def _inputs(rows, p, seed=0):
     ks = jax.random.split(jax.random.key(seed), 6)
@@ -42,8 +48,7 @@ def test_chunked_scan_is_the_sequential_recurrence(p):
     length that is no multiple of the chunk."""
     x, dt, a, b, c = _inputs(2, p, seed=p)
     lengths = jnp.array([p, p], jnp.int32)
-    y, state = jax.jit(ssd.ssd_scan, static_argnums=6)(
-        x, dt, a, b, c, lengths, CHUNK)
+    y, state = _scan(x, dt, a, b, c, lengths, CHUNK)
     assert y.shape == (2, p, H, D) and state.shape == (2, H, D, N)
     assert y.dtype == state.dtype == jnp.float32
     for i in range(2):
@@ -61,7 +66,7 @@ def test_right_padded_rows_hand_over_the_state_at_their_true_length(bucket):
     zeros."""
     lengths = np.array([0, 1, 7, 8, 9, 23], np.int32)
     x, dt, a, b, c = _inputs(len(lengths), bucket, seed=3)
-    y, state = ssd.ssd_scan(x, dt, a, b, c, jnp.asarray(lengths), CHUNK)
+    y, state = _scan(x, dt, a, b, c, jnp.asarray(lengths), CHUNK)
     assert np.isfinite(np.asarray(y)).all()
     for i, n in enumerate(lengths):
         want_y, want_state = _sequential(x[i], dt[i], a, b[i], c[i], n)
@@ -73,7 +78,7 @@ def test_right_padded_rows_hand_over_the_state_at_their_true_length(bucket):
     # what stands in the padding does not matter
     junk = jnp.where(jnp.arange(bucket)[None, :, None, None]
                      >= lengths[:, None, None, None], 1e3, x)
-    _, again = ssd.ssd_scan(junk, dt, a, b, c, jnp.asarray(lengths), CHUNK)
+    _, again = _scan(junk, dt, a, b, c, jnp.asarray(lengths), CHUNK)
     np.testing.assert_array_equal(again, state)
 
 
@@ -81,12 +86,10 @@ def test_right_padded_rows_hand_over_the_state_at_their_true_length(bucket):
 def test_a_scan_of_n_then_k_steps_is_a_scan_of_n_plus_k(n, k):
     x, dt, a, b, c = _inputs(2, n + k, seed=n)
     full = jnp.array([n + k] * 2, jnp.int32)
-    want_y, want_state = ssd.ssd_scan(x, dt, a, b, c, full, CHUNK)
-    _, state = ssd.ssd_scan(x, dt, a, b, c, jnp.array([n] * 2, jnp.int32),
-                            CHUNK)
-    step = jax.jit(ssd.ssd_step)
+    want_y, want_state = _scan(x, dt, a, b, c, full, CHUNK)
+    _, state = _scan(x, dt, a, b, c, jnp.array([n] * 2, jnp.int32), CHUNK)
     for t in range(n, n + k):
-        y, state = step(state, x[:, t], dt[:, t], a, b[:, t], c[:, t])
+        y, state = _step(state, x[:, t], dt[:, t], a, b[:, t], c[:, t])
         np.testing.assert_allclose(y, want_y[:, t], atol=2e-5, rtol=1e-5)
     np.testing.assert_allclose(state, want_state, atol=2e-5, rtol=1e-5)
 
@@ -94,10 +97,10 @@ def test_a_scan_of_n_then_k_steps_is_a_scan_of_n_plus_k(n, k):
 def test_bfloat16_operands_keep_a_float32_carry():
     x, dt, a, b, c = _inputs(2, 19, seed=7)
     lengths = jnp.array([19, 11], jnp.int32)
-    want_y, want_state = ssd.ssd_scan(x, dt, a, b, c, lengths, CHUNK)
+    want_y, want_state = _scan(x, dt, a, b, c, lengths, CHUNK)
     lo = jnp.bfloat16
-    y, state = ssd.ssd_scan(x.astype(lo), dt, a, b.astype(lo), c.astype(lo),
-                            lengths, CHUNK)
+    y, state = _scan(x.astype(lo), dt, a, b.astype(lo), c.astype(lo),
+                     lengths, CHUNK)
     assert y.dtype == state.dtype == jnp.float32
     # a product of bfloat16 operands: a few parts in a thousand of the
     # values' spread
@@ -105,8 +108,8 @@ def test_bfloat16_operands_keep_a_float32_carry():
         jnp.abs(want_state).max())
     assert np.abs(np.asarray(y - want_y))[1, :11].max() < 0.05 * float(
         jnp.abs(want_y).max())
-    step_y, stepped = ssd.ssd_step(state, x[:, 0].astype(lo), dt[:, 0], a,
-                                   b[:, 0].astype(lo), c[:, 0].astype(lo))
+    step_y, stepped = _step(state, x[:, 0].astype(lo), dt[:, 0], a,
+                            b[:, 0].astype(lo), c[:, 0].astype(lo))
     assert step_y.dtype == stepped.dtype == jnp.float32
 
 
@@ -222,8 +225,7 @@ def test_grouped_scan_step_and_recurrence_agree(lengths, bucket):
     state and one output, right-padded rows included."""
     x, dt, a, b, c = _grouped_inputs(2, bucket, seed=bucket)
     n = jnp.asarray(lengths, jnp.int32)
-    y, state = jax.jit(ssd.ssd_scan, static_argnums=6)(x, dt, a, b, c, n,
-                                                       CHUNK)
+    y, state = _scan(x, dt, a, b, c, n, CHUNK)
     assert y.shape == (2, bucket, GH, D) and state.shape == (2, GH, D, N)
     for i, length in enumerate(lengths):
         want_y, want_state = _sequential_grouped(x[i], dt[i], a, b[i], c[i],
@@ -235,12 +237,12 @@ def test_grouped_scan_step_and_recurrence_agree(lengths, bucket):
     back = 3
     if min(lengths) < back:
         return
-    _, stepped = ssd.ssd_scan(x, dt, a, b, c, n - back, CHUNK)
+    _, stepped = _scan(x, dt, a, b, c, n - back, CHUNK)
     rows = jnp.arange(2)
     for j in range(back):
         t = n - back + j
-        y_t, stepped = ssd.ssd_step(stepped, x[rows, t], dt[rows, t], a,
-                                    b[rows, t], c[rows, t])
+        y_t, stepped = _step(stepped, x[rows, t], dt[rows, t], a,
+                             b[rows, t], c[rows, t])
         np.testing.assert_allclose(y_t, y[rows, t], atol=2e-5, rtol=1e-5)
     np.testing.assert_allclose(stepped, state, atol=2e-5, rtol=1e-5)
 
@@ -251,13 +253,12 @@ def test_one_group_with_and_without_its_axis_is_one_result():
     form computes at ``G`` = 1."""
     x, dt, a, b, c = _inputs(2, 19, seed=5)
     lengths = jnp.array([19, 11], jnp.int32)
-    y, state = ssd.ssd_scan(x, dt, a, b, c, lengths, CHUNK)
-    y1, state1 = ssd.ssd_scan(x, dt, a, b[:, :, None], c[:, :, None],
-                              lengths, CHUNK)
+    y, state = _scan(x, dt, a, b, c, lengths, CHUNK)
+    y1, state1 = _scan(x, dt, a, b[:, :, None], c[:, :, None], lengths,
+                       CHUNK)
     np.testing.assert_allclose(y1, y, atol=1e-6, rtol=1e-6)
     np.testing.assert_allclose(state1, state, atol=1e-6, rtol=1e-6)
-    s, s1 = ssd.ssd_step(state, x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0]), \
-        ssd.ssd_step(state, x[:, 0], dt[:, 0], a, b[:, 0, None],
-                     c[:, 0, None])
+    s, s1 = _step(state, x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0]), \
+        _step(state, x[:, 0], dt[:, 0], a, b[:, 0, None], c[:, 0, None])
     np.testing.assert_array_equal(s[0], s1[0])
     np.testing.assert_array_equal(s[1], s1[1])

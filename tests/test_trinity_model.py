@@ -19,6 +19,7 @@ from perf.lib import reference_trinity as ref
 from progen_tpu.models import experts
 from progen_tpu.models import trinity as tr
 from progen_tpu.ops import gqa
+from tests.families import jitted, reference
 from tests.trinity_tiny import TINY, WINDOW, as_dict, make
 
 T, MAX_LEN = 40, 48
@@ -49,17 +50,16 @@ def _served_logits(params, policy, toks, primes, bucket):
     prefill's last position, then one decode step per token through the
     caches (rows of different primes step together, each at its own
     position)."""
-    rows = toks.shape[0]
+    live = jnp.ones((toks.shape[0],), bool)
     primes = jnp.asarray(primes)
     first, per_token, _ = _prefill(params, toks[:, :bucket], primes, policy)
-    caches = tr.caches_from(per_token, primes, TINY, MAX_LEN)
-    step = jax.jit(lambda p, t, ps, c: tr.decode_step(
-        p, t, ps, c, jnp.ones((rows,), bool), TINY, policy)[:2])
+    caches = jitted(tr.caches_from)(per_token, primes, TINY, MAX_LEN)
     out = [first[:, 0]]
     for i in range(T - int(primes.max())):
         pos = primes + i
         tok = jnp.take_along_axis(toks, pos[:, None], axis=1)[:, 0]
-        logits, caches = step(params, tok, pos, caches)
+        logits, caches, _ = jitted(tr.decode_step)(
+            params, tok, pos, caches, live, TINY, policy)
         out.append(logits)
     return jnp.stack(out, axis=1)
 
@@ -94,12 +94,14 @@ def test_prefill_logits_match_the_reference_at_every_position():
     toks = _tokens()
     pos = jnp.broadcast_to(jnp.arange(T), (2, T))
     with jax.default_matmul_precision("highest"):
-        want = ref.forward(params, toks, as_dict(TINY))
-        got, rows, stats = tr.prefill(params, toks, jnp.array([T, 13]), TINY,
-                                      policy, logit_positions=pos)
+        want = reference(ref, TINY)(params, toks)
+        got, rows, stats = jitted(tr.prefill)(
+            params, toks, jnp.array([T, 13]), TINY, policy,
+            logit_positions=pos)
         junk = toks.at[1, 13:].set(5)
-        again, _, _ = tr.prefill(params, junk, jnp.array([T, 13]), TINY,
-                                 policy, logit_positions=pos)
+        again, _, _ = jitted(tr.prefill)(
+            params, junk, jnp.array([T, 13]), TINY, policy,
+            logit_positions=pos)
     assert float(jnp.abs(got[0] - want[0]).max()) < 5e-5
     assert float(jnp.abs(got[1, :13] - want[1, :13]).max()) < 5e-5
     np.testing.assert_array_equal(got[1, :13], again[1, :13])
@@ -119,11 +121,13 @@ def test_the_window_and_the_missing_rotation_change_the_logits():
     toks = _tokens()
     cfg = as_dict(TINY)
     with jax.default_matmul_precision("highest"):
-        want = ref.forward(params, toks, cfg)
-        wide = ref.forward(params, toks, {**cfg, "sliding_window": T})
-        all_sliding = ref.forward(params, toks, {
-            **cfg, "sliding_window": T,
-            "layer_types": ["sliding_attention"] * 5})
+        want = reference(ref, TINY)(params, toks)
+        wide, all_sliding = (
+            jax.jit(lambda p, t: ref.forward(p, t, {**cfg, **other}))(
+                params, toks)
+            for other in ({"sliding_window": T},
+                          {"sliding_window": T,
+                           "layer_types": ["sliding_attention"] * 5}))
     np.testing.assert_allclose(want[:, :WINDOW], wide[:, :WINDOW], atol=1e-5)
     assert float(jnp.abs(want - wide)[:, WINDOW:].max()) > 0.05
     assert float(jnp.abs(wide - all_sliding).max()) > 0.05
@@ -201,7 +205,7 @@ def test_decode_counts_rows_contexts_windows_and_cache_rows_read():
     caches = family.init_caches(3, MAX_LEN)
     live = jnp.array([True, False, True])
     pos = jnp.array([2, 30, 20])
-    _, _, stats, chosen = tr.decode_step(
+    _, _, stats, chosen = jitted(tr.decode_step)(
         params, jnp.array([4, 5, 6]), pos, caches, live, TINY, policy,
         with_choices=True)
     assert chosen.shape == (4, 3, TINY.num_experts_per_tok)
@@ -216,8 +220,9 @@ def test_decode_counts_rows_contexts_windows_and_cache_rows_read():
     assert float(stats["moe.held_load"].sum()) == 4 * 2 * 3
     assert 0 < float(stats["moe.experts_touched"]) <= 4 * 2 * 3
     # no live row: nothing is counted
-    _, _, idle = tr.decode_step(params, jnp.array([4, 5, 6]), pos, caches,
-                                jnp.zeros((3,), bool), TINY, policy)
+    _, _, idle = jitted(tr.decode_step)(
+        params, jnp.array([4, 5, 6]), pos, caches, jnp.zeros((3,), bool),
+        TINY, policy)
     assert all(float(jnp.sum(v)) == 0 for v in idle.values())
     assert set(idle) == set(tr.STAT_KEYS)
     assert not set(tr.STAT_KEYS) & {"mla.decode_rows", "mla.context_tokens",

@@ -12,7 +12,8 @@ import pytest
 from perf.lib import reference_trinity as ref
 from progen_tpu.models import trinity as tr
 from progen_tpu.models.driver import swiglu
-from tests.trinity_tiny import TINY, as_dict, make
+from tests.families import jitted, reference
+from tests.trinity_tiny import TINY, make
 
 TOKENS = 40
 
@@ -35,13 +36,13 @@ def test_shares_over_all_ranks_sum_to_the_uncut_layer(ranks):
     live = jnp.ones((TOKENS,), bool)
     held = TINY.num_experts // ranks
     with jax.default_matmul_precision("highest"):
-        routed, _ = ref.routed(u, layer["router"], layer["experts"],
-                               as_dict(TINY))
+        routed, _ = reference(ref, TINY, "routed")(u, layer["router"],
+                                                   layer["experts"])
         whole = routed + ref.swiglu(u, layer["shared"])
         total = jnp.zeros_like(u)
         for rank in range(ranks):
             cut, part = _share(layer, TINY, rank * held, held)
-            y, _, _ = tr.moe_share(u, part, cut, live)
+            y, _, _ = jitted(tr.moe_share)(u, part, cut, live)
             total = total + y
         # every chip computes the shared expert alike: counted once
         shared = swiglu(u, layer["shared"], scope="moe.shared")
@@ -57,9 +58,10 @@ def test_routing_is_over_the_whole_router_whatever_is_held(first, held):
     cut, part = _share(layer, TINY, first, held)
     live = jnp.ones((TOKENS,), bool)
     with jax.default_matmul_precision("highest"):
-        got, ids, stats = tr.moe_share(u, part, cut, live)
-        _, all_ids, _ = tr.moe_share(u, layer, TINY, live)
-        want, _ = ref.routed(u, part["router"], part["experts"], as_dict(cut))
+        got, ids, stats = jitted(tr.moe_share)(u, part, cut, live)
+        _, all_ids, _ = jitted(tr.moe_share)(u, layer, TINY, live)
+        want, _ = reference(ref, cut, "routed")(u, part["router"],
+                                                part["experts"])
     np.testing.assert_array_equal(ids, all_ids)
     np.testing.assert_allclose(got, want, atol=2e-5)
     counts = np.bincount(np.asarray(ids).ravel(), minlength=8)
@@ -71,7 +73,7 @@ def test_routing_is_over_the_whole_router_whatever_is_held(first, held):
 def test_tokens_that_are_not_live_reach_no_expert_and_are_not_counted():
     layer, u = _layer_and_input()
     live = jnp.arange(TOKENS) < 25
-    y, _, stats = tr.moe_share(u, layer, TINY, live)
+    y, _, stats = jitted(tr.moe_share)(u, layer, TINY, live)
     assert float(jnp.abs(y[25:]).max()) == 0
     assert float(stats["moe.tokens"]) == 25
     assert float(stats["moe.held_load"].sum()) == 25 * 3
@@ -86,9 +88,9 @@ def test_a_whole_model_of_one_share_is_the_references_of_that_share():
     toks = jax.random.randint(jax.random.key(1), (1, 24), 1, TINY.vocab_size)
     pos = jnp.arange(24)[None]
     with jax.default_matmul_precision("highest"):
-        want = ref.forward(params, toks, as_dict(cut))
-        got, _, stats = tr.prefill(params, toks, jnp.array([24]), cut, policy,
-                                   logit_positions=pos)
+        want = reference(ref, cut)(params, toks)
+        got, _, stats = jitted(tr.prefill)(params, toks, jnp.array([24]), cut,
+                                           policy, logit_positions=pos)
     assert float(jnp.abs(got - want).max()) < 5e-5
     # 3 of 8 a token, 2 of 8 held: 0.75 assignments a token on average
     assert 0 < float(stats["moe.prefill_held"]) < 4 * 24 * 2
