@@ -933,7 +933,8 @@ class ServingEngine:
         product (``"moe_experts"``: ``"pallas"`` / ``"pallas_grouped"`` /
         ``"pallas_sorted"`` / ``"xla"``, stated per program: it is in
         both, and an engine's admission programs, one a bucket, may differ
-        — joined by ``+``) and
+        — joined by ``+``), how an admission's sorted experts' terms reach
+        their tokens (``"moe_combine"``, per program too) and
         the draw's k-th largest logit (``"sample_kth"``: ``"xla"`` /
         ``"xla_tiled"``, per program too)."""
 
@@ -2760,6 +2761,10 @@ class ServingEngine:
             # the held experts' product, by program: {"chunk": ..,
             # "admit": ..} as far as traced, None for a family without
             "moe_experts": self._lowering_by_program("moe_experts"),
+            # how the terms of an admission's sorted experts reach their
+            # tokens' rows (models/experts.py:_sorted), by program; None
+            # where no program sorts
+            "moe_combine": self._lowering_by_program("moe_combine"),
             # how the draw's k-th largest logit is counted: all rows in
             # one loop ("xla") or a group of rows at a time, where only a
             # group's keys stay on the chip ("xla_tiled"), by program

@@ -43,7 +43,9 @@ on the lane tile, a call's TOKENS decide:
   again), the window's token rows gathered in sorted order, row tiles of
   128 of them through the expert whose rows they are — a tile that
   straddles experts once an expert, nothing for the tiles past the last
-  live row — and the terms scatter-added to their tokens;
+  live row — and each item's float32 terms added to their tokens' rows of
+  ``y`` by the kernel itself, by row DMAs (XLA's scatter-add of them took
+  3 us a row at 5,120 wide: PERF.md section 6, PR 59);
 * every other call (the CPU, a mesh, two float types, widths off the lane
   tile: ``"xla"``) groups the tokens by held expert likewise and multiplies
   them by ``jax.lax.ragged_dot`` in windows of ``capacity`` assignments
@@ -74,7 +76,8 @@ F32 = jnp.float32
 # ``trinity.ATTN_STAT_KEYS``) to these
 STAT_KEYS = ("moe.tokens", "moe.held_load", "moe.prefill_held",
              "moe.decode_layers", "moe.experts_touched", "moe.expert_passes",
-             "moe.rows_computed", "moe.prefill_rows_computed")
+             "moe.rows_computed", "moe.prefill_rows_computed",
+             "moe.prefill_rows_combined")
 
 
 def zero_stats(keys, held: int) -> dict:
@@ -123,11 +126,13 @@ def moe_capacity(c, tokens: int) -> int:
 def held_experts(u, ids, w, live, experts, c, capacity=None):
     """The held real experts' terms for ``u (T, h)``: ``(y (T, h) float32,
     load (held,))`` where ``load`` counts the live tokens' assignments to
-    each held expert.  Assignments of tokens that are not ``live``
-    (padding, finished rows) are not computed.  ``capacity``: assignments
-    per window of the sorted assignments where a form with windows runs
-    (default :func:`moe_capacity` under the XLA form,
-    :func:`sorted_window` under ``moe_sorted_fwd``); a window that
+    each held expert.  A token's ``k`` experts ``ids (T, k)`` are distinct,
+    as a router's top-k are (``moe_sorted_fwd`` fetches the rows of ``y``
+    that one expert's row tile adds to together).  Assignments of tokens
+    that are not ``live`` (padding, finished rows) are not computed.
+    ``capacity``: assignments per window of the sorted assignments where a
+    form with windows runs (default :func:`moe_capacity` under the XLA
+    form, :func:`sorted_window` under ``moe_sorted_fwd``); a window that
     overflows runs again, so it changes no result."""
     t, k = ids.shape
     held = c.experts_held
@@ -184,9 +189,9 @@ def sorted_window(c, tokens: int, k: int, row_tile: int,
     ``moe_sorted_fwd``: ``capacity``, by default HALF of what the tokens
     send the held experts if every token slot is live (a third of an
     admission's slots are: one window a call, as a rule — the kernel's
-    work ends at the live count, but the rows' gather and the terms'
-    scatter-add around it are the window's), in whole row tiles, at least
-    one and never more than hold the ``tokens * k`` assignments."""
+    work ends at the live count, but the rows' gather before it is the
+    window's), in whole row tiles, at least one and never more than hold
+    the ``tokens * k`` assignments."""
     rows = capacity or (c.moe_topk * c.experts_held * tokens
                         // (2 * c.router_width))
     tiles = min(-(-rows // row_tile), -(-(tokens * k) // row_tile))
@@ -204,12 +209,14 @@ def _sorted(u, group, w, experts, c, tiles, capacity=None):
     """``held_experts`` through ``moe_sorted_fwd``: ``(y, load)``.  The
     assignments sorted by expert (``group (T k,)``: the held expert, or
     ``held`` for what is not this chip's or not live), a window of them at
-    a time: the window's token rows gathered in sorted order, ONE kernel
+    a time: the window's token rows gathered in sorted order and ONE kernel
     call over row tiles of them — a tile that straddles experts visited
-    once an expert, the work ending with the last live row —, and the
-    terms added to their tokens' rows.  (``load`` is a sum of comparisons:
-    on the chip ``bincount``'s scatter of 262,144 ones takes 2.3 ms where
-    this takes 3 us; PERF.md section 6, PR 53.)"""
+    once an expert, the work ending with the last live row —, which adds
+    each item's terms to its tokens' rows of ``y`` itself (``y`` is the
+    kernel's own HBM operand, carried from window to window; noted under
+    ``"moe_combine"`` as ``"pallas_rows"``).  (``load`` is a sum of
+    comparisons: on the chip ``bincount``'s scatter of 262,144 ones takes
+    2.3 ms where this takes 3 us; PERF.md section 6, PR 53.)"""
     t = u.shape[0]
     k = group.shape[0] // t
     order = jnp.argsort(group)                    # held first, by expert
@@ -221,24 +228,28 @@ def _sorted(u, group, w, experts, c, tiles, capacity=None):
     order = jnp.pad(order, (0, -(-(t * k) // cap) * cap - t * k))
     weights = w.reshape(-1)
 
+    note("moe_combine", "pallas_rows")
+
     def window(carry):
         it, y = carry
         base = it * cap
         idx = jax.lax.dynamic_slice(order, (base,), (cap,))
         tok = idx // k
-        out = moe_decode.pallas_sorted_terms(
-            u[tok], weights[idx], *_in_window(starts, ends, base, cap),
-            experts.get("wg"), experts["wu"], experts["wd"],
-            row_tile=tiles.rows, tile=tiles.inner)
-        # what lies past the last real row may be a tile the kernel did
-        # not write: those rows go nowhere
-        valid = base + jnp.arange(cap) < n_mine
-        return it + 1, y.at[jnp.where(valid, tok, t)].add(out, mode="drop")
+        # what lies past the last real row is no expert's: the kernel
+        # moves nothing for it
+        return it + 1, moe_decode.pallas_sorted_add(
+            y, u[tok], tok, weights[idx],
+            *_in_window(starts, ends, base, cap), experts.get("wg"),
+            experts["wu"], experts["wd"], row_tile=tiles.rows,
+            tile=tiles.inner)
 
+    # a token's row as lane tiles: one run of HBM, which a row DMA can name
+    lane = moe_decode.LANE
     _, y = jax.lax.while_loop(
         lambda carry: carry[0] * cap < n_mine, window,
-        (jnp.zeros((), jnp.int32), jnp.zeros(u.shape, F32)))
-    return y, load
+        (jnp.zeros((), jnp.int32), jnp.zeros((t, u.shape[1] // lane, lane),
+                                             F32)))
+    return y.reshape(u.shape), load
 
 
 def _streamed(u, group, w, load, experts, tiles):
@@ -304,10 +315,16 @@ def kernel_counters(u, experts, load, c) -> dict:
     pass an item where it does not; under ``moe_sorted_fwd`` one item a row
     tile of the sorted rows an expert has a row in, window by window, and
     the passes likewise (an expert whose rows two windows share is streamed
-    in both); 0 under the XLA form, whose reads the program cannot know."""
+    in both); 0 under the XLA form, whose reads the program cannot know.
+    And ``moe.prefill_rows_combined``, the rows of ``y`` that
+    ``moe_sorted_fwd`` fetched, added to and wrote back: the live
+    assignments to held experts, one each (the XLA scatter-add it replaced
+    moved every row of every window: 1.3 to 1.4 a live assignment at
+    dots3's 16,384 bucket); 0 under every other lowering."""
     tiles = moe_decode.fitted_tile(u, experts)
     touched = jnp.sum(load > 0)
     whole = tiles is not None and tiles.inner == experts["wu"].shape[-1]
+    combined = jnp.zeros((), F32)
     if tiles is None:
         passes = rows = jnp.zeros((), F32)
     elif tiles.rows is None:
@@ -323,9 +340,10 @@ def kernel_counters(u, experts, load, c) -> dict:
             *_in_window(ends - load, ends, base, cap), tiles.rows)
         items = jnp.sum(ntile)
         passes = jnp.sum(ntile > 0) if whole else items
-        rows = items * tiles.rows
+        rows, combined = items * tiles.rows, jnp.sum(load)
     else:
         items = jnp.sum(-(-load // tiles.rows))
         passes, rows = touched if whole else items, items * tiles.rows
     return {"moe.expert_passes": passes.astype(F32),
-            "moe.rows_computed": rows.astype(F32)}
+            "moe.rows_computed": rows.astype(F32),
+            "moe.prefill_rows_combined": combined.astype(F32)}

@@ -6,7 +6,9 @@ note of which one it took.
 ``ops/moe_decode.py``'s; the note ``"moe_experts"``: ``"pallas"`` for a
 decode step's handful of tokens, ``"pallas_grouped"`` for a block step's
 few hundred, ``"pallas_sorted"`` for an admission's thousands, ``"xla"``
-off the chip) each keep a Pallas
+off the chip; under ``"pallas_sorted"`` the note ``"moe_combine"`` says how
+the terms reached their tokens: ``"pallas_rows"``, the kernel's own row
+DMAs) each keep a Pallas
 lowering and an XLA form behind one function and choose between them from
 the backend, the mesh in scope and the shapes, never from a knob.
 ``decode/sampler.py:_kth_largest_by_counting`` chooses in the same way

@@ -42,18 +42,30 @@ gathers the rows in and sums each token's ``k`` rows back by a gather.
 Past 1,024 tokens the padded grouping is what costs (its list is sized for
 ``T k / 32 + held`` items whatever share of the router the chip holds, and
 every item's rows are gathered into a tile of their own).
-:func:`pallas_sorted_terms` takes the rows as SORTED by expert and not
+:func:`pallas_sorted_add` takes the rows as SORTED by expert and not
 padded — ``xs (N, h)``, expert ``e``'s rows at ``[lo[e], hi[e])``, back to
 back — in row tiles of 128 read by the kernel's own ``BlockSpec``: the work
 list (:func:`sorted_work_list`) names ``(expert, row tile)`` items, an
 expert's tiles side by side, a tile that straddles two or three experts
-once an expert with the others' rows selected away; it is as long as the
+once an expert with the others' rows left alone; it is as long as the
 tiles that hold a row and the experts that share one (``N / 128 + held - 1``
 at most, read from a prefetched count: a grid step past the last real tile
 fetches and multiplies nothing), so the work follows the LIVE rows while
-the shapes follow the window.  The terms come back in sorted order,
-float32; the caller (``models/experts.py:_sorted``) gathers the rows in and
-scatter-adds the terms to their tokens.  A step holds an expert's whole
+the shapes follow the window.  The caller (``models/experts.py:_sorted``)
+gathers the rows in; the terms reach their tokens INSIDE the kernel: ``y
+(T, h)`` float32 stays in HBM, aliased to the result and laid out ``(T, h /
+128, 128)`` so that a token's row is one run of it (row ``t`` of a ``(T,
+h)`` array is a sublane of ``h / 128`` tiles, which no DMA may slice), the
+window's token numbers are prefetched beside the work list, and at its
+last step an item fetches its live rows' rows of ``y`` by row DMAs, adds
+its float32 terms and writes them back before the next item starts.
+(XLA's scatter-add of the same terms is a serial loop over the rows whose
+rate follows the width in no simple way — us a row on a v5e: 0.08 at 1,024
+wide, 0.22 at 2,048, 0.32 at 4,096, 3.8 at 5,120, 0.86 at 6,144 — and at
+dots3's 5,120 it was five sixths of the layer: 65.0 ms a layer alone at a
+16,384-token admission, 18.2 with the scatter in column slabs of 1,024,
+12.2 here, and faster or level at every cell's width; PERF.md section 6,
+PR 59.)  A step holds an expert's whole
 inner width wherever three (two) tiles of it fit ``SORTED_STEP_BYTES``
 (Trinity's 12.6 MB, LFM2's 22, Nemotron-3's 11, SDAR's 9.4), so consecutive
 items of one expert re-use its matrices and only the rows move; DeepSeek-V2's
@@ -67,11 +79,11 @@ once, and its product with the ``wd`` tile (float32) is weighted in float32
 and added to a float32 output block — the whole resident ``y`` under
 ``moe_decode_fwd`` (the down product's sum over inner tiles and the sum over
 experts are one accumulator), the item's own ``(row_tile, h)`` block under
-``moe_grouped_fwd``, the row tile's block under ``moe_sorted_fwd`` (the
-experts that share a tile add to it in turn).  The work list is
-scalar-prefetched and the index maps read it, so while an expert's last
-tiles are used the next
-expert's first are in flight (the pipeline does not drain between
+``moe_grouped_fwd``, a ``(row_tile, h)`` scratch under ``moe_sorted_fwd``
+(an item's terms, added to its tokens' rows of ``y`` at its last step).
+The work list is scalar-prefetched and the index maps read it, so while an
+expert's last tiles are used the next expert's first are in flight (the
+pipeline does not drain between
 experts); consecutive items of one expert name the same weight blocks, so
 where a step holds the whole inner width (SDAR's 768) its second row tile
 costs MXU time and no second stream; an item past ``n_real`` points at the
@@ -235,33 +247,6 @@ def _grouped_kernel(eid_ref, n_ref, x_ref, wt_ref, *refs):
             o_ref[...] += term
 
 
-def _sorted_kernel(eid_ref, tile_ref, lo_ref, hi_ref, n_ref, x_ref, wt_ref,
-                   *refs):
-    from jax.experimental import pallas as pl
-
-    *w_refs, o_ref = refs
-    i, s = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(i < n_ref[0])
-    def _():
-        out, w = _item_product(x_ref, wt_ref, w_refs)
-        rt, e, tile = x_ref.shape[0], eid_ref[i], tile_ref[i]
-        row = tile * rt + jax.lax.broadcasted_iota(jnp.int32, (rt, 1), 0)
-        # a tile that straddles experts is visited once an expert: the
-        # others' rows, and what lies past the last real row, add nothing
-        term = jnp.where((row >= lo_ref[e]) & (row < hi_ref[e]), out * w, 0.0)
-        opens = (s == 0) & ((i == 0)
-                            | (tile_ref[jnp.maximum(i - 1, 0)] != tile))
-
-        @pl.when(opens)
-        def _():
-            o_ref[...] = term
-
-        @pl.when(jnp.logical_not(opens))
-        def _():
-            o_ref[...] += term
-
-
 def inner_tile(h: int, inner: int, itemsize: int, matrices: int = 3,
                step_bytes: int | None = None) -> int:
     """The largest multiple of ``LANE`` that divides ``inner`` and keeps a
@@ -296,9 +281,9 @@ def _item_map(i, s, eid_ref, n_ref):
     return jnp.minimum(i, jnp.maximum(n_ref[0] - 1, 0))
 
 
-def _tile_map(i, s, eid_ref, tile_ref, lo_ref, hi_ref, n_ref):
-    """The row tile an item of ``moe_sorted_fwd`` reads and writes (the
-    list repeats its last real item past the end)."""
+def _tile_map(i, s, eid_ref, tile_ref, *lists):
+    """The row tile an item of ``moe_sorted_fwd`` reads (the list repeats
+    its last real item past the end)."""
     return tile_ref[i], 0
 
 
@@ -461,18 +446,81 @@ def sorted_work_list(lo, hi, tiles: int, row_tile: int):
             n.astype(jnp.int32).reshape(1))
 
 
-def pallas_sorted_terms(xs, wt, lo, hi, wg, wu, wd, *, row_tile, tile=None,
-                        interpret=None):
+def _sorted_kernel(eid_ref, tile_ref, lo_ref, hi_ref, tok_ref, n_ref, x_ref,
+                   wt_ref, *refs):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    # ``y`` comes in and goes out as one HBM array (aliased)
+    *w_refs, _, y_ref, acc_ref, rows_ref, sem = refs
+    i, s = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(i < n_ref[0])
+    def _():
+        out, w = _item_product(x_ref, wt_ref, w_refs)
+
+        @pl.when(s == 0)
+        def _():
+            acc_ref[...] = out * w
+
+        @pl.when(s != 0)
+        def _():
+            acc_ref[...] += out * w
+
+        @pl.when(s == pl.num_programs(1) - 1)
+        def _():
+            # the item's own rows of the tile — a tile that straddles
+            # experts is visited once an expert —: one expert's, so their
+            # tokens are distinct, and what lies past the last real row is
+            # nobody's
+            rt, e = x_ref.shape[0], eid_ref[i]
+            base = tile_ref[i] * rt
+            r0 = jnp.maximum(lo_ref[e] - base, 0)
+            r1 = jnp.minimum(hi_ref[e] - base, rt)
+
+            def row_copy(r, fetch):
+                at = y_ref.at[tok_ref[base + r]], rows_ref.at[r]
+                return pltpu.make_async_copy(*(at if fetch else at[::-1]),
+                                             sem)
+
+            def each(do):
+                jax.lax.fori_loop(r0, r1, lambda r, _: do(r), None)
+
+            # every read in flight together, every write waited for before
+            # the next item starts.  (The reads started at the item's FIRST
+            # step, to fly while it multiplies, made the kernel slower —
+            # 9.0 -> 10.0 ms a layer at dots3's 16,384 bucket, 4.65 -> 5.2
+            # at MiMo's: they queue ahead of the next weight tiles; PERF.md
+            # section 6, PR 59.)
+            each(lambda r: row_copy(r, True).start())
+            each(lambda r: row_copy(r, True).wait())
+            rows_ref[...] += acc_ref[...].reshape(rows_ref.shape)
+            each(lambda r: row_copy(r, False).start())
+            each(lambda r: row_copy(r, False).wait())
+
+
+def pallas_sorted_add(y, xs, tok, wt, lo, hi, wg, wu, wd, *, row_tile,
+                      tile=None, interpret=None):
     """The kernel lowering for rows SORTED by expert and not padded: ``xs
     (N, h)``, ``N`` a multiple of ``row_tile``, expert ``e``'s rows at
     ``[lo[e], hi[e])`` (``lo``, ``hi (held,)``, ascending and back to back;
     an expert without rows has ``lo == hi``), ``wt (N,)`` each row's
-    routing weight.  Returns ``(N, h)`` float32: each row through its
-    expert, weighted — the rows of every row tile that holds an expert's
-    row (zero where the row is nobody's); a tile no expert has a row in is
-    NOT WRITTEN, and the work ends with the last expert's last tile: the
-    grid steps past it fetch nothing and multiply nothing.  ``wg`` None
-    for experts of two matrices."""
+    routing weight, ``tok (N,)`` the token each row is of.  Returns ``y (T,
+    h / LANE, LANE)`` float32 — token ``t``'s row as ``h / LANE`` lane
+    tiles, so that a row is one run of HBM: ``y.reshape(T, h)`` is the
+    layer's — with each row ``r`` of some ``[lo[e], hi[e])`` through its
+    expert, weighted, ADDED to row ``tok[r]``.  ``y`` stays in HBM and is
+    the result (aliased): at an item's last step its own live rows of ``y``
+    are fetched by row DMAs, all in flight together, the item's terms are
+    added and the rows written back, all waited for before the next item
+    starts.  An item is one expert's rows of one tile, so its tokens are
+    distinct, and items run in order: a token that two experts share is
+    added to twice in sequence, in ascending order of expert.  A row
+    outside every ``[lo, hi)`` moves nothing, whatever ``xs``, ``wt`` and
+    ``tok`` hold there, a token no live row names keeps its bits, and the
+    work ends with the last expert's last tile: the grid steps past it
+    fetch nothing and multiply nothing.  ``wg`` None for experts of two
+    matrices."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -489,28 +537,37 @@ def pallas_sorted_terms(xs, wt, lo, hi, wg, wu, wd, *, row_tile, tile=None,
     eid, tiles, n_real = sorted_work_list(lo, hi, n // row_tile, row_tile)
 
     vmem = (2 * len(weights) * h * ik * itemsize    # the streamed tiles
-            + 2 * row_tile * h * (itemsize + 4)     # rows in, terms out
-            + 2 * row_tile * h * 4                  # a product, a term
+            + 2 * row_tile * h * itemsize           # rows in
+            + 4 * row_tile * h * 4      # the terms, y's rows, a product, a sum
             + 3 * row_tile * ik * 4 + 2 * row_tile * LANE * 4)
+    # the lists the index maps read (the count last), the blocked operands
+    # and ``y``, which the kernel adds to where it lies
+    operands = (eid, tiles, lo, hi, tok.astype(jnp.int32), n_real, xs,
+                wt.astype(F32)[:, None], *weights, y)
     return pl.pallas_call(
         _sorted_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=6,
             grid=(eid.shape[0], steps),
             in_specs=[
                 pl.BlockSpec((row_tile, h), _tile_map),
                 pl.BlockSpec((row_tile, 1), _tile_map),
                 *_weight_specs(h, ik, steps, wg is not None),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((row_tile, h), _tile_map),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((row_tile, h), F32),
+                            pltpu.VMEM((row_tile, h // LANE, LANE), F32),
+                            pltpu.SemaphoreType.DMA(())],
         ),
-        out_shape=jax.ShapeDtypeStruct((n, h), F32),
+        out_shape=jax.ShapeDtypeStruct(y.shape, F32),
+        input_output_aliases={len(operands) - 1: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=vmem + (8 << 20)),
         interpret=interpret,
         name="moe_sorted_fwd",
-    )(eid, tiles, lo, hi, n_real, xs, wt.astype(F32)[:, None], *weights)
+    )(*operands)
 
 
 class Tiles(NamedTuple):

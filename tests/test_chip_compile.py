@@ -581,10 +581,13 @@ def test_moe_grouped_kernel_compiles_for_v5e(shape, no_persistent_cache,
     (32768, 8, 128, 2048, 1024, 16, True), (8192, 4, 32, 2048, 1792, 32, True),
     (4096, 22, 512, 1024, 2688, 128, False),
     (2048, 6, 160, 5120, 1536, 40, True), (2048, 8, 128, 2048, 768, 128, True),
-    (4096, 12, 768, 6144, 2048, 16, True)],
+    (4096, 12, 768, 6144, 2048, 16, True),
+    (16384, 8, 256, 5120, 1536, 32, True),
+    (16384, 8, 256, 4096, 2048, 16, True)],
     ids=["trinity-admit-4x8192", "lfm2-admit-8x1024",
          "nemotron3-admit-4x1024-two-matrix", "dsv2-admit-4x512",
-         "sdar-admit-4x512", "longcat-admit-2x2048"])
+         "sdar-admit-4x512", "longcat-admit-2x2048", "dots3-admit-1x16384",
+         "mimo-admit-1x16384"])
 def test_moe_sorted_kernel_compiles_for_v5e(shape, no_persistent_cache,
                                             monkeypatch, tokens, k, router, h,
                                             inner, held, gated):
@@ -593,7 +596,10 @@ def test_moe_sorted_kernel_compiles_for_v5e(shape, no_persistent_cache,
     expert cell, over one window of ``experts.sorted_window`` rows with the
     tiles ``fitted_tile`` gives the chip path: row tiles of 128, Trinity's
     and LFM2's whole expert a step (12.6 and 22 MB, double-buffered),
-    DeepSeek-V2's and LongCat's in two and four steps, all under the
+    DeepSeek-V2's, dots3's and LongCat's in two and four steps, beside the
+    item's terms and its tokens' rows of ``y`` (two float32 ``(128, h)``
+    scratches; ``y`` itself in HBM, a row a DMA, the window's token numbers
+    prefetched: 64 KB of scalars at Trinity's 16,384 rows), all under the
     ``vmem_limit_bytes`` the call states."""
     import types
 
@@ -610,14 +616,20 @@ def test_moe_sorted_kernel_compiles_for_v5e(shape, no_persistent_cache,
         if w is not None})
     assert tiles.sorted
     rows = experts.sorted_window(c, tokens, k, tiles.rows)
-    fn = jax.jit(lambda xs, wt, lo, hi, wg, wu, wd: md.pallas_sorted_terms(
-        xs, wt, lo, hi, wg, wu, wd, row_tile=tiles.rows, tile=tiles.inner,
-        interpret=False))
+    fn = jax.jit(lambda y, xs, tok, wt, lo, hi, wg, wu, wd:
+                 md.pallas_sorted_add(
+                     y, xs, tok, wt, lo, hi, wg, wu, wd, row_tile=tiles.rows,
+                     tile=tiles.inner, interpret=False), donate_argnums=0)
     compiled = fn.lower(
-        shape((rows, h), bf16), shape((rows,), jnp.float32),
-        shape((held,), jnp.int32), shape((held,), jnp.int32), wg, wu,
-        wd).compile()
+        shape((tokens, h // md.LANE, md.LANE), jnp.float32),
+        shape((rows, h), bf16), shape((rows,), jnp.int32),
+        shape((rows,), jnp.float32), shape((held,), jnp.int32),
+        shape((held,), jnp.int32), wg, wu, wd).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # ``y`` is added to where it lies: no second copy of it
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == tokens * h * 4, m
+    assert m.temp_size_in_bytes < 8 << 20, m
 
 
 # ---- the families' whole programs at published widths ----

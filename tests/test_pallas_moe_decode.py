@@ -11,7 +11,9 @@ experts, on the cases that break grouped kernels; the choice of lowering
 from backend, mesh and shape at each cell's decode, block-step and
 admission shapes, as ``status()`` shows it; and the counters
 ``moe.expert_passes``, ``moe.rows_computed`` and
-``moe.prefill_rows_computed``."""
+``moe.prefill_rows_computed``, and the terms' way to their tokens,
+``moe_sorted_fwd``'s own row DMAs, noted under ``"moe_combine"`` and
+counted by ``moe.prefill_rows_combined``."""
 
 import dataclasses
 import functools
@@ -67,7 +69,7 @@ def _kernel_path(monkeypatch, lane=16, step_bytes=None, row_tile=None,
     if sorted_tile:
         monkeypatch.setattr(md, "SORTED_ROW_TILE", sorted_tile)
     for name in ("pallas_expert_terms", "pallas_grouped_terms",
-                 "pallas_sorted_terms"):
+                 "pallas_sorted_add"):
         monkeypatch.setattr(
             md, name, lambda *a, _f=getattr(md, name), **kw: _f(
                 *a, **{**kw, "interpret": True}))
@@ -505,6 +507,28 @@ SORTED = [("sdar", case) for case in SORTED_CASES] + [
     ("dsv2", "a-window-overflows-and-runs-again")]
 
 
+def _sorted_terms(xs, wt, lo, hi, wg, wu, wd, *, row_tile, tile=None):
+    """``moe_sorted_fwd``'s terms in sorted order: every row its own token
+    of a ``y`` of zeros, so row ``r`` of the result is what the kernel adds
+    for row ``r`` (zero where it adds nothing)."""
+    n, h = xs.shape
+    return md.pallas_sorted_add(
+        jnp.zeros((n, h // md.LANE, md.LANE), F32), xs, jnp.arange(n), wt,
+        lo, hi, wg, wu, wd, row_tile=row_tile, tile=tile,
+        interpret=True).reshape(n, h)
+
+
+def _once_a_token(ids, c):
+    """A token names an expert once, as a router's top-k does (what
+    ``moe_sorted_fwd`` is given: an item's rows are one expert's, and it
+    fetches them together): where a rewritten row names one twice the
+    later ones go to an expert no chip holds."""
+    k = ids.shape[-1]
+    again = (ids[:, :, None] == ids[:, None, :]) & (
+        jnp.arange(k)[:, None] > jnp.arange(k)[None, :])
+    return jnp.where(again.any(-1), c.router_width, ids)
+
+
 def _experts_in_the_fullest_tile(load, rt):
     """How many experts have a row in one row tile of the sorted rows, at
     most."""
@@ -523,7 +547,7 @@ def test_sorted_kernel_equals_the_xla_form_the_oracle_and_the_dense_loop(
     u = jax.random.normal(jax.random.key(23), (t, c.hidden_size))
     ids, w = _routed(module, c, layer, u)
     if rewrite is not None:
-        ids = rewrite(ids, c)
+        ids = _once_a_token(rewrite(ids, c), c)
     live = live_of(t)
     # two inner steps an item
     inner = layer["experts"]["wg"].shape[-1]
@@ -554,7 +578,7 @@ def test_sorted_kernel_equals_the_xla_form_the_oracle_and_the_dense_loop(
     if case == "rows-end-on-a-tiles-edge":
         assert load[1] == 2 * ROW_TILE
     if case == "all-to-one-expert":
-        assert load[1] == int(live.sum()) * c.moe_topk == load.sum()
+        assert load[1] == int(live.sum()) == load.sum()
     if capacity:                                 # more than two windows
         cap = experts.sorted_window(c, t, c.moe_topk, rt, capacity)
         assert cap == -(-capacity // rt) * rt and load.sum() > 2 * cap
@@ -617,9 +641,9 @@ def test_sorted_junk_past_the_live_rows_changes_no_bit(monkeypatch):
     hi = jnp.cumsum(load)
     wt = jnp.full(40, 0.5)
     rows = jnp.arange(40)[:, None]
-    clean, dirty = (md.pallas_sorted_terms(
+    clean, dirty = (_sorted_terms(
         jnp.where(rows < 19, xs, fill), wt, hi - load, hi, e["wg"], e["wu"],
-        e["wd"], row_tile=8, interpret=True) for fill in (0.0, jnp.nan))
+        e["wd"], row_tile=8) for fill in (0.0, jnp.nan))
     np.testing.assert_array_equal(np.asarray(clean)[:24],
                                   np.asarray(dirty)[:24])
     assert not np.asarray(clean)[19:24].any()
@@ -653,7 +677,8 @@ def test_sorted_work_list_by_hand():
 @pytest.mark.parametrize("gated", [True, False],
                          ids=["three-matrices", "two-matrices"])
 def test_sorted_terms_by_hand_over_a_short_list(gated):
-    """``pallas_sorted_terms``'s own contract: row ``r`` of the sorted
+    """``pallas_sorted_add``'s own contract, a row a token: row ``r`` of the
+    sorted
     rows through the expert whose ``[lo, hi)`` holds it, weighted — both
     expert forms, in one or in several inner steps; an expert without rows
     is not read, and the rows of a visited tile that are nobody's are
@@ -678,25 +703,143 @@ def test_sorted_terms_by_hand_over_a_short_list(gated):
         by_hand = jnp.stack([wt[r] * expert(xs[r], e)
                              for r, e in enumerate(owner)])
         for tile in (128, 384, None):
-            got = md.pallas_sorted_terms(
+            got = _sorted_terms(
                 xs, wt, hi - load, hi, wg if gated else None, wu, wd,
-                row_tile=rt, tile=tile, interpret=True)
+                row_tile=rt, tile=tile)
             assert got.shape == (5 * rt, h) and got.dtype == F32
             assert float(jnp.abs(got[:27] - by_hand).max()) < TOL["float32"]
             assert not np.asarray(got[27:32]).any()
         # a NaN expert without rows is not read
-        poisoned = md.pallas_sorted_terms(
+        poisoned = _sorted_terms(
             xs, wt, hi - load, hi, wg.at[1].set(jnp.nan) if gated else None,
-            wu.at[4].set(jnp.nan), wd, row_tile=rt, interpret=True)
+            wu.at[4].set(jnp.nan), wd, row_tile=rt)
     np.testing.assert_array_equal(np.asarray(poisoned[:32]),
                                   np.asarray(got[:32]))
     assert float(jnp.abs(by_hand).max()) > 0.1
     with pytest.raises(ValueError, match="does not divide"):
-        md.pallas_sorted_terms(xs, wt, hi - load, hi, wg, wu, wd,
-                               row_tile=rt, tile=256, interpret=True)
+        _sorted_terms(xs, wt, hi - load, hi, wg, wu, wd, row_tile=rt,
+                      tile=256)
     with pytest.raises(ValueError, match="whole tiles of"):
-        md.pallas_sorted_terms(xs[:-1], wt[:-1], hi - load, hi, wg, wu, wd,
-                               row_tile=rt, interpret=True)
+        _sorted_terms(xs[:-1], wt[:-1], hi - load, hi, wg, wu, wd,
+                      row_tile=rt)
+
+
+# ---- the terms reach their tokens: the kernel adds them to ``y`` itself ----
+
+def _combine_case(gated, h=256, inner=384, held=6, rt=8, tokens=24):
+    """27 sorted rows in tiles of 8 over 24 tokens: experts 0, 2, 3 and 5
+    hold rows [0, 3), [3, 15), [15, 22) and [22, 27) — tile 0 is two
+    experts', tile 1 two, tile 2 three —, each expert's tokens distinct and
+    ascending, tokens 1 and 2 held by experts 0 and 2 IN ONE TILE, token 9
+    by experts 2 and 3 in tile 1 and 2; past row 27 the list names tokens
+    that are really there."""
+    ks = jax.random.split(jax.random.key(59), 7)
+    load = jnp.array([3, 0, 12, 7, 0, 5])
+    tok = jnp.concatenate([
+        jnp.array([0, 1, 2]), jnp.arange(1, 13),
+        jnp.array([9, *range(14, 20)]),
+        jnp.arange(19, 24), jnp.array([1, 2, 9, 0, 23] + [5] * 8)])
+    operands = dict(
+        xs=jax.random.normal(ks[0], (5 * rt, h)),
+        wt=jax.random.uniform(ks[4], (5 * rt,)) + 0.1,
+        wg=(jax.random.normal(ks[1], (held, h, inner)) * h ** -0.5
+            if gated else None),
+        wu=jax.random.normal(ks[2], (held, h, inner)) * h ** -0.5,
+        wd=jax.random.normal(ks[3], (held, inner, h)) * inner ** -0.5)
+    y = jax.random.normal(ks[5], (tokens, h))
+    return y, tok.astype(jnp.int32), load, operands
+
+
+def _combined(y, tok, load, operands, rt=8, tile=None):
+    hi = jnp.cumsum(load)
+    t, h = y.shape
+    return md.pallas_sorted_add(
+        y.reshape(t, h // md.LANE, md.LANE), operands["xs"], tok,
+        operands["wt"],
+        hi - load, hi, operands["wg"], operands["wu"], operands["wd"],
+        row_tile=rt, tile=tile, interpret=True).reshape(t, h)
+
+
+def _scattered(y, tok, load, operands):
+    """The parent's combine, by hand: every sorted row through the expert
+    whose rows hold it, weighted, and XLA's scatter-add of the live ones."""
+    o = operands
+    owner = np.repeat(np.arange(load.shape[0]), np.asarray(load))
+
+    def expert(x, e):
+        if o["wg"] is None:
+            return jnp.square(jax.nn.relu(x @ o["wu"][e])) @ o["wd"][e]
+        return (jax.nn.silu(x @ o["wg"][e]) * (x @ o["wu"][e])) @ o["wd"][e]
+
+    terms = jnp.stack([o["wt"][r] * expert(o["xs"][r], e)
+                       for r, e in enumerate(owner)])
+    return y.at[tok[:len(owner)]].add(terms)
+
+
+@pytest.mark.parametrize("tile", [128, None], ids=["three-steps", "one-step"])
+@pytest.mark.parametrize("gated", [True, False],
+                         ids=["three-matrices", "two-matrices"])
+def test_combine_equals_the_parents_scatter_add(gated, tile):
+    """``pallas_sorted_add`` against the scatter-add it replaces, both
+    expert forms, an expert's inner width in one step and in three: a
+    token that two experts hold in one tile is added to twice, one that no
+    live row names keeps its bits, and so does every token when no expert
+    has a row."""
+    y, tok, load, operands = _combine_case(gated)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(_scattered(y, tok, load, operands))
+        got = np.asarray(_combined(y, tok, load, operands, tile=tile))
+        idle = np.asarray(_combined(y, tok, jnp.zeros_like(load), operands,
+                                    tile=tile))
+    assert got.shape == y.shape and got.dtype == np.float32
+    assert float(np.abs(got - want).max()) < TOL["float32"]
+    moved = np.abs(want - np.asarray(y)).max(axis=-1)
+    assert moved[[1, 2, 9]].min() > 0.05           # held twice
+    np.testing.assert_array_equal(got[13], np.asarray(y)[13])   # by nobody
+    assert moved[13] == 0
+    np.testing.assert_array_equal(idle, np.asarray(y))
+
+
+def test_combine_reads_nothing_past_an_experts_rows():
+    """What lies outside every ``[lo, hi)`` — the rest of the last visited
+    tile, the tiles after it, an expert without rows — moves no bit of
+    ``y``: NaN rows, NaN weights and a NaN expert there, and token numbers
+    that point at real rows."""
+    y, tok, load, operands = _combine_case(True)
+    rows = jnp.arange(tok.shape[0])
+    dirty = dict(
+        operands,
+        xs=jnp.where(rows[:, None] < 27, operands["xs"], jnp.nan),
+        wt=jnp.where(rows < 27, operands["wt"], jnp.nan),
+        wg=operands["wg"].at[1].set(jnp.nan),
+        wu=operands["wu"].at[4].set(jnp.nan))
+    with jax.default_matmul_precision("highest"):
+        clean, again = (np.asarray(_combined(y, tok, load, o))
+                        for o in (operands, dirty))
+    assert np.isfinite(again).all()
+    np.testing.assert_array_equal(clean, again)
+
+
+def test_combine_carries_y_from_window_to_window(monkeypatch):
+    """Two windows of one admission: the second call adds to what the
+    first left (``y`` is the kernel's own operand, aliased), as the
+    parent's loop carried its scatter-add — ``held_experts`` whole, under a
+    window of one tile and under the rule's."""
+    module, c, layer, _ = _layer("sdar")
+    u = jax.random.normal(jax.random.key(31), (96, c.hidden_size))
+    ids, w = _routed(module, c, layer, u)
+    live = jnp.arange(96) % 3 != 0
+    _kernel_path(monkeypatch, lane=8, most=(16, 64), sorted_tile=8)
+    held = jax.jit(lambda cap: experts.held_experts(
+        u, ids, w, live, layer["experts"], c, cap)[0], static_argnums=0)
+    with jax.default_matmul_precision("highest"), \
+            record_lowerings() as chosen:
+        one, many = np.asarray(held(None)), np.asarray(held(8))
+    assert chosen["moe_combine"] == {"pallas_rows"}
+    assert int(live.sum()) * c.moe_topk > 4 * 8            # many windows
+    assert float(np.abs(one).max()) > 0.05
+    assert float(np.abs(one - many).max()) < TOL["float32"]
+    assert not many[~np.asarray(live)].any()
 
 
 # ---- which lowering, and where it is stated --------------------------------
@@ -933,8 +1076,12 @@ def test_expert_passes_by_hand(monkeypatch):
 
     def counted(load, tokens=u):
         got = experts.kernel_counters(tokens, layer["experts"], load, c)
-        assert sorted(got) == ["moe.expert_passes", "moe.rows_computed"]
+        assert sorted(got) == ["moe.expert_passes",
+                               "moe.prefill_rows_combined",
+                               "moe.rows_computed"]
         assert all(v.dtype == F32 and v.shape == () for v in got.values())
+        # a decode step's few tokens: no combine around the kernel
+        assert float(got["moe.prefill_rows_combined"]) == 0
         return float(got["moe.expert_passes"]), float(
             got["moe.rows_computed"])
 
@@ -1009,6 +1156,10 @@ def test_sorted_counters_by_hand(monkeypatch, steps, passes):
     assert experts.sorted_window(c, t, 1, 8) == 24
     assert float(got["moe.expert_passes"]) == passes
     assert float(got["moe.rows_computed"]) == (2 + 2 + 1 + 1 + 2) * 8
+    # the rows of ``y`` the kernel fetched and wrote back: the live
+    # assignments (the scatter-add it replaced moved both windows' 48)
+    assert float(got["moe.prefill_rows_combined"]) == 35
+    assert chosen["moe_combine"] == {"pallas_rows"}
     want = _dense(u, ids, jnp.ones((t, 1), F32), live, layer, c)
     assert float(jnp.abs(y - want).max()) < 10 * TOL["float32"]
 
@@ -1020,7 +1171,9 @@ def test_a_prefill_counts_the_rows_its_lowering_computed(monkeypatch):
     program cannot know; under ``moe_sorted_fwd`` whole row tiles, at
     least the assignments and, a layer, less than a tile more an expert
     and a straddled tile more an expert; the decode counters stay 0 in a
-    prefill."""
+    prefill.  And ``moe.prefill_rows_combined`` beside them: the rows of
+    ``y`` the kernel moved, one a held assignment (0 under the XLA
+    form)."""
     module, config, make, _ = FAMILIES["trinity"]
     params, policy = make(config)
     assert "moe.prefill_rows_computed" in experts.STAT_KEYS
@@ -1034,6 +1187,8 @@ def test_a_prefill_counts_the_rows_its_lowering_computed(monkeypatch):
     before = prefill()
     assert float(before["moe.prefill_held"]) > 0
     assert float(before["moe.prefill_rows_computed"]) == 0
+    assert "moe.prefill_rows_combined" in experts.STAT_KEYS
+    assert float(before["moe.prefill_rows_combined"]) == 0
     rt = 8
     _kernel_path(monkeypatch, lane=8, most=(4, 16), sorted_tile=rt)
     with record_lowerings() as chosen:
@@ -1045,6 +1200,8 @@ def test_a_prefill_counts_the_rows_its_lowering_computed(monkeypatch):
     expert_layers = config.num_hidden_layers - config.num_dense_layers
     assert rows % rt == 0
     assert held <= rows < held + expert_layers * config.experts_held * 2 * rt
+    assert float(after["moe.prefill_rows_combined"]) == held
+    assert chosen["moe_combine"] == {"pallas_rows"}
     assert float(after["moe.rows_computed"]) == 0
     assert float(after["moe.expert_passes"]) == 0
 
@@ -1157,7 +1314,9 @@ def test_cpu_notes_xla_and_the_engine_states_it_per_program():
     (done,) = eng.run_until_idle(max_chunks=10)
     assert done.uid == 0
     assert eng.status()["moe_experts"] == {"chunk": "xla", "admit": "xla"}
+    assert eng.status()["moe_combine"] is None      # no program sorts
     snap = get_registry().snapshot()
+    assert snap["moe.prefill_rows_combined"]["value"] == 0
     assert snap["moe.experts_touched"]["value"] > 0
     assert snap["moe.expert_passes"]["value"] == 0
     assert snap["moe.rows_computed"]["value"] == 0
@@ -1206,3 +1365,8 @@ def test_the_admission_program_names_the_sorted_kernel(monkeypatch):
     # 20 real tokens, 3 of 16 experts each, all held, two expert layers
     assert held == 20 * 3 * 2
     assert rows % 8 == 0 and held <= rows < held + 2 * 16 * 2 * 8
+    # and how the admission's terms reached their tokens: the kernel's row
+    # DMAs, a row of ``y`` a held assignment
+    assert eng.status()["moe_combine"] == {"admit": "pallas_rows"}
+    assert eng.lowerings["moe_combine"] == "pallas_rows"
+    assert snap["moe.prefill_rows_combined"]["value"] == held
