@@ -182,7 +182,10 @@ class ProGenFamily:
 # shapes: a short ring under a learned sink beside grown keys, the keys
 # wider than the values; the ninth, dots3, a share over LATENT attention of
 # two shapes: rows an indexer thins to ``index_topk`` beside a ring of
-# latents, a gate a head on both
+# latents, a gate a head on both; the tenth, GLM-5.2, a share over latent
+# attention of ONE shape under a selection in every layer, which one layer in
+# four computes (an indexer's second cache leaf) and the next three borrow
+# (the plain leaf)
 _DRIVER_FAMILIES = (
     ("progen_tpu.models.longcat", "LongCatConfig", "LongCatFamily"),
     ("progen_tpu.models.deepseek_v2", "DeepSeekV2Config", "DeepSeekV2Family"),
@@ -194,6 +197,7 @@ _DRIVER_FAMILIES = (
     ("progen_tpu.models.nemotron_h", "NemotronHConfig", "NemotronHFamily"),
     ("progen_tpu.models.mimo_v2", "MiMoV2Config", "MiMoV2Family"),
     ("progen_tpu.models.dots3", "Dots3Config", "Dots3Family"),
+    ("progen_tpu.models.glm_dsa", "GLMDSAConfig", "GLMDSAFamily"),
 )
 
 

@@ -39,6 +39,16 @@ with
 ``decode(x (S, h), pos (S,), cache, weights)``
     ``(out (S, h), cache)``: one token a row at position ``pos``, written
     into the cache (or folded into the state) and mixed up to it
+``selection``
+    only of a block that attends under a SELECTION of keys which one block
+    computes and the blocks after it reuse (``models/latent.py``'s option,
+    ``models/glm_dsa.py``'s layers): ``"own"`` or ``"borrow"``.  Such a
+    block's ``prefill`` and ``decode`` take one more argument, what the last
+    owner before it HANDED ON (``None`` before the first), and return it as
+    a third result: an owner hands on its own, a borrower what it was
+    given.  The driver carries it from block to block inside the one
+    program and never looks inside; a block without the attribute is called
+    as above
 ``decode_block(x (S, B or 2B, h), pos0 (S,), cache, weights, commit (S,), queries=None)``
     only of a block whose family generates B tokens a row a step
     (:func:`block_step`; ``models/kv.py``'s grown keys have it): ``(out,
@@ -155,6 +165,17 @@ def rope(x, positions, inv_freq):
                            axis=-1).astype(x.dtype)
 
 
+def rope_pairs(x, positions, inv_freq):
+    """Rotation of the INTERLEAVED pairs ``(2i, 2i + 1)`` of ``x``'s last
+    axis (``rope_interleave``), arguments as :func:`rope`'s.  The result's
+    columns are in the program's own order — the pairs' first members, then
+    their second: every product of two vectors rotated here is the product
+    of the same two in the published order, and nothing else reads a
+    rotated column."""
+    return rope(jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1),
+                positions, inv_freq)
+
+
 def mm(x, w):
     return jnp.dot(x, w.astype(x.dtype))
 
@@ -208,10 +229,15 @@ def prefill(stack, blocks, params, tokens, lengths, config, policy: Policy,
     r, n = tokens.shape
     live = (jnp.arange(n)[None, :] < lengths[:, None]).reshape(-1)
     rows = {}
+    handed = [None]     # a selection on its way from its owner to borrowers
 
     def attend(x, name, p):
-        out, rows[name] = blocks[name].prefill(x.reshape(r, n, -1), p,
-                                               lengths)
+        block, x = blocks[name], x.reshape(r, n, -1)
+        if getattr(block, "selection", None):
+            out, rows[name], handed[0] = block.prefill(x, p, lengths,
+                                                       handed[0])
+        else:
+            out, rows[name] = block.prefill(x, p, lengths)
         return out.reshape(r * n, -1)
 
     x = _embed(params, tokens.reshape(-1), c, dt)
@@ -254,9 +280,15 @@ def decode_step(stack, blocks, attention_stats, params, tok, pos, caches,
     c = config
     dt = policy.compute_dtype
     caches = dict(caches)
+    handed = [None]     # as :func:`prefill`'s
 
     def attend(x, name, p):
-        out, caches[name] = blocks[name].decode(x, pos, caches[name], p)
+        block = blocks[name]
+        if getattr(block, "selection", None):
+            out, caches[name], handed[0] = block.decode(
+                x, pos, caches[name], p, handed[0])
+        else:
+            out, caches[name] = block.decode(x, pos, caches[name], p)
         return out
 
     x = _embed(params, tok, c, dt)

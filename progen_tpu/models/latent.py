@@ -25,8 +25,8 @@ W_kvb[v]``.
 ``p``; a slot at position ``pos`` has ``pos + 1`` rows.  That is the one
 kind every block of LongCat and DeepSeek-V2 is, under ONE shape (the config
 itself).  ``models/dots3.py`` brings two shapes in one stack and asks three
-more things of a block, each an option of its constructor that changes
-nothing where it is not asked for:
+more things of a block, ``models/glm_dsa.py`` a fourth, each an option of
+its constructor that changes nothing where it is not asked for:
 
 * ``window`` — the latent lies in a RING of ``min(window, max_len)`` rows
   (``models/kv.py``'s rule: the token at ``p`` in row ``p % rows``, a slot
@@ -41,7 +41,20 @@ nothing where it is not asked for:
   equations);
 * ``gate`` — one sigmoid a head, ``o_h <- o_h * sigmoid(x W_g)_h``, between
   the core and ``W_o`` (:func:`gate_heads`; ``x`` is the block's normed
-  input).
+  input);
+* ``selection`` — the block attends under a selection that SEVERAL blocks
+  read (``models/driver.py`` carries it from one to the next): ``"own"``,
+  the block has the ``indexer``'s weights and cache leaf, computes the
+  selection once and hands it on — an admission's keep mask ``(R, P, P)``
+  (``ops/dsa.py:prefill_keep``; ``None`` where nothing is dropped), a decode
+  step's ``(rows (S, K), kept (S,))`` —; ``"borrow"``, the block has NO
+  indexer, neither weights nor a second leaf — its cache is the plain
+  latent leaf — and its core reads what the last owner handed on: the same
+  mask, ITS OWN latent rows gathered at the same numbers.
+
+A config may also state ``rope_interleave`` (and ``indexer_rope_interleave``
+for the indexer's side): the rotations then pair the columns ``(2i, 2i +
+1)`` (``driver.rope_pairs``) where they pair ``(i, i + d / 2)`` without it.
 """
 
 from __future__ import annotations
@@ -102,6 +115,12 @@ def init_attn(key, c, dt):
 # ---------------------------------------------------------------------- MLA
 
 
+def _rope_of(c, key: str = "rope_interleave"):
+    """The rotation ``c`` states under ``key``: interleaved pairs or (the
+    default) half-split ones."""
+    return driver.rope_pairs if getattr(c, key, False) else rope
+
+
 def mla_project(x, p, c, positions, latent_query: bool = False):
     """``x (..., n, h)`` at ``positions (..., n)`` -> ``q_nope (..., n, H,
     nope)``, rotated ``q_rope (..., n, H, rope)`` and the cache row
@@ -110,6 +129,7 @@ def mla_project(x, p, c, positions, latent_query: bool = False):
     it)."""
     heads = c.num_attention_heads
     nope, rot = c.qk_nope_head_dim, c.qk_rope_head_dim
+    rope = _rope_of(c)
     with jax.named_scope("mla.project"):
         c_q = rms_norm(mm(x, p["wqa"]), p["q_norm"], c.rms_norm_eps)
         q = mm(c_q, p["wqb"])
@@ -133,9 +153,10 @@ def index_project(x, c_q, p, c, positions):
     query latent as the attention's query does —, ``k^I = LayerNorm(x
     W^I_k) (..., n, d)``, one key for all ``J`` heads and the row the cache
     keeps, the leading ``qk_rope_head_dim`` columns of both rotated
-    (half-split) at the block's own base, and ``w = x W^I_w * J^-1/2 *
-    d^-1/2 (..., n, J)`` in float32."""
+    (half-split, or as ``indexer_rope_interleave`` says) at the block's own
+    base, and ``w = x W^I_w * J^-1/2 * d^-1/2 (..., n, J)`` in float32."""
     heads, d, rot = c.index_n_heads, c.index_head_dim, c.qk_rope_head_dim
+    rope = _rope_of(c, "indexer_rope_interleave")
 
     def rotated(a):
         return jnp.concatenate(
@@ -173,7 +194,7 @@ def _wkvb(p, c, dtype):
 
 
 def mla_prefill(x, p, c, lengths=None, *, window=None, indexer=False,
-                gate=False):
+                gate=False, selection=None, handed=None):
     """Full causal attention over ``x (R, P, h)`` in the NON-absorbed form
     (keys and values expanded from the latent once); the core is
     ``ops/mla_prefill.py``: a flash kernel on the chip at the published
@@ -186,7 +207,13 @@ def mla_prefill(x, p, c, lengths=None, *, window=None, indexer=False,
     ``ops/dsa.py``'s — the selection in XLA, and under it the SAME core
     with the selection as its keep mask where the kernel applies, masked
     blocks in XLA elsewhere — and the rows are ``{"latent": .., "index":
-    (R, P, index_head_dim)}``."""
+    (R, P, index_head_dim)}``.  Under a ``selection`` the core is
+    ``ops/gqa.py``'s over the JOINED heads under the keep mask (its kernel
+    where the joined width and the values' are one lane multiple, GLM-5.2's
+    256; PERF.md section 6, PR 60, has why not ``ops/mla_prefill.py``'s),
+    the mask computed here (``"own"``; the rows as an indexer's) or
+    ``handed`` over (``"borrow"``; the plain rows; ``None``: nothing is
+    dropped), and a third result is the mask for the blocks that follow."""
     r, n, _ = x.shape
     with jax.named_scope("mla.prefill"):
         positions = jnp.broadcast_to(jnp.arange(n), (r, n))
@@ -196,7 +223,17 @@ def mla_prefill(x, p, c, lengths=None, *, window=None, indexer=False,
         k_nope = jnp.einsum("rnl,lhd->rhnd", c_kv, wk)
         v = jnp.einsum("rnl,lhd->rhnd", c_kv, wv)
         rows = latent
-        if indexer:
+        if selection is not None:
+            if selection == "own":
+                q_idx, w, k_idx = index_project(x, c_q, p, c, positions)
+                handed = dsa.prefill_keep(q_idx, w, k_idx, c.index_topk)
+                rows = {"latent": latent, "index": k_idx}
+            with jax.named_scope("attn.sparse" if selection == "own"
+                                 else "dsa.borrow"):
+                q, k = dsa.joined_heads(q_nope, q_rope, k_nope, k_r)
+                o = gqa.prefill_attention(q, k, v, q.shape[-1] ** -0.5,
+                                          lengths=lengths, keep=handed)
+        elif indexer:
             q_idx, w, k_idx = index_project(x, c_q, p, c, positions)
             o = dsa.sparse_prefill_attention(
                 q_nope, q_rope, k_nope, k_r, v, q_idx, w, k_idx,
@@ -211,11 +248,12 @@ def mla_prefill(x, p, c, lengths=None, *, window=None, indexer=False,
             o = prefill_attention(q_nope, q_rope, k_nope, k_r, v, lengths)
         if gate:
             o = gate_heads(o, x, p, c.num_attention_heads)
-        return mm(o, p["wo"]), rows
+        out = mm(o, p["wo"]), rows
+        return out if selection is None else out + (handed,)
 
 
 def mla_decode(x, pos, cache, p, c, *, window=None, indexer=False,
-               gate=False):
+               gate=False, selection=None, handed=None):
     """One token per row in the ABSORBED form: ``x (S, h)`` at ``pos (S,)``
     against ``cache (S, T, latent)``, which gains the row's new entry at
     ``pos`` and is then attended up to it (``ops/mla_decode.py``: one kernel
@@ -224,10 +262,14 @@ def mla_decode(x, pos, cache, p, c, *, window=None, indexer=False,
     are :class:`LatentBlock`'s: under a ``window`` the cache is the ring
     and the core reads the rows the slot has in it; with an ``indexer`` the
     cache is ``{"latent", "index"}``, both written here, and the core reads
-    the rows ``ops/dsa.py`` selects."""
+    the rows ``ops/dsa.py`` selects.  Under a ``selection`` the core reads
+    the rows selected here (``"own"``: an indexer's cache) or ``handed``
+    over (``"borrow"``: the plain leaf), and a third result is ``(rows,
+    kept)`` for the blocks that follow."""
     s = x.shape[0]
     rank = c.kv_lora_rank
     scale = 1.0 / math.sqrt(c.qk_nope_head_dim + c.qk_rope_head_dim)
+    indexer = indexer or selection == "own"
     with jax.named_scope("mla.decode"):
         q_nope, q_rope, row, c_q = mla_project(x[:, None], p, c,
                                                pos[:, None], True)
@@ -244,12 +286,16 @@ def mla_decode(x, pos, cache, p, c, *, window=None, indexer=False,
                                             pos[:, None])
             index = write_rows(index, k_idx[:, 0].astype(index.dtype), pos,
                                axis=0)
-            picked, kept = dsa.select_rows(q_idx[:, 0], w[:, 0], index,
-                                           pos + 1, c.index_topk)
+            handed = dsa.select_rows(q_idx[:, 0], w[:, 0], index, pos + 1,
+                                     c.index_topk)
             with jax.named_scope("attn.sparse"):
-                o_lat = dsa.sparse_decode_attention(q_cat, cache, picked,
-                                                    kept, rank, scale)
+                o_lat = dsa.sparse_decode_attention(q_cat, cache, *handed,
+                                                    rank, scale)
             cache = {"latent": cache, "index": index}
+        elif selection == "borrow":
+            with jax.named_scope("dsa.borrow"):
+                o_lat = dsa.sparse_decode_attention(q_cat, cache, *handed,
+                                                    rank, scale)
         elif window is not None:
             with jax.named_scope("attn.window"):
                 o_lat = decode_attention(
@@ -260,7 +306,8 @@ def mla_decode(x, pos, cache, p, c, *, window=None, indexer=False,
         o = jnp.einsum("shl,lhd->shd", o_lat, wv).reshape(s, -1)
         if gate:
             o = gate_heads(o, x, p, c.num_attention_heads)
-        return mm(o, p["wo"]), cache
+        out = mm(o, p["wo"]), cache
+        return out if selection is None else out + (handed,)
 
 
 # ------------------------------------------- the block, and the driver over it
@@ -276,22 +323,32 @@ def _grown(rows, max_len: int):
 class LatentBlock:
     """An attention block over latent rows of ``config``'s shape
     (``models/driver.py`` says what a block is); the module docstring has
-    the three options."""
+    the four options."""
 
     def __init__(self, config, *, window: int | None = None,
-                 indexer: bool = False, gate: bool = False):
-        if window is not None and indexer:
+                 indexer: bool = False, gate: bool = False,
+                 selection: str | None = None):
+        if selection not in (None, "own", "borrow"):
+            raise ValueError(f"a selection is owned or borrowed, not "
+                             f"{selection!r}")
+        # an owner is a block with an indexer: its weights, its cache leaf
+        indexer = indexer or selection == "own"
+        if (window is not None and (indexer or selection)) or (
+                indexer and selection == "borrow"):
             raise ValueError("an indexer selects among grown rows, not a "
-                             "ring's")
+                             "ring's, and a borrower has none")
         self.config = config
         self.window = window
         self.indexer = indexer
         self.gate = gate
+        self.selection = selection
 
     @property
     def options(self) -> dict:
-        return {"window": self.window, "indexer": self.indexer,
-                "gate": self.gate}
+        out = {"window": self.window, "indexer": self.indexer,
+               "gate": self.gate}
+        return out if self.selection is None else {
+            **out, "selection": self.selection}
 
     def rows(self, max_len: int) -> int:
         """Latent rows a slot's cache has in an engine of ``max_len``."""
@@ -305,8 +362,11 @@ class LatentBlock:
         return {"latent": latent,
                 "index": jnp.zeros((slots, max_len, c.index_head_dim), dtype)}
 
-    def prefill(self, x, p, lengths):
-        return mla_prefill(x, p, self.config, lengths, **self.options)
+    def prefill(self, x, p, lengths, handed=None):
+        """``handed``: under a ``selection`` alone, what the last owner
+        handed on; it comes back as a third result."""
+        return mla_prefill(x, p, self.config, lengths, **self.options,
+                           handed=handed)
 
     def cache_rows(self, rows, lengths, max_len: int):
         if self.indexer:
@@ -321,8 +381,9 @@ class LatentBlock:
         at = jnp.clip(j + size * ((last - j) // size), 0, rows.shape[1] - 1)
         return jnp.take_along_axis(rows, at[..., None], axis=1)
 
-    def decode(self, x, pos, cache, p):
-        return mla_decode(x, pos, cache, p, self.config, **self.options)
+    def decode(self, x, pos, cache, p, handed=None):
+        return mla_decode(x, pos, cache, p, self.config, **self.options,
+                          handed=handed)
 
 
 def attention_stats(config, dt, caches, pos, live) -> dict:

@@ -49,6 +49,17 @@ choice of lowering:
     not, pads included.
 
   :func:`prefill_pairs` says how many pairs either form computes.
+
+**A selection that several layers read** (``models/glm_dsa.py``: one layer
+in four computes it, the three after it BORROW it).  The selection is a
+function apart from the cores: :func:`prefill_keep` gives an admission's as
+the keep mask, whatever core and lowering read it (GLM's layers:
+``ops/gqa.py:prefill_attention`` over the joined heads, its kernel where it
+applies and its blocked XLA form under the same mask elsewhere), and a decode
+step's :func:`select_rows` result goes to as many
+:func:`sparse_decode_attention` calls as there are layers under it, each
+gathering ITS OWN latent rows at the same numbers.  With no borrower
+(dots3) every function traces what it traced before the split.
 """
 
 from __future__ import annotations
@@ -96,6 +107,30 @@ def record_selections():
         _recorder.reset(token)
 
 
+_computed: contextvars.ContextVar = contextvars.ContextVar(
+    "dsa_selections_computed", default=None)
+
+
+@contextlib.contextmanager
+def count_selections():
+    """Tally the selections COMPUTED while tracing inside the block: one
+    entry a call of :func:`select_rows`, one a call of :func:`prefill_keep`
+    that made a mask.  A family whose layers share selections counts who
+    selected from it — what was traced, not what its blocks are labelled."""
+    made: list = []
+    token = _computed.set(made)
+    try:
+        yield made
+    finally:
+        _computed.reset(token)
+
+
+def _note_computed(what: str) -> None:
+    made = _computed.get()
+    if made is not None:
+        made.append(what)
+
+
 def select_rows(q_idx, w, index, counts, top_k: int):
     """One query a slot: ``q_idx (S, J, d)``, ``w (S, J)`` over the first
     ``counts (S,)`` rows of ``index (S, T, d)`` -> ``(rows (S, K) int32,
@@ -112,6 +147,7 @@ def select_rows(q_idx, w, index, counts, top_k: int):
     picked = _recorder.get()
     if picked is not None:
         picked.append((rows, kept))
+    _note_computed("rows")
     return rows, kept
 
 
@@ -164,6 +200,59 @@ def joined_heads(q_nope, q_rope, k_nope, k_r):
     return jnp.concatenate([q_nope, q_rope], axis=-1), k
 
 
+def selected(q_idx, w, k_idx, top_k: int, first, bq: int, end: int):
+    """``(R, bq, end)`` bool: the keys ``0 .. end - 1`` that query rows
+    ``first .. first + bq - 1`` attend (``q_idx (R, P, J, d)``, ``w (R, P,
+    J)``, ``k_idx (R, P, d)``); the selection is computed only where a row
+    can see more than ``top_k`` keys (``end > top_k``: a static fact of the
+    segment)."""
+    r = q_idx.shape[0]
+
+    def rows(x):
+        return jax.lax.dynamic_slice_in_dim(x, first, bq, axis=1)
+
+    gap = first + jnp.arange(bq)[:, None] - jnp.arange(end)[None, :]
+    seen = jnp.broadcast_to(gap >= 0, (r, bq, end))
+    if end > top_k:
+        with jax.named_scope("dsa.index"):
+            scores = jnp.where(seen, index_scores(
+                rows(q_idx), rows(w), k_idx[:, :end]), -jnp.inf)
+        with jax.named_scope("dsa.select"):
+            kth = jax.lax.top_k(scores, top_k)[0][..., -1:]
+            seen = seen & (scores >= kth)
+    return seen
+
+
+def prefill_keep(q_idx, w, k_idx, top_k: int):
+    """An admission's selection as a function of its own: the keep mask
+    ``(R, P, P)`` int8 that ``ops/mla_prefill.py:prefill_attention`` and
+    ``ops/gqa.py:prefill_attention`` take, one byte a pair for all heads,
+    which ONE core or several read (a layer that
+    borrows its selection reads the mask of the layer that computed it);
+    ``None`` where no row can see more than ``top_k`` keys (``P <= top_k``:
+    the causal rule alone)."""
+    r, n = q_idx.shape[:2]
+    if n <= top_k:
+        return None
+    _note_computed("mask")
+    seg, bq = segments(n, top_k)
+    outs = []
+    for start in range(0, n, seg):
+        end = start + seg
+        if end <= top_k:
+            # every visible key: the core's own causal rule suffices
+            outs.append(jnp.ones((r, seg, n), jnp.int8))
+            continue
+        firsts = start + bq * jnp.arange(seg // bq)
+        out = jax.lax.map(
+            lambda f, e=end: selected(q_idx, w, k_idx, top_k, f, bq,
+                                      e).astype(jnp.int8), firsts)
+        # (blocks, R, bq, end) -> (R, seg, P): no key past the span
+        out = out.transpose(1, 0, 2, 3).reshape(r, seg, end)
+        outs.append(jnp.pad(out, ((0, 0), (0, 0), (0, n - end))))
+    return jnp.concatenate(outs, axis=1)
+
+
 def sparse_prefill_attention(q_nope, q_rope, k_nope, k_r, v, q_idx, w, k_idx,
                              top_k: int, lengths=None):
     """Causal latent attention in the expanded form under the indexer's
@@ -175,44 +264,28 @@ def sparse_prefill_attention(q_nope, q_rope, k_nope, k_r, v, q_idx, w, k_idx,
     ``lengths (R,)`` positions of each row (default all): a real position
     sees real keys only (attention is causal); the XLA core computes the
     pads too, the kernel writes zeros past a row's last live tile."""
-    r, n = q_nope.shape[:2]
+    n = q_nope.shape[1]
+    if mla_prefill.prefill_lowering(
+            n, q_nope.shape[-1], q_rope.shape[-1], v.shape[-1],
+            v.dtype) == "pallas":
+        keep = prefill_keep(q_idx, w, k_idx, top_k)
+        with jax.named_scope("attn.sparse"):
+            return mla_prefill.prefill_attention(q_nope, q_rope, k_nope, k_r,
+                                                 v, lengths, keep)
     seg, bq = segments(n, top_k)
-    kernel = mla_prefill.prefill_lowering(
-        n, q_nope.shape[-1], q_rope.shape[-1], v.shape[-1],
-        v.dtype) == "pallas"
-    if not kernel:
-        q, k = joined_heads(q_nope, q_rope, k_nope, k_r)
-        q = q.transpose(0, 2, 1, 3)
-        scale = q.shape[-1] ** -0.5
-
-    def rows(x, start, axis):
-        return jax.lax.dynamic_slice_in_dim(x, start, bq, axis=axis)
-
-    def selected(first, end):
-        """``(R, bq, end)``: the keys ``0 .. end - 1`` that query rows
-        ``first .. first + bq - 1`` attend; the selection is computed only
-        where a row can see more than ``top_k`` keys (``end > top_k``: a
-        static fact of the segment)."""
-        gap = first + jnp.arange(bq)[:, None] - jnp.arange(end)[None, :]
-        seen = jnp.broadcast_to(gap >= 0, (r, bq, end))
-        if end > top_k:
-            with jax.named_scope("dsa.index"):
-                scores = jnp.where(seen, index_scores(
-                    rows(q_idx, first, 1), rows(w, first, 1),
-                    k_idx[:, :end]), -jnp.inf)
-            with jax.named_scope("dsa.select"):
-                kth = jax.lax.top_k(scores, top_k)[0][..., -1:]
-                seen = seen & (scores >= kth)
-        return seen
+    q, k = joined_heads(q_nope, q_rope, k_nope, k_r)
+    q = q.transpose(0, 2, 1, 3)
+    scale = q.shape[-1] ** -0.5
 
     def block(first, end):
-        """The XLA core of one block of query rows over its segment's
-        keys."""
-        seen = selected(first, end)
+        """The XLA core of one block of query rows over its segment's keys,
+        under the selection computed beside it."""
+        seen = selected(q_idx, w, k_idx, top_k, first, bq, end)
         with jax.named_scope("attn.sparse"):
-            logits = jnp.einsum("rhqd,rhkd->rhqk", rows(q, first, 2),
-                                k[:, :, :end],
-                                preferred_element_type=F32) * scale
+            logits = jnp.einsum(
+                "rhqd,rhkd->rhqk",
+                jax.lax.dynamic_slice_in_dim(q, first, bq, axis=2),
+                k[:, :, :end], preferred_element_type=F32) * scale
             # unnormalised probabilities and ONE division after the value
             # product, as ``ops/gqa.py``'s blocked form: every row keeps a
             # key, so the maximum is finite
@@ -223,30 +296,11 @@ def sparse_prefill_attention(q_nope, q_rope, k_nope, k_r, v, q_idx, w, k_idx,
             total = jnp.sum(p, axis=-1).transpose(0, 2, 1)
             return (out / total[..., None]).astype(v.dtype)
 
-    def mask(first, end):
-        return selected(first, end).astype(jnp.int8)
-
-    body = mask if kernel else block
     outs = []
-    for start in range(0, n, seg):
-        end = start + seg
-        if kernel and end <= top_k:
-            # every visible key: the kernel's own causal rule suffices
-            outs.append(jnp.ones((r, seg, n), jnp.int8))
-            continue
-        firsts = start + bq * jnp.arange(seg // bq)
-        out = jax.lax.map(lambda f, e=end: body(f, e), firsts)
-        if kernel:
-            # (blocks, R, bq, end) -> (R, seg, P): no key past the span
-            out = out.transpose(1, 0, 2, 3).reshape(r, seg, end)
-            outs.append(jnp.pad(out, ((0, 0), (0, 0), (0, n - end))))
-        else:
-            # (blocks, R, bq, H, vd) -> (R, seg, H * vd)
-            outs.append(out.transpose(1, 0, 2, 3, 4).reshape(r, seg, -1))
-    if not kernel:
-        return jnp.concatenate(outs, axis=1)
-    with jax.named_scope("attn.sparse"):
-        # one segment that needs no selection (``n <= top_k``): no mask
-        keep = jnp.concatenate(outs, axis=1) if n > top_k else None
-        return mla_prefill.prefill_attention(q_nope, q_rope, k_nope, k_r, v,
-                                             lengths, keep)
+    for end in range(seg, n + 1, seg):
+        firsts = end - seg + bq * jnp.arange(seg // bq)
+        out = jax.lax.map(lambda f, e=end: block(f, e), firsts)
+        # (blocks, R, bq, H, vd) -> (R, seg, H * vd)
+        outs.append(out.transpose(1, 0, 2, 3, 4).reshape(
+            q.shape[0], seg, -1))
+    return jnp.concatenate(outs, axis=1)

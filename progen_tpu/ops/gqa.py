@@ -199,6 +199,25 @@ program it traced before the arguments existed
 ``tests/test_pallas_gqa_decode.py`` holds the decode kernels' jaxpr text at
 one width).  The block mask and the block form of the decode step take
 neither.
+
+**A keep mask** (``models/glm_dsa.py``; PR 60).  The prefill core takes an
+optional ``keep (R, P, P)`` — one byte a pair, the same for every head: ``(t,
+s)`` is attended iff the causal rule allows it AND ``keep[r, t, s]``; every
+real row keeps at least one key it can see.  It is how GLM-5.2's layers
+attend under the indexer's selection (``ops/dsa.py:prefill_keep``), over
+latent attention's heads JOINED to one width (``[nope | rope]`` 256 beside
+values of 256: lane multiples both, where ``ops/mla_prefill.py``'s kernel
+wants a ``nope`` of 128s).  The blocked form ands it into a block's mask; the
+kernel takes it as one more operand in ``(bq, bk)`` int8 tiles on the keys'
+clamped index map (an unvisited tile's mask is not fetched) and one more
+select on every visited tile — a row that keeps no key of its first tiles
+carries ``MASKED`` and is wiped by the first key it does keep, as under a
+window.  Measured against ``mla_prefill_fwd`` taught a 192-wide ``nope``:
+65.8 against 82.2 ms a layer at 1 x 16,384, 18.9 against 23.8 at 1 x 8,192
+(PERF.md section 6, PR 60): ONE contraction over 256 columns is two passes of
+the 128-wide matrix unit where 192 and 64 apart are three.  No window, no
+block mask, no sink and one width beside it; without it every function
+traces the program it traced before the operand existed.
 """
 
 from __future__ import annotations
@@ -229,12 +248,13 @@ MASKED = -0.7 * float(jnp.finfo(F32).max)   # a masked score: finite
 
 
 def _score_block(q, k, v, first_row, first_key, scale, window, block=1,
-                 sink=None):
+                 sink=None, keep=None):
     """``q (R, KV, G, bq, d)`` at rows ``first_row + arange(bq)`` against
     ``k (R, KV, t, d)``, ``v (R, KV, t, dv)`` at positions ``first_key +
     arange(t)`` (a position below 0 is padding): ``(R, KV, G, bq, dv)``.
     ``sink (KV, G, 1, 1)`` float32: one more term of every row's softmax,
-    which takes mass and has no value."""
+    which takes mass and has no value.  ``keep (R, bq, t)``: the pairs of
+    the block that may be attended at all."""
     logits = jnp.einsum("rkgqd,rktd->rkgqt", q, k,
                         preferred_element_type=F32) * scale
     at = first_key + jnp.arange(k.shape[2])[None, :]
@@ -246,6 +266,8 @@ def _score_block(q, k, v, first_row, first_key, scale, window, block=1,
     else:       # causal across blocks of ``block``, every key inside one
         rows = first_row + jnp.arange(q.shape[3])[:, None]
         seen = at // block <= rows // block
+    if keep is not None:
+        seen = seen & (keep != 0)[:, None, None]
     # float32 scores, maximum and sum; the unnormalised probabilities are
     # cast for the value product and the ONE division by the sum comes
     # after it, on (bq, d) numbers and not (bq, t) — the repo's kernels'
@@ -285,15 +307,18 @@ def _blocked_bodies(n: int, window) -> list:
 
 
 def blocked_prefill_attention(q, k, v, scale, window=None, block=1,
-                              sink=None):
+                              sink=None, keep=None):
     """The XLA form: every position of every row computed, the score
     tensor ``(R, KV, G, QUERY_BLOCK, keys)`` float32.  ``block``: the block
     mask's length (a query block holds whole ones, so the keys a block of
     query rows can see end where the causal mask's do).  ``v``'s heads may
     be another width than ``q``'s and ``k``'s (the output is ``H * dv``
-    wide); ``sink (H,)`` adds its term to every row's softmax."""
+    wide); ``sink (H,)`` adds its term to every row's softmax; ``keep (R, P,
+    P)`` thins the causal pairs (no window beside it)."""
     r, n, heads, d = q.shape
     dv = v.shape[-1]
+    if keep is not None and (window is not None or block != 1):
+        raise ValueError("a keep mask thins the causal mask alone")
     if block != 1 and (window is not None or QUERY_BLOCK % block
                        or n % block):
         raise ValueError(
@@ -309,6 +334,8 @@ def blocked_prefill_attention(q, k, v, scale, window=None, block=1,
     q = q.reshape(r, n, kv, heads // kv, d).transpose(0, 2, 3, 1, 4)
     q = jnp.pad(q, ((0, 0),) * 3 + ((0, pad), (0, 0)))
     k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0))) for a in (k, v))
+    if keep is not None:    # a padded row keeps the padded key it stands on
+        keep = jnp.pad(keep, ((0, 0), (0, pad), (0, pad)), constant_values=1)
     bodies = _blocked_bodies(n, window)
     if window is not None:
         back = bodies[0][2] - bq
@@ -327,9 +354,12 @@ def blocked_prefill_attention(q, k, v, scale, window=None, block=1,
         for first, count, span in bodies:
             keys, values = k[:, :, :span], v[:, :, :span]
 
-            def body(i, keys=keys, values=values):
+            def body(i, keys=keys, values=values, span=span):
+                kept = None if keep is None else jax.lax.dynamic_slice_in_dim(
+                    keep[:, :, :span], i * bq, bq, axis=1)
                 return _score_block(_rows(q, i * bq, bq), keys, values,
-                                    i * bq, 0, scale, None, block, sink)
+                                    i * bq, 0, scale, None, block, sink,
+                                    kept)
 
             outs.append(jax.lax.map(body, jnp.arange(first, first + count)))
         out = jnp.concatenate(outs, axis=0)
@@ -370,10 +400,14 @@ def _dot_t(a, b):  # a @ b^T, float32 accumulate
                                preferred_element_type=F32)
 
 
-def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-                  *, scale, bq, bk, window, block=1):
+def _flash_kernel(len_ref, q_ref, k_ref, v_ref, *refs, scale, bq, bk, window,
+                  block=1, masked=False):
+    """``refs``: ``(o, m, l, acc)``, after ``keep (1, bq, bk)`` where the
+    call is ``masked``."""
     from jax.experimental import pallas as pl
 
+    keep_ref = refs[0] if masked else None
+    o_ref, m_ref, l_ref, acc_ref = refs[-4:]
     length = len_ref[pl.program_id(0)]
     qi, ki = pl.program_id(2), pl.program_id(3)
     live, first, last = key_tiles(qi, length, bq, bk, window)
@@ -404,6 +438,8 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
             if not inside_window:
                 seen = seen & (gap < window)
             s = jnp.where(seen, s, MASKED)
+        if keep_ref is not None:
+            s = jnp.where(keep_ref[0] != 0, s, MASKED)
         m_prev = m_ref[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_next)
@@ -452,14 +488,15 @@ def fitted_tile(n: int) -> int:
 
 
 def pallas_prefill_attention(q, k, v, lengths, scale, window=None, *,
-                             block=1, block_q=None, block_k=None,
+                             block=1, keep=None, block_q=None, block_k=None,
                              interpret=None):
     """The kernel lowering.  ``q (R, P, H * d)``, ``k, v (R, KV, P, d)``,
     ``lengths (R,)`` -> ``(R, P, H * d)``.  ``interpret=None`` auto-selects
     the Pallas interpreter off-TPU; ``block_q`` / ``block_k`` default to
     :func:`fitted_tile``; ``block`` is the block mask's length (a power of
     two that divides both tiles, no window beside it; ``lengths`` whole
-    blocks)."""
+    blocks); ``keep (R, P, P)`` int8 thins the causal pairs (no window and
+    no block mask beside it)."""
     n = k.shape[2]
     bq = block_q or fitted_tile(n)
     bk = block_k or fitted_tile(n)
@@ -470,11 +507,13 @@ def pallas_prefill_attention(q, k, v, lengths, scale, window=None, *,
         raise ValueError(
             f"a block mask of {block} takes no window ({window}) and is a "
             f"power of two that divides the tiles ({bq}, {bk})")
+    if keep is not None and (window is not None or block != 1):
+        raise ValueError("a keep mask thins the causal mask alone")
     if interpret is None:
         interpret = not _on_tpu()
     return _flash_call(q, k, v, lengths.astype(jnp.int32), scale=scale,
                        window=window, bq=bq, bk=bk, interpret=interpret,
-                       block=block)
+                       block=block, keep=keep)
 
 
 # jitted so that the blocks of one kind in a model share ONE traced and
@@ -483,7 +522,7 @@ def pallas_prefill_attention(q, k, v, lengths, scale, window=None, *,
 @functools.partial(jax.jit, static_argnames=("scale", "window", "bq", "bk",
                                              "interpret", "block"))
 def _flash_call(q, k, v, lengths, *, scale, window, bq, bk, interpret,
-                block=1):
+                block=1, keep=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -503,9 +542,16 @@ def _flash_call(q, k, v, lengths, *, scale, window, bq, bk, interpret,
         return ri, hi // group, jnp.minimum(
             jnp.where(live, first + ki, last), last), 0
 
+    def keep_map(ri, hi, qi, ki, len_ref):
+        # the queries' and the keys' clamps: an unvisited tile is not fetched
+        return (ri, q_map(ri, hi, qi, ki, len_ref)[1],
+                kv_map(ri, hi, qi, ki, len_ref)[2])
+
+    masks = [] if keep is None else [(keep, pl.BlockSpec((1, bq, bk),
+                                                         keep_map))]
     return pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, bq=bq, bk=bk,
-                          window=window, block=block),
+                          window=window, block=block, masked=bool(masks)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(r, heads, n // bq, key_steps(n, bq, bk, window)),
@@ -513,6 +559,7 @@ def _flash_call(q, k, v, lengths, *, scale, window, bq, bk, interpret,
                 pl.BlockSpec((1, bq, d), q_map),
                 pl.BlockSpec((1, 1, bk, d), kv_map),
                 pl.BlockSpec((1, 1, bk, d), kv_map),
+                *[spec for _, spec in masks],
             ],
             out_specs=pl.BlockSpec(
                 (1, bq, d), lambda ri, hi, qi, ki, len_ref: (ri, qi, hi)),
@@ -526,7 +573,7 @@ def _flash_call(q, k, v, lengths, *, scale, window, bq, bk, interpret,
                                  "arbitrary")),
         interpret=interpret,
         name="gqa_prefill_fwd",
-    )(lengths, q, k, v)
+    )(lengths, q, k, v, *[mask for mask, _ in masks])
 
 
 def _kernel_takes(dtype) -> bool:
@@ -538,12 +585,14 @@ def _kernel_takes(dtype) -> bool:
 
 
 def prefill_lowering(n: int, d: int, dtype, window, block: int = 1, *,
-                     dv: int | None = None, sink: bool = False) -> str:
+                     dv: int | None = None, sink: bool = False,
+                     keep: bool = False) -> str:
     """``"pallas"`` or ``"xla"``: what :func:`prefill_attention` takes for
     ``n`` positions of heads ``d`` wide (values ``dv`` wide: default ``d``)
-    in ``dtype``, with a ``sink`` or without, traced here and now (the
-    module docstring has the rule)."""
+    in ``dtype``, with a ``sink`` or a ``keep`` mask or without, traced here
+    and now (the module docstring has the rule)."""
     kernel = (_kernel_takes(dtype) and not sink and dv in (None, d)
+              and not (keep and (window is not None or block != 1))
               and d % 128 == 0 and n % MIN_TILE == 0
               and (window is None or window % fitted_tile(n) == 0)
               and (block == 1 or (window is None and not block & (block - 1)
@@ -552,22 +601,28 @@ def prefill_lowering(n: int, d: int, dtype, window, block: int = 1, *,
 
 
 def prefill_attention(q, k, v, scale, window=None, lengths=None, block=1,
-                      sink=None):
+                      sink=None, keep=None):
     """``(R, P, H * dv)`` in ``q``'s dtype, exact at the first ``lengths
     (R,)`` positions of each row (default: all ``P``).  ``block``: 1 for
     the causal mask, else the block mask's length (``lengths`` then whole
     blocks).  ``sink (H,)``: a learned term of every row's softmax beside
-    its keys.  The lowering is chosen as the module docstring says."""
+    its keys.  ``keep (R, P, P)``: one byte a pair, the same for every head;
+    ``(t, s)`` is attended iff ``s <= t`` and ``keep[r, t, s]``.  The
+    lowering is chosen as the module docstring says."""
     r, n, heads, d = q.shape
     lowering = prefill_lowering(n, d, q.dtype, window, block,
-                                dv=v.shape[-1], sink=sink is not None)
+                                dv=v.shape[-1], sink=sink is not None,
+                                keep=keep is not None)
     note("gqa_prefill", lowering)
     if lowering == "xla":
-        return blocked_prefill_attention(q, k, v, scale, window, block, sink)
+        return blocked_prefill_attention(q, k, v, scale, window, block, sink,
+                                         keep)
     if lengths is None:
         lengths = jnp.full((r,), n, jnp.int32)
     # the causal call is the one it was (callers wrap it with that signature)
     extra = {} if block == 1 else {"block": block}
+    if keep is not None:
+        extra["keep"] = keep
     return pallas_prefill_attention(q.reshape(r, n, heads * d), k, v,
                                     lengths, scale, window, **extra)
 

@@ -155,6 +155,11 @@ CASES = {case.name: case for case in (
                "reference_dots3", primes=(4, 5, 7, 8, 9, 21), greedy=(6,),
                counted=dict(n=3, seed=5, primes=(3, 13, 6)),
                forward=dict(q_block=8)),
+    # two tokens, the selector's edges (top-k 8), one past a chunk
+    FamilyCase("glm_dsa", "glm_dsa", "GLMDSAFamily", "glm_dsa_tiny",
+               "reference_glm52", primes=(2, 5, 7, 8, 9, 21), greedy=(6,),
+               counted=dict(n=3, seed=5, primes=(3, 13, 6)),
+               forward=dict(q_block=8)),
 )}
 
 

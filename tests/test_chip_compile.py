@@ -775,6 +775,27 @@ WHOLE_PROGRAMS = {
                "row_write"),
         admission=("tpu_custom_call", "mla_prefill_fwd", "moe_sorted_fwd"),
         never={"chunk": ("mla_prefill_fwd",), "admit": ("mla_decode_fwd",)}),
+    # the published layers 2-6 of 78, an eighth of the vocabulary, 16 slots
+    # of 17,408 rows in five latent leaves and two indexer leaves (the chunk
+    # program 13.32 GB, 2.06 of it the row-major twins of the five latent
+    # leaves, which the chip keeps with their rows minor; the admission
+    # 14.37: PERF.md section 6, PR 60): the
+    # indexers' score and ``top_k`` twice a step, the 2,048 gathered rows
+    # through ``mla_decode_fwd`` in all five layers; 1 row at the 16,384
+    # bucket: every layer's core ``gqa_prefill_fwd`` over the joined 256-wide
+    # heads under a keep mask that two layers compute and three borrow
+    "glm52": Whole(
+        "serve-glm52-longdoc-backlog", "glm_dsa",
+        lambda m: _perf_config(m, "GLMDSAConfig", "glm-5.2-ep16"),
+        dict(num_slots=16, chunk_size=32, max_len=17408), admit=(1, 16384),
+        weights=(7.76e9, 7.77e9), state=(1.74e9, 1.76e9), chunk_peak=13.4e9,
+        ops=("ops.row_write", "ops.gqa", "ops.mla_decode", "ops.moe_decode",
+             "decode.sampler"),
+        chunk=("tpu_custom_call", "mla_decode_fwd", "moe_decode_fwd",
+               "row_write"),
+        admission=("tpu_custom_call", "gqa_prefill_fwd", "moe_sorted_fwd"),
+        never={"chunk": ("gqa_prefill_fwd",),
+               "admit": ("mla_decode_fwd", "mla_prefill_fwd")}),
 }
 
 PROGRAMS = [(name, program) for name, row in WHOLE_PROGRAMS.items()

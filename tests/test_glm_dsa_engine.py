@@ -1,0 +1,172 @@
+"""GLM-5.2 through ``ServingEngine``'s normal path (the seam of
+``decode/family.py``, unchanged): the tests every driver family runs
+(``tests/families.py``) over slots of mixed lengths — under, at and past
+``index_topk`` at admission, all past it before they finish; what is GLM's
+own here: a slot's state holds TWO cache shapes among blocks of one latent
+shape — latent rows and indexer rows for each full block, the plain latent
+leaf for each shared one; a slot readmitted after a longer request (stale
+latent rows in five leaves, stale indexer rows in two) serves what a fresh
+one serves; the selections' counters and the byte gauges reach the registry
+and ``status()["model_stats"]``; and with the heads at the published widths
+(192 + 64 beside 256) and the kernel forced, every layer's admission core
+(``ops/gqa.py``'s over the joined heads, under the keep mask) says
+``"gqa_prefill": "pallas"``, serves the same tokens and counts the tiles it
+visited."""
+
+import jax
+import numpy as np
+import pytest
+
+from progen_tpu.decode import Request, ServingEngine
+from progen_tpu.decode.engine import SLOTS_PER_ADMIT_ROW
+from progen_tpu.observe.metrics import get_registry
+from tests import families
+from tests.families import SLOTS
+from tests.glm_dsa_tiny import (TINY, TOP_K, WIDE, WIDE_LAYERS,
+                                 force_prefill_kernel, make)
+
+pytestmark = pytest.mark.serving
+
+CASE = families.CASES["glm_dsa"]
+MAX_LEN = CASE.max_len
+LAYERS, OWNERS = 5, 2
+assert CASE.primes == (2, 5, TOP_K - 1, TOP_K, TOP_K + 1, 21)
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return families.engine_of(CASE)
+
+
+def greedy(case, reqs, done):
+    assert all(len(r.tokens) + r.max_new_tokens > TOP_K for r in reqs)
+    families.serves_the_plain_samplers_tokens(case, reqs, done)
+
+
+def test_a_slot_readmitted_after_a_longer_request_serves_what_a_fresh_one_does(
+        engine):
+    """Every slot holds a 21-token request's latent rows (five leaves) and
+    indexer rows (two), then takes a prime of 2-9 tokens: stale rows past
+    the short request's count must reach nothing — an indexer must not
+    select one, and a shared layer must not gather one at a number its
+    full layer handed over."""
+    long = families.requests(CASE, SLOTS, seed=7, first_uid=200, primes=(21,))
+    assert len(families.serve(engine, long)) == SLOTS
+    short = families.requests(
+        CASE, SLOTS, seed=8, first_uid=300,
+        primes=(2, TOP_K - 1, TOP_K, TOP_K + 1, 5))
+    families.serves_the_plain_samplers_tokens(
+        CASE, short, families.serve(engine, short))
+
+
+def slot_holds(engine):
+    owner = {"latent": (MAX_LEN, 20), "index": (MAX_LEN, 8)}
+    assert jax.tree.map(lambda a: a.shape[1:], engine.state["caches"]) == {
+        "l0": owner, "l1": (MAX_LEN, 20), "l2": (MAX_LEN, 20), "l3": owner,
+        "l4": (MAX_LEN, 20)}
+    status = engine.status()
+    assert status["row_write"] == "scatter"     # the CPU's lowering
+    assert status["mla_decode"] == status["gqa_prefill"] == "xla"
+    assert status.get("mla_prefill") is None
+
+
+def states(family):
+    assert family.block_length is None
+    assert family.vocab == TINY.vocab_size
+    assert family.seq_len == TINY.max_position_embeddings
+    assert [b.selection for b in family.blocks.values()] == [
+        "own", "borrow", "borrow", "own", "borrow"]
+
+
+def counters(engine, reqs, stats, total):
+    prime_tokens = sum(len(r.tokens) for r in reqs)
+    steps = sum(r.max_new_tokens - 1 for r in reqs)   # the first is prefill's
+    assert stats["moe.tokens"] == 4 * (prime_tokens + steps)
+    assert stats["mla.decode_rows"] == steps
+    assert stats["moe.held_load"].sum() == 2 * stats["moe.tokens"]
+    # the i-th step of a request stands on position prime + i - 1: it has
+    # prime + i tokens of context, min(., 8) of them selected, in EVERY
+    # layer; two layers computed that selection and three borrowed it
+    lengths = [len(r.tokens) + i for r in reqs
+               for i in range(1, r.max_new_tokens)]
+    assert stats["mla.context_tokens"] == sum(lengths)
+    assert stats["dsa.context_tokens"] == LAYERS * sum(lengths)
+    assert stats["dsa.keys_selected"] == LAYERS * sum(
+        min(n, TOP_K) for n in lengths)
+    # a row admitted in a bucket past index_topk (the 13-token prime's 16,
+    # and whatever rode in its run) has a selection too
+    admitted = stats["dsa.selections_computed"] / OWNERS - steps
+    assert admitted == int(admitted) and 1 <= admitted <= len(reqs)
+    assert stats["dsa.selections_borrowed"] == (LAYERS - OWNERS) * (
+        steps + admitted)
+    # the XLA score reads every slot's every indexer row each step that ran,
+    # in the two full layers alone; the sparse core top-k gathered rows
+    chunk_steps = stats["dsa.index_rows_read"] / (OWNERS * SLOTS * MAX_LEN)
+    assert chunk_steps == int(chunk_steps) and chunk_steps >= max(
+        r.max_new_tokens - 1 for r in reqs)
+    assert stats["mla.cache_rows_read"] == chunk_steps * SLOTS * TOP_K
+    assert stats["dsa.prefill_pairs_attended"] > stats[
+        "dsa.prefill_pairs_selected"] > 0
+    assert stats["dsa.prefill_pairs_scored"] > 0
+    # no byte counter rides in the state: the gauges are the rows at each
+    # leaf's own row bytes (float32 here); an indexer's rows for the full
+    # layers only, a latent row for every layer
+    assert not [k for k in stats if k.endswith("_bytes_read")]
+    gauges = engine.status()["model_stats"]
+    assert gauges["dsa.index_bytes_read"] == (
+        total["dsa.index_rows_read"] * 8 * 4)
+    assert gauges["mla.cache_bytes_read"] == (
+        total["mla.cache_rows_read"] * LAYERS * 20 * 4)
+    assert gauges["dsa.selections_read"] == 2.5 * total[
+        "dsa.selections_computed"]
+    snap = get_registry().snapshot()
+    for name in ("moe.tokens", "moe.decode_layers", "moe.experts_touched",
+                 "mla.decode_rows", "mla.context_tokens", "dsa.keys_selected",
+                 "dsa.index_rows_read", "mla.cache_rows_read",
+                 "dsa.selections_computed", "dsa.selections_borrowed",
+                 "dsa.prefill_pairs_scored"):
+        assert snap[name]["value"] == total[name], name
+    for name in ("dsa.index_bytes_read", "mla.cache_bytes_read",
+                 "dsa.selections_read"):
+        assert snap[name]["value"] == gauges[name], name
+
+
+TestEngine = families.engine_tests(
+    CASE, slot_holds=slot_holds, states=states, counters=counters,
+    greedy=greedy)
+
+
+def test_engine_states_the_kernel_and_serves_the_same_tokens(monkeypatch):
+    """The engine over ``WIDE`` (heads 192 + 64 beside 256, a selection of
+    512), a prime of 600 in the 1,024 bucket: on the CPU every layer's
+    admission core is the blocked XLA form under the keep mask; with the
+    kernel forced (interpreter, tiles of 256) it says ``"pallas"``, the
+    greedy tokens are the same, and ``dsa.prefill_pairs_attended`` is the six
+    tiles a row of 600 visits in each of ``WIDE``'s three layers.  Two engines by what
+    it tests: each traces its admission under the lowering in force."""
+    params, policy = make(WIDE)
+    prime = np.random.default_rng(0).integers(1, WIDE.vocab_size, 600)
+
+    def serve():
+        eng = ServingEngine(WIDE, params, policy=policy,
+                            num_slots=SLOTS_PER_ADMIT_ROW, chunk_size=4,
+                            max_len=1024 + 8)
+        eng.submit(Request(uid=0, tokens=prime.tolist(), max_new_tokens=5,
+                           temperature=0.0, seed=1,
+                           logit_mask=families.never_zero(CASE)))
+        (done,) = eng.run_until_idle(max_chunks=10)
+        return list(done.tokens), eng.status(), eng.model_stats
+
+    want, status, stats = serve()
+    assert status["gqa_prefill"] == "xla"
+    # one row a run: four blocks of 256 query rows share the keys of the last
+    assert stats["dsa.prefill_pairs_attended"] == WIDE_LAYERS * 1024 * 1024
+    force_prefill_kernel(monkeypatch)
+    got, status, kernel_stats = serve()
+    assert status["gqa_prefill"] == "pallas"
+    assert got == want
+    assert kernel_stats["dsa.prefill_pairs_attended"] == (
+        WIDE_LAYERS * 6 * 256 ** 2)
+    for name in ("dsa.prefill_pairs_scored", "dsa.prefill_pairs_selected",
+                 "dsa.selections_computed", "dsa.selections_borrowed"):
+        assert kernel_stats[name] == stats[name] > 0

@@ -6,8 +6,11 @@ form and a dense masked float32 reference at every real position, for
 windows under, at and over a tile, groups of 1 and 8, lengths at 0, 1, a
 tile's edge, the window's edge and ``P``; real positions bit-equal whatever
 the padding holds; skipped tiles written as zeros; the tile-visit rule
-against a brute-force count; the pair counters; and the choice of lowering
-from backend, mesh and shape, as ``status()`` shows it."""
+against a brute-force count; the pair counters; the choice of lowering
+from backend, mesh and shape, as ``status()`` shows it; and (PR 60) the
+``keep`` operand: one byte a pair that thins the causal pairs, in both
+lowerings against a dense masked softmax, with rows whose first tiles keep
+nothing."""
 
 import dataclasses
 import functools
@@ -256,6 +259,131 @@ def test_the_blocked_forms_pair_count_is_its_score_tensors():
 
 
 # ---- which lowering, and where it is stated --------------------------------
+
+
+# ---------------------------------------------------------------- a keep mask
+
+
+def _keep(p, seed, rows=R, share=0.3, late=False):
+    """``(rows, p, p)`` int8: a share of the causal pairs at random; every
+    row keeps at least one key it can see — its own, or with ``late`` (rows
+    past the first tile) ONLY keys of its last tile, so that its first key
+    tiles hold nothing kept."""
+    gap = np.arange(p)[:, None] - np.arange(p)[None, :]
+    rng = np.random.default_rng(seed)
+    keep = (rng.random((rows, p, p)) < share) | (gap == 0)
+    if late:
+        keep = keep & ((gap < TILE // 2) | (gap == 0))
+        keep[:, :TILE] = gap[:TILE] >= 0
+    return jnp.asarray(keep, jnp.int8)
+
+
+def _dense_kept(q, k, v, keep):
+    q, k, v = (np.asarray(a.astype(jnp.float32), np.float64)
+               for a in (q, k, v))
+    r, p, heads, d = q.shape
+    group = heads // k.shape[1]
+    seen = (np.arange(p)[:, None] >= np.arange(p)[None, :]) & (
+        np.asarray(keep) != 0)
+    out = np.zeros((r, p, heads, v.shape[-1]))
+    for h in range(heads):
+        s = np.einsum("rqd,rkd->rqk", q[:, :, h], k[:, h // group]) * SCALE
+        s = np.where(seen, s, -np.inf)
+        w = np.exp(s - s.max(-1, keepdims=True))
+        out[:, :, h] = np.einsum("rqk,rkd->rqd", w / w.sum(-1, keepdims=True),
+                                 v[:, h // group])
+    return out.reshape(r, p, -1)
+
+
+@pytest.mark.parametrize("late", [False, True], ids=["random", "late-keys"])
+@pytest.mark.parametrize("lengths", [[P, P], [2 * TILE + 1, 1], [TILE, 0]],
+                         ids=str)
+@pytest.mark.parametrize("group,dtype,tiles", [
+    (1, "float32", (128, 128)), (8, "bfloat16", (256, 128)),
+    (1, "bfloat16", (128, 256))], ids=lambda v: str(v))
+def test_both_lowerings_attend_under_a_keep_mask(group, dtype, tiles,
+                                                 lengths, late):
+    """The kernel with the ``keep`` operand and the blocked form with it
+    against a dense softmax over exactly the kept causal pairs, at every
+    real position; ``late``: rows that keep no key of their first key
+    tiles (the running maximum rides on ``MASKED`` until the last)."""
+    q, k, v = _operands(P, group, jnp.dtype(dtype))
+    keep = _keep(P, 3, late=late)
+    r, p, heads, d = q.shape
+    with jax.default_matmul_precision("highest"):
+        got = _f32(gqa.pallas_prefill_attention(
+            q.reshape(r, p, heads * d), k, v, jnp.asarray(lengths, jnp.int32),
+            SCALE, keep=keep, block_q=tiles[0], block_k=tiles[1],
+            interpret=True))
+        blocked = _f32(jax.jit(
+            lambda *a: gqa.blocked_prefill_attention(*a[:3], SCALE,
+                                                     keep=a[3]))(
+            q, k, v, keep))
+    dense = _dense_kept(q, k, v, keep)
+    assert np.isfinite(got).all() and float(np.abs(dense).max()) > 1.0
+    for row, n in enumerate(lengths):
+        np.testing.assert_allclose(got[row, :n], dense[row, :n],
+                                   atol=TOL[dtype])
+        np.testing.assert_allclose(blocked[row, :n], dense[row, :n],
+                                   atol=TOL[dtype])
+        # a query tile past the row's length reads as zeros
+        first_dead = -(-n // tiles[0]) * tiles[0]
+        assert not got[row, first_dead:].any()
+    # the mask bites (the late rows' first tile keeps every key): the
+    # unmasked core reads elsewhere
+    if not late or lengths[0] > TILE:
+        plain = _f32(_kernel(q, k, v, lengths, None, block_q=tiles[0],
+                             block_k=tiles[1]))
+        assert float(np.abs(plain[0, :lengths[0]]
+                            - got[0, :lengths[0]]).max()) > 0.1
+
+
+def test_a_keep_mask_of_ones_is_the_causal_core_and_takes_no_window():
+    q, k, v = _operands(P, 8, jnp.float32)
+    ones = jnp.ones((R, P, P), jnp.int8)
+    r, p, heads, d = q.shape
+    with jax.default_matmul_precision("highest"):
+        got = gqa.pallas_prefill_attention(
+            q.reshape(r, p, heads * d), k, v, jnp.array([P, 300], jnp.int32),
+            SCALE, keep=ones, block_q=TILE, block_k=TILE, interpret=True)
+    want = _kernel(q, k, v, [P, 300], None, block_q=TILE, block_k=TILE)
+    np.testing.assert_allclose(_f32(got), _f32(want), atol=1e-6)
+    for fn in (gqa.pallas_prefill_attention, gqa.blocked_prefill_attention):
+        with pytest.raises(ValueError, match="keep mask"):
+            if fn is gqa.blocked_prefill_attention:
+                fn(q, k, v, SCALE, TILE, keep=ones)
+            else:
+                fn(q.reshape(r, p, heads * d), k, v, jnp.array([P, P]),
+                   SCALE, TILE, keep=ones, interpret=True)
+
+
+@pytest.mark.parametrize("shape,window,want", [
+    ((1, 1024, 4, 4, 256), None, "pallas"),     # GLM-5.2's joined heads
+    ((1, 1024, 4, 4, 256), 512, "xla"),         # no window beside a mask
+    ((1, 1024, 4, 4, 192), None, "xla"),        # 192 is no lane multiple
+], ids=["joined-256", "window", "d-192"])
+def test_on_tpu_a_keep_mask_takes_the_kernel_without_a_window(
+        monkeypatch, shape, window, want):
+    r, n, heads, kv, d = shape
+    monkeypatch.setattr(gqa, "_on_tpu", lambda: True)
+    assert gqa.prefill_lowering(n, d, jnp.bfloat16, window, keep=True) == want
+    if window is not None:
+        return
+    q = jax.ShapeDtypeStruct((r, n, heads, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((r, kv, n, d), jnp.bfloat16)
+    keep = jax.ShapeDtypeStruct((r, n, n), jnp.int8)
+    with record_lowerings() as chosen:
+        masked = str(jax.make_jaxpr(lambda q, k, v, m: gqa.prefill_attention(
+            q, k, v, 0.1, keep=m))(q, k, k, keep))
+        plain = str(jax.make_jaxpr(lambda q, k, v: gqa.prefill_attention(
+            q, k, v, 0.1))(q, k, k))
+    assert chosen["gqa_prefill"] == {want}
+    assert ("pallas_call" in masked) == (want == "pallas")
+    if want == "pallas":
+        # the mask is one more operand of the same kernel; without it the
+        # call has the operands it had
+        assert masked.count("name=gqa_prefill_fwd") == 1
+        assert f"i8[{r},{n},{n}]" in masked and "i8[" not in plain
 
 
 def _lowering(shape, window, dtype=jnp.bfloat16, monkeypatch=None,
