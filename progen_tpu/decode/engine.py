@@ -935,7 +935,8 @@ class ServingEngine:
         both, and an engine's admission programs, one a bucket, may differ
         — joined by ``+``), how an admission's sorted experts' terms reach
         their tokens (``"moe_combine"``, per program too) and
-        the draw's k-th largest logit (``"sample_kth"``: ``"xla"`` /
+        the draw's k-th largest logit and a learned selection's k-th
+        largest score (``"sample_kth"``, ``"dsa_kth"``: ``"xla"`` /
         ``"xla_tiled"``, per program too)."""
 
         @wraps(impl)
@@ -2769,6 +2770,9 @@ class ServingEngine:
             # one loop ("xla") or a group of rows at a time, where only a
             # group's keys stay on the chip ("xla_tiled"), by program
             "sample_kth": self._lowering_by_program("sample_kth"),
+            # the same of an admission's learned selection (ops/dsa.py);
+            # None for a family without
+            "dsa_kth": self._lowering_by_program("dsa_kth"),
             # the device counters as last fetched with the slot flags,
             # under their registry names ({} for a family without)
             "model_stats": dict(self.model_gauges),

@@ -36,9 +36,7 @@ from progen_tpu.decode.prefill import (
     pad_prime_length,
 )
 from progen_tpu.models.progen import ProGenConfig
-from progen_tpu.ops.lowering import mesh_in_scope as _mesh_in_scope
-from progen_tpu.ops.lowering import note
-from progen_tpu.ops.lowering import on_tpu as _on_tpu
+from progen_tpu.ops.kth import kth_largest_by_counting
 
 
 def apply_logit_mask(logits, mask):
@@ -79,89 +77,6 @@ def gumbel_topk_sample(key, logits, top_k: int | None, temperature: float = 1.0,
     return jnp.argmax(logits + noise, axis=-1)
 
 
-# The most bytes of float32 rows, ``B * V * 4``, whose 32 rounds are left to
-# ONE loop, and the most a row group of the tiled form may hold.  On a v5e the
-# compiler keeps a loop's keys on the chip by itself while they are few:
-# Granite's 32 x 100,352 (12.8 MB, the largest draw of the token-by-token
-# cells) run their 32 rounds in 119 us, 3.5 TB/s; SDAR's 256 x 151,936
-# (155.6 MB) do not fit and are read from HBM every round, 6.6 ms a draw;
-# in groups of 32 rows (19.4 MB) the rounds take 1.2 ms.  Groups of 16 under
-# a budget of 16 MiB read 0.5 % fewer tokens a second end to end, and groups
-# of 8 take half as long again as 16 alone (PERF.md section 6, PR 39 and
-# PR 43)
-ROUNDS_ON_CHIP_BYTES = 32 << 20
-
-
-def _group_rows(b: int, v: int) -> int | None:
-    """Rows of a group for the rounds of a ``(b, v)`` draw, ``None`` where
-    one loop takes them all: groups on a TPU backend with no mesh in scope
-    and more rows than ``ROUNDS_ON_CHIP_BYTES`` hold — the largest power of
-    two of them that the budget does hold."""
-    fit = ROUNDS_ON_CHIP_BYTES // (v * 4)
-    if not 0 < fit < b or not _on_tpu() or _mesh_in_scope():
-        return None
-    return 1 << (fit.bit_length() - 1)
-
-
-def _kth_largest_by_counting(scaled, k):
-    """``(B, 1)``: each row's ``k``-th largest value, the element
-    ``jnp.sort(scaled, axis=-1)[v - k]`` of the row, found without ordering
-    the row (:func:`_count_rounds`).  ``scaled`` is float32 ``(B, V)``, ``k``
-    int32 ``(B,)`` in ``1..V``.
-
-    WHERE the 32 rounds read their keys is what they cost, and the shape
-    decides it (:func:`_group_rows`; noted as ``"sample_kth"``,
-    ``ops/lowering.py``).  A ``(B, V)`` that fits on the chip — every
-    token-by-token cell's draw, 64 x 256 up to 32 x 100,352 — is one loop,
-    whose keys the compiler keeps on the chip between rounds by itself
-    (``"xla"``).  A ``(B, V)`` that does not — a block step's 256 x 151,936,
-    155.6 MB — would be read from HBM again every round; there, on a TPU,
-    the same loop runs over one group of rows at a time under ``lax.map``, a
-    group's keys small enough to stay on the chip through its 32 rounds, so
-    that HBM is read once (``"xla_tiled"``).  Rows are independent: the
-    value handed out is the same, bit for bit.
-    """
-    b, v = scaled.shape
-    rows = _group_rows(b, v)
-    note("sample_kth", "xla" if rows is None else "xla_tiled")
-    if rows is None:
-        return _count_rounds(scaled, k)
-    pad = -b % rows     # (a last group that is not full counts spare rows)
-    groups = (jnp.pad(scaled, ((0, pad), (0, 0))).reshape(-1, rows, v),
-              jnp.pad(k, (0, pad), constant_values=1).reshape(-1, rows))
-    kth = jax.lax.map(lambda group: _count_rounds(*group), groups)
-    return kth.reshape(-1, 1)[:b]
-
-
-def _count_rounds(scaled, k):
-    """:func:`_kth_largest_by_counting` over rows that are on the chip
-    together.
-
-    Each float32 becomes a uint32 key whose unsigned order is the sort's
-    order (``-inf`` lowest, finite values by value, ``+inf``, every NaN of
-    either sign highest).  The k-th largest key is then built bit by bit
-    from the top: a bit stays set when at least ``k`` keys of the row are
-    still at or above the candidate.  Thirty-two compare-and-count rounds
-    over the keys, each one fused reduction of a ``fori_loop`` whose keys
-    are a loop invariant, whatever ``k`` is; the largest ``t`` with
-    ``count(key >= t) >= k`` is a key the row holds, multiplicity counted
-    as the sort counts it.
-    """
-    bits = jax.lax.bitcast_convert_type(scaled, jnp.uint32)
-    top = jnp.uint32(1 << 31)
-    key = jnp.where(bits >= top, ~bits, bits | top)
-    key = jnp.where(jnp.isnan(scaled), jnp.uint32(0xFFFFFFFF), key)
-
-    def keep_bit(i, t):
-        cand = t | (top >> i.astype(jnp.uint32))
-        at_or_above = jnp.sum(key >= cand[:, None], axis=-1, dtype=jnp.int32)
-        return jnp.where(at_or_above >= k, cand, t)
-
-    t = jax.lax.fori_loop(0, 32, keep_bit, jnp.zeros(k.shape, jnp.uint32))
-    bits = jnp.where(t >= top, t ^ top, ~t)
-    return jax.lax.bitcast_convert_type(bits, jnp.float32)[:, None]
-
-
 def gumbel_topk_sample_batched(keys, logits, top_k, temperature, mask=None):
     """Per-row sampling for the serving engine: each row has its own key,
     top-k and temperature.
@@ -175,14 +90,14 @@ def gumbel_topk_sample_batched(keys, logits, top_k, temperature, mask=None):
     scaled logit.  Sorting every row to read one element of it was, on a
     sliced or whole chat vocabulary (16,384 to 100,352 columns), the largest
     single operation of a decode step after the model's own, so the number
-    is found by counting (``_kth_largest_by_counting``), at every width:
-    on ProGen's 256 columns the 32 rounds cost a few microseconds more than
-    the tiny sort did and no cell can tell (PERF.md §6, PR 39).  While the
-    rows of a draw fit on the chip together (up to 32 x 100,352) the rounds
-    run from the chip's memory by themselves; a block step's 256 x 151,936
-    do not, and there the same rounds run over one group of rows at a time,
-    so that HBM is read once and not 32 times (PERF.md §6, PR 43) — the
-    same algorithm and the same value either way.  The value
+    is found by counting (``ops/kth.py:kth_largest_by_counting``), at every
+    width: on ProGen's 256 columns the 32 rounds cost a few microseconds
+    more than the tiny sort did and no cell can tell (PERF.md §6, PR 39).
+    While the rows of a draw fit on the chip together (up to 32 x 100,352)
+    the rounds run from the chip's memory by themselves; a block step's
+    256 x 151,936 do not, and there the same rounds run over one group of
+    rows at a time, so that HBM is read once and not 32 times (PERF.md §6,
+    PR 43) — the same algorithm and the same value either way.  The value
     is the one a full ascending sort of the row hands out at ``[v - k]``,
     so the mask, and under the same keys the tokens, are that form's:
 
@@ -223,7 +138,7 @@ def _cut_and_draw(keys, logits, top_k, temperature, mask):
     greedy = jnp.argmax(logits, axis=-1)
     scaled = logits / jnp.maximum(temperature, 1e-8)[:, None]
     k_eff = jnp.where(top_k > 0, jnp.clip(top_k, 1, v), v)
-    kth = _kth_largest_by_counting(scaled, k_eff)
+    kth = kth_largest_by_counting(scaled, k_eff, "sample_kth")
     kept = scaled >= kth
     masked = apply_logit_mask(scaled, kept)
     noise = jax.vmap(

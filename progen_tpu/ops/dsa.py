@@ -29,8 +29,10 @@ choice of lowering:
   first needs no indexer at all (its queries see ``top_k`` keys or fewer),
   and inside a segment a ``lax.map`` over blocks of ``QUERY_BLOCK`` rows
   keeps ``I`` a block high, ``(J, bq, keys)`` float32.  A row's threshold
-  is its ``top_k``-th largest score (``lax.top_k``), the mask ``I >=
-  threshold`` (every score tied with the threshold is kept).  What reads
+  is its ``top_k``-th largest score, found by counting and not by ordering
+  the row (``ops/kth.py``: 32 compare-and-count rounds over the block, the
+  value a sort of the row hands out; the note ``"dsa_kth"``), the mask ``I
+  >= threshold`` (every score tied with the threshold is kept).  What reads
   the mask is ``ops/mla_prefill.py``'s decision
   (``mla_prefill.prefill_lowering``):
 
@@ -71,6 +73,7 @@ import jax
 import jax.numpy as jnp
 
 from progen_tpu.ops import mla_decode, mla_prefill
+from progen_tpu.ops.kth import kth_largest_by_counting
 
 F32 = jnp.float32
 QUERY_BLOCK = 128     # an admission's query rows per score block
@@ -218,8 +221,10 @@ def selected(q_idx, w, k_idx, top_k: int, first, bq: int, end: int):
             scores = jnp.where(seen, index_scores(
                 rows(q_idx), rows(w), k_idx[:, :end]), -jnp.inf)
         with jax.named_scope("dsa.select"):
-            kth = jax.lax.top_k(scores, top_k)[0][..., -1:]
-            seen = seen & (scores >= kth)
+            kth = kth_largest_by_counting(
+                scores.reshape(r * bq, end),
+                jnp.full((r * bq,), top_k, jnp.int32), "dsa_kth")
+            seen = seen & (scores >= kth.reshape(r, bq, 1))
     return seen
 
 

@@ -11,11 +11,13 @@ the terms reached their tokens: ``"pallas_rows"``, the kernel's own row
 DMAs) each keep a Pallas
 lowering and an XLA form behind one function and choose between them from
 the backend, the mesh in scope and the shapes, never from a knob.
-``decode/sampler.py:_kth_largest_by_counting`` chooses in the same way
-between two XLA forms of one loop (the note ``"sample_kth"``: ``"xla"``
-where a draw's keys fit on the chip and the compiler keeps them there,
+``ops/kth.py:kth_largest_by_counting`` chooses in the same way between two
+XLA forms of one loop, under the name its caller gives (``"sample_kth"``
+from the engine's draw, ``decode/sampler.py``; ``"dsa_kth"`` from an
+admission's learned selection, ``ops/dsa.py:selected``): ``"xla"`` where
+the block's keys fit on the chip and the compiler keeps them there,
 ``"xla_tiled"`` where only a group of rows does and the loop runs a group
-at a time).
+at a time.
 (``ops/gqa.py`` owns three such ops: the prefill core, ``"gqa_prefill"``;
 the one-query decode core, ``"gqa_decode"`` — the kernel at head widths on
 the lane tile, the XLA form at Granite 4.0-H's 64 —; and

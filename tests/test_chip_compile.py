@@ -651,6 +651,22 @@ def _sdar_draws_by_groups_in_the_chips_own_memory(text):
     assert "u32[32,151936]{1,0:T(8,128)S(1)}" in loops[0]
 
 
+def _selection_counts_in_the_chips_own_memory(text):
+    """An admission's learned selection at the 16,384 bucket (``ops/dsa.py``
+    over ``ops/kth.py``): each of the seven spans past ``top_k`` counts its
+    thresholds a block of 128 query rows in loops whose keys the compiler
+    keeps in the chip's own memory (memory space 1) through the 32 rounds,
+    and no row of scores is sorted."""
+    for end in range(4096, 16384 + 1, 2048):
+        loops = [line for line in _buffers_of(text, f"u32[128,{end}]")
+                 if " while(" in line]
+        assert loops, end
+        for line in loops:
+            assert f"u32[128,{end}]{{1,0:T(8,128)S(1)}}" in line, line
+    assert not [line for line in text.splitlines()
+                if " sort(" in line and "f32[1,128," in line]
+
+
 @dataclasses.dataclass(frozen=True)
 class Whole:
     """One family's serving cell as its engine's programs are compiled for
@@ -669,12 +685,13 @@ class Whole:
     dropped: float = 0.0        # weights an admission does not even take
     # the modules under ``progen_tpu`` whose ``_on_tpu`` says the chip is there
     ops: tuple = ("ops.row_write", "ops.gqa", "ops.moe_decode",
-                  "decode.sampler")
+                  "ops.kth")
     chunk: tuple = ()           # names the chunk program's text must hold
     admission: tuple = ()       # names the admission's text must hold
     never: dict = dataclasses.field(default_factory=dict)   # program -> names
     no_buffers: dict = dataclasses.field(default_factory=dict)  # -> shapes
     chunk_also: object = None   # a further check of the chunk's text
+    admit_also: object = None   # a further check of the admission's text
 
 
 WHOLE_PROGRAMS = {
@@ -770,11 +787,12 @@ WHOLE_PROGRAMS = {
         dict(num_slots=16, chunk_size=32, max_len=17408), admit=(1, 16384),
         weights=(8.17e9, 8.18e9), state=(0.8e9, 0.9e9),
         ops=("ops.row_write", "ops.gqa", "ops.mla_decode", "ops.mla_prefill",
-             "ops.moe_decode", "decode.sampler"),
+             "ops.moe_decode", "ops.kth"),
         chunk=("tpu_custom_call", "mla_decode_fwd", "moe_decode_fwd",
                "row_write"),
         admission=("tpu_custom_call", "mla_prefill_fwd", "moe_sorted_fwd"),
-        never={"chunk": ("mla_prefill_fwd",), "admit": ("mla_decode_fwd",)}),
+        never={"chunk": ("mla_prefill_fwd",), "admit": ("mla_decode_fwd",)},
+        admit_also=_selection_counts_in_the_chips_own_memory),
     # the published layers 2-6 of 78, an eighth of the vocabulary, 16 slots
     # of 17,408 rows in five latent leaves and two indexer leaves (the chunk
     # program 13.32 GB, 2.06 of it the row-major twins of the five latent
@@ -790,12 +808,13 @@ WHOLE_PROGRAMS = {
         dict(num_slots=16, chunk_size=32, max_len=17408), admit=(1, 16384),
         weights=(7.76e9, 7.77e9), state=(1.74e9, 1.76e9), chunk_peak=13.4e9,
         ops=("ops.row_write", "ops.gqa", "ops.mla_decode", "ops.moe_decode",
-             "decode.sampler"),
+             "ops.kth"),
         chunk=("tpu_custom_call", "mla_decode_fwd", "moe_decode_fwd",
                "row_write"),
         admission=("tpu_custom_call", "gqa_prefill_fwd", "moe_sorted_fwd"),
         never={"chunk": ("gqa_prefill_fwd",),
-               "admit": ("mla_decode_fwd", "mla_prefill_fwd")}),
+               "admit": ("mla_decode_fwd", "mla_prefill_fwd")},
+        admit_also=_selection_counts_in_the_chips_own_memory),
 }
 
 PROGRAMS = [(name, program) for name, row in WHOLE_PROGRAMS.items()
@@ -907,5 +926,6 @@ def test_a_familys_programs_compile_for_the_chip_and_fit_it(
         assert kernel not in text, kernel
     for buffer in row.no_buffers.get(program, ()):
         assert not _buffers_of(text, buffer), buffer
-    if program == "chunk" and row.chunk_also:
-        row.chunk_also(text)
+    also = row.chunk_also if program == "chunk" else row.admit_also
+    if also:
+        also(text)

@@ -387,16 +387,19 @@ def test_a_full_layer_traces_one_kernel_call_where_the_kernel_applies(
     heads = shape.num_attention_heads
     blocks = f"f32[1,{heads},{dsa.segments(n, c.index_topk)[1]},"
     if want == "pallas":
-        assert chosen == {"mla_prefill": {"pallas"}}
+        assert chosen == {"mla_prefill": {"pallas"}, "dsa_kth": {"xla"}}
         assert jaxpr.count("pallas_call") == 1
         assert "name=mla_prefill_fwd" in jaxpr and blocks not in jaxpr
         assert f"i8[1,{n},{n}]" in jaxpr      # the mask, a byte a pair
     else:
-        assert "mla_prefill" not in chosen
+        assert chosen == {"dsa_kth": {"xla"}}
         assert "pallas_call" not in jaxpr and blocks in jaxpr
-    # the selection is the same text either way: one ``top_k`` a segment
-    # past the first
-    assert jaxpr.count("top_k[") == n // c.index_topk - 1
+    # the selection is the same text either way: its thresholds counted
+    # (``ops/kth.py``), one loop of rounds a segment past the first, no
+    # ``top_k``
+    assert jaxpr.count("bitcast_convert_type[new_dtype=uint32]") == (
+        n // c.index_topk - 1)
+    assert "top_k[" not in jaxpr
     # a row of 3,071: three live query tiles of 1,024 under the kernel
     scored, attended = dsa.prefill_pairs(n, c.index_topk)
     assert float(got["dsa.prefill_pairs_scored"]) == 2 * scored

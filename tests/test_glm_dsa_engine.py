@@ -67,6 +67,8 @@ def slot_holds(engine):
     status = engine.status()
     assert status["row_write"] == "scatter"     # the CPU's lowering
     assert status["mla_decode"] == status["gqa_prefill"] == "xla"
+    # the admissions past ``index_topk`` count their thresholds, one loop
+    assert status["dsa_kth"] == {"admit": "xla"}
     assert status.get("mla_prefill") is None
 
 

@@ -412,7 +412,8 @@ def test_every_layer_traces_one_kernel_call_where_the_kernel_applies(
     4,096 of one row: at the published widths (192 + 64 joined to 256,
     beside values of 256) on a TPU each is exactly ONE ``pallas_call``,
     ``gqa_prefill_fwd``, under the byte mask — which the full layer
-    computes (one ``top_k``) and the shared one takes as an operand (none);
+    computes (its thresholds counted, ``ops/kth.py``: one loop of rounds a
+    segment, no ``top_k``) and the shared one takes as an operand (none);
     on the CPU and at the tiny widths the blocked XLA form under the same
     mask."""
     c, n = (TINY, 32) if where.startswith("tiny") else (_published(), 4096)
@@ -431,9 +432,10 @@ def test_every_layer_traces_one_kernel_call_where_the_kernel_applies(
         borrowed = str(jax.make_jaxpr(lambda x, p, m, k: latent.mla_prefill(
             x, p, c, m, selection="borrow", handed=k)[0])(
             x, params["layers"][1]["attn"], lengths, keep))
-    assert chosen == {"gqa_prefill": {want}}
-    assert own.count("top_k[") == n // c.index_topk - 1
-    assert "top_k[" not in borrowed
+    assert chosen == {"gqa_prefill": {want}, "dsa_kth": {"xla"}}
+    keys = "bitcast_convert_type[new_dtype=uint32]"
+    assert own.count(keys) == n // c.index_topk - 1 and keys not in borrowed
+    assert "top_k[" not in own + borrowed
     for text in (own, borrowed):
         assert text.count("pallas_call") == (want == "pallas")
         assert ("name=gqa_prefill_fwd" in text) == (want == "pallas")

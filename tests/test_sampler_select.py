@@ -25,6 +25,7 @@ from progen_tpu.decode.sampler import (
     gumbel_topk_sample_batched,
     gumbel_topk_sample_with_confidence,
 )
+from progen_tpu.ops import kth
 from progen_tpu.ops.lowering import record_lowerings
 from tests.granite_tiny import TINY, make
 
@@ -50,9 +51,9 @@ CELL_DRAWS = {
 def _force_groups(monkeypatch, budget=None):
     """The chip's choice on the CPU: the gate sees a TPU and (for the tests'
     small arrays) a budget of ``budget`` bytes."""
-    monkeypatch.setattr(sampler, "_on_tpu", lambda: True)
+    monkeypatch.setattr(kth, "_on_tpu", lambda: True)
     if budget is not None:
-        monkeypatch.setattr(sampler, "ROUNDS_ON_CHIP_BYTES", budget)
+        monkeypatch.setattr(kth, "ROUNDS_ON_CHIP_BYTES", budget)
 
 
 def reference_kth(scaled, k_eff):
@@ -135,7 +136,8 @@ def test_selection_is_the_sorts_element_and_draws_its_tokens(
     k_eff = jnp.where(top_k > 0, jnp.clip(top_k, 1, v), v)
     want = np.asarray(jax.jit(reference_kth)(scaled, k_eff))
     got = np.asarray(jax.jit(
-        lambda *a: sampler._kth_largest_by_counting(*a))(scaled, k_eff))
+        lambda *a: kth.kth_largest_by_counting(*a, "sample_kth"))(
+            scaled, k_eff))
     assert got.shape == want.shape == (ROWS, 1)
     # bit for bit, but for which of two equal zeros is handed out and for
     # a NaN's payload: neither reaches the cut
@@ -195,15 +197,17 @@ def test_groups_of_other_sizes_hand_out_the_sorts_element(
         np.int32))
     want = np.asarray(reference_kth(jnp.asarray(x), k))
     _force_groups(monkeypatch, fit * v * 4)
-    assert sampler._group_rows(rows, v) == (groups and groups[1])
+    assert kth.group_rows(rows, v) == (groups and groups[1])
     jaxpr = str(jax.make_jaxpr(
-        lambda *a: sampler._kth_largest_by_counting(*a))(jnp.asarray(x), k))
+        lambda *a: kth.kth_largest_by_counting(*a, "sample_kth"))(
+            jnp.asarray(x), k))
     if groups:
         assert f"u32[{groups[0]},{groups[1]},{v}]" not in jaxpr
         assert f"u32[{groups[1]},{v}]" in jaxpr
     else:
         assert f"u32[{rows},{v}]" in jaxpr
-    got = np.asarray(sampler._kth_largest_by_counting(jnp.asarray(x), k))
+    got = np.asarray(kth.kth_largest_by_counting(jnp.asarray(x), k,
+                                                 "sample_kth"))
     np.testing.assert_array_equal(got, want)
     exact = ~np.isnan(want) & (want != 0)
     np.testing.assert_array_equal(got.view(np.uint32)[exact],
@@ -241,7 +245,7 @@ def test_the_shape_decides_where_the_rounds_run(monkeypatch, cell):
         assert chosen == {"sample_kth": {"xla"}} and forced == text
         return
     assert chosen == {"sample_kth": {"xla_tiled"}}
-    assert sampler._group_rows(rows, v) == 32
+    assert kth.group_rows(rows, v) == 32
     # the keys exist a group at a time, never as one array of the draw's
     # shape for a loop to read 32 times
     keys = "u32[{}] = bitcast_convert_type[new_dtype=uint32]"
@@ -296,20 +300,20 @@ def test_engine_serves_the_sort_forms_tokens(monkeypatch, vocab):
     params, policy = make(config)
     calls = []
 
-    def counted(form):
-        def kth(scaled, k):
-            calls.append(form.__name__)
-            return form(scaled, k)
-        return kth
+    def counted(name, form):
+        def kth_of(scaled, k, op):
+            calls.append((name, op))
+            return form(scaled, k, op)
+        return kth_of
 
-    monkeypatch.setattr(sampler, "_kth_largest_by_counting",
-                        counted(sampler._kth_largest_by_counting))
+    monkeypatch.setattr(sampler, "kth_largest_by_counting",
+                        counted("counting", kth.kth_largest_by_counting))
     selected = _serve(config, params, policy)
-    assert calls and set(calls) == {"_kth_largest_by_counting"}
+    assert calls and set(calls) == {("counting", "sample_kth")}
     del calls[:]
-    monkeypatch.setattr(sampler, "_kth_largest_by_counting",
-                        counted(reference_kth))
+    monkeypatch.setattr(sampler, "kth_largest_by_counting", counted(
+        "sort", lambda scaled, k, op: reference_kth(scaled, k)))
     sorted_ = _serve(config, params, policy)
-    assert calls and set(calls) == {"reference_kth"}
+    assert calls and set(calls) == {("sort", "sample_kth")}
     assert len(selected) == 6 and selected == sorted_
     assert all(len(t) >= 6 for t in selected.values())

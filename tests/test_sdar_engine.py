@@ -16,9 +16,10 @@ import numpy as np
 import pytest
 
 from perf.lib import reference_sdar as ref
-from progen_tpu.decode import Request, ServingEngine, sampler
+from progen_tpu.decode import Request, ServingEngine
 from progen_tpu.decode.family import UnsupportedFamilyMode, family_for
 from progen_tpu.observe.metrics import get_registry
+from progen_tpu.ops import kth
 from tests import families
 from tests.families import SLOTS
 from tests.sdar_tiny import BLOCK, MASK_ID, TINY, as_dict
@@ -367,10 +368,10 @@ def test_the_tiled_draw_serves_the_one_loops_tokens(monkeypatch, served,
 
     took, want = serve()
     assert took == {"chunk": "xla"}
-    monkeypatch.setattr(sampler, "_on_tpu", lambda: True)
-    monkeypatch.setattr(sampler, "ROUNDS_ON_CHIP_BYTES",
+    monkeypatch.setattr(kth, "_on_tpu", lambda: True)
+    monkeypatch.setattr(kth, "ROUNDS_ON_CHIP_BYTES",
                         24 * TINY.vocab_size * 4)
-    assert sampler._group_rows(SLOTS * BLOCK, TINY.vocab_size) == 16
+    assert kth.group_rows(SLOTS * BLOCK, TINY.vocab_size) == 16
     took, got = serve()
     assert took == {"chunk": "xla_tiled"}
     assert len(got) == len(PRIMES) and got == want
