@@ -18,7 +18,10 @@ per-slot count.  ``dv`` is ``d`` unless the family says otherwise
 arrays are then written by a call each, since one call of the row write
 takes arrays of one shape, and read by the same decode core — on a TPU its
 kernel ``gqa_decode_fwd`` takes the two widths (a grown cache's rows up to
-a slot's count, in key tiles).  A block with a ``sink`` hands its layer's
+a slot's count, in key tiles), and so does the prefill's
+(``gqa_prefill_fwd``, over keys padded to the lane tile on the way in: the
+rows a prefill hands back are the projected ones, at the published width).
+A block with a ``sink`` hands its layer's
 ``p["sink"] (H,)`` to both cores: one more term of every softmax, which
 only their XLA forms have; a ring under ``gqa.MIN_TILE`` rows keeps the XLA
 decode core too (``ops/gqa.py``: "Two widths and a sink").  They differ in

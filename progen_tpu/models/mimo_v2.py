@@ -16,7 +16,8 @@ Trinity, Granite, SDAR, LFM2 and Nemotron-H: MiMo is the block's first user
 of ``v_head_dim`` and ``sink``); the held experts' product, its counters
 and the sigmoid router are ``models/experts.py`` (``experts.sigmoid_route``
 as it stands, at Trinity's ``1e-20``).  The attention cores are
-``ops/gqa.py``, whose XLA forms this family runs on the chip too (below).
+``ops/gqa.py``: its kernels for the full layers, its XLA forms for the
+sliding ones, on the chip too (below).
 
 ``x0 = E[token]``.  Layer ``l`` (pre-norm, ``N_*`` RMSNorms with a learned
 scale, statistics in float32, eps ``layernorm_epsilon`` 1e-5)::
@@ -62,19 +63,23 @@ layer adds the terms of the held experts (``first_expert .. first_expert +
 experts_held - 1``) and leaves out the absent ones'; attention and the dense
 layer are whole on every chip.
 
-**On the chip the full layers' decode core is the kernel**
+**On the chip the FULL layers' cores are the kernels**: the decode step's
 ``gqa_decode_fwd`` (PR 55: it takes keys 192 wide beside values of 128 and
-reads a slot's grown rows up to its count), and everything else of the
-attention the XLA forms: the sliding kind's decode core (a sink, and a ring
-of 128 rows, under ``gqa.MIN_TILE`` — each alone is enough) and both kinds'
-prefill core (a key width of 192 is no multiple of the lane tile, the values
-are another width, the sliding kind has a sink: ``ops/gqa.py``'s
-docstring).  ``ServingEngine.status()["gqa_decode"]`` reads ``"pallas+xla"``
-and ``["gqa_prefill"]`` ``"xla"``.  So a decode step reads every row of the
-RINGS only, and a prefill of a sliding layer computes ``QUERY_BLOCK + 128``
-keys for every ``QUERY_BLOCK`` rows; the counters (``attn.*_rows_read``,
-``attn.*_bytes_read``, ``attn.prefill_pairs_visited``) say what that
-costs.
+reads a slot's grown rows up to its count) and the prefill's
+``gqa_prefill_fwd`` (PR 62: values of 128 as they are, q's and k's heads
+padded with zero columns from 192 to 256 on the way in — the scores are the
+same numbers, the cache rows stay 192 wide, and no float32 score block
+passes through HBM: 50 ms a layer at 1 x 16,384 where the blocked form
+took 160).  **The SLIDING layers keep the XLA forms**, both cores: a sink,
+and a window of 128 — a ring under ``gqa.MIN_TILE`` rows in a step, no
+multiple of the key tile in a prefill — each alone is enough
+(``ops/gqa.py``'s docstring).  ``ServingEngine.status()["gqa_decode"]`` and
+``["gqa_prefill"]`` both read ``"pallas+xla"``.  So a decode step reads
+every row of the RINGS only, and a prefill of a sliding layer computes
+``QUERY_BLOCK + 128`` keys for every ``QUERY_BLOCK`` rows, of a full layer
+the key tiles under the diagonal up to the row's length; the counters
+(``attn.*_rows_read``, ``attn.*_bytes_read``,
+``attn.prefill_pairs_visited``) say what that costs.
 """
 
 from __future__ import annotations

@@ -21,13 +21,17 @@ from what the code can observe and never from a knob (as
 ``ops/mla_prefill.py`` and ``ops/row_write.py``):
 
 * **Pallas kernel** ``gqa_prefill_fwd`` — on a TPU backend, no mesh in
-  scope, 2- or 4-byte floats, ``d`` a multiple of 128 (the lane tile: a
-  block of ``q`` and of the output is one head's columns of ``(R, P, H *
-  d)``, so neither is ever transposed), ``P`` a multiple of ``MIN_TILE``
-  (Trinity's prefill buckets are 512 * 2^k) and ``window`` None or a
-  multiple of the key tile.  So Trinity's head width of 128 takes the
-  kernel, and Granite 4.0-H's 64 (``models/granite_hybrid.py``) the blocked
-  XLA form below, on a TPU too.  One flash kernel for both kinds of block,
+  scope, 2- or 4-byte floats, the values' width ``dv`` (``d`` unless the
+  family says otherwise) a multiple of 128 (the lane tile: a block of ``q``
+  and of the output is one head's columns of ``(R, P, H * d)`` and ``(R, P,
+  H * dv)``, so neither is ever transposed) beside keys of at least 128
+  (padded to the next multiple where they are none: "Two widths and a sink"
+  below), ``P`` a multiple of ``MIN_TILE`` (Trinity's prefill buckets are
+  512 * 2^k) and ``window`` None or a multiple of the key tile.  So
+  Trinity's head width of 128 takes the kernel, MiMo's full layers (keys
+  192 beside values of 128) take it over keys padded to 256, and Granite
+  4.0-H's 64 (``models/granite_hybrid.py``) the blocked XLA form below, on
+  a TPU too.  One flash kernel for both kinds of block,
   ``window`` a static parameter that changes which tiles are visited and
   nothing else.  Grid ``(R, H, P / bq, key steps)``, the key axis
   innermost and RELATIVE: step ``ki`` of query tile ``qi`` is key tile
@@ -167,7 +171,7 @@ Which one a traced call took is noted under ``"gqa_block_decode"``, and
 :func:`rows_visited` counts its rows as the one-query core's (a count of 0
 is no tile).
 
-**Two widths and a sink** (``models/mimo_v2.py``; PR 54, PR 55).  The
+**Two widths and a sink** (``models/mimo_v2.py``; PR 54, PR 55, PR 62).  The
 prefill core and the one-query decode core take values of ANOTHER WIDTH than
 the keys — ``k (..., T, d)`` beside ``v (..., T, dv)``, the output ``H *
 dv`` columns — and an optional learned ``sink (H,)``: one more term of every
@@ -185,20 +189,37 @@ such a row in two lane tiles (256 columns of HBM inside the program), so a
 key tile's fetch costs what a 256-wide one would.  Measured level with two
 products over columns ``[0:128]`` and ``[128:192]`` of the same tile, and
 0.045 ms a call behind keys PADDED to 256 in the cache, which would cost a
-fifth more cache memory (PERF.md section 6, PR 55, has the table).  **What
-still keeps the XLA forms:** a sink (both cores), a ring shorter than
-``MIN_TILE`` rows (MiMo's 128: its sliding layers' decode core), and for the
-PREFILL kernel two widths or a key width that is no multiple of 128 — a
-query block there is one head's columns of ``(R, P, H * d)``, and 192
-columns are no lane multiple.  A window SHORTER than ``QUERY_BLOCK`` costs
-the blocked form a block of ``QUERY_BLOCK + window`` keys for every
-``QUERY_BLOCK`` rows, most of it masked; :func:`pairs_visited` counts it as
-it is.  Without a sink and with ``dv == d`` every function traces the
-program it traced before the arguments existed
-(``tests/test_program_identity.py`` for the XLA forms;
-``tests/test_pallas_gqa_decode.py`` holds the decode kernels' jaxpr text at
-one width).  The block mask and the block form of the decode step take
-neither.
+fifth more cache memory (PERF.md section 6, PR 55, has the table).  **The
+prefill kernel takes two widths too** (PR 62: MiMo's full layers, 64 query
+heads over 4 key/value heads, four fifths of what that family's admissions
+spent in attention): the value block, the accumulator, the output block and
+the output are ``dv`` wide — whole lane tiles, since an output block is one
+head's columns of ``(R, P, H * dv)`` — and the query and key blocks ``d``.
+A query block is one head's columns of ``(R, P, H * d)`` as well, and 192
+columns are no lane multiple: where ``d`` is none, q's heads and k's are
+PADDED WITH ZERO COLUMNS to the next (256) on the way into the kernel
+(:func:`pallas_prefill_attention`) — zero columns add nothing to ``q . k``,
+so the scores are the same numbers, the scale stays the caller's ``192 **
+-0.5``, and the matrix unit, which contracts 128 columns a pass, takes the
+two passes 192 would have cost it.  The cache keeps the published 192-wide
+rows: the padded copies (537 MB of q at 1 x 16,384, 34 MB of k) live for
+one layer's core.  Timed alone at 1 x 16,384 against a query transposed to
+``(R, H, P, 192)`` (a block whose last dimension is the array's own) and
+against two heads a 384-column block: 50.7 | 49.0 | 45.5 ms with what each
+prepares, the blocked form 160.5 (PERF.md section 6, PR 62, has the table
+and why the form that adds nothing to the kernel was taken).  **What still
+keeps the XLA forms:** a sink (both cores), a ring shorter than ``MIN_TILE``
+rows (MiMo's 128: its sliding layers' decode core), a window that is no
+multiple of the key tile (MiMo's 128, dots3's 513 over joined heads of 256
+beside values of 128), values that are no whole lane tiles, and keys under
+one.  A window SHORTER than ``QUERY_BLOCK`` costs the blocked form a block
+of ``QUERY_BLOCK + window`` keys for every ``QUERY_BLOCK`` rows, most of it
+masked; :func:`pairs_visited` counts it as it is.  Without a sink and with
+``dv == d`` every function traces the program it traced before the
+arguments existed (``tests/test_program_identity.py`` for the XLA forms;
+``tests/test_pallas_gqa_decode.py`` and ``tests/test_pallas_gqa_prefill.py``
+hold the kernels' jaxpr text at one width).  The block mask and the block
+form of the decode step take neither.
 
 **A keep mask** (``models/glm_dsa.py``; PR 60).  The prefill core takes an
 optional ``keep (R, P, P)`` — one byte a pair, the same for every head: ``(t,
@@ -490,14 +511,21 @@ def fitted_tile(n: int) -> int:
 def pallas_prefill_attention(q, k, v, lengths, scale, window=None, *,
                              block=1, keep=None, block_q=None, block_k=None,
                              interpret=None):
-    """The kernel lowering.  ``q (R, P, H * d)``, ``k, v (R, KV, P, d)``,
-    ``lengths (R,)`` -> ``(R, P, H * d)``.  ``interpret=None`` auto-selects
+    """The kernel lowering.  ``q (R, P, H * d)``, ``k (R, KV, P, d)``, ``v
+    (R, KV, P, dv)``, ``lengths (R,)`` -> ``(R, P, H * dv)``.  A ``d`` that
+    is no multiple of 128 is PADDED to the next with zero columns, q's heads
+    and k's alike, on the way in (module docstring, "Two widths and a
+    sink"); ``scale`` stays the caller's.  ``interpret=None`` auto-selects
     the Pallas interpreter off-TPU; ``block_q`` / ``block_k`` default to
     :func:`fitted_tile``; ``block`` is the block mask's length (a power of
     two that divides both tiles, no window beside it; ``lengths`` whole
     blocks); ``keep (R, P, P)`` int8 thins the causal pairs (no window and
     no block mask beside it)."""
-    n = k.shape[2]
+    r, _, n, d = k.shape
+    if d % 128:     # zero columns add nothing to ``q . k``
+        columns = ((0, 0),) * 3 + ((0, -d % 128),)
+        q = jnp.pad(q.reshape(r, n, -1, d), columns).reshape(r, n, -1)
+        k = jnp.pad(k, columns)
     bq = block_q or fitted_tile(n)
     bk = block_k or fitted_tile(n)
     if n % bq or n % bk:
@@ -527,6 +555,7 @@ def _flash_call(q, k, v, lengths, *, scale, window, bq, bk, interpret,
     from jax.experimental.pallas import tpu as pltpu
 
     r, kv, n, d = k.shape
+    dv = v.shape[-1]
     heads = q.shape[-1] // d
     group = heads // kv
 
@@ -558,16 +587,16 @@ def _flash_call(q, k, v, lengths, *, scale, window, bq, bk, interpret,
             in_specs=[
                 pl.BlockSpec((1, bq, d), q_map),
                 pl.BlockSpec((1, 1, bk, d), kv_map),
-                pl.BlockSpec((1, 1, bk, d), kv_map),
+                pl.BlockSpec((1, 1, bk, dv), kv_map),
                 *[spec for _, spec in masks],
             ],
             out_specs=pl.BlockSpec(
-                (1, bq, d), lambda ri, hi, qi, ki, len_ref: (ri, qi, hi)),
+                (1, bq, dv), lambda ri, hi, qi, ki, len_ref: (ri, qi, hi)),
             scratch_shapes=[pltpu.VMEM((bq, 1), F32),
                             pltpu.VMEM((bq, 1), F32),
-                            pltpu.VMEM((bq, d), F32)],
+                            pltpu.VMEM((bq, dv), F32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((r, n, heads * d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((r, n, heads * dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
@@ -591,9 +620,13 @@ def prefill_lowering(n: int, d: int, dtype, window, block: int = 1, *,
     ``n`` positions of heads ``d`` wide (values ``dv`` wide: default ``d``)
     in ``dtype``, with a ``sink`` or a ``keep`` mask or without, traced here
     and now (the module docstring has the rule)."""
-    kernel = (_kernel_takes(dtype) and not sink and dv in (None, d)
+    # values of whole lane tiles (an output block is one head's columns of
+    # ``(R, P, H * dv)``) beside keys of at least one, which are padded to
+    # the next where they are no multiple (MiMo's 192 beside 128)
+    dv = d if dv is None else dv
+    kernel = (_kernel_takes(dtype) and not sink
               and not (keep and (window is not None or block != 1))
-              and d % 128 == 0 and n % MIN_TILE == 0
+              and dv % 128 == 0 and d >= 128 and n % MIN_TILE == 0
               and (window is None or window % fitted_tile(n) == 0)
               and (block == 1 or (window is None and not block & (block - 1)
                                   and MIN_TILE % block == 0)))

@@ -438,6 +438,25 @@ def test_gqa_prefill_kernel_compiles_for_v5e(shape, no_persistent_cache,
         shape((r, kv, bucket, d), bf16), shape((r,), jnp.int32))
 
 
+@pytest.mark.parametrize("bucket", [512, 1024, 2048, 4096, 8192, 16384])
+def test_gqa_prefill_kernel_compiles_for_v5e_at_two_widths(
+        shape, no_persistent_cache, bucket):
+    """``gqa_prefill_fwd`` as a FULL layer of ``serve-mimo-longdoc-backlog``
+    admits (PR 62): 1 row, 64 query heads over 4 key/value heads, keys 192
+    wide — padded to 256 on the way in — beside values of 128, bfloat16,
+    each prefill bucket the cell warms; the output is ``H * 128`` wide."""
+    from progen_tpu.ops.gqa import pallas_prefill_attention
+
+    r, heads, kv, d, dv, bf16 = 1, 64, 4, 192, 128, jnp.bfloat16
+    fn = jax.jit(lambda q, k, v, n: pallas_prefill_attention(
+        q, k, v, n, d ** -0.5, interpret=False))
+    args = (shape((r, bucket, heads * d), bf16),
+            shape((r, kv, bucket, d), bf16), shape((r, kv, bucket, dv), bf16),
+            shape((r,), jnp.int32))
+    assert "tpu_custom_call" in fn.lower(*args).compile().as_text()
+    assert fn.eval_shape(*args).shape == (r, bucket, heads * dv)
+
+
 @pytest.mark.parametrize("slots,heads,max_len", [(64, 128, 3072),
                                                  (32, 64, 4096)],
                          ids=["dsv2", "longcat"])
@@ -764,7 +783,9 @@ WHOLE_PROGRAMS = {
     # layers 0-6 of 48, an eighth of the vocabulary, 16 slots of 128-row
     # rings and 17,408 grown rows: the full layers' core ``gqa_decode_fwd``
     # at two widths since PR 55, the rings' core the XLA form; 1 row at the
-    # 16,384 bucket through ``moe_sorted_fwd`` and the blocked XLA attention
+    # 16,384 bucket through ``moe_sorted_fwd``, the full layers' core
+    # ``gqa_prefill_fwd`` over keys padded to 256 (PR 62: no float32 score
+    # block of theirs is a buffer) and the sliding layers' blocked XLA form
     "mimo": Whole(
         "serve-mimo-longdoc-backlog", "mimo_v2",
         lambda m: _perf_config(m, "MiMoV2Config", "mimo-v2.5-ep16"),
@@ -772,9 +793,9 @@ WHOLE_PROGRAMS = {
         weights=(6.85e9, 6.87e9), state=(1.4e9, 1.6e9),
         chunk=("tpu_custom_call", "gqa_decode_fwd", "moe_decode_fwd",
                "row_write"),
-        admission=("tpu_custom_call", "moe_sorted_fwd"),
-        never={"chunk": ("gqa_prefill_fwd",),
-               "admit": ("gqa_prefill_fwd", "gqa_decode_fwd")}),
+        admission=("tpu_custom_call", "moe_sorted_fwd", "gqa_prefill_fwd"),
+        never={"chunk": ("gqa_prefill_fwd",), "admit": ("gqa_decode_fwd",)},
+        no_buffers={"admit": ("f32[4,16,256", "bf16[4,1,4,16,256,128]")}),
     # layers 0-4 of 46, an eighth of the vocabulary, 16 slots of 17,408
     # rows: the indexer's score and ``top_k`` over every slot's rows, the
     # gathered 2,048 rows through ``mla_decode_fwd``; 1 row at the 16,384

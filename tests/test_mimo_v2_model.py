@@ -473,10 +473,12 @@ def test_decode_core_with_a_sink_and_two_widths(with_sink):
 def test_the_decode_kernel_takes_two_widths_and_the_rest_is_declined(
         monkeypatch):
     """On a TPU: the one-query decode kernel takes the FULL layers' grown
-    caches, keys 192 wide beside values of 128 (PR 55); a sink or a ring
-    under a tile, each alone, keeps the sliding layers on the XLA decode
-    core, and the PREFILL kernel still declines a sink, two widths, a key
-    width of 192 and a window of 128 (``ops/gqa.py``'s docstring)."""
+    caches, keys 192 wide beside values of 128 (PR 55), and the PREFILL
+    kernel the same two widths (PR 62: the keys padded to 256 on the way
+    in); a sink or a ring under a tile, each alone, keeps the sliding layers
+    on the XLA decode core, and the prefill kernel still declines a sink,
+    values that are no whole lane tiles — 64, or 192 at one width — and a
+    window of 128 (``ops/gqa.py``'s docstring)."""
     monkeypatch.setattr(gqa, "_on_tpu", lambda: True)
     bf = jnp.bfloat16
     assert gqa.prefill_lowering(1024, 128, bf, None) == "pallas"
@@ -484,7 +486,7 @@ def test_the_decode_kernel_takes_two_widths_and_the_rest_is_declined(
     assert gqa.prefill_lowering(1024, 128, bf, None, sink=True) == "xla"
     assert gqa.prefill_lowering(1024, 128, bf, None, dv=64) == "xla"
     assert gqa.prefill_lowering(1024, 192, bf, None) == "xla"
-    assert gqa.prefill_lowering(1024, 192, bf, None, dv=128) == "xla"
+    assert gqa.prefill_lowering(1024, 192, bf, None, dv=128) == "pallas"
     assert gqa.prefill_lowering(1024, 128, bf, 128) == "xla"
     sd = jax.ShapeDtypeStruct
     k = sd((16, 4, 1024, 128), bf)

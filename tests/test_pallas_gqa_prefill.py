@@ -10,7 +10,10 @@ against a brute-force count; the pair counters; the choice of lowering
 from backend, mesh and shape, as ``status()`` shows it; and (PR 60) the
 ``keep`` operand: one byte a pair that thins the causal pairs, in both
 lowerings against a dense masked softmax, with rows whose first tiles keep
-nothing."""
+nothing; and (PR 62) keys 192 wide beside values of 128 — MiMo's full
+layers: the kernel pads the keys to 256 on the way in —, the rule that sends
+such a block to the kernel, MiMo's engine through it, and the kernel's
+jaxpr text at ONE width held to the parent's."""
 
 import dataclasses
 import functools
@@ -24,8 +27,10 @@ from perf.lib import reference_trinity as ref
 from progen_tpu.models import trinity as tr
 from progen_tpu.ops import gqa
 from progen_tpu.ops.lowering import record_lowerings
+from tests import mimo_v2_tiny
 from tests.families import fresh, reference
 from tests.trinity_tiny import TINY, make
+from tools import program_hash
 
 D, R, P, TILE = 128, 2, 512, 128
 SCALE = D ** -0.5
@@ -46,38 +51,38 @@ def _operands(p, group, dtype, seed=0, rows=R):
             normal(ks[2], (rows, kv, p, D)))
 
 
-def _kernel(q, k, v, lengths, window, **tiles):
+def _kernel(q, k, v, lengths, window, scale=SCALE, **tiles):
     r, p, heads, d = q.shape
     with jax.default_matmul_precision("highest"):
         return gqa.pallas_prefill_attention(
             q.reshape(r, p, heads * d), k, v, jnp.asarray(lengths, jnp.int32),
-            SCALE, window, interpret=True, **tiles)
+            scale, window, interpret=True, **tiles)
 
 
-def _blocked(q, k, v, window):
+def _blocked(q, k, v, window, scale=SCALE):
     """One compiled program a shape and window (eagerly the blocks are
     dispatched op by op)."""
     with jax.default_matmul_precision("highest"):
         return jax.jit(gqa.blocked_prefill_attention, static_argnums=(3, 4))(
-            q, k, v, SCALE, window)
+            q, k, v, scale, window)
 
 
-def _dense(q, k, v, window):
+def _dense(q, k, v, window, scale=SCALE):
     """Every position against every key under the mask, float64."""
     q, k, v = (np.asarray(a.astype(jnp.float32), np.float64)
                for a in (q, k, v))
-    r, p, heads, d = q.shape
-    group = heads // k.shape[1]
+    r, p, heads, _ = q.shape
+    group, dv = heads // k.shape[1], v.shape[-1]
     gap = np.arange(p)[:, None] - np.arange(p)[None, :]
     seen = gap >= 0 if window is None else (gap >= 0) & (gap < window)
-    out = np.zeros((r, p, heads, d))
+    out = np.zeros((r, p, heads, dv))
     for h in range(heads):
-        s = np.einsum("rqd,rkd->rqk", q[:, :, h], k[:, h // group]) * SCALE
+        s = np.einsum("rqd,rkd->rqk", q[:, :, h], k[:, h // group]) * scale
         s = np.where(seen, s, -np.inf)
         w = np.exp(s - s.max(-1, keepdims=True))
         out[:, :, h] = np.einsum("rqk,rkd->rqd", w / w.sum(-1, keepdims=True),
                                  v[:, h // group])
-    return out.reshape(r, p, heads * d)
+    return out.reshape(r, p, heads * dv)
 
 
 def _f32(x):
@@ -553,3 +558,220 @@ def test_engine_states_the_lowering_and_publishes_the_pair_counters():
     snap = get_registry().snapshot()
     assert snap["attn.prefill_pairs_allowed"]["value"] == allowed
     assert snap["attn.prefill_pairs_visited"]["value"] == visited
+
+
+# ---- two widths: keys 192 beside values of 128 (MiMo's full layers; PR 62) --
+
+D2, DV2, GROUP2 = 192, 128, 16
+SCALE2 = D2 ** -0.5
+
+
+@functools.lru_cache(maxsize=4)      # the two dtypes under the two windows
+def _two_widths(dtype, window):
+    """``(q (3, P, 16, 192), k (3, 1, P, 192), v (3, 1, P, 128))`` with O(1)
+    logits, and the blocked form's and the dense reference's outputs."""
+    ks = jax.random.split(jax.random.key(62), 3)
+    dt = jnp.dtype(dtype)
+    q = jax.random.normal(ks[0], (3, P, GROUP2, D2), jnp.float32).astype(dt)
+    k = (3.0 * jax.random.normal(ks[1], (3, 1, P, D2), jnp.float32)).astype(dt)
+    v = jax.random.normal(ks[2], (3, 1, P, DV2), jnp.float32).astype(dt)
+    return (q, k, v), _f32(_blocked(q, k, v, window, SCALE2)), _dense(
+        q, k, v, window, SCALE2)
+
+
+@pytest.mark.parametrize("lengths", [[P - 100, 129, 0], [P, 1, 2 * TILE]],
+                         ids=str)
+@pytest.mark.parametrize("window", [None, TILE], ids=["no-window", "one-tile"])
+@pytest.mark.parametrize("dtype,tiles", [
+    ("bfloat16", (128, 128)), ("float32", (256, 128)),
+    ("bfloat16", (128, 256))], ids=lambda v: str(v))
+def test_the_kernel_takes_keys_192_wide_beside_values_of_128(
+        dtype, tiles, window, lengths):
+    """MiMo's full head shape, 16 query heads a key/value head: ragged rows
+    with pads and an empty row against the blocked XLA form and a dense
+    reference at every real position, within the file's tolerance; the
+    output is ``H * 128`` wide; tiles past a row's length read as zeros.
+    (The window beside two widths is no cell's today; the kernel takes it.)"""
+    (q, k, v), blocked, dense = _two_widths(dtype, window)
+    got = _f32(_kernel(q, k, v, lengths, window, SCALE2, block_q=tiles[0],
+                       block_k=tiles[1]))
+    assert got.shape == (3, P, GROUP2 * DV2) == blocked.shape
+    assert np.isfinite(got).all() and float(np.abs(dense).max()) > 1.0
+    for row, n in enumerate(lengths):
+        if n:
+            assert float(np.abs(got[row, :n] - blocked[row, :n]).max()) \
+                < TOL[dtype]
+            assert float(np.abs(got[row, :n] - dense[row, :n]).max()) \
+                < TOL[dtype]
+        first_dead = -(-n // tiles[0]) * tiles[0]
+        assert not got[row, first_dead:].any()
+
+
+def test_padded_key_columns_change_no_bit():
+    """The kernel over keys 192 wide IS the kernel over the same heads with
+    64 zero columns written behind each by hand, bit for bit."""
+    (q, k, v), _, _ = _two_widths("bfloat16", None)
+    lengths = [P - 100, 129, 0]
+    columns = ((0, 0),) * 3 + ((0, 256 - D2),)
+    kw = dict(block_q=128, block_k=256)
+    got = _kernel(q, k, v, lengths, None, SCALE2, **kw)
+    by_hand = _kernel(jnp.pad(q, columns), jnp.pad(k, columns), v, lengths,
+                      None, SCALE2, **kw)
+    np.testing.assert_array_equal(_f32(got), _f32(by_hand))
+
+
+# what ``prefill_lowering`` answers on a TPU with no mesh in scope, bfloat16:
+# (n, d, window, further arguments) -> the lowering
+LOWERINGS = {
+    "mimo-full-16384": ((16384, 192, None, dict(dv=128)), "pallas"),
+    "mimo-full-512": ((512, 192, None, dict(dv=128)), "pallas"),
+    # a sink; a window of 128 under a tile: each alone is enough
+    "mimo-sliding": ((16384, 192, 128, dict(dv=128, sink=True)), "xla"),
+    "mimo-sliding-without-its-sink": ((16384, 192, 128, dict(dv=128)), "xla"),
+    "mimo-full-with-a-sink": ((16384, 192, None, dict(dv=128, sink=True)),
+                              "xla"),
+    "two-widths-under-a-tiled-window": ((2048, 192, 1024, dict(dv=128)),
+                                        "pallas"),
+    "granite-64": ((1024, 64, None, {}), "xla"),
+    "trinity-sliding": ((8192, 128, 2048, {}), "pallas"),
+    "glm52-joined": ((16384, 256, None, dict(keep=True)), "pallas"),
+    # joined heads of 256 beside values of 128: the window of 513 declines
+    "dots3-sliding": ((16384, 256, 513, dict(dv=128)), "xla"),
+    # an output block is one head's dv columns: 192 of them are off the lane
+    "192-at-one-width": ((1024, 192, None, {}), "xla"),
+    "values-of-192": ((1024, 256, None, dict(dv=192)), "xla"),
+    # keys under a lane tile would be padded to twice their width and more
+    "64-beside-128": ((1024, 64, None, dict(dv=128)), "xla"),
+}
+
+
+@pytest.mark.parametrize("case", list(LOWERINGS))
+def test_the_lowering_table(monkeypatch, case):
+    (n, d, window, more), want = LOWERINGS[case]
+    assert gqa.prefill_lowering(n, d, jnp.bfloat16, window, **more) == "xla"
+    monkeypatch.setattr(gqa, "_on_tpu", lambda: True)
+    assert gqa.prefill_lowering(n, d, jnp.bfloat16, window, **more) == want
+
+
+def test_on_tpu_mimos_full_block_traces_one_kernel_over_padded_keys(
+        monkeypatch):
+    """The cell's own 16,384 bucket: the call is ``gqa_prefill_fwd`` over q
+    of ``64 x 256`` columns and keys of 256, values of 128 as they came, no
+    float32 score block, the output where ``wo`` reads it; the sliding kind
+    keeps the blocked form."""
+    r, n, heads, kv = 1, 16384, 64, 4
+    monkeypatch.setattr(gqa, "_on_tpu", lambda: True)
+    sd, bf = jax.ShapeDtypeStruct, jnp.bfloat16
+    q, k, v = (sd((r, n, heads, D2), bf), sd((r, kv, n, D2), bf),
+               sd((r, kv, n, DV2), bf))
+    lengths = sd((r,), jnp.int32)
+    with record_lowerings() as chosen:
+        closed = jax.make_jaxpr(lambda q, k, v, m: gqa.prefill_attention(
+            q, k, v, SCALE2, lengths=m))(q, k, v, lengths)
+    jaxpr = str(closed)
+    assert chosen["gqa_prefill"] == {"pallas"}
+    assert jaxpr.count("pallas_call") == jaxpr.count(
+        "name=gqa_prefill_fwd") == 1
+    assert closed.out_avals[0].shape == (r, n, heads * DV2)
+    for operand in (f"bf16[{r},{n},{heads * 256}]", f"bf16[{r},{kv},{n},256]",
+                    f"bf16[{r},{kv},{n},{DV2}]"):
+        assert operand in jaxpr, operand
+    assert f"f32[{r},{kv},{heads // kv},256," not in jaxpr
+    assert "transpose" not in jaxpr
+    sink = sd((heads,), bf)
+    with record_lowerings() as chosen:
+        jax.make_jaxpr(lambda q, k, v, m, s: gqa.prefill_attention(
+            q, k, v, SCALE2, 128, m, sink=s))(q, k, v, lengths, sink)
+    assert chosen["gqa_prefill"] == {"xla"}
+
+
+MIMO_WIDE = dataclasses.replace(
+    mimo_v2_tiny.TINY, head_dim=D2, v_head_dim=DV2, num_key_value_heads=2,
+    max_position_embeddings=1024)
+
+
+def test_mimo_engine_admits_through_the_kernel_and_serves_the_same_tokens(
+        monkeypatch):
+    """MiMo's engine at the published FULL head widths, a prime of 300 in the
+    512 bucket: on the CPU both kinds admit through the blocked form; with
+    the kernel forced (interpreter, tiles of 128) the full layers take it and
+    the sliding ones — a sink, a window of 4 — do not:
+    ``status()["gqa_prefill"]`` names both, the greedy tokens are the same,
+    and ``attn.prefill_pairs_visited`` counts the full layers' six visited
+    tiles where it counted two blocks of 256 rows against 512 keys."""
+    from progen_tpu.decode import Request, ServingEngine
+    from progen_tpu.decode.engine import SLOTS_PER_ADMIT_ROW
+    from progen_tpu.models import mimo_v2 as mm
+
+    params, policy = mimo_v2_tiny.make(MIMO_WIDE)
+    prime = np.random.default_rng(0).integers(1, MIMO_WIDE.vocab_size, 300)
+
+    def serve():
+        eng = ServingEngine(MIMO_WIDE, params, policy=policy,
+                            num_slots=SLOTS_PER_ADMIT_ROW, chunk_size=4,
+                            max_len=P + 8)
+        eng.submit(Request(uid=0, tokens=prime.tolist(), max_new_tokens=5,
+                           temperature=0.0, seed=1))
+        (done,) = eng.run_until_idle(max_chunks=10)
+        return list(done.tokens), eng.status(), eng.model_stats
+
+    want, status, stats = serve()
+    assert status["gqa_prefill"] == "xla"
+    blocks = mm.blocks_of(MIMO_WIDE).values()
+    full = [b for b in blocks if b.window is None]
+    sliding = float(sum(gqa.pairs_visited(jnp.array([300]), P, b.window, "xla")
+                        for b in blocks if b.window is not None))
+    assert len(full) == 2 and stats["attn.prefill_pairs_visited"] == (
+        sliding + len(full) * P * P)
+    _force_kernel(monkeypatch)
+    got, status, kernel_stats = serve()
+    assert status["gqa_prefill"] == "pallas+xla"
+    assert got == want
+    assert kernel_stats["attn.prefill_pairs_visited"] == (
+        sliding + len(full) * 6 * TILE ** 2)
+    assert kernel_stats["attn.prefill_pairs_allowed"] == stats[
+        "attn.prefill_pairs_allowed"]
+
+
+# sha256 heads of ``gqa_prefill_fwd``'s jaxpr text at ONE head width, taken
+# on PR 61's tree (c9e78f8) before the kernel took values of another width:
+# ``(R, P, H, KV, d)`` of an admission of Trinity's, SDAR's and GLM-5.2's
+# cells and what else the call takes.  They trace what they traced, letter
+# for letter (``tests/golden/programs.json`` is the CPU's trace and holds no
+# Pallas lowering)
+KERNEL_TEXT = {
+    "trinity-sliding-512": ((4, 512, 32, 4, 128), dict(window=2048),
+                            "0a2c07812635734c"),
+    "trinity-sliding-8192": ((4, 8192, 32, 4, 128), dict(window=2048),
+                             "6bf03e5c42328570"),
+    "trinity-full-512": ((4, 512, 32, 4, 128), {}, "321e962f59be05a8"),
+    "trinity-full-8192": ((4, 8192, 32, 4, 128), {}, "1ea2364b97369372"),
+    "sdar-block-1024": ((4, 1024, 32, 4, 128), dict(block=4),
+                        "61ffed2d28f9dc3b"),
+    "glm52-keep-512": ((1, 512, 64, 64, 256), dict(keep=True),
+                       "16bc43dcde0c5033"),
+    "glm52-keep-8192": ((1, 8192, 64, 64, 256), dict(keep=True),
+                        "2985c4281a3a098c"),
+    "glm52-keep-16384": ((1, 16384, 64, 64, 256), dict(keep=True),
+                         "fdbe6ed782e6e708"),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_TEXT))
+def test_at_one_width_the_kernel_traces_the_text_it_traced(case):
+    """The cells' own shapes, bfloat16, interpreter, the tiles the chip path
+    takes."""
+    (r, n, heads, kv, d), opts, want = KERNEL_TEXT[case]
+    opts = dict(opts)
+    window = opts.pop("window", None)
+    sd, bf = jax.ShapeDtypeStruct, jnp.bfloat16
+    shapes = [sd((r, n, heads * d), bf), sd((r, kv, n, d), bf),
+              sd((r, kv, n, d), bf), sd((r,), jnp.int32)]
+    if opts.pop("keep", False):
+        shapes.append(sd((r, n, n), jnp.int8))
+    head = program_hash.program_head(
+        lambda q, k, v, m, *keep: gqa.pallas_prefill_attention(
+            q, k, v, m, 0.1, window, interpret=True, **opts,
+            **({"keep": keep[0]} if keep else {})),
+        tuple(shapes))
+    assert head == want
