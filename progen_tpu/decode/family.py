@@ -185,7 +185,9 @@ class ProGenFamily:
 # latents, a gate a head on both; the tenth, GLM-5.2, a share over latent
 # attention of ONE shape under a selection in every layer, which one layer in
 # four computes (an indexer's second cache leaf) and the next three borrow
-# (the plain leaf)
+# (the plain leaf); the eleventh, Qwen3-Next, a share under three delta-rule
+# layers (a float32 state that erases before it writes, no keys) to one
+# gated full-attention layer
 _DRIVER_FAMILIES = (
     ("progen_tpu.models.longcat", "LongCatConfig", "LongCatFamily"),
     ("progen_tpu.models.deepseek_v2", "DeepSeekV2Config", "DeepSeekV2Family"),
@@ -198,6 +200,7 @@ _DRIVER_FAMILIES = (
     ("progen_tpu.models.mimo_v2", "MiMoV2Config", "MiMoV2Family"),
     ("progen_tpu.models.dots3", "Dots3Config", "Dots3Family"),
     ("progen_tpu.models.glm_dsa", "GLMDSAConfig", "GLMDSAFamily"),
+    ("progen_tpu.models.qwen3_next", "Qwen3NextConfig", "Qwen3NextFamily"),
 )
 
 

@@ -2,8 +2,9 @@
 experts the chip holds, its window, and the device counters the families
 with such a layer carry (``models/longcat.py``, ``models/deepseek_v2.py``,
 ``models/trinity.py``, ``models/sdar.py``, ``models/lfm2.py``,
-``models/nemotron_h.py``), and the sigmoid router three of them share
-(:func:`sigmoid_route`).
+``models/nemotron_h.py``, ``models/qwen3_next.py``), the sigmoid router
+three of them share (:func:`sigmoid_route`) and the softmax router of two
+(:func:`softmax_route`).
 
 **Two expert forms**, told apart by what ``experts`` holds and never by a
 knob: ``{"wg", "wu", "wd"}``, a gated SwiGLU of three matrices, ``(silu(u
@@ -110,6 +111,21 @@ def sigmoid_route(u, router, topk: int, *, norm: bool, scale: float,
         if norm:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
         return ids, w * scale
+
+
+def softmax_route(u, router, topk: int, *, norm: bool):
+    """The softmax router of the Qwen3-MoE layers (``models/sdar.py``: top-8
+    of 128; ``models/qwen3_next.py``: top-10 of 512): ``(ids (T, k), weights
+    (T, k))``, float32 throughout: the ``topk`` largest of ``softmax(u
+    W_r)`` over the whole router, renormalised to sum to 1 where ``norm``
+    (``norm_topk_prob``)."""
+    with jax.named_scope("moe.router"):
+        logits = jnp.dot(u.astype(F32), router["w"].astype(F32),
+                         precision=jax.lax.Precision.HIGHEST)
+        w, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), topk)
+        if norm:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        return ids, w
 
 
 def moe_capacity(c, tokens: int) -> int:

@@ -256,14 +256,8 @@ def prefill_attention_stats(c: SDARConfig, n: int, lengths, dt) -> dict:
 def route(u, router, c: SDARConfig):
     """``(ids (T, k), weights (T, k))``, float32 throughout: the largest of
     ``softmax(u W_r)``, renormalised to sum to 1 (``norm_topk_prob``)."""
-    with jax.named_scope("moe.router"):
-        logits = jnp.dot(u.astype(F32), router["w"].astype(F32),
-                         precision=jax.lax.Precision.HIGHEST)
-        w, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
-                               c.num_experts_per_tok)
-        if c.norm_topk_prob:
-            w = w / jnp.sum(w, axis=-1, keepdims=True)
-        return ids, w
+    return experts.softmax_route(u, router, c.num_experts_per_tok,
+                                 norm=c.norm_topk_prob)
 
 
 STAT_KEYS = experts.STAT_KEYS + ATTN_STAT_KEYS

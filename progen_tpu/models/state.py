@@ -1,5 +1,9 @@
-"""A Mamba-2 mixer that carries a recurrent state, and what it states about
-its cache (``models/driver.py`` says what a block is).  Shared by the
+"""The mixers that carry a recurrent state, and what each states about its
+cache (``models/driver.py`` says what a block is): :class:`StateBlock`, a
+Mamba-2 mixer, and :class:`DeltaBlock`, a gated delta-rule mixer (below it,
+with its own equations).
+
+**StateBlock.**  Shared by the
 families with such layers (``models/granite_hybrid.py``: one B/C group,
 beside an MLP in every layer; ``models/nemotron_h.py``: eight groups, the
 layer's only sublayer), as ``models/kv.py`` is by those whose attention is
@@ -47,7 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from progen_tpu.models.driver import F32, init_norm, mm, normal, rms_norm
-from progen_tpu.ops import ssd
+from progen_tpu.ops import gdn, ssd
 
 
 def _log_uniform(key, shape, lo, hi):
@@ -195,3 +199,193 @@ def prefill_stats(blocks: dict, tokens_shape, lengths) -> dict:
     slots = sum(ssd.scanned_slots(*tokens_shape, b.chunk) for b in mine)
     return {"ssm.prefill_tokens": len(mine) * jnp.sum(lengths).astype(F32),
             "ssm.prefill_slots": jnp.asarray(slots, F32)}
+
+
+# --------------------------------------------------------- the delta rule
+
+
+class DeltaBlock:
+    """One gated delta-rule mixer (``ops/gdn.py`` has the recurrence):
+    ``key_heads`` key heads of ``key_dim`` feeding ``value_heads`` value
+    heads of ``value_dim`` (value head ``j`` reads key head ``j //
+    (value_heads / key_heads)``), a convolution of ``conv`` taps with no
+    bias, a norm at ``eps`` and a prefill in chunks of ``chunk`` tokens.
+    ``Kw = key_heads * key_dim``, ``Vw = value_heads * value_dim``, ``u (...,
+    h)`` the normed stream::
+
+        [q (Kw) | k (Kw) | v (Vw) | z (Vw)] = u W_qkvz       (no bias)
+        [b (value_heads) | a (value_heads)] = u W_ba
+        [q|k|v]_t <- silu(sum_j w_conv[:, j] * [q|k|v]_{t-(K-1)+j})
+        q <- q rsqrt(sum q^2 + 1e-6) key_dim^-1/2,  k <- k rsqrt(sum k^2 + 1e-6)
+        beta = sigmoid(b),  g = -exp(A_log) softplus(a + dt_bias)   (float32)
+        S_t = exp(g_t) S_{t-1} + k_t (x) beta_t (v_t - exp(g_t) S_{t-1}^T k_t)
+        o_t = S_t^T q_t
+        out = [RMSNorm_w(o_t) * silu(z_t)] W_out
+
+    the l2 norms a head and in float32, the gated norm over each value
+    head's ``value_dim`` channels with ONE plain weight ``w (value_dim,)``
+    for every head and the gate AFTER it (Mamba-2's gate comes before its
+    norm: :class:`StateBlock`'s is not this one).
+
+    **The cache**: ``{"state": (slots, value_heads, key_dim, value_dim)
+    float32, "conv": (slots, K - 1, 2 Kw + Vw)}``, whatever ``max_len``:
+    2.10 MB + 49 KB a slot and layer at Qwen3-Next's widths, read AND
+    written every token.  What a prefill hands over, what an admission
+    overwrites and what rows that are not live do is as
+    :class:`StateBlock`'s (every decay is at most 1, every key a unit
+    vector and every write strength under 1)."""
+
+    L2_EPS = 1e-6
+
+    def __init__(self, key_heads: int, value_heads: int, key_dim: int,
+                 value_dim: int, conv: int, eps: float, chunk: int):
+        if value_heads % key_heads:
+            raise ValueError(f"{value_heads} value heads do not split over "
+                             f"{key_heads} key heads")
+        self.key_heads, self.value_heads = key_heads, value_heads
+        self.key_dim, self.value_dim = key_dim, value_dim
+        self.conv, self.eps, self.chunk = conv, eps, chunk
+        self.key_width = key_heads * key_dim
+        self.value_width = value_heads * value_dim
+        self.conv_channels = 2 * self.key_width + self.value_width
+
+    def init_weights(self, key, hidden: int, dt, dt_range, a_range) -> dict:
+        """Seeded weights: per value head ``softplus(dt_bias)`` and ``A``
+        drawn log-uniform from ``dt_range`` / ``a_range`` (their product is
+        minus the log of a step's decay where ``a`` is 0)."""
+        heads = self.value_heads
+        ks = jax.random.split(key, 7)
+        step = _log_uniform(ks[3], (heads,), *dt_range)
+        return {
+            "in_proj": normal(
+                ks[0], (hidden, self.conv_channels + self.value_width),
+                hidden ** -0.5, dt),
+            "ba_proj": normal(ks[1], (hidden, 2 * heads), hidden ** -0.5, dt),
+            "conv_w": normal(ks[2], (self.conv_channels, self.conv),
+                             self.conv ** -0.5, dt),
+            # the recurrence's own parameters stay float32, as the releases
+            # keep them; softplus(dt_bias) is the drawn step
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "a_log": jnp.log(_log_uniform(ks[4], (heads,), *a_range)),
+            "norm": init_norm(ks[5], (self.value_dim,), dt),
+            "out_proj": normal(ks[6], (self.value_width, hidden),
+                               self.value_width ** -0.5, dt),
+        }
+
+    def state_bytes(self) -> int:
+        """One slot's float32 carry."""
+        return self.value_heads * self.key_dim * self.value_dim * 4
+
+    def init_cache(self, slots: int, max_len: int, dtype):
+        return {"state": jnp.zeros((slots, self.value_heads, self.key_dim,
+                                    self.value_dim), F32),
+                "conv": jnp.zeros((slots, self.conv - 1,
+                                   self.conv_channels), dtype)}
+
+    def _project(self, x, p, columns=None):
+        """``columns`` of ``u W_qkvz``: all of them a step; an admission
+        takes ``[q | k | v]`` first and the gate ``z`` when the recurrence
+        is done (two rows of 16,384 tokens' ``z`` are half a gigabyte that
+        nothing reads until then)."""
+        w = p["in_proj"] if columns is None else p["in_proj"][:, columns]
+        with jax.named_scope("gdn.in_proj"):
+            return mm(x, w)
+
+    def _strengths(self, x, p):
+        """``(beta, g)``: the write strength and the log of the decay a
+        value head, float32."""
+        with jax.named_scope("gdn.in_proj"):
+            ba = mm(x, p["ba_proj"]).astype(F32)
+        heads = self.value_heads
+        return jax.nn.sigmoid(ba[..., :heads]), -jnp.exp(
+            p["a_log"]) * jax.nn.softplus(ba[..., heads:] + p["dt_bias"])
+
+    def _split_conv(self, qkv):
+        """``[q | k | v]`` after the convolution as heads: q and k unit
+        vectors in float32, rounded once."""
+        kw = self.key_width
+        keys = (self.key_heads, self.key_dim)
+
+        def unit(x):
+            x = x.reshape(x.shape[:-1] + keys).astype(F32)
+            return x * jax.lax.rsqrt(
+                jnp.sum(x * x, axis=-1, keepdims=True) + self.L2_EPS)
+
+        q = unit(qkv[..., :kw]) * self.key_dim ** -0.5
+        k = unit(qkv[..., kw:2 * kw])
+        v = qkv[..., 2 * kw:]
+        v = v.reshape(v.shape[:-1] + (self.value_heads, self.value_dim))
+        return q.astype(qkv.dtype), k.astype(qkv.dtype), v
+
+    def _out(self, o, z, p):
+        """``o`` from the recurrence (float32 from a step, rounded once
+        from the chunked form): the norm a head, THEN the gate, the output
+        projection."""
+        with jax.named_scope("gdn.norm"):
+            o = rms_norm(o.astype(z.dtype), p["norm"], self.eps)
+            o = o.reshape(z.shape) * jax.nn.silu(z)
+        with jax.named_scope("gdn.out_proj"):
+            return mm(o, p["out_proj"])
+
+    def prefill(self, u, p, lengths):
+        """The mixer over ``u (R, P, h)``; what the slot will hold is the
+        carry at each row's true length and its last ``K - 1`` real
+        convolution inputs."""
+        split = self.conv_channels
+        qkv = self._project(u, p, slice(None, split))
+        beta, g = self._strengths(u, p)
+        with jax.named_scope("gdn.conv"):
+            tail = ssd.conv_tail(qkv, lengths, self.conv)
+            qkv = jax.nn.silu(ssd.causal_conv(
+                qkv, p["conv_w"], None)).astype(u.dtype)
+        q, k, v = self._split_conv(qkv)
+        with jax.named_scope("gdn.scan"):
+            o, state = gdn.gdn_scan(q, k, v, g, beta, lengths, self.chunk)
+        z = self._project(u, p, slice(split, None))
+        return self._out(o, z, p), {"state": state, "conv": tail}
+
+    def cache_rows(self, rows, lengths, max_len: int):
+        return rows
+
+    def decode(self, u, pos, cache, p):
+        """One token a row: the tail shifted, the carry decayed, erased
+        under the new key and written."""
+        qkvz = self._project(u, p)
+        qkv, z = (qkvz[..., :self.conv_channels],
+                  qkvz[..., self.conv_channels:])
+        beta, g = self._strengths(u, p)
+        with jax.named_scope("gdn.conv"):
+            qkv, tail = ssd.conv_step(cache["conv"], qkv, p["conv_w"], None)
+            qkv = jax.nn.silu(qkv).astype(u.dtype)
+        q, k, v = self._split_conv(qkv)
+        with jax.named_scope("gdn.step"):
+            o, state = gdn.gdn_step(cache["state"], q, k, v, g, beta)
+        return self._out(o, z, p), {"state": state, "conv": tail}
+
+
+# the delta block's device counters, all float32 sums
+# (docs/OBSERVABILITY.md section 3): the token slots the chunked form
+# computed in all delta layers of a prefill (padding and partial chunks
+# included) and the real prime tokens x delta layers among them; the carry
+# bytes the decode steps had to read and write: each LIVE row's carry once
+# each way in every delta layer (the static batch moves the idle slots' too)
+DELTA_STAT_KEYS = ("gdn.scan_slots", "gdn.real_tokens", "gdn.state_bytes")
+
+
+def _delta_blocks(blocks: dict) -> list:
+    return [b for b in blocks.values() if isinstance(b, DeltaBlock)]
+
+
+def delta_decode_stats(blocks: dict, live) -> dict:
+    """A decode step's ``gdn.*`` counter."""
+    moved = sum(2 * b.state_bytes() for b in _delta_blocks(blocks))
+    return {"gdn.state_bytes": moved * jnp.sum(live).astype(F32)}
+
+
+def delta_prefill_stats(blocks: dict, tokens_shape, lengths) -> dict:
+    """A prefill's ``gdn.*`` counters over rows of ``lengths`` padded to
+    ``tokens_shape = (R, P)``."""
+    mine = _delta_blocks(blocks)
+    slots = sum(gdn.scanned_slots(*tokens_shape, b.chunk) for b in mine)
+    return {"gdn.real_tokens": len(mine) * jnp.sum(lengths).astype(F32),
+            "gdn.scan_slots": jnp.asarray(slots, F32)}

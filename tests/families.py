@@ -160,6 +160,11 @@ CASES = {case.name: case for case in (
                "reference_glm52", primes=(2, 5, 7, 8, 9, 21), greedy=(6,),
                counted=dict(n=3, seed=5, primes=(3, 13, 6)),
                forward=dict(q_block=8)),
+    # three shorter than the four taps; 9, 12 and 21 cross chunks of 4
+    FamilyCase("qwen3_next", "qwen3_next", "Qwen3NextFamily",
+               "qwen3_next_tiny", "reference_qwen3next",
+               primes=(3, 12, 1, 21, 2, 9), greedy=(6,),
+               sampled=ADMIT_ROWS + 3, counted=dict(n=4, seed=5)),
 )}
 
 

@@ -836,6 +836,22 @@ WHOLE_PROGRAMS = {
         never={"chunk": ("gqa_prefill_fwd",),
                "admit": ("mla_decode_fwd", "mla_prefill_fwd")},
         admit_also=_selection_counts_in_the_chips_own_memory),
+    # layers 0-7 of 48 (two periods of three delta-rule layers to one gated
+    # full-attention layer), 128 of 512 experts, a quarter of the
+    # vocabulary, 32 slots: six float32 carries and tails and two grown
+    # caches of 17,408 rows of 2 heads of 256 each; the full layers' cores
+    # ``gqa_decode_fwd`` / ``gqa_prefill_fwd`` at d = 256, the delta rule
+    # plain XLA; 2 rows at the 16,384 bucket through ``moe_sorted_fwd``
+    "qwen3next": Whole(
+        "serve-qwen3next-longdoc-backlog", "qwen3_next",
+        lambda m: _perf_config(m, "Qwen3NextConfig",
+                               "qwen3-next-80b-a3b-ep4pp6"),
+        dict(num_slots=32, chunk_size=32, max_len=17408), admit=(2, 16384),
+        weights=(7.33e9, 7.34e9), state=(2.6e9, 2.8e9),
+        chunk=("tpu_custom_call", "gqa_decode_fwd", "moe_decode_fwd",
+               "row_write"),
+        admission=("tpu_custom_call", "moe_sorted_fwd", "gqa_prefill_fwd"),
+        never={"chunk": ("gqa_prefill_fwd",), "admit": ("gqa_decode_fwd",)}),
 }
 
 PROGRAMS = [(name, program) for name, row in WHOLE_PROGRAMS.items()
