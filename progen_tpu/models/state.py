@@ -344,6 +344,12 @@ class DeltaBlock:
         z = self._project(u, p, slice(split, None))
         return self._out(o, z, p), {"state": state, "conv": tail}
 
+    def scan_lowering(self, p: int) -> str:
+        """What ``gdn.gdn_scan`` takes for this block's rows padded to
+        ``p``, traced here and now."""
+        return gdn.scan_lowering(p, self.key_heads, self.value_heads,
+                                 self.key_dim, self.value_dim, self.chunk)
+
     def cache_rows(self, rows, lengths, max_len: int):
         return rows
 
@@ -365,8 +371,9 @@ class DeltaBlock:
 
 # the delta block's device counters, all float32 sums
 # (docs/OBSERVABILITY.md section 3): the token slots the chunked form
-# computed in all delta layers of a prefill (padding and partial chunks
-# included) and the real prime tokens x delta layers among them; the carry
+# computed in all delta layers of a prefill (the XLA form: every chunk of
+# the bucket, padding included; the kernel: whole chunks up to each row's
+# length) and the real prime tokens x delta layers among them; the carry
 # bytes the decode steps had to read and write: each LIVE row's carry once
 # each way in every delta layer (the static batch moves the idle slots' too)
 DELTA_STAT_KEYS = ("gdn.scan_slots", "gdn.real_tokens", "gdn.state_bytes")
@@ -386,6 +393,8 @@ def delta_prefill_stats(blocks: dict, tokens_shape, lengths) -> dict:
     """A prefill's ``gdn.*`` counters over rows of ``lengths`` padded to
     ``tokens_shape = (R, P)``."""
     mine = _delta_blocks(blocks)
-    slots = sum(gdn.scanned_slots(*tokens_shape, b.chunk) for b in mine)
+    slots = sum(gdn.computed_slots(lengths, tokens_shape[1], b.chunk,
+                                   b.scan_lowering(tokens_shape[1]))
+                for b in mine)
     return {"gdn.real_tokens": len(mine) * jnp.sum(lengths).astype(F32),
             "gdn.scan_slots": jnp.asarray(slots, F32)}

@@ -21,8 +21,9 @@ Hv, Dv)`` in ``v``'s dtype (accumulated in float32, rounded once as it leaves
 its chunk: 0.5 GB less at two rows of 16,384), ``S (R, Hv, Dk, Dv)
 float32)``, the carry AT EACH ROW'S TRUE LENGTH.  ``g`` and ``beta`` are zeroed at and past ``lengths`` (decay 1,
 nothing written), so padding leaves the carry alone whatever the bucket and
-a row of length 0 hands over zeros; ``o`` at a pad position is finite and
-nothing reads it.  The sequence is cut into chunks of ``C = min(chunk, P)``
+a row of length 0 hands over zeros; ``o`` at a pad position is finite
+(zero in a chunk the kernel leaves alone) and nothing reads it.  The
+sequence is cut into chunks of ``C = min(chunk, P)``
 tokens (``P`` padded up to a whole number of them, again with ``g = beta =
 0``).  With ``gam_i`` the cumulative sum of ``g`` inside a chunk — a sum of
 non-positive numbers, kept in log space, so every exponent taken is of a
@@ -37,37 +38,91 @@ UT form)::
     S <- exp(gam_C) S + (K exp(gam_C - gam))^T V'
 
 ``K K^T`` and ``Q K^T`` are computed once a KEY head and shared by its value
-heads.  ``T`` is made by forward substitution (:func:`unit_lower_inverse`);
-``T``, the two products that apply it and every ``exp`` are float32
+heads.  ``T``, the two products that apply it and every ``exp`` are float32
 (``Precision.HIGHEST``: the chip's default would round ``T`` to bfloat16 on
-the way into the matrix unit).  Everything up to ``U`` and ``W``
-is computed for a SEGMENT of ``SEGMENT`` chunks at once (2,048 tokens a row:
-all 256 chunks of a 16,384 bucket side by side are 3 GB of float32
-triangles); the three lines that read ``S`` are a sequential ``lax.scan``
-over the chunks of a segment inside one over the segments of a row, their
-operands in ``v``'s dtype (bfloat16 as served), accumulated in float32, the
-carry float32.  ``P`` is padded up to whole segments (of ``min(SEGMENT,
-chunks of P)`` chunks: a bucket of ``512 * 2^k`` tokens needs none).
+the way into the matrix unit); the three lines that read ``S`` take their
+operands in ``v``'s dtype (bfloat16 as served: ``W``, the in-chunk scores,
+``Q exp(gam)``, ``K exp(gam_C - gam)``, ``V'`` and ``S`` itself as an
+operand), accumulate in float32 and keep the carry float32.  Two lowerings
+of that one contract, chosen by :func:`scan_lowering` from the backend, the
+mesh in scope and the shapes, never from a knob, and noted under
+``"gdn_prefill"`` (``ops/lowering.py``):
+
+**The kernel** ``gdn_prefill_fwd`` (``"pallas"``: a TPU, no mesh in scope,
+``Dk`` and ``Dv`` whole lane tiles, ``C`` whole blocks of ``SOLVED`` = 16
+rows, whole key heads a value head; forward only).  Its grid is (row, group
+of heads, block of chunks), the last axis sequential: a grid step holds
+``STEP_TOKENS`` = 512 tokens (8 chunks of 64, an inner loop) of
+``STEP_HEADS`` = 4 value heads (two key heads and both value heads of
+each), their carries ``(Dk, Dv)`` float32 in a VMEM scratch from the row's
+first chunk to its last and written to HBM once, at the end.  ``q``, ``k``,
+``v`` and ``o`` are read and written IN PLACE as ``(1, tokens, heads *
+width)`` blocks of the ``(R, P, heads * width)`` arrays the block has (no
+transposed copy in or out); ``g`` and ``beta`` alone are handed over a
+chunk at a time with the tokens on the lanes (``(R, groups, chunks, 2
+heads, C)`` float32, 4 MB at two rows of 16,384), and the kernel makes the
+cumulative ``gam`` along lanes and along sublanes with three small float32
+products against a triangle of ones (no transpose).  Everything of a chunk
+stays in VMEM: ``gam``, the decay mask, ``K K^T``, ``Q K^T``, ``A``, ``T``,
+``U``, ``W``, ``V'``, ``O``, the carry's update — no float32 triangle is a
+buffer of the admission any more.  ``T`` is made by blocks
+(:func:`blocked_lower_inverse`): the diagonal 16 x 16 blocks by forward
+substitution a row at a time on the vector unit (15 dependent steps, the
+four blocks of a chunk and the heads of the step independent chains — the
+triangles of all four heads are inverted as ONE batch of operations and a
+block's column ``j`` comes from one 0 / 1 product, which keeps the kernel's
+trace a quarter as long: every admission bucket traces and lowers it once
+a process, cached or not), the blocks under the
+diagonal by float32 products ``T21 = T22 A21 T11`` at 32 and at 64 rows; no
+power of ``A`` is formed.  The kernel keeps float32 what
+the XLA form keeps and rounds only where it rounds.  **It stops at a row's
+length**: ``lengths`` is a prefetched scalar; a chunk whose first token is
+at or past ``lengths[r]`` computes nothing, leaves the carry alone and
+writes ZEROS to its rows of ``o``, and the index maps of a block wholly
+past the length point at the row's last live block, so nothing is fetched
+for it.  A chunk that straddles the length is computed whole with ``g =
+beta = 0`` past it.  On a v5e, bfloat16, 16 / 32 heads of 128, chunks of
+64: 20.3 ms a layer at 2 x 16,384 tokens with both rows full where the XLA
+form takes 63.1, and 9.0 at lengths 9,000 and 3,000 (PERF.md section 6,
+PR 64).
+
+**The XLA form** (``"xla"``: the CPU, a mesh, any other shape) computes
+EVERY chunk of the bucket.  ``T`` is made by forward substitution
+(:func:`unit_lower_inverse`, ``solve_triangular``).  Everything up to ``U``
+and ``W`` is computed for a SEGMENT of ``SEGMENT`` chunks at once (2,048
+tokens a row: all 256 chunks of a 16,384 bucket side by side are 3 GB of
+float32 triangles); the three lines that read ``S`` are a sequential
+``lax.scan`` over the chunks of a segment inside one over the segments of a
+row.  ``P`` is padded up to whole segments (of ``min(SEGMENT, chunks of
+P)`` chunks: a bucket of ``512 * 2^k`` tokens needs none).
+
+:func:`computed_slots` says how many token slots a call computes under
+either (the counter ``gdn.scan_slots``: ``models/state.py``).
 
 :func:`gdn_step` — one token a slot, ``state (S, Hv, Dk, Dv)`` float32 read
 and written once: ``(o (S, Hv, Dv) float32, state)``.  No matrix unit: the
 decay, the erase, the write and the read-out are float32 elementwise passes
-over the carry, so the carry is never rounded.
-
-Both are plain XLA and say so under ``"gdn_prefill"`` / ``"gdn_step"``
-(``ops/lowering.py``), where a kernel would say ``"pallas"``.
+over the carry, so the carry is never rounded.  Plain XLA, noted as
+``"gdn_step"``.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
+from progen_tpu.ops.lowering import mesh_in_scope as _mesh_in_scope
 from progen_tpu.ops.lowering import note
+from progen_tpu.ops.lowering import on_tpu as _on_tpu
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
-SEGMENT = 32        # chunks whose products are computed side by side
+SEGMENT = 32        # XLA form: chunks whose products are computed side by side
+# the kernel: tokens a grid step (whole chunks), value heads a grid step (whole
+# key heads), the triangle's diagonal blocks solved by substitution
+STEP_TOKENS, STEP_HEADS, SOLVED = 512, 4, 16
 
 
 def _cut(p: int, chunk: int):
@@ -139,14 +194,56 @@ def _segment(s, xs):
     return jax.lax.scan(chunk_of, s, (u, w, within, q_in, k_out, whole))
 
 
+def scan_lowering(p: int, hk: int, hv: int, dk: int, dv: int,
+                  chunk: int) -> str:
+    """``"pallas"`` or ``"xla"``: what :func:`gdn_scan` takes for rows
+    padded to ``p`` tokens of ``hk`` key heads ``dk`` wide feeding ``hv``
+    value heads ``dv`` wide in chunks of ``chunk``, traced here and now: the
+    kernel on a TPU with no mesh in scope, both widths on the lane tile, the
+    chunk ``min(chunk, p)`` whole blocks of ``SOLVED`` rows and whole key
+    heads a value head."""
+    kernel = (_on_tpu() and not _mesh_in_scope() and dk % 128 == 0
+              and dv % 128 == 0 and min(chunk, p) % SOLVED == 0
+              and hv % hk == 0)
+    return "pallas" if kernel else "xla"
+
+
+def computed_slots(lengths, p: int, chunk: int, lowering: str):
+    """Token slots :func:`gdn_scan` computes over rows of ``lengths (R,)``
+    padded to ``p`` under ``lowering``: the kernel's whole chunks up to each
+    row's length, a float32 scalar; the XLA form's every chunk of every
+    row, whatever the ``lengths`` (they are not looked at: a number of the
+    shapes)."""
+    if lowering == "xla":
+        return scanned_slots(lengths.shape[0], p, chunk)
+    c = min(chunk, p)
+    return (jnp.sum(-(-lengths // c)) * c).astype(F32)
+
+
+def _real_only(g, beta, lengths):
+    """``g`` and ``beta (R, P, Hv)`` in float32, zero at and past each
+    row's length: decay 1, nothing written."""
+    real = (jnp.arange(g.shape[1])[None, :] < lengths[:, None])[..., None]
+    return (jnp.where(real, g.astype(F32), 0.0),
+            jnp.where(real, beta.astype(F32), 0.0))
+
+
 def gdn_scan(q, k, v, g, beta, lengths, chunk: int):
-    note("gdn_prefill", "xla")
+    r, p, hk, dk = k.shape
+    lowering = scan_lowering(p, hk, v.shape[2], dk, v.shape[3], chunk)
+    note("gdn_prefill", lowering)
+    if lowering == "pallas":
+        return pallas_gdn_scan(q, k, v, g, beta, lengths, chunk)
+    return xla_gdn_scan(q, k, v, g, beta, lengths, chunk)
+
+
+def xla_gdn_scan(q, k, v, g, beta, lengths, chunk: int):
+    """The XLA form of :func:`gdn_scan`: every chunk of the bucket, a
+    segment's triangles side by side."""
     r, p, hk, dk = k.shape
     hv, dv = v.shape[2:]
     e = hv // hk                    # value heads a key head
-    real = (jnp.arange(p)[None, :] < lengths[:, None])[..., None]
-    g = jnp.where(real, g.astype(F32), 0.0)
-    beta = jnp.where(real, beta.astype(F32), 0.0)
+    g, beta = _real_only(g, beta, lengths)
     c, seg, n = _cut(p, chunk)
     pad = n * seg * c - p
     if pad:
@@ -164,6 +261,245 @@ def gdn_scan(q, k, v, g, beta, lengths, chunk: int):
                             (q, k, v, g, beta))
     o = o.transpose(2, 0, 1, 5, 3, 4, 6).reshape(r, n * seg * c, hv, dv)
     return o[:, :p], final.reshape(r, hv, dk, dv)
+
+
+# ------------------------------------------------------------- the kernel
+
+_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+
+
+def _dot(a, b, dims, precision=None):
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=precision,
+                               preferred_element_type=F32)
+
+
+def _iota(c: int):
+    """``(row, column)`` numbers of a ``(c, c)`` tile."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (c, c), 0),
+            jax.lax.broadcasted_iota(jnp.int32, (c, c), 1))
+
+
+def blocked_lower_inverse(a):
+    """``(I - a)^-1`` of strictly lower triangular ``a (..., C, C)`` float32
+    as the kernel makes it, ``C`` whole blocks of ``SOLVED`` rows (the
+    kernel hands over the triangles of all value heads of a grid step at
+    once: one chain of operations, every head in it).  The diagonal blocks
+    by forward substitution, a row at a time: with ``T = I`` to begin with,
+    step ``j`` adds ``a[:, j] (x) T[j, :]`` to the rows under ``j``, whose
+    row ``j`` is final by then — ``SOLVED - 1`` dependent steps on the
+    vector unit over ``(..., C / SOLVED, SOLVED, C)``, every diagonal block
+    and every head an independent chain.  The blocks under the diagonal by
+    products, a level at a time: with ``D`` the inverse of the diagonal
+    blocks of size ``s`` and ``L`` what ``a`` holds inside the blocks of ``2
+    s`` and outside those of ``s``, the inverse at ``2 s`` is ``D + D L D``
+    (``T21 = T22 A21 T11``), float32 on the matrix unit.  No power of ``a``
+    is formed, so no entry on the way is larger than the inverse's own."""
+    c = a.shape[-1]
+    row, col = _iota(c)
+    diagonal = jnp.where(row // SOLVED == col // SOLVED, a, 0.0)
+    # column j of EVERY diagonal block at once: one product with the 0 / 1
+    # matrix that sends lane l to lane l mod SOLVED (a row of a diagonal
+    # block has nothing outside the block, so the sum has one term)
+    fold = (row - row // SOLVED * SOLVED == col)[:, :SOLVED].astype(F32)
+    blocks = a.shape[:-2] + (c // SOLVED, SOLVED)
+    columns = _matmul(diagonal, fold).reshape(blocks + (SOLVED,))
+    t = jnp.broadcast_to(
+        (row == col).astype(F32).reshape(c // SOLVED, SOLVED, c),
+        blocks + (c,))
+    for j in range(SOLVED - 1):
+        t = t + columns[..., j:j + 1] * t[..., j:j + 1, :]
+    t = t.reshape(a.shape)
+    size = SOLVED
+    while size < c:
+        under = jnp.where((row // (2 * size) == col // (2 * size))
+                          & (row // size != col // size), a, 0.0)
+        t = t + _matmul(_matmul(t, under), t)
+        size *= 2
+    return t
+
+
+def _matmul(a, b):
+    """``a @ b`` over leading batch axes, float32 on the matrix unit."""
+    return jnp.matmul(a, b, precision=HIGHEST, preferred_element_type=F32)
+
+
+def _scan_kernel(len_ref, q_ref, k_ref, v_ref, gb_ref, o_ref, carry_ref,
+                 s_ref, *, c, nb, e):
+    """One grid step: ``nb`` chunks of ``c`` tokens of one row, the key
+    heads of one group (``e`` value heads each).  ``gb_ref (1, 1, nb, 2
+    heads, c)``: a chunk's ``g`` a value head, then its ``beta``, tokens on
+    the lanes.  ``s_ref (heads, Dk, Dv)`` float32 carries the state from the
+    row's first chunk to its last."""
+    from jax.experimental import pallas as pl
+
+    ri, bi = pl.program_id(0), pl.program_id(2)
+    length = len_ref[ri]
+    heads, dk, dv = s_ref.shape
+    dtype = v_ref.dtype
+    row, col = _iota(c)
+    lower, strict = row >= col, row > col
+    summed, eye = lower.astype(F32), (row == col).astype(F32)
+
+    @pl.when(bi == 0)
+    def _():
+        s_ref[...] = jnp.zeros(s_ref.shape, F32)
+
+    def computed(ci, rows):
+        gb = gb_ref[0, 0, ci]                               # (2 heads, c)
+        # the cumulative decay with the tokens along the lanes and along
+        # the sublanes, and beta along the sublanes: three small products
+        # in place of a transpose (the matrix unit adds a float32's pieces
+        # in an order of its own: a sum of non-positive numbers is held
+        # at 0 from above)
+        gam_rows = jnp.minimum(
+            _dot(gb[:heads], summed, _NT, HIGHEST), 0.0)        # (heads, c)
+        gam_cols = jnp.minimum(
+            _dot(summed, gb[:heads], _NT, HIGHEST), 0.0)        # (c, heads)
+        beta_cols = _dot(eye, gb[heads:], _NT, HIGHEST)
+
+        def triangle(h, kk):
+            """``(decay mask, A)`` of value head ``h``, ``(c, c)`` each."""
+            gam, beta = gam_cols[:, h:h + 1], beta_cols[:, h:h + 1]
+            apart = jnp.minimum(gam - gam_rows[h:h + 1], 0.0)
+            decay = jnp.where(lower, jnp.exp(apart), 0.0)
+            return decay, jnp.where(strict, -(beta * kk * decay), 0.0)
+
+        def value_head(h, t, decay, qk, kf, qf):
+            gam, beta = gam_cols[:, h:h + 1], beta_cols[:, h:h + 1]
+            grown = jnp.exp(gam)
+            values = v_ref[0, rows, h * dv:(h + 1) * dv].astype(F32)
+            u = _dot(t, beta * values, _NN, HIGHEST)
+            w = _dot(t, (beta * grown) * kf, _NN, HIGHEST).astype(dtype)
+            within = (qk * decay).astype(dtype)
+            q_in = (qf * grown).astype(dtype)
+            end = gam[c - 1:c]
+            k_out = (kf * jnp.exp(jnp.minimum(end - gam, 0.0))).astype(dtype)
+            s = s_ref[h]
+            sd = s.astype(dtype)
+            fresh = (u - _dot(w, sd, _NN)).astype(dtype)
+            o = _dot(q_in, sd, _NN) + _dot(within, fresh, _NN)
+            o_ref[0, rows, h * dv:(h + 1) * dv] = o.astype(o_ref.dtype)
+            # (1, 1) to the lanes first: the chip broadcasts one way a time
+            whole = jnp.exp(jnp.broadcast_to(end, (1, dv)))
+            s_ref[h] = s * whole + _dot(k_out, fresh, _TN)
+
+        # a key head's rows and products, then every value head's triangle
+        # inverted in ONE chain of operations, then the heads one by one
+        keys = []
+        for n in range(heads // e):
+            kb = k_ref[0, rows, n * dk:(n + 1) * dk]
+            qb = q_ref[0, rows, n * dk:(n + 1) * dk]
+            keys.append((_dot(kb, kb, _NT), _dot(qb, kb, _NT),
+                         kb.astype(F32), qb.astype(F32)))
+        masks = [triangle(h, keys[h // e][0]) for h in range(heads)]
+        ts = blocked_lower_inverse(jnp.stack([a for _, a in masks]))
+        for h in range(heads):
+            value_head(h, ts[h], masks[h][0], *keys[h // e][1:])
+
+    def chunk(ci, done):
+        rows = pl.ds(pl.multiple_of(ci * c, c), c)
+        live = (bi * nb + ci) * c < length
+
+        @pl.when(live)
+        def _():
+            computed(ci, rows)
+
+        @pl.when(jnp.logical_not(live))
+        def _():
+            o_ref[0, rows, :] = jnp.zeros((c, o_ref.shape[2]), o_ref.dtype)
+
+        return done
+
+    jax.lax.fori_loop(0, nb, chunk, 0)
+
+    @pl.when(bi == pl.num_programs(2) - 1)
+    def _():
+        carry_ref[0] = s_ref[...]
+
+
+def key_heads_a_step(hk: int, e: int) -> int:
+    """Key heads a grid step: ``STEP_HEADS`` value heads' worth where that
+    divides ``hk`` (the widths are lane tiles, so any count is a block)."""
+    n = max(1, STEP_HEADS // e)
+    while hk % n:
+        n -= 1
+    return n
+
+
+def pallas_gdn_scan(q, k, v, g, beta, lengths, chunk: int, *,
+                    interpret=None):
+    """The kernel lowering of :func:`gdn_scan`.  ``q``, ``k``, ``v`` and
+    ``o`` are read and written in place as ``(R, P, heads * width)``; ``g``
+    and ``beta``, zeroed at and past ``lengths``, are handed over a chunk at
+    a time with the tokens on the lanes (4 MB at two rows of 16,384).
+    ``interpret=None`` auto-selects the Pallas interpreter off-TPU."""
+    r, p, hk, dk = k.shape
+    hv, dv = v.shape[2:]
+    e = hv // hk
+    c = min(chunk, p)
+    kh = key_heads_a_step(hk, e)
+    nb = max(1, min(STEP_TOKENS // c, -(-p // c)))
+    padded = -(-p // (nb * c)) * nb * c
+    gb = jnp.stack(_real_only(g, beta, lengths), axis=2)
+    q, k, v = (x.reshape(r, p, -1) for x in (q, k, v))
+    if padded != p:
+        q, k, v, gb = (
+            jnp.pad(x, ((0, 0), (0, padded - p)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, gb))
+    gb = gb.reshape(r, padded // c, c, 2, hk // kh, kh * e).transpose(
+        0, 4, 1, 3, 5, 2).reshape(r, hk // kh, padded // c, 2 * kh * e, c)
+    o, carry = _scan_call(
+        q, k, v, gb, lengths.astype(jnp.int32), c=c, nb=nb, kh=kh, e=e,
+        dk=dk, interpret=not _on_tpu() if interpret is None else interpret)
+    return o[:, :p].reshape(r, p, hv, dv), carry
+
+
+# jitted as ``gqa._flash_call`` is: a model's delta layers share ONE traced
+# and lowered kernel a bucket
+@functools.partial(jax.jit, static_argnames=("c", "nb", "kh", "e", "dk",
+                                             "interpret"))
+def _scan_call(q, k, v, gb, lengths, *, c, nb, kh, e, dk, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    r, padded = q.shape[:2]
+    hk = q.shape[2] // dk
+    heads, dv, tb = kh * e, v.shape[2] // (hk * e), nb * c
+
+    def fetched(ri, bi, len_ref):
+        # a block wholly past the row's length is neither computed nor
+        # fetched: its steps point at the row's last live block
+        return jnp.minimum(bi, jnp.maximum(-(-len_ref[ri] // tb) - 1, 0))
+
+    def tokens(ri, gi, bi, len_ref):
+        return ri, fetched(ri, bi, len_ref), gi
+
+    def gates(ri, gi, bi, len_ref):
+        return ri, gi, fetched(ri, bi, len_ref), 0, 0
+
+    return pl.pallas_call(
+        functools.partial(_scan_kernel, c=c, nb=nb, e=e),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(r, hk // kh, padded // tb),
+            in_specs=[pl.BlockSpec((1, tb, kh * dk), tokens),
+                      pl.BlockSpec((1, tb, kh * dk), tokens),
+                      pl.BlockSpec((1, tb, heads * dv), tokens),
+                      pl.BlockSpec((1, 1, nb, 2 * heads, c), gates)],
+            out_specs=[
+                pl.BlockSpec((1, tb, heads * dv),
+                             lambda ri, gi, bi, len_ref: (ri, bi, gi)),
+                pl.BlockSpec((1, heads, dk, dv),
+                             lambda ri, gi, bi, len_ref: (ri, gi, 0, 0))],
+            scratch_shapes=[pltpu.VMEM((heads, dk, dv), F32)],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct((r, hk * e, dk, dv), F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="gdn_prefill_fwd",
+    )(lengths, q, k, v, gb)
 
 
 def gdn_step(state, q, k, v, g, beta):

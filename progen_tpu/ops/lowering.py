@@ -8,7 +8,11 @@ decode step's handful of tokens, ``"pallas_grouped"`` for a block step's
 few hundred, ``"pallas_sorted"`` for an admission's thousands, ``"xla"``
 off the chip; under ``"pallas_sorted"`` the note ``"moe_combine"`` says how
 the terms reached their tokens: ``"pallas_rows"``, the kernel's own row
-DMAs) each keep a Pallas
+DMAs) and ``ops/gdn.py:gdn_scan`` (the note ``"gdn_prefill"``: the kernel
+``gdn_prefill_fwd`` at widths on the lane tile and chunks of whole blocks
+of 16 rows, which stops at a row's true length; its one-token sibling
+``gdn_step`` is plain XLA and says ``"xla"`` under ``"gdn_step"``) each keep
+a Pallas
 lowering and an XLA form behind one function and choose between them from
 the backend, the mesh in scope and the shapes, never from a knob.
 ``ops/kth.py:kth_largest_by_counting`` chooses in the same way between two

@@ -840,18 +840,28 @@ WHOLE_PROGRAMS = {
     # full-attention layer), 128 of 512 experts, a quarter of the
     # vocabulary, 32 slots: six float32 carries and tails and two grown
     # caches of 17,408 rows of 2 heads of 256 each; the full layers' cores
-    # ``gqa_decode_fwd`` / ``gqa_prefill_fwd`` at d = 256, the delta rule
-    # plain XLA; 2 rows at the 16,384 bucket through ``moe_sorted_fwd``
+    # ``gqa_decode_fwd`` / ``gqa_prefill_fwd`` at d = 256, the delta rule's
+    # prefill a kernel, its step plain XLA: an admission holds neither a
+    # segment's float32 triangles nor an operand transposed chunk-major; 2
+    # rows at the 16,384 bucket through ``moe_sorted_fwd``
     "qwen3next": Whole(
         "serve-qwen3next-longdoc-backlog", "qwen3_next",
         lambda m: _perf_config(m, "Qwen3NextConfig",
                                "qwen3-next-80b-a3b-ep4pp6"),
         dict(num_slots=32, chunk_size=32, max_len=17408), admit=(2, 16384),
         weights=(7.33e9, 7.34e9), state=(2.6e9, 2.8e9),
+        ops=("ops.row_write", "ops.gqa", "ops.moe_decode", "ops.kth",
+             "ops.gdn"),
         chunk=("tpu_custom_call", "gqa_decode_fwd", "moe_decode_fwd",
                "row_write"),
-        admission=("tpu_custom_call", "moe_sorted_fwd", "gqa_prefill_fwd"),
-        never={"chunk": ("gqa_prefill_fwd",), "admit": ("gqa_decode_fwd",)}),
+        admission=("tpu_custom_call", "moe_sorted_fwd", "gqa_prefill_fwd",
+                   "gdn_prefill_fwd"),
+        never={"chunk": ("gqa_prefill_fwd", "gdn_prefill_fwd"),
+               "admit": ("gqa_decode_fwd",)},
+        no_buffers={"admit": ("f32[32,2,16,2,1,64,64]",
+                              "f32[32,2,16,2,64,64]",
+                              "bf16[8,32,2,16,64,128]",
+                              "bf16[8,32,2,16,2,64,128]")}),
 }
 
 PROGRAMS = [(name, program) for name, row in WHOLE_PROGRAMS.items()

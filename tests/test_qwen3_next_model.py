@@ -8,6 +8,7 @@ norm, the rotation over a quarter of a head, what each kind of layer states
 about its cache and the config's refusals."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -154,20 +155,46 @@ def _token_by_token(q, k, v, g, beta):
 
 
 C = 4
-_scan = jax.jit(gdn.gdn_scan, static_argnames="chunk")
+WIDE = dict(dk=128, dv=128)     # the kernel's widths: whole lane tiles
 
 
-@pytest.mark.parametrize("lengths,bucket", [
-    ((0, 1, C - 1, C, C + 1), 8), ((13, 7, 16, 2, 0), 16)],
-    ids=["a-chunks-edges", "a-padded-bucket"])
-def test_the_chunked_form_is_the_recurrence_token_by_token(lengths, bucket):
+def _form(kernel: bool, chunk: int):
+    """``gdn_scan``'s XLA form, or its kernel under the interpreter, in
+    chunks of ``chunk``: a fresh program a call (the kernel's blocks follow
+    ``gdn.STEP_TOKENS``, which a case may patch)."""
+    form = (functools.partial(gdn.pallas_gdn_scan, interpret=True)
+            if kernel else gdn.xla_gdn_scan)
+    return jax.jit(lambda *a: form(*a, chunk))
+
+
+# (the kernel?, chunk, tokens a grid step, lengths, bucket)
+CHUNKED = {
+    "a-chunks-edges": (False, C, None, (0, 1, C - 1, C, C + 1), 8),
+    "a-padded-bucket": (False, C, None, (13, 7, 16, 2, 0), 16),
+    "kernel-a-chunks-edges": (True, 16, None, (0, 1, 15, 16, 17), 32),
+    # three grid steps of two chunks a row: a row that ends in the last, one
+    # in the first and one at the second's first token
+    "kernel-rows-end-in-other-steps": (True, 16, 32, (90, 20, 33), 96),
+    "kernel-a-bucket-past-whole-chunks": (True, 16, None, (40, 7), 40),
+    "kernel-chunks-of-64": (True, 64, None, (128, 65), 128),
+}
+
+
+@pytest.mark.parametrize("case", CHUNKED)
+def test_the_chunked_form_is_the_recurrence_token_by_token(case, monkeypatch):
     """Rows of 0, 1, C - 1, C and C + 1 tokens, and rows padded to a bucket
     past whole chunks: the outputs at real positions and the carry AT EACH
     ROW'S TRUE LENGTH (zeros for a row of length 0) are the recurrence's,
-    whatever the padding holds."""
-    q, k, v, g, beta = _delta_inputs(len(lengths), bucket, seed=bucket)
+    whatever the padding holds — by the XLA form and by the kernel, whose
+    rows also end in different grid steps."""
+    kernel, chunk, step, lengths, bucket = CHUNKED[case]
+    if step:
+        monkeypatch.setattr(gdn, "STEP_TOKENS", step)
+    q, k, v, g, beta = _delta_inputs(len(lengths), bucket, seed=bucket,
+                                     **(WIDE if kernel else {}))
     with jax.default_matmul_precision("highest"):
-        o, carry = _scan(q, k, v, g, beta, jnp.asarray(lengths), chunk=C)
+        o, carry = _form(kernel, chunk)(q, k, v, g, beta,
+                                        jnp.asarray(lengths))
         for i, n in enumerate(lengths):
             want_o, carries = _token_by_token(q[i], k[i], v[i], g[i],
                                               beta[i])
@@ -176,34 +203,134 @@ def test_the_chunked_form_is_the_recurrence_token_by_token(lengths, bucket):
             if n:
                 assert float(jnp.abs(o[i, :n] - want_o[:n]).max()) < 1e-6
     assert bool(jnp.isfinite(o).all())
-    assert float(jnp.abs(carry).max()) > 0.5
-    assert gdn.scanned_slots(len(lengths), bucket, C) == len(lengths) * bucket
+    # not a vacuous bound (a unit key of 128 columns has smaller entries)
+    assert float(jnp.abs(carry).max()) > (0.3 if kernel else 0.5)
+    assert gdn.scanned_slots(len(lengths), bucket, chunk) == (
+        len(lengths) * -(-bucket // chunk) * chunk)
+    if kernel:      # nothing is left in a chunk wholly past a row's length
+        for i, n in enumerate(lengths):
+            assert not np.asarray(o[i, -(-n // chunk) * chunk:]).any()
 
 
-@pytest.mark.parametrize("c", [1, 2, 3, 4, 5, 8, 64])
-def test_substitution_inverts_a_unit_lower_triangle(c):
+def test_the_kernel_leaves_a_chunk_past_a_rows_length_alone():
+    """NaN in every chunk that lies wholly past its row's length (and in
+    ``g`` and ``beta`` from the length on): ``o`` is zero there, and ``o``
+    before it and the carry are, bit for bit, what clean inputs give."""
+    lengths, chunk = (33, 0, 16), 16
+    clean = _delta_inputs(3, 64, seed=5, **WIDE)
+    past = (jnp.arange(64)[None, :]
+            >= -(-jnp.asarray(lengths) // chunk)[:, None] * chunk)
+    at = jnp.arange(64)[None, :] >= jnp.asarray(lengths)[:, None]
+    q, k, v = (jnp.where(past[..., None, None], jnp.nan, x)
+               for x in clean[:3])
+    g, beta = (jnp.where(at[..., None], jnp.nan, x) for x in clean[3:])
+    scan = _form(True, chunk)
+    o, carry = scan(q, k, v, g, beta, jnp.asarray(lengths))
+    want_o, want = scan(*clean, jnp.asarray(lengths))
+    assert bool(jnp.isnan(q).any()) and bool(jnp.isnan(g).any())
+    np.testing.assert_array_equal(carry, want)
+    assert not np.asarray(carry[1]).any()
+    assert not np.asarray(jnp.where(past[..., None, None], o, 0.0)).any()
+    for i, n in enumerate(lengths):
+        np.testing.assert_array_equal(o[i, :n], want_o[i, :n])
+
+
+def test_the_scan_slots_counter_follows_the_lowering(monkeypatch):
+    """``gdn.scan_slots`` counts what the traced lowering computes: every
+    chunk of the bucket under the XLA form, whole chunks up to each row's
+    length under the kernel — which only the published widths take."""
+    wide = {f"l{i}": state.DeltaBlock(2, 4, 128, 128, 4, 1e-6, 64)
+            for i in range(3)}
+    lengths = jnp.asarray([65, 0, 512, 1], jnp.int32)
+    stats = fresh(lambda n: state.delta_prefill_stats(wide, (4, 512), n))
+    assert stats(lengths)["gdn.scan_slots"] == 3 * 4 * 512
+    monkeypatch.setattr(gdn, "_on_tpu", lambda: True)
+    assert wide["l0"].scan_lowering(512) == "pallas"
+    stats = fresh(lambda n: state.delta_prefill_stats(wide, (4, 512), n))
+    assert stats(lengths)["gdn.scan_slots"] == 3 * (128 + 0 + 512 + 64)
+    assert stats(lengths)["gdn.real_tokens"] == 3 * 578
+    # a chunk that is not whole blocks of 16 rows, a width off the lane tile
+    assert state.DeltaBlock(2, 4, 128, 128, 4, 1e-6, 24).scan_lowering(
+        512) == "xla"
+    assert qn.delta_block(TINY).scan_lowering(512) == "xla"
+
+
+def test_a_delta_block_of_published_widths_prefills_through_the_kernel(
+        monkeypatch):
+    """``DeltaBlock.prefill`` at ``Dk = Dv = 128`` with the chip said to be
+    there: the op notes ``"pallas"`` and the kernel (under the interpreter)
+    hands over what the XLA form hands over — the mixer's output at real
+    positions, the carry and the tail."""
+    block = state.DeltaBlock(2, 4, 128, 128, 4, 1e-6, 16)
+    p = block.init_weights(jax.random.key(3), 64, F32, (0.001, 0.1),
+                           (1.0, 16.0))
+    u = jax.random.normal(jax.random.key(4), (2, 48, 64))
+    lengths = jnp.asarray([37, 16], jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        with record_lowerings() as chosen:
+            want, held = fresh(block.prefill)(u, p, lengths)
+        assert chosen["gdn_prefill"] == {"xla"}
+        monkeypatch.setattr(gdn, "_on_tpu", lambda: True)
+        monkeypatch.setattr(gdn, "pallas_gdn_scan", functools.partial(
+            gdn.pallas_gdn_scan, interpret=True))
+        with record_lowerings() as chosen:
+            got, handed = fresh(block.prefill)(u, p, lengths)
+    assert chosen["gdn_prefill"] == {"pallas"}
+    for i, n in enumerate((37, 16)):
+        np.testing.assert_allclose(got[i, :n], want[i, :n], atol=4e-6)
+    np.testing.assert_allclose(handed["state"], held["state"], atol=4e-6)
+    np.testing.assert_array_equal(handed["conv"], held["conv"])
+    assert float(jnp.abs(held["state"]).max()) > 0.1
+
+
+@jax.jit
+def _inverse_in_a_kernel(a):
+    """``gdn.blocked_lower_inverse`` as the kernel runs it: on one triangle
+    in a kernel's memory, under the interpreter."""
+    from jax.experimental import pallas as pl
+
+    def kernel(a_ref, t_ref):
+        t_ref[...] = gdn.blocked_lower_inverse(a_ref[...])
+
+    return pl.pallas_call(kernel, out_shape=jax.ShapeDtypeStruct(
+        a.shape, F32), interpret=True)(a)
+
+
+INVERSES = {"substitution": lambda a: gdn.unit_lower_inverse(a[None])[0],
+            "kernel": _inverse_in_a_kernel}
+
+
+@pytest.mark.parametrize("form,c", [
+    ("substitution", c) for c in (1, 2, 3, 4, 5, 8, 64)] + [
+    ("kernel", c) for c in (16, 32, 48, 64)])
+def test_substitution_inverts_a_unit_lower_triangle(form, c):
+    """The XLA form's substitution at any size; the kernel's by blocks of 16
+    rows, three of them too (a level's last block may be short)."""
     a = jnp.tril(jax.random.normal(jax.random.key(c), (3, c, c)), -1) * 0.3
     with jax.default_matmul_precision("highest"):
-        t = gdn.unit_lower_inverse(a)
+        t = jnp.stack([INVERSES[form](x) for x in a])
         eye = jnp.eye(c)
         np.testing.assert_allclose(t @ (eye - a), jnp.broadcast_to(
             eye, a.shape), atol=1e-5)
 
 
+@pytest.mark.parametrize("form", INVERSES)
 @pytest.mark.parametrize("beta,decay", [(0.5, 1.0), (0.9, 0.97), (1.0, 1.0)])
-def test_a_chunk_of_one_repeated_token_keeps_its_digits(beta, decay):
+def test_a_chunk_of_one_repeated_token_keeps_its_digits(beta, decay, form):
     """Equal keys all through a chunk — a run of one token — make ``A``'s
     entries ``-beta decay^(i - j)``: the inverse is bounded by 1, and the
     series of powers ``(I + A)(I + A^2)...`` would form terms up to ``C(62,
     k) beta^k`` on its way there (160 off at ``beta`` 0.5 in float32 here, 176
     on the chip).
-    Forward substitution stays at a rounding of the float64 inverse."""
+    Forward substitution stays at a rounding of the float64 inverse, and so
+    does the kernel's: substitution inside blocks of 16 rows, ``T21 = T22
+    A21 T11`` under them."""
     i = np.arange(64)
     a = -beta * np.tril(np.ones((64, 64)), -1) * decay ** (
         i[:, None] - i[None, :])
     want = np.linalg.inv(np.eye(64) - a)
     with jax.default_matmul_precision("highest"):
-        got = gdn.unit_lower_inverse(jnp.asarray(a, F32)[None])[0]
+        got = INVERSES[form](jnp.asarray(a, F32))
     assert np.abs(want).max() <= 1.0
     assert np.abs(np.asarray(got, np.float64) - want).max() < 1e-5
 
