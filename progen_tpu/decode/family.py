@@ -187,7 +187,8 @@ class ProGenFamily:
 # four computes (an indexer's second cache leaf) and the next three borrow
 # (the plain leaf); the eleventh, Qwen3-Next, a share under three delta-rule
 # layers (a float32 state that erases before it writes, no keys) to one
-# gated full-attention layer
+# gated full-attention layer; the twelfth, Ling-3.0-flash, a share under five
+# delta-rule layers whose decay is a CHANNEL's to one latent-attention layer
 _DRIVER_FAMILIES = (
     ("progen_tpu.models.longcat", "LongCatConfig", "LongCatFamily"),
     ("progen_tpu.models.deepseek_v2", "DeepSeekV2Config", "DeepSeekV2Family"),
@@ -201,6 +202,8 @@ _DRIVER_FAMILIES = (
     ("progen_tpu.models.dots3", "Dots3Config", "Dots3Family"),
     ("progen_tpu.models.glm_dsa", "GLMDSAConfig", "GLMDSAFamily"),
     ("progen_tpu.models.qwen3_next", "Qwen3NextConfig", "Qwen3NextFamily"),
+    ("progen_tpu.models.bailing_hybrid", "BailingHybridConfig",
+     "BailingHybridFamily"),
 )
 
 

@@ -84,6 +84,7 @@ import jax.numpy as jnp
 from progen_tpu.core.precision import Policy, make_policy
 from progen_tpu.models import kv
 from progen_tpu.models.experts import zero_stats
+from progen_tpu.ops.moe_decode import clipped
 
 F32 = jnp.float32
 
@@ -180,9 +181,16 @@ def mm(x, w):
     return jnp.dot(x, w.astype(x.dtype))
 
 
-def swiglu(x, p, scope="ffn.dense"):
+def swiglu(x, p, scope="ffn.dense", limit: float = 0.0):
+    """``(silu(x W_g) * (x W_u)) W_d``; under a ``limit`` the two products
+    are clipped BEFORE the activation (``ops/moe_decode.py:clipped``;
+    ``models/bailing_hybrid.py``'s last layers)."""
     with jax.named_scope(scope):
-        return mm(jax.nn.silu(mm(x, p["wg"])) * mm(x, p["wu"]), p["wd"])
+        if not limit:
+            return mm(jax.nn.silu(mm(x, p["wg"])) * mm(x, p["wu"]), p["wd"])
+        with jax.named_scope("moe.clip"):
+            gate, up = clipped(mm(x, p["wg"]), mm(x, p["wu"]), limit)
+        return mm(jax.nn.silu(gate) * up, p["wd"])
 
 
 def _embed(params, tokens, c, dt):

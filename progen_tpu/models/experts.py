@@ -2,9 +2,10 @@
 experts the chip holds, its window, and the device counters the families
 with such a layer carry (``models/longcat.py``, ``models/deepseek_v2.py``,
 ``models/trinity.py``, ``models/sdar.py``, ``models/lfm2.py``,
-``models/nemotron_h.py``, ``models/qwen3_next.py``), the sigmoid router
-three of them share (:func:`sigmoid_route`) and the softmax router of two
-(:func:`softmax_route`).
+``models/nemotron_h.py``, ``models/qwen3_next.py``,
+``models/bailing_hybrid.py``), the sigmoid router several of them share
+(:func:`sigmoid_route`, with ``noaux_tc``'s group limit where a family
+states groups) and the softmax router of two (:func:`softmax_route`).
 
 **Two expert forms**, told apart by what ``experts`` holds and never by a
 knob: ``{"wg", "wu", "wd"}``, a gated SwiGLU of three matrices, ``(silu(u
@@ -93,7 +94,7 @@ def add_stats(a: dict, b: dict) -> dict:
 
 
 def sigmoid_route(u, router, topk: int, *, norm: bool, scale: float,
-                  eps: float):
+                  eps: float, groups: int = 1, kept_groups: int = 1):
     """The sigmoid router with a selection bias, of the families that have
     it (``models/trinity.py``, ``models/lfm2.py``,
     ``models/nemotron_h.py``): ``(ids (T, k), weights
@@ -101,12 +102,26 @@ def sigmoid_route(u, router, topk: int, *, norm: bool, scale: float,
     largest of ``s + b`` are chosen (``b = router["bias"]`` picks and does
     not weigh); the weights are the chosen ``s`` alone, over ``sum s + eps``
     where ``norm``, times ``scale``.  ``eps`` is the family's own (Trinity
-    and Nemotron-H 1e-20, LFM2 1e-6)."""
+    and Nemotron-H 1e-20, LFM2 1e-6).  With ``groups`` > 1 (``noaux_tc``'s
+    group limit, ``models/bailing_hybrid.py``) the experts lie in ``groups``
+    groups of consecutive experts, a group scores the sum of its TWO largest
+    ``s + b``, and only the ``kept_groups`` best groups' experts can be
+    chosen."""
     with jax.named_scope("moe.route"):
         logits = jnp.dot(u.astype(F32), router["w"].astype(F32),
                          precision=jax.lax.Precision.HIGHEST)
         scores = jax.nn.sigmoid(logits)
-        _, ids = jax.lax.top_k(scores + router["bias"].astype(F32), topk)
+        biased = scores + router["bias"].astype(F32)
+        if groups > 1:
+            t, size = biased.shape[0], biased.shape[1] // groups
+            best = jnp.sum(jax.lax.top_k(
+                biased.reshape(t, groups, size), 2)[0], axis=-1)
+            _, keep = jax.lax.top_k(best, kept_groups)
+            kept = jnp.zeros((t, groups), bool).at[
+                jnp.arange(t)[:, None], keep].set(True)
+            biased = jnp.where(jnp.repeat(kept, size, axis=1), biased,
+                               -jnp.inf)
+        _, ids = jax.lax.top_k(biased, topk)
         w = jnp.take_along_axis(scores, ids, axis=-1)
         if norm:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
@@ -139,7 +154,8 @@ def moe_capacity(c, tokens: int) -> int:
     return min(tokens * c.moe_topk, max(128, steps * 128))
 
 
-def held_experts(u, ids, w, live, experts, c, capacity=None):
+def held_experts(u, ids, w, live, experts, c, capacity=None,
+                 limit: float = 0.0):
     """The held real experts' terms for ``u (T, h)``: ``(y (T, h) float32,
     load (held,))`` where ``load`` counts the live tokens' assignments to
     each held expert.  A token's ``k`` experts ``ids (T, k)`` are distinct,
@@ -149,7 +165,9 @@ def held_experts(u, ids, w, live, experts, c, capacity=None):
     ``capacity``: assignments per window of the sorted assignments where a
     form with windows runs (default :func:`moe_capacity` under the XLA
     form, :func:`sorted_window` under ``moe_sorted_fwd``); a window that
-    overflows runs again, so it changes no result."""
+    overflows runs again, so it changes no result.  ``limit``: the layer's
+    clip on a gated expert's two products (``ops/moe_decode.py:clipped``),
+    a static of whichever lowering runs; 0 for none."""
     t, k = ids.shape
     held = c.experts_held
     tiles = moe_decode.fitted_tile(u, experts)
@@ -159,12 +177,12 @@ def held_experts(u, ids, w, live, experts, c, capacity=None):
         mine = (local >= 0) & (local < held) & live[:, None]
         group = jnp.where(mine, local, held).reshape(-1)
         if tiles is not None and tiles.sorted:
-            return _sorted(u, group, w, experts, c, tiles, capacity)
+            return _sorted(u, group, w, experts, c, tiles, capacity, limit)
         if tiles is not None:
             load = jnp.bincount(group, length=held + 1)[:held]
             form = _streamed if tiles.rows is None else _grouped
-            return form(u, group.reshape(t, k), w, load, experts,
-                        tiles), load
+            return form(u, group.reshape(t, k), w, load, experts, tiles,
+                        limit), load
         order = jnp.argsort(group)                    # held first, by expert
         load = jnp.bincount(group, length=held + 1)[:held]
         ends = jnp.cumsum(load)
@@ -187,8 +205,9 @@ def held_experts(u, ids, w, live, experts, c, capacity=None):
                 xs, experts["wg"].astype(u.dtype),
                 sizes) if "wg" in experts else None
             up = jax.lax.ragged_dot(xs, experts["wu"].astype(u.dtype), sizes)
-            out = jax.lax.ragged_dot(moe_decode.activation(gate, up),
-                                     experts["wd"].astype(u.dtype), sizes)
+            out = jax.lax.ragged_dot(
+                moe_decode.activation(gate, up, limit),
+                experts["wd"].astype(u.dtype), sizes)
             wt = jnp.where(valid, weights[idx], 0.0)
             term = jnp.where(valid[:, None], out.astype(F32) * wt[:, None], 0.0)
             return it + 1, y.at[tok].add(term)
@@ -221,7 +240,8 @@ def _in_window(starts, ends, base, cap):
     return jnp.clip(starts - base, 0, cap), jnp.clip(ends - base, 0, cap)
 
 
-def _sorted(u, group, w, experts, c, tiles, capacity=None):
+def _sorted(u, group, w, experts, c, tiles, capacity=None,
+            limit: float = 0.0):
     """``held_experts`` through ``moe_sorted_fwd``: ``(y, load)``.  The
     assignments sorted by expert (``group (T k,)``: the held expert, or
     ``held`` for what is not this chip's or not live), a window of them at
@@ -257,7 +277,7 @@ def _sorted(u, group, w, experts, c, tiles, capacity=None):
             y, u[tok], tok, weights[idx],
             *_in_window(starts, ends, base, cap), experts.get("wg"),
             experts["wu"], experts["wd"], row_tile=tiles.rows,
-            tile=tiles.inner)
+            tile=tiles.inner, limit=limit)
 
     # a token's row as lane tiles: one run of HBM, which a row DMA can name
     lane = moe_decode.LANE
@@ -268,7 +288,7 @@ def _sorted(u, group, w, experts, c, tiles, capacity=None):
     return y.reshape(u.shape), load
 
 
-def _streamed(u, group, w, load, experts, tiles):
+def _streamed(u, group, w, load, experts, tiles, limit: float = 0.0):
     """The terms of ``held_experts`` through ``moe_decode_fwd``: the
     touched experts in ascending order, each with the routing weight of
     every token (zero where ``group (T, k)`` does not name it)."""
@@ -279,10 +299,10 @@ def _streamed(u, group, w, load, experts, tiles):
     wt = jnp.sum(jnp.where(names, w.astype(F32)[None], 0.0), axis=-1)
     return moe_decode.pallas_expert_terms(
         u, eid, jnp.sum(touched), wt[eid], experts.get("wg"), experts["wu"],
-        experts["wd"], tile=tiles.inner)
+        experts["wd"], tile=tiles.inner, limit=limit)
 
 
-def _grouped(u, group, w, load, experts, tiles):
+def _grouped(u, group, w, load, experts, tiles, limit: float = 0.0):
     """The terms of ``held_experts`` through ``moe_grouped_fwd``: each
     touched expert's own rows, gathered into whole row tiles (an expert's
     tiles side by side, in ascending order of expert, so that its matrices
@@ -309,7 +329,7 @@ def _grouped(u, group, w, load, experts, tiles):
         u[src // k], eid, tile_end[-1],
         jnp.where(real.reshape(-1), w.reshape(-1)[src], 0.0),
         experts.get("wg"), experts["wu"], experts["wd"], row_tile=rt,
-        tile=tiles.inner)
+        tile=tiles.inner, limit=limit)
     # where each assignment's term is: its expert's first row and its place
     # among the expert's assignments (the inverse of ``order``)
     e = jnp.minimum(group, held - 1)

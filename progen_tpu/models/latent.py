@@ -10,7 +10,9 @@ two things that differ between families: ``rope_inv_freq(d)`` (the rotary
 frequency table) and the gains ``q_gain`` / ``kv_gain`` applied to the
 projected query and to the normed latent (1 where there is none).
 
-**MLA.**  ``c_q = RMSNorm(x W_qa)``; ``q = (c_q W_qb) * q_gain``;
+**MLA.**  ``c_q = RMSNorm(x W_qa)``; ``q = (c_q W_qb) * q_gain`` (or ``q = (x
+W_q) * q_gain`` where the weights hold ``"wq"`` and no low-rank pair: a
+config whose ``q_lora_rank`` is null, ``models/bailing_hybrid.py``);
 ``[c_kv | k_r] = x W_kva``; ``c_kv = RMSNorm(c_kv) * kv_gain`` (so the CACHE
 holds the scaled latent); ``[k_nope | v] = c_kv W_kvb``.  RoPE (half-split)
 on the rope part of q and on ``k_r``, which all heads share.  The cache row
@@ -131,8 +133,11 @@ def mla_project(x, p, c, positions, latent_query: bool = False):
     nope, rot = c.qk_nope_head_dim, c.qk_rope_head_dim
     rope = _rope_of(c)
     with jax.named_scope("mla.project"):
-        c_q = rms_norm(mm(x, p["wqa"]), p["q_norm"], c.rms_norm_eps)
-        q = mm(c_q, p["wqb"])
+        if "wqa" in p:
+            c_q = rms_norm(mm(x, p["wqa"]), p["q_norm"], c.rms_norm_eps)
+            q = mm(c_q, p["wqb"])
+        else:       # ``q_lora_rank`` null: no low rank, no query norm
+            c_q, q = None, mm(x, p["wq"])
         if c.q_gain != 1:
             q = q * jnp.asarray(c.q_gain, q.dtype)
         q = q.reshape(q.shape[:-1] + (heads, nope + rot))

@@ -1,7 +1,8 @@
 """The mixers that carry a recurrent state, and what each states about its
 cache (``models/driver.py`` says what a block is): :class:`StateBlock`, a
-Mamba-2 mixer, and :class:`DeltaBlock`, a gated delta-rule mixer (below it,
-with its own equations).
+Mamba-2 mixer, :class:`DeltaBlock`, a gated delta-rule mixer (below it,
+with its own equations), and :class:`ChannelDeltaBlock`, the delta rule with
+a decay a CHANNEL under a bound (below that).
 
 **StateBlock.**  Shared by the
 families with such layers (``models/granite_hybrid.py``: one B/C group,
@@ -379,8 +380,10 @@ class DeltaBlock:
 DELTA_STAT_KEYS = ("gdn.scan_slots", "gdn.real_tokens", "gdn.state_bytes")
 
 
-def _delta_blocks(blocks: dict) -> list:
-    return [b for b in blocks.values() if isinstance(b, DeltaBlock)]
+def _delta_blocks(blocks: dict, kind=DeltaBlock) -> list:
+    """The blocks of exactly ``kind`` (a :class:`ChannelDeltaBlock` counts
+    under its own keys)."""
+    return [b for b in blocks.values() if type(b) is kind]
 
 
 def delta_decode_stats(blocks: dict, live) -> dict:
@@ -398,3 +401,145 @@ def delta_prefill_stats(blocks: dict, tokens_shape, lengths) -> dict:
                 for b in mine)
     return {"gdn.real_tokens": len(mine) * jnp.sum(lengths).astype(F32),
             "gdn.scan_slots": jnp.asarray(slots, F32)}
+
+
+# ----------------------------------------- the delta rule, a decay a channel
+
+
+class ChannelDeltaBlock(DeltaBlock):
+    """One delta-rule mixer whose decay is a key CHANNEL's (Kimi Delta
+    Attention; ``ops/gdn.py:kda_scan`` / ``kda_step`` have the recurrence):
+    ``heads`` heads, as many key heads as value heads, ``Kw = heads *
+    key_dim``, ``Vw = heads * value_dim``, ``u (..., h)`` the normed stream::
+
+        [q (Kw) | k (Kw) | v (Vw)] = u W_qkv                 (no bias)
+        [q|k|v]_t <- silu(sum_j w_conv[:, j] * [q|k|v]_{t-(K-1)+j})
+        q <- q rsqrt(sum q^2 + 1e-6) key_dim^-1/2,  k <- k rsqrt(sum k^2 + 1e-6)
+        f = u W_f  (Kw: full rank),  beta = sigmoid(u W_b)  (heads)
+        g = bound * sigmoid(exp(A_log)_head * (f + dt_bias))     (float32)
+        S_t = diag(exp(g_t)) S_{t-1} + k_t (x) beta_t (v_t - (diag(exp(g_t))
+              S_{t-1})^T k_t),   o_t = S_t^T q_t
+        out = [RMSNorm_w(o_t) * sigmoid(u W_og)_head] W_out
+
+    ``g`` lies in ``(bound, 0)`` (``bound`` < 0: the published kernels'
+    lower-bound gate), a vector over ``key_dim`` a head and token, ``A_log``
+    a head and ``dt_bias`` a channel; the norm over each head's ``value_dim``
+    channels with one weight ``w (value_dim,)``; the output gate a HEAD and
+    AFTER the norm (an RMS norm forgets a positive scale before it).  The
+    chunked form's products are made in blocks of ``block`` rows.
+
+    **The cache** is :class:`DeltaBlock`'s: ``{"state": (slots, heads,
+    key_dim, value_dim) float32, "conv": (slots, K - 1, 2 Kw + Vw)}``."""
+
+    def __init__(self, heads: int, key_dim: int, value_dim: int, conv: int,
+                 eps: float, chunk: int, block: int, bound: float):
+        super().__init__(heads, heads, key_dim, value_dim, conv, eps, chunk)
+        if not bound < 0:
+            raise ValueError(f"the gate's bound {bound} is not negative")
+        self.block, self.bound = block, float(bound)
+
+    def init_weights(self, key, hidden: int, dt, bias_range, a_range) -> dict:
+        """Seeded weights: ``exp(A_log)`` a head log-uniform from
+        ``a_range``, ``dt_bias`` a channel uniform from ``bias_range``."""
+        heads, kw = self.value_heads, self.key_width
+        ks = jax.random.split(key, 9)
+        return {
+            "in_proj": normal(ks[0], (hidden, self.conv_channels),
+                              hidden ** -0.5, dt),
+            "f_proj": normal(ks[1], (hidden, kw), hidden ** -0.5, dt),
+            "b_proj": normal(ks[2], (hidden, heads), hidden ** -0.5, dt),
+            "g_proj": normal(ks[3], (hidden, heads), hidden ** -0.5, dt),
+            "conv_w": normal(ks[4], (self.conv_channels, self.conv),
+                             self.conv ** -0.5, dt),
+            # the recurrence's own parameters stay float32
+            "a_log": jnp.log(_log_uniform(ks[5], (heads,), *a_range)),
+            "dt_bias": jax.random.uniform(ks[6], (heads, self.key_dim), F32,
+                                          *bias_range),
+            "norm": init_norm(ks[7], (self.value_dim,), dt),
+            "out_proj": normal(ks[8], (self.value_width, hidden),
+                               self.value_width ** -0.5, dt),
+        }
+
+    def _gates(self, x, p):
+        """``(beta (..., heads), g (..., heads, key_dim))``: the write
+        strength and the log of the decay a channel, float32."""
+        with jax.named_scope("kda.gate"):
+            f = jnp.dot(x, p["f_proj"].astype(x.dtype),
+                        preferred_element_type=F32)
+            f = f.reshape(f.shape[:-1] + (self.value_heads, self.key_dim))
+            g = self.bound * jax.nn.sigmoid(
+                jnp.exp(p["a_log"])[:, None] * (f + p["dt_bias"]))
+            return jax.nn.sigmoid(mm(x, p["b_proj"]).astype(F32)), g
+
+    def _out(self, o, x, p):
+        """``o`` from the recurrence: the norm a head, THEN the gate a head
+        (float32 inside the sigmoid), the output projection."""
+        with jax.named_scope("kda.norm"):
+            o = rms_norm(o.astype(x.dtype), p["norm"], self.eps)
+            gate = jax.nn.sigmoid(mm(x, p["g_proj"]).astype(F32))
+            o = o * gate.astype(o.dtype)[..., None]
+        with jax.named_scope("kda.out_proj"):
+            return mm(o.reshape(o.shape[:-2] + (-1,)), p["out_proj"])
+
+    def _row(self, u, p, lengths):
+        with jax.named_scope("kda.in_proj"):
+            qkv = mm(u, p["in_proj"])
+        beta, g = self._gates(u, p)
+        with jax.named_scope("kda.conv"):
+            tail = ssd.conv_tail(qkv, lengths, self.conv)
+            qkv = jax.nn.silu(ssd.causal_conv(
+                qkv, p["conv_w"], None)).astype(u.dtype)
+        q, k, v = self._split_conv(qkv)
+        with jax.named_scope("kda.scan"):
+            o, state = gdn.kda_scan(q, k, v, g, beta, lengths, self.chunk,
+                                    self.block)
+        return self._out(o, u, p), {"state": state, "conv": tail}
+
+    def prefill(self, u, p, lengths):
+        """The mixer over ``u (R, P, h)``, ONE ROW AT A TIME: a token's
+        ``[q | k | v]`` twice over and its float32 ``g`` are 77 KB, 5 GB at
+        four rows of 16,384, and a row shares nothing with the next.  What
+        the slot will hold is as :class:`DeltaBlock`'s."""
+        if u.shape[0] == 1:
+            return self._row(u, p, lengths)
+        out, rows = jax.lax.map(
+            lambda x: self._row(x[0][None], p, x[1][None]), (u, lengths))
+        return out[:, 0], {name: a[:, 0] for name, a in rows.items()}
+
+    def scan_lowering(self, p: int) -> str:
+        return "xla"
+
+    def decode(self, u, pos, cache, p):
+        """One token a row: the tail shifted, the carry decayed a channel,
+        erased under the new key and written."""
+        with jax.named_scope("kda.in_proj"):
+            qkv = mm(u, p["in_proj"])
+        beta, g = self._gates(u, p)
+        with jax.named_scope("kda.conv"):
+            qkv, tail = ssd.conv_step(cache["conv"], qkv, p["conv_w"], None)
+            qkv = jax.nn.silu(qkv).astype(u.dtype)
+        q, k, v = self._split_conv(qkv)
+        with jax.named_scope("kda.step"):
+            o, state = gdn.kda_step(cache["state"], q, k, v, g, beta)
+        return self._out(o, u, p), {"state": state, "conv": tail}
+
+
+# the channel-decay block's device counters: :data:`DELTA_STAT_KEYS` under
+# its own names (its chunked form is plain XLA: every chunk of the bucket)
+KDA_STAT_KEYS = ("kda.scan_slots", "kda.real_tokens", "kda.state_bytes")
+
+
+def kda_decode_stats(blocks: dict, live) -> dict:
+    """A decode step's ``kda.*`` counter."""
+    moved = sum(2 * b.state_bytes()
+                for b in _delta_blocks(blocks, ChannelDeltaBlock))
+    return {"kda.state_bytes": moved * jnp.sum(live).astype(F32)}
+
+
+def kda_prefill_stats(blocks: dict, tokens_shape, lengths) -> dict:
+    """A prefill's ``kda.*`` counters over rows of ``lengths`` padded to
+    ``tokens_shape = (R, P)``."""
+    mine = _delta_blocks(blocks, ChannelDeltaBlock)
+    slots = sum(gdn.scanned_slots(*tokens_shape, b.chunk) for b in mine)
+    return {"kda.real_tokens": len(mine) * jnp.sum(lengths).astype(F32),
+            "kda.scan_slots": jnp.asarray(slots, F32)}

@@ -511,3 +511,160 @@ def gdn_step(state, q, k, v, g, beta):
     write = beta.astype(F32)[..., None] * (v.astype(F32) - held)
     state = state + k * write[..., None, :]
     return jnp.sum(state * q, axis=-2), state
+
+
+# ------------------------------------------------- a decay a channel (KDA)
+
+BLOCK = 16      # rows of a block of :func:`kda_scan`'s decayed products
+
+
+def _diagonal_products(qb, kb, gb):
+    """The decayed products INSIDE the diagonal blocks ``(..., nb, B, Dk)``
+    float32: ``(sum_d K_id K_jd exp(gam_id - gam_jd), the same under Q_id)``
+    over ``i >= j`` of a block, ``(..., nb, B, B)`` each, zero above the
+    diagonal.  The ``B x B x Dk`` sum taken directly on the vector unit:
+    every exponent is of a non-positive number (``PERF.md`` section 6, PR
+    65, has it timed beside a reference row inside the block on the matrix
+    unit, whose positive exponents reach ``B`` times the gate's bound)."""
+    b = kb.shape[-2]
+    inside = jnp.tril(jnp.ones((b, b), bool))[..., None]
+    apart = gb[..., :, None, :] - gb[..., None, :, :]       # gam_i - gam_j
+    decay = jnp.where(inside, jnp.exp(jnp.where(inside, apart, 0.0)), 0.0)
+    cols = kb[..., None, :, :] * decay
+    return (jnp.sum(kb[..., :, None, :] * cols, axis=-1),
+            jnp.sum(qb[..., :, None, :] * cols, axis=-1))
+
+
+def decayed_products(q, k, gam, block: int, dtype):
+    """``(sum_d K_id K_jd exp(gam_id - gam_jd), sum_d Q_id K_jd exp(gam_id -
+    gam_jd))`` over ``i >= j`` of a chunk, ``(..., C, C)`` float32 each and
+    zero above the diagonal, from ``q, k, gam (..., C, Dk)`` float32 with
+    ``gam`` the cumulative log decay a channel (non-increasing along ``C``).
+    The decay sits INSIDE the sum over ``d``, so no ``C x C`` mask comes out
+    of it, and ``exp(-gam_j)`` alone overflows: the chunk is cut into blocks
+    of ``block`` rows.  A block of rows ``I`` over a block of columns ``J <
+    I``, ``r`` the last row of ``J``: ``(K_I exp(gam_I - gam_r)) (K_J
+    exp(gam_r - gam_J))^T`` — both exponents non-positive, the factors
+    rounded to ``dtype`` like every operand of a served product, float32
+    accumulation.  The diagonal blocks: :func:`_diagonal_products`."""
+    c, dk = k.shape[-2:]
+    if c % block:
+        block = c
+    nb, lead = c // block, k.shape[:-2]
+    cut = lead + (nb, block, dk)
+    qb, kb, gb = q.reshape(cut), k.reshape(cut), gam.reshape(cut)
+    kk, qk = _diagonal_products(qb, kb, gb)
+    same = jnp.eye(nb, dtype=F32)[:, None, :, None]         # (I, 1, J, 1)
+    kk, qk = ((x[..., None, :] * same).reshape(lead + (c, c))
+              for x in (kk, qk))
+    if nb == 1:
+        return kk, qk
+    ref = gb[..., -1, :]                                    # (..., nb, Dk)
+    cols = (kb * jnp.exp(ref[..., None, :] - gb)).astype(dtype)
+    rows = jnp.exp(jnp.minimum(
+        gam[..., None, :, :] - ref[..., :, None, :], 0.0))  # (..., J, C, Dk)
+    under = (jnp.arange(c)[:, None] // block
+             > jnp.arange(c)[None, :] // block)
+
+    def below(x):
+        scaled = (x[..., None, :, :] * rows).astype(dtype)
+        out = jnp.einsum("...jid,...jbd->...ijb", scaled, cols,
+                         preferred_element_type=F32)
+        return jnp.where(under, out.reshape(lead + (c, c)), 0.0)
+
+    return kk + below(k), qk + below(q)
+
+
+def _kda_segment(block, s, xs):
+    """One segment's chunks ``(seg, R, H, C, ...)`` over the carry ``s (R,
+    H, Dk, Dv)`` before it: ``(the carry after it, o (seg, R, H, C, Dv)`` in
+    ``v``'s dtype``)``; :func:`_segment` with a decay a channel."""
+    q, k, v, g, beta = xs
+    dtype, c = v.dtype, k.shape[-2]
+    gam = jnp.cumsum(g, axis=-2)                            # <= 0
+    qf, kf = q.astype(F32), k.astype(F32)
+    kk, qk = decayed_products(qf, kf, gam, block, dtype)
+    a = -jnp.where(jnp.tril(jnp.ones((c, c), bool), -1),
+                   beta[..., None] * kk, 0.0)
+    t = unit_lower_inverse(a)                               # (seg, r, h, c, c)
+    grown, end = jnp.exp(gam), gam[..., -1:, :]
+    u = jnp.matmul(t, beta[..., None] * v.astype(F32), precision=HIGHEST)
+    w = jnp.matmul(t, beta[..., None] * (kf * grown),
+                   precision=HIGHEST).astype(dtype)
+    within = qk.astype(dtype)                               # lower: its own
+    q_in = (qf * grown).astype(dtype)
+    k_out = (kf * jnp.exp(end - gam)).astype(dtype)
+    whole = jnp.exp(end[..., 0, :])                         # (seg, r, h, dk)
+
+    def chunk_of(s, xs):
+        u, w, within, q_in, k_out, whole = xs
+        sd = s.astype(dtype)
+        fresh = u - jnp.matmul(w, sd, preferred_element_type=F32)
+        o = (jnp.matmul(q_in, sd, preferred_element_type=F32)
+             + jnp.matmul(within, fresh.astype(dtype),
+                          preferred_element_type=F32))
+        s = s * whole[..., None] + jnp.einsum(
+            "rhik,rhiv->rhkv", k_out, fresh.astype(dtype),
+            preferred_element_type=F32)
+        return s, o.astype(dtype)
+
+    return jax.lax.scan(chunk_of, s, (u, w, within, q_in, k_out, whole))
+
+
+def kda_scan(q, k, v, g, beta, lengths, chunk: int, block: int = BLOCK):
+    """:func:`gdn_scan` with a decay a CHANNEL (Kimi Delta Attention):
+    ``q, k (R, P, H, Dk)``, ``v (R, P, H, Dv)``, ``g (R, P, H, Dk)`` the log
+    of the decay ``alpha_t = exp(g_t)`` a key channel, ``beta (R, P, H)``::
+
+        S_t = diag(alpha_t) S_{t-1} + k_t (x) beta_t (v_t - (diag(alpha_t)
+              S_{t-1})^T k_t),   o_t = S_t^T q_t
+
+    and in chunks of ``C``, ``gam`` the cumulative sum of ``g`` inside a
+    chunk, a vector over ``Dk``::
+
+        A  = -strict_lower[beta_i sum_d K_id K_jd exp(gam_id - gam_jd)]
+        T  = (I - A)^-1,   U = T (beta V),   W = T (beta K exp(gam))
+        V' = U - W S
+        O  = (Q exp(gam)) S + lower[sum_d Q_id K_jd exp(gam_id - gam_jd)] V'
+        S <- diag(exp(gam_C)) S + (K exp(gam_C - gam))^T V'
+
+    Results, padding (``g = beta = 0`` at and past ``lengths``; the carry at
+    each row's TRUE length), segments and precision as the XLA form of
+    :func:`gdn_scan`; the two decayed products by blocks of ``block`` rows
+    (:func:`decayed_products`), so that every exponent taken is of a
+    non-positive number whatever the gate's bound.  Plain XLA, every chunk
+    of the bucket, noted as ``"kda_prefill"``."""
+    note("kda_prefill", "xla")
+    r, p, h, dk = k.shape
+    dv = v.shape[3]
+    real = jnp.arange(p)[None, :] < lengths[:, None]
+    g = jnp.where(real[..., None, None], g.astype(F32), 0.0)
+    beta = jnp.where(real[..., None], beta.astype(F32), 0.0)
+    c, seg, n = _cut(p, chunk)
+    pad = n * seg * c - p
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, g, beta))
+    # segments and their chunks lead, a head's rows of a chunk are the last
+    # two axes
+    q, k, v, g = (x.reshape(r, n, seg, c, h, -1).transpose(1, 2, 0, 4, 3, 5)
+                  for x in (q, k, v, g))            # (n, seg, r, h, c, d)
+    beta = beta.reshape(r, n, seg, c, h).transpose(1, 2, 0, 4, 3)
+    final, o = jax.lax.scan(functools.partial(_kda_segment, block),
+                            jnp.zeros((r, h, dk, dv), F32),
+                            (q, k, v, g, beta))
+    o = o.transpose(2, 0, 1, 4, 3, 5).reshape(r, n * seg * c, h, dv)
+    return o[:, :p], final
+
+
+def kda_step(state, q, k, v, g, beta):
+    """:func:`gdn_step` with a decay a channel: ``g (S, H, Dk)``; float32
+    elementwise passes over the carry, noted as ``"kda_step"``."""
+    note("kda_step", "xla")
+    q, k = (x.astype(F32)[..., None] for x in (q, k))
+    state = state * jnp.exp(g.astype(F32))[..., None]
+    held = jnp.sum(state * k, axis=-2)                      # S^T k: (S, H, Dv)
+    write = beta.astype(F32)[..., None] * (v.astype(F32) - held)
+    state = state + k * write[..., None, :]
+    return jnp.sum(state * q, axis=-2), state

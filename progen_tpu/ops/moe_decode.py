@@ -21,7 +21,10 @@ by what the caller holds and never by a knob: three matrices, the gated
 SwiGLU above; or two (``wg`` None: ``models/nemotron_h.py``'s latent
 experts), ``relu(u wu[e])^2 wd[e]`` — no gate, the activation squared.
 Everything below is the same for both but the step's tiles, two where
-there is no gate.  With ``T`` at most the MXU's 128
+there is no gate.  A gated expert's layer may state a ``limit`` (a static of
+the three calls; ``models/bailing_hybrid.py``'s last layers): its two
+products are then clipped before the activation (:func:`clipped`), and a
+call without one is the program it was.  With ``T`` at most the MXU's 128
 rows a weight tile takes no longer to use than to load, so ALL tokens go
 through every listed expert and the routing weight (zero for the others)
 selects: no sort, no gather and no scatter-add around the kernel, and the
@@ -102,6 +105,7 @@ from a knob (as ``ops/mla_decode.py`` and its siblings), and noted under
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -167,15 +171,24 @@ SORTED_STEP_BYTES = 24 << 20
 SORTED_ROW_TILE = 128
 
 
-def activation(gate, up):
+def clipped(gate, up, limit: float):
+    """A SwiGLU's two products under a ``limit``, BEFORE the activation:
+    the gate's held from above, the other on both sides."""
+    return jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+
+
+def activation(gate, up, limit: float = 0.0):
     """An expert's hidden row from its float32 products: ``silu(gate) *
-    up``, or ``relu(up)^2`` where the expert has no gate."""
+    up`` (the two :func:`clipped` first where the layer has a ``limit``),
+    or ``relu(up)^2`` where the expert has no gate."""
     if gate is None:
         return jnp.square(jax.nn.relu(up))
+    if limit:
+        gate, up = clipped(gate, up, limit)
     return jax.nn.silu(gate) * up
 
 
-def xla_expert_terms(u, eid, n_real, wt, wg, wu, wd):
+def xla_expert_terms(u, eid, n_real, wt, wg, wu, wd, limit: float = 0.0):
     """The contract in plain XLA (the tests' oracle): a loop over the
     listed experts, float32 accumulation; ``wg`` None for experts of two
     matrices."""
@@ -184,7 +197,7 @@ def xla_expert_terms(u, eid, n_real, wt, wg, wu, wd):
         gate = None if wg is None else jnp.dot(
             u, wg[e].astype(u.dtype), preferred_element_type=F32)
         up = jnp.dot(u, wu[e].astype(u.dtype), preferred_element_type=F32)
-        out = jnp.dot(activation(gate, up).astype(u.dtype),
+        out = jnp.dot(activation(gate, up, limit).astype(u.dtype),
                       wd[e].astype(u.dtype), preferred_element_type=F32)
         w = wt[i][:, None]
         return y + jnp.where(w != 0, out * w, 0.0)
@@ -192,17 +205,20 @@ def xla_expert_terms(u, eid, n_real, wt, wg, wu, wd):
     return jax.lax.fori_loop(0, n_real, item, jnp.zeros(u.shape, F32))
 
 
-def _item_product(x_ref, wt_ref, w_refs):
+def _item_product(x_ref, wt_ref, w_refs, limit: float = 0.0):
     """One step of either kernel: the rows ``x (R, h)`` through an inner
     slice of one expert — ``w_refs`` its tiles, ``(gate, up, down)`` or
     ``(up, down)`` — and their routing weights ``(R, 1)``; what the step
     adds is ``where(w != 0, out * w, 0)``: a row whose weight is zero adds
-    nothing, whatever it holds."""
+    nothing, whatever it holds.  Under a ``limit`` (a static of the call)
+    the gated form's two products are :func:`clipped` first."""
     x = x_ref[...]
     *wg_ref, wu_ref, wd_ref = w_refs
     if wg_ref:
         gate = jnp.dot(x, wg_ref[0][...], preferred_element_type=F32)
     up = jnp.dot(x, wu_ref[...], preferred_element_type=F32)
+    if wg_ref and limit:
+        gate, up = clipped(gate, up, limit)
     act = (gate * jax.nn.sigmoid(gate) * up if wg_ref
            else jnp.square(jnp.maximum(up, 0.0)))
     out = jnp.dot(act.astype(x.dtype), wd_ref[...],
@@ -210,7 +226,7 @@ def _item_product(x_ref, wt_ref, w_refs):
     return out, wt_ref[...]
 
 
-def _kernel(eid_ref, n_ref, u_ref, wt_ref, *refs):
+def _kernel(eid_ref, n_ref, u_ref, wt_ref, *refs, limit: float = 0.0):
     from jax.experimental import pallas as pl
 
     *w_refs, y_ref = refs
@@ -223,11 +239,12 @@ def _kernel(eid_ref, n_ref, u_ref, wt_ref, *refs):
 
     @pl.when(i < n_ref[0])
     def _():
-        out, w = _item_product(u_ref, wt_ref, w_refs)
+        out, w = _item_product(u_ref, wt_ref, w_refs, limit)
         y_ref[...] += jnp.where(w != 0, out * w, 0.0)
 
 
-def _grouped_kernel(eid_ref, n_ref, x_ref, wt_ref, *refs):
+def _grouped_kernel(eid_ref, n_ref, x_ref, wt_ref, *refs,
+                    limit: float = 0.0):
     from jax.experimental import pallas as pl
 
     *w_refs, o_ref = refs
@@ -235,7 +252,7 @@ def _grouped_kernel(eid_ref, n_ref, x_ref, wt_ref, *refs):
 
     @pl.when(i < n_ref[0])
     def _():
-        out, w = _item_product(x_ref, wt_ref, w_refs)
+        out, w = _item_product(x_ref, wt_ref, w_refs, limit)
         term = jnp.where(w != 0, out * w, 0.0)
 
         @pl.when(s == 0)
@@ -245,6 +262,13 @@ def _grouped_kernel(eid_ref, n_ref, x_ref, wt_ref, *refs):
         @pl.when(s != 0)
         def _():
             o_ref[...] += term
+
+
+def _under(kernel, limit: float):
+    """``kernel`` with the clip's ``limit`` as a static; the kernel itself
+    where there is none: a caller that passes no limit traces what it
+    traced."""
+    return functools.partial(kernel, limit=float(limit)) if limit else kernel
 
 
 def inner_tile(h: int, inner: int, itemsize: int, matrices: int = 3,
@@ -315,7 +339,7 @@ def _inner_steps(h, inner, itemsize, tile, matrices):
 
 
 def pallas_expert_terms(u, eid, n_real, wt, wg, wu, wd, *, tile=None,
-                        interpret=None):
+                        limit: float = 0.0, interpret=None):
     """The kernel lowering for at most ``MAX_TOKENS`` tokens; ``wg`` None
     for experts of two matrices; ``interpret=None`` auto-selects the Pallas
     interpreter off-TPU; ``tile`` (of the inner width) defaults to
@@ -345,7 +369,7 @@ def pallas_expert_terms(u, eid, n_real, wt, wg, wu, wd, *, tile=None,
             + rows * h * (itemsize + 3 * 4)         # u, y twice, a product
             + 3 * rows * ik * 4 + 2 * rows * LANE * 4)
     y = pl.pallas_call(
-        _kernel,
+        _under(_kernel, limit),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(items, steps),
@@ -367,7 +391,7 @@ def pallas_expert_terms(u, eid, n_real, wt, wg, wu, wd, *, tile=None,
 
 
 def pallas_grouped_terms(xs, eid, n_real, wt, wg, wu, wd, *, row_tile,
-                         tile=None, interpret=None):
+                         tile=None, limit: float = 0.0, interpret=None):
     """The kernel lowering for rows GROUPED by expert: ``xs (items *
     row_tile, h)``, item ``i`` the rows ``[i * row_tile, (i + 1) *
     row_tile)`` and all of them expert ``eid[i]``'s, ``wt (items *
@@ -398,7 +422,7 @@ def pallas_grouped_terms(xs, eid, n_real, wt, wg, wu, wd, *, row_tile,
             + 2 * row_tile * h * (itemsize + 2 * 4)  # rows in, terms out
             + 3 * row_tile * ik * 4 + 2 * row_tile * LANE * 4)
     return pl.pallas_call(
-        _grouped_kernel,
+        _under(_grouped_kernel, limit),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(items, steps),
@@ -447,7 +471,7 @@ def sorted_work_list(lo, hi, tiles: int, row_tile: int):
 
 
 def _sorted_kernel(eid_ref, tile_ref, lo_ref, hi_ref, tok_ref, n_ref, x_ref,
-                   wt_ref, *refs):
+                   wt_ref, *refs, limit: float = 0.0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -457,7 +481,7 @@ def _sorted_kernel(eid_ref, tile_ref, lo_ref, hi_ref, tok_ref, n_ref, x_ref,
 
     @pl.when(i < n_ref[0])
     def _():
-        out, w = _item_product(x_ref, wt_ref, w_refs)
+        out, w = _item_product(x_ref, wt_ref, w_refs, limit)
 
         @pl.when(s == 0)
         def _():
@@ -500,7 +524,7 @@ def _sorted_kernel(eid_ref, tile_ref, lo_ref, hi_ref, tok_ref, n_ref, x_ref,
 
 
 def pallas_sorted_add(y, xs, tok, wt, lo, hi, wg, wu, wd, *, row_tile,
-                      tile=None, interpret=None):
+                      tile=None, limit: float = 0.0, interpret=None):
     """The kernel lowering for rows SORTED by expert and not padded: ``xs
     (N, h)``, ``N`` a multiple of ``row_tile``, expert ``e``'s rows at
     ``[lo[e], hi[e])`` (``lo``, ``hi (held,)``, ascending and back to back;
@@ -545,7 +569,7 @@ def pallas_sorted_add(y, xs, tok, wt, lo, hi, wg, wu, wd, *, row_tile,
     operands = (eid, tiles, lo, hi, tok.astype(jnp.int32), n_real, xs,
                 wt.astype(F32)[:, None], *weights, y)
     return pl.pallas_call(
-        _sorted_kernel,
+        _under(_sorted_kernel, limit),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
             grid=(eid.shape[0], steps),

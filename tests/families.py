@@ -47,7 +47,8 @@ SLOTS = ADMIT_ROWS * SLOTS_PER_ADMIT_ROW
 MODES = {"paged": True, "disagg": True, "lora_bank": {},
          "quantize": "weights", "mesh": object()}
 # the arguments of the model modules' functions that are not arrays
-STATIC = ("config", "c", "policy", "max_len", "with_choices", "capacity")
+STATIC = ("config", "c", "policy", "max_len", "with_choices", "capacity",
+          "limit")
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -165,6 +166,13 @@ CASES = {case.name: case for case in (
                "qwen3_next_tiny", "reference_qwen3next",
                primes=(3, 12, 1, 21, 2, 9), greedy=(6,),
                sampled=ADMIT_ROWS + 3, counted=dict(n=4, seed=5)),
+    # three shorter than the four taps; 9, 12, 19 and 21 cross blocks of 4
+    # and chunks of 8
+    FamilyCase("bailing_hybrid", "bailing_hybrid", "BailingHybridFamily",
+               "bailing_hybrid_tiny", "reference_ling3",
+               primes=(3, 12, 1, 19, 2, 9), greedy=(6,),
+               sampled=ADMIT_ROWS + 3, counted=dict(n=4, seed=5),
+               forward=dict(q_block=8)),
 )}
 
 

@@ -862,6 +862,26 @@ WHOLE_PROGRAMS = {
                               "f32[32,2,16,2,64,64]",
                               "bf16[8,32,2,16,64,128]",
                               "bf16[8,32,2,16,2,64,128]")}),
+    # the published layers 0 and 37-41 of 42 (five channel-decay delta-rule
+    # layers to one gated latent layer), 128 of 512 experts under a SwiGLU
+    # limit, a quarter of the vocabulary, 64 slots: five float32 carries and
+    # tails and ONE latent leaf of 17,408 rows of 576; the latent layer's
+    # cores ``mla_decode_fwd`` / ``mla_prefill_fwd`` at 32 heads, the delta
+    # rule plain XLA in both programs, a row of the admission at a time; 4
+    # rows at the 16,384 bucket through ``moe_sorted_fwd``
+    "ling3": Whole(
+        "serve-ling3-longdoc-backlog", "bailing_hybrid",
+        lambda m: _perf_config(m, "BailingHybridConfig",
+                               "ling-3.0-flash-ep4pp7"),
+        dict(num_slots=64, chunk_size=32, max_len=17408), admit=(4, 16384),
+        weights=(8.70e9, 8.72e9), state=(1.9e9, 2.1e9),
+        ops=("ops.row_write", "ops.mla_decode", "ops.mla_prefill",
+             "ops.moe_decode", "ops.kth"),
+        chunk=("tpu_custom_call", "mla_decode_fwd", "moe_decode_fwd",
+               "row_write"),
+        admission=("tpu_custom_call", "moe_sorted_fwd", "mla_prefill_fwd"),
+        never={"chunk": ("mla_prefill_fwd", "gdn_prefill_fwd"),
+               "admit": ("mla_decode_fwd", "gdn_prefill_fwd")}),
 }
 
 PROGRAMS = [(name, program) for name, row in WHOLE_PROGRAMS.items()

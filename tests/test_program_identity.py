@@ -1,5 +1,5 @@
 """The engine's programs, held to their recorded text: the admission program
-at the smallest bucket and the chunk program of each of the twelve families at
+at the smallest bucket and the chunk program of each of the thirteen families at
 its tiny configuration hash to the heads in ``tests/golden/programs.json``
 (``tools/program_hash.py`` makes both).  A PR that leaves a family's serving
 path alone leaves its two heads alone, and this is where it shows."""

@@ -1,6 +1,6 @@
 """The text of the engine's programs, hashed: one head per family and program.
 
-For each of the twelve model families at its tiny test configuration
+For each of the thirteen model families at its tiny test configuration
 (``tests/*_tiny.py``; ProGen's is ``tests/test_serving.py``'s ``CFG``) this
 builds ``ServingEngine`` as the family's engine test does, takes
 ``jax.make_jaxpr`` of the admission program at the smallest prefill bucket
@@ -34,7 +34,8 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 GOLDEN = os.path.join(REPO, "tests", "golden", "programs.json")
 FAMILIES = ("progen", "longcat", "deepseek_v2", "trinity", "granite", "sdar",
-            "lfm2", "nemotron_h", "mimo_v2", "dots3", "glm_dsa", "qwen3_next")
+            "lfm2", "nemotron_h", "mimo_v2", "dots3", "glm_dsa", "qwen3_next",
+            "bailing_hybrid")
 PROGRAMS = ("admit", "chunk")
 HEAD = 16
 # what the families' engine tests build, over two admission rows of slots
