@@ -507,7 +507,10 @@ class ChannelDeltaBlock(DeltaBlock):
         return out[:, 0], {name: a[:, 0] for name, a in rows.items()}
 
     def scan_lowering(self, p: int) -> str:
-        return "xla"
+        """What ``gdn.kda_scan`` takes for this block's rows padded to
+        ``p``, traced here and now."""
+        return gdn.kda_scan_lowering(p, self.key_dim, self.value_dim,
+                                     self.chunk, self.block)
 
     def decode(self, u, pos, cache, p):
         """One token a row: the tail shifted, the carry decayed a channel,
@@ -525,7 +528,7 @@ class ChannelDeltaBlock(DeltaBlock):
 
 
 # the channel-decay block's device counters: :data:`DELTA_STAT_KEYS` under
-# its own names (its chunked form is plain XLA: every chunk of the bucket)
+# its own names
 KDA_STAT_KEYS = ("kda.scan_slots", "kda.real_tokens", "kda.state_bytes")
 
 
@@ -540,6 +543,8 @@ def kda_prefill_stats(blocks: dict, tokens_shape, lengths) -> dict:
     """A prefill's ``kda.*`` counters over rows of ``lengths`` padded to
     ``tokens_shape = (R, P)``."""
     mine = _delta_blocks(blocks, ChannelDeltaBlock)
-    slots = sum(gdn.scanned_slots(*tokens_shape, b.chunk) for b in mine)
+    slots = sum(gdn.computed_slots(lengths, tokens_shape[1], b.chunk,
+                                   b.scan_lowering(tokens_shape[1]))
+                for b in mine)
     return {"kda.real_tokens": len(mine) * jnp.sum(lengths).astype(F32),
             "kda.scan_slots": jnp.asarray(slots, F32)}

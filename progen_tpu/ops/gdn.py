@@ -104,6 +104,35 @@ and written once: ``(o (S, Hv, Dv) float32, state)``.  No matrix unit: the
 decay, the erase, the write and the read-out are float32 elementwise passes
 over the carry, so the carry is never rounded.  Plain XLA, noted as
 ``"gdn_step"``.
+
+:func:`kda_scan` / :func:`kda_step` — the same rule with a decay a key
+CHANNEL (``g (R, P, H, Dk)``, as many key heads as value heads; the formulas
+are in :func:`kda_scan`'s docstring).  The decay sits inside the sum over
+``Dk``, so a chunk's two products are made by blocks of 16 rows
+(:func:`decayed_products`), every exponent non-positive.  The chunked form
+has two lowerings of that one contract too, chosen by
+:func:`kda_scan_lowering` and noted under ``"kda_prefill"``: **the kernel**
+``kda_prefill_fwd`` (``"pallas"``: a TPU, no mesh in scope, ``Dk`` and ``Dv``
+whole lane tiles, ``C`` whole blocks of ``SOLVED`` rows, the products' blocks
+``SOLVED`` rows) in ``gdn_prefill_fwd``'s mould — the same grid, carries,
+in-place blocks of ``q``, ``k``, ``v``, ``o`` and the stop at a row's length
+—, with ``g`` streamed as ``k`` is (a ``(1, tokens, heads * Dk)`` float32
+block: no chunk-major copy of anything, ``beta`` alone with the tokens on the
+lanes) and EVERY operation over the grid step's four heads at once: ``gam``
+by one float32 product with a triangle of ones; the diagonal blocks' direct
+``16 x 16 x Dk`` sums on the vector unit (:func:`_diagonal_blocks`), sent to
+their columns by one 0 / 1 product; the blocks under the diagonal against
+the last row of their column block, all column blocks in ONE product
+(:func:`_blocks_below`); ``T`` by :func:`blocked_lower_inverse`; then the XLA
+form's lines with ``gam`` a ``(C, Dk)`` array a head, float32 where it is
+float32 and rounded only where it rounds.  On a v5e, bfloat16, 32 heads of
+128, chunks of 64: 15.0 ms a layer at one full row of 16,384 tokens where
+the XLA form takes 54.3 (the inverse 5.0 of them, the diagonal sums 3.7), 6.5
+at a row of 6,000 in that bucket (PERF.md section 6, PR 66).  **The XLA
+form** (:func:`xla_kda_scan`: the CPU, a mesh, any other shape):
+:func:`xla_gdn_scan`'s segments and scans, every chunk of the bucket.
+:func:`kda_step` is :func:`gdn_step`'s passes with the decay a ``(S, H, Dk)``
+array, noted as ``"kda_step"``.
 """
 
 from __future__ import annotations
@@ -611,6 +640,21 @@ def _kda_segment(block, s, xs):
     return jax.lax.scan(chunk_of, s, (u, w, within, q_in, k_out, whole))
 
 
+def kda_scan_lowering(p: int, dk: int, dv: int, chunk: int,
+                      block: int) -> str:
+    """``"pallas"`` or ``"xla"``: what :func:`kda_scan` takes for rows padded
+    to ``p`` tokens, keys ``dk`` and values ``dv`` wide, in chunks of
+    ``chunk`` cut into blocks of ``block`` rows, traced here and now: the
+    kernel on a TPU with no mesh in scope, both widths on the lane tile, the
+    chunk ``min(chunk, p)`` whole blocks of ``SOLVED`` rows and the products'
+    blocks the inverse's own (``block == SOLVED``).  Any count of heads will
+    do (:func:`key_heads_a_step`), so it is not asked."""
+    kernel = (_on_tpu() and not _mesh_in_scope() and dk % 128 == 0
+              and dv % 128 == 0 and min(chunk, p) % SOLVED == 0
+              and block == SOLVED)
+    return "pallas" if kernel else "xla"
+
+
 def kda_scan(q, k, v, g, beta, lengths, chunk: int, block: int = BLOCK):
     """:func:`gdn_scan` with a decay a CHANNEL (Kimi Delta Attention):
     ``q, k (R, P, H, Dk)``, ``v (R, P, H, Dv)``, ``g (R, P, H, Dk)`` the log
@@ -629,12 +673,24 @@ def kda_scan(q, k, v, g, beta, lengths, chunk: int, block: int = BLOCK):
         S <- diag(exp(gam_C)) S + (K exp(gam_C - gam))^T V'
 
     Results, padding (``g = beta = 0`` at and past ``lengths``; the carry at
-    each row's TRUE length), segments and precision as the XLA form of
-    :func:`gdn_scan`; the two decayed products by blocks of ``block`` rows
-    (:func:`decayed_products`), so that every exponent taken is of a
-    non-positive number whatever the gate's bound.  Plain XLA, every chunk
-    of the bucket, noted as ``"kda_prefill"``."""
-    note("kda_prefill", "xla")
+    each row's TRUE length) and precision as :func:`gdn_scan`; the two
+    decayed products by blocks of ``block`` rows (:func:`decayed_products`),
+    so that every exponent taken is of a non-positive number whatever the
+    gate's bound.  Two lowerings of that one contract, chosen by
+    :func:`kda_scan_lowering` and noted as ``"kda_prefill"``: the kernel
+    ``kda_prefill_fwd`` (:func:`pallas_kda_scan`) and the XLA form
+    (:func:`xla_kda_scan`)."""
+    r, p, h, dk = k.shape
+    lowering = kda_scan_lowering(p, dk, v.shape[3], chunk, block)
+    note("kda_prefill", lowering)
+    if lowering == "pallas":
+        return pallas_kda_scan(q, k, v, g, beta, lengths, chunk)
+    return xla_kda_scan(q, k, v, g, beta, lengths, chunk, block)
+
+
+def xla_kda_scan(q, k, v, g, beta, lengths, chunk: int, block: int = BLOCK):
+    """The XLA form of :func:`kda_scan`: every chunk of the bucket, a
+    segment's triangles side by side (:func:`xla_gdn_scan`'s segments)."""
     r, p, h, dk = k.shape
     dv = v.shape[3]
     real = jnp.arange(p)[None, :] < lengths[:, None]
@@ -656,6 +712,232 @@ def kda_scan(q, k, v, g, beta, lengths, chunk: int, block: int = BLOCK):
                             (q, k, v, g, beta))
     o = o.transpose(2, 0, 1, 4, 3, 5).reshape(r, n * seg * c, h, dv)
     return o[:, :p], final
+
+
+def _diagonal_blocks(q, k, gam):
+    """:func:`_diagonal_products` as the kernel makes them, of every block
+    of ``q, k, gam (heads, c, dk)`` float32: ``(heads, blocks, 16, 16)``
+    twice, ``i`` then ``j``.  The mask comes from an iota (the chip's
+    compiler takes no boolean constant) and the exponent is held at 0 from
+    above (``gam`` is a product's sum)."""
+    heads, c, dk = k.shape
+    cut = (heads, c // SOLVED, SOLVED, dk)
+    q, k, gam = q.reshape(cut), k.reshape(cut), gam.reshape(cut)
+    pair = (SOLVED, SOLVED, dk)
+    inside = (jax.lax.broadcasted_iota(jnp.int32, pair, 0)
+              >= jax.lax.broadcasted_iota(jnp.int32, pair, 1))
+    apart = gam[:, :, :, None] - gam[:, :, None]            # gam_i - gam_j
+    cols = k[:, :, None] * jnp.where(
+        inside, jnp.exp(jnp.minimum(apart, 0.0)), 0.0)
+    return (jnp.sum(k[:, :, :, None] * cols, axis=-1),
+            jnp.sum(q[:, :, :, None] * cols, axis=-1))
+
+
+def _blocks_below(q, k, gam, ends, dtype):
+    """The decayed products of the blocks UNDER the diagonal, ``k`` over
+    ``q`` ``(heads, 2 c, c)`` float32 (what lies in and above the diagonal
+    blocks is not theirs: the caller masks it).  ``ends[J] (heads, 1, dk)``
+    is ``gam`` at the last row of block ``J``, the reference of its columns:
+    rows ``(x exp(gam - ends[J]))``, columns ``(k exp(ends[J] - gam_J))``,
+    both exponents non-positive (held there: ``gam`` is a product's sum),
+    both factors rounded to ``dtype``.  ONE product a chunk: block ``J``'s
+    row factors side by side on the lanes, its columns' factors zero
+    outside its own rows."""
+    heads, c, dk = k.shape
+    own = jnp.concatenate([jnp.broadcast_to(e, (heads, SOLVED, dk))
+                           for e in ends], 1)
+    cols = (k * jnp.exp(jnp.minimum(own - gam, 0.0))).astype(dtype)
+    block = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) // SOLVED
+    down = [jnp.exp(jnp.minimum(gam - e, 0.0)) for e in ends[:-1]]
+    rows = jnp.concatenate(
+        [jnp.concatenate([(x * d).astype(dtype) for d in down], 2)
+         for x in (k, q)], 1)                           # (heads, 2 c, J dk)
+    mine = jnp.concatenate(
+        [jnp.where(block == j, cols, jnp.zeros_like(cols))
+         for j in range(len(down))], 2)                 # (heads, c, J dk)
+    return jnp.einsum("hid,hjd->hij", rows, mine,
+                      preferred_element_type=F32)
+
+
+def _kda_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, carry_ref,
+                s_ref, *, c, nb):
+    """One grid step: ``nb`` chunks of ``c`` tokens of one row, one group of
+    heads, every operation over the group's heads at once.  ``g_ref (1,
+    tokens, heads * Dk)`` float32 as ``k_ref`` is; ``b_ref (1, 1, nb, heads,
+    c)``: a chunk's ``beta`` a head, tokens on the lanes.  ``s_ref (heads,
+    Dk, Dv)`` float32 carries the state from the row's first chunk to its
+    last."""
+    from jax.experimental import pallas as pl
+
+    ri, bi = pl.program_id(0), pl.program_id(2)
+    length = len_ref[ri]
+    heads, dk, dv = s_ref.shape
+    dtype = v_ref.dtype
+    row, col = _iota(c)
+    strict, summed = row > col, (row >= col).astype(F32)
+    same, under = row // SOLVED == col // SOLVED, row // SOLVED > col // SOLVED
+    # sends column l of a block's 16 to the lanes l, 16 + l, 32 + l, ...
+    spread = (col - col // SOLVED * SOLVED == row)[:SOLVED].astype(F32)
+    token = row[:, :1]
+
+    @pl.when(bi == 0)
+    def _():
+        s_ref[...] = jnp.zeros(s_ref.shape, F32)
+
+    def a_head(x, width):
+        """``(heads, rows, width)`` of ``x (rows, heads * width)``."""
+        return jnp.stack([x[:, h * width:(h + 1) * width]
+                          for h in range(heads)])
+
+    def columns(x):
+        """``(heads, rows, 1)`` of ``x (heads, rows)``, a head's numbers
+        along the sublanes: a product with the identity in place of a
+        transpose."""
+        i, j = _iota(x.shape[1])
+        return a_head(_dot((i == j).astype(F32), x, _NT, HIGHEST), 1)
+
+    def computed(ci, rows):
+        real = (bi * nb + ci) * c + token < length
+        # the cumulative decay of every head at once: a float32 product with
+        # a triangle of ones, held at 0 from above (the matrix unit adds a
+        # float32's pieces in an order of its own)
+        g = jnp.where(real, g_ref[0, rows, :], 0.0)
+        gam = a_head(jnp.minimum(_dot(summed, g, _NN, HIGHEST), 0.0), dk)
+        kf = a_head(k_ref[0, rows, :], dk).astype(F32)
+        qf = a_head(q_ref[0, rows, :], dk).astype(F32)
+        beta = columns(b_ref[0, 0, ci])
+        # the decayed products by blocks of 16 rows: the diagonal blocks'
+        # direct sums sent to their columns by a 0 / 1 product, the blocks
+        # under them against the last row of their column block
+        kk, qk = _diagonal_blocks(qf, kf, gam)
+        both = _matmul(jnp.concatenate([kk.reshape(heads, c, SOLVED),
+                                        qk.reshape(heads, c, SOLVED)], 1),
+                       spread)                          # (heads, 2 c, c)
+        kk, qk = (jnp.where(same, x, 0.0) for x in (both[:, :c], both[:, c:]))
+        ends = [gam[:, (j + 1) * SOLVED - 1:(j + 1) * SOLVED]
+                for j in range(c // SOLVED)]            # (heads, 1, dk) each
+        if len(ends) > 1:
+            below = _blocks_below(qf, kf, gam, ends, dtype)
+            kk, qk = (x + jnp.where(under, y, 0.0)
+                      for x, y in ((kk, below[:, :c]), (qk, below[:, c:])))
+        t = blocked_lower_inverse(jnp.where(strict, -(beta * kk), 0.0))
+        # ``value_head``'s lines with ``gam`` a (c, dk) array a head
+        grown, end = jnp.exp(gam), ends[-1]
+        values = a_head(v_ref[0, rows, :], dv).astype(F32)
+        u = _matmul(t, beta * values)
+        w = _matmul(t, beta * (kf * grown)).astype(dtype)
+        q_in = (qf * grown).astype(dtype)
+        k_out = (kf * jnp.exp(jnp.minimum(end - gam, 0.0))).astype(dtype)
+        s = s_ref[...]
+        sd = s.astype(dtype)
+        fresh = (u - jnp.matmul(w, sd, preferred_element_type=F32)).astype(
+            dtype)
+        o = (jnp.matmul(q_in, sd, preferred_element_type=F32)
+             + jnp.matmul(qk.astype(dtype), fresh,
+                          preferred_element_type=F32))
+        o_ref[0, rows, :] = jnp.concatenate(
+            [o[h] for h in range(heads)], 1).astype(o_ref.dtype)
+        whole = jnp.exp(columns(jnp.concatenate(
+            [end[h] for h in range(heads)], 0)))        # (heads, dk, 1)
+        s_ref[...] = s * whole + jnp.einsum(
+            "hck,hcv->hkv", k_out, fresh, preferred_element_type=F32)
+
+    def chunk(ci, done):
+        rows = pl.ds(pl.multiple_of(ci * c, c), c)
+        live = (bi * nb + ci) * c < length
+
+        @pl.when(live)
+        def _():
+            computed(ci, rows)
+
+        @pl.when(jnp.logical_not(live))
+        def _():
+            o_ref[0, rows, :] = jnp.zeros((c, o_ref.shape[2]), o_ref.dtype)
+
+        return done
+
+    jax.lax.fori_loop(0, nb, chunk, 0)
+
+    @pl.when(bi == pl.num_programs(2) - 1)
+    def _():
+        carry_ref[0] = s_ref[...]
+
+
+def pallas_kda_scan(q, k, v, g, beta, lengths, chunk: int, *,
+                    interpret=None):
+    """The kernel lowering of :func:`kda_scan` (blocks of ``SOLVED`` rows).
+    ``q``, ``k``, ``v``, ``o`` and ``g`` are read and written in place as
+    ``(R, P, heads * width)``, ``g`` float32 and zeroed past ``lengths`` in
+    the kernel; ``beta`` alone, zeroed at and past ``lengths``, is handed
+    over a chunk at a time with the tokens on the lanes.  ``interpret=None``
+    auto-selects the Pallas interpreter off-TPU."""
+    r, p, h, dk = k.shape
+    dv = v.shape[3]
+    c = min(chunk, p)
+    kh = key_heads_a_step(h, 1)
+    nb = max(1, min(STEP_TOKENS // c, -(-p // c)))
+    padded = -(-p // (nb * c)) * nb * c
+    real = jnp.arange(p)[None, :] < lengths[:, None]
+    beta = jnp.where(real[..., None], beta.astype(F32), 0.0)
+    q, k, v, g = (x.reshape(r, p, -1) for x in (q, k, v, g.astype(F32)))
+    if padded != p:
+        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, padded - p), (0, 0)))
+                            for x in (q, k, v, g, beta))
+    beta = beta.reshape(r, padded // c, c, h // kh, kh).transpose(
+        0, 3, 1, 4, 2)                      # (r, groups, chunks, heads, c)
+    o, carry = _kda_call(
+        q, k, v, g, beta, lengths.astype(jnp.int32), c=c, nb=nb, kh=kh,
+        dk=dk, interpret=not _on_tpu() if interpret is None else interpret)
+    return o[:, :p].reshape(r, p, h, dv), carry
+
+
+# jitted as ``_scan_call`` is: a model's delta layers share ONE traced and
+# lowered kernel a bucket
+@functools.partial(jax.jit, static_argnames=("c", "nb", "kh", "dk",
+                                             "interpret"))
+def _kda_call(q, k, v, g, beta, lengths, *, c, nb, kh, dk, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    r, padded = q.shape[:2]
+    h = q.shape[2] // dk
+    dv, tb = v.shape[2] // h, nb * c
+
+    def fetched(ri, bi, len_ref):
+        # a block wholly past the row's length is neither computed nor
+        # fetched: its steps point at the row's last live block
+        return jnp.minimum(bi, jnp.maximum(-(-len_ref[ri] // tb) - 1, 0))
+
+    def tokens(ri, gi, bi, len_ref):
+        return ri, fetched(ri, bi, len_ref), gi
+
+    def gates(ri, gi, bi, len_ref):
+        return ri, gi, fetched(ri, bi, len_ref), 0, 0
+
+    return pl.pallas_call(
+        functools.partial(_kda_kernel, c=c, nb=nb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(r, h // kh, padded // tb),
+            in_specs=[pl.BlockSpec((1, tb, kh * dk), tokens),
+                      pl.BlockSpec((1, tb, kh * dk), tokens),
+                      pl.BlockSpec((1, tb, kh * dv), tokens),
+                      pl.BlockSpec((1, tb, kh * dk), tokens),
+                      pl.BlockSpec((1, 1, nb, kh, c), gates)],
+            out_specs=[
+                pl.BlockSpec((1, tb, kh * dv),
+                             lambda ri, gi, bi, len_ref: (ri, bi, gi)),
+                pl.BlockSpec((1, kh, dk, dv),
+                             lambda ri, gi, bi, len_ref: (ri, gi, 0, 0))],
+            scratch_shapes=[pltpu.VMEM((kh, dk, dv), F32)],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct((r, h, dk, dv), F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="kda_prefill_fwd",
+    )(lengths, q, k, v, g, beta)
 
 
 def kda_step(state, q, k, v, g, beta):

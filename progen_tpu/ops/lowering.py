@@ -12,10 +12,11 @@ DMAs) and ``ops/gdn.py:gdn_scan`` (the note ``"gdn_prefill"``: the kernel
 ``gdn_prefill_fwd`` at widths on the lane tile and chunks of whole blocks
 of 16 rows, which stops at a row's true length; its one-token sibling
 ``gdn_step`` is plain XLA and says ``"xla"`` under ``"gdn_step"``; the rule
-with a decay a channel, ``kda_scan`` / ``kda_step``, is plain XLA in both
-forms and says so under ``"kda_prefill"`` / ``"kda_step"``, so that a later
-kernel has a name to take) each keep
-a Pallas
+with a decay a channel, ``kda_scan``, chooses in the same way under
+``"kda_prefill"`` — the kernel ``kda_prefill_fwd`` at widths on the lane
+tile, chunks of whole blocks of 16 rows and products in blocks of 16 —, and
+its one-token sibling ``kda_step`` says ``"xla"`` under ``"kda_step"``) each
+keep a Pallas
 lowering and an XLA form behind one function and choose between them from
 the backend, the mesh in scope and the shapes, never from a knob.
 ``ops/kth.py:kth_largest_by_counting`` chooses in the same way between two

@@ -2,13 +2,15 @@
 (``perf/lib/reference_ling3.py``) at tiny widths on the CPU, seeded weights:
 the forward over right-padded rows, prefill then decode through the blocks'
 caches, the chunked channel-decay delta rule against the recurrence token by
-token at a block's and a chunk's edges, a chunk whose decays sit AT the
-gate's bound, the rule with every channel's decay equal against
+token at a block's and a chunk's edges — by the XLA form and by the kernel
+``kda_prefill_fwd`` under the interpreter, which stops at a row's length —, a
+chunk whose decays sit AT the gate's bound, the rule with every channel's decay equal against
 ``gdn_scan``, the one-token step, the gate a head after the norm, the
 full-rank query under interleaved rotary pairs, what each kind of layer
 states about its cache and the config's refusals."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -119,14 +121,17 @@ def _delta_inputs(r, p, h=2, dk=8, dv=8, seed=0, spread=2.0, shift=0.0):
 
 @jax.jit
 def _token_by_token(q, k, v, g, beta):
-    """The reference's recurrence over one row ``(P, ...)``: every output
-    and the carry after every token."""
-    def token(s, at):
-        s, o = ref.delta_token(s, *at)
-        return s, (o, s)
+    """The reference's recurrence over rows ``(R, P, ...)``: every output
+    and the carry after every token, a row."""
+    def row(q, k, v, g, beta):
+        def token(s, at):
+            s, o = ref.delta_token(s, *at)
+            return s, (o, s)
 
-    zero = jnp.zeros((v.shape[1], k.shape[2], v.shape[2]), F32)
-    return jax.lax.scan(token, zero, (q, k, v, jnp.exp(g), beta))[1]
+        zero = jnp.zeros((v.shape[1], k.shape[2], v.shape[2]), F32)
+        return jax.lax.scan(token, zero, (q, k, v, jnp.exp(g), beta))[1]
+
+    return jax.vmap(row)(q, k, v, g, beta)
 
 
 @jax.jit
@@ -134,49 +139,117 @@ def _scan(q, k, v, g, beta, lengths):
     return gdn.kda_scan(q, k, v, g, beta, lengths, C, B)
 
 
-def _held_to_the_recurrence(inputs, lengths, tol=1e-6):
-    q, k, v, g, beta = inputs
+WIDE = dict(dk=128, dv=128)     # the kernel's widths: whole lane tiles
+KC = 64                         # its chunk, in blocks of gdn.SOLVED rows
+# The CARRY's limit at these sizes (sums of 128 terms over chunks of 64, a
+# carry of 0.3-0.5), set between two readings over ``CHUNKED``'s kernel cases
+# (PR 66, this file's inputs): the float32 forms lie at most 1.4e-6 (the
+# kernel) and 2.6e-6 (``xla_kda_scan`` in chunks of 64, blocks of 16) from the
+# recurrence, a bfloat16 path about 1e-3.  The OUTPUTS keep the XLA cases'
+# 1e-6 (the kernel's largest: 7.9e-8, the XLA form's 1.0e-7).
+WIDE_TOL = 4e-6
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel(step=None):
+    """The kernel under the interpreter in chunks of 64, ONE program a
+    ``step`` (the tokens a grid step that the caller has patched into
+    ``gdn.STEP_TOKENS``, which the trace reads) and shape: ``lengths`` is a
+    runtime scalar, so cases that differ in it alone share a compiled
+    body."""
+    return jax.jit(lambda *a: gdn.pallas_kda_scan(*a, KC, interpret=True))
+
+
+def _held_to_the_recurrence(inputs, lengths, scan=_scan, carry_tol=1e-6):
+    """``scan``'s outputs at real positions (to 1e-6) and its carry at each
+    row's true length (to ``carry_tol``) against the recurrence token by
+    token (compared on the host: a slice a length is no program of its
+    own)."""
     with jax.default_matmul_precision("highest"):
-        o, carry = _scan(q, k, v, g, beta, jnp.asarray(lengths))
-        for i, n in enumerate(lengths):
-            want_o, carries = _token_by_token(q[i], k[i], v[i], g[i],
-                                              beta[i])
-            want = carries[n - 1] if n else jnp.zeros_like(carries[0])
-            assert float(jnp.abs(carry[i] - want).max()) < tol, (i, n)
-            if n:
-                assert float(jnp.abs(o[i, :n] - want_o[:n]).max()) < tol
-    assert bool(jnp.isfinite(o).all())
+        o, carry = (np.asarray(a) for a in scan(*inputs,
+                                                jnp.asarray(lengths)))
+        want_o, carries = (np.asarray(a) for a in _token_by_token(*inputs))
+    for i, n in enumerate(lengths):
+        want = carries[i, n - 1] if n else np.zeros_like(carries[i, 0])
+        assert np.abs(carry[i] - want).max() < carry_tol, (i, n)
+        assert np.abs(o[i, :n] - want_o[i, :n]).max(initial=0) < 1e-6, (i, n)
+    assert np.isfinite(o).all()
     return o, carry
 
 
-# (lengths, bucket): the first two are ONE compiled shape
+# (tokens a grid step of the kernel | None: the XLA form, heads, lengths,
+# bucket); the XLA form's first two are ONE compiled shape
 CHUNKED = {
     "a-blocks-and-a-chunks-edges": (
-        (0, 1, B - 1, B, B + 1, C - 1, C, C + 1), 24),
-    "a-padded-bucket": ((19, 7, 24, 2, 0, 13, 24, 17), 24),
-    "a-bucket-past-whole-chunks": ((21, 13), 21),
+        None, 2, (0, 1, B - 1, B, B + 1, C - 1, C, C + 1), 24),
+    "a-padded-bucket": (None, 2, (19, 7, 24, 2, 0, 13, 24, 17), 24),
+    "a-bucket-past-whole-chunks": (None, 2, (21, 13), 21),
+    "kernel-a-blocks-and-a-chunks-edges": (
+        512, 2, (0, 1, 15, 16, 17, 63, 64, 65), 128),
+    # three grid steps of two chunks a row: a row that ends in the last, one
+    # in the first and one at the second's second token
+    "kernel-rows-end-in-other-steps": (128, 2, (300, 70, 129), 384),
+    # eight heads: two grid steps of four a block of tokens
+    "kernel-a-bucket-past-whole-steps": (128, 8, (200, 77), 200),
 }
 
 
 @pytest.mark.parametrize("case", CHUNKED)
-def test_the_chunked_form_is_the_recurrence_token_by_token(case):
+def test_the_chunked_form_is_the_recurrence_token_by_token(case, monkeypatch):
     """Rows of 0, 1, block - 1, block, block + 1, C - 1, C and C + 1 tokens,
     and rows padded to a bucket past whole chunks: the outputs at real
     positions and the carry AT EACH ROW'S TRUE LENGTH (zeros for a row of
-    length 0) are the recurrence's, whatever the padding holds."""
-    lengths, bucket = CHUNKED[case]
-    _, carry = _held_to_the_recurrence(
-        _delta_inputs(len(lengths), bucket, seed=bucket), lengths)
-    assert float(jnp.abs(carry).max()) > 0.5        # not a vacuous bound
-    assert gdn.scanned_slots(len(lengths), bucket, C) == (
-        len(lengths) * -(-bucket // C) * C)
+    length 0) are the recurrence's, whatever the padding holds — by the XLA
+    form and by the kernel, whose rows also end in different grid steps and
+    whose bucket need not be whole ones."""
+    step, heads, lengths, bucket = CHUNKED[case]
+    chunk = KC if step else C
+    if step:
+        monkeypatch.setattr(gdn, "STEP_TOKENS", step)
+    o, carry = _held_to_the_recurrence(
+        _delta_inputs(len(lengths), bucket, h=heads, seed=bucket,
+                      **(WIDE if step else {})),
+        lengths, *((_kernel(step), WIDE_TOL) if step else ()))
+    # not a vacuous bound (a unit key of 128 columns has smaller entries)
+    assert np.abs(carry).max() > (0.3 if step else 0.5)
+    assert gdn.scanned_slots(len(lengths), bucket, chunk) == (
+        len(lengths) * -(-bucket // chunk) * chunk)
+    if step:        # nothing is left in a chunk wholly past a row's length
+        for i, n in enumerate(lengths):
+            assert not o[i, -(-n // chunk) * chunk:].any()
+
+
+def test_the_kernel_leaves_a_chunk_past_a_rows_length_alone(monkeypatch):
+    """NaN in every chunk that lies wholly past its row's length (and in
+    ``g`` and ``beta`` from the length on): ``o`` is zero there, and ``o``
+    before it and the carry are, bit for bit, what clean inputs give.  The
+    shape of ``kernel-rows-end-in-other-steps``: one compiled body."""
+    lengths, bucket = (130, 0, 64), 384
+    monkeypatch.setattr(gdn, "STEP_TOKENS", 128)
+    clean = _delta_inputs(3, bucket, seed=5, **WIDE)
+    past = (jnp.arange(bucket)[None, :]
+            >= -(-jnp.asarray(lengths) // KC)[:, None] * KC)
+    at = jnp.arange(bucket)[None, :] >= jnp.asarray(lengths)[:, None]
+    q, k, v = (jnp.where(past[..., None, None], jnp.nan, x)
+               for x in clean[:3])
+    g = jnp.where(at[..., None, None], jnp.nan, clean[3])
+    beta = jnp.where(at[..., None], jnp.nan, clean[4])
+    o, carry = _kernel(128)(q, k, v, g, beta, jnp.asarray(lengths))
+    want_o, want = _kernel(128)(*clean, jnp.asarray(lengths))
+    assert bool(jnp.isnan(q).any()) and bool(jnp.isnan(g).any())
+    np.testing.assert_array_equal(carry, want)
+    assert not np.asarray(carry[1]).any()
+    assert not np.asarray(jnp.where(past[..., None, None], o, 0.0)).any()
+    for i, n in enumerate(lengths):
+        np.testing.assert_array_equal(o[i, :n], want_o[i, :n])
 
 
 def test_a_chunk_whose_decays_sit_at_the_bound_stays_finite_and_equal():
     """Channels at the gate's bound of -5 a token beside channels that
     hardly decay: inside a chunk ``exp(-gam)`` alone would pass e^35 here
     (e^320 at the published chunk of 64: not a float32), and the products
-    by blocks take no positive exponent at all."""
+    by blocks take no positive exponent at all — in the XLA form and in the
+    kernel."""
     q, k, v, g, beta = _delta_inputs(8, 24, seed=3)
     at_bound = (jnp.arange(8) % 2 == 0)[None, None, None, :]
     g = jnp.broadcast_to(jnp.where(at_bound, -5.0 + 1e-4, -1e-3), g.shape)
@@ -184,48 +257,104 @@ def test_a_chunk_whose_decays_sit_at_the_bound_stays_finite_and_equal():
     _held_to_the_recurrence((q, k, v, g, beta), (24, 17, 8, 9, 1, 0, 16, 23))
     # the published sizes: a chunk of 64 in blocks of 16, every channel at
     # the bound, would overflow any form that takes exp(-gam)
-    wide = _delta_inputs(1, 64, h=1, seed=4)
-    wide = wide[:3] + (jnp.full_like(wide[3], -5.0 + 1e-4), wide[4])
-    with jax.default_matmul_precision("highest"):
-        o, carry = jax.jit(lambda *a: gdn.kda_scan(*a, 64, 16))(
-            *wide, jnp.asarray([64]))
-        want_o, carries = _token_by_token(*(a[0] for a in wide))
-    assert float(jnp.exp(-jnp.sum(wide[3][0, :, 0, 0]))) == float("inf")
-    np.testing.assert_allclose(o[0], want_o, atol=1e-6)
-    np.testing.assert_allclose(carry[0], carries[-1], atol=1e-6)
+    # (both to 1e-6: at the bound the carry is small, and both read 3e-8)
+    for widths, scan in (({}, jax.jit(
+            lambda *a: gdn.xla_kda_scan(*a, 64, 16))), (WIDE, _kernel())):
+        wide = _delta_inputs(1, 64, h=1, seed=4, **widths)
+        wide = wide[:3] + (jnp.full_like(wide[3], -5.0 + 1e-4), wide[4])
+        assert float(jnp.exp(-jnp.sum(wide[3][0, :, 0, 0]))) == float("inf")
+        _held_to_the_recurrence(wide, (64,), scan)
 
 
 def test_with_every_channels_decay_equal_it_is_the_heads_decay_rule():
     """``g`` constant over a head's channels: ``kda_scan`` is ``gdn_scan``
     with that decay a head (its XLA form), and ``kda_step`` ``gdn_step``."""
-    q, k, v, g, beta = _delta_inputs(3, 24, seed=6)
+    q, k, v, g, beta = _delta_inputs(8, 24, seed=6)     # ``CHUNKED``'s shape
     head = g[..., 0]
-    lengths = jnp.asarray([24, 11, 0])
+    lengths = (24, 11, 0, 7, 19, 8, 1, 16)
     with jax.default_matmul_precision("highest"):
-        o, carry = _scan(q, k, v, jnp.broadcast_to(head[..., None], g.shape),
-                         beta, lengths)
-        want_o, want = jax.jit(lambda *a: gdn.xla_gdn_scan(*a, C))(
-            q, k, v, head, beta, lengths)
-    for i, n in enumerate((24, 11, 0)):
+        o, carry = (np.asarray(a) for a in _scan(
+            q, k, v, jnp.broadcast_to(head[..., None], g.shape), beta,
+            jnp.asarray(lengths)))
+        want_o, want = (np.asarray(a) for a in jax.jit(
+            lambda *a: gdn.xla_gdn_scan(*a, C))(q, k, v, head, beta,
+                                                jnp.asarray(lengths)))
+    for i, n in enumerate(lengths):
         np.testing.assert_allclose(o[i, :n], want_o[i, :n], atol=1e-6)
     np.testing.assert_allclose(carry, want, atol=1e-6)
-    s = jax.random.normal(jax.random.key(2), (3, 2, 8, 8))
+    s = jax.random.normal(jax.random.key(2), (8, 2, 8, 8))
     one = [a[:, 0] for a in (q, k, v, head, beta)]
     got = jitted(gdn.kda_step)(s, *one[:3], jnp.broadcast_to(
-        one[3][..., None], (3, 2, 8)), one[4])
+        one[3][..., None], (8, 2, 8)), one[4])
     for a, b in zip(got, jitted(gdn.gdn_step)(s, *one)):
         np.testing.assert_allclose(a, b, atol=1e-6)
+
+
+def test_the_scan_slots_counter_follows_the_lowering(monkeypatch):
+    """``kda.scan_slots`` counts what the traced lowering computes: every
+    chunk of the bucket under the XLA form, whole chunks up to each row's
+    length under the kernel — which only the published widths in blocks of
+    16 rows take."""
+    def wide(chunk=64, block=16, dim=128):
+        return state.ChannelDeltaBlock(2, dim, 128, 4, 1e-6, chunk, block,
+                                       -5.0)
+
+    blocks = {f"l{i}": wide() for i in range(3)}
+    lengths = jnp.asarray([65, 0, 512, 1], jnp.int32)
+    stats = fresh(lambda n: state.kda_prefill_stats(blocks, (4, 512), n))
+    assert stats(lengths)["kda.scan_slots"] == 3 * 4 * 512
+    monkeypatch.setattr(gdn, "_on_tpu", lambda: True)
+    assert blocks["l0"].scan_lowering(512) == "pallas"
+    stats = fresh(lambda n: state.kda_prefill_stats(blocks, (4, 512), n))
+    assert stats(lengths)["kda.scan_slots"] == 3 * (128 + 0 + 512 + 64)
+    assert stats(lengths)["kda.real_tokens"] == 3 * 578
+    # products in blocks of 8 rows, a chunk that is not whole blocks of 16,
+    # a width off the lane tile
+    assert wide(block=8).scan_lowering(512) == "xla"
+    assert wide(chunk=24).scan_lowering(512) == "xla"
+    assert wide(dim=64).scan_lowering(512) == "xla"
+    assert bh.delta_block(TINY).scan_lowering(512) == "xla"
+    assert bh.delta_block(bh.BailingHybridConfig()).scan_lowering(
+        16384) == "pallas"
+
+
+def test_a_delta_block_of_published_widths_prefills_through_the_kernel(
+        monkeypatch):
+    """``ChannelDeltaBlock.prefill`` at ``Dk = Dv = 128`` in blocks of 16
+    with the chip said to be there, a row at a time: the op notes
+    ``"pallas"`` and the kernel (under the interpreter) hands over what the
+    XLA form hands over — the mixer's output at real positions, the carry
+    and the tail."""
+    block = state.ChannelDeltaBlock(2, 128, 128, 4, 1e-6, 16, 16, -5.0)
+    p = block.init_weights(jax.random.key(3), 64, F32, (-6.0, 2.0),
+                           (0.5, 2.0))
+    u = jax.random.normal(jax.random.key(4), (2, 48, 64))
+    lengths = jnp.asarray([37, 16], jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        with record_lowerings() as chosen:
+            want, held = fresh(block.prefill)(u, p, lengths)
+        assert chosen["kda_prefill"] == {"xla"}
+        monkeypatch.setattr(gdn, "_on_tpu", lambda: True)
+        monkeypatch.setattr(gdn, "pallas_kda_scan", functools.partial(
+            gdn.pallas_kda_scan, interpret=True))
+        with record_lowerings() as chosen:
+            got, handed = fresh(block.prefill)(u, p, lengths)
+    assert chosen["kda_prefill"] == {"pallas"}
+    for i, n in enumerate((37, 16)):
+        np.testing.assert_allclose(got[i, :n], want[i, :n], atol=1e-6)
+    np.testing.assert_allclose(handed["state"], held["state"], atol=WIDE_TOL)
+    np.testing.assert_array_equal(handed["conv"], held["conv"])
+    assert float(jnp.abs(held["state"]).max()) > 0.1
 
 
 def test_the_step_is_the_recurrence():
     q, k, v, g, beta = (a[:, 0] for a in _delta_inputs(3, 1, seed=2))
     carry = jax.random.normal(jax.random.key(9), (3, 2, 8, 8))
     o, new = jitted(gdn.kda_step)(carry, q, k, v, g, beta)
-    for i in range(3):
-        want_s, want_o = ref.delta_token(carry[i], q[i], k[i], v[i],
-                                         jnp.exp(g[i]), beta[i])
-        np.testing.assert_allclose(new[i], want_s, atol=1e-6)
-        np.testing.assert_allclose(o[i], want_o, atol=1e-6)
+    want_s, want_o = jax.jit(jax.vmap(ref.delta_token))(   # every slot's
+        carry, q, k, v, jnp.exp(g), beta)
+    np.testing.assert_allclose(new, want_s, atol=1e-6)
+    np.testing.assert_allclose(o, want_o, atol=1e-6)
     # a channel's decay is its own: channel 0 held, the others forgotten
     only = jnp.full_like(g, -5.0).at[..., 0].set(0.0)
     _, kept = jitted(gdn.kda_step)(carry, q, jnp.zeros_like(k), v, only, beta)

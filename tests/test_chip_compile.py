@@ -867,21 +867,30 @@ WHOLE_PROGRAMS = {
     # limit, a quarter of the vocabulary, 64 slots: five float32 carries and
     # tails and ONE latent leaf of 17,408 rows of 576; the latent layer's
     # cores ``mla_decode_fwd`` / ``mla_prefill_fwd`` at 32 heads, the delta
-    # rule plain XLA in both programs, a row of the admission at a time; 4
-    # rows at the 16,384 bucket through ``moe_sorted_fwd``
+    # rule's step plain XLA, its prefill the kernel ``kda_prefill_fwd``
+    # (PR 66) a row of the admission at a time: an admission holds neither a
+    # segment's float32 triangles nor ``q``, ``k``, ``v`` or ``g``
+    # transposed chunk-major; 4 rows at the 16,384 bucket through
+    # ``moe_sorted_fwd``, 15.38 GB before the kernel
     "ling3": Whole(
         "serve-ling3-longdoc-backlog", "bailing_hybrid",
         lambda m: _perf_config(m, "BailingHybridConfig",
                                "ling-3.0-flash-ep4pp7"),
         dict(num_slots=64, chunk_size=32, max_len=17408), admit=(4, 16384),
-        weights=(8.70e9, 8.72e9), state=(1.9e9, 2.1e9),
+        weights=(8.70e9, 8.72e9), state=(1.9e9, 2.1e9), peak=15.39e9,
         ops=("ops.row_write", "ops.mla_decode", "ops.mla_prefill",
-             "ops.moe_decode", "ops.kth"),
+             "ops.moe_decode", "ops.kth", "ops.gdn"),
         chunk=("tpu_custom_call", "mla_decode_fwd", "moe_decode_fwd",
                "row_write"),
-        admission=("tpu_custom_call", "moe_sorted_fwd", "mla_prefill_fwd"),
-        never={"chunk": ("mla_prefill_fwd", "gdn_prefill_fwd"),
-               "admit": ("mla_decode_fwd", "gdn_prefill_fwd")}),
+        admission=("tpu_custom_call", "moe_sorted_fwd", "mla_prefill_fwd",
+                   "kda_prefill_fwd"),
+        never={"chunk": ("mla_prefill_fwd", "gdn_prefill_fwd",
+                         "kda_prefill_fwd"),
+               "admit": ("mla_decode_fwd", "gdn_prefill_fwd")},
+        no_buffers={"admit": ("f32[32,1,32,1,64,64]", "f32[32,32,64,64]",
+                              "bf16[32,1,32,64,64]",
+                              "bf16[8,32,1,32,64,128]",
+                              "f32[8,32,1,32,64,128]")}),
 }
 
 PROGRAMS = [(name, program) for name, row in WHOLE_PROGRAMS.items()

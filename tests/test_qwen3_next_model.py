@@ -141,27 +141,33 @@ def _delta_inputs(r, p, hk=2, hv=4, dk=8, dv=8, seed=0):
 
 @jax.jit
 def _token_by_token(q, k, v, g, beta):
-    """The reference's recurrence over one row ``(P, ...)``: every output
-    and the carry after every token."""
-    e = v.shape[1] // k.shape[1]
-    q, k = (jnp.repeat(a, e, axis=1) for a in (q, k))
+    """The reference's recurrence over rows ``(R, P, ...)``: every output
+    and the carry after every token, a row."""
+    def row(q, k, v, g, beta):
+        e = v.shape[1] // k.shape[1]
+        q, k = (jnp.repeat(a, e, axis=1) for a in (q, k))
 
-    def token(s, at):
-        s, o = ref.delta_token(s, *at)
-        return s, (o, s)
+        def token(s, at):
+            s, o = ref.delta_token(s, *at)
+            return s, (o, s)
 
-    zero = jnp.zeros((v.shape[1], k.shape[2], v.shape[2]), F32)
-    return jax.lax.scan(token, zero, (q, k, v, jnp.exp(g), beta))[1]
+        zero = jnp.zeros((v.shape[1], k.shape[2], v.shape[2]), F32)
+        return jax.lax.scan(token, zero, (q, k, v, jnp.exp(g), beta))[1]
+
+    return jax.vmap(row)(q, k, v, g, beta)
 
 
 C = 4
 WIDE = dict(dk=128, dv=128)     # the kernel's widths: whole lane tiles
 
 
-def _form(kernel: bool, chunk: int):
+@functools.lru_cache(maxsize=None)
+def _form(kernel: bool, chunk: int, step=None):
     """``gdn_scan``'s XLA form, or its kernel under the interpreter, in
-    chunks of ``chunk``: a fresh program a call (the kernel's blocks follow
-    ``gdn.STEP_TOKENS``, which a case may patch)."""
+    chunks of ``chunk``: ONE program a ``step`` (the tokens a grid step that
+    the caller has patched into ``gdn.STEP_TOKENS``, which the trace reads)
+    and shape — ``lengths`` is a runtime scalar, so cases that differ in it
+    alone share a compiled body."""
     form = (functools.partial(gdn.pallas_gdn_scan, interpret=True)
             if kernel else gdn.xla_gdn_scan)
     return jax.jit(lambda *a: form(*a, chunk))
@@ -186,45 +192,46 @@ def test_the_chunked_form_is_the_recurrence_token_by_token(case, monkeypatch):
     past whole chunks: the outputs at real positions and the carry AT EACH
     ROW'S TRUE LENGTH (zeros for a row of length 0) are the recurrence's,
     whatever the padding holds — by the XLA form and by the kernel, whose
-    rows also end in different grid steps."""
+    rows also end in different grid steps.  Compared on the host: a slice a
+    length is no program of its own."""
     kernel, chunk, step, lengths, bucket = CHUNKED[case]
     if step:
         monkeypatch.setattr(gdn, "STEP_TOKENS", step)
-    q, k, v, g, beta = _delta_inputs(len(lengths), bucket, seed=bucket,
-                                     **(WIDE if kernel else {}))
+    inputs = _delta_inputs(len(lengths), bucket, seed=bucket,
+                           **(WIDE if kernel else {}))
     with jax.default_matmul_precision("highest"):
-        o, carry = _form(kernel, chunk)(q, k, v, g, beta,
-                                        jnp.asarray(lengths))
-        for i, n in enumerate(lengths):
-            want_o, carries = _token_by_token(q[i], k[i], v[i], g[i],
-                                              beta[i])
-            want = carries[n - 1] if n else jnp.zeros_like(carries[0])
-            assert float(jnp.abs(carry[i] - want).max()) < 1e-6, (i, n)
-            if n:
-                assert float(jnp.abs(o[i, :n] - want_o[:n]).max()) < 1e-6
-    assert bool(jnp.isfinite(o).all())
+        o, carry = (np.asarray(a) for a in _form(kernel, chunk, step)(
+            *inputs, jnp.asarray(lengths)))
+        want_o, carries = (np.asarray(a) for a in _token_by_token(*inputs))
+    for i, n in enumerate(lengths):
+        want = carries[i, n - 1] if n else np.zeros_like(carries[i, 0])
+        assert np.abs(carry[i] - want).max() < 1e-6, (i, n)
+        assert np.abs(o[i, :n] - want_o[i, :n]).max(initial=0) < 1e-6, (i, n)
+    assert np.isfinite(o).all()
     # not a vacuous bound (a unit key of 128 columns has smaller entries)
-    assert float(jnp.abs(carry).max()) > (0.3 if kernel else 0.5)
+    assert np.abs(carry).max() > (0.3 if kernel else 0.5)
     assert gdn.scanned_slots(len(lengths), bucket, chunk) == (
         len(lengths) * -(-bucket // chunk) * chunk)
     if kernel:      # nothing is left in a chunk wholly past a row's length
         for i, n in enumerate(lengths):
-            assert not np.asarray(o[i, -(-n // chunk) * chunk:]).any()
+            assert not o[i, -(-n // chunk) * chunk:].any()
 
 
-def test_the_kernel_leaves_a_chunk_past_a_rows_length_alone():
+def test_the_kernel_leaves_a_chunk_past_a_rows_length_alone(monkeypatch):
     """NaN in every chunk that lies wholly past its row's length (and in
     ``g`` and ``beta`` from the length on): ``o`` is zero there, and ``o``
-    before it and the carry are, bit for bit, what clean inputs give."""
-    lengths, chunk = (33, 0, 16), 16
-    clean = _delta_inputs(3, 64, seed=5, **WIDE)
-    past = (jnp.arange(64)[None, :]
+    before it and the carry are, bit for bit, what clean inputs give.  The
+    shape of ``kernel-rows-end-in-other-steps``: one compiled body."""
+    lengths, chunk, bucket = (33, 0, 16), 16, 96
+    monkeypatch.setattr(gdn, "STEP_TOKENS", 32)
+    clean = _delta_inputs(3, bucket, seed=5, **WIDE)
+    past = (jnp.arange(bucket)[None, :]
             >= -(-jnp.asarray(lengths) // chunk)[:, None] * chunk)
-    at = jnp.arange(64)[None, :] >= jnp.asarray(lengths)[:, None]
+    at = jnp.arange(bucket)[None, :] >= jnp.asarray(lengths)[:, None]
     q, k, v = (jnp.where(past[..., None, None], jnp.nan, x)
                for x in clean[:3])
     g, beta = (jnp.where(at[..., None], jnp.nan, x) for x in clean[3:])
-    scan = _form(True, chunk)
+    scan = _form(True, chunk, 32)
     o, carry = scan(q, k, v, g, beta, jnp.asarray(lengths))
     want_o, want = scan(*clean, jnp.asarray(lengths))
     assert bool(jnp.isnan(q).any()) and bool(jnp.isnan(g).any())
@@ -342,12 +349,10 @@ def test_the_step_is_the_recurrence_and_a_value_head_reads_key_head_j_over_2():
     q, k, v, g, beta = (a[:, 0] for a in _delta_inputs(3, 1, seed=2))
     carry = jax.random.normal(jax.random.key(9), (3, 4, 8, 8))
     o, new = jitted(gdn.gdn_step)(carry, q, k, v, g, beta)
-    for i in range(3):
-        want_s, want_o = ref.delta_token(
-            carry[i], jnp.repeat(q[i], 2, 0), jnp.repeat(k[i], 2, 0), v[i],
-            jnp.exp(g[i]), beta[i])
-        np.testing.assert_allclose(new[i], want_s, atol=1e-6)
-        np.testing.assert_allclose(o[i], want_o, atol=1e-6)
+    want_s, want_o = jax.jit(jax.vmap(ref.delta_token))(   # every slot's
+        carry, jnp.repeat(q, 2, 1), jnp.repeat(k, 2, 1), v, jnp.exp(g), beta)
+    np.testing.assert_allclose(new, want_s, atol=1e-6)
+    np.testing.assert_allclose(o, want_o, atol=1e-6)
     zeroed = k.at[:, 0].set(0.0)
     o0, new0 = jitted(gdn.gdn_step)(carry, q.at[:, 0].set(0.0), zeroed, v,
                                     g, beta)
