@@ -976,40 +976,44 @@ class ServingEngine:
                 state["caches"], self.mesh, self.strategies)}
 
             def body(st, _):
-                live = lay.live(st, operands)
-                pos = st["pos"]
-                tok = jnp.take_along_axis(st["seq"], pos[:, None],
-                                          axis=1)[:, 0]
+                with jax.named_scope("engine.advance"):
+                    live = lay.live(st, operands)
+                    pos = st["pos"]
+                    tok = jnp.take_along_axis(st["seq"], pos[:, None],
+                                              axis=1)[:, 0]
                 logits, caches, stats = lay.step(
                     self._target_params(params), tok, pos, st["caches"],
                     live, self._adapters(params), st.get("tenant"),
                     operands)
-                caches = lay.idle_keeps(live, caches, st["caches"])
-                kd, sub = split_keys_batched(st["keys"])
-                writepos = jnp.clip(pos + 1, 0, self.max_len - 1)
-                # the infill mask row for the position this step WRITES;
-                # all-pass rows leave sampling bit-identical
-                mrow = jnp.take_along_axis(
-                    st["lmask"], writepos[:, None, None], axis=1
-                )[:, 0] if self.family.position_masks else st["lmask"]
-                nxt = gumbel_topk_sample_batched(
-                    sub, logits, st["top_k"], st["temp"],
-                    mask=mrow).astype(jnp.int32)
-                cur = jnp.take_along_axis(st["seq"], writepos[:, None],
-                                          axis=1)[:, 0]
-                val = jnp.where(live, nxt, cur)
-                seq = write_rows(st["seq"], val, writepos, axis=0)
-                new_pos = jnp.where(live, pos + 1, pos)
-                done = st["done"] | (live & (
-                    (val == EOS_ID) | (new_pos + 1 >= st["stop"])))
-                # a slot's key advances only on its own live steps, so a
-                # request's trajectory is independent of its neighbours
-                # (and pausing delays it, never alters it)
-                new_keys = jnp.where(live[:, None], kd, st["keys"])
-                out = {**st, "seq": seq, "caches": caches, "pos": new_pos,
-                       "done": done, "keys": new_keys}
-                if stats:
-                    out["stats"] = jax.tree.map(jnp.add, st["stats"], stats)
+                # the draw names itself (``sample.draw``)
+                with jax.named_scope("engine.advance"):
+                    caches = lay.idle_keeps(live, caches, st["caches"])
+                    kd, sub = split_keys_batched(st["keys"])
+                    writepos = jnp.clip(pos + 1, 0, self.max_len - 1)
+                    # the infill mask row for the position this step WRITES;
+                    # all-pass rows leave sampling bit-identical
+                    mrow = jnp.take_along_axis(
+                        st["lmask"], writepos[:, None, None], axis=1
+                    )[:, 0] if self.family.position_masks else st["lmask"]
+                    nxt = gumbel_topk_sample_batched(
+                        sub, logits, st["top_k"], st["temp"],
+                        mask=mrow).astype(jnp.int32)
+                    cur = jnp.take_along_axis(st["seq"], writepos[:, None],
+                                              axis=1)[:, 0]
+                    val = jnp.where(live, nxt, cur)
+                    seq = write_rows(st["seq"], val, writepos, axis=0)
+                    new_pos = jnp.where(live, pos + 1, pos)
+                    done = st["done"] | (live & (
+                        (val == EOS_ID) | (new_pos + 1 >= st["stop"])))
+                    # a slot's key advances only on its own live steps, so a
+                    # request's trajectory is independent of its neighbours
+                    # (and pausing delays it, never alters it)
+                    new_keys = jnp.where(live[:, None], kd, st["keys"])
+                    out = {**st, "seq": seq, "caches": caches, "pos": new_pos,
+                           "done": done, "keys": new_keys}
+                    if stats:
+                        out["stats"] = jax.tree.map(jnp.add, st["stats"],
+                                                    stats)
                 return out, None
 
             state, _ = jax.lax.scan(body, state, None,
@@ -1071,71 +1075,74 @@ class ServingEngine:
 
         with self._trace_ctx():
             def body(st, _):
-                live = lay.live(st, operands)
-                blk, p0, dstep = st["block"], st["cursor"], st["dstep"]
-                riding = live & st["has_pending"]
+                with jax.named_scope("engine.advance"):
+                    live = lay.live(st, operands)
+                    blk, p0, dstep = st["block"], st["cursor"], st["dstep"]
+                    riding = live & st["has_pending"]
                 logits, caches, stats = fam.block_step(
                     self._target_params(params), blk, p0, st["caches"],
                     live, riding, st["pending"])
-                kd, sub = split_keys_batched(st["keys"])
-                keys = jax.vmap(lambda k: jax.random.split(k, b))(
-                    sub).reshape(s * b)
-                drawn, conf = gumbel_topk_sample_with_confidence(
-                    keys, logits.reshape(s * b, -1),
-                    jnp.repeat(st["top_k"], b), jnp.repeat(st["temp"], b),
-                    mask=jnp.repeat(st["lmask"], b, axis=0))
-                take = live[:, None] & confident_positions(
-                    conf.reshape(s, b), blk == mask_id,
-                    per_step[jnp.clip(dstep, 0, steps - 1)], threshold)
-                filled = jnp.where(take, drawn.reshape(s, b).astype(
-                    jnp.int32), blk)
-                finish = live & ~jnp.any(filled == mask_id, axis=1)
+                # the draw names itself (``sample.confidence``)
+                with jax.named_scope("engine.advance"):
+                    kd, sub = split_keys_batched(st["keys"])
+                    keys = jax.vmap(lambda k: jax.random.split(k, b))(
+                        sub).reshape(s * b)
+                    drawn, conf = gumbel_topk_sample_with_confidence(
+                        keys, logits.reshape(s * b, -1),
+                        jnp.repeat(st["top_k"], b), jnp.repeat(st["temp"], b),
+                        mask=jnp.repeat(st["lmask"], b, axis=0))
+                    take = live[:, None] & confident_positions(
+                        conf.reshape(s, b), blk == mask_id,
+                        per_step[jnp.clip(dstep, 0, steps - 1)], threshold)
+                    filled = jnp.where(take, drawn.reshape(s, b).astype(
+                        jnp.int32), blk)
+                    finish = live & ~jnp.any(filled == mask_id, axis=1)
 
-                # a finished block: which of its tokens count
-                where = p0[:, None] + at
-                generated = where >= st["start"][:, None]
-                eos = (generated & (where < st["stop"][:, None])
-                       & (filled == EOS_ID))
-                ended = jnp.any(eos, axis=1)
-                last = jnp.where(ended, p0 + jnp.argmax(eos, axis=1),
-                                 jnp.minimum(p0 + b, st["stop"]) - 1)
-                counted = jnp.where(finish, last - st["pos"], 0)
-                done = finish & (ended | (p0 + b >= st["stop"]))
-                values, here = spread(filled, p0)
-                seq = jnp.where(here & finish[:, None], values, st["seq"])
-                steps_at, _ = spread(jnp.where(take, dstep[:, None], -1), p0)
-                fill = jnp.where(here & (steps_at >= 0),
-                                 steps_at.astype(jnp.int8), st["fill"])
-                out = {
-                    **st, "seq": seq, "caches": caches, "fill": fill,
-                    "pos": jnp.where(finish, last, st["pos"]),
-                    "done": st["done"] | done,
-                    "cursor": jnp.where(finish, p0 + b, p0),
-                    "block": jnp.where(finish[:, None], mask_id, filled),
-                    "dstep": jnp.where(finish, 0, dstep + live),
-                    "pending": jnp.where(finish[:, None], filled,
-                                         st["pending"]),
-                    # what rode is written; a row that is not live keeps
-                    # what it has
-                    "has_pending": jnp.where(live, finish & ~done,
-                                             st["has_pending"]),
-                    "keys": jnp.where(live[:, None], kd, st["keys"]),
-                }
-                kept = jnp.sum(take, axis=1)
-                stats = {
-                    **stats,
-                    "diffusion.forwards": jnp.sum(live).astype(f32),
-                    "diffusion.commit_forwards": jnp.sum(riding).astype(f32),
-                    "diffusion.tokens_committed": jnp.sum(counted).astype(
-                        f32),
-                    "diffusion.tokens_dropped": jnp.sum(jnp.where(
-                        finish, jnp.sum(generated, axis=1) - counted,
-                        0)).astype(f32),
-                    "diffusion.positions_kept": jnp.sum(jnp.where(
-                        dstep[:, None] == jnp.arange(steps)[None, :],
-                        kept[:, None], 0), axis=0).astype(f32),
-                }
-                out["stats"] = jax.tree.map(jnp.add, st["stats"], stats)
+                    # a finished block: which of its tokens count
+                    where = p0[:, None] + at
+                    generated = where >= st["start"][:, None]
+                    eos = (generated & (where < st["stop"][:, None])
+                           & (filled == EOS_ID))
+                    ended = jnp.any(eos, axis=1)
+                    last = jnp.where(ended, p0 + jnp.argmax(eos, axis=1),
+                                     jnp.minimum(p0 + b, st["stop"]) - 1)
+                    counted = jnp.where(finish, last - st["pos"], 0)
+                    done = finish & (ended | (p0 + b >= st["stop"]))
+                    values, here = spread(filled, p0)
+                    seq = jnp.where(here & finish[:, None], values, st["seq"])
+                    steps_at, _ = spread(jnp.where(take, dstep[:, None], -1), p0)
+                    fill = jnp.where(here & (steps_at >= 0),
+                                     steps_at.astype(jnp.int8), st["fill"])
+                    out = {
+                        **st, "seq": seq, "caches": caches, "fill": fill,
+                        "pos": jnp.where(finish, last, st["pos"]),
+                        "done": st["done"] | done,
+                        "cursor": jnp.where(finish, p0 + b, p0),
+                        "block": jnp.where(finish[:, None], mask_id, filled),
+                        "dstep": jnp.where(finish, 0, dstep + live),
+                        "pending": jnp.where(finish[:, None], filled,
+                                             st["pending"]),
+                        # what rode is written; a row that is not live keeps
+                        # what it has
+                        "has_pending": jnp.where(live, finish & ~done,
+                                                 st["has_pending"]),
+                        "keys": jnp.where(live[:, None], kd, st["keys"]),
+                    }
+                    kept = jnp.sum(take, axis=1)
+                    stats = {
+                        **stats,
+                        "diffusion.forwards": jnp.sum(live).astype(f32),
+                        "diffusion.commit_forwards": jnp.sum(riding).astype(f32),
+                        "diffusion.tokens_committed": jnp.sum(counted).astype(
+                            f32),
+                        "diffusion.tokens_dropped": jnp.sum(jnp.where(
+                            finish, jnp.sum(generated, axis=1) - counted,
+                            0)).astype(f32),
+                        "diffusion.positions_kept": jnp.sum(jnp.where(
+                            dstep[:, None] == jnp.arange(steps)[None, :],
+                            kept[:, None], 0), axis=0).astype(f32),
+                    }
+                    out["stats"] = jax.tree.map(jnp.add, st["stats"], stats)
                 return out, None
 
             state, _ = jax.lax.scan(body, state, None,
@@ -1179,32 +1186,34 @@ class ServingEngine:
                 self._adapters(params), tenant)
             caches = _constrain_caches(caches, self.mesh, self.strategies)
 
-        keys = jax.vmap(jax.random.key)(seeds.astype(jnp.uint32))
-        split = jax.vmap(jax.random.split)(keys)
-        # the first generated token writes at position ``lengths`` — its
-        # mask row applies here, not in the decode chunk
-        first_mrow = jnp.take_along_axis(
-            lmask, lengths[:, None, None], axis=1
-        )[:, 0] if self.family.position_masks else lmask
-        first = gumbel_topk_sample_batched(
-            split[:, 1], last, top_k, temp,
-            mask=first_mrow).astype(jnp.int32)
+        # the handle's own rows; the draw names itself (``sample.draw``)
+        with jax.named_scope("engine.prime"):
+            keys = jax.vmap(jax.random.key)(seeds.astype(jnp.uint32))
+            split = jax.vmap(jax.random.split)(keys)
+            # the first generated token writes at position ``lengths`` — its
+            # mask row applies here, not in the decode chunk
+            first_mrow = jnp.take_along_axis(
+                lmask, lengths[:, None, None], axis=1
+            )[:, 0] if self.family.position_masks else lmask
+            first = gumbel_topk_sample_batched(
+                split[:, 1], last, top_k, temp,
+                mask=first_mrow).astype(jnp.int32)
 
-        rows = tokens.shape[0]
-        seq = self._prime_rows(tokens, lengths)
-        seq = seq.at[jnp.arange(rows), lengths].set(first)
-        out = {
-            "seq": seq,
-            "caches": caches,
-            "pos": lengths,
-            "start": lengths,
-            "stop": stops,
-            "done": (first == EOS_ID) | (lengths + 1 >= stops),
-            "keys": jax.random.key_data(split[:, 0]),
-            "top_k": top_k,
-            "temp": temp,
-            "lmask": lmask,
-        }
+            rows = tokens.shape[0]
+            seq = self._prime_rows(tokens, lengths)
+            seq = seq.at[jnp.arange(rows), lengths].set(first)
+            out = {
+                "seq": seq,
+                "caches": caches,
+                "pos": lengths,
+                "start": lengths,
+                "stop": stops,
+                "done": (first == EOS_ID) | (lengths + 1 >= stops),
+                "keys": jax.random.key_data(split[:, 0]),
+                "top_k": top_k,
+                "temp": temp,
+                "lmask": lmask,
+            }
         if self.lora:
             out["tenant"] = tenant
         if stats:
@@ -1232,27 +1241,28 @@ class ServingEngine:
         with self._trace_ctx():
             _, caches, stats = fam.prefill(
                 self._target_params(params), tokens, lengths, self.max_len)
-        L, rows = self.max_len, tokens.shape[0]
-        seq = self._prime_rows(tokens, lengths)
-        whole = lengths // b * b
-        where = whole[:, None] + jnp.arange(b)
-        block = jnp.where(
-            where < lengths[:, None],
-            jnp.take_along_axis(seq, jnp.minimum(where, L - 1), axis=1),
-            fam.mask_token_id)
-        keys = jax.vmap(jax.random.key)(seeds.astype(jnp.uint32))
-        return {
-            "seq": seq, "caches": caches, "pos": lengths - 1,
-            "start": lengths, "stop": stops,
-            "done": jnp.zeros((rows,), bool),
-            "keys": jax.random.key_data(keys), "top_k": top_k, "temp": temp,
-            "lmask": lmask, "cursor": whole, "block": block,
-            "dstep": jnp.zeros((rows,), jnp.int32),
-            "fill": jnp.full((rows, L), -1, jnp.int8),
-            "pending": jnp.full((rows, b), fam.mask_token_id, jnp.int32),
-            "has_pending": jnp.zeros((rows,), bool),
-            "stats": {**stats, **self._diffusion_zeros()},
-        }
+        with jax.named_scope("engine.prime"):
+            L, rows = self.max_len, tokens.shape[0]
+            seq = self._prime_rows(tokens, lengths)
+            whole = lengths // b * b
+            where = whole[:, None] + jnp.arange(b)
+            block = jnp.where(
+                where < lengths[:, None],
+                jnp.take_along_axis(seq, jnp.minimum(where, L - 1), axis=1),
+                fam.mask_token_id)
+            keys = jax.vmap(jax.random.key)(seeds.astype(jnp.uint32))
+            return {
+                "seq": seq, "caches": caches, "pos": lengths - 1,
+                "start": lengths, "stop": stops,
+                "done": jnp.zeros((rows,), bool),
+                "keys": jax.random.key_data(keys), "top_k": top_k,
+                "temp": temp, "lmask": lmask, "cursor": whole, "block": block,
+                "dstep": jnp.zeros((rows,), jnp.int32),
+                "fill": jnp.full((rows, L), -1, jnp.int8),
+                "pending": jnp.full((rows, b), fam.mask_token_id, jnp.int32),
+                "has_pending": jnp.zeros((rows,), bool),
+                "stats": {**stats, **self._diffusion_zeros()},
+            }
 
     def _merge_impl(self, state, hstate, gate_rows, src, mask, *extra):
         """The merge half of admission: gather handle rows (as many as the
@@ -1266,36 +1276,38 @@ class ServingEngine:
         handle rows: no duplicate-index hazard, and dead rows vanish for
         free.  ``gate_rows`` and ``extra`` are the cache layout's: what it
         split out of the handle (NOT donated) and its merge operands."""
-        csrc = jnp.clip(src, 0, hstate["pos"].shape[0] - 1)
+        with jax.named_scope("engine.merge"):
+            csrc = jnp.clip(src, 0, hstate["pos"].shape[0] - 1)
 
-        def take(h, old):
-            m = mask.reshape((-1,) + (1,) * (old.ndim - 1))
-            return jnp.where(m, jnp.take(h, csrc, axis=0), old)
+            def take(h, old):
+                m = mask.reshape((-1,) + (1,) * (old.ndim - 1))
+                return jnp.where(m, jnp.take(h, csrc, axis=0), old)
 
-        caches = self._layout.merge(take, state["caches"], hstate,
-                                    gate_rows, extra)
-        out = {
-            "seq": take(hstate["seq"], state["seq"]),
-            "caches": caches,
-            "pos": take(hstate["pos"], state["pos"]),
-            "start": take(hstate["start"], state["start"]),
-            "stop": take(hstate["stop"], state["stop"]),
-            "active": state["active"] | mask,
-            "done": take(hstate["done"], state["done"]),
-            "keys": take(hstate["keys"], state["keys"]),
-            "top_k": take(hstate["top_k"], state["top_k"]),
-            "temp": take(hstate["temp"], state["temp"]),
-            "lmask": take(hstate["lmask"], state["lmask"]),
-        }
-        if self.block_length:
-            out.update({k: take(hstate[k], state[k]) for k in _BLOCK_STATE})
-        if self.lora:
-            out["tenant"] = take(hstate["tenant"], state["tenant"])
-        if "stats" in state:
-            # counters are sums, not rows: the handle's join the state's
-            out["stats"] = jax.tree.map(jnp.add, state["stats"],
-                                        hstate["stats"])
-        return out
+            caches = self._layout.merge(take, state["caches"], hstate,
+                                        gate_rows, extra)
+            out = {
+                "seq": take(hstate["seq"], state["seq"]),
+                "caches": caches,
+                "pos": take(hstate["pos"], state["pos"]),
+                "start": take(hstate["start"], state["start"]),
+                "stop": take(hstate["stop"], state["stop"]),
+                "active": state["active"] | mask,
+                "done": take(hstate["done"], state["done"]),
+                "keys": take(hstate["keys"], state["keys"]),
+                "top_k": take(hstate["top_k"], state["top_k"]),
+                "temp": take(hstate["temp"], state["temp"]),
+                "lmask": take(hstate["lmask"], state["lmask"]),
+            }
+            if self.block_length:
+                out.update({k: take(hstate[k], state[k])
+                            for k in _BLOCK_STATE})
+            if self.lora:
+                out["tenant"] = take(hstate["tenant"], state["tenant"])
+            if "stats" in state:
+                # counters are sums, not rows: the handle's join the state's
+                out["stats"] = jax.tree.map(jnp.add, state["stats"],
+                                            hstate["stats"])
+            return out
 
     def _prefill_worker_call(self, *args):
         fn = self._aot.get(("prefill", args[0].shape[1]),
@@ -2773,6 +2785,10 @@ class ServingEngine:
             # the same of an admission's learned selection (ops/dsa.py);
             # None for a family without
             "dsa_kth": self._lowering_by_program("dsa_kth"),
+            # every lowering the traced programs noted (ops/lowering.py),
+            # whole: an op a family brings (``ssd_*``, ``gdn_*``,
+            # ``kda_*``) shows here without a key of its own above
+            "lowerings": dict(self.lowerings),
             # the device counters as last fetched with the slot flags,
             # under their registry names ({} for a family without)
             "model_stats": dict(self.model_gauges),

@@ -82,6 +82,7 @@ from __future__ import annotations
 
 import importlib
 
+import jax
 import jax.numpy as jnp
 
 from progen_tpu.core.precision import Policy
@@ -150,11 +151,13 @@ class ProGenFamily:
                 tenant=None):
         logits, varz = self.prefill_model.apply(
             params, tokens, adapters, tenant, mutable=["cache"])
-        caches = harvest_caches(self.config, varz["cache"], lengths,
-                                self.policy, max_len)
-        last = jnp.take_along_axis(
-            logits, (lengths - 1)[:, None, None], axis=1
-        )[:, 0].astype(jnp.float32)
+        with jax.named_scope("engine.rows"):
+            caches = harvest_caches(self.config, varz["cache"], lengths,
+                                    self.policy, max_len)
+        with jax.named_scope("head.logits"):
+            last = jnp.take_along_axis(
+                logits, (lengths - 1)[:, None, None], axis=1
+            )[:, 0].astype(jnp.float32)
         return last, caches, {}
 
     def decode_step(self, params, tok, pos, caches, live, adapters=None,

@@ -157,38 +157,43 @@ class LocalAttentionDecode(nn.Module):
         inner = h * d
         b = x.shape[0]
 
-        normed = _norm(self.policy, name="norm")(x)
+        with jax.named_scope("norm.layer"):
+            normed = _norm(self.policy, name="norm")(x)
         new_prev = normed
-        if self.shift:
-            normed = _shift_with_carry(normed, prev)
+        with jax.named_scope("attn.project"):
+            if self.shift:
+                normed = _shift_with_carry(normed, prev)
 
-        qkv = _dense(inner * 3, use_bias=False, axes=("embed", "qkv"),
-                     policy=self.policy, name="to_qkv",
-                     weights=self.weights)(normed)
-        if adapters is not None:
-            qkv = apply_lora(qkv, normed, adapters["qkv"], tenant)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q, k, v = (t.reshape(b, h, d) for t in (q, k, v))
-        q, k, v = (_rotate_at(t, sin_row, cos_row) for t in (q, k, v))
+            qkv = _dense(inner * 3, use_bias=False, axes=("embed", "qkv"),
+                         policy=self.policy, name="to_qkv",
+                         weights=self.weights)(normed)
+            if adapters is not None:
+                qkv = apply_lora(qkv, normed, adapters["qkv"], tenant)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q, k, v = (t.reshape(b, h, d) for t in (q, k, v))
+            q, k, v = (_rotate_at(t, sin_row, cos_row) for t in (q, k, v))
 
-        # per-row ring slot (rows may sit at different positions — the
-        # continuous-batching engine drives one step with a (B,) pos vector)
-        k_cache, v_cache = write_rows((k_cache, v_cache), (k, v), slot,
-                                      axis=1)
+        with jax.named_scope("attn.local"):
+            # per-row ring slot (rows may sit at different positions — the
+            # continuous-batching engine drives one step with a (B,) pos
+            # vector)
+            k_cache, v_cache = write_rows((k_cache, v_cache), (k, v), slot,
+                                          axis=1)
 
-        sim = jnp.einsum("bhd,bhsd->bhs", q, k_cache,
-                         preferred_element_type=jnp.float32) * (d ** -0.5)
-        sim = jnp.where(valid[:, None, :], sim, ATTN_MASK_VALUE)
-        attn = jax.nn.softmax(sim, axis=-1).astype(v_cache.dtype)
-        out = jnp.einsum(
-            "bhs,bhsd->bhd", attn, v_cache,
-            preferred_element_type=jnp.float32,
-        ).astype(v_cache.dtype).reshape(b, inner)
-        proj = _dense(self.dim, use_bias=True, axes=("qkv", "embed"),
-                      policy=self.policy, name="to_out",
-                      weights=self.weights)(out)
-        if adapters is not None:
-            proj = apply_lora(proj, out, adapters["out"], tenant)
+            sim = jnp.einsum("bhd,bhsd->bhs", q, k_cache,
+                             preferred_element_type=jnp.float32) * (d ** -0.5)
+            sim = jnp.where(valid[:, None, :], sim, ATTN_MASK_VALUE)
+            attn = jax.nn.softmax(sim, axis=-1).astype(v_cache.dtype)
+            out = jnp.einsum(
+                "bhs,bhsd->bhd", attn, v_cache,
+                preferred_element_type=jnp.float32,
+            ).astype(v_cache.dtype).reshape(b, inner)
+        with jax.named_scope("attn.out"):
+            proj = _dense(self.dim, use_bias=True, axes=("qkv", "embed"),
+                          policy=self.policy, name="to_out",
+                          weights=self.weights)(out)
+            if adapters is not None:
+                proj = apply_lora(proj, out, adapters["out"], tenant)
         return proj, new_prev, k_cache, v_cache
 
 
@@ -204,8 +209,9 @@ class SGUDecode(nn.Module):
     @nn.compact
     def __call__(self, x, pos, gate_cache, adapters=None, tenant=None):
         n = self.seq_len
-        x, gate = jnp.split(x, 2, axis=-1)
-        gate = _norm(self.policy, name="norm")(gate)
+        with jax.named_scope("sgu.gate"):
+            x, gate = jnp.split(x, 2, axis=-1)
+            gate = _norm(self.policy, name="norm")(gate)
 
         init_scale = self.eps / n
 
@@ -230,25 +236,28 @@ class SGUDecode(nn.Module):
         # only weight columns < n_cache can be causally live since pos
         # stays < n_cache for the whole decode.  ``pos`` is (B,): each row
         # reads its own weight row / bias and masks at its own position.
-        n_cache = gate_cache.shape[1]
-        gate_cache = write_rows(gate_cache, gate, pos, axis=0)
-        w_rows = weights.astype(jnp.float32)[pos][:, :n_cache]  # (B, n_cache)
-        if w_scale is not None:
-            # per-ROW scale: each batch row reads weight row pos[b]
-            w_rows = w_rows * w_scale[pos][:, None]
-        causal = (jnp.arange(n_cache)[None, :] <= pos[:, None])
-        w_rows = w_rows * causal.astype(jnp.float32)
-        mixed = jnp.einsum("bnd,bn->bd", gate_cache.astype(jnp.float32),
-                           w_rows, preferred_element_type=jnp.float32)
-        bias_m = biases.astype(jnp.float32)[pos]  # (B, 1)
-        mixed = (mixed + bias_m).astype(x.dtype)
+        with jax.named_scope("sgu.spatial"):
+            n_cache = gate_cache.shape[1]
+            gate_cache = write_rows(gate_cache, gate, pos, axis=0)
+            w_rows = weights.astype(jnp.float32)[pos][:, :n_cache]  # (B, n_cache)
+            if w_scale is not None:
+                # per-ROW scale: each batch row reads weight row pos[b]
+                w_rows = w_rows * w_scale[pos][:, None]
+            causal = (jnp.arange(n_cache)[None, :] <= pos[:, None])
+            w_rows = w_rows * causal.astype(jnp.float32)
+            mixed = jnp.einsum("bnd,bn->bd", gate_cache.astype(jnp.float32),
+                               w_rows, preferred_element_type=jnp.float32)
+            bias_m = biases.astype(jnp.float32)[pos]  # (B, 1)
+            mixed = (mixed + bias_m).astype(x.dtype)
 
-        x = x * mixed
-        out = _dense(self.dim_out, use_bias=True, axes=("mlp_in", "mlp"),
-                     policy=self.policy, name="proj_out",
-                     weights=self.weights)(x)
-        if adapters is not None:
-            out = apply_lora(out, x, adapters, tenant)
+        with jax.named_scope("sgu.gate"):
+            x = x * mixed
+        with jax.named_scope("sgu.proj"):
+            out = _dense(self.dim_out, use_bias=True, axes=("mlp_in", "mlp"),
+                         policy=self.policy, name="proj_out",
+                         weights=self.weights)(x)
+            if adapters is not None:
+                out = apply_lora(out, x, adapters, tenant)
         return out, gate_cache
 
 
@@ -266,19 +275,21 @@ class FeedForwardDecode(nn.Module):
     def __call__(self, x, pos, prev, gate_cache, adapters=None, tenant=None):
         hidden = self.dim * self.ff_mult * (2 if self.glu else 1)
 
-        normed = _norm(self.policy, name="norm")(x)
+        with jax.named_scope("norm.layer"):
+            normed = _norm(self.policy, name="norm")(x)
         new_prev = normed
-        if self.shift:
-            normed = _shift_with_carry(normed, prev)
+        with jax.named_scope("ffn.dense"):
+            if self.shift:
+                normed = _shift_with_carry(normed, prev)
 
-        h = _dense(hidden, use_bias=True, axes=("embed", "mlp"),
-                   policy=self.policy, name="proj_in",
-                   weights=self.weights)(normed)
-        if self.glu:
-            h, gate = jnp.split(h, 2, axis=-1)
-            h = h * nn.gelu(gate)
-        else:
-            h = nn.gelu(h)
+            h = _dense(hidden, use_bias=True, axes=("embed", "mlp"),
+                       policy=self.policy, name="proj_in",
+                       weights=self.weights)(normed)
+            if self.glu:
+                h, gate = jnp.split(h, 2, axis=-1)
+                h = h * nn.gelu(gate)
+            else:
+                h = nn.gelu(h)
 
         if self.use_sgu:
             h, gate_cache = SGUDecode(
@@ -287,9 +298,10 @@ class FeedForwardDecode(nn.Module):
             )(h, pos, gate_cache,
               None if adapters is None else adapters["sgu"], tenant)
 
-        out = _dense(self.dim, use_bias=True, axes=("mlp", "embed"),
-                     policy=self.policy, name="proj_out",
-                     weights=self.weights)(h)
+        with jax.named_scope("ffn.dense"):
+            out = _dense(self.dim, use_bias=True, axes=("mlp", "embed"),
+                         policy=self.policy, name="proj_out",
+                         weights=self.weights)(h)
         return out, new_prev, gate_cache
 
 
@@ -313,29 +325,31 @@ class ProGenDecodeStep(nn.Module):
         ring = 2 * wsz
         b = tok.shape[0]
 
-        x = nn.Embed(
-            cfg.num_tokens, cfg.dim,
-            dtype=pol.compute_dtype, param_dtype=pol.param_dtype,
-            embedding_init=nn.initializers.variance_scaling(
-                1.0, "fan_in", "normal", out_axis=0),
-            name="embed",
-        )(tok)
+        with jax.named_scope("embed.tokens"):
+            x = nn.Embed(
+                cfg.num_tokens, cfg.dim,
+                dtype=pol.compute_dtype, param_dtype=pol.param_dtype,
+                embedding_init=nn.initializers.variance_scaling(
+                    1.0, "fan_in", "normal", out_axis=0),
+                name="embed",
+            )(tok)
 
         pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-        sin_t, cos_t = fixed_pos_embedding(cfg.seq_len, cfg.dim_head)
-        sin_row = sin_t[pos].astype(pol.compute_dtype)  # (B, dim_head)
-        cos_row = cos_t[pos].astype(pol.compute_dtype)
-        slot = pos % ring
+        with jax.named_scope("attn.rotary"):
+            sin_t, cos_t = fixed_pos_embedding(cfg.seq_len, cfg.dim_head)
+            sin_row = sin_t[pos].astype(pol.compute_dtype)  # (B, dim_head)
+            cos_row = cos_t[pos].astype(pol.compute_dtype)
+            slot = pos % ring
 
-        s = jnp.arange(ring)[None, :]
-        p_s = pos[:, None] - jnp.mod(pos[:, None] - s, ring)
-        w_start = ((pos // wsz) * wsz)[:, None]
-        # NOTE no ``p_s >= 0`` clause: in window 0 the reference attends a
-        # phantom ZERO-pad previous window (progen.py:90-95) whose keys
-        # contribute exp(0 - max) to the softmax denominator; ring slots
-        # with negative p_s are untouched zeros, which reproduces that
-        # exactly.
-        valid = p_s >= w_start - wsz  # (B, ring)
+            s = jnp.arange(ring)[None, :]
+            p_s = pos[:, None] - jnp.mod(pos[:, None] - s, ring)
+            w_start = ((pos // wsz) * wsz)[:, None]
+            # NOTE no ``p_s >= 0`` clause: in window 0 the reference attends a
+            # phantom ZERO-pad previous window (progen.py:90-95) whose keys
+            # contribute exp(0 - max) to the softmax denominator; ring slots
+            # with negative p_s are untouched zeros, which reproduces that
+            # exactly.
+            valid = p_s >= w_start - wsz  # (B, ring)
 
         new: dict[str, Any] = {
             "attn_prev": list(caches["attn_prev"]),
@@ -358,7 +372,8 @@ class ProGenDecodeStep(nn.Module):
                   caches["attn_prev"][i], caches["k"][i], caches["v"][i],
                   attn_ad, tenant)
             )
-            x = x + attn_out
+            with jax.named_scope("attn.out"):
+                x = x + attn_out
 
             gate_cache = caches["sgu_gate"].get(str(i))
             ff_out, new["ff_prev"][i], gate_cache = FeedForwardDecode(
@@ -369,14 +384,17 @@ class ProGenDecodeStep(nn.Module):
             )(x, pos, caches["ff_prev"][i],
               gate_cache if gate_cache is not None else jnp.zeros(()),
               ff_ad, tenant)
-            x = x + ff_out
+            with jax.named_scope("ffn.dense"):
+                x = x + ff_out
             if str(i) in new["sgu_gate"]:
                 new["sgu_gate"][str(i)] = gate_cache
 
-        h = _norm(pol, name="norm_out")(x)
-        logits = _dense(cfg.num_tokens, use_bias=True, axes=("embed", "vocab"),
-                        policy=pol, name="to_logits")(h)
-        return pol.cast_to_output(logits), new
+        with jax.named_scope("head.logits"):
+            h = _norm(pol, name="norm_out")(x)
+            logits = _dense(cfg.num_tokens, use_bias=True,
+                            axes=("embed", "vocab"),
+                            policy=pol, name="to_logits")(h)
+            return pol.cast_to_output(logits), new
 
 
 class SGUDecodePaged(nn.Module):
@@ -407,8 +425,9 @@ class SGUDecodePaged(nn.Module):
             paged_gate_mix, write_gate_row)
 
         n = self.seq_len
-        x, gate = jnp.split(x, 2, axis=-1)
-        gate = _norm(self.policy, name="norm")(gate)
+        with jax.named_scope("sgu.gate"):
+            x, gate = jnp.split(x, 2, axis=-1)
+            gate = _norm(self.policy, name="norm")(gate)
 
         init_scale = self.eps / n
 
@@ -429,24 +448,27 @@ class SGUDecodePaged(nn.Module):
         biases = self.param("spatial_biases", nn.initializers.ones, (n, 1),
                             self.policy.param_dtype)
 
-        if self.gate_dtype == "int8":
-            # quantize-on-scatter: the row's int8 code and its f32 scale
-            # land in twin pools through the same dump-redirected target
-            pool, pool_scale = write_gate_row(pool, table, pos, gate,
-                                              write_ok, scale=pool_scale)
-        else:
-            pool = write_gate_row(pool, table, pos, gate, write_ok)
-        mixed = paged_gate_mix(weights, biases, pool, table, pos,
-                               n_rows=self.n_rows, impl=self.impl,
-                               w_scale=w_scale, pool_scale=pool_scale)
-        mixed = mixed.astype(x.dtype)
+        with jax.named_scope("sgu.spatial"):
+            if self.gate_dtype == "int8":
+                # quantize-on-scatter: the row's int8 code and its f32 scale
+                # land in twin pools through the same dump-redirected target
+                pool, pool_scale = write_gate_row(pool, table, pos, gate,
+                                                  write_ok, scale=pool_scale)
+            else:
+                pool = write_gate_row(pool, table, pos, gate, write_ok)
+            mixed = paged_gate_mix(weights, biases, pool, table, pos,
+                                   n_rows=self.n_rows, impl=self.impl,
+                                   w_scale=w_scale, pool_scale=pool_scale)
+            mixed = mixed.astype(x.dtype)
 
-        x = x * mixed
-        out = _dense(self.dim_out, use_bias=True, axes=("mlp_in", "mlp"),
-                     policy=self.policy, name="proj_out",
-                     weights=self.weights)(x)
-        if adapters is not None:
-            out = apply_lora(out, x, adapters, tenant)
+        with jax.named_scope("sgu.gate"):
+            x = x * mixed
+        with jax.named_scope("sgu.proj"):
+            out = _dense(self.dim_out, use_bias=True, axes=("mlp_in", "mlp"),
+                         policy=self.policy, name="proj_out",
+                         weights=self.weights)(x)
+            if adapters is not None:
+                out = apply_lora(out, x, adapters, tenant)
         return out, pool, pool_scale
 
 
@@ -469,15 +491,17 @@ class FeedForwardDecodePaged(nn.Module):
                  adapters=None, tenant=None):
         hidden = self.dim * self.ff_mult
 
-        normed = _norm(self.policy, name="norm")(x)
+        with jax.named_scope("norm.layer"):
+            normed = _norm(self.policy, name="norm")(x)
         new_prev = normed
-        if self.shift:
-            normed = _shift_with_carry(normed, prev)
+        with jax.named_scope("ffn.dense"):
+            if self.shift:
+                normed = _shift_with_carry(normed, prev)
 
-        h = _dense(hidden, use_bias=True, axes=("embed", "mlp"),
-                   policy=self.policy, name="proj_in",
-                   weights=self.weights)(normed)
-        h = nn.gelu(h)
+            h = _dense(hidden, use_bias=True, axes=("embed", "mlp"),
+                       policy=self.policy, name="proj_in",
+                       weights=self.weights)(normed)
+            h = nn.gelu(h)
 
         h, pool, pool_scale = SGUDecodePaged(
             seq_len=self.seq_len, dim_out=hidden // 2, n_rows=self.n_rows,
@@ -486,9 +510,10 @@ class FeedForwardDecodePaged(nn.Module):
         )(h, pos, pool, table, write_ok, pool_scale,
           None if adapters is None else adapters["sgu"], tenant)
 
-        out = _dense(self.dim, use_bias=True, axes=("mlp", "embed"),
-                     policy=self.policy, name="proj_out",
-                     weights=self.weights)(h)
+        with jax.named_scope("ffn.dense"):
+            out = _dense(self.dim, use_bias=True, axes=("mlp", "embed"),
+                         policy=self.policy, name="proj_out",
+                         weights=self.weights)(h)
         return out, new_prev, pool, pool_scale
 
 
@@ -519,24 +544,26 @@ class ProGenPagedDecodeStep(nn.Module):
         ring = 2 * wsz
         b = tok.shape[0]
 
-        x = nn.Embed(
-            cfg.num_tokens, cfg.dim,
-            dtype=pol.compute_dtype, param_dtype=pol.param_dtype,
-            embedding_init=nn.initializers.variance_scaling(
-                1.0, "fan_in", "normal", out_axis=0),
-            name="embed",
-        )(tok)
+        with jax.named_scope("embed.tokens"):
+            x = nn.Embed(
+                cfg.num_tokens, cfg.dim,
+                dtype=pol.compute_dtype, param_dtype=pol.param_dtype,
+                embedding_init=nn.initializers.variance_scaling(
+                    1.0, "fan_in", "normal", out_axis=0),
+                name="embed",
+            )(tok)
 
         pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-        sin_t, cos_t = fixed_pos_embedding(cfg.seq_len, cfg.dim_head)
-        sin_row = sin_t[pos].astype(pol.compute_dtype)
-        cos_row = cos_t[pos].astype(pol.compute_dtype)
-        slot = pos % ring
+        with jax.named_scope("attn.rotary"):
+            sin_t, cos_t = fixed_pos_embedding(cfg.seq_len, cfg.dim_head)
+            sin_row = sin_t[pos].astype(pol.compute_dtype)
+            cos_row = cos_t[pos].astype(pol.compute_dtype)
+            slot = pos % ring
 
-        s = jnp.arange(ring)[None, :]
-        p_s = pos[:, None] - jnp.mod(pos[:, None] - s, ring)
-        w_start = ((pos // wsz) * wsz)[:, None]
-        valid = p_s >= w_start - wsz  # (B, ring); see ProGenDecodeStep
+            s = jnp.arange(ring)[None, :]
+            p_s = pos[:, None] - jnp.mod(pos[:, None] - s, ring)
+            w_start = ((pos // wsz) * wsz)[:, None]
+            valid = p_s >= w_start - wsz  # (B, ring); see ProGenDecodeStep
 
         new: dict[str, Any] = {
             "attn_prev": list(caches["attn_prev"]),
@@ -561,7 +588,8 @@ class ProGenPagedDecodeStep(nn.Module):
                   caches["attn_prev"][i], caches["k"][i], caches["v"][i],
                   attn_ad, tenant)
             )
-            x = x + attn_out
+            with jax.named_scope("attn.out"):
+                x = x + attn_out
 
             if use_gmlp:
                 pool_scale = (caches["sgu_pool_scale"][str(i)]
@@ -586,9 +614,12 @@ class ProGenPagedDecodeStep(nn.Module):
                     shift=cfg.shift_tokens, policy=pol, weights=self.weights,
                     name=f"ff{i}",
                 )(x, pos, caches["ff_prev"][i], jnp.zeros(()), ff_ad, tenant)
-            x = x + ff_out
+            with jax.named_scope("ffn.dense"):
+                x = x + ff_out
 
-        h = _norm(pol, name="norm_out")(x)
-        logits = _dense(cfg.num_tokens, use_bias=True, axes=("embed", "vocab"),
-                        policy=pol, name="to_logits")(h)
-        return pol.cast_to_output(logits), new
+        with jax.named_scope("head.logits"):
+            h = _norm(pol, name="norm_out")(x)
+            logits = _dense(cfg.num_tokens, use_bias=True,
+                            axes=("embed", "vocab"),
+                            policy=pol, name="to_logits")(h)
+            return pol.cast_to_output(logits), new

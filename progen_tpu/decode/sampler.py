@@ -64,17 +64,18 @@ def gumbel_topk_sample(key, logits, top_k: int | None, temperature: float = 1.0,
     cut as ``-inf`` (``-inf >= kth`` only when ``kth`` is itself ``-inf``,
     which keeps them ``-inf``), so top-k and constraints compose.
     """
-    logits = logits.astype(jnp.float32)
-    if mask is not None:
-        logits = apply_logit_mask(logits, mask)
-    if temperature == 0.0:
-        return jnp.argmax(logits, axis=-1)
-    logits = logits / temperature
-    if top_k is not None:
-        kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
-        logits = apply_logit_mask(logits, logits >= kth)
-    noise = jax.random.gumbel(key, logits.shape, dtype=logits.dtype)
-    return jnp.argmax(logits + noise, axis=-1)
+    with jax.named_scope("sample.draw"):
+        logits = logits.astype(jnp.float32)
+        if mask is not None:
+            logits = apply_logit_mask(logits, mask)
+        if temperature == 0.0:
+            return jnp.argmax(logits, axis=-1)
+        logits = logits / temperature
+        if top_k is not None:
+            kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
+            logits = apply_logit_mask(logits, logits >= kth)
+        noise = jax.random.gumbel(key, logits.shape, dtype=logits.dtype)
+        return jnp.argmax(logits + noise, axis=-1)
 
 
 def gumbel_topk_sample_batched(keys, logits, top_k, temperature, mask=None):
@@ -131,20 +132,21 @@ def _cut_and_draw(keys, logits, top_k, temperature, mask):
     """:func:`gumbel_topk_sample_batched`'s draw, and what it drew from:
     ``(tokens (B,), the float32 logits under the mask (B, V), the kept
     entries (B, V) bool)``."""
-    logits = logits.astype(jnp.float32)
-    if mask is not None:
-        logits = apply_logit_mask(logits, mask)
-    v = logits.shape[-1]
-    greedy = jnp.argmax(logits, axis=-1)
-    scaled = logits / jnp.maximum(temperature, 1e-8)[:, None]
-    k_eff = jnp.where(top_k > 0, jnp.clip(top_k, 1, v), v)
-    kth = kth_largest_by_counting(scaled, k_eff, "sample_kth")
-    kept = scaled >= kth
-    masked = apply_logit_mask(scaled, kept)
-    noise = jax.vmap(
-        lambda k: jax.random.gumbel(k, (v,), jnp.float32))(keys)
-    sampled = jnp.argmax(masked + noise, axis=-1)
-    return jnp.where(temperature == 0.0, greedy, sampled), logits, kept
+    with jax.named_scope("sample.draw"):
+        logits = logits.astype(jnp.float32)
+        if mask is not None:
+            logits = apply_logit_mask(logits, mask)
+        v = logits.shape[-1]
+        greedy = jnp.argmax(logits, axis=-1)
+        scaled = logits / jnp.maximum(temperature, 1e-8)[:, None]
+        k_eff = jnp.where(top_k > 0, jnp.clip(top_k, 1, v), v)
+        kth = kth_largest_by_counting(scaled, k_eff, "sample_kth")
+        kept = scaled >= kth
+        masked = apply_logit_mask(scaled, kept)
+        noise = jax.vmap(
+            lambda k: jax.random.gumbel(k, (v,), jnp.float32))(keys)
+        sampled = jnp.argmax(masked + noise, axis=-1)
+        return jnp.where(temperature == 0.0, greedy, sampled), logits, kept
 
 
 def gumbel_topk_sample_with_confidence(keys, logits, top_k, temperature,

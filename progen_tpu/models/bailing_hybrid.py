@@ -67,7 +67,9 @@ from progen_tpu.models import driver, experts, latent, state
 from progen_tpu.models.driver import (  # noqa: F401
     F32,
     bf16_policy,
+    residual,
     rms_norm,
+    stack_norm,
     swiglu,
 )
 from progen_tpu.models.experts import held_experts, kernel_counters
@@ -390,7 +392,9 @@ def moe_share(u, layer, c: BailingHybridConfig, live, limit: float = 0.0):
     stats = {"moe.tokens": jnp.sum(live).astype(F32),
              "moe.held_load": load.astype(F32),
              **kernel_counters(u, layer["experts"], load, c)}
-    return y.astype(u.dtype), ids, stats
+    with jax.named_scope("moe.experts"):    # the terms' own rounding
+        y = y.astype(u.dtype)
+    return y, ids, stats
 
 
 def zero_stats(c: BailingHybridConfig) -> dict:
@@ -408,18 +412,19 @@ def _layers(x, params, c, attend, live):
     eps = c.rms_norm_eps
     for i, layer in enumerate(params["layers"]):
         n = layer["norm"]
-        x = x + attend(rms_norm(x, n[0], eps), f"l{i}", layer["mixer"])
-        u = rms_norm(x, n[1], eps)
+        x = residual(x, attend(stack_norm(x, n[0], eps), f"l{i}",
+                               layer["mixer"]))
+        u = stack_norm(x, n[1], eps)
         if "experts" not in layer:
-            x = x + swiglu(u, layer["ffn"])
+            x = residual(x, swiglu(u, layer["ffn"]))
             continue
         limit, shared_limit = c.limits(i)
         m, ids, s = moe_share(u, layer, c, live, limit)
         stats = experts.add_stats(stats, s)
         touched += jnp.sum(s["moe.held_load"] > 0).astype(F32)
         chosen.append(ids)
-        x = x + m + swiglu(u, layer["shared"], scope="moe.shared",
-                           limit=shared_limit)
+        x = residual(residual(x, m), swiglu(
+            u, layer["shared"], scope="moe.shared", limit=shared_limit))
     return x, stats, chosen, touched
 
 
@@ -439,9 +444,7 @@ def prefill(params, tokens, lengths, config: BailingHybridConfig,
 def caches_from(rows, lengths, config: BailingHybridConfig, max_len: int):
     """What :func:`prefill` returned, as the caches of R slots in an engine
     of ``max_len``."""
-    blocks = blocks_of(config)
-    return {name: blocks[name].cache_rows(v, lengths, max_len)
-            for name, v in rows.items()}
+    return driver.cache_rows(blocks_of(config), rows, lengths, max_len)
 
 
 def decode_step(params, tok, pos, caches, live, config: BailingHybridConfig,

@@ -49,6 +49,7 @@ import numpy as np
 
 from progen_tpu.core.precision import Policy
 from progen_tpu.models import experts, latent
+from progen_tpu.models.driver import residual, stack_norm
 from progen_tpu.models.experts import held_experts, kernel_counters
 from progen_tpu.models.latent import F32, bf16_policy, rms_norm, swiglu
 
@@ -260,7 +261,9 @@ def moe_share(u, layer, c: DeepSeekV2Config, live):
              "moe.held_groups_chosen": mine.astype(F32),
              "moe.held_load": load.astype(F32),
              **kernel_counters(u, layer["experts"], load, c)}
-    return y.astype(u.dtype), ids, stats
+    with jax.named_scope("moe.experts"):    # the terms' own rounding
+        y = y.astype(u.dtype)
+    return y, ids, stats
 
 
 STAT_KEYS = (experts.STAT_KEYS + latent.STAT_KEYS
@@ -279,16 +282,18 @@ def _layers(x, params, c, attend, live):
     chosen, touched = [], 0.0
     for i, layer in enumerate(params["layers"]):
         n, eps = layer["norm"], c.rms_norm_eps
-        a = x + attend(rms_norm(x, n[0], eps), f"l{i}", layer["attn"])
-        u = rms_norm(a, n[1], eps)
+        a = residual(x, attend(stack_norm(x, n[0], eps), f"l{i}",
+                               layer["attn"]))
+        u = stack_norm(a, n[1], eps)
         if "experts" not in layer:
-            x = a + swiglu(u, layer["ffn"])
+            x = residual(a, swiglu(u, layer["ffn"]))
             continue
         m, ids, s = moe_share(u, layer, c, live)
         stats = experts.add_stats(stats, s)
         touched += jnp.sum(s["moe.held_load"] > 0).astype(F32)
         chosen.append(ids)
-        x = a + m + swiglu(u, layer["shared"], scope="moe.shared")
+        x = residual(residual(a, m),
+                     swiglu(u, layer["shared"], scope="moe.shared"))
     return x, stats, chosen, touched
 
 

@@ -151,6 +151,23 @@ def rms_norm(x, scale, eps):
         x.dtype)
 
 
+def stack_norm(x, scale, eps):
+    """:func:`rms_norm` where a STACK calls it, between two blocks, under a
+    name of its own; a norm inside a block (a head's, a state's) calls
+    :func:`rms_norm` and keeps its block's name."""
+    with jax.named_scope("norm.rms"):
+        return rms_norm(x, scale, eps)
+
+
+def residual(x, branch):
+    """``x + branch`` where a stack ends a branch: the add the compiler
+    fuses with the next norm's statistics, under the norm's group.  Two
+    branches nest, ``residual(residual(x, a), b())``: Python then traces
+    ``x + a`` before ``b()``, as ``x + a + b()`` did."""
+    with jax.named_scope("norm.residual"):
+        return x + branch
+
+
 def rope(x, positions, inv_freq):
     """Half-split rotation of ``x (..., n, [heads,] d)`` at ``positions
     (..., n)``; ``inv_freq(d)`` gives the ``d / 2`` frequencies; tables in
@@ -194,23 +211,25 @@ def swiglu(x, p, scope="ffn.dense", limit: float = 0.0):
 
 
 def _embed(params, tokens, c, dt):
-    x = params["embed"][tokens].astype(dt)
-    if c.embed_gain != 1:
-        x = x * jnp.asarray(c.embed_gain, dt)
-    return x
+    with jax.named_scope("embed.tokens"):
+        x = params["embed"][tokens].astype(dt)
+        if c.embed_gain != 1:
+            x = x * jnp.asarray(c.embed_gain, dt)
+        return x
 
 
 def _logits(x, params, c):
-    x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
-    if "head" in params:
-        out = jnp.dot(x, params["head"].astype(x.dtype),
-                      preferred_element_type=F32)
-    else:       # tied: the embedding (V, h) read along h, never transposed
-        out = jnp.einsum("...h,vh->...v", x,
-                         params["embed"].astype(x.dtype),
-                         preferred_element_type=F32)
-    scaling = getattr(c, "logits_scaling", 1)
-    return out / scaling if scaling != 1 else out
+    with jax.named_scope("head.logits"):
+        x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
+        if "head" in params:
+            out = jnp.dot(x, params["head"].astype(x.dtype),
+                          preferred_element_type=F32)
+        else:   # tied: the embedding (V, h) read along h, never transposed
+            out = jnp.einsum("...h,vh->...v", x,
+                             params["embed"].astype(x.dtype),
+                             preferred_element_type=F32)
+        scaling = getattr(c, "logits_scaling", 1)
+        return out / scaling if scaling != 1 else out
 
 
 # --------------------------------------------------------------- the driver
@@ -251,7 +270,8 @@ def prefill(stack, blocks, params, tokens, lengths, config, policy: Policy,
     x = _embed(params, tokens.reshape(-1), c, dt)
     x, stats, chosen, _ = stack(x, params, c, attend, live)
     if "moe.held_load" in stats:
-        stats["moe.prefill_held"] = jnp.sum(stats["moe.held_load"])
+        with jax.named_scope("engine.stats"):
+            stats["moe.prefill_held"] = jnp.sum(stats["moe.held_load"])
         # the rows the lowering passed through the experts for them
         stats["moe.prefill_rows_computed"] = stats["moe.rows_computed"]
         # a decode step's counters, as ``moe.experts_touched`` beside them
@@ -259,12 +279,21 @@ def prefill(stack, blocks, params, tokens, lengths, config, policy: Policy,
         stats["moe.rows_computed"] = jnp.zeros((), F32)
     if logit_positions is None:       # a row of no tokens reads position 0
         logit_positions = jnp.maximum(lengths - 1, 0)[:, None]
-    x = jnp.take_along_axis(x.reshape(r, n, -1),
-                            logit_positions[..., None], axis=1)
+    with jax.named_scope("head.logits"):
+        x = jnp.take_along_axis(x.reshape(r, n, -1),
+                                logit_positions[..., None], axis=1)
     out = _logits(x, params, c), rows, stats
     if with_choices:
         return out + (jnp.stack(chosen).reshape(len(chosen), r, n, -1),)
     return out
+
+
+def cache_rows(blocks, rows, lengths, max_len: int) -> dict:
+    """What :func:`prefill` returned (``{block: rows}``), laid out as the
+    blocks' caches of R slots in an engine of ``max_len``."""
+    with jax.named_scope("engine.rows"):
+        return {name: blocks[name].cache_rows(v, lengths, max_len)
+                for name, v in rows.items()}
 
 
 def _step_moe_stats(stats, chosen, touched, live) -> None:
@@ -272,8 +301,9 @@ def _step_moe_stats(stats, chosen, touched, live) -> None:
     expert layers it ran (if any row was live) and the held experts it
     touched."""
     if "moe.held_load" in stats:
-        stats["moe.decode_layers"] = jnp.asarray(
-            len(chosen), F32) * jnp.any(live)
+        with jax.named_scope("engine.stats"):
+            stats["moe.decode_layers"] = jnp.asarray(
+                len(chosen), F32) * jnp.any(live)
         stats["moe.experts_touched"] = touched
 
 
@@ -302,7 +332,8 @@ def decode_step(stack, blocks, attention_stats, params, tok, pos, caches,
     x = _embed(params, tok, c, dt)
     x, stats, chosen, touched = stack(x, params, c, attend, live)
     _step_moe_stats(stats, chosen, touched, live)
-    stats.update(attention_stats(dt, caches, pos, live))
+    with jax.named_scope("engine.stats"):
+        stats.update(attention_stats(dt, caches, pos, live))
     out = _logits(x, params, c), caches, stats
     if with_choices:
         return out + (jnp.stack(chosen),)
@@ -362,7 +393,8 @@ def block_step(stack, blocks, attention_stats, params, tok, pos0, caches,
     x, stats, chosen, touched = stack(x, params, c, attend, rows_live,
                                       tail=mine)
     _step_moe_stats(stats, chosen, touched, live)
-    stats.update(attention_stats(dt, caches, pos0, live, riding))
+    with jax.named_scope("engine.stats"):
+        stats.update(attention_stats(dt, caches, pos0, live, riding))
     out = _logits(x, params, c).reshape(s, b, -1), caches, stats
     if with_choices:
         return out + (jnp.stack([
@@ -434,8 +466,7 @@ class Family:
         logits, rows, stats = prefill(self.stack, self.blocks, params,
                                       tokens, lengths, self.config,
                                       self.policy)
-        caches = {name: self.blocks[name].cache_rows(v, lengths, max_len)
-                  for name, v in rows.items()}
+        caches = cache_rows(self.blocks, rows, lengths, max_len)
         return logits[:, 0], caches, stats
 
     def decode_step(self, params, tok, pos, caches, live, adapters=None,

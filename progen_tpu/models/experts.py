@@ -90,7 +90,8 @@ def zero_stats(keys, held: int) -> dict:
 
 
 def add_stats(a: dict, b: dict) -> dict:
-    return {k: a[k] + b[k] if k in b else a[k] for k in a}
+    with jax.named_scope("engine.stats"):
+        return {k: a[k] + b[k] if k in b else a[k] for k in a}
 
 
 def sigmoid_route(u, router, topk: int, *, norm: bool, scale: float,
@@ -357,29 +358,30 @@ def kernel_counters(u, experts, load, c) -> dict:
     assignments to held experts, one each (the XLA scatter-add it replaced
     moved every row of every window: 1.3 to 1.4 a live assignment at
     dots3's 16,384 bucket); 0 under every other lowering."""
-    tiles = moe_decode.fitted_tile(u, experts)
-    touched = jnp.sum(load > 0)
-    whole = tiles is not None and tiles.inner == experts["wu"].shape[-1]
-    combined = jnp.zeros((), F32)
-    if tiles is None:
-        passes = rows = jnp.zeros((), F32)
-    elif tiles.rows is None:
-        passes = touched
-        rows = touched * (-(-u.shape[0] // moe_decode.ROW_GROUP)
-                          * moe_decode.ROW_GROUP)
-    elif tiles.sorted:
-        t, k = u.shape[0], c.moe_topk
-        cap = sorted_window(c, t, k, tiles.rows)
-        base = (jnp.arange(-(-(t * k) // cap)) * cap)[:, None]
-        ends = jnp.cumsum(load)
-        ntile = moe_decode.tiles_an_expert(       # (windows, held)
-            *_in_window(ends - load, ends, base, cap), tiles.rows)
-        items = jnp.sum(ntile)
-        passes = jnp.sum(ntile > 0) if whole else items
-        rows, combined = items * tiles.rows, jnp.sum(load)
-    else:
-        items = jnp.sum(-(-load // tiles.rows))
-        passes, rows = touched if whole else items, items * tiles.rows
-    return {"moe.expert_passes": passes.astype(F32),
-            "moe.rows_computed": rows.astype(F32),
-            "moe.prefill_rows_combined": combined.astype(F32)}
+    with jax.named_scope("engine.stats"):
+        tiles = moe_decode.fitted_tile(u, experts)
+        touched = jnp.sum(load > 0)
+        whole = tiles is not None and tiles.inner == experts["wu"].shape[-1]
+        combined = jnp.zeros((), F32)
+        if tiles is None:
+            passes = rows = jnp.zeros((), F32)
+        elif tiles.rows is None:
+            passes = touched
+            rows = touched * (-(-u.shape[0] // moe_decode.ROW_GROUP)
+                              * moe_decode.ROW_GROUP)
+        elif tiles.sorted:
+            t, k = u.shape[0], c.moe_topk
+            cap = sorted_window(c, t, k, tiles.rows)
+            base = (jnp.arange(-(-(t * k) // cap)) * cap)[:, None]
+            ends = jnp.cumsum(load)
+            ntile = moe_decode.tiles_an_expert(       # (windows, held)
+                *_in_window(ends - load, ends, base, cap), tiles.rows)
+            items = jnp.sum(ntile)
+            passes = jnp.sum(ntile > 0) if whole else items
+            rows, combined = items * tiles.rows, jnp.sum(load)
+        else:
+            items = jnp.sum(-(-load // tiles.rows))
+            passes, rows = touched if whole else items, items * tiles.rows
+        return {"moe.expert_passes": passes.astype(F32),
+                "moe.rows_computed": rows.astype(F32),
+                "moe.prefill_rows_combined": combined.astype(F32)}

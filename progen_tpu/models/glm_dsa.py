@@ -90,7 +90,9 @@ from progen_tpu.models import driver, experts
 from progen_tpu.models.driver import (  # noqa: F401
     F32,
     bf16_policy,
+    residual,
     rms_norm,
+    stack_norm,
     swiglu,
 )
 from progen_tpu.models.experts import held_experts, kernel_counters
@@ -451,7 +453,9 @@ def moe_share(u, layer, c: GLMDSAConfig, live):
     stats = {"moe.tokens": jnp.sum(live).astype(F32),
              "moe.held_load": load.astype(F32),
              **kernel_counters(u, layer["experts"], load, c)}
-    return y.astype(u.dtype), ids, stats
+    with jax.named_scope("moe.experts"):    # the terms' own rounding
+        y = y.astype(u.dtype)
+    return y, ids, stats
 
 
 STAT_KEYS = experts.STAT_KEYS + ATTN_STAT_KEYS
@@ -473,16 +477,18 @@ def _layers(x, params, c, attend, live):
     eps = c.rms_norm_eps
     for i, layer in enumerate(params["layers"]):
         n = layer["norm"]
-        x = x + attend(rms_norm(x, n[0], eps), f"l{i}", layer["attn"])
-        u = rms_norm(x, n[1], eps)
+        x = residual(x, attend(stack_norm(x, n[0], eps), f"l{i}",
+                               layer["attn"]))
+        u = stack_norm(x, n[1], eps)
         if "experts" not in layer:
-            x = x + swiglu(u, layer["ffn"])
+            x = residual(x, swiglu(u, layer["ffn"]))
             continue
         m, ids, s = moe_share(u, layer, c, live)
         stats = experts.add_stats(stats, s)
         touched += jnp.sum(s["moe.held_load"] > 0).astype(F32)
         chosen.append(ids)
-        x = x + m + swiglu(u, layer["shared"], scope="moe.shared")
+        x = residual(residual(x, m),
+                     swiglu(u, layer["shared"], scope="moe.shared"))
     return x, stats, chosen, touched
 
 
@@ -505,9 +511,7 @@ def prefill(params, tokens, lengths, config: GLMDSAConfig,
 def caches_from(rows, lengths, config: GLMDSAConfig, max_len: int):
     """The per-token rows :func:`prefill` returned, as the caches of R
     slots in an engine of ``max_len``."""
-    blocks = blocks_of(config)
-    return {name: blocks[name].cache_rows(v, lengths, max_len)
-            for name, v in rows.items()}
+    return driver.cache_rows(blocks_of(config), rows, lengths, max_len)
 
 
 def decode_step(params, tok, pos, caches, live, config: GLMDSAConfig,

@@ -61,7 +61,9 @@ from progen_tpu.models.driver import (  # noqa: F401
     F32,
     bf16_policy,
     mm,
+    residual,
     rms_norm,
+    stack_norm,
     swiglu,
 )
 
@@ -288,8 +290,9 @@ def _layers(x, params, c, attend, live):
     m, eps = jnp.asarray(c.residual_multiplier, x.dtype), c.rms_norm_eps
     for i, layer in enumerate(params["layers"]):
         n = layer["norm"]
-        a = x + m * attend(rms_norm(x, n[0], eps), f"l{i}", layer["mixer"])
-        x = a + m * swiglu(rms_norm(a, n[1], eps), layer["ffn"])
+        a = residual(x, m * attend(stack_norm(x, n[0], eps), f"l{i}",
+                                   layer["mixer"]))
+        x = residual(a, m * swiglu(stack_norm(a, n[1], eps), layer["ffn"]))
     return x, driver.zero_scalars(STAT_KEYS), [], 0.0
 
 
@@ -308,9 +311,7 @@ def prefill(params, tokens, lengths, config: GraniteHybridConfig,
 def caches_from(rows, lengths, config: GraniteHybridConfig, max_len: int):
     """What :func:`prefill` returned, as the caches of R slots in an engine
     of ``max_len``."""
-    blocks = blocks_of(config)
-    return {name: blocks[name].cache_rows(v, lengths, max_len)
-            for name, v in rows.items()}
+    return driver.cache_rows(blocks_of(config), rows, lengths, max_len)
 
 
 def decode_step(params, tok, pos, caches, live, config: GraniteHybridConfig,

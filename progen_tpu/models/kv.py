@@ -136,11 +136,12 @@ class KVBlock:
         r, n, _ = x.shape
         positions = jnp.broadcast_to(jnp.arange(n), (r, n))
         q, k, v, rest = self.project(x, p, positions)
-        k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
         with jax.named_scope(self.scope):
+            k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
             o = gqa.prefill_attention(q, k, v, self.scale, self.window,
                                       lengths, self.block, **self._sink(p))
-        return self.finish(o, rest, p), {"k": k, "v": v}
+        with jax.named_scope("attn.out"):
+            return self.finish(o, rest, p), {"k": k, "v": v}
 
     def cache_rows(self, rows, lengths, max_len: int):
         """The per-token rows of R primes as R slots' caches: a full block
@@ -165,10 +166,10 @@ class KVBlock:
         value are written (``ops/row_write.py``) and the slot's rows
         attended."""
         q, k, v, rest = self.project(x[:, None], p, pos[:, None])
-        at, counts = self.place(pos, cache["k"].shape[2])
-        k, v = (k[:, 0].astype(cache["k"].dtype),
-                v[:, 0].astype(cache["v"].dtype))
         with jax.named_scope(self.scope):
+            at, counts = self.place(pos, cache["k"].shape[2])
+            k, v = (k[:, 0].astype(cache["k"].dtype),
+                    v[:, 0].astype(cache["v"].dtype))
             if self.v_head_dim == self.head_dim:
                 keys, values = write_rows((cache["k"], cache["v"]), (k, v),
                                           at, axis=1)
@@ -177,8 +178,9 @@ class KVBlock:
                 values = write_rows(cache["v"], v, at, axis=1)
             o = gqa.decode_attention(q[:, 0], keys, values, counts,
                                      self.scale, **self._sink(p))
-        rest = jax.tree.map(lambda a: a[:, 0], rest)
-        return self.finish(o, rest, p), {"k": keys, "v": values}
+        with jax.named_scope("attn.out"):
+            rest = jax.tree.map(lambda a: a[:, 0], rest)
+            return self.finish(o, rest, p), {"k": keys, "v": values}
 
     def decode_block(self, x, pos0, cache, p, commit, queries=None):
         """``B`` tokens a row (``B`` the mask's ``block``): ``x (S, B, h)``
@@ -209,11 +211,12 @@ class KVBlock:
                 f"block in front of it ({2 * b}): not {n}")
         first = pos0 - (n - b)
         q, k, v, rest = self.project(x, p, first[:, None] + jnp.arange(n))
-        k = k.transpose(0, 2, 1, 3).astype(cache["k"].dtype)
-        v = v.transpose(0, 2, 1, 3).astype(cache["v"].dtype)
-        if queries is not None:
-            q, rest = jax.tree.map(lambda a: a[:, n - queries:], (q, rest))
         with jax.named_scope("attn.block"):
+            k = k.transpose(0, 2, 1, 3).astype(cache["k"].dtype)
+            v = v.transpose(0, 2, 1, 3).astype(cache["v"].dtype)
+            if queries is not None:
+                q, rest = jax.tree.map(lambda a: a[:, n - queries:],
+                                       (q, rest))
             # the committed rows: all before ``pos0``, less a pending block
             # whose keys are this forward's
             o = gqa.block_decode_attention(
@@ -223,7 +226,8 @@ class KVBlock:
             keys, values = write_row_blocks(
                 (cache["k"], cache["v"]), (k[:, :, :b], v[:, :, :b]),
                 jnp.maximum(first, 0), commit)
-        return self.finish(o, rest, p), {"k": keys, "v": values}
+        with jax.named_scope("attn.out"):
+            return self.finish(o, rest, p), {"k": keys, "v": values}
 
 
 def decode_stats(blocks: dict, caches, pos, live, block_form=False) -> dict:

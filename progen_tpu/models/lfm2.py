@@ -79,7 +79,9 @@ from progen_tpu.models.driver import (  # noqa: F401
     F32,
     bf16_policy,
     mm,
+    residual,
     rms_norm,
+    stack_norm,
     swiglu,
 )
 from progen_tpu.models.experts import held_experts, kernel_counters
@@ -427,7 +429,9 @@ def moe_share(u, layer, c: LFM2Config, live):
     stats = {"moe.tokens": jnp.sum(live).astype(F32),
              "moe.held_load": load.astype(F32),
              **kernel_counters(u, layer["experts"], load, c)}
-    return y.astype(u.dtype), ids, stats
+    with jax.named_scope("moe.experts"):    # the terms' own rounding
+        y = y.astype(u.dtype)
+    return y, ids, stats
 
 
 def zero_stats(c: LFM2Config) -> dict:
@@ -445,16 +449,17 @@ def _layers(x, params, c, attend, live):
     eps = c.norm_eps
     for i, layer in enumerate(params["layers"]):
         n = layer["norm"]
-        r = x + attend(rms_norm(x, n[0], eps), f"l{i}", layer["mixer"])
-        u = rms_norm(r, n[1], eps)
+        r = residual(x, attend(stack_norm(x, n[0], eps), f"l{i}",
+                               layer["mixer"]))
+        u = stack_norm(r, n[1], eps)
         if "experts" not in layer:
-            x = r + swiglu(u, layer["ffn"])
+            x = residual(r, swiglu(u, layer["ffn"]))
             continue
         y, ids, s = moe_share(u, layer, c, live)
         stats = experts.add_stats(stats, s)
         touched += jnp.sum(s["moe.held_load"] > 0).astype(F32)
         chosen.append(ids)
-        x = r + y
+        x = residual(r, y)
     return x, stats, chosen, touched
 
 
@@ -470,9 +475,7 @@ def prefill(params, tokens, lengths, config: LFM2Config,
 def caches_from(rows, lengths, config: LFM2Config, max_len: int):
     """What :func:`prefill` returned, as the caches of R slots in an engine
     of ``max_len``."""
-    blocks = blocks_of(config)
-    return {name: blocks[name].cache_rows(v, lengths, max_len)
-            for name, v in rows.items()}
+    return driver.cache_rows(blocks_of(config), rows, lengths, max_len)
 
 
 def decode_step(params, tok, pos, caches, live, config: LFM2Config,

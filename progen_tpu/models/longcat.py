@@ -38,6 +38,7 @@ import jax.numpy as jnp
 
 from progen_tpu.core.precision import Policy
 from progen_tpu.models import experts, latent
+from progen_tpu.models.driver import residual, stack_norm
 from progen_tpu.models.experts import (  # noqa: F401
     held_experts, kernel_counters, moe_capacity)
 from progen_tpu.models.latent import (  # noqa: F401
@@ -187,7 +188,9 @@ def moe_share(u, layer, c: LongCatConfig, live):
              "moe.real_chosen": real.astype(F32),
              "moe.held_load": load.astype(F32),
              **kernel_counters(u, layer["experts"], load, c)}
-    return y.astype(u.dtype), ids, stats
+    with jax.named_scope("moe.experts"):    # the terms' own rounding
+        y = y.astype(u.dtype)
+    return y, ids, stats
 
 
 STAT_KEYS = (("moe.tokens", "moe.real_chosen") + experts.STAT_KEYS[1:]
@@ -206,15 +209,18 @@ def _layers(x, params, c, attend, live):
     chosen, touched = [], 0.0
     for i, layer in enumerate(params["layers"]):
         n, eps = layer["norm"], c.rms_norm_eps
-        a = x + attend(rms_norm(x, n[0], eps), f"l{i}a0", layer["attn"][0])
-        u = rms_norm(a, n[1], eps)
+        a = residual(x, attend(stack_norm(x, n[0], eps), f"l{i}a0",
+                               layer["attn"][0]))
+        u = stack_norm(a, n[1], eps)
         m, ids, s = moe_share(u, layer, c, live)
         stats = experts.add_stats(stats, s)
         touched += jnp.sum(s["moe.held_load"] > 0).astype(F32)
         chosen.append(ids)
-        b = a + swiglu(u, layer["ffn"][0])
-        cc = b + attend(rms_norm(b, n[2], eps), f"l{i}a1", layer["attn"][1])
-        x = cc + swiglu(rms_norm(cc, n[3], eps), layer["ffn"][1]) + m
+        b = residual(a, swiglu(u, layer["ffn"][0]))
+        cc = residual(b, attend(stack_norm(b, n[2], eps), f"l{i}a1",
+                                layer["attn"][1]))
+        x = residual(residual(cc, swiglu(stack_norm(cc, n[3], eps),
+                                         layer["ffn"][1])), m)
     return x, stats, chosen, touched
 
 

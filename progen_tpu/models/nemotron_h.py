@@ -75,7 +75,9 @@ from progen_tpu.models.driver import (  # noqa: F401
     F32,
     bf16_policy,
     mm,
+    residual,
     rms_norm,
+    stack_norm,
 )
 from progen_tpu.models.experts import held_experts, kernel_counters
 from progen_tpu.ops.moe_decode import activation
@@ -400,15 +402,16 @@ def _layers(x, params, c, attend, live):
     chosen, touched = [], 0.0
     for i, (kind, layer) in enumerate(zip(c.hybrid_override_pattern,
                                           params["layers"])):
-        u = rms_norm(x, layer["norm"], c.layer_norm_epsilon)
+        u = stack_norm(x, layer["norm"], c.layer_norm_epsilon)
         if kind != EXPERTS:
-            x = x + attend(u, f"l{i}", layer["mixer"])
+            x = residual(x, attend(u, f"l{i}", layer["mixer"]))
             continue
         y, ids, s = moe_share(u, layer, c, live)
         stats = experts.add_stats(stats, s)
         touched += jnp.sum(s["moe.held_load"] > 0).astype(F32)
         chosen.append(ids)
-        x = x + y + relu2(u, layer["shared"], "ffn.shared")
+        x = residual(residual(x, y),
+                     relu2(u, layer["shared"], "ffn.shared"))
     return x, stats, chosen, touched
 
 
@@ -427,9 +430,7 @@ def prefill(params, tokens, lengths, config: NemotronHConfig,
 def caches_from(rows, lengths, config: NemotronHConfig, max_len: int):
     """What :func:`prefill` returned, as the caches of R slots in an engine
     of ``max_len``."""
-    blocks = blocks_of(config)
-    return {name: blocks[name].cache_rows(v, lengths, max_len)
-            for name, v in rows.items()}
+    return driver.cache_rows(blocks_of(config), rows, lengths, max_len)
 
 
 def decode_step(params, tok, pos, caches, live, config: NemotronHConfig,

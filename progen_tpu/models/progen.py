@@ -175,28 +175,31 @@ class LocalAttention(nn.Module):
         h, d = self.heads, self.dim_head
         inner = h * d
 
-        x = _norm(self.policy, name="norm")(x)
+        with jax.named_scope("norm.layer"):
+            x = _norm(self.policy, name="norm")(x)
         # post-norm PRE-shift activations: the decode token-shift carry
         # (harvested by decode/prefill.py when the "cache" collection is
         # mutable; a no-op otherwise, and skipped at init so the variable
         # tree stays params-only)
         if self.sow_caches and not self.is_initializing():
             self.sow("cache", "prev", x)
-        if self.shift:
-            x = shift_tokens(x)
+        with jax.named_scope("attn.project"):
+            if self.shift:
+                x = shift_tokens(x)
 
-        qkv = _dense(inner * 3, use_bias=False, axes=("embed", "qkv"),
-                     policy=self.policy, name="to_qkv",
-                     weights=self.weights)(x)
-        if adapters is not None:
-            qkv = apply_lora(qkv, x, adapters["qkv"], tenant)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        # (B, L, H*D) -> (B, H, L, D)
-        q, k, v = (
-            t.reshape(b, n, h, d).transpose(0, 2, 1, 3) for t in (q, k, v)
-        )
-        # rotary on q, k AND v — reference progen.py:87
-        q, k, v = (apply_rotary_pos_emb(t, sin, cos) for t in (q, k, v))
+            qkv = _dense(inner * 3, use_bias=False, axes=("embed", "qkv"),
+                         policy=self.policy, name="to_qkv",
+                         weights=self.weights)(x)
+            if adapters is not None:
+                qkv = apply_lora(qkv, x, adapters["qkv"], tenant)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            # (B, L, H*D) -> (B, H, L, D)
+            q, k, v = (
+                t.reshape(b, n, h, d).transpose(0, 2, 1, 3)
+                for t in (q, k, v)
+            )
+            # rotary on q, k AND v — reference progen.py:87
+            q, k, v = (apply_rotary_pos_emb(t, sin, cos) for t in (q, k, v))
         # names for the 'attn' remat policy (save_only_these_names): the
         # post-rotary q/k/v feed the attention backward directly, so
         # saving them skips the norm->qkv->rotary replay
@@ -212,42 +215,44 @@ class LocalAttention(nn.Module):
             self.sow("cache", "k", k)
             self.sow("cache", "v", v)
 
-        if self.mesh is not None and self.attn_impl == "pallas":
-            # pallas_call has no GSPMD rule — run it full-manual over the
-            # mesh (halo exchange included); covers dp/fsdp/tp/sp meshes.
-            from progen_tpu.parallel.context import (
-                sharded_pallas_local_attention,
-            )
+        with jax.named_scope("attn.local"):
+            if self.mesh is not None and self.attn_impl == "pallas":
+                # pallas_call has no GSPMD rule — run it full-manual over the
+                # mesh (halo exchange included); covers dp/fsdp/tp/sp meshes.
+                from progen_tpu.parallel.context import (
+                    sharded_pallas_local_attention,
+                )
 
-            out = sharded_pallas_local_attention(
-                q, k, v, mesh=self.mesh, window_size=self.window_size,
-                scale=d ** -0.5,
-            )
-        elif _cp_active(self.mesh):
-            from progen_tpu.parallel.context import cp_local_attention
+                out = sharded_pallas_local_attention(
+                    q, k, v, mesh=self.mesh, window_size=self.window_size,
+                    scale=d ** -0.5,
+                )
+            elif _cp_active(self.mesh):
+                from progen_tpu.parallel.context import cp_local_attention
 
-            out = cp_local_attention(
-                q, k, v, mesh=self.mesh, window_size=self.window_size,
-                scale=d ** -0.5,
-            )
-        elif self.attn_impl == "pallas":
-            from progen_tpu.ops.pallas_attention import pallas_local_attention
+                out = cp_local_attention(
+                    q, k, v, mesh=self.mesh, window_size=self.window_size,
+                    scale=d ** -0.5,
+                )
+            elif self.attn_impl == "pallas":
+                from progen_tpu.ops.pallas_attention import pallas_local_attention
 
-            out = pallas_local_attention(q, k, v, self.window_size, d ** -0.5)
-        elif self.attn_impl == "xla":
-            out = local_attention(q, k, v, window_size=self.window_size,
-                                  scale=d ** -0.5)
-        else:
-            raise ValueError(
-                f"unknown attn_impl {self.attn_impl!r}; use 'xla' or 'pallas'"
-            )
-        out = out.transpose(0, 2, 1, 3).reshape(b, n, inner)
+                out = pallas_local_attention(q, k, v, self.window_size, d ** -0.5)
+            elif self.attn_impl == "xla":
+                out = local_attention(q, k, v, window_size=self.window_size,
+                                      scale=d ** -0.5)
+            else:
+                raise ValueError(
+                    f"unknown attn_impl {self.attn_impl!r}; use 'xla' or 'pallas'"
+                )
+            out = out.transpose(0, 2, 1, 3).reshape(b, n, inner)
         out = checkpoint_name(out, "attn_out")
-        y = _dense(self.dim, use_bias=True, axes=("qkv", "embed"),
-                   policy=self.policy, name="to_out",
-                   weights=self.weights)(out)
-        if adapters is not None:
-            y = apply_lora(y, out, adapters["out"], tenant)
+        with jax.named_scope("attn.out"):
+            y = _dense(self.dim, use_bias=True, axes=("qkv", "embed"),
+                       policy=self.policy, name="to_out",
+                       weights=self.weights)(out)
+            if adapters is not None:
+                y = apply_lora(y, out, adapters["out"], tenant)
         return y
 
 
@@ -271,8 +276,9 @@ class SGU(nn.Module):
     @nn.compact
     def __call__(self, x, adapters=None, tenant=None):
         n = self.seq_len
-        x, gate = jnp.split(x, 2, axis=-1)
-        gate = _norm(self.policy, name="norm")(gate)
+        with jax.named_scope("sgu.gate"):
+            x, gate = jnp.split(x, 2, axis=-1)
+            gate = _norm(self.policy, name="norm")(gate)
         # normed gate activations per position: the decode SGU gate cache
         # rows (decode/incremental.py SGUDecode) — prefill harvests these
         if self.sow_caches and not self.is_initializing():
@@ -301,7 +307,8 @@ class SGU(nn.Module):
             w_scale = self.variable(
                 "qscale", "spatial_weights_scale",
                 lambda: jnp.ones((n,), jnp.float32)).value
-            weights = weights_q.astype(jnp.float32) * w_scale[:, None]
+            with jax.named_scope("sgu.spatial"):
+                weights = weights_q.astype(jnp.float32) * w_scale[:, None]
         else:
             weights = self.param(
                 "spatial_weights",
@@ -326,53 +333,57 @@ class SGU(nn.Module):
         # inputs shorter than seq_len (one-pass prefill of a prime) use the
         # leading L rows/cols of the learned causal weights — exact, since
         # row m only ever reads columns <= m < L
-        L = gate.shape[-2]
-        if _cp_active(self.mesh):
-            # cp_spatial_gate owns the op under sequence parallelism (the
-            # all-gather + row-sharded matmul IS the sp decomposition);
-            # sgu_impl="pallas" deliberately falls back here rather than
-            # mis-sharding the blocked kernel across the seq axis.
-            from progen_tpu.parallel.context import cp_spatial_gate
+        with jax.named_scope("sgu.spatial"):
+            L = gate.shape[-2]
+            if _cp_active(self.mesh):
+                # cp_spatial_gate owns the op under sequence parallelism (the
+                # all-gather + row-sharded matmul IS the sp decomposition);
+                # sgu_impl="pallas" deliberately falls back here rather than
+                # mis-sharding the blocked kernel across the seq axis.
+                from progen_tpu.parallel.context import cp_spatial_gate
 
-            if L != n:
-                raise ValueError(
-                    f"context-parallel SGU requires the full seq_len {n}, "
-                    f"got length {L}"
+                if L != n:
+                    raise ValueError(
+                        f"context-parallel SGU requires the full seq_len {n}, "
+                        f"got length {L}"
+                    )
+                gate = cp_spatial_gate(
+                    gate,
+                    weights.astype(self.policy.compute_dtype),
+                    biases.astype(self.policy.compute_dtype),
+                    mesh=self.mesh,
                 )
-            gate = cp_spatial_gate(
-                gate,
-                weights.astype(self.policy.compute_dtype),
-                biases.astype(self.policy.compute_dtype),
-                mesh=self.mesh,
-            )
-            x = x * gate
-        else:
-            w = weights[:L, :L] if L != n else weights
-            b = biases[:L] if L != n else biases
-            w = w.astype(self.policy.compute_dtype)
-            b = b.astype(self.policy.compute_dtype)
-            if self.sgu_impl == "pallas" and self.mesh is not None:
-                # pallas_call has no GSPMD rule — run the fused kernel
-                # full-manual over the mesh (weights replicated per device)
-                from progen_tpu.parallel.context import (
-                    sharded_pallas_spatial_gate,
-                )
-
-                x = sharded_pallas_spatial_gate(x, gate, w, b, mesh=self.mesh)
-            elif self.sgu_impl == "pallas":
-                # fused res * (tril(W) @ gate + b): the mixed tensor never
-                # round-trips HBM and upper-triangle blocks are skipped
-                from progen_tpu.ops.pallas_sgu import pallas_spatial_gate
-
-                x = pallas_spatial_gate(x, gate, w, b)
+                with jax.named_scope("sgu.gate"):
+                    x = x * gate
             else:
-                gate = spatial_gate(gate, w, b)
-                x = x * gate
-        y = _dense(self.dim_out, use_bias=True, axes=("mlp_in", "mlp"),
-                   policy=self.policy, name="proj_out",
-                   weights=self.weights)(x)
-        if adapters is not None:
-            y = apply_lora(y, x, adapters, tenant)
+                w = weights[:L, :L] if L != n else weights
+                b = biases[:L] if L != n else biases
+                w = w.astype(self.policy.compute_dtype)
+                b = b.astype(self.policy.compute_dtype)
+                if self.sgu_impl == "pallas" and self.mesh is not None:
+                    # pallas_call has no GSPMD rule — run the fused kernel
+                    # full-manual over the mesh (weights replicated per device)
+                    from progen_tpu.parallel.context import (
+                        sharded_pallas_spatial_gate,
+                    )
+
+                    x = sharded_pallas_spatial_gate(x, gate, w, b, mesh=self.mesh)
+                elif self.sgu_impl == "pallas":
+                    # fused res * (tril(W) @ gate + b): the mixed tensor never
+                    # round-trips HBM and upper-triangle blocks are skipped
+                    from progen_tpu.ops.pallas_sgu import pallas_spatial_gate
+
+                    x = pallas_spatial_gate(x, gate, w, b)
+                else:
+                    gate = spatial_gate(gate, w, b)
+                    with jax.named_scope("sgu.gate"):
+                        x = x * gate
+        with jax.named_scope("sgu.proj"):
+            y = _dense(self.dim_out, use_bias=True, axes=("mlp_in", "mlp"),
+                       policy=self.policy, name="proj_out",
+                       weights=self.weights)(x)
+            if adapters is not None:
+                y = apply_lora(y, x, adapters, tenant)
         return y
 
 
@@ -400,22 +411,25 @@ class FeedForward(nn.Module):
         assert not (self.glu and self.use_sgu)
         hidden = self.dim * self.ff_mult * (2 if self.glu else 1)
 
-        x = _norm(self.policy, name="norm")(x)
+        with jax.named_scope("norm.layer"):
+            x = _norm(self.policy, name="norm")(x)
         if self.sow_caches and not self.is_initializing():
             self.sow("cache", "prev", x)
-        if self.shift:
-            x = shift_tokens(x)
+        with jax.named_scope("ffn.dense"):
+            if self.shift:
+                x = shift_tokens(x)
 
-        x = _dense(hidden, use_bias=True, axes=("embed", "mlp"),
-                   policy=self.policy, name="proj_in",
-                   weights=self.weights)(x)
-        x = nn.with_logical_constraint(x, ("act_batch", "act_seq", "act_mlp"))
+            x = _dense(hidden, use_bias=True, axes=("embed", "mlp"),
+                       policy=self.policy, name="proj_in",
+                       weights=self.weights)(x)
+            x = nn.with_logical_constraint(
+                x, ("act_batch", "act_seq", "act_mlp"))
 
-        if self.glu:
-            x, gate = jnp.split(x, 2, axis=-1)
-            x = x * nn.gelu(gate)
-        else:
-            x = nn.gelu(x)
+            if self.glu:
+                x, gate = jnp.split(x, 2, axis=-1)
+                x = x * nn.gelu(gate)
+            else:
+                x = nn.gelu(x)
 
         if self.use_sgu:
             x = SGU(seq_len=self.seq_len, dim_out=hidden // 2,
@@ -426,9 +440,10 @@ class FeedForward(nn.Module):
                         None if adapters is None else adapters["sgu"],
                         tenant)
 
-        return _dense(self.dim, use_bias=True, axes=("mlp", "embed"),
-                      policy=self.policy, name="proj_out",
-                      weights=self.weights)(x)
+        with jax.named_scope("ffn.dense"):
+            return _dense(self.dim, use_bias=True, axes=("mlp", "embed"),
+                          policy=self.policy, name="proj_out",
+                          weights=self.weights)(x)
 
 
 class ProGen(nn.Module):
@@ -495,22 +510,24 @@ class ProGen(nn.Module):
                 "rows past seq_len"
             )
 
-        x = nn.Embed(
-            cfg.num_tokens,
-            cfg.dim,
-            dtype=self.policy.compute_dtype,
-            param_dtype=self.policy.param_dtype,
-            embedding_init=nn.with_logical_partitioning(
-                nn.initializers.variance_scaling(1.0, "fan_in", "normal", out_axis=0),
-                ("vocab", "embed"),
-            ),
-            name="embed",
-        )(tokens)
+        with jax.named_scope("embed.tokens"):
+            x = nn.Embed(
+                cfg.num_tokens,
+                cfg.dim,
+                dtype=self.policy.compute_dtype,
+                param_dtype=self.policy.param_dtype,
+                embedding_init=nn.with_logical_partitioning(
+                    nn.initializers.variance_scaling(1.0, "fan_in", "normal", out_axis=0),
+                    ("vocab", "embed"),
+                ),
+                name="embed",
+            )(tokens)
         x = nn.with_logical_constraint(x, ("act_batch", "act_seq", "act_embed"))
 
         # rotary tables computed once, shared by all layers (progen.py:227);
         # kept f32, cast inside apply.
-        sin, cos = fixed_pos_embedding(n, cfg.dim_head)
+        with jax.named_scope("attn.rotary"):
+            sin, cos = fixed_pos_embedding(n, cfg.dim_head)
 
         if self.remat:
             if self.remat_policy == "full":
@@ -539,7 +556,7 @@ class ProGen(nn.Module):
             use_gmlp = cfg.layer_uses_gmlp(i)
             attn_ad = None if adapters is None else adapters.get(f"attn{i}")
             ff_ad = None if adapters is None else adapters.get(f"ff{i}")
-            x = x + attn_cls(
+            attn_out = attn_cls(
                 dim=cfg.dim,
                 window_size=cfg.window_size,
                 heads=cfg.heads,
@@ -552,7 +569,9 @@ class ProGen(nn.Module):
                 weights=self.weights,
                 name=f"attn{i}",
             )(x, sin, cos, attn_ad, tenant)
-            x = x + ff_cls(
+            with jax.named_scope("attn.out"):
+                x = x + attn_out
+            ff_out = ff_cls(
                 dim=cfg.dim,
                 seq_len=cfg.seq_len,
                 ff_mult=cfg.ff_mult,
@@ -566,11 +585,15 @@ class ProGen(nn.Module):
                 weights=self.weights,
                 name=f"ff{i}",
             )(x, ff_ad, tenant)
+            with jax.named_scope("ffn.dense"):
+                x = x + ff_out
             x = nn.with_logical_constraint(x, ("act_batch", "act_seq", "act_embed"))
 
-        x = _norm(self.policy, name="norm_out")(x)
-        if self.sow_final_hidden and not self.is_initializing():
-            self.sow("cache", "final_hidden", x)
-        logits = _dense(cfg.num_tokens, use_bias=True, axes=("embed", "vocab"),
-                        policy=self.policy, name="to_logits")(x)
-        return self.policy.cast_to_output(logits)
+        with jax.named_scope("head.logits"):
+            x = _norm(self.policy, name="norm_out")(x)
+            if self.sow_final_hidden and not self.is_initializing():
+                self.sow("cache", "final_hidden", x)
+            logits = _dense(cfg.num_tokens, use_bias=True,
+                            axes=("embed", "vocab"),
+                            policy=self.policy, name="to_logits")(x)
+            return self.policy.cast_to_output(logits)

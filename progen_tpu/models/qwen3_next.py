@@ -64,6 +64,7 @@ from progen_tpu.models.driver import (  # noqa: F401
     F32,
     bf16_policy,
     mm,
+    residual,
     swiglu,
 )
 from progen_tpu.models.experts import held_experts, kernel_counters
@@ -259,6 +260,12 @@ def norm(x, w, eps):
     return driver.rms_norm(x, 1.0 + w.astype(F32), eps)
 
 
+def stack_norm(x, w, eps):
+    """``N_w`` between two blocks (``driver.stack_norm``'s name)."""
+    with jax.named_scope("norm.rms"):
+        return norm(x, w, eps)
+
+
 def delta_block(c: Qwen3NextConfig) -> state.DeltaBlock:
     """Qwen3-Next's sizes of the shared delta-rule block."""
     return state.DeltaBlock(
@@ -362,7 +369,9 @@ def moe_share(u, layer, c: Qwen3NextConfig, live):
     stats = {"moe.tokens": jnp.sum(live).astype(F32),
              "moe.held_load": load.astype(F32),
              **kernel_counters(u, layer["experts"], load, c)}
-    return y.astype(u.dtype), ids, stats
+    with jax.named_scope("moe.experts"):    # the terms' own rounding
+        y = y.astype(u.dtype)
+    return y, ids, stats
 
 
 def zero_stats(c: Qwen3NextConfig) -> dict:
@@ -379,13 +388,14 @@ def _layers(x, params, c, attend, live):
     chosen, touched = [], 0.0
     for i, layer in enumerate(params["layers"]):
         n, eps = layer["norm"], c.rms_norm_eps
-        x = x + attend(norm(x, n[0], eps), f"l{i}", layer["mixer"])
-        u = norm(x, n[1], eps)
+        x = residual(x, attend(stack_norm(x, n[0], eps), f"l{i}",
+                               layer["mixer"]))
+        u = stack_norm(x, n[1], eps)
         y, ids, s = moe_share(u, layer, c, live)
         stats = experts.add_stats(stats, s)
         touched += jnp.sum(s["moe.held_load"] > 0).astype(F32)
         chosen.append(ids)
-        x = x + y + gated_shared(u, layer)
+        x = residual(residual(x, y), gated_shared(u, layer))
     return x, stats, chosen, touched
 
 
@@ -412,9 +422,7 @@ def prefill(params, tokens, lengths, config: Qwen3NextConfig,
 def caches_from(rows, lengths, config: Qwen3NextConfig, max_len: int):
     """What :func:`prefill` returned, as the caches of R slots in an engine
     of ``max_len``."""
-    blocks = blocks_of(config)
-    return {name: blocks[name].cache_rows(v, lengths, max_len)
-            for name, v in rows.items()}
+    return driver.cache_rows(blocks_of(config), rows, lengths, max_len)
 
 
 def decode_step(params, tok, pos, caches, live, config: Qwen3NextConfig,

@@ -54,7 +54,9 @@ from progen_tpu.models.driver import (  # noqa: F401
     F32,
     bf16_policy,
     mm,
+    residual,
     rms_norm,
+    stack_norm,
 )
 from progen_tpu.models.experts import held_experts, kernel_counters
 from progen_tpu.ops import gqa
@@ -281,11 +283,11 @@ def _layers(x, params, c, attend, live, tail=None):
     chosen, touched = [], 0.0
     for i, layer in enumerate(params["layers"]):
         n, eps = layer["norm"], c.rms_norm_eps
-        out = attend(rms_norm(x, n[0], eps), f"l{i}", layer["attn"])
+        out = attend(stack_norm(x, n[0], eps), f"l{i}", layer["attn"])
         if out.shape[0] != x.shape[0]:
             x, live = tail(x), tail(live)
-        a = x + out
-        t = rms_norm(a, n[1], eps)
+        a = residual(x, out)
+        t = stack_norm(a, n[1], eps)
         ids, w = route(t, layer["router"], c)
         y, load = held_experts(t, ids, w, live, layer["experts"], c)
         stats = experts.add_stats(stats, {
@@ -294,7 +296,9 @@ def _layers(x, params, c, attend, live, tail=None):
             **kernel_counters(t, layer["experts"], load, c)})
         touched += jnp.sum(load > 0).astype(F32)
         chosen.append(ids)
-        x = a + y.astype(x.dtype)
+        with jax.named_scope("moe.experts"):    # the terms' own rounding
+            y = y.astype(x.dtype)
+        x = residual(a, y)
     return x, stats, chosen, touched
 
 
@@ -322,9 +326,7 @@ def prefill(params, tokens, lengths, config: SDARConfig,
 def caches_from(rows, lengths, config: SDARConfig, max_len: int):
     """The per-token rows :func:`prefill` returned, as the caches of R
     slots in an engine of ``max_len``."""
-    blocks = blocks_of(config)
-    return {name: blocks[name].cache_rows(v, lengths, max_len)
-            for name, v in rows.items()}
+    return driver.cache_rows(blocks_of(config), rows, lengths, max_len)
 
 
 def block_step(params, tok, pos0, caches, live, commit, config: SDARConfig,

@@ -107,13 +107,13 @@ class StateBlock:
     def _split_in(self, x, p):
         with jax.named_scope("ssm.in_proj"):
             zxbcdt = mm(x, p["in_proj"])
-        inner = self.inner
-        z = zxbcdt[..., :inner]
-        xbc = zxbcdt[..., inner:inner + self.conv_channels]
-        dt = jax.nn.softplus(
-            zxbcdt[..., inner + self.conv_channels:].astype(F32)
-            + p["dt_bias"])
-        return z, xbc, dt
+            inner = self.inner
+            z = zxbcdt[..., :inner]
+            xbc = zxbcdt[..., inner:inner + self.conv_channels]
+            dt = jax.nn.softplus(
+                zxbcdt[..., inner + self.conv_channels:].astype(F32)
+                + p["dt_bias"])
+            return z, xbc, dt
 
     def _split_conv(self, xbc):
         inner, width = self.inner, self.groups * self.state
@@ -128,9 +128,9 @@ class StateBlock:
     def _out(self, y, x, z, p):
         """``y`` float32 from the recurrence: the skip, the gate, the norm
         a group of channels, the output projection."""
-        y = y + p["d"][:, None] * x.astype(F32)
-        y = y.reshape(y.shape[:-2] + (self.inner,)).astype(z.dtype)
         with jax.named_scope("ssm.norm"):
+            y = y + p["d"][:, None] * x.astype(F32)
+            y = y.reshape(y.shape[:-2] + (self.inner,)).astype(z.dtype)
             y, scale = y * jax.nn.silu(z), p["norm"]
             if self.groups > 1:
                 split = (self.groups, self.inner // self.groups)
@@ -151,7 +151,7 @@ class StateBlock:
             tail = ssd.conv_tail(xbc, lengths, self.conv)
             xbc = jax.nn.silu(ssd.causal_conv(
                 xbc, p["conv_w"], p["conv_b"])).astype(u.dtype)
-        x, b, cc = self._split_conv(xbc)
+            x, b, cc = self._split_conv(xbc)
         with jax.named_scope("ssm.scan"):
             y, state = ssd.ssd_scan(x, dt, -jnp.exp(p["a_log"]), b, cc,
                                     lengths, self.chunk)
@@ -167,7 +167,7 @@ class StateBlock:
             xbc, tail = ssd.conv_step(cache["conv"], xbc, p["conv_w"],
                                       p["conv_b"])
             xbc = jax.nn.silu(xbc).astype(u.dtype)
-        x, b, cc = self._split_conv(xbc)
+            x, b, cc = self._split_conv(xbc)
         with jax.named_scope("ssm.step"):
             y, state = ssd.ssd_step(cache["ssm"], x, dt,
                                     -jnp.exp(p["a_log"]), b, cc)
@@ -297,9 +297,9 @@ class DeltaBlock:
         value head, float32."""
         with jax.named_scope("gdn.in_proj"):
             ba = mm(x, p["ba_proj"]).astype(F32)
-        heads = self.value_heads
-        return jax.nn.sigmoid(ba[..., :heads]), -jnp.exp(
-            p["a_log"]) * jax.nn.softplus(ba[..., heads:] + p["dt_bias"])
+            heads = self.value_heads
+            return jax.nn.sigmoid(ba[..., :heads]), -jnp.exp(
+                p["a_log"]) * jax.nn.softplus(ba[..., heads:] + p["dt_bias"])
 
     def _split_conv(self, qkv):
         """``[q | k | v]`` after the convolution as heads: q and k unit
@@ -339,7 +339,7 @@ class DeltaBlock:
             tail = ssd.conv_tail(qkv, lengths, self.conv)
             qkv = jax.nn.silu(ssd.causal_conv(
                 qkv, p["conv_w"], None)).astype(u.dtype)
-        q, k, v = self._split_conv(qkv)
+            q, k, v = self._split_conv(qkv)
         with jax.named_scope("gdn.scan"):
             o, state = gdn.gdn_scan(q, k, v, g, beta, lengths, self.chunk)
         z = self._project(u, p, slice(split, None))
@@ -364,7 +364,7 @@ class DeltaBlock:
         with jax.named_scope("gdn.conv"):
             qkv, tail = ssd.conv_step(cache["conv"], qkv, p["conv_w"], None)
             qkv = jax.nn.silu(qkv).astype(u.dtype)
-        q, k, v = self._split_conv(qkv)
+            q, k, v = self._split_conv(qkv)
         with jax.named_scope("gdn.step"):
             o, state = gdn.gdn_step(cache["state"], q, k, v, g, beta)
         return self._out(o, z, p), {"state": state, "conv": tail}
@@ -489,7 +489,7 @@ class ChannelDeltaBlock(DeltaBlock):
             tail = ssd.conv_tail(qkv, lengths, self.conv)
             qkv = jax.nn.silu(ssd.causal_conv(
                 qkv, p["conv_w"], None)).astype(u.dtype)
-        q, k, v = self._split_conv(qkv)
+            q, k, v = self._split_conv(qkv)
         with jax.named_scope("kda.scan"):
             o, state = gdn.kda_scan(q, k, v, g, beta, lengths, self.chunk,
                                     self.block)
@@ -521,7 +521,7 @@ class ChannelDeltaBlock(DeltaBlock):
         with jax.named_scope("kda.conv"):
             qkv, tail = ssd.conv_step(cache["conv"], qkv, p["conv_w"], None)
             qkv = jax.nn.silu(qkv).astype(u.dtype)
-        q, k, v = self._split_conv(qkv)
+            q, k, v = self._split_conv(qkv)
         with jax.named_scope("kda.step"):
             o, state = gdn.kda_step(cache["state"], q, k, v, g, beta)
         return self._out(o, u, p), {"state": state, "conv": tail}

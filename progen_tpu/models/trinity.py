@@ -74,7 +74,9 @@ from progen_tpu.models.driver import (  # noqa: F401
     F32,
     bf16_policy,
     mm,
+    residual,
     rms_norm,
+    stack_norm,
     swiglu,
 )
 from progen_tpu.models.experts import held_experts, kernel_counters
@@ -310,7 +312,9 @@ def moe_share(u, layer, c: TrinityConfig, live):
     stats = {"moe.tokens": jnp.sum(live).astype(F32),
              "moe.held_load": load.astype(F32),
              **kernel_counters(u, layer["experts"], load, c)}
-    return y.astype(u.dtype), ids, stats
+    with jax.named_scope("moe.experts"):    # the terms' own rounding
+        y = y.astype(u.dtype)
+    return y, ids, stats
 
 
 STAT_KEYS = experts.STAT_KEYS + ATTN_STAT_KEYS
@@ -331,18 +335,18 @@ def _layers(x, params, c, attend, live):
     chosen, touched = [], 0.0
     for i, layer in enumerate(params["layers"]):
         n, eps = layer["norm"], c.rms_norm_eps
-        attn = attend(rms_norm(x, n[0], eps), f"l{i}", layer["attn"])
-        a = x + rms_norm(attn, n[1], eps)
-        u = rms_norm(a, n[2], eps)
+        attn = attend(stack_norm(x, n[0], eps), f"l{i}", layer["attn"])
+        a = residual(x, stack_norm(attn, n[1], eps))
+        u = stack_norm(a, n[2], eps)
         if "experts" not in layer:
-            x = a + rms_norm(swiglu(u, layer["ffn"]), n[3], eps)
+            x = residual(a, stack_norm(swiglu(u, layer["ffn"]), n[3], eps))
             continue
         m, ids, s = moe_share(u, layer, c, live)
         stats = experts.add_stats(stats, s)
         touched += jnp.sum(s["moe.held_load"] > 0).astype(F32)
         chosen.append(ids)
-        f = m + swiglu(u, layer["shared"], scope="moe.shared")
-        x = a + rms_norm(f, n[3], eps)
+        f = residual(m, swiglu(u, layer["shared"], scope="moe.shared"))
+        x = residual(a, stack_norm(f, n[3], eps))
     return x, stats, chosen, touched
 
 
@@ -363,9 +367,7 @@ def prefill(params, tokens, lengths, config: TrinityConfig,
 def caches_from(rows, lengths, config: TrinityConfig, max_len: int):
     """The per-token rows :func:`prefill` returned, as the caches of R
     slots in an engine of ``max_len``."""
-    blocks = blocks_of(config)
-    return {name: blocks[name].cache_rows(v, lengths, max_len)
-            for name, v in rows.items()}
+    return driver.cache_rows(blocks_of(config), rows, lengths, max_len)
 
 
 def decode_step(params, tok, pos, caches, live, config: TrinityConfig,

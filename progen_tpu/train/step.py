@@ -149,7 +149,8 @@ def make_train_functions(
     def loss_from_batch(params, batch):
         ids, labels = batch[:, :-1], batch[:, 1:]
         logits = apply_model(params, ids)
-        return batch_loss(logits, labels)
+        with jax.named_scope("loss.xent"):
+            return batch_loss(logits, labels)
 
     def _lr_value(count):
         # the lr the update at optimizer-step count `count` was scaled
@@ -167,12 +168,14 @@ def make_train_functions(
 
     def _train_step_body(state: TrainState, batch):
         loss, grads = jax.value_and_grad(loss_from_batch)(state.params, batch)
-        updates, opt_state = optimizer.update(grads, state.opt_state,
-                                              state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optim.update"):
+            updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                  state.params)
+            params = optax.apply_updates(state.params, updates)
+            grad_norm = optax.global_norm(grads)
         new_state = TrainState(step=state.step + 1, params=params,
                                opt_state=opt_state)
-        metrics = {"loss": loss, "grad_norm": optax.global_norm(grads)}
+        metrics = {"loss": loss, "grad_norm": grad_norm}
         if lr_schedule is not None:
             metrics["lr"] = _lr_value(_opt_count(state))
         return new_state, metrics
@@ -212,13 +215,14 @@ def make_train_functions(
     def eval_step(state: TrainState, batch):
         ids, labels = batch[:, :-1], batch[:, 1:]
         logits = apply_model(state.params, ids)
-        # all-zero rows are padding added to square off a final partial
-        # eval batch; callers drop them via this mask (a real collated row
-        # always has content after the BOS column)
-        real_rows = jnp.any(batch != 0, axis=1)
-        return {"loss": batch_loss(logits, labels),
-                "per_row_loss": cross_entropy(logits, labels),
-                "real_rows": real_rows}
+        with jax.named_scope("loss.xent"):
+            # all-zero rows are padding added to square off a final partial
+            # eval batch; callers drop them via this mask (a real collated
+            # row always has content after the BOS column)
+            real_rows = jnp.any(batch != 0, axis=1)
+            return {"loss": batch_loss(logits, labels),
+                    "per_row_loss": cross_entropy(logits, labels),
+                    "real_rows": real_rows}
 
     if mesh is not None:
         super_sharding = superbatch_sharding(mesh)

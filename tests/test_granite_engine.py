@@ -56,6 +56,22 @@ def test_a_slot_reused_after_idling_serves_as_a_fresh_one(engine):
     assert first == families.sequential_greedy(CASE, alone[0])
 
 
+def test_status_publishes_every_lowering_the_programs_noted(engine):
+    """ROADMAP D20: ``status()["lowerings"]`` is ``engine.lowerings`` whole,
+    so the state block's ``ssd_*`` — which have no key of their own among
+    the ten the engine spells — are on ``/statusz`` without an engine edit,
+    and the ten stay as they are."""
+    families.serve(engine, families.requests(CASE, 1, seed=5, first_uid=600))
+    status = engine.status()
+    assert status["lowerings"] == engine.lowerings
+    assert status["lowerings"]["ssd_prefill"] == "xla"
+    assert status["lowerings"]["ssd_step"] == "xla"
+    assert "ssd_step" not in status
+    assert status["row_write"] == status["lowerings"]["row_write"]
+    status["lowerings"]["ssd_step"] = "x"       # a copy, not the engine's
+    assert engine.lowerings["ssd_step"] == "xla"
+
+
 def slot_holds(engine):
     caches = engine.state["caches"]
     assert sorted(caches) == [f"l{i}" for i in range(6)]

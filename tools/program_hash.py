@@ -6,7 +6,8 @@ builds ``ServingEngine`` as the family's engine test does, takes
 ``jax.make_jaxpr`` of the admission program at the smallest prefill bucket
 and of the chunk program at the shapes ``aot_warmup`` compiles them for, and
 prints ``family.program -> sha256 head`` of the jaxpr's text with object
-addresses struck out.  Nothing runs and nothing compiles.
+addresses struck out.  The weights are shapes: nothing runs and nothing
+compiles.
 
 A PR that says "the other families' programs are the parent's letter for
 letter" shows it with this: ``tests/test_program_identity.py`` holds each
@@ -45,7 +46,9 @@ _ADDRESS = re.compile(r"0x[0-9a-fA-F]+")
 
 
 def build_engine(family: str):
-    """The family's engine at its tiny configuration, float32."""
+    """The family's engine at its tiny configuration, float32, over weights
+    that are SHAPES (``jax.eval_shape`` of what the family's tests
+    initialise): a program's text needs no weight, and nothing compiles."""
     import importlib
 
     import jax
@@ -62,12 +65,20 @@ def build_engine(family: str):
 
         policy = make_policy(False)
         tokens = jnp.zeros((2, config.seq_len), jnp.int32)
-        params = unbox(ProGen(config=config, policy=policy).init(
-            jax.random.key(7), tokens))
+        params = jax.eval_shape(lambda: unbox(ProGen(
+            config=config, policy=policy).init(jax.random.key(7), tokens)))
     else:
         tiny = importlib.import_module(f"tests.{family}_tiny")
         config = tiny.TINY
-        params, policy = tiny.make()
+        made = {}
+
+        def weights():
+            # past the module's cache: a traced call must not fill it
+            params, made["policy"] = getattr(
+                tiny.make, "__wrapped__", tiny.make)()
+            return params
+
+        params, policy = jax.eval_shape(weights), made["policy"]
     return ServingEngine(config, params, policy=policy,
                          num_slots=2 * SLOTS_PER_ADMIT_ROW, **ENGINE)
 
